@@ -116,7 +116,10 @@ def _train_lm(d_model, n_layers, n_heads, max_len, iters=120, lr=3e-3,
 
 
 def _build_decoder(d_model, n_layers, n_heads, block_size, max_blocks,
-                   kv_dtype=None, states=None):
+                   kv_dtype=None, states=None, platform="cpu"):
+    """`platform`: the backend the decoder will run on — every server
+    in this file is placed on CPUPlace, so "cpu" is the default even on
+    a TPU host (bench.py's kernel microbench passes its own)."""
     import paddle_tpu as fluid
     import paddle_tpu.core.framework as fw
     from paddle_tpu.models.transformer import build_lm_paged_decoder
@@ -124,7 +127,7 @@ def _build_decoder(d_model, n_layers, n_heads, block_size, max_blocks,
     fw.reset_unique_names()
     startup, dec = build_lm_paged_decoder(
         VOCAB, block_size, max_blocks, d_model=d_model, n_heads=n_heads,
-        n_layers=n_layers, kv_dtype=kv_dtype)
+        n_layers=n_layers, kv_dtype=kv_dtype, platform=platform)
     if states is None:
         scope = fluid.Scope()
         fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
@@ -306,7 +309,8 @@ def _quant_residency(d_model, n_layers, n_heads, block_size, max_blocks,
 
 
 def _build_kernel_decoder(d_model, n_layers, n_heads, block_size,
-                          max_blocks, kv_dtype=None, states=None):
+                          max_blocks, kv_dtype=None, states=None,
+                          platform="cpu"):
     """`_build_decoder` with the serving-kernel tier forced ON for the
     duration of the build (kernel selection happens at build time),
     restoring the user's flag after."""
@@ -317,7 +321,7 @@ def _build_kernel_decoder(d_model, n_layers, n_heads, block_size,
     try:
         return _build_decoder(d_model, n_layers, n_heads, block_size,
                               max_blocks, kv_dtype=kv_dtype,
-                              states=states)
+                              states=states, platform=platform)
     finally:
         core_flags.set_flags({"serving_kernels": prev})
 
@@ -332,11 +336,14 @@ def _measured_step_cost(d_model, n_layers, n_heads, block_size,
     "accesses" its whole operand), so an oversized pool inflates
     measured bytes with buffer size — traffic the per-step static
     model deliberately does not charge."""
+    import jax
     import jax.numpy as jnp
 
+    # the probe lowers on the default backend, so build for it
     build = _build_kernel_decoder if kernels_on else _build_decoder
     dec, states = build(d_model, n_layers, n_heads, block_size,
-                        max_blocks, kv_dtype=kv_dtype)
+                        max_blocks, kv_dtype=kv_dtype,
+                        platform=jax.default_backend())
     sj = {n: jnp.asarray(v) for n, v in states.items()}
     pool_k, pool_v = dec.init_pool(max_blocks)
     tables = jnp.zeros((slots, max_blocks), jnp.int32)
@@ -347,11 +354,9 @@ def _measured_step_cost(d_model, n_layers, n_heads, block_size,
                              zi, zi, jnp.zeros((slots,), jnp.float32),
                              jnp.ones((slots,), bool))
     ca = lowered.compile().cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
     backend = dec.kernels.get("paged_attention_decode", "xla")
-    return (float((ca or {}).get("flops", 0.0)),
-            float((ca or {}).get("bytes accessed", 0.0)), backend)
+    return (float(ca.get("flops", 0.0)),
+            float(ca.get("bytes accessed", 0.0)), backend)
 
 
 def kernel_roofline(d_model, n_layers, n_heads, block_size, max_blocks,
@@ -362,11 +367,15 @@ def kernel_roofline(d_model, n_layers, n_heads, block_size, max_blocks,
     kernel-backed estimates.  Band per tests/test_cost_model.py:
     flops within [0.5, 2.5]x and bytes within [0.4, 3]x of XLA's
     per-step cost analysis (estimated / measured)."""
-    from paddle_tpu.analysis.cost_model import (roofline_seconds,
+    from paddle_tpu.analysis.cost_model import (DEFAULT_DEVICE,
+                                                roofline_seconds,
                                                 serving_kernel_cost)
 
     ctx = block_size * max_blocks
+    # the floors are a STATIC estimate for a named target chip, not a
+    # reading of whatever device this process runs on
     out = {"slots": slots, "context": ctx, "rows": [],
+           "floor_device": DEFAULT_DEVICE,
            "band": {"flops": [0.5, 2.5], "bytes": [0.4, 3.0]},
            "pallas_vs_xla_bytes": {}}
     in_band = True
@@ -386,7 +395,8 @@ def kernel_roofline(d_model, n_layers, n_heads, block_size, max_blocks,
                    "ai_flop_per_byte": est["ai_flop_per_byte"],
                    "bound": est["bound"],
                    "floor_s": roofline_seconds(est["flops"],
-                                               est["bytes"])}
+                                               est["bytes"],
+                                               DEFAULT_DEVICE)}
             if calibrate:
                 mf, mb, built = _measured_step_cost(
                     d_model, n_layers, n_heads, block_size,
@@ -700,17 +710,20 @@ def run_fleet_ramp_bench(*, requests=64, peak_rps=20.0, phase_s=6.0,
                          sustain_s=1.0, idle_sustain_s=4.0,
                          cooldown_s=4.0, d_model=32, n_layers=1,
                          n_heads=2, block_size=4, max_blocks=8,
-                         slots=2, kv_blocks=24, use_tpu=0,
+                         slots=2, kv_blocks=24,
                          workdir=None, spawn_timeout_s=300.0,
                          decode_delay_s=0.02, phase_hook=None,
                          post_hook=None, env_extra=None):
-    """BENCH_SERVING_RAMP entry point: save a warm-start model dir,
-    front it with ReplicaRouter + Autoscaler spawning REAL `cli serve`
-    replicas, drive the open-loop ramp, and report per-phase serving
-    stats alongside the scaling timeline and each new replica's
-    cold-start accounting (spawn->live seconds; warm-started replicas
-    deserialize their executables, so the time-to-first-token of a
-    scale-out is bounded by model load, not XLA compile).
+    """BENCH_SERVING_RAMP entry point: save a model dir, front it with
+    ReplicaRouter + Autoscaler spawning REAL `cli serve` replicas,
+    drive the open-loop ramp, and report per-phase serving stats
+    alongside the scaling timeline and each surviving replica's
+    warmup accounting (compiles vs compile-cache hits).
+
+    This is a CPU-fleet bench: several replicas share one host and a
+    chip belongs to one process, so every replica is pinned to the CPU
+    (`--use_tpu 0`, JAX_PLATFORMS=cpu in its environment) — the calling
+    process may hold the chip without starving them.
 
     `decode_delay_s` arms a PADDLE_TPU_FAULTS delay rule on the
     replicas' ``serving.decode`` chaos site: the bench model is tiny
@@ -731,7 +744,6 @@ def run_fleet_ramp_bench(*, requests=64, peak_rps=20.0, phase_s=6.0,
     import shutil
     import tempfile
 
-    import paddle_tpu as fluid
     from paddle_tpu.cloud.autoscaler import (Autoscaler,
                                              AutoscalerPolicy,
                                              SubprocessReplicaLauncher)
@@ -745,15 +757,12 @@ def run_fleet_ramp_bench(*, requests=64, peak_rps=20.0, phase_s=6.0,
     max_len = block_size * max_blocks
     dec, states = _build_decoder(d_model, n_layers, n_heads,
                                  block_size, max_blocks)
-    t0 = time.perf_counter()
     save_generation_model(
         model_dir, states,
         {"vocab_size": VOCAB, "d_model": d_model, "n_heads": n_heads,
          "n_layers": n_layers, "block_size": block_size,
          "max_blocks_per_seq": max_blocks, "slots": slots,
-         "kv_blocks": kv_blocks},
-        warm_start=True, place=fluid.CPUPlace())
-    artifact_s = round(time.perf_counter() - t0, 2)
+         "kv_blocks": kv_blocks})
 
     router = ReplicaRouter(desired=max_replicas * 2, refresh_s=0.1)
     policy = AutoscalerPolicy(
@@ -761,16 +770,15 @@ def run_fleet_ramp_bench(*, requests=64, peak_rps=20.0, phase_s=6.0,
         backlog_high=backlog_high, backlog_low=backlog_low,
         sustain_s=sustain_s, idle_sustain_s=idle_sustain_s,
         cooldown_s=cooldown_s)
-    extra = dict(env_extra or {})
+    extra = dict(env_extra or {}, JAX_PLATFORMS="cpu")
     if decode_delay_s > 0:
         extra["PADDLE_TPU_FAULTS"] = ",".join(filter(None, [
             extra.get("PADDLE_TPU_FAULTS",
                       os.environ.get("PADDLE_TPU_FAULTS", "")),
             f"serving.decode:delay:1:1000000000:{decode_delay_s}"]))
-    env = dict(os.environ, **extra) if extra else None
     launcher = SubprocessReplicaLauncher(
-        model_dir, router.registry_addr, use_tpu=use_tpu, ttl_s=1.5,
-        drain_grace_s=30.0, env=env)
+        model_dir, router.registry_addr, use_tpu=0, ttl_s=1.5,
+        drain_grace_s=30.0, env=dict(os.environ, **extra))
     scaler = Autoscaler(router, launcher, policy, poll_s=0.2,
                         window_s=8.0,
                         spawn_timeout_s=spawn_timeout_s,
@@ -817,7 +825,6 @@ def run_fleet_ramp_bench(*, requests=64, peak_rps=20.0, phase_s=6.0,
             "peak_rps": peak_rps, "phase_s": phase_s,
             "decode_delay_s": decode_delay_s,
             "band": [min_replicas, max_replicas],
-            "artifact_build_s": artifact_s,
             "ramp": ramp,
             "fleet_size_per_phase": fleet_sizes,
             "fleet_size_final": len(

@@ -70,6 +70,7 @@ __all__ = [
     "roofline_seconds",
     "DEVICE_SPECS",
     "DEFAULT_DEVICE",
+    "running_device_kind",
     "DEFAULT_BATCH",
     "SERVING_KERNELS",
     "register_serving_kernel",
@@ -87,9 +88,11 @@ DEFAULT_BATCH = 32
 
 # device ridge points (bf16 peak FLOP/s, HBM bytes/s) — the ONE chip
 # table; benchmark/harness reads it too, so the measured roofline and
-# this compile-free estimate share every ridge point.  The default is
-# the bench chip every committed artifact (BENCH_r*/MOE_r*/RIDGE_r*)
-# was measured on
+# this compile-free estimate share every ridge point.  DEFAULT_DEVICE
+# is only the TARGET a compile-free report assumes when the caller
+# names none (`cli analyze --device`); anything that bands a RUNNING
+# device looks its kind up with `running_device_kind` and never
+# assumes these peaks
 DEVICE_SPECS: Dict[str, Tuple[float, float]] = {
     "TPU v5 lite": (197e12, 819e9),   # v5e
     "TPU v5": (459e12, 2765e9),       # v5p
@@ -99,6 +102,18 @@ DEVICE_SPECS: Dict[str, Tuple[float, float]] = {
 DEFAULT_DEVICE = "TPU v5 lite"
 
 
+def running_device_kind(device) -> str:
+    """`device.device_kind` of a live jax device, checked against
+    DEVICE_SPECS — KeyError naming the kind when the table has no
+    peaks for it (callers fail or skip by that name)."""
+    kind = device.device_kind
+    if kind not in DEVICE_SPECS:
+        raise KeyError(
+            f"device kind {kind!r} has no entry in "
+            f"cost_model.DEVICE_SPECS ({sorted(DEVICE_SPECS)})")
+    return kind
+
+
 def ridge_point(device: str = DEFAULT_DEVICE) -> float:
     """flop/byte at which `device` flips memory- to compute-bound."""
     peak, hbm = DEVICE_SPECS[device]
@@ -106,7 +121,7 @@ def ridge_point(device: str = DEFAULT_DEVICE) -> float:
 
 
 def roofline_seconds(flops: float, bytes_: float,
-                     device: str = DEFAULT_DEVICE) -> float:
+                     device: str) -> float:
     """Static roofline floor in SECONDS for work doing `flops` FLOPs
     and moving `bytes_` HBM bytes on `device` — max of the compute
     floor and the bandwidth floor.  The time-attribution plane
@@ -1172,25 +1187,20 @@ def _paged_decode_step_cost(spec: Dict, slots: int = 1,
 def _resolve_decode_backend(spec: Dict, kv_dtype: str) -> str:
     """What the serving-kernel tier would actually run for this spec on
     THIS process's platform (docs/performance.md "Serving kernels") —
-    so the analyze report's rows reflect reality, not aspiration.
-    Best-effort: a static analyzer must never fail on registry
-    absence."""
-    try:
-        from ..kernels import registry as kreg
-        from ..kernels.paged_attention import paged_attention_supports
-        import jax
+    so the analyze report's rows reflect reality, not aspiration."""
+    import jax
 
-        platform = jax.default_backend()
-        if not kreg.kernels_armed(platform):
-            return "xla"
-        d, h, layers, v, di, bs, nb = _spec_dims(spec)
-        reason = paged_attention_supports(
-            d_model=d, n_heads=h, block_size=bs,
-            max_blocks_per_seq=nb, kv_dtype=kv_dtype,
-            platform=platform)
-        return "xla" if reason else "pallas"
-    except Exception:
+    from ..kernels import registry as kreg
+    from ..kernels.paged_attention import paged_attention_supports
+
+    platform = jax.default_backend()
+    if not kreg.kernels_armed(platform):
         return "xla"
+    d, h, layers, v, di, bs, nb = _spec_dims(spec)
+    reason = paged_attention_supports(
+        d_model=d, n_heads=h, block_size=bs, max_blocks_per_seq=nb,
+        kv_dtype=kv_dtype, platform=platform)
+    return "xla" if reason else "pallas"
 
 
 def analyze_generation_spec(spec: Dict, slots: Optional[int] = None,

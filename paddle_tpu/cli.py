@@ -785,10 +785,6 @@ def cmd_serve(argv):
                     "its lease, finishes in-flight streams within "
                     "this budget, delists telemetry, then exits "
                     "(docs/serving.md 'Autoscaling')")
-    ap.add_argument("--cold", action="store_true",
-                    help="ignore the model dir's warm-start xla_cache "
-                    "artifact (compile from scratch — the baseline "
-                    "the artifact is measured against)")
     ap.add_argument("--telemetry",
                     default=os.environ.get(
                         "PADDLE_TPU_TELEMETRY_REGISTRY", ""),
@@ -809,7 +805,6 @@ def cmd_serve(argv):
         kv_dtype=args.kv_dtype or None,
         spec_k=args.spec_k or None,
         use_draft=not args.no_draft,
-        warm_start=not args.cold,
         place=_place(args.use_tpu))
     rep = ReplicaServer(server, port=args.port, host=args.host,
                         registry_addr=args.registry or None,
@@ -822,7 +817,7 @@ def cmd_serve(argv):
     suffix = (f", registered in {args.registry}" if args.registry
               else "")
     ws = server.warmup_stats
-    if server.warm_start_dir:
+    if server.warm_start:
         suffix += (f" (warm start: {ws['cache_hits']} executables "
                    f"deserialized, {ws['cache_misses']} compiled, "
                    f"warmup {ws['warmup_s']:.2f}s)")
@@ -865,8 +860,7 @@ def cmd_autoscale(argv):
         prog="paddle_tpu.cli autoscale",
         description="signal-driven autoscaling serving fleet")
     ap.add_argument("model_dir", help="save_generation_model output "
-                    "dir (ship it with warm_start=True so scale-out "
-                    "replicas skip XLA compile)")
+                    "dir")
     ap.add_argument("--registry", default="",
                     help="join an existing replica registry instead "
                     "of hosting one")
@@ -894,6 +888,13 @@ def cmd_autoscale(argv):
     ap.add_argument("--status_period", type=float, default=5.0)
     ap.add_argument("--use_tpu", type=int, default=1)
     args = ap.parse_args(argv)
+    if args.use_tpu and args.max_replicas > 1:
+        # the launcher spawns on THIS host and a chip belongs to one
+        # process: refuse the band up front instead of letting the
+        # second replica die at boot
+        ap.error("--use_tpu 1 with --max > 1 would put several "
+                 "chip-using replicas on one host; use --max 1 here "
+                 "(one autoscaler per host) or --use_tpu 0")
 
     policy = AutoscalerPolicy(
         args.min_replicas, args.max_replicas,

@@ -9,11 +9,10 @@ loop: a controller that watches those signals and grows or shrinks the
 
 * **scale-out** — sustained backlog (reserved-token queue) or p99 burn
   above target spawns one replica against the router's lease registry.
-  The cold-start enabler is the WARM-START artifact
-  (serving.save_generation_model(warm_start=True)): the new process
-  points PADDLE_TPU_COMPILATION_CACHE_DIR at the model dir's
-  ``xla_cache`` and deserializes its executables instead of compiling,
-  so time-to-first-token is bounded by model load, not XLA;
+  Replicas on a host share the one persistent compile cache
+  (core/compile_cache.py): after the first has compiled, a new process
+  deserializes its executables instead of compiling, so
+  time-to-first-token is bounded by model load, not XLA;
 * **scale-in** — sustained idle retires one replica via graceful
   drain: mark it draining at the router (no new placements), send the
   replica `drain` verb (stop admission, finish every accepted stream —
@@ -90,7 +89,7 @@ _M_SPAWN_FAILS = obs_metrics.counter(
 _M_SPAWN_S = obs_metrics.histogram(
     "paddle_tpu_autoscaler_spawn_seconds",
     "spawn -> live-in-the-routing-table latency (the cold-start cost "
-    "the warm-start artifact bounds)", ("scaler",), always=True)
+    "a warm compile cache bounds)", ("scaler",), always=True)
 
 
 # ---------------------------------------------------------------------------
@@ -288,15 +287,22 @@ class ReplicaProcess:
 
 class SubprocessReplicaLauncher:
     """Spawns `python -m paddle_tpu.cli serve MODEL_DIR --registry ...`
-    replicas.  The model dir's warm-start artifact (if shipped) is
-    picked up by `cli serve` itself — nothing to configure here."""
+    replicas on THIS host.
+
+    A chip belongs to one process: with `use_tpu` a second replica
+    would die at start-up (or hang) because the first holds the device,
+    so `spawn()` refuses while an earlier chip-using child is alive —
+    there is no device assignment here, scale a TPU fleet with one
+    launcher per host.  Children inherit this process's stderr unless
+    `stderr` redirects it; a replica that dies at boot must leave its
+    reason somewhere."""
 
     def __init__(self, model_dir: str, registry_addr: str, *,
                  use_tpu: int = 1, ttl_s: float = 2.0,
                  drain_grace_s: float = 30.0,
                  extra_args: Optional[List[str]] = None,
                  env: Optional[Dict[str, str]] = None,
-                 stderr=subprocess.DEVNULL):
+                 stderr=None):
         self.model_dir = model_dir
         self.registry_addr = registry_addr
         self.use_tpu = int(use_tpu)
@@ -305,8 +311,16 @@ class SubprocessReplicaLauncher:
         self.extra_args = list(extra_args or ())
         self.env = env
         self.stderr = stderr
+        self._spawned: List[ReplicaProcess] = []
 
     def spawn(self) -> ReplicaProcess:
+        self._spawned = [h for h in self._spawned if h.alive()]
+        if self.use_tpu and self._spawned:
+            raise RuntimeError(
+                "one chip-using process per host: replica pid "
+                f"{self._spawned[0].proc.pid} holds the TPU, a second "
+                "`cli serve --use_tpu 1` here would fail or hang at "
+                "start-up (run one launcher per host, or use_tpu=0)")
         cmd = [sys.executable, "-m", "paddle_tpu.cli", "serve",
                self.model_dir, "--registry", self.registry_addr,
                "--use_tpu", str(self.use_tpu),
@@ -316,7 +330,9 @@ class SubprocessReplicaLauncher:
         proc = subprocess.Popen(
             cmd, env=self.env, text=True, stdout=subprocess.PIPE,
             stderr=self.stderr)
-        return ReplicaProcess(proc)
+        handle = ReplicaProcess(proc)
+        self._spawned.append(handle)
+        return handle
 
 
 # ---------------------------------------------------------------------------

@@ -69,6 +69,18 @@ class ExecContext:
         self.executor = executor
         self.compiled = compiled
 
+    @property
+    def platform(self) -> str:
+        """Backend the ops under this context run on: the driving
+        Executor's place when there is one, else the process default
+        (the mesh executors build their meshes from jax.devices()).
+        Lowerings that choose a backend-specific kernel read this, not
+        jax.default_backend() — a CPUPlace executor on a TPU host must
+        not trace Mosaic calls."""
+        if self.executor is not None:
+            return self.executor.place.jax_device().platform
+        return jax.default_backend()
+
     def rng(self):
         """A fresh PRNG key, deterministic per (base key, call index)."""
         if self._rng_key is None:

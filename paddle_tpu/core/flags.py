@@ -20,8 +20,7 @@ _ON_CHANGE: Dict[str, list] = {}
 def on_flag_change(name: str, callback):
     """Register `callback()` to run whenever `set_flags` touches `name` —
     for flags that must take effect immediately rather than at the next
-    consumer read (e.g. compilation_cache_dir re-pointing JAX's
-    persistent cache)."""
+    consumer read (e.g. trace_dir arming the tracer)."""
     _ON_CHANGE.setdefault(name, []).append(callback)
 
 
@@ -94,12 +93,6 @@ define_flag("log_recompiles", False,
             "shape/dtype/LoD or trace-time-flag leak re-tracing the hot "
             "path.  Counted unconditionally in Executor.cache_stats()"
             "['recompiles_after_warmup']")
-define_flag("compilation_cache_dir", "",
-            "directory for JAX's persistent compilation cache: compiled "
-            "executables survive process restarts, so a relaunched "
-            "trainer pays deserialization instead of XLA compile time "
-            "for warm configs.  Wired on Executor init "
-            "(core/executor.py:_maybe_enable_persistent_cache)")
 define_flag("verify", "off",
             "static program verification before execution "
             "(paddle_tpu.analysis): 'off' = skip; 'warn' = run every "

@@ -26,11 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_reference"]
 
@@ -175,7 +171,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
     )(q, k, v)
     return o, lse
@@ -327,8 +323,7 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
-        if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -380,7 +375,7 @@ def _bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
     )(q, k, v, do, lse_t, delta_t)
     return dq, dk, dv
@@ -449,17 +444,20 @@ def _largest_tile(seq, block, align=128):
 
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None,
-                    interpret=None, min_seq_k=MIN_PALLAS_SEQ_K):
+                    interpret=None, min_seq_k=MIN_PALLAS_SEQ_K,
+                    platform=None):
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
     Streams K/V through VMEM with online softmax (fwd) and recomputation
-    (bwd).  Falls back to the XLA composition when not on a TPU backend
-    (unless `interpret=True` asks for the pallas interpreter, e.g. tests),
-    when the sequence doesn't tile onto MXU-aligned blocks, or when the
-    K/V length is below `min_seq_k` (where the XLA composition measures
-    faster; pass min_seq_k=0 to force the kernel).  Block sizes default
-    to the shape-keyed measured table (`_select_blocks`); explicit
-    block_q/block_k override it.
+    (bwd).  Falls back to the XLA composition when the computation will
+    not run on a TPU (unless `interpret=True` asks for the pallas
+    interpreter, e.g. tests), when the sequence doesn't tile onto
+    MXU-aligned blocks, or when the K/V length is below `min_seq_k`
+    (where the XLA composition measures faster; pass min_seq_k=0 to
+    force the kernel).  Block sizes default to the shape-keyed measured
+    table (`_select_blocks`); explicit block_q/block_k override it.
+    `platform` names the backend the traced computation targets (the
+    op lowering passes its executor's; None = the process default).
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -475,7 +473,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     block_k = min(block_k, sk)
     scale_v = float(d ** -0.5 if scale is None else scale)
     interp = bool(interpret)
-    if not interp and jax.default_backend() != "tpu":
+    if not interp and (platform or jax.default_backend()) != "tpu":
         # Mosaic only lowers on TPU, and emulating the grid loop on CPU/GPU
         # is far slower than one fused XLA attention — fall back unless the
         # caller opted into the pallas interpreter (interpret=True, tests)
@@ -496,7 +494,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
         # shapes to the XLA composition instead of failing at jit time
         tiles_ok = (tiles_ok and block_q % 128 == 0 and block_k % 128 == 0
                     and d % 8 == 0)
-    if (pltpu is None or not tiles_ok
+    if (not tiles_ok
             or k.shape != (b, sk, h, d) or v.shape != (b, sk, h, d)):
         return flash_attention_reference(q, k, v, causal, scale_v)
     # head-pair packing: at d_head 64 the [block, d] tiles fill half the

@@ -20,7 +20,9 @@ path bit-identical to the oracle composition
 
 Selection and fallback accounting: kernels/registry.py
 ("moe_gate_dispatch"); oversized routing tensors or non-f32 operands
-fall back to the oracle, counted.
+fall back to the oracle, counted.  So does every TPU selection: Mosaic
+cannot lower the in-kernel cumsum, so the kernel runs only under the
+Pallas interpreter (its parity tests).
 """
 from __future__ import annotations
 
@@ -58,10 +60,11 @@ def moe_dispatch_supports(*, tokens: int, d_model: int,
             > _VMEM_BUDGET_BYTES:
         return "vmem_routing"
     if platform == "tpu":
-        if d_model % 128:
-            return "lane_misaligned"
-        if tokens % 8:
-            return "sublane_misaligned"
+        # Mosaic has no lowering for the capacity-position cumsum
+        # ("Unimplemented primitive in Pallas TPU lowering: cumsum"):
+        # on a TPU this kernel is a counted fallback at every geometry
+        # (ROADMAP S5 replaces the capacity machinery it fuses)
+        return "mosaic_no_cumsum"
     return None
 
 

@@ -14,17 +14,21 @@ logical-order copy of the pool ever exists in HBM.
 
 Grid = (slots, max_blocks_per_seq), block index innermost so one
 slot's K/V blocks accumulate into a VMEM scratch of the logical
-context; the last block step runs the attention math for that slot.
-The math is POSITION-FOR-POSITION the oracle's (gather + QK^T +
--inf mask + jax.nn.softmax + att@V, f32 accumulation), which is what
-makes greedy decode through this kernel bit-identical to the XLA paged
-path — tests/test_serving_kernels.py pins it for fp32/bf16/int8 under
-Pallas interpret mode on CPU.
+context; the last block step runs the attention math for that slot:
+the oracle's QK^T, -inf mask, jax.nn.softmax and att@V with f32
+accumulation, one head at a time as 2-D contractions over lane-aligned
+column bands (the forms Mosaic lowers).  Under Pallas interpret mode on
+CPU greedy decode through it is token-identical to the XLA paged path
+for fp32/bf16/int8 (tests/test_serving_kernels.py); on a TPU the MXU's
+f32 passes differ from XLA's default-precision einsum, so the on-chip
+gate is a logit tolerance (chip_smoke.py serve_lm).
 
 `window > 1` is the teacher-forced multi-position variant: the same
 kernel body scores a [W, ctx] tile per slot (causal within the window
 via the position offsets), so speculative-decoding verification and
-chunked prefill ride the same kernel as single-token decode.
+chunked prefill ride the same kernel as single-token decode.  The
+window is padded to whole 8-row sublane tiles, so W=1 decode and a
+draft window share one code path.
 
 Selection and fallback accounting live in kernels/registry.py
 ("paged_attention_decode"); unsupported shape/dtype/platform
@@ -39,11 +43,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .registry import register_kernel
 
@@ -55,6 +55,12 @@ __all__ = ["paged_attention_supports", "build_paged_attention"]
 # registry falls back instead
 _SCRATCH_BUDGET_BYTES = 8 * 1024 * 1024
 
+_KV_DTYPES = ("fp32", "bf16", "int8")
+
+# the q/out window is padded to whole f32 sublane tiles so every
+# matmul operand and the output store are (8, 128)-aligned
+_WINDOW_ALIGN = 8
+
 
 def paged_attention_supports(*, d_model: int, n_heads: int,
                              block_size: int, max_blocks_per_seq: int,
@@ -63,7 +69,7 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
                              **_) -> Optional[str]:
     """None when the decode shape runs on the Pallas path, else a short
     fallback reason (the {kernel,reason} counter label)."""
-    if kv_dtype not in ("fp32", "bf16", "int8"):
+    if kv_dtype not in _KV_DTYPES:
         return "kv_dtype"
     if d_model % n_heads:
         return "head_split"
@@ -82,71 +88,56 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
             return "head_dim_misaligned"
         if block_size % 8:
             return "sublane_misaligned"
-    if pltpu is None:
-        return "no_pallas_tpu"
     return None
 
 
-def _decode_kernel(tables_ref, pos_ref, q_ref, kv_refs, vv_refs,
-                   o_ref, k_s, v_s, *, nb, bs, n_heads, d_head, scale,
-                   kv_dtype):
+def _decode_kernel(tables_ref, pos_ref, q_ref, *refs, nb, bs, n_heads,
+                   d_head, scale, kv_dtype):
     """Grid step (s, i): dequantize-copy pool block `tables[s, i]` into
     the logical-context scratch; at the slot's last block, run the
     oracle's attention math on the assembled [ctx, d] tiles.
 
-    `kv_refs`/`vv_refs` mirror the pool pytree: a bare block ref for
-    fp32/bf16, a (payload, scale) ref pair for int8."""
+    `refs` is (k block, v block[, k scales, v scales], out, k scratch,
+    v scratch): int8 pools bring their per-block f32 scales in whole
+    through SMEM and index them with the prefetched table entry."""
     s, i = pl.program_id(0), pl.program_id(1)
     ctx_len = nb * bs
+    rows = pl.ds(pl.multiple_of(i * bs, bs), bs)
 
     if kv_dtype == "int8":
-        kq_ref, ks_ref = kv_refs
-        vq_ref, vs_ref = vv_refs
-        k_s[pl.ds(i * bs, bs), :] = (kq_ref[0, 0].astype(jnp.float32)
-                                     * ks_ref[0, 0])
-        v_s[pl.ds(i * bs, bs), :] = (vq_ref[0, 0].astype(jnp.float32)
-                                     * vs_ref[0, 0])
+        k_ref, v_ref, ks_ref, vs_ref, o_ref, k_s, v_s = refs
+        blk = tables_ref[s, i]
+        k_s[rows, :] = k_ref[0, 0].astype(jnp.float32) * ks_ref[blk]
+        v_s[rows, :] = v_ref[0, 0].astype(jnp.float32) * vs_ref[blk]
     else:
-        k_s[pl.ds(i * bs, bs), :] = kv_refs[0, 0].astype(jnp.float32)
-        v_s[pl.ds(i * bs, bs), :] = vv_refs[0, 0].astype(jnp.float32)
+        k_ref, v_ref, o_ref, k_s, v_s = refs
+        k_s[rows, :] = k_ref[0, 0].astype(jnp.float32)
+        v_s[rows, :] = v_ref[0, 0].astype(jnp.float32)
 
     @pl.when(i == nb - 1)
     def _attend():
-        # the math below is TOKEN-FOR-TOKEN the oracle's gather block
-        # (same einsum contractions, same mask/softmax order) — that,
-        # not just closeness, is what the bit-identity pins rely on
-        w_n = q_ref.shape[1]
-        kh = k_s[...].reshape(ctx_len, n_heads, d_head)
-        vh = v_s[...].reshape(ctx_len, n_heads, d_head)
-        if w_n == 1:
-            # single-token decode: mirror step()'s windowless einsums —
-            # a size-1 q-dim contraction is NOT bitwise the same, so
-            # the branch is static on the block shape
-            qh = q_ref[0, 0].astype(jnp.float32).reshape(n_heads,
-                                                         d_head)
-            sc = jnp.einsum("hd,shd->hs", qh, kh) * scale
-            cols = jax.lax.broadcasted_iota(jnp.int32, (1, ctx_len), 1)
-            keep = (cols <= pos_ref[s])[0]
-            sc = jnp.where(keep[None, :], sc, -jnp.inf)
+        # per-head 2-D contractions over lane-aligned column bands —
+        # the oracle's QK^T / -inf mask / softmax / att@V, one head at
+        # a time (Mosaic has no batched dot without a free lhs dim, and
+        # no in-kernel [ctx, d] -> [ctx, h, d_head] relayout)
+        w_p = q_ref.shape[1]
+        q = q_ref[0].astype(jnp.float32)
+        # absolute position of window row w is pos[s] + w; row w
+        # attends to logical positions <= it (row 0 is step()'s mask,
+        # the rest step_window's teacher-forced causal mask)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (w_p, ctx_len), 1)
+        row_i = jax.lax.broadcasted_iota(jnp.int32, (w_p, ctx_len), 0)
+        keep = cols <= pos_ref[s] + row_i
+        for h in range(n_heads):
+            band = slice(h * d_head, (h + 1) * d_head)
+            sc = jax.lax.dot_general(
+                q[:, band], k_s[:, band], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(keep, sc, -jnp.inf)
             w_att = jax.nn.softmax(sc, axis=-1)
-            ctxh = jnp.einsum("hs,shd->hd", w_att, vh)
-            o_ref[0, 0] = ctxh.reshape(n_heads * d_head)
-        else:
-            qh = q_ref[0].astype(jnp.float32).reshape(
-                w_n, n_heads, d_head)
-            sc = jnp.einsum("qhd,shd->qhs", qh, kh) * scale
-            # absolute position of window row w is pos[s] + w; row w
-            # attends to logical positions <= it, matching
-            # step_window's teacher-forced causal mask
-            cols = jax.lax.broadcasted_iota(
-                jnp.int32, (w_n, ctx_len), 1)
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (w_n, ctx_len), 0)
-            keep = cols <= pos_ref[s] + rows
-            sc = jnp.where(keep[:, None, :], sc, -jnp.inf)
-            w_att = jax.nn.softmax(sc, axis=-1)
-            ctxh = jnp.einsum("qhs,shd->qhd", w_att, vh)
-            o_ref[0] = ctxh.reshape(w_n, n_heads * d_head)
+            o_ref[0, :, band] = jax.lax.dot_general(
+                w_att, v_s[:, band], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
 
 @register_kernel("paged_attention_decode", paged_attention_supports)
@@ -170,43 +161,43 @@ def build_paged_attention(*, d_model: int, n_heads: int,
         _decode_kernel, nb=nb, bs=bs, n_heads=n_heads, d_head=d_head,
         scale=scale, kv_dtype=kv_dtype)
 
-    def _pool_specs(layer):
+    def attend(q, pool_k, pool_v, tables, positions, layer):
+        s_n, w_n = q.shape[0], q.shape[1]
+        w_p = -(-w_n // _WINDOW_ALIGN) * _WINDOW_ALIGN
+        q = jnp.pad(q, ((0, 0), (0, w_p - w_n), (0, 0)))
+
         # one physical pool block per grid step, addressed THROUGH the
         # prefetched table — the kernel never sees a logical-order copy
         def blk(s, i, tab, pos):
             return (layer, tab[s, i], 0, 0)
 
+        def slot(s, i, tab, pos):
+            return (s, 0, 0)
+
+        pool_spec = pl.BlockSpec((1, 1, bs, d_model), blk)
         if kv_dtype == "int8":
-            def scl(s, i, tab, pos):
-                return (layer, tab[s, i])
-
-            return (pl.BlockSpec((1, 1, bs, d_model), blk),
-                    pl.BlockSpec((1, 1), scl))
-        return pl.BlockSpec((1, 1, bs, d_model), blk)
-
-    def attend(q, pool_k, pool_v, tables, positions, layer):
-        s_n, w_n = q.shape[0], q.shape[1]
+            (pool_k, k_scale), (pool_v, v_scale) = pool_k, pool_v
+            scales = (k_scale[layer], v_scale[layer])
+            scale_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        else:
+            scales, scale_specs = (), []
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(s_n, nb),
-            in_specs=[
-                pl.BlockSpec((1, w_n, d_model),
-                             lambda s, i, tab, pos: (s, 0, 0)),
-                _pool_specs(layer),
-                _pool_specs(layer),
-            ],
-            out_specs=pl.BlockSpec((1, w_n, d_model),
-                                   lambda s, i, tab, pos: (s, 0, 0)),
+            in_specs=[pl.BlockSpec((1, w_p, d_model), slot),
+                      pool_spec, pool_spec] + scale_specs,
+            out_specs=pl.BlockSpec((1, w_p, d_model), slot),
             scratch_shapes=[
                 pltpu.VMEM((nb * bs, d_model), jnp.float32),
                 pltpu.VMEM((nb * bs, d_model), jnp.float32),
             ],
         )
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kern, grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((s_n, w_n, d_model),
+            out_shape=jax.ShapeDtypeStruct((s_n, w_p, d_model),
                                            jnp.float32),
             interpret=interpret,
-        )(tables, positions, q, pool_k, pool_v)
+        )(tables, positions, q, pool_k, pool_v, *scales)
+        return out[:, :w_n]
 
     return attend

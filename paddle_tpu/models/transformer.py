@@ -489,7 +489,7 @@ def build_lm_kv_decoder(vocab_size, max_len, d_model=256, n_heads=4,
 
 def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                            d_model=256, n_heads=4, n_layers=2,
-                           d_inner=None, kv_dtype=None):
+                           d_inner=None, kv_dtype=None, platform=None):
     """Paged-attention decode step for the decoder-only LM.
 
     `build_lm_kv_decoder` owns a dense per-sequence cache
@@ -560,6 +560,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     prediction.  It is what chunked prefill and speculative-decoding
     verification (serving/generation.py) run; window rows past
     n_valid write into the null block and return garbage.
+
+    `decoder.step_logits(...)` takes `step`'s arguments and returns the
+    [S, vocab] float32 logits `step` samples from, without donating or
+    updating the pools — the numerics gate between the Pallas and XLA
+    attention paths (chip_smoke.py).
+
+    `platform` names the backend the decoder will RUN on (None = the
+    process default): kernel selection and pool donation follow it, and
+    GenerationServer refuses a decoder built for another platform than
+    its place's device (`decoder.platform`).
     """
     import functools
     import math
@@ -588,11 +598,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # the oracle (docs/performance.md "Serving kernels")
     from ..kernels import registry as _kernel_registry
 
+    platform = platform or jax.default_backend()
     kernel_selection = _kernel_registry.Selection()
     _attend = kernel_selection.pick(
         "paged_attention_decode", d_model=d_model, n_heads=n_heads,
         block_size=int(block_size),
-        max_blocks_per_seq=int(max_blocks_per_seq), kv_dtype=kv_dtype)
+        max_blocks_per_seq=int(max_blocks_per_seq), kv_dtype=kv_dtype,
+        platform=platform)
 
     startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
         _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -602,7 +614,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # buffer donation makes the pool update in place (no copy of the
     # whole cache per token); CPU has no donation support and would
     # warn once per compile, so only donate where it lands
-    donate = (1, 2) if jax.default_backend() != "cpu" else ()
+    donate = (1, 2) if platform != "cpu" else ()
 
     # -- pool storage: quantize-on-write / dequantize-on-gather ------------
     def _write(pool, l, wb, wi, row):
@@ -654,9 +666,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                                    logits / safe_t)
         return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
-             active):
+    def _step_logits(g, pool_k, pool_v, tables, positions, tokens,
+                     active):
         s_n = tokens.shape[0]
         lane = jnp.arange(s_n)
 
@@ -717,9 +728,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
         xf = ln(x, 2 * n_layers)
         wf, bf = W(6 * n_layers)
-        logits = xf @ wf + bf                                # [S, V]
-        nxt = _sample(logits, seeds, positions, temps)
-        return nxt, pool_k, pool_v
+        return xf @ wf + bf, pool_k, pool_v                  # [S, V]
+
+    @functools.partial(jax.jit, donate_argnums=donate)
+    def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
+             active):
+        logits, pool_k, pool_v = _step_logits(
+            g, pool_k, pool_v, tables, positions, tokens, active)
+        return _sample(logits, seeds, positions, temps), pool_k, pool_v
+
+    @jax.jit
+    def step_logits(g, pool_k, pool_v, tables, positions, tokens, seeds,
+                    temps, active):
+        return _step_logits(g, pool_k, pool_v, tables, positions,
+                            tokens, active)[0]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -832,7 +854,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     import types
 
     decoder = types.SimpleNamespace(
-        step=step, step_window=step_window, init_pool=init_pool,
+        step=step, step_window=step_window, step_logits=step_logits,
+        init_pool=init_pool, platform=platform,
         state_names=sorted(shapes), state_shapes=shapes, block_size=bs,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,

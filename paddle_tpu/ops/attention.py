@@ -49,5 +49,5 @@ def flash_attention_op(ctx, ins, attrs):
     if msk >= 0:
         kw["min_seq_k"] = msk
     out = _flash(q, k, v, causal=bool(attrs.get("causal", False)),
-                 scale=scale, **kw)
+                 scale=scale, platform=ctx.platform, **kw)
     return {"Out": out}

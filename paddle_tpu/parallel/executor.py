@@ -699,13 +699,19 @@ class ParallelExecutor(ShardedCheckpointMixin):
         if hasattr(self, "_kernel_selection"):
             self._kernel_selection.close()
 
-    def compiled_collectives(self, feed: Dict) -> Dict[str, int]:
-        """Counts of cross-device collective ops in the optimized HLO of
-        the train step compiled for `feed`'s shapes — pins the
-        communication STRUCTURE of a mesh without the hardware (e.g.
-        dp-N must show grad all-reduces and nothing else; run_scaling.py
-        --virtual reports this per N alongside the no-op virtual
-        throughput)."""
+    def compiled_collectives(self, feed: Dict,
+                             optimized: bool = True) -> Dict[str, int]:
+        """Counts of cross-device collective ops in the train step
+        compiled for `feed`'s shapes — pins the communication STRUCTURE
+        of a mesh without the hardware (e.g. dp-N must show grad
+        all-reduces and nothing else; run_scaling.py --virtual reports
+        this per N alongside the no-op virtual throughput).
+
+        `optimized=True` counts the optimized HLO, i.e. what runs — XLA's
+        all-reduce combiner may have merged neighbouring reductions
+        there.  `optimized=False` counts the module as TRACED (one op
+        per psum the step issues), which is where a bucketing policy is
+        pinned exactly."""
         from .mesh import count_collectives
 
         feeds = {
@@ -716,9 +722,9 @@ class ParallelExecutor(ShardedCheckpointMixin):
             for n, v in feed.items()
         }
         key = jax.random.key(self._seed)
-        txt = self._jit_step.lower(feeds, self._states, key) \
-            .compile().as_text()
-        return count_collectives(txt)
+        lowered = self._jit_step.lower(feeds, self._states, key)
+        return count_collectives(lowered.compile().as_text() if optimized
+                                 else lowered.as_text())
 
     def state(self, name, return_numpy=True):
         v = self._states[name]

@@ -11,6 +11,7 @@ onto ICI; DCN-spanning meshes put the slowest-varying axis across hosts.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -20,93 +21,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["make_mesh", "get_places", "data_sharding", "replicated",
            "init_distributed", "PartitionSpec", "NamedSharding",
-           "shard_map", "pvary"]
+           "shard_map"]
 
 
-# ---------------------------------------------------------------------------
-# shard_map entry-point shim
-# ---------------------------------------------------------------------------
-# jax moved shard_map from jax.experimental.shard_map (kwargs `auto`,
-# `check_rep`) to jax.shard_map (kwargs `axis_names`, `check_vma`).
-# Every shard_map in this package goes through THIS helper so the
-# version split lives in exactly one place; callers use the modern
-# surface (`axis_names` = the manual axes) and the shim translates for
-# whichever entry point the installed jax provides.
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _EXP_SHARD_MAP
-else:
-    _EXP_SHARD_MAP = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """Map `f` over `mesh` shards (the jax.shard_map contract).
-
-    `axis_names`: the MANUAL axes (values inside `f` have a local view
-    of them; collectives may reference them).  Omitted/None = all mesh
-    axes manual.  Axes left out stay in GSPMD-auto mode: arrays keep
-    their NamedShardings over them and XLA propagates/inserts their
-    collectives.
-
-    Replication checking is disabled on both entry points: the bodies
-    in this package mix manual and auto axes plus masked psums, and the
-    old-jax checker rejects exactly the invariant-to-varying casts the
-    new jax expresses with `pvary` (shimmed to a no-op below when the
-    primitive is absent — semantically right because an unchecked body
-    already treats every value as varying).
-
-    Old-jax degradation: jax.experimental.shard_map's partial-auto mode
-    (`auto=`) is unusable with this jaxlib's SPMD partitioner
-    (axis_index lowers to a PartitionId the partitioner rejects, and
-    ppermute trips a hard CHECK in spmd_partitioner.cc), so auto axes
-    fall back to manual-and-GATHERED there: in_specs never mention
-    them, so shard_map gathers inputs along those axes and the body
-    computes replicated over them.  Numerics are identical; the auto
-    axes simply stop sharding compute until a jax with a working
-    partial-auto mode is installed."""
-    if _NEW_SHARD_MAP is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        try:
-            return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_vma=False,
-                                  **kw)
-        except TypeError:
-            # jax with jax.shard_map but the older (check_rep=, auto=)
-            # spelling: translate axis_names to its auto= complement
-            kw = {}
-            if axis_names is not None:
-                kw["auto"] = (frozenset(mesh.axis_names)
-                              - frozenset(axis_names))
-            try:
-                return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                      out_specs=out_specs,
-                                      check_rep=False, **kw)
-            except TypeError:
-                # no partial-auto support at all: degrade to
-                # manual-and-gathered like the experimental path
-                return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                                      out_specs=out_specs,
-                                      check_rep=False)
-    return _EXP_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-def pvary(x, axis_names):
-    """Cast a replicated value to device-varying over `axis_names`
-    (jax.lax.pvary / the older pcast(to="varying")).  On jax versions
-    without the primitive this is the identity: those versions'
-    shard_map runs with replication checking off, where every value is
-    already treated as varying and the cast has no semantic content."""
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is None:
-        fn = getattr(jax.lax, "pcast", None)
-        if fn is not None:
-            return fn(x, tuple(axis_names), to="varying")
-        return x
-    return fn(x, tuple(axis_names))
+# Every shard_map in this package goes through this spelling: the bodies
+# mix manual and auto axes plus masked psums, which the varying-manual-
+# axes checker rejects, so it is off for all of them.  `axis_names` (a
+# set) names the MANUAL axes — values inside the body have a local view
+# of them and collectives may reference them; axes left out stay in
+# GSPMD-auto mode.  Omitted = every mesh axis manual.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def get_places(device_count: Optional[int] = None):
@@ -164,18 +88,21 @@ def init_distributed(coordinator_address: Optional[str] = None,
 
 
 def count_collectives(hlo_text: str) -> dict:
-    """Counts of cross-device collective instructions in optimized HLO
-    text — the one shared digest behind ParallelExecutor.
-    compiled_collectives and composite.collective_counts.  Instruction
-    forms: `<name> = <type> <op>(`; async pairs appear as
+    """Counts of cross-device collective instructions in module text —
+    the one shared digest behind ParallelExecutor.compiled_collectives
+    and composite.collective_counts.  Optimized-HLO instruction forms:
+    `<name> = <type> <op>(`; async pairs appear as
     <op>-start(/<op>-done( and count once.  `<op>(` never matches operand
-    references (those are `%<op>.N`)."""
+    references (those are `%<op>.N`).  The traced (StableHLO) module
+    spells the same ops `stablehlo.all_reduce` etc.; both count."""
     import re
 
     out = {}
     for op in ("all-reduce", "all-gather", "reduce-scatter",
                "collective-permute", "all-to-all"):
-        n = len(re.findall(rf"{op}(?:-start)?\(", hlo_text))
+        n = len(re.findall(
+            rf"{op}(?:-start)?\(|stablehlo\.{op.replace('-', '_')}\b",
+            hlo_text))
         if n:
             out[op] = n
     return out

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import pvary, shard_map
+from .mesh import shard_map
 
 __all__ = ["spmd_pipeline", "spmd_pipeline_1f1b", "stack_stage_params",
            "microbatch", "unmicrobatch", "schedule_steps",
@@ -161,7 +161,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params, x, mesh: Mesh,
         pad = jnp.broadcast_to(xs[:1], (pp - 1,) + xs.shape[1:])
         stream = jnp.concatenate([xs, pad], axis=0)
         state0 = jax.lax.stop_gradient(xs[0])
-        state0 = pvary(state0, (axis,))
+        state0 = jax.lax.pcast(state0, (axis,), to="varying")
 
         def tick(state, xt_t):
             xt, t = xt_t
@@ -304,17 +304,18 @@ def spmd_pipeline_1f1b(stage_fn: Callable, last_fn: Callable,
             # are replicated over dp/sp, so keep their grads per-device
             # local and do the one explicit psum at the end
             params_local = jax.tree_util.tree_map(
-                lambda p: pvary(p, other_axes),
+                lambda p: jax.lax.pcast(p, other_axes, to="varying"),
                 params_local)
         # last_p arrives INVARIANT over the manual axes; differentiating
         # w.r.t. an invariant value makes the vjp transpose insert an
         # implicit psum (the transpose of the invariant->varying
         # broadcast), which would sum every device's masked-out garbage
-        # gradient into each step.  pvary (cast to varying) first: grads stay
+        # gradient into each step.  Cast to varying first: grads stay
         # per-device local and the single masked psum at the end is the
         # only cross-device reduction.
         last_p_v = jax.tree_util.tree_map(
-            lambda l: pvary(l, (axis,) + other_axes), last_p)
+            lambda l: jax.lax.pcast(l, (axis,) + other_axes,
+                                    to="varying"), last_p)
 
         def fwd_vjp(h, t):
             if with_tick:
@@ -334,7 +335,7 @@ def spmd_pipeline_1f1b(stage_fn: Callable, last_fn: Callable,
         # prime the residual buffer with ONE real vjp (structure + finite
         # values for the masked early backward ticks)
         h0 = jax.lax.stop_gradient(xs[0])
-        h0 = pvary(h0, (axis,))
+        h0 = jax.lax.pcast(h0, (axis,), to="varying")
         out0, leaves0, treedef = fwd_vjp(h0, 0)
         res_buf0 = [jnp.broadcast_to(l, (BUF,) + l.shape) for l in leaves0]
         zeros_g = jax.tree_util.tree_map(jnp.zeros_like, params_local)
@@ -347,8 +348,8 @@ def spmd_pipeline_1f1b(stage_fn: Callable, last_fn: Callable,
             res_buf=res_buf0,
             g_stage=zeros_g,
             g_last=zeros_gl,
-            loss=pvary(jnp.zeros((), jnp.float32),
-                       (axis,) + other_axes),
+            loss=jax.lax.pcast(jnp.zeros((), jnp.float32),
+                               (axis,) + other_axes, to="varying"),
         )
 
         fwd_perm = [(i, (i + 1) % pp) for i in range(pp)]

@@ -42,16 +42,9 @@ __all__ = [
 def lowered_ir_text(lowered) -> str:
     """Debug-info MLIR text of a `jax.jit(...).lower(...)` result — the
     loc() metadata carries the per-op named_scope the compiled executor
-    emits, so scope assertions and debugging work on it.  Spans the jax
-    API split: `as_text(debug_info=True)` where available, else the
-    MLIR printer with debug info enabled."""
-    try:
-        return lowered.as_text(debug_info=True)
-    except TypeError:
-        from jax._src.interpreters import mlir
+    emits, so scope assertions and debugging work on it."""
+    return lowered.as_text(debug_info=True)
 
-        return mlir.module_to_string(lowered.compiler_ir(),
-                                     enable_debug_info=True)
 
 _enabled = False
 _events: Dict[str, List[float]] = {}
@@ -257,44 +250,18 @@ def profile_compiled_ops(run_fn, steps: int = 3, hlo_text: str = "",
                 out = run_fn()
                 jax.block_until_ready(out)
         per_op: Dict[str, List[float]] = {}
-        if hasattr(jax.profiler, "ProfileData"):
-            pbs = glob.glob(tmp + "/**/*.xplane.pb", recursive=True)
-            if not pbs:
-                raise RuntimeError("jax.profiler produced no xplane capture")
-            pd = jax.profiler.ProfileData.from_file(pbs[0])
-            for plane in pd.planes:
-                for line in plane.lines:
-                    for ev in line.events:
-                        try:
-                            stats = dict(ev.stats)
-                        except Exception:
-                            stats = {}
-                        hlo = stats.get("hlo_op")
-                        if not hlo:
-                            continue
-                        dur = getattr(ev, "duration_ns", 0.0) or 0.0
-                        if dur <= 0:
-                            continue
-                        per_op.setdefault(str(hlo), []).append(dur / 1e9)
-        else:
-            # jax without the xplane reader: the same capture also writes
-            # a Chrome trace whose complete events carry args.hlo_op and
-            # microsecond durations — digest that instead
-            import gzip
-            import json
-
-            traces = glob.glob(tmp + "/**/*.trace.json.gz", recursive=True)
-            if not traces:
-                raise RuntimeError("jax.profiler produced no trace capture")
-            for path in traces:
-                with gzip.open(path, "rt") as fh:
-                    events = json.load(fh).get("traceEvents", [])
-                for ev in events:
-                    hlo = (ev.get("args") or {}).get("hlo_op")
-                    dur = ev.get("dur", 0)
-                    if ev.get("ph") != "X" or not hlo or dur <= 0:
+        pbs = glob.glob(tmp + "/**/*.xplane.pb", recursive=True)
+        if not pbs:
+            raise RuntimeError("jax.profiler produced no xplane capture")
+        pd = jax.profiler.ProfileData.from_file(pbs[0])
+        for plane in pd.planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    hlo = dict(ev.stats).get("hlo_op")
+                    if not hlo or ev.duration_ns <= 0:
                         continue
-                    per_op.setdefault(str(hlo), []).append(dur / 1e6)
+                    per_op.setdefault(str(hlo), []).append(
+                        ev.duration_ns / 1e9)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 

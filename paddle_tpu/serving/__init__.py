@@ -39,7 +39,6 @@ See docs/serving.md for the architecture and runbook.
 from .batching import (InferenceServer, RequestDeadlineExceeded,
                        ServerSaturated)
 from .generation import (GenerationServer, GenerationStream,
-                         build_warm_start_artifact,
                          load_generation_model, save_generation_model,
                          server_from_model_dir)
 from .kv_cache import KVPoolExhausted, PagedKVCache
@@ -57,7 +56,6 @@ __all__ = [
     "save_generation_model",
     "load_generation_model",
     "server_from_model_dir",
-    "build_warm_start_artifact",
     "ReplicaServer",
     "ReplicaError",
     "ReplicaShed",

@@ -60,10 +60,10 @@ from __future__ import annotations
 
 import itertools
 import json
+import logging
 import os
 import threading
 import time
-import warnings
 from collections import deque
 from typing import Dict, List, Optional, Sequence
 
@@ -77,22 +77,14 @@ from ..observability import tracing as obs_tracing
 from .batching import RequestDeadlineExceeded, ServerSaturated
 from .kv_cache import KVPoolExhausted, PagedKVCache
 
+_LOG = logging.getLogger(__name__)
+
 __all__ = ["GenerationServer", "GenerationStream",
-           "save_generation_model", "load_generation_model",
-           "build_warm_start_artifact"]
+           "save_generation_model", "load_generation_model"]
 
 MODEL_SPEC_FILENAME = "generation.json"
 MODEL_PARAMS_FILENAME = "generation_params.npz"
 MODEL_DRAFT_PARAMS_FILENAME = "generation_draft_params.npz"
-# the warm-start artifact: a persistent XLA compilation cache shipped
-# NEXT TO the model (save_generation_model(warm_start=True) /
-# build_warm_start_artifact).  A scale-out replica started from the dir
-# points PADDLE_TPU_COMPILATION_CACHE_DIR at it and DESERIALIZES the
-# serving executables instead of compiling them, so its time-to-first-
-# token is bounded by model load, not XLA compile (docs/serving.md
-# "Autoscaling").
-WARM_START_DIRNAME = "xla_cache"
-
 _SERVER_IDS = itertools.count()
 # stats()-backing series are always=True (the stats contract predates
 # the PADDLE_TPU_METRICS switch); latency/depth series are gated.
@@ -371,6 +363,17 @@ class GenerationServer:
         self._idle_poll_s = float(idle_poll_s)
         place = place or TPUPlace()
         self._device = place.jax_device()
+        for dec in (decoder, draft_decoder):
+            # selection read the platform the decoder was BUILT for;
+            # placement reads the place — a Mosaic kernel over CPU
+            # arrays (or donation on a backend without it) must fail
+            # here, by name, not inside the first compile
+            if dec is not None and dec.platform != self._device.platform:
+                raise ValueError(
+                    f"decoder was built for platform {dec.platform!r} "
+                    f"but {place!r} runs on {self._device.platform!r}"
+                    " — pass platform= to build_lm_paged_decoder (or "
+                    "use server_from_model_dir, which threads it)")
         self._states = {n: jax.device_put(np.asarray(states[n]),
                                           self._device)
                         for n in decoder.state_names}
@@ -417,9 +420,6 @@ class GenerationServer:
         self._draining = False
         self._pending_states = None
         self._swap_done = threading.Event()
-        # which warm-start artifact (if any) fed this server's warmup;
-        # server_from_model_dir sets it for ping/stats introspection
-        self.warm_start_dir: Optional[str] = None
 
         self._m_requests = _M_REQUESTS.labels(server=sid)
         self._m_tokens = _M_TOKENS.labels(server=sid)
@@ -434,16 +434,19 @@ class GenerationServer:
         self._m_proposed = _M_DRAFT_PROPOSED.labels(server=sid)
         self._m_accepted = _M_DRAFT_ACCEPTED.labels(server=sid)
 
+        from ..core.compile_cache import compile_cache_dir
         from ..core.executor import xla_compile_counts
 
+        compile_cache_dir()
         c0 = xla_compile_counts()
         t0 = time.perf_counter()
         self._warmup()
         c1 = xla_compile_counts()
         # warm-start accounting (process-wide counters, diffed around
         # THIS warmup): cache_misses == 0 with hits > 0 means every
-        # serving executable deserialized from a warm-start artifact —
-        # the cold-start contract ROADMAP 4's autoscaler relies on
+        # serving executable worth persisting deserialized from the
+        # host's compile cache (core/compile_cache.py) — the cold-start
+        # contract the autoscaler's scale-out relies on
         self.warmup_stats = {
             "warmup_s": round(time.perf_counter() - t0, 4),
             "compiles": int(c1["compiles"] - c0["compiles"]),
@@ -646,6 +649,13 @@ class GenerationServer:
             self._draining = False
             self._lock.notify_all()
 
+    @property
+    def warm_start(self) -> bool:
+        """Whether warmup deserialized its executables from the compile
+        cache and wrote none — observed, not configured."""
+        ws = self.warmup_stats
+        return ws["cache_hits"] > 0 and ws["cache_misses"] == 0
+
     def stats(self) -> Dict[str, float]:
         """Serving telemetry view (docs/serving.md): request/token/tick
         counters, shed accounting, live occupancy, KV-pool state,
@@ -685,7 +695,7 @@ class GenerationServer:
                "spec_k": self._spec_k if self._draft is not None else 0,
                "draining": draining,
                "recompiles_after_warmup": recompiles,
-               "warm_start": bool(self.warm_start_dir)}
+               "warm_start": self.warm_start}
         out.update(self.warmup_stats)
         out.update(self._cache.prefix_stats())
         return out
@@ -1144,9 +1154,7 @@ class GenerationServer:
 
 def save_generation_model(dirname: str, states: Dict[str, np.ndarray],
                           spec: Dict,
-                          draft_states: Optional[Dict] = None,
-                          warm_start: bool = False,
-                          place=None) -> str:
+                          draft_states: Optional[Dict] = None) -> str:
     """Persist a generation model: `generation.json` (architecture
     spec: vocab_size/d_model/n_heads/n_layers/d_inner, plus optional
     serving defaults block_size/max_blocks_per_seq/slots/kv_blocks/
@@ -1156,14 +1164,7 @@ def save_generation_model(dirname: str, states: Dict[str, np.ndarray],
     name its architecture ({d_model, n_heads, n_layers[, d_inner]};
     vocab and block geometry are shared with the target).  The
     directory is what `cli serve` and the replica hot-swap verb
-    consume.
-
-    `warm_start=True` additionally ships the cold-start artifact: the
-    serving executables are compiled once, at save time, into a
-    persistent XLA compilation cache at ``<dirname>/xla_cache``
-    (build_warm_start_artifact).  A replica later started from the dir
-    deserializes them — its time-to-first-token is bounded by model
-    load, not XLA compile."""
+    consume."""
     os.makedirs(dirname, exist_ok=True)
     for key in ("vocab_size", "d_model", "n_heads", "n_layers"):
         if key not in spec:
@@ -1183,11 +1184,6 @@ def save_generation_model(dirname: str, states: Dict[str, np.ndarray],
         json.dump(spec, f, indent=1, sort_keys=True)
     np.savez(os.path.join(dirname, MODEL_PARAMS_FILENAME),
              **{n: np.asarray(v) for n, v in states.items()})
-    if warm_start:
-        # the ROADMAP-4 cold-start enabler: compile the serving
-        # executables ONCE at save time into <dirname>/xla_cache so
-        # every scale-out replica deserializes instead of compiling
-        build_warm_start_artifact(dirname, place=place)
     return dirname
 
 
@@ -1208,22 +1204,6 @@ def load_generation_model(dirname: str, with_draft: bool = False):
     return states, spec, draft_states
 
 
-def build_warm_start_artifact(dirname: str, place=None) -> str:
-    """Grow a saved generation model dir's warm-start artifact: build
-    its serving decoder(s) and run the server warmup with the
-    persistent XLA compilation cache pointed at
-    ``<dirname>/xla_cache``, so the compiled executables serialize
-    next to the parameters they serve.  The executables are keyed by
-    shape, so the artifact covers the SPEC's serving geometry
-    (slots/kv_blocks/block_size/...); a replica started with overrides
-    compiles those shapes fresh.  Returns the artifact path."""
-    cache = os.path.join(dirname, WARM_START_DIRNAME)
-    srv = server_from_model_dir(dirname, place=place,
-                                warm_cache_dir=cache)
-    srv.close()
-    return cache
-
-
 def server_from_model_dir(dirname: str, *, block_size: Optional[int] = None,
                           max_blocks_per_seq: Optional[int] = None,
                           slots: Optional[int] = None,
@@ -1231,8 +1211,7 @@ def server_from_model_dir(dirname: str, *, block_size: Optional[int] = None,
                           kv_dtype: Optional[str] = None,
                           spec_k: Optional[int] = None,
                           use_draft: bool = True,
-                          warm_start: bool = True,
-                          warm_cache_dir: Optional[str] = None,
+                          place=None,
                           **kw) -> GenerationServer:
     """Build a GenerationServer from a saved model dir.
 
@@ -1241,32 +1220,19 @@ def server_from_model_dir(dirname: str, *, block_size: Optional[int] = None,
     fresh serving processes (cli serve, replicas), not mid-session.
     `kv_dtype` overrides the spec's pool precision; a model dir with
     draft params arms speculative decoding unless `use_draft=False`.
+    The decoder is built FOR `place`'s platform (kernel selection and
+    pool donation follow the device the server runs on, not the
+    process default).
 
-    When the dir ships a warm-start artifact (``xla_cache/``, written
-    by ``save_generation_model(warm_start=True)``) and no persistent
-    compilation cache is already configured, the build+warmup runs
-    with PADDLE_TPU_COMPILATION_CACHE_DIR pointed at the artifact and
-    the executables DESERIALIZE instead of compiling
-    (``warmup_stats['cache_misses'] == 0``); the prior flag value is
-    restored afterwards.  ``warm_start=False`` opts out;
-    ``warm_cache_dir`` forces a cache dir (creating it — how
-    build_warm_start_artifact writes the artifact in the first
-    place)."""
-    from ..core import flags as core_flags
+    Executables persist in the host's one compile cache
+    (core/compile_cache.py): the first replica on a host compiles, the
+    next deserializes (``stats()['warm_start']``)."""
     from ..core import framework as fw
+    from ..core.executor import TPUPlace
     from ..models.transformer import build_lm_paged_decoder
 
-    cache = warm_cache_dir or ""
-    if not cache and warm_start:
-        shipped = os.path.join(dirname, WARM_START_DIRNAME)
-        if os.path.isdir(shipped):
-            cache = shipped
-    prev = core_flags.get_flag("compilation_cache_dir")
-    # an EXPLICIT warm_cache_dir always arms (build_warm_start_artifact
-    # must write the artifact even when the operator runs with a global
-    # cache configured); the shipped-artifact auto-arm never stomps a
-    # configured cache
-    armed = bool(cache) and (warm_cache_dir is not None or not prev)
+    place = place or TPUPlace()
+    platform = place.jax_device().platform
     states, spec, draft_states = load_generation_model(
         dirname, with_draft=True)
     bs = int(block_size or spec.get("block_size", 16))
@@ -1274,38 +1240,29 @@ def server_from_model_dir(dirname: str, *, block_size: Optional[int] = None,
              or spec.get("max_blocks_per_seq",
                          -(-int(spec.get("max_len", 256)) // bs)))
     kvd = kv_dtype or spec.get("kv_dtype")
-    try:
-        if armed:
-            core_flags.set_flags({"compilation_cache_dir": cache})
+    fw.reset_unique_names()
+    _, decoder = build_lm_paged_decoder(
+        spec["vocab_size"], bs, nb, d_model=spec["d_model"],
+        n_heads=spec["n_heads"], n_layers=spec["n_layers"],
+        d_inner=spec.get("d_inner"), kv_dtype=kvd, platform=platform)
+    draft_decoder = None
+    if draft_states is not None and use_draft:
+        dspec = spec["draft"]
         fw.reset_unique_names()
-        _, decoder = build_lm_paged_decoder(
-            spec["vocab_size"], bs, nb, d_model=spec["d_model"],
-            n_heads=spec["n_heads"], n_layers=spec["n_layers"],
-            d_inner=spec.get("d_inner"), kv_dtype=kvd)
-        draft_decoder = None
-        if draft_states is not None and use_draft:
-            dspec = spec["draft"]
-            fw.reset_unique_names()
-            _, draft_decoder = build_lm_paged_decoder(
-                spec["vocab_size"], bs, nb, d_model=dspec["d_model"],
-                n_heads=dspec["n_heads"], n_layers=dspec["n_layers"],
-                d_inner=dspec.get("d_inner"), kv_dtype=kvd)
-        else:
-            draft_states = None
-        server = GenerationServer(
-            decoder, states,
-            slots=int(slots or spec.get("slots", 8)),
-            kv_blocks=int(kv_blocks or spec.get("kv_blocks", 64)),
-            draft_decoder=draft_decoder, draft_states=draft_states,
-            spec_k=(spec_k if spec_k is not None
-                    else spec.get("spec_k")), **kw)
-    finally:
-        if armed:
-            # the executables are loaded; later in-process compiles
-            # must follow the caller's own cache configuration
-            core_flags.set_flags({"compilation_cache_dir": prev})
-    if armed:
-        server.warm_start_dir = cache
+        _, draft_decoder = build_lm_paged_decoder(
+            spec["vocab_size"], bs, nb, d_model=dspec["d_model"],
+            n_heads=dspec["n_heads"], n_layers=dspec["n_layers"],
+            d_inner=dspec.get("d_inner"), kv_dtype=kvd,
+            platform=platform)
+    else:
+        draft_states = None
+    server = GenerationServer(
+        decoder, states,
+        slots=int(slots or spec.get("slots", 8)),
+        kv_blocks=int(kv_blocks or spec.get("kv_blocks", 64)),
+        draft_decoder=draft_decoder, draft_states=draft_states,
+        spec_k=(spec_k if spec_k is not None
+                else spec.get("spec_k")), place=place, **kw)
     _publish_static_decode_floor(spec, server)
     return server
 
@@ -1313,32 +1270,32 @@ def server_from_model_dir(dirname: str, *, block_size: Optional[int] = None,
 def _publish_static_decode_floor(spec: dict, server: GenerationServer):
     """Publish the static roofline floor for the decode phase so the
     collector's calibration detector can band measured-vs-static
-    (docs/observability.md "Time attribution").  Best-effort: the cost
-    model not covering a spec must never block serving."""
+    (docs/observability.md "Time attribution").  Skipped, by name, on
+    a device the cost model has no peaks for."""
+    from ..analysis.cost_model import (analyze_generation_spec,
+                                       roofline_seconds,
+                                       running_device_kind,
+                                       serving_kernel_cost)
     try:
-        from ..analysis.cost_model import (analyze_generation_spec,
-                                           roofline_seconds,
-                                           serving_kernel_cost)
-        rows = analyze_generation_spec(
-            spec, slots=server._slots)["kernels"]
-        step = rows[0]
-        # band against the backend the DECODER actually selected (the
-        # registry's spec-level resolution can disagree with a build
-        # that fell back on shape) — the calibration ratio must compare
-        # measured time to the floor of what runs, not of the oracle
-        backend = ("pallas" if getattr(server._decoder, "kernels", {})
-                   .get("paged_attention_decode") == "pallas"
-                   else "xla")
-        if step.get("backend") != backend:
-            step = serving_kernel_cost(
-                "paged_decode_step", spec, slots=server._slots,
-                context=(int(spec.get("block_size", 16))
-                         * int(spec.get("max_blocks_per_seq", 64)))
-                // 2,
-                kv_dtype=str(spec.get("kv_dtype") or "fp32"),
-                backend=backend)
-        obs_attr.publish_static_floor("generation", {
-            "decode": roofline_seconds(step["flops"], step["bytes"]),
-        })
-    except Exception as e:
-        warnings.warn(f"static decode floor unavailable: {e!r}")
+        kind = running_device_kind(server._device)
+    except KeyError as e:
+        _LOG.info("no static decode floor: %s", e.args[0])
+        return
+    step = analyze_generation_spec(
+        spec, slots=server._slots, device=kind)["kernels"][0]
+    # band against the backend the DECODER actually selected (the
+    # registry's spec-level resolution can disagree with a build
+    # that fell back on shape) — the calibration ratio must compare
+    # measured time to the floor of what runs, not of the oracle
+    backend = ("pallas" if server._decoder.kernels.get(
+        "paged_attention_decode") == "pallas" else "xla")
+    if step.get("backend") != backend:
+        step = serving_kernel_cost(
+            "paged_decode_step", spec, slots=server._slots,
+            context=(int(spec.get("block_size", 16))
+                     * int(spec.get("max_blocks_per_seq", 64))) // 2,
+            kv_dtype=str(spec.get("kv_dtype") or "fp32"),
+            backend=backend, device=kind)
+    obs_attr.publish_static_floor("generation", {
+        "decode": roofline_seconds(step["flops"], step["bytes"], kind),
+    })

@@ -178,7 +178,7 @@ class ReplicaServer:
                              or self._server._pending_states
                              is not None),
                 "warm_start": bool(getattr(self._server,
-                                           "warm_start_dir", None))})
+                                           "warm_start", False))})
         elif op == "stats":
             self._reply(f, {"ok": True, "stats": self._server.stats()})
         elif op == "flight":

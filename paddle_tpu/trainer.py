@@ -535,20 +535,23 @@ class Trainer:
     def _publish_static_floor(self):
         """Static roofline floor for the compute phase, for the
         collector's calibration-drift detector (docs/observability.md
-        "Time attribution").  Best-effort and gated: never slows or
-        breaks an uninstrumented run."""
+        "Time attribution").  Gated on the metrics switch, and skipped
+        on a device the cost model has no peaks for (a CPU run has no
+        floor to band against)."""
         if not obs_metrics.enabled():
             return
+        from .analysis.cost_model import (estimate_program,
+                                          roofline_seconds,
+                                          running_device_kind)
         try:
-            from .analysis.cost_model import (estimate_program,
-                                              roofline_seconds)
-            est = estimate_program(self.main_program)
-            obs_attr.publish_static_floor("trainer", {
-                "compute": roofline_seconds(est.total_flops,
-                                            est.total_bytes),
-            })
-        except Exception:
-            pass
+            kind = running_device_kind(self.exe.place.jax_device())
+        except KeyError:
+            return
+        est = estimate_program(self.main_program)
+        obs_attr.publish_static_floor("trainer", {
+            "compute": roofline_seconds(est.total_flops,
+                                        est.total_bytes, kind),
+        })
 
     def test(self, reader: Callable, feeder: Optional[DataFeeder] = None,
              fetch_list: Optional[Sequence] = None):

@@ -221,9 +221,11 @@ def _train_serial(build):
 
 def test_spmd_overlap_matches_serial_and_buckets_structurally():
     """The dp-8 overlapped step: identical training to serial (tolerance
-    = strategy equivalence), and the all-reduce count in the optimized
-    HLO is EXACTLY bucket count + 1 (the loss pmean) — the overlap is
-    asserted from collective structure, not wall clock."""
+    = strategy equivalence), and the step as TRACED issues exactly
+    bucket count + 1 all-reduces (the loss pmean) — the overlap is
+    asserted from collective structure, not wall clock.  The optimized
+    HLO is only bounded: XLA's all-reduce combiner may merge neighbours
+    (it does on the CPU backend), but never adds one."""
     build = lambda: _annotated_mlp(annotate=False)
     serial_params, serial_losses = _train_serial(build)
 
@@ -244,14 +246,17 @@ def test_spmd_overlap_matches_serial_and_buckets_structurally():
     np.testing.assert_allclose(losses, serial_losses, rtol=1e-4,
                                atol=1e-6)
     x, y = _batches()[0]
+    want = pe.overlap_info["buckets"] + 1
+    traced = pe.compiled_collectives({"x": x, "y": y}, optimized=False)
+    assert traced.get("all-reduce", 0) == want, (traced, pe.overlap_info)
     cc = pe.compiled_collectives({"x": x, "y": y})
-    assert cc.get("all-reduce", 0) == pe.overlap_info["buckets"] + 1, \
-        (cc, pe.overlap_info)
+    assert 1 <= cc.get("all-reduce", 0) <= want, (cc, pe.overlap_info)
 
 
 def test_overlap_bucket_cap_shapes_the_allreduce_count():
     """overlap_bucket_bytes=0 puts every gradient in its own bucket —
-    the all-reduce count moves with the knob (6 grads -> 7 ARs)."""
+    the traced all-reduce count moves with the knob (6 grads -> 7 ARs;
+    the combiner bounds what survives optimization)."""
     prev = get_flag("overlap_bucket_bytes")
     set_flags({"overlap_bucket_bytes": 0})
     try:
@@ -264,8 +269,12 @@ def test_overlap_bucket_cap_shapes_the_allreduce_count():
         pe = t.build_executor(["x", "y"], [loss])
         assert pe.overlap_info["buckets"] == pe.overlap_info["grads"]
         x, y = _batches(n=1)[0]
+        want = pe.overlap_info["grads"] + 1
+        traced = pe.compiled_collectives({"x": x, "y": y},
+                                         optimized=False)
+        assert traced.get("all-reduce", 0) == want, traced
         cc = pe.compiled_collectives({"x": x, "y": y})
-        assert cc.get("all-reduce", 0) == pe.overlap_info["grads"] + 1, cc
+        assert 1 <= cc.get("all-reduce", 0) <= want, cc
     finally:
         set_flags({"overlap_bucket_bytes": prev})
 

@@ -210,7 +210,7 @@ rm -f "$FLEET_PROM"
 echo "== [12/14] autoscaling fleet: scale-out / SIGKILL / scale-in drill =="
 # the ROADMAP-4 acceptance (docs/serving.md "Autoscaling"): an
 # open-loop load ramp against a live router+autoscaler fleet triggers
-# scale-out (warm-start replicas deserialize their executables), a
+# scale-out (no replica compiles after its warmup), a
 # SIGKILLed replica mid-ramp is absorbed by the router's resume
 # contract, the ramp-down scales back in via graceful drain — with
 # ZERO failed requests — and the fleet-size / crash-loop / zero-failed

@@ -10,9 +10,13 @@ TRAINING_ROLE / SERVER_ENDPOINT / PADDLE_INIT_TRAINER_ID).
 Two modes:
 
 1. pserver cluster (CPU hosts, DistributeTranspiler pserver mode):
-       python tools/launch.py --pservers 2 --trainers 2 train.py [args...]
+       JAX_PLATFORMS=cpu python tools/launch.py --pservers 2 \
+           --trainers 2 train.py [args...]
    Spawns the script once per role-instance with the reference's env-var
-   convention; pserver endpoints are auto-assigned on localhost.  For a
+   convention; pserver endpoints are auto-assigned on localhost.  Every
+   role-instance is a JAX process and a chip belongs to ONE process, so
+   the launcher refuses to start more than one on a host unless
+   JAX_PLATFORMS pins them all to the CPU (it assigns no devices).  For a
    multi-host cluster, pass --endpoints with ALL pserver endpoints and run
    one launcher per host spawning only that host's share, using
    --pserver-offset to pick which endpoints this host serves:
@@ -47,6 +51,19 @@ import sys
 __all__ = ["launch_pserver_cluster", "launch_registry_cluster"]
 
 
+def _require_cpu_for_many(n_procs: int) -> None:
+    """N > 1 JAX processes on one host would all open every chip (the
+    first wins, the rest fail or hang at start-up): say so and stop
+    unless the environment keeps them off the accelerator."""
+    if n_procs > 1 and os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        raise SystemExit(
+            f"tools/launch.py: refusing to start {n_procs} JAX "
+            "processes on one host — a chip belongs to one process and "
+            "this launcher assigns no devices.  Set JAX_PLATFORMS=cpu "
+            "(pserver mode is a CPU-host mode) or run one process per "
+            "host (--coordinator)")
+
+
 def _free_port() -> int:
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -66,6 +83,7 @@ def launch_registry_cluster(script, script_args, n_pservers, n_trainers,
 
     Returns (registry, [(role, proc)...]); stop the registry after the
     trainers exit."""
+    _require_cpu_for_many(n_pservers + n_trainers)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from paddle_tpu.cloud.registry import Registry
@@ -104,6 +122,7 @@ def launch_pserver_cluster(script, script_args, n_pservers, n_trainers,
     TRAINER Popen calls only (e.g. stdout=PIPE to harvest results);
     pservers deliberately inherit stdio — nobody drains their pipes, and
     a full unread pipe would block the server."""
+    _require_cpu_for_many(n_pservers + n_trainers)
     eps = (endpoints.split(",") if endpoints else
            [f"127.0.0.1:{_free_port()}" for _ in range(n_pservers)])
     if pserver_offset + n_pservers > len(eps):
