@@ -1,0 +1,12 @@
+"""Peak of the fullest chip in 1e9 bytes (`peak_bytes_in_use` plus
+`peak_bytes_reserved`), in the cells fed whole arrays (`Executor`, `ParallelExecutor`)."""
+LAYER = "device"
+UNIT = "GB"
+MOVES = "train_throughput"
+SOURCE = "program_counter"
+
+
+def compute(run):
+    import common
+
+    return common.hbm_peak_gb(run)
