@@ -484,6 +484,8 @@ class Executor:
                                            fetch_names)
             block = program.global_block()
         t0 = time.perf_counter()
+        # children in compiled mode: executor.feed, executor.dispatch
+        # (_run_compiled) and, below, executor.fetch
         with obs_tracing.span("executor.run", mode=mode):
             if mode == "segmented":
                 outs = self._run_segmented(
@@ -503,11 +505,14 @@ class Executor:
                 outs = self._run_interpreted(
                     program, block, scope, feed, fetch_names, step_key
                 )
-        if obs_metrics.enabled():
-            _M_RUN_SECONDS.labels(exe=self._exe_id, mode=mode).observe(
-                time.perf_counter() - t0)
-        if return_numpy:
-            outs = [_to_numpy(v) for v in outs]
+            if obs_metrics.enabled():
+                _M_RUN_SECONDS.labels(
+                    exe=self._exe_id, mode=mode).observe(
+                        time.perf_counter() - t0)
+            if return_numpy:
+                # the wait for the device: the step's results are read
+                with obs_tracing.span("executor.fetch"):
+                    outs = [_to_numpy(v) for v in outs]
         return outs
 
     def close(self):
@@ -793,84 +798,92 @@ class Executor:
 
     def _run_compiled(self, program, block, scope, feed, fetch_names, key):
         device = self.place.jax_device()
-        feed_vals, fresh = {}, set()
-        for n, v in feed.items():
-            feed_vals[n], is_fresh = _place_feed(v, device)
-            if is_fresh:
-                fresh.add(n)
-        state_in_names, state_out_names = self._analyze_states(
-            program, block, feed_vals.keys()
-        )
-        ro_names = [n for n in state_in_names if n not in state_out_names]
-        rw_names = [n for n in state_in_names if n in state_out_names]
-
-        # liveness donation plan (memory_optimization_transpiler): which
-        # buffers die inside this step.  Read-write states are always
-        # donated (the in-place param update); feed buffers are donated
-        # under the memory_optimize flag or an explicit per-var `donate`
-        # hint — but only when the executor itself created the device
-        # buffer (`fresh`), so a caller-held array is never invalidated.
-        # Unsafe explicit hints raise DonationError here, at build time.
-        plan = self._donation_plan(program, feed_vals.keys(), fetch_names,
-                                   rw_names)
-        donate_all_feeds = get_flag("memory_optimize")
-        hinted = {n for n in plan.feeds
-                  if n in block.vars
-                  and getattr(block.vars[n], "donate", False)}
-        don_names = tuple(sorted(
-            n for n in (plan.feeds if donate_all_feeds else hinted)
-            if n in fresh))
-
-        def get_state(n):
-            if not scope.has_var(n) or scope.find_var(n) is None:
-                raise _MissingState(n)
-            return scope.find_var(n)
-
-        repl = _dp_replicated_sharding(block.ops)
-        target = repl if repl is not None else device
-        ro = {n: _commit(get_state(n), target) for n in ro_names}
-        rw = {n: _commit(get_state(n), target) for n in rw_names}
-
-        cache_key = (
-            self._fingerprint(program),
-            block.idx,
-            tuple(sorted((n, _aval_key(v)) for n, v in feed_vals.items())),
-            tuple((n, _aval_key(v)) for n, v in ro.items()),
-            tuple((n, _aval_key(v)) for n, v in rw.items()),
-            tuple(fetch_names),
-            str(device),
-            don_names,  # donation is baked into the executable
-            get_flag("amp_bf16"),  # amp changes traced compute dtypes
-            get_flag("conv_layout"),  # changes the traced conv layout
-            get_flag("flash_min_seq_k"),  # changes the traced attn path
-            get_flag("flash_pack_heads"),  # changes the traced kernel
-            get_flag("flash_block_q"), get_flag("flash_block_k"),
-        )
-        fn = self._cache.get(cache_key)
-        miss = fn is None
-        self._note_lookup(not miss, cache_key[0], cache_key)
-        if miss:
-            fn = self._build_compiled_fn(
-                block, fetch_names, state_out_names, repl
+        # executor.feed: feeds placed on the device, states committed
+        with obs_tracing.span("executor.feed"):
+            feed_vals, fresh = {}, set()
+            for n, v in feed.items():
+                feed_vals[n], is_fresh = _place_feed(v, device)
+                if is_fresh:
+                    fresh.add(n)
+            state_in_names, state_out_names = self._analyze_states(
+                program, block, feed_vals.keys()
             )
-            self._cache[cache_key] = fn
-        don_feeds = {n: feed_vals[n] for n in don_names}
-        keep_feeds = {n: v for n, v in feed_vals.items()
-                      if n not in don_feeds}
-        from paddle_tpu import profiler
+            ro_names = [n for n in state_in_names if n not in state_out_names]
+            rw_names = [n for n in state_in_names if n in state_out_names]
 
-        t0 = time.perf_counter() if miss else None
-        if profiler.is_enabled():
-            with profiler.record_event("xla_block"):
+            # liveness donation plan (memory_optimization_transpiler): which
+            # buffers die inside this step.  Read-write states are always
+            # donated (the in-place param update); feed buffers are donated
+            # under the memory_optimize flag or an explicit per-var `donate`
+            # hint — but only when the executor itself created the device
+            # buffer (`fresh`), so a caller-held array is never invalidated.
+            # Unsafe explicit hints raise DonationError here, at build time.
+            plan = self._donation_plan(program, feed_vals.keys(), fetch_names,
+                                       rw_names)
+            donate_all_feeds = get_flag("memory_optimize")
+            hinted = {n for n in plan.feeds
+                      if n in block.vars
+                      and getattr(block.vars[n], "donate", False)}
+            don_names = tuple(sorted(
+                n for n in (plan.feeds if donate_all_feeds else hinted)
+                if n in fresh))
+
+            def get_state(n):
+                if not scope.has_var(n) or scope.find_var(n) is None:
+                    raise _MissingState(n)
+                return scope.find_var(n)
+
+            repl = _dp_replicated_sharding(block.ops)
+            target = repl if repl is not None else device
+            ro = {n: _commit(get_state(n), target) for n in ro_names}
+            rw = {n: _commit(get_state(n), target) for n in rw_names}
+        # executor.dispatch: cache lookup and the jitted call
+        with obs_tracing.span("executor.dispatch"):
+            cache_key = (
+                self._fingerprint(program),
+                block.idx,
+                tuple(sorted((n, _aval_key(v)) for n, v in feed_vals.items())),
+                tuple((n, _aval_key(v)) for n, v in ro.items()),
+                tuple((n, _aval_key(v)) for n, v in rw.items()),
+                tuple(fetch_names),
+                str(device),
+                don_names,  # donation is baked into the executable
+                get_flag("amp_bf16"),  # amp changes traced compute dtypes
+                get_flag("conv_layout"),  # changes the traced conv layout
+                get_flag("flash_min_seq_k"),  # changes the traced attn path
+                get_flag("flash_pack_heads"),  # changes the traced kernel
+                get_flag("flash_block_q"), get_flag("flash_block_k"),
+            )
+            fn = self._cache.get(cache_key)
+            miss = fn is None
+            self._note_lookup(not miss, cache_key[0], cache_key)
+            if miss:
+                fn = self._build_compiled_fn(
+                    block, fetch_names, state_out_names, repl
+                )
+                self._cache[cache_key] = fn
+            don_feeds = {n: feed_vals[n] for n in don_names}
+            keep_feeds = {n: v for n, v in feed_vals.items()
+                          if n not in don_feeds}
+            from paddle_tpu import profiler
+
+            if miss:
+                # device time by scope: hlo_scopes() can read this
+                # executable's compiled text later (shapes, no buffers)
+                profiler.register_jitted("executor.block", fn, don_feeds,
+                                         keep_feeds, ro, rw, key)
+            t0 = time.perf_counter() if miss else None
+            if profiler.is_enabled():
+                with profiler.record_event("xla_block"):
+                    fetches, state_out = fn(don_feeds, keep_feeds, ro, rw, key)
+                    jax.block_until_ready((fetches, state_out))
+            else:
                 fetches, state_out = fn(don_feeds, keep_feeds, ro, rw, key)
-                jax.block_until_ready((fetches, state_out))
-        else:
-            fetches, state_out = fn(don_feeds, keep_feeds, ro, rw, key)
-        if miss:
-            self._m_compile_s.inc(time.perf_counter() - t0)
-            self._m_entries.set(len(self._cache))
-        for n, v in state_out.items():
-            scope.set_var(n, v)
+            if miss:
+                self._m_compile_s.inc(time.perf_counter() - t0)
+                self._m_entries.set(len(self._cache))
+            for n, v in state_out.items():
+                scope.set_var(n, v)
         return [fetches[n] for n in fetch_names]
 
     def _build_compiled_fn(self, block, fetch_names, state_out_names,

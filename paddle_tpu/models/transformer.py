@@ -653,18 +653,40 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         return (q[l][tables].astype(jnp.float32)
                 * sc_[l][tables][:, :, None, None])
 
+    # Names on the device's time (metadata only, the math is untouched):
+    # everything a step traces lies under `paged_decoder/<part>`, so a
+    # device trace joined through profiler.hlo_scopes() says what share
+    # of a tick is the table gather, the attention over it, the weight
+    # matmuls...  `kv_gather` is the gather through the block table
+    # with its dequantisation or cast and the reshape; `attention` is
+    # scores, mask, softmax and the weighted sum (the Pallas `_attend`
+    # call lies there when selection takes it).
+    scope = jax.named_scope
+
     def _sample(logits, seeds, positions, temps):
         """Greedy/sampled next token per row; stateless per-sequence
         sampling: the key depends only on (seed, position), never on
         the slot or tick number."""
-        greedy = jnp.argmax(logits, axis=-1)
-        subs = jax.vmap(
-            lambda sd, p: jax.random.fold_in(jax.random.key(sd), p))(
-                seeds, positions)
-        safe_t = jnp.where(temps > 0, temps, 1.0)[:, None]
-        sampled = jax.vmap(jax.random.categorical)(subs,
-                                                   logits / safe_t)
-        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        with scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+            subs = jax.vmap(
+                lambda sd, p: jax.random.fold_in(jax.random.key(sd), p))(
+                    seeds, positions)
+            safe_t = jnp.where(temps > 0, temps, 1.0)[:, None]
+            sampled = jax.vmap(jax.random.categorical)(subs,
+                                                       logits / safe_t)
+            return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+    def _ln(g, x, i):
+        sc_, b_ = g[lns[i][0]], g[lns[i][1]]
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return (x - mu) / jnp.sqrt(var + 1e-5) * sc_ + b_
+
+    def _gather_heads(pool, l, tables, s_n):
+        with scope("kv_gather"):
+            return _gather(pool, l, tables).reshape(
+                s_n, nb * bs, n_heads, d_head)
 
     def _step_logits(g, pool_k, pool_v, tables, positions, tokens,
                      active):
@@ -674,78 +696,91 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         def W(i):
             return g[weights[i]], g[biases[i]]
 
-        def ln(x, i):
-            sc_, b_ = g[lns[i][0]], g[lns[i][1]]
-            mu = x.mean(-1, keepdims=True)
-            var = ((x - mu) ** 2).mean(-1, keepdims=True)
-            return (x - mu) / jnp.sqrt(var + 1e-5) * sc_ + b_
-
-        x = g[tok_emb][tokens] + g[pos_tab][positions]       # [S, D]
-        # this tick's K/V land at the cursor's (block, offset); inactive
-        # slots are routed to block 0 offset 0 — the pool's reserved
-        # null/scratch block, never owned by a sequence
-        wb = jnp.where(active, tables[lane, positions // bs], 0)
-        wi = jnp.where(active, positions % bs, 0)
-        # mask over the table's logical span: position j participates
-        # iff j <= cursor, which also hides unallocated tail entries
-        pos_mask = jnp.arange(nb * bs)[None, :] <= positions[:, None]
+        with scope("embed"):
+            x = g[tok_emb][tokens] + g[pos_tab][positions]   # [S, D]
+        with scope("kv_write"):
+            # this tick's K/V land at the cursor's (block, offset);
+            # inactive slots are routed to block 0 offset 0 — the
+            # pool's reserved null/scratch block, never owned by a
+            # sequence
+            wb = jnp.where(active, tables[lane, positions // bs], 0)
+            wi = jnp.where(active, positions % bs, 0)
+        with scope("attention"):
+            # mask over the table's logical span: position j
+            # participates iff j <= cursor, which also hides
+            # unallocated tail entries
+            pos_mask = jnp.arange(nb * bs)[None, :] <= positions[:, None]
         for l in range(n_layers):
-            h = ln(x, 2 * l)
             wq, bq = W(6 * l + 0)
             wk, bk = W(6 * l + 1)
             wv, bv = W(6 * l + 2)
             wo, bo = W(6 * l + 3)
-            q = h @ wq + bq
-            kk = h @ wk + bk
-            vv = h @ wv + bv
-            pool_k = _write(pool_k, l, wb, wi, kk)
-            pool_v = _write(pool_v, l, wb, wi, vv)
+            with scope("qkv"):
+                h = _ln(g, x, 2 * l)
+                q = h @ wq + bq
+                kk = h @ wk + bk
+                vv = h @ wv + bv
+            with scope("kv_write"):
+                pool_k = _write(pool_k, l, wb, wi, kk)
+                pool_v = _write(pool_v, l, wb, wi, vv)
             if _attend is not None:
                 # Pallas path: block-table reads + dequant + attention
                 # in one kernel; bit-identical to the gather branch
                 # (tests/test_serving_kernels.py)
-                ctx_av = _attend(q[:, None, :], pool_k, pool_v, tables,
-                                 positions, l)[:, 0]
+                with scope("attention"):
+                    ctx_av = _attend(q[:, None, :], pool_k, pool_v,
+                                     tables, positions, l)[:, 0]
             else:
                 # gather-based attention over the block table:
                 # [S, NB, BS, D] in table order IS logical order, so
                 # after the reshape the math is the dense cache's math
                 # on the same values
-                kh = _gather(pool_k, l, tables).reshape(
-                    s_n, nb * bs, n_heads, d_head)
-                vh = _gather(pool_v, l, tables).reshape(
-                    s_n, nb * bs, n_heads, d_head)
-                qh = q.reshape(s_n, n_heads, d_head)
-                sc = jnp.einsum("bhd,bshd->bhs", qh, kh) * scale
-                sc = jnp.where(pos_mask[:, None, :], sc, -jnp.inf)
-                w_att = jax.nn.softmax(sc, axis=-1)
-                ctxh = jnp.einsum("bhs,bshd->bhd", w_att, vh)
-                ctx_av = ctxh.reshape(s_n, d_model)
-            x = x + (ctx_av @ wo + bo)
-            h2 = ln(x, 2 * l + 1)
-            w1, b1 = W(6 * l + 4)
-            w2, b2 = W(6 * l + 5)
-            x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
-        xf = ln(x, 2 * n_layers)
-        wf, bf = W(6 * n_layers)
-        return xf @ wf + bf, pool_k, pool_v                  # [S, V]
+                kh = _gather_heads(pool_k, l, tables, s_n)
+                vh = _gather_heads(pool_v, l, tables, s_n)
+                with scope("attention"):
+                    qh = q.reshape(s_n, n_heads, d_head)
+                    sc = jnp.einsum("bhd,bshd->bhs", qh, kh) * scale
+                    sc = jnp.where(pos_mask[:, None, :], sc, -jnp.inf)
+                    w_att = jax.nn.softmax(sc, axis=-1)
+                    ctxh = jnp.einsum("bhs,bshd->bhd", w_att, vh)
+                    ctx_av = ctxh.reshape(s_n, d_model)
+            with scope("attn_out"):
+                x = x + (ctx_av @ wo + bo)
+            with scope("mlp"):
+                h2 = _ln(g, x, 2 * l + 1)
+                w1, b1 = W(6 * l + 4)
+                w2, b2 = W(6 * l + 5)
+                x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
+        with scope("head"):
+            xf = _ln(g, x, 2 * n_layers)
+            wf, bf = W(6 * n_layers)
+            return xf @ wf + bf, pool_k, pool_v              # [S, V]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
              active):
-        logits, pool_k, pool_v = _step_logits(
-            g, pool_k, pool_v, tables, positions, tokens, active)
-        return _sample(logits, seeds, positions, temps), pool_k, pool_v
+        with scope("paged_decoder"):
+            logits, pool_k, pool_v = _step_logits(
+                g, pool_k, pool_v, tables, positions, tokens, active)
+            return (_sample(logits, seeds, positions, temps), pool_k,
+                    pool_v)
 
     @jax.jit
     def step_logits(g, pool_k, pool_v, tables, positions, tokens, seeds,
                     temps, active):
-        return _step_logits(g, pool_k, pool_v, tables, positions,
-                            tokens, active)[0]
+        with scope("paged_decoder"):
+            return _step_logits(g, pool_k, pool_v, tables, positions,
+                                tokens, active)[0]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
                     temps, n_valid):
+        with scope("paged_decoder"):
+            return _step_window(g, pool_k, pool_v, tables, positions,
+                                tokens, seeds, temps, n_valid)
+
+    def _step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
+                     temps, n_valid):
         # teacher-forced multi-position step: slot s processes window
         # positions positions[s]+j for j < n_valid[s] in one dispatch.
         # Rows past n_valid write to the null block; their predictions
@@ -757,65 +792,69 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         def W(i):
             return g[weights[i]], g[biases[i]]
 
-        def ln(x, i):
-            sc_, b_ = g[lns[i][0]], g[lns[i][1]]
-            mu = x.mean(-1, keepdims=True)
-            var = ((x - mu) ** 2).mean(-1, keepdims=True)
-            return (x - mu) / jnp.sqrt(var + 1e-5) * sc_ + b_
-
-        pos_w = positions[:, None] + offs_w[None, :]          # [S, W]
-        valid = offs_w[None, :] < n_valid[:, None]            # [S, W]
-        pos_c = jnp.clip(pos_w, 0, max_len - 1)
-        x = g[tok_emb][tokens] + g[pos_tab][pos_c]            # [S, W, D]
-        wb = jnp.where(valid,
-                       tables[lane[:, None],
-                              jnp.clip(pos_w // bs, 0, nb - 1)], 0)
-        wi = jnp.where(valid, pos_w % bs, 0)
-        # causal within the window AND over the committed span: window
-        # row j attends to absolute positions <= positions[s]+j (row 0
-        # reproduces `step`'s mask exactly)
-        pos_mask = (jnp.arange(nb * bs)[None, None, :]
-                    <= pos_w[:, :, None])                     # [S, W, L]
+        with scope("embed"):
+            pos_w = positions[:, None] + offs_w[None, :]      # [S, W]
+            valid = offs_w[None, :] < n_valid[:, None]        # [S, W]
+            pos_c = jnp.clip(pos_w, 0, max_len - 1)
+            x = g[tok_emb][tokens] + g[pos_tab][pos_c]        # [S, W, D]
+        with scope("kv_write"):
+            wb = jnp.where(valid,
+                           tables[lane[:, None],
+                                  jnp.clip(pos_w // bs, 0, nb - 1)], 0)
+            wi = jnp.where(valid, pos_w % bs, 0)
+        with scope("attention"):
+            # causal within the window AND over the committed span:
+            # window row j attends to absolute positions
+            # <= positions[s]+j (row 0 reproduces `step`'s mask exactly)
+            pos_mask = (jnp.arange(nb * bs)[None, None, :]
+                        <= pos_w[:, :, None])                 # [S, W, L]
         for l in range(n_layers):
-            h = ln(x, 2 * l)
             wq, bq = W(6 * l + 0)
             wk, bk = W(6 * l + 1)
             wv, bv = W(6 * l + 2)
             wo, bo = W(6 * l + 3)
-            q = h @ wq + bq
-            kk = h @ wk + bk
-            vv = h @ wv + bv
+            with scope("qkv"):
+                h = _ln(g, x, 2 * l)
+                q = h @ wq + bq
+                kk = h @ wk + bk
+                vv = h @ wv + bv
             # the whole window's K/V is written before the gather, so
             # in-window attention sees the fresh values; int8 blocks
             # re-quantize per position, in order (the running-max
             # discipline needs offsets written low-to-high)
-            for j in range(w_n):
-                pool_k = _write(pool_k, l, wb[:, j], wi[:, j], kk[:, j])
-                pool_v = _write(pool_v, l, wb[:, j], wi[:, j], vv[:, j])
+            with scope("kv_write"):
+                for j in range(w_n):
+                    pool_k = _write(pool_k, l, wb[:, j], wi[:, j],
+                                    kk[:, j])
+                    pool_v = _write(pool_v, l, wb[:, j], wi[:, j],
+                                    vv[:, j])
             if _attend is not None:
                 # speculative verify rides the SAME kernel as decode:
                 # the window dim comes from q's shape at trace time
-                ctx_av = _attend(q, pool_k, pool_v, tables, positions,
-                                 l)
+                with scope("attention"):
+                    ctx_av = _attend(q, pool_k, pool_v, tables,
+                                     positions, l)
             else:
-                kh = _gather(pool_k, l, tables).reshape(
-                    s_n, nb * bs, n_heads, d_head)
-                vh = _gather(pool_v, l, tables).reshape(
-                    s_n, nb * bs, n_heads, d_head)
-                qh = q.reshape(s_n, w_n, n_heads, d_head)
-                sc = jnp.einsum("bqhd,bshd->bqhs", qh, kh) * scale
-                sc = jnp.where(pos_mask[:, :, None, :], sc, -jnp.inf)
-                w_att = jax.nn.softmax(sc, axis=-1)
-                ctxh = jnp.einsum("bqhs,bshd->bqhd", w_att, vh)
-                ctx_av = ctxh.reshape(s_n, w_n, d_model)
-            x = x + (ctx_av @ wo + bo)
-            h2 = ln(x, 2 * l + 1)
-            w1, b1 = W(6 * l + 4)
-            w2, b2 = W(6 * l + 5)
-            x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
-        xf = ln(x, 2 * n_layers)
-        wf, bf = W(6 * n_layers)
-        logits = xf @ wf + bf                                 # [S, W, V]
+                kh = _gather_heads(pool_k, l, tables, s_n)
+                vh = _gather_heads(pool_v, l, tables, s_n)
+                with scope("attention"):
+                    qh = q.reshape(s_n, w_n, n_heads, d_head)
+                    sc = jnp.einsum("bqhd,bshd->bqhs", qh, kh) * scale
+                    sc = jnp.where(pos_mask[:, :, None, :], sc, -jnp.inf)
+                    w_att = jax.nn.softmax(sc, axis=-1)
+                    ctxh = jnp.einsum("bqhs,bshd->bqhd", w_att, vh)
+                    ctx_av = ctxh.reshape(s_n, w_n, d_model)
+            with scope("attn_out"):
+                x = x + (ctx_av @ wo + bo)
+            with scope("mlp"):
+                h2 = _ln(g, x, 2 * l + 1)
+                w1, b1 = W(6 * l + 4)
+                w2, b2 = W(6 * l + 5)
+                x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
+        with scope("head"):
+            xf = _ln(g, x, 2 * n_layers)
+            wf, bf = W(6 * n_layers)
+            logits = xf @ wf + bf                             # [S, W, V]
         seeds_w = jnp.broadcast_to(seeds[:, None], (s_n, w_n))
         temps_w = jnp.broadcast_to(temps[:, None], (s_n, w_n))
         preds = _sample(logits.reshape(s_n * w_n, -1),

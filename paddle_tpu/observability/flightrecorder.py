@@ -6,10 +6,11 @@ tell you what it was DOING in its last 800 ms before the OOM killer got
 it.  The flight recorder is the post-mortem side of the telemetry
 plane: three bounded rings per process —
 
-  * **spans** — finished trace spans, tapped straight off
-    tracing's recorder via a span listener.  Arming the recorder makes
-    span() live even with full tracing off, so the ring always holds
-    the last ~N spans without growing the 100k export buffer;
+  * **spans** — the newest finished trace spans, read off tracing's
+    one span store (`tracing.finished_spans(last=N)`) when a dump is
+    made.  Arming the recorder registers a span listener, which makes
+    span() live even with full tracing off, so the store always holds
+    the last spans;
   * **events** — structured notes (``note("trainer.step", step=i)``,
     faults fired, view changes) appended by the runtimes;
   * **metric snapshots** — a few recent compact registry snapshots,
@@ -73,7 +74,7 @@ class FlightRecorder:
                  max_snapshots: int = 8, capture_spans: bool = True):
         self.dir = dir
         self.flush_s = float(flush_s)
-        self._spans: deque = deque(maxlen=max_spans)
+        self._max_spans = max_spans
         self._events: deque = deque(maxlen=max_events)
         self._snaps: deque = deque(maxlen=max_snapshots)
         self._seq = 0            # bumped per append; flush skips idle
@@ -87,8 +88,7 @@ class FlightRecorder:
 
     # -- ingestion (hot paths) ---------------------------------------------
     def _on_span(self, rec: dict) -> None:
-        self._spans.append(rec)
-        self._seq += 1
+        self._seq += 1      # tracing's store holds the record
 
     def note(self, event: str, /, **data) -> None:
         # positional-only: the data dict may itself carry a "kind" key
@@ -115,7 +115,8 @@ class FlightRecorder:
             "pid": os.getpid(),
             "time": time.time(),
             "reason": reason,
-            "spans": _ring_snapshot(self._spans),
+            "spans": (tracing.finished_spans(last=self._max_spans)
+                      if self._capture_spans else []),
             "events": _ring_snapshot(self._events),
             "metric_snapshots": _ring_snapshot(self._snaps),
         }

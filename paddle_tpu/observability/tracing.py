@@ -21,13 +21,17 @@ OpenTelemetry-shaped substrate for that:
     under the remote caller: one training step yields a single coherent
     trace across trainer, pserver and master.
 
-Finished spans collect in a bounded in-process buffer and export as
-Chrome-trace JSON (``chrome://tracing`` / Perfetto; see
-observability/exporters.py).  Tracing is off (spans cost one boolean
-test) unless ``PADDLE_TPU_TRACE=on`` or ``PADDLE_TPU_TRACE_DIR`` is set
+Finished spans collect in ONE bounded in-process store, a ring of the
+last ``_MAX_SPANS`` full records (oldest dropped and counted), which
+``finished_spans()`` reads and the Chrome-trace export
+(``chrome://tracing`` / Perfetto; see observability/exporters.py)
+writes.  The store holds every span that was LIVE: spans are live while
+tracing is enabled (``PADDLE_TPU_TRACE=on`` or ``PADDLE_TPU_TRACE_DIR``
 — the latter also auto-writes ``trace_<pid>.json`` into the directory
 at process exit, so a multi-process run drops one merge-able trace file
-per process.
+per process) or while any span listener is registered (the flight
+recorder, the tail sampler, a benchmark's tap).  While neither holds, a
+span costs one boolean test and nothing is created or stored.
 """
 from __future__ import annotations
 
@@ -36,6 +40,8 @@ import os
 import random
 import threading
 import time
+from collections import deque
+from itertools import islice
 from typing import Dict, List, NamedTuple, Optional
 
 __all__ = [
@@ -52,6 +58,7 @@ __all__ = [
     "remove_span_listener",
     "trace_dir",
     "finished_spans",
+    "dropped_spans",
     "clear",
     "chrome_trace_events",
     "write_chrome_trace",
@@ -66,18 +73,22 @@ _ENABLED = bool(_TRACE_DIR) or (os.environ.get("PADDLE_TPU_TRACE", "")
                                 .strip().lower() in ("1", "on", "true",
                                                      "yes"))
 
-# bounded buffer: a runaway loop under tracing must degrade (drop +
-# count) instead of eating the host's memory
-_MAX_SPANS = 100_000
-_spans: List[dict] = []
+# the one span store: a ring of the last _MAX_SPANS finished spans as
+# full records.  A runaway loop under tracing degrades (the oldest
+# record goes, `dropped_spans()` counts it) instead of eating the
+# host's memory: a record is about 0.8 kB, so the ring holds at most
+# some 50 MB, and only in a process that keeps spans live.  The busiest
+# benchmark cell (serving: 17 ticks a second, 7 spans a tick, 48 s
+# window) leaves about 5 k records.
+_MAX_SPANS = 65_536
+_spans: deque = deque(maxlen=_MAX_SPANS)
 _dropped = 0
 _lock = threading.Lock()
 _tls = threading.local()
 _rng = random.Random()
-# span listeners (the flight recorder's tap): when any is registered,
-# spans are CREATED and delivered to listeners even with full tracing
-# off — the recorder's always-on ring wants the last seconds of spans
-# without paying for (or growing) the 100k export buffer
+# span listeners (the flight recorder's tap, the tail sampler, a
+# benchmark's tap): while any is registered, spans are CREATED, stored
+# in the ring and delivered to the listeners even with full tracing off
 _listeners: List = []
 
 
@@ -89,7 +100,7 @@ def _after_fork_in_child():
     global _spans, _dropped, _lock, _TAIL
     _rng.seed()  # fresh OS entropy
     _lock = threading.Lock()
-    _spans = []
+    _spans = deque(maxlen=_MAX_SPANS)
     _dropped = 0
     # a forked child shares the parent's tail buffer: re-arm with a
     # fresh one so the child's dump carries only its own spans
@@ -206,8 +217,8 @@ class Span:
 def add_span_listener(fn) -> None:
     """Register `fn(rec_dict)` to receive every finished span.  While
     any listener is registered, span() is live even when full tracing
-    is off — records then flow ONLY to listeners, not the export
-    buffer.  Listeners must be cheap and must not raise."""
+    is off: records go to the span store (`finished_spans()`) and to
+    the listeners.  Listeners must be cheap and must not raise."""
     if fn not in _listeners:
         _listeners.append(fn)
 
@@ -217,8 +228,19 @@ def remove_span_listener(fn) -> None:
         _listeners.remove(fn)
 
 
-def _record(s: Span, duration: float) -> None:
+def _store(rec: dict) -> None:
+    """Append one finished record to the ring and hand it to the
+    listeners.  Only reached while spans are live."""
     global _dropped
+    with _lock:
+        if len(_spans) == _spans.maxlen:
+            _dropped += 1
+        _spans.append(rec)
+    for fn in _listeners:
+        fn(rec)
+
+
+def _record(s: Span, duration: float) -> None:
     rec = {
         "name": s.name,
         "trace_id": s.context.trace_id,
@@ -231,14 +253,7 @@ def _record(s: Span, duration: float) -> None:
         "thread": threading.current_thread().name,
         "attrs": dict(s.attrs),
     }
-    if _ENABLED:
-        with _lock:
-            if len(_spans) >= _MAX_SPANS:
-                _dropped += 1
-            else:
-                _spans.append(rec)
-    for fn in _listeners:
-        fn(rec)
+    _store(rec)
 
 
 class _NoopCtx:
@@ -328,7 +343,6 @@ def record_span(name: str, ts: float, dur: float,
     seconds (time.time()), `dur` seconds; `parent` parents it into an
     existing trace, else it starts its own.  Returns the recorded
     context (None when tracing is off)."""
-    global _dropped
     if not (_ENABLED or _listeners):
         return None
     ctx = SpanContext(
@@ -346,23 +360,27 @@ def record_span(name: str, ts: float, dur: float,
         "thread": threading.current_thread().name,
         "attrs": dict(attrs),
     }
-    if _ENABLED:
-        with _lock:
-            if len(_spans) >= _MAX_SPANS:
-                _dropped += 1
-            else:
-                _spans.append(rec)
-    for fn in _listeners:
-        fn(rec)
+    _store(rec)
     return ctx
 
 
-def finished_spans() -> List[dict]:
+def finished_spans(last: Optional[int] = None) -> List[dict]:
+    """The span store, oldest first: every span that finished while
+    spans were live (tracing enabled, or a listener registered), as
+    full records: name, ts (wall seconds), dur, trace_id, span_id,
+    parent_id, pid, tid, thread, attrs.  At most the last `_MAX_SPANS`;
+    `dropped_spans()` says how many older ones the ring let go.  `last`
+    keeps the newest that many (the flight recorder's dump)."""
     with _lock:
-        return list(_spans)
+        if last is None:
+            return list(_spans)
+        tail = list(islice(reversed(_spans), last))
+    tail.reverse()
+    return tail
 
 
 def dropped_spans() -> int:
+    """Records the ring dropped (the oldest first) since `clear()`."""
     with _lock:
         return _dropped
 

@@ -31,6 +31,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import profiler
 from ..core.executor import CPUPlace, Executor, program_to_fn
 from ..core.framework import Variable, default_startup_program
 from ..core.scope import Scope
@@ -222,6 +223,7 @@ class ParallelExecutor(ShardedCheckpointMixin):
                 self._overlap_cfg = cfg
                 self.overlap_info = {"mode": "bucketed"}
         self._jit_step = self._make_jit_step()
+        self._hlo_registered = None    # the jit step hlo_scopes() knows
         self._trace_flags_state = _trace_flags()
 
     def _make_jit_step(self):
@@ -667,21 +669,33 @@ class ParallelExecutor(ShardedCheckpointMixin):
                        if fetch_list is not None else self.fetch_names)
         assert fetch_names == self.fetch_names, \
             "fetch_list must match construction-time fetch_list"
+        # the same three children as core.executor's `executor.run`
         with obs_tracing.span("executor.run", mode="parallel"):
-            feeds = {
-                n: jax.device_put(
-                    np.asarray(v),
-                    self._feed_shardings.get(n, self._data_sharding))
-                for n, v in feed.items()
-            }
-            key = jax.random.fold_in(jax.random.key(self._seed),
-                                     self._step)
-            self._step += 1
-            fetches, self._states = self._jit_step(feeds, self._states,
-                                                   key)
-            out = [fetches[n] for n in fetch_names]
+            with obs_tracing.span("executor.feed"):
+                feeds = {
+                    n: jax.device_put(
+                        np.asarray(v),
+                        self._feed_shardings.get(n, self._data_sharding))
+                    for n, v in feed.items()
+                }
+            with obs_tracing.span("executor.dispatch"):
+                key = jax.random.fold_in(jax.random.key(self._seed),
+                                         self._step)
+                self._step += 1
+                if self._hlo_registered is not self._jit_step:
+                    # first run of this jit step: hlo_scopes() can
+                    # read its compiled text later (shapes, no buffers)
+                    self._hlo_registered = self._jit_step
+                    profiler.register_jitted(
+                        "parallel_executor.step", self._jit_step, feeds,
+                        self._states, key)
+                fetches, self._states = self._jit_step(
+                    feeds, self._states, key)
+                out = [fetches[n] for n in fetch_names]
             if return_numpy:
-                out = [np.asarray(v) for v in out]
+                # the wait for the device
+                with obs_tracing.span("executor.fetch"):
+                    out = [np.asarray(v) for v in out]
         if obs_metrics.enabled():
             if not hasattr(self, "_m_run"):
                 self._m_run_id = f"pe{next(_PE_IDS)}"

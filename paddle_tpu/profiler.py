@@ -17,6 +17,7 @@ instead — the TPU answer to `cuda_profiler`'s nvprof output.
 from __future__ import annotations
 
 import contextlib
+import logging
 import threading
 import time
 from typing import Dict, List, Optional
@@ -33,6 +34,9 @@ __all__ = [
     "record_event",
     "profiler_summary",
     "profile_compiled_ops",
+    "hlo_scopes",
+    "scope_seconds",
+    "register_jitted",
     "lowered_ir_text",
     "event_totals",
     "host_blocked_fraction",
@@ -101,6 +105,7 @@ def enable_profiler(state: str = "All"):
 def reset_profiler():
     with _events_lock:
         _events.clear()
+        _hlo_text_providers.clear()
 
 
 def disable_profiler(sorted_key: Optional[str] = None, print_table=True):
@@ -208,16 +213,183 @@ def cuda_profiler(output_file=None, output_mode=None, config=None):
 # ---------------------------------------------------------------------------
 
 
-def _scope_map(hlo_text: str) -> Dict[str, str]:
-    """HLO instruction name -> source op_name metadata (carries the
-    per-op jax.named_scope the compiled executor emits)."""
+def _scope_tables(hlo_text: str):
+    """(HLO instruction name -> source op_name metadata, names that
+    inherited theirs).  The metadata carries the per-op
+    jax.named_scope the compiled executor emits.  An instruction the
+    compiler made itself has none (on the TPU: the relayout
+    `reshape(fusion.N)` of a gathered tensor, async copies and slices);
+    it takes the scope of its first operand that has one, its
+    producer's, and is listed in the second value: a choice of producer
+    over consumer, not compiler metadata."""
     import re
 
     out = {}
-    for m in re.finditer(
-            r"%?([\w.\-]+) = [^\n]*metadata={[^}]*op_name=\"([^\"]+)\"",
-            hlo_text):
-        out[m.group(1)] = m.group(2)
+    orphans = []
+    for m in re.finditer(r"%?([\w.\-]+) = ([^\n]*)", hlo_text):
+        name, rest = m.groups()
+        meta = re.search(r"metadata={[^}]*op_name=\"([^\"]+)\"", rest)
+        if meta:
+            out[name] = meta.group(1)
+        else:
+            orphans.append((name, re.findall(r"%([\w.\-]+)", rest)))
+    inherited = set()
+    for _ in range(3):      # a short chain: copy-done(copy-start(fusion))
+        for name, operands in orphans:
+            if name not in out:
+                scope = next((out[o] for o in operands if o in out), None)
+                if scope is not None:
+                    out[name] = scope
+                    inherited.add(name)
+    return out, inherited
+
+
+def _scope_map(hlo_text: str) -> Dict[str, str]:
+    return _scope_tables(hlo_text)[0]
+
+
+# Device time by scope: the step executables this process built, each
+# as a zero-argument provider of its compiled (optimized) HLO text.
+# An owner registers one at its first compile or warm-up; nothing is
+# lowered or read until `hlo_scopes()` asks.  A provider keeps its
+# jitted function and the shapes it first ran with alive (never device
+# buffers), also after the owner's close(), so the registry is bounded
+# to the last few executables.  Tables, once read, replace the text.
+_HLO_PROVIDERS_CAP = 8
+_hlo_text_providers: List[list] = []       # [label, provider, tables]
+_METADATA_KEY_OPTION = "compilation_cache_include_metadata_in_key"
+
+
+def _register_hlo_text(label: str, provider) -> None:
+    with _events_lock:
+        _hlo_text_providers.append([label, provider, None])
+        del _hlo_text_providers[:-_HLO_PROVIDERS_CAP]
+
+
+def register_jitted(label: str, jitted, *args) -> None:
+    """Register the jitted step `jitted`, as called with `args`, under
+    `label` ("executor.block", "parallel_executor.step",
+    "paged_decoder.step"): what `Executor`, `ParallelExecutor` and
+    `GenerationServer` do at their first compile or warm-up.  Several
+    executables may share a label (a startup and a main program).
+    Keeps the function and the arguments' shapes and shardings, no
+    buffer; lowers and compiles only when `hlo_scopes()` asks."""
+    specs = _arg_specs(*args)
+    _register_hlo_text(label, lambda: _compiled_text(
+        lambda: jitted.lower(*specs)))
+
+
+def _arg_specs(*args):
+    """`jax.ShapeDtypeStruct`s (shape, dtype, weak type and, for device
+    arrays, sharding) standing in for `args` when a provider lowers a
+    jitted function again: they keep no buffer alive.  Leaves that are
+    no arrays pass through."""
+    import jax
+    import numpy as np
+
+    def spec(x):
+        if isinstance(x, jax.Array):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=x.sharding,
+                weak_type=getattr(x, "weak_type", False))
+        if isinstance(x, (np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(spec, args)
+
+
+class ScopeNamesUnavailable(RuntimeError):
+    """This JAX cannot key a compile with metadata, so compiled text
+    cannot be trusted to carry this build's scope names."""
+
+
+def _compiled_text(lower) -> str:
+    """Compiled HLO text of `lower() -> jax.stages.Lowered`, carrying
+    THIS build's scope names.  JAX keys its compile caches (the
+    process's and the persistent one) without metadata, so the
+    executable a cache hit hands back, which may well be the one the
+    process runs, names the scopes, files and lines of whichever build
+    compiled it first.  This compile is therefore keyed WITH metadata
+    (the option is set for this thread alone, so a compile on another
+    thread keeps its key) and apart from the running executable (a
+    compiler option at its default value): a full compile in a cache
+    another build filled, a hit in one this build filled.  XLA is
+    deterministic, so the instruction names are those of the
+    executable that runs."""
+    import jax
+    from jax._src import config as jax_config
+
+    keyed = getattr(jax_config, _METADATA_KEY_OPTION, None)
+    if keyed is None:
+        raise ScopeNamesUnavailable(
+            f"jax {jax.__version__} has no {_METADATA_KEY_OPTION}")
+    with keyed(True):
+        return lower().compile(
+            compiler_options={"xla_embed_ir_in_executable": False}
+        ).as_text()
+
+
+def _hlo_tables(label: Optional[str]):
+    """{key: (table, inherited names)} behind `hlo_scopes`."""
+    with _events_lock:
+        providers = list(_hlo_text_providers)
+    out = {}
+    seen: Dict[str, int] = {}
+    for entry in providers:
+        lbl, provider, tables = entry
+        if label is not None and lbl != label:
+            continue
+        seen[lbl] = seen.get(lbl, 0) + 1
+        if tables is None:
+            try:
+                tables = entry[2] = _scope_tables(provider())
+            except ScopeNamesUnavailable:
+                raise       # no table can be had at all: say so loudly
+            except Exception as e:      # the other tables still count
+                logging.getLogger(__name__).warning(
+                    "hlo_scopes: no compiled text for %s: %r", lbl, e)
+                continue
+        out[lbl if seen[lbl] == 1 else f"{lbl}#{seen[lbl]}"] = tables
+    return out
+
+
+def hlo_scopes(label: Optional[str] = None) -> Dict[str, Dict[str, str]]:
+    """{label: {HLO instruction name: named scope}} for the step
+    executables this process built: the join from a device trace's
+    operation names (`fusion.12`, `reshape.3`) back to the
+    `jax.named_scope` they were traced under (`paged_decoder/kv_gather`,
+    `mul:fc_0.tmp_0`).  A second and further executable under one label
+    is keyed `<label>#2`, `<label>#3`...; `label` keeps that label's
+    tables only.  A provider that fails is logged and left out; a JAX
+    that cannot give this build's names at all raises
+    `ScopeNamesUnavailable`."""
+    return {k: t for k, (t, _) in _hlo_tables(label).items()}
+
+
+def scope_seconds(op_seconds: Dict[str, float], label: str,
+                  inherited_only: bool = False) -> Dict[str, float]:
+    """Device seconds by named scope: a device trace's seconds per HLO
+    instruction name (`op_seconds`) joined with `hlo_scopes(label)`.
+    Where several executables share the label, the table that covers
+    the most of these seconds is taken.  Instructions the table does
+    not name (other executables, transfers) go under ""; the values
+    add up to `op_seconds`' total.  With `inherited_only`, only the
+    seconds of instructions that carry no metadata of their own and
+    took their producer's scope (`_scope_tables`): the part of each
+    scope's seconds that is a heuristic, not the compiler's word."""
+    best, inherited = {}, set()
+    covered = -1.0
+    for table, names in _hlo_tables(label).values():
+        c = sum(t for op, t in op_seconds.items() if op in table)
+        if c > covered:
+            best, inherited, covered = table, names, c
+    out: Dict[str, float] = {}
+    for op, t in op_seconds.items():
+        if inherited_only and op not in inherited:
+            continue
+        scope = best.get(op, "")
+        out[scope] = out.get(scope, 0.0) + t
     return out
 
 

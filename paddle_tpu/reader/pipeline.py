@@ -146,7 +146,8 @@ class PrefetchIterator:
     def _work(self, reader):
         try:
             with obs_tracing.activate(self._trace_ctx):
-                for batch in reader():
+                for batch in obs_attr.phased_iter("trainer", "reader",
+                                                  reader()):
                     if self._stop.is_set():
                         return
                     with obs_tracing.span("pipeline.prepare"):

@@ -69,6 +69,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .. import profiler
 from ..core.resilience import (fault_injector,
                                sched_fault_armed as _sched_fault)
 from ..observability import attribution as obs_attr
@@ -250,8 +251,9 @@ class _Seq:
 
     __slots__ = ("stream", "tokens", "prompt_len", "max_new", "eos_id",
                  "temperature", "seed", "cur", "slot", "emitted",
-                 "t_submit", "t_submit_wall", "expires", "trace_ctx",
-                 "draft_next", "prompt_keys")
+                 "t_submit", "t_submit_wall", "t_admit", "t_first",
+                 "cached", "expires", "trace_ctx", "draft_next",
+                 "prompt_keys")
 
     def __init__(self, stream, max_new, eos_id, temperature, seed,
                  expires, trace_ctx):
@@ -267,6 +269,13 @@ class _Seq:
         self.emitted = 0
         self.t_submit = time.perf_counter()
         self.t_submit_wall = time.time()
+        # stamped by the scheduler (perf_counter): admission to a slot
+        # and the first delivered token split the `serving.request`
+        # span into queue_s / prefill_s / decode_s; `cached` is the
+        # prompt positions the prefix cache supplied
+        self.t_admit = None
+        self.t_first = None
+        self.cached = 0
         self.expires = expires
         self.trace_ctx = trace_ctx
         # next position the DRAFT model's KV is missing (speculative
@@ -470,9 +479,14 @@ class GenerationServer:
         zs = z.astype(np.uint32)
         zt = np.zeros(self._slots, np.float32)
         if self._draft is None:
-            nxt, self._pool_k, self._pool_v = self._decoder.step(
-                self._states, self._pool_k, self._pool_v, self._tables,
-                z, z, zs, zt, np.zeros(self._slots, bool))
+            args = (self._states, self._pool_k, self._pool_v,
+                    self._tables, z, z, zs, zt,
+                    np.zeros(self._slots, bool))
+            # device time by scope: hlo_scopes() can read the resident
+            # step's compiled text later (shapes, no buffers)
+            profiler.register_jitted("paged_decoder.step",
+                                     self._decoder.step, *args)
+            nxt, self._pool_k, self._pool_v = self._decoder.step(*args)
             np.asarray(nxt)  # block: compile is done when this returns
             return
         w = self._spec_k + 1
@@ -720,8 +734,10 @@ class GenerationServer:
                          + list(self._queue))
             self._active = [None] * self._slots
             self._queue.clear()
+        now = time.perf_counter()
         for seq in leftovers:
             self._cache.release(seq)
+            self._request_span(seq, now, error="ServerClosed")
             seq.stream._fail(err)
         self._cache.close()
         for fam in (_M_REQUESTS, _M_TOKENS, _M_TICKS, _M_SWAPS,
@@ -789,6 +805,8 @@ class GenerationServer:
             # degenerate of copy-on-write.
             seq.cur = min(cached, seq.prompt_len - 1)
             seq.draft_next = seq.cur
+            seq.cached = seq.cur
+            seq.t_admit = time.perf_counter()
             seq.slot = slot
             self._active[slot] = seq
             self._tables[slot] = table
@@ -818,6 +836,8 @@ class GenerationServer:
             metrics_on = obs_metrics.enabled()
             for seq in shed:
                 self._m_deadline.inc()
+                self._request_span(seq, time.perf_counter(),
+                                   error="RequestDeadlineExceeded")
                 seq.stream._fail(RequestDeadlineExceeded(
                     "request deadline expired while queued for "
                     "admission"))
@@ -848,7 +868,10 @@ class GenerationServer:
                 with self._lock:
                     for seq in seqs:
                         self._evict_locked(seq)
+                now = time.perf_counter()
                 for seq in seqs:
+                    self._request_span(seq, now,
+                                       error=type(e).__name__)
                     seq.stream._fail(e)
                 continue
             if self._draft is None:
@@ -881,7 +904,10 @@ class GenerationServer:
         # surfaces
         phase_name = ("prefill" if all(s.cur < s.prompt_len - 1
                                        for s in seqs) else "decode")
-        with obs_tracing.span("serving.decode_tick", active=len(seqs)):
+        with obs_tracing.span("serving.decode_tick",
+                              active=len(seqs)) as sp:
+            if sp is not None:
+                self._tick_attrs(sp, seqs)
             with obs_attr.phase("generation", phase_name):
                 fault_injector().fire("serving.decode")
                 nxt, self._pool_k, self._pool_v = self._decoder.step(
@@ -892,6 +918,17 @@ class GenerationServer:
                 out = np.asarray(nxt)
         self._m_ticks.inc()
         return out
+
+    def _tick_attrs(self, sp, seqs: List[_Seq]) -> None:
+        """The scheduler's counts for one tick, on its live
+        `serving.decode_tick` span: `prefill` slots teacher-force a
+        prompt position and deliver nothing (cursor below
+        prompt_len - 1), `kv_used` of `kv_total` pool blocks are
+        owned."""
+        sp.set_attr("prefill", sum(1 for s in seqs
+                                   if s.cur < s.prompt_len - 1))
+        sp.set_attr("kv_used", self._cache.used_blocks)
+        sp.set_attr("kv_total", self._cache.num_blocks)
 
     def _deliver(self, seqs: List[_Seq], nxt: np.ndarray,
                  metrics_on: bool):
@@ -907,9 +944,11 @@ class GenerationServer:
                 seq.tokens.append(tok)
                 seq.emitted += 1
                 delivered += 1
-                if metrics_on and seq.emitted == 1:
-                    with obs_tracing.activate(seq.trace_ctx):
-                        self._m_ttft.observe(now - seq.t_submit)
+                if seq.emitted == 1:
+                    seq.t_first = now
+                    if metrics_on:
+                        with obs_tracing.activate(seq.trace_ctx):
+                            self._m_ttft.observe(now - seq.t_submit)
                 seq.stream._put(tok)
                 if (seq.emitted >= seq.max_new
                         or (seq.eos_id is not None
@@ -931,15 +970,31 @@ class GenerationServer:
         router/replica hops join into one trace) and observe latency
         with that trace active — the histogram exemplar then points at
         this request's trace."""
-        dur = now - seq.t_submit
-        ctx = obs_tracing.record_span(
-            "serving.request", seq.t_submit_wall, dur,
-            parent=seq.trace_ctx, server=self._sid,
-            tokens=seq.emitted) or seq.trace_ctx
+        ctx = self._request_span(seq, now) or seq.trace_ctx
         if metrics_on:
             with obs_tracing.activate(ctx):
-                self._m_latency.observe(dur)
+                self._m_latency.observe(now - seq.t_submit)
         seq.stream._finish()
+
+    def _request_span(self, seq: _Seq, now: float, **attrs):
+        """The one `serving.request` span of a request, recorded when
+        it ends (finished, shed or failed: the latter carry `error`).
+        Its duration splits into `queue_s` (submit to admission),
+        `prefill_s` (admission to the first token) and `decode_s`
+        (first to last token); a request that never got that far ends
+        the split where it stopped.  No child spans: a request's
+        lifetime lies on no thread."""
+        if not (obs_tracing.enabled() or obs_tracing._listeners):
+            return None
+        t_admit = seq.t_admit if seq.t_admit is not None else now
+        t_first = seq.t_first if seq.t_first is not None else now
+        return obs_tracing.record_span(
+            "serving.request", seq.t_submit_wall, now - seq.t_submit,
+            parent=seq.trace_ctx, server=self._sid,
+            tokens=seq.emitted, prompt_tokens=seq.prompt_len,
+            cached_tokens=seq.cached, queue_s=t_admit - seq.t_submit,
+            prefill_s=t_first - t_admit, decode_s=now - t_first,
+            **attrs)
 
     # -- speculative path ---------------------------------------------------
     def _tick_spec(self, seqs: List[_Seq]):
@@ -1044,7 +1099,9 @@ class GenerationServer:
             temps[seq.slot] = seq.temperature
             seeds[seq.slot] = seq.seed
         with obs_tracing.span("serving.decode_tick", active=len(seqs),
-                              speculative=True):
+                              speculative=True) as sp:
+            if sp is not None:
+                self._tick_attrs(sp, seqs)
             with obs_attr.phase("generation", "draft_verify"):
                 fault_injector().fire("serving.decode")
                 nxt, self._pool_k, self._pool_v = \
@@ -1100,9 +1157,11 @@ class GenerationServer:
                 # bonus token
                 seq.draft_next = min(seq.draft_next, seq.cur)
                 if emitted:
-                    if metrics_on and seq.emitted == 0:
-                        with obs_tracing.activate(seq.trace_ctx):
-                            self._m_ttft.observe(now - seq.t_submit)
+                    if seq.emitted == 0:
+                        seq.t_first = now
+                        if metrics_on:
+                            with obs_tracing.activate(seq.trace_ctx):
+                                self._m_ttft.observe(now - seq.t_submit)
                     seq.tokens.extend(emitted)
                     seq.emitted += len(emitted)
                     delivered += len(emitted)

@@ -396,7 +396,7 @@ class Trainer:
                                        depth=prefetch)()
 
             def packed():
-                for b in rd():
+                for b in obs_attr.phased_iter("trainer", "reader", rd()):
                     with obs_attr.phase("trainer", "feed_pack"):
                         feed = feeder.feed(b)
                     yield feed
@@ -453,7 +453,10 @@ class Trainer:
                 pass_reader = reader
             feeds = make_feeds(pass_reader)
             try:
-                for batch_id, feed in enumerate(feeds, start=n_skip):
+                # no enumerate(): it would hold the last batch (see below)
+                batch_id = n_skip - 1
+                for feed in feeds:
+                    batch_id += 1
                     if resuming and not trained:
                         event_handler(BeginPass(pass_id))
                     trained = True
@@ -515,6 +518,10 @@ class Trainer:
                             and checkpoint_every_n_iters > 0 \
                             and self.step % checkpoint_every_n_iters == 0:
                         _save(pass_id, batch_id + 1)
+                    # drop the batch with its step: its host arrays are
+                    # then freed under the next trainer.phase.feed_pack,
+                    # not between spans
+                    del feed
             finally:
                 # a prefetching iterator owns a worker thread: an
                 # exception mid-pass must not leak it blocked on the queue

@@ -196,8 +196,9 @@ def test_timeseries_sampler_thread_and_capacity():
 
 
 def test_flightrecorder_ring_only_span_capture():
-    """Armed recorder captures spans with full tracing OFF, without
-    touching the export buffer; uninstall restores the no-op span."""
+    """Armed recorder captures spans with full tracing OFF; a span kept
+    live by a listener is in the one span store too (PR 24); uninstall
+    restores the no-op span."""
     assert not tracing.enabled()
     flightrecorder.install()
     with tracing.span("work.unit", k=1) as s:
@@ -207,7 +208,7 @@ def test_flightrecorder_ring_only_span_capture():
     assert [s["name"] for s in d["spans"]] == ["work.unit"]
     assert d["events"][0]["kind"] == "checkpoint"
     assert d["events"][0]["data"] == {"step": 3}
-    assert tracing.finished_spans() == []
+    assert [s["name"] for s in tracing.finished_spans()] == ["work.unit"]
     flightrecorder.uninstall()
     with tracing.span("gone") as s:
         assert s is None

@@ -1,0 +1,484 @@
+"""PR 24: the one span store, the counts on the scheduler's and the
+executors' spans, the reader phase, the named scopes inside the paged
+decoder's step and `profiler.hlo_scopes()` (docs/observability.md "Span
+vocabulary" and "Device time by scope")."""
+import contextlib
+from collections import deque
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as fluid
+from paddle_tpu import parallel, profiler
+from paddle_tpu import trainer as trainer_mod
+from paddle_tpu.core.executor import global_scope
+from paddle_tpu.core.framework import reset_unique_names
+from paddle_tpu.models.transformer import build_lm_paged_decoder
+from paddle_tpu.observability import (attribution, flightrecorder, metrics,
+                                      tracing)
+from paddle_tpu.serving import GenerationServer
+from paddle_tpu.serving.batching import RequestDeadlineExceeded
+
+
+@pytest.fixture(autouse=True)
+def _fresh():
+    def reset():
+        metrics.set_enabled(False)
+        tracing.set_enabled(False)
+        tracing.disarm_tail_sampler()
+        flightrecorder.uninstall()
+        del tracing._listeners[:]
+        tracing.clear()
+        profiler.reset_profiler()
+
+    reset()
+    yield
+    reset()
+
+
+@contextlib.contextmanager
+def listening():
+    """Spans kept live by a listener alone (full tracing off), as the
+    benchmark's tap and the flight recorder do."""
+    got = []
+    tracing.add_span_listener(got.append)
+    try:
+        yield got
+    finally:
+        tracing.remove_span_listener(got.append)
+
+
+def _named(name):
+    return [s for s in tracing.finished_spans() if s["name"] == name]
+
+
+# ---------------------------------------------------------------------------
+# A. the span store
+# ---------------------------------------------------------------------------
+
+
+def test_store_keeps_full_records_under_a_listener_alone():
+    assert not tracing.enabled()
+    with listening() as got:
+        with tracing.span("outer", k=1) as outer:
+            with tracing.span("inner") as inner:
+                inner.set_attr("n", 3)
+        tracing.record_span("late", 12.5, 0.25, parent=outer.context, x="y")
+    assert [s["name"] for s in tracing.finished_spans()] == [
+        "inner", "outer", "late"]
+    assert tracing.finished_spans() == got     # the same records
+    rec = tracing.finished_spans()[0]
+    assert set(rec) == {"name", "trace_id", "span_id", "parent_id", "ts",
+                        "dur", "pid", "tid", "thread", "attrs"}
+    assert rec["attrs"] == {"n": 3}
+    assert rec["parent_id"] == outer.context.span_id
+    assert rec["trace_id"] == outer.context.trace_id
+    late = tracing.finished_spans()[2]
+    assert (late["ts"], late["dur"], late["attrs"]) == (12.5, 0.25,
+                                                         {"x": "y"})
+
+
+def test_store_holds_nothing_while_spans_are_off():
+    assert tracing.span("x") is tracing._NOOP
+    with tracing.span("x", k=1) as s:
+        assert s is None
+    assert tracing.record_span("y", 0.0, 1.0) is None
+    assert tracing.finished_spans() == []
+    assert tracing.dropped_spans() == 0
+
+
+def test_store_is_a_ring_that_drops_the_oldest_and_counts(monkeypatch):
+    monkeypatch.setattr(tracing, "_spans", deque(maxlen=4))
+    tracing.set_enabled(True)
+    for i in range(7):
+        with tracing.span(f"s{i}"):
+            pass
+    assert [s["name"] for s in tracing.finished_spans()] == [
+        "s3", "s4", "s5", "s6"]
+    assert tracing.dropped_spans() == 3
+    assert [s["name"] for s in tracing.finished_spans(last=2)] == [
+        "s5", "s6"]
+    assert len(tracing.finished_spans(last=9)) == 4
+    tracing.clear()
+    assert tracing.finished_spans() == [] and tracing.dropped_spans() == 0
+    assert tracing._MAX_SPANS >= 4 * 17 * 7 * 63   # 4x the serving cell
+
+
+# ---------------------------------------------------------------------------
+# B. counts on the scheduler's spans
+# ---------------------------------------------------------------------------
+
+
+def _tiny_server(kv_dtype="fp32", **kw):
+    reset_unique_names()
+    startup, dec = build_lm_paged_decoder(
+        50, 4, 8, d_model=32, n_heads=4, n_layers=2, kv_dtype=kv_dtype)
+    fluid.Executor(fluid.CPUPlace()).run(startup)
+    states = {n: np.asarray(global_scope().find_var(n))
+              for n in dec.state_names}
+    return GenerationServer(dec, states, slots=4, kv_blocks=32,
+                            place=fluid.CPUPlace(), **kw), dec
+
+
+def test_decode_tick_and_request_spans_account_for_the_run():
+    srv, _ = _tiny_server()
+    try:
+        with listening():
+            streams = [srv.submit(list(range(1, 3 + i)), 5)
+                       for i in range(6)]
+            outs = [s.result(60) for s in streams]
+            # the request span is recorded just before the stream ends
+            ticks = [s["attrs"] for s in _named("serving.decode_tick")]
+            reqs = _named("serving.request")
+    finally:
+        srv.close()
+    delivered = sum(len(o) for o in outs)
+    assert delivered == 30
+    assert sum(a["active"] - a["prefill"] for a in ticks) == delivered
+    assert all(0 <= a["kv_used"] <= a["kv_total"] == 32 for a in ticks)
+    # every attribute has a reader (docs/observability.md): no more
+    assert all(set(a) == {"active", "prefill", "kv_used", "kv_total"}
+               for a in ticks)
+    assert len(reqs) == 6
+    for r in reqs:
+        a = r["attrs"]
+        assert a["queue_s"] >= 0 and a["prefill_s"] >= 0 \
+            and a["decode_s"] >= 0 and "error" not in a
+        assert a["queue_s"] + a["prefill_s"] + a["decode_s"] == \
+            pytest.approx(r["dur"], abs=1e-9)
+        assert a["tokens"] == 5 and a["cached_tokens"] <= a["prompt_tokens"]
+    assert sorted(r["attrs"]["prompt_tokens"] for r in reqs) == [
+        2, 3, 4, 5, 6, 7]
+    # the two that waited for a slot queued longer than the four that
+    # were admitted at once
+    waits = sorted(r["attrs"]["queue_s"] for r in reqs)
+    assert waits[4] > waits[3]
+
+
+def test_shed_request_span_carries_error():
+    srv, _ = _tiny_server()
+    try:
+        with listening():
+            busy = [srv.submit([1, 2, 3], 24) for _ in range(4)]
+            late = srv.submit([4, 5], 4, deadline_ms=0.01)
+            with pytest.raises(RequestDeadlineExceeded):
+                late.result(60)
+            for s in busy:
+                s.result(60)
+            shed = [r for r in _named("serving.request")
+                    if "error" in r["attrs"]]
+    finally:
+        srv.close()
+    assert len(shed) == 1
+    a = shed[0]["attrs"]
+    assert a["error"] == "RequestDeadlineExceeded" and a["tokens"] == 0
+    assert a["queue_s"] == pytest.approx(shed[0]["dur"], abs=1e-9)
+    assert a["prefill_s"] == 0 and a["decode_s"] == 0
+
+
+# ---------------------------------------------------------------------------
+# B. the executors' three children, the trainer's reader phase
+# ---------------------------------------------------------------------------
+
+
+def _classifier():
+    reset_unique_names()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[16], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="int64")
+        h = fluid.layers.fc(input=x, size=32, act="relu")
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.fc(input=h, size=4), y))
+        fluid.SGD(learning_rate=0.1).minimize(loss)
+    return main, startup, loss
+
+
+def _batch():
+    r = np.random.RandomState(0)
+    return {"x": r.randn(32, 16).astype(np.float32),
+            "y": r.randint(0, 4, (32, 1)).astype(np.int64)}
+
+
+def _run_serial(feed, **kw):
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    tracing.clear()
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope, **kw)
+    exe.close()
+
+
+def _run_parallel(feed, **kw):
+    main, startup, loss = _classifier()
+    pe = parallel.ParallelExecutor(main, ["x", "y"], [loss],
+                                   mesh={"dp": 8},
+                                   startup_program=startup)
+    tracing.clear()
+    pe.run(feed, **kw)
+    pe.close()
+
+
+@pytest.mark.parametrize("run", [_run_serial, _run_parallel])
+def test_executor_run_has_feed_dispatch_fetch_children(run):
+    feed = _batch()
+    with listening():
+        run(feed)
+        (parent,) = _named("executor.run")
+        kids = {n: _named(f"executor.{n}")
+                for n in ("feed", "dispatch", "fetch")}
+        assert all(len(v) == 1 for v in kids.values()), kids
+        for (k,) in kids.values():
+            assert k["parent_id"] == parent["span_id"]
+            assert k["trace_id"] == parent["trace_id"]
+            assert k["ts"] >= parent["ts"]
+        assert sum(k[0]["dur"] for k in kids.values()) <= parent["dur"]
+        # with the results left on the device there is no wait to record
+        run(feed, return_numpy=False)
+        assert len(_named("executor.run")) == 1
+        assert _named("executor.fetch") == []
+        assert len(_named("executor.feed")) == 1
+
+
+@pytest.mark.parametrize("prefetch", [0, 2])
+def test_trainer_reader_phase_fires_once_a_batch(prefetch):
+    r = np.random.RandomState(7)
+    data = [[(r.rand(16).astype(np.float32), r.rand(1).astype(np.float32))
+             for _ in range(8)] for _ in range(5)]
+    reset_unique_names()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[16], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        p = fluid.layers.fc(input=fluid.layers.fc(input=x, size=8,
+                                                  act="relu"), size=1)
+        loss = fluid.layers.mean(
+            fluid.layers.square_error_cost(input=p, label=y))
+        fluid.SGD(learning_rate=0.05).minimize(loss)
+    assert "reader" in attribution.PHASES["trainer"]
+    with fluid.scope_guard(fluid.Scope()), listening():
+        t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
+                                feed_list=[x, y], main_program=main,
+                                startup_program=startup)
+        tracing.clear()
+        t.train(2, lambda: iter(data), prefetch=prefetch)
+        reads = _named("trainer.phase.reader")
+        assert len(reads) == 2 * len(data)
+        assert len(_named("trainer.phase.feed_pack")) == len(reads)
+        assert len(_named("trainer.step")) == len(reads)
+        assert all("error" not in s["attrs"] for s in reads)
+        threads = {s["thread"] for s in reads}
+        assert (threads == {"paddle-tpu-prefetch"}) == bool(prefetch)
+
+
+def test_phased_iter_times_the_pull_and_is_plain_when_off():
+    def slow():
+        import time
+
+        for i in range(3):
+            time.sleep(0.002)
+            yield i
+
+    assert list(attribution.phased_iter("trainer", "reader", slow())) == [
+        0, 1, 2]
+    assert tracing.finished_spans() == []
+    with listening():
+        with tracing.span("epoch") as ep:
+            assert list(attribution.phased_iter(
+                "trainer", "reader", slow())) == [0, 1, 2]
+    reads = _named("trainer.phase.reader")
+    assert len(reads) == 3 and all(s["dur"] >= 0.002 for s in reads)
+    assert all(s["parent_id"] == ep.context.span_id for s in reads)
+
+
+# ---------------------------------------------------------------------------
+# C. names on the device's time
+# ---------------------------------------------------------------------------
+
+_PARTS = ("embed", "qkv", "kv_write", "kv_gather", "attention", "attn_out",
+          "mlp", "head", "sample")
+
+
+def _greedy(dec, states, steps=6):
+    pool_k, pool_v = dec.init_pool(9)
+    tables = np.arange(1, 9, dtype=np.int32).reshape(2, 4).repeat(2, 1)
+    tables = np.ascontiguousarray(tables[:, :8])
+    toks = np.array([3, 7], np.int32)
+    out = []
+    for pos in range(steps):
+        nxt, pool_k, pool_v = dec.step(
+            states, pool_k, pool_v, tables, np.full(2, pos, np.int32), toks,
+            np.zeros(2, np.uint32), np.zeros(2, np.float32),
+            np.ones(2, bool))
+        toks = np.asarray(nxt)
+        out.append(toks.tolist())
+    return out
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_paged_decoder_step_carries_scopes_and_same_tokens(kv_dtype,
+                                                           monkeypatch):
+    srv, dec = _tiny_server(kv_dtype)
+    states = dict(srv._states)
+    srv.close()
+    table = profiler.hlo_scopes("paged_decoder.step")["paged_decoder.step"]
+    scopes = set(table.values())
+    for part in ("kv_gather", "attention"):
+        assert any(f"paged_decoder/{part}" in s for s in scopes), part
+    # the traced module names every part; XLA may fuse a small one away
+    lowered = dec.step.lower(*profiler._arg_specs(
+        states, *dec.init_pool(9), np.zeros((4, 8), np.int32),
+        *[np.zeros(4, t) for t in (np.int32, np.int32, np.uint32,
+                                   np.float32, bool)]))
+    text = profiler.lowered_ir_text(lowered)
+    for part in _PARTS:
+        assert f"paged_decoder/{part}" in text, part
+    # metadata only: the same decoder traced without any scope gives
+    # the same greedy tokens (the parent's step, as it was)
+    want = _greedy(dec, states)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    reset_unique_names()
+    _, bare = build_lm_paged_decoder(50, 4, 8, d_model=32, n_heads=4,
+                                     n_layers=2, kv_dtype=kv_dtype)
+    bare_text = profiler.lowered_ir_text(bare.step.lower(
+        *profiler._arg_specs(
+            states, *bare.init_pool(9), np.zeros((4, 8), np.int32),
+            *[np.zeros(4, t) for t in (np.int32, np.int32, np.uint32,
+                                       np.float32, bool)])))
+    assert "paged_decoder/" not in bare_text
+    assert _greedy(bare, states) == want
+
+
+def test_hlo_scopes_outlive_executor_close_and_join_a_trace():
+    _run_serial(_batch())               # startup and main, then close()
+    tables = profiler.hlo_scopes()
+    assert sorted(tables) == ["executor.block", "executor.block#2"]
+    main_table = tables["executor.block#2"]
+    assert main_table and profiler.hlo_scopes("nothing") == {}
+    types = {c.split(":")[0] for s in main_table.values()
+             for c in s.split("/") if ":" in c}
+    assert {"mul", "softmax_with_cross_entropy", "sgd"} <= types
+    # the join: seconds by instruction -> seconds by scope, on the table
+    # that covers the most of them
+    op = next(k for k, s in main_table.items() if "mul:" in s)
+    by_scope = profiler.scope_seconds({op: 2.0, "not-an-op": 0.5},
+                                      "executor.block")
+    assert by_scope[main_table[op]] == 2.0 and by_scope[""] == 0.5
+    profiler.reset_profiler()
+    assert profiler.hlo_scopes() == {}
+
+
+def test_registry_keeps_only_its_cap_of_programs_alive_past_close():
+    """What `hlo_scopes()` costs a process that never asks: the last
+    `_HLO_PROVIDERS_CAP` step functions (with their Programs) stay alive
+    past `Executor.close()`; an older one goes with its executor."""
+    import gc
+    import weakref
+
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=_batch(), fetch_list=[loss], scope=scope)
+    exe.close()
+    block = weakref.ref(main.global_block())
+    del main, startup, loss, scope
+    gc.collect()
+    assert block() is not None          # held by the registry alone
+    assert len(profiler._hlo_text_providers) == 2
+    for _ in range(profiler._HLO_PROVIDERS_CAP):     # later executables
+        profiler.register_jitted("x", jax.jit(lambda: 0))
+    assert len(profiler._hlo_text_providers) == profiler._HLO_PROVIDERS_CAP
+    gc.collect()
+    assert block() is None
+
+
+def test_compiled_text_names_this_builds_scopes(compile_cache):
+    """JAX keys its compile cache without metadata: the executable a
+    build left there comes back with THAT build's scope names, also for
+    the build that runs now.  `_compiled_text` keys its compile with
+    metadata (for its own thread, not the process), so its table names
+    this build's."""
+    import jax.numpy as jnp
+
+    def build(scope):
+        @jax.jit
+        def f(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x) + 1
+        return f
+
+    x = jnp.ones((8, 8))
+    build("old_name")(x)                        # fills the cache
+    new = build("new_name")
+    new(x)                                      # a hit: the old names
+    assert "old_name/" in new.lower(x).compile().as_text()
+    text = profiler._compiled_text(lambda: new.lower(x))
+    assert "new_name/" in text and "old_name/" not in text
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+def test_a_jax_without_the_metadata_key_fails_loudly(monkeypatch):
+    monkeypatch.setattr(profiler, "_METADATA_KEY_OPTION", "no_such_option")
+    profiler.register_jitted("f", jax.jit(lambda x: x + 1), np.ones(2))
+    with pytest.raises(profiler.ScopeNamesUnavailable):
+        profiler.hlo_scopes()
+    with pytest.raises(profiler.ScopeNamesUnavailable):
+        profiler.scope_seconds({"add.1": 1.0}, "f")
+
+
+def test_scope_map_gives_compiler_made_instructions_their_producers_scope():
+    hlo = '''
+  %fusion.3 = f32[4,8]{1,0} fusion(%p), kind=kLoop, calls=%fc, metadata={op_name="jit(step)/paged_decoder/kv_gather/gather" stack_frame_id=3}
+  %reshape.100 = f32[4,2,4]{2,1,0} reshape(%fusion.3), backend_config={"flag_configs":[]}
+  %copy-start.1 = (f32[4,2,4], u32[]) copy-start(%reshape.100)
+  %copy-done.1 = f32[4,2,4] copy-done(%copy-start.1)
+  %lonely.7 = f32[] constant(0)
+'''
+    table, inherited = profiler._scope_tables(hlo)
+    want = "jit(step)/paged_decoder/kv_gather/gather"
+    assert table == profiler._scope_map(hlo) == {
+        "fusion.3": want, "reshape.100": want,
+        "copy-start.1": want, "copy-done.1": want}
+    assert inherited == {"reshape.100", "copy-start.1", "copy-done.1"}
+    # a reader can tell the compiler's word from the heuristic
+    profiler._register_hlo_text("step", lambda: hlo)
+    ops = {"fusion.3": 1.0, "reshape.100": 2.0, "copy-done.1": 0.5,
+           "elsewhere": 4.0}
+    assert profiler.scope_seconds(ops, "step") == {want: 3.5, "": 4.0}
+    assert profiler.scope_seconds(ops, "step", inherited_only=True) == {
+        want: 2.5}
+
+
+# ---------------------------------------------------------------------------
+# F. what it costs when everything is off
+# ---------------------------------------------------------------------------
+
+
+def test_every_new_site_is_the_shared_noop_when_all_is_off(monkeypatch):
+    assert not (metrics.enabled() or tracing.enabled()
+                or tracing._listeners)
+    made = []
+    monkeypatch.setattr(tracing.Span, "__init__",
+                        lambda self, *a, **k: made.append(a))
+    monkeypatch.setattr(tracing.Span, "set_attr",
+                        lambda self, *a: made.append(a))
+    monkeypatch.setattr(tracing, "_store", made.append)
+    for name in ("executor.feed", "executor.dispatch", "executor.fetch",
+                 "serving.decode_tick"):
+        assert tracing.span(name) is tracing._NOOP
+    assert attribution.phase("trainer", "reader") is attribution._NOOP
+    feed = _batch()
+    _run_serial(feed)
+    _run_parallel(feed)
+    srv, _ = _tiny_server()
+    try:
+        assert len(srv.submit([1, 2, 3], 4).result(60)) == 4
+        assert srv._request_span(object(), 0.0) is None
+    finally:
+        srv.close()
+    assert list(attribution.phased_iter("trainer", "reader", [1, 2])) == [
+        1, 2]
+    assert made == [] and tracing.finished_spans() == []
