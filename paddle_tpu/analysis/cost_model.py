@@ -1024,19 +1024,25 @@ def _paged_attention_gather_cost(spec: Dict, slots: int = 1,
                                  kv_dtype: str = "fp32", **_) -> Dict:
     """Gather-through-block-table attention for ONE query position per
     slot: K/V [n_layers, blocks, block_size, d_model] gathered through
-    the table to `context` logical positions, dequantized, then QK^T +
-    att*V (2*ctx*d each, per layer).
+    the table to `context` logical positions IN THE POOL'S DTYPE, then
+    contracted over d_model against the block-diagonal query (QK^T)
+    and the softmax weights (att*V); int8 scales ride on the scores
+    and the weights, so no dequantized copy exists.
 
-    Bytes charge BOTH legs of the composition: the pool reads in
-    storage precision AND the logical-order f32 gathered copy the XLA
-    path materializes (written, then re-read by the einsums) — the
-    traffic the fused `paged_attention_decode` kernel deletes."""
+    Flops are what the composition executes: each of the n_heads query
+    rows contracts over all of d_model (2*ctx*d a row for QK^T and
+    again for att*V, per layer), n_heads times a head-split
+    contraction's — the price of reading K and V once from unpadded
+    tiles.  Bytes charge BOTH legs: the pool reads in storage
+    precision AND the logical-order gathered copy in the same
+    precision (written once, read once) — the traffic the fused
+    `paged_attention_decode` kernel deletes."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
-    flops = slots * layers * 4.0 * ctx * d
+    flops = slots * layers * 4.0 * ctx * d * h
     pool_bytes = slots * layers * 2.0 * ctx * d * kvb
-    copy_bytes = slots * layers * 2.0 * ctx * d * 8.0
+    copy_bytes = 2.0 * pool_bytes
     return {
         "kernel": "paged_attention_gather",
         "shapes": {"pool": f"[{layers}, blocks, {bs}, {d}] x2 ({kv_dtype})",
@@ -1055,16 +1061,18 @@ def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
                                  window: int = 1, **_) -> Dict:
     """The fused Pallas decode-attention kernel
     (kernels/paged_attention.py): K/V blocks stream through the block
-    table straight into VMEM, dequantized in-lane — same flops as the
-    gather composition, but the XLA path's logical-order f32 copy of
-    the gathered context (written then re-read in HBM) never exists.
-    `gather_copy_bytes_avoided` quantifies that saved traffic."""
+    table straight into VMEM, dequantized in-lane, and each head
+    contracts over its own d_head columns (1/n_heads of the gather
+    composition's multiply-adds); the XLA path's logical-order copy of
+    the gathered context (pool precision, written then re-read) never
+    exists.  `gather_copy_bytes_avoided` quantifies that saved
+    traffic."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
     flops = slots * window * layers * 4.0 * ctx * d
     # pool-block reads only, in storage precision: q/out traffic is the
-    # step row's act_bytes, and the oracle's logical-order f32 copy
+    # step row's act_bytes, and the oracle's logical-order copy
     # (write + re-read) simply never exists on this path
     pool_bytes = slots * layers * 2.0 * ctx * d * kvb
     return {
@@ -1074,10 +1082,10 @@ def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
                    "tables": f"[{slots}, {nb}] int32",
                    "query": f"[{slots}, {window}, {d}]"},
         "flops": flops, "bytes": pool_bytes,
-        # what the oracle pays on top: the dequantized logical-order
-        # copy, f32, materialized (write) and consumed (read) per layer
-        "gather_copy_bytes_avoided": slots * layers * 2.0 * ctx * d
-        * 8.0,
+        # what the oracle pays on top: the logical-order copy in the
+        # pool's precision, materialized (write) and consumed (read)
+        # per layer
+        "gather_copy_bytes_avoided": 2.0 * pool_bytes,
         "fused_dequant": kv_dtype != "fp32",
         "context": ctx, "slots": slots, "window": window,
     }

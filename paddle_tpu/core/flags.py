@@ -199,8 +199,8 @@ define_flag("serving_kv_dtype", "",
             "passes kv_dtype=None (docs/serving.md 'KV quantization'): "
             "'' or 'fp32' = float32 blocks; 'bf16' = bfloat16 blocks "
             "(half the resident KV bytes); 'int8' = int8 blocks with "
-            "one float32 scale per (layer, block), quantize-on-write / "
-            "dequantize-on-gather (~4x fewer resident KV bytes, so the "
+            "one float32 scale per (layer, block), quantize-on-write, "
+            "scales applied in attention (~4x fewer resident KV bytes, so the "
             "same HBM budget holds ~2x the sequences K+V vs bf16 and "
             "~4x vs fp32).  Read at BUILD time; the model-dir spec's "
             "kv_dtype and explicit builder/server args override it")

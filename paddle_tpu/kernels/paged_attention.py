@@ -3,9 +3,10 @@
 The paged decoder (models/transformer.build_lm_paged_decoder) is the
 serving hot path and the top entry on the static analyzer's
 memory-bound worklist: its XLA lowering gathers K/V through the block
-table into a logical-order [S, ctx, d] copy in HBM every tick, and
-quantized pools additionally pay a full dequantization round-trip on
-that copy.  This kernel reads K/V blocks DIRECTLY through the block
+table into a logical-order [S, ctx, d] copy (in the pool's dtype)
+every tick, whole tables, unowned entries included, and contracts
+over all of d_model for every head.  This kernel reads K/V blocks
+DIRECTLY through the block
 table — the table rides the scalar-prefetch lane, so each grid step's
 BlockSpec index map addresses one physical pool block and Pallas
 streams exactly the blocks a slot owns into VMEM, dequantizing in-lane

@@ -535,9 +535,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
       Program path (shared structural extraction with the dense
       decoder).
 
-    `kv_dtype` selects the POOL's storage precision (the
-    quantize-on-write / dequantize-on-gather side of docs/serving.md
-    "KV quantization"; compute stays float32):
+    `kv_dtype` selects the POOL's storage precision (docs/serving.md
+    "KV quantization": writes quantize, attention reads the gathered
+    blocks as stored; scores, softmax and accumulation stay float32):
       * "fp32" (default): plain float32 blocks;
       * "bf16": blocks stored bfloat16 (half the resident bytes,
         ~mantissa-rounding error on attention values);
@@ -616,7 +616,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # warn once per compile, so only donate where it lands
     donate = (1, 2) if platform != "cpu" else ()
 
-    # -- pool storage: quantize-on-write / dequantize-on-gather ------------
+    # -- pool storage: quantize-on-write ---------------------------------
     def _write(pool, l, wb, wi, row):
         """Write `row` [S, D] at (layer l, block wb[s], offset wi[s])."""
         if kv_dtype == "fp32":
@@ -643,24 +643,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                       -127, 127).astype(jnp.int8)
         return (q.at[l, wb].set(qn), sc_.at[l, wb].set(new_scale))
 
-    def _gather(pool, l, tables):
-        """Dequantized [S, NB, BS, D] float32 view through the table."""
-        if kv_dtype == "fp32":
-            return pool[l][tables]
-        if kv_dtype == "bf16":
-            return pool[l][tables].astype(jnp.float32)
-        q, sc_ = pool
-        return (q[l][tables].astype(jnp.float32)
-                * sc_[l][tables][:, :, None, None])
-
     # Names on the device's time (metadata only, the math is untouched):
     # everything a step traces lies under `paged_decoder/<part>`, so a
     # device trace joined through profiler.hlo_scopes() says what share
     # of a tick is the table gather, the attention over it, the weight
     # matmuls...  `kv_gather` is the gather through the block table
-    # with its dequantisation or cast and the reshape; `attention` is
-    # scores, mask, softmax and the weighted sum (the Pallas `_attend`
-    # call lies there when selection takes it).
+    # (values and int8 scales); `attention` is the two contractions,
+    # the mask and the softmax (the Pallas `_attend` call lies there
+    # when selection takes it).
     scope = jax.named_scope
 
     def _sample(logits, seeds, positions, temps):
@@ -683,10 +673,65 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         var = ((x - mu) ** 2).mean(-1, keepdims=True)
         return (x - mu) / jnp.sqrt(var + 1e-5) * sc_ + b_
 
-    def _gather_heads(pool, l, tables, s_n):
+    def _gather(pool, l, tables):
+        """Layer `l` through the block table, as the pool stores it:
+        ([S, NB*BS, D] values in the pool's dtype, [S, NB*BS] float32
+        scales or None).  Layer and table index the pool TOGETHER, so
+        no [num_blocks, BS, D] slice of the pool is copied first, and
+        table order IS logical order, so the rows are the dense
+        cache's rows.  int8 values stay int8: their per-(layer, block)
+        scale is applied to the scores and to the softmax weights
+        (`_attention`), which is the same product."""
+        s_n = tables.shape[0]
         with scope("kv_gather"):
-            return _gather(pool, l, tables).reshape(
-                s_n, nb * bs, n_heads, d_head)
+            if kv_dtype == "int8":
+                q, sc_ = pool
+                return (q[l, tables].reshape(s_n, nb * bs, d_model),
+                        jnp.repeat(sc_[l, tables], bs, axis=1))
+            return pool[l, tables].reshape(s_n, nb * bs, d_model), None
+
+    def _attention(q, pool_k, pool_v, l, tables, pos_mask):
+        """Attention of q [S, W, D] over layer `l` of the paged pools
+        -> [S, W, D]; pos_mask [S, W, NB*BS] says which logical
+        positions each window row sees.
+
+        K and V are read once, in the pool's dtype, with d_model as
+        the minor dimension all the way into the contraction: the
+        query is laid out block-diagonally by head ([S, W*H, D], zero
+        outside a head's d_head columns), so `Qbd . K^T` over d_model
+        IS the per-head score, and `weights . V` gives every head all
+        d_model columns of which it keeps its own.  That spends
+        n_heads times the multiply-adds of a head-split contraction
+        and never reshapes K or V to [.., n_heads, d_head] (on a TPU a
+        relayout of the whole gathered view into half-empty lane
+        tiles) nor widens them to float32.  Scores, mask, softmax and
+        both accumulations are float32."""
+        s_n, w_n = q.shape[0], q.shape[1]
+        k, k_scale = _gather(pool_k, l, tables)
+        v, v_scale = _gather(pool_v, l, tables)
+        batched = ((0,), (0,))
+        with scope("attention"):
+            # [H, D]: column d belongs to head d // d_head
+            head_cols = (jnp.arange(d_model)[None, :] // d_head
+                         == jnp.arange(n_heads)[:, None])
+            q_bd = jnp.where(head_cols, q[:, :, None, :], 0.0).reshape(
+                s_n, w_n * n_heads, d_model)
+            sc = jax.lax.dot_general(
+                q_bd, k, (((2,), (2,)), batched),
+                preferred_element_type=jnp.float32) * scale
+            if k_scale is not None:
+                sc = sc * k_scale[:, None, :]
+            sc = jnp.where(jnp.repeat(pos_mask, n_heads, axis=1), sc,
+                           -jnp.inf)
+            w_att = jax.nn.softmax(sc, axis=-1)
+            if v_scale is not None:
+                w_att = w_att * v_scale[:, None, :]
+            ctx = jax.lax.dot_general(
+                w_att, v, (((2,), (1,)), batched),
+                preferred_element_type=jnp.float32)
+            return jnp.where(
+                head_cols, ctx.reshape(s_n, w_n, n_heads, d_model),
+                0.0).sum(axis=2)
 
     def _step_logits(g, pool_k, pool_v, tables, positions, tokens,
                      active):
@@ -731,19 +776,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     ctx_av = _attend(q[:, None, :], pool_k, pool_v,
                                      tables, positions, l)[:, 0]
             else:
-                # gather-based attention over the block table:
-                # [S, NB, BS, D] in table order IS logical order, so
-                # after the reshape the math is the dense cache's math
-                # on the same values
-                kh = _gather_heads(pool_k, l, tables, s_n)
-                vh = _gather_heads(pool_v, l, tables, s_n)
-                with scope("attention"):
-                    qh = q.reshape(s_n, n_heads, d_head)
-                    sc = jnp.einsum("bhd,bshd->bhs", qh, kh) * scale
-                    sc = jnp.where(pos_mask[:, None, :], sc, -jnp.inf)
-                    w_att = jax.nn.softmax(sc, axis=-1)
-                    ctxh = jnp.einsum("bhs,bshd->bhd", w_att, vh)
-                    ctx_av = ctxh.reshape(s_n, d_model)
+                ctx_av = _attention(q[:, None, :], pool_k, pool_v, l,
+                                    tables, pos_mask[:, None, :])[:, 0]
             with scope("attn_out"):
                 x = x + (ctx_av @ wo + bo)
             with scope("mlp"):
@@ -835,15 +869,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     ctx_av = _attend(q, pool_k, pool_v, tables,
                                      positions, l)
             else:
-                kh = _gather_heads(pool_k, l, tables, s_n)
-                vh = _gather_heads(pool_v, l, tables, s_n)
-                with scope("attention"):
-                    qh = q.reshape(s_n, w_n, n_heads, d_head)
-                    sc = jnp.einsum("bqhd,bshd->bqhs", qh, kh) * scale
-                    sc = jnp.where(pos_mask[:, :, None, :], sc, -jnp.inf)
-                    w_att = jax.nn.softmax(sc, axis=-1)
-                    ctxh = jnp.einsum("bqhs,bshd->bqhd", w_att, vh)
-                    ctx_av = ctxh.reshape(s_n, w_n, d_model)
+                ctx_av = _attention(q, pool_k, pool_v, l, tables,
+                                    pos_mask)
             with scope("attn_out"):
                 x = x + (ctx_av @ wo + bo)
             with scope("mlp"):

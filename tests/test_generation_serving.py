@@ -151,6 +151,179 @@ def test_paged_decoder_matches_dense_kv_decoder():
         np.testing.assert_array_equal(want[i, 3:9], outs[i])
 
 
+def _random_paged_inputs(dec, slots, window, seed):
+    """Random pools (block 0 is the null block, no table's live entry),
+    partly filled tables whose tails point at block 0, cursors inside
+    the owned span with room for `window` positions."""
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(seed)
+    nb, bs = dec.max_blocks_per_seq, dec.block_size
+    n_blocks = 1 + slots * nb
+    shape = (dec.n_layers, n_blocks, bs, dec.d_model)
+
+    def pool():
+        if dec.kv_dtype == "int8":
+            return (jnp.asarray(r.randint(-127, 128, shape), jnp.int8),
+                    jnp.asarray(r.uniform(0.002, 0.02, shape[:2]),
+                                jnp.float32))
+        return jnp.asarray(r.standard_normal(shape),
+                           {"fp32": jnp.float32,
+                            "bf16": jnp.bfloat16}[dec.kv_dtype])
+
+    owned = r.randint(2, nb + 1, slots)            # blocks a slot owns
+    tables = np.zeros((slots, nb), np.int32)
+    ids = 1 + r.permutation(slots * nb)            # never block 0
+    for s in range(slots):
+        tables[s, :owned[s]] = ids[s * nb:s * nb + owned[s]]
+    positions = np.array([r.randint(0, owned[s] * bs - window + 1)
+                          for s in range(slots)], np.int32)
+    tokens = r.randint(0, V, (slots, window)).astype(np.int32)
+    return pool(), pool(), tables, positions, tokens
+
+
+def _old_gather_path_logits(dec, states, pool_k, pool_v, tables,
+                            positions, tokens, n_heads):
+    """The decoder's forward with attention as the gather path wrote it
+    before it read K and V once: `pool[l][tables]` -> float32 ->
+    [S, L, H, d_head] einsums.  Plain function, whole window
+    (tokens [S, W]), over pools that already hold the window's K/V
+    (the decoder's own writes), so only the attention formula differs.
+    -> [S, W, V] float32 logits."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.transformer import _lm_param_structure
+
+    nb, bs, d = dec.max_blocks_per_seq, dec.block_size, dec.d_model
+    d_head = d // n_heads
+    fw.reset_unique_names()
+    _, _, tok_emb, pos_tab, lns, weights, biases = _lm_param_structure(
+        V, nb * bs, d, n_heads, dec.n_layers, 4 * d)
+    g = {n: jnp.asarray(v) for n, v in states.items()}
+    s_n, w_n = tokens.shape
+
+    def ln(x, i):
+        mu = x.mean(-1, keepdims=True)
+        var = ((x - mu) ** 2).mean(-1, keepdims=True)
+        return ((x - mu) / jnp.sqrt(var + 1e-5) * g[lns[i][0]]
+                + g[lns[i][1]])
+
+    def gather(pool, l):
+        if dec.kv_dtype == "int8":
+            q, sc = pool
+            dense = (q[l][tables].astype(jnp.float32)
+                     * sc[l][tables][:, :, None, None])
+        else:
+            dense = pool[l][tables].astype(jnp.float32)
+        return dense.reshape(s_n, nb * bs, n_heads, d_head)
+
+    pos_w = positions[:, None] + np.arange(w_n)[None, :]
+    mask = np.arange(nb * bs)[None, None, :] <= pos_w[:, :, None]
+    x = g[tok_emb][tokens] + g[pos_tab][pos_w]
+    for l in range(dec.n_layers):
+        q = ln(x, 2 * l) @ g[weights[6 * l]] + g[biases[6 * l]]
+        kh, vh = gather(pool_k, l), gather(pool_v, l)
+        qh = q.reshape(s_n, w_n, n_heads, d_head)
+        sc = jnp.einsum("bqhd,bshd->bqhs", qh, kh) / np.sqrt(d_head)
+        sc = jnp.where(mask[:, :, None, :], sc, -jnp.inf)
+        ctx = jnp.einsum("bqhs,bshd->bqhd", jax.nn.softmax(sc, -1), vh)
+        x = x + (ctx.reshape(s_n, w_n, d) @ g[weights[6 * l + 3]]
+                 + g[biases[6 * l + 3]])
+        h2 = ln(x, 2 * l + 1)
+        x = x + (jax.nn.relu(h2 @ g[weights[6 * l + 4]]
+                             + g[biases[6 * l + 4]])
+                 @ g[weights[6 * l + 5]] + g[biases[6 * l + 5]])
+    xf = ln(x, 2 * dec.n_layers)
+    return np.asarray(xf @ g[weights[6 * dec.n_layers]]
+                      + g[biases[6 * dec.n_layers]], np.float32)
+
+
+@pytest.mark.parametrize("entry", ["step_logits", "step_window"])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_gather_path_matches_old_head_split_formula(kv_dtype, entry):
+    """One gather in the pool's dtype and contractions over d_model
+    (block-diagonal query, each head keeping its own columns) give the
+    old float32 head-split formula's numbers, through both entry
+    points, on random pools with partly filled tables."""
+    import jax.numpy as jnp
+
+    slots, window, n_heads = 4, 3, 2
+    dec, states = _decoder(block_size=4, max_blocks=5,
+                           kv_dtype=None if kv_dtype == "fp32"
+                           else kv_dtype)
+    assert dec.kv_dtype == kv_dtype
+    g = {n: jnp.asarray(v) for n, v in states.items()}
+    zs, zt = np.zeros(slots, np.uint32), np.zeros(slots, np.float32)
+
+    if entry == "step_logits":
+        pool_k, pool_v, tables, positions, tokens = _random_paged_inputs(
+            dec, slots, 1, seed=11)
+        args = (g, pool_k, pool_v, tables, positions, tokens[:, 0], zs,
+                zt, np.ones(slots, bool))
+        got = np.asarray(dec.step_logits(*args))[:, None]
+        _, pool_k, pool_v = dec.step(*args)       # the tick's writes
+        valid = np.ones((slots, 1), bool)
+    else:
+        pool_k, pool_v, tables, positions, tokens = _random_paged_inputs(
+            dec, slots, window, seed=12)
+        n_valid = np.array([3, 1, 2, 2], np.int32)    # below the window
+        got, pool_k, pool_v = dec.step_window(
+            g, pool_k, pool_v, tables, positions, tokens, zs, zt,
+            n_valid)
+        got = np.asarray(got)
+        valid = np.arange(window)[None, :] < n_valid[:, None]
+    want = _old_gather_path_logits(dec, states, pool_k, pool_v, tables,
+                                   positions, tokens, n_heads)
+    assert np.isfinite(want[valid]).all()
+    if entry == "step_logits":
+        np.testing.assert_allclose(got, want, rtol=1e-5,
+                                   atol=1e-5 * np.abs(want).max())
+    else:
+        # the window returns greedy tokens: each valid row's is the old
+        # formula's argmax, and the old formula's margin over the
+        # runner-up is far above the tolerance the logits are held to
+        top2 = np.sort(want, axis=-1)[..., -2:]
+        assert ((top2[..., 1] - top2[..., 0])[valid]
+                > 1e-4 * np.abs(want).max()).all()
+        np.testing.assert_array_equal(got[valid],
+                                      want.argmax(-1)[valid])
+
+
+def test_gather_path_holds_no_float32_dense_view():
+    """Structural: at kv_dtype bf16 no float32 value of the step is as
+    large as the gathered K or V ([S, NB*BS, D]) — they stay in the
+    pool's dtype from the gather into the contraction."""
+    import jax
+    import jax.numpy as jnp
+
+    slots = 4
+    dec, states = _decoder(block_size=4, max_blocks=16, kv_dtype="bf16")
+    pool_k, pool_v, tables, positions, tokens = _random_paged_inputs(
+        dec, slots, 1, seed=13)
+    g = {n: jnp.asarray(v) for n, v in states.items()}
+    jaxpr = jax.make_jaxpr(dec.step)(
+        g, pool_k, pool_v, tables, positions, tokens[:, 0],
+        np.zeros(slots, np.uint32), np.zeros(slots, np.float32),
+        np.ones(slots, bool))
+    dense = slots * dec.max_len * dec.d_model
+    assert all(np.size(v) < dense for v in states.values())
+
+    def values(jp):
+        for eqn in jp.eqns:
+            yield from (v.aval for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from values(sub)
+
+    avals = list(values(jaxpr.jaxpr))
+    gathered = [a for a in avals if a.dtype == jnp.bfloat16
+                and a.shape == (slots, dec.max_len, dec.d_model)]
+    assert gathered, "the gathered K/V should be visible in the jaxpr"
+    wide = [a for a in avals
+            if a.size >= dense and a.dtype == jnp.float32]
+    assert not wide, wide
+
+
 def test_continuous_batching_bit_identical_to_solo():
     """Mixed prompt lengths, admissions mid-decode, evictions: every
     request's tokens are bit-identical to running it alone."""
