@@ -489,8 +489,28 @@ def build_lm_kv_decoder(vocab_size, max_len, d_model=256, n_heads=4,
 
 def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                            d_model=256, n_heads=4, n_layers=2,
-                           d_inner=None, kv_dtype=None, platform=None):
+                           d_inner=None, kv_dtype=None, platform=None,
+                           block=None):
     """Paged-attention decode step for the decoder-only LM.
+
+    `block` says WHICH block the step computes, as a
+    `lm_block.BlockSpec` (models/lm_block.py: norm kind, position kind,
+    QK-norm, biases, FFN kind): None is `lm_block.OPT`, the block
+    `transformer_lm` trains (LayerNorm, learned positions, biases,
+    ReLU FFN), whose parameter names and shapes are read from the
+    training Program; `lm_block.olmoe(...)` is RMSNorm + RoPE +
+    QK-norm + a dropless top-k SwiGLU expert layer (`d_inner` is then
+    ONE expert's width), whose `state_shapes` come from the
+    description alone: it has no training Program and its
+    `startup_program` is None.  Cache, gather, attention, write,
+    sampling, `step`, `step_logits` and `step_window` are one code
+    path for every block.  A block with experts returns a FOURTH
+    value from `step` and `step_window`: int32 [n_layers], the
+    distinct experts each layer routed to this call
+    (`decoder.step_counters` names it; empty for a block without),
+    and has `decoder.step_routing`: `step_logits` that also returns
+    what every layer's router was given and what it chose, for a
+    comparison that must not mistake a near-tie for a fault.
 
     `build_lm_kv_decoder` owns a dense per-sequence cache
     ([B, max_len, d]) whose lifetime is one generate() call — fine for
@@ -573,11 +593,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     """
     import functools
     import math
+    import types
 
     import jax
     import jax.numpy as jnp
 
     from ..core import flags as core_flags
+    from . import lm_block
 
     d_inner = d_inner or 4 * d_model
     d_head = d_model // n_heads
@@ -606,9 +628,30 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         max_blocks_per_seq=int(max_blocks_per_seq), kv_dtype=kv_dtype,
         platform=platform)
 
-    startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
-        _lm_param_structure(vocab_size, max_len, d_model, n_heads,
-                            n_layers, d_inner))
+    spec = lm_block.OPT if block is None else block
+    if not isinstance(spec, lm_block.BlockSpec):
+        raise TypeError(f"block={block!r}: a lm_block.BlockSpec "
+                        "(lm_block.OPT, lm_block.olmoe(...)) or None")
+    if spec is lm_block.OPT:
+        startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
+            _lm_param_structure(vocab_size, max_len, d_model, n_heads,
+                                n_layers, d_inner))
+
+        def fc(i):
+            return weights[i], biases[i]
+
+        layout = types.SimpleNamespace(
+            tok=tok_emb, pos=pos_tab, final=lns[2 * n_layers],
+            head=fc(6 * n_layers),
+            layers=[{"norm1": lns[2 * l], "q": fc(6 * l),
+                     "k": fc(6 * l + 1), "v": fc(6 * l + 2),
+                     "o": fc(6 * l + 3), "norm2": lns[2 * l + 1],
+                     "w1": fc(6 * l + 4), "w2": fc(6 * l + 5)}
+                    for l in range(n_layers)])
+    else:
+        startup = None
+        layout, shapes = lm_block.param_layout(
+            spec, vocab_size, d_model, n_layers, d_inner)
 
     scale = 1.0 / math.sqrt(d_head)
     # buffer donation makes the pool update in place (no copy of the
@@ -667,11 +710,75 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                                        logits / safe_t)
             return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
-    def _ln(g, x, i):
-        sc_, b_ = g[lns[i][0]], g[lns[i][1]]
-        mu = x.mean(-1, keepdims=True)
-        var = ((x - mu) ** 2).mean(-1, keepdims=True)
-        return (x - mu) / jnp.sqrt(var + 1e-5) * sc_ + b_
+    # -- the block, from its description ---------------------------------
+    # `layout` names every parameter as a (weight-or-scale,
+    # bias-or-shift-or-None) pair; what a block lacks is None and adds
+    # no operation, so OPT's step is the operations it always was.
+    def _norm(g, x, pair):
+        return lm_block.norm(spec, x, g[pair[0]],
+                             None if pair[1] is None else g[pair[1]])
+
+    def _fc(g, x, pair):
+        y = x @ g[pair[0]]
+        return y if pair[1] is None else y + g[pair[1]]
+
+    def _embed(g, tokens, pos):
+        """Token rows, plus the position table's where the block has
+        one.  Under RoPE nothing is added and the residual stream is
+        float32 from the start (with a table, OPT's stays in the
+        weights' dtype until the first attention output widens it)."""
+        with scope("embed"):
+            if layout.pos is not None:
+                return g[layout.tok][tokens] + g[layout.pos][pos]
+            return g[layout.tok][tokens].astype(jnp.float32)
+
+    def _rotation(pos):
+        """cos and sin of each row's OWN position (RoPE is per slot,
+        and per window row), or None for a block without."""
+        if spec.positions != "rope":
+            return None
+        with scope("rope"):
+            return lm_block.rope_tables(spec, pos, d_head)
+
+    def _qkv(g, lay, x, rot):
+        with scope("qkv"):
+            h = _norm(g, x, lay["norm1"])
+            q, kk, vv = (_fc(g, h, lay[n]) for n in ("q", "k", "v"))
+        if spec.qk_norm:
+            with scope("qk_norm"):
+                q = _norm(g, q, lay["q_norm"])
+                kk = _norm(g, kk, lay["k_norm"])
+        if rot is not None:
+            # K is turned BEFORE it is written: the pool holds rotated
+            # keys, so a cached position is never turned again
+            with scope("rope"):
+                q = lm_block.rope(q, *rot, n_heads)
+                kk = lm_block.rope(kk, *rot, n_heads)
+        return q, kk, vv
+
+    def _ffn(g, lay, x, hits):
+        """x + FFN(norm(x)); a block with experts appends (its count
+        of distinct experts hit, the router's input, the weights and
+        the experts it chose) to `hits`."""
+        with scope("mlp"):
+            h2 = _norm(g, x, lay["norm2"])
+            if spec.ffn == "relu":
+                return x + _fc(g, jax.nn.relu(_fc(g, h2, lay["w1"])),
+                               lay["w2"])
+        h2 = h2.reshape(-1, d_model)
+        y, hit, routed = lm_block.moe_ffn(
+            spec, h2, g[lay["router"][0]], g[lay["gate"][0]],
+            g[lay["up"][0]], g[lay["down"][0]], scope=scope)
+        hits.append((hit, h2) + routed)
+        with scope("moe_combine"):
+            return x + y.reshape(x.shape)
+
+    def _head(g, x):
+        with scope("head"):
+            return _fc(g, _norm(g, x, layout.final), layout.head)
+
+    def _with_counts(out, hits):
+        return out + ((jnp.stack([h[0] for h in hits]),) if hits else ())
 
     def _gather(pool, l, tables):
         """Layer `l` through the block table, as the pool stores it:
@@ -737,12 +844,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                      active):
         s_n = tokens.shape[0]
         lane = jnp.arange(s_n)
-
-        def W(i):
-            return g[weights[i]], g[biases[i]]
-
-        with scope("embed"):
-            x = g[tok_emb][tokens] + g[pos_tab][positions]   # [S, D]
+        hits = []
+        x = _embed(g, tokens, positions)                      # [S, D]
+        rot = _rotation(positions)
         with scope("kv_write"):
             # this tick's K/V land at the cursor's (block, offset);
             # inactive slots are routed to block 0 offset 0 — the
@@ -755,16 +859,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # participates iff j <= cursor, which also hides
             # unallocated tail entries
             pos_mask = jnp.arange(nb * bs)[None, :] <= positions[:, None]
-        for l in range(n_layers):
-            wq, bq = W(6 * l + 0)
-            wk, bk = W(6 * l + 1)
-            wv, bv = W(6 * l + 2)
-            wo, bo = W(6 * l + 3)
-            with scope("qkv"):
-                h = _ln(g, x, 2 * l)
-                q = h @ wq + bq
-                kk = h @ wk + bk
-                vv = h @ wv + bv
+        for l, lay in enumerate(layout.layers):
+            q, kk, vv = _qkv(g, lay, x, rot)
             with scope("kv_write"):
                 pool_k = _write(pool_k, l, wb, wi, kk)
                 pool_v = _write(pool_v, l, wb, wi, vv)
@@ -779,25 +875,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 ctx_av = _attention(q[:, None, :], pool_k, pool_v, l,
                                     tables, pos_mask[:, None, :])[:, 0]
             with scope("attn_out"):
-                x = x + (ctx_av @ wo + bo)
-            with scope("mlp"):
-                h2 = _ln(g, x, 2 * l + 1)
-                w1, b1 = W(6 * l + 4)
-                w2, b2 = W(6 * l + 5)
-                x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
-        with scope("head"):
-            xf = _ln(g, x, 2 * n_layers)
-            wf, bf = W(6 * n_layers)
-            return xf @ wf + bf, pool_k, pool_v              # [S, V]
+                x = x + _fc(g, ctx_av, lay["o"])
+            x = _ffn(g, lay, x, hits)
+        return _head(g, x), pool_k, pool_v, hits              # [S, V]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
              active):
         with scope("paged_decoder"):
-            logits, pool_k, pool_v = _step_logits(
+            logits, pool_k, pool_v, hits = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
-            return (_sample(logits, seeds, positions, temps), pool_k,
-                    pool_v)
+            return _with_counts(
+                (_sample(logits, seeds, positions, temps), pool_k,
+                 pool_v), hits)
 
     @jax.jit
     def step_logits(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -805,6 +895,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("paged_decoder"):
             return _step_logits(g, pool_k, pool_v, tables, positions,
                                 tokens, active)[0]
+
+    @jax.jit
+    def step_routing(g, pool_k, pool_v, tables, positions, tokens, seeds,
+                     temps, active):
+        """`step_logits`, and beside the logits every layer's routing
+        as the step computed it: {"inputs": float32 [n_layers, S, D]
+        (what the router was given), "weights": float32 [n_layers, S,
+        k], "experts": int32 [n_layers, S, k]}."""
+        with scope("paged_decoder"):
+            logits, _, _, hits = _step_logits(
+                g, pool_k, pool_v, tables, positions, tokens, active)
+            inputs, weights, experts = (
+                jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
+            return logits, {"inputs": inputs, "weights": weights,
+                            "experts": experts}
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -822,15 +927,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         s_n, w_n = tokens.shape
         lane = jnp.arange(s_n)
         offs_w = jnp.arange(w_n)
-
-        def W(i):
-            return g[weights[i]], g[biases[i]]
-
-        with scope("embed"):
-            pos_w = positions[:, None] + offs_w[None, :]      # [S, W]
-            valid = offs_w[None, :] < n_valid[:, None]        # [S, W]
-            pos_c = jnp.clip(pos_w, 0, max_len - 1)
-            x = g[tok_emb][tokens] + g[pos_tab][pos_c]        # [S, W, D]
+        hits = []
+        pos_w = positions[:, None] + offs_w[None, :]          # [S, W]
+        valid = offs_w[None, :] < n_valid[:, None]            # [S, W]
+        pos_c = jnp.clip(pos_w, 0, max_len - 1)
+        x = _embed(g, tokens, pos_c)                          # [S, W, D]
+        rot = _rotation(pos_c)
         with scope("kv_write"):
             wb = jnp.where(valid,
                            tables[lane[:, None],
@@ -842,16 +944,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # <= positions[s]+j (row 0 reproduces `step`'s mask exactly)
             pos_mask = (jnp.arange(nb * bs)[None, None, :]
                         <= pos_w[:, :, None])                 # [S, W, L]
-        for l in range(n_layers):
-            wq, bq = W(6 * l + 0)
-            wk, bk = W(6 * l + 1)
-            wv, bv = W(6 * l + 2)
-            wo, bo = W(6 * l + 3)
-            with scope("qkv"):
-                h = _ln(g, x, 2 * l)
-                q = h @ wq + bq
-                kk = h @ wk + bk
-                vv = h @ wv + bv
+        for l, lay in enumerate(layout.layers):
+            q, kk, vv = _qkv(g, lay, x, rot)
             # the whole window's K/V is written before the gather, so
             # in-window attention sees the fresh values; int8 blocks
             # re-quantize per position, in order (the running-max
@@ -872,22 +966,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 ctx_av = _attention(q, pool_k, pool_v, l, tables,
                                     pos_mask)
             with scope("attn_out"):
-                x = x + (ctx_av @ wo + bo)
-            with scope("mlp"):
-                h2 = _ln(g, x, 2 * l + 1)
-                w1, b1 = W(6 * l + 4)
-                w2, b2 = W(6 * l + 5)
-                x = x + (jax.nn.relu(h2 @ w1 + b1) @ w2 + b2)
-        with scope("head"):
-            xf = _ln(g, x, 2 * n_layers)
-            wf, bf = W(6 * n_layers)
-            logits = xf @ wf + bf                             # [S, W, V]
+                x = x + _fc(g, ctx_av, lay["o"])
+            x = _ffn(g, lay, x, hits)
+        logits = _head(g, x)                                  # [S, W, V]
         seeds_w = jnp.broadcast_to(seeds[:, None], (s_n, w_n))
         temps_w = jnp.broadcast_to(temps[:, None], (s_n, w_n))
         preds = _sample(logits.reshape(s_n * w_n, -1),
                         seeds_w.reshape(-1), pos_c.reshape(-1),
                         temps_w.reshape(-1)).reshape(s_n, w_n)
-        return preds, pool_k, pool_v
+        return _with_counts((preds, pool_k, pool_v), hits)
 
     if kv_dtype == "fp32":
         elem_bytes = 4.0
@@ -917,11 +1004,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             zv = jax.device_put(zv, device)
         return zk, zv
 
-    import types
-
     decoder = types.SimpleNamespace(
         step=step, step_window=step_window, step_logits=step_logits,
+        step_routing=(step_routing if spec.ffn == "moe_swiglu" else None),
         init_pool=init_pool, platform=platform,
+        step_counters=(("moe_experts_hit",)
+                       if spec.ffn == "moe_swiglu" else ()),
+        compiler_scopes=(lm_block.MOE_COMPILER_SCOPES
+                         if spec.ffn == "moe_swiglu" else None),
         state_names=sorted(shapes), state_shapes=shapes, block_size=bs,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,

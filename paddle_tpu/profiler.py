@@ -266,17 +266,32 @@ def _register_hlo_text(label: str, provider) -> None:
         del _hlo_text_providers[:-_HLO_PROVIDERS_CAP]
 
 
-def register_jitted(label: str, jitted, *args) -> None:
+def register_jitted(label: str, jitted, *args,
+                    compiler_scopes: Optional[Dict[str, str]] = None
+                    ) -> None:
     """Register the jitted step `jitted`, as called with `args`, under
     `label` ("executor.block", "parallel_executor.step",
     "paged_decoder.step"): what `Executor`, `ParallelExecutor` and
     `GenerationServer` do at their first compile or warm-up.  Several
     executables may share a label (a startup and a main program).
     Keeps the function and the arguments' shapes and shardings, no
-    buffer; lowers and compiles only when `hlo_scopes()` asks."""
+    buffer; lowers and compiles only when `hlo_scopes()` asks.
+
+    `compiler_scopes` is the owner's word on instructions the compiler
+    REWRITES under an `op_name` of its own, losing the scope they were
+    traced under ({the compiler's op_name: the scope}): the TPU
+    compiler turns every `ragged_dot` into a grouped-matmul call named
+    `ragged-dot-none`, and only the owner knows that all of its
+    `ragged_dot`s lie under one scope."""
     specs = _arg_specs(*args)
-    _register_hlo_text(label, lambda: _compiled_text(
-        lambda: jitted.lower(*specs)))
+
+    def text():
+        out = _compiled_text(lambda: jitted.lower(*specs))
+        for made, scope in (compiler_scopes or {}).items():
+            out = out.replace(f'op_name="{made}"', f'op_name="{scope}"')
+        return out
+
+    _register_hlo_text(label, text)
 
 
 def _arg_specs(*args):

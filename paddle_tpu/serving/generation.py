@@ -484,14 +484,17 @@ class GenerationServer:
                     np.zeros(self._slots, bool))
             # device time by scope: hlo_scopes() can read the resident
             # step's compiled text later (shapes, no buffers)
-            profiler.register_jitted("paged_decoder.step",
-                                     self._decoder.step, *args)
-            nxt, self._pool_k, self._pool_v = self._decoder.step(*args)
+            profiler.register_jitted(
+                "paged_decoder.step", self._decoder.step, *args,
+                compiler_scopes=getattr(self._decoder,
+                                        "compiler_scopes", None))
+            nxt, self._pool_k, self._pool_v, *_ = self._decoder.step(
+                *args)
             np.asarray(nxt)  # block: compile is done when this returns
             return
         w = self._spec_k + 1
         zw = np.zeros((self._slots, w), np.int32)
-        nxt, self._pool_k, self._pool_v = self._decoder.step_window(
+        nxt, self._pool_k, self._pool_v, *_ = self._decoder.step_window(
             self._states, self._pool_k, self._pool_v, self._tables,
             z, zw, zs, zt, z)
         np.asarray(nxt)
@@ -910,12 +913,14 @@ class GenerationServer:
                 self._tick_attrs(sp, seqs)
             with obs_attr.phase("generation", phase_name):
                 fault_injector().fire("serving.decode")
-                nxt, self._pool_k, self._pool_v = self._decoder.step(
-                    self._states, self._pool_k, self._pool_v,
-                    self._tables, positions, tokens, seeds, temps,
-                    active)
+                nxt, self._pool_k, self._pool_v, *counts = (
+                    self._decoder.step(
+                        self._states, self._pool_k, self._pool_v,
+                        self._tables, positions, tokens, seeds, temps,
+                        active))
             with obs_attr.phase("generation", "sample"):
                 out = np.asarray(nxt)
+                self._step_counts(sp, counts)
         self._m_ticks.inc()
         return out
 
@@ -929,6 +934,16 @@ class GenerationServer:
                                    if s.cur < s.prompt_len - 1))
         sp.set_attr("kv_used", self._cache.used_blocks)
         sp.set_attr("kv_total", self._cache.num_blocks)
+
+    def _step_counts(self, sp, counts) -> None:
+        """What the step counted on the device, summed onto the live
+        tick span under the decoder's own names (`step_counters`:
+        `moe_experts_hit`, distinct experts routed to over all layers,
+        for a block with experts; nothing for one without).  Read
+        after the tokens, in the phase that has already blocked."""
+        if sp is not None and counts:
+            for name, value in zip(self._decoder.step_counters, counts):
+                sp.set_attr(name, int(np.asarray(value).sum()))
 
     def _deliver(self, seqs: List[_Seq], nxt: np.ndarray,
                  metrics_on: bool):
@@ -1104,12 +1119,13 @@ class GenerationServer:
                 self._tick_attrs(sp, seqs)
             with obs_attr.phase("generation", "draft_verify"):
                 fault_injector().fire("serving.decode")
-                nxt, self._pool_k, self._pool_v = \
+                nxt, self._pool_k, self._pool_v, *counts = (
                     self._decoder.step_window(
                         self._states, self._pool_k, self._pool_v,
-                        self._tables, pos, toks, seeds, temps, nv)
+                        self._tables, pos, toks, seeds, temps, nv))
             with obs_attr.phase("generation", "sample"):
                 preds = np.asarray(nxt)
+                self._step_counts(sp, counts)
         self._m_ticks.inc()
         full_plans = [(seq, c, m, teacher, n_prop, proposals[seq])
                       for seq, c, m, teacher, n_prop in plans]
