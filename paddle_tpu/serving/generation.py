@@ -8,6 +8,14 @@ schedule:
 
 * ONE resident decode step (build_lm_paged_decoder) runs per tick over
   the active slot set — a single device dispatch per token position;
+* the scheduler runs ONE TICK AHEAD of the device: it dispatches tick
+  n+1 and only then reads, delivers and evicts tick n.  All tick n+1
+  needs from tick n is each decoding slot's sampled token, and that
+  stays on the device (selected against the host's prompt tokens just
+  before the step); cursors, positions, seeds and the `max_new` finish
+  are known to the host without it.  Admission, delivery, the array
+  building and the dispatch itself so run under the device's step
+  (docs/serving.md "The scheduler loop");
 * BETWEEN ticks the scheduler admits queued requests into free slots
   (prefill is folded into the same per-token step: a just-admitted
   sequence is teacher-forced through its prompt positions while
@@ -58,6 +66,7 @@ optimizations (docs/serving.md):
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import logging
@@ -264,6 +273,8 @@ class _Seq:
         self.eos_id = eos_id
         self.temperature = float(temperature)
         self.seed = int(seed) & 0xFFFFFFFF
+        # next position to DISPATCH (it advances when a tick goes out,
+        # not when its token comes back)
         self.cur = 0
         self.slot = -1
         self.emitted = 0
@@ -291,6 +302,35 @@ class _Seq:
         # the cursor writes K/V at positions 0 .. prompt+max_new-2 (the
         # final emitted token is delivered, never re-attended)
         return self.prompt_len + self.max_new - 1
+
+
+class _Tick:
+    """One dispatched decode tick: `rows` holds (seq, slot, cursor) as
+    each sequence went out (the sequence's own cursor and slot move on
+    while the tick is in flight), `nxt` and `counts` are the step's
+    results, still on the device; `tokens` is `nxt` on the host once
+    the tick has been read."""
+
+    __slots__ = ("rows", "nxt", "counts", "tokens")
+
+    def __init__(self, rows, nxt, counts):
+        self.rows = rows
+        self.nxt = nxt
+        self.counts = counts
+        self.tokens = None
+
+
+@functools.lru_cache(maxsize=None)
+def _feed_tokens():
+    """The jitted select that keeps sampled tokens on the device: a
+    slot whose previous position is still in flight is fed that tick's
+    sampled token, every other slot the host's (a prompt token, or one
+    already read)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda prev, host, from_prev:
+                   jnp.where(from_prev, prev, host))
 
 
 class GenerationServer:
@@ -419,6 +459,11 @@ class GenerationServer:
             self._dpool_k, self._dpool_v = draft_decoder.init_pool(
                 kv_blocks + 1, self._device)
 
+        # the tick dispatched and not yet read (None: nothing in
+        # flight), and what the token select is given in its place
+        self._inflight: Optional[_Tick] = None
+        self._no_tokens = jax.device_put(
+            np.zeros(self._slots, np.int32), self._device)
         self._active: List[Optional[_Seq]] = [None] * self._slots
         self._tables = np.zeros(
             (self._slots, decoder.max_blocks_per_seq), np.int32)
@@ -479,9 +524,14 @@ class GenerationServer:
         zs = z.astype(np.uint32)
         zt = np.zeros(self._slots, np.float32)
         if self._draft is None:
+            # fed as the scheduler feeds it: the tokens come out of the
+            # select, on the device (a host array there would be a
+            # second signature, compiled inside the first request)
+            none = np.zeros(self._slots, bool)
             args = (self._states, self._pool_k, self._pool_v,
-                    self._tables, z, z, zs, zt,
-                    np.zeros(self._slots, bool))
+                    self._tables, z,
+                    _feed_tokens()(self._no_tokens, z, none), zs, zt,
+                    none)
             # device time by scope: hlo_scopes() can read the resident
             # step's compiled text later (shapes, no buffers)
             profiler.register_jitted(
@@ -824,11 +874,19 @@ class GenerationServer:
             self._cache.release(seq)
 
     def _loop(self):
-        dec = self._decoder
+        """The scheduler thread.  Without a draft model it runs one
+        tick ahead of the device: an iteration admits, dispatches tick
+        n+1 (`_tick`, which then blocks on tick n's tokens) and
+        delivers tick n, so everything but the read runs under the
+        device's step.  The tick in flight is read with nothing
+        dispatched behind it (`_flush`) when no sequence has a position
+        left to run, before a hot swap and at close.  A speculative
+        server's accept rule is host code over the window's tokens, so
+        its tick stays serial (`_tick_spec`, nothing ever in flight)."""
         while True:
             with obs_attr.phase("generation", "admit"), self._lock:
                 if self._stop:
-                    return
+                    break
                 shed = self._shed_expired_locked(time.monotonic())
                 admitted = self._admit_locked()
                 seqs = [s for s in self._active if s is not None]
@@ -850,9 +908,20 @@ class GenerationServer:
                 self._m_qdepth.set(qdepth)
                 self._m_active.set(len(seqs))
             if swap is not None:
+                # no sequence holds a slot, but the extra position of
+                # one that ended by eos may still be out
+                self._flush()
                 self._install_states(swap)
                 continue
+            if self._draft is None:
+                # a sequence whose last position is in flight has
+                # nothing left to run: it holds its slot until that
+                # tick is delivered
+                seqs = [s for s in seqs if s.cur < s.positions_needed]
             if not seqs:
+                if self._inflight is not None:
+                    self._flush()
+                    continue
                 with self._lock:
                     if (not self._queue and not self._stop
                             and self._pending_states is None):
@@ -860,41 +929,50 @@ class GenerationServer:
                 continue
             try:
                 # chaos hook fires inside _tick/_tick_spec, within the
-                # attributed phase block: an error rule fails this
-                # tick's sequences (they are evicted, their streams get
-                # the error) but must never kill the scheduler thread
+                # attributed phase block: an error rule fails the
+                # sequences holding a slot (they are evicted, their
+                # streams get the error) but must never kill the
+                # scheduler thread
                 if self._draft is None:
-                    nxt = self._tick(seqs)
+                    done = self._tick(seqs)
                 else:
                     plans, preds = self._tick_spec(seqs)
             except Exception as e:
-                with self._lock:
-                    for seq in seqs:
-                        self._evict_locked(seq)
-                now = time.perf_counter()
-                for seq in seqs:
-                    self._request_span(seq, now,
-                                       error=type(e).__name__)
-                    seq.stream._fail(e)
+                self._fail_active(e)
                 continue
-            if self._draft is None:
-                self._deliver(seqs, nxt, metrics_on)
-            else:
+            if self._draft is not None:
                 self._deliver_spec(plans, preds, metrics_on)
-            # freshly-filled full prompt blocks become shareable the
-            # moment the cursor passes their end (no-op once a
-            # sequence has nothing pending or was evicted)
-            for seq in seqs:
-                self._cache.commit_prefix(seq, seq.cur)
+                # freshly-filled full prompt blocks become shareable
+                # the moment the cursor passes their end (no-op once a
+                # sequence has nothing pending or was evicted)
+                for seq in seqs:
+                    self._cache.commit_prefix(seq, seq.cur)
+            elif done is not None:
+                self._deliver(done, metrics_on)
+        self._flush()
 
-    def _tick(self, seqs: List[_Seq]) -> np.ndarray:
+    def _tick(self, seqs: List[_Seq]) -> Optional[_Tick]:
+        """Dispatch one tick over `seqs`, each at the cursor it is to
+        run, and advance their cursors; THEN block on the tokens of the
+        tick dispatched before it.  Returns that earlier tick, read and
+        ready for `_deliver` (None when nothing was in flight); the new
+        one stays in `self._inflight`.  One `serving.decode_tick` span
+        covers both halves."""
+        prev = self._inflight
         tokens = np.zeros(self._slots, np.int32)
         positions = np.zeros(self._slots, np.int32)
         temps = np.zeros(self._slots, np.float32)
         seeds = np.zeros(self._slots, np.uint32)
         active = np.zeros(self._slots, bool)
+        # slots whose token is the one `prev` sampled, still unread
+        from_prev = np.zeros(self._slots, bool)
+        rows = []
         for seq in seqs:
-            tokens[seq.slot] = seq.tokens[seq.cur]
+            rows.append((seq, seq.slot, seq.cur))
+            if seq.cur < len(seq.tokens):
+                tokens[seq.slot] = seq.tokens[seq.cur]
+            else:
+                from_prev[seq.slot] = True
             positions[seq.slot] = seq.cur
             temps[seq.slot] = seq.temperature
             seeds[seq.slot] = seq.seed
@@ -903,31 +981,41 @@ class GenerationServer:
         # sequence is still teacher-forcing its prompt, else "decode"
         # (mixed ticks are decode work for at least one stream); the
         # host-side sync that materializes the sampled tokens is
-        # "sample" — on an async backend that is where the device time
-        # surfaces
+        # "sample" — that is where the host waits for the device
         phase_name = ("prefill" if all(s.cur < s.prompt_len - 1
                                        for s in seqs) else "decode")
         with obs_tracing.span("serving.decode_tick",
                               active=len(seqs)) as sp:
             if sp is not None:
                 self._tick_attrs(sp, seqs)
+                sp.set_attr("ahead", int(prev is not None))
             with obs_attr.phase("generation", phase_name):
                 fault_injector().fire("serving.decode")
+                fed = _feed_tokens()(
+                    self._no_tokens if prev is None else prev.nxt,
+                    tokens, from_prev)
+                # the table is copied: a host array handed to a
+                # dispatch may be read after the call returns, and
+                # eviction and admission rewrite `_tables` meanwhile
                 nxt, self._pool_k, self._pool_v, *counts = (
                     self._decoder.step(
                         self._states, self._pool_k, self._pool_v,
-                        self._tables, positions, tokens, seeds, temps,
-                        active))
-            with obs_attr.phase("generation", "sample"):
-                out = np.asarray(nxt)
-                self._step_counts(sp, counts)
-        self._m_ticks.inc()
-        return out
+                        self._tables.copy(), positions, fed, seeds,
+                        temps, active))
+            self._inflight = _Tick(rows, nxt, counts)
+            for seq in seqs:
+                seq.cur += 1
+            self._m_ticks.inc()
+            if prev is not None:
+                with obs_attr.phase("generation", "sample"):
+                    prev.tokens = np.asarray(prev.nxt)
+                    self._step_counts(sp, prev.counts)
+        return prev
 
     def _tick_attrs(self, sp, seqs: List[_Seq]) -> None:
-        """The scheduler's counts for one tick, on its live
-        `serving.decode_tick` span: `prefill` slots teacher-force a
-        prompt position and deliver nothing (cursor below
+        """The scheduler's counts for the tick being dispatched, on its
+        live `serving.decode_tick` span: `prefill` slots teacher-force
+        a prompt position and deliver nothing (cursor below
         prompt_len - 1), `kv_used` of `kv_total` pool blocks are
         owned."""
         sp.set_attr("prefill", sum(1 for s in seqs
@@ -936,26 +1024,60 @@ class GenerationServer:
         sp.set_attr("kv_total", self._cache.num_blocks)
 
     def _step_counts(self, sp, counts) -> None:
-        """What the step counted on the device, summed onto the live
+        """What a step counted on the device, summed onto the live
         tick span under the decoder's own names (`step_counters`:
         `moe_experts_hit`, distinct experts routed to over all layers,
         for a block with experts; nothing for one without).  Read
-        after the tokens, in the phase that has already blocked."""
+        after the tokens, in the phase that has already blocked: on
+        the pipelined path they are the counts of the tick READ, one
+        before the tick the span dispatched."""
         if sp is not None and counts:
             for name, value in zip(self._decoder.step_counters, counts):
                 sp.set_attr(name, int(np.asarray(value).sum()))
 
-    def _deliver(self, seqs: List[_Seq], nxt: np.ndarray,
-                 metrics_on: bool):
+    def _flush(self) -> None:
+        """Read and deliver the tick in flight, if any, with nothing
+        dispatched behind it (no span: a span is a dispatch)."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
+            return
+        try:
+            with obs_attr.phase("generation", "sample"):
+                tick.tokens = np.asarray(tick.nxt)
+        except Exception as e:
+            self._fail_active(e)
+            return
+        self._deliver(tick, obs_metrics.enabled())
+
+    def _fail_active(self, exc: BaseException) -> None:
+        """A tick failed, at its dispatch or (a device error under
+        asynchronous dispatch) at its read: every sequence holding a
+        slot is in a tick that is lost with it, so all are evicted and
+        fail with the error, and what is in flight is dropped unread."""
+        self._inflight = None
+        with self._lock:
+            seqs = [s for s in self._active if s is not None]
+            for seq in seqs:
+                self._evict_locked(seq)
+        now = time.perf_counter()
+        for seq in seqs:
+            self._request_span(seq, now, error=type(exc).__name__)
+            seq.stream._fail(exc)
+
+    def _deliver(self, tick: _Tick, metrics_on: bool):
         now = time.perf_counter()
         delivered = 0
         finished = []
         with obs_attr.phase("generation", "deliver"):
-            for seq in seqs:
-                tok = int(nxt[seq.slot])
-                seq.cur += 1
-                if seq.cur < seq.prompt_len:
+            for seq, slot, cur in tick.rows:
+                if seq.slot < 0:
+                    # ended by eos in the tick before this one, which
+                    # was read only after this one had gone out with
+                    # it: the extra position is computed and dropped
+                    continue
+                if cur + 1 < seq.prompt_len:
                     continue      # still prefilling: teacher-forced
+                tok = int(tick.tokens[slot])
                 seq.tokens.append(tok)
                 seq.emitted += 1
                 delivered += 1
@@ -978,6 +1100,12 @@ class GenerationServer:
                 self._lock.notify_all()
             for seq in finished:
                 self._finish_seq(seq, now, metrics_on)
+        # freshly-filled full prompt blocks become shareable once the
+        # tick that passed their end has been READ, so a block of a
+        # tick that fails is never shared (no-op once a sequence has
+        # nothing pending or was evicted)
+        for seq, _, cur in tick.rows:
+            self._cache.commit_prefix(seq, cur + 1)
 
     def _finish_seq(self, seq: _Seq, now: float, metrics_on: bool):
         """Close out a finished sequence: record the end-to-end

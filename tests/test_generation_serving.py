@@ -24,6 +24,7 @@ Pins the subsystem's contracts:
     downtime — in-process (chaos) and across SIGKILLed subprocess
     replicas driven through `cli serve` (chaos+slow).
 """
+import contextlib
 import os
 import signal
 import subprocess
@@ -378,6 +379,434 @@ def test_sampling_deterministic_per_seed_and_eos_eviction():
         assert e == g[:2]
     finally:
         srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the scheduler runs one tick ahead of the device
+# ---------------------------------------------------------------------------
+
+
+def _serial_decode(dec, states, prompt, max_new, temperature=0.0,
+                   seed=0, eos_id=None):
+    """What the serial loop computes, with no scheduler at all: one
+    sequence alone in a one-lane step, every token read on the host
+    before the next position is run."""
+    import jax
+
+    dev = jax.devices("cpu")[0]
+    need = -(-(len(prompt) + max_new - 1) // dec.block_size)
+    pool_k, pool_v = dec.init_pool(need + 1, dev)
+    tables = np.zeros((1, dec.max_blocks_per_seq), np.int32)
+    tables[0, :need] = 1 + np.arange(need)
+    g = {n: jax.device_put(np.asarray(states[n]), dev)
+         for n in dec.state_names}
+    toks, out = list(prompt), []
+    for pos in range(len(prompt) + max_new - 1):
+        nxt, pool_k, pool_v, *_ = dec.step(
+            g, pool_k, pool_v, tables, np.full(1, pos, np.int32),
+            np.array([toks[pos]], np.int32),
+            np.array([seed], np.uint32),
+            np.array([temperature], np.float32), np.ones(1, bool))
+        if pos + 1 >= len(prompt):
+            toks.append(int(np.asarray(nxt)[0]))
+            out.append(toks[-1])
+            if toks[-1] == eos_id:
+                break
+    return out
+
+
+@contextlib.contextmanager
+def _tick_spans():
+    """The `serving.decode_tick` spans' attributes, in order, of what
+    runs inside."""
+    from paddle_tpu.observability import tracing
+
+    ticks = []
+    tracing.clear()
+    tracing.set_enabled(True)
+    try:
+        yield ticks
+        ticks.extend(s["attrs"] for s in tracing.finished_spans()
+                     if s["name"] == "serving.decode_tick")
+    finally:
+        tracing.set_enabled(False)
+        tracing.clear()
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_pipelined_streams_equal_serial_decode(temperature):
+    """Tick n+1 goes out before tick n is read, with the sampled
+    tokens left on the device; every stream is still token for token
+    what one sequence decoded alone and serially gives.  Prompts of
+    different lengths, so prompt and decode slots share ticks, and
+    more requests than slots, so slots and blocks are reused."""
+    dec, states = _decoder()                     # max_len 20
+    r = np.random.RandomState(5)
+    prompts = [list(r.randint(0, V, n))
+               for n in (3, 6, 2, 5, 4, 3, 7, 1)]
+    max_news = [6, 9, 12, 4, 8, 5, 7, 3]
+    want = [_serial_decode(dec, states, p, m, temperature, seed=40 + i)
+            for i, (p, m) in enumerate(zip(prompts, max_news))]
+    assert [len(w) for w in want] == max_news
+
+    srv = GenerationServer(dec, states, slots=3, kv_blocks=15,
+                           place=fluid.CPUPlace())
+    try:
+        with _tick_spans() as ticks:
+            first = [srv.submit(p, m, temperature=temperature,
+                                seed=40 + i)
+                     for i, (p, m) in enumerate(
+                         zip(prompts[:3], max_news[:3]))]
+            while srv.stats()["generated_tokens"] == 0:
+                time.sleep(0.002)
+            rest = [srv.submit(p, m, temperature=temperature,
+                               seed=43 + i)
+                    for i, (p, m) in enumerate(
+                        zip(prompts[3:], max_news[3:]))]
+            got = [s.result(timeout=60) for s in first + rest]
+        st = srv.stats()
+    finally:
+        srv.close()
+    assert got == want
+    assert st["kv_blocks_free"] == 15 and st["requests"] == 8
+    # the pipeline was engaged, over ticks that mixed both kinds of
+    # slot, and no position was run that the serial loop does not run
+    assert sum(a["ahead"] for a in ticks) > len(ticks) // 2
+    assert any(0 < a["prefill"] < a["active"] for a in ticks)
+    assert sum(a["active"] for a in ticks) == sum(
+        len(p) + m - 1 for p, m in zip(prompts, max_news))
+
+
+def test_eos_is_found_one_position_late_and_that_position_dropped():
+    """eos is seen when tick n is read, after tick n+1 went out with
+    the sequence: the stream ends AT eos, the extra position delivers
+    nothing, the blocks are released once, and the request that gets
+    the freed slot and blocks decodes as if alone."""
+    dec, states = _decoder(block_size=4, max_blocks=4)    # max_len 16
+    a, b = [3, 1, 4], [2, 7, 1, 8, 2, 8]
+    full = _serial_decode(dec, states, a, 9)
+    eos = full[1]
+    assert eos != full[0]
+    want_b = _serial_decode(dec, states, b, 7)
+
+    srv = GenerationServer(dec, states, slots=1, kv_blocks=3,
+                           place=fluid.CPUPlace())
+    released = []
+    release = srv._cache.release
+    srv._cache.release = lambda owner: (released.append(owner),
+                                        release(owner))[1]
+    try:
+        with _tick_spans() as ticks:
+            sa = srv.submit(a, 9, eos_id=eos)
+            sb = srv.submit(b, 7)      # waits for a's slot AND blocks
+            got_a = sa.result(timeout=60)
+            got_b = sb.result(timeout=60)
+        st = srv.stats()
+    finally:
+        srv.close()
+    assert got_a == full[:2] and got_a[-1] == eos
+    assert got_b == want_b
+    assert st["generated_tokens"] == 2 + 7
+    assert st["kv_blocks_free"] == 3
+    assert len(released) == 2 and len(set(map(id, released))) == 2
+    # a ran positions 0..3 and eos came out of the fourth; position 4
+    # was already out, and is the one slot-tick the serial loop saves
+    assert st["ticks"] == (len(a) + 2 - 1) + 1 + (len(b) + 7 - 1)
+    assert sum(t["active"] for t in ticks) == st["ticks"]
+
+
+def _submit_together(srv, *requests):
+    """Streams of requests that the scheduler admits in one pass."""
+    with srv._lock:
+        return [srv.submit(p, m) for p, m in requests]
+
+
+@pytest.mark.chaos
+def test_failed_tick_fails_every_tick_in_flight_and_frees_all():
+    """An error at the dispatch of a tick (the chaos hook) loses the
+    tick before it too, still unread: every sequence holding a slot
+    fails with the error, nothing stays allocated or in flight, the
+    request spans carry the error, and the scheduler thread lives to
+    serve the next request."""
+    from paddle_tpu.core.resilience import FaultError, fault_injector
+    from paddle_tpu.observability import tracing
+
+    dec, states = _decoder()
+    inj = fault_injector()
+    inj.clear()
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    tracing.clear()
+    tracing.set_enabled(True)
+    try:
+        # ticks 1..5 go out; the 6th fails at its dispatch with the
+        # 5th unread: ticks 1..4 were delivered, the 3rd and 4th with
+        # a token for the three-token prompt
+        inj.inject("serving.decode", "error", nth=6)
+        sa, sb = _submit_together(srv, ([3, 1, 4], 14),
+                                  ([1, 5, 9, 2], 14))
+        for s in (sa, sb):
+            with pytest.raises(FaultError):
+                s.result(timeout=60)
+        assert len(sa.tokens_so_far()) == 2
+        assert len(sb.tokens_so_far()) == 1
+        st = srv.stats()
+        assert st["kv_blocks_free"] == 10
+        assert st["active_sequences"] == 0 and srv._inflight is None
+        failed = [s["attrs"] for s in tracing.finished_spans()
+                  if s["name"] == "serving.request"]
+        assert [a["error"] for a in failed] == ["FaultError"] * 2
+        assert srv.submit([2, 7], 5).result(timeout=60) == \
+            _serial_decode(dec, states, [2, 7], 5)
+    finally:
+        tracing.set_enabled(False)
+        tracing.clear()
+        inj.clear()
+        srv.close()
+
+
+@pytest.mark.chaos
+def test_block_of_a_failed_tick_is_not_shared():
+    """The tick that fills a prompt block is in flight, unread, when
+    the next dispatch fails: the block must not have become shareable
+    (the serial loop had read that tick; this one loses it)."""
+    from paddle_tpu.core.resilience import FaultError, fault_injector
+
+    dec, states = _decoder()                     # block_size 4
+    prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5]
+    inj = fault_injector()
+    inj.clear()
+    srv = GenerationServer(dec, states, slots=1, kv_blocks=5,
+                           place=fluid.CPUPlace())
+    try:
+        # ticks 0..3 go out (position 3 fills block 0); tick 4 fails
+        # at its dispatch with tick 3 still unread
+        inj.inject("serving.decode", "error", nth=5)
+        with pytest.raises(FaultError):
+            srv.submit(prompt, 4).result(timeout=60)
+        assert srv.stats()["kv_blocks_cached"] == 0
+        inj.clear()
+        again = srv.submit(prompt, 4).result(timeout=60)
+        st = srv.stats()
+    finally:
+        inj.clear()
+        srv.close()
+    assert again == _serial_decode(dec, states, prompt, 4)
+    assert st["prefix_hits"] == 0 and st["kv_blocks_free"] == 5
+
+
+def test_hot_swap_drain_and_close_with_a_tick_in_flight():
+    """Each reads what is in flight before it acts: a swap installs
+    the new parameters behind the last tick of the old ones, a drain
+    returns with every accepted stream whole, and close leaves the
+    scheduler thread ended with nothing on the device unread."""
+    dec, states = _decoder()
+    states2 = {n: v * 0.5 for n, v in states.items()}
+    prompts = [[5, 2, 8], [1, 7], [9, 9, 3, 1], [4]]
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    try:
+        # swap: the request in flight finishes on the OLD parameters
+        old = srv.submit(prompts[0], 8)
+        while not old.tokens_so_far():
+            time.sleep(0.001)
+        assert srv.swap_states(states2, wait=True, timeout=60)
+        assert srv._inflight is None
+        assert old.result(timeout=60) == _serial_decode(
+            dec, states, prompts[0], 8)
+        assert srv.generate(prompts[0], 8, timeout=60) == \
+            _serial_decode(dec, states2, prompts[0], 8)
+
+        # drain: more requests than slots, all delivered whole
+        streams = [srv.submit(p, 6, temperature=0.5, seed=i)
+                   for i, p in enumerate(prompts)]
+        assert srv.drain(wait=True, timeout=60)
+        assert all(s.done for s in streams) and srv._inflight is None
+        assert [s.result(timeout=1) for s in streams] == [
+            _serial_decode(dec, states2, p, 6, 0.5, seed=i)
+            for i, p in enumerate(prompts)]
+        assert srv.stats()["kv_blocks_free"] == 10
+        with pytest.raises(RuntimeError, match="draining"):
+            srv.submit([1], 1)
+        srv.resume()
+
+        # close mid-decode: the stream ends (whole, or failed as
+        # closed) holding a prefix of the serial tokens
+        last = srv.submit(prompts[2], 14)
+        while not last.tokens_so_far():
+            time.sleep(0.001)
+        t0 = time.monotonic()
+        srv.close()
+        assert time.monotonic() - t0 < 5
+        assert not srv._worker.is_alive() and srv._inflight is None
+        assert last.done
+        want = _serial_decode(dec, states2, prompts[2], 14)
+        got = last.tokens_so_far()
+        assert got and got == want[:len(got)]
+        if len(got) < 14:
+            with pytest.raises(RuntimeError, match="closed"):
+                last.result(timeout=1)
+    finally:
+        srv.close()
+
+
+class _DeviceError(RuntimeError):
+    pass
+
+
+def _gated_decoder(dec, read_timeout=20.0, lost=()):
+    """`dec`, except that the tokens of a step reach the host only
+    when the test releases that tick (`gates[k].set()`): reading them
+    blocks until then, as reading a step the device has not finished
+    does, and raises for a tick in `lost`, as reading a step that
+    failed on the device does.  `log` holds ("dispatch", k) and
+    ("read", k) in the order the scheduler performed them.  The select
+    that keeps tokens on the device takes the pending value as the
+    array it wraps."""
+    import jax
+
+    log, gates = [], []
+
+    @jax.tree_util.register_pytree_node_class
+    class Pending:
+        def __init__(self, value, k=None):
+            self.value, self.k = value, k
+
+        def tree_flatten(self):
+            return (self.value,), None
+
+        @classmethod
+        def tree_unflatten(cls, aux, children):
+            return cls(children[0])
+
+        def __jax_array__(self):
+            return self.value
+
+        def __array__(self, dtype=None, copy=None):
+            if not gates[self.k].wait(read_timeout):
+                log.append(("never released", self.k))
+            log.append(("read", self.k))
+            if self.k in lost:
+                raise _DeviceError("the step failed on the device")
+            return np.asarray(self.value)
+
+    class Gated:
+        armed = False
+
+        def __getattr__(self, name):
+            return getattr(dec, name)
+
+        def step(self, *args):
+            nxt, *rest = dec.step(*args)
+            if not self.armed:
+                return (nxt, *rest)
+            gates.append(threading.Event())
+            log.append(("dispatch", len(gates) - 1))
+            return (Pending(nxt, len(gates) - 1), *rest)
+
+    return Gated(), log, gates
+
+
+def _wait_for(cond, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.001)
+    return cond()
+
+
+def test_next_tick_is_dispatched_before_the_last_one_is_read():
+    """With every tick's tokens held back until the test lets them
+    go, tick n+1 is seen going out while tick n is still unread, for
+    every n: a serial loop would wait for ever.  `ahead` says so on
+    the spans: 0 on the first tick and on the first after a flush, 1
+    on every other."""
+    dec, states = _decoder()
+    gated, log, gates = _gated_decoder(dec)
+    srv = GenerationServer(gated, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    gated.armed = True
+    prompt, max_new = [3, 1, 4], 4
+    n_ticks = len(prompt) + max_new - 1
+    try:
+        with _tick_spans() as ticks:
+            for base in (0, n_ticks):    # the second after a flush
+                stream = srv.submit(prompt, max_new)
+                for k in range(base, base + n_ticks - 1):
+                    assert _wait_for(
+                        lambda: ("dispatch", k + 1) in log), (k, log)
+                    assert ("read", k) not in log
+                    gates[k].set()
+                # the last tick has none behind it: read by a flush
+                assert _wait_for(lambda: len(gates) == base + n_ticks)
+                gates[base + n_ticks - 1].set()
+                assert stream.result(timeout=30) == _serial_decode(
+                    dec, states, prompt, max_new)
+    finally:
+        for gate in gates:
+            gate.set()
+        srv.close()
+    assert not [e for e in log if e[0] == "never released"]
+    order = {e: i for i, e in enumerate(log)}
+    for k in list(range(n_ticks - 1)) + list(
+            range(n_ticks, 2 * n_ticks - 1)):
+        assert order[("dispatch", k + 1)] < order[("read", k)]
+    assert [a["ahead"] for a in ticks] == (
+        [0] + [1] * (n_ticks - 1)) * 2
+
+
+@pytest.mark.chaos
+def test_error_at_the_read_fails_both_ticks_in_flight():
+    """Under asynchronous dispatch a device error surfaces where the
+    tokens are read, by when the next tick is out as well: both are
+    lost, their sequences fail with the error and free everything."""
+    dec, states = _decoder()
+    gated, log, gates = _gated_decoder(dec, read_timeout=0.0, lost={3})
+    srv = GenerationServer(gated, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    gated.armed = True
+    try:
+        sa, sb = _submit_together(srv, ([3, 1, 4], 8), ([1, 5], 8))
+        for s in (sa, sb):
+            with pytest.raises(_DeviceError):
+                s.result(timeout=60)
+        gated.armed = False
+        st = srv.stats()
+        assert st["kv_blocks_free"] == 10 and srv._inflight is None
+        # tick 4 was out when tick 3 failed, and is never read
+        assert ("dispatch", 4) in log and ("read", 4) not in log
+        assert len(sa.tokens_so_far()) == 1     # tick 2's, of 0..2
+        assert srv.generate([2, 7], 5, timeout=60) == _serial_decode(
+            dec, states, [2, 7], 5)
+    finally:
+        srv.close()
+
+
+def test_draft_model_server_keeps_its_serial_tick():
+    """The accept rule needs the window's tokens on the host, so a
+    speculative server never has a tick in flight: its spans are the
+    speculative ones, with no `ahead`."""
+    dec, states = _decoder(block_size=4, max_blocks=4)
+    draft, dstates = _decoder(block_size=4, max_blocks=4, d_model=16,
+                              n_layers=1)
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=8,
+                           place=fluid.CPUPlace(), draft_decoder=draft,
+                           draft_states=dstates, spec_k=2)
+    seen = []
+    tick_spec = srv._tick_spec
+    srv._tick_spec = lambda seqs: (seen.append(srv._inflight),
+                                   tick_spec(seqs))[1]
+    try:
+        with _tick_spans() as ticks:
+            got = [srv.submit(p, 6).result(timeout=60)
+                   for p in ([3, 1, 4], [1, 5, 9, 2])]
+    finally:
+        srv.close()
+    assert got == [_serial_decode(dec, states, p, 6)
+                   for p in ([3, 1, 4], [1, 5, 9, 2])]
+    assert seen and all(t is None for t in seen)
+    assert ticks and all(a["speculative"] and "ahead" not in a
+                         for a in ticks)
 
 
 # ---------------------------------------------------------------------------

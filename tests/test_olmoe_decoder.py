@@ -453,8 +453,13 @@ def test_generation_server_serves_the_block_and_counts_experts():
         tracing.set_enabled(False)
         tracing.clear()
     assert batched == serve(together=False)
-    assert ticks and all(
-        L <= a["moe_experts_hit"] <= L * min(E, 3 * K) for a in ticks)
+    # the count comes back with the tokens, so it is on the span of
+    # the iteration that READ a tick: every one that ran ahead
+    assert ticks and all(("moe_experts_hit" in a) == bool(a["ahead"])
+                         for a in ticks)
+    assert any(a["ahead"] for a in ticks)
+    assert all(L <= a["moe_experts_hit"] <= L * min(E, 3 * K)
+               for a in ticks if a["ahead"])
 
 
 def test_expert_layer_lowers_for_tpu_as_three_grouped_matmuls():
