@@ -1,8 +1,8 @@
 """Static cost model over the Program IR: roofline, peak HBM, comm volume.
 
 ROADMAP item 5's kernel tier needs to know WHERE kernels pay off, and the
-only instrument so far was compile-and-measure (`benchmark/harness`
-`step_cost_analysis` — an XLA compile per question).  The Program IR
+only instrument so far was compile-and-measure (an XLA compile per
+question).  The Program IR
 already carries everything a first-order answer needs: op descs, declared
 shapes/dtypes, the PR 6 liveness machinery and the PR 9 `SpmdPlan`.  This
 module is the compile-free estimator over that information:
@@ -35,8 +35,8 @@ module is the compile-free estimator over that information:
 Byte convention: **traffic** (per-op reads + writes), the same side of
 the roofline as XLA's `bytes accessed`; both over-count what fusion
 keeps in registers, the static model more so (every op boundary counts),
-which is why `benchmark/harness.static_vs_measured` pins the
-estimated-vs-measured band instead of asserting equality.  Collective
+which is why tests/test_cost_model.py pins an estimated-vs-measured
+band instead of asserting equality.  Collective
 bytes are logical payload bytes (the operand tensor), matching the
 all-reduce operand shapes in optimized HLO.
 
@@ -87,10 +87,9 @@ _GRAD = "_grad"
 DEFAULT_BATCH = 32
 
 # device ridge points (bf16 peak FLOP/s, HBM bytes/s) — the ONE chip
-# table; benchmark/harness reads it too, so the measured roofline and
-# this compile-free estimate share every ridge point.  DEFAULT_DEVICE
-# is only the TARGET a compile-free report assumes when the caller
-# names none (`cli analyze --device`); anything that bands a RUNNING
+# table.  DEFAULT_DEVICE is only the TARGET a compile-free report
+# assumes when the caller names none (`cli analyze --device`);
+# anything that bands a RUNNING
 # device looks its kind up with `running_device_kind` and never
 # assumes these peaks
 DEVICE_SPECS: Dict[str, Tuple[float, float]] = {
@@ -1091,53 +1090,6 @@ def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
     }
 
 
-@register_serving_kernel("moe_gate_dispatch")
-def _moe_gate_dispatch_cost(spec: Dict, tokens: int = 0,
-                            num_experts: int = 0, capacity: int = 0,
-                            top_k: int = 1, **_) -> Dict:
-    """The fused MoE gate+dispatch kernel (kernels/moe_dispatch.py):
-    gate logits, softmax, top-k routing, capacity cumsum and the
-    dispatch contraction in one launch.  Emits only expert_in/combine;
-    `routing_bytes_avoided` is the [T, E]/[T, E, C] routing traffic the
-    oracle materializes in HBM between its ~15 ops."""
-    d, _, _, _, _, _, _ = _spec_dims(spec)
-    T = int(tokens or spec.get("tokens") or 0)
-    E = int(num_experts or spec.get("num_experts") or 0)
-    C = int(capacity or max(1, int(1.25 * top_k * T / max(E, 1))))
-    flops = (2.0 * T * d * E              # gate logits
-             + 2.0 * T * E * C * d * top_k)  # dispatch contraction
-    bytes_ = 4.0 * (T * d + d * E + E * C * d + T * E * C)
-    return {
-        "kernel": "moe_gate_dispatch",
-        "backend": "pallas",
-        "shapes": {"x": f"[{T}, {d}]", "gate_w": f"[{d}, {E}]",
-                   "expert_in": f"[{E}, {C}, {d}]",
-                   "combine": f"[{T}, {E}, {C}]"},
-        "flops": flops, "bytes": bytes_,
-        "routing_bytes_avoided": 4.0 * (T * E * C + 6.0 * T * E),
-        "tokens": T, "num_experts": E, "capacity": C, "top_k": top_k,
-    }
-
-
-@register_serving_kernel("fused_bucket_update")
-def _fused_bucket_update_cost(spec: Dict, numel: int = 0,
-                              n_params: int = 1, **_) -> Dict:
-    """The fused per-bucket optimizer update (kernels/fused_update.py):
-    p -= lr*g over one concatenated flat bucket — the bytes are the
-    same as the per-parameter chain (read p, read g, write p), the win
-    is `launches_replaced` dispatches collapsing into one."""
-    n = int(numel or spec.get("numel") or 0)
-    return {
-        "kernel": "fused_bucket_update",
-        "backend": "pallas",
-        "shapes": {"flat_params": f"[{n}] f32",
-                   "flat_grads": f"[{n}] f32"},
-        "flops": 2.0 * n, "bytes": 12.0 * n,
-        "launches_replaced": int(n_params),
-        "numel": n,
-    }
-
-
 @register_serving_kernel("paged_decode_step")
 def _paged_decode_step_cost(spec: Dict, slots: int = 1,
                             context: Optional[int] = None,
@@ -1153,9 +1105,8 @@ def _paged_decode_step_cost(spec: Dict, slots: int = 1,
     statically.
 
     `backend` picks the attention sub-cost: "xla" (default) is the
-    gather composition, "pallas" the fused paged-attention kernel —
-    the row then reflects what the serving-kernel tier actually
-    runs."""
+    gather composition, "pallas" the fused paged-attention kernel:
+    the row then reflects what the decoder actually runs."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
@@ -1193,21 +1144,17 @@ def _paged_decode_step_cost(spec: Dict, slots: int = 1,
 
 
 def _resolve_decode_backend(spec: Dict, kv_dtype: str) -> str:
-    """What the serving-kernel tier would actually run for this spec on
-    THIS process's platform (docs/performance.md "Serving kernels") —
-    so the analyze report's rows reflect reality, not aspiration."""
+    """What a decoder built for this spec on THIS process's platform
+    runs (docs/performance.md "Kernel selection"), so the analyze
+    report's rows reflect reality, not aspiration."""
     import jax
 
-    from ..kernels import registry as kreg
     from ..kernels.paged_attention import paged_attention_supports
 
-    platform = jax.default_backend()
-    if not kreg.kernels_armed(platform):
-        return "xla"
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     reason = paged_attention_supports(
         d_model=d, n_heads=h, block_size=bs, max_blocks_per_seq=nb,
-        kv_dtype=kv_dtype, platform=platform)
+        kv_dtype=kv_dtype, platform=jax.default_backend())
     return "xla" if reason else "pallas"
 
 

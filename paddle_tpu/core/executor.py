@@ -36,7 +36,7 @@ from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
 from .compile_cache import compile_cache_dir
 from .execution import DictEnv, ExecContext, ScopeEnv, run_op
-from .flags import get_flag
+from .flags import get_flag, trace_flags
 from .framework import Program, Variable, default_main_program
 from .lod import LoDTensor
 from .scope import Scope
@@ -728,11 +728,7 @@ class Executor:
         cache_key = (
             fp, "seg", seg_idx,
             tuple((n, _aval_key(v)) for n, v in sorted(in_vals.items())),
-            get_flag("amp_bf16"),  # amp changes traced compute dtypes
-            get_flag("conv_layout"),  # changes the traced conv layout
-            get_flag("flash_min_seq_k"),  # changes the traced attn path
-            get_flag("flash_pack_heads"),  # changes the traced kernel
-            get_flag("flash_block_q"), get_flag("flash_block_k"),
+            trace_flags(),
         )
         fn = self._cache.get(cache_key)
         miss = fn is None
@@ -848,11 +844,7 @@ class Executor:
                 tuple(fetch_names),
                 str(device),
                 don_names,  # donation is baked into the executable
-                get_flag("amp_bf16"),  # amp changes traced compute dtypes
-                get_flag("conv_layout"),  # changes the traced conv layout
-                get_flag("flash_min_seq_k"),  # changes the traced attn path
-                get_flag("flash_pack_heads"),  # changes the traced kernel
-                get_flag("flash_block_q"), get_flag("flash_block_k"),
+                trace_flags(),
             )
             fn = self._cache.get(cache_key)
             miss = fn is None
@@ -941,7 +933,7 @@ def program_to_fn(program: Program, feed_names, fetch_names, block_idx=0):
     fn.state_in_names = state_in
     fn.state_out_names = state_out
     # liveness donation plan for callers that jit this fn themselves
-    # (benchmark/harness.py, parallel.ParallelExecutor): which feed
+    # (parallel.ParallelExecutor, perf/rehearse_compile.py): which feed
     # buffers die inside the step, and therefore may ride donate_argnums
     from ..memory_optimization_transpiler import plan_donation
 

@@ -58,6 +58,25 @@ def flag_defaults():
     return {k: d for k, (d, _) in _DEFS.items()}
 
 
+# The flags read while a step is traced or built.  Flipping one leaves
+# the input avals as they were, so an executable made under one set of
+# values would silently serve another: Executor puts trace_flags() in
+# both of its cache keys, ParallelExecutor and PipelineExecutor rebuild
+# their jitted step when it changes.  A flag that changes the trace is
+# added HERE and nowhere else (tests/test_trace_flags.py flips each).
+TRACE_FLAGS = ("amp_bf16", "flash_min_seq_k")
+# read when the parallel executors build their step: feed donation and
+# the overlap step's gradient buckets (the serial Executor keys on the
+# donated names themselves)
+PARALLEL_TRACE_FLAGS = TRACE_FLAGS + ("memory_optimize",
+                                      "overlap_bucket_bytes")
+
+
+def trace_flags(parallel: bool = False) -> tuple:
+    names = PARALLEL_TRACE_FLAGS if parallel else TRACE_FLAGS
+    return tuple(_VALUES[n] for n in names)
+
+
 # -- the reference's executor/debug flags -----------------------------------
 define_flag("check_nan_inf", False,
             "scan every op output for nan/inf in interpreter mode "
@@ -76,16 +95,8 @@ define_flag("flash_min_seq_k", -1,
             "attention, but in a full training step it materializes "
             "scores+probs (f32 after the softmax upcast) for backward — "
             "at large d_model that dominates HBM traffic and memory, so "
-            "training benches force the kernel (run_ridge.py).  Read at "
-            "TRACE time: Executor caches key on it like amp_bf16")
-define_flag("flash_block_q", -1,
-            "override the flash kernel's shape-keyed Q block size "
-            "(-1 = the measured table in kernels/flash_attention."
-            "_select_blocks); tuning/benchmark hook, read at TRACE time")
-define_flag("flash_block_k", -1,
-            "override the flash kernel's shape-keyed K block size "
-            "(-1 = the measured table); tuning/benchmark hook, read at "
-            "TRACE time")
+            "training runs force the kernel.  Read at TRACE time: one of "
+            "trace_flags()")
 define_flag("log_recompiles", False,
             "warn (RuntimeWarning) whenever the Executor misses its "
             "executable cache for a program that already reached "
@@ -174,15 +185,6 @@ define_flag("remat", False,
             "backward instead of living in HBM — the bytes-for-FLOPs "
             "trade of Chen et al. (sublinear memory cost).  Read at "
             "BUILD time (program construction), not trace time")
-define_flag("conv_layout", "",
-            "opt-in conv layout override, read at TRACE time: 'NHWC' "
-            "runs every NCHW-declared conv2d channels-last inside the "
-            "lowering (transpose in, NHWC conv, transpose out — XLA "
-            "cancels adjacent pairs between consecutive convs), the "
-            "TPU's native vector-lane layout.  '' (default) keeps each "
-            "op's declared data_format.  Executor cache keys include "
-            "it like amp_bf16; combine with amp_bf16 for the "
-            "bf16-native NHWC path")
 define_flag("jit_granularity", "block",
             "how much program one executable covers: 'block' (default) "
             "traces whole block 0 into one XLA program; 'segment' "
@@ -204,21 +206,6 @@ define_flag("serving_kv_dtype", "",
             "same HBM budget holds ~2x the sequences K+V vs bf16 and "
             "~4x vs fp32).  Read at BUILD time; the model-dir spec's "
             "kv_dtype and explicit builder/server args override it")
-define_flag("serving_kernels", "auto",
-            "Pallas serving-kernel tier selection "
-            "(docs/performance.md 'Serving kernels'): 'auto' (default) "
-            "arms the paged-attention decode / fused MoE dispatch / "
-            "fused bucket-update kernels on TPU backends only; 'on' "
-            "arms everywhere (non-TPU backends run them under Pallas "
-            "interpret mode — a correctness harness, not a fast "
-            "path); 'off' keeps the XLA oracle path.  Env alias "
-            "PADDLE_TPU_SERVING_KERNELS.  Armed-but-unsupported "
-            "shape/dtype/platform combinations fall back to the "
-            "oracle per op, silently but counted "
-            "(paddle_tpu_kernel_fallbacks_total{kernel,reason}).  "
-            "Read at BUILD time by build_lm_paged_decoder (like "
-            "serving_kv_dtype) and at TRACE time by "
-            "ParallelExecutor/moe_dense")
 define_flag("serving_spec_k", 4,
             "default speculative-decoding draft length: how many "
             "tokens the draft model proposes per scheduler tick for "
@@ -229,13 +216,3 @@ define_flag("serving_spec_k", 4,
             "dir with draft params); greedy outputs stay bit-identical "
             "for any k — k trades verify-step width against accept "
             "probability per window")
-define_flag("flash_pack_heads", True,
-            "fold head PAIRS into the 128-lane dim inside the flash "
-            "kernel when head_dim == 64 (and the head count is even): "
-            "loads/stores then move full-lane [block, 128] tiles and "
-            "the online softmax runs per packed head on block-diagonal "
-            "scores.  Measured step-level NEUTRAL on v5e (r5, "
-            "RIDGE_r05.json): the d_head-64 penalty is the MXU "
-            "contraction width of the per-head matmuls, which packing "
-            "loads cannot fix — prefer d_head 128 architecturally.  "
-            "Read at TRACE time like flash_min_seq_k")

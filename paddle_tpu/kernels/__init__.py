@@ -5,22 +5,10 @@ operators/math/*.cu); here XLA fusion covers most of that ground, and Pallas
 covers what fusion cannot: the attention inner loop (flash attention — the
 reference has no attention kernel at all, SURVEY.md §5.7) where materializing
 the [q, k] score matrix in HBM is the bandwidth bottleneck.
-The serving tier (registry.py) extends that to the static analyzer's
-memory-bound worklist: paged-attention decode with in-kernel block-table
-reads and fused dequant, fused MoE gate+dispatch, and the fused per-bucket
-optimizer update — all selected behind the `serving_kernels` flag with
-per-op fallback to the XLA oracle path (docs/performance.md).
+Paged-attention decode (in-kernel block-table reads and fused dequant)
+is the serving path's kernel.  Each kernel's module decides from the
+shapes, dtypes and platform it is called with whether the Pallas kernel
+or the XLA composition runs (docs/performance.md "Kernel selection").
 """
 from .flash_attention import flash_attention, flash_attention_reference  # noqa: F401
-from .registry import (  # noqa: F401
-    FALLBACK_METRIC,
-    Selection,
-    interpret_mode,
-    kernels_armed,
-    kernels_mode,
-    register_kernel,
-    select,
-)
-from .paged_attention import build_paged_attention, paged_attention_supports  # noqa: F401
-from .moe_dispatch import build_moe_gate_dispatch, moe_dispatch_supports  # noqa: F401
-from .fused_update import build_fused_bucket_update, fused_update_supports  # noqa: F401
+from .paged_attention import paged_attention_supports, select_paged_attention  # noqa: F401

@@ -429,7 +429,7 @@ def flash_attention_reference(q, k, v, causal=False, scale=None):
 # faster than the Pallas kernel on v5e (the S^2 matrix still fits cache-
 # friendly tiles and XLA's single fusion beats the grid-loop overhead);
 # above it the kernel wins and keeps winning as S^2 grows (1.5-2.3x at
-# 4k-8k, and 32k+ only runs at all on the kernel) — run_attention.py
+# 4k-8k, and 32k+ only runs at all on the kernel: benchmark/README.md)
 MIN_PALLAS_SEQ_K = 2048
 
 
@@ -461,12 +461,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     """
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    from ..core.flags import get_flag
     sel_q, sel_k = _select_blocks(sq, sk, d)
-    if int(get_flag("flash_block_q")) > 0:
-        sel_q = int(get_flag("flash_block_q"))
-    if int(get_flag("flash_block_k")) > 0:
-        sel_k = int(get_flag("flash_block_k"))
     block_q = sel_q if block_q is None else block_q
     block_k = sel_k if block_k is None else block_k
     block_q = min(block_q, sq)
@@ -500,9 +495,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
     # head-pair packing: at d_head 64 the [block, d] tiles fill half the
     # 128-lane dim; folding two heads side-by-side ([b*h/2, s, 128])
     # fills the lanes for every load/store while the per-head score
-    # tiles stay block-diagonal inside the kernel (flash_pack_heads)
-    pack = 2 if (d == 64 and h % 2 == 0
-                 and bool(get_flag("flash_pack_heads"))) else 1
+    # tiles stay block-diagonal inside the kernel
+    pack = 2 if d == 64 and h % 2 == 0 else 1
 
     def fold(x, s_len):
         # ADJACENT heads pair up by a pure reshape ((h, d) dims are

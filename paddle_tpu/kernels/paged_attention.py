@@ -20,7 +20,7 @@ the oracle's QK^T, -inf mask, jax.nn.softmax and att@V with f32
 accumulation, one head at a time as 2-D contractions over lane-aligned
 column bands (the forms Mosaic lowers).  Under Pallas interpret mode on
 CPU greedy decode through it is token-identical to the XLA paged path
-for fp32/bf16/int8 (tests/test_serving_kernels.py); on a TPU the MXU's
+for fp32/bf16/int8 (tests/test_paged_attention.py); on a TPU the MXU's
 f32 passes differ from XLA's default-precision einsum, so the on-chip
 gate is a logit tolerance (chip_smoke.py serve_lm).
 
@@ -31,29 +31,26 @@ chunked prefill ride the same kernel as single-token decode.  The
 window is padded to whole 8-row sublane tiles, so W=1 decode and a
 draft window share one code path.
 
-Selection and fallback accounting live in kernels/registry.py
-("paged_attention_decode"); unsupported shape/dtype/platform
-combinations route back to the oracle, counted.
+`select_paged_attention` is the one entry point: from the decoder's
+geometry and the platform it is built for it returns the kernel, or
+None and the reason the XLA gather path runs instead.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .registry import register_kernel
-
-__all__ = ["paged_attention_supports", "build_paged_attention"]
+__all__ = ["paged_attention_supports", "select_paged_attention"]
 
 # VMEM budget for the per-slot K+V logical-context scratch: past this
 # the context must be tiled with an online softmax, which trades away
-# the oracle's exact math — out of scope for the serving tier, so the
-# registry falls back instead
+# the oracle's exact math, so such a context is refused instead
 _SCRATCH_BUDGET_BYTES = 8 * 1024 * 1024
 
 _KV_DTYPES = ("fp32", "bf16", "int8")
@@ -65,11 +62,15 @@ _WINDOW_ALIGN = 8
 
 def paged_attention_supports(*, d_model: int, n_heads: int,
                              block_size: int, max_blocks_per_seq: int,
-                             kv_dtype: str, window: int = 1,
-                             platform: str = "cpu",
-                             **_) -> Optional[str]:
-    """None when the decode shape runs on the Pallas path, else a short
-    fallback reason (the {kernel,reason} counter label)."""
+                             kv_dtype: str, platform: str,
+                             interpret: bool = False) -> Optional[str]:
+    """None when `select_paged_attention` would return the kernel for
+    this geometry on `platform`, else the short reason it is refused
+    (what `decoder.kernels` reports after "xla:").  Off a TPU there is
+    no Mosaic compiler: refused unless `interpret` (tests) asks for the
+    Pallas interpreter, a correctness harness and never a fast path."""
+    if platform != "tpu" and not interpret:
+        return "not_tpu"
     if kv_dtype not in _KV_DTYPES:
         return "kv_dtype"
     if d_model % n_heads:
@@ -77,8 +78,6 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
     ctx = max_blocks_per_seq * block_size
     if 2 * ctx * d_model * 4 > _SCRATCH_BUDGET_BYTES:
         return "vmem_scratch"
-    if int(window) < 1:
-        return "window"
     if platform == "tpu":
         # Mosaic tiling: last dim on the 128-lane grid, K/V block rows
         # on the 8-sublane grid; the per-head slice must stay
@@ -141,19 +140,28 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, *refs, nb, bs, n_heads,
                 preferred_element_type=jnp.float32)
 
 
-@register_kernel("paged_attention_decode", paged_attention_supports)
-def build_paged_attention(*, d_model: int, n_heads: int,
-                          block_size: int, max_blocks_per_seq: int,
-                          kv_dtype: str, window: int = 1,
-                          interpret: bool = False, platform: str = "cpu",
-                          **_):
-    """-> attend(q, pool_k, pool_v, tables, positions, layer) where
-    q is [S, W, d_model] f32 (the window W is taken from q's shape at
-    trace time — the single-token step passes W=1, speculative verify
-    its draft window), pools are the paged decoder's layer-major pool
+def select_paged_attention(
+        *, d_model: int, n_heads: int, block_size: int,
+        max_blocks_per_seq: int, kv_dtype: str, platform: str,
+        interpret: bool = False,
+) -> Tuple[Optional[Callable], Optional[str]]:
+    """-> (attend, None), or (None, reason) where
+    `paged_attention_supports` refuses: the caller then keeps its XLA
+    gather path.  A function of the geometry and the platform alone.
+
+    attend(q, pool_k, pool_v, tables, positions, layer): q is
+    [S, W, d_model] f32 (the window W is taken from q's shape at trace
+    time: the single-token step passes W=1, speculative verify its
+    draft window), pools are the paged decoder's layer-major pool
     pytrees, and the result is the pre-output-projection context
-    [S, W, d_model] f32 — a drop-in for the oracle's
-    gather/einsum/softmax block."""
+    [S, W, d_model] f32, a drop-in for the gather/einsum/softmax
+    block."""
+    reason = paged_attention_supports(
+        d_model=d_model, n_heads=n_heads, block_size=block_size,
+        max_blocks_per_seq=max_blocks_per_seq, kv_dtype=kv_dtype,
+        platform=platform, interpret=interpret)
+    if reason is not None:
+        return None, reason
     nb, bs = int(max_blocks_per_seq), int(block_size)
     d_head = d_model // n_heads
     scale = 1.0 / math.sqrt(d_head)
@@ -201,4 +209,4 @@ def build_paged_attention(*, d_model: int, n_heads: int,
         )(tables, positions, q, pool_k, pool_v, *scales)
         return out[:, :w_n]
 
-    return attend
+    return attend, None

@@ -613,20 +613,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         raise ValueError(
             f"kv_dtype {kv_dtype!r} not in ('fp32', 'bf16', 'int8')")
 
-    # serving-kernel selection, read at BUILD time like kv_dtype: when
-    # armed and supported, attention reads K/V straight through the
-    # block table inside the Pallas kernel (fused dequant, no
-    # logical-order gather copy); otherwise the XLA gather below stays
-    # the oracle (docs/performance.md "Serving kernels")
-    from ..kernels import registry as _kernel_registry
+    # the kernel's own module decides, from this geometry and the
+    # platform, whether attention reads K/V straight through the block
+    # table inside the Pallas kernel (fused dequant, no logical-order
+    # gather copy) or the XLA gather below runs
+    # (docs/performance.md "Kernel selection")
+    from ..kernels import paged_attention as _paged_attention
 
     platform = platform or jax.default_backend()
-    kernel_selection = _kernel_registry.Selection()
-    _attend = kernel_selection.pick(
-        "paged_attention_decode", d_model=d_model, n_heads=n_heads,
-        block_size=int(block_size),
-        max_blocks_per_seq=int(max_blocks_per_seq), kv_dtype=kv_dtype,
-        platform=platform)
+    _attend, _refused = _paged_attention.select_paged_attention(
+        d_model=d_model, n_heads=n_heads, block_size=bs,
+        max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform)
 
     spec = lm_block.OPT if block is None else block
     if not isinstance(spec, lm_block.BlockSpec):
@@ -867,7 +864,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             if _attend is not None:
                 # Pallas path: block-table reads + dequant + attention
                 # in one kernel; bit-identical to the gather branch
-                # (tests/test_serving_kernels.py)
+                # (tests/test_paged_attention.py)
                 with scope("attention"):
                     ctx_av = _attend(q[:, None, :], pool_k, pool_v,
                                      tables, positions, l)[:, 0]
@@ -1016,8 +1013,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,
         bytes_per_block=bytes_per_block,
-        kernel_selection=kernel_selection,
-        kernels=dict(kernel_selection.chosen))
+        kernels={"paged_attention_decode":
+                 "pallas" if _attend is not None else f"xla:{_refused}"})
     return startup, decoder
 
 

@@ -19,7 +19,6 @@ import numpy as np
 
 from ..amp import amp_cast
 from ..core.execution import data_of, one
-from ..core.flags import get_flag
 from ..core.registry import register_op
 
 
@@ -37,25 +36,13 @@ def _pair(v, n=2):
 def conv2d(ctx, ins, attrs):
     """data_format "NHWC" keeps activations channels-last — the TPU's
     native conv layout (vector lanes = channels); weights stay OIHW at the
-    IR level either way (lax handles the rhs spec).
-
-    The `conv_layout` flag (PADDLE_TPU_CONV_LAYOUT=NHWC, trace-time)
-    opt-in overrides NCHW-declared convs to run channels-last inside the
-    lowering: transpose in, NHWC conv, transpose out.  XLA cancels the
-    adjacent transpose pairs between consecutive convs, so a whole conv
-    trunk runs natively channels-last without touching the program IR —
-    the layout half of the memory knobs (docs/performance.md 'Memory');
-    combine with amp_bf16 for the bf16-native NHWC path."""
+    IR level either way (lax handles the rhs spec)."""
     x = data_of(one(ins, "Input"))        # [N, C, H, W] or [N, H, W, C]
     w = data_of(one(ins, "Filter"))       # [M, C/groups, kh, kw]
     x, w = amp_cast(x, w)
     s, p, d = (_pair(attrs["strides"]), _pair(attrs["paddings"]),
                _pair(attrs["dilations"]))
     df = attrs.get("data_format", "NCHW")
-    relayout = (df == "NCHW" and x.ndim == 4
-                and str(get_flag("conv_layout")).upper() == "NHWC")
-    if relayout:
-        x, df = jnp.transpose(x, (0, 2, 3, 1)), "NHWC"
     out = jax.lax.conv_general_dilated(
         x, w, window_strides=s,
         padding=[(p[0], p[0]), (p[1], p[1])],
@@ -64,8 +51,6 @@ def conv2d(ctx, ins, attrs):
         feature_group_count=int(attrs.get("groups") or 1),
         preferred_element_type=jnp.float32
         if x.dtype == jnp.float32 else None)
-    if relayout:
-        out = jnp.transpose(out, (0, 3, 1, 2))
     return {"Output": out.astype(x.dtype)}
 
 

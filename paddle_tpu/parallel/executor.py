@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import profiler
 from ..core.executor import CPUPlace, Executor, program_to_fn
+from ..core.flags import get_flag, trace_flags
 from ..core.framework import Variable, default_startup_program
 from ..core.scope import Scope
 from ..observability import metrics as obs_metrics
@@ -47,25 +48,6 @@ _M_RUN_SECONDS = obs_metrics.histogram(
     "paddle_tpu_executor_run_seconds",
     "Executor.run wall latency by execution mode", ("exe", "mode"))
 
-
-def _amp_enabled() -> bool:
-    from ..amp import is_bf16_enabled
-    return is_bf16_enabled()
-
-
-def _trace_flags() -> tuple:
-    """Snapshot of every flag read at TRACE time by op lowerings (plus
-    memory_optimize, which decides feed donation, and
-    overlap_bucket_bytes, which shapes the overlap step's grad buckets
-    — both part of the built executable); a jit built under one
-    snapshot must not serve another."""
-    from ..core.flags import get_flag
-    return (_amp_enabled(), get_flag("flash_min_seq_k"),
-            get_flag("flash_pack_heads"), get_flag("flash_block_q"),
-            get_flag("flash_block_k"), get_flag("conv_layout"),
-            get_flag("memory_optimize"),
-            get_flag("overlap_bucket_bytes"),
-            get_flag("serving_kernels"))
 
 __all__ = ["ParallelExecutor", "DistributeTranspiler",
            "SimpleDistributeTranspiler", "ShardingTranspiler"]
@@ -204,13 +186,6 @@ class ParallelExecutor(ShardedCheckpointMixin):
         self.overlap_info = {"mode": "off",
                              "reason": "overlap='off' requested"}
         self._overlap_cfg = None
-        # serving-kernel tier (docs/performance.md "Serving kernels"):
-        # one Selection per executor so fallback series are reclaimed
-        # on close; consulted by _make_overlap_step for the fused
-        # per-bucket optimizer update
-        from ..kernels import registry as _kernel_registry
-
-        self._kernel_selection = _kernel_registry.Selection()
         if overlap != "off":
             cfg, reason = self._analyze_overlap(program, blk)
             if cfg is None:
@@ -224,7 +199,7 @@ class ParallelExecutor(ShardedCheckpointMixin):
                 self.overlap_info = {"mode": "bucketed"}
         self._jit_step = self._make_jit_step()
         self._hlo_registered = None    # the jit step hlo_scopes() knows
-        self._trace_flags_state = _trace_flags()
+        self._trace_flags_state = trace_flags(parallel=True)
 
     def _make_jit_step(self):
         # donation plan (memory_optimization_transpiler via
@@ -234,8 +209,6 @@ class ParallelExecutor(ShardedCheckpointMixin):
         # freshly device_put from host in `run`) join under the
         # memory_optimize flag when the plan covers every feed — jit
         # donation is per-argument, and a fetched feed must survive.
-        from ..core.flags import get_flag
-
         donate = [1]
         plan = self._fn.donation_plan
         if get_flag("memory_optimize") and \
@@ -441,7 +414,6 @@ class ParallelExecutor(ShardedCheckpointMixin):
 
     def _make_overlap_step(self, donate):
         from ..core.execution import DictEnv, ExecContext, run_op
-        from ..core.flags import get_flag
         from .mesh import shard_map
         import jax.numpy as jnp
 
@@ -472,13 +444,9 @@ class ParallelExecutor(ShardedCheckpointMixin):
             cur_dt, cur_bytes = dtype, cur_bytes + nbytes
         if cur:
             buckets.append(tuple(cur))
-        fused_plan = self._plan_fused_update(buckets, update_ops)
         self.overlap_info.update(
             buckets=len(buckets), grads=len(cfg["grad_meta"]),
-            split=cfg["split"],
-            update=("fused" if fused_plan is not None else
-                    self._kernel_selection.chosen.get(
-                        "fused_bucket_update", "per_op")))
+            split=cfg["split"])
 
         feed_in_specs = {n: P(dp_ax) for n in self.feed_names}
         state_in_specs = {n: P() for n in inside_state}
@@ -526,28 +494,6 @@ class ParallelExecutor(ShardedCheckpointMixin):
             fet, grads = sharded(
                 feeds, {n: states[n] for n in inside_state},
                 jax.random.key_data(key))
-            if fused_plan is not None:
-                # fused per-bucket update: ONE Pallas launch applies a
-                # whole bucket's p -= lr*g over the concatenated flat
-                # views instead of the per-parameter sgd op chain
-                new_states = dict(states)
-                for entries, lr_name, kern in fused_plan:
-                    flat_p = jnp.concatenate(
-                        [jnp.ravel(states[p]) for p, _, _ in entries]) \
-                        if len(entries) > 1 \
-                        else jnp.ravel(states[entries[0][0]])
-                    flat_g = jnp.concatenate(
-                        [jnp.ravel(grads[g]) for _, g, _ in entries]) \
-                        if len(entries) > 1 \
-                        else jnp.ravel(grads[entries[0][1]])
-                    new_flat = kern(flat_p, flat_g, states[lr_name])
-                    off = 0
-                    for p, _, shape in entries:
-                        size = int(np.prod(shape, dtype=np.int64))
-                        new_states[p] = \
-                            new_flat[off:off + size].reshape(shape)
-                        off += size
-                return {n: fet[n] for n in fetch_names}, new_states
             env = DictEnv({**states, **grads})
             ctx = ExecContext(jax.random.fold_in(key, 1), compiled=True)
             for op in update_ops:
@@ -561,80 +507,14 @@ class ParallelExecutor(ShardedCheckpointMixin):
             donate_argnums=donate,
         )
 
-    def _plan_fused_update(self, buckets, update_ops):
-        """Map the overlap buckets onto the fused Pallas bucket update
-        (docs/performance.md "Serving kernels"): one kernel per bucket
-        replaces the per-parameter sgd op chain WHEN the chain's shape
-        allows it — every update op a plain dense `sgd` writing its
-        param in place, fed the raw reduced bucket grad, all params of
-        a bucket sharing one learning-rate scalar.  Anything fancier
-        (momentum/adam, clipping chains, per-param LR) keeps the op
-        chain, counted through the fallback registry.
-
-        Returns [(entries, lr_name, kern)] with entries
-        [(param, grad, shape)] in bucket order, or None."""
-        structure = None
-        grad_to_op = {}
-        for op in update_ops:
-            if op.type != "sgd":
-                structure = "op_mix"
-                break
-            ps, gs = op.input("Param"), op.input("Grad")
-            ls, pouts = op.input("LearningRate"), op.output("ParamOut")
-            if len(ps) != 1 or len(gs) != 1 or len(ls) != 1 \
-                    or pouts != ps:
-                structure = "op_shape"
-                break
-            grad_to_op[gs[0]] = (ps[0], ls[0])
-
-        plan = []
-        if structure is None:
-            for bucket in buckets:
-                entries, lr_names = [], set()
-                for g, shape, dtype in bucket:
-                    if g not in grad_to_op:
-                        # the op chain reads something other than the
-                        # raw reduced grad (e.g. a clip rewrote it)
-                        structure = "clipped_grads"
-                        break
-                    pname, lr_name = grad_to_op[g]
-                    entries.append((pname, g, shape))
-                    lr_names.add(lr_name)
-                if structure is not None:
-                    break
-                if len(lr_names) != 1:
-                    structure = "lr_mismatch"
-                    break
-                lr_name = lr_names.pop()
-                if lr_name not in self._states:
-                    structure = "lr_missing"
-                    break
-                numel = int(sum(np.prod(s, dtype=np.int64)
-                                for _, _, s in entries))
-                kern = self._kernel_selection.pick(
-                    "fused_bucket_update", numel=numel,
-                    dtype=str(bucket[0][2]))
-                if kern is None:
-                    return None
-                plan.append((tuple(entries), lr_name, kern))
-            if structure is None:
-                return plan
-
-        # chain shape ruled the fusion out: route the verdict through
-        # the registry so it is counted (when armed) like any other
-        # unsupported combination
-        self._kernel_selection.pick("fused_bucket_update", numel=0,
-                                    structure=structure)
-        return None
-
     def _refresh_trace_flags(self):
-        # trace-time flags (amp_bf16, flash_min_seq_k) are read inside op
-        # lowerings; identical input avals would silently reuse an
-        # executable traced under the old flag state, so any flip gets a
-        # fresh jit cache (serial Executor: same flags in its cache keys)
-        if _trace_flags() != self._trace_flags_state:
+        # a flip of a flag read while tracing or building the step
+        # leaves the input avals as they were: rebuild, or the old
+        # executable serves the new values (core.flags.trace_flags)
+        flags = trace_flags(parallel=True)
+        if flags != self._trace_flags_state:
             self._jit_step = self._make_jit_step()
-            self._trace_flags_state = _trace_flags()
+            self._trace_flags_state = flags
 
     # -- sharding policy -----------------------------------------------------
     def _spec_for(self, name, val, param_names, param_shardings,
@@ -710,16 +590,13 @@ class ParallelExecutor(ShardedCheckpointMixin):
         metrics dump without bound).  The executor stays usable."""
         if hasattr(self, "_m_run"):
             _M_RUN_SECONDS.remove(exe=self._m_run_id, mode="parallel")
-        if hasattr(self, "_kernel_selection"):
-            self._kernel_selection.close()
 
     def compiled_collectives(self, feed: Dict,
                              optimized: bool = True) -> Dict[str, int]:
         """Counts of cross-device collective ops in the train step
         compiled for `feed`'s shapes — pins the communication STRUCTURE
         of a mesh without the hardware (e.g. dp-N must show grad
-        all-reduces and nothing else; run_scaling.py --virtual reports
-        this per N alongside the no-op virtual throughput).
+        all-reduces and nothing else).
 
         `optimized=True` counts the optimized HLO, i.e. what runs — XLA's
         all-reduce combiner may have merged neighbouring reductions

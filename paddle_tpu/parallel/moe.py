@@ -109,37 +109,16 @@ def _expert_mm(inp, wi, wo, activation):
 
 def moe_dense(x, gate_w, w_in, w_out, capacity_factor: float = 1.25,
               top_k: int = 1, activation=jax.nn.relu,
-              capacity: int = None,
-              selection=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              capacity: int = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mesh-free MoE FFN: the math every parallel form implements.
-    x: [T, D]; returns (y [T, D], aux_loss).
-
-    When the serving-kernel tier is armed (docs/performance.md
-    "Serving kernels"), gate + capacity dispatch run as ONE Pallas
-    kernel — same math, no [T, E, C] dispatch tensor in HBM;
-    `selection` takes an existing kernels.registry.Selection for
-    fallback-series ownership (defaults to a one-off pick)."""
+    x: [T, D]; returns (y [T, D], aux_loss)."""
     E = gate_w.shape[1]
     T = x.shape[0]
     if capacity is None:
         capacity = _capacity(T, E, capacity_factor, top_k)
-
-    from ..kernels import registry as _kernel_registry
-
-    picker = selection if selection is not None \
-        else _kernel_registry.Selection()
-    fused = picker.pick(
-        "moe_gate_dispatch", tokens=int(T), d_model=int(x.shape[1]),
-        num_experts=int(E), capacity=int(capacity), top_k=int(top_k),
-        dtype=str(x.dtype))
-    if fused is not None:
-        expert_in_f, combine, aux2 = fused(x, gate_w)
-        expert_in = expert_in_f.astype(x.dtype)
-        aux = aux2[0, 0]
-    else:
-        dispatch, combine, aux = moe_gate(x, gate_w, E, capacity, top_k)
-        expert_in = jnp.einsum("td,tec->ecd", x.astype(jnp.float32),
-                               dispatch).astype(x.dtype)
+    dispatch, combine, aux = moe_gate(x, gate_w, E, capacity, top_k)
+    expert_in = jnp.einsum("td,tec->ecd", x.astype(jnp.float32),
+                           dispatch).astype(x.dtype)
     expert_out = _expert_mm(expert_in, w_in, w_out, activation)
     y = jnp.einsum("ecd,tec->td", expert_out.astype(jnp.float32),
                    combine).astype(x.dtype)

@@ -89,9 +89,9 @@ from ..core.execution import DictEnv, ExecContext, run_op
 from ..core.framework import (GRAD_SUFFIX, Parameter, Variable,
                               default_startup_program, grad_var_name)
 from ..core.executor import CPUPlace, Executor
+from ..core.flags import trace_flags
 from ..core.scope import Scope
 from .checkpoint import ShardedCheckpointMixin
-from .executor import _trace_flags
 from .mesh import count_collectives, make_mesh
 from .pipeline import microbatch, spmd_pipeline, unmicrobatch
 
@@ -199,7 +199,7 @@ class PipelineExecutor(ShardedCheckpointMixin):
         self._init_states(scope, shard_optimizer_states)
 
         self._jit_step = self._make_jit_step()
-        self._trace_flags_state = _trace_flags()
+        self._trace_flags_state = trace_flags(parallel=True)
 
     # ------------------------------------------------------------------
     # program partitioning
@@ -1008,11 +1008,11 @@ class PipelineExecutor(ShardedCheckpointMixin):
                        donate_argnums=(1,))
 
     def _refresh_trace_flags(self):
-        # see parallel/executor.py:_refresh_trace_flags — amp_bf16 and
-        # flash_min_seq_k are read at trace time
-        if _trace_flags() != self._trace_flags_state:
+        # see parallel/executor.py:_refresh_trace_flags
+        flags = trace_flags(parallel=True)
+        if flags != self._trace_flags_state:
             self._jit_step = self._make_jit_step()
-            self._trace_flags_state = _trace_flags()
+            self._trace_flags_state = flags
 
     # ------------------------------------------------------------------
     # public API

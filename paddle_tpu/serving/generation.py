@@ -36,7 +36,7 @@ schedule:
 
 `static_batch=True` degrades the scheduler to the drain-then-refill
 baseline (admit only into an EMPTY active set) — same compiled step,
-same numerics — which is what benchmark/run_serving.py measures the
+same numerics: what tests/test_generation_serving.py measures the
 continuous schedule against.
 
 On top of the PR 8 substrate ride the two algorithmic serving
@@ -799,12 +799,6 @@ class GenerationServer:
             fam.remove(server=self._sid)
         for reason in ("saturated", "deadline"):
             _M_SHED.remove(server=self._sid, reason=reason)
-        # serving-kernel fallback series counted by this server's
-        # decoders (kernels/registry.py Selection contract)
-        for dec in (self._decoder, self._draft):
-            sel = getattr(dec, "kernel_selection", None)
-            if sel is not None:
-                sel.close()
 
     # -- scheduler ----------------------------------------------------------
     def _shed_expired_locked(self, now: float) -> List[_Seq]:
@@ -1486,10 +1480,10 @@ def _publish_static_decode_floor(spec: dict, server: GenerationServer):
         return
     step = analyze_generation_spec(
         spec, slots=server._slots, device=kind)["kernels"][0]
-    # band against the backend the DECODER actually selected (the
-    # registry's spec-level resolution can disagree with a build
-    # that fell back on shape) — the calibration ratio must compare
-    # measured time to the floor of what runs, not of the oracle
+    # band against the backend the DECODER was built with (the
+    # analyzer resolves for this process's platform, the decoder for
+    # its own): the calibration ratio must compare measured time to
+    # the floor of what runs
     backend = ("pallas" if server._decoder.kernels.get(
         "paged_attention_decode") == "pallas" else "xla")
     if step.get("backend") != backend:

@@ -709,9 +709,9 @@ def test_autoscale_ramp_acceptance_sigkill_zero_failed():
     one replica is SIGKILLed at the peak; ZERO requests fail (the
     router resume contract holds through spawn, drain and the kill);
     the scale-out replica is warm-started (no XLA compile)."""
-    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
-        from run_serving import make_requests, ramp_rates, run_ramp
+        from mini_fleet import make_requests, ramp_rates, run_ramp
     finally:
         sys.path.pop(0)
     import shutil

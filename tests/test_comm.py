@@ -12,6 +12,7 @@ Wire-compat matrix pinned here:
 import json
 import struct
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -467,6 +468,124 @@ def test_two_trainer_fan_in_with_batched_sends():
         np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
+def _run_comm_bench(n_grads=64, dim=16, rounds=4, pservers=2, trials=3):
+    """Pserver comm microbench: one trainer, `pservers`
+    in-process VariableServers, `n_grads` small grads per sync round.
+    Baseline = the pre-bucketing wire path (one SEND frame per var,
+    endpoints visited serially, per-var GETs); fused = parallel/comm's
+    CommPool (arrival-order SEND_BATCH buckets, concurrent endpoints,
+    one batched GET per endpoint).  Walls are best-of-`trials` over the
+    post-warmup rounds — round 0 absorbs the optimize-program compile on
+    both sides — and the dict also reports whether both paths left the
+    pservers with byte-identical parameters (they must)."""
+    import paddle_tpu as fluid
+    from paddle_tpu.parallel import comm
+    from paddle_tpu.parallel.pserver import VariableClient, VariableServer
+
+    names = [f"bw{i}" for i in range(n_grads)]
+    owner = {n: i % pservers for i, n in enumerate(names)}
+    rng = np.random.RandomState(7)
+    grads = [{n: rng.rand(dim).astype(np.float32) for n in names}
+             for _ in range(rounds + 1)]  # +1: untimed warmup round
+
+    def build_servers():
+        servers = []
+        for s in range(pservers):
+            scope = fluid.Scope()
+            prog = fluid.Program()
+            with fluid.program_guard(prog, fluid.Program()):
+                blk = prog.global_block()
+                blk.create_var(name="lr", shape=[1], dtype="float32",
+                               persistable=True)
+                for n in names:
+                    if owner[n] != s:
+                        continue
+                    blk.create_var(name=n, shape=[dim], dtype="float32",
+                                   persistable=True)
+                    blk.create_var(name=n + "@GRAD", shape=[dim],
+                                   dtype="float32", persistable=True)
+                    blk.append_op("sgd",
+                                  {"Param": [n], "Grad": [n + "@GRAD"],
+                                   "LearningRate": ["lr"]},
+                                  {"ParamOut": [n]}, {})
+            scope.set_var("lr", np.asarray([0.1], np.float32))
+            for n in names:
+                if owner[n] == s:
+                    scope.set_var(n, np.ones(dim, np.float32))
+            srv = VariableServer(prog, scope,
+                                 fluid.Executor(fluid.CPUPlace()),
+                                 fan_in=1)
+            srv.serve(0)
+            servers.append(srv)
+        return servers, [f"127.0.0.1:{s.port}" for s in servers]
+
+    def run_serial(eps):
+        clients = {ep: VariableClient(ep, client_id="bench-serial")
+                   for ep in eps}
+
+        def one_round(r):
+            for n in names:
+                clients[eps[owner[n]]].send_var(n + "@GRAD", grads[r][n])
+            for ep in eps:
+                clients[ep].send_batch_barrier()
+            for n in names:
+                clients[eps[owner[n]]].get_var(n)
+
+        one_round(0)  # warmup: optimize-program compile on the servers
+        t0 = time.perf_counter()
+        for r in range(1, rounds + 1):
+            one_round(r)
+        wall = time.perf_counter() - t0
+        params = {n: np.asarray(clients[eps[owner[n]]].get_var(n))
+                  for n in names}
+        for c in clients.values():
+            c.close()
+        return wall, params
+
+    def run_fused(eps):
+        pool = comm.CommPool()
+
+        def one_round(r):
+            pool.send_round(
+                [(eps[owner[n]], n + "@GRAD", grads[r][n])
+                 for n in names],
+                [(eps[owner[n]], n) for n in names])
+
+        one_round(0)
+        t0 = time.perf_counter()
+        for r in range(1, rounds + 1):
+            one_round(r)
+        wall = time.perf_counter() - t0
+        vals = pool.send_round([], [(eps[owner[n]], n) for n in names])
+        params = {n: np.asarray(v) for n, v in zip(names, vals)}
+        pool.close()
+        return wall, params
+
+    best = {"serial": float("inf"), "fused": float("inf")}
+    params_serial = params_fused = None
+    for _ in range(trials):
+        for mode, runner in (("serial", run_serial), ("fused", run_fused)):
+            servers, eps = build_servers()
+            try:
+                wall, params = runner(eps)
+            finally:
+                for s in servers:
+                    s.stop()
+            best[mode] = min(best[mode], wall)
+            if mode == "serial":
+                params_serial = params
+            else:
+                params_fused = params
+    identical = all(params_serial[n].tobytes() == params_fused[n].tobytes()
+                    for n in names)
+    return {"n_grads": n_grads, "dim": dim, "rounds": rounds,
+            "pservers": pservers,
+            "serial_seconds": round(best["serial"], 4),
+            "fused_seconds": round(best["fused"], 4),
+            "speedup": round(best["serial"] / best["fused"], 3),
+            "params_identical": identical}
+
+
 @pytest.mark.perf
 def test_comm_bucketed_round_speedup_and_metrics():
     """Acceptance microbench: 2 pservers x 64 small grads — the
@@ -475,7 +594,6 @@ def test_comm_bucketed_round_speedup_and_metrics():
     1.496 against the old 1.5 cut, a pure threshold flake) with
     byte-identical final params, and the round metrics must land in a
     Prometheus dump."""
-    import bench
     from paddle_tpu.observability import exporters
     from paddle_tpu.observability import metrics as obs_metrics
 
@@ -484,9 +602,9 @@ def test_comm_bucketed_round_speedup_and_metrics():
     try:
         result = None
         for _ in range(3):  # best-of walls inside; re-roll on a loaded
-            result = bench.run_comm_bench(n_grads=64, dim=16,  # CI host
-                                          rounds=4, pservers=2,
-                                          trials=2)
+            result = _run_comm_bench(n_grads=64, dim=16,  # CI host
+                                     rounds=4, pservers=2,
+                                     trials=2)
             assert result["params_identical"]
             if result["speedup"] >= 1.35:
                 break

@@ -1384,16 +1384,49 @@ def test_quantized_pool_admits_2x_resident_sequences():
 # ---------------------------------------------------------------------------
 
 
+def _run_load(dec, states, reqs, *, static_batch, slots, kv_blocks,
+              place):
+    """Submit every request at once to one server configuration, wait
+    for all; returns completions, tokens/s and the p99 latency."""
+    server = GenerationServer(dec, states, slots=slots,
+                              kv_blocks=kv_blocks,
+                              static_batch=static_batch, place=place)
+    lat = [None] * len(reqs)
+    toks = [0] * len(reqs)
+
+    def wait_for(i, t0, stream):
+        out = stream.result(timeout=300)
+        lat[i] = time.perf_counter() - t0
+        toks[i] = len(out)
+
+    t_start = time.perf_counter()
+    waiters = []
+    for i, (prompt, max_new) in enumerate(reqs):
+        t0 = time.perf_counter()
+        stream = server.submit(prompt, max_new, seed=i)
+        w = threading.Thread(target=wait_for, args=(i, t0, stream),
+                             daemon=True)
+        w.start()
+        waiters.append(w)
+    for w in waiters:
+        w.join(timeout=300)
+    wall = time.perf_counter() - t_start
+    server.close()
+    done = [l for l in lat if l is not None]
+    return {"completed": len(done),
+            "tokens_per_sec": sum(toks) / wall,
+            "latency_p99_s": float(np.percentile(done, 99))}
+
+
 @pytest.mark.perf
 def test_continuous_batching_2x_static_at_equal_p99():
-    """Under the mixed-length open-loop load (benchmark/run_serving.py)
-    continuous batching sustains >= 2x the static drain-then-refill
+    """Under the mixed-length open-loop load continuous batching sustains >= 2x the static drain-then-refill
     tokens/s at no worse p99.  Best-of-trials; the ratio is structural
     (identical executables, ~2.4x fewer decode ticks), so it holds on
     loaded CI hosts."""
-    sys.path.insert(0, os.path.join(REPO, "benchmark"))
+    sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
-        from run_serving import make_requests, run_load
+        from mini_fleet import make_requests   # the drills' request mix
     finally:
         sys.path.pop(0)
 
@@ -1404,7 +1437,7 @@ def test_continuous_batching_2x_static_at_equal_p99():
             for p, m in make_requests(24, 96, rng)]
     best = {}
     for static in (True, False):
-        rows = [run_load(dec, states, reqs, static_batch=static,
+        rows = [_run_load(dec, states, reqs, static_batch=static,
                          slots=4, kv_blocks=56,
                          place=fluid.CPUPlace())
                 for _ in range(2)]

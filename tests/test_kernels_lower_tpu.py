@@ -1,28 +1,27 @@
 """Every Pallas kernel cross-lowered for TPU from the CPU.
 
-The interpret-mode parity suites (test_serving_kernels.py,
+The interpret-mode parity suites (test_paged_attention.py,
 test_flash_attention.py) prove the kernels' MATH; they say nothing
 about whether the Pallas TPU lowering accepts the kernel at all, and
-that is where the first chip bring-up found three of four serving
-kernels refused (a dot with no free lhs dimension, a (1, 1) VMEM block
-over a [layers, blocks] array, an in-kernel cumsum).  Lowering for the
+that is where the first chip bring-up found kernels refused (a dot
+with no free lhs dimension, a (1, 1) VMEM block over a [layers,
+blocks] array).  Lowering for the
 "tpu" platform needs no TPU: `jit(f).trace(*args).lower(
 lowering_platforms=("tpu",))` runs the whole Pallas->Mosaic lowering
 on the host, in seconds.  What it cannot see is Mosaic's own compile
 (VMEM fit, layouts) — chip_smoke.py covers that on the device.
 
-Contract: at one geometry its `supports(platform="tpu")` accepts,
-each registered kernel lowers to a Mosaic custom call; a kernel whose
-predicate names a TPU reason is never picked there — it is a counted
-fallback, not a trace-time crash.
+Contract: at a geometry `select_paged_attention(platform="tpu")`
+accepts, the kernel it returns lowers to a Mosaic custom call, for
+every pool dtype and window; the flash kernels lower forward and
+backward.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.core.flags import get_flag, set_flags
-from paddle_tpu.kernels import registry as kreg
 from paddle_tpu.kernels.flash_attention import flash_attention
+from paddle_tpu.kernels.paged_attention import select_paged_attention
 
 MOSAIC_CALL = "tpu_custom_call"
 
@@ -51,77 +50,16 @@ def _paged_args(kv_dtype, window):
             jnp.zeros((S, NB), jnp.int32), jnp.zeros((S,), jnp.int32))
 
 
-# kernel name -> [(selection ctx, example-args builder, call adapter)]:
-# one entry per variant that has its own lowering path
-CASES = {
-    "paged_attention_decode": [
-        (dict(d_model=D, n_heads=H, block_size=BS, max_blocks_per_seq=NB,
-              kv_dtype=kv_dtype, window=window),
-         lambda kv_dtype=kv_dtype, window=window:
-             _paged_args(kv_dtype, window),
-         lambda kern: lambda q, pk, pv, t, p: kern(q, pk, pv, t, p, 1))
-        for kv_dtype in ("fp32", "bf16", "int8") for window in (1, 5)],
-    "moe_gate_dispatch": [
-        (dict(tokens=64, d_model=128, num_experts=4, capacity=32,
-              top_k=2, dtype="float32"),
-         lambda: (jnp.zeros((64, 128), jnp.float32),
-                  jnp.zeros((128, 4), jnp.float32)),
-         lambda kern: kern)],
-    "fused_bucket_update": [
-        (dict(numel=1_000_003, dtype="float32"),
-         lambda: (jnp.zeros((1_000_003,), jnp.float32),
-                  jnp.zeros((1_000_003,), jnp.float32), jnp.float32(0.1)),
-         lambda kern: kern)],
-}
-
-
-@pytest.fixture
-def armed_auto():
-    """`serving_kernels=auto`: arms exactly where platform == "tpu"."""
-    prev = get_flag("serving_kernels")
-    set_flags({"serving_kernels": "auto"})
-    yield
-    set_flags({"serving_kernels": prev})
-
-
-def test_every_registered_kernel_has_a_lowering_case():
-    assert sorted(CASES) == sorted(kreg._REGISTRY), \
-        "a newly registered kernel needs a TPU cross-lowering case here"
-
-
-@pytest.mark.parametrize(
-    "name,case", [(n, i) for n, cs in CASES.items()
-                  for i in range(len(cs))])
-def test_registered_kernel_lowers_for_tpu_or_is_a_named_fallback(
-        name, case, armed_auto):
-    ctx, make_args, adapt = CASES[name][case]
-    kdef = kreg._REGISTRY[name]
-    reason = kdef.supports(platform="tpu", **ctx)
-    sel = kreg.Selection()
-    try:
-        picked = sel.pick(name, platform="tpu", **ctx)
-        if reason is not None:
-            # the predicate names a TPU reason: selection must route to
-            # the oracle, counted under that name — never build
-            assert picked is None
-            assert sel.chosen[name] == f"xla:{reason}"
-            return
-        assert sel.chosen[name] == "pallas"
-        assert MOSAIC_CALL in lower_tpu(adapt(picked), *make_args())
-    finally:
-        sel.close()
-
-
-def test_moe_dispatch_is_refused_by_name_on_tpu():
-    """Mosaic has no cumsum lowering; the predicate must say so at any
-    geometry (this is the case the parametrized test above routes
-    through its fallback branch — pinned here so a predicate that
-    starts accepting TPU has to bring a kernel that lowers)."""
-    ctx = CASES["moe_gate_dispatch"][0][0]
-    assert kreg._REGISTRY["moe_gate_dispatch"].supports(
-        platform="tpu", **ctx) == "mosaic_no_cumsum"
-    assert kreg._REGISTRY["moe_gate_dispatch"].supports(
-        platform="cpu", **ctx) is None
+@pytest.mark.parametrize("window", [1, 5])
+@pytest.mark.parametrize("kv_dtype", ["fp32", "bf16", "int8"])
+def test_paged_attention_lowers_for_tpu(kv_dtype, window):
+    kern, reason = select_paged_attention(
+        d_model=D, n_heads=H, block_size=BS, max_blocks_per_seq=NB,
+        kv_dtype=kv_dtype, platform="tpu")
+    assert reason is None
+    text = lower_tpu(lambda q, pk, pv, t, p: kern(q, pk, pv, t, p, 1),
+                     *_paged_args(kv_dtype, window))
+    assert MOSAIC_CALL in text
 
 
 @pytest.mark.parametrize("shape,dtype", [

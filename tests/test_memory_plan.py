@@ -1,7 +1,7 @@
 """Whole-program memory layer (memory_optimization_transpiler + the
 executors): liveness donation plan, build-time rejection of unsafe
 donations, dead-var freeing, the memory_optimize flag's bit-identical
-guarantee, the remat/conv_layout/jit_granularity knobs, and the
+guarantee, the remat/jit_granularity knobs, and the
 LoD-bucketing recompile pin (the BOOK_MATRIX_r05 recommender compile
 outlier)."""
 import numpy as np
@@ -21,7 +21,7 @@ from paddle_tpu.memory_optimization_transpiler import (
 @pytest.fixture(autouse=True)
 def _restore_flags():
     keep = {k: get_flag(k) for k in ("memory_optimize", "remat",
-                                     "conv_layout", "jit_granularity")}
+                                     "jit_granularity")}
     yield
     set_flags(keep)
 
@@ -333,7 +333,7 @@ def test_bucketed_lod_recompiles_after_warmup_zero():
 
 
 # ---------------------------------------------------------------------------
-# knobs: jit_granularity, conv_layout, remat
+# knobs: jit_granularity, remat
 # ---------------------------------------------------------------------------
 
 
@@ -358,28 +358,6 @@ def test_jit_granularity_modes():
     assert s_seg["entries"] >= 1      # segment cache
     np.testing.assert_allclose(v_block, v_op, rtol=1e-5)
     np.testing.assert_allclose(v_block, v_seg, rtol=1e-5)
-
-
-def test_conv_layout_nhwc_matches_nchw():
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        img = fluid.layers.data(name="img", shape=[3, 8, 8],
-                                dtype="float32")
-        c = fluid.layers.conv2d(input=img, num_filters=4, filter_size=3,
-                                padding=1)
-        out = fluid.layers.reduce_mean(c)
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    exe.run(startup, scope=scope)
-    feed = {"img": np.random.rand(2, 3, 8, 8).astype(np.float32)}
-    ref, = exe.run(main, feed=feed, fetch_list=[out], scope=scope)
-    misses0 = exe.cache_stats()["misses"]
-    set_flags({"conv_layout": "NHWC"})
-    got, = exe.run(main, feed=feed, fetch_list=[out], scope=scope)
-    # trace-time flag: flipping it must re-key the executable cache
-    assert exe.cache_stats()["misses"] == misses0 + 1
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=1e-6)
 
 
 def test_remat_flag_default_for_builders():

@@ -239,73 +239,14 @@ python -m paddle_tpu.cli slo --check --spec tools/slo.json \
     --prom "$ATTR_PROM"
 rm -f "$ATTR_PROM"
 
-echo "== [14/14] serving kernels: Pallas/XLA parity + fallback accounting =="
-# the serving-kernel tier (docs/performance.md "Serving kernels"):
-# greedy decode through the fused paged-attention path must be
-# BIT-identical to the XLA oracle under interpret mode on CPU with
-# runtime verification armed, and armed-but-unsupported selections
-# must surface as the counted fallback series, reclaimed on close
+echo "== [14/14] kernels: Pallas/XLA parity, selection, TPU lowering =="
+# greedy decode through the paged-attention kernel (Pallas interpreter
+# on the CPU) must be BIT-identical to the XLA gather path with runtime
+# verification armed; selection must be the benchmark geometries'
+# documented one; every kernel must lower for the TPU
+# (docs/performance.md "Kernel selection")
 JAX_PLATFORMS=cpu PADDLE_TPU_VERIFY=error python -m pytest \
-    tests/test_serving_kernels.py \
+    tests/test_paged_attention.py tests/test_kernels_lower_tpu.py \
     -q -m 'not slow' -p no:cacheprovider
-JAX_PLATFORMS=cpu PADDLE_TPU_VERIFY=error PADDLE_TPU_METRICS=on \
-    python - <<'EOF_KERNELS'
-import numpy as np
-import paddle_tpu as fluid
-import paddle_tpu.core.framework as fw
-from paddle_tpu.core.flags import get_flag, set_flags
-from paddle_tpu.kernels import registry as kreg
-from paddle_tpu.models.transformer import build_lm_paged_decoder
-from paddle_tpu.observability import exporters
-from paddle_tpu.serving import GenerationServer
-
-
-def build(mode, kv_dtype=None):
-    prev = get_flag("serving_kernels")
-    set_flags({"serving_kernels": mode})
-    try:
-        fw.reset_unique_names()
-        startup, dec = build_lm_paged_decoder(
-            23, 4, 4, d_model=16, n_heads=2, n_layers=1,
-            kv_dtype=kv_dtype)
-    finally:
-        set_flags({"serving_kernels": prev})
-    return startup, dec
-
-
-startup, dec_x = build("off")
-scope = fluid.Scope()
-fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
-states = {n: np.asarray(scope.find_var(n)) for n in dec_x.state_names}
-_, dec_p = build("on")
-assert dec_p.kernels["paged_attention_decode"] == "pallas", dec_p.kernels
-
-outs = []
-for dec in (dec_x, dec_p):
-    srv = GenerationServer(dec, states, slots=2, kv_blocks=8,
-                           place=fluid.CPUPlace())
-    outs.append([srv.generate([1, 2, 3, 4], 8, timeout=120),
-                 srv.generate([5, 1, 2], 6, timeout=120)])
-    srv.close()
-assert outs[0] == outs[1], "Pallas decode diverged from the XLA oracle"
-
-# armed-but-unsupported: counted fallback series, reclaimed on close
-prev = get_flag("serving_kernels")
-set_flags({"serving_kernels": "on"})
-try:
-    sel = kreg.Selection()
-    assert sel.pick("paged_attention_decode", d_model=64, n_heads=2,
-                    block_size=64, max_blocks_per_seq=512,
-                    kv_dtype="fp32") is None
-    series = (kreg.FALLBACK_METRIC
-              + '{kernel="paged_attention_decode",reason="vmem_scratch"}')
-    assert series in exporters.prometheus_text(), "fallback not counted"
-    sel.close()
-    assert series not in exporters.prometheus_text(), "series leaked"
-finally:
-    set_flags({"serving_kernels": prev})
-print("serving kernels: greedy decode bit-identical (fp32), "
-      "fallback series counted and reclaimed")
-EOF_KERNELS
 
 echo "ci_check: all green"

@@ -190,7 +190,10 @@ def _wait_line(proc, prefix, timeout_s, what):
     raise SystemExit(f"{what}: no '{prefix}' within {timeout_s}s")
 
 
-def _build_model_dir(workdir):
+def _build_model_dir(workdir, vocab=23, d_model=16, max_blocks=4,
+                     kv_blocks=16):
+    """A tiny random-init decoder saved as a generation model dir (2
+    heads, 1 layer, blocks of 4, 2 slots)."""
     import numpy as np
 
     import paddle_tpu as fluid
@@ -199,17 +202,19 @@ def _build_model_dir(workdir):
     from paddle_tpu.serving import save_generation_model
 
     fw.reset_unique_names()
-    startup, dec = build_lm_paged_decoder(23, 4, 4, d_model=16,
-                                          n_heads=2, n_layers=1)
+    startup, dec = build_lm_paged_decoder(vocab, 4, max_blocks,
+                                          d_model=d_model, n_heads=2,
+                                          n_layers=1)
     scope = fluid.Scope()
     fluid.Executor(fluid.CPUPlace()).run(startup, scope=scope)
     states = {n: np.asarray(scope.find_var(n))
               for n in dec.state_names}
     model_dir = os.path.join(workdir, "model")
     save_generation_model(model_dir, states, {
-        "vocab_size": 23, "d_model": 16, "n_heads": 2, "n_layers": 1,
-        "block_size": 4, "max_blocks_per_seq": 4, "slots": 2,
-        "kv_blocks": 16})
+        "vocab_size": vocab, "d_model": d_model, "n_heads": 2,
+        "n_layers": 1, "block_size": 4,
+        "max_blocks_per_seq": max_blocks, "slots": 2,
+        "kv_blocks": kv_blocks})
     return model_dir
 
 
@@ -339,10 +344,269 @@ def driver(args):
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# open-loop ramp against a fleet of real `cli serve` replicas (the
+# autoscale drill's driver; tests/test_autoscaler.py rides the ramp)
+# ---------------------------------------------------------------------------
+
+VOCAB = 211
+
+
+def make_requests(n, max_len, rng, long_every=4):
+    """Mixed-length open-loop mix: 1 long pole per `long_every`
+    requests, the rest short: the shape that separates continuous
+    batching from drain-then-refill (a static batch always waits for
+    its pole)."""
+    reqs = []
+    for i in range(n):
+        prompt = list(rng.randint(0, VOCAB, rng.randint(2, 9)))
+        if i % long_every == long_every - 1:
+            max_new = max_len - len(prompt) - 8   # long pole
+        else:
+            max_new = int(rng.randint(4, 9))      # short answer
+        reqs.append((prompt, max_new))
+    return reqs
+
+
+def ramp_rates(peak_rps, floor_frac=0.25):
+    """The up-then-down open-loop schedule: floor -> half -> peak ->
+    half -> floor."""
+    return [peak_rps * floor_frac, peak_rps * 0.5, peak_rps,
+            peak_rps * 0.5, peak_rps * floor_frac]
+
+
+def run_ramp(submit, reqs, rates, phase_s, *, result_timeout_s=180.0,
+             deadline_ms=None, on_phase=None):
+    """Drive an open-loop up-then-down ramp through `submit(prompt,
+    max_new, deadline_ms=...) -> stream` (a GenerationServer or a
+    ReplicaRouter — the fleet path).  Arrivals follow the rate
+    schedule alone; each request is attributed to the phase it ARRIVED
+    in.  Returns per-phase tokens/s, p50/p99 completion latency and
+    shed rate, plus the totals the zero-failed acceptance pins:
+    `failed` counts non-shed errors (sheds are policy answers)."""
+    import threading
+
+    import numpy as np
+
+    from paddle_tpu.serving import (RequestDeadlineExceeded,
+                                    ServerSaturated)
+
+    reqs = list(reqs)
+    results = []  # (phase, latency_or_None, ntokens, shed, failed)
+    rlock = threading.Lock()
+    waiters = []
+    it = iter(reqs)
+
+    def wait_for(phase, t0, stream):
+        lat = ntok = 0
+        shed = failed = False
+        try:
+            out = stream.result(timeout=result_timeout_s)
+            lat, ntok = time.perf_counter() - t0, len(out)
+        except (RequestDeadlineExceeded, ServerSaturated):
+            shed = True
+        except Exception:
+            failed = True
+        with rlock:
+            results.append((phase, lat if ntok else None, ntok, shed,
+                            failed))
+
+    t_start = time.perf_counter()
+    for phase, rate in enumerate(rates):
+        phase_t0 = time.perf_counter()
+        n_phase = max(1, int(rate * phase_s))
+        for i in range(n_phase):
+            target = phase_t0 + i / rate if rate > 0 else phase_t0
+            delay = target - time.perf_counter()
+            if delay > 0:
+                time.sleep(delay)
+            req = next(it, None)
+            if req is None:
+                it = iter(reqs)   # recycle the mix
+                req = next(it)
+            prompt, max_new = req
+            t0 = time.perf_counter()
+            try:
+                stream = submit(prompt, max_new,
+                                deadline_ms=deadline_ms)
+            except (ServerSaturated, RequestDeadlineExceeded):
+                with rlock:
+                    results.append((phase, None, 0, True, False))
+                continue
+            except Exception:
+                with rlock:
+                    results.append((phase, None, 0, False, True))
+                continue
+            w = threading.Thread(target=wait_for,
+                                 args=(phase, t0, stream), daemon=True)
+            w.start()
+            waiters.append(w)
+        left = phase_s - (time.perf_counter() - phase_t0)
+        if left > 0:
+            time.sleep(left)
+        if on_phase is not None:
+            on_phase(phase, rate)
+    for w in waiters:
+        w.join(timeout=result_timeout_s)
+    wall = time.perf_counter() - t_start
+
+    phases = []
+    for phase, rate in enumerate(rates):
+        rows = [r for r in results if r[0] == phase]
+        lats = [r[1] for r in rows if r[1] is not None]
+        toks = sum(r[2] for r in rows)
+        phases.append({
+            "phase": phase, "rate_rps": round(rate, 2),
+            "requests": len(rows),
+            "tokens_per_sec": round(toks / phase_s, 1),
+            "latency_p50_s": round(float(np.percentile(lats, 50)), 4)
+            if lats else None,
+            "latency_p99_s": round(float(np.percentile(lats, 99)), 4)
+            if lats else None,
+            "shed_rate": round(sum(r[3] for r in rows)
+                               / max(len(rows), 1), 4),
+        })
+    return {
+        "rates_rps": [round(r, 2) for r in rates],
+        "phase_s": phase_s,
+        "wall_s": round(wall, 2),
+        "requests": len(results),
+        "tokens": sum(r[2] for r in results),
+        "shed": sum(1 for r in results if r[3]),
+        "failed": sum(1 for r in results if r[4]),
+        "phases": phases,
+    }
+
+
+def run_fleet_ramp(*, requests=64, peak_rps=20.0, phase_s=6.0,
+                   max_replicas=3, backlog_low=8.0, sustain_s=1.0,
+                   idle_sustain_s=4.0, cooldown_s=4.0, d_model=32,
+                   decode_delay_s=0.02, phase_hook=None, post_hook=None,
+                   env_extra=None):
+    """Save a model dir, front it with
+    ReplicaRouter + Autoscaler spawning REAL `cli serve` replicas,
+    drive the open-loop ramp, and report per-phase serving stats
+    alongside the scaling timeline and each surviving replica's
+    warmup accounting (compiles vs compile-cache hits).
+
+    This is a CPU fleet: several replicas share one host and a
+    chip belongs to one process, so every replica is pinned to the CPU
+    (`--use_tpu 0`, JAX_PLATFORMS=cpu in its environment) — the calling
+    process may hold the chip without starving them.
+
+    `decode_delay_s` arms a PADDLE_TPU_FAULTS delay rule on the
+    replicas' ``serving.decode`` chaos site: the bench model is tiny
+    (a laptop CPU decodes it at thousands of tokens/s), so the
+    injected per-tick latency stands in for a real accelerator's — it
+    makes the overload, and therefore the scale-out/scale-in
+    trajectory, deterministic across hosts.  Pass 0 to measure the
+    raw fleet instead.
+
+    Chaos-drill hooks (`drill_autoscale` rides this function):
+    `phase_hook(phase, rate, router, scaler)` fires after each ramp
+    phase (e.g. SIGKILL an owned replica at the peak);
+    `post_hook(record, router, scaler)` fires on the finished record
+    BEFORE teardown (the autoscaler/router metric series are reclaimed
+    on close, so a telemetry scrape must happen here); `env_extra`
+    merges into the replica environment."""
+    import shutil
+
+    import numpy as np
+
+    from paddle_tpu.cloud.autoscaler import (Autoscaler,
+                                             AutoscalerPolicy,
+                                             SubprocessReplicaLauncher)
+    from paddle_tpu.cloud.router import ReplicaRouter
+    from paddle_tpu.serving.replica import replica_call
+
+    workdir = tempfile.mkdtemp(prefix="paddle_ramp_")
+    min_replicas, max_blocks, spawn_timeout_s = 1, 8, 300.0
+    model_dir = _build_model_dir(workdir, vocab=VOCAB, d_model=d_model,
+                                 max_blocks=max_blocks, kv_blocks=24)
+
+    router = ReplicaRouter(desired=max_replicas * 2, refresh_s=0.1)
+    policy = AutoscalerPolicy(
+        min_replicas, max_replicas, p99_high_s=30.0,
+        backlog_high=64.0, backlog_low=backlog_low,
+        sustain_s=sustain_s, idle_sustain_s=idle_sustain_s,
+        cooldown_s=cooldown_s)
+    extra = dict(env_extra or {}, JAX_PLATFORMS="cpu")
+    if decode_delay_s > 0:
+        extra["PADDLE_TPU_FAULTS"] = ",".join(filter(None, [
+            extra.get("PADDLE_TPU_FAULTS",
+                      os.environ.get("PADDLE_TPU_FAULTS", "")),
+            f"serving.decode:delay:1:1000000000:{decode_delay_s}"]))
+    launcher = SubprocessReplicaLauncher(
+        model_dir, router.registry_addr, use_tpu=0, ttl_s=1.5,
+        drain_grace_s=30.0, env=dict(os.environ, **extra))
+    scaler = Autoscaler(router, launcher, policy, poll_s=0.2,
+                        window_s=8.0,
+                        spawn_timeout_s=spawn_timeout_s,
+                        drain_grace_s=30.0)
+    reqs = make_requests(requests, 4 * max_blocks,
+                         np.random.RandomState(0))
+    fleet_sizes = []
+
+    def _on_phase(p, r):
+        fleet_sizes.append(
+            len(router.live_replicas(include_draining=False)))
+        if phase_hook is not None:
+            phase_hook(p, r, router, scaler)
+
+    try:
+        scaler.ensure_min(timeout_s=spawn_timeout_s)
+        scaler.start()
+        ramp = run_ramp(
+            router.submit, reqs, ramp_rates(peak_rps), phase_s,
+            on_phase=_on_phase)
+        # ramp-down tail: give the idle-sustain window room to retire
+        deadline = time.monotonic() + 4 * (idle_sustain_s
+                                           + cooldown_s) + 30
+        while (len(router.live_replicas(include_draining=False))
+               > min_replicas and time.monotonic() < deadline):
+            time.sleep(0.2)
+        replicas = {}
+        for addr in router.live_replicas():
+            try:
+                st = replica_call(addr, {"op": "stats"},
+                                  timeout_s=10)["stats"]
+                replicas[addr] = {
+                    "warm_start": st.get("warm_start"),
+                    "warmup_s": st.get("warmup_s"),
+                    "compile_seconds": st.get("compile_seconds"),
+                    "cache_hits": st.get("cache_hits"),
+                    "cache_misses": st.get("cache_misses"),
+                    "recompiles_after_warmup":
+                        st.get("recompiles_after_warmup"),
+                }
+            except OSError:
+                pass
+        out = {
+            "peak_rps": peak_rps, "phase_s": phase_s,
+            "decode_delay_s": decode_delay_s,
+            "band": [min_replicas, max_replicas],
+            "ramp": ramp,
+            "fleet_size_per_phase": fleet_sizes,
+            "fleet_size_final": len(
+                router.live_replicas(include_draining=False)),
+            "scale_events": list(scaler.events),
+            "status": scaler.status(),
+            "replicas": replicas,
+            "router": router.stats(),
+        }
+        if post_hook is not None:
+            post_hook(out, router, scaler)
+        return out
+    finally:
+        scaler.close(retire_owned=True)
+        router.close()
+        shutil.rmtree(workdir, ignore_errors=True)
+
+
 def drill_autoscale(args):
     """Chaos acceptance for the autoscaling fleet (docs/serving.md
-    "Autoscaling"): ride `run_fleet_ramp_bench` — the BENCH_SERVING_RAMP
-    fleet driver owns the model/router/autoscaler/teardown — with its
+    "Autoscaling"): ride `run_fleet_ramp`, which owns the
+    model/router/autoscaler/teardown, with its
     chaos hooks: ramp open-loop load until a second `cli serve` replica
     spawns, SIGKILL one AT THE PEAK (phase_hook), keep ramping down
     until the fleet scales back in — asserting ZERO failed requests end
@@ -355,9 +619,6 @@ def drill_autoscale(args):
     fleet-size / crash-loop / zero-failed gate that follows in
     ci_check."""
     import signal as _signal
-
-    sys.path.insert(0, os.path.join(REPO, "benchmark"))
-    from run_serving import run_fleet_ramp_bench
 
     from paddle_tpu.cloud.registry import Registry
     from paddle_tpu.observability.collector import (TelemetryCollector,
@@ -395,7 +656,7 @@ def drill_autoscale(args):
         coll.scrape_once()
 
     try:
-        record = run_fleet_ramp_bench(
+        record = run_fleet_ramp(
             requests=64, peak_rps=args.peak_rps, phase_s=args.phase_s,
             max_replicas=args.max_replicas, backlog_low=6.0,
             sustain_s=0.8, idle_sustain_s=3.0, cooldown_s=3.0,
