@@ -114,16 +114,11 @@ define_flag("verify", "off",
             "and io.load_inference_model; results are cached per "
             "(program, version) so steady-state loops verify once.  "
             "Explicit Program.verify(level=...) calls ignore this flag")
-define_flag("prefetch_depth", 0,
-            "default Trainer.train prefetch depth: N > 0 runs reader + "
-            "DataFeeder.feed + device_put N batches ahead on a "
-            "background thread (reader/pipeline.py); 0 keeps the serial "
-            "loop.  Per-call override: Trainer.train(prefetch=N)")
 define_flag("sync_every_n", 1,
             "default Trainer.train fetch-sync cadence: K > 1 hands "
             "EndIteration a LazyFetch cost (device->host copy deferred "
             "until read) and fences the dispatch queue every K steps; "
-            "1 materializes every step (the serial loop).  Per-call "
+            "1 materializes every step.  Per-call "
             "override: Trainer.train(sync_every_n=K)")
 define_flag("metrics", False,
             "arm the observability metrics instruments "

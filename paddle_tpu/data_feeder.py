@@ -24,9 +24,10 @@ class DataFeeder:
         lod_level==0 slots are stacked dense; lod_level==1 slots are lists of
         variable-length sequences, packed flat + offset table (LoD).
 
-        Emits a `feed.pack` profiler event: in the serial loop this is
-        host time the device sits idle; the prefetch pipeline
-        (reader/pipeline.py) moves it onto the worker thread."""
+        Emits a `feed.pack` profiler event: under `Trainer.train` it
+        runs on the prefetch worker's thread (reader/pipeline.py),
+        beside the device's step; in a hand-written serial loop it is
+        host time the device sits idle."""
         from . import profiler
 
         with profiler.record_event("feed.pack"):

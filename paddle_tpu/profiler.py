@@ -150,11 +150,12 @@ def event_totals() -> Dict[str, float]:
 
 def host_blocked_fraction(wall_seconds: float, events) -> float:
     """Fraction of `wall_seconds` spent inside the named host-side
-    events.  Which events block the loop depends on the pipeline mode:
-    the serial loop blocks in `feed.pack` (DataFeeder) + `pipeline.h2d`;
-    the prefetched loop's worker absorbs those, and the loop itself only
-    blocks in `pipeline.wait` (queue empty) and `pipeline.fetch_sync`
-    (LazyFetch reads) — pass the event set matching the mode measured."""
+    events.  Which events block the loop depends on the loop: a
+    hand-written serial loop blocks in `feed.pack` (DataFeeder) +
+    `pipeline.h2d`; under `Trainer.train` the prefetch worker absorbs
+    those, and the loop itself only blocks in `pipeline.wait` (nothing
+    prepared yet) and `pipeline.fetch_sync` (LazyFetch reads) — pass
+    the event set matching the loop measured."""
     if wall_seconds <= 0:
         return 0.0
     with _events_lock:

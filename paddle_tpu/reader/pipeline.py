@@ -2,19 +2,18 @@
 
 The reference overlaps input preparation with compute through the async
 PyDataProvider2 pool and the gserver double-buffered data providers
-(framework/reader.h double_buffer); our Trainer loop was fully serial —
-`DataFeeder.feed` packed numpy on the host while the device idled.  On
-TPU, dispatch is async by design, so the whole host-side portion of a
-step is hideable: this module runs the batch reader, the feed packing
-and an eager `jax.device_put` on a background thread ahead of the
-training loop, handing the consumer feed dicts whose values are already
-device-resident.
+(framework/reader.h double_buffer).  This module runs the batch reader,
+the feed packing and an eager `jax.device_put` on a background thread
+ahead of the training loop, handing the consumer feed dicts whose values
+are already device-resident: `Trainer.train` takes every batch through
+it, one batch ahead (batch n+1 is read, packed and staged while the
+device runs step n).
 
 Layering: this sits ON TOP of the reader decorators (shuffle/batch/
 bucket_by_length/...), not instead of them — `prefetch_feeder(reader,
 feeder)` takes any batch reader and returns another zero-arg reader
 (the package idiom), whose iterator is a `PrefetchIterator` with clean
-shutdown (`close()`), bounded-queue backpressure, and exception
+shutdown (`close()`), a bounded read-ahead, and exception
 propagation (a reader/feeder failure re-raises in the consumer instead
 of truncating the stream, same contract as `buffered`).
 
@@ -38,8 +37,8 @@ from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
 
 # pipeline telemetry (gated by PADDLE_TPU_METRICS): queue occupancy
-# answers "is the reader keeping up" (pinned near `depth` = yes, near 0
-# with high wait = the reader is the bottleneck; docs/performance.md).
+# answers "is the reader keeping up" (at `depth` = yes, at 0 with high
+# wait = the reader is the bottleneck; docs/performance.md).
 # The gauge is labeled per iterator — concurrent streams must not
 # clobber one series — and close() reclaims it, so a finished stream
 # does not export a stale depth forever.
@@ -70,38 +69,53 @@ def stage_to_device(value, device):
 
 class PrefetchIterator:
     """One epoch of prefetched feeds: a daemon thread runs
-    `reader() -> feeder.feed -> device_put` into a bounded queue.
+    `reader() -> feeder.feed -> device_put`, at most `depth` batches
+    ahead of the consumer.
 
-    * backpressure: the queue holds at most `depth` packed batches, so a
-      slow consumer bounds host memory and the worker's readahead;
+    * hand-off: the worker asks the reader for a batch only against a
+      credit, and there are `depth` of them; the consumer gives one back
+      each time it TAKES a batch.  So at most `depth` batches are read
+      and not yet taken (prepared or in preparation), and with
+      `depth=1` batch n+1 is read no earlier than the take of batch n:
+      exactly one ahead.  That bounds host memory, device memory and
+      how far a stateful reader runs ahead of its consumer;
     * errors: any exception in the reader/feeder/transfer re-raises at the
-      consumer's next `__next__` (after already-queued good batches);
+      consumer's next `__next__` (after the good batches before it);
     * shutdown: `close()` (idempotent; also called on exhaustion) stops
-      the worker and joins it, so breaking out of a pass early never
-      leaks a thread blocked on a full queue.  NOTE: a live worker holds
-      a reference to this iterator (the thread's bound-method target),
-      so an ABANDONED iterator is not garbage-collected — consumers that
-      may abandon mid-stream should hold the `PrefetchReader` wrapper
-      (what `prefetch_feeder` returns), whose `__del__` IS reachable and
-      closes the inner iterator.
+      the worker, joins it and drops what it had prepared, so breaking
+      out of a pass early leaks neither a thread nor a device buffer.
+      NOTE: a live worker holds a reference to this iterator (the
+      thread's bound-method target), so an ABANDONED iterator is not
+      garbage-collected — consumers that may abandon mid-stream should
+      hold the `PrefetchReader` wrapper (what `prefetch_feeder`
+      returns), whose `__del__` IS reachable and closes the inner
+      iterator.
     """
 
-    def __init__(self, reader, feeder=None, place=None, depth=2,
+    def __init__(self, reader, feeder=None, place=None, depth=1,
                  device_put=True):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
-        self._q: "queue.Queue" = queue.Queue(maxsize=depth)
+        # never full: an item (the end and an error too) is put only
+        # against a credit, so it holds at most `depth`
+        self._q: "queue.Queue" = queue.Queue()
+        self._credits = threading.Semaphore(depth)
         self._stop = threading.Event()
         self._done = False
         # cumulative consumer-side blocked time (queue empty): the
         # host-blocked numerator a bench can read without enabling the
         # profiler (whose compiled-mode events fence the device)
         self.wait_s = 0.0
+        # the same for the last take alone, and whether its batch was
+        # already prepared when the consumer asked (trainer.step's
+        # `feed_wait_s` and `feed_ready`)
+        self.last_wait_s = 0.0
+        self.last_ready = False
         self._feeder = feeder
         self._device_put = device_put
         # thread handoff: batches prepared on the worker record under
-        # the span that constructed the iterator (e.g. trainer.step /
-        # the pass that opened the reader)
+        # the span that constructed the iterator (e.g. the pass that
+        # opened the reader)
         self._trace_ctx = obs_tracing.current_context()
         self._pipe_id = str(next(_PIPE_IDS))
         self._m_depth = _M_QUEUE_DEPTH.labels(pipe=self._pipe_id)
@@ -117,15 +131,17 @@ class PrefetchIterator:
         self.thread.start()
 
     # -- worker -------------------------------------------------------------
-    def _put(self, item) -> bool:
-        """Blocking put that wakes up when the consumer closes early."""
-        while not self._stop.is_set():
-            try:
-                self._q.put(item, timeout=0.05)
-                return True
-            except queue.Full:
-                continue
-        return False
+    def _on_credit(self, batches):
+        """`batches`, each pulled only once the consumer has taken one
+        of the `depth` before it (close() gives a credit to wake us)."""
+        while True:
+            self._credits.acquire()
+            if self._stop.is_set():
+                return
+            batch = next(batches, _End)
+            if batch is _End:
+                return
+            yield batch
 
     def _prepare(self, batch):
         if self._feeder is not None:
@@ -146,19 +162,18 @@ class PrefetchIterator:
     def _work(self, reader):
         try:
             with obs_tracing.activate(self._trace_ctx):
-                for batch in obs_attr.phased_iter("trainer", "reader",
-                                                  reader()):
-                    if self._stop.is_set():
-                        return
+                for batch in self._on_credit(obs_attr.phased_iter(
+                        "trainer", "reader", reader())):
                     with obs_tracing.span("pipeline.prepare"):
                         item = self._prepare(batch)
-                    if not self._put(item):
+                    if self._stop.is_set():
                         return
+                    self._q.put(item)
                     if obs_metrics.enabled():
                         self._m_depth.set(self._q.qsize())
-                self._put(_End)
+                self._q.put(_End)
         except BaseException as e:  # propagate, don't truncate the stream
-            self._put(_Error(e))
+            self._q.put(_Error(e))
 
     # -- consumer -----------------------------------------------------------
     def __iter__(self):
@@ -170,9 +185,10 @@ class PrefetchIterator:
         if self._done:
             raise StopIteration
         with profiler.record_event("pipeline.wait"):
+            self.last_ready = not self._q.empty()
             t0 = time.perf_counter()
             item = self._q.get()
-            dt = time.perf_counter() - t0
+            self.last_wait_s = dt = time.perf_counter() - t0
             self.wait_s += dt
         if obs_metrics.enabled():
             _M_WAIT_SECONDS.observe(dt)
@@ -185,20 +201,24 @@ class PrefetchIterator:
             self._done = True
             self._stop.set()
             raise item.exc
+        # the take: the worker may read one batch more
+        self._credits.release()
         return item
 
     def close(self):
-        """Stop the worker and join it (safe to call more than once)."""
+        """Stop the worker, join it and drop what it prepared (safe to
+        call more than once)."""
         self._done = True
         self._stop.set()
+        self._credits.release()  # wake a worker waiting for a take
         _M_QUEUE_DEPTH.remove(pipe=self._pipe_id)
-        while True:  # drain so a blocked put wakes immediately
+        if self.thread.is_alive():
+            self.thread.join(timeout=5)
+        while True:
             try:
                 self._q.get_nowait()
             except queue.Empty:
                 break
-        if self.thread.is_alive():
-            self.thread.join(timeout=5)
 
     def __del__(self):
         try:
@@ -218,7 +238,7 @@ class PrefetchReader:
     without `close()`) leaks neither the thread nor the queued
     device-resident batches."""
 
-    def __init__(self, reader, feeder=None, place=None, depth=2,
+    def __init__(self, reader, feeder=None, place=None, depth=1,
                  device_put=True):
         self._args = (reader, feeder, place, depth, device_put)
         self._it: "PrefetchIterator | None" = None
@@ -242,6 +262,14 @@ class PrefetchReader:
         """Consumer-side blocked seconds (see PrefetchIterator.wait_s)."""
         return self._it.wait_s if self._it is not None else 0.0
 
+    @property
+    def last_wait_s(self) -> float:
+        return self._it.last_wait_s if self._it is not None else 0.0
+
+    @property
+    def last_ready(self) -> bool:
+        return self._it.last_ready if self._it is not None else False
+
     def close(self):
         self._closed = True
         if self._it is not None:
@@ -254,19 +282,20 @@ class PrefetchReader:
             pass
 
 
-def prefetch_feeder(reader, feeder=None, place=None, depth=2,
+def prefetch_feeder(reader, feeder=None, place=None, depth=1,
                     device_put=True):
     """Reader decorator: batch reader -> reader of DEVICE-RESIDENT feed
-    dicts, prepared `depth` batches ahead on a background thread.
+    dicts, prepared at most `depth` batches ahead on a background thread
+    (the default, one, is what `Trainer.train` runs).
 
-        feeds = prefetch_feeder(train_reader, feeder, place, depth=2)
+        feeds = prefetch_feeder(train_reader, feeder, place)
         for feed in feeds():
             exe.run(main, feed=feed, fetch_list=[loss])
 
     `feeder=None` means the reader already yields feed dicts and only the
     device transfer is staged; `device_put=False` keeps values on host
     (pure pack-ahead).  Each call of the returned reader yields a fresh
-    `PrefetchReader` (own thread + queue once iterated), so it composes
+    `PrefetchReader` (own thread once iterated), so it composes
     with the multi-pass Trainer loop exactly like any other reader.
     """
 

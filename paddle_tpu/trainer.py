@@ -27,8 +27,8 @@ from .observability import metrics as obs_metrics
 from .observability import tracing as obs_tracing
 
 # train-loop telemetry (docs/observability.md): gated by
-# PADDLE_TPU_METRICS, so the serial loop's semantics and cost are
-# untouched when off
+# PADDLE_TPU_METRICS, so the loop's semantics and cost are untouched
+# when off
 _M_STEPS = obs_metrics.counter(
     "paddle_tpu_trainer_steps_total", "training steps completed")
 _M_EXAMPLES = obs_metrics.counter(
@@ -249,7 +249,6 @@ class Trainer:
               checkpoint_max_keep: int = 3,
               checkpoint_every_n_iters: int = 0,
               resume_from: Optional[str] = None,
-              prefetch: Optional[int] = None,
               sync_every_n: Optional[int] = None,
               cluster=None):
         """reader: batch reader (yields lists of samples per batch).
@@ -273,18 +272,30 @@ class Trainer:
         `checkpoint_dir` is not given.  The running step count is exposed
         as `self.step`.
 
-        Async hot path: `prefetch=N` (default flag `prefetch_depth`, env
-        PADDLE_TPU_PREFETCH_DEPTH) runs reader + feed packing + H2D on a
-        background thread N batches ahead (reader/pipeline.py);
+        One batch ahead: while the device runs step n, a worker thread
+        (reader/pipeline.py) reads batch n+1 from `reader`, packs it
+        (`feeder.feed`) and places it on the device, and holds it until
+        the loop takes it.  The reader is asked for batch n+1 no earlier
+        than the loop's take of batch n, so it is never more than one
+        batch ahead of the step being dispatched, and one extra batch is
+        all that is held on the host and on the device.  The steps run
+        the same ops in the same order on the same inputs as a plain
+        `Executor.run` loop over the reader, so final parameters are
+        bit-identical to one (test-enforced, tests/test_async_feed.py);
+        events fire in the same order and number; an exception of the
+        reader or the feeder re-raises here after the batches before it
+        have trained; the worker is joined on every way out.  What a
+        caller can see of it: the reader runs on that thread, one batch
+        ahead of the step, so a reader that reads state its own event
+        handler writes sees it one step late.
+
         `sync_every_n=K` (default flag `sync_every_n`, env
         PADDLE_TPU_SYNC_EVERY_N) > 1 threads the cost through
         `EndIteration` as a `LazyFetch` that materializes only when the
         callback reads it (or every K steps, bounding the in-flight
         dispatch queue), so step N+1 dispatches while step N computes.
-        Both default off/1: the default loop is bit-for-bit the serial
-        one, and the async loop runs the SAME ops in the SAME order, so
-        final parameters are bit-identical (test-enforced,
-        tests/test_async_feed.py).
+        At the default 1 every `EndIteration` carries the loss of a
+        finished step as a float.
 
         Elastic clusters: `cluster=` (a cloud.cluster.ClusterClient,
         an in-process ClusterController, or a controller address
@@ -330,7 +341,7 @@ class Trainer:
                 num_passes, reader, event_handler, feeder,
                 checkpoint_dir, checkpoint_every_n_passes,
                 checkpoint_max_keep, checkpoint_every_n_iters,
-                resume_from, prefetch, sync_every_n, io,
+                resume_from, sync_every_n, io,
                 fault_injector, prefetch_feeder)
         finally:
             if lease is not None:
@@ -370,7 +381,7 @@ class Trainer:
     def _train_loop(self, num_passes, reader, event_handler, feeder,
                     checkpoint_dir, checkpoint_every_n_passes,
                     checkpoint_max_keep, checkpoint_every_n_iters,
-                    resume_from, prefetch, sync_every_n, io,
+                    resume_from, sync_every_n, io,
                     fault_injector, prefetch_feeder):
         event_handler = event_handler or (lambda e: None)
         feeder = feeder or self._feeder()
@@ -382,25 +393,10 @@ class Trainer:
         from .observability.collector import maybe_announce
 
         maybe_announce("trainer")
-        if prefetch is None:
-            prefetch = int(get_flag("prefetch_depth"))
         if sync_every_n is None:
             sync_every_n = int(get_flag("sync_every_n"))
         sync_every_n = max(int(sync_every_n), 1)
         lazy = sync_every_n > 1
-        def make_feeds(rd):
-            if prefetch > 0:
-                # feed_pack/h2d attribution happens on the prefetch
-                # worker (reader/pipeline.py)
-                return prefetch_feeder(rd, feeder, self.place,
-                                       depth=prefetch)()
-
-            def packed():
-                for b in obs_attr.phased_iter("trainer", "reader", rd()):
-                    with obs_attr.phase("trainer", "feed_pack"):
-                        feed = feeder.feed(b)
-                    yield feed
-            return packed()
         self._publish_static_floor()
         if resume_from is not None and checkpoint_dir is None:
             checkpoint_dir = resume_from
@@ -451,7 +447,10 @@ class Trainer:
                     yield from it
             else:
                 pass_reader = reader
-            feeds = make_feeds(pass_reader)
+            # batch n+1 is read, packed and staged on the worker thread
+            # of reader/pipeline.py while step n runs: one batch ahead
+            # (the trainer.phase.reader/feed_pack/h2d spans are its)
+            feeds = prefetch_feeder(pass_reader, feeder, self.place)()
             try:
                 # no enumerate(): it would hold the last batch (see below)
                 batch_id = n_skip - 1
@@ -464,9 +463,11 @@ class Trainer:
                     fault_injector().fire("trainer.iteration")
                     event_handler(BeginIteration(pass_id, batch_id))
                     t_step = time.perf_counter()
-                    with obs_tracing.span("trainer.step",
-                                          pass_id=pass_id,
-                                          batch_id=batch_id):
+                    with obs_tracing.span(
+                            "trainer.step", pass_id=pass_id,
+                            batch_id=batch_id,
+                            feed_ready=int(feeds.last_ready),
+                            feed_wait_s=feeds.last_wait_s):
                         with obs_attr.phase("trainer", "compute"):
                             outs = self.exe.run(
                                 self.main_program, feed=feed,
@@ -518,15 +519,13 @@ class Trainer:
                             and checkpoint_every_n_iters > 0 \
                             and self.step % checkpoint_every_n_iters == 0:
                         _save(pass_id, batch_id + 1)
-                    # drop the batch with its step: its host arrays are
-                    # then freed under the next trainer.phase.feed_pack,
-                    # not between spans
+                    # drop the batch with its step: its device buffers
+                    # are then free before the take of the next one
                     del feed
             finally:
-                # a prefetching iterator owns a worker thread: an
-                # exception mid-pass must not leak it blocked on the queue
-                if hasattr(feeds, "close"):
-                    feeds.close()
+                # the iterator owns a worker thread and one prepared
+                # batch: no way out of the pass may leak either
+                feeds.close()
             if resuming and not trained:
                 # the snapshot was taken AT the pass boundary: this pass
                 # is already complete, so no events and no redundant

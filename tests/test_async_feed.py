@@ -1,11 +1,12 @@
 """Async training hot path (reader/pipeline.py + Trainer lazy fetches).
 
-Covers the prefetch pipeline's contract (ordering, backpressure,
-exception propagation, clean shutdown), the LazyFetch handle, the
-bit-identity of the async loop vs the serial loop on a deterministic
-reader, and the host-bound overlap microbench (perf marker): prefetch +
-lazy fetch must beat the serial loop by >= 20% steps/s without a single
-post-warmup recompile.
+Covers the prefetch pipeline's contract (ordering, the bounded
+read-ahead, exception propagation, clean shutdown), the LazyFetch
+handle, `Trainer.train`'s one loop (one batch ahead by construction,
+bit-identical to a plain serial `Executor.run` loop over the same reader,
+the worker joined on every way out), and the host-bound overlap
+microbench (perf marker): prefetch + lazy fetch must beat the serial
+loop by >= 20% steps/s without a single post-warmup recompile.
 """
 import threading
 import time
@@ -68,15 +69,93 @@ class TestPrefetchIterator:
         with pytest.raises(ValueError, match="cannot pack"):
             next(it)
 
-    def test_bounded_queue_backpressure(self):
+    @pytest.mark.parametrize("depth", [1, 2, 5])
+    def test_reads_exactly_depth_ahead_of_the_take(self, depth):
+        """The reader is asked for batch n + depth at the take of batch
+        n and no earlier: prepared or in preparation, `depth` batches
+        are all the worker ever holds."""
         produced = []
         it = PrefetchIterator(_dict_reader(50, produced), feeder=None,
-                              place=fluid.CPUPlace(), depth=2)
-        next(it)
-        time.sleep(0.3)  # give the worker time to run ahead if unbounded
-        # 1 consumed + 2 queued + 1 in the worker's hands, +1 race slack
-        assert len(produced) <= 5, produced
+                              place=fluid.CPUPlace(), depth=depth)
+        for taken in range(1, 4):
+            next(it)
+            deadline = time.monotonic() + 5.0
+            while len(produced) < taken + depth \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+            time.sleep(0.1)  # time to run further ahead, if it could
+            assert produced == list(range(taken + depth)), produced
         it.close()
+        assert not it.thread.is_alive()
+
+    def test_handoff_holds_under_contention(self):
+        """More streams than cores at a shortened switch interval: every
+        consumer still sees its batches in order, and no reader is ever
+        asked for more than one batch past its consumer's takes."""
+        import os
+        import sys
+
+        n_streams, n_items = 2 * (os.cpu_count() or 4), 150
+        errors = []
+
+        def stream(k):
+            taken = [0]
+
+            def reader():
+                for i in range(n_items):
+                    if i > taken[0] + 1:  # asked for i with < i - 1 taken
+                        errors.append((k, "ahead", i, taken[0]))
+                    yield i
+
+            it = PrefetchIterator(reader, feeder=None, device_put=False)
+            try:
+                for want in range(n_items):
+                    got = next(it)
+                    taken[0] = want + 1
+                    if got != want:
+                        errors.append((k, "order", want, got))
+                if next(it, None) is not None:
+                    errors.append((k, "no end"))
+            except BaseException as e:  # surfaced below, not lost
+                errors.append((k, repr(e)))
+            finally:
+                it.close()
+
+        was = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=stream, args=(k,))
+                       for k in range(n_streams)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(was)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors[:5]
+        assert not _workers()
+
+    def test_take_reports_ready_and_wait(self):
+        gate = threading.Event()
+
+        def reader():
+            yield {"i": np.zeros(1, np.float32)}
+            gate.wait(5)
+            yield {"i": np.ones(1, np.float32)}
+
+        it = PrefetchIterator(reader, feeder=None, device_put=False)
+        next(it)
+        threading.Timer(0.1, gate.set).start()
+        next(it)  # the worker was still inside the reader: a wait
+        assert not it.last_ready and it.last_wait_s >= 0.03
+        assert it.wait_s >= it.last_wait_s
+        deadline = time.monotonic() + 5.0
+        while it._q.empty() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        with pytest.raises(StopIteration):
+            next(it)  # the end was waiting for us
+        assert it.last_ready and it.last_wait_s < 0.03
 
     def test_close_stops_worker_promptly(self):
         it = PrefetchIterator(_dict_reader(10_000), feeder=None,
@@ -122,9 +201,7 @@ class TestPrefetchIterator:
         wrapper is not."""
         import gc
 
-        def workers():
-            return [t for t in threading.enumerate()
-                    if t.name == "paddle-tpu-prefetch"]
+        workers = _workers
 
         feeds = prefetch_feeder(_dict_reader(10_000), feeder=None,
                                 place=fluid.CPUPlace(), depth=2)()
@@ -194,9 +271,7 @@ def _deterministic_data(n_batches=6, bs=8, dim=16, seed=7):
             for _ in range(n_batches)]
 
 
-def _train_mlp(data, passes=2, dim=16, **train_kwargs):
-    """Build + train a fresh MLP in an isolated scope; returns (params,
-    per-iteration costs, trainer)."""
+def _mlp(dim=16):
     reset_unique_names()
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
@@ -207,40 +282,94 @@ def _train_mlp(data, passes=2, dim=16, **train_kwargs):
         loss = fluid.layers.mean(
             fluid.layers.square_error_cost(input=p, label=y))
         fluid.SGD(learning_rate=0.05).minimize(loss)
+    return main, startup, x, y, loss
 
+
+def _params(main, scope):
+    return {v.name: np.asarray(scope.find_var(v.name))
+            for v in main.list_vars() if v.persistable}
+
+
+def _train_mlp(data, passes=2, dim=16, event_handler=None, reader=None,
+               **train_kwargs):
+    """Build + train a fresh MLP in an isolated scope; returns (params,
+    per-iteration costs, trainer)."""
+    main, startup, x, y, loss = _mlp(dim)
     costs = []
 
     def on_event(e):
         if isinstance(e, trainer_mod.EndIteration):
             costs.append(float(e.cost))
+        if event_handler is not None:
+            event_handler(e)
 
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
                                 feed_list=[x, y], main_program=main,
                                 startup_program=startup)
-        t.train(passes, lambda: iter(data), event_handler=on_event,
-                **train_kwargs)
-        params = {v.name: np.asarray(scope.find_var(v.name))
-                  for v in main.list_vars() if v.persistable}
+        t.train(passes, reader or (lambda: iter(data)),
+                event_handler=on_event, **train_kwargs)
+        params = _params(main, scope)
     return params, costs, t
 
 
+def _serial_executor_loop(data, passes=2, dim=16):
+    """The oracle: a plain `Executor.run` loop over the same reader, each
+    batch packed and fed inside its own step."""
+    main, startup, x, y, loss = _mlp(dim)
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup, scope=scope)
+    feeder = DataFeeder([x, y], fluid.CPUPlace())
+    costs = []
+    for _ in range(passes):
+        for batch in data:
+            out, = exe.run(main, feed=feeder.feed(batch),
+                           fetch_list=[loss], scope=scope)
+            costs.append(float(np.asarray(out).reshape(-1)[0]))
+    return _params(main, scope), costs
+
+
+def _workers():
+    return [t for t in threading.enumerate()
+            if t.name == "paddle-tpu-prefetch"]
+
+
+class _Boom(Exception):
+    pass
+
+
+class _BadFeeder(DataFeeder):
+    """Packs `good` batches, then raises."""
+
+    def __init__(self, feed_list, good):
+        super().__init__(feed_list, fluid.CPUPlace())
+        self.good = good
+
+    def feed(self, batch):
+        if self.good == 0:
+            raise _Boom("cannot pack")
+        self.good -= 1
+        return super().feed(batch)
+
+
 class TestTrainerAsync:
-    def test_async_params_bit_identical_to_sync(self):
+    @pytest.mark.parametrize("sync_every_n", [1, 4])
+    def test_params_bit_identical_to_serial_executor_loop(
+            self, sync_every_n):
         data = _deterministic_data()
-        sync_params, sync_costs, _ = _train_mlp(data)
-        async_params, async_costs, _ = _train_mlp(
-            data, prefetch=3, sync_every_n=4)
-        assert set(sync_params) == set(async_params)
-        for name, arr in sync_params.items():
-            other = async_params[name]
+        want_params, want_costs = _serial_executor_loop(data)
+        params, costs, _ = _train_mlp(data, sync_every_n=sync_every_n)
+        assert set(want_params) == set(params)
+        for name, arr in want_params.items():
+            other = params[name]
             assert arr.dtype == other.dtype, name
             assert np.array_equal(arr, other), \
-                f"param {name} diverged between sync and async loops"
+                f"param {name} diverged from the serial Executor.run loop"
         # the observable training trajectory matches too
-        np.testing.assert_array_equal(np.asarray(sync_costs),
-                                      np.asarray(async_costs))
+        np.testing.assert_array_equal(np.asarray(want_costs),
+                                      np.asarray(costs))
 
     def test_async_cost_is_lazy_fetch(self):
         data = _deterministic_data(n_batches=3)
@@ -250,34 +379,71 @@ class TestTrainerAsync:
             if isinstance(e, trainer_mod.EndIteration):
                 seen.append((e.cost, e.metrics))
 
-        reset_unique_names()
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            x = fluid.layers.data(name="x", shape=[16], dtype="float32")
-            y = fluid.layers.data(name="y", shape=[1], dtype="float32")
-            p = fluid.layers.fc(input=x, size=1)
-            loss = fluid.layers.mean(
-                fluid.layers.square_error_cost(input=p, label=y))
-            fluid.SGD(learning_rate=0.05).minimize(loss)
+        main, startup, x, y, loss = _mlp()
         with fluid.scope_guard(fluid.Scope()):
             t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
                                     feed_list=[x, y], main_program=main,
                                     startup_program=startup)
             t.train(1, lambda: iter(data), event_handler=on_event,
-                    prefetch=2, sync_every_n=2)
+                    sync_every_n=2)
         assert len(seen) == 3
         for cost, _metrics in seen:
             assert isinstance(cost, trainer_mod.LazyFetch)
             assert np.isfinite(float(cost))
 
-    def test_flag_defaults_keep_serial_loop(self):
+    def test_default_loop_is_one_batch_ahead(self):
+        """With no option set, `Trainer.train` asks the reader for batch
+        n+1 after it takes batch n and before EndIteration n (the worker
+        prepares it under step n), and never for batch n+2 before it
+        takes n+1; every EndIteration carries a float."""
+        import inspect
+
         from paddle_tpu.core.flags import get_flag
 
-        assert get_flag("prefetch_depth") == 0
+        assert "prefetch" not in inspect.signature(
+            trainer_mod.Trainer.train).parameters
         assert get_flag("sync_every_n") == 1
-        data = _deterministic_data(n_batches=2, dim=16)
-        _, costs, _ = _train_mlp(data, passes=1)
+        data = _deterministic_data(n_batches=5)
+        log, lock = [], threading.Lock()
+        asked = [threading.Event() for _ in range(len(data) + 1)]
+
+        def note(*what):
+            with lock:
+                log.append(what)
+
+        def reader():
+            for i, batch in enumerate(data):
+                note("ask", i, threading.current_thread().name)
+                asked[i].set()
+                yield batch
+            asked[len(data)].set()  # the pull that finds the end
+
+        def on_event(e):
+            if isinstance(e, trainer_mod.BeginIteration):
+                note("begin", e.batch_id)
+            elif isinstance(e, trainer_mod.EndIteration):
+                # the serial loop would ask only after this handler
+                assert asked[e.batch_id + 1].wait(5), \
+                    f"batch {e.batch_id + 1} not asked for under step " \
+                    f"{e.batch_id}"
+                time.sleep(0.05)  # time to run two ahead, if it could
+                note("end", e.batch_id)
+
+        _, costs, _ = _train_mlp(data, passes=1, reader=reader,
+                                 event_handler=on_event)
+        assert len(costs) == len(data)
         assert all(isinstance(c, float) for c in costs)
+        at = {(w[0], w[1]): i for i, w in enumerate(log)}
+        for n in range(len(data)):
+            assert log[at["ask", n]][2] == "paddle-tpu-prefetch"
+            if n >= 1:
+                # batch n is asked for no earlier than the take of n-1,
+                # which follows EndIteration n-2 ...
+                assert at["ask", n] < at["end", n - 1]
+            if n >= 2:
+                # ... and never while n-2 is still the step in hand
+                assert at["ask", n] > at["end", n - 2]
+        assert not _workers()
 
     def test_resume_fast_forward_skips_feed_packing(self, tmp_path):
         """Resume replays the RAW reader past already-trained batches:
@@ -291,15 +457,7 @@ class TestTrainerAsync:
                 return super().feed(batch)
 
         data = _deterministic_data(n_batches=6)
-        reset_unique_names()
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            x = fluid.layers.data(name="x", shape=[16], dtype="float32")
-            y = fluid.layers.data(name="y", shape=[1], dtype="float32")
-            p = fluid.layers.fc(input=x, size=1)
-            loss = fluid.layers.mean(
-                fluid.layers.square_error_cost(input=p, label=y))
-            fluid.SGD(learning_rate=0.05).minimize(loss)
+        main, startup, x, y, loss = _mlp()
         ckpt = str(tmp_path / "ckpt")
         with fluid.scope_guard(fluid.Scope()):
             t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
@@ -317,39 +475,120 @@ class TestTrainerAsync:
             feeder = CountingFeeder([x, y], fluid.CPUPlace())
             t2.train(1, lambda: iter(data), feeder=feeder,
                      resume_from=ckpt, checkpoint_every_n_passes=0,
-                     prefetch=2, sync_every_n=2)
+                     sync_every_n=2)
             assert t2.step == 6
         assert CountingFeeder.calls == 2, CountingFeeder.calls
 
-    def test_reader_failure_mid_pass_closes_pipeline(self):
+    def test_checkpoint_cursor_counts_trained_batches(self, tmp_path):
+        """The worker has read batch n+1 when step n's snapshot is
+        taken: the cursor says n+1 batches are done, not n+2."""
+        from paddle_tpu import io as pio
+
+        data = _deterministic_data(n_batches=5)
+        main, startup, x, y, loss = _mlp()
+        ckpt = str(tmp_path / "ckpt")
+        with fluid.scope_guard(fluid.Scope()):
+            t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
+                                    feed_list=[x, y], main_program=main,
+                                    startup_program=startup)
+            t.train(1, lambda: iter(data), checkpoint_dir=ckpt,
+                    checkpoint_every_n_iters=3,
+                    checkpoint_every_n_passes=0)
+            meta = pio.load_checkpoint(t.exe, ckpt, main_program=main)
+        assert meta["trainer_args"] == {
+            "next_pass_id": 0, "next_batch_id": 3, "step": 3}
+
+    @pytest.mark.parametrize("way_out", [
+        "reader_raises", "feeder_raises", "handler_raises",
+        "fault_injector_fires", "pass_abandoned"])
+    def test_every_way_out_joins_the_worker(self, way_out):
+        """The error surfaces after the good batches have trained, and
+        no way out of a pass leaves the worker thread behind."""
+        from paddle_tpu.core.resilience import fault_injector
+
+        data = _deterministic_data(n_batches=6)
+        good = 2  # batches that train before the way out is taken
+        kwargs, exc = {}, _Boom
+
+        def reader():
+            for i, batch in enumerate(data):
+                if way_out == "reader_raises" and i == good:
+                    raise _Boom("stream died")
+                yield batch
+
+        def on_event(e):
+            if isinstance(e, trainer_mod.BeginIteration) \
+                    and e.batch_id == good:
+                if way_out == "handler_raises":
+                    raise _Boom("handler")
+                if way_out == "pass_abandoned":
+                    raise KeyboardInterrupt  # with batch `good` in hand
+
+        if way_out == "feeder_raises":
+            kwargs["feeder"] = _BadFeeder(["x", "y"], good)
+        elif way_out == "pass_abandoned":
+            exc = KeyboardInterrupt
+        inj = fault_injector()
+        inj.clear()
+        if way_out == "fault_injector_fires":
+            inj.inject("trainer.iteration", "error", nth=good + 1,
+                       exc=_Boom("SIGKILL stand-in"))
+        ends = []
+
+        def handler(e):
+            if isinstance(e, trainer_mod.EndIteration):
+                ends.append(e.batch_id)
+            on_event(e)
+
+        try:
+            with pytest.raises(exc):
+                _train_mlp(data, passes=1, reader=reader,
+                           event_handler=handler, **kwargs)
+        finally:
+            inj.clear()
+        assert ends == list(range(good))
+        assert not _workers(), "the prefetch worker outlived its pass"
+
+    def test_step_span_says_whether_the_feed_was_ready(self):
+        from paddle_tpu.observability import tracing
+
         data = _deterministic_data(n_batches=4)
+        slow = threading.Event()
 
-        def flaky():
-            yield data[0]
-            yield data[1]
-            raise IOError("stream died")
+        def reader():
+            for i, batch in enumerate(data):
+                if i == 2:
+                    slow.wait(5)  # the worker is the slower side once
+                yield batch
 
-        before = threading.active_count()
-        with pytest.raises(IOError, match="stream died"):
-            reset_unique_names()
-            main, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(main, startup):
-                x = fluid.layers.data(name="x", shape=[16],
-                                      dtype="float32")
-                y = fluid.layers.data(name="y", shape=[1],
-                                      dtype="float32")
-                p = fluid.layers.fc(input=x, size=1)
-                loss = fluid.layers.mean(
-                    fluid.layers.square_error_cost(input=p, label=y))
-                fluid.SGD(learning_rate=0.05).minimize(loss)
-            with fluid.scope_guard(fluid.Scope()):
-                t = trainer_mod.Trainer(loss, place=fluid.CPUPlace(),
-                                        feed_list=[x, y],
-                                        main_program=main,
-                                        startup_program=startup)
-                t.train(1, flaky, prefetch=2, sync_every_n=2)
-        time.sleep(0.1)
-        assert threading.active_count() <= before + 1
+        def on_event(e):
+            if not isinstance(e, trainer_mod.EndIteration):
+                return
+            if e.batch_id == 1:
+                threading.Timer(0.1, slow.set).start()
+            elif e.batch_id == 2:
+                time.sleep(0.2)  # the loop is the slower side once
+
+        was = tracing.enabled()
+        tracing.set_enabled(True)
+        tracing.clear()
+        try:
+            _train_mlp(data, passes=1, reader=reader,
+                       event_handler=on_event)
+            steps = [s for s in tracing.finished_spans()
+                     if s["name"] == "trainer.step"]
+        finally:
+            tracing.set_enabled(was)
+            tracing.clear()
+        assert [s["attrs"]["batch_id"] for s in steps] == [0, 1, 2, 3]
+        for s in steps:
+            assert s["attrs"]["feed_ready"] in (0, 1)
+            assert s["attrs"]["feed_wait_s"] >= 0.0
+        # batch 2 came from a reader that slept past the step before
+        # it; batch 3 was prepared under a handler that slept
+        assert [steps[i]["attrs"]["feed_ready"] for i in (2, 3)] == [0, 1]
+        assert steps[2]["attrs"]["feed_wait_s"] >= 0.03
+        assert steps[3]["attrs"]["feed_wait_s"] < 0.03
 
 
 # ---------------------------------------------------------------------------

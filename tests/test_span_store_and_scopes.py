@@ -243,8 +243,8 @@ def test_executor_run_has_feed_dispatch_fetch_children(run):
         assert len(_named("executor.feed")) == 1
 
 
-@pytest.mark.parametrize("prefetch", [0, 2])
-def test_trainer_reader_phase_fires_once_a_batch(prefetch):
+@pytest.mark.parametrize("sync_every_n", [1, 2])
+def test_trainer_reader_phase_fires_once_a_batch(sync_every_n):
     r = np.random.RandomState(7)
     data = [[(r.rand(16).astype(np.float32), r.rand(1).astype(np.float32))
              for _ in range(8)] for _ in range(5)]
@@ -264,14 +264,22 @@ def test_trainer_reader_phase_fires_once_a_batch(prefetch):
                                 feed_list=[x, y], main_program=main,
                                 startup_program=startup)
         tracing.clear()
-        t.train(2, lambda: iter(data), prefetch=prefetch)
+        t.train(2, lambda: iter(data), sync_every_n=sync_every_n)
         reads = _named("trainer.phase.reader")
         assert len(reads) == 2 * len(data)
-        assert len(_named("trainer.phase.feed_pack")) == len(reads)
-        assert len(_named("trainer.step")) == len(reads)
+        packs = _named("trainer.phase.feed_pack")
+        assert len(packs) == len(_named("trainer.phase.h2d")) == len(reads)
+        steps = _named("trainer.step")
+        assert len(steps) == len(reads)
         assert all("error" not in s["attrs"] for s in reads)
-        threads = {s["thread"] for s in reads}
-        assert (threads == {"paddle-tpu-prefetch"}) == bool(prefetch)
+        # the batch is read, packed and staged on the worker's thread,
+        # the step runs on the caller's; one trace holds both
+        assert {s["thread"] for s in reads + packs} == {
+            "paddle-tpu-prefetch"}
+        assert {s["thread"] for s in steps}.isdisjoint(
+            {"paddle-tpu-prefetch"})
+        assert all({"feed_ready", "feed_wait_s"} <= set(s["attrs"])
+                   for s in steps)
 
 
 def test_phased_iter_times_the_pull_and_is_plain_when_off():
