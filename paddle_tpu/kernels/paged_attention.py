@@ -63,16 +63,30 @@ _WINDOW_ALIGN = 8
 def paged_attention_supports(*, d_model: int, n_heads: int,
                              block_size: int, max_blocks_per_seq: int,
                              kv_dtype: str, platform: str,
-                             interpret: bool = False) -> Optional[str]:
+                             interpret: bool = False,
+                             kv_width: Optional[int] = None,
+                             d_head: Optional[int] = None,
+                             ringed: bool = False) -> Optional[str]:
     """None when `select_paged_attention` would return the kernel for
     this geometry on `platform`, else the short reason it is refused
     (what `decoder.kernels` reports after "xla:").  Off a TPU there is
     no Mosaic compiler: refused unless `interpret` (tests) asks for the
-    Pallas interpreter, a correctness harness and never a fast path."""
+    Pallas interpreter, a correctness harness and never a fast path.
+
+    `kv_width` (a pool row: K/V heads x head size), `d_head` and
+    `ringed` (some layers keep a ring of blocks instead of the table)
+    are the K/V geometry; left out they are multi-head attention over
+    `d_model`, the one geometry the kernel computes."""
     if platform != "tpu" and not interpret:
         return "not_tpu"
     if kv_dtype not in _KV_DTYPES:
         return "kv_dtype"
+    if (ringed or kv_width not in (None, d_model)
+            or (d_head is not None and d_head * n_heads != d_model)):
+        # grouped-query heads, a head size that is not d_model /
+        # n_heads, or a second kind of cache: the kernel is multi-head
+        # attention over d_model-wide rows of ONE table
+        return "kv_geometry"
     if d_model % n_heads:
         return "head_split"
     ctx = max_blocks_per_seq * block_size
@@ -143,7 +157,8 @@ def _decode_kernel(tables_ref, pos_ref, q_ref, *refs, nb, bs, n_heads,
 def select_paged_attention(
         *, d_model: int, n_heads: int, block_size: int,
         max_blocks_per_seq: int, kv_dtype: str, platform: str,
-        interpret: bool = False,
+        interpret: bool = False, kv_width: Optional[int] = None,
+        d_head: Optional[int] = None, ringed: bool = False,
 ) -> Tuple[Optional[Callable], Optional[str]]:
     """-> (attend, None), or (None, reason) where
     `paged_attention_supports` refuses: the caller then keeps its XLA
@@ -159,7 +174,8 @@ def select_paged_attention(
     reason = paged_attention_supports(
         d_model=d_model, n_heads=n_heads, block_size=block_size,
         max_blocks_per_seq=max_blocks_per_seq, kv_dtype=kv_dtype,
-        platform=platform, interpret=interpret)
+        platform=platform, interpret=interpret, kv_width=kv_width,
+        d_head=d_head, ringed=ringed)
     if reason is not None:
         return None, reason
     nb, bs = int(max_blocks_per_seq), int(block_size)

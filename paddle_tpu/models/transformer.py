@@ -581,6 +581,23 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     verification (serving/generation.py) run; window rows past
     n_valid write into the null block and return garbage.
 
+    A block with SLIDING-WINDOW layers (`BlockSpec.layer_types`) keeps
+    two kinds of state: `pool_k` and `pool_v` are then each the pair
+    (full layers' pool, sliding layers' pool), `tables` the pair
+    (tables [S, max_blocks_per_seq], rings [S,
+    decoder.window_blocks_per_seq]), and `init_pool` takes the ring
+    pool's size as `window_blocks`.  The table's blocks come from
+    serving/kv_cache.py as ever; a ring belongs to a LANE, not to a
+    sequence (`decoder.slot_rings(S)`: lane s holds ring-pool blocks
+    1 + s * window_blocks_per_seq onward, block 0 being that pool's
+    null block).  A sliding layer writes position p into ring
+    entry (p // block_size) % window_blocks_per_seq and attends over
+    its ring under a mask made from the cursor alone (K is rotated
+    before it is written, so order in the ring does not matter).
+    Such a block is refused an int8 pool and `step_window`, by name.
+    Grouped-query heads and a head size of its own (`n_kv_heads`,
+    `d_head`) make a pool row n_kv_heads * d_head wide.
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -591,6 +608,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     GenerationServer refuses a decoder built for another platform than
     its place's device (`decoder.platform`).
     """
+    import contextlib
     import functools
     import math
     import types
@@ -602,7 +620,6 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     from . import lm_block
 
     d_inner = d_inner or 4 * d_model
-    d_head = d_model // n_heads
     nb, bs = int(max_blocks_per_seq), int(block_size)
     max_len = nb * bs
     if kv_dtype is None:
@@ -621,14 +638,41 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     from ..kernels import paged_attention as _paged_attention
 
     platform = platform or jax.default_backend()
-    _attend, _refused = _paged_attention.select_paged_attention(
-        d_model=d_model, n_heads=n_heads, block_size=bs,
-        max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform)
-
     spec = lm_block.OPT if block is None else block
     if not isinstance(spec, lm_block.BlockSpec):
         raise TypeError(f"block={block!r}: a lm_block.BlockSpec "
                         "(lm_block.OPT, lm_block.olmoe(...)) or None")
+
+    # -- attention geometry, from the description -------------------------
+    # `group` query heads share one K/V head of `d_head` columns; a pool
+    # row is the K/V heads side by side (`d_kv` wide: d_model for plain
+    # multi-head attention).  SLIDING layers keep their K/V in a RING of
+    # `nw` blocks a sequence (the window, in blocks) in a pool of their
+    # own, FULL layers in the table of `nb` blocks.
+    n_kv, d_head = spec.heads(d_model, n_heads)
+    group, d_kv = n_heads // n_kv, n_kv * d_head
+    kinds = [spec.kind_of(l) for l in range(n_layers)]
+    ringed = lm_block.SLIDING in kinds
+    nw = 0
+    if ringed:
+        if spec.window % bs:
+            raise ValueError(
+                f"block {spec.name!r}: window {spec.window} is not a "
+                f"whole number of {bs}-position blocks")
+        if kv_dtype == "int8":
+            raise NotImplementedError(
+                f"block {spec.name!r}: an int8 pool re-quantizes a "
+                "block under the offsets written so far, which a ring "
+                "that overwrites its oldest block in place breaks; "
+                "sliding layers take kv_dtype fp32 or bf16")
+        nw = min(spec.window // bs, nb)
+    # a layer's index inside the pool of its kind
+    pool_index = [kinds[:l].count(k) for l, k in enumerate(kinds)]
+
+    _attend, _refused = _paged_attention.select_paged_attention(
+        d_model=d_model, n_heads=n_heads, block_size=bs,
+        max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform,
+        kv_width=d_kv, d_head=d_head, ringed=ringed)
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -648,7 +692,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     else:
         startup = None
         layout, shapes = lm_block.param_layout(
-            spec, vocab_size, d_model, n_layers, d_inner)
+            spec, vocab_size, d_model, n_heads, n_layers, d_inner)
 
     scale = 1.0 / math.sqrt(d_head)
     # buffer donation makes the pool update in place (no copy of the
@@ -730,12 +774,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             return g[layout.tok][tokens].astype(jnp.float32)
 
     def _rotation(pos):
-        """cos and sin of each row's OWN position (RoPE is per slot,
-        and per window row), or None for a block without."""
+        """{layer kind: cos and sin of each row's OWN position} (RoPE
+        is per slot, and per window row, and a kind of layer may scale
+        it its own way); None a kind for a block without."""
         if spec.positions != "rope":
-            return None
+            return dict.fromkeys(kinds)
         with scope("rope"):
-            return lm_block.rope_tables(spec, pos, d_head)
+            return {kind: lm_block.rope_tables(spec, pos, d_head, kind)
+                    for kind in dict.fromkeys(kinds)}
 
     def _qkv(g, lay, x, rot):
         with scope("qkv"):
@@ -750,7 +796,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # keys, so a cached position is never turned again
             with scope("rope"):
                 q = lm_block.rope(q, *rot, n_heads)
-                kk = lm_block.rope(kk, *rot, n_heads)
+                kk = lm_block.rope(kk, *rot, n_kv)
         return q, kk, vv
 
     def _ffn(g, lay, x, hits):
@@ -777,49 +823,74 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def _with_counts(out, hits):
         return out + ((jnp.stack([h[0] for h in hits]),) if hits else ())
 
-    def _gather(pool, l, tables):
-        """Layer `l` through the block table, as the pool stores it:
-        ([S, NB*BS, D] values in the pool's dtype, [S, NB*BS] float32
-        scales or None).  Layer and table index the pool TOGETHER, so
-        no [num_blocks, BS, D] slice of the pool is copied first, and
-        table order IS logical order, so the rows are the dense
-        cache's rows.  int8 values stay int8: their per-(layer, block)
-        scale is applied to the scores and to the softmax weights
-        (`_attention`), which is the same product."""
-        s_n = tables.shape[0]
-        with scope("kv_gather"):
+    def _kind_scope(name, kind):
+        """`paged_decoder/<name>`, and under it the layer's kind where
+        the block has sliding layers: the gather and the attention of
+        ring and table then read apart, and still sum under `name`."""
+        if not ringed:
+            return scope(name)
+        both = contextlib.ExitStack()
+        both.enter_context(scope(name))
+        both.enter_context(scope(kind.split("_")[0]))
+        return both
+
+    def _gather(pool, l, tables, kind):
+        """Layer `l` (its index in the pool of its kind) through the
+        block table, as the pool stores it: ([S, NB*BS, Dkv] values in
+        the pool's dtype, [S, NB*BS] float32 scales or None).  Layer
+        and table index the pool TOGETHER, so no [num_blocks, BS, Dkv]
+        slice of the pool is copied first, and table order IS logical
+        order (ring order, on a sliding layer), so the rows are the
+        dense cache's rows.  int8 values stay int8: their per-(layer,
+        block) scale is applied to the scores and to the softmax
+        weights (`_attention`), which is the same product."""
+        s_n, rows = tables.shape[0], tables.shape[1] * bs
+        with _kind_scope("kv_gather", kind):
             if kv_dtype == "int8":
                 q, sc_ = pool
-                return (q[l, tables].reshape(s_n, nb * bs, d_model),
+                return (q[l, tables].reshape(s_n, rows, d_kv),
                         jnp.repeat(sc_[l, tables], bs, axis=1))
-            return pool[l, tables].reshape(s_n, nb * bs, d_model), None
+            return pool[l, tables].reshape(s_n, rows, d_kv), None
 
-    def _attention(q, pool_k, pool_v, l, tables, pos_mask):
-        """Attention of q [S, W, D] over layer `l` of the paged pools
-        -> [S, W, D]; pos_mask [S, W, NB*BS] says which logical
-        positions each window row sees.
+    def _attention(q, pool_k, pool_v, l, tables, pos_mask, kind):
+        """Attention of q [S, W, H*dh] over layer `l` of the paged
+        pools -> [S, W, H*dh]; pos_mask [S, W, rows] says which rows
+        of the table (logical positions; ring slots on a sliding
+        layer) each window row sees.
 
-        K and V are read once, in the pool's dtype, with d_model as
-        the minor dimension all the way into the contraction: the
-        query is laid out block-diagonally by head ([S, W*H, D], zero
-        outside a head's d_head columns), so `Qbd . K^T` over d_model
-        IS the per-head score, and `weights . V` gives every head all
-        d_model columns of which it keeps its own.  That spends
-        n_heads times the multiply-adds of a head-split contraction
-        and never reshapes K or V to [.., n_heads, d_head] (on a TPU a
-        relayout of the whole gathered view into half-empty lane
-        tiles) nor widens them to float32.  Scores, mask, softmax and
-        both accumulations are float32."""
+        K and V are read once, in the pool's dtype, with the pool's
+        row (the K/V heads side by side, d_model wide under plain
+        multi-head attention) as the minor dimension all the way into
+        the contraction: the query is laid out block-diagonally by K/V
+        head ([S, W*H, Dkv], a query head's d_head columns in ITS K/V
+        head's columns and zero outside them), so `Qbd . K^T` over Dkv
+        IS the per-head score, and `weights . V` gives every query
+        head all Dkv columns of which it keeps its K/V head's.  That
+        spends n_kv_heads times the multiply-adds of a head-split
+        contraction and never reshapes K or V to [.., heads, d_head]
+        (on a TPU a relayout of the whole gathered view into
+        half-empty lane tiles) nor widens them to float32.  Scores,
+        mask, softmax and both accumulations are float32."""
         s_n, w_n = q.shape[0], q.shape[1]
-        k, k_scale = _gather(pool_k, l, tables)
-        v, v_scale = _gather(pool_v, l, tables)
+        k, k_scale = _gather(pool_k, l, tables, kind)
+        v, v_scale = _gather(pool_v, l, tables, kind)
         batched = ((0,), (0,))
-        with scope("attention"):
-            # [H, D]: column d belongs to head d // d_head
-            head_cols = (jnp.arange(d_model)[None, :] // d_head
-                         == jnp.arange(n_heads)[:, None])
-            q_bd = jnp.where(head_cols, q[:, :, None, :], 0.0).reshape(
-                s_n, w_n * n_heads, d_model)
+        with _kind_scope("attention", kind):
+            # [H, Dkv]: column c belongs to K/V head c // d_head, which
+            # query heads h with h // group == c // d_head share
+            kv_head = jnp.arange(n_heads)[:, None]
+            if group > 1:
+                kv_head = kv_head // group
+            head_cols = jnp.arange(d_kv)[None, :] // d_head == kv_head
+            if (group, d_kv) == (1, d_model):
+                # a head's columns of q ARE its columns of a pool row
+                q_wide = q[:, :, None, :]
+            else:
+                # a head's d_head columns, under every K/V head
+                q_wide = jnp.tile(
+                    q.reshape(s_n, w_n, n_heads, d_head), (1, 1, 1, n_kv))
+            q_bd = jnp.where(head_cols, q_wide, 0.0).reshape(
+                s_n, w_n * n_heads, d_kv)
             sc = jax.lax.dot_general(
                 q_bd, k, (((2,), (2,)), batched),
                 preferred_element_type=jnp.float32) * scale
@@ -833,15 +904,50 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             ctx = jax.lax.dot_general(
                 w_att, v, (((2,), (1,)), batched),
                 preferred_element_type=jnp.float32)
-            return jnp.where(
-                head_cols, ctx.reshape(s_n, w_n, n_heads, d_model),
-                0.0).sum(axis=2)
+            kept = jnp.where(
+                head_cols, ctx.reshape(s_n, w_n, n_heads, d_kv), 0.0)
+            if (group, d_kv) == (1, d_model):
+                return kept.sum(axis=2)
+            return kept.reshape(s_n, w_n, n_heads, n_kv, d_head).sum(
+                axis=3).reshape(s_n, w_n, n_heads * d_head)
+
+    def _by_kind(x):
+        """A pool or the tables as the step is given them, by layer
+        kind: one array (or int8 pair) where every layer is full, else
+        the pair (full layers', sliding layers')."""
+        if not ringed:
+            return {lm_block.FULL: x}
+        return {lm_block.FULL: x[0], lm_block.SLIDING: x[1]}
+
+    def _joined(by_kind):
+        return (tuple(by_kind[k] for k in (lm_block.FULL,
+                                           lm_block.SLIDING))
+                if ringed else by_kind[lm_block.FULL])
+
+    def _ring_cursor(win_tables, positions, active):
+        """Where a sliding layer writes position c and what it then
+        sees, from the cursor alone: the ring's `nw` blocks hold
+        position p at block (p // BS) % nw of the sequence's ring
+        table, so after the write ring slot r holds the newest
+        position p <= c with p = r (mod nw*BS), which is inside the
+        window or (before the first wrap) negative: never written."""
+        lane = jnp.arange(positions.shape[0])
+        with scope("kv_write"):
+            wb = jnp.where(
+                active, win_tables[lane, (positions // bs) % nw], 0)
+        with _kind_scope("attention", lm_block.SLIDING):
+            c = positions[:, None]
+            held = c - (c - jnp.arange(nw * bs)[None, :]) % (nw * bs)
+            return wb, held >= 0
 
     def _step_logits(g, pool_k, pool_v, tables, positions, tokens,
                      active):
         s_n = tokens.shape[0]
         lane = jnp.arange(s_n)
         hits = []
+        pools_k, pools_v = _by_kind(pool_k), _by_kind(pool_v)
+        tabs = _by_kind(tables)
+        tables = tabs[lm_block.FULL]
         x = _embed(g, tokens, positions)                      # [S, D]
         rot = _rotation(positions)
         with scope("kv_write"):
@@ -851,30 +957,38 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # sequence
             wb = jnp.where(active, tables[lane, positions // bs], 0)
             wi = jnp.where(active, positions % bs, 0)
-        with scope("attention"):
+        with _kind_scope("attention", lm_block.FULL):
             # mask over the table's logical span: position j
             # participates iff j <= cursor, which also hides
             # unallocated tail entries
             pos_mask = jnp.arange(nb * bs)[None, :] <= positions[:, None]
-        for l, lay in enumerate(layout.layers):
-            q, kk, vv = _qkv(g, lay, x, rot)
+        cursor = {lm_block.FULL: (wb, pos_mask)}
+        if ringed:
+            cursor[lm_block.SLIDING] = _ring_cursor(
+                tabs[lm_block.SLIDING], positions, active)
+        for lay, kind, li in zip(layout.layers, kinds, pool_index):
+            q, kk, vv = _qkv(g, lay, x, rot[kind])
+            wb, mask = cursor[kind]
             with scope("kv_write"):
-                pool_k = _write(pool_k, l, wb, wi, kk)
-                pool_v = _write(pool_v, l, wb, wi, vv)
+                pools_k[kind] = _write(pools_k[kind], li, wb, wi, kk)
+                pools_v[kind] = _write(pools_v[kind], li, wb, wi, vv)
             if _attend is not None:
                 # Pallas path: block-table reads + dequant + attention
                 # in one kernel; bit-identical to the gather branch
                 # (tests/test_paged_attention.py)
                 with scope("attention"):
-                    ctx_av = _attend(q[:, None, :], pool_k, pool_v,
-                                     tables, positions, l)[:, 0]
+                    ctx_av = _attend(q[:, None, :], pools_k[kind],
+                                     pools_v[kind], tables, positions,
+                                     li)[:, 0]
             else:
-                ctx_av = _attention(q[:, None, :], pool_k, pool_v, l,
-                                    tables, pos_mask[:, None, :])[:, 0]
+                ctx_av = _attention(
+                    q[:, None, :], pools_k[kind], pools_v[kind], li,
+                    tabs[kind], mask[:, None, :], kind)[:, 0]
             with scope("attn_out"):
                 x = x + _fc(g, ctx_av, lay["o"])
             x = _ffn(g, lay, x, hits)
-        return _head(g, x), pool_k, pool_v, hits              # [S, V]
+        return (_head(g, x), _joined(pools_k), _joined(pools_v),
+                hits)                                         # [S, V]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
@@ -917,6 +1031,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     def _step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
                      temps, n_valid):
+        if ringed:
+            raise NotImplementedError(
+                f"block {spec.name!r}: step_window writes a window of "
+                "positions before it attends, and a ring exactly one "
+                "window long has then overwritten keys its first rows "
+                "still see; a block with sliding layers runs `step` "
+                "alone (no draft model, no chunked prefill)")
         # teacher-forced multi-position step: slot s processes window
         # positions positions[s]+j for j < n_valid[s] in one dispatch.
         # Rows past n_valid write to the null block; their predictions
@@ -942,7 +1063,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             pos_mask = (jnp.arange(nb * bs)[None, None, :]
                         <= pos_w[:, :, None])                 # [S, W, L]
         for l, lay in enumerate(layout.layers):
-            q, kk, vv = _qkv(g, lay, x, rot)
+            q, kk, vv = _qkv(g, lay, x, rot[kinds[l]])
             # the whole window's K/V is written before the gather, so
             # in-window attention sees the fresh values; int8 blocks
             # re-quantize per position, in order (the running-max
@@ -961,7 +1082,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                      positions, l)
             else:
                 ctx_av = _attention(q, pool_k, pool_v, l, tables,
-                                    pos_mask)
+                                    pos_mask, kinds[l])
             with scope("attn_out"):
                 x = x + _fc(g, ctx_av, lay["o"])
             x = _ffn(g, lay, x, hits)
@@ -979,32 +1100,56 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         elem_bytes = 2.0
     else:
         # int8 payload + one f32 scale per (layer, block)
-        elem_bytes = 1.0 + 4.0 / (bs * d_model)
-    bytes_per_block = int(2 * n_layers * bs * d_model * elem_bytes)
+        elem_bytes = 1.0 + 4.0 / (bs * d_kv)
+    n_win = kinds.count(lm_block.SLIDING)
+    # K+V of one block over the layers that hold it: a table block
+    # over the full layers, a ring block over the sliding ones
+    bytes_per_block = int(2 * (n_layers - n_win) * bs * d_kv * elem_bytes)
+    window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
 
-    def init_pool(num_blocks, device=None):
-        shape = (n_layers, int(num_blocks), bs, d_model)
-        if kv_dtype == "int8":
-            def z():
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.full((n_layers, int(num_blocks)), 1e-8,
-                                 jnp.float32))
-        elif kv_dtype == "bf16":
-            def z():
-                return jnp.zeros(shape, jnp.bfloat16)
-        else:
-            def z():
-                return jnp.zeros(shape, jnp.float32)
-        zk, zv = z(), z()
-        if device is not None:
-            zk = jax.device_put(zk, device)
-            zv = jax.device_put(zv, device)
-        return zk, zv
+    def init_pool(num_blocks, device=None, window_blocks=None):
+        """Zero pools of `num_blocks` blocks (the null block included)
+        for the full layers and, for a block with sliding layers,
+        `window_blocks` for their rings (read by no other block):
+        (pool_k, pool_v), each one array (an int8 pair) or the pair
+        (full, ring) `step` takes."""
+        def zeros(layers, blocks):
+            shape = (layers, int(blocks), bs, d_kv)
+            if kv_dtype == "int8":
+                z = (jnp.zeros(shape, jnp.int8),
+                     jnp.full(shape[:2], 1e-8, jnp.float32))
+            else:
+                z = jnp.zeros(shape, jnp.bfloat16 if kv_dtype == "bf16"
+                              else jnp.float32)
+            return z if device is None else jax.device_put(z, device)
+
+        def z():
+            if not ringed:
+                return zeros(n_layers, num_blocks)
+            if window_blocks is None:
+                raise ValueError(
+                    f"block {spec.name!r} has sliding layers: "
+                    "init_pool needs window_blocks, the ring pool's "
+                    "size (null block included)")
+            return (zeros(n_layers - n_win, num_blocks),
+                    zeros(n_win, window_blocks))
+
+        return z(), z()
+
+    def slot_rings(slots):
+        """[slots, window_blocks_per_seq] int32: the ring-pool blocks
+        of each lane of a `slots`-lane step, for a pool of
+        `init_pool(..., window_blocks=slots * window_blocks_per_seq
+        + 1)`."""
+        import numpy as np
+
+        return 1 + np.arange(slots * nw, dtype=np.int32).reshape(
+            slots, nw)
 
     decoder = types.SimpleNamespace(
         step=step, step_window=step_window, step_logits=step_logits,
         step_routing=(step_routing if spec.ffn == "moe_swiglu" else None),
-        init_pool=init_pool, platform=platform,
+        init_pool=init_pool, slot_rings=slot_rings, platform=platform,
         step_counters=(("moe_experts_hit",)
                        if spec.ffn == "moe_swiglu" else ()),
         compiler_scopes=(lm_block.MOE_COMPILER_SCOPES
@@ -1013,6 +1158,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,
         bytes_per_block=bytes_per_block,
+        # the ring of a block with sliding layers: blocks a sequence
+        # (0: every layer is full), the window, and a ring block's bytes
+        window_blocks_per_seq=nw, window=spec.window if ringed else 0,
+        window_bytes_per_block=window_bytes_per_block,
         kernels={"paged_attention_decode":
                  "pallas" if _attend is not None else f"xla:{_refused}"})
     return startup, decoder

@@ -341,6 +341,18 @@ class GenerationServer:
     decoder.state_names — same unique-name discipline as the other
     generator builders).  `slots` bounds concurrent sequences,
     `kv_blocks` is the preallocated pool budget shared by ALL of them.
+
+    A decoder whose block has SLIDING-WINDOW layers
+    (`decoder.window_blocks_per_seq` > 0) keeps two kinds of state:
+    `kv_blocks` stays the table pool of its full layers, allocated
+    and released by the cache manager, and the sliding layers' rings
+    live in a second pool that the server sizes itself and never
+    allocates from: slot s's ring is the blocks `decoder.slot_rings`
+    gives it, the same for every sequence the slot serves (a ring is
+    needed whole by any sequence past the window, so a slot IS a
+    ring; docs/serving.md "Two pools").  Such a decoder is served by
+    the one-token step alone: `prefix_cache=True` and a draft model
+    are refused at construction, by name.
     """
 
     def __init__(self, decoder, states, *, slots: int = 8,
@@ -382,6 +394,21 @@ class GenerationServer:
                     "parameters")
 
         _check_states(decoder, states, "target")
+        ring = int(getattr(decoder, "window_blocks_per_seq", 0))
+        if ring and draft_decoder is not None:
+            raise ValueError(
+                "a decoder with sliding-window layers takes no draft "
+                "model: speculative verification writes a window of "
+                "positions before it attends, which a ring one window "
+                "long cannot hold (build_lm_paged_decoder's "
+                "step_window refuses it too)")
+        if ring and prefix_cache:
+            raise ValueError(
+                "prefix_cache=True with sliding-window layers: a "
+                "cached prompt block's sliding-layer K/V lives in the "
+                "ring of the slot that wrote it and is overwritten as "
+                "that slot goes on, so a later hit would attend over "
+                "another request's keys; pass prefix_cache=False")
         if (draft_decoder is None) != (draft_states is None):
             raise ValueError(
                 "speculative decoding needs BOTH draft_decoder and "
@@ -449,8 +476,12 @@ class GenerationServer:
                 and getattr(draft_decoder, "kv_dtype", "fp32")
                 == "int8"))
         # +1: device block 0 is the reserved null/scratch block
+        # (and of the sliding layers' pool, which holds a whole ring
+        # for every slot beside it: the table pool alone decides how
+        # many sequences fit)
         self._pool_k, self._pool_v = decoder.init_pool(
-            kv_blocks + 1, self._device)
+            kv_blocks + 1, self._device,
+            window_blocks=ring * self._slots + 1)
         if draft_decoder is not None:
             self._draft_states = {
                 n: jax.device_put(np.asarray(draft_states[n]),
@@ -467,6 +498,15 @@ class GenerationServer:
         self._active: List[Optional[_Seq]] = [None] * self._slots
         self._tables = np.zeros(
             (self._slots, decoder.max_blocks_per_seq), np.int32)
+        # each slot's ring of the sliding layers' pool, fixed for the
+        # server's life (None where every layer is full): a sequence
+        # admitted to a used slot writes over its predecessor's keys,
+        # and the step's mask shows a sequence only positions it has
+        # written itself
+        self._rings = jax.device_put(
+            decoder.slot_rings(self._slots),
+            self._device) if ring else None
+        self._window = int(getattr(decoder, "window", 0))
         self._queue: deque = deque()
         self._max_queue = int(max_queue)
         self._lock = threading.Condition()
@@ -529,7 +569,7 @@ class GenerationServer:
             # second signature, compiled inside the first request)
             none = np.zeros(self._slots, bool)
             args = (self._states, self._pool_k, self._pool_v,
-                    self._tables, z,
+                    self._step_tables(), z,
                     _feed_tokens()(self._no_tokens, z, none), zs, zt,
                     none)
             # device time by scope: hlo_scopes() can read the resident
@@ -752,6 +792,9 @@ class GenerationServer:
                "kv_blocks_free": self._cache.free_blocks,
                "kv_blocks_total": self._cache.num_blocks,
                "kv_pool_utilization": self._cache.utilization(),
+               # the sliding layers' rings, one a slot (0 without)
+               "kv_window_blocks": 0 if self._rings is None
+               else self._rings.size,
                "kv_dtype": getattr(self._decoder, "kv_dtype", "fp32"),
                "decode_kernel": getattr(self._decoder, "kernels", {})
                .get("paged_attention_decode", "xla"),
@@ -988,13 +1031,10 @@ class GenerationServer:
                 fed = _feed_tokens()(
                     self._no_tokens if prev is None else prev.nxt,
                     tokens, from_prev)
-                # the table is copied: a host array handed to a
-                # dispatch may be read after the call returns, and
-                # eviction and admission rewrite `_tables` meanwhile
                 nxt, self._pool_k, self._pool_v, *counts = (
                     self._decoder.step(
                         self._states, self._pool_k, self._pool_v,
-                        self._tables.copy(), positions, fed, seeds,
+                        self._step_tables(), positions, fed, seeds,
                         temps, active))
             self._inflight = _Tick(rows, nxt, counts)
             for seq in seqs:
@@ -1006,16 +1046,36 @@ class GenerationServer:
                     self._step_counts(sp, prev.counts)
         return prev
 
+    def _step_tables(self):
+        """The tables `step` takes: each slot's block table, with the
+        slots' rings beside it where the decoder has sliding layers.
+        The table is copied: a host array handed to a dispatch may be
+        read after the call returns, and eviction and admission
+        rewrite `_tables` meanwhile (the rings never change)."""
+        if self._rings is None:
+            return self._tables.copy()
+        return self._tables.copy(), self._rings
+
     def _tick_attrs(self, sp, seqs: List[_Seq]) -> None:
         """The scheduler's counts for the tick being dispatched, on its
         live `serving.decode_tick` span: `prefill` slots teacher-force
         a prompt position and deliver nothing (cursor below
         prompt_len - 1), `kv_used` of `kv_total` pool blocks are
-        owned."""
+        owned.  With sliding layers also `past_window` (slots whose
+        cursor is at or past the window: their rings have wrapped) and
+        the K/V rows the tick has to attend over on a layer of each
+        kind, summed over its slots: `kv_rows_full` (cursor + 1) and
+        `kv_rows_win` (the window at most)."""
         sp.set_attr("prefill", sum(1 for s in seqs
                                    if s.cur < s.prompt_len - 1))
         sp.set_attr("kv_used", self._cache.used_blocks)
         sp.set_attr("kv_total", self._cache.num_blocks)
+        if self._window:
+            sp.set_attr("past_window", sum(1 for s in seqs
+                                           if s.cur >= self._window))
+            sp.set_attr("kv_rows_full", sum(s.cur + 1 for s in seqs))
+            sp.set_attr("kv_rows_win", sum(min(s.cur + 1, self._window)
+                                           for s in seqs))
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live

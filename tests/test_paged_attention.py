@@ -180,12 +180,20 @@ def _cell_geometry(workload):
              if w["name"] == workload)
     m = load("perf", "configs", w["config"] + ".json")
     t = load("perf", "traffic", w["traffic"] + ".json")
-    return dict(d_model=m["hidden_size"],
-                n_heads=m["num_attention_heads"],
-                block_size=int(t["block_size"]),
-                max_blocks_per_seq=(int(t["context"])
-                                    // int(t["block_size"])),
-                kv_dtype=t["kv_dtype"])
+    geometry = dict(d_model=m["hidden_size"],
+                    n_heads=m["num_attention_heads"],
+                    block_size=int(t["block_size"]),
+                    max_blocks_per_seq=(int(t["context"])
+                                        // int(t["block_size"])),
+                    kv_dtype=t["kv_dtype"])
+    if "head_dim" in m:
+        # a configuration that states its K/V geometry: what the
+        # decoder derives from the block description
+        geometry.update(
+            d_head=m["head_dim"],
+            kv_width=m["num_key_value_heads"] * m["head_dim"],
+            ringed="sliding_attention" in m.get("layer_types", ()))
+    return geometry
 
 
 CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
@@ -197,6 +205,19 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     ("opt-1.3b-serve-closed32", "tpu", False, "head_dim_misaligned"),
     # d2048, 16 heads of 128, 64 blocks of 16 (context 1024), bf16
     ("olmoe-1b-7b-serve-chat32", "tpu", False, "vmem_scratch"),
+    # d2304, 32 query heads of 128 over 4 K/V heads (rows of 512),
+    # sliding layers on a ring: not the kernel's geometry
+    ("mellum2-12b-a2.5b-serve-agent96", "tpu", False, "kv_geometry"),
+    # each part of that geometry alone is refused too
+    (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=256), "tpu", False,
+     "kv_geometry"),
+    (dict(CHIP_SMOKE, kv_dtype="bf16", d_head=256), "tpu", False,
+     "kv_geometry"),
+    (dict(CHIP_SMOKE, kv_dtype="bf16", ringed=True), "tpu", False,
+     "kv_geometry"),
+    # stated and plain (a pool row IS d_model): the kernel
+    (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=1024, d_head=128), "tpu",
+     False, None),
     # chip_smoke.py --legs serve_lm: heads of 128, context 512
     (dict(CHIP_SMOKE, kv_dtype="fp32"), "tpu", False, None),
     (dict(CHIP_SMOKE, kv_dtype="int8"), "tpu", False, None),
@@ -204,7 +225,9 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     (dict(CHIP_SMOKE, kv_dtype="fp32"), "cpu", False, "not_tpu"),
     # ...unless a test asks for the Pallas interpreter
     (dict(CHIP_SMOKE, kv_dtype="fp32"), "cpu", True, None),
-], ids=["opt-1.3b-tpu", "olmoe-1b-7b-1chip-tpu", "chip_smoke-fp32-tpu",
+], ids=["opt-1.3b-tpu", "olmoe-1b-7b-1chip-tpu", "mellum2-1chip-tpu",
+        "grouped-kv-tpu", "wide-heads-tpu", "ring-tpu", "stated-mha-tpu",
+        "chip_smoke-fp32-tpu",
         "chip_smoke-int8-tpu", "chip_smoke-cpu",
         "chip_smoke-cpu-interpret"])
 def test_selection_follows_geometry_and_platform(geometry, platform,
