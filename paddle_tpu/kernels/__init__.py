@@ -6,9 +6,11 @@ covers what fusion cannot: the attention inner loop (flash attention — the
 reference has no attention kernel at all, SURVEY.md §5.7) where materializing
 the [q, k] score matrix in HBM is the bandwidth bottleneck.
 Paged-attention decode (in-kernel block-table reads and fused dequant)
-is the serving path's kernel.  Each kernel's module decides from the
+and the expert layer's grouped matmul (each expert's matrices meet its
+own rows only) are the serving path's kernels.  Each kernel's module decides from the
 shapes, dtypes and platform it is called with whether the Pallas kernel
 or the XLA composition runs (docs/performance.md "Kernel selection").
 """
 from .flash_attention import flash_attention, flash_attention_reference  # noqa: F401
+from .grouped_matmul import grouped_matmul_supports, select_grouped_matmul  # noqa: F401
 from .paged_attention import paged_attention_supports, select_paged_attention  # noqa: F401

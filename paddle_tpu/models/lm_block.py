@@ -271,7 +271,8 @@ def rope(x, cos, sin, n_heads: int):
 
 
 # What the TPU compiler calls the instructions it makes of `moe_ffn`'s
-# `ragged_dot`s (one offsets call, then a grouped matmul each), under
+# `ragged_dot`s (the fallback where the Pallas grouped matmul is
+# refused: one offsets call, then a grouped matmul each), under
 # an `op_name` of its own that drops the scope they were traced under:
 # {its op_name: the step's scope}, for `profiler.register_jitted`.
 MOE_COMPILER_SCOPES = {"ragged-dot-none": "paged_decoder/moe_experts",
@@ -299,20 +300,25 @@ def route(spec: BlockSpec, m, w_router):
 
 
 def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
-            scope=None):
+            scope=None, experts=None):
     """Dropless top-k-of-E SwiGLU expert layer over tokens m [T, D]
     (float32) -> ([T, D] float32, experts hit: int32 scalar, routing:
     `route`'s (weights, experts)).
 
     Every one of the T*k assignments is computed: they are sorted by
-    expert and run as three grouped matmuls (`jax.lax.ragged_dot`, one
-    group an expert), so an expert's matrices are read once however
-    many rows it has and NO capacity bounds a group: what one token
-    gets never depends on where the others went, which is what keeps a
-    continuously batched sequence bit-identical to the same sequence
-    alone.  Routing is `route`'s; the expert matmuls take the weights'
-    dtype with float32 accumulation.  A token's k results are summed
-    in top-k order."""
+    expert and run as grouped matmuls (one group an expert), so an
+    expert's matrices are read once however many rows it has and NO
+    capacity bounds a group: what one token gets never depends on
+    where the others went, which is what keeps a continuously batched
+    sequence bit-identical to the same sequence alone.  Routing is
+    `route`'s; the expert matmuls take the weights' dtype with float32
+    accumulation.  A token's k results are summed in top-k order.
+
+    `experts` is what `kernels.grouped_matmul.select_grouped_matmul`
+    returned for these shapes: the Pallas kernel (gate, up and the
+    gated product in one call, down in a second, over work items it
+    plans from the group sizes under `moe_dispatch`), or None: three
+    `jax.lax.ragged_dot`s, the one fallback."""
     import contextlib
 
     import jax
@@ -328,15 +334,20 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
         sizes = jnp.zeros(e_n, jnp.int32).at[flat_e].add(1)
         rows = m[order // k_n].astype(w_gate.dtype)         # [T*k, D]
         hit = jnp.sum(sizes > 0).astype(jnp.int32)
+        plan = None if experts is None else experts.plan(sizes)
     with scope("moe_experts"):
         f32 = jnp.float32
-        gate = jax.lax.ragged_dot(rows, w_gate, sizes,
-                                  preferred_element_type=f32)
-        up = jax.lax.ragged_dot(rows, w_up, sizes,
-                                preferred_element_type=f32)
-        act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
-        out = jax.lax.ragged_dot(act, w_down, sizes,
-                                 preferred_element_type=f32)
+        if experts is not None:
+            act = experts.gate_up(rows, w_gate, w_up, plan)
+            out = experts.down(act, w_down, plan)
+        else:
+            gate = jax.lax.ragged_dot(rows, w_gate, sizes,
+                                      preferred_element_type=f32)
+            up = jax.lax.ragged_dot(rows, w_up, sizes,
+                                    preferred_element_type=f32)
+            act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
+            out = jax.lax.ragged_dot(act, w_down, sizes,
+                                     preferred_element_type=f32)
     with scope("moe_combine"):
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(t_n * k_n, dtype=order.dtype))

@@ -635,6 +635,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # table inside the Pallas kernel (fused dequant, no logical-order
     # gather copy) or the XLA gather below runs
     # (docs/performance.md "Kernel selection")
+    from ..kernels import grouped_matmul as _grouped_matmul
     from ..kernels import paged_attention as _paged_attention
 
     platform = platform or jax.default_backend()
@@ -809,9 +810,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 return x + _fc(g, jax.nn.relu(_fc(g, h2, lay["w1"])),
                                lay["w2"])
         h2 = h2.reshape(-1, d_model)
+        w_gate = g[lay["gate"][0]]
+        # the kernel's own module decides from the rows of this trace,
+        # the widths, the weights' dtype and the platform whether the
+        # grouped matmuls are its Pallas kernel or `ragged_dot`
+        experts, refused = _grouped_matmul.select_grouped_matmul(
+            rows=h2.shape[0] * spec.experts_per_token, d_model=d_model,
+            d_ff=w_gate.shape[-1], n_experts=spec.n_experts,
+            dtype=w_gate.dtype, platform=platform)
+        decoder.expert_kernel = (experts.name if experts is not None
+                                 else f"xla:{refused}")
         y, hit, routed = lm_block.moe_ffn(
-            spec, h2, g[lay["router"][0]], g[lay["gate"][0]],
-            g[lay["up"][0]], g[lay["down"][0]], scope=scope)
+            spec, h2, g[lay["router"][0]], w_gate, g[lay["up"][0]],
+            g[lay["down"][0]], scope=scope, experts=experts)
         hits.append((hit, h2) + routed)
         with scope("moe_combine"):
             return x + y.reshape(x.shape)
@@ -1163,7 +1174,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         window_blocks_per_seq=nw, window=spec.window if ringed else 0,
         window_bytes_per_block=window_bytes_per_block,
         kernels={"paged_attention_decode":
-                 "pallas" if _attend is not None else f"xla:{_refused}"})
+                 "pallas" if _attend is not None else f"xla:{_refused}"},
+        # what the expert layer of the step traced last runs: the
+        # Pallas grouped matmul's name, or "xla:<reason>" where
+        # `ragged_dot` does; None until a step is traced (the weights'
+        # dtype and the rows are the step's arguments, not the
+        # builder's) and for a block without experts
+        expert_kernel=None)
     return startup, decoder
 
 

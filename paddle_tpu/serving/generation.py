@@ -798,6 +798,11 @@ class GenerationServer:
                "kv_dtype": getattr(self._decoder, "kv_dtype", "fp32"),
                "decode_kernel": getattr(self._decoder, "kernels", {})
                .get("paged_attention_decode", "xla"),
+               # the expert layer of the step traced last: the Pallas
+               # grouped matmul's name or "xla:<reason>"; None for a
+               # block without experts
+               "expert_kernel": getattr(self._decoder, "expert_kernel",
+                                        None),
                "kv_bytes_resident": (self._cache.used_blocks
                                      * self._cache.bytes_per_block),
                "draft_proposed": int(self._m_proposed.value),
@@ -1065,7 +1070,9 @@ class GenerationServer:
         cursor is at or past the window: their rings have wrapped) and
         the K/V rows the tick has to attend over on a layer of each
         kind, summed over its slots: `kv_rows_full` (cursor + 1) and
-        `kv_rows_win` (the window at most)."""
+        `kv_rows_win` (the window at most).  With experts `moe_kernel`:
+        1 where the step's expert layer is the Pallas grouped matmul
+        (`decoder.expert_kernel`), 0 where `ragged_dot`."""
         sp.set_attr("prefill", sum(1 for s in seqs
                                    if s.cur < s.prompt_len - 1))
         sp.set_attr("kv_used", self._cache.used_blocks)
@@ -1076,6 +1083,10 @@ class GenerationServer:
             sp.set_attr("kv_rows_full", sum(s.cur + 1 for s in seqs))
             sp.set_attr("kv_rows_win", sum(min(s.cur + 1, self._window)
                                            for s in seqs))
+        expert_kernel = getattr(self._decoder, "expert_kernel", None)
+        if expert_kernel is not None:
+            sp.set_attr("moe_kernel",
+                        int(not expert_kernel.startswith("xla:")))
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live

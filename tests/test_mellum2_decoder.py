@@ -11,6 +11,7 @@ window (8 positions, 2 blocks) is shorter than the sequences, so every
 comparison runs past the ring's first wrap.  What is compared is
 LOGITS, never tokens.
 """
+import functools
 import importlib.util
 import json
 import math
@@ -22,6 +23,7 @@ import pytest
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
+from paddle_tpu.kernels import grouped_matmul
 from paddle_tpu.models import lm_block
 from paddle_tpu.models.transformer import build_lm_paged_decoder
 from paddle_tpu.observability import tracing
@@ -202,11 +204,20 @@ def test_reference_follows_where_told_and_takes_its_own_elsewhere():
     assert moved[:10].max() == 0.0 and moved[10:].min() > 0.0
 
 
-def test_batched_slot_bit_identical_to_the_same_sequence_alone():
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "pallas_interpreted"])
+def test_batched_slot_bit_identical_to_the_same_sequence_alone(
+        kernel, monkeypatch):
     """Three sequences of different lengths in one call of the same
     four-lane step, the sequence in another lane and other table and
     ring blocks than alone: bit for bit the same logits (no capacity
-    in the expert layer, no slot in the mask, no order in the ring)."""
+    in the expert layer, no slot in the mask, no order in the ring),
+    through `ragged_dot` and through the Pallas grouped matmul under
+    the interpreter (the step asks for the kernel when it is traced)."""
+    if kernel:
+        monkeypatch.setattr(
+            grouped_matmul, "select_grouped_matmul", functools.partial(
+                grouped_matmul.select_grouped_matmul, interpret=True))
     dec = _decoder()
     g = _weights(dec, seed=3)
     others = [list(np.random.RandomState(s).randint(0, V, n))
@@ -215,6 +226,8 @@ def test_batched_slot_bit_identical_to_the_same_sequence_alone():
     together = _drive(dec, g, [others[0], SEQ, others[1]], slots=4,
                       lanes=[3, 1, 0])
     assert np.array_equal(together[1], alone)
+    assert dec.expert_kernel == (grouped_matmul.NAME if kernel
+                                 else "xla:not_tpu")
 
 
 def test_yarn_table_is_the_published_equations():

@@ -8,6 +8,7 @@ largest logit changes on rounding.  `rel` is the largest |difference|
 over the largest |reference logit|.
 """
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
+from paddle_tpu.kernels import grouped_matmul
 from paddle_tpu.models import lm_block
 from paddle_tpu.models.transformer import build_lm_paged_decoder
 from paddle_tpu.observability import tracing
@@ -204,14 +206,30 @@ def test_step_window_matches_step():
                                atol=1e-5)
 
 
+def _through_the_kernel(monkeypatch):
+    """From here to the test's end `select_grouped_matmul`, which a
+    step calls when it is TRACED, asks for the Pallas interpreter: the
+    entry point's own argument for tests."""
+    monkeypatch.setattr(
+        grouped_matmul, "select_grouped_matmul", functools.partial(
+            grouped_matmul.select_grouped_matmul, interpret=True))
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "pallas_interpreted"])
 @pytest.mark.parametrize("router", [None, 0.0],
                          ids=["routed_apart", "all_to_the_same_experts"])
-def test_batched_slot_bit_identical_to_the_same_sequence_alone(router):
+def test_batched_slot_bit_identical_to_the_same_sequence_alone(
+        router, kernel, monkeypatch):
     """The dropless property: what a slot's token gets never depends on
     where the other slots' tokens went.  With a zero router every
     probability ties, `top_k` takes experts 0 and 1 for EVERY token of
     every slot, and a layer with a capacity would drop most of them;
-    here the batched slot is bit for bit the slot alone."""
+    here the batched slot is bit for bit the slot alone, through
+    `ragged_dot` and through the Pallas grouped matmul (whose row
+    tiles the other slots' rows share)."""
+    if kernel:
+        _through_the_kernel(monkeypatch)
     dec = _decoder()
     g = _weights(dec, 4, router=router)
     r = np.random.RandomState(9)
@@ -223,6 +241,8 @@ def test_batched_slot_bit_identical_to_the_same_sequence_alone(router):
         (alone,), _ = _drive(dec, g, [s], slots=4, lanes=[lane])
         assert np.array_equal(alone, batched[lane])
         assert _rel(alone, _ref_logits(g, s)) <= TOL_FP32
+    assert dec.expert_kernel == (grouped_matmul.NAME if kernel
+                                 else "xla:not_tpu")
 
 
 def test_every_token_to_one_expert_loses_nothing():
@@ -420,10 +440,16 @@ def test_block_description_is_checked():
     assert len(dec.state_names) == 4 + L * 12 - 1
 
 
-def test_generation_server_serves_the_block_and_counts_experts():
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "pallas_interpreted"])
+def test_generation_server_serves_the_block_and_counts_experts(
+        kernel, monkeypatch):
     """The normal path: GenerationServer over the same decoder, mixed
-    admissions bit-identical to solo runs, and the span attribute this
-    block brings (`moe_experts_hit`)."""
+    admissions bit-identical to solo runs, and the span attributes
+    this block brings (`moe_experts_hit`; `moe_kernel`, which with
+    `stats()["expert_kernel"]` says what the expert layer runs as)."""
+    if kernel:
+        _through_the_kernel(monkeypatch)
     dec = _decoder()
     states = {n: np.asarray(v) for n, v in _weights(dec, 12).items()}
     r = np.random.RandomState(13)
@@ -433,6 +459,8 @@ def test_generation_server_serves_the_block_and_counts_experts():
     def serve(together):
         srv = GenerationServer(dec, states, slots=3, kv_blocks=18,
                                place=fluid.CPUPlace())
+        assert srv.stats()["expert_kernel"] == (
+            grouped_matmul.NAME if kernel else "xla:not_tpu")
         try:
             if not together:
                 return [srv.submit(p, m).result(timeout=60)
@@ -460,23 +488,45 @@ def test_generation_server_serves_the_block_and_counts_experts():
     assert any(a["ahead"] for a in ticks)
     assert all(L <= a["moe_experts_hit"] <= L * min(E, 3 * K)
                for a in ticks if a["ahead"])
+    assert all(a["moe_kernel"] == int(kernel) for a in ticks)
 
 
 def test_expert_layer_lowers_for_tpu_as_three_grouped_matmuls():
     """At the published widths (32 tokens, 64 experts of 2048 x 1024,
-    bf16) the expert layer reaches the TPU lowering as three grouped
-    matmuls over the sorted rows: no [tokens, experts, capacity]
-    one-hot and no float32 copy of an expert tensor."""
+    bf16) the expert layer reaches the TPU lowering as grouped matmuls
+    over the sorted rows: the Pallas kernel's two Mosaic calls (gate,
+    up and the gated product in one, down in the other) where
+    selection takes it, three `ragged_dot`s where it does not (the CPU
+    every tier-1 test runs on); on neither path a [tokens, experts,
+    capacity] one-hot or a float32 copy of an expert tensor."""
     spec = lm_block.olmoe()
     sds, bf = jax.ShapeDtypeStruct, jnp.bfloat16
     args = (sds((32, 2048), jnp.float32), sds((2048, 64), bf),
             sds((64, 2048, 1024), bf), sds((64, 2048, 1024), bf),
             sds((64, 1024, 2048), bf))
-    text = jax.jit(lambda *a: lm_block.moe_ffn(spec, *a)[0]).trace(
-        *args).lower(lowering_platforms=("tpu",)).as_text()
+
+    def lowered(platform):
+        experts, reason = grouped_matmul.select_grouped_matmul(
+            rows=32 * 8, d_model=2048, d_ff=1024, n_experts=64, dtype=bf,
+            platform=platform)
+        traced = jax.jit(lambda *a: lm_block.moe_ffn(
+            spec, *a, experts=experts)[0]).trace(*args)
+        text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        assert "tensor<64x2048x1024xf32>" not in text
+        assert "tensor<64x1024x2048xf32>" not in text
+        return reason, text, str(traced.jaxpr)
+
+    reason, text, jaxpr = lowered("tpu")
+    assert reason is None
+    assert text.count("tpu_custom_call") == 2
+    assert "ragged_dot" not in text and "ragged_dot" not in jaxpr
+    # what a CPU build traces (lowered for the TPU all the same: the
+    # CPU's own lowering expands the op into a loop over groups)
+    reason, text, jaxpr = lowered("cpu")
+    assert reason == "not_tpu"
+    assert jaxpr.count("= ragged_dot_general[") == 3
     assert text.count('"chlo.ragged_dot"') == 3
-    assert "tensor<64x2048x1024xf32>" not in text
-    assert "tensor<64x1024x2048xf32>" not in text
+    assert "tpu_custom_call" not in text
 
 
 def test_compiler_made_op_names_resolve_to_the_owners_scope():

@@ -247,6 +247,6 @@ echo "== [14/14] kernels: Pallas/XLA parity, selection, TPU lowering =="
 # (docs/performance.md "Kernel selection")
 JAX_PLATFORMS=cpu PADDLE_TPU_VERIFY=error python -m pytest \
     tests/test_paged_attention.py tests/test_kernels_lower_tpu.py \
-    -q -m 'not slow' -p no:cacheprovider
+    tests/test_grouped_matmul.py -q -m 'not slow' -p no:cacheprovider
 
 echo "ci_check: all green"
