@@ -26,7 +26,24 @@ architecture is a second description and not a second decoder.
                "full_attention" sees all), and RoPE parameters per kind
                (`rope_parameters`: "default" or "yarn").
 
-All have an untied output head; a tied one would be one more field.
+  granite-     the fourth (IBM Granite 4.0-H, `model_type:
+  hybrid-like  granitemoehybrid`), again fields and no constructor:
+               a layer kind with NO attention ("mamba": a Mamba-2
+               mixer, arXiv:2405.21060, whose cache is a recurrent
+               state of fixed size a lane, `mamba2_step`) beside
+               "attention" (full attention under another name), no
+               position signal at all (`positions: "none"`), a SHARED
+               SwiGLU expert every token takes beside the routed ones
+               (`shared_d_inner`), the experts HELD here a contiguous
+               range of those routed over (`experts_first`,
+               `experts_held`: one chip's share of an expert-parallel
+               layer), an output head tied to the embedding
+               (`tied_head`) and four scalar multipliers.  Its router
+               needs no kind of its own: Granite takes the k largest
+               LOGITS and a softmax over those k, which is `route`
+               under `norm_topk_prob` (p_i / sum of the chosen p_j =
+               exp(l_i) / sum of the chosen exp(l_j)).
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -43,9 +60,12 @@ from typing import Dict, Tuple
 
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
-           "MOE_COMPILER_SCOPES", "SLIDING", "FULL"]
+           "swiglu", "mamba2_step", "MOE_COMPILER_SCOPES", "SLIDING",
+           "FULL", "MAMBA", "ATTENTION"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# Granite's names: a layer with no attention, and full attention
+MAMBA, ATTENTION = "mamba", "attention"
 
 
 def _frozen(value):
@@ -62,13 +82,14 @@ def _frozen(value):
 class BlockSpec:
     """One decoder block.  Valid today: `OPT`, what `olmoe(...)`
     returns (any expert count, top-k, theta, eps), and OLMoE's block
-    with `qk_norm` off and the attention geometry below (module
+    with `qk_norm` off and the attention geometry below, and that
+    block with Mamba-2 layers and the fields under them (module
     docstring).  `layer_types` and `rope_parameters` may be given as
     the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
     name: str
     norm: str                       # "layer_norm" | "rms_norm"
-    positions: str                  # "learned" | "rope"
+    positions: str                  # "learned" | "rope" | "none"
     ffn: str                        # "relu" | "moe_swiglu"
     bias: bool                      # on every projection and the head
     qk_norm: bool = False           # a norm on all of Q and of K
@@ -80,18 +101,46 @@ class BlockSpec:
     # -- attention geometry: 0 / empty = "as the model's width gives it"
     n_kv_heads: int = 0             # K/V heads (0: one a query head)
     d_head: int = 0                 # a head's size (0: d_model/n_heads)
-    layer_types: tuple = ()         # SLIDING | FULL a layer (): all FULL
+    layer_types: tuple = ()         # a kind a layer; (): all FULL
     window: int = 0                 # keys a SLIDING layer sees
     rope_parameters: tuple = ()     # {kind: {"rope_type", ...}}, frozen
+    # -- the expert layer's share: the experts HELD here are
+    #    [experts_first, experts_first + experts_held) of the n_experts
+    #    the router routes over (0 held: all of them)
+    experts_first: int = 0
+    experts_held: int = 0
+    shared_d_inner: int = 0         # a shared SwiGLU expert's width
+    tied_head: bool = False         # the head is the embedding's rows
+    # -- scalar multipliers (Granite's four)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0    # on each sub-block's output
+    attention_multiplier: float = 0.0   # on q.k (0: 1 / sqrt(d_head))
+    logits_scaling: float = 1.0         # the logits are DIVIDED by it
+    # -- a MAMBA layer's geometry (Mamba-2, one group of B and C)
+    ssm_heads: int = 0              # heads H
+    ssm_d_head: int = 0             # a head's size P (H * P columns)
+    ssm_d_state: int = 0            # the state's size N
+    ssm_conv: int = 0               # the causal convolution's width
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters"):
             object.__setattr__(self, name, _frozen(getattr(self, name)))
-        bad = set(self.layer_types) - {SLIDING, FULL}
+        bad = set(self.layer_types) - {SLIDING, FULL, MAMBA, ATTENTION}
         if bad:
             raise ValueError(f"layer_types: unknown kind(s) {sorted(bad)}")
         if SLIDING in self.layer_types and self.window < 1:
             raise ValueError(f"{SLIDING} layers need window >= 1")
+        if not 0 <= self.experts_first <= (
+                self.n_experts - self.experts_held):
+            raise ValueError(
+                f"experts [{self.experts_first}, {self.experts_first} + "
+                f"{self.experts_held}) are not among the "
+                f"{self.n_experts} routed over")
+
+    @property
+    def held(self):
+        """(first, count) of the experts whose matrices are here."""
+        return self.experts_first, self.experts_held or self.n_experts
 
     def heads(self, d_model: int, n_heads: int):
         """(K/V heads, head size) at a model width and query heads."""
@@ -111,7 +160,8 @@ class BlockSpec:
             raise ValueError(
                 f"block {self.name!r}: {len(self.layer_types)} "
                 f"layer_types, and a layer {layer}")
-        return self.layer_types[layer]
+        kind = self.layer_types[layer]
+        return FULL if kind == ATTENTION else kind
 
     def rope_of(self, kind: str) -> dict:
         """RoPE parameters of a layer kind: the kind's entry of
@@ -142,16 +192,41 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                  n_heads: int, n_layers: int, d_inner: int):
     """(layout, shapes) of a block whose parameters are named by the
     description itself (no training Program).  `layout` is what the
-    step reads: `.tok`, `.pos` (None under RoPE), `.layers[l]` (a dict
-    of (weight-or-scale, bias-or-shift-or-None) name pairs), `.final`,
-    `.head`.  Q and O are [d, H*dh] and [H*dh, d], K and V
-    [d, Hkv*dh]: all [d, d] where the heads split the model's width."""
-    if (spec.norm, spec.positions, spec.ffn, spec.bias) != (
-            "rms_norm", "rope", "moe_swiglu", False):
+    step reads: `.tok`, `.pos` (None without a position table),
+    `.layers[l]` (a dict of (weight-or-scale, bias-or-shift-or-None)
+    name pairs), `.final`, `.head` (the embedding's own pair under
+    `tied_head`: the step multiplies by its transpose).  Q and O are
+    [d, H*dh] and [H*dh, d], K and V [d, Hkv*dh]: all [d, d] where the
+    heads split the model's width.  A MAMBA layer has, in place of
+    those four, the mixer's: `ssm_in` [d, 2*H*P + 2*N + H] (z, then x B
+    C, then dt, side by side), the depthwise convolution over x B C
+    (`ssm_conv`: [width, H*P + 2*N] and its bias), `ssm_dt` (dt's
+    bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
+    `ssm_out` [H*P, d]; no bias on a projection.  The expert matrices
+    are [experts HELD, ...]; the router keeps its published width."""
+    if (spec.norm, spec.ffn, spec.bias) != ("rms_norm", "moe_swiglu",
+                                            False):
         raise NotImplementedError(
             f"block {spec.name!r}: only the OLMoE combination is laid "
             "out from its description; OPT's names come from the "
             "training Program")
+    kinds = [spec.kind_of(l) for l in range(n_layers)]
+    mamba = MAMBA in kinds
+    if spec.positions != ("none" if mamba else "rope"):
+        raise NotImplementedError(
+            f"block {spec.name!r}: positions {spec.positions!r} "
+            f"{'with' if mamba else 'without'} Mamba layers; built are "
+            "RoPE on a block of attention layers, and no position "
+            "signal where Mamba layers carry the order")
+    if mamba and SLIDING in kinds:
+        raise NotImplementedError(
+            f"block {spec.name!r}: Mamba layers beside sliding-window "
+            "layers (a lane's state, a ring and the table at once)")
+    if mamba and min(spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state,
+                     spec.ssm_conv - 1) < 1:
+        raise ValueError(
+            f"block {spec.name!r}: Mamba layers need ssm_heads, "
+            "ssm_d_head, ssm_d_state and ssm_conv >= 2")
     n_kv, d_head = spec.heads(d_model, n_heads)
     if spec.qk_norm and (n_kv * d_head, n_heads * d_head) != (
             d_model, d_model):
@@ -159,13 +234,16 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             f"block {spec.name!r}: qk_norm is built over all of Q and "
             "K at the model's width, not per head of a grouped or "
             "wider geometry")
-    for kind in set(spec.layer_types) or {FULL}:
+    for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
         if spec.rope_of(kind)["rope_type"] not in ("default", "yarn"):
             raise NotImplementedError(
                 f"block {spec.name!r}: rope_type "
                 f"{spec.rope_of(kind)['rope_type']!r} on {kind} layers")
     d, e, f = int(d_model), spec.n_experts, int(d_inner)
+    held, fs = spec.held[1], spec.shared_d_inner
     dq, dkv = n_heads * d_head, n_kv * d_head
+    di = spec.ssm_heads * spec.ssm_d_head
+    conv = di + 2 * spec.ssm_d_state
     shapes: Dict[str, Tuple[int, ...]] = {}
 
     def add(name, *shape):
@@ -173,26 +251,44 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         return name, None
 
     layers = []
-    for l in range(n_layers):
+    for l, kind in enumerate(kinds):
         p = f"layer_{l}."
-        lay = {"norm1": add(p + "attn_norm.scale_0", d),
-               "q": add(p + "q_proj.w_0", d, dq),
-               "k": add(p + "k_proj.w_0", d, dkv),
-               "v": add(p + "v_proj.w_0", d, dkv),
-               "o": add(p + "o_proj.w_0", dq, d),
-               "norm2": add(p + "ffn_norm.scale_0", d),
-               "router": add(p + "router.w_0", d, e),
-               "gate": add(p + "experts_gate.w_0", e, d, f),
-               "up": add(p + "experts_up.w_0", e, d, f),
-               "down": add(p + "experts_down.w_0", e, f, d)}
+        if kind == MAMBA:
+            lay = {"norm1": add(p + "mixer_norm.scale_0", d),
+                   "ssm_in": add(p + "ssm_in_proj.w_0", d,
+                                 di + conv + spec.ssm_heads),
+                   "ssm_conv": (add(p + "ssm_conv.w_0", spec.ssm_conv,
+                                    conv)[0],
+                                add(p + "ssm_conv.b_0", conv)[0]),
+                   "ssm_dt": add(p + "ssm_dt.b_0", spec.ssm_heads),
+                   "ssm_a_log": add(p + "ssm_a_log.w_0", spec.ssm_heads),
+                   "ssm_d": add(p + "ssm_d.w_0", spec.ssm_heads),
+                   "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
+                   "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
+        else:
+            lay = {"norm1": add(p + "attn_norm.scale_0", d),
+                   "q": add(p + "q_proj.w_0", d, dq),
+                   "k": add(p + "k_proj.w_0", d, dkv),
+                   "v": add(p + "v_proj.w_0", d, dkv),
+                   "o": add(p + "o_proj.w_0", dq, d)}
+        lay.update({"norm2": add(p + "ffn_norm.scale_0", d),
+                    "router": add(p + "router.w_0", d, e),
+                    "gate": add(p + "experts_gate.w_0", held, d, f),
+                    "up": add(p + "experts_up.w_0", held, d, f),
+                    "down": add(p + "experts_down.w_0", held, f, d)})
+        if fs:
+            lay.update({"shared_gate": add(p + "shared_gate.w_0", d, fs),
+                        "shared_up": add(p + "shared_up.w_0", d, fs),
+                        "shared_down": add(p + "shared_down.w_0", fs, d)})
         if spec.qk_norm:
             lay["q_norm"] = add(p + "q_norm.scale_0", d)
             lay["k_norm"] = add(p + "k_norm.scale_0", d)
         layers.append(lay)
+    tok = add("tok_embedding.w_0", vocab_size, d)
     layout = types.SimpleNamespace(
-        tok=add("tok_embedding.w_0", vocab_size, d)[0], pos=None,
-        layers=layers, final=add("final_norm.scale_0", d),
-        head=add("lm_head.w_0", d, vocab_size))
+        tok=tok[0], pos=None, layers=layers,
+        final=add("final_norm.scale_0", d),
+        head=tok if spec.tied_head else add("lm_head.w_0", d, vocab_size))
     return layout, shapes
 
 
@@ -286,7 +382,10 @@ def route(spec: BlockSpec, m, w_router):
     bf16 pass moves a probability by 1e-3 of itself and swaps the k-th
     and k+1-th expert wherever they lie that close); the weights are
     the probabilities as they are, renormalised only under
-    `norm_topk_prob`."""
+    `norm_topk_prob`.  Renormalised, they are also the softmax over
+    the k largest LOGITS alone (Granite's form): p_i / sum of the
+    chosen p_j = exp(l_i) / sum of the chosen exp(l_j), and the k
+    largest probabilities are the k largest logits."""
     import jax
     import jax.numpy as jnp
 
@@ -314,6 +413,14 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     `route`'s; the expert matmuls take the weights' dtype with float32
     accumulation.  A token's k results are summed in top-k order.
 
+    Where the description HOLDS a share of the experts (`spec.held`:
+    `w_gate`, `w_up`, `w_down` are then those experts' matrices alone)
+    the router still routes over all of them and the result is the
+    part the held ones give: an assignment to an absent expert sorts
+    past the last group, is in no group and adds nothing, and its
+    weight is NOT shared out among the others (the chip that holds the
+    expert adds that part).  `hit` counts held experts.
+
     `experts` is what `kernels.grouped_matmul.select_grouped_matmul`
     returned for these shapes: the Pallas kernel (gate, up and the
     gated product in one call, down in a second, over work items it
@@ -325,16 +432,26 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     import jax.numpy as jnp
 
     scope = scope or (lambda name: contextlib.nullcontext())
-    t_n, k_n, e_n = m.shape[0], spec.experts_per_token, spec.n_experts
+    t_n, k_n = m.shape[0], spec.experts_per_token
+    first, e_n = spec.held
+    share = e_n < spec.n_experts
     with scope("moe_router"):
         top_w, top_e = route(spec, m, w_router)             # [T, k]
     with scope("moe_dispatch"):
         flat_e = top_e.reshape(t_n * k_n)
+        if share:
+            # the held experts count from 0; an absent one is e_n,
+            # which sorts last and falls off the end of `sizes`
+            here = (top_e >= first) & (top_e < first + e_n)
+            flat_e = jnp.where(here.reshape(-1), flat_e - first, e_n)
         order = jnp.argsort(flat_e, stable=True)            # by expert
-        sizes = jnp.zeros(e_n, jnp.int32).at[flat_e].add(1)
+        sizes = jnp.zeros(e_n, jnp.int32).at[flat_e].add(1, mode="drop")
         rows = m[order // k_n].astype(w_gate.dtype)         # [T*k, D]
         hit = jnp.sum(sizes > 0).astype(jnp.int32)
         plan = None if experts is None else experts.plan(sizes)
+        if share and plan is not None:
+            # no row here at all: the plan's one item would be tile -1
+            plan = (plan[0], jnp.maximum(plan[1], 0)) + tuple(plan[2:])
     with scope("moe_experts"):
         f32 = jnp.float32
         if experts is not None:
@@ -352,5 +469,92 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(t_n * k_n, dtype=order.dtype))
         per_tok = out[back].reshape(t_n, k_n, -1)
+        if share:
+            # rows past the last group are whatever the kernel left
+            per_tok = jnp.where(here[..., None], per_tok, 0.0)
         y = (per_tok * top_w[..., None]).sum(axis=1)
     return y, hit, (top_w, top_e)
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """One SwiGLU FFN every row takes (a shared expert): x [T, D]
+    float32 -> [T, D] float32, the matmuls in the weights' dtype with
+    float32 accumulation and the gated product rounded to it, as an
+    expert of `moe_ffn` rounds."""
+    import jax
+    import jax.numpy as jnp
+
+    f32 = jnp.float32
+    rows = x.astype(w_gate.dtype)
+    gate = jnp.dot(rows, w_gate, preferred_element_type=f32)
+    up = jnp.dot(rows, w_up, preferred_element_type=f32)
+    act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
+    return jnp.dot(act, w_down, preferred_element_type=f32)
+
+
+def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
+                scope=None):
+    """ONE position of a Mamba-2 mixer (Dao & Gu, arXiv:2405.21060;
+    one group of B and C) for every lane: u [S, D] float32 (the normed
+    residual) -> (out [S, D] float32, the lane's SSM state [S, H, P, N]
+    float32, its convolution tail [S, width - 1, H*P + 2N] float32:
+    the last rows of x B C before the convolution, and what the
+    recurrence was given: x B C after the convolution and dt after
+    the softplus side by side, [S, H*P + 2N + H] float32, for a
+    comparison that judges the recurrence on its own inputs).
+
+      z, xBC, dt = u @ W_in            (H*P, H*P + 2N and H columns)
+      xBC = silu(sum_j w_conv[j] * (tail, xBC)[j] + b_conv)
+      x [H, P], B [N], C [N] = xBC;  dt = softplus(dt + dt_bias) [H]
+      h = exp(dt * -exp(A_log)) * h + dt * (x outer B)     [H, P, N]
+      y = h . C + D * x;  out = (rmsnorm(y * silu(z)) * w) @ W_out
+
+    The recurrence IS the prefill: the scheduler feeds a prompt one
+    position a tick like any other, so there is no scan over a chunk.
+    `fresh` [S] bool: the lane starts a sequence here (its cursor is
+    0) and takes a zero state and a zero tail whatever it held, so
+    nobody has to zero a lane at admission.  `live` [S] bool: a lane
+    that is not keeps state and tail as they were.  `p`: the layer's
+    arrays by `param_layout`'s keys.  The recurrence, the convolution
+    and the norm are float32; the two projections take the weights'
+    dtype with float32 accumulation."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    scope = scope or (lambda name: contextlib.nullcontext())
+    f32 = jnp.float32
+    s_n = u.shape[0]
+    h_n, p_n, n_n = spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state
+    di = h_n * p_n
+    conv_w, conv_b = p["ssm_conv"]
+    with scope("ssm_in_proj"):
+        zxd = jnp.dot(u.astype(p["ssm_in"].dtype), p["ssm_in"],
+                      preferred_element_type=f32)
+        z, xbc, dt = (zxd[:, :di], zxd[:, di:-h_n], zxd[:, -h_n:])
+    with scope("ssm_conv"):
+        before = jnp.where(fresh[:, None, None], 0.0, tail)
+        rows = jnp.concatenate([before, xbc[:, None, :]], axis=1)
+        tail = jnp.where(live[:, None, None], rows[:, 1:], tail)
+        xbc = jax.nn.silu((rows * conv_w.astype(f32)[None]).sum(axis=1)
+                          + conv_b.astype(f32))
+    with scope("ssm_scan"):
+        x = xbc[:, :di].reshape(s_n, h_n, p_n)
+        b, c = xbc[:, di:di + n_n], xbc[:, di + n_n:]
+        dt = jax.nn.softplus(dt + p["ssm_dt"].astype(f32))      # [S, H]
+        given = jnp.concatenate([xbc, dt], axis=-1)
+        decay = jnp.exp(-dt * jnp.exp(p["ssm_a_log"].astype(f32)))
+        h0 = jnp.where(fresh[:, None, None, None], 0.0, state)
+        h = (decay[:, :, None, None] * h0
+             + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
+        y = ((h * c[:, None, None, :]).sum(axis=-1)
+             + p["ssm_d"].astype(f32)[None, :, None] * x)
+        state = jnp.where(live[:, None, None, None], h, state)
+    with scope("ssm_gate_norm"):
+        y = y.reshape(s_n, di) * jax.nn.silu(z)
+        y = norm(spec, y, p["ssm_gate_norm"].astype(f32))
+    with scope("ssm_out_proj"):
+        out = jnp.dot(y.astype(p["ssm_out"].dtype), p["ssm_out"],
+                      preferred_element_type=f32)
+    return out, state, tail, given

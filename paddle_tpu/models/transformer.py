@@ -598,6 +598,22 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     Grouped-query heads and a head size of its own (`n_kv_heads`,
     `d_head`) make a pool row n_kv_heads * d_head wide.
 
+    A block with MAMBA layers (`layer_types` "mamba": a Mamba-2 mixer
+    and no attention, `lm_block.mamba2_step`) keeps a THIRD kind of
+    state, of fixed size and belonging to a LANE: per Mamba layer a
+    float32 SSM state [S, H, P, N] and a float32 convolution tail
+    [S, width - 1, H*P + 2N].  `pool_k` is then the pair (the
+    attention layers' K pool, a tuple of the layers' states) and
+    `pool_v` the pair (their V pool, a tuple of the tails): donated
+    and updated in place with the pools, one buffer a layer.
+    `init_pool` takes the lane count as `lanes` (the step's S).  A
+    lane whose cursor is 0 starts from a zero state and tail whatever
+    it holds (the reset is made from the cursor alone, like the ring's
+    mask); an inactive lane's state does not move.  The table serves
+    the attention layers alone.  `step_window` is refused by name;
+    `decoder.state_layers` and `decoder.state_bytes_per_lane` say what
+    a lane holds (0 without Mamba layers).
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -654,6 +670,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     group, d_kv = n_heads // n_kv, n_kv * d_head
     kinds = [spec.kind_of(l) for l in range(n_layers)]
     ringed = lm_block.SLIDING in kinds
+    stateful = lm_block.MAMBA in kinds
+    n_full = kinds.count(lm_block.FULL)
     nw = 0
     if ringed:
         if spec.window % bs:
@@ -674,6 +692,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         d_model=d_model, n_heads=n_heads, block_size=bs,
         max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform,
         kv_width=d_kv, d_head=d_head, ringed=ringed)
+    if _attend is not None and spec.attention_multiplier:
+        # the kernel scales its scores by 1 / sqrt(d_head) itself
+        _attend, _refused = None, "attention_multiplier"
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -695,7 +716,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         layout, shapes = lm_block.param_layout(
             spec, vocab_size, d_model, n_heads, n_layers, d_inner)
 
-    scale = 1.0 / math.sqrt(d_head)
+    scale = spec.attention_multiplier or 1.0 / math.sqrt(d_head)
+    # the residual stream takes each sub-block's output times this
+    # (Python's 1.0: no operation, the other blocks' steps as they were)
+    res_mult = spec.residual_multiplier
+
+    def _residual(x, y):
+        return x + (y if res_mult == 1.0 else res_mult * y)
+
     # buffer donation makes the pool update in place (no copy of the
     # whole cache per token); CPU has no donation support and would
     # warn once per compile, so only donate where it lands
@@ -772,7 +800,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("embed"):
             if layout.pos is not None:
                 return g[layout.tok][tokens] + g[layout.pos][pos]
-            return g[layout.tok][tokens].astype(jnp.float32)
+            x = g[layout.tok][tokens].astype(jnp.float32)
+            if spec.embedding_multiplier != 1.0:
+                x = x * spec.embedding_multiplier
+            return x
 
     def _rotation(pos):
         """{layer kind: cos and sin of each row's OWN position} (RoPE
@@ -816,7 +847,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # grouped matmuls are its Pallas kernel or `ragged_dot`
         experts, refused = _grouped_matmul.select_grouped_matmul(
             rows=h2.shape[0] * spec.experts_per_token, d_model=d_model,
-            d_ff=w_gate.shape[-1], n_experts=spec.n_experts,
+            d_ff=w_gate.shape[-1], n_experts=spec.held[1],
             dtype=w_gate.dtype, platform=platform)
         decoder.expert_kernel = (experts.name if experts is not None
                                  else f"xla:{refused}")
@@ -824,12 +855,39 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             spec, h2, g[lay["router"][0]], w_gate, g[lay["up"][0]],
             g[lay["down"][0]], scope=scope, experts=experts)
         hits.append((hit, h2) + routed)
+        if spec.shared_d_inner:
+            # the shared expert: every token, whole, weight 1
+            with scope("shared_expert"):
+                y = y + lm_block.swiglu(h2, *(
+                    g[lay[n][0]] for n in ("shared_gate", "shared_up",
+                                           "shared_down")))
         with scope("moe_combine"):
-            return x + y.reshape(x.shape)
+            return _residual(x, y.reshape(x.shape))
 
     def _head(g, x):
         with scope("head"):
-            return _fc(g, _norm(g, x, layout.final), layout.head)
+            h = _norm(g, x, layout.final)
+            logits = (h @ g[layout.tok].T if spec.tied_head
+                      else _fc(g, h, layout.head))
+            if spec.logits_scaling != 1.0:
+                logits = logits / spec.logits_scaling
+            return logits
+
+    def _mixer(g, lay, x, state, tail, fresh, live):
+        """x + the Mamba-2 mixer of norm(x), one position a lane, the
+        layer's state and tail after it, and what its recurrence was
+        given (`mamba2_step`)."""
+        with scope("ssm_in_proj"):
+            u = _norm(g, x, lay["norm1"])
+        out, state, tail, given = lm_block.mamba2_step(
+            spec, u, state, tail, fresh, live,
+            {n: (tuple(g[w] for w in lay[n]) if n == "ssm_conv"
+                 else g[lay[n][0]])
+             for n in ("ssm_in", "ssm_conv", "ssm_dt", "ssm_a_log",
+                       "ssm_d", "ssm_gate_norm", "ssm_out")},
+            scope=scope)
+        with scope("ssm_out_proj"):
+            return _residual(x, out), state, tail, given
 
     def _with_counts(out, hits):
         return out + ((jnp.stack([h[0] for h in hits]),) if hits else ())
@@ -925,12 +983,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def _by_kind(x):
         """A pool or the tables as the step is given them, by layer
         kind: one array (or int8 pair) where every layer is full, else
-        the pair (full layers', sliding layers')."""
+        the pair (full layers', sliding layers') or (attention
+        layers', Mamba layers': a list, one array a layer)."""
+        if stateful:
+            return {lm_block.FULL: x[0], lm_block.MAMBA: list(x[1])}
         if not ringed:
             return {lm_block.FULL: x}
         return {lm_block.FULL: x[0], lm_block.SLIDING: x[1]}
 
     def _joined(by_kind):
+        if stateful:
+            return (by_kind[lm_block.FULL],
+                    tuple(by_kind[lm_block.MAMBA]))
         return (tuple(by_kind[k] for k in (lm_block.FULL,
                                            lm_block.SLIDING))
                 if ringed else by_kind[lm_block.FULL])
@@ -955,9 +1019,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                      active):
         s_n = tokens.shape[0]
         lane = jnp.arange(s_n)
-        hits = []
+        hits, scans = [], []
         pools_k, pools_v = _by_kind(pool_k), _by_kind(pool_v)
-        tabs = _by_kind(tables)
+        # only a ring brings a second table
+        tabs = _by_kind(tables) if ringed else {lm_block.FULL: tables}
         tables = tabs[lm_block.FULL]
         x = _embed(g, tokens, positions)                      # [S, D]
         rot = _rotation(positions)
@@ -978,6 +1043,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             cursor[lm_block.SLIDING] = _ring_cursor(
                 tabs[lm_block.SLIDING], positions, active)
         for lay, kind, li in zip(layout.layers, kinds, pool_index):
+            if kind == lm_block.MAMBA:
+                # the lane's state rides where a pool's K does, its
+                # convolution tail where the V does
+                x, pools_k[kind][li], pools_v[kind][li], given = _mixer(
+                    g, lay, x, pools_k[kind][li], pools_v[kind][li],
+                    positions == 0, active)
+                scans.append(given)
+                x = _ffn(g, lay, x, hits)
+                continue
             q, kk, vv = _qkv(g, lay, x, rot[kind])
             wb, mask = cursor[kind]
             with scope("kv_write"):
@@ -996,16 +1070,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     q[:, None, :], pools_k[kind], pools_v[kind], li,
                     tabs[kind], mask[:, None, :], kind)[:, 0]
             with scope("attn_out"):
-                x = x + _fc(g, ctx_av, lay["o"])
+                x = _residual(x, _fc(g, ctx_av, lay["o"]))
             x = _ffn(g, lay, x, hits)
         return (_head(g, x), _joined(pools_k), _joined(pools_v),
-                hits)                                         # [S, V]
+                hits, scans)                                  # [S, V]
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
              active):
         with scope("paged_decoder"):
-            logits, pool_k, pool_v, hits = _step_logits(
+            logits, pool_k, pool_v, hits, _ = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
             return _with_counts(
                 (_sample(logits, seeds, positions, temps), pool_k,
@@ -1024,14 +1098,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         """`step_logits`, and beside the logits every layer's routing
         as the step computed it: {"inputs": float32 [n_layers, S, D]
         (what the router was given), "weights": float32 [n_layers, S,
-        k], "experts": int32 [n_layers, S, k]}."""
+        k], "experts": int32 [n_layers, S, k]}, and for a block with
+        Mamba layers "ssm_inputs": float32 [Mamba layers, S, H*P + 2N +
+        H], what each layer's recurrence was given at this position
+        (`lm_block.mamba2_step`)."""
         with scope("paged_decoder"):
-            logits, _, _, hits = _step_logits(
+            logits, _, _, hits, scans = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
             inputs, weights, experts = (
                 jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
-            return logits, {"inputs": inputs, "weights": weights,
-                            "experts": experts}
+            out = {"inputs": inputs, "weights": weights,
+                   "experts": experts}
+            if scans:
+                out["ssm_inputs"] = jnp.stack(scans)
+            return logits, out
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -1042,6 +1122,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     def _step_window(g, pool_k, pool_v, tables, positions, tokens, seeds,
                      temps, n_valid):
+        if stateful:
+            raise NotImplementedError(
+                f"block {spec.name!r}: step_window runs a window of "
+                "positions in one dispatch, and a Mamba layer's state "
+                "is a recurrence over them that only the one-position "
+                "`step` computes (a chunked scan is not built); a "
+                "block with Mamba layers runs `step` alone (no draft "
+                "model, no chunked prefill)")
         if ringed:
             raise NotImplementedError(
                 f"block {spec.name!r}: step_window writes a window of "
@@ -1095,7 +1183,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 ctx_av = _attention(q, pool_k, pool_v, l, tables,
                                     pos_mask, kinds[l])
             with scope("attn_out"):
-                x = x + _fc(g, ctx_av, lay["o"])
+                x = _residual(x, _fc(g, ctx_av, lay["o"]))
             x = _ffn(g, lay, x, hits)
         logits = _head(g, x)                                  # [S, W, V]
         seeds_w = jnp.broadcast_to(seeds[:, None], (s_n, w_n))
@@ -1115,15 +1203,28 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     n_win = kinds.count(lm_block.SLIDING)
     # K+V of one block over the layers that hold it: a table block
     # over the full layers, a ring block over the sliding ones
-    bytes_per_block = int(2 * (n_layers - n_win) * bs * d_kv * elem_bytes)
+    bytes_per_block = int(2 * n_full * bs * d_kv * elem_bytes)
     window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
+    # a lane's recurrent state over the Mamba layers: the SSM state
+    # and the convolution tail, both float32
+    n_mamba = kinds.count(lm_block.MAMBA)
+    state_shape = (spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state)
+    tail_shape = (spec.ssm_conv - 1,
+                  spec.ssm_heads * spec.ssm_d_head + 2 * spec.ssm_d_state)
+    state_bytes_per_lane = 4 * n_mamba * (
+        math.prod(state_shape) + math.prod(tail_shape))
 
-    def init_pool(num_blocks, device=None, window_blocks=None):
+    def init_pool(num_blocks, device=None, window_blocks=None,
+                  lanes=None):
         """Zero pools of `num_blocks` blocks (the null block included)
         for the full layers and, for a block with sliding layers,
         `window_blocks` for their rings (read by no other block):
         (pool_k, pool_v), each one array (an int8 pair) or the pair
-        (full, ring) `step` takes."""
+        (full, ring) `step` takes.  For a block with Mamba layers each
+        is the pair (the attention layers' pool, one float32 array a
+        Mamba layer: `lanes` SSM states beside K, `lanes` convolution
+        tails beside V); `lanes` is the step's lane count and read by
+        no other block."""
         def zeros(layers, blocks):
             shape = (layers, int(blocks), bs, d_kv)
             if kv_dtype == "int8":
@@ -1133,6 +1234,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 z = jnp.zeros(shape, jnp.bfloat16 if kv_dtype == "bf16"
                               else jnp.float32)
             return z if device is None else jax.device_put(z, device)
+
+        def lane_state(shape):
+            if lanes is None:
+                raise ValueError(
+                    f"block {spec.name!r} has Mamba layers: init_pool "
+                    "needs lanes, the lane count of the step")
+            z = [jnp.zeros((int(lanes),) + shape, jnp.float32)
+                 for _ in range(n_mamba)]
+            return tuple(z if device is None
+                         else jax.device_put(z, device))
+
+        if stateful:
+            return ((zeros(n_full, num_blocks), lane_state(state_shape)),
+                    (zeros(n_full, num_blocks), lane_state(tail_shape)))
 
         def z():
             if not ringed:
@@ -1173,6 +1288,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # (0: every layer is full), the window, and a ring block's bytes
         window_blocks_per_seq=nw, window=spec.window if ringed else 0,
         window_bytes_per_block=window_bytes_per_block,
+        # the Mamba layers' recurrent state: how many layers keep one
+        # (0: none) and the float32 bytes a lane holds over them
+        state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
         kernels={"paged_attention_decode":
                  "pallas" if _attend is not None else f"xla:{_refused}"},
         # what the expert layer of the step traced last runs: the

@@ -353,6 +353,15 @@ class GenerationServer:
     ring; docs/serving.md "Two pools").  Such a decoder is served by
     the one-token step alone: `prefix_cache=True` and a draft model
     are refused at construction, by name.
+
+    A decoder whose block has MAMBA layers (`decoder.state_layers`
+    > 0) keeps a third kind: a recurrent state of fixed size a LANE
+    (docs/serving.md "Three kinds of state"), made with the pools and
+    carried in them, which the server never touches: the step itself
+    starts a lane whose cursor is 0 from zero, so admission, eviction
+    and the tick in flight behind them have nothing to reset.  What
+    cannot be right with it is refused here, by name:
+    `prefix_cache=True` and a draft model (`step_window`).
     """
 
     def __init__(self, decoder, states, *, slots: int = 8,
@@ -409,6 +418,21 @@ class GenerationServer:
                 "ring of the slot that wrote it and is overwritten as "
                 "that slot goes on, so a later hit would attend over "
                 "another request's keys; pass prefix_cache=False")
+        stateful = int(getattr(decoder, "state_layers", 0))
+        if stateful and draft_decoder is not None:
+            raise ValueError(
+                "a decoder with Mamba layers takes no draft model: "
+                "speculative verification runs a window of positions "
+                "through step_window, and a recurrent state is "
+                "computed one position a step (and cannot be rolled "
+                "back over rejected tokens)")
+        if stateful and prefix_cache:
+            raise ValueError(
+                "prefix_cache=True with Mamba layers: a hit starts a "
+                "sequence past position 0, where the attention "
+                "layers find the prompt's K/V in the shared blocks "
+                "but a lane has no recurrent state for it (no "
+                "snapshot is kept); pass prefix_cache=False")
         if (draft_decoder is None) != (draft_states is None):
             raise ValueError(
                 "speculative decoding needs BOTH draft_decoder and "
@@ -481,7 +505,7 @@ class GenerationServer:
         # many sequences fit)
         self._pool_k, self._pool_v = decoder.init_pool(
             kv_blocks + 1, self._device,
-            window_blocks=ring * self._slots + 1)
+            window_blocks=ring * self._slots + 1, lanes=self._slots)
         if draft_decoder is not None:
             self._draft_states = {
                 n: jax.device_put(np.asarray(draft_states[n]),
@@ -507,6 +531,8 @@ class GenerationServer:
             decoder.slot_rings(self._slots),
             self._device) if ring else None
         self._window = int(getattr(decoder, "window", 0))
+        # Mamba layers: a lane's recurrent state rides in the pools
+        self._stateful = bool(stateful)
         self._queue: deque = deque()
         self._max_queue = int(max_queue)
         self._lock = threading.Condition()
@@ -795,6 +821,10 @@ class GenerationServer:
                # the sliding layers' rings, one a slot (0 without)
                "kv_window_blocks": 0 if self._rings is None
                else self._rings.size,
+               # the Mamba layers' recurrent state over all lanes (0
+               # without): float32, resident whatever the lanes hold
+               "state_bytes": self._slots * int(getattr(
+                   self._decoder, "state_bytes_per_lane", 0)),
                "kv_dtype": getattr(self._decoder, "kv_dtype", "fp32"),
                "decode_kernel": getattr(self._decoder, "kernels", {})
                .get("paged_attention_decode", "xla"),
@@ -1070,7 +1100,10 @@ class GenerationServer:
         cursor is at or past the window: their rings have wrapped) and
         the K/V rows the tick has to attend over on a layer of each
         kind, summed over its slots: `kv_rows_full` (cursor + 1) and
-        `kv_rows_win` (the window at most).  With experts `moe_kernel`:
+        `kv_rows_win` (the window at most).  With Mamba layers
+        `state_lanes` (lanes of the tick with a recurrent state: all
+        its slots) and `state_resets` (those at position 0, which the
+        step starts from a zero state).  With experts `moe_kernel`:
         1 where the step's expert layer is the Pallas grouped matmul
         (`decoder.expert_kernel`), 0 where `ragged_dot`."""
         sp.set_attr("prefill", sum(1 for s in seqs
@@ -1083,6 +1116,10 @@ class GenerationServer:
             sp.set_attr("kv_rows_full", sum(s.cur + 1 for s in seqs))
             sp.set_attr("kv_rows_win", sum(min(s.cur + 1, self._window)
                                            for s in seqs))
+        if self._stateful:
+            sp.set_attr("state_lanes", len(seqs))
+            sp.set_attr("state_resets", sum(1 for s in seqs
+                                            if s.cur == 0))
         expert_kernel = getattr(self._decoder, "expert_kernel", None)
         if expert_kernel is not None:
             sp.set_attr("moe_kernel",

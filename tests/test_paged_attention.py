@@ -186,14 +186,40 @@ def _cell_geometry(workload):
                     max_blocks_per_seq=(int(t["context"])
                                         // int(t["block_size"])),
                     kv_dtype=t["kv_dtype"])
-    if "head_dim" in m:
+    if "num_key_value_heads" in m:
         # a configuration that states its K/V geometry: what the
-        # decoder derives from the block description
+        # decoder derives from the block description (a head is the
+        # model's width over its query heads where no key says other)
+        d_head = m.get("head_dim", m["hidden_size"]
+                       // m["num_attention_heads"])
         geometry.update(
-            d_head=m["head_dim"],
-            kv_width=m["num_key_value_heads"] * m["head_dim"],
+            d_head=d_head, kv_width=m["num_key_value_heads"] * d_head,
             ringed="sliding_attention" in m.get("layer_types", ()))
     return geometry
+
+
+def _cell_expert_shapes(workload):
+    """What a serving cell's step asks `select_grouped_matmul` when it
+    is traced: the slots' assignments, the widths and the experts HELD,
+    read from the cell's own files."""
+    import json
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*parts):
+        with open(os.path.join(repo, *parts)) as f:
+            return json.load(f)
+
+    w = next(w for w in load("BENCHMARK.json")["workloads"]
+             if w["name"] == workload)
+    m = load("perf", "configs", w["config"] + ".json")
+    t = load("perf", "traffic", w["traffic"] + ".json")
+    held = m[m["block"]["from_keys"].get("experts_held")
+             or m["block"]["from_keys"]["n_experts"]]
+    return dict(rows=int(t["slots"]) * m["num_experts_per_tok"],
+                d_model=m["hidden_size"], d_ff=m[m["block"]["d_inner"]],
+                n_experts=held, dtype=m["dtype"])
 
 
 CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
@@ -208,6 +234,9 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     # d2304, 32 query heads of 128 over 4 K/V heads (rows of 512),
     # sliding layers on a ring: not the kernel's geometry
     ("mellum2-12b-a2.5b-serve-agent96", "tpu", False, "kv_geometry"),
+    # d4096, 32 query heads of 128 over 8 K/V heads (rows of 1024) on
+    # its one attention layer in ten, context 1024: not its geometry
+    ("granite-4.0-h-small-serve-chat64", "tpu", False, "kv_geometry"),
     # each part of that geometry alone is refused too
     (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=256), "tpu", False,
      "kv_geometry"),
@@ -226,7 +255,7 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     # ...unless a test asks for the Pallas interpreter
     (dict(CHIP_SMOKE, kv_dtype="fp32"), "cpu", True, None),
 ], ids=["opt-1.3b-tpu", "olmoe-1b-7b-1chip-tpu", "mellum2-1chip-tpu",
-        "grouped-kv-tpu", "wide-heads-tpu", "ring-tpu", "stated-mha-tpu",
+        "granite-4.0-h-small-1chip-tpu", "grouped-kv-tpu", "wide-heads-tpu", "ring-tpu", "stated-mha-tpu",
         "chip_smoke-fp32-tpu",
         "chip_smoke-int8-tpu", "chip_smoke-cpu",
         "chip_smoke-cpu-interpret"])
@@ -242,6 +271,27 @@ def test_selection_follows_geometry_and_platform(geometry, platform,
     assert (kern is None) == (want is not None)
     assert paged_attention.paged_attention_supports(
         platform=platform, interpret=interpret, **geometry) == want
+
+
+@pytest.mark.parametrize("workload,held,rows", [
+    ("olmoe-1b-7b-serve-chat32", 64, 256),
+    ("mellum2-12b-a2.5b-serve-agent96", 64, 768),
+    # one chip's 36 of the 72 experts the router routes 640 rows over
+    ("granite-4.0-h-small-serve-chat64", 36, 640)])
+def test_expert_kernel_selection_follows_the_cells_shapes(workload, held,
+                                                          rows):
+    """The cells with experts, from their own files: every one runs the
+    Pallas grouped matmul on a TPU (what `sched_moe_kernel_share` reads
+    as 100) and `ragged_dot` off one."""
+    from paddle_tpu.kernels import grouped_matmul
+
+    shapes = _cell_expert_shapes(workload)
+    assert (shapes["n_experts"], shapes["rows"]) == (held, rows)
+    kern, reason = grouped_matmul.select_grouped_matmul(
+        platform="tpu", **shapes)
+    assert reason is None and kern.name == grouped_matmul.NAME
+    assert grouped_matmul.select_grouped_matmul(
+        platform="cpu", **shapes) == (None, "not_tpu")
 
 
 def test_unsupported_shape_is_refused_with_its_reason():
