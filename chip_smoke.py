@@ -460,9 +460,12 @@ def _serve_once(smoke: Smoke, kv_dtype: str, states, workdir):
     check(stats["kv_blocks_free"] == stats["kv_blocks_total"], stats)
     check(stats["recompiles_after_warmup"] == 0, stats)
     if smoke.on_tpu:
-        # auto arms on a TPU: at this geometry the predicate accepts,
-        # so anything but the kernel means selection lost it
-        check(stats["decode_kernel"] == "pallas", stats["decode_kernel"])
+        # on a TPU the predicate accepts this pool at fp32, so anything
+        # but the kernel means selection lost it; an int8 pool it
+        # refuses by name, and the gather path serves
+        check(stats["decode_kernel"] == (
+            "xla:kv_dtype" if kv_dtype == "int8" else "pallas"),
+            stats["decode_kernel"])
     lat = sorted(res[1] for res in results)
     return {"decode_kernel": stats["decode_kernel"],
             "requests": len(prompts), "ticks": stats["ticks"],

@@ -1023,24 +1023,28 @@ def _paged_attention_gather_cost(spec: Dict, slots: int = 1,
                                  kv_dtype: str = "fp32", **_) -> Dict:
     """Gather-through-block-table attention for ONE query position per
     slot: K/V [n_layers, blocks, block_size, d_model] gathered through
-    the table to `context` logical positions IN THE POOL'S DTYPE, then
-    contracted over d_model against the block-diagonal query (QK^T)
-    and the softmax weights (att*V); int8 scales ride on the scores
-    and the weights, so no dequantized copy exists.
+    the WHOLE table (all `max_blocks_per_seq` pages of every slot,
+    whatever its `context`: the cursor only masks the scores) IN THE
+    POOL'S DTYPE, then contracted over d_model against the
+    block-diagonal query (QK^T) and the softmax weights (att*V); int8
+    scales ride on the scores and the weights, so no dequantized copy
+    exists.
 
     Flops are what the composition executes: each of the n_heads query
-    rows contracts over all of d_model (2*ctx*d a row for QK^T and
-    again for att*V, per layer), n_heads times a head-split
-    contraction's — the price of reading K and V once from unpadded
-    tiles.  Bytes charge BOTH legs: the pool reads in storage
-    precision AND the logical-order gathered copy in the same
-    precision (written once, read once) — the traffic the fused
-    `paged_attention_decode` kernel deletes."""
+    rows contracts over all of d_model (2*rows*d a row for QK^T and
+    again for att*V, per layer) over every row of the table, n_heads
+    times a head-split contraction's — the price of reading K and V
+    once from unpadded tiles.  Bytes charge BOTH legs of the
+    `pages_read` pages: the pool reads in storage precision AND the
+    logical-order gathered copy in the same precision (written once,
+    read once) — the traffic the streaming `paged_attention_decode`
+    kernel deletes, with the pages past the cursor."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
-    flops = slots * layers * 4.0 * ctx * d * h
-    pool_bytes = slots * layers * 2.0 * ctx * d * kvb
+    rows = bs * nb
+    flops = slots * layers * 4.0 * rows * d * h
+    pool_bytes = slots * layers * 2.0 * rows * d * kvb
     copy_bytes = 2.0 * pool_bytes
     return {
         "kernel": "paged_attention_gather",
@@ -1049,6 +1053,7 @@ def _paged_attention_gather_cost(spec: Dict, slots: int = 1,
                    "query": f"[{slots}, {h}, {d // max(h, 1)}]"},
         "flops": flops, "bytes": pool_bytes + copy_bytes,
         "pool_bytes": pool_bytes, "copy_bytes": copy_bytes,
+        "pages_read": slots * layers * nb,
         "context": ctx, "slots": slots,
     }
 
@@ -1056,37 +1061,42 @@ def _paged_attention_gather_cost(spec: Dict, slots: int = 1,
 @register_serving_kernel("paged_attention_decode")
 def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
                                  context: Optional[int] = None,
-                                 kv_dtype: str = "fp32",
-                                 window: int = 1, **_) -> Dict:
-    """The fused Pallas decode-attention kernel
-    (kernels/paged_attention.py): K/V blocks stream through the block
-    table straight into VMEM, dequantized in-lane, and each head
-    contracts over its own d_head columns (1/n_heads of the gather
-    composition's multiply-adds); the XLA path's logical-order copy of
-    the gathered context (pool precision, written then re-read) never
-    exists.  `gather_copy_bytes_avoided` quantifies that saved
-    traffic."""
+                                 kv_dtype: str = "fp32", **_) -> Dict:
+    """The streaming Pallas decode-attention kernel
+    (kernels/paged_attention.py), one query position a slot: a slot
+    reads the `ceil(context / block_size)` pages its cursor has
+    reached, straight from the pool into VMEM, and no other; the XLA
+    path's logical-order copy of the whole table (pool precision,
+    written then re-read) never exists.  The arithmetic is the gather
+    composition's (the query block-diagonal over whole pool rows:
+    n_heads times a head-split contraction's multiply-adds) over the
+    rows of those pages.  `gather_bytes_avoided` is what the gather
+    path moves on top: the pages past the cursor and both legs of the
+    copy."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
-    flops = slots * window * layers * 4.0 * ctx * d
-    # pool-block reads only, in storage precision: q/out traffic is the
-    # step row's act_bytes, and the oracle's logical-order copy
-    # (write + re-read) simply never exists on this path
-    pool_bytes = slots * layers * 2.0 * ctx * d * kvb
+    pages = min(-(-ctx // bs), nb)
+    rows = pages * bs
+    flops = slots * layers * 4.0 * rows * d * h
+    # pool pages in storage precision, and the [slots, n_heads, d]
+    # float32 result the heads keep their own columns of outside
+    pool_bytes = slots * layers * 2.0 * rows * d * kvb
+    out_bytes = slots * layers * 2.0 * h * d * 4.0
+    gather = serving_kernel_cost("paged_attention_gather", spec,
+                                 slots=slots, context=ctx,
+                                 kv_dtype=kv_dtype)
     return {
         "kernel": "paged_attention_decode",
         "backend": "pallas",
         "shapes": {"pool": f"[{layers}, blocks, {bs}, {d}] x2 ({kv_dtype})",
                    "tables": f"[{slots}, {nb}] int32",
-                   "query": f"[{slots}, {window}, {d}]"},
-        "flops": flops, "bytes": pool_bytes,
-        # what the oracle pays on top: the logical-order copy in the
-        # pool's precision, materialized (write) and consumed (read)
-        # per layer
-        "gather_copy_bytes_avoided": 2.0 * pool_bytes,
-        "fused_dequant": kv_dtype != "fp32",
-        "context": ctx, "slots": slots, "window": window,
+                   "query": f"[{slots}, {h}, {d}]"},
+        "flops": flops, "bytes": pool_bytes + out_bytes,
+        "pool_bytes": pool_bytes,
+        "pages_read": slots * layers * pages,
+        "gather_bytes_avoided": gather["bytes"] - pool_bytes,
+        "context": ctx, "slots": slots,
     }
 
 
@@ -1105,16 +1115,18 @@ def _paged_decode_step_cost(spec: Dict, slots: int = 1,
     statically.
 
     `backend` picks the attention sub-cost: "xla" (default) is the
-    gather composition, "pallas" the fused paged-attention kernel:
-    the row then reflects what the decoder actually runs."""
+    gather composition, "pallas" the streaming paged-attention kernel
+    (the one-position step alone: `step_window` runs the gather
+    whatever the backend): the row then reflects what the decoder
+    actually runs."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
     per_pos = layers * (8.0 * d * d + 4.0 * d * di) + 2.0 * d * v
-    if backend == "pallas":
+    if backend == "pallas" and window == 1:
         att = serving_kernel_cost("paged_attention_decode", spec,
                                   slots=slots, context=ctx,
-                                  kv_dtype=kv_dtype, window=window)
+                                  kv_dtype=kv_dtype)
     else:
         att = serving_kernel_cost("paged_attention_gather", spec,
                                   slots=slots * window, context=ctx,

@@ -646,11 +646,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         raise ValueError(
             f"kv_dtype {kv_dtype!r} not in ('fp32', 'bf16', 'int8')")
 
-    # the kernel's own module decides, from this geometry and the
-    # platform, whether attention reads K/V straight through the block
-    # table inside the Pallas kernel (fused dequant, no logical-order
-    # gather copy) or the XLA gather below runs
-    # (docs/performance.md "Kernel selection")
+    # the kernel's own module decides, from the pool's geometry, its
+    # dtype and the platform, whether the resident step's attention
+    # reads the pages a slot's cursor has reached straight from the
+    # pool inside the Pallas kernel (no logical-order gather copy) or
+    # the XLA gather below runs (docs/performance.md "Kernel
+    # selection")
     from ..kernels import grouped_matmul as _grouped_matmul
     from ..kernels import paged_attention as _paged_attention
 
@@ -692,9 +693,6 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         d_model=d_model, n_heads=n_heads, block_size=bs,
         max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform,
         kv_width=d_kv, d_head=d_head, ringed=ringed)
-    if _attend is not None and spec.attention_multiplier:
-        # the kernel scales its scores by 1 / sqrt(d_head) itself
-        _attend, _refused = None, "attention_multiplier"
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -762,8 +760,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # of a tick is the table gather, the attention over it, the weight
     # matmuls...  `kv_gather` is the gather through the block table
     # (values and int8 scales); `attention` is the two contractions,
-    # the mask and the softmax (the Pallas `_attend` call lies there
-    # when selection takes it).
+    # the mask and the softmax (where selection takes the Pallas
+    # `_attend`, its call, which reads the pool itself: no gather).
     scope = jax.named_scope
 
     def _sample(logits, seeds, positions, temps):
@@ -921,47 +919,69 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         jnp.repeat(sc_[l, tables], bs, axis=1))
             return pool[l, tables].reshape(s_n, rows, d_kv), None
 
+    def _block_diagonal(q):
+        """q [S, W, H*dh] laid out block-diagonally by K/V head:
+        [S, W*H, Dkv], a query head's d_head columns in ITS K/V head's
+        columns of a pool row and zero outside them, so `Qbd . K^T`
+        over Dkv IS the per-head score."""
+        s_n, w_n = q.shape[0], q.shape[1]
+        if (group, d_kv) == (1, d_model):
+            # a head's columns of q ARE its columns of a pool row
+            q_wide = q[:, :, None, :]
+        else:
+            # a head's d_head columns, under every K/V head
+            q_wide = jnp.tile(
+                q.reshape(s_n, w_n, n_heads, d_head), (1, 1, 1, n_kv))
+        return jnp.where(_head_cols(), q_wide, 0.0).reshape(
+            s_n, w_n * n_heads, d_kv)
+
+    def _head_cols():
+        """[H, Dkv] bool: column c belongs to K/V head c // d_head,
+        which query heads h with h // group == c // d_head share."""
+        kv_head = jnp.arange(n_heads)[:, None]
+        if group > 1:
+            kv_head = kv_head // group
+        return jnp.arange(d_kv)[None, :] // d_head == kv_head
+
+    def _own_columns(ctx, w_n):
+        """`weights . V` over whole pool rows [S, W*H, Dkv] ->
+        [S, W, H*dh]: of all Dkv columns a query head keeps its K/V
+        head's."""
+        s_n = ctx.shape[0]
+        kept = jnp.where(
+            _head_cols(), ctx.reshape(s_n, w_n, n_heads, d_kv), 0.0)
+        if (group, d_kv) == (1, d_model):
+            return kept.sum(axis=2)
+        return kept.reshape(s_n, w_n, n_heads, n_kv, d_head).sum(
+            axis=3).reshape(s_n, w_n, n_heads * d_head)
+
     def _attention(q, pool_k, pool_v, l, tables, pos_mask, kind):
         """Attention of q [S, W, H*dh] over layer `l` of the paged
         pools -> [S, W, H*dh]; pos_mask [S, W, rows] says which rows
         of the table (logical positions; ring slots on a sliding
-        layer) each window row sees.
+        layer) each window row sees.  The XLA gather path: what
+        `step_window` runs, and the resident step where
+        `select_paged_attention` refuses the pool.
 
         K and V are read once, in the pool's dtype, with the pool's
         row (the K/V heads side by side, d_model wide under plain
         multi-head attention) as the minor dimension all the way into
-        the contraction: the query is laid out block-diagonally by K/V
-        head ([S, W*H, Dkv], a query head's d_head columns in ITS K/V
-        head's columns and zero outside them), so `Qbd . K^T` over Dkv
-        IS the per-head score, and `weights . V` gives every query
-        head all Dkv columns of which it keeps its K/V head's.  That
-        spends n_kv_heads times the multiply-adds of a head-split
-        contraction and never reshapes K or V to [.., heads, d_head]
-        (on a TPU a relayout of the whole gathered view into
-        half-empty lane tiles) nor widens them to float32.  Scores,
-        mask, softmax and both accumulations are float32."""
-        s_n, w_n = q.shape[0], q.shape[1]
+        the contraction: the query is block-diagonal by K/V head
+        (`_block_diagonal`), and `weights . V` gives every query head
+        all Dkv columns of which it keeps its K/V head's
+        (`_own_columns`).  That spends n_kv_heads times the
+        multiply-adds of a head-split contraction and never reshapes K
+        or V to [.., heads, d_head] (on a TPU a relayout of the whole
+        gathered view into half-empty lane tiles) nor widens them to
+        float32.  Scores, mask, softmax and both accumulations are
+        float32."""
+        w_n = q.shape[1]
         k, k_scale = _gather(pool_k, l, tables, kind)
         v, v_scale = _gather(pool_v, l, tables, kind)
         batched = ((0,), (0,))
         with _kind_scope("attention", kind):
-            # [H, Dkv]: column c belongs to K/V head c // d_head, which
-            # query heads h with h // group == c // d_head share
-            kv_head = jnp.arange(n_heads)[:, None]
-            if group > 1:
-                kv_head = kv_head // group
-            head_cols = jnp.arange(d_kv)[None, :] // d_head == kv_head
-            if (group, d_kv) == (1, d_model):
-                # a head's columns of q ARE its columns of a pool row
-                q_wide = q[:, :, None, :]
-            else:
-                # a head's d_head columns, under every K/V head
-                q_wide = jnp.tile(
-                    q.reshape(s_n, w_n, n_heads, d_head), (1, 1, 1, n_kv))
-            q_bd = jnp.where(head_cols, q_wide, 0.0).reshape(
-                s_n, w_n * n_heads, d_kv)
             sc = jax.lax.dot_general(
-                q_bd, k, (((2,), (2,)), batched),
+                _block_diagonal(q), k, (((2,), (2,)), batched),
                 preferred_element_type=jnp.float32) * scale
             if k_scale is not None:
                 sc = sc * k_scale[:, None, :]
@@ -973,12 +993,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             ctx = jax.lax.dot_general(
                 w_att, v, (((2,), (1,)), batched),
                 preferred_element_type=jnp.float32)
-            kept = jnp.where(
-                head_cols, ctx.reshape(s_n, w_n, n_heads, d_kv), 0.0)
-            if (group, d_kv) == (1, d_model):
-                return kept.sum(axis=2)
-            return kept.reshape(s_n, w_n, n_heads, n_kv, d_head).sum(
-                axis=3).reshape(s_n, w_n, n_heads * d_head)
+            return _own_columns(ctx, w_n)
+
+    def _streamed(q, pool_k, pool_v, l, tables, lengths, kind):
+        """`_attention` of one query position a slot, q [S, H*dh],
+        through the Pallas kernel: slot s attends over the first
+        `lengths[s]` rows of its table (its ring, on a sliding layer)
+        and the kernel reads those pages from the pool and no other;
+        no logical-order copy exists.  The arithmetic is
+        `_attention`'s, an online softmax over chunks of pages in
+        place of one softmax over the table."""
+        with _kind_scope("attention", kind):
+            q_bd = _block_diagonal(q[:, None, :]).astype(pool_k.dtype)
+            ctx = _attend(q_bd, pool_k, pool_v, tables, lengths, l,
+                          scale)
+            return _own_columns(ctx, 1)[:, 0]
 
     def _by_kind(x):
         """A pool or the tables as the step is given them, by layer
@@ -999,21 +1028,37 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                            lm_block.SLIDING))
                 if ringed else by_kind[lm_block.FULL])
 
-    def _ring_cursor(win_tables, positions, active):
-        """Where a sliding layer writes position c and what it then
-        sees, from the cursor alone: the ring's `nw` blocks hold
-        position p at block (p // BS) % nw of the sequence's ring
-        table, so after the write ring slot r holds the newest
-        position p <= c with p = r (mod nw*BS), which is inside the
-        window or (before the first wrap) negative: never written."""
+    def _ring_block(win_tables, positions, active):
+        """The ring block a sliding layer writes position c into: the
+        ring's `nw` blocks hold position p at block (p // BS) % nw of
+        the lane's ring table."""
         lane = jnp.arange(positions.shape[0])
         with scope("kv_write"):
-            wb = jnp.where(
+            return jnp.where(
                 active, win_tables[lane, (positions // bs) % nw], 0)
-        with _kind_scope("attention", lm_block.SLIDING):
+
+    def _sees(kind, positions, active):
+        """What each slot sees, after this position's write, of the
+        rows of its table (full layer) or ring (sliding layer), from
+        the cursor alone.  On a table row j is logical position j,
+        seen iff j <= cursor, which also hides unallocated tail
+        entries.  Ring row r holds the newest position p <= cursor
+        with p = r (mod the ring's rows), which is inside the window
+        or (before the first wrap) negative: never written.  Either
+        way the rows seen are the FIRST `min(cursor + 1, rows)`, in
+        whatever order the ring holds them (the keys are rotated before
+        they are written), so the kernel takes that length [S] (an
+        inactive slot's is 1: a page read, the row thrown away) and the
+        gather path the mask [S, rows] that says the same."""
+        rows = (nw if kind == lm_block.SLIDING else nb) * bs
+        with _kind_scope("attention", kind):
+            if _attend is not None:
+                return jnp.minimum(jnp.where(active, positions + 1, 1),
+                                   rows)
             c = positions[:, None]
-            held = c - (c - jnp.arange(nw * bs)[None, :]) % (nw * bs)
-            return wb, held >= 0
+            if kind != lm_block.SLIDING:
+                return jnp.arange(rows)[None, :] <= c
+            return c - (c - jnp.arange(rows)[None, :]) % rows >= 0
 
     def _step_logits(g, pool_k, pool_v, tables, positions, tokens,
                      active):
@@ -1033,15 +1078,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # sequence
             wb = jnp.where(active, tables[lane, positions // bs], 0)
             wi = jnp.where(active, positions % bs, 0)
-        with _kind_scope("attention", lm_block.FULL):
-            # mask over the table's logical span: position j
-            # participates iff j <= cursor, which also hides
-            # unallocated tail entries
-            pos_mask = jnp.arange(nb * bs)[None, :] <= positions[:, None]
-        cursor = {lm_block.FULL: (wb, pos_mask)}
+        written = {lm_block.FULL: wb}
         if ringed:
-            cursor[lm_block.SLIDING] = _ring_cursor(
+            written[lm_block.SLIDING] = _ring_block(
                 tabs[lm_block.SLIDING], positions, active)
+        cursor = {kind: (wb, _sees(kind, positions, active))
+                  for kind, wb in written.items()}
         for lay, kind, li in zip(layout.layers, kinds, pool_index):
             if kind == lm_block.MAMBA:
                 # the lane's state rides where a pool's K does, its
@@ -1053,22 +1095,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 x = _ffn(g, lay, x, hits)
                 continue
             q, kk, vv = _qkv(g, lay, x, rot[kind])
-            wb, mask = cursor[kind]
+            wb, seen = cursor[kind]
             with scope("kv_write"):
                 pools_k[kind] = _write(pools_k[kind], li, wb, wi, kk)
                 pools_v[kind] = _write(pools_v[kind], li, wb, wi, vv)
             if _attend is not None:
-                # Pallas path: block-table reads + dequant + attention
-                # in one kernel; bit-identical to the gather branch
-                # (tests/test_paged_attention.py)
-                with scope("attention"):
-                    ctx_av = _attend(q[:, None, :], pools_k[kind],
-                                     pools_v[kind], tables, positions,
-                                     li)[:, 0]
+                ctx_av = _streamed(q, pools_k[kind], pools_v[kind], li,
+                                   tabs[kind], seen, kind)
             else:
                 ctx_av = _attention(
                     q[:, None, :], pools_k[kind], pools_v[kind], li,
-                    tabs[kind], mask[:, None, :], kind)[:, 0]
+                    tabs[kind], seen[:, None, :], kind)[:, 0]
             with scope("attn_out"):
                 x = _residual(x, _fc(g, ctx_av, lay["o"]))
             x = _ffn(g, lay, x, hits)
@@ -1173,15 +1210,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                     kk[:, j])
                     pool_v = _write(pool_v, l, wb[:, j], wi[:, j],
                                     vv[:, j])
-            if _attend is not None:
-                # speculative verify rides the SAME kernel as decode:
-                # the window dim comes from q's shape at trace time
-                with scope("attention"):
-                    ctx_av = _attend(q, pool_k, pool_v, tables,
-                                     positions, l)
-            else:
-                ctx_av = _attention(q, pool_k, pool_v, l, tables,
-                                    pos_mask, kinds[l])
+            # a window of query rows a slot: the gather path (the
+            # kernel is one row a slot: `decoder.kernels`)
+            ctx_av = _attention(q, pool_k, pool_v, l, tables, pos_mask,
+                                kinds[l])
             with scope("attn_out"):
                 x = _residual(x, _fc(g, ctx_av, lay["o"]))
             x = _ffn(g, lay, x, hits)
@@ -1272,14 +1304,31 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         return 1 + np.arange(slots * nw, dtype=np.int32).reshape(
             slots, nw)
 
+    # The TPU compiler brings a matmul's weight in ahead of it in
+    # slices of its own making (`slice-start` / `slice-done`, no
+    # metadata), and the wait for them counts under their producer:
+    # the step's PARAMETER, named `g['<weight>']`.  Only the builder
+    # knows which part multiplies by which weight: {the parameter's
+    # op_name as compiled text spells it: the part's scope}, for
+    # `profiler.register_jitted`, beside the calls the compiler renames.
+    parts = {"q": "qkv", "k": "qkv", "v": "qkv", "o": "attn_out",
+             "w1": "mlp", "w2": "mlp"}
+    weights_of = [(lay[key], part) for lay in layout.layers
+                  for key, part in parts.items() if key in lay]
+    compiler_scopes = {
+        f"g[\\'{name}\\']": f"paged_decoder/{part}"
+        for pair, part in weights_of + [(layout.head, "head")]
+        for name in pair or () if name is not None}
+    if spec.ffn == "moe_swiglu":
+        compiler_scopes.update(lm_block.MOE_COMPILER_SCOPES)
+
     decoder = types.SimpleNamespace(
         step=step, step_window=step_window, step_logits=step_logits,
         step_routing=(step_routing if spec.ffn == "moe_swiglu" else None),
         init_pool=init_pool, slot_rings=slot_rings, platform=platform,
         step_counters=(("moe_experts_hit",)
                        if spec.ffn == "moe_swiglu" else ()),
-        compiler_scopes=(lm_block.MOE_COMPILER_SCOPES
-                         if spec.ffn == "moe_swiglu" else None),
+        compiler_scopes=compiler_scopes,
         state_names=sorted(shapes), state_shapes=shapes, block_size=bs,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,
@@ -1288,11 +1337,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # (0: every layer is full), the window, and a ring block's bytes
         window_blocks_per_seq=nw, window=spec.window if ringed else 0,
         window_bytes_per_block=window_bytes_per_block,
+        # attention layers by where their K/V live: on the table, on a
+        # slot's ring
+        table_layers=n_full, ring_layers=n_win,
         # the Mamba layers' recurrent state: how many layers keep one
         # (0: none) and the float32 bytes a lane holds over them
         state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
+        # what attends in the resident step (`step`, `step_logits`,
+        # `step_routing`): the streaming Pallas kernel, or the XLA
+        # gather and the reason the kernel was refused; `step_window`
+        # (a window of query rows a slot) runs the gather always
         kernels={"paged_attention_decode":
-                 "pallas" if _attend is not None else f"xla:{_refused}"},
+                 "pallas" if _attend is not None else f"xla:{_refused}",
+                 "paged_attention_window":
+                 f"xla:{_refused or 'window_rows'}"},
         # what the expert layer of the step traced last runs: the
         # Pallas grouped matmul's name, or "xla:<reason>" where
         # `ragged_dot` does; None until a step is traced (the weights'

@@ -531,6 +531,17 @@ class GenerationServer:
             decoder.slot_rings(self._slots),
             self._device) if ring else None
         self._window = int(getattr(decoder, "window", 0))
+        # K/V pages a slot holds over the attention layers (a table's
+        # blocks on the full layers, a ring's on the sliding ones), and
+        # whether the resident step's attention reads only those the
+        # cursor has reached (the Pallas kernel) or all of them
+        self._kv_layers = (int(getattr(decoder, "table_layers", 0)),
+                           int(getattr(decoder, "ring_layers", 0)))
+        self._kv_pages_table = self._slots * (
+            self._kv_layers[0] * decoder.max_blocks_per_seq
+            + self._kv_layers[1] * ring)
+        self._kv_streamed = getattr(decoder, "kernels", {}).get(
+            "paged_attention_decode") == "pallas"
         # Mamba layers: a lane's recurrent state rides in the pools
         self._stateful = bool(stateful)
         self._queue: deque = deque()
@@ -1091,12 +1102,21 @@ class GenerationServer:
             return self._tables.copy()
         return self._tables.copy(), self._rings
 
-    def _tick_attrs(self, sp, seqs: List[_Seq]) -> None:
+    def _tick_attrs(self, sp, seqs: List[_Seq],
+                    window: bool = False) -> None:
         """The scheduler's counts for the tick being dispatched, on its
         live `serving.decode_tick` span: `prefill` slots teacher-force
         a prompt position and deliver nothing (cursor below
         prompt_len - 1), `kv_used` of `kv_total` pool blocks are
-        owned.  With sliding layers also `past_window` (slots whose
+        owned.  `kv_pages_read` of `kv_pages_table`: the K/V pages the
+        dispatched step's attention reads, summed over slots and
+        attention layers, of the pages the slots' tables and rings
+        hold (slots x pages a slot x layers).  Where the step attends
+        through the Pallas kernel (`decoder.kernels`; never a
+        `step_window` tick: `window`) those are the pages each cursor
+        has reached, and one a layer for a slot with no sequence; on
+        the gather path every page.
+        With sliding layers also `past_window` (slots whose
         cursor is at or past the window: their rings have wrapped) and
         the K/V rows the tick has to attend over on a layer of each
         kind, summed over its slots: `kv_rows_full` (cursor + 1) and
@@ -1110,6 +1130,17 @@ class GenerationServer:
                                    if s.cur < s.prompt_len - 1))
         sp.set_attr("kv_used", self._cache.used_blocks)
         sp.set_attr("kv_total", self._cache.num_blocks)
+        read = self._kv_pages_table
+        if self._kv_streamed and not window:
+            bs = self._cache.block_size
+            full, win = self._kv_layers
+            ring_rows = 0 if self._rings is None else (
+                self._rings.shape[1] * bs)
+            read = (self._slots - len(seqs)) * (full + win) + sum(
+                full * -(-(s.cur + 1) // bs)
+                + win * -(-min(s.cur + 1, ring_rows) // bs) for s in seqs)
+        sp.set_attr("kv_pages_read", read)
+        sp.set_attr("kv_pages_table", self._kv_pages_table)
         if self._window:
             sp.set_attr("past_window", sum(1 for s in seqs
                                            if s.cur >= self._window))
@@ -1346,7 +1377,7 @@ class GenerationServer:
         with obs_tracing.span("serving.decode_tick", active=len(seqs),
                               speculative=True) as sp:
             if sp is not None:
-                self._tick_attrs(sp, seqs)
+                self._tick_attrs(sp, seqs, window=True)
             with obs_attr.phase("generation", "draft_verify"):
                 fault_injector().fire("serving.decode")
                 nxt, self._pool_k, self._pool_v, *counts = (

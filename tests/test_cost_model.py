@@ -947,6 +947,17 @@ def test_cli_analyze_generation_model_dir(tmp_path, capsys):
     gather = analysis.serving_kernel_cost("paged_attention_gather",
                                           spec, slots=4, context=16)
     assert gather["bytes"] > 0 and "shapes" in gather
+    # the gather reads every page of every table whatever the cursor;
+    # the streaming kernel the pages the cursor has reached
+    kernel = analysis.serving_kernel_cost("paged_attention_decode",
+                                          spec, slots=4, context=16)
+    assert gather["pages_read"] == 4 * 2 * 8
+    assert kernel["pages_read"] == 4 * 2 * 4
+    assert gather["bytes"] == 3 * gather["pool_bytes"]
+    assert kernel["pool_bytes"] == gather["pool_bytes"] / 2
+    assert analysis.serving_kernel_cost(
+        "paged_attention_gather", spec, slots=4,
+        context=1)["bytes"] == gather["bytes"]
 
 
 # ---------------------------------------------------------------------------

@@ -524,7 +524,10 @@ def test_configuration_file_describes_the_block_and_its_arithmetic():
         n_heads=m["num_attention_heads"], n_layers=m["num_hidden_layers"],
         d_inner=m[b["d_inner"]], kv_dtype="bf16", platform="tpu",
         block=spec)
-    assert dec.kernels == {"paged_attention_decode": "xla:kv_geometry"}
+    # the one attention layer in ten, `attention_multiplier` and all,
+    # through the streaming kernel (`scale` is its argument)
+    assert dec.kernels["paged_attention_decode"] == "pallas"
+    assert (dec.table_layers, dec.ring_layers) == (1, 0)
     shapes = dec.state_shapes
     assert shapes["layer_0.ssm_in_proj.w_0"] == (4096, 16768)
     assert shapes["layer_0.ssm_conv.w_0"] == (4, 8448)

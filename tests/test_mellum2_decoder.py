@@ -306,7 +306,8 @@ def test_description_is_hashable_from_json_and_checked():
     assert dec.state_shapes["layer_0.q_proj.w_0"] == (D, H * DH)
     assert dec.state_shapes["layer_0.k_proj.w_0"] == (D, HKV * DH)
     assert dec.state_shapes["layer_0.o_proj.w_0"] == (H * DH, D)
-    assert dec.kernels == {"paged_attention_decode": "xla:not_tpu"}
+    assert dec.kernels == {"paged_attention_decode": "xla:not_tpu",
+                           "paged_attention_window": "xla:not_tpu"}
     # K+V of a block over the layers that hold it
     assert dec.bytes_per_block == 2 * 2 * BS * HKV * DH * 4
     assert dec.window_bytes_per_block == 2 * 6 * BS * HKV * DH * 4
@@ -501,7 +502,9 @@ def test_configuration_file_describes_the_block_and_its_arithmetic():
         n_heads=m["num_attention_heads"], n_layers=m["num_hidden_layers"],
         d_inner=m[b["d_inner"]], kv_dtype="bf16", platform="tpu",
         block=spec)
-    assert dec.kernels == {"paged_attention_decode": "xla:kv_geometry"}
+    # table and ring both through the streaming kernel
+    assert dec.kernels["paged_attention_decode"] == "pallas"
+    assert (dec.table_layers, dec.ring_layers) == (2, 6)
     shapes = dec.state_shapes
     assert shapes["layer_0.q_proj.w_0"] == (2304, 4096)
     assert shapes["layer_0.v_proj.w_0"] == (2304, 512)

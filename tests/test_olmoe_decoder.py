@@ -531,11 +531,22 @@ def test_expert_layer_lowers_for_tpu_as_three_grouped_matmuls():
 
 def test_compiler_made_op_names_resolve_to_the_owners_scope():
     """The TPU compiler renames `ragged_dot`'s calls and drops their
-    scope; the decoder says which scope they were traced under and
+    scope, and prefetches a weight in slices named after the step's
+    parameter; the decoder says which scope they belong to and
     `register_jitted` applies it to the table it reads."""
     from paddle_tpu import profiler
 
-    assert _decoder().compiler_scopes == lm_block.MOE_COMPILER_SCOPES
+    scopes = _decoder().compiler_scopes
+    assert lm_block.MOE_COMPILER_SCOPES.items() <= scopes.items()
+    # and a weight's prefetch (the compiler's own slices of a step's
+    # parameter) under the part that multiplies by it
+    assert scopes["g[\\'layer_0.q_proj.w_0\\']"] == "paged_decoder/qkv"
+    assert scopes["g[\\'layer_1.o_proj.w_0\\']"] == \
+        "paged_decoder/attn_out"
+    assert set(scopes.values()) == {
+        "paged_decoder/moe_experts", "paged_decoder/moe_dispatch",
+        "paged_decoder/qkv", "paged_decoder/attn_out",
+        "paged_decoder/head"}
     f = jax.jit(lambda x: x + 1)
     try:
         profiler.register_jitted(
