@@ -4,11 +4,12 @@ PR 13's telemetry plane can say THAT a latency objective regressed;
 this layer says WHERE the time went and WHO is slow:
 
   * **Phase instrumentation** — :func:`phase(kind, name)` wraps one
-    phase of a serving tick (admit / prefill / decode / draft_verify /
-    sample / deliver / kv_alloc / kv_release), a training iteration
-    (feed_pack / h2d / compute / send_round / barrier_wait / get) or a
-    pserver round (optimize / recv / barrier) in a labeled child span
-    PLUS an observation into the per-kind
+    phase of a serving tick (admit / build / prefill / decode /
+    draft_verify / sample / deliver / kv_alloc / kv_release), a
+    training iteration (feed_pack / h2d / compute / send_round /
+    barrier_wait / get) or a pserver round (optimize / recv /
+    barrier) in a labeled child span PLUS an observation into the
+    per-kind
     ``paddle_tpu_<kind>_phase_seconds{phase=...}`` histogram family.
     Cost: one no-op context manager when both metrics and tracing are
     off; two perf_counter reads + a cached-child observe when on.
@@ -67,8 +68,9 @@ __all__ = [
 KINDS = ("generation", "trainer", "pserver")
 
 PHASES: Dict[str, Tuple[str, ...]] = {
-    "generation": ("admit", "prefill", "decode", "draft_verify",
-                   "sample", "deliver", "kv_alloc", "kv_release"),
+    "generation": ("admit", "build", "prefill", "decode",
+                   "draft_verify", "sample", "deliver", "kv_alloc",
+                   "kv_release"),
     "trainer": ("reader", "feed_pack", "h2d", "compute", "send_round",
                 "barrier_wait", "get"),
     "pserver": ("optimize", "recv", "barrier"),

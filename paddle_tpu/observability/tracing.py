@@ -77,10 +77,12 @@ _ENABLED = bool(_TRACE_DIR) or (os.environ.get("PADDLE_TPU_TRACE", "")
 # full records.  A runaway loop under tracing degrades (the oldest
 # record goes, `dropped_spans()` counts it) instead of eating the
 # host's memory: a record is about 0.8 kB, so the ring holds at most
-# some 50 MB, and only in a process that keeps spans live.  The busiest
-# benchmark cell (serving: 17 ticks a second, 7 spans a tick, 48 s
-# window) leaves about 5 k records.
-_MAX_SPANS = 65_536
+# some 210 MB, and only in a process that keeps spans live.  It has to
+# hold a benchmark window whole, or every reader of an attribute loses
+# the window's first seconds: the busiest cell (serving, 32 slots:
+# 201 ticks a second, 6 spans a tick and 3 a request, 48 s) leaves
+# about 67 k records, and a tick of 3.5 ms would leave 95 k.
+_MAX_SPANS = 262_144
 _spans: deque = deque(maxlen=_MAX_SPANS)
 _dropped = 0
 _lock = threading.Lock()

@@ -82,6 +82,7 @@ from .. import profiler
 from ..core.resilience import (fault_injector,
                                sched_fault_armed as _sched_fault)
 from ..observability import attribution as obs_attr
+from ..observability import flightrecorder
 from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
 from .batching import RequestDeadlineExceeded, ServerSaturated
@@ -320,6 +321,67 @@ class _Tick:
         self.tokens = None
 
 
+class _IterationClock:
+    """Where a scheduler iteration's time went, kept whether tracing
+    is on or off.  An iteration runs from the end of one blocking read
+    to the end of the next; the loop writes a `time.perf_counter`
+    reading into `deliver`, `admit`, `build` and `dispatch` as each
+    part ends (and `lock_wait`, the seconds `admit` spent taking the
+    server's lock), and `end` closes the iteration at the end of the
+    read.  `start` is None while the loop is not ticking (idle, a
+    flush, a hot swap, a failed tick): the next tick then begins an
+    iteration of its own.
+
+    The reference period is the median of the last 64 periods,
+    refreshed every 64 iterations (one sort of 64 floats); an
+    iteration longer than `SLOW_FACTOR` times it comes back from `end`
+    as a record naming the part that was longest.  Until 64 iterations
+    have run there is no reference and nothing is slow."""
+
+    __slots__ = ("start", "deliver", "admit", "build", "dispatch",
+                 "lock_wait", "reference", "_periods", "_n")
+
+    SLOW_FACTOR = 4.0
+    _WINDOW = 64
+
+    def __init__(self):
+        self.start: Optional[float] = None
+        self.deliver = self.admit = self.build = self.dispatch = 0.0
+        self.lock_wait = 0.0
+        self.reference: Optional[float] = None
+        self._periods = [0.0] * self._WINDOW
+        self._n = 0
+
+    def begin(self, now: float) -> None:
+        """An iteration with no read before it: nothing to deliver."""
+        self.start = self.deliver = now
+
+    def end(self, now: float, active: int) -> Optional[dict]:
+        start, self.start = self.start, now
+        if start is None:
+            return None
+        period = now - start
+        self._periods[self._n % self._WINDOW] = period
+        self._n += 1
+        if self._n % self._WINDOW == 0:
+            self.reference = sorted(self._periods)[self._WINDOW // 2]
+        ref = self.reference
+        if ref is None or period <= self.SLOW_FACTOR * ref:
+            return None
+        wait = now - self.dispatch
+        phase, longest = max(
+            (("deliver", self.deliver - start),
+             ("admit", self.admit - self.deliver),
+             ("build", self.build - self.admit),
+             ("dispatch", self.dispatch - self.build),
+             ("wait", wait)), key=lambda part: part[1])
+        return {"at": time.time(), "ms": 1e3 * period,
+                "wait_ms": 1e3 * wait, "phase": phase,
+                "phase_ms": 1e3 * longest, "active": active,
+                "lock_wait_ms": 1e3 * self.lock_wait,
+                "reference_ms": 1e3 * ref}
+
+
 @functools.lru_cache(maxsize=None)
 def _feed_tokens():
     """The jitted select that keeps sampled tokens on the device: a
@@ -517,6 +579,11 @@ class GenerationServer:
         # the tick dispatched and not yet read (None: nothing in
         # flight), and what the token select is given in its place
         self._inflight: Optional[_Tick] = None
+        # where each iteration's time goes, and the newest iterations
+        # that took over four reference periods (`stats()["slow_ticks"]`;
+        # rebound whole, never mutated: `stats` reads it unlocked)
+        self._clock = _IterationClock()
+        self._slow_ticks: List[dict] = []
         self._no_tokens = jax.device_put(
             np.zeros(self._slots, np.int32), self._device)
         self._active: List[Optional[_Seq]] = [None] * self._slots
@@ -851,7 +918,12 @@ class GenerationServer:
                "spec_k": self._spec_k if self._draft is not None else 0,
                "draining": draining,
                "recompiles_after_warmup": recompiles,
-               "warm_start": self.warm_start}
+               "warm_start": self.warm_start,
+               # the newest iterations of the scheduler that took over
+               # four reference periods, oldest first: `wait_ms` near
+               # `ms` puts one in the device or the runtime, anything
+               # else names host code by `phase` (docs/serving.md)
+               "slow_ticks": [dict(r) for r in self._slow_ticks]}
         out.update(self.warmup_stats)
         out.update(self._cache.prefix_stats())
         return out
@@ -965,46 +1037,63 @@ class GenerationServer:
         dispatched behind it (`_flush`) when no sequence has a position
         left to run, before a hot swap and at close.  A speculative
         server's accept rule is host code over the window's tokens, so
-        its tick stays serial (`_tick_spec`, nothing ever in flight)."""
+        its tick stays serial (`_tick_spec`, nothing ever in flight).
+
+        Every piece of an iteration's host work lies under one phase
+        span, in the order `deliver`, `admit`, `build`, `decode` or
+        `prefill`, `sample` (the one place the host waits), and
+        `self._clock` takes a clock reading as each ends, with tracing
+        on or off (docs/serving.md "The scheduler loop")."""
+        clock = self._clock
         while True:
-            with obs_attr.phase("generation", "admit"), self._lock:
-                if self._stop:
-                    break
-                shed = self._shed_expired_locked(time.monotonic())
-                admitted = self._admit_locked()
-                seqs = [s for s in self._active if s is not None]
-                swap = (self._pending_states
-                        if self._pending_states is not None
-                        and not seqs else None)
-                qdepth = len(self._queue)
-            metrics_on = obs_metrics.enabled()
-            for seq in shed:
-                self._m_deadline.inc()
-                self._request_span(seq, time.perf_counter(),
-                                   error="RequestDeadlineExceeded")
-                seq.stream._fail(RequestDeadlineExceeded(
-                    "request deadline expired while queued for "
-                    "admission"))
-            if admitted:
-                self._m_requests.inc(len(admitted))
-            if metrics_on:
-                self._m_qdepth.set(qdepth)
-                self._m_active.set(len(seqs))
+            if clock.start is None:
+                clock.begin(time.perf_counter())
+            with obs_attr.phase("generation", "admit") as asp:
+                t_lock = time.perf_counter()
+                with self._lock:
+                    clock.lock_wait = time.perf_counter() - t_lock
+                    if self._stop:
+                        break
+                    shed = self._shed_expired_locked(time.monotonic())
+                    admitted = self._admit_locked()
+                    seqs = [s for s in self._active if s is not None]
+                    swap = (self._pending_states
+                            if self._pending_states is not None
+                            and not seqs else None)
+                    qdepth = len(self._queue)
+                if asp is not None:
+                    asp.set_attr("lock_wait_s", clock.lock_wait)
+                metrics_on = obs_metrics.enabled()
+                for seq in shed:
+                    self._m_deadline.inc()
+                    self._request_span(seq, time.perf_counter(),
+                                       error="RequestDeadlineExceeded")
+                    seq.stream._fail(RequestDeadlineExceeded(
+                        "request deadline expired while queued for "
+                        "admission"))
+                if admitted:
+                    self._m_requests.inc(len(admitted))
+                if metrics_on:
+                    self._m_qdepth.set(qdepth)
+                    self._m_active.set(len(seqs))
+                if self._draft is None:
+                    # a sequence whose last position is in flight has
+                    # nothing left to run: it holds its slot until that
+                    # tick is delivered
+                    seqs = [s for s in seqs
+                            if s.cur < s.positions_needed]
+            clock.admit = time.perf_counter()
             if swap is not None:
                 # no sequence holds a slot, but the extra position of
                 # one that ended by eos may still be out
                 self._flush()
                 self._install_states(swap)
                 continue
-            if self._draft is None:
-                # a sequence whose last position is in flight has
-                # nothing left to run: it holds its slot until that
-                # tick is delivered
-                seqs = [s for s in seqs if s.cur < s.positions_needed]
             if not seqs:
                 if self._inflight is not None:
                     self._flush()
                     continue
+                clock.start = None
                 with self._lock:
                     if (not self._queue and not self._stop
                             and self._pending_states is None):
@@ -1025,13 +1114,9 @@ class GenerationServer:
                 continue
             if self._draft is not None:
                 self._deliver_spec(plans, preds, metrics_on)
-                # freshly-filled full prompt blocks become shareable
-                # the moment the cursor passes their end (no-op once a
-                # sequence has nothing pending or was evicted)
-                for seq in seqs:
-                    self._cache.commit_prefix(seq, seq.cur)
             elif done is not None:
                 self._deliver(done, metrics_on)
+            clock.deliver = time.perf_counter()
         self._flush()
 
     def _tick(self, seqs: List[_Seq]) -> Optional[_Tick]:
@@ -1039,38 +1124,59 @@ class GenerationServer:
         run, and advance their cursors; THEN block on the tokens of the
         tick dispatched before it.  Returns that earlier tick, read and
         ready for `_deliver` (None when nothing was in flight); the new
-        one stays in `self._inflight`.  One `serving.decode_tick` span
-        covers both halves."""
+        one stays in `self._inflight`.  The step's arrays are made
+        under the phase `build`; one `serving.decode_tick` span then
+        covers both halves, the dispatch (`decode` or `prefill`) and
+        the read (`sample`)."""
         prev = self._inflight
-        tokens = np.zeros(self._slots, np.int32)
-        positions = np.zeros(self._slots, np.int32)
-        temps = np.zeros(self._slots, np.float32)
-        seeds = np.zeros(self._slots, np.uint32)
-        active = np.zeros(self._slots, bool)
-        # slots whose token is the one `prev` sampled, still unread
-        from_prev = np.zeros(self._slots, bool)
-        rows = []
-        for seq in seqs:
-            rows.append((seq, seq.slot, seq.cur))
-            if seq.cur < len(seq.tokens):
-                tokens[seq.slot] = seq.tokens[seq.cur]
+        clock = self._clock
+        with obs_attr.phase("generation", "build") as bsp:
+            # a span is live: the tick span's counts come out of this
+            # one walk and the arrays it fills; else none is computed
+            # (a tick span that goes live between this block and the
+            # next, once an arming, carries `active` and `ahead` alone)
+            live = bsp is not None
+            tokens = np.zeros(self._slots, np.int32)
+            positions = np.zeros(self._slots, np.int32)
+            temps = np.zeros(self._slots, np.float32)
+            seeds = np.zeros(self._slots, np.uint32)
+            active = np.zeros(self._slots, bool)
+            # slots whose token is the one `prev` sampled, still unread
+            from_prev = np.zeros(self._slots, bool)
+            rows = []
+            prefill = 0
+            for seq in seqs:
+                slot, cur = seq.slot, seq.cur
+                rows.append((seq, slot, cur))
+                if cur < len(seq.tokens):
+                    tokens[slot] = seq.tokens[cur]
+                else:
+                    from_prev[slot] = True
+                positions[slot] = cur
+                temps[slot] = seq.temperature
+                seeds[slot] = seq.seed
+                active[slot] = True
+                if live and cur < seq.prompt_len - 1:
+                    prefill += 1
+            # attribution: dispatch is "prefill" while EVERY ticking
+            # sequence is still teacher-forcing its prompt, else
+            # "decode" (mixed ticks are decode work for at least one
+            # stream); the host-side sync that materializes the sampled
+            # tokens is "sample": that is where the host waits for the
+            # device
+            if live:
+                prefilling = prefill == len(rows)
             else:
-                from_prev[seq.slot] = True
-            positions[seq.slot] = seq.cur
-            temps[seq.slot] = seq.temperature
-            seeds[seq.slot] = seq.seed
-            active[seq.slot] = True
-        # attribution: dispatch is "prefill" while EVERY ticking
-        # sequence is still teacher-forcing its prompt, else "decode"
-        # (mixed ticks are decode work for at least one stream); the
-        # host-side sync that materializes the sampled tokens is
-        # "sample" — that is where the host waits for the device
-        phase_name = ("prefill" if all(s.cur < s.prompt_len - 1
-                                       for s in seqs) else "decode")
-        with obs_tracing.span("serving.decode_tick",
-                              active=len(seqs)) as sp:
+                prefilling = all(c < s.prompt_len - 1 for s, _, c in rows)
+            phase_name = "prefill" if prefilling else "decode"
+            tables = self._step_tables()
+            attrs = (self._tick_attrs(len(rows), prefill,
+                                      positions[active])
+                     if live else {})
+        clock.build = time.perf_counter()
+        with obs_tracing.span("serving.decode_tick", active=len(rows),
+                              **attrs) as sp:
             if sp is not None:
-                self._tick_attrs(sp, seqs)
                 sp.set_attr("ahead", int(prev is not None))
             with obs_attr.phase("generation", phase_name):
                 fault_injector().fire("serving.decode")
@@ -1080,17 +1186,32 @@ class GenerationServer:
                 nxt, self._pool_k, self._pool_v, *counts = (
                     self._decoder.step(
                         self._states, self._pool_k, self._pool_v,
-                        self._step_tables(), positions, fed, seeds,
-                        temps, active))
-            self._inflight = _Tick(rows, nxt, counts)
-            for seq in seqs:
-                seq.cur += 1
-            self._m_ticks.inc()
+                        tables, positions, fed, seeds, temps, active))
+                self._inflight = _Tick(rows, nxt, counts)
+                for seq in seqs:
+                    seq.cur += 1
+                self._m_ticks.inc()
+            clock.dispatch = time.perf_counter()
             if prev is not None:
                 with obs_attr.phase("generation", "sample"):
                     prev.tokens = np.asarray(prev.nxt)
                     self._step_counts(sp, prev.counts)
+            self._end_iteration(sp, len(rows))
         return prev
+
+    def _end_iteration(self, sp, active: int) -> None:
+        """Close the iteration at the end of its read.  One that took
+        over four reference periods is kept among the newest 8 of
+        `stats()["slow_ticks"]`, goes to the flight recorder where one
+        is armed, and marks the live tick span `slow=1`."""
+        rec = self._clock.end(time.perf_counter(), active)
+        if rec is None:
+            return
+        self._slow_ticks = (self._slow_ticks + [rec])[-8:]
+        flightrecorder.note("serving.slow_tick", server=self._sid,
+                            **rec)
+        if sp is not None:
+            sp.set_attr("slow", 1)
 
     def _step_tables(self):
         """The tables `step` takes: each slot's block table, with the
@@ -1102,12 +1223,14 @@ class GenerationServer:
             return self._tables.copy()
         return self._tables.copy(), self._rings
 
-    def _tick_attrs(self, sp, seqs: List[_Seq],
-                    window: bool = False) -> None:
-        """The scheduler's counts for the tick being dispatched, on its
-        live `serving.decode_tick` span: `prefill` slots teacher-force
-        a prompt position and deliver nothing (cursor below
-        prompt_len - 1), `kv_used` of `kv_total` pool blocks are
+    def _tick_attrs(self, n: int, prefill: int, cur: np.ndarray,
+                    window: bool = False) -> dict:
+        """The scheduler's counts for the tick being dispatched, for
+        its `serving.decode_tick` span, from what `build` has already
+        made: `n` slots, `prefill` of them teacher-forcing a prompt
+        position (cursor below prompt_len - 1: they deliver nothing),
+        and `cur`, the step's `positions` at those slots.  Only called
+        while a span is live.  `kv_used` of `kv_total` pool blocks are
         owned.  `kv_pages_read` of `kv_pages_table`: the K/V pages the
         dispatched step's attention reads, summed over slots and
         attention layers, of the pages the slots' tables and rings
@@ -1126,35 +1249,35 @@ class GenerationServer:
         step starts from a zero state).  With experts `moe_kernel`:
         1 where the step's expert layer is the Pallas grouped matmul
         (`decoder.expert_kernel`), 0 where `ragged_dot`."""
-        sp.set_attr("prefill", sum(1 for s in seqs
-                                   if s.cur < s.prompt_len - 1))
-        sp.set_attr("kv_used", self._cache.used_blocks)
-        sp.set_attr("kv_total", self._cache.num_blocks)
+        attrs = {"prefill": prefill,
+                 "kv_used": self._cache.used_blocks,
+                 "kv_total": self._cache.num_blocks}
+        rows = cur.astype(np.int64) + 1      # K/V rows a slot attends
         read = self._kv_pages_table
         if self._kv_streamed and not window:
             bs = self._cache.block_size
             full, win = self._kv_layers
-            ring_rows = 0 if self._rings is None else (
-                self._rings.shape[1] * bs)
-            read = (self._slots - len(seqs)) * (full + win) + sum(
-                full * -(-(s.cur + 1) // bs)
-                + win * -(-min(s.cur + 1, ring_rows) // bs) for s in seqs)
-        sp.set_attr("kv_pages_read", read)
-        sp.set_attr("kv_pages_table", self._kv_pages_table)
+            read = ((self._slots - n) * (full + win)
+                    + full * int((-(-rows // bs)).sum()))
+            if win:
+                ring_rows = self._rings.shape[1] * bs
+                read += win * int(
+                    (-(-np.minimum(rows, ring_rows) // bs)).sum())
+        attrs["kv_pages_read"] = read
+        attrs["kv_pages_table"] = self._kv_pages_table
         if self._window:
-            sp.set_attr("past_window", sum(1 for s in seqs
-                                           if s.cur >= self._window))
-            sp.set_attr("kv_rows_full", sum(s.cur + 1 for s in seqs))
-            sp.set_attr("kv_rows_win", sum(min(s.cur + 1, self._window)
-                                           for s in seqs))
+            attrs["past_window"] = int((rows > self._window).sum())
+            attrs["kv_rows_full"] = int(rows.sum())
+            attrs["kv_rows_win"] = int(
+                np.minimum(rows, self._window).sum())
         if self._stateful:
-            sp.set_attr("state_lanes", len(seqs))
-            sp.set_attr("state_resets", sum(1 for s in seqs
-                                            if s.cur == 0))
+            attrs["state_lanes"] = n
+            attrs["state_resets"] = n - int(np.count_nonzero(cur))
         expert_kernel = getattr(self._decoder, "expert_kernel", None)
         if expert_kernel is not None:
-            sp.set_attr("moe_kernel",
-                        int(not expert_kernel.startswith("xla:")))
+            attrs["moe_kernel"] = int(
+                not expert_kernel.startswith("xla:"))
+        return attrs
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live
@@ -1170,7 +1293,9 @@ class GenerationServer:
 
     def _flush(self) -> None:
         """Read and deliver the tick in flight, if any, with nothing
-        dispatched behind it (no span: a span is a dispatch)."""
+        dispatched behind it (no span: a span is a dispatch; and no
+        iteration of the clock's: the next tick begins one)."""
+        self._clock.start = None
         tick, self._inflight = self._inflight, None
         if tick is None:
             return
@@ -1188,6 +1313,7 @@ class GenerationServer:
         slot is in a tick that is lost with it, so all are evicted and
         fail with the error, and what is in flight is dropped unread."""
         self._inflight = None
+        self._clock.start = None
         with self._lock:
             seqs = [s for s in self._active if s is not None]
             for seq in seqs:
@@ -1198,6 +1324,10 @@ class GenerationServer:
             seq.stream._fail(exc)
 
     def _deliver(self, tick: _Tick, metrics_on: bool):
+        """Hand the tokens of a tick that has been read to their
+        streams, evict and close out the sequences that ended, and
+        make the prompt blocks the tick filled shareable: all of it
+        under the phase `deliver`."""
         now = time.perf_counter()
         delivered = 0
         finished = []
@@ -1224,21 +1354,28 @@ class GenerationServer:
                         or (seq.eos_id is not None
                             and tok == seq.eos_id)):
                     finished.append(seq)
-        if delivered:
-            self._m_tokens.inc(delivered)
-        if finished:
-            with self._lock:
-                for seq in finished:
-                    self._evict_locked(seq)
-                self._lock.notify_all()
+            if delivered:
+                self._m_tokens.inc(delivered)
+            self._finish_seqs(finished, now, metrics_on)
+            # freshly-filled full prompt blocks become shareable once
+            # the tick that passed their end has been READ, so a block
+            # of a tick that fails is never shared (no-op once a
+            # sequence has nothing pending or was evicted)
+            for seq, _, cur in tick.rows:
+                self._cache.commit_prefix(seq, cur + 1)
+
+    def _finish_seqs(self, finished: List[_Seq], now: float,
+                     metrics_on: bool):
+        """Evict the sequences a delivery ended, under the lock, and
+        close each out."""
+        if not finished:
+            return
+        with self._lock:
             for seq in finished:
-                self._finish_seq(seq, now, metrics_on)
-        # freshly-filled full prompt blocks become shareable once the
-        # tick that passed their end has been READ, so a block of a
-        # tick that fails is never shared (no-op once a sequence has
-        # nothing pending or was evicted)
-        for seq, _, cur in tick.rows:
-            self._cache.commit_prefix(seq, cur + 1)
+                self._evict_locked(seq)
+            self._lock.notify_all()
+        for seq in finished:
+            self._finish_seq(seq, now, metrics_on)
 
     def _finish_seq(self, seq: _Seq, now: float, metrics_on: bool):
         """Close out a finished sequence: record the end-to-end
@@ -1284,16 +1421,19 @@ class GenerationServer:
         follow them.  Sampled requests and prefill interiors get
         n_prop=0 — pure (chunked) teacher forcing."""
         w = self._spec_k + 1
+        clock = self._clock
         plans = []
-        for seq in seqs:
-            c = seq.cur
-            m = len(seq.tokens) - c
-            n_max = min(w, seq.positions_needed - c)
-            teacher = min(m, n_max)
-            greedy = seq.temperature == 0.0
-            n_prop = (n_max - teacher
-                      if greedy and teacher == m else 0)
-            plans.append((seq, c, m, teacher, n_prop))
+        with obs_attr.phase("generation", "build"):
+            for seq in seqs:
+                c = seq.cur
+                m = len(seq.tokens) - c
+                n_max = min(w, seq.positions_needed - c)
+                teacher = min(m, n_max)
+                greedy = seq.temperature == 0.0
+                n_prop = (n_max - teacher
+                          if greedy and teacher == m else 0)
+                plans.append((seq, c, m, teacher, n_prop))
+        clock.build = time.perf_counter()
 
         # draft catch-up: teacher-force the draft over the window's
         # committed head so its KV tracks the target's (positions a
@@ -1362,34 +1502,41 @@ class GenerationServer:
                     seq.draft_next = pos[seq.slot] + 1
 
         # ONE target dispatch verifies/extends every slot's window
-        pos = np.zeros(self._slots, np.int32)
-        toks = np.zeros((self._slots, w), np.int32)
-        nv = np.zeros(self._slots, np.int32)
-        temps = np.zeros(self._slots, np.float32)
-        seeds = np.zeros(self._slots, np.uint32)
-        for seq, c, m, teacher, n_prop in plans:
-            window = seq.tokens[c:c + teacher] + proposals[seq]
-            pos[seq.slot] = c
-            toks[seq.slot, :len(window)] = window
-            nv[seq.slot] = teacher + n_prop
-            temps[seq.slot] = seq.temperature
-            seeds[seq.slot] = seq.seed
-        with obs_tracing.span("serving.decode_tick", active=len(seqs),
-                              speculative=True) as sp:
-            if sp is not None:
-                self._tick_attrs(sp, seqs, window=True)
+        with obs_attr.phase("generation", "build") as bsp:
+            pos = np.zeros(self._slots, np.int32)
+            toks = np.zeros((self._slots, w), np.int32)
+            nv = np.zeros(self._slots, np.int32)
+            temps = np.zeros(self._slots, np.float32)
+            seeds = np.zeros(self._slots, np.uint32)
+            prefill = 0
+            for seq, c, m, teacher, n_prop in plans:
+                window = seq.tokens[c:c + teacher] + proposals[seq]
+                pos[seq.slot] = c
+                toks[seq.slot, :len(window)] = window
+                nv[seq.slot] = teacher + n_prop
+                temps[seq.slot] = seq.temperature
+                seeds[seq.slot] = seq.seed
+                if bsp is not None and c < seq.prompt_len - 1:
+                    prefill += 1
+            full_plans = [(seq, c, m, teacher, n_prop, proposals[seq])
+                          for seq, c, m, teacher, n_prop in plans]
+            attrs = (self._tick_attrs(len(plans), prefill, pos[nv > 0],
+                                      window=True)
+                     if bsp is not None else {})
+        with obs_tracing.span("serving.decode_tick", active=len(plans),
+                              speculative=True, **attrs) as sp:
             with obs_attr.phase("generation", "draft_verify"):
                 fault_injector().fire("serving.decode")
                 nxt, self._pool_k, self._pool_v, *counts = (
                     self._decoder.step_window(
                         self._states, self._pool_k, self._pool_v,
                         self._tables, pos, toks, seeds, temps, nv))
+                self._m_ticks.inc()
+            clock.dispatch = time.perf_counter()
             with obs_attr.phase("generation", "sample"):
                 preds = np.asarray(nxt)
                 self._step_counts(sp, counts)
-        self._m_ticks.inc()
-        full_plans = [(seq, c, m, teacher, n_prop, proposals[seq])
-                      for seq, c, m, teacher, n_prop in plans]
+            self._end_iteration(sp, len(plans))
         return full_plans, preds
 
     def _deliver_spec(self, plans, preds: np.ndarray, metrics_on: bool):
@@ -1448,19 +1595,18 @@ class GenerationServer:
                             or (seq.eos_id is not None
                                 and emitted[-1] == seq.eos_id)):
                         finished.append(seq)
-        if delivered:
-            self._m_tokens.inc(delivered)
-        if proposed:
-            self._m_proposed.inc(proposed)
-        if accepted:
-            self._m_accepted.inc(accepted)
-        if finished:
-            with self._lock:
-                for seq in finished:
-                    self._evict_locked(seq)
-                self._lock.notify_all()
-            for seq in finished:
-                self._finish_seq(seq, now, metrics_on)
+            if delivered:
+                self._m_tokens.inc(delivered)
+            if proposed:
+                self._m_proposed.inc(proposed)
+            if accepted:
+                self._m_accepted.inc(accepted)
+            self._finish_seqs(finished, now, metrics_on)
+            # freshly-filled full prompt blocks become shareable the
+            # moment the cursor passes their end (no-op once a
+            # sequence has nothing pending or was evicted)
+            for plan in plans:
+                self._cache.commit_prefix(plan[0], plan[0].cur)
 
     def _install_states(self, pending):
         import jax

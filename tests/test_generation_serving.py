@@ -810,6 +810,270 @@ def test_draft_model_server_keeps_its_serial_tick():
 
 
 # ---------------------------------------------------------------------------
+# the scheduler's iteration: phases that tile it, the slowest named
+# ---------------------------------------------------------------------------
+
+
+_HOST_PHASES = ("deliver", "admit", "build", "decode", "prefill")
+
+
+def _iterations(spans):
+    """Cut the scheduler's phase spans (in the order they ended) at the
+    ends of the `sample` spans: [(period, {phase: seconds})], one an
+    iteration from the end of one blocking read to the end of the
+    next."""
+    out, last_end, acc = [], None, {}
+    for s in spans:
+        if not s["name"].startswith("generation.phase."):
+            continue
+        name = s["name"][len("generation.phase."):]
+        if name in _HOST_PHASES or name == "sample":
+            acc[name] = acc.get(name, 0.0) + s["dur"]
+        if name == "sample":
+            end = s["ts"] + s["dur"]
+            if last_end is not None:
+                out.append((end - last_end, acc))
+            last_end, acc = end, {}
+    return out
+
+
+def test_phases_tile_the_scheduler_iteration():
+    """Under a span listener alone every piece of an iteration's host
+    work lies under one phase span: `deliver`, `admit`, `build`, the
+    dispatch (`decode` or `prefill`) and `sample` add up to the period
+    between the ends of two reads.  The step is slowed to 4 ms (a delay
+    at the chaos hook, inside the dispatch phase) so that the span
+    bookkeeping itself, a few tens of microseconds an iteration, is
+    not what is measured."""
+    from paddle_tpu.core.resilience import fault_injector
+    from paddle_tpu.observability import attribution, tracing
+
+    assert "build" in attribution.PHASES["generation"]
+    dec, states = _decoder()
+    inj = fault_injector()
+    inj.clear()
+    srv = GenerationServer(dec, states, slots=4, kv_blocks=20,
+                           place=fluid.CPUPlace())
+    got = []
+    tracing.clear()
+    tracing.add_span_listener(got.append)
+    try:
+        inj.inject("serving.decode", "delay", nth=1, count=10 ** 9,
+                   delay_s=0.004)
+        for _ in range(4):
+            for s in _submit_together(srv, ([3, 1, 4], 16), ([1, 5], 14),
+                                      ([9, 2, 6, 5], 12)):
+                s.result(timeout=60)
+        admits = [s["attrs"] for s in got
+                  if s["name"] == "generation.phase.admit"]
+    finally:
+        tracing.remove_span_listener(got.append)
+        tracing.clear()
+        inj.clear()
+        srv.close()
+    its = [(period, acc) for period, acc in _iterations(got)
+           if "decode" in acc or "prefill" in acc]
+    assert len(its) >= 50
+    # every iteration has every phase but, at a busy period's start,
+    # a delivery
+    assert all({"admit", "build", "sample"} <= set(acc) for _, acc in its)
+    assert sum("deliver" in acc for _, acc in its) >= len(its) - 8
+    covered = [sum(acc.values()) / period for period, acc in its]
+    total = sum(sum(acc.values()) for _, acc in its) / sum(
+        period for period, _ in its)
+    # (a period is read off the wall clock the spans start on, a
+    # duration off the monotonic one: they may drift by parts in 1e4)
+    assert 0.9 <= total <= 1.001, total
+    # one by one too, but for the few a loaded host preempts between
+    # two spans
+    assert sum(0.9 <= c <= 1.001 for c in covered) >= \
+        0.9 * len(covered), sorted(covered)[:8]
+    assert admits and all(
+        0.0 <= a["lock_wait_s"] < 0.5 for a in admits)
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("traced", [False, True],
+                         ids=["untraced", "traced"])
+def test_slow_iteration_is_kept_and_named_by_its_phase(traced,
+                                                       monkeypatch):
+    """The loop times every iteration with tracing on or off: one that
+    a 50 ms delay at the dispatch makes slow is kept in
+    `stats()["slow_ticks"]` with the phase that held it, noted to the
+    flight recorder, and marks its tick span where spans are live."""
+    from paddle_tpu.core.resilience import fault_injector
+    from paddle_tpu.observability import flightrecorder, tracing
+
+    dec, states = _decoder()
+    inj = fault_injector()
+    inj.clear()
+    notes = []
+    monkeypatch.setattr(flightrecorder, "note",
+                        lambda event, **data: notes.append((event, data)))
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    tracing.clear()
+    tracing.set_enabled(traced)
+    try:
+        # 64 iterations give the clock its reference period
+        for _ in range(6):
+            srv.submit([3, 1, 4], 16).result(timeout=60)
+        assert srv._clock.reference is not None
+        before = len(srv.stats()["slow_ticks"])
+        inj.inject("serving.decode", "delay", nth=6, count=1,
+                   delay_s=0.05)
+        srv.submit([1, 5, 9], 16).result(timeout=60)
+        slow = srv.stats()["slow_ticks"]
+        spans = tracing.finished_spans()
+    finally:
+        tracing.set_enabled(False)
+        tracing.clear()
+        inj.clear()
+        srv.close()
+    assert before <= len(slow) <= 8
+    (rec,) = [r for r in slow if r["ms"] >= 50.0]
+    assert rec["phase"] == "dispatch" and rec["phase_ms"] >= 50.0
+    assert rec["wait_ms"] < 25.0 and rec["active"] == 1
+    assert rec["ms"] > 4 * rec["reference_ms"] > 0
+    assert abs(rec["at"] - time.time()) < 120
+    assert ("serving.slow_tick", dict(rec, server=srv._sid)) in notes
+    marked = [s for s in spans if s["name"] == "serving.decode_tick"
+              and s["attrs"].get("slow")]
+    if traced:
+        assert any(s["dur"] >= 0.05 for s in marked)
+    else:
+        assert spans == []
+
+
+def test_untraced_ticks_make_no_span_and_count_nothing(monkeypatch):
+    """With tracing off (and no listener) a hundred ticks create no
+    span and compute none of the tick span's counts."""
+    from paddle_tpu.observability import metrics, tracing
+
+    assert not (metrics.enabled() or tracing.enabled()
+                or tracing._listeners)
+    made = []
+    monkeypatch.setattr(tracing.Span, "__init__",
+                        lambda self, *a, **k: made.append(a))
+    monkeypatch.setattr(tracing, "_store", made.append)
+    dec, states = _decoder()
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=10,
+                           place=fluid.CPUPlace())
+    counted = []
+    attrs = srv._tick_attrs
+    srv._tick_attrs = lambda *a, **k: (counted.append(a),
+                                       attrs(*a, **k))[1]
+    try:
+        for _ in range(7):
+            srv.submit([3, 1, 4], 16).result(timeout=60)
+        ticks = srv.stats()["ticks"]
+    finally:
+        srv.close()
+    assert ticks >= 100
+    assert made == [] and counted == []
+
+
+def _old_tick_attrs(srv, seqs, window=False):
+    """`GenerationServer._tick_attrs` as it was before PR 36, a walk of
+    its own over `seqs` for each count: the oracle."""
+    out = {"prefill": sum(1 for s in seqs if s.cur < s.prompt_len - 1),
+           "kv_used": srv._cache.used_blocks,
+           "kv_total": srv._cache.num_blocks}
+    read = srv._kv_pages_table
+    if srv._kv_streamed and not window:
+        bs = srv._cache.block_size
+        full, win = srv._kv_layers
+        ring_rows = 0 if srv._rings is None else (
+            srv._rings.shape[1] * bs)
+        read = (srv._slots - len(seqs)) * (full + win) + sum(
+            full * -(-(s.cur + 1) // bs)
+            + win * -(-min(s.cur + 1, ring_rows) // bs) for s in seqs)
+    out["kv_pages_read"] = read
+    out["kv_pages_table"] = srv._kv_pages_table
+    if srv._window:
+        out["past_window"] = sum(1 for s in seqs if s.cur >= srv._window)
+        out["kv_rows_full"] = sum(s.cur + 1 for s in seqs)
+        out["kv_rows_win"] = sum(min(s.cur + 1, srv._window)
+                                 for s in seqs)
+    if srv._stateful:
+        out["state_lanes"] = len(seqs)
+        out["state_resets"] = sum(1 for s in seqs if s.cur == 0)
+    expert_kernel = getattr(srv._decoder, "expert_kernel", None)
+    if expert_kernel is not None:
+        out["moe_kernel"] = int(not expert_kernel.startswith("xla:"))
+    return out
+
+
+def _block_decoder(kind):
+    """A decoder of each kind of state at toy widths: the table alone,
+    a ring beside it (sliding layers), a recurrent state a lane."""
+    from paddle_tpu.models import lm_block
+    from paddle_tpu.models.transformer import build_lm_paged_decoder
+
+    if kind == "table":
+        return _decoder(max_blocks=6)
+    common = dict(norm="rms_norm", ffn="moe_swiglu", bias=False,
+                  n_experts=4, experts_per_token=2, norm_topk_prob=True,
+                  n_kv_heads=2)
+    if kind == "ring":
+        spec = lm_block.BlockSpec(
+            name="ring", positions="rope", d_head=8, window=8,
+            layer_types=["sliding_attention"] * 3 + ["full_attention"],
+            **common)
+    else:
+        spec = lm_block.BlockSpec(
+            name="state", positions="none", tied_head=True,
+            layer_types=["mamba", "attention", "mamba", "mamba"],
+            ssm_heads=4, ssm_d_head=8, ssm_d_state=8, ssm_conv=4,
+            **common)
+    _, dec = build_lm_paged_decoder(
+        V, 4, 6, d_model=32, n_heads=4, n_layers=4, d_inner=16,
+        block=spec, platform="cpu")
+    rng = np.random.RandomState(0)
+    states = {n: (0.05 * rng.randn(*shape)).astype(np.float32)
+              for n, shape in dec.state_shapes.items()}
+    return dec, states
+
+
+@pytest.mark.parametrize("kind,streamed", [
+    ("table", False), ("table", True), ("ring", True), ("state", True)])
+def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
+    """The counts on `serving.decode_tick` come out of the one loop
+    `build` runs and the `positions` it fills: tick for tick they are
+    what the old `_tick_attrs` computed from the same sequences in up
+    to seven walks.  `streamed` reads the pages as the Pallas kernel
+    would (the attribute alone: the CPU's step gathers)."""
+    dec, states = _block_decoder(kind)
+    srv = GenerationServer(dec, states, slots=3, kv_blocks=18,
+                           place=fluid.CPUPlace(), prefix_cache=False)
+    srv._kv_streamed = streamed
+    want = []
+    tick = srv._tick
+    srv._tick = lambda seqs: (want.append(
+        dict(_old_tick_attrs(srv, seqs), active=len(seqs))),
+        tick(seqs))[1]
+    requests = [([3, 1, 4, 1, 5], 12), ([2, 7], 20), ([1], 9),
+                ([6, 2, 8, 3, 1, 8, 5], 14), ([4, 4], 3)]
+    try:
+        with _tick_spans() as ticks:
+            for s in [srv.submit(p, m) for p, m in requests]:
+                s.result(timeout=60)
+    finally:
+        srv.close()
+    assert len(ticks) == len(want) >= 30
+    extra = {"ahead"} | set(getattr(dec, "step_counters", ()))
+    for got, old in zip(ticks, want):
+        assert {k: v for k, v in got.items() if k not in extra} == old
+        assert all(type(v) is int for v in got.values())
+    assert any(a["prefill"] for a in want)
+    if kind == "ring":
+        assert any(a["past_window"] for a in want)
+        assert any(a["kv_pages_read"] < a["kv_pages_table"] for a in want)
+    if kind == "state":
+        assert sum(a["state_resets"] for a in want) == len(requests)
+
+
+# ---------------------------------------------------------------------------
 # scheduling: admission control, shedding, streaming
 # ---------------------------------------------------------------------------
 

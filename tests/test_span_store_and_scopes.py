@@ -103,7 +103,9 @@ def test_store_is_a_ring_that_drops_the_oldest_and_counts(monkeypatch):
     assert len(tracing.finished_spans(last=9)) == 4
     tracing.clear()
     assert tracing.finished_spans() == [] and tracing.dropped_spans() == 0
-    assert tracing._MAX_SPANS >= 4 * 17 * 7 * 63   # 4x the serving cell
+    # the busiest cell's window whole, with room: 201 ticks a second of
+    # 6 spans, 62 requests a second of 3, 48 s (docs/observability.md)
+    assert tracing._MAX_SPANS >= 2 * 48 * (201 * 6 + 62 * 3)
 
 
 # ---------------------------------------------------------------------------
@@ -178,6 +180,107 @@ def test_shed_request_span_carries_error():
     assert a["error"] == "RequestDeadlineExceeded" and a["tokens"] == 0
     assert a["queue_s"] == pytest.approx(shed[0]["dur"], abs=1e-9)
     assert a["prefill_s"] == 0 and a["decode_s"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the nine readers of the scheduler's iteration (perf/metrics/sched_*.py)
+# ---------------------------------------------------------------------------
+
+_SCHED_READERS = (
+    "sched_host_busy_share", "sched_admit_ms", "sched_build_ms",
+    "sched_dispatch_ms", "sched_deliver_ms", "sched_lock_wait_ms",
+    "sched_host_unattributed_share", "sched_iteration_max_ms",
+    "sched_iteration_max_host_ms")
+
+
+def _read_sched(monkeypatch, iterations):
+    """Record the given iterations as the scheduler would (phase spans
+    in the order they end, under a tap like the benchmark's) and give
+    what each of the nine readers makes of them.  An iteration is a
+    list of (phase, seconds, attrs); 1 ms passes before every
+    iteration's first phase, under no span."""
+    import os
+    import sys
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf")
+    monkeypatch.syspath_prepend(perf)
+    monkeypatch.delitem(sys.modules, "common", raising=False)
+    import common
+
+    tap = common.SpanTap()
+    tap.arm()
+    try:
+        t = 1000.0
+        for phases in iterations:
+            t += 0.001
+            for name, dur, attrs in phases:
+                tracing.record_span("generation.phase." + name, t, dur,
+                                    **attrs)
+                t += dur
+    finally:
+        tap.disarm()
+    run = common.Run()
+    run.spans = tap.records
+    return {name: common.load_module(os.path.join(
+        perf, "metrics", name + ".py")).compute(run)
+        for name in _SCHED_READERS}
+
+
+def _iteration(deliver=0.001, admit=0.002, build=0.001, dispatch=0.003,
+               sample=0.002, lock=0.0005, kind="decode"):
+    return [p for p in (("deliver", deliver, {}),
+                        ("admit", admit, {"lock_wait_s": lock}),
+                        ("build", build, {}), (kind, dispatch, {}),
+                        ("sample", sample, {})) if p[1] is not None]
+
+
+def test_scheduler_iteration_readers_on_known_spans(monkeypatch):
+    """Three counted iterations: a plain one (1 ms of it under no
+    span), one stalled 0.5 s in `sample`, one with no delivery and a
+    `prefill` dispatch.  Before them the read that opens the window's
+    first period; after them two the readers leave out: the loop went
+    round twice (an idle poll), and a speculative tick."""
+    got = _read_sched(monkeypatch, [
+        [("sample", 0.002, {})],
+        _iteration(),
+        _iteration(sample=0.5, lock=0.0015),
+        _iteration(deliver=None, sample=0.003, lock=0.001,
+                   kind="prefill"),
+        [("admit", 0.3, {})] + _iteration(),
+        [("draft_verify", 0.2, {})] + _iteration()])
+    # periods 10, 508 and 10 ms, a millisecond of each under no span
+    assert got["sched_iteration_max_ms"] == pytest.approx(508.0)
+    assert got["sched_iteration_max_host_ms"] == pytest.approx(8.0)
+    assert got["sched_host_busy_share"] == pytest.approx(
+        100 * (1 - 0.505 / 0.528))
+    assert got["sched_admit_ms"] == pytest.approx(2.0)
+    assert got["sched_build_ms"] == pytest.approx(1.0)
+    assert got["sched_dispatch_ms"] == pytest.approx(3.0)
+    assert got["sched_deliver_ms"] == pytest.approx(2.0 / 3)
+    assert got["sched_host_unattributed_share"] == pytest.approx(
+        100 * 0.003 / 0.023)
+    # every admit of the window, the left-out iterations' too
+    assert got["sched_lock_wait_ms"] == pytest.approx(
+        1e3 * (0.0005 * 3 + 0.0015 + 0.001) / 5)
+
+
+def test_scheduler_iteration_readers_say_nothing_when_they_cannot(
+        monkeypatch):
+    """A program whose phases do not tile the iteration (no `build`:
+    the parent of PR 36) gives none of the nine; a span store that has
+    dropped records gives no `sched_lock_wait_ms` and leaves the
+    readers of durations, which read the tap's own list, alone."""
+    old = [_iteration(build=None, lock=None)[:1]
+           + [("admit", 0.002, {})] + _iteration(build=None)[2:]
+           for _ in range(3)]
+    assert set(_read_sched(monkeypatch, old).values()) == {None}
+    tracing.clear()
+    monkeypatch.setattr(tracing, "_dropped", 1)
+    got = _read_sched(monkeypatch, [_iteration() for _ in range(3)])
+    assert got.pop("sched_lock_wait_ms") is None
+    assert None not in got.values()
+    assert got["sched_iteration_max_ms"] == pytest.approx(10.0)
 
 
 # ---------------------------------------------------------------------------
