@@ -28,6 +28,8 @@ import queue
 import threading
 import time
 
+import numpy as np
+
 __all__ = ["prefetch_feeder", "PrefetchIterator", "PrefetchReader",
            "stage_to_device"]
 
@@ -79,6 +81,26 @@ class PrefetchIterator:
       `depth=1` batch n+1 is read no earlier than the take of batch n:
       exactly one ahead.  That bounds host memory, device memory and
       how far a stateful reader runs ahead of its consumer;
+    * host buffers: with a `DataFeeder` and `device_put=True` the
+      arrays the feeder packs stay this iterator's, ONE a dense feed
+      name whatever the depth, and the next batch is packed into them
+      (`DataFeeder.feed(batch, out=...)`: no allocation, no page
+      faults).  The invariant: a buffer is written again only after the
+      transfer that read it has completed, and never while anything the
+      consumer can hold refers to its memory.  Kept by the form that
+      blocks: the worker waits for the staged arrays
+      (`block_until_ready`, which holds no GIL) BEFORE it hands the
+      batch over, so the consumer only ever holds device arrays whose
+      transfer is over, and a buffer is free the moment its batch is
+      handed on.  The form that does not block needs `depth + 1`
+      buffers and a wait before each rewrite, to hide a transfer that
+      is a seventh of the step it already runs beside.  On a cpu device
+      the staged array can BE the buffer (the backend takes an aligned
+      numpy array without a copy): then the consumer owns that memory
+      and the iterator lets it go.  A batch of another shape gets new
+      arrays, which become the buffers; with `device_put=False` the
+      consumer holds the host arrays and nothing is reused, as for a
+      feeder whose `feed` is its own;
     * errors: any exception in the reader/feeder/transfer re-raises at the
       consumer's next `__next__` (after the good batches before it);
     * shutdown: `close()` (idempotent; also called on exhaustion) stops
@@ -113,6 +135,13 @@ class PrefetchIterator:
         self.last_ready = False
         self._feeder = feeder
         self._device_put = device_put
+        from ..data_feeder import DataFeeder
+
+        # `out=` is DataFeeder.feed's own; an overriding feed is a
+        # caller's, with the one argument it always had
+        self._reuse = device_put and \
+            getattr(type(feeder), "feed", None) is DataFeeder.feed
+        self._bufs = {}  # dense feed name -> host array free to rewrite
         # thread handoff: batches prepared on the worker record under
         # the span that constructed the iterator (e.g. the pass that
         # opened the reader)
@@ -144,9 +173,22 @@ class PrefetchIterator:
             yield batch
 
     def _prepare(self, batch):
+        packed = {}
         if self._feeder is not None:
-            with obs_attr.phase("trainer", "feed_pack"):
-                feed = self._feeder.feed(batch)
+            with obs_attr.phase("trainer", "feed_pack") as span:
+                if self._reuse:
+                    feed = self._feeder.feed(batch, out=self._bufs)
+                else:
+                    feed = self._feeder.feed(batch)
+                if isinstance(feed, dict):
+                    packed = {k: v for k, v in feed.items()
+                              if isinstance(v, np.ndarray)}
+                if span is not None:
+                    span.set_attr("bytes", sum(
+                        v.nbytes for v in packed.values()))
+                    span.set_attr("reused", int(bool(packed) and all(
+                        v is self._bufs.get(k)
+                        for k, v in packed.items())))
         else:
             feed = batch  # reader already yields feed dicts
         if not self._device_put:
@@ -157,7 +199,19 @@ class PrefetchIterator:
                         for k, v in feed.items()}
             else:
                 feed = stage_to_device(feed, self._device)
+            if self._reuse:
+                import jax
+
+                jax.block_until_ready([feed[k] for k in packed])
+                self._bufs = {k: v for k, v in packed.items()
+                              if not self._is_staged(v, feed[k])}
         return feed
+
+    def _is_staged(self, host, staged):
+        """Whether the staged array's memory is the host array's own:
+        only a device whose memory is the host's can do that."""
+        return self._device.platform == "cpu" and np.shares_memory(
+            host, np.asarray(staged))
 
     def _work(self, reader):
         try:

@@ -592,6 +592,162 @@ class TestTrainerAsync:
 
 
 # ---------------------------------------------------------------------------
+# the pipeline's host buffers (PR 37): ONE a dense feed name at any depth,
+# free again the moment its batch is handed on, because the worker waits
+# for the transfer first
+# ---------------------------------------------------------------------------
+
+
+def _placed(offset, made=None):
+    """A `data_feeder._allocate` whose arrays start `offset` bytes past
+    a 64-byte boundary.  The CPU backend stages a numpy array WITHOUT a
+    copy exactly when it is 64-byte aligned, and where `np.empty` puts
+    a small array is chance: the tests choose."""
+
+    def allocate(shape, dtype):
+        dtype = np.dtype(dtype)
+        n = int(np.prod(shape)) * dtype.itemsize
+        raw = np.empty(n + 128, np.uint8)
+        at = (-raw.ctypes.data) % 64 + offset
+        if made is not None:
+            made.append(tuple(shape))
+        return raw[at:at + n].view(dtype).reshape(shape)
+
+    return allocate
+
+
+def _xy_feeder(dim=16):
+    reset_unique_names()
+    with fluid.program_guard(fluid.Program(), fluid.Program()):
+        x = fluid.layers.data(name="x", shape=[dim], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+    return DataFeeder([x, y], fluid.CPUPlace())
+
+
+def _distinct_batches(bs=8, short=3):
+    """Eight distinct full batches and the short last one of a pass."""
+    data = _deterministic_data(n_batches=9, bs=bs)
+    data[-1] = data[-1][:short]
+    return data
+
+
+def _pack_spans(run):
+    from paddle_tpu.observability import tracing
+
+    was = tracing.enabled()
+    tracing.set_enabled(True)
+    tracing.clear()
+    try:
+        run()
+        return [s["attrs"] for s in tracing.finished_spans()
+                if s["name"] == "trainer.phase.feed_pack"]
+    finally:
+        tracing.set_enabled(was)
+        tracing.clear()
+
+
+class TestPackedBuffers:
+    @pytest.mark.parametrize("placed", ["aligned", "off_by_16", "numpy"])
+    @pytest.mark.parametrize("device_put", [True, False])
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_kept_feeds_stay_their_rows(self, depth, device_put, placed,
+                                        monkeypatch):
+        """The consumer KEEPS every feed it took until the end of the
+        pass: each still equals its rows, so no buffer was rewritten
+        before its transfer finished or while the consumer held its
+        memory (aligned: the staged array IS the host array here)."""
+        from paddle_tpu import data_feeder
+
+        if placed != "numpy":
+            monkeypatch.setattr(
+                data_feeder, "_allocate",
+                _placed({"aligned": 0, "off_by_16": 16}[placed]))
+        data = _distinct_batches()
+        feeds = prefetch_feeder(lambda: iter(data), _xy_feeder(),
+                                fluid.CPUPlace(), depth=depth,
+                                device_put=device_put)()
+        kept = []
+        for feed in feeds:
+            kept.append(feed)
+            time.sleep(0.01)  # the worker runs ahead under the "step"
+        assert len(kept) == len(data)
+        for batch, feed in zip(data, kept):
+            assert isinstance(feed["x"], np.ndarray) != device_put
+            np.testing.assert_array_equal(
+                np.asarray(feed["x"]), np.asarray([r[0] for r in batch]))
+            np.testing.assert_array_equal(
+                np.asarray(feed["y"]), np.asarray([r[1] for r in batch]))
+        assert not _workers()
+
+    @pytest.mark.parametrize("depth", [1, 2])
+    def test_pack_spans_say_bytes_and_reused(self, depth, monkeypatch):
+        """`reused` is 0 on the first batch, 1 on every batch once the
+        buffers exist, 0 again on the short last batch; the form that
+        blocks needs ONE buffer a feed name whatever the depth, so a
+        pass allocates twice a name: first batch and short batch."""
+        from paddle_tpu import data_feeder
+
+        made = []
+        monkeypatch.setattr(data_feeder, "_allocate", _placed(16, made))
+        data = _distinct_batches()
+        feeds = prefetch_feeder(lambda: iter(data), _xy_feeder(),
+                                fluid.CPUPlace(), depth=depth)
+        attrs = _pack_spans(lambda: [time.sleep(0.005) for _ in feeds()])
+        assert [a["reused"] for a in attrs] == [0] + [1] * 7 + [0]
+        full, short = 8 * 16 * 4 + 8 * 4, 3 * 16 * 4 + 3 * 4
+        assert [a["bytes"] for a in attrs] == [full] * 8 + [short]
+        assert made == [(8, 16), (8, 1), (3, 16), (3, 1)], made
+
+    def test_buffer_the_consumer_holds_is_let_go(self, monkeypatch):
+        """On a cpu device an aligned host array is staged without a
+        copy: the consumer's array IS that memory, so the iterator lets
+        it go and the next batch gets a new one (`reused` stays 0)."""
+        from paddle_tpu import data_feeder
+
+        made = []
+        monkeypatch.setattr(data_feeder, "_allocate", _placed(0, made))
+        data = _distinct_batches(short=8)
+        feeds = prefetch_feeder(lambda: iter(data), _xy_feeder(),
+                                fluid.CPUPlace())
+        attrs = _pack_spans(lambda: list(feeds()))
+        assert [a["reused"] for a in attrs] == [0] * 9
+        assert made.count((8, 16)) == 9
+
+    def test_host_feeds_are_never_reused(self, monkeypatch):
+        """`device_put=False`: the consumer holds the host arrays."""
+        from paddle_tpu import data_feeder
+
+        made = []
+        monkeypatch.setattr(data_feeder, "_allocate", _placed(16, made))
+        data = _distinct_batches(short=8)
+        feeds = prefetch_feeder(lambda: iter(data), _xy_feeder(),
+                                device_put=False)
+        attrs = _pack_spans(lambda: list(feeds()))
+        assert [a["reused"] for a in attrs] == [0] * 9
+        assert [a["bytes"] for a in attrs] == [8 * 16 * 4 + 8 * 4] * 9
+        assert made.count((8, 16)) == 9
+
+    def test_a_feeders_own_feed_gets_no_destination(self):
+        """A subclass that overrides `feed(batch)` keeps its signature:
+        the pipeline passes `out=` to `DataFeeder.feed` alone."""
+        calls = []
+
+        class Own(DataFeeder):
+            def feed(self, batch):
+                calls.append(len(batch))
+                return super().feed(batch)
+
+        inner = _xy_feeder()
+        data = _distinct_batches()
+        feeds = prefetch_feeder(lambda: iter(data),
+                                Own(inner.feed_list, fluid.CPUPlace()),
+                                fluid.CPUPlace())
+        attrs = _pack_spans(lambda: list(feeds()))
+        assert calls == [8] * 8 + [3]
+        assert [a["reused"] for a in attrs] == [0] * 9
+
+
+# ---------------------------------------------------------------------------
 # host-bound overlap microbench (tier-1-safe: deterministic sleep-based
 # host work; the speedup floor is half the ~2x the construction implies)
 # ---------------------------------------------------------------------------
