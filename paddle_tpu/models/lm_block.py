@@ -44,6 +44,23 @@ architecture is a second description and not a second decoder.
                under `norm_topk_prob` (p_i / sum of the chosen p_j =
                exp(l_i) / sum of the chosen exp(l_j)).
 
+  looped       the fifth (ByteDance Ouro, arXiv:2510.25741, `model_type:
+  dense-like   ouro`), fields again: ONE stack of layers run `passes`
+               times a token over the same weights, every (pass,
+               layer) pair with K/V of its own; a dense SwiGLU FFN
+               (`ffn: "swiglu"`, `swiglu` below: what Granite's shared
+               expert already computes); an RMSNorm on each
+               sub-block's OUTPUT before it joins the residual stream
+               as well as on its input (`post_norm`: four scales a
+               layer); the ONE final norm applied after EVERY pass,
+               its output what the next pass starts from; and an exit
+               gate, one linear map to a scalar and a sigmoid, read
+               after each pass (`exit_gate`).  With
+               `early_exit_threshold` 1 the gate decides nothing (all
+               passes run for every token); a threshold under 1 is
+               refused by name: lanes of one tick would run different
+               numbers of passes.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -83,14 +100,15 @@ class BlockSpec:
     """One decoder block.  Valid today: `OPT`, what `olmoe(...)`
     returns (any expert count, top-k, theta, eps), and OLMoE's block
     with `qk_norm` off and the attention geometry below, and that
-    block with Mamba-2 layers and the fields under them (module
-    docstring).  `layer_types` and `rope_parameters` may be given as
+    block with Mamba-2 layers and the fields under them, and the
+    dense SwiGLU block on plain multi-head attention with the looped
+    stack's fields (module docstring).  `layer_types` and `rope_parameters` may be given as
     the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
     name: str
     norm: str                       # "layer_norm" | "rms_norm"
     positions: str                  # "learned" | "rope" | "none"
-    ffn: str                        # "relu" | "moe_swiglu"
+    ffn: str                        # "relu" | "moe_swiglu" | "swiglu"
     bias: bool                      # on every projection and the head
     qk_norm: bool = False           # a norm on all of Q and of K
     norm_eps: float = 1e-5
@@ -121,6 +139,12 @@ class BlockSpec:
     ssm_d_head: int = 0             # a head's size P (H * P columns)
     ssm_d_state: int = 0            # the state's size N
     ssm_conv: int = 0               # the causal convolution's width
+    # -- a LOOPED stack: the layers run `passes` times a token over the
+    #    same weights, the final norm after every pass
+    passes: int = 1
+    post_norm: bool = False         # a norm on each sub-block's OUTPUT
+    exit_gate: bool = False         # sigmoid(w . x_t + b) after a pass
+    early_exit_threshold: float = 1.0   # 1: the gate decides nothing
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters"):
@@ -130,6 +154,16 @@ class BlockSpec:
             raise ValueError(f"layer_types: unknown kind(s) {sorted(bad)}")
         if SLIDING in self.layer_types and self.window < 1:
             raise ValueError(f"{SLIDING} layers need window >= 1")
+        if self.passes < 1:
+            raise ValueError(f"passes {self.passes}: at least 1")
+        if self.early_exit_threshold < 1:
+            raise NotImplementedError(
+                f"block {self.name!r}: early_exit_threshold "
+                f"{self.early_exit_threshold} < 1 is adaptive exit: "
+                "lanes of one tick would run different numbers of "
+                "passes, and the scheduler's tick gives every lane the "
+                "same work; only a threshold of 1 (every pass runs, "
+                "the gate is reported and decides nothing) is built")
         if not 0 <= self.experts_first <= (
                 self.n_experts - self.experts_held):
             raise ValueError(
@@ -204,13 +238,28 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
     `ssm_out` [H*P, d]; no bias on a projection.  The expert matrices
     are [experts HELD, ...]; the router keeps its published width."""
-    if (spec.norm, spec.ffn, spec.bias) != ("rms_norm", "moe_swiglu",
-                                            False):
+    dense = spec.ffn == "swiglu"
+    if (spec.norm, spec.bias) != ("rms_norm", False) or spec.ffn not in (
+            "moe_swiglu", "swiglu"):
         raise NotImplementedError(
-            f"block {spec.name!r}: only the OLMoE combination is laid "
-            "out from its description; OPT's names come from the "
-            "training Program")
+            f"block {spec.name!r}: only the OLMoE combination (RMSNorm, "
+            "no bias, a SwiGLU FFN: experts, or dense) is laid out from "
+            "its description; OPT's names come from the training "
+            "Program")
     kinds = [spec.kind_of(l) for l in range(n_layers)]
+    if dense and (set(kinds) != {FULL} or spec.n_experts or spec.qk_norm
+                  or spec.shared_d_inner or spec.tied_head
+                  or spec.n_kv_heads not in (0, n_heads)):
+        raise NotImplementedError(
+            f"block {spec.name!r}: a dense SwiGLU FFN is built on plain "
+            "multi-head full attention under RoPE with an untied head: "
+            "no experts, shared expert, QK-norm, grouped K/V heads or "
+            "other layer kinds beside it")
+    if not dense and (spec.passes > 1 or spec.post_norm
+                      or spec.exit_gate):
+        raise NotImplementedError(
+            f"block {spec.name!r}: passes, post_norm and exit_gate are "
+            "built for the dense SwiGLU block alone (ffn 'swiglu')")
     mamba = MAMBA in kinds
     if spec.positions != ("none" if mamba else "rope"):
         raise NotImplementedError(
@@ -271,11 +320,21 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "k": add(p + "k_proj.w_0", d, dkv),
                    "v": add(p + "v_proj.w_0", d, dkv),
                    "o": add(p + "o_proj.w_0", dq, d)}
-        lay.update({"norm2": add(p + "ffn_norm.scale_0", d),
-                    "router": add(p + "router.w_0", d, e),
-                    "gate": add(p + "experts_gate.w_0", held, d, f),
-                    "up": add(p + "experts_up.w_0", held, d, f),
-                    "down": add(p + "experts_down.w_0", held, f, d)})
+        lay["norm2"] = add(p + "ffn_norm.scale_0", d)
+        if dense:
+            lay.update({"gate": add(p + "ffn_gate.w_0", d, f),
+                        "up": add(p + "ffn_up.w_0", d, f),
+                        "down": add(p + "ffn_down.w_0", f, d)})
+        else:
+            lay.update({"router": add(p + "router.w_0", d, e),
+                        "gate": add(p + "experts_gate.w_0", held, d, f),
+                        "up": add(p + "experts_up.w_0", held, d, f),
+                        "down": add(p + "experts_down.w_0", held, f, d)})
+        if spec.post_norm:
+            # g2 and g4: on what attention and the FFN give, before the
+            # residual stream takes it
+            lay["post1"] = add(p + "attn_post_norm.scale_0", d)
+            lay["post2"] = add(p + "ffn_post_norm.scale_0", d)
         if fs:
             lay.update({"shared_gate": add(p + "shared_gate.w_0", d, fs),
                         "shared_up": add(p + "shared_up.w_0", d, fs),
@@ -288,7 +347,10 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     layout = types.SimpleNamespace(
         tok=tok[0], pos=None, layers=layers,
         final=add("final_norm.scale_0", d),
-        head=tok if spec.tied_head else add("lm_head.w_0", d, vocab_size))
+        head=tok if spec.tied_head else add("lm_head.w_0", d, vocab_size),
+        # w_exit [d, 1] and b_exit [1]: lambda_t = sigmoid(x_t . w + b)
+        exit=((add("exit_gate.w_0", d, 1)[0], add("exit_gate.b_0", 1)[0])
+              if spec.exit_gate else None))
     return layout, shapes
 
 

@@ -614,6 +614,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     `decoder.state_layers` and `decoder.state_bytes_per_lane` say what
     a lane holds (0 without Mamba layers).
 
+    A LOOPED stack (`BlockSpec.passes` > 1: the layers run `passes`
+    times a token over the same weights, the final norm after every
+    pass, docs/serving.md "A looped stack") keeps the ONE table pool
+    with `passes * n_layers` planes: pass t of layer l writes and reads
+    plane t * n_layers + l (`decoder.kv_planes`, `decoder.passes`), and
+    `bytes_per_block` counts them all; a block id is common to all
+    planes, so tables, the cache manager and the kernel are untouched.
+    The step holds ONE stack's body under a `lax.scan` over the pass
+    (the plane index is traced into the write and the kernel's `layer`
+    operand): program size and compile time do not grow with `passes`.
+    `step` returns a fourth value, `exit_gate_open` (`step_counters`):
+    (live lane, pass) pairs whose exit gate is over a half; the gate
+    decides nothing.  `step_routing` returns every pass's x_t and
+    gate.  `step_window` and an int8 pool are refused by name.
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -688,6 +703,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         nw = min(spec.window // bs, nb)
     # a layer's index inside the pool of its kind
     pool_index = [kinds[:l].count(k) for l, k in enumerate(kinds)]
+    # A LOOPED stack (`BlockSpec.passes`, or its exit gate): the layers
+    # run `passes` times a token over the same weights, and the pool
+    # has a plane for every (pass, layer) pair: pass t of layer l
+    # writes and reads plane t * layers + l and no other.
+    passes = int(spec.passes)
+    looped = passes > 1 or spec.exit_gate
+    if looped and kv_dtype == "int8":
+        raise NotImplementedError(
+            f"block {spec.name!r}: an int8 pool under a looped stack is "
+            "not built (its per-(plane, block) scales are untested "
+            "there); kv_dtype fp32 or bf16")
 
     _attend, _refused = _paged_attention.select_paged_attention(
         d_model=d_model, n_heads=n_heads, block_size=bs,
@@ -703,7 +729,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
         layout = types.SimpleNamespace(
             tok=tok_emb, pos=pos_tab, final=lns[2 * n_layers],
-            head=fc(6 * n_layers),
+            head=fc(6 * n_layers), exit=None,
             layers=[{"norm1": lns[2 * l], "q": fc(6 * l),
                      "k": fc(6 * l + 1), "v": fc(6 * l + 2),
                      "o": fc(6 * l + 3), "norm2": lns[2 * l + 1],
@@ -829,6 +855,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 kk = lm_block.rope(kk, *rot, n_kv)
         return q, kk, vv
 
+    def _post_join(g, x, y, pair):
+        """x + norm(y): a sub-block's output joins the residual stream
+        through the norm `post_norm` puts on it (`loop_norm`: with the
+        final norm of each pass, the norms a looped block adds)."""
+        with scope("loop_norm"):
+            return _residual(x, _norm(g, y, pair))
+
     def _ffn(g, lay, x, hits):
         """x + FFN(norm(x)); a block with experts appends (its count
         of distinct experts hit, the router's input, the weights and
@@ -838,6 +871,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             if spec.ffn == "relu":
                 return x + _fc(g, jax.nn.relu(_fc(g, h2, lay["w1"])),
                                lay["w2"])
+            if spec.ffn == "swiglu":
+                y = lm_block.swiglu(h2.reshape(-1, d_model), *(
+                    g[lay[n][0]] for n in ("gate", "up", "down")))
+                if not spec.post_norm:
+                    return _residual(x, y.reshape(x.shape))
+        if spec.ffn == "swiglu":
+            return _post_join(g, x, y.reshape(x.shape), lay["post2"])
         h2 = h2.reshape(-1, d_model)
         w_gate = g[lay["gate"][0]]
         # the kernel's own module decides from the rows of this trace,
@@ -862,9 +902,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("moe_combine"):
             return _residual(x, y.reshape(x.shape))
 
-    def _head(g, x):
+    def _head(g, x, normed=False):
+        """The logits of x; `normed`: x is already through the final
+        norm (a looped stack applies it after every pass)."""
         with scope("head"):
-            h = _norm(g, x, layout.final)
+            h = x if normed else _norm(g, x, layout.final)
             logits = (h @ g[layout.tok].T if spec.tied_head
                       else _fc(g, h, layout.head))
             if spec.logits_scaling != 1.0:
@@ -1084,43 +1126,96 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 tabs[lm_block.SLIDING], positions, active)
         cursor = {kind: (wb, _sees(kind, positions, active))
                   for kind, wb in written.items()}
-        for lay, kind, li in zip(layout.layers, kinds, pool_index):
-            if kind == lm_block.MAMBA:
-                # the lane's state rides where a pool's K does, its
-                # convolution tail where the V does
-                x, pools_k[kind][li], pools_v[kind][li], given = _mixer(
-                    g, lay, x, pools_k[kind][li], pools_v[kind][li],
-                    positions == 0, active)
-                scans.append(given)
+
+        def stack(x, pools_k, pools_v, plane0=None):
+            """The layers once over x, each writing this position's K/V
+            and attending; `plane0`: the first plane of this pass of a
+            looped stack (traced), else a layer's plane is its index in
+            the pool of its kind (a Python int, as ever)."""
+            for lay, kind, li in zip(layout.layers, kinds, pool_index):
+                if kind == lm_block.MAMBA:
+                    # the lane's state rides where a pool's K does, its
+                    # convolution tail where the V does
+                    x, pools_k[kind][li], pools_v[kind][li], given = (
+                        _mixer(g, lay, x, pools_k[kind][li],
+                               pools_v[kind][li], positions == 0, active))
+                    scans.append(given)
+                    x = _ffn(g, lay, x, hits)
+                    continue
+                q, kk, vv = _qkv(g, lay, x, rot[kind])
+                wb, seen = cursor[kind]
+                plane = li if plane0 is None else plane0 + li
+                with scope("kv_write"):
+                    pools_k[kind] = _write(pools_k[kind], plane, wb, wi,
+                                           kk)
+                    pools_v[kind] = _write(pools_v[kind], plane, wb, wi,
+                                           vv)
+                if _attend is not None:
+                    ctx_av = _streamed(q, pools_k[kind], pools_v[kind],
+                                       plane, tabs[kind], seen, kind)
+                else:
+                    ctx_av = _attention(
+                        q[:, None, :], pools_k[kind], pools_v[kind],
+                        plane, tabs[kind], seen[:, None, :], kind)[:, 0]
+                with scope("attn_out"):
+                    y = _fc(g, ctx_av, lay["o"])
+                    if not spec.post_norm:
+                        x = _residual(x, y)
+                if spec.post_norm:
+                    x = _post_join(g, x, y, lay["post1"])
                 x = _ffn(g, lay, x, hits)
-                continue
-            q, kk, vv = _qkv(g, lay, x, rot[kind])
-            wb, seen = cursor[kind]
-            with scope("kv_write"):
-                pools_k[kind] = _write(pools_k[kind], li, wb, wi, kk)
-                pools_v[kind] = _write(pools_v[kind], li, wb, wi, vv)
-            if _attend is not None:
-                ctx_av = _streamed(q, pools_k[kind], pools_v[kind], li,
-                                   tabs[kind], seen, kind)
-            else:
-                ctx_av = _attention(
-                    q[:, None, :], pools_k[kind], pools_v[kind], li,
-                    tabs[kind], seen[:, None, :], kind)[:, 0]
-            with scope("attn_out"):
-                x = _residual(x, _fc(g, ctx_av, lay["o"]))
-            x = _ffn(g, lay, x, hits)
-        return (_head(g, x), _joined(pools_k), _joined(pools_v),
-                hits, scans)                                  # [S, V]
+            return x
+
+        if not looped:
+            x = stack(x, pools_k, pools_v)
+            return (_head(g, x), _joined(pools_k), _joined(pools_v),
+                    hits, scans)                              # [S, V]
+
+        def one_pass(carry, t):
+            """Pass t of the stack: ONE body in the program however
+            many passes run it.  -> x_t (through the final norm: what
+            the next pass starts from) and the exit gate lambda_t."""
+            x, pk, pv = carry
+            pools_k, pools_v = {lm_block.FULL: pk}, {lm_block.FULL: pv}
+            # the loop's body starts a name stack of its own
+            # (`.../while/body/...`): the parts keep the names the
+            # scope tables join on, `paged_decoder/<part>`
+            with scope("paged_decoder"):
+                x = stack(x, pools_k, pools_v, plane0=t * n_full)
+                with scope("loop_norm"):
+                    x = _norm(g, x, layout.final)
+                lam = jnp.zeros(s_n, jnp.float32)
+                if layout.exit is not None:
+                    with scope("exit_gate"):
+                        w_exit, b_exit = (g[n].astype(jnp.float32)
+                                          for n in layout.exit)
+                        lam = jax.nn.sigmoid((x @ w_exit)[:, 0]
+                                             + b_exit[0])
+            return ((x, pools_k[lm_block.FULL], pools_v[lm_block.FULL]),
+                    (x, lam))
+
+        (x, pool_k, pool_v), (x_t, lam_t) = jax.lax.scan(
+            one_pass, (x, pools_k[lm_block.FULL], pools_v[lm_block.FULL]),
+            jnp.arange(passes, dtype=jnp.int32))
+        return (_head(g, x, normed=True), pool_k, pool_v, hits,
+                {"passes": x_t, "gates": lam_t})
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def step(g, pool_k, pool_v, tables, positions, tokens, seeds, temps,
              active):
         with scope("paged_decoder"):
-            logits, pool_k, pool_v, hits, _ = _step_logits(
+            logits, pool_k, pool_v, hits, loop = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
-            return _with_counts(
-                (_sample(logits, seeds, positions, temps), pool_k,
-                 pool_v), hits)
+            out = (_sample(logits, seeds, positions, temps), pool_k,
+                   pool_v)
+            if spec.exit_gate:
+                # what an adaptive exit would look at: (lane, pass)
+                # pairs whose gate is over a half, of the live lanes
+                with scope("exit_gate"):
+                    return out + (jnp.sum(
+                        (loop["gates"] > 0.5) & active[None, :],
+                        dtype=jnp.int32),)
+            return _with_counts(out, hits)
 
     @jax.jit
     def step_logits(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -1138,10 +1233,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         k], "experts": int32 [n_layers, S, k]}, and for a block with
         Mamba layers "ssm_inputs": float32 [Mamba layers, S, H*P + 2N +
         H], what each layer's recurrence was given at this position
-        (`lm_block.mamba2_step`)."""
+        (`lm_block.mamba2_step`).  Its twin for a LOOPED stack, which
+        routes nothing: {"passes": float32 [passes, S, D], x_t of every
+        pass (through the final norm), "gates": float32 [passes, S],
+        the exit gate lambda_t}, so that a pass that read another
+        pass's plane shows at the pass where it happened."""
         with scope("paged_decoder"):
             logits, _, _, hits, scans = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
+            if looped:
+                return logits, scans
             inputs, weights, experts = (
                 jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
             out = {"inputs": inputs, "weights": weights,
@@ -1167,6 +1268,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 "`step` computes (a chunked scan is not built); a "
                 "block with Mamba layers runs `step` alone (no draft "
                 "model, no chunked prefill)")
+        if looped:
+            raise NotImplementedError(
+                f"block {spec.name!r}: step_window is not built for a "
+                "looped stack (a window of positions through every "
+                "pass, each pass's K/V in its own planes); such a "
+                "block runs `step` alone (no draft model, no chunked "
+                "prefill)")
         if ringed:
             raise NotImplementedError(
                 f"block {spec.name!r}: step_window writes a window of "
@@ -1233,9 +1341,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # int8 payload + one f32 scale per (layer, block)
         elem_bytes = 1.0 + 4.0 / (bs * d_kv)
     n_win = kinds.count(lm_block.SLIDING)
-    # K+V of one block over the layers that hold it: a table block
-    # over the full layers, a ring block over the sliding ones
-    bytes_per_block = int(2 * n_full * bs * d_kv * elem_bytes)
+    # K+V of one block over the planes that hold it: a table block
+    # over the full layers (of every pass of a looped stack), a ring
+    # block over the sliding ones
+    planes = passes * n_full
+    bytes_per_block = int(2 * planes * bs * d_kv * elem_bytes)
     window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
     # a lane's recurrent state over the Mamba layers: the SSM state
     # and the convolution tail, both float32
@@ -1283,7 +1393,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
         def z():
             if not ringed:
-                return zeros(n_layers, num_blocks)
+                return zeros(planes, num_blocks)
             if window_blocks is None:
                 raise ValueError(
                     f"block {spec.name!r} has sliding layers: "
@@ -1313,6 +1423,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # `profiler.register_jitted`, beside the calls the compiler renames.
     parts = {"q": "qkv", "k": "qkv", "v": "qkv", "o": "attn_out",
              "w1": "mlp", "w2": "mlp"}
+    if spec.ffn == "swiglu":
+        parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
                   for key, part in parts.items() if key in lay]
     compiler_scopes = {
@@ -1324,10 +1436,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     decoder = types.SimpleNamespace(
         step=step, step_window=step_window, step_logits=step_logits,
-        step_routing=(step_routing if spec.ffn == "moe_swiglu" else None),
+        step_routing=(step_routing if spec.ffn == "moe_swiglu" or looped
+                      else None),
         init_pool=init_pool, slot_rings=slot_rings, platform=platform,
-        step_counters=(("moe_experts_hit",)
-                       if spec.ffn == "moe_swiglu" else ()),
+        step_counters=(("moe_experts_hit",) if spec.ffn == "moe_swiglu"
+                       else ("exit_gate_open",) if spec.exit_gate
+                       else ()),
+        # a looped stack: the passes a token takes over the one stack
+        # (1: a plain block) and the planes its table pool has
+        passes=passes, kv_planes=planes,
         compiler_scopes=compiler_scopes,
         state_names=sorted(shapes), state_shapes=shapes, block_size=bs,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
@@ -1337,9 +1454,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # (0: every layer is full), the window, and a ring block's bytes
         window_blocks_per_seq=nw, window=spec.window if ringed else 0,
         window_bytes_per_block=window_bytes_per_block,
-        # attention layers by where their K/V live: on the table, on a
-        # slot's ring
-        table_layers=n_full, ring_layers=n_win,
+        # attention layers by where their K/V live: on the table (a
+        # plane for every pass of a looped stack), on a slot's ring
+        table_layers=planes, ring_layers=n_win,
         # the Mamba layers' recurrent state: how many layers keep one
         # (0: none) and the float32 bytes a lane holds over them
         state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,

@@ -214,6 +214,13 @@ def cuda_profiler(output_file=None, output_mode=None, config=None):
 # ---------------------------------------------------------------------------
 
 
+# The scope a LOOP instruction gets in the tables: a device trace times
+# a `while` as one event AND every instruction of its body as events of
+# their own, so the loop's seconds are its body's over again and
+# `scope_seconds` leaves them out.
+LOOP_SCOPE = "<loop>"
+
+
 def _scope_tables(hlo_text: str):
     """(HLO instruction name -> source op_name metadata, names that
     inherited theirs).  The metadata carries the per-op
@@ -222,18 +229,26 @@ def _scope_tables(hlo_text: str):
     `reshape(fusion.N)` of a gathered tensor, async copies and slices);
     it takes the scope of its first operand that has one, its
     producer's, and is listed in the second value: a choice of producer
-    over consumer, not compiler metadata."""
+    over consumer, not compiler metadata.  Only where no producer has
+    one (a weight's slices inside a loop's body) does it take its first
+    consumer's."""
     import re
 
     out = {}
     orphans = []
+    users = {}              # an instruction's first consumer
     for m in re.finditer(r"%?([\w.\-]+) = ([^\n]*)", hlo_text):
         name, rest = m.groups()
         meta = re.search(r"metadata={[^}]*op_name=\"([^\"]+)\"", rest)
-        if meta:
+        operands = re.findall(r"%([\w.\-]+)", rest)
+        for o in operands:
+            users.setdefault(o, name)
+        if " while(" in rest and " body=" in rest:
+            out[name] = LOOP_SCOPE
+        elif meta:
             out[name] = meta.group(1)
         else:
-            orphans.append((name, re.findall(r"%([\w.\-]+)", rest)))
+            orphans.append((name, operands))
     inherited = set()
     for _ in range(3):      # a short chain: copy-done(copy-start(fusion))
         for name, operands in orphans:
@@ -242,6 +257,16 @@ def _scope_tables(hlo_text: str):
                 if scope is not None:
                     out[name] = scope
                     inherited.add(name)
+    # What no producer names takes its first CONSUMER's scope.  Inside
+    # a loop's body the weights are elements of the body's parameter
+    # tuple, which carries no metadata, so the slices the compiler
+    # brings a weight in with (`slice-done(slice-start(gte))`) have no
+    # producer to ask; the matmul that waits for them is their consumer.
+    for _ in range(3):      # slice-start <- slice-done <- the matmul
+        for name, _ in orphans:
+            if name not in out and users.get(name) in out:
+                out[name] = out[users[name]]
+                inherited.add(name)
     return out, inherited
 
 
@@ -390,7 +415,9 @@ def scope_seconds(op_seconds: Dict[str, float], label: str,
     Where several executables share the label, the table that covers
     the most of these seconds is taken.  Instructions the table does
     not name (other executables, transfers) go under ""; the values
-    add up to `op_seconds`' total.  With `inherited_only`, only the
+    add up to `op_seconds`' total, less the seconds of LOOP
+    instructions (`LOOP_SCOPE`: their bodies' instructions are in
+    `op_seconds` themselves).  With `inherited_only`, only the
     seconds of instructions that carry no metadata of their own and
     took their producer's scope (`_scope_tables`): the part of each
     scope's seconds that is a heuristic, not the compiler's word."""
@@ -405,7 +432,8 @@ def scope_seconds(op_seconds: Dict[str, float], label: str,
         if inherited_only and op not in inherited:
             continue
         scope = best.get(op, "")
-        out[scope] = out.get(scope, 0.0) + t
+        if scope != LOOP_SCOPE:
+            out[scope] = out.get(scope, 0.0) + t
     return out
 
 

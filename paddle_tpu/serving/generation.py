@@ -424,6 +424,15 @@ class GenerationServer:
     and the tick in flight behind them have nothing to reset.  What
     cannot be right with it is refused here, by name:
     `prefix_cache=True` and a draft model (`step_window`).
+
+    A decoder with a LOOPED stack (`decoder.passes` > 1) keeps the one
+    table pool, with a plane for every (pass, layer) pair
+    (docs/serving.md "A looped stack"): a block id is common to all
+    planes, so admission, eviction and `prefix_cache=True` are the
+    plain decoder's (a cached block holds every pass's K/V).  A block
+    is `passes` times as large, so the POOL and not the slot count is
+    what `can_admit` runs out of first (`kv_wait` on the tick span).
+    A draft model is refused by name.
     """
 
     def __init__(self, decoder, states, *, slots: int = 8,
@@ -495,6 +504,14 @@ class GenerationServer:
                 "layers find the prompt's K/V in the shared blocks "
                 "but a lane has no recurrent state for it (no "
                 "snapshot is kept); pass prefix_cache=False")
+        if int(getattr(decoder, "passes", 1)) > 1 and (
+                draft_decoder is not None):
+            raise ValueError(
+                "a decoder with a looped stack takes no draft model: "
+                "speculative verification runs a window of positions "
+                "through step_window, which is not built for a stack "
+                "run several passes a token (build_lm_paged_decoder's "
+                "step_window refuses it too)")
         if (draft_decoder is None) != (draft_states is None):
             raise ValueError(
                 "speculative decoding needs BOTH draft_decoder and "
@@ -611,6 +628,11 @@ class GenerationServer:
             "paged_attention_decode") == "pallas"
         # Mamba layers: a lane's recurrent state rides in the pools
         self._stateful = bool(stateful)
+        # a looped stack: the passes a tick's step runs (1: a plain one)
+        self._passes = int(getattr(decoder, "passes", 1))
+        # the last admission left the queue's head waiting for BLOCKS
+        # with a slot free (on the next tick's span as `kv_wait`)
+        self._kv_wait = False
         self._queue: deque = deque()
         self._max_queue = int(max_queue)
         self._lock = threading.Condition()
@@ -978,6 +1000,7 @@ class GenerationServer:
         and slots last.  Head-of-line order is deliberate: skipping a
         big request to admit later small ones would starve it."""
         admitted = []
+        self._kv_wait = False
         n_active = sum(1 for s in self._active if s is not None)
         if self._static and n_active:
             return admitted   # drain-then-refill baseline
@@ -991,6 +1014,8 @@ class GenerationServer:
             seq = self._queue[0]
             if not self._cache.can_admit(seq.positions_needed,
                                          prompt_keys=seq.prompt_keys):
+                # a slot is free and the pool's blocks refuse the head
+                self._kv_wait = True
                 break
             self._queue.popleft()
             try:
@@ -1248,10 +1273,19 @@ class GenerationServer:
         its slots) and `state_resets` (those at position 0, which the
         step starts from a zero state).  With experts `moe_kernel`:
         1 where the step's expert layer is the Pallas grouped matmul
-        (`decoder.expert_kernel`), 0 where `ragged_dot`."""
+        (`decoder.expert_kernel`), 0 where `ragged_dot`.  `kv_wait`: 1
+        where the admission before this tick left the queue's head
+        waiting with a slot free because `can_admit` refused it for
+        blocks.  With a looped stack `loop_passes` (the passes the
+        step runs) and `kv_planes` (passes x layers: the planes that
+        `kv_pages_read` and `kv_pages_table` are counted over)."""
         attrs = {"prefill": prefill,
                  "kv_used": self._cache.used_blocks,
-                 "kv_total": self._cache.num_blocks}
+                 "kv_total": self._cache.num_blocks,
+                 "kv_wait": int(self._kv_wait)}
+        if self._passes > 1:
+            attrs["loop_passes"] = self._passes
+            attrs["kv_planes"] = self._kv_layers[0]
         rows = cur.astype(np.int64) + 1      # K/V rows a slot attends
         read = self._kv_pages_table
         if self._kv_streamed and not window:

@@ -973,6 +973,59 @@ def test_untraced_ticks_make_no_span_and_count_nothing(monkeypatch):
     assert made == [] and counted == []
 
 
+@pytest.mark.parametrize("kind", ["table", "loop"])
+def test_a_pool_smaller_than_slots_x_context_admits_by_blocks(kind):
+    """Three slots and a pool of 7 blocks where three whole contexts
+    would be 18: `can_admit` and not the slot count says what runs.
+    The queue's head waits with a slot free (`kv_wait` on the next
+    tick's span), nothing is dropped or overtaken, every request
+    completes with the tokens it gets alone, and `stats()` adds up."""
+    dec, states = _block_decoder(kind)
+    place = fluid.CPUPlace()
+    # (prompt, new tokens): 4, 3, 5, 2, 3 and 1 blocks of 4 positions
+    requests = [([3, 1, 4, 1, 5], 9), ([2, 7], 8), ([6, 2, 8, 3, 1], 13),
+                ([1], 7), ([4, 4, 9], 7), ([5], 2)]
+
+    def ask(server, i):
+        return server.submit(requests[i][0], requests[i][1],
+                             temperature=1.0, seed=70 + i)
+
+    solo = GenerationServer(dec, states, slots=1, kv_blocks=6, place=place,
+                            prefix_cache=False)
+    try:
+        want = [ask(solo, i).result(timeout=120)
+                for i in range(len(requests))]
+    finally:
+        solo.close()
+    srv = GenerationServer(dec, states, slots=3, kv_blocks=7, place=place,
+                           prefix_cache=False)
+    admitted = []
+    admit = srv._admit_locked
+    srv._admit_locked = lambda: (lambda got: (admitted.extend(
+        s.seed for s in got), got)[1])(admit())
+    try:
+        with _tick_spans() as ticks:
+            streams = [ask(srv, i) for i in range(len(requests))]
+            assert [s.result(timeout=120) for s in streams] == want
+        stats = srv.stats()
+    finally:
+        srv.close()
+    assert admitted == [70 + i for i in range(len(requests))]   # FIFO
+    waits = [a for a in ticks if a["kv_wait"]]
+    # the head waited for BLOCKS while a slot stood free
+    assert waits and all(a["active"] < 3 for a in waits)
+    assert all(a["kv_used"] <= a["kv_total"] == 7 for a in ticks)
+    assert max(a["kv_used"] for a in ticks) >= 6
+    assert stats["shed"] == 0 and stats["kv_blocks_total"] == 7
+    assert stats["kv_blocks_free"] == 7                  # all given back
+    assert stats["kv_bytes_resident"] == 0
+    assert stats["generated_tokens"] == sum(len(w) for w in want)
+    assert stats["requests"] == len(requests)
+    if kind == "loop":
+        assert dec.bytes_per_block == 2 * 6 * 4 * 32 * 4  # 6 planes
+        assert all(a["kv_planes"] == 6 for a in ticks)
+
+
 def _old_tick_attrs(srv, seqs, window=False):
     """`GenerationServer._tick_attrs` as it was before PR 36, a walk of
     its own over `seqs` for each count: the oracle."""
@@ -1006,12 +1059,24 @@ def _old_tick_attrs(srv, seqs, window=False):
 
 def _block_decoder(kind):
     """A decoder of each kind of state at toy widths: the table alone,
-    a ring beside it (sliding layers), a recurrent state a lane."""
+    a ring beside it (sliding layers), a recurrent state a lane, a
+    table with a plane for every pass of a looped stack."""
     from paddle_tpu.models import lm_block
     from paddle_tpu.models.transformer import build_lm_paged_decoder
 
     if kind == "table":
         return _decoder(max_blocks=6)
+    if kind == "loop":
+        spec = lm_block.BlockSpec(
+            name="loop", norm="rms_norm", positions="rope", ffn="swiglu",
+            bias=False, passes=3, post_norm=True, exit_gate=True)
+        _, dec = build_lm_paged_decoder(
+            V, 4, 6, d_model=32, n_heads=4, n_layers=2, d_inner=16,
+            block=spec, platform="cpu")
+        rng = np.random.RandomState(0)
+        return dec, {
+            n: (0.1 * rng.randn(*shape) + (".scale_" in n)).astype(
+                np.float32) for n, shape in dec.state_shapes.items()}
     common = dict(norm="rms_norm", ffn="moe_swiglu", bias=False,
                   n_experts=4, experts_per_token=2, norm_topk_prob=True,
                   n_kv_heads=2)
@@ -1036,7 +1101,8 @@ def _block_decoder(kind):
 
 
 @pytest.mark.parametrize("kind,streamed", [
-    ("table", False), ("table", True), ("ring", True), ("state", True)])
+    ("table", False), ("table", True), ("ring", True), ("state", True),
+    ("loop", True)])
 def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     """The counts on `serving.decode_tick` come out of the one loop
     `build` runs and the `positions` it fills: tick for tick they are
@@ -1061,11 +1127,19 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     finally:
         srv.close()
     assert len(ticks) == len(want) >= 30
-    extra = {"ahead"} | set(getattr(dec, "step_counters", ()))
+    # what PR 38 added beside the old walks' counts
+    loop = {"loop_passes": 3, "kv_planes": 6} if kind == "loop" else {}
+    extra = ({"ahead", "kv_wait"} | set(loop)
+             | set(getattr(dec, "step_counters", ())))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old
         assert all(type(v) is int for v in got.values())
+        assert got["kv_wait"] == 0
+        assert {k: got[k] for k in loop} == loop
     assert any(a["prefill"] for a in want)
+    if kind == "loop":
+        # the pages of all 6 planes: 3 slots x 6 blocks x 6 planes
+        assert all(a["kv_pages_table"] == 3 * 6 * 6 for a in want)
     if kind == "ring":
         assert any(a["past_window"] for a in want)
         assert any(a["kv_pages_read"] < a["kv_pages_table"] for a in want)

@@ -423,6 +423,9 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     # d4096, 32 query heads of 128 over 8 K/V heads (rows of 1024) on
     # its one attention layer in ten, context 1024
     ("granite-4.0-h-small-serve-chat64", "tpu", False, None),
+    # d2048, 16 heads of 128 (OLMoE's page, 64 KB) on every one of a
+    # looped stack's 4 x 48 planes: the layer is the kernel's operand
+    ("ouro-2.6b-serve-chat12", "tpu", False, None),
     # no part of a K/V geometry is refused: the query comes
     # block-diagonal by K/V head, whatever the heads
     (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=256), "tpu", False, None),
@@ -444,7 +447,8 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     # ...unless a test asks for the Pallas interpreter
     (dict(CHIP_SMOKE, kv_dtype="fp32"), "cpu", True, None),
 ], ids=["opt-1.3b-tpu", "olmoe-1b-7b-1chip-tpu", "mellum2-1chip-tpu",
-        "granite-4.0-h-small-1chip-tpu", "grouped-kv-tpu", "wide-heads-tpu",
+        "granite-4.0-h-small-1chip-tpu", "ouro-2.6b-tpu", "grouped-kv-tpu",
+        "wide-heads-tpu",
         "ring-tpu", "row-off-the-lanes-tpu", "half-tile-pages-tpu",
         "chip_smoke-fp32-tpu", "chip_smoke-int8-tpu",
         "fp32-pages-of-8-tpu", "chip_smoke-cpu",
