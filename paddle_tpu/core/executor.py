@@ -253,6 +253,121 @@ class _MissingState(KeyError):
     pass
 
 
+def _keeps_identity(v) -> bool:
+    """True for a value that cannot change while the scope holds the same
+    object: a jax array, or a LoDTensor over one.  A NumPy array or a
+    Python scalar may be written in place by its owner (and a scalar
+    must stay weakly typed), so those are committed at every step."""
+    if isinstance(v, LoDTensor):
+        v = v.data
+    return isinstance(v, jax.Array)
+
+
+def _rests_on(v, target) -> bool:
+    """True where `_commit(v, target)` would hand back `v`'s own buffer:
+    `v` (which `_keeps_identity`) is committed, and where `target` (a
+    device or a sharding) says."""
+    a = v.data if isinstance(v, LoDTensor) else v
+    if not a.committed:
+        return False
+    if isinstance(target, jax.sharding.Sharding):
+        return a.sharding.is_equivalent_to(target, a.ndim)
+    return a.devices() == {target}
+
+
+class _StepRecord:
+    """What `_run_compiled` learnt about one compiled step the last time
+    it ran it: the analysis of the Program (which never changes under
+    the same `_version`), and for every persistable state the object
+    last `seen` in the scope, its committed `twin` and its `_aval_key`.
+    A state whose scope object IS the one seen costs a dictionary read
+    at the next step; anything else (replaced by `scope.set_var`, a
+    NumPy value, a first sight) goes through `_commit` and `_aval_key`
+    as if there were no record, for that state alone."""
+
+    __slots__ = ("device", "flags", "state_in_names", "state_out_names",
+                 "ro_names", "rw_names", "donatable", "repl", "target",
+                 "seen", "twin", "keys", "feed_keys", "don_names",
+                 "cache_key", "outs")
+
+    def __init__(self, device, flags, state_in_names, state_out_names,
+                 repl):
+        self.device, self.flags = device, flags
+        self.state_in_names = state_in_names
+        self.state_out_names = state_out_names
+        written = set(state_out_names)
+        self.ro_names = [n for n in state_in_names if n not in written]
+        self.rw_names = [n for n in state_in_names if n in written]
+        # the feeds whose buffers the step may take, where the executor
+        # itself made them (`fresh`): `_new_step_record` fills it in
+        self.donatable = frozenset()
+        self.repl = repl
+        self.target = repl if repl is not None else device
+        self.seen: Dict = {}
+        self.twin: Dict = {}
+        self.keys: Dict = {}
+        self.feed_keys = self.don_names = None
+        # the executable-cache key of the last call while every part of
+        # it still holds; None once a state's key moved
+        self.cache_key = None
+        # cache key -> {written state: its `_aval_key`}: an executable's
+        # output shapes are fixed at compile time, so they are read from
+        # its first outputs only
+        self.outs: Dict = {}
+
+    def gather(self, names, scope):
+        """({name: committed value} for `names` from the scope, how many
+        of them went through `_commit`)."""
+        seen, twin, keys = self.seen, self.twin, self.keys
+        find, vals, recommitted = scope.find_var, {}, 0
+        for n in names:
+            try:
+                v = find(n)
+            except KeyError:
+                v = None
+            if v is None:
+                raise _MissingState(n)
+            if v is seen.get(n):
+                vals[n] = twin[n]
+                continue
+            recommitted += 1
+            c = vals[n] = _commit(v, self.target)
+            k = _aval_key(c)
+            if keys.get(n) != k:
+                keys[n], self.cache_key = k, None
+            if _keeps_identity(v):
+                seen[n], twin[n] = v, c
+            else:
+                seen.pop(n, None)
+                twin.pop(n, None)
+        return vals, recommitted
+
+    def written(self, cache_key, state_out):
+        """After the call: what the step wrote is what the scope now
+        holds, and the donated read-write inputs are gone.  A written
+        array that rests where `_commit` would put it (the outputs of a
+        jit over committed arguments do; a startup program's, which
+        has none, are uncommitted) is its own twin."""
+        out_keys = self.outs.get(cache_key)
+        if out_keys is None:
+            out_keys = self.outs[cache_key] = {
+                n: _aval_key(v) for n, v in state_out.items()
+                if _keeps_identity(v) and _rests_on(v, self.target)}
+        seen, twin, keys = self.seen, self.twin, self.keys
+        for n in self.rw_names:
+            k = out_keys.get(n)
+            if k is None:
+                seen.pop(n, None)
+                twin.pop(n, None)
+                continue
+            seen[n] = twin[n] = state_out[n]
+            old = keys.get(n)
+            if k is not old:
+                if k != old:
+                    self.cache_key = None
+                keys[n] = k
+
+
 # ---------------------------------------------------------------------------
 # process-wide XLA compile accounting (jax monitoring events)
 # ---------------------------------------------------------------------------
@@ -331,6 +446,11 @@ _M_RECOMPILES = obs_metrics.counter(
     "paddle_tpu_executor_recompiles_after_warmup_total",
     "cache misses for a program that already reached steady state",
     ("exe",), always=True)
+_M_STATE_COMMITS = obs_metrics.counter(
+    "paddle_tpu_executor_state_commits_total",
+    "persistable states a compiled step put through _commit (a step "
+    "whose states are all as the last one left them adds none)",
+    ("exe",), always=True)
 _M_ENTRIES = obs_metrics.gauge(
     "paddle_tpu_executor_cache_entries",
     "live executables in the cache", ("exe",), always=True)
@@ -356,12 +476,18 @@ class Executor:
         self._donation_plans: Dict = {}
         self._free_plans: Dict = {}
         self._memopt_cache: Dict = {}
+        # scope -> program -> {(version, block, feeds, fetches, flags):
+        # _StepRecord}: scope and program held weakly, each innermost
+        # table bounded like the plans'
+        self._step_records: "weakref.WeakKeyDictionary" = \
+            weakref.WeakKeyDictionary()
         self._exe_id = str(next(_EXE_IDS))
         self._m_hits = _M_LOOKUPS.labels(exe=self._exe_id, result="hit")
         self._m_misses = _M_LOOKUPS.labels(exe=self._exe_id,
                                            result="miss")
         self._m_compile_s = _M_COMPILE_S.labels(exe=self._exe_id)
         self._m_recompiles = _M_RECOMPILES.labels(exe=self._exe_id)
+        self._m_state_commits = _M_STATE_COMMITS.labels(exe=self._exe_id)
         self._m_entries = _M_ENTRIES.labels(exe=self._exe_id)
         self._warm_fps: set = set()
         compile_cache_dir()
@@ -370,10 +496,15 @@ class Executor:
         """Dispatch/compile telemetry for this Executor's executable cache:
         `hits`/`misses` (cache lookups across compiled + segmented modes),
         `compile_s` (wall time of first invocations, i.e. trace + XLA
-        compile + first dispatch), `entries` (live executables), and
+        compile + first dispatch), `entries` (live executables),
         `recompiles_after_warmup` — misses for a program that already had
         a steady-state hit, the signature of a shape/flag leak re-tracing
-        the hot path (PADDLE_TPU_LOG_RECOMPILES=1 also warns per event).
+        the hot path (PADDLE_TPU_LOG_RECOMPILES=1 also warns per event) —
+        and `state_commits`, the persistable states compiled steps put
+        through `_commit`: a step that finds every state as the last one
+        left it adds none, so a count that grows with the steps names a
+        loop that replaces states or keeps NumPy values in the scope
+        (docs/performance.md).
 
         A view over this instance's series in the process metrics
         registry (exported with everything else by
@@ -382,6 +513,7 @@ class Executor:
                 "misses": int(self._m_misses.value),
                 "compile_s": self._m_compile_s.value,
                 "recompiles_after_warmup": int(self._m_recompiles.value),
+                "state_commits": int(self._m_state_commits.value),
                 "entries": len(self._cache)}
 
     def _note_lookup(self, hit: bool, fp, cache_key, once=None) -> None:
@@ -442,10 +574,22 @@ class Executor:
         # per-op kernels are cached by jax ACROSS programs — the coarse
         # compile-time escape hatch.  An explicit `compiled` arg wins.
         gran = str(get_flag("jit_granularity") or "block").lower()
+        # what the last compiled run of this very call left behind
+        # (_StepRecord), taken OUT of the table: only a step that ends
+        # well puts it back, so a failed one leaves nothing half-updated
+        record_key = (program._version, block.idx, tuple(feed),
+                      tuple(fetch_names), bool(get_flag("memory_optimize")),
+                      trace_flags())
+        record = self._take_step_record(scope, program, record_key)
+        # asked once, and only where the mode hangs on it; a record says
+        # no without a walk over the ops: only a compiled run leaves one
+        host_ops = (record is None
+                    and (gran != "op" if compiled is None else compiled)
+                    and self._has_host_ops(block))
         if compiled is None:
             if gran == "op":
                 compiled = False
-            elif not self._has_host_ops(block):
+            elif not host_ops:
                 compiled = True
         step_key = jax.random.fold_in(
             jax.random.key(program.seed or self._seed), self._step
@@ -456,7 +600,7 @@ class Executor:
             # host ops can't be jit-traced: "compiled" with host ops
             # means compile the maximal device segments between them
             mode = ("segmented"
-                    if self._has_host_ops(block) or gran == "segment"
+                    if host_ops or gran == "segment"
                     else "compiled")
         elif compiled is None:
             # host ops present (else compiled was defaulted True above):
@@ -494,7 +638,8 @@ class Executor:
             elif mode == "compiled":
                 try:
                     outs = self._run_compiled(
-                        program, block, scope, feed, fetch_names, step_key
+                        program, block, scope, feed, fetch_names, step_key,
+                        record_key, record
                     )
                 except _MissingState as e:
                     raise RuntimeError(
@@ -517,13 +662,15 @@ class Executor:
 
     def close(self):
         self._cache.clear()
+        self._step_records.clear()
         self._m_entries.set(0)
         # reclaim this instance's registry series (cache_stats() keeps
         # reading the held child objects); processes that churn
         # Executors must not grow every dump without bound
         _M_LOOKUPS.remove(exe=self._exe_id, result="hit")
         _M_LOOKUPS.remove(exe=self._exe_id, result="miss")
-        for fam in (_M_COMPILE_S, _M_RECOMPILES, _M_ENTRIES):
+        for fam in (_M_COMPILE_S, _M_RECOMPILES, _M_STATE_COMMITS,
+                    _M_ENTRIES):
             fam.remove(exe=self._exe_id)
         for mode in ("interpreted", "segmented", "compiled"):
             _M_RUN_SECONDS.remove(exe=self._exe_id, mode=mode)
@@ -792,66 +939,89 @@ class Executor:
         visit(block, set(feed_names), reads, writes)
         return sorted(reads), sorted(writes)
 
-    def _run_compiled(self, program, block, scope, feed, fetch_names, key):
+    def _take_step_record(self, scope, program, record_key):
+        try:
+            return self._step_records[scope][program].pop(record_key, None)
+        except KeyError:
+            return None
+
+    def _keep_step_record(self, scope, program, record_key, record):
+        by_program = self._step_records.setdefault(
+            scope, weakref.WeakKeyDictionary())
+        _bounded_put(by_program.setdefault(program, {}), record_key, record,
+                     cap=_PLAN_CACHE_CAP)
+
+    def _new_step_record(self, program, block, feed_names, fetch_names,
+                         device):
+        rec = _StepRecord(device, trace_flags(),
+                          *self._analyze_states(program, block, feed_names),
+                          _dp_replicated_sharding(block.ops))
+        # liveness donation plan (memory_optimization_transpiler): which
+        # buffers die inside this step.  Read-write states are always
+        # donated (the in-place param update); feed buffers are donated
+        # under the memory_optimize flag or an explicit per-var `donate`
+        # hint — but only when the executor itself created the device
+        # buffer (`fresh`), so a caller-held array is never invalidated.
+        # Unsafe explicit hints raise DonationError here, at build time.
+        plan = self._donation_plan(program, feed_names, fetch_names,
+                                   rec.rw_names)
+        rec.donatable = plan.feeds if get_flag("memory_optimize") else {
+            n for n in plan.feeds
+            if n in block.vars and getattr(block.vars[n], "donate", False)}
+        return rec
+
+    def _run_compiled(self, program, block, scope, feed, fetch_names, key,
+                      record_key, rec):
+        """`rec`: the `_StepRecord` `run` took out of the table for
+        this call, or None; it goes back under `record_key` only when
+        the step has run to its end."""
         device = self.place.jax_device()
         # executor.feed: feeds placed on the device, states committed
-        with obs_tracing.span("executor.feed"):
+        with obs_tracing.span("executor.feed") as feed_span:
             feed_vals, fresh = {}, set()
             for n, v in feed.items():
                 feed_vals[n], is_fresh = _place_feed(v, device)
                 if is_fresh:
                     fresh.add(n)
-            state_in_names, state_out_names = self._analyze_states(
-                program, block, feed_vals.keys()
-            )
-            ro_names = [n for n in state_in_names if n not in state_out_names]
-            rw_names = [n for n in state_in_names if n in state_out_names]
-
-            # liveness donation plan (memory_optimization_transpiler): which
-            # buffers die inside this step.  Read-write states are always
-            # donated (the in-place param update); feed buffers are donated
-            # under the memory_optimize flag or an explicit per-var `donate`
-            # hint — but only when the executor itself created the device
-            # buffer (`fresh`), so a caller-held array is never invalidated.
-            # Unsafe explicit hints raise DonationError here, at build time.
-            plan = self._donation_plan(program, feed_vals.keys(), fetch_names,
-                                       rw_names)
-            donate_all_feeds = get_flag("memory_optimize")
-            hinted = {n for n in plan.feeds
-                      if n in block.vars
-                      and getattr(block.vars[n], "donate", False)}
+            if rec is None or rec.device != device:
+                rec = self._new_step_record(program, block, feed_vals.keys(),
+                                            fetch_names, device)
             don_names = tuple(sorted(
-                n for n in (plan.feeds if donate_all_feeds else hinted)
-                if n in fresh))
-
-            def get_state(n):
-                if not scope.has_var(n) or scope.find_var(n) is None:
-                    raise _MissingState(n)
-                return scope.find_var(n)
-
-            repl = _dp_replicated_sharding(block.ops)
-            target = repl if repl is not None else device
-            ro = {n: _commit(get_state(n), target) for n in ro_names}
-            rw = {n: _commit(get_state(n), target) for n in rw_names}
+                n for n in rec.donatable if n in fresh))
+            ro, n_ro = rec.gather(rec.ro_names, scope)
+            rw, n_rw = rec.gather(rec.rw_names, scope)
+            if n_ro + n_rw:
+                self._m_state_commits.inc(n_ro + n_rw)
+            if feed_span is not None:
+                feed_span.set_attr("states", len(rec.state_in_names))
+                feed_span.set_attr("recommitted", n_ro + n_rw)
         # executor.dispatch: cache lookup and the jitted call
         with obs_tracing.span("executor.dispatch"):
-            cache_key = (
-                self._fingerprint(program),
-                block.idx,
-                tuple(sorted((n, _aval_key(v)) for n, v in feed_vals.items())),
-                tuple((n, _aval_key(v)) for n, v in ro.items()),
-                tuple((n, _aval_key(v)) for n, v in rw.items()),
-                tuple(fetch_names),
-                str(device),
-                don_names,  # donation is baked into the executable
-                trace_flags(),
-            )
+            feed_keys = tuple(sorted(
+                (n, _aval_key(v)) for n, v in feed_vals.items()))
+            if (rec.cache_key is None or feed_keys != rec.feed_keys
+                    or don_names != rec.don_names):
+                keys = rec.keys
+                rec.feed_keys, rec.don_names = feed_keys, don_names
+                rec.cache_key = (
+                    self._fingerprint(program),
+                    block.idx,
+                    feed_keys,
+                    tuple((n, keys[n]) for n in rec.ro_names),
+                    tuple((n, keys[n]) for n in rec.rw_names),
+                    tuple(fetch_names),
+                    str(device),
+                    don_names,  # donation is baked into the executable
+                    rec.flags,
+                )
+            # no state's key and no feed's moved: the last call's tuple
+            cache_key = rec.cache_key
             fn = self._cache.get(cache_key)
             miss = fn is None
             self._note_lookup(not miss, cache_key[0], cache_key)
             if miss:
                 fn = self._build_compiled_fn(
-                    block, fetch_names, state_out_names, repl
+                    block, fetch_names, rec.state_out_names, rec.repl
                 )
                 self._cache[cache_key] = fn
             don_feeds = {n: feed_vals[n] for n in don_names}
@@ -876,6 +1046,8 @@ class Executor:
                 self._m_entries.set(len(self._cache))
             for n, v in state_out.items():
                 scope.set_var(n, v)
+            rec.written(cache_key, state_out)
+            self._keep_step_record(scope, program, record_key, rec)
         return [fetches[n] for n in fetch_names]
 
     def _build_compiled_fn(self, block, fetch_names, state_out_names,

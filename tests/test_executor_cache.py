@@ -125,7 +125,7 @@ def test_cache_stats_counters_and_steady_state():
     scope = fluid.Scope()
     assert exe.cache_stats() == {"hits": 0, "misses": 0, "compile_s": 0.0,
                                  "recompiles_after_warmup": 0,
-                                 "entries": 0}
+                                 "state_commits": 0, "entries": 0}
     exe.run(startup, scope=scope)
     _run_steps(exe, main, loss, scope, [_feed()] * 5)
     s = exe.cache_stats()
@@ -300,3 +300,300 @@ def test_failed_run_does_not_leak_local_scope_segmented():
             exe.run(main, feed=feed, fetch_list=["no_such_var"],
                     scope=scope)
     assert scope.kids == [], "segmented mode leaked local scopes"
+
+
+# ---------------------------------------------------------------------------
+# the record of a compiled step's states (PR 39): a state the scope holds
+# as the last step left it costs a dictionary read, everything else goes
+# through `_commit` and `_aval_key` as before, for that state alone
+# ---------------------------------------------------------------------------
+
+
+def _records(exe, program=None):
+    """The executor's step records: all (the startup program's run left
+    one too), or those of `program`."""
+    return [rec for by_program in exe._step_records.values()
+            for prog, recs in by_program.items() for rec in recs.values()
+            if program is None or prog is program]
+
+
+def _warm(n=3, feed=None):
+    main, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.CPUPlace())
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = _feed() if feed is None else feed
+    for _ in range(n):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    return main, loss, exe, scope, feed
+
+
+def _step_commits(exe, main, loss, scope, feed):
+    """(loss, states the step put through `_commit`)."""
+    before = exe.cache_stats()["state_commits"]
+    out, = exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    return out, exe.cache_stats()["state_commits"] - before
+
+
+_STATES = ("fc_0.b_0", "fc_0.w_0", "fc_1.b_0", "fc_1.w_0",
+           "learning_rate_0")
+
+
+@pytest.mark.parametrize("numpy_held", [(), ("learning_rate_0",)],
+                         ids=["device_states", "numpy_lr"])
+@pytest.mark.parametrize("device_feeds", [False, True],
+                         ids=["numpy_feeds", "device_feeds"])
+def test_warm_step_commits_only_what_may_have_changed(monkeypatch,
+                                                      device_feeds,
+                                                      numpy_held):
+    """After warm-up a step recommits no state the scope holds as a jax
+    array, and `jax.device_put` is called once a feed and once a state
+    held as a NumPy array (whose owner may have written into it: the
+    learning rate, which no step writes, can stay one)."""
+    feed = _feed()
+    if device_feeds:
+        feed = {k: jax.device_put(v) for k, v in feed.items()}
+    main, loss, exe, scope, feed = _warm(feed=feed)
+    assert sorted(_records(exe, main)[-1].state_in_names) == sorted(_STATES)
+    for n in numpy_held:
+        scope.set_var(n, np.asarray(scope.find_var(n)))
+    _step_commits(exe, main, loss, scope, feed)  # sees the NumPy values
+    calls = []
+    real = jax.device_put
+    monkeypatch.setattr(jax, "device_put",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    for _ in range(3):
+        del calls[:]
+        _, commits = _step_commits(exe, main, loss, scope, feed)
+        assert commits == len(numpy_held)
+        assert len(calls) == len(feed) + len(numpy_held)
+    monkeypatch.undo()
+    s = exe.cache_stats()
+    assert s["recompiles_after_warmup"] == 0, s
+    assert s["entries"] == 2, s
+    assert all(size == 1 for size in _jit_cache_sizes(exe))
+
+
+def _build_momentum_dropout():
+    main, startup = fluid.Program(), fluid.Program()
+    main.seed = startup.seed = 7
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[16], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        h = fluid.layers.fc(input=x, size=32, act="relu")
+        h = fluid.layers.dropout(h, dropout_prob=0.3)
+        p = fluid.layers.fc(input=h, size=1)
+        loss = fluid.layers.mean(
+            fluid.layers.square_error_cost(input=p, label=y))
+        fluid.Momentum(learning_rate=0.05, momentum=0.9).minimize(loss)
+    return main, startup, loss
+
+
+@pytest.mark.parametrize("build", [_build_mlp, _build_momentum_dropout],
+                         ids=["sgd", "momentum_dropout"])
+def test_ten_steps_bit_identical_to_a_fresh_executor_every_step(build):
+    """The record changes no number: ten steps through one Executor give
+    the losses and the parameters, bit for bit, of ten steps that each
+    take a new Executor on the same scope (no record: every state
+    through `_commit` and `_aval_key`)."""
+    from paddle_tpu.core.framework import reset_unique_names
+
+    def run(fresh_every_step):
+        reset_unique_names()
+        main, startup, loss = build()
+        scope = fluid.Scope()
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup, scope=scope)
+        losses = []
+        for i in range(10):
+            if fresh_every_step:
+                exe = fluid.Executor(fluid.CPUPlace())
+                exe._step = i + 1  # the step's PRNG key follows the count
+            losses.append(exe.run(main, feed=_feed(i), fetch_list=[loss],
+                                  scope=scope)[0])
+        if not fresh_every_step:
+            assert exe.cache_stats()["state_commits"] == \
+                len(_records(exe, main)[-1].state_in_names)
+        return losses, {n: np.asarray(scope.find_var(n))
+                        for n in scope.local_names()}
+
+    losses, params = run(False)
+    want_losses, want_params = run(True)
+    assert [v.tobytes() for v in losses] == \
+        [v.tobytes() for v in want_losses]
+    assert params.keys() == want_params.keys() and len(params) >= 5
+    for n, v in params.items():
+        assert v.tobytes() == want_params[n].tobytes(), n
+
+
+@pytest.mark.parametrize("as_numpy", [False, True],
+                         ids=["jax_array", "numpy_array"])
+def test_replaced_state_is_used_and_alone_recommitted(as_numpy):
+    """`scope.set_var(name, new)` with the same shape: the next step
+    computes with the new value, puts that one state through `_commit`
+    and compiles nothing."""
+    main, loss, exe, scope, feed = _warm()
+    zero = np.zeros((32, 1), np.float32)
+    scope.set_var("fc_1.w_0", zero if as_numpy else jax.numpy.asarray(zero))
+    bias = np.asarray(scope.find_var("fc_1.b_0"))
+    out, commits = _step_commits(exe, main, loss, scope, feed)
+    assert commits == 1
+    # with the last layer's weight zero the prediction is its bias
+    want = np.mean((bias.reshape(1, 1) - feed["y"]) ** 2)
+    np.testing.assert_allclose(out, want, rtol=1e-6)
+    # the step wrote a device array in its place: nothing to commit
+    assert _step_commits(exe, main, loss, scope, feed)[1] == 0
+    s = exe.cache_stats()
+    assert s["recompiles_after_warmup"] == 0 and s["entries"] == 2, s
+
+
+def test_state_replaced_by_another_shape_recompiles_once_and_warns():
+    from paddle_tpu.core.flags import set_flags
+
+    main, loss, exe, scope, feed = _warm()
+    lr = np.asarray(scope.find_var("learning_rate_0"))
+    assert lr.shape == (1,)
+    scope.set_var("learning_rate_0", jax.numpy.asarray(lr.reshape(())))
+    set_flags({"log_recompiles": True})
+    try:
+        with pytest.warns(RuntimeWarning, match="recompile after warmup"):
+            _, commits = _step_commits(exe, main, loss, scope, feed)
+    finally:
+        set_flags({"log_recompiles": False})
+    assert commits == 1
+    assert _step_commits(exe, main, loss, scope, feed)[1] == 0
+    s = exe.cache_stats()
+    assert s["recompiles_after_warmup"] == 1 and s["entries"] == 3, s
+
+
+def test_numpy_state_written_in_place_is_seen_at_the_next_step():
+    """A NumPy array in the scope is read anew at every step: writing
+    0 into the learning rate IN PLACE stops the parameters."""
+    main, loss, exe, scope, feed = _warm()
+    lr = np.array(np.asarray(scope.find_var("learning_rate_0")))
+    scope.set_var("learning_rate_0", lr)
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    moved = np.asarray(scope.find_var("fc_0.w_0")).copy()
+    lr[...] = 0.0
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    assert scope.find_var("learning_rate_0") is lr
+    np.testing.assert_array_equal(np.asarray(scope.find_var("fc_0.w_0")),
+                                  moved)
+
+
+@pytest.mark.parametrize("how", ["erased", "none"])
+def test_removed_state_raises_run_the_startup_program_first(how):
+    main, loss, exe, scope, feed = _warm()
+    if how == "erased":
+        scope.erase("fc_0.w_0")
+    else:
+        scope.set_var("fc_0.w_0", None)
+    with pytest.raises(RuntimeError, match="'fc_0.w_0' has no value in "
+                       "scope — run the startup program first"):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    assert _records(exe, main) == []
+
+
+def _mutate_program(main, scope):
+    main.bump_version()
+    return scope
+
+
+def _second_scope(main, scope):
+    other = fluid.Scope()
+    for n in scope.local_names():
+        other.set_var(n, jax.numpy.array(scope.find_var(n)))
+    return other
+
+
+def _toggle_memory_optimize(main, scope):
+    from paddle_tpu.core.flags import set_flags
+
+    set_flags({"memory_optimize": True})
+    return scope
+
+
+def _change_trace_flags(main, scope):
+    from paddle_tpu.core.flags import set_flags
+
+    set_flags({"flash_min_seq_k": 12345})
+    return scope
+
+
+@pytest.mark.parametrize("change", [_mutate_program, _second_scope,
+                                    _toggle_memory_optimize,
+                                    _change_trace_flags])
+def test_what_differs_gets_a_record_of_its_own(change):
+    """One record a (program version, scope, flags): the old one stays
+    as it was and serves again when its call comes back."""
+    from paddle_tpu.core.flags import get_flag, set_flags
+
+    saved = {n: get_flag(n) for n in ("memory_optimize", "flash_min_seq_k")}
+    main, loss, exe, scope, feed = _warm()
+    (first,) = _records(exe, main)
+    try:
+        scope2 = change(main, scope)
+        _, commits = _step_commits(exe, main, loss, scope2, feed)
+        assert commits == len(_STATES)   # a new record knows no state
+        assert len(_records(exe, main)) == 2
+        assert first in _records(exe, main)
+        assert _step_commits(exe, main, loss, scope2, feed)[1] == 0
+    finally:
+        set_flags(saved)
+    if change is not _mutate_program:    # a version never comes back
+        # the first call again: its record is still there, and holds what
+        # the scope holds unless the other call wrote the same scope
+        _, commits = _step_commits(exe, main, loss, scope, feed)
+        assert commits == (0 if change is _second_scope else 4)
+        assert len(_records(exe, main)) == 2
+        assert first in _records(exe, main)
+
+
+@pytest.mark.parametrize("failure", ["trace", "call"])
+def test_failed_compiled_run_leaves_no_record(failure):
+    """Any exception inside the step drops its record: the next good
+    run starts from the scope, as a first run does, and still hits the
+    executable it compiled before."""
+    main, loss, exe, scope, feed = _warm()
+    assert len(_records(exe, main)) == 1
+    # a feed the trace refuses (a width the first layer cannot take), or
+    # one the jitted call itself refuses (no array at all)
+    bad = {"x": np.ones((8, 5), np.float32) if failure == "trace"
+           else object(), "y": feed["y"]}
+    with pytest.raises(Exception):
+        exe.run(main, feed=bad, fetch_list=[loss], scope=scope)
+    assert _records(exe, main) == []
+    assert scope.kids == []
+    misses = exe.cache_stats()["misses"]
+    _, commits = _step_commits(exe, main, loss, scope, feed)
+    assert commits == len(_STATES)
+    assert exe.cache_stats()["misses"] == misses
+    assert _step_commits(exe, main, loss, scope, feed)[1] == 0
+
+
+@pytest.mark.parametrize("what", ["scope", "program"])
+def test_executor_keeps_neither_scope_nor_program_alive(what):
+    """The table of records holds scope and program weakly, and a
+    record names neither: a scope out of reach goes with its arrays;
+    a program goes once `close()` has dropped the executables that
+    close over its blocks (as `test_fp_cache_dropped_with_program`)."""
+    import gc
+    import weakref
+
+    main, loss, exe, scope, feed = _warm()
+    assert len(_records(exe, main)) == 1
+    if what == "scope":
+        ref = weakref.ref(scope)
+        arr = weakref.ref(scope.find_var("fc_0.w_0"))
+        del scope
+    else:
+        from paddle_tpu import profiler
+
+        ref, arr = weakref.ref(main), None
+        exe._cache.clear()
+        profiler.reset_profiler()
+        del main, loss
+    gc.collect()
+    assert ref() is None
+    assert arr is None or arr() is None
+    assert _records(exe) == []

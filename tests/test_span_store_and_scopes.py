@@ -285,6 +285,96 @@ def test_scheduler_iteration_readers_say_nothing_when_they_cannot(
 
 
 # ---------------------------------------------------------------------------
+# the two readers of `Executor.run`'s record of its states (PR 39:
+# perf/metrics/reader_state_reuse_share.py, reader_dispatch_share.py)
+# ---------------------------------------------------------------------------
+
+
+def _read_executor(monkeypatch, body):
+    """Run `body()` under a tap like the benchmark's and give what the
+    two readers make of the spans it left."""
+    import os
+    import sys
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf")
+    monkeypatch.syspath_prepend(perf)
+    monkeypatch.delitem(sys.modules, "common", raising=False)
+    import common
+
+    tap = common.SpanTap()
+    tap.arm()
+    try:
+        body()
+    finally:
+        tap.disarm()
+    run = common.Run()
+    run.spans = tap.records
+    return {name: common.load_module(os.path.join(
+        perf, "metrics", name + ".py")).compute(run)
+        for name in ("reader_state_reuse_share", "reader_dispatch_share")}
+
+
+def _steps_by_hand(attrs):
+    """Four steps of 10 ms as `Executor.run` records them: 2 ms under
+    `executor.feed` (with each step's `attrs`), 1 ms under
+    `executor.dispatch`, 6 ms under `executor.fetch`."""
+    def body():
+        t = 1000.0
+        for a in attrs:
+            tracing.record_span("executor.feed", t + 0.001, 0.002, **a)
+            tracing.record_span("executor.dispatch", t + 0.003, 0.001)
+            tracing.record_span("executor.fetch", t + 0.004, 0.006)
+            tracing.record_span("executor.run", t, 0.010, mode="compiled")
+            t += 0.010
+    return body
+
+
+@pytest.mark.parametrize("attrs, reuse", [
+    ([{"states": 429, "recommitted": 0}] * 4, 100.0),
+    ([{"states": 429, "recommitted": 429}]
+     + [{"states": 429, "recommitted": 1}] * 3,
+     100.0 * (1 - 432 / 1716)),
+    # a program that commits every state at every step says nothing of it
+    ([{}] * 4, None),
+    ([{"states": 0, "recommitted": 0}] * 4, None),
+], ids=["all_kept", "one_replaced_a_step", "no_attributes", "no_states"])
+def test_state_reuse_and_dispatch_readers_on_known_spans(monkeypatch,
+                                                         attrs, reuse):
+    got = _read_executor(monkeypatch, _steps_by_hand(attrs))
+    assert got["reader_state_reuse_share"] == (
+        None if reuse is None else pytest.approx(reuse))
+    # the dispatch's share needs no attribute: 4 ms of the 40 spanned
+    assert got["reader_dispatch_share"] == pytest.approx(10.0)
+
+
+def test_state_reuse_and_dispatch_readers_on_the_programs_own_spans(
+        monkeypatch):
+    """Five steps of a Program with five states, recorded by
+    `Executor.run` itself: the first finds none of them in its record,
+    the others all; without spans the readers say nothing."""
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = _batch()
+
+    def body():
+        for _ in range(5):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+
+    got = _read_executor(monkeypatch, body)
+    feeds = _named("executor.feed")
+    assert [(s["attrs"]["states"], s["attrs"]["recommitted"])
+            for s in feeds] == [(5, 5)] + [(5, 0)] * 4
+    assert got["reader_state_reuse_share"] == pytest.approx(80.0)
+    assert 0 < got["reader_dispatch_share"] < 100
+    assert exe.cache_stats()["state_commits"] == 5
+    exe.close()
+    tracing.clear()
+    assert set(_read_executor(monkeypatch, lambda: None).values()) == {None}
+
+
+# ---------------------------------------------------------------------------
 # B. the executors' three children, the trainer's reader phase
 # ---------------------------------------------------------------------------
 
