@@ -61,6 +61,25 @@ architecture is a second description and not a second decoder.
                refused by name: lanes of one tick would run different
                numbers of passes.
 
+  layer-kinds  the sixth (LG K-EXAONE, `model_type: exaone_moe`, whose
+  MoE-like     keys are DeepSeek-V3's), fields again: an FFN KIND A
+               LAYER (`mlp_layer_types`: a "dense" layer takes one
+               SwiGLU of width `dense_d_inner` where a "sparse" one
+               takes the experts and the shared expert; a leading dense
+               layer before the sparse ones); the QK-norm PER HEAD
+               (`qk_norm_per_head`: one scale of `d_head` for all heads
+               of Q, one for K, over each head's own columns, on a
+               grouped geometry); a position signal BY LAYER KIND
+               (`rope_layers`: the kinds RoPE turns, here the sliding
+               ones; a full layer then carries no position at all);
+               and a router of its own (`router: "sigmoid"`): scores
+               sigmoid(logits), a bias added for the CHOICE of the k
+               alone (`router_bias`), the chosen scores renormalised
+               (`norm_topk_prob`) and scaled (`routed_scaling_factor`).
+               Ring, table, held experts and the shared expert are the
+               third and fourth descriptions', together for the first
+               time.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -78,9 +97,11 @@ from typing import Dict, Tuple
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
            "swiglu", "mamba2_step", "MOE_COMPILER_SCOPES", "SLIDING",
-           "FULL", "MAMBA", "ATTENTION"]
+           "FULL", "MAMBA", "ATTENTION", "DENSE", "SPARSE"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# an FFN kind a layer (`mlp_layer_types`)
+DENSE, SPARSE = "dense", "sparse"
 # Granite's names: a layer with no attention, and full attention
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -102,8 +123,11 @@ class BlockSpec:
     with `qk_norm` off and the attention geometry below, and that
     block with Mamba-2 layers and the fields under them, and the
     dense SwiGLU block on plain multi-head attention with the looped
-    stack's fields (module docstring).  `layer_types` and `rope_parameters` may be given as
-    the JSON list and dict a config.json holds: they are kept as
+    stack's fields, and the ring-and-table block with experts, an FFN
+    kind a layer, the per-head QK-norm, RoPE on some layer kinds only
+    and the sigmoid router (module docstring).  `layer_types`,
+    `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
+    as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
     name: str
     norm: str                       # "layer_norm" | "rms_norm"
@@ -145,10 +169,34 @@ class BlockSpec:
     post_norm: bool = False         # a norm on each sub-block's OUTPUT
     exit_gate: bool = False         # sigmoid(w . x_t + b) after a pass
     early_exit_threshold: float = 1.0   # 1: the gate decides nothing
+    # -- an FFN kind a layer: DENSE layers take one SwiGLU of width
+    #    `dense_d_inner`, SPARSE ones the experts (and the shared
+    #    expert); (): every layer is what `ffn` says
+    mlp_layer_types: tuple = ()
+    dense_d_inner: int = 0
+    qk_norm_per_head: bool = False  # `qk_norm` over each head's columns
+    rope_layers: tuple = ()         # the kinds RoPE turns; (): all
+    # -- the router: scores softmax or sigmoid of the logits, a bias
+    #    [n_experts] added for the CHOICE of the k alone, and a factor
+    #    on the (renormalised) weights
+    router: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
-        for name in ("layer_types", "rope_parameters"):
+        for name in ("layer_types", "rope_parameters", "mlp_layer_types",
+                     "rope_layers"):
             object.__setattr__(self, name, _frozen(getattr(self, name)))
+        bad = set(self.mlp_layer_types) - {DENSE, SPARSE}
+        if bad:
+            raise ValueError(
+                f"mlp_layer_types: unknown kind(s) {sorted(bad)}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"router {self.router!r}: 'softmax' or "
+                             "'sigmoid'")
+        if set(self.rope_layers) - {SLIDING, FULL}:
+            raise ValueError(f"rope_layers {self.rope_layers}: of "
+                             f"{SLIDING!r} and {FULL!r}")
         bad = set(self.layer_types) - {SLIDING, FULL, MAMBA, ATTENTION}
         if bad:
             raise ValueError(f"layer_types: unknown kind(s) {sorted(bad)}")
@@ -197,10 +245,28 @@ class BlockSpec:
         kind = self.layer_types[layer]
         return FULL if kind == ATTENTION else kind
 
+    def ffn_of(self, layer: int) -> str:
+        """Layer `layer`'s FFN kind, DENSE or SPARSE; a description
+        without `mlp_layer_types` is all SPARSE (what `ffn` says)."""
+        if not self.mlp_layer_types:
+            return SPARSE
+        if layer >= len(self.mlp_layer_types):
+            raise ValueError(
+                f"block {self.name!r}: {len(self.mlp_layer_types)} "
+                f"mlp_layer_types, and a layer {layer}")
+        return self.mlp_layer_types[layer]
+
+    def rotated(self, kind: str) -> bool:
+        """Whether RoPE turns Q and K on a layer of this kind."""
+        return self.positions == "rope" and (
+            not self.rope_layers or kind in self.rope_layers)
+
     def rope_of(self, kind: str) -> dict:
         """RoPE parameters of a layer kind: the kind's entry of
-        `rope_parameters`, else plain RoPE at `rope_theta`."""
-        params = dict(self.rope_parameters).get(kind)
+        `rope_parameters` (or the one set of parameters it holds for
+        every kind), else plain RoPE at `rope_theta`."""
+        params = dict(self.rope_parameters)
+        params = params if "rope_type" in params else params.get(kind)
         if params is None:
             return {"rope_type": "default", "rope_theta": self.rope_theta}
         return dict(params)
@@ -237,7 +303,10 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     (`ssm_conv`: [width, H*P + 2*N] and its bias), `ssm_dt` (dt's
     bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
     `ssm_out` [H*P, d]; no bias on a projection.  The expert matrices
-    are [experts HELD, ...]; the router keeps its published width."""
+    are [experts HELD, ...]; the router keeps its published width.  A
+    DENSE layer among sparse ones (`mlp_layer_types`) has the dense
+    block's three matrices at `dense_d_inner` and no "router" key:
+    that is how the step tells the two apart."""
     dense = spec.ffn == "swiglu"
     if (spec.norm, spec.bias) != ("rms_norm", False) or spec.ffn not in (
             "moe_swiglu", "swiglu"):
@@ -277,13 +346,31 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             f"block {spec.name!r}: Mamba layers need ssm_heads, "
             "ssm_d_head, ssm_d_state and ssm_conv >= 2")
     n_kv, d_head = spec.heads(d_model, n_heads)
-    if spec.qk_norm and (n_kv * d_head, n_heads * d_head) != (
-            d_model, d_model):
+    if spec.qk_norm_per_head and not spec.qk_norm:
+        raise ValueError(
+            f"block {spec.name!r}: qk_norm_per_head says how qk_norm "
+            "is applied, and qk_norm is off")
+    if spec.qk_norm and not spec.qk_norm_per_head and (
+            n_kv * d_head, n_heads * d_head) != (d_model, d_model):
         raise NotImplementedError(
-            f"block {spec.name!r}: qk_norm is built over all of Q and "
-            "K at the model's width, not per head of a grouped or "
-            "wider geometry")
+            f"block {spec.name!r}: qk_norm over all of Q and K is built "
+            "at the model's width; a grouped or wider geometry takes "
+            "it per head (qk_norm_per_head)")
+    ffns = [spec.ffn_of(l) for l in range(n_layers)]
+    if DENSE in ffns and (dense or mamba or spec.dense_d_inner < 1):
+        raise NotImplementedError(
+            f"block {spec.name!r}: dense layers among sparse ones "
+            "(mlp_layer_types) are built for a block of attention "
+            "layers with experts (ffn 'moe_swiglu') and need "
+            "dense_d_inner, the dense layers' width")
+    if spec.router != "sigmoid" and (
+            spec.router_bias or spec.routed_scaling_factor != 1.0):
+        raise NotImplementedError(
+            f"block {spec.name!r}: a choice bias and a scaling factor "
+            "are built and tested on the sigmoid router alone")
     for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
+        if not spec.rotated(kind):
+            continue
         if spec.rope_of(kind)["rope_type"] not in ("default", "yarn"):
             raise NotImplementedError(
                 f"block {spec.name!r}: rope_type "
@@ -321,10 +408,13 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "v": add(p + "v_proj.w_0", d, dkv),
                    "o": add(p + "o_proj.w_0", dq, d)}
         lay["norm2"] = add(p + "ffn_norm.scale_0", d)
-        if dense:
-            lay.update({"gate": add(p + "ffn_gate.w_0", d, f),
-                        "up": add(p + "ffn_up.w_0", d, f),
-                        "down": add(p + "ffn_down.w_0", f, d)})
+        if dense or ffns[l] == DENSE:
+            # one SwiGLU every token takes: the block's FFN, or a dense
+            # layer's among sparse ones (no router, no shared expert)
+            fd = f if dense else spec.dense_d_inner
+            lay.update({"gate": add(p + "ffn_gate.w_0", d, fd),
+                        "up": add(p + "ffn_up.w_0", d, fd),
+                        "down": add(p + "ffn_down.w_0", fd, d)})
         else:
             lay.update({"router": add(p + "router.w_0", d, e),
                         "gate": add(p + "experts_gate.w_0", held, d, f),
@@ -335,13 +425,18 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             # residual stream takes it
             lay["post1"] = add(p + "attn_post_norm.scale_0", d)
             lay["post2"] = add(p + "ffn_post_norm.scale_0", d)
-        if fs:
+        if spec.router_bias and "router" in lay:
+            lay["router_bias"] = add(p + "router_bias.b_0", e)
+        if fs and "router" in lay:
             lay.update({"shared_gate": add(p + "shared_gate.w_0", d, fs),
                         "shared_up": add(p + "shared_up.w_0", d, fs),
                         "shared_down": add(p + "shared_down.w_0", fs, d)})
         if spec.qk_norm:
-            lay["q_norm"] = add(p + "q_norm.scale_0", d)
-            lay["k_norm"] = add(p + "k_norm.scale_0", d)
+            # one scale for all of Q (of K), or one of d_head for every
+            # head of Q (of K)
+            dn = d_head if spec.qk_norm_per_head else d
+            lay["q_norm"] = add(p + "q_norm.scale_0", dn)
+            lay["k_norm"] = add(p + "k_norm.scale_0", dn)
         layers.append(lay)
     tok = add("tok_embedding.w_0", vocab_size, d)
     layout = types.SimpleNamespace(
@@ -437,10 +532,15 @@ MOE_COMPILER_SCOPES = {"ragged-dot-none": "paged_decoder/moe_experts",
                        "ragged-dot-metadata": "paged_decoder/moe_dispatch"}
 
 
-def route(spec: BlockSpec, m, w_router):
+def route(spec: BlockSpec, m, w_router, b_router=None):
     """The router: tokens m [T, D] (float32) -> (weights [T, k]
     float32, experts [T, k] int32), the k largest of the softmax over
-    ALL experts, largest first.  Float32 at `highest` precision (one
+    ALL experts, largest first.  Under `router: "sigmoid"` the scores
+    are sigmoid(logits), the k chosen are the k largest of scores +
+    `b_router` [E] (the bias decides the CHOICE alone: the weights are
+    the scores as they are), renormalised under `norm_topk_prob` and
+    then times `routed_scaling_factor`; a tie goes to the lower expert
+    index either way.  Float32 at `highest` precision (one
     bf16 pass moves a probability by 1e-3 of itself and swaps the k-th
     and k+1-th expert wherever they lie that close); the weights are
     the probabilities as they are, renormalised only under
@@ -453,15 +553,25 @@ def route(spec: BlockSpec, m, w_router):
 
     logits = jnp.dot(m, w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)                 # [T, E]
-    top_w, top_e = jax.lax.top_k(probs, spec.experts_per_token)
+    if spec.router == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)             # [T, E]
+    if b_router is None:
+        top_w, top_e = jax.lax.top_k(probs, spec.experts_per_token)
+    else:
+        _, top_e = jax.lax.top_k(probs + b_router.astype(jnp.float32),
+                                 spec.experts_per_token)
+        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
     if spec.norm_topk_prob:
         top_w = top_w / top_w.sum(-1, keepdims=True)
+    if spec.routed_scaling_factor != 1.0:
+        top_w = top_w * spec.routed_scaling_factor
     return top_w, top_e
 
 
 def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
-            scope=None, experts=None):
+            scope=None, experts=None, b_router=None):
     """Dropless top-k-of-E SwiGLU expert layer over tokens m [T, D]
     (float32) -> ([T, D] float32, experts hit: int32 scalar, routing:
     `route`'s (weights, experts)).
@@ -487,7 +597,8 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     returned for these shapes: the Pallas kernel (gate, up and the
     gated product in one call, down in a second, over work items it
     plans from the group sizes under `moe_dispatch`), or None: three
-    `jax.lax.ragged_dot`s, the one fallback."""
+    `jax.lax.ragged_dot`s, the one fallback.  `b_router`: the
+    router's choice bias, where the description has one (`route`)."""
     import contextlib
 
     import jax
@@ -498,7 +609,7 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     first, e_n = spec.held
     share = e_n < spec.n_experts
     with scope("moe_router"):
-        top_w, top_e = route(spec, m, w_router)             # [T, k]
+        top_w, top_e = route(spec, m, w_router, b_router)   # [T, k]
     with scope("moe_dispatch"):
         flat_e = top_e.reshape(t_n * k_n)
         if share:

@@ -505,9 +505,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     `startup_program` is None.  Cache, gather, attention, write,
     sampling, `step`, `step_logits` and `step_window` are one code
     path for every block.  A block with experts returns a FOURTH
-    value from `step` and `step_window`: int32 [n_layers], the
-    distinct experts each layer routed to this call
-    (`decoder.step_counters` names it; empty for a block without),
+    value from `step` and `step_window`: int32 [layers with experts:
+    `decoder.moe_layers`], the distinct experts each routed to this
+    call, and where it HOLDS a share of its experts a FIFTH, the
+    assignments of live lanes that fell on held experts, a layer
+    (`decoder.step_counters` names them; empty for a block without),
     and has `decoder.step_routing`: `step_logits` that also returns
     what every layer's router was given and what it chose, for a
     comparison that must not mistake a near-tie for a fault.
@@ -740,6 +742,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         layout, shapes = lm_block.param_layout(
             spec, vocab_size, d_model, n_heads, n_layers, d_inner)
 
+    # layers with experts (a DENSE layer of `mlp_layer_types` has
+    # none), and whether the experts here are a share of those routed
+    moe_layers = (sum(spec.ffn_of(l) == lm_block.SPARSE
+                      for l in range(n_layers))
+                  if spec.ffn == "moe_swiglu" else 0)
+    shares = spec.ffn == "moe_swiglu" and spec.held[1] < spec.n_experts
+
     scale = spec.attention_multiplier or 1.0 / math.sqrt(d_head)
     # the residual stream takes each sub-block's output times this
     # (Python's 1.0: no operation, the other blocks' steps as they were)
@@ -836,7 +845,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if spec.positions != "rope":
             return dict.fromkeys(kinds)
         with scope("rope"):
-            return {kind: lm_block.rope_tables(spec, pos, d_head, kind)
+            # a kind RoPE does not turn (`BlockSpec.rope_layers`)
+            # carries no position signal at all
+            return {kind: (lm_block.rope_tables(spec, pos, d_head, kind)
+                           if spec.rotated(kind) else None)
                     for kind in dict.fromkeys(kinds)}
 
     def _qkv(g, lay, x, rot):
@@ -845,8 +857,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             q, kk, vv = (_fc(g, h, lay[n]) for n in ("q", "k", "v"))
         if spec.qk_norm:
             with scope("qk_norm"):
-                q = _norm(g, q, lay["q_norm"])
-                kk = _norm(g, kk, lay["k_norm"])
+                if spec.qk_norm_per_head:
+                    # over each head's own columns, one scale of
+                    # d_head for all heads of Q and one for K
+                    q, kk = (_norm(g, t.reshape(t.shape[:-1]
+                                                + (-1, d_head)),
+                                   lay[n]).reshape(t.shape)
+                             for t, n in ((q, "q_norm"), (kk, "k_norm")))
+                else:
+                    q = _norm(g, q, lay["q_norm"])
+                    kk = _norm(g, kk, lay["k_norm"])
         if rot is not None:
             # K is turned BEFORE it is written: the pool holds rotated
             # keys, so a cached position is never turned again
@@ -865,7 +885,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def _ffn(g, lay, x, hits):
         """x + FFN(norm(x)); a block with experts appends (its count
         of distinct experts hit, the router's input, the weights and
-        the experts it chose) to `hits`."""
+        the experts it chose) to `hits`; a DENSE layer of such a
+        block (no "router" among its names) is one SwiGLU under a
+        scope of its own and appends nothing."""
+        if spec.ffn == "moe_swiglu" and "router" not in lay:
+            with scope("dense_ffn"):
+                h2 = _norm(g, x, lay["norm2"])
+                y = lm_block.swiglu(h2.reshape(-1, d_model), *(
+                    g[lay[n][0]] for n in ("gate", "up", "down")))
+                return _residual(x, y.reshape(x.shape))
         with scope("mlp"):
             h2 = _norm(g, x, lay["norm2"])
             if spec.ffn == "relu":
@@ -891,7 +919,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                  else f"xla:{refused}")
         y, hit, routed = lm_block.moe_ffn(
             spec, h2, g[lay["router"][0]], w_gate, g[lay["up"][0]],
-            g[lay["down"][0]], scope=scope, experts=experts)
+            g[lay["down"][0]], scope=scope, experts=experts,
+            b_router=(g[lay["router_bias"][0]] if "router_bias" in lay
+                      else None))
         hits.append((hit, h2) + routed)
         if spec.shared_d_inner:
             # the shared expert: every token, whole, weight 1
@@ -929,8 +959,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("ssm_out_proj"):
             return _residual(x, out), state, tail, given
 
-    def _with_counts(out, hits):
-        return out + ((jnp.stack([h[0] for h in hits]),) if hits else ())
+    def _with_counts(out, hits, live):
+        """`out` and what the step counted (`decoder.step_counters`):
+        the distinct experts each layer with experts routed to and,
+        where the block HOLDS a share of them, the assignments of the
+        `live` rows [T] that fell on held experts, a layer."""
+        if not hits:
+            return out
+        out = out + (jnp.stack([h[0] for h in hits]),)
+        if not shares:
+            return out
+        first, e_n = spec.held
+        with scope("moe_dispatch"):
+            return out + (jnp.stack([jnp.sum(
+                (h[3] >= first) & (h[3] < first + e_n) & live[:, None],
+                dtype=jnp.int32) for h in hits]),)
 
     def _kind_scope(name, kind):
         """`paged_decoder/<name>`, and under it the layer's kind where
@@ -1215,7 +1258,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     return out + (jnp.sum(
                         (loop["gates"] > 0.5) & active[None, :],
                         dtype=jnp.int32),)
-            return _with_counts(out, hits)
+            return _with_counts(out, hits, active)
 
     @jax.jit
     def step_logits(g, pool_k, pool_v, tables, positions, tokens, seeds,
@@ -1228,9 +1271,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def step_routing(g, pool_k, pool_v, tables, positions, tokens, seeds,
                      temps, active):
         """`step_logits`, and beside the logits every layer's routing
-        as the step computed it: {"inputs": float32 [n_layers, S, D]
-        (what the router was given), "weights": float32 [n_layers, S,
-        k], "experts": int32 [n_layers, S, k]}, and for a block with
+        as the step computed it: {"inputs": float32 [layers, S, D]
+        (what the router was given), "weights": float32 [layers, S,
+        k], "experts": int32 [layers, S, k]}, the layers those with
+        experts (`decoder.moe_layers`), and for a block with
         Mamba layers "ssm_inputs": float32 [Mamba layers, S, H*P + 2N +
         H], what each layer's recurrence was given at this position
         (`lm_block.mamba2_step`).  Its twin for a LOOPED stack, which
@@ -1331,7 +1375,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         preds = _sample(logits.reshape(s_n * w_n, -1),
                         seeds_w.reshape(-1), pos_c.reshape(-1),
                         temps_w.reshape(-1)).reshape(s_n, w_n)
-        return _with_counts((preds, pool_k, pool_v), hits)
+        return _with_counts((preds, pool_k, pool_v), hits,
+                            valid.reshape(-1))
 
     if kv_dtype == "fp32":
         elem_bytes = 4.0
@@ -1427,6 +1472,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
                   for key, part in parts.items() if key in lay]
+    if spec.ffn == "moe_swiglu":
+        # a dense layer among sparse ones: its three matrices
+        weights_of += [(lay[key], "dense_ffn") for lay in layout.layers
+                       if "router" not in lay
+                       for key in ("gate", "up", "down")]
     compiler_scopes = {
         f"g[\\'{name}\\']": f"paged_decoder/{part}"
         for pair, part in weights_of + [(layout.head, "head")]
@@ -1439,9 +1489,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         step_routing=(step_routing if spec.ffn == "moe_swiglu" or looped
                       else None),
         init_pool=init_pool, slot_rings=slot_rings, platform=platform,
-        step_counters=(("moe_experts_hit",) if spec.ffn == "moe_swiglu"
+        step_counters=(("moe_experts_hit",)
+                       + (("moe_rows_held",) if shares else ())
+                       if spec.ffn == "moe_swiglu"
                        else ("exit_gate_open",) if spec.exit_gate
                        else ()),
+        # layers with experts: what `moe_experts_hit` and
+        # `moe_rows_held` are summed over (0: a block without)
+        moe_layers=moe_layers,
         # a looped stack: the passes a token takes over the one stack
         # (1: a plain block) and the planes its table pool has
         passes=passes, kv_planes=planes,

@@ -630,6 +630,7 @@ class GenerationServer:
         self._stateful = bool(stateful)
         # a looped stack: the passes a tick's step runs (1: a plain one)
         self._passes = int(getattr(decoder, "passes", 1))
+        self._moe_layers = int(getattr(decoder, "moe_layers", 0))
         # the last admission left the queue's head waiting for BLOCKS
         # with a slot free (on the next tick's span as `kv_wait`)
         self._kv_wait = False
@@ -1273,7 +1274,9 @@ class GenerationServer:
         its slots) and `state_resets` (those at position 0, which the
         step starts from a zero state).  With experts `moe_kernel`:
         1 where the step's expert layer is the Pallas grouped matmul
-        (`decoder.expert_kernel`), 0 where `ragged_dot`.  `kv_wait`: 1
+        (`decoder.expert_kernel`), 0 where `ragged_dot`, and
+        `moe_layers`: the layers with experts (`decoder.moe_layers`),
+        over which the step's counts are summed.  `kv_wait`: 1
         where the admission before this tick left the queue's head
         waiting with a slot free because `can_admit` refused it for
         blocks.  With a looped stack `loop_passes` (the passes the
@@ -1311,13 +1314,17 @@ class GenerationServer:
         if expert_kernel is not None:
             attrs["moe_kernel"] = int(
                 not expert_kernel.startswith("xla:"))
+        if self._moe_layers:
+            attrs["moe_layers"] = self._moe_layers
         return attrs
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live
         tick span under the decoder's own names (`step_counters`:
         `moe_experts_hit`, distinct experts routed to over all layers,
-        for a block with experts; nothing for one without).  Read
+        for a block with experts, and `moe_rows_held`, the live lanes'
+        assignments that fell on experts held here, where the block
+        holds a share of them; nothing for one without).  Read
         after the tokens, in the phase that has already blocked: on
         the pipelined path they are the counts of the tick READ, one
         before the tick the span dispatched."""

@@ -1129,7 +1129,8 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     assert len(ticks) == len(want) >= 30
     # what PR 38 added beside the old walks' counts
     loop = {"loop_passes": 3, "kv_planes": 6} if kind == "loop" else {}
-    extra = ({"ahead", "kv_wait"} | set(loop)
+    # and PR 40: the layers with experts, on a block that has any
+    extra = ({"ahead", "kv_wait", "moe_layers"} | set(loop)
              | set(getattr(dec, "step_counters", ())))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old

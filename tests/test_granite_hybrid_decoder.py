@@ -136,7 +136,7 @@ def _drive(dec, g, seqs, slots=None, lanes=None, routing=False,
         lg = np.asarray(lg)
         routed.append({k: np.asarray(v)[:, lanes[0]:lanes[0] + 1]
                        for k, v in r.items()})
-        _, pool_k, pool_v, _ = dec.step(*args)
+        _, pool_k, pool_v, *_ = dec.step(*args)
         for i, (s, lane) in enumerate(zip(seqs, lanes)):
             if pos < len(s):
                 out[i].append(lg[lane])

@@ -10,9 +10,10 @@ Pins three contracts:
     the group sizes, and a row's result depends on no other row, on no
     group size and on no tile boundary;
   * which of the two runs is a function of the shapes, the weights'
-    dtype and the platform, and of nothing else: both MoE cells of
-    the benchmark select the kernel on a TPU, read from their own
-    files;
+    dtype and the platform, and of nothing else: every cell of the
+    benchmark with experts selects the kernel on a TPU, read from its
+    own files, with an expert's whole matrix as one block wherever
+    that fits and tiles of its rows (K) where it does not;
   * set-up: the kernel's bodies are traced once a process and lowered
     once a program however many layers call them, and building a
     decoder compiles and runs nothing.
@@ -168,8 +169,9 @@ def _cell_shapes(workload):
                 dtype={"bfloat16": jnp.bfloat16}[m["dtype"]])
 
 
-OLMOE, MELLUM = ("olmoe-1b-7b-serve-chat32",
-                 "mellum2-12b-a2.5b-serve-agent96")
+OLMOE, MELLUM, GRANITE, K_EXAONE = (
+    "olmoe-1b-7b-serve-chat32", "mellum2-12b-a2.5b-serve-agent96",
+    "granite-4.0-h-small-serve-chat64", "k-exaone-236b-a23b-serve-chat64")
 
 
 @pytest.mark.parametrize("shapes,platform,interpret,want", [
@@ -186,13 +188,15 @@ OLMOE, MELLUM = ("olmoe-1b-7b-serve-chat32",
     # Mosaic's lane grid
     (dict(d_ff=1000), "tpu", False, "width_misaligned"),
     (dict(d_model=1000), "tpu", False, "width_misaligned"),
-    # a whole matrix is one block: 8192 x 4096 twice over is past VMEM
-    (dict(d_model=8192, d_ff=4096), "tpu", False, "vmem"),
+    # 8192 x 4096 twice over is past VMEM whole, and goes in tiles
+    (dict(d_model=8192, d_ff=4096), "tpu", False, None),
+    # not even one lane tile of 131072 rows fits
+    (dict(d_model=131072), "tpu", False, "vmem"),
     # a speculative window's rows are a shape like any other
     (dict(rows=32 * 5 * 8), "tpu", False, None),
 ], ids=["olmoe-tpu", "mellum2-tpu", "olmoe-cpu", "olmoe-cpu-interpret",
         "float32-weights", "expert-width-1000", "model-width-1000",
-        "past-vmem", "window-rows"])
+        "tiled", "past-vmem", "window-rows"])
 def test_selection_follows_shapes_dtype_and_platform(shapes, platform,
                                                      interpret, want):
     """The benchmark's own shapes, read from the cells' files."""
@@ -208,7 +212,97 @@ def test_selection_follows_shapes_dtype_and_platform(shapes, platform,
         platform=platform, interpret=interpret, **shapes) == want
 
 
-@pytest.mark.parametrize("workload", [OLMOE, MELLUM])
+@pytest.mark.parametrize("workload,tiles", [
+    # an expert's whole matrix is one block, as before there were tiles
+    (OLMOE, (2048, 1024)), (MELLUM, (2304, 896)), (GRANITE, (4096, 768)),
+    # 6144 x 2048: gate and up in three tiles of 2048 rows, down in two
+    # of 1024
+    (K_EXAONE, (2048, 1024))])
+def test_k_tiles_are_the_whole_matrix_wherever_it_fits(workload, tiles):
+    """The three cells that had experts before K tiles keep the block
+    they had (the whole matrix: their compiled steps hold the Pallas
+    calls they held), and the wide experts get the largest equal split
+    of their rows that fits the kernel's VMEM."""
+    s = _cell_shapes(workload)
+    kern, reason = grouped_matmul.select_grouped_matmul(platform="tpu", **s)
+    assert reason is None and kern.k_tiles == tiles
+    assert kern.row_tile == 128
+    whole = tiles == (s["d_model"], s["d_ff"])
+    assert whole == (workload != K_EXAONE)
+    item = jnp.dtype(s["dtype"]).itemsize
+    assert grouped_matmul._call_vmem_bytes(
+        128, tiles[0], s["d_ff"], 2, item, item, accumulate=not whole) \
+        <= grouped_matmul._VMEM_LIMIT_BYTES
+    if not whole:
+        # the next larger split (halves) does not fit
+        assert grouped_matmul._call_vmem_bytes(
+            128, s["d_model"] // 2, s["d_ff"], 2, item, item,
+            accumulate=True) > grouped_matmul._VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("sizes", [
+    # 128 sorted rows of which 16 are in a group: seven eighths sort
+    # past the last group, as on a chip that holds an eighth of the
+    # experts
+    [3, 0, 5, 1, 0, 2, 4, 1],
+    # a group that straddles the two row tiles, and rows past the end
+    [40, 0, 30, 3, 0, 0, 9, 0],
+    [0, 0, 0, 0, 0, 0, 0, 128],
+], ids=["seven_eighths_past_the_last_group", "straddles_row_tiles",
+        "all_rows_to_the_last_expert"])
+def test_k_tiled_kernel_is_ragged_dot_over_the_same_sorted_rows(
+        sizes, monkeypatch):
+    """Under a VMEM limit that a toy expert's whole matrix does not
+    fit, gate and up go in four K tiles and down in two (the grid
+    gains the tiles as its inner axis, the partial products a float32
+    accumulator): the rows that are in a group equal `ragged_dot`'s as
+    the whole-matrix kernel's do (the sum over K in another order);
+    rows past the last group are whatever was there (`moe_ffn` masks
+    them)."""
+    monkeypatch.setattr(grouped_matmul, "_VMEM_LIMIT_BYTES", 1500 * 1024)
+    d, f, rows = 512, 512, 128
+    x, w_gate, w_up, w_down = _operands([rows], jnp.bfloat16, d=d, f=f)
+    w_gate, w_up, w_down = (jnp.concatenate([w] * len(sizes)) * (
+        1.0 + jnp.arange(len(sizes), dtype=w.dtype)[:, None, None] / 8)
+        for w in (w_gate, w_up, w_down))
+    kern = _kernel(rows, len(sizes), jnp.bfloat16, d, f)
+    assert kern.row_tile == 64 and kern.k_tiles == (128, 256)
+    plan = kern.plan(jnp.asarray(sizes, jnp.int32))
+    # the static bound on the items is past the groups that have rows:
+    # the padded items must move no block (they repeat the last one's)
+    assert int(plan[3][0]) < plan[0].shape[0]
+    act = kern.gate_up(x, w_gate, w_up, plan)
+    out = kern.down(act, w_down, plan)
+    want_act, want = _through_ragged_dot(x, w_gate, w_up, w_down, sizes)
+    n = int(np.sum(sizes))
+    a, wa = (np.asarray(v, np.float32)[:n] for v in (act, want_act))
+    larger = np.maximum(np.maximum(np.abs(a), np.abs(wa)), 1e-30)
+    ulp = 2.0 ** (np.floor(np.log2(larger)) - 7)
+    assert np.all(np.abs(a - wa) <= np.maximum(ulp, 1e-6))
+    scale = float(np.abs(np.asarray(want)[:n]).max())
+    assert np.abs(np.asarray(out)[:n] - np.asarray(want)[:n]).max() \
+        <= scale * 2 ** -8
+    # the whole-matrix kernel over the same operands: within the same
+    # ulp (one sum over K against four partial sums)
+    monkeypatch.undo()
+    whole = _kernel(rows, len(sizes), jnp.bfloat16, d, f)
+    assert whole.k_tiles == (d, f)
+    a1 = np.asarray(whole.gate_up(x, w_gate, w_up, plan), np.float32)[:n]
+    assert np.all(np.abs(a1 - a) <= np.maximum(ulp, 1e-6))
+
+
+def test_items_past_the_last_repeat_its_last_k_tile():
+    """`_k_index`, which every block's index map of a K-tiled call
+    goes through: an item with rows walks its expert's K tiles in
+    turn, a padded item addresses the last item's FINAL tile at every
+    step of its walk, so no block moves for it."""
+    total = jnp.asarray([3], jnp.int32)
+    seen = [[int(grouped_matmul._k_index(w, j, total, 4))
+             for j in range(4)] for w in range(5)]
+    assert seen == [[0, 1, 2, 3]] * 3 + [[3, 3, 3, 3]] * 2
+
+
+@pytest.mark.parametrize("workload", [OLMOE, MELLUM, K_EXAONE])
 def test_kernel_lowers_for_tpu_at_the_cells_widths(workload):
     """Both calls reach the TPU lowering as Mosaic custom calls at the
     published widths, on the weights as the state dict holds them."""
