@@ -1070,19 +1070,21 @@ def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
     written then re-read) never exists.  The arithmetic is the gather
     composition's (the query block-diagonal over whole pool rows:
     n_heads times a head-split contraction's multiply-adds) over the
-    rows of those pages.  `gather_bytes_avoided` is what the gather
-    path moves on top: the pages past the cursor and both legs of the
-    copy."""
+    rows of those pages.  The query goes in and the result comes out
+    at the heads' own width (`query_result_bytes`: the kernel builds
+    the block-diagonal operand and keeps each head's columns in VMEM).
+    `gather_bytes_avoided` is what the gather path moves on top: the
+    pages past the cursor and both legs of the copy."""
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     ctx = int(context if context is not None else bs * nb)
     kvb = _kv_elem_bytes(kv_dtype, bs, d)
     pages = min(-(-ctx // bs), nb)
     rows = pages * bs
     flops = slots * layers * 4.0 * rows * d * h
-    # pool pages in storage precision, and the [slots, n_heads, d]
-    # float32 result the heads keep their own columns of outside
+    # pool pages in storage precision; the [slots, d] query read in the
+    # pool's dtype and the [slots, d] float32 result written and read
     pool_bytes = slots * layers * 2.0 * rows * d * kvb
-    out_bytes = slots * layers * 2.0 * h * d * 4.0
+    out_bytes = slots * layers * d * (kvb + 2.0 * 4.0)
     gather = serving_kernel_cost("paged_attention_gather", spec,
                                  slots=slots, context=ctx,
                                  kv_dtype=kv_dtype)
@@ -1091,9 +1093,9 @@ def _paged_attention_decode_cost(spec: Dict, slots: int = 1,
         "backend": "pallas",
         "shapes": {"pool": f"[{layers}, blocks, {bs}, {d}] x2 ({kv_dtype})",
                    "tables": f"[{slots}, {nb}] int32",
-                   "query": f"[{slots}, {h}, {d}]"},
+                   "query": f"[{slots}, {d}]"},
         "flops": flops, "bytes": pool_bytes + out_bytes,
-        "pool_bytes": pool_bytes,
+        "pool_bytes": pool_bytes, "query_result_bytes": out_bytes,
         "pages_read": slots * layers * pages,
         "gather_bytes_avoided": gather["bytes"] - pool_bytes,
         "context": ctx, "slots": slots,

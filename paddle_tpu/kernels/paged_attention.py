@@ -23,18 +23,35 @@ of step overhead for 0.08 us of copying).  Inside a step the slot's
 pages arrive in chunks of `pages` pages through manual async copies
 into a double-buffered VMEM scratch of two chunks, K and V, whatever
 the context; an online softmax (running max and sum, float32) joins
-the chunks.  While a slot's last chunk is computed the NEXT slot's
-first chunk is already in flight (the scratch and the buffer cursor
-outlive a grid step), so DMA latency is paid once a call, not once a
-slot.
+them a chunk at a time: the chunk is the unit of copying, and its two
+products run over the smallest row window (128 rows, doubled up to the
+chunk) that holds the pages copied into it.  While a slot's last chunk
+is computed the NEXT slot's first chunk is already in flight (the
+scratch and the buffer cursor outlive a grid step), so DMA latency is
+paid once a call, not once a slot.
 
-The arithmetic is `_attention`'s: the query arrives block-diagonal by
-K/V head (`q_bd` [S, H, Dkv], built by the decoder, in the pool's
-dtype: what the MXU rounds it to on the XLA path too), scores are
-`q_bd . page^T` over the whole pool row and the context `p . page`
-over the whole row, of which each head keeps its own columns outside.
-The kernel therefore knows nothing of head size or grouping.  Scores,
-mask, softmax and both sums are float32; `scale` is an argument.
+The arithmetic is `_attention`'s.  The query arrives as the projection
+made it (`q` [S, H*dh], cast to the pool's dtype: what the MXU rounds
+it to on the XLA path too) and the kernel lays it out block-diagonal by
+K/V head in VMEM, once a grid step: query head i's d_head columns in
+ITS K/V head's columns of a pool row and exact zeros outside them
+([H, Dkv]; under plain multi-head attention the row broadcast over the
+heads under an iota mask, under grouped heads the [H, dh] block tiled
+along the lanes under the same mask).  Scores are `q_bd . K^T` over
+the whole pool row and the context `p . V` over the whole row into a
+float32 accumulator [H, Dkv] in VMEM, of which each head keeps its own
+columns when the last chunk is in: [S, H*dh] float32 leaves the kernel,
+at a head's own width as the query came.  Scores, mask, softmax and
+both sums are float32; `scale`, the heads and their size are static
+arguments.
+
+The kernel also WRITES this position's K and V (`write=`): the row
+goes into its page as the page lies in VMEM, before the products read
+it, and the sublane tile of 8 rows that holds it goes back to the pool
+under them (the pools are aliased outputs: the caller's buffers where
+it donates them).  The step's two XLA scatters a layer, 7.6 us each on
+the v5e and most of that launch, are gone; a row alone cannot go back,
+because a DMA moves whole sublane tiles (PERF.md section 6, PR 41).
 
 The call sits behind one module-level `jax.jit` (`paged_attention`),
 the layer a TRACED scalar: the body is traced once a process for a set
@@ -57,17 +74,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "paged_attention_supports",
-           "select_paged_attention"]
+           "rows_multiplied", "select_paged_attention"]
 
 # K (or V) bytes a chunk: the copies of one chunk are in flight while
 # the one before it is computed, so a chunk is long enough to hide a
-# DMA's latency; only the pages a slot has are copied, so a slot with
-# a page or two pays for the chunk's rows in the two products alone.
-# On the v5e 1 MiB reads 3 to 6% faster than 512 KiB and 10 to 15%
-# faster than 256 KiB, at pages of 64 KB and of 16 KB alike (PERF.md
-# section 6, PR 35).  Two chunks of K and two of V are the whole
-# scratch: 4 MiB, whatever the context.
+# DMA's latency; only the pages a slot has are copied.  On the v5e
+# 1 MiB reads 3 to 6% faster than 512 KiB and 10 to 15% faster than
+# 256 KiB, at pages of 64 KB and of 16 KB alike (PERF.md section 6,
+# PR 35).  Two chunks of K and two of V are the whole scratch: 4 MiB,
+# whatever the context.
 _CHUNK_BYTES = 1024 * 1024
+# The fewest rows of a chunk the two products run over: the chunk is
+# the unit of COPYING, and the products take the smallest row window
+# (this many rows, doubled up to the chunk) that holds the pages copied
+# into it, so a slot with a page or two pays for 128 rows and not for
+# the chunk's.  One product a window, chosen by a switch: a LOOP over
+# row tiles costs some 0.2 us an iteration in latency (Mellum 2's
+# 1024-row chunks in tiles of 128: 3.6 ms a tick where the switch
+# takes 2.5; PERF.md section 6, PR 41).  128 rows fill the MXU's
+# columns once; fewer would save no pass of it.
+_TILE_ROWS = 128
 _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 
 
@@ -88,9 +114,10 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
     The kernel sees a pool ROW (`kv_width`: the K/V heads side by side,
     `d_model` under plain multi-head attention), a page of `block_size`
     rows and the pool's dtype.  `n_heads`, `d_head`, `ringed` and
-    `max_blocks_per_seq` change nothing it refuses: the query comes
-    block-diagonal by K/V head, a ring is a table, and the scratch is
-    two chunks whatever the context."""
+    `max_blocks_per_seq` change nothing it refuses: the kernel lays
+    the query out block-diagonal by K/V head whatever the heads, a
+    ring is a table, and the scratch is two chunks whatever the
+    context."""
     del n_heads, d_head, ringed, max_blocks_per_seq
     if platform != "tpu" and not interpret:
         return "not_tpu"
@@ -109,16 +136,52 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
     return None
 
 
-def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
-            o_ref, k_buf, v_buf, sems, cursor_ref, *, bs, nb, pages,
-            scale):
+def _windows(pages: int, tile: int) -> Tuple[int, ...]:
+    """The row windows (in pages) a chunk of `pages` pages is
+    multiplied over: the tile doubled up to the chunk, the chunk
+    last."""
+    sizes = []
+    while tile < pages:
+        sizes.append(tile)
+        tile *= 2
+    return tuple(sizes) + (pages,)
+
+
+def rows_multiplied(n_pages, pages: int, tile: int, block_size: int):
+    """Rows of K (and of V) the kernel's two products run over for a
+    slot of `n_pages` pages (an integer or an integer array): for each
+    chunk of `pages` pages the smallest window of `_windows(pages,
+    tile)` that holds the pages copied into it."""
+    whole, rest = n_pages // pages, n_pages % pages
+    last, smaller = 0, 0
+    for window in _windows(pages, tile):
+        last = last + (window - smaller) * (rest > smaller)
+        smaller = window
+    return (whole * pages + last) * block_size
+
+
+def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
+            windows, scale, h, dh, n_kv, writes):
     """Grid step s: slot s's attention over its first
-    `ceil(lengths[s] / bs)` pages of layer `layer[0]`, a chunk of
-    `pages` pages at a time.  `cursor_ref[0]` is the buffer (0 or 1)
-    that holds this slot's first chunk, started by the step before."""
+    `ceil(lengths[s] / bs)` pages of layer `layer[0]`, copied a chunk
+    of `pages` pages at a time and multiplied over the smallest of
+    `windows` (pages, static) that the copied pages fill.
+    `cursor_ref[0]` is the buffer (0 or 1) that holds this slot's
+    first chunk, started by the step before."""
+    if writes:
+        (wrow_ref, q_ref, k_new_ref, v_new_ref, k_hbm, v_hbm, o_ref, k_out,
+         v_out, k_buf, v_buf, acc_ref, sems, cursor_ref, wsems) = refs
+    else:
+        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, acc_ref, sems,
+         cursor_ref) = refs
     s, n_slots = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     rows = pages * bs
+    d_kv = n_kv * dh
+    group = h // n_kv
+    # the rows a written row goes back to the pool with: a sublane
+    # tile where a page is whole tiles, else the page
+    group_rows = 8 if bs % 8 == 0 else bs
 
     def n_pages(slot):
         return (lengths_ref[slot] + bs - 1) // bs
@@ -147,7 +210,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
     @pl.when(s == 0)
     def _first_slot():
         cursor_ref[0] = 0
-        # rows of a chunk no page was copied into weigh 0 in `p . V`:
+        # rows of a window no page was copied into weigh 0 in `p . V`:
         # they must be finite, which VMEM as it comes is not
         v_buf[...] = jnp.zeros_like(v_buf)
         start(0, 0, 0)
@@ -155,11 +218,70 @@ def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
     first_buf = cursor_ref[0]
     length = lengths_ref[s]
     n_chunks = (n_pages(s) + pages - 1) // pages
-    q = q_ref[0]                                            # [H, Dkv]
-    o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    # The block-diagonal operand [H, Dkv]: query head i's d_head
+    # columns in ITS K/V head's columns of a pool row, exact zeros
+    # outside them (`_block_diagonal` of the decoder, built here).
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    kv_head = iota((h, 1), 0)
+    if group > 1:
+        kv_head = jax.lax.div(kv_head, group)
+    col = iota((h, d_kv), 1)
+    own = (col >= kv_head * dh) & (col < kv_head * dh + dh)
+    if group == 1:
+        # q_ref[0] is the projection's row [1, H*dh]: a head's columns
+        # of it ARE its columns of a pool row
+        q_wide = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (h, d_kv))
+    else:
+        # q_ref[0] is [H, dh]: a head's columns under every K/V head
+        q_wide = jnp.concatenate(
+            [q_ref[0].astype(jnp.float32)] * n_kv, axis=1)
+    q = jnp.where(own, q_wide, 0.0).astype(k_buf.dtype)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def row_copies(c, buf):
+        """This position's K and V on their way back to the pool: the
+        `group_rows` rows of the chunk in buffer `buf` that hold row
+        `wrow_ref[s]` of the slot's table, to their place in its
+        page."""
+        at = wrow_ref[s] - c * rows
+        first = pl.multiple_of(at // group_rows * group_rows, group_rows)
+        blk = tables_ref[s * nb + c * pages + at // bs]
+        src = pl.ds(first, group_rows)
+        dst = pl.ds(pl.multiple_of(first % bs, group_rows), group_rows)
+        return (pltpu.make_async_copy(k_buf.at[buf, src],
+                                      k_out.at[layer, blk, dst],
+                                      wsems.at[0]),
+                pltpu.make_async_copy(v_buf.at[buf, src],
+                                      v_out.at[layer, blk, dst],
+                                      wsems.at[1]))
+
+    def put_row(c, buf):
+        """Where chunk c holds the row this tick writes (`wrow_ref[s]`
+        of the slot's table; negative: a slot that writes nothing),
+        put this position's K and V into its page as it lies in VMEM,
+        before the products read it, and start the page's rows back to
+        the pool.  -> whether it did."""
+        wrow = wrow_ref[s]
+        here = (wrow >= c * rows) & (wrow < (c + 1) * rows)
+
+        @pl.when(here)
+        def _put():
+            at = wrow - c * rows
+            page = pl.ds(pl.multiple_of(at // bs * bs, bs), bs)
+            mine = iota((bs, 1), 0) == at % bs
+            k_buf[buf, page] = jnp.where(mine, k_new_ref[0],
+                                         k_buf[buf, page])
+            v_buf[buf, page] = jnp.where(mine, v_new_ref[0],
+                                         v_buf[buf, page])
+            for copy in row_copies(c, buf):
+                copy.start()
+
+        return here
 
     def chunk(c, carry):
-        m, l = carry
         buf = (first_buf + c) % 2
 
         @pl.when(c + 1 < n_chunks)
@@ -171,71 +293,139 @@ def _kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
             start(s + 1, 0, 1 - buf)
 
         each_page(s, c, buf, lambda copy: copy.wait())
-        sc = jax.lax.dot_general(
-            q, k_buf[buf], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [H, rows]
-        row = c * rows + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(row < length, sc, -jnp.inf)
-        m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(sc - m_new)
-        v = v_buf[buf]
-        o_ref[0] = alpha * o_ref[0] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        if writes:
+            written = put_row(c, buf)
 
-    h = q.shape[0]
+        def over(n_rows):
+            """The online softmax over the chunk's first `n_rows`
+            rows (static)."""
+            def multiply(carry):
+                m, l = carry
+                sc = jax.lax.dot_general(
+                    q, k_buf[buf, :n_rows], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
+                sc = jnp.where(row < length, sc, -jnp.inf)
+                m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(sc - m_new)
+                v = v_buf[buf, :n_rows]
+                acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, alpha * l + jnp.sum(p, axis=1,
+                                                  keepdims=True)
+            return multiply
+
+        # the smallest window the pages copied into this chunk fill
+        copied = jnp.minimum(pages, n_pages(s) - c * pages)
+        carry = jax.lax.switch(
+            sum((copied > w).astype(jnp.int32) for w in windows[:-1]),
+            [over(w * bs) for w in windows], carry)
+        if writes:
+            # the row's way back to the pool lay under the products
+            @pl.when(written)
+            def _row_is_back():
+                for copy in row_copies(c, buf):
+                    copy.wait()
+        return carry
+
     _, l = jax.lax.fori_loop(
         0, n_chunks, chunk, (jnp.full((h, 1), -jnp.inf, jnp.float32),
                              jnp.zeros((h, 1), jnp.float32)))
-    o_ref[0] = o_ref[0] / l
+    # of all Dkv columns a head keeps its K/V head's
+    # (`_own_columns` of the decoder)
+    ctx = acc_ref[...] / l
+    if group == 1:
+        o_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0,
+                           keepdims=True)
+    else:
+        o_ref[0] = sum(
+            jnp.where(kv_head == g, ctx[:, g * dh:(g + 1) * dh], 0.0)
+            for g in range(n_kv))
     cursor_ref[0] = (first_buf + n_chunks) % 2
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "pages",
-                                             "interpret"))
-def paged_attention(q_bd, pool_k, pool_v, tables, lengths, layer, *,
-                    scale: float, pages: int, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "pages", "tile", "n_heads", "d_head", "interpret"))
+def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
+                    scale: float, pages: int, tile: int, n_heads: int,
+                    d_head: int, interpret: bool = False, write=None):
     """Attention of one query position a slot over a paged pool.
 
-    q_bd [S, H, Dkv] in the pools' dtype (a query head's columns in
-    its K/V head's columns of a pool row, zero outside them), pools
-    [layers, blocks, block_size, Dkv], tables [S, NB] int32 block ids,
-    lengths [S] int32 (rows of its table, in table order, that slot s
-    attends over: at least 1, and no page past `ceil(length /
-    block_size)` is read), layer an int32 scalar, traced.  Returns
-    [S, H, Dkv] float32: `softmax(scale * q_bd . K^T) . V` over all
-    Dkv columns, of which a head keeps its own outside."""
-    s_n, h, d_kv = q_bd.shape
-    bs, nb = pool_k.shape[2], tables.shape[1]
+    q [S, H*dh] (the projection's rows; cast to the pools' dtype: what
+    the MXU rounds it to on the XLA path too), pools [layers, blocks,
+    block_size, Dkv] with Dkv = n_kv * dh and H a multiple of n_kv,
+    tables [S, NB] int32 block ids, lengths [S] int32 (rows of its
+    table, in table order, that slot s attends over: at least 1, and
+    no page past `ceil(length / block_size)` is read), layer an int32
+    scalar, traced.  A chunk is `pages` pages, its smallest row window
+    `tile` of them.  Returns [S, H*dh] float32: head i's
+    `softmax(scale * q_i . K_g^T) . V_g` over its K/V head g.
+
+    `write` = (k [S, Dkv], v [S, Dkv], rows [S] int32): this
+    position's K and V, written by the kernel itself at row `rows[s]`
+    of slot s's table (under its length; negative: nothing is written)
+    into the page as it lies in VMEM before the products read it, and
+    from there back into the pools, which are then RETURNED beside the
+    result, (out, pool_k, pool_v), the same buffers where the caller
+    donates them: no scatter runs before the kernel."""
+    s_n, h, dh = q.shape[0], n_heads, d_head
+    bs, nb, d_kv = pool_k.shape[2], tables.shape[1], pool_k.shape[3]
+    n_kv = d_kv // dh
+    # under plain multi-head attention the kernel takes a slot's query
+    # and gives its result as ONE row, under grouped heads a row a head
+    block = (1, 1, h * dh) if n_kv == h else (1, h, dh)
 
     def slot(s, *_):
         return (s, 0, 0)
 
-    return pl.pallas_call(
+    def hbm():
+        return pl.BlockSpec(memory_space=pl.ANY)
+
+    scalars = [tables.reshape(-1).astype(jnp.int32),
+               jnp.maximum(lengths.astype(jnp.int32), 1),
+               jnp.asarray(layer, jnp.int32).reshape(1)]
+    inputs = [q.astype(pool_k.dtype).reshape((s_n,) + block[1:])]
+    in_specs = [pl.BlockSpec(block, slot)]
+    out_specs = [pl.BlockSpec(block, slot)]
+    out_shape = [jax.ShapeDtypeStruct((s_n,) + block[1:], jnp.float32)]
+    scratch = [pltpu.VMEM((2, pages * bs, d_kv), pool_k.dtype),
+               pltpu.VMEM((2, pages * bs, d_kv), pool_v.dtype),
+               pltpu.VMEM((h, d_kv), jnp.float32),
+               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]
+    aliases = {}
+    if write is not None:
+        k_new, v_new, rows = write
+        scalars.append(rows.astype(jnp.int32))
+        for new, pool in ((k_new, pool_k), (v_new, pool_v)):
+            inputs.append(new.astype(pool.dtype).reshape(s_n, 1, d_kv))
+            in_specs.append(pl.BlockSpec((1, 1, d_kv), slot))
+            out_specs.append(hbm())
+            out_shape.append(jax.ShapeDtypeStruct(pool.shape, pool.dtype))
+        # operands are numbered with the scalars: the pools come last
+        aliases = {len(scalars) + len(inputs): 1,
+                   len(scalars) + len(inputs) + 1: 2}
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+    out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, nb=nb, pages=pages,
-                          scale=scale),
+                          windows=_windows(pages, tile), scale=scale,
+                          h=h, dh=dh, n_kv=n_kv,
+                          writes=write is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(s_n,),
-            in_specs=[pl.BlockSpec((1, h, d_kv), slot),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, h, d_kv), slot),
-            scratch_shapes=[
-                pltpu.VMEM((2, pages * bs, d_kv), pool_k.dtype),
-                pltpu.VMEM((2, pages * bs, d_kv), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((s_n, h, d_kv), jnp.float32),
+            num_scalar_prefetch=len(scalars), grid=(s_n,),
+            in_specs=in_specs + [hbm(), hbm()], out_specs=out_specs,
+            scratch_shapes=scratch),
+        out_shape=out_shape, input_output_aliases=aliases,
         # a slot's first chunk is started by the slot before it
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_attention",
-    )(tables.reshape(-1).astype(jnp.int32),
-      jnp.maximum(lengths.astype(jnp.int32), 1),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_bd, pool_k, pool_v)
+    )(*scalars, *inputs, pool_k, pool_v)
+    ctx = out[0].reshape(s_n, h * dh)
+    return ctx if write is None else (ctx, out[1], out[2])
 
 
 def select_paged_attention(
@@ -249,8 +439,10 @@ def select_paged_attention(
     gather path.  A function of the pool's geometry, its dtype and the
     platform alone; it touches no array and runs nothing.
 
-    attend(q_bd, pool_k, pool_v, tables, lengths, layer, scale):
-    `paged_attention` with the chunk chosen from a page's bytes."""
+    attend(q, pool_k, pool_v, tables, lengths, layer, scale):
+    `paged_attention` at this geometry's heads, with the chunk and the
+    row tile chosen from a page's bytes and the table's (the ring's)
+    pages: `attend.tiling(table_pages)` says which."""
     reason = paged_attention_supports(
         d_model=d_model, n_heads=n_heads, block_size=block_size,
         max_blocks_per_seq=max_blocks_per_seq, kv_dtype=kv_dtype,
@@ -260,13 +452,25 @@ def select_paged_attention(
         return None, reason
     page_bytes = (int(block_size) * int(kv_width or d_model)
                   * jnp.dtype(_KV_DTYPES[kv_dtype]).itemsize)
-    pages = max(1, _CHUNK_BYTES // page_bytes)
+    chunk = max(1, _CHUNK_BYTES // page_bytes)
+    row_tile = max(1, _TILE_ROWS // int(block_size))
 
-    def attend(q_bd, pool_k, pool_v, tables, lengths, layer, scale):
-        # a chunk holds no more pages than the table (the ring) has
+    def tiling(table_pages):
+        """(pages a chunk, pages a row tile) over slots that hold
+        `table_pages` pages (a table's, a ring's): a chunk no longer
+        than the table, a tile no longer than the chunk."""
+        pages = min(chunk, int(table_pages))
+        return pages, min(row_tile, pages)
+
+    def attend(q, pool_k, pool_v, tables, lengths, layer, scale,
+               write=None):
+        pages, tile = tiling(tables.shape[1])
         return paged_attention(
-            q_bd, pool_k, pool_v, tables, lengths, layer,
-            scale=float(scale), pages=min(pages, tables.shape[1]),
-            interpret=interpret)
+            q, pool_k, pool_v, tables, lengths, layer,
+            scale=float(scale), pages=pages, tile=tile,
+            n_heads=int(n_heads),
+            d_head=int(d_head or d_model // n_heads),
+            interpret=interpret, write=write)
 
+    attend.tiling = tiling
     return attend, None

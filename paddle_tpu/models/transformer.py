@@ -1080,19 +1080,24 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 preferred_element_type=jnp.float32)
             return _own_columns(ctx, w_n)
 
-    def _streamed(q, pool_k, pool_v, l, tables, lengths, kind):
-        """`_attention` of one query position a slot, q [S, H*dh],
-        through the Pallas kernel: slot s attends over the first
-        `lengths[s]` rows of its table (its ring, on a sliding layer)
-        and the kernel reads those pages from the pool and no other;
-        no logical-order copy exists.  The arithmetic is
-        `_attention`'s, an online softmax over chunks of pages in
-        place of one softmax over the table."""
+    def _streamed(q, kk, vv, pool_k, pool_v, l, tables, cursor, kind):
+        """`_write` and `_attention` of one position a slot, q
+        [S, H*dh], through the Pallas kernel -> (context, pools):
+        `cursor` is (the row of its table, its ring on a sliding
+        layer, that slot s's K and V of this position go to, negative
+        for a slot that writes nothing; the rows `lengths[s]` it then
+        attends over).  The kernel reads those pages from the pool
+        and no other, puts the new row into its page in VMEM and
+        sends it back to the pool from there: no scatter and no
+        logical-order copy exists.  The arithmetic is
+        `_attention`'s (the kernel builds `_block_diagonal`'s operand
+        and keeps `_own_columns`' columns itself, in VMEM), an online
+        softmax over chunks of pages in place of one softmax over the
+        table."""
+        row, lengths = cursor
         with _kind_scope("attention", kind):
-            q_bd = _block_diagonal(q[:, None, :]).astype(pool_k.dtype)
-            ctx = _attend(q_bd, pool_k, pool_v, tables, lengths, l,
-                          scale)
-            return _own_columns(ctx, 1)[:, 0]
+            return _attend(q, pool_k, pool_v, tables, lengths, l, scale,
+                           write=(kk, vv, row))
 
     def _by_kind(x):
         """A pool or the tables as the step is given them, by layer
@@ -1138,8 +1143,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         rows = (nw if kind == lm_block.SLIDING else nb) * bs
         with _kind_scope("attention", kind):
             if _attend is not None:
-                return jnp.minimum(jnp.where(active, positions + 1, 1),
-                                   rows)
+                # and the row the kernel writes this position's K and
+                # V to: on a table the position, on a ring where
+                # `_ring_block` puts it; nothing for a slot with no
+                # sequence
+                return (jnp.where(active, positions % rows, -1),
+                        jnp.minimum(jnp.where(active, positions + 1, 1),
+                                    rows))
             c = positions[:, None]
             if kind != lm_block.SLIDING:
                 return jnp.arange(rows)[None, :] <= c
@@ -1188,15 +1198,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 q, kk, vv = _qkv(g, lay, x, rot[kind])
                 wb, seen = cursor[kind]
                 plane = li if plane0 is None else plane0 + li
-                with scope("kv_write"):
-                    pools_k[kind] = _write(pools_k[kind], plane, wb, wi,
-                                           kk)
-                    pools_v[kind] = _write(pools_v[kind], plane, wb, wi,
-                                           vv)
                 if _attend is not None:
-                    ctx_av = _streamed(q, pools_k[kind], pools_v[kind],
-                                       plane, tabs[kind], seen, kind)
+                    ctx_av, pools_k[kind], pools_v[kind] = _streamed(
+                        q, kk, vv, pools_k[kind], pools_v[kind], plane,
+                        tabs[kind], seen, kind)
                 else:
+                    with scope("kv_write"):
+                        pools_k[kind] = _write(pools_k[kind], plane, wb,
+                                               wi, kk)
+                        pools_v[kind] = _write(pools_v[kind], plane, wb,
+                                               wi, vv)
                     ctx_av = _attention(
                         q[:, None, :], pools_k[kind], pools_v[kind],
                         plane, tabs[kind], seen[:, None, :], kind)[:, 0]
@@ -1523,6 +1534,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                  "pallas" if _attend is not None else f"xla:{_refused}",
                  "paged_attention_window":
                  f"xla:{_refused or 'window_rows'}"},
+        # (pages a chunk, pages of its smallest row window) of the
+        # kernel over a slot's table and over its ring: what it copies
+        # and multiplies in (`kernels.paged_attention.rows_multiplied`);
+        # None on the gather path, and for a ring where there is none
+        attention_tiling=(
+            (_attend.tiling(nb), _attend.tiling(nw) if nw else None)
+            if _attend is not None else None),
         # what the expert layer of the step traced last runs: the
         # Pallas grouped matmul's name, or "xla:<reason>" where
         # `ragged_dot` does; None until a step is traced (the weights'

@@ -81,6 +81,7 @@ import numpy as np
 from .. import profiler
 from ..core.resilience import (fault_injector,
                                sched_fault_armed as _sched_fault)
+from ..kernels.paged_attention import rows_multiplied
 from ..observability import attribution as obs_attr
 from ..observability import flightrecorder
 from ..observability import metrics as obs_metrics
@@ -626,6 +627,9 @@ class GenerationServer:
             + self._kv_layers[1] * ring)
         self._kv_streamed = getattr(decoder, "kernels", {}).get(
             "paged_attention_decode") == "pallas"
+        # (pages a chunk, pages a row tile) the kernel copies and
+        # multiplies in, over a table and over a ring
+        self._kv_tiling = getattr(decoder, "attention_tiling", None)
         # Mamba layers: a lane's recurrent state rides in the pools
         self._stateful = bool(stateful)
         # a looped stack: the passes a tick's step runs (1: a plain one)
@@ -1264,7 +1268,12 @@ class GenerationServer:
         through the Pallas kernel (`decoder.kernels`; never a
         `step_window` tick: `window`) those are the pages each cursor
         has reached, and one a layer for a slot with no sequence; on
-        the gather path every page.
+        the gather path every page.  `kv_rows_multiplied`: the K/V
+        rows the step's two products run over, summed the same way:
+        through the kernel, for each chunk of a slot's pages, the
+        smallest row window that holds them (the tile of
+        `decoder.attention_tiling` doubled up to the chunk), on the
+        gather path every row of the table.
         With sliding layers also `past_window` (slots whose
         cursor is at or past the window: their rings have wrapped) and
         the K/V rows the tick has to attend over on a layer of each
@@ -1290,18 +1299,26 @@ class GenerationServer:
             attrs["loop_passes"] = self._passes
             attrs["kv_planes"] = self._kv_layers[0]
         rows = cur.astype(np.int64) + 1      # K/V rows a slot attends
+        bs = self._cache.block_size
         read = self._kv_pages_table
+        multiplied = read * bs
         if self._kv_streamed and not window:
-            bs = self._cache.block_size
             full, win = self._kv_layers
-            read = ((self._slots - n) * (full + win)
-                    + full * int((-(-rows // bs)).sum()))
+            idle = self._slots - n           # a page each, a layer
+            read = multiplied = 0
+            kinds = [(full, -(-rows // bs), self._kv_tiling[0])]
             if win:
                 ring_rows = self._rings.shape[1] * bs
-                read += win * int(
-                    (-(-np.minimum(rows, ring_rows) // bs)).sum())
+                kinds.append((win, -(-np.minimum(rows, ring_rows) // bs),
+                              self._kv_tiling[1]))
+            for layers, pages, (chunk, tile) in kinds:
+                read += layers * (idle + int(pages.sum()))
+                multiplied += layers * int(
+                    idle * rows_multiplied(1, chunk, tile, bs)
+                    + rows_multiplied(pages, chunk, tile, bs).sum())
         attrs["kv_pages_read"] = read
         attrs["kv_pages_table"] = self._kv_pages_table
+        attrs["kv_rows_multiplied"] = multiplied
         if self._window:
             attrs["past_window"] = int((rows > self._window).sum())
             attrs["kv_rows_full"] = int(rows.sum())

@@ -955,6 +955,14 @@ def test_cli_analyze_generation_model_dir(tmp_path, capsys):
     assert kernel["pages_read"] == 4 * 2 * 4
     assert gather["bytes"] == 3 * gather["pool_bytes"]
     assert kernel["pool_bytes"] == gather["pool_bytes"] / 2
+    # beside its pages the kernel moves a query and a result at the
+    # heads' own width: [slots, d_model] in the pool's dtype read, and
+    # float32 written and read, a layer (n_heads times as much when the
+    # block-diagonal operand was built outside it)
+    assert kernel["query_result_bytes"] == 4 * 2 * 32 * (4 + 2 * 4)
+    assert kernel["bytes"] == (kernel["pool_bytes"]
+                               + kernel["query_result_bytes"])
+    assert kernel["shapes"]["query"] == "[4, 32]"
     assert analysis.serving_kernel_cost(
         "paged_attention_gather", spec, slots=4,
         context=1)["bytes"] == gather["bytes"]

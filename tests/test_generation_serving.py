@@ -1113,6 +1113,8 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     srv = GenerationServer(dec, states, slots=3, kv_blocks=18,
                            place=fluid.CPUPlace(), prefix_cache=False)
     srv._kv_streamed = streamed
+    # row tiles of a page: the products then run over the pages read
+    srv._kv_tiling = ((2, 1), (2, 1))
     want = []
     tick = srv._tick
     srv._tick = lambda seqs: (want.append(
@@ -1130,10 +1132,13 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     # what PR 38 added beside the old walks' counts
     loop = {"loop_passes": 3, "kv_planes": 6} if kind == "loop" else {}
     # and PR 40: the layers with experts, on a block that has any
-    extra = ({"ahead", "kv_wait", "moe_layers"} | set(loop)
-             | set(getattr(dec, "step_counters", ())))
+    # and PR 41: the rows the attention's products run over
+    extra = ({"ahead", "kv_wait", "moe_layers", "kv_rows_multiplied"}
+             | set(loop) | set(getattr(dec, "step_counters", ())))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old
+        assert got["kv_rows_multiplied"] == (
+            got["kv_pages_read"] * srv._cache.block_size)
         assert all(type(v) is int for v in got.values())
         assert got["kv_wait"] == 0
         assert {k: got[k] for k in loop} == loop

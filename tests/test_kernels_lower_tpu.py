@@ -29,9 +29,10 @@ from paddle_tpu.kernels.paged_attention import select_paged_attention
 MOSAIC_CALL = "tpu_custom_call"
 
 # name: slots, query heads, pool row, page rows, table pages, layers,
-# pool dtype, scale.  chip_smoke.py's serving leg (d_model 1024, 8
-# heads of 128, 8 slots) and the four serving cells' tables and ring at
-# their real sizes (perf/configs, perf/traffic)
+# pool dtype (a head is 128 wide but for OPT's 64: the pool row over
+# its K/V heads).  chip_smoke.py's serving leg (d_model 1024, 8 heads
+# of 128, 8 slots) and the six serving cells' tables and rings at their
+# real sizes (perf/configs, perf/traffic)
 POOLS = {
     "chip_smoke-fp32": (8, 8, 1024, 16, 32, 2, "fp32"),
     "chip_smoke-bf16": (8, 8, 1024, 16, 32, 2, "bf16"),
@@ -40,7 +41,12 @@ POOLS = {
     "mellum2-agent96-table": (96, 32, 512, 16, 256, 2, "bf16"),
     "mellum2-agent96-ring": (96, 32, 512, 16, 64, 6, "bf16"),
     "granite-chat64": (64, 32, 1024, 16, 64, 1, "bf16"),
+    # a plane for every (pass, layer) pair of the looped stack
+    "ouro-chat12": (12, 16, 2048, 16, 64, 192, "bf16"),
+    "k-exaone-chat64-table": (64, 64, 1024, 16, 64, 2, "bf16"),
+    "k-exaone-chat64-ring": (64, 64, 1024, 16, 8, 6, "bf16"),
 }
+D_HEAD = {"opt-1.3b-closed32": 64}
 
 
 def lower_tpu(f, *args):
@@ -52,26 +58,46 @@ def _paged(name, sharding=None):
     """(a function that attends over every layer of pool `name`
     through the selected kernel, its arguments as shapes)."""
     s_n, h, d_kv, bs, nb, layers, kv_dtype = POOLS[name]
+    d_head = D_HEAD.get(name, 128)
     kern, reason = select_paged_attention(
-        d_model=d_kv, n_heads=h, block_size=bs, max_blocks_per_seq=nb,
-        kv_dtype=kv_dtype, platform="tpu")
+        d_model=h * d_head, n_heads=h, d_head=d_head, kv_width=d_kv,
+        block_size=bs, max_blocks_per_seq=nb, kv_dtype=kv_dtype,
+        platform="tpu")
     assert reason is None
     dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
 
     def shape(dims, dt):
         return jax.ShapeDtypeStruct(dims, dt, sharding=sharding)
 
-    pool = shape((layers, s_n * nb + 1, bs, d_kv), dtype)
+    # Ouro's planes at 12 slots would be 19 GB of pool: the planes its
+    # 288 blocks hold
+    blocks = min(s_n * nb, 288) + 1
+    pool = shape((layers, blocks, bs, d_kv), dtype)
 
     def attend(q, pool_k, pool_v, tables, lengths):
-        # layer upon layer, as a step's are: each query from the last
+        # layer upon layer, as a step's are (a looped stack's planes
+        # under a scan, the layer traced): each query from the last
         # layer's result
-        for layer in range(layers):
-            out = kern(q, pool_k, pool_v, tables, lengths, layer, 0.125)
-            q = (q + out).astype(dtype)
-        return out
+        # and this position's K and V written by the kernel, into
+        # pools that pass from layer to layer as the step's do
+        kv = jnp.zeros((s_n, d_kv), dtype)
 
-    return attend, (shape((s_n, h, d_kv), dtype), pool, pool,
+        def layer(carry, l):
+            q, pool_k, pool_v = carry
+            out, pool_k, pool_v = kern(
+                q, pool_k, pool_v, tables, lengths, l, 0.125,
+                write=(kv, kv, lengths - 1))
+            return ((q + out).astype(dtype), pool_k, pool_v), out
+
+        carry = (q, pool_k, pool_v)
+        if layers > 24:
+            carry, outs = jax.lax.scan(layer, carry, jnp.arange(layers))
+            return outs[-1], carry[1], carry[2]
+        for l in range(layers):
+            carry, out = layer(carry, l)
+        return out, carry[1], carry[2]
+
+    return attend, (shape((s_n, h * d_head), dtype), pool, pool,
                     shape((s_n, nb), jnp.int32), shape((s_n,), jnp.int32))
 
 
@@ -108,11 +134,16 @@ def test_paged_attention_compiles_for_a_v5e(name, one_v5e):
     and the next one's query lives outside the kernel: no
     logical-order copy of a pool."""
     attend, args = _paged(name, one_v5e)
-    compiled = jax.jit(attend).lower(*args).compile()
+    # the pools donated, as the step's are: the kernel writes in place
+    compiled = jax.jit(attend, donate_argnums=(1, 2)).lower(
+        *args).compile()
     assert MOSAIC_CALL in compiled.as_text()
-    s_n, h, d_kv = args[0].shape
+    # a query and a result at the heads' own width: where the
+    # block-diagonal operand lived outside the kernel this bound was
+    # n_kv times as wide
+    s_n, width = args[0].shape
     assert compiled.memory_analysis().temp_size_in_bytes <= \
-        4 * s_n * h * d_kv * 4
+        4 * s_n * width * 4
 
 
 @pytest.mark.parametrize("shape,dtype", [

@@ -9,8 +9,9 @@ Pins two contracts:
     TPU interpreter on the CPU: async copies, semaphores and all) equal
     the XLA gather path's to a stated float32 tolerance at the cursors
     that break such kernels, it never reads a page past a slot's
-    cursor, greedy and sampled streams agree, and speculative verify
-    (`step_window`) keeps the gather path beside it;
+    cursor, the K and V it writes for this position are bit-equal to
+    a scatter's, greedy and sampled streams agree, and speculative
+    verify (`step_window`) keeps the gather path beside it;
   * which of the two runs is a function of the pool's geometry, its
     dtype and the platform the decoder is built for, and of nothing
     else: a refused pool returns None with its reason,
@@ -36,12 +37,13 @@ _DECODERS = {}
 
 
 @contextlib.contextmanager
-def _interpreted(chunk_bytes=None):
+def _interpreted(chunk_bytes=None, tile_rows=None):
     """Inside this context `build_lm_paged_decoder`'s one call of
     `select_paged_attention` asks for the Pallas interpreter: the entry
     point's own argument for tests.  `chunk_bytes` makes a chunk that
     small (toy pages are a few hundred bytes: a chunk the module's own
-    size holds a whole table), so a slot's pages come in several."""
+    size holds a whole table), so a slot's pages come in several, and
+    `tile_rows` a row tile that short, so a chunk comes in several."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(paged_attention, "select_paged_attention",
                    functools.partial(
@@ -49,6 +51,8 @@ def _interpreted(chunk_bytes=None):
                        interpret=True))
         if chunk_bytes is not None:
             mp.setattr(paged_attention, "_CHUNK_BYTES", chunk_bytes)
+        if tile_rows is not None:
+            mp.setattr(paged_attention, "_TILE_ROWS", tile_rows)
         yield
 
 
@@ -112,10 +116,13 @@ def _serve(dec, states, prompts, max_news, **kw):
 # ---------------------------------------------------------------------------
 
 # The four serving cells' attention geometries at rehearsal sizes: pages
-# of 16 positions as the cells have them, a table of 4 (a context of
-# 64), and where the block has sliding layers a ring of 2 (a window of
-# 32).
-BS, NB, WINDOW = 16, 4, 32
+# of 16 positions as the cells have them, a table of 8 (a context of
+# 128) that the kernel copies in chunks of 4 pages and multiplies over
+# the chunk's first 2 (`TILE` rows) or all 4, and where the block has
+# sliding layers a ring of 2 (a window of 32: one chunk of one row
+# window, as K-EXAONE's ring is).
+BS, NB, WINDOW = 16, 8, 32
+CHUNK_PAGES, TILE = 4, 32
 
 
 def _geometry(name):
@@ -154,9 +161,13 @@ def _geometry(name):
 
 
 # what a slot's cursor does to such a kernel: the first row of all, the
-# last row of a page and the first of the next, the ring's last row
-# before its first wrap and the first after, the table's last row
-CURSORS = [0, 15, 16, WINDOW - 1, WINDOW, BS * NB - 1]
+# last row of a page and the first of the next, the last row of a row
+# tile (which is the ring's last before its first wrap) and the first
+# of the next tile (the first after the wrap), the last row of a chunk
+# and a row in the second chunk's first page, the table's last row
+CURSORS = [0, 15, 16, TILE - 1, TILE, CHUNK_PAGES * BS - 1,
+           CHUNK_PAGES * BS + 5, BS * NB - 1]
+assert (WINDOW, CURSORS[3], CURSORS[4]) == (TILE, WINDOW - 1, WINDOW)
 # and beside them a slot with no sequence (None), and one in mid-page
 SLOTS = CURSORS + [None, 20]
 
@@ -187,18 +198,25 @@ def _kernel_against_gather(name, kv_dtype):
     geo = _geometry(name)
     s_n = len(SLOTS)
 
-    def build(chunk_bytes=None):
-        with (_interpreted(chunk_bytes) if chunk_bytes
-              else contextlib.nullcontext()):
+    def build(interpreted):
+        with interpreted:
             fw.reset_unique_names()
             return build_lm_paged_decoder(
                 V, BS, NB, kv_dtype=kv_dtype, platform="cpu", **geo)[1]
 
-    dec_x = build()
-    # a chunk of 2 pages (a block's K and V over a layer are as many
-    # bytes): a table comes in two chunks, a ring in one
-    dec_p = build(dec_x.bytes_per_block // dec_x.table_layers)
+    dec_x = build(contextlib.nullcontext())
+    # a chunk of 4 pages (a block's K and V over a layer are 2 pages'
+    # bytes of K) with row windows of 2 pages and of 4: a table comes in
+    # two chunks, a ring in one of one window
+    dec_p = build(_interpreted(
+        CHUNK_PAGES * dec_x.bytes_per_block // (2 * dec_x.table_layers),
+        TILE))
     assert dec_p.kernels["paged_attention_decode"] == "pallas"
+    assert dec_p.attention_tiling == (
+        (CHUNK_PAGES, TILE // BS),
+        (WINDOW // BS, TILE // BS) if dec_x.window_blocks_per_seq
+        else None)
+    assert dec_x.attention_tiling is None
     assert dec_x.kernels["paged_attention_decode"] == "xla:not_tpu"
     r = np.random.RandomState(7)
     g = {n: jnp.asarray((1.0 if "scale" in n or "layer_norm" in n else 0.0)
@@ -207,7 +225,7 @@ def _kernel_against_gather(name, kv_dtype):
 
     ring = dec_x.window_blocks_per_seq
     clean = 1 + s_n * NB                  # table blocks 1 .. s_n * NB
-    stale = np.arange(clean, clean + 4)   # and four no sequence owns
+    stale = np.arange(clean, clean + NB)  # and NB no sequence owns
     tables = np.zeros((s_n, NB), np.int32)
     positions = np.zeros(s_n, np.int32)
     active = np.zeros(s_n, bool)
@@ -275,6 +293,52 @@ def test_kernel_equals_the_gather_path_and_reads_no_stale_page(
     assert np.isfinite(got).all() and np.isfinite(want).all()
     err = np.max(np.abs(got - want)) / np.max(np.abs(want))
     assert err <= (TOL_FP32 if kv_dtype == "fp32" else TOL_BF16), err
+
+
+@pytest.mark.parametrize("dtype,h,dh,n_kv,bs,nb", [
+    ("float32", 4, 8, 4, 16, 8), ("bfloat16", 4, 8, 4, 16, 8),
+    ("bfloat16", 4, 16, 2, 16, 8), ("float32", 2, 16, 2, 4, 8),
+], ids=["fp32-mha", "bf16-mha", "bf16-grouped", "fp32-pages-of-4"])
+def test_kernel_writes_the_row_a_scatter_would(dtype, h, dh, n_kv, bs, nb):
+    """`write=`: the kernel's result and its pools are BIT-equal to a
+    scatter of this position's K and V followed by the kernel, at rows
+    on a page's, a sublane tile's and a chunk's edges, layer after
+    layer over the pools it returned; a slot with a negative row
+    writes nothing and nothing else of the pools moves (the scatter
+    path puts such a slot's row into the null block 0)."""
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(0)
+    dtype, d_kv, layers = jnp.dtype(dtype), n_kv * dh, 2
+    pos = np.array([0, bs - 1, bs, 4 * bs - 1, 4 * bs + 5, nb * bs - 1, 7])
+    active = np.array([1, 1, 1, 0, 1, 1, 1], bool)
+    s_n = len(pos)
+    pool_k, pool_v = (jnp.asarray(
+        r.randn(layers, 1 + s_n * nb, bs, d_kv), dtype) for _ in "kv")
+    tables = 1 + np.arange(s_n * nb, dtype=np.int32).reshape(s_n, nb)
+    lengths = jnp.asarray(np.where(active, pos + 1, 1), jnp.int32)
+    q, k_new, v_new = (jnp.asarray(r.randn(s_n, w), jnp.float32)
+                       for w in (h * dh, d_kv, d_kv))
+    attend = functools.partial(
+        paged_attention.paged_attention, scale=0.3, pages=4, tile=2,
+        n_heads=h, d_head=dh, interpret=True)
+    lane = np.arange(s_n)
+    wb = np.where(active, tables[lane, pos // bs], 0)
+    wi = np.where(active, pos % bs, 0)
+    want_k, want_v = pool_k, pool_v
+    for layer in range(layers):
+        want_k = want_k.at[layer, wb, wi].set(k_new.astype(dtype))
+        want_v = want_v.at[layer, wb, wi].set(v_new.astype(dtype))
+        want = attend(q, want_k, want_v, tables, lengths, layer)
+        got, pool_k, pool_v = attend(
+            q, pool_k, pool_v, tables, lengths, layer,
+            write=(k_new, v_new, np.where(active, pos, -1)))
+        np.testing.assert_array_equal(np.asarray(got)[active],
+                                      np.asarray(want)[active])
+    for got, want in ((pool_k, want_k), (pool_v, want_v)):
+        np.testing.assert_array_equal(
+            np.asarray(got[:, 1:], np.float32),
+            np.asarray(want[:, 1:], np.float32))
 
 
 @pytest.mark.parametrize("kv_dtype,kernel", [
@@ -514,38 +578,115 @@ def test_unsupported_shape_is_refused_with_its_reason():
         srv.close()
 
 
-def test_tick_spans_count_the_pages_read(monkeypatch):
+@pytest.mark.parametrize("pages,tile,n_pages,want", [
+    # closed32's table: chunks of 16 pages, windows of 8 and 16
+    (16, 8, [1, 8, 9, 16, 17, 25, 32],
+     [128, 128, 256, 256, 384, 512, 512]),
+    # Mellum 2's: chunks of 64, windows of 8, 16, 32 and 64
+    (64, 8, [1, 9, 17, 33, 64, 65, 100],
+     [128, 256, 512, 1024, 1024, 1152, 2048]),
+    # a table of 12 pages: the chunk is the last window
+    (12, 8, [8, 9, 12], [128, 192, 192]),
+    # K-EXAONE's ring: one window, the chunk
+    (8, 8, [1, 8], [128, 128]),
+], ids=["two-windows", "four-windows", "odd-chunk", "one-window"])
+def test_rows_multiplied_follow_the_windows(pages, tile, n_pages, want):
+    """What `kv_rows_multiplied` sums: whole chunks whole, the last
+    chunk's smallest window (the tile doubled up to the chunk) that
+    holds its pages, for integers and for arrays alike."""
+    got = paged_attention.rows_multiplied(np.asarray(n_pages), pages,
+                                          tile, 16)
+    assert list(got) == want
+    assert [paged_attention.rows_multiplied(n, pages, tile, 16)
+            for n in n_pages] == want
+
+
+def _tick_attrs(dec, states):
+    """The attributes of the `serving.decode_tick` spans of one request
+    of 5 prompt tokens and 6 new ones on a server of 3 slots."""
+    from paddle_tpu.observability import tracing
+
+    tracing.set_enabled(True)
+    tracing.clear()
+    srv = GenerationServer(dec, states, slots=3, kv_blocks=12,
+                           place=fluid.CPUPlace())
+    try:
+        srv.submit([3, 1, 4, 1, 5], 6).result(timeout=120)
+    finally:
+        srv.close()
+        tracing.set_enabled(False)
+    return [s["attrs"] for s in tracing.finished_spans()
+            if s["name"] == "serving.decode_tick"]
+
+
+def test_tick_spans_count_the_pages_read():
     """`kv_pages_read` of `kv_pages_table` on `serving.decode_tick`:
     through the kernel the pages each cursor has reached (and one for a
     slot with no sequence), on the gather path every page of every
     slot's table."""
-    from paddle_tpu.observability import tracing
-
-    def ticks(dec, states):
-        tracing.set_enabled(True)
-        tracing.clear()
-        srv = GenerationServer(dec, states, slots=3, kv_blocks=12,
-                               place=fluid.CPUPlace())
-        try:
-            srv.submit([3, 1, 4, 1, 5], 6).result(timeout=120)
-        finally:
-            srv.close()
-            tracing.set_enabled(False)
-        return [s["attrs"] for s in tracing.finished_spans()
-                if s["name"] == "serving.decode_tick"]
-
     dec_x, states = _decoder()
     dec_p, _ = _decoder(interpret=True)
     # 2 layers, 3 slots, tables of 4 blocks of 4 positions
-    for a in ticks(dec_x, states):
+    for a in _tick_attrs(dec_x, states):
         assert a["kv_pages_read"] == a["kv_pages_table"] == 2 * 3 * 4
-    got = ticks(dec_p, states)
+    got = _tick_attrs(dec_p, states)
     assert [a["kv_pages_table"] for a in got] == [24] * len(got)
     # one sequence at cursors 0, 1, 2...: ceil((cursor + 1) / 4) pages a
     # layer, and a page a layer for each of the two idle slots
     assert [a["kv_pages_read"] for a in got] == [
         2 * (-(-(c + 1) // 4) + 2) for c in range(len(got))]
     assert len(got) == 10
+
+
+def test_tick_spans_count_the_rows_multiplied():
+    """`kv_rows_multiplied` on `serving.decode_tick`: through the
+    kernel the smallest row window that holds a slot's pages (here
+    2 pages of 4 rows, or the chunk: the table's 4), on the gather path
+    every row of every slot's table; the benchmark's reader divides it
+    by the rows of the pages read."""
+    import importlib.util
+    import os
+    import types
+
+    from paddle_tpu.models.transformer import build_lm_paged_decoder
+    from paddle_tpu.observability import tracing
+
+    dec_x, states = _decoder()
+    with _interpreted(tile_rows=8):
+        fw.reset_unique_names()
+        _, dec_p = build_lm_paged_decoder(
+            V, 4, 4, d_model=32, n_heads=2, n_layers=2, platform="cpu")
+    assert dec_p.attention_tiling == ((4, 2), None)
+    assert dec_x.attention_tiling is None
+    for a in _tick_attrs(dec_x, states):
+        assert a["kv_rows_multiplied"] == 4 * a["kv_pages_table"] == 96
+    got = _tick_attrs(dec_p, states)
+    assert len(got) == 10
+    # one sequence at cursors 0, 1, 2...: a window of two pages or of
+    # four, and the smaller a layer for each of the two idle slots
+    pages = [-(-(c + 1) // 4) for c in range(len(got))]
+    assert [a["kv_rows_multiplied"] for a in got] == [
+        2 * 8 * (-(-p // 2) + 2) for p in pages]
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf", "metrics",
+        "sched_kv_rows_multiplied_share.py")
+    spec = importlib.util.spec_from_file_location("_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    ticks = [s for s in tracing.finished_spans()
+             if s["name"] == "serving.decode_tick"]
+    run = types.SimpleNamespace(
+        spans=[{k: s[k] for k in ("name", "ts", "dur")} for s in ticks],
+        cell=types.SimpleNamespace(traffic={"block_size": 4}))
+    want = 100.0 * sum(a["kv_rows_multiplied"] for a in got) / (
+        4 * sum(a["kv_pages_read"] for a in got))
+    assert reader.compute(run) == pytest.approx(want)
+    assert 100.0 < want < 200.0
+    # a program without the attribute (the parent): nothing to read
+    for s in ticks:
+        del s["attrs"]["kv_rows_multiplied"]
+    assert reader.compute(run) is None
 
 
 # ---------------------------------------------------------------------------

@@ -143,8 +143,8 @@ def test_decode_tick_and_request_spans_account_for_the_run():
     # every attribute has a reader (docs/observability.md): no more
     # (a block without experts sets no `moe_experts_hit`)
     assert all(set(a) == {"active", "prefill", "kv_used", "kv_total",
-                          "kv_pages_read", "kv_pages_table", "kv_wait",
-                          "ahead"}
+                          "kv_pages_read", "kv_pages_table",
+                          "kv_rows_multiplied", "kv_wait", "ahead"}
                for a in ticks)
     assert len(reqs) == 6
     for r in reqs:
