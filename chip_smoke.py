@@ -325,11 +325,12 @@ def leg_train_lm_flash(smoke: Smoke):
           < 0.2 * math.log(cfg["vocab"]), f"first loss {losses[0]}")
     check(stats["recompiles_after_warmup"] == 0, stats)
     if smoke.on_tpu:
-        # forward, dq and dk/dv kernels per attention layer — without
-        # this the XLA composition could have run in silence
-        check(mosaic >= 3 * cfg["n_layers"],
+        # the forward kernel and the fused backward kernel (a dq and a
+        # dk/dv kernel past its VMEM budget) per attention layer —
+        # without this the XLA composition could have run in silence
+        check(mosaic >= 2 * cfg["n_layers"],
               f"{mosaic} Mosaic calls in the compiled step, want "
-              f">= {3 * cfg['n_layers']}")
+              f">= {2 * cfg['n_layers']}")
     return {"model": "transformer_lm", "d_model": cfg["d_model"],
             "n_layers": cfg["n_layers"], "seq": cfg["seq"],
             "batch": cfg["batch"], "amp": "bf16", "steps": len(losses),

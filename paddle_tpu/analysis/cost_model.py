@@ -482,6 +482,20 @@ def _flash_attention_cost(op, resolve):
                   + (" causal/2" if op.attrs.get("causal") else ""))
 
 
+@register_op_cost("flash_attention_grad")
+def _flash_attention_grad_cost(op, resolve):
+    """The op's own grad lowering (ops/attention.py) binds Q and K as
+    the forward does, so the forward estimator reads it as it is: its
+    operations x the class's backward multiple, the desc's own bytes —
+    what the generic '<t>_grad' path gave it."""
+    cost = _flash_attention_cost(op, resolve)
+    if not cost.known:
+        return cost
+    mult = _GRAD_MULT["attention"]
+    return OpCost(cost.flops * mult, cost.bytes, cost.kind,
+                  cost.note + f" (grad x{mult})")
+
+
 @register_op_cost("moe_ffn")
 def _moe_ffn_cost(op, resolve):
     """GShard dense form (parallel/moe.py): gating GEMM + dispatch/

@@ -14,8 +14,14 @@ jax.numpy / lax.  That single definition serves as:
 Gradients: ops may register an explicit `grad_maker` (emitting grad-op descs
 like the reference's GradOpMaker), but the default is a *generic VJP grad*:
 a `<type>_grad` op whose lowering calls `jax.vjp` on the forward lowering.
-XLA CSE dedupes the re-traced forward, so this costs nothing after fusion and
-guarantees analytic gradients exactly consistent with the forward op.
+For XLA's own ops CSE dedupes the re-traced forward, so this costs nothing
+after fusion and guarantees analytic gradients exactly consistent with the
+forward op.  It does NOT dedupe a Mosaic (Pallas) custom call: the two calls
+survive to the final module and the kernel runs twice.  An op whose lowering
+is such a kernel saves the kernel's residuals in an output slot and registers
+`<type>_grad` with a lowering of its own over the kernel's backward
+(ops/attention.py: `flash_attention`'s `LSE`), keeping the generic VJP for
+where the kernel is not selected.
 """
 from __future__ import annotations
 

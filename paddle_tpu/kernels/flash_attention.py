@@ -9,12 +9,22 @@ VMEM and streams K/V blocks through the MXU with an online softmax, so HBM
 traffic is O(seq·d) instead of O(seq²).
 
 Layout: [batch, seq, heads, head_dim] (matches parallel/ring_attention.py).
-Internally folded to [batch·heads, seq, head_dim]; grid = (bh, q_blocks,
-k_blocks) with the k dimension innermost so the VMEM accumulator scratch
-persists across K/V blocks of one query tile.
+The kernels take it as [batch, seq, heads * head_dim], the projections'
+own arrangement, and pick a head's (or, at head size 64, a head pair's)
+tile out of a row by the BlockSpec's index map (`_tile`), so nothing is
+transposed around a call; a head size that does not fill whole lanes
+goes through a transposed copy, [batch * heads, seq, head_dim].  grid =
+(bh, q_blocks, k_blocks) with the k dimension innermost so the VMEM
+accumulator scratch persists across K/V blocks of one query tile.
 
 Backward is the standard flash recomputation: forward saves only the
-per-row logsumexp; dq / dk / dv are three more streaming kernels.
+per-row logsumexp ([bh, pack, seq], one lane a query); dq, dk and dv come
+from ONE more streaming kernel that computes each score tile once, or
+from a dq and a dk/dv kernel where dq's whole-sequence accumulator does
+not fit VMEM (`_fused_bwd_fits`).  `flash_attention` under `jax.grad` is
+a custom_vjp over the two halves; a caller that keeps the residuals
+itself (the Program op, ops/attention.py) calls the halves:
+`flash_attention_forward`, `flash_attention_backward`.
 
 Falls back to a plain XLA composition when shapes don't tile (seq not a
 multiple of the block) or no TPU is present and interpret mode is off.
@@ -22,13 +32,15 @@ multiple of the block) or no TPU is present and interpret mode is off.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_reference"]
+__all__ = ["flash_attention", "flash_attention_forward",
+           "flash_attention_backward", "flash_attention_reference"]
 
 NEG_INF = -1e30  # finite mask value: keeps exp()/max() NaN-free in-kernel
 # measured on v5e at seq 4096, d 128, bf16 (async-chain, distinct inputs):
@@ -118,18 +130,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(j == nk - 1)
     def _finish():
+        l_safe = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
+        # the statistics sit one row a query (block_q sublanes); they are
+        # SAVED one lane a query, [pack, block_q]: a [.., seq, pack] array
+        # pads its 2 lanes to 128 in HBM (67 MB a layer at 64 x 2048 x 2)
+        lse_t = (m_ref[:] + jnp.log(l_safe)).T      # (128, block_q)
         for hs in range(pack):
             sl = slice(hs * d_head, (hs + 1) * d_head)
-            l = l_ref[:, hs * cw:hs * cw + 1]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, :, sl] = (acc_ref[:, sl] / l_safe).astype(o_ref.dtype)
-            # (block_q, pack) tile: one lse column per packed head
-            lse_ref[0, :, hs:hs + 1] = (m_ref[:, hs * cw:hs * cw + 1]
-                                        + jnp.log(l_safe))
+            o_ref[0, :, sl] = (acc_ref[:, sl]
+                               / l_safe[:, hs * cw:hs * cw + 1]
+                               ).astype(o_ref.dtype)
+            lse_ref[0, hs:hs + 1, :] = lse_t[hs * cw:hs * cw + 1, :]
 
 
-def _kv_index_map(causal, block_q, block_k):
-    """K/V block index for grid step (b, i, j).
+def _tile(block, d, groups, seq_block):
+    """BlockSpec of one folded head's (block, d) tile in a
+    [bh / groups, seq, groups * d] array: grid index b is batch element
+    b // groups and, inside its rows, the folded head b % groups.
+    `seq_block(i, j)` gives the tile's place along the sequence.  With
+    groups = heads / pack the array is the projection's own
+    [batch, seq, heads * d_head] and nothing is transposed around the
+    call; with groups = 1 it is the folded [batch * heads / pack, seq,
+    d]."""
+    return pl.BlockSpec((1, block, d), lambda b, i, j: (
+        b // groups, seq_block(i, j), b % groups))
+
+
+def _kv_block(causal, block_q, block_k):
+    """K/V block of grid step (i, j) = (q block, k block).
 
     Causal: clamp j to the diagonal block of query tile i.  Steps above the
     diagonal (compute skipped by pl.when) then repeat the previous block
@@ -137,35 +165,35 @@ def _kv_index_map(causal, block_q, block_k):
     index — masked K/V tiles cost no bandwidth.
     """
     if not causal:
-        return lambda b, i, j: (b, j, 0)
-    return lambda b, i, j: (
-        b, jnp.minimum(j, (i * block_q + (block_q - 1)) // block_k), 0)
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(
+        j, (i * block_q + (block_q - 1)) // block_k)
 
 
 def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
-                pack=1):
-    bh, sq, d = q.shape          # d = pack * d_head (packed layout)
+                pack=1, groups=1):
+    """q, k, v [bh / groups, seq, groups * d] (`_tile`); (out like q,
+    lse [bh, pack, sq])."""
+    nb, sq, gd = q.shape
     sk = k.shape[1]
+    bh, d = nb * groups, gd // groups    # d = pack * d_head
     nq, nk = sq // block_q, sk // block_k
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=block_q, block_k=block_k, nk=nk,
                              pack=pack, d_head=d // pack)
-    kv_map = _kv_index_map(causal, block_q, block_k)
-    o, lse = pl.pallas_call(
+    q_spec = _tile(block_q, d, groups, lambda i, j: i)
+    kv_spec = _tile(block_k, d, groups, _kv_block(causal, block_q, block_k))
+    return pl.pallas_call(
         kern,
         grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, pack), lambda b, i, j: (b, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, pack, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, pack), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, pack, sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -174,12 +202,27 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
         ],
         interpret=interpret,
     )(q, k, v)
-    return o, lse
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
+#
+# The row statistics reach every backward kernel one lane a query:
+# lse and delta are [bh, pack, seq] float32 and a block of them is
+# (pack, block_q).  The two kernels whose score tile is k-major (rows =
+# k positions, lanes = q positions) subtract such a row as it lies; the
+# q-major dq kernel turns its block into columns itself.
+
+def _stat_columns(lse_ref, delta_ref, pack, block_q):
+    """The (pack, block_q) blocks of lse and delta as ONE (block_q, 128)
+    tile whose column hs is lse's and pack + hs delta's: one transpose a
+    grid step where two padded [.., seq, pack] copies crossed HBM."""
+    rows = jnp.concatenate(
+        [lse_ref[0], delta_ref[0],
+         jnp.zeros((128 - 2 * pack, block_q), jnp.float32)], axis=0)
+    return rows.T
+
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc, *, scale, causal, block_q, block_k, nk, pack,
@@ -196,8 +239,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]        # (block_q, pack)
-        delta = delta_ref[0]    # (block_q, pack)
+        stats = _stat_columns(lse_ref, delta_ref, pack, block_q)
         if causal:
             rows = i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -211,11 +253,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 preferred_element_type=jnp.float32) * scale
             if causal:
                 s = jnp.where(keep, s, NEG_INF)
-            p = jnp.exp(s - lse[:, hs:hs + 1])
+            p = jnp.exp(s - stats[:, hs:hs + 1])
             dp = jax.lax.dot_general(
                 do[:, sl], v[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, hs:hs + 1]) * scale
+            ds = p * (dp - stats[:, pack + hs:pack + hs + 1]) * scale
             dq_acc[:, sl] += jax.lax.dot_general(
                 ds.astype(k.dtype), k[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -233,15 +275,34 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc,
-                *, scale, causal, block_q, block_k, nq, pack, d_head):
+                dk_ref, dv_ref, *rest,
+                scale, causal, block_q, block_k, nq, nk, pack, d_head,
+                fused):
+    """dk and dv of one K block, accumulated over its Q blocks in
+    scratch.  `fused`: dq as well, from the same score tile: s, p, dp
+    and ds are computed ONCE a tile (five products and one exp where
+    the dq kernel and this one spend seven and two).  dq then
+    accumulates in a float32 scratch that holds the head pair's WHOLE
+    sequence, under an output block that stays resident for the head
+    pair and is written once."""
+    if fused:
+        dq_ref, dk_acc, dv_acc, dq_acc = rest
+    else:
+        dk_acc, dv_acc = rest
     # grid = (bh, k_blocks, q_blocks): q innermost so dk/dv scratch persists
     i, j = pl.program_id(1), pl.program_id(2)   # i: k block, j: q block
+    q_rows = pl.ds(pl.multiple_of(j * block_q, block_q), block_q)
 
     @pl.when(j == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if fused:
+        @pl.when(i == 0)
+        def _init_dq():
+            dq_acc[q_rows, :] = jnp.zeros((block_q, pack * d_head),
+                                          jnp.float32)
 
     def _compute():
         # native-dtype MXU operands, f32 accumulate (see _fwd_kernel)
@@ -249,7 +310,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0]        # (pack, block_q) — transposed layout
+        lse = lse_ref[0]        # (pack, block_q): a row a packed head
         delta = delta_ref[0]    # (pack, block_q)
         if causal:
             krows = i * block_k + jax.lax.broadcasted_iota(
@@ -272,10 +333,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dpt = jax.lax.dot_general(
                 v[:, sl], do[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            dst = pt * (dpt - delta[hs:hs + 1, :]) * scale
+            dst = (pt * (dpt - delta[hs:hs + 1, :]) * scale).astype(q.dtype)
             dk_acc[:, sl] += jax.lax.dot_general(
-                dst.astype(q.dtype), q[:, sl], (((1,), (0,)), ((), ())),
+                dst, q[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if fused:
+                # ds^T k: the tile's rows (k positions) are contracted
+                dq_acc[q_rows, sl] += jax.lax.dot_general(
+                    dst, k[:, sl], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     if causal:
         # a k block gets gradient only from q blocks at/below its diagonal
@@ -290,118 +356,146 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if fused:
+        @pl.when((i == nk - 1) & (j == nq - 1))
+        def _finish_dq():
+            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
-def _bwd_pallas(q, k, v, o, lse, do, scale, causal, block_q, block_k,
-                interpret, pack=1):
-    bh, sq, d = q.shape          # d = pack * d_head
+
+# The fused backward keeps, for one head pair, a float32 dq of the whole
+# sequence and its resident output block (two buffers) in VMEM beside the
+# tiles: sq x d x (4 + 2 x itemsize) bytes, 2 MiB at seq 2048 and 8 MiB at
+# 8192 (bf16, packed width 128).  Past this budget (seq 16384 and up) the
+# dq kernel and the dk/dv kernel stay two.
+FUSED_BWD_DQ_VMEM_BUDGET = 8 * 1024 * 1024
+# what the k-major backward call may take in all: at blocks of 1024 x
+# 2048 (seq 8192 and up) the compiler asks for 23.5 MiB fused and 17.4
+# MiB for dk and dv alone, over the 16 MiB a call gets unasked, of the
+# v5e's 128
+BWD_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def _fused_bwd_fits(sq: int, d: int, itemsize: int) -> bool:
+    return sq * d * (4 + 2 * itemsize) <= FUSED_BWD_DQ_VMEM_BUDGET
+
+
+def _bwd_pallas(q, k, v, lse, delta, do, scale, causal, block_q, block_k,
+                interpret, pack=1, groups=1):
+    """(dq, dk, dv) like q, k, v: [bh / groups, seq, groups * d]
+    (`_tile`).  lse is the forward's [bh, pack, sq]; delta = rowsum(do *
+    o) a head in the same layout."""
+    nb, sq, gd = q.shape
     sk = k.shape[1]
+    bh, d = nb * groups, gd // groups    # d = pack * d_head
     d_head = d // pack
     nq, nk = sq // block_q, sk // block_k
-    # lse arrives as (bh, sq, pack); delta matches (per packed head),
-    # plus (bh, pack, sq) transposed copies for the dkv kernel's k-major
-    # tiles
-    delta = jnp.sum(
-        (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-            bh, sq, pack, d_head),
-        axis=-1)
-    lse_t = jnp.transpose(lse, (0, 2, 1))
-    delta_t = jnp.transpose(delta, (0, 2, 1))
+    fused = _fused_bwd_fits(sq, d, q.dtype.itemsize)
 
-    kv_map = _kv_index_map(causal, block_q, block_k)
+    if causal:
+        # q blocks strictly below a k block's diagonal are masked; clamping
+        # their index repeats the previous block -> the pipeline skips the
+        # copy (mirror of _kv_block for the transposed iteration).  min()
+        # keeps the index in range when sk > sq (the last k blocks'
+        # diagonals lie past the final q block); out-of-range block
+        # indices are undefined behavior on Mosaic even for compute-masked
+        # steps
+        def q_block(i, j):
+            return jnp.minimum(jnp.maximum(j, (i * block_k) // block_q),
+                               nq - 1)
+    else:
+        def q_block(i, j):
+            return j
+
+    # grid (bh, k block i, q block j)
+    q_spec = _tile(block_q, d, groups, q_block)
+    kv_spec = _tile(block_k, d, groups, lambda i, j: i)
+    stat_spec = pl.BlockSpec((1, pack, block_q),
+                             lambda b, i, j: (b, 0, q_block(i, j)))
+    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
+    res = pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, nq=nq, nk=nk,
+                          pack=pack, d_head=d_head, fused=fused),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=[kv_spec, kv_spec] + (
+            [_tile(sq, d, groups, lambda i, j: 0)] if fused else []),
+        out_shape=[kv_shape, kv_shape] + (
+            [jax.ShapeDtypeStruct(q.shape, q.dtype)] if fused else []),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)] + (
+            [pltpu.VMEM((sq, d), jnp.float32)] if fused else []),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=BWD_VMEM_LIMIT),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+    if fused:
+        dk, dv, dq = res
+        return dq, dk, dv
+    dk, dv = res
+
+    # grid (bh, q block i, k block j)
+    q_spec = _tile(block_q, d, groups, lambda i, j: i)
+    kv_spec = _tile(block_k, d, groups, _kv_block(causal, block_q, block_k))
+    stat_spec = pl.BlockSpec((1, pack, block_q), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, nk=nk,
                           pack=pack, d_head=d_head),
         grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, pack), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, pack), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-
-    if causal:
-        # q blocks strictly below a k block's diagonal are masked; clamping
-        # their index repeats the previous block -> the pipeline skips the
-        # copy (mirror of _kv_index_map for the transposed iteration)
-        def _clamped(i, j):
-            # min() keeps the index in range when sk > sq (the last k
-            # blocks' diagonals lie past the final q block); out-of-range
-            # block indices are undefined behavior on Mosaic even for
-            # compute-masked steps
-            return jnp.minimum(jnp.maximum(j, (i * block_k) // block_q),
-                               nq - 1)
-
-        def q_map(b, i, j):
-            return (b, _clamped(i, j), 0)
-
-        def q_vec_map(b, i, j):
-            return (b, 0, _clamped(i, j))
-    else:
-        def q_map(b, i, j):
-            return (b, j, 0)
-
-        def q_vec_map(b, i, j):
-            return (b, 0, j)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq=nq,
-                          pack=pack, d_head=d_head),
-        grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, pack, block_q), q_vec_map),
-            pl.BlockSpec((1, pack, block_q), q_vec_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, do, lse_t, delta_t)
     return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp wrapper over [bh, seq, d]
+# custom_vjp wrapper over the kernels' layout (`_tile`)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret, pack):
-    o, _ = _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
-                       interpret, pack)
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, pack,
+           groups):
+    """(o, lse): the row statistics are a result, so that a caller which
+    keeps them (the Program op) can run the backward itself; their own
+    cotangent is never used."""
+    return _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
+                       interpret, pack, groups)
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, pack):
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret, pack,
+               groups):
     o, lse = _fwd_pallas(q, k, v, scale, causal, block_q, block_k,
-                         interpret, pack)
-    return o, (q, k, v, o, lse)
+                         interpret, pack, groups)
+    return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, pack, res, do):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, pack, groups,
+               res, cts):
     q, k, v, o, lse = res
-    return _bwd_pallas(q, k, v, o, lse, do, scale, causal,
-                       block_q, block_k, interpret, pack)
+    return _backward(q, k, v, o, lse, cts[0], scale, causal, block_q,
+                     block_k, interpret, pack, groups)
+
+
+def _backward(q, k, v, o, lse, do, scale, causal, block_q, block_k,
+              interpret, pack, groups):
+    """(dq, dk, dv) from the forward's `o` and `lse` and o's cotangent,
+    all but lse in the kernels' layout."""
+    nb, sq, gd = o.shape
+    # `o` waits for `do`: what the backward makes of `o` alone (a float32
+    # or a transposed copy) XLA otherwise schedules into the FORWARD pass
+    # and keeps alive a layer to the backward (33 to 67 MB a layer at
+    # 64 x 2048 x 128)
+    o, do = jax.lax.optimization_barrier((o, do))
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32)
+                     ).reshape(nb, sq, groups, pack, gd // groups // pack),
+                    axis=-1)
+    delta = jnp.transpose(delta, (0, 2, 3, 1)).reshape(lse.shape)
+    return _bwd_pallas(q, k, v, lse, delta, do.astype(q.dtype), scale,
+                       causal, block_q, block_k, interpret, pack, groups)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -442,6 +536,127 @@ def _largest_tile(seq, block, align=128):
     return 0
 
 
+class _Plan(NamedTuple):
+    """How the kernel runs one attention shape: the static arguments of
+    `_flash` after q, k, v."""
+    scale: float
+    causal: bool
+    block_q: int
+    block_k: int
+    interpret: bool
+    pack: int
+    groups: int     # folded heads a row of the kernels' arrays (`_tile`)
+
+
+def _plan(q, k, v, causal, scale, block_q, block_k, interpret, min_seq_k,
+          platform) -> Optional[_Plan]:
+    """The ONE decision on blocks, head packing and fallback, for the
+    forward, the backward and the function under `jax.grad` alike; None
+    where the XLA composition runs instead: the computation will not run
+    on a TPU (unless `interpret=True` asks for the pallas interpreter,
+    e.g. tests), the sequence doesn't tile onto MXU-aligned blocks, or
+    the K/V length is below `min_seq_k`."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    sel_q, sel_k = _select_blocks(sq, sk, d)
+    block_q = sel_q if block_q is None else block_q
+    block_k = sel_k if block_k is None else block_k
+    block_q = min(block_q, sq)
+    block_k = min(block_k, sk)
+    interp = bool(interpret)
+    if not interp and (platform or jax.default_backend()) != "tpu":
+        # Mosaic only lowers on TPU, and emulating the grid loop on CPU/GPU
+        # is far slower than one fused XLA attention — fall back unless the
+        # caller opted into the pallas interpreter (interpret=True, tests)
+        return None
+    if not interp and sk < min_seq_k:
+        return None
+    if not interp and (sq % block_q or sk % block_k):
+        # seqs that are MXU-aligned but not multiples of the large
+        # default blocks (e.g. sk=2560 vs block_k=1024) must shrink to
+        # the largest 128-multiple divisor, not fall back to the
+        # score-materializing composition — above the crossover that
+        # fallback is exactly what the kernel exists to avoid
+        block_q = _largest_tile(sq, block_q) or block_q
+        block_k = _largest_tile(sk, block_k) or block_k
+    tiles_ok = sq % block_q == 0 and sk % block_k == 0
+    if not interp:
+        # Mosaic lowering wants MXU-aligned tiles; route small/ragged
+        # shapes to the XLA composition instead of failing at jit time
+        tiles_ok = (tiles_ok and block_q % 128 == 0 and block_k % 128 == 0
+                    and d % 8 == 0)
+    if (not tiles_ok
+            or k.shape != (b, sk, h, d) or v.shape != (b, sk, h, d)):
+        return None
+    # head-pair packing: at d_head 64 the [block, d] tiles fill half the
+    # 128-lane dim; folding two heads side-by-side ([b*h/2, s, 128])
+    # fills the lanes for every load/store while the per-head score
+    # tiles stay block-diagonal inside the kernel
+    pack = 2 if d == 64 and h % 2 == 0 else 1
+    # a folded head's tile taken straight from [batch, seq, heads * d]
+    # where it fills whole lanes; else from a transposed copy (`_to_kernel`)
+    groups = h // pack if (pack * d) % 128 == 0 else 1
+    return _Plan(float(d ** -0.5 if scale is None else scale), bool(causal),
+                 block_q, block_k, interp, pack, groups)
+
+
+def _to_kernel(x, plan):
+    """[b, s, h, d] -> the kernels' [b * h / pack / groups, s, groups *
+    pack * d] (`_tile`): a reshape where a folded head fills whole lanes
+    (groups = h / pack), else the fold: ADJACENT heads pair up by a pure
+    reshape ((h, d) dims are contiguous) and one transpose brings the
+    folded heads before the sequence."""
+    b, s, h, d = x.shape
+    if plan.groups * plan.pack == h:
+        return x.reshape(b, s, h * d)
+    x = x.reshape(b, s, h // plan.pack, plan.pack * d)
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+        b * h // plan.pack, s, plan.pack * d)
+
+
+def _from_kernel(x, shape, plan):
+    """`_to_kernel`'s inverse, to `shape` = [b, s, h, d]."""
+    b, s, h, d = shape
+    if plan.groups * plan.pack == h:
+        return x.reshape(shape)
+    x = x.reshape(b, h // plan.pack, s, plan.pack * d)
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(shape)
+
+
+def flash_attention_forward(q, k, v, causal=False, scale=None,
+                            block_q=None, block_k=None, interpret=None,
+                            min_seq_k=MIN_PALLAS_SEQ_K, platform=None):
+    """The kernel's forward half for a caller that keeps the residuals
+    itself: (out [b, sq, h, d], lse), or None where `flash_attention`
+    would run the XLA composition.  lse is float32
+    [b*h/pack, pack, sq], one LANE a query (pack: heads folded side by
+    side, 2 at head size 64): what `flash_attention_backward` takes.
+    Differentiable like `flash_attention` (its custom_vjp)."""
+    plan = _plan(q, k, v, causal, scale, block_q, block_k, interpret,
+                 min_seq_k, platform)
+    if plan is None:
+        return None
+    o, lse = _flash(*(_to_kernel(x, plan) for x in (q, k, v)), *plan)
+    return _from_kernel(o, q.shape, plan), lse
+
+
+def flash_attention_backward(q, k, v, out, lse, d_out, causal=False,
+                             scale=None, block_q=None, block_k=None,
+                             interpret=None, min_seq_k=MIN_PALLAS_SEQ_K,
+                             platform=None):
+    """(dq, dk, dv) from what `flash_attention_forward` gave for the
+    same arguments: no score tile of the forward is computed again.
+    None where the forward gave None."""
+    plan = _plan(q, k, v, causal, scale, block_q, block_k, interpret,
+                 min_seq_k, platform)
+    if plan is None:
+        return None
+    grads = _backward(*(_to_kernel(x, plan) for x in (q, k, v, out)), lse,
+                      _to_kernel(d_out, plan), *plan)
+    return tuple(_from_kernel(g, x.shape, plan)
+                 for g, x in zip(grads, (q, k, v)))
+
+
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None,
                     interpret=None, min_seq_k=MIN_PALLAS_SEQ_K,
@@ -459,56 +674,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
     `platform` names the backend the traced computation targets (the
     op lowering passes its executor's; None = the process default).
     """
-    b, sq, h, d = q.shape
-    sk = k.shape[1]
-    sel_q, sel_k = _select_blocks(sq, sk, d)
-    block_q = sel_q if block_q is None else block_q
-    block_k = sel_k if block_k is None else block_k
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    scale_v = float(d ** -0.5 if scale is None else scale)
-    interp = bool(interpret)
-    if not interp and (platform or jax.default_backend()) != "tpu":
-        # Mosaic only lowers on TPU, and emulating the grid loop on CPU/GPU
-        # is far slower than one fused XLA attention — fall back unless the
-        # caller opted into the pallas interpreter (interpret=True, tests)
-        return flash_attention_reference(q, k, v, causal, scale_v)
-    if not interp and sk < min_seq_k:
-        return flash_attention_reference(q, k, v, causal, scale_v)
-    if not interp and (sq % block_q or sk % block_k):
-        # seqs that are MXU-aligned but not multiples of the large
-        # default blocks (e.g. sk=2560 vs block_k=1024) must shrink to
-        # the largest 128-multiple divisor, not fall back to the
-        # score-materializing composition — above the crossover that
-        # fallback is exactly what the kernel exists to avoid
-        block_q = _largest_tile(sq, block_q) or block_q
-        block_k = _largest_tile(sk, block_k) or block_k
-    tiles_ok = sq % block_q == 0 and sk % block_k == 0
-    if not interp:
-        # Mosaic lowering wants MXU-aligned tiles; route small/ragged
-        # shapes to the XLA composition instead of failing at jit time
-        tiles_ok = (tiles_ok and block_q % 128 == 0 and block_k % 128 == 0
-                    and d % 8 == 0)
-    if (not tiles_ok
-            or k.shape != (b, sk, h, d) or v.shape != (b, sk, h, d)):
-        return flash_attention_reference(q, k, v, causal, scale_v)
-    # head-pair packing: at d_head 64 the [block, d] tiles fill half the
-    # 128-lane dim; folding two heads side-by-side ([b*h/2, s, 128])
-    # fills the lanes for every load/store while the per-head score
-    # tiles stay block-diagonal inside the kernel
-    pack = 2 if d == 64 and h % 2 == 0 else 1
-
-    def fold(x, s_len):
-        # ADJACENT heads pair up by a pure reshape ((h, d) dims are
-        # contiguous), so packing costs exactly the transposes the
-        # unpacked path already pays — and the one real transpose now
-        # moves a full-128-lane last dim instead of a half-filled one
-        x = x.reshape(b, s_len, h // pack, pack * d)
-        x = jnp.transpose(x, (0, 2, 1, 3))
-        return x.reshape(b * h // pack, s_len, pack * d)
-
-    o = _flash(fold(q, sq), fold(k, sk), fold(v, sk), scale_v,
-               bool(causal), block_q, block_k, interp, pack)
-    o = jnp.transpose(o.reshape(b, h // pack, sq, pack * d),
-                      (0, 2, 1, 3))
-    return o.reshape(b, sq, h, d)
+    res = flash_attention_forward(q, k, v, causal, scale, block_q, block_k,
+                                  interpret, min_seq_k, platform)
+    if res is None:
+        return flash_attention_reference(q, k, v, causal, scale)
+    return res[0]

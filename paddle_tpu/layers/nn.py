@@ -148,9 +148,12 @@ def flash_attention(q, k, v, causal=False, scale=None, min_seq_k=None,
     helper = LayerHelper("flash_attention", name=name)
     out = helper.create_tmp_variable(q.dtype)
     out.shape = q.shape
+    # the kernel's row statistics, kept for the op's own gradient; never
+    # written where the XLA composition runs (ops/attention.py)
+    lse = helper.create_tmp_variable("float32", stop_gradient=True)
     helper.append_op("flash_attention",
                      {"Q": [q.name], "K": [k.name], "V": [v.name]},
-                     {"Out": [out.name]},
+                     {"Out": [out.name], "LSE": [lse.name]},
                      {"causal": bool(causal),
                       "scale": 1.0 if scale is None else float(scale),
                       "default_scale": scale is None,

@@ -157,6 +157,32 @@ def test_flash_attention_never_counts_score_matrix():
     assert c.bytes == 4 * B * S * H * D * 4        # qkv + out, NOT S*S
 
 
+def test_flash_attention_grad_is_priced_with_a_lowering_of_its_own():
+    """`flash_attention_grad` is a registered op (it runs the kernel's
+    backward on the forward's saved statistics), so the generic
+    '<t>_grad' pricing never sees it: its own estimator must give the
+    forward's operations x 2.5, and the program no unknown op."""
+    B, S, H, D = 2, 128, 4, 16
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = fluid.layers.data(name="q", shape=[S, H, D], dtype="float32")
+        q.stop_gradient = False
+        out = fluid.layers.flash_attention(q, q, q, causal=True)
+        loss = fluid.layers.mean(out)
+        fluid.backward.calc_gradient(loss, [q])
+    b = main.global_block()
+    fwd = next(op for op in b.ops if op.type == "flash_attention")
+    grad = next(op for op in b.ops if op.type == "flash_attention_grad")
+    cf = analysis.estimate_op(fwd, b, batch_size=B)
+    cg = analysis.estimate_op(grad, b, batch_size=B)
+    assert cf.flops == 4 * B * H * S * S * D * 0.5
+    assert cg.kind == "attention" and cg.flops == 2.5 * cf.flops
+    assert "grad x2.5" in cg.note
+    assert cg.bytes > cf.bytes      # q, k, v, out, d_out in; three out
+    est = analysis.estimate_program(main, batch_size=B)
+    assert not est.unknown_types, est.unknown_types
+
+
 # ---------------------------------------------------------------------------
 # static peak HBM (liveness + donation)
 # ---------------------------------------------------------------------------

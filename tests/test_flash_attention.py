@@ -171,3 +171,216 @@ def test_flash_min_seq_k_flag_rekeys_executor_cache():
         set_flags({"flash_min_seq_k": prev})
     assert n2 > n1, "flag flip must add a cache entry, not reuse"
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the op's own gradient: the forward saves LSE, the grad op runs the
+# kernel's backward on it (ops/attention.py)
+# ---------------------------------------------------------------------------
+
+def _kernel_module():
+    # `paddle_tpu.kernels.flash_attention` the ATTRIBUTE is the function
+    import importlib
+
+    return importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+@pytest.fixture
+def interpreted_op(monkeypatch):
+    """The op's lowerings select the kernel from platform and shape
+    alone; on the CPU that is never.  Steer them into the Pallas
+    interpreter HERE (the program has no option for it) and count what
+    they call."""
+    import functools
+
+    from paddle_tpu.ops import attention as op_mod
+
+    calls = {"forward": 0, "backward": 0}
+
+    def counted(name, fn):
+        @functools.wraps(fn)
+        def wrapper(*a, **kw):
+            calls[name] += 1
+            kw.pop("platform", None)
+            return fn(*a, interpret=True, min_seq_k=0,
+                      **{k: v for k, v in kw.items() if k != "min_seq_k"})
+        return wrapper
+
+    monkeypatch.setattr(op_mod, "flash_attention_forward",
+                        counted("forward", op_mod.flash_attention_forward))
+    monkeypatch.setattr(op_mod, "flash_attention_backward",
+                        counted("backward", op_mod.flash_attention_backward))
+    return calls
+
+
+def _attention_program(q_shape, k_shape, causal, with_lse=True):
+    """q, k, v fed; loss = sum(attention * w); grads of all three."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        q = fluid.layers.data(name="q", shape=list(q_shape[1:]),
+                              dtype="float32")
+        k = fluid.layers.data(name="k", shape=list(k_shape[1:]),
+                              dtype="float32")
+        v = fluid.layers.data(name="v", shape=list(k_shape[1:]),
+                              dtype="float32")
+        for x in (q, k, v):
+            x.stop_gradient = False
+        if with_lse:
+            out = fluid.layers.flash_attention(q, k, v, causal=causal)
+        else:       # a Program built before the op had the slot
+            block = main.global_block()
+            out = block.create_var(name="att_out", dtype="float32")
+            out.shape = q.shape
+            block.append_op("flash_attention",
+                            {"Q": [q.name], "K": [k.name], "V": [v.name]},
+                            {"Out": [out.name]}, {"causal": causal})
+        w = fluid.layers.data(name="w", shape=list(q_shape[1:]),
+                              dtype="float32")
+        loss = fluid.layers.reduce_sum(
+            fluid.layers.elementwise_mul(out, w))
+        grads = fluid.backward.calc_gradient(loss, [q, k, v])
+    return main, startup, loss, grads
+
+
+def _reference_grads(q, k, v, w, causal):
+    return jax.grad(lambda q, k, v: jnp.sum(flash_attention_reference(
+        q, k, v, causal=causal) * w), (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal,q_shape,k_shape", [
+    (False, (2, 256, 2, 64), (2, 256, 2, 64)),    # pack 2
+    (True, (2, 256, 4, 64), (2, 256, 4, 64)),     # two head pairs a row
+    (True, (1, 256, 2, 128), (1, 256, 2, 128)),   # pack 1
+    (True, (1, 128, 2, 64), (1, 256, 2, 64)),     # sk > sq
+    (True, (2, 128, 2, 32), (2, 128, 2, 32)),     # half-filled lanes: fold
+])
+def test_op_gradient_runs_the_kernels_backward_on_saved_lse(
+        interpreted_op, causal, q_shape, k_shape):
+    rng = np.random.RandomState(5)
+    feed = {"q": rng.randn(*q_shape).astype("float32"),
+            "k": rng.randn(*k_shape).astype("float32"),
+            "v": rng.randn(*k_shape).astype("float32"),
+            "w": rng.randn(*q_shape).astype("float32")}
+    main, startup, loss, grads = _attention_program(q_shape, k_shape,
+                                                    causal)
+    fwd_op = next(op for op in main.global_block().ops
+                  if op.type == "flash_attention")
+    grad_op = next(op for op in main.global_block().ops
+                   if op.type == "flash_attention_grad")
+    assert grad_op.input("LSE") == fwd_op.output("LSE")
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    interpreted_op.update(forward=0, backward=0)  # shape inference's
+    got = exe.run(main, feed=feed, fetch_list=[g.name for g in grads]
+                  + fwd_op.output("LSE"))
+    # the forward lowering once, the kernel's backward once: no re-run
+    assert interpreted_op == {"forward": 1, "backward": 1}
+    b, sq, h, d = q_shape
+    pack = 2 if d == 64 else 1
+    assert _kernel_module()._plan(
+        *(jnp.zeros(x) for x in (q_shape, k_shape, k_shape)), causal, None,
+        None, None, True, 0, None).groups == (h // pack if d != 32 else 1)
+    lse = np.asarray(got[3])
+    assert lse.shape == (b * h // pack, pack, sq)
+    assert lse.dtype == np.float32
+    want = _reference_grads(*(jnp.asarray(feed[n]) for n in "qkvw"),
+                            causal)
+    for g, r in zip(got[:3], want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_op_without_lse_slot_differentiates_through_the_generic_path(
+        kernel, request):
+    """A Program built or saved before the op had `LSE`: the grad op has
+    no statistics to use and takes the generic VJP over the forward
+    lowering, with the kernel (its custom_vjp) or without."""
+    calls = request.getfixturevalue("interpreted_op") if kernel else None
+    shape = (1, 128, 2, 64)
+    rng = np.random.RandomState(6)
+    feed = {n: rng.randn(*shape).astype("float32") for n in "qkvw"}
+    main, startup, loss, grads = _attention_program(shape, shape, True,
+                                                    with_lse=False)
+    grad_op = next(op for op in main.global_block().ops
+                   if op.type == "flash_attention_grad")
+    assert not grad_op.input("LSE")
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    if kernel:
+        calls.update(forward=0, backward=0)     # shape inference's
+    got = exe.run(main, feed=feed, fetch_list=[g.name for g in grads])
+    if kernel:  # forward, and the forward again under jax.vjp
+        assert calls == {"forward": 2, "backward": 0}
+    want = _reference_grads(*(jnp.asarray(feed[n]) for n in "qkvw"), True)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal,q_shape,k_shape", [
+    (False, (2, 256, 2, 64), (2, 256, 2, 64)),
+    (True, (2, 256, 4, 64), (2, 256, 4, 64)),
+    (True, (1, 256, 2, 128), (1, 256, 2, 128)),
+    (True, (1, 128, 2, 64), (1, 256, 2, 64)),
+    (True, (2, 256, 2, 32), (2, 256, 2, 32)),
+])
+def test_fused_backward_matches_the_two_kernels(monkeypatch, causal,
+                                                q_shape, k_shape):
+    """One kernel for dq, dk and dv against the dq kernel and the dk/dv
+    kernel on the same inputs (blocks of 128: 2 x 2 grids, so dq
+    accumulates across K blocks in the whole-sequence scratch)."""
+    fa = _kernel_module()
+    rng = np.random.RandomState(7)
+    q = jnp.asarray(rng.randn(*q_shape).astype(np.float32))
+    k = jnp.asarray(rng.randn(*k_shape).astype(np.float32))
+    v = jnp.asarray(rng.randn(*k_shape).astype(np.float32))
+    w = jnp.asarray(rng.randn(*q_shape).astype(np.float32))
+
+    def grads():
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, interpret=True, block_q=128,
+            block_k=128) * w), (0, 1, 2))(q, k, v)
+
+    assert fa._fused_bwd_fits(q_shape[1], 128, 4)
+    fused = grads()
+    for got, ref in zip(fused, _reference_grads(q, k, v, w, causal)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
+    monkeypatch.setattr(fa, "FUSED_BWD_DQ_VMEM_BUDGET", 0)
+    two = grads()
+    for got, want in zip(fused, two):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("seq,fused", [
+    (2048, True), (8192, True), (16384, False), (32768, False)])
+def test_backward_is_chosen_by_shape(seq, fused):
+    """Fused where the head pair's float32 dq and its resident output
+    block fit the stated VMEM budget (bf16, packed width 128), the two
+    kernels past it."""
+    fa = _kernel_module()
+    assert fa._fused_bwd_fits(seq, 128, 2) is fused
+
+
+def test_forward_and_backward_halves_agree_with_the_function():
+    """`flash_attention_forward` + `flash_attention_backward` are the
+    function under `jax.grad`, split where the residuals are."""
+    fa = _kernel_module()
+    q, k, v = _rand_qkv(s=256)
+    w = jnp.cos(jnp.arange(q.shape[-1], dtype=jnp.float32))
+    out, lse = fa.flash_attention_forward(q, k, v, causal=True,
+                                          interpret=True)
+    assert lse.shape == (2 * 2 // 2, 2, 256) and lse.dtype == jnp.float32
+    halves = fa.flash_attention_backward(
+        q, k, v, out, lse, jnp.broadcast_to(w, out.shape), causal=True,
+        interpret=True)
+    whole = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, interpret=True) * w), (0, 1, 2))(q, k, v)
+    for got, want in zip(halves, whole):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=1e-6)
+    # not selected (no TPU, no interpreter): both halves say so
+    assert fa.flash_attention_forward(q, k, v) is None
+    assert fa.flash_attention_backward(q, k, v, out, lse, out) is None

@@ -17,7 +17,9 @@ compiles for a chip that is described and not attached
 Contract: at a pool `select_paged_attention(platform="tpu")` accepts,
 the kernel it returns lowers to ONE Mosaic custom call however many
 layers call it, and compiles for a v5e at every serving cell's
-geometry; the flash kernels lower forward and backward.
+geometry; the flash kernels lower forward and backward, compile for a
+v5e at the training cell's shape, and a training step holds the forward
+kernel once a layer.
 """
 import jax
 import jax.numpy as jnp
@@ -146,12 +148,13 @@ def test_paged_attention_compiles_for_a_v5e(name, one_v5e):
         4 * s_n * width * 4
 
 
-@pytest.mark.parametrize("shape,dtype", [
-    ((1, 8192, 6, 128), jnp.bfloat16),   # the 1024x2048 block table
-    ((1, 4096, 8, 64), jnp.bfloat16),    # d64 head-pair packing
-    ((1, 2560, 4, 128), jnp.float32),    # non-default tile divisor
+@pytest.mark.parametrize("shape,dtype,calls", [
+    ((1, 8192, 6, 128), jnp.bfloat16, 2),   # the 1024x2048 block table
+    ((1, 4096, 8, 64), jnp.bfloat16, 2),    # d64 head-pair packing
+    ((1, 2560, 4, 128), jnp.float32, 2),    # non-default tile divisor
+    ((1, 16384, 2, 64), jnp.bfloat16, 3),   # dq's scratch past its budget
 ])
-def test_flash_attention_fwd_and_bwd_lower_for_tpu(shape, dtype):
+def test_flash_attention_fwd_and_bwd_lower_for_tpu(shape, dtype, calls):
     q = jnp.zeros(shape, dtype)
 
     def loss(q, k, v):
@@ -159,5 +162,62 @@ def test_flash_attention_fwd_and_bwd_lower_for_tpu(shape, dtype):
         return jnp.sum(out.astype(jnp.float32))
 
     text = lower_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
-    # forward, dq and dk/dv are three separate Mosaic kernels
-    assert text.count(MOSAIC_CALL) == 3
+    # the forward and ONE backward kernel for dq, dk and dv; where the
+    # head pair's dq does not fit VMEM, a dq and a dk/dv kernel
+    assert text.count(MOSAIC_CALL) == calls
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 2048, 32, 64),      # opt-1.3b-train-seq2048: 4 sequences a step
+    (1, 8192, 32, 64),      # the same tokens as one sequence
+])
+def test_flash_attention_compiles_for_a_v5e(shape, one_v5e):
+    """Mosaic's own compile of the forward (statistics transposed to one
+    lane a query) and of the fused backward (dq's whole-sequence scratch,
+    the product that contracts the tile's sublanes) at the training
+    cell's width: 23.5 MiB of VMEM at 8192, which the call asks for."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_v5e)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, platform="tpu")
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count(MOSAIC_CALL) == 2
+
+
+def test_training_step_runs_the_forward_kernel_once_a_layer(
+        one_v5e, monkeypatch):
+    """The guard for the op's own gradient: a one-layer training Program
+    compiled for the described chip holds TWO Mosaic calls, the forward
+    and the fused backward.  With the generic VJP grad it held four: the
+    forward again under the grad op (XLA does not merge two Mosaic calls
+    as it merges its own ops), a dq and a dk/dv kernel."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core.executor import program_to_fn
+
+    # what the lowerings ask where no executor drives them
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, h, d = 2, 256, 2, 64
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[s, h * d], dtype="float32")
+        qkv = fluid.layers.fc(input=x, size=3 * h * d, num_flatten_dims=2)
+        q, k, v = (fluid.layers.reshape(t, shape=[0, s, h, d]) for t in
+                   fluid.layers.split(qkv, 3, dim=2))
+        att = fluid.layers.flash_attention(q, k, v, causal=True,
+                                           min_seq_k=0)
+        loss = fluid.layers.mean(fluid.layers.square(att))
+        fluid.SGD(learning_rate=0.1).minimize(loss)
+    fn = program_to_fn(main, ["x"], [loss.name])
+    blk = main.global_block()
+    states = {n: jax.ShapeDtypeStruct(
+        tuple(int(i) for i in blk.vars[n].shape), jnp.float32,
+        sharding=one_v5e) for n in fn.state_in_names}
+    feeds = {"x": jax.ShapeDtypeStruct((b, s, h * d), jnp.float32,
+                                       sharding=one_v5e)}
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_v5e)
+    text = jax.jit(fn).lower(feeds, states, key).compile().as_text()
+    assert text.count(MOSAIC_CALL) == 2
