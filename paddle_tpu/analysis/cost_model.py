@@ -1181,8 +1181,8 @@ def _resolve_decode_backend(spec: Dict, kv_dtype: str) -> str:
 
     d, h, layers, v, di, bs, nb = _spec_dims(spec)
     reason = paged_attention_supports(
-        d_model=d, n_heads=h, block_size=bs, max_blocks_per_seq=nb,
-        kv_dtype=kv_dtype, platform=jax.default_backend())
+        d_model=d, block_size=bs, kv_dtype=kv_dtype,
+        platform=jax.default_backend())
     return "xla" if reason else "pallas"
 
 

@@ -763,12 +763,11 @@ def cmd_serve(argv):
                     help="KV block size in positions (0 = spec / 16)")
     ap.add_argument("--kv_dtype", default="",
                     help="KV pool precision: fp32|bf16|int8 ('' = "
-                    "model spec / PADDLE_TPU_SERVING_KV_DTYPE / fp32); "
+                    "model spec / fp32); "
                     "docs/serving.md 'KV quantization'")
     ap.add_argument("--spec_k", type=int, default=0,
                     help="speculative draft tokens per tick (0 = model "
-                    "spec / flag default; needs draft params in the "
-                    "model dir)")
+                    "spec / 4; needs draft params in the model dir)")
     ap.add_argument("--no_draft", action="store_true",
                     help="ignore draft params in the model dir "
                     "(disable speculative decoding)")

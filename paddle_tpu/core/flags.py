@@ -190,24 +190,3 @@ define_flag("jit_granularity", "block",
             "escape hatch when whole-program XLA compile time "
             "dominates short runs (docs/performance.md).  An explicit "
             "Executor.run(compiled=...) argument overrides it")
-define_flag("serving_kv_dtype", "",
-            "default KV-pool storage precision for "
-            "models.transformer.build_lm_paged_decoder when the caller "
-            "passes kv_dtype=None (docs/serving.md 'KV quantization'): "
-            "'' or 'fp32' = float32 blocks; 'bf16' = bfloat16 blocks "
-            "(half the resident KV bytes); 'int8' = int8 blocks with "
-            "one float32 scale per (layer, block), quantize-on-write, "
-            "scales applied in attention (~4x fewer resident KV bytes, so the "
-            "same HBM budget holds ~2x the sequences K+V vs bf16 and "
-            "~4x vs fp32).  Read at BUILD time; the model-dir spec's "
-            "kv_dtype and explicit builder/server args override it")
-define_flag("serving_spec_k", 4,
-            "default speculative-decoding draft length: how many "
-            "tokens the draft model proposes per scheduler tick for "
-            "the target to verify in ONE step_window dispatch "
-            "(docs/serving.md 'Speculative decoding').  Used when a "
-            "GenerationServer is given a draft model without an "
-            "explicit spec_k (e.g. server_from_model_dir on a model "
-            "dir with draft params); greedy outputs stay bit-identical "
-            "for any k — k trades verify-step width against accept "
-            "probability per window")

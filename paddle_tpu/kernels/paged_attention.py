@@ -97,13 +97,11 @@ _TILE_ROWS = 128
 _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 
 
-def paged_attention_supports(*, d_model: int, n_heads: int,
-                             block_size: int, max_blocks_per_seq: int,
+def paged_attention_supports(*, d_model: int, block_size: int,
                              kv_dtype: str, platform: str,
                              interpret: bool = False,
-                             kv_width: Optional[int] = None,
-                             d_head: Optional[int] = None,
-                             ringed: bool = False) -> Optional[str]:
+                             kv_width: Optional[int] = None
+                             ) -> Optional[str]:
     """None when `select_paged_attention` would return the kernel for
     this pool on `platform`, else the short reason it is refused (what
     `decoder.kernels` reports after "xla:").  Off a TPU there is no
@@ -113,12 +111,11 @@ def paged_attention_supports(*, d_model: int, n_heads: int,
 
     The kernel sees a pool ROW (`kv_width`: the K/V heads side by side,
     `d_model` under plain multi-head attention), a page of `block_size`
-    rows and the pool's dtype.  `n_heads`, `d_head`, `ringed` and
-    `max_blocks_per_seq` change nothing it refuses: the kernel lays
-    the query out block-diagonal by K/V head whatever the heads, a
-    ring is a table, and the scratch is two chunks whatever the
-    context."""
-    del n_heads, d_head, ringed, max_blocks_per_seq
+    rows and the pool's dtype.  The heads, a ring and the table's
+    length are no parameters because they change nothing it refuses:
+    the kernel lays the query out block-diagonal by K/V head whatever
+    the heads, a ring is a table, and the scratch is two chunks
+    whatever the context."""
     if platform != "tpu" and not interpret:
         return "not_tpu"
     if kv_dtype not in _KV_DTYPES:
@@ -429,10 +426,9 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
 
 
 def select_paged_attention(
-        *, d_model: int, n_heads: int, block_size: int,
-        max_blocks_per_seq: int, kv_dtype: str, platform: str,
-        interpret: bool = False, kv_width: Optional[int] = None,
-        d_head: Optional[int] = None, ringed: bool = False,
+        *, d_model: int, n_heads: int, block_size: int, kv_dtype: str,
+        platform: str, interpret: bool = False,
+        kv_width: Optional[int] = None, d_head: Optional[int] = None,
 ) -> Tuple[Optional[Callable], Optional[str]]:
     """-> (attend, None), or (None, reason) where
     `paged_attention_supports` refuses: the caller then keeps its XLA
@@ -444,10 +440,8 @@ def select_paged_attention(
     row tile chosen from a page's bytes and the table's (the ring's)
     pages: `attend.tiling(table_pages)` says which."""
     reason = paged_attention_supports(
-        d_model=d_model, n_heads=n_heads, block_size=block_size,
-        max_blocks_per_seq=max_blocks_per_seq, kv_dtype=kv_dtype,
-        platform=platform, interpret=interpret, kv_width=kv_width,
-        d_head=d_head, ringed=ringed)
+        d_model=d_model, block_size=block_size, kv_dtype=kv_dtype,
+        platform=platform, interpret=interpret, kv_width=kv_width)
     if reason is not None:
         return None, reason
     page_bytes = (int(block_size) * int(kv_width or d_model)

@@ -17,6 +17,8 @@ activations bounded for bf16 training on the MXU.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .. import layers, nets
 from ..core.flags import get_flag
@@ -33,6 +35,7 @@ __all__ = [
     "build_lm_generator",
     "build_lm_kv_decoder",
     "build_lm_paged_decoder",
+    "PagedDecoder",
     "build_translate_generator",
     "build_lm_beam_search",
 ]
@@ -487,6 +490,126 @@ def build_lm_kv_decoder(vocab_size, max_len, d_model=256, n_heads=4,
     return startup, generate
 
 
+# What a block cannot be served with, by the kind of state that rules it
+# out and by what was asked: `PagedDecoder.refuses`, which
+# `GenerationServer` raises as it stands.
+_REFUSALS = {
+    "ring": {
+        "draft_model": (
+            "a decoder with sliding-window layers takes no draft model: "
+            "speculative verification writes a window of positions before it "
+            "attends, which a ring one window long cannot hold "
+            "(build_lm_paged_decoder's step_window refuses it too)"),
+        "prefix_cache": (
+            "prefix_cache=True with sliding-window layers: a cached prompt "
+            "block's sliding-layer K/V lives in the ring of the slot that "
+            "wrote it and is overwritten as that slot goes on, so a later "
+            "hit would attend over another request's keys; pass "
+            "prefix_cache=False")},
+    "state": {
+        "draft_model": (
+            "a decoder with Mamba layers takes no draft model: speculative "
+            "verification runs a window of positions through step_window, "
+            "and a recurrent state is computed one position a step (and "
+            "cannot be rolled back over rejected tokens)"),
+        "prefix_cache": (
+            "prefix_cache=True with Mamba layers: a hit starts a sequence "
+            "past position 0, where the attention layers find the prompt's "
+            "K/V in the shared blocks but a lane has no recurrent state for "
+            "it (no snapshot is kept); pass prefix_cache=False")},
+    # a cached block holds every pass's K/V: the prefix cache works
+    "loop": {
+        "draft_model": (
+            "a decoder with a looped stack takes no draft model: speculative "
+            "verification runs a window of positions through step_window, "
+            "which is not built for a stack run several passes a token "
+            "(build_lm_paged_decoder's step_window refuses it too)")},
+}
+
+
+@dataclasses.dataclass(eq=False)
+class PagedDecoder:
+    """What `build_lm_paged_decoder` returns, its only constructor: the
+    jitted steps of one block description (its docstring has their
+    arguments), what they keep on the device, and what the builder
+    alone knows of them (docs/serving.md "What a decoder tells the
+    server").  Not frozen: a step's first trace sets `expert_kernel`."""
+
+    step: Callable
+    step_window: Callable
+    step_logits: Callable
+    # `step_logits` and what every layer's router (every pass of a
+    # looped stack) computed; None for a block with neither
+    step_routing: Optional[Callable]
+    # init_pool(num_blocks, device, window_blocks=, lanes=) -> the pools;
+    # slot_rings(slots) -> int32 [slots, window_blocks_per_seq]
+    init_pool: Callable
+    slot_rings: Callable
+    # the backend the steps were built for (selection, donation)
+    platform: str
+    # names of what `step` returns after the pools, counted on the
+    # device: "moe_experts_hit" (and "moe_rows_held" where the block
+    # holds a share of its experts), "exit_gate_open"; () without
+    step_counters: Tuple[str, ...]
+    # layers with experts: what `moe_experts_hit` and `moe_rows_held`
+    # are summed over (0: a block without)
+    moe_layers: int
+    # a looped stack: the passes a token takes over the one stack (1: a
+    # plain block) and the planes its table pool has
+    passes: int
+    kv_planes: int
+    # {a parameter's op_name in compiled text: the part's scope}, for
+    # `profiler.register_jitted`
+    compiler_scopes: Dict[str, str]
+    # the parameters: names (sorted) and shapes
+    state_names: List[str]
+    state_shapes: Dict[str, Tuple[int, ...]]
+    block_size: int
+    max_blocks_per_seq: int
+    max_len: int
+    n_layers: int
+    d_model: int
+    vocab_size: int
+    # the pool's storage: "fp32", "bf16" or "int8"
+    kv_dtype: str
+    # K+V bytes of one table block over the planes that hold it
+    bytes_per_block: int
+    # the ring of a block with sliding layers: blocks a sequence (0:
+    # every layer is full), the window, and a ring block's bytes
+    window_blocks_per_seq: int
+    window: int
+    window_bytes_per_block: int
+    # attention layers by where their K/V live: on the table (a plane
+    # for every pass of a looped stack), on a slot's ring
+    table_layers: int
+    ring_layers: int
+    # the Mamba layers' recurrent state: how many layers keep one (0:
+    # none) and the float32 bytes a lane holds over them
+    state_layers: int
+    state_bytes_per_lane: int
+    # what attends in the resident step (`step`, `step_logits`,
+    # `step_routing`): the streaming Pallas kernel, or the XLA gather
+    # and the reason the kernel was refused; `step_window` (a window of
+    # query rows a slot) runs the gather always
+    kernels: Dict[str, str]
+    # (pages a chunk, pages of its smallest row window) of the kernel
+    # over a slot's table and over its ring: what it copies and
+    # multiplies in (`kernels.paged_attention.rows_multiplied`); None
+    # on the gather path, and for a ring where there is none
+    attention_tiling: Optional[Tuple[Any, Any]]
+    # tick_counts(cursors, slots, windowed=False) -> dict: what one
+    # dispatched step reads and does (the builder's `tick_counts`)
+    tick_counts: Callable
+    # {"draft_model": reason, "prefix_cache": reason}: a key for each
+    # the server must refuse this block
+    refuses: Dict[str, str]
+    # what the expert layer of the step traced last runs: the Pallas
+    # grouped matmul's name, or "xla:<reason>" where `ragged_dot` does;
+    # None until a step is traced (the weights' dtype and the rows are
+    # the step's arguments) and for a block without experts
+    expert_kernel: Optional[str] = None
+
+
 def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                            d_model=256, n_heads=4, n_layers=2,
                            d_inner=None, kv_dtype=None, platform=None,
@@ -536,7 +659,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     with the pool buffers donated, so a tick is one dispatch and the
     pool updates in place on device.
 
-    Returns (startup_program, decoder):
+    Returns (startup_program, decoder), the decoder a `PagedDecoder`:
       decoder.step(states, pool_k, pool_v, tables, positions, tokens,
                    seeds, temps, active)
           -> (next_tokens [S] int32, pool_k, pool_v)
@@ -568,8 +691,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         under the new running max (blocks fill strictly in position
         order, so the valid region is exactly the offsets below the
         cursor) — a quarter of the resident bytes.
-    None reads the `serving_kv_dtype` flag (PADDLE_TPU_SERVING_KV_DTYPE)
-    and falls back to fp32.  Pools for bf16/int8 are pytrees the caller
+    None is "fp32".  Pools for bf16/int8 are pytrees the caller
     treats opaquely; `decoder.bytes_per_block` reports the resident
     K+V bytes per block for sizing/telemetry.
 
@@ -648,17 +770,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
-    from ..core import flags as core_flags
     from . import lm_block
 
     d_inner = d_inner or 4 * d_model
     nb, bs = int(max_blocks_per_seq), int(block_size)
     max_len = nb * bs
-    if kv_dtype is None:
-        kv_dtype = core_flags.get_flag("serving_kv_dtype") or "fp32"
+    kv_dtype = str(kv_dtype or "fp32").lower()
     kv_dtype = {"float32": "fp32", "bfloat16": "bf16"}.get(
-        str(kv_dtype).lower(), str(kv_dtype).lower())
+        kv_dtype, kv_dtype)
     if kv_dtype not in ("fp32", "bf16", "int8"):
         raise ValueError(
             f"kv_dtype {kv_dtype!r} not in ('fp32', 'bf16', 'int8')")
@@ -718,9 +839,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             "there); kv_dtype fp32 or bf16")
 
     _attend, _refused = _paged_attention.select_paged_attention(
-        d_model=d_model, n_heads=n_heads, block_size=bs,
-        max_blocks_per_seq=nb, kv_dtype=kv_dtype, platform=platform,
-        kv_width=d_kv, d_head=d_head, ringed=ringed)
+        d_model=d_model, n_heads=n_heads, d_head=d_head, kv_width=d_kv,
+        block_size=bs, kv_dtype=kv_dtype, platform=platform)
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -1495,7 +1615,81 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     if spec.ffn == "moe_swiglu":
         compiler_scopes.update(lm_block.MOE_COMPILER_SCOPES)
 
-    decoder = types.SimpleNamespace(
+    window = spec.window if ringed else 0
+    tiling = ((_attend.tiling(nb), _attend.tiling(nw) if nw else None)
+              if _attend is not None else None)
+
+    def tick_counts(cursors, slots, windowed=False):
+        """What the step dispatched for a tick reads and does, for its
+        `serving.decode_tick` span, from `cursors` (int array: the
+        step's `positions` at the lanes that hold a sequence) and
+        `slots`, its lanes; `windowed`: a `step_window` tick, which
+        gathers always.  `kv_pages_read` of `kv_pages_table`: the K/V
+        pages the step's attention reads, summed over lanes and
+        attention layers, of the pages the lanes' tables and rings hold:
+        through the Pallas kernel (`kernels`) the pages each cursor has
+        reached, and one a layer for a lane with no sequence; on the
+        gather path every page.  `kv_rows_multiplied`: the K/V rows the
+        step's two products run over, summed the same way: through the
+        kernel, for each chunk of a lane's pages, the smallest row
+        window that holds them (`attention_tiling`), on the gather path
+        every row.  With sliding layers `past_window` (cursors at or
+        past the window: their rings have wrapped) and the rows a layer
+        of each kind attends over, `kv_rows_full` (cursor + 1) and
+        `kv_rows_win` (the window at most).  With Mamba layers
+        `state_lanes` (lanes with a recurrent state: all the tick's) and
+        `state_resets` (those at position 0, which the step starts from
+        zero).  With experts `moe_kernel` (1: the traced step's expert
+        layer is the Pallas grouped matmul, 0: `ragged_dot`) and
+        `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
+        the planes the pages are counted over."""
+        n = len(cursors)
+        counts = {}
+        if passes > 1:
+            counts["loop_passes"] = passes
+            counts["kv_planes"] = planes
+        rows = cursors.astype(np.int64) + 1  # K/V rows a lane attends
+        table = slots * (planes * nb + n_win * nw)
+        read, multiplied = table, table * bs
+        if tiling is not None and not windowed:
+            idle = slots - n                 # a page each, a layer
+            read = multiplied = 0
+            reached = [(planes, -(-rows // bs), tiling[0])]
+            if n_win:
+                reached.append(
+                    (n_win, -(-np.minimum(rows, nw * bs) // bs), tiling[1]))
+            for layers_n, pages, (chunk, tile) in reached:
+                read += layers_n * (idle + int(pages.sum()))
+                multiplied += layers_n * int(
+                    idle * _paged_attention.rows_multiplied(
+                        1, chunk, tile, bs)
+                    + _paged_attention.rows_multiplied(
+                        pages, chunk, tile, bs).sum())
+        counts["kv_pages_read"] = read
+        counts["kv_pages_table"] = table
+        counts["kv_rows_multiplied"] = multiplied
+        if window:
+            counts["past_window"] = int((rows > window).sum())
+            counts["kv_rows_full"] = int(rows.sum())
+            counts["kv_rows_win"] = int(np.minimum(rows, window).sum())
+        if stateful:
+            counts["state_lanes"] = n
+            counts["state_resets"] = n - int(np.count_nonzero(cursors))
+        if decoder.expert_kernel is not None:
+            counts["moe_kernel"] = int(
+                not decoder.expert_kernel.startswith("xla:"))
+        if moe_layers:
+            counts["moe_layers"] = moe_layers
+        return counts
+
+    # where a block has two kinds of state, the ring's word stands
+    refuses = {}
+    for kind, has in (("loop", looped), ("state", stateful),
+                      ("ring", ringed)):
+        if has:
+            refuses.update(_REFUSALS[kind])
+
+    decoder = PagedDecoder(
         step=step, step_window=step_window, step_logits=step_logits,
         step_routing=(step_routing if spec.ffn == "moe_swiglu" or looped
                       else None),
@@ -1505,48 +1699,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                        if spec.ffn == "moe_swiglu"
                        else ("exit_gate_open",) if spec.exit_gate
                        else ()),
-        # layers with experts: what `moe_experts_hit` and
-        # `moe_rows_held` are summed over (0: a block without)
-        moe_layers=moe_layers,
-        # a looped stack: the passes a token takes over the one stack
-        # (1: a plain block) and the planes its table pool has
-        passes=passes, kv_planes=planes,
+        moe_layers=moe_layers, passes=passes, kv_planes=planes,
         compiler_scopes=compiler_scopes,
         state_names=sorted(shapes), state_shapes=shapes, block_size=bs,
         max_blocks_per_seq=nb, max_len=max_len, n_layers=n_layers,
         d_model=d_model, vocab_size=vocab_size, kv_dtype=kv_dtype,
         bytes_per_block=bytes_per_block,
-        # the ring of a block with sliding layers: blocks a sequence
-        # (0: every layer is full), the window, and a ring block's bytes
-        window_blocks_per_seq=nw, window=spec.window if ringed else 0,
+        window_blocks_per_seq=nw, window=window,
         window_bytes_per_block=window_bytes_per_block,
-        # attention layers by where their K/V live: on the table (a
-        # plane for every pass of a looped stack), on a slot's ring
         table_layers=planes, ring_layers=n_win,
-        # the Mamba layers' recurrent state: how many layers keep one
-        # (0: none) and the float32 bytes a lane holds over them
         state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
-        # what attends in the resident step (`step`, `step_logits`,
-        # `step_routing`): the streaming Pallas kernel, or the XLA
-        # gather and the reason the kernel was refused; `step_window`
-        # (a window of query rows a slot) runs the gather always
         kernels={"paged_attention_decode":
                  "pallas" if _attend is not None else f"xla:{_refused}",
                  "paged_attention_window":
                  f"xla:{_refused or 'window_rows'}"},
-        # (pages a chunk, pages of its smallest row window) of the
-        # kernel over a slot's table and over its ring: what it copies
-        # and multiplies in (`kernels.paged_attention.rows_multiplied`);
-        # None on the gather path, and for a ring where there is none
-        attention_tiling=(
-            (_attend.tiling(nb), _attend.tiling(nw) if nw else None)
-            if _attend is not None else None),
-        # what the expert layer of the step traced last runs: the
-        # Pallas grouped matmul's name, or "xla:<reason>" where
-        # `ragged_dot` does; None until a step is traced (the weights'
-        # dtype and the rows are the step's arguments, not the
-        # builder's) and for a block without experts
-        expert_kernel=None)
+        attention_tiling=tiling, tick_counts=tick_counts, refuses=refuses)
     return startup, decoder
 
 

@@ -81,7 +81,6 @@ import numpy as np
 from .. import profiler
 from ..core.resilience import (fault_injector,
                                sched_fault_armed as _sched_fault)
-from ..kernels.paged_attention import rows_multiplied
 from ..observability import attribution as obs_attr
 from ..observability import flightrecorder
 from ..observability import metrics as obs_metrics
@@ -98,6 +97,8 @@ MODEL_SPEC_FILENAME = "generation.json"
 MODEL_PARAMS_FILENAME = "generation_params.npz"
 MODEL_DRAFT_PARAMS_FILENAME = "generation_draft_params.npz"
 _SERVER_IDS = itertools.count()
+# draft tokens proposed a slot a speculative tick where `spec_k` is None
+_SPEC_K = 4
 # stats()-backing series are always=True (the stats contract predates
 # the PADDLE_TPU_METRICS switch); latency/depth series are gated.
 _M_REQUESTS = obs_metrics.counter(
@@ -405,35 +406,13 @@ class GenerationServer:
     generator builders).  `slots` bounds concurrent sequences,
     `kv_blocks` is the preallocated pool budget shared by ALL of them.
 
-    A decoder whose block has SLIDING-WINDOW layers
-    (`decoder.window_blocks_per_seq` > 0) keeps two kinds of state:
-    `kv_blocks` stays the table pool of its full layers, allocated
-    and released by the cache manager, and the sliding layers' rings
-    live in a second pool that the server sizes itself and never
-    allocates from: slot s's ring is the blocks `decoder.slot_rings`
-    gives it, the same for every sequence the slot serves (a ring is
-    needed whole by any sequence past the window, so a slot IS a
-    ring; docs/serving.md "Two pools").  Such a decoder is served by
-    the one-token step alone: `prefix_cache=True` and a draft model
-    are refused at construction, by name.
-
-    A decoder whose block has MAMBA layers (`decoder.state_layers`
-    > 0) keeps a third kind: a recurrent state of fixed size a LANE
-    (docs/serving.md "Three kinds of state"), made with the pools and
-    carried in them, which the server never touches: the step itself
-    starts a lane whose cursor is 0 from zero, so admission, eviction
-    and the tick in flight behind them have nothing to reset.  What
-    cannot be right with it is refused here, by name:
-    `prefix_cache=True` and a draft model (`step_window`).
-
-    A decoder with a LOOPED stack (`decoder.passes` > 1) keeps the one
-    table pool, with a plane for every (pass, layer) pair
-    (docs/serving.md "A looped stack"): a block id is common to all
-    planes, so admission, eviction and `prefix_cache=True` are the
-    plain decoder's (a cached block holds every pass's K/V).  A block
-    is `passes` times as large, so the POOL and not the slot count is
-    what `can_admit` runs out of first (`kv_wait` on the tick span).
-    A draft model is refused by name.
+    What the decoder keeps beside the table pool (a ring a slot, a
+    recurrent state a lane, a plane a pass), what its ticks count and
+    what it cannot be served with are the decoder's to say
+    (`PagedDecoder`; docs/serving.md "What a decoder tells the
+    server"): the server sizes the pools it is asked for, hands
+    `_step_tables()` to the step and refuses what `decoder.refuses`
+    names.
     """
 
     def __init__(self, decoder, states, *, slots: int = 8,
@@ -445,7 +424,6 @@ class GenerationServer:
                  spec_k: Optional[int] = None):
         import jax
 
-        from ..core import flags as core_flags
         from ..core.executor import TPUPlace
 
         def _check_states(dec, sts, who):
@@ -461,8 +439,7 @@ class GenerationServer:
             # position table out of bounds inside jit, where gathers
             # CLAMP — silently wrong tokens instead of an error.
             bad = [(n, tuple(np.shape(sts[n])), want)
-                   for n, want in getattr(dec, "state_shapes",
-                                          {}).items()
+                   for n, want in dec.state_shapes.items()
                    if tuple(np.shape(sts[n])) != want]
             if bad:
                 n, got, want = bad[0]
@@ -475,44 +452,11 @@ class GenerationServer:
                     "parameters")
 
         _check_states(decoder, states, "target")
-        ring = int(getattr(decoder, "window_blocks_per_seq", 0))
-        if ring and draft_decoder is not None:
-            raise ValueError(
-                "a decoder with sliding-window layers takes no draft "
-                "model: speculative verification writes a window of "
-                "positions before it attends, which a ring one window "
-                "long cannot hold (build_lm_paged_decoder's "
-                "step_window refuses it too)")
-        if ring and prefix_cache:
-            raise ValueError(
-                "prefix_cache=True with sliding-window layers: a "
-                "cached prompt block's sliding-layer K/V lives in the "
-                "ring of the slot that wrote it and is overwritten as "
-                "that slot goes on, so a later hit would attend over "
-                "another request's keys; pass prefix_cache=False")
-        stateful = int(getattr(decoder, "state_layers", 0))
-        if stateful and draft_decoder is not None:
-            raise ValueError(
-                "a decoder with Mamba layers takes no draft model: "
-                "speculative verification runs a window of positions "
-                "through step_window, and a recurrent state is "
-                "computed one position a step (and cannot be rolled "
-                "back over rejected tokens)")
-        if stateful and prefix_cache:
-            raise ValueError(
-                "prefix_cache=True with Mamba layers: a hit starts a "
-                "sequence past position 0, where the attention "
-                "layers find the prompt's K/V in the shared blocks "
-                "but a lane has no recurrent state for it (no "
-                "snapshot is kept); pass prefix_cache=False")
-        if int(getattr(decoder, "passes", 1)) > 1 and (
-                draft_decoder is not None):
-            raise ValueError(
-                "a decoder with a looped stack takes no draft model: "
-                "speculative verification runs a window of positions "
-                "through step_window, which is not built for a stack "
-                "run several passes a token (build_lm_paged_decoder's "
-                "step_window refuses it too)")
+        # what a block cannot be served with is the block's to say
+        for what, asked in (("draft_model", draft_decoder is not None),
+                            ("prefix_cache", prefix_cache)):
+            if asked and what in decoder.refuses:
+                raise ValueError(decoder.refuses[what])
         if (draft_decoder is None) != (draft_states is None):
             raise ValueError(
                 "speculative decoding needs BOTH draft_decoder and "
@@ -533,9 +477,7 @@ class GenerationServer:
                 raise ValueError("draft/target vocab_size mismatch")
         self._decoder = decoder
         self._draft = draft_decoder
-        self._spec_k = int(spec_k
-                           if spec_k is not None
-                           else core_flags.get_flag("serving_spec_k"))
+        self._spec_k = int(_SPEC_K if spec_k is None else spec_k)
         if draft_decoder is not None and self._spec_k < 1:
             raise ValueError("spec_k must be >= 1 with a draft model")
         self._slots = int(slots)
@@ -558,9 +500,9 @@ class GenerationServer:
                                           self._device)
                         for n in decoder.state_names}
         sid = self._sid = str(next(_SERVER_IDS))
-        bpb = getattr(decoder, "bytes_per_block", 0)
+        bpb = decoder.bytes_per_block
         if draft_decoder is not None:
-            bpb += getattr(draft_decoder, "bytes_per_block", 0)
+            bpb += draft_decoder.bytes_per_block
         self._cache = PagedKVCache(
             kv_blocks, decoder.block_size, decoder.max_blocks_per_seq,
             server_label=f"gen{sid}", prefix_cache=prefix_cache,
@@ -574,15 +516,14 @@ class GenerationServer:
         # deterministic in the prefix), so they keep full sharing;
         # for int8 the submit-time keys drop the last prompt token,
         # which excludes exactly the aligned final block.
-        self._kv_int8 = (
-            getattr(decoder, "kv_dtype", "fp32") == "int8"
-            or (draft_decoder is not None
-                and getattr(draft_decoder, "kv_dtype", "fp32")
-                == "int8"))
+        self._kv_int8 = "int8" in (
+            decoder.kv_dtype,
+            draft_decoder.kv_dtype if draft_decoder is not None else None)
         # +1: device block 0 is the reserved null/scratch block
         # (and of the sliding layers' pool, which holds a whole ring
         # for every slot beside it: the table pool alone decides how
         # many sequences fit)
+        ring = decoder.window_blocks_per_seq
         self._pool_k, self._pool_v = decoder.init_pool(
             kv_blocks + 1, self._device,
             window_blocks=ring * self._slots + 1, lanes=self._slots)
@@ -615,26 +556,6 @@ class GenerationServer:
         self._rings = jax.device_put(
             decoder.slot_rings(self._slots),
             self._device) if ring else None
-        self._window = int(getattr(decoder, "window", 0))
-        # K/V pages a slot holds over the attention layers (a table's
-        # blocks on the full layers, a ring's on the sliding ones), and
-        # whether the resident step's attention reads only those the
-        # cursor has reached (the Pallas kernel) or all of them
-        self._kv_layers = (int(getattr(decoder, "table_layers", 0)),
-                           int(getattr(decoder, "ring_layers", 0)))
-        self._kv_pages_table = self._slots * (
-            self._kv_layers[0] * decoder.max_blocks_per_seq
-            + self._kv_layers[1] * ring)
-        self._kv_streamed = getattr(decoder, "kernels", {}).get(
-            "paged_attention_decode") == "pallas"
-        # (pages a chunk, pages a row tile) the kernel copies and
-        # multiplies in, over a table and over a ring
-        self._kv_tiling = getattr(decoder, "attention_tiling", None)
-        # Mamba layers: a lane's recurrent state rides in the pools
-        self._stateful = bool(stateful)
-        # a looped stack: the passes a tick's step runs (1: a plain one)
-        self._passes = int(getattr(decoder, "passes", 1))
-        self._moe_layers = int(getattr(decoder, "moe_layers", 0))
         # the last admission left the queue's head waiting for BLOCKS
         # with a slot free (on the next tick's span as `kv_wait`)
         self._kv_wait = False
@@ -707,8 +628,7 @@ class GenerationServer:
             # step's compiled text later (shapes, no buffers)
             profiler.register_jitted(
                 "paged_decoder.step", self._decoder.step, *args,
-                compiler_scopes=getattr(self._decoder,
-                                        "compiler_scopes", None))
+                compiler_scopes=self._decoder.compiler_scopes)
             nxt, self._pool_k, self._pool_v, *_ = self._decoder.step(
                 *args)
             np.asarray(nxt)  # block: compile is done when this returns
@@ -928,16 +848,15 @@ class GenerationServer:
                else self._rings.size,
                # the Mamba layers' recurrent state over all lanes (0
                # without): float32, resident whatever the lanes hold
-               "state_bytes": self._slots * int(getattr(
-                   self._decoder, "state_bytes_per_lane", 0)),
-               "kv_dtype": getattr(self._decoder, "kv_dtype", "fp32"),
-               "decode_kernel": getattr(self._decoder, "kernels", {})
-               .get("paged_attention_decode", "xla"),
+               "state_bytes": (self._slots
+                               * self._decoder.state_bytes_per_lane),
+               "kv_dtype": self._decoder.kv_dtype,
+               "decode_kernel":
+               self._decoder.kernels["paged_attention_decode"],
                # the expert layer of the step traced last: the Pallas
                # grouped matmul's name or "xla:<reason>"; None for a
                # block without experts
-               "expert_kernel": getattr(self._decoder, "expert_kernel",
-                                        None),
+               "expert_kernel": self._decoder.expert_kernel,
                "kv_bytes_resident": (self._cache.used_blocks
                                      * self._cache.bytes_per_block),
                "draft_proposed": int(self._m_proposed.value),
@@ -1255,85 +1174,24 @@ class GenerationServer:
 
     def _tick_attrs(self, n: int, prefill: int, cur: np.ndarray,
                     window: bool = False) -> dict:
-        """The scheduler's counts for the tick being dispatched, for
-        its `serving.decode_tick` span, from what `build` has already
-        made: `n` slots, `prefill` of them teacher-forcing a prompt
-        position (cursor below prompt_len - 1: they deliver nothing),
-        and `cur`, the step's `positions` at those slots.  Only called
-        while a span is live.  `kv_used` of `kv_total` pool blocks are
-        owned.  `kv_pages_read` of `kv_pages_table`: the K/V pages the
-        dispatched step's attention reads, summed over slots and
-        attention layers, of the pages the slots' tables and rings
-        hold (slots x pages a slot x layers).  Where the step attends
-        through the Pallas kernel (`decoder.kernels`; never a
-        `step_window` tick: `window`) those are the pages each cursor
-        has reached, and one a layer for a slot with no sequence; on
-        the gather path every page.  `kv_rows_multiplied`: the K/V
-        rows the step's two products run over, summed the same way:
-        through the kernel, for each chunk of a slot's pages, the
-        smallest row window that holds them (the tile of
-        `decoder.attention_tiling` doubled up to the chunk), on the
-        gather path every row of the table.
-        With sliding layers also `past_window` (slots whose
-        cursor is at or past the window: their rings have wrapped) and
-        the K/V rows the tick has to attend over on a layer of each
-        kind, summed over its slots: `kv_rows_full` (cursor + 1) and
-        `kv_rows_win` (the window at most).  With Mamba layers
-        `state_lanes` (lanes of the tick with a recurrent state: all
-        its slots) and `state_resets` (those at position 0, which the
-        step starts from a zero state).  With experts `moe_kernel`:
-        1 where the step's expert layer is the Pallas grouped matmul
-        (`decoder.expert_kernel`), 0 where `ragged_dot`, and
-        `moe_layers`: the layers with experts (`decoder.moe_layers`),
-        over which the step's counts are summed.  `kv_wait`: 1
-        where the admission before this tick left the queue's head
-        waiting with a slot free because `can_admit` refused it for
-        blocks.  With a looped stack `loop_passes` (the passes the
-        step runs) and `kv_planes` (passes x layers: the planes that
-        `kv_pages_read` and `kv_pages_table` are counted over)."""
-        attrs = {"prefill": prefill,
-                 "kv_used": self._cache.used_blocks,
-                 "kv_total": self._cache.num_blocks,
-                 "kv_wait": int(self._kv_wait)}
-        if self._passes > 1:
-            attrs["loop_passes"] = self._passes
-            attrs["kv_planes"] = self._kv_layers[0]
-        rows = cur.astype(np.int64) + 1      # K/V rows a slot attends
-        bs = self._cache.block_size
-        read = self._kv_pages_table
-        multiplied = read * bs
-        if self._kv_streamed and not window:
-            full, win = self._kv_layers
-            idle = self._slots - n           # a page each, a layer
-            read = multiplied = 0
-            kinds = [(full, -(-rows // bs), self._kv_tiling[0])]
-            if win:
-                ring_rows = self._rings.shape[1] * bs
-                kinds.append((win, -(-np.minimum(rows, ring_rows) // bs),
-                              self._kv_tiling[1]))
-            for layers, pages, (chunk, tile) in kinds:
-                read += layers * (idle + int(pages.sum()))
-                multiplied += layers * int(
-                    idle * rows_multiplied(1, chunk, tile, bs)
-                    + rows_multiplied(pages, chunk, tile, bs).sum())
-        attrs["kv_pages_read"] = read
-        attrs["kv_pages_table"] = self._kv_pages_table
-        attrs["kv_rows_multiplied"] = multiplied
-        if self._window:
-            attrs["past_window"] = int((rows > self._window).sum())
-            attrs["kv_rows_full"] = int(rows.sum())
-            attrs["kv_rows_win"] = int(
-                np.minimum(rows, self._window).sum())
-        if self._stateful:
-            attrs["state_lanes"] = n
-            attrs["state_resets"] = n - int(np.count_nonzero(cur))
-        expert_kernel = getattr(self._decoder, "expert_kernel", None)
-        if expert_kernel is not None:
-            attrs["moe_kernel"] = int(
-                not expert_kernel.startswith("xla:"))
-        if self._moe_layers:
-            attrs["moe_layers"] = self._moe_layers
-        return attrs
+        """The counts of the tick being dispatched, for its
+        `serving.decode_tick` span, from what `build` has already made:
+        `n` slots (`len(cur)`), `prefill` of them teacher-forcing a
+        prompt position (cursor below prompt_len - 1: they deliver
+        nothing), and `cur`, the step's `positions` at those slots.
+        Only called while a span is live.  The scheduler's own:
+        `prefill`, `kv_used` of `kv_total` pool blocks owned, and
+        `kv_wait`: 1 where the admission before this tick left the
+        queue's head waiting with a slot free because `can_admit`
+        refused it for blocks.  What the step reads and does at those
+        cursors is the decoder's to count (`decoder.tick_counts`; never
+        through the kernel on a `step_window` tick: `window`)."""
+        return {"prefill": prefill,
+                "kv_used": self._cache.used_blocks,
+                "kv_total": self._cache.num_blocks,
+                "kv_wait": int(self._kv_wait),
+                **self._decoder.tick_counts(cur, self._slots,
+                                            windowed=window)}
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live

@@ -1028,44 +1028,79 @@ def test_a_pool_smaller_than_slots_x_context_admits_by_blocks(kind):
 
 def _old_tick_attrs(srv, seqs, window=False):
     """`GenerationServer._tick_attrs` as it was before PR 36, a walk of
-    its own over `seqs` for each count: the oracle."""
+    its own over `seqs` for each count: the oracle.  What it knows of
+    the block it reads from the decoder's declared fields."""
+    dec = srv._decoder
     out = {"prefill": sum(1 for s in seqs if s.cur < s.prompt_len - 1),
            "kv_used": srv._cache.used_blocks,
            "kv_total": srv._cache.num_blocks}
-    read = srv._kv_pages_table
-    if srv._kv_streamed and not window:
-        bs = srv._cache.block_size
-        full, win = srv._kv_layers
-        ring_rows = 0 if srv._rings is None else (
-            srv._rings.shape[1] * bs)
+    full, win = dec.table_layers, dec.ring_layers
+    table = srv._slots * (full * dec.max_blocks_per_seq
+                          + win * dec.window_blocks_per_seq)
+    read = table
+    if dec.kernels["paged_attention_decode"] == "pallas" and not window:
+        bs = dec.block_size
+        ring_rows = dec.window_blocks_per_seq * bs
         read = (srv._slots - len(seqs)) * (full + win) + sum(
             full * -(-(s.cur + 1) // bs)
             + win * -(-min(s.cur + 1, ring_rows) // bs) for s in seqs)
     out["kv_pages_read"] = read
-    out["kv_pages_table"] = srv._kv_pages_table
-    if srv._window:
-        out["past_window"] = sum(1 for s in seqs if s.cur >= srv._window)
+    out["kv_pages_table"] = table
+    if dec.window:
+        out["past_window"] = sum(1 for s in seqs if s.cur >= dec.window)
         out["kv_rows_full"] = sum(s.cur + 1 for s in seqs)
-        out["kv_rows_win"] = sum(min(s.cur + 1, srv._window)
+        out["kv_rows_win"] = sum(min(s.cur + 1, dec.window)
                                  for s in seqs)
-    if srv._stateful:
+    if dec.state_layers:
         out["state_lanes"] = len(seqs)
         out["state_resets"] = sum(1 for s in seqs if s.cur == 0)
-    expert_kernel = getattr(srv._decoder, "expert_kernel", None)
-    if expert_kernel is not None:
-        out["moe_kernel"] = int(not expert_kernel.startswith("xla:"))
+    if dec.expert_kernel is not None:
+        out["moe_kernel"] = int(not dec.expert_kernel.startswith("xla:"))
     return out
 
 
-def _block_decoder(kind):
+@contextlib.contextmanager
+def _streamed_kernel(chunk_bytes=512, tile_rows=4):
+    """Inside, `build_lm_paged_decoder` selects the paged-attention
+    kernel on the CPU (the Pallas interpreter: the entry point's own
+    argument for tests), as it stands in row tiles of one 4-row page
+    and chunks of 512 bytes, a toy page or two: the products then run
+    over exactly the pages read."""
+    import functools
+
+    from paddle_tpu.kernels import paged_attention
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_attention, "select_paged_attention",
+                   functools.partial(
+                       paged_attention.select_paged_attention,
+                       interpret=True))
+        mp.setattr(paged_attention, "_CHUNK_BYTES", chunk_bytes)
+        mp.setattr(paged_attention, "_TILE_ROWS", tile_rows)
+        yield
+
+
+def _block_decoder(kind, streamed=False, **tiling):
     """A decoder of each kind of state at toy widths: the table alone,
     a ring beside it (sliding layers), a recurrent state a lane, a
-    table with a plane for every pass of a looped stack."""
+    table with a plane for every pass of a looped stack.  `streamed`:
+    its step attends through the kernel (`_streamed_kernel`, which
+    takes `tiling`)."""
     from paddle_tpu.models import lm_block
-    from paddle_tpu.models.transformer import build_lm_paged_decoder
+    from paddle_tpu.models import transformer
+
+    def build_lm_paged_decoder(*args, **kw):
+        with (_streamed_kernel(**tiling) if streamed
+              else contextlib.nullcontext()):
+            return transformer.build_lm_paged_decoder(*args, **kw)
 
     if kind == "table":
-        return _decoder(max_blocks=6)
+        dec, states = _decoder(max_blocks=6)
+        if streamed:
+            fw.reset_unique_names()     # the same parameters' names
+            _, dec = build_lm_paged_decoder(V, 4, 6, d_model=32,
+                                            n_heads=2, n_layers=2)
+        return dec, states
     if kind == "loop":
         spec = lm_block.BlockSpec(
             name="loop", norm="rms_norm", positions="rope", ffn="swiglu",
@@ -1107,14 +1142,12 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     """The counts on `serving.decode_tick` come out of the one loop
     `build` runs and the `positions` it fills: tick for tick they are
     what the old `_tick_attrs` computed from the same sequences in up
-    to seven walks.  `streamed` reads the pages as the Pallas kernel
-    would (the attribute alone: the CPU's step gathers)."""
-    dec, states = _block_decoder(kind)
+    to seven walks.  `streamed`: the step reads the pages through the
+    Pallas kernel (interpreted), else it gathers."""
+    dec, states = _block_decoder(kind, streamed)
+    assert (dec.kernels["paged_attention_decode"] == "pallas") == streamed
     srv = GenerationServer(dec, states, slots=3, kv_blocks=18,
                            place=fluid.CPUPlace(), prefix_cache=False)
-    srv._kv_streamed = streamed
-    # row tiles of a page: the products then run over the pages read
-    srv._kv_tiling = ((2, 1), (2, 1))
     want = []
     tick = srv._tick
     srv._tick = lambda seqs: (want.append(
@@ -1122,19 +1155,24 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
         tick(seqs))[1]
     requests = [([3, 1, 4, 1, 5], 12), ([2, 7], 20), ([1], 9),
                 ([6, 2, 8, 3, 1, 8, 5], 14), ([4, 4], 3)]
+    if streamed:
+        # the interpreter takes a second a tick: a slot is still given
+        # twice, and a cursor still passes the ring's window of 8
+        requests = [([6, 2, 8, 3, 1, 8, 5], 4), ([2, 7], 3), ([1], 2),
+                    ([4, 4, 9], 2)]
     try:
         with _tick_spans() as ticks:
             for s in [srv.submit(p, m) for p, m in requests]:
                 s.result(timeout=60)
     finally:
         srv.close()
-    assert len(ticks) == len(want) >= 30
+    assert len(ticks) == len(want) >= (10 if streamed else 30)
     # what PR 38 added beside the old walks' counts
     loop = {"loop_passes": 3, "kv_planes": 6} if kind == "loop" else {}
     # and PR 40: the layers with experts, on a block that has any
     # and PR 41: the rows the attention's products run over
     extra = ({"ahead", "kv_wait", "moe_layers", "kv_rows_multiplied"}
-             | set(loop) | set(getattr(dec, "step_counters", ())))
+             | set(loop) | set(dec.step_counters))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old
         assert got["kv_rows_multiplied"] == (
@@ -1151,6 +1189,122 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
         assert any(a["kv_pages_read"] < a["kv_pages_table"] for a in want)
     if kind == "state":
         assert sum(a["state_resets"] for a in want) == len(requests)
+
+
+# What `decoder.tick_counts` says of one step of 4 lanes, three of them
+# at cursors 0, 9 and 21 (1, 3 and 6 pages of 4 rows; a lane with no
+# sequence reads a page), through the kernel in chunks of 2048 bytes and
+# row tiles of 2 pages, worked out by hand.  Rows multiplied, in pages:
+# a table in chunks of 4 (toy pages of 512 bytes) takes 2, 4 and 4 + 2
+# for those three lanes and 2 for the idle one, 14; a table in one chunk
+# of 6 (pages of 256 bytes) windows of 2, 4, 6 and 2: 14 as well; a
+# ring of 2 pages is one tile: 2 a lane, 8.
+_TICK_COUNTS = {
+    # 2 layers on a table of 6 pages
+    "table": dict(
+        tiling=((4, 2), None),
+        streamed={"kv_pages_read": 2 * 11, "kv_pages_table": 48,
+                  "kv_rows_multiplied": 2 * 14 * 4},
+        gathered={"kv_pages_read": 48, "kv_pages_table": 48,
+                  "kv_rows_multiplied": 192}),
+    # a full layer, and 3 sliding ones on a ring of 2 pages (window 8:
+    # two cursors are past it), experts on all 4
+    "ring": dict(
+        tiling=((6, 2), (2, 2)),
+        streamed={"kv_pages_read": 11 + 3 * (1 + 1 + 2 + 2),
+                  "kv_pages_table": 4 * (6 + 3 * 2),
+                  "kv_rows_multiplied": (14 + 3 * 8) * 4,
+                  "past_window": 2, "kv_rows_full": 1 + 10 + 22,
+                  "kv_rows_win": 1 + 8 + 8, "moe_layers": 4},
+        gathered={"kv_pages_read": 48, "kv_pages_table": 48,
+                  "kv_rows_multiplied": 192, "past_window": 2,
+                  "kv_rows_full": 33, "kv_rows_win": 17,
+                  "moe_layers": 4}),
+    # an attention layer among 3 Mamba layers: a state a lane, one of
+    # the three at position 0
+    "state": dict(
+        tiling=((6, 2), None),
+        streamed={"kv_pages_read": 11, "kv_pages_table": 24,
+                  "kv_rows_multiplied": 14 * 4, "state_lanes": 3,
+                  "state_resets": 1, "moe_layers": 4},
+        gathered={"kv_pages_read": 24, "kv_pages_table": 24,
+                  "kv_rows_multiplied": 96, "state_lanes": 3,
+                  "state_resets": 1, "moe_layers": 4}),
+    # 2 layers x 3 passes: 6 planes of the one table
+    "loop": dict(
+        tiling=((4, 2), None),
+        streamed={"loop_passes": 3, "kv_planes": 6,
+                  "kv_pages_read": 6 * 11, "kv_pages_table": 144,
+                  "kv_rows_multiplied": 6 * 14 * 4},
+        gathered={"loop_passes": 3, "kv_planes": 6,
+                  "kv_pages_read": 144, "kv_pages_table": 144,
+                  "kv_rows_multiplied": 576}),
+}
+
+
+@pytest.mark.parametrize("kind", list(_TICK_COUNTS))
+def test_decoder_tick_counts_at_hand_written_cursors(kind):
+    """`decoder.tick_counts(cursors, slots)` is the builder's own
+    account of a dispatched step, asked with no server: through the
+    kernel the pages the cursors reach and the row windows that hold
+    them, on the gather path (and on any `step_window` tick) the whole
+    table; the names a kind of block adds, and no other."""
+    want = _TICK_COUNTS[kind]
+    cursors = np.array([0, 9, 21], np.int32)
+    dec, _ = _block_decoder(kind, streamed=True, chunk_bytes=2048,
+                            tile_rows=8)
+    assert dec.attention_tiling == want["tiling"]
+    assert dec.tick_counts(cursors, 4) == want["streamed"]
+    assert dec.tick_counts(cursors, 4, windowed=True) == want["gathered"]
+    gathers, _ = _block_decoder(kind)
+    assert gathers.attention_tiling is None
+    assert gathers.tick_counts(cursors, 4) == want["gathered"]
+    assert all(type(v) is int
+               for v in dec.tick_counts(cursors, 4).values())
+    # no step traced yet: `moe_kernel` comes with `expert_kernel`
+    assert dec.expert_kernel is None
+    if "moe_layers" in want["streamed"]:
+        for name, flag in (("xla:not_tpu", 0), ("grouped_matmul", 1)):
+            gathers.expert_kernel = name
+            assert gathers.tick_counts(cursors, 4) == dict(
+                want["gathered"], moe_kernel=flag)
+    # no lane holds a sequence: a page a lane a layer
+    assert dec.tick_counts(cursors[:0], 4)["kv_pages_read"] == 4 * (
+        dec.table_layers + dec.ring_layers)
+
+
+@pytest.mark.parametrize("block", ["opt", "olmoe"])
+def test_a_block_on_the_table_alone_refuses_nothing(block):
+    """`decoder.refuses` names what a block cannot be served with, and
+    the server raises it as it stands: nothing for `lm_block.OPT` and
+    `lm_block.olmoe(...)`, whose state is the table pool alone (the
+    ring's, the recurrent state's and the loop's words are in their
+    own decoders' tests), so both take a draft model and the prefix
+    cache."""
+    from paddle_tpu.models import lm_block
+    from paddle_tpu.models.transformer import (PagedDecoder,
+                                               build_lm_paged_decoder)
+
+    draft, draft_states = dec, states = _decoder()
+    if block == "olmoe":
+        _, dec = build_lm_paged_decoder(
+            V, 4, 5, d_model=32, n_heads=2, n_layers=2, d_inner=16,
+            block=lm_block.olmoe(4, 2), platform="cpu")
+        rng = np.random.RandomState(0)
+        states = {n: (0.05 * rng.randn(*shape)).astype(np.float32)
+                  for n, shape in dec.state_shapes.items()}
+    assert isinstance(dec, PagedDecoder) and dec.refuses == {}
+    srv = GenerationServer(dec, states, slots=2, kv_blocks=12,
+                           place=fluid.CPUPlace(), prefix_cache=True,
+                           draft_decoder=draft, draft_states=draft_states)
+    try:
+        assert srv.stats()["spec_k"] == 4        # where nothing says
+        assert len(srv.submit([3, 1, 4], 5).result(timeout=60)) == 5
+    finally:
+        srv.close()
+    # a field the decoder does not declare fails by name
+    with pytest.raises(AttributeError, match="kv_pages"):
+        dec.kv_pages
 
 
 # ---------------------------------------------------------------------------
@@ -1970,3 +2124,38 @@ def test_lint_covers_serving_package(tmp_path):
     assert not list(lint_mod.check_silent_excepts(ok, "serving/x.py"))
     # and the shipped serving package itself is clean
     assert lint_mod.lint([serving_dir]) == 0
+
+
+@pytest.mark.parametrize("package,forbidden", [
+    # what a dispatched step reads is the decoder's to say
+    # (`decoder.tick_counts`): the scheduler asks no kernel
+    ("serving", "kernels"),
+    ("models", "serving"), ("kernels", "serving")])
+def test_the_serving_path_imports_one_way(package, forbidden):
+    """serving/ -> models/ -> kernels/, and never around or back: no
+    module of `package` imports `paddle_tpu.<forbidden>`, at its top or
+    inside a function (the files are parsed, not imported)."""
+    import ast
+    import glob
+
+    def imported(path):
+        """Dotted names `path` imports, relative ones as written from
+        the package's own directory (`..kernels.x` -> `kernels.x`)."""
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                yield from (a.name for a in node.names)
+            elif isinstance(node, ast.ImportFrom):
+                module = node.module or ""
+                if node.level == 1:
+                    module = f"{package}.{module}"
+                yield from (f"{module}.{a.name}".lstrip(".")
+                            for a in node.names)
+
+    files = glob.glob(os.path.join(REPO, "paddle_tpu", package, "*.py"))
+    assert len(files) >= 3
+    bad = [(os.path.basename(path), name)
+           for path in files for name in imported(path)
+           if forbidden in name.replace("paddle_tpu.", "").split(".")[:1]]
+    assert bad == []

@@ -63,8 +63,7 @@ def _paged(name, sharding=None):
     d_head = D_HEAD.get(name, 128)
     kern, reason = select_paged_attention(
         d_model=h * d_head, n_heads=h, d_head=d_head, kv_width=d_kv,
-        block_size=bs, max_blocks_per_seq=nb, kv_dtype=kv_dtype,
-        platform="tpu")
+        block_size=bs, kv_dtype=kv_dtype, platform="tpu")
     assert reason is None
     dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
 

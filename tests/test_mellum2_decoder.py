@@ -367,6 +367,10 @@ def test_generation_server_serves_both_kinds_of_state():
     dec = _decoder()
     g = {n: np.asarray(v) for n, v in _weights(dec).items()}
     place = fluid.CPUPlace()
+    # the block's own word, which the server raises as it stands
+    assert set(dec.refuses) == {"draft_model", "prefix_cache"}
+    assert all("sliding-window layers" in why
+               for why in dec.refuses.values())
     with pytest.raises(ValueError, match="prefix_cache=True"):
         GenerationServer(dec, g, slots=2, kv_blocks=16, place=place)
     with pytest.raises(ValueError, match="no draft model"):

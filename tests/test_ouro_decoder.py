@@ -323,6 +323,8 @@ def test_generation_server_serves_a_looped_stack():
     dec = _decoder()
     g = {n: np.asarray(v) for n, v in _weights(dec).items()}
     place = fluid.CPUPlace()
+    # the block's own word: a draft model alone, the prefix cache works
+    assert set(dec.refuses) == {"draft_model"}
     with pytest.raises(ValueError, match="looped stack takes no draft"):
         GenerationServer(dec, g, slots=2, kv_blocks=16, place=place,
                          prefix_cache=False, draft_decoder=dec,

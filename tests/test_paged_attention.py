@@ -433,8 +433,6 @@ def _cell_geometry(workload):
     geometry = dict(d_model=m["hidden_size"],
                     n_heads=m["num_attention_heads"],
                     block_size=int(t["block_size"]),
-                    max_blocks_per_seq=(int(t["context"])
-                                        // int(t["block_size"])),
                     kv_dtype=t["kv_dtype"])
     if "num_key_value_heads" in m:
         # a configuration that states its K/V geometry: what the
@@ -443,8 +441,7 @@ def _cell_geometry(workload):
         d_head = m.get("head_dim", m["hidden_size"]
                        // m["num_attention_heads"])
         geometry.update(
-            d_head=d_head, kv_width=m["num_key_value_heads"] * d_head,
-            ringed="sliding_attention" in m.get("layer_types", ()))
+            d_head=d_head, kv_width=m["num_key_value_heads"] * d_head)
     return geometry
 
 
@@ -472,8 +469,7 @@ def _cell_expert_shapes(workload):
                 n_experts=held, dtype=m["dtype"])
 
 
-CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
-                  max_blocks_per_seq=32)
+CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16)
 
 
 @pytest.mark.parametrize("geometry,platform,interpret,want", [
@@ -494,7 +490,10 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16,
     # block-diagonal by K/V head, whatever the heads
     (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=256), "tpu", False, None),
     (dict(CHIP_SMOKE, kv_dtype="bf16", d_head=256), "tpu", False, None),
-    (dict(CHIP_SMOKE, kv_dtype="bf16", ringed=True), "tpu", False, None),
+    # d6144, 64 query heads of 128 over 8 K/V heads, sliding layers on
+    # a ring: the selection takes no word on rings or on a table's
+    # length (a ring is a table, the scratch is two chunks)
+    ("k-exaone-236b-a23b-serve-chat64", "tpu", False, None),
     # what Mosaic's tiling refuses: a pool row off the 128-lane grid, a
     # page that is not whole sublane tiles of its dtype (16 rows of bf16)
     (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=192), "tpu", False,
@@ -527,8 +526,11 @@ def test_selection_follows_geometry_and_platform(geometry, platform,
         platform=platform, interpret=interpret, **geometry)
     assert reason == want
     assert (kern is None) == (want is not None)
+    # the heads lay the query out; they refuse nothing
+    pool = {k: v for k, v in geometry.items()
+            if k not in ("n_heads", "d_head")}
     assert paged_attention.paged_attention_supports(
-        platform=platform, interpret=interpret, **geometry) == want
+        platform=platform, interpret=interpret, **pool) == want
 
 
 @pytest.mark.parametrize("workload,held,rows", [
@@ -560,7 +562,7 @@ def test_unsupported_shape_is_refused_with_its_reason():
 
     # a pool row of 64 columns: half a lane tile
     geometry = dict(d_model=64, n_heads=2, block_size=16,
-                    max_blocks_per_seq=4, kv_dtype="fp32")
+                    kv_dtype="fp32")
     assert paged_attention.select_paged_attention(
         platform="tpu", **geometry) == (None, "lane_misaligned")
     fw.reset_unique_names()

@@ -148,10 +148,13 @@ def test_pipeline_executor_rebuilds_on_each_trace_flag(
 
 @pytest.mark.parametrize("name", [
     "serving_kernels", "flash_pack_heads", "flash_block_q",
-    "flash_block_k", "conv_layout"])
+    "flash_block_k", "conv_layout", "serving_kv_dtype",
+    "serving_spec_k"])
 def test_deleted_flags_are_refused_by_name(name):
     """What each decided is now worked out from shape and platform
-    where the kernel or the op lives; nothing is left to set."""
+    where the kernel or the op lives (the last two: an argument of the
+    builder and of the server, which every caller passes); nothing is
+    left to set."""
     assert name not in flags.flag_defaults()
     with pytest.raises(KeyError, match=name):
         set_flags({name: get_flag("benchmark")})
