@@ -53,6 +53,18 @@ it donates them).  The step's two XLA scatters a layer, 7.6 us each on
 the v5e and most of that launch, are gone; a row alone cannot go back,
 because a DMA moves whole sublane tiles (PERF.md section 6, PR 41).
 
+A LATENT pool is the same kernel over ONE array (`pool_v` None,
+`d_value` columns): a row is a compressed latent and one rotated key
+part that ALL heads share, and the value is the row's own first
+`d_value` columns.  One copy a chunk serves both products: the scores
+are `q . row^T` over the whole row with a DENSE query [H, row] (one
+"K/V head" for every query head: the block-diagonal operand's
+degenerate case, so nothing is masked), the context `p . row[:,
+:d_value]` from the same VMEM buffer, [S, H*d_value] float32 out; the
+position's one row is written inside as K and V are.  At 128 heads a
+row's two products are 240 operations a byte: the one kernel here that
+the MXU can pace (PERF.md section 6, PR 45).
+
 The call sits behind one module-level `jax.jit` (`paged_attention`),
 the layer a TRACED scalar: the body is traced once a process for a set
 of shapes and lowered once a program however many layers call it (an
@@ -100,7 +112,8 @@ _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 def paged_attention_supports(*, d_model: int, block_size: int,
                              kv_dtype: str, platform: str,
                              interpret: bool = False,
-                             kv_width: Optional[int] = None
+                             kv_width: Optional[int] = None,
+                             value_width: Optional[int] = None
                              ) -> Optional[str]:
     """None when `select_paged_attention` would return the kernel for
     this pool on `platform`, else the short reason it is refused (what
@@ -115,7 +128,9 @@ def paged_attention_supports(*, d_model: int, block_size: int,
     length are no parameters because they change nothing it refuses:
     the kernel lays the query out block-diagonal by K/V head whatever
     the heads, a ring is a table, and the scratch is two chunks
-    whatever the context."""
+    whatever the context.  `value_width`: a LATENT pool, one array
+    whose row (`kv_width`, as stored) every head reads whole and whose
+    first `value_width` columns are the value."""
     if platform != "tpu" and not interpret:
         return "not_tpu"
     if kv_dtype not in _KV_DTYPES:
@@ -126,7 +141,7 @@ def paged_attention_supports(*, d_model: int, block_size: int,
         # Mosaic tiling: a pool row on the 128-lane grid, a page a whole
         # number of the dtype's sublane tiles (8 rows of float32, 16 of
         # bfloat16), so a page lands in the scratch as whole tiles
-        if (kv_width or d_model) % 128:
+        if (kv_width or d_model) % 128 or (value_width or 0) % 128:
             return "lane_misaligned"
         if block_size % (32 // jnp.dtype(_KV_DTYPES[kv_dtype]).itemsize):
             return "sublane_misaligned"
@@ -158,19 +173,33 @@ def rows_multiplied(n_pages, pages: int, tile: int, block_size: int):
 
 
 def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
-            windows, scale, h, dh, n_kv, writes):
+            windows, scale, h, dh, n_kv, writes, d_value=0):
     """Grid step s: slot s's attention over its first
     `ceil(lengths[s] / bs)` pages of layer `layer[0]`, copied a chunk
     of `pages` pages at a time and multiplied over the smallest of
     `windows` (pages, static) that the copied pages fill.
     `cursor_ref[0]` is the buffer (0 or 1) that holds this slot's
-    first chunk, started by the step before."""
-    if writes:
-        (wrow_ref, q_ref, k_new_ref, v_new_ref, k_hbm, v_hbm, o_ref, k_out,
-         v_out, k_buf, v_buf, acc_ref, sems, cursor_ref, wsems) = refs
-    else:
-        (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, acc_ref, sems,
-         cursor_ref) = refs
+    first chunk, started by the step before.  `d_value`: a latent
+    pool, ONE array whose first `d_value` columns are the value (0: a
+    K pool and a V pool)."""
+    n_pools = 1 if d_value else 2
+    refs = iter(refs)
+
+    def take(n):
+        return tuple(next(refs) for _ in range(n))
+
+    # as `paged_attention` orders them: the written row's scalars and
+    # new rows (a pool each) only where the kernel writes
+    wrow_ref = next(refs) if writes else None
+    q_ref = next(refs)
+    new_refs = take(n_pools if writes else 0)
+    hbms, (o_ref,) = take(n_pools), take(1)
+    outs = take(n_pools if writes else 0)
+    bufs = take(n_pools)
+    acc_ref, sems, cursor_ref = take(3)
+    wsems = next(refs) if writes else None
+    # the keys' buffer, and the values': the same one on a latent pool
+    k_buf, v_buf = bufs[0], bufs[-1]
     s, n_slots = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
     rows = pages * bs
@@ -184,18 +213,18 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         return (lengths_ref[slot] + bs - 1) // bs
 
     def each_page(slot, chunk, buf, do):
-        """`do` each page copy (K, then V) of `slot`'s chunk `chunk`
-        into buffer `buf`: the pages the slot's length reaches, so a
-        table entry past it is never read."""
+        """`do` each page copy (K, then V; a latent row once) of
+        `slot`'s chunk `chunk` into buffer `buf`: the pages the slot's
+        length reaches, so a table entry past it is never read."""
         first = chunk * pages
 
         def page(i, _):
             blk = tables_ref[slot * nb + first + i]
             dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            do(pltpu.make_async_copy(k_hbm.at[layer, blk],
-                                     k_buf.at[buf, dst], sems.at[0, buf]))
-            do(pltpu.make_async_copy(v_hbm.at[layer, blk],
-                                     v_buf.at[buf, dst], sems.at[1, buf]))
+            for i_pool, (hbm, into) in enumerate(zip(hbms, bufs)):
+                do(pltpu.make_async_copy(hbm.at[layer, blk],
+                                         into.at[buf, dst],
+                                         sems.at[i_pool, buf]))
             return 0
 
         jax.lax.fori_loop(0, jnp.minimum(pages, n_pages(slot) - first),
@@ -222,20 +251,26 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     def iota(shape, axis):
         return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
-    kv_head = iota((h, 1), 0)
-    if group > 1:
-        kv_head = jax.lax.div(kv_head, group)
-    col = iota((h, d_kv), 1)
-    own = (col >= kv_head * dh) & (col < kv_head * dh + dh)
-    if group == 1:
-        # q_ref[0] is the projection's row [1, H*dh]: a head's columns
-        # of it ARE its columns of a pool row
-        q_wide = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (h, d_kv))
+    if d_value:
+        # every head reads the whole latent row: q_ref[0] [H, row] IS
+        # the operand
+        q = q_ref[0]
     else:
-        # q_ref[0] is [H, dh]: a head's columns under every K/V head
-        q_wide = jnp.concatenate(
-            [q_ref[0].astype(jnp.float32)] * n_kv, axis=1)
-    q = jnp.where(own, q_wide, 0.0).astype(k_buf.dtype)
+        kv_head = iota((h, 1), 0)
+        if group > 1:
+            kv_head = jax.lax.div(kv_head, group)
+        col = iota((h, d_kv), 1)
+        own = (col >= kv_head * dh) & (col < kv_head * dh + dh)
+        if group == 1:
+            # q_ref[0] is the projection's row [1, H*dh]: a head's
+            # columns of it ARE its columns of a pool row
+            q_wide = jnp.broadcast_to(q_ref[0].astype(jnp.float32),
+                                      (h, d_kv))
+        else:
+            # q_ref[0] is [H, dh]: a head's columns under every K/V head
+            q_wide = jnp.concatenate(
+                [q_ref[0].astype(jnp.float32)] * n_kv, axis=1)
+        q = jnp.where(own, q_wide, 0.0).astype(k_buf.dtype)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def row_copies(c, buf):
@@ -248,12 +283,10 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         blk = tables_ref[s * nb + c * pages + at // bs]
         src = pl.ds(first, group_rows)
         dst = pl.ds(pl.multiple_of(first % bs, group_rows), group_rows)
-        return (pltpu.make_async_copy(k_buf.at[buf, src],
-                                      k_out.at[layer, blk, dst],
-                                      wsems.at[0]),
-                pltpu.make_async_copy(v_buf.at[buf, src],
-                                      v_out.at[layer, blk, dst],
-                                      wsems.at[1]))
+        return tuple(
+            pltpu.make_async_copy(back.at[buf, src],
+                                  out.at[layer, blk, dst], wsems.at[i])
+            for i, (back, out) in enumerate(zip(bufs, outs)))
 
     def put_row(c, buf):
         """Where chunk c holds the row this tick writes (`wrow_ref[s]`
@@ -269,10 +302,9 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
             at = wrow - c * rows
             page = pl.ds(pl.multiple_of(at // bs * bs, bs), bs)
             mine = iota((bs, 1), 0) == at % bs
-            k_buf[buf, page] = jnp.where(mine, k_new_ref[0],
-                                         k_buf[buf, page])
-            v_buf[buf, page] = jnp.where(mine, v_new_ref[0],
-                                         v_buf[buf, page])
+            for into, new_ref in zip(bufs, new_refs):
+                into[buf, page] = jnp.where(mine, new_ref[0],
+                                            into[buf, page])
             for copy in row_copies(c, buf):
                 copy.start()
 
@@ -306,7 +338,8 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
                 m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(sc - m_new)
-                v = v_buf[buf, :n_rows]
+                v = (v_buf[buf, :n_rows, :d_value] if d_value
+                     else v_buf[buf, :n_rows])
                 acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -333,7 +366,9 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     # of all Dkv columns a head keeps its K/V head's
     # (`_own_columns` of the decoder)
     ctx = acc_ref[...] / l
-    if group == 1:
+    if d_value:
+        o_ref[0] = ctx
+    elif group == 1:
         o_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0,
                            keepdims=True)
     else:
@@ -344,10 +379,11 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "pages", "tile", "n_heads", "d_head", "interpret"))
+    "scale", "pages", "tile", "n_heads", "d_head", "d_value", "interpret"))
 def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
                     scale: float, pages: int, tile: int, n_heads: int,
-                    d_head: int, interpret: bool = False, write=None):
+                    d_head: int, d_value: int = 0,
+                    interpret: bool = False, write=None):
     """Attention of one query position a slot over a paged pool.
 
     q [S, H*dh] (the projection's rows; cast to the pools' dtype: what
@@ -366,13 +402,23 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
     into the page as it lies in VMEM before the products read it, and
     from there back into the pools, which are then RETURNED beside the
     result, (out, pool_k, pool_v), the same buffers where the caller
-    donates them: no scatter runs before the kernel."""
-    s_n, h, dh = q.shape[0], n_heads, d_head
+    donates them: no scatter runs before the kernel.
+
+    A LATENT pool: `pool_v` None and `d_value` > 0 (`d_head` is not
+    read).  `pool_k`'s row is then what every head attends over, q
+    [S, H*Dkv] a whole row a head, the result [S, H*d_value] float32:
+    head i's `softmax(scale * q_i . rows^T) . rows[:, :d_value]`;
+    `write` = (row [S, Dkv], None, rows) and (out, pool) comes back."""
+    s_n, h = q.shape[0], n_heads
     bs, nb, d_kv = pool_k.shape[2], tables.shape[1], pool_k.shape[3]
+    pools = (pool_k,) if d_value else (pool_k, pool_v)
+    dh = d_kv if d_value else d_head
     n_kv = d_kv // dh
     # under plain multi-head attention the kernel takes a slot's query
-    # and gives its result as ONE row, under grouped heads a row a head
+    # and gives its result as ONE row, under grouped heads (a latent
+    # pool's one row for all heads among them) a row a head
     block = (1, 1, h * dh) if n_kv == h else (1, h, dh)
+    out_block = (1, h, d_value) if d_value else block
 
     def slot(s, *_):
         return (s, 0, 0)
@@ -385,50 +431,52 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
                jnp.asarray(layer, jnp.int32).reshape(1)]
     inputs = [q.astype(pool_k.dtype).reshape((s_n,) + block[1:])]
     in_specs = [pl.BlockSpec(block, slot)]
-    out_specs = [pl.BlockSpec(block, slot)]
-    out_shape = [jax.ShapeDtypeStruct((s_n,) + block[1:], jnp.float32)]
-    scratch = [pltpu.VMEM((2, pages * bs, d_kv), pool_k.dtype),
-               pltpu.VMEM((2, pages * bs, d_kv), pool_v.dtype),
-               pltpu.VMEM((h, d_kv), jnp.float32),
-               pltpu.SemaphoreType.DMA((2, 2)),
-               pltpu.SMEM((1,), jnp.int32)]
+    out_specs = [pl.BlockSpec(out_block, slot)]
+    out_shape = [jax.ShapeDtypeStruct((s_n,) + out_block[1:], jnp.float32)]
+    scratch = [pltpu.VMEM((2, pages * bs, d_kv), pool.dtype)
+               for pool in pools]
+    scratch += [pltpu.VMEM(out_block[1:] if d_value else (h, d_kv),
+                           jnp.float32),
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((1,), jnp.int32)]
     aliases = {}
     if write is not None:
-        k_new, v_new, rows = write
+        *news, rows = write
         scalars.append(rows.astype(jnp.int32))
-        for new, pool in ((k_new, pool_k), (v_new, pool_v)):
+        for new, pool in zip(news, pools):
             inputs.append(new.astype(pool.dtype).reshape(s_n, 1, d_kv))
             in_specs.append(pl.BlockSpec((1, 1, d_kv), slot))
             out_specs.append(hbm())
             out_shape.append(jax.ShapeDtypeStruct(pool.shape, pool.dtype))
         # operands are numbered with the scalars: the pools come last
-        aliases = {len(scalars) + len(inputs): 1,
-                   len(scalars) + len(inputs) + 1: 2}
-        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+        aliases = {len(scalars) + len(inputs) + i: 1 + i
+                   for i in range(len(pools))}
+        scratch.append(pltpu.SemaphoreType.DMA((len(pools),)))
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, nb=nb, pages=pages,
                           windows=_windows(pages, tile), scale=scale,
                           h=h, dh=dh, n_kv=n_kv,
-                          writes=write is not None),
+                          writes=write is not None, d_value=d_value),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(s_n,),
-            in_specs=in_specs + [hbm(), hbm()], out_specs=out_specs,
-            scratch_shapes=scratch),
+            in_specs=in_specs + [hbm() for _ in pools],
+            out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape, input_output_aliases=aliases,
         # a slot's first chunk is started by the slot before it
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="paged_attention",
-    )(*scalars, *inputs, pool_k, pool_v)
-    ctx = out[0].reshape(s_n, h * dh)
-    return ctx if write is None else (ctx, out[1], out[2])
+    )(*scalars, *inputs, *pools)
+    ctx = out[0].reshape(s_n, -1)
+    return ctx if write is None else (ctx,) + tuple(out[1:])
 
 
 def select_paged_attention(
         *, d_model: int, n_heads: int, block_size: int, kv_dtype: str,
         platform: str, interpret: bool = False,
         kv_width: Optional[int] = None, d_head: Optional[int] = None,
+        value_width: Optional[int] = None,
 ) -> Tuple[Optional[Callable], Optional[str]]:
     """-> (attend, None), or (None, reason) where
     `paged_attention_supports` refuses: the caller then keeps its XLA
@@ -438,10 +486,14 @@ def select_paged_attention(
     attend(q, pool_k, pool_v, tables, lengths, layer, scale):
     `paged_attention` at this geometry's heads, with the chunk and the
     row tile chosen from a page's bytes and the table's (the ring's)
-    pages: `attend.tiling(table_pages)` says which."""
+    pages: `attend.tiling(table_pages)` says which.  With
+    `value_width` the pool is a LATENT one (`paged_attention`): one
+    array of rows `kv_width` wide, `pool_v` None and `write`'s V
+    None."""
     reason = paged_attention_supports(
         d_model=d_model, block_size=block_size, kv_dtype=kv_dtype,
-        platform=platform, interpret=interpret, kv_width=kv_width)
+        platform=platform, interpret=interpret, kv_width=kv_width,
+        value_width=value_width)
     if reason is not None:
         return None, reason
     page_bytes = (int(block_size) * int(kv_width or d_model)
@@ -464,7 +516,8 @@ def select_paged_attention(
             scale=float(scale), pages=pages, tile=tile,
             n_heads=int(n_heads),
             d_head=int(d_head or d_model // n_heads),
-            interpret=interpret, write=write)
+            d_value=int(value_width or 0), interpret=interpret,
+            write=write)
 
     attend.tiling = tiling
     return attend, None

@@ -80,6 +80,30 @@ architecture is a second description and not a second decoder.
                third and fourth descriptions', together for the first
                time.
 
+  latent-      the seventh (DeepSeek-V2, arXiv:2405.04434, `model_type:
+  cache-like   deepseek_v2`), fields again: an ATTENTION KIND that is
+               not Q, K, V, O (`kv_lora_rank` > 0).  The query goes
+               down to `q_lora_rank`, through an RMSNorm and up to H
+               heads of `qk_nope_head_dim` + `qk_rope_head_dim`
+               columns; key and value go down TOGETHER to
+               `kv_lora_rank` + `qk_rope_head_dim` columns: a latent
+               (RMSNorm over the latent alone) and ONE rotated key
+               part all heads share.  That row is all the cache holds
+               a position a layer; a per-head matrix [kv_lora_rank,
+               qk_nope_head_dim + v_head_dim] would widen it to keys
+               and values, and the step never does: it multiplies the
+               query's unrotated part by the key half of that matrix
+               (the ABSORBED form: scores and context over the latent
+               row itself) and the context by its value half.  RoPE
+               turns `qk_rope_head_dim` columns alone; the query/key
+               width is not the value width.  And a GROUP-LIMITED
+               softmax router (`n_group`, `topk_group`: the experts in
+               `n_group` consecutive groups, a group's score its
+               largest probability, the k chosen from the `topk_group`
+               best groups), its weights the probabilities times
+               `routed_scaling_factor`, not renormalised.  Dense layer,
+               held experts and the shared expert are the sixth's.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -125,7 +149,9 @@ class BlockSpec:
     dense SwiGLU block on plain multi-head attention with the looped
     stack's fields, and the ring-and-table block with experts, an FFN
     kind a layer, the per-head QK-norm, RoPE on some layer kinds only
-    and the sigmoid router (module docstring).  `layer_types`,
+    and the sigmoid router, and the table-only block with experts on a
+    LATENT cache under the group-limited softmax router (module
+    docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -182,6 +208,20 @@ class BlockSpec:
     router: str = "softmax"
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # -- GROUP-LIMITED choice: the experts routed over are `n_group`
+    #    consecutive groups, a group's score its largest score, and the
+    #    k are chosen among the `topk_group` best groups (1 group: all)
+    n_group: int = 1
+    topk_group: int = 1
+    # -- LATENT attention (`kv_lora_rank` > 0): the cache holds one row
+    #    [kv_lora_rank latent | qk_rope_head_dim rotated key] a position
+    #    a layer for all heads; a query head is qk_nope_head_dim +
+    #    qk_rope_head_dim columns, a value head v_head_dim
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
@@ -212,6 +252,12 @@ class BlockSpec:
                 "passes, and the scheduler's tick gives every lane the "
                 "same work; only a threshold of 1 (every pass runs, "
                 "the gate is reported and decides nothing) is built")
+        if not 1 <= self.topk_group <= self.n_group or (
+                self.n_experts % self.n_group):
+            raise ValueError(
+                f"{self.topk_group} of {self.n_group} groups over "
+                f"{self.n_experts} experts: groups are equal parts of "
+                "the experts, and at least one and at most all are kept")
         if not 0 <= self.experts_first <= (
                 self.n_experts - self.experts_held):
             raise ValueError(
@@ -223,6 +269,12 @@ class BlockSpec:
     def held(self):
         """(first, count) of the experts whose matrices are here."""
         return self.experts_first, self.experts_held or self.n_experts
+
+    @property
+    def latent(self) -> bool:
+        """Whether attention keeps a latent row a position (module
+        docstring, the seventh description)."""
+        return self.kv_lora_rank > 0
 
     def heads(self, d_model: int, n_heads: int):
         """(K/V heads, head size) at a model width and query heads."""
@@ -302,7 +354,14 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     C, then dt, side by side), the depthwise convolution over x B C
     (`ssm_conv`: [width, H*P + 2*N] and its bias), `ssm_dt` (dt's
     bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
-    `ssm_out` [H*P, d]; no bias on a projection.  The expert matrices
+    `ssm_out` [H*P, d]; no bias on a projection.  A LATENT layer has
+    seven arrays in place of the four: `q_a` [d, q_lora_rank], its
+    norm's scale `q_a_norm`, `q_b` [q_lora_rank, H * (nope + rope)] (a
+    head's unrotated columns, then its rotated ones), `kv_a` [d,
+    kv_lora_rank + rope] (the latent, then the one key part),
+    `kv_a_norm` [kv_lora_rank], `kv_b` [kv_lora_rank, H * (nope +
+    v_head_dim)] (a head's key columns, then its value columns) and
+    `o` [H * v_head_dim, d].  The expert matrices
     are [experts HELD, ...]; the router keeps its published width.  A
     DENSE layer among sparse ones (`mlp_layer_types`) has the dense
     block's three matrices at `dense_d_inner` and no "router" key:
@@ -363,11 +422,32 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             "(mlp_layer_types) are built for a block of attention "
             "layers with experts (ffn 'moe_swiglu') and need "
             "dense_d_inner, the dense layers' width")
-    if spec.router != "sigmoid" and (
-            spec.router_bias or spec.routed_scaling_factor != 1.0):
+    if spec.router != "sigmoid" and (spec.router_bias or (
+            spec.routed_scaling_factor != 1.0 and spec.norm_topk_prob)):
         raise NotImplementedError(
-            f"block {spec.name!r}: a choice bias and a scaling factor "
-            "are built and tested on the sigmoid router alone")
+            f"block {spec.name!r}: a choice bias, and a scaling factor "
+            "on renormalised weights, are built and tested on the "
+            "sigmoid router alone (the softmax router's probabilities "
+            "take a factor as they are)")
+    if spec.n_group > 1 and (spec.router != "softmax"
+                             or spec.router_bias):
+        raise NotImplementedError(
+            f"block {spec.name!r}: group-limited choice is built on the "
+            "softmax router without a choice bias (a group's score is "
+            "its largest probability)")
+    if spec.latent and (
+            dense or mamba or SLIDING in kinds or spec.qk_norm
+            or spec.n_kv_heads not in (0, n_heads) or spec.d_head
+            or min(spec.q_lora_rank, spec.qk_nope_head_dim,
+                   spec.qk_rope_head_dim, spec.v_head_dim) < 1
+            or spec.qk_rope_head_dim % 2):
+        raise NotImplementedError(
+            f"block {spec.name!r}: a latent cache (kv_lora_rank) is "
+            "built for a block of full-attention layers with experts "
+            "under RoPE, and needs q_lora_rank, qk_nope_head_dim, an "
+            "even qk_rope_head_dim and v_head_dim; no ring, Mamba "
+            "layers, QK-norm, grouped K/V heads or d_head beside it "
+            "(every head reads the one latent row)")
     for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
         if not spec.rotated(kind):
             continue
@@ -401,6 +481,23 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_d": add(p + "ssm_d.w_0", spec.ssm_heads),
                    "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
+        elif spec.latent:
+            dqk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
+            lay = {"norm1": add(p + "attn_norm.scale_0", d),
+                   "q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
+                   "q_a_norm": add(p + "q_a_norm.scale_0",
+                                   spec.q_lora_rank),
+                   "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
+                              n_heads * dqk),
+                   "kv_a": add(p + "kv_a_proj.w_0", d,
+                               spec.kv_lora_rank + spec.qk_rope_head_dim),
+                   "kv_a_norm": add(p + "kv_a_norm.scale_0",
+                                    spec.kv_lora_rank),
+                   "kv_b": add(p + "kv_b_proj.w_0", spec.kv_lora_rank,
+                               n_heads * (spec.qk_nope_head_dim
+                                          + spec.v_head_dim)),
+                   "o": add(p + "o_proj.w_0", n_heads * spec.v_head_dim,
+                            d)}
         else:
             lay = {"norm1": add(p + "attn_norm.scale_0", d),
                    "q": add(p + "q_proj.w_0", d, dq),
@@ -540,7 +637,12 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     `b_router` [E] (the bias decides the CHOICE alone: the weights are
     the scores as they are), renormalised under `norm_topk_prob` and
     then times `routed_scaling_factor`; a tie goes to the lower expert
-    index either way.  Float32 at `highest` precision (one
+    index either way.  Under `n_group` > 1 the choice is GROUP-LIMITED:
+    the experts lie in `n_group` consecutive groups, a group's score is
+    its largest probability, the `topk_group` groups of highest score
+    are kept (a tie to the lower group), every other expert's score is
+    set to 0 and the k are the largest of what is left.  Float32 at
+    `highest` precision (one
     bf16 pass moves a probability by 1e-3 of itself and swaps the k-th
     and k+1-th expert wherever they lie that close); the weights are
     the probabilities as they are, renormalised only under
@@ -557,7 +659,14 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jax.nn.softmax(logits, axis=-1)             # [T, E]
-    if b_router is None:
+    if spec.n_group > 1:
+        grouped = probs.reshape(probs.shape[:-1] + (spec.n_group, -1))
+        _, kept = jax.lax.top_k(grouped.max(-1), spec.topk_group)
+        keep = (kept[..., None] == jnp.arange(spec.n_group)).any(-2)
+        top_w, top_e = jax.lax.top_k(
+            jnp.where(keep[..., None], grouped, 0.0).reshape(probs.shape),
+            spec.experts_per_token)
+    elif b_router is None:
         top_w, top_e = jax.lax.top_k(probs, spec.experts_per_token)
     else:
         _, top_e = jax.lax.top_k(probs + b_router.astype(jnp.float32),

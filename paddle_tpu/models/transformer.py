@@ -517,6 +517,15 @@ _REFUSALS = {
             "past position 0, where the attention layers find the prompt's "
             "K/V in the shared blocks but a lane has no recurrent state for "
             "it (no snapshot is kept); pass prefix_cache=False")},
+    # a cached block holds every layer's latent rows: the prefix cache
+    # works on the latent table as on any other
+    "latent": {
+        "draft_model": (
+            "a decoder with a latent cache takes no draft model: speculative "
+            "verification runs a window of positions through step_window, "
+            "whose XLA gather path knows a K pool and a V pool and is not "
+            "built for one latent row a position "
+            "(build_lm_paged_decoder's step_window refuses it too)")},
     # a cached block holds every pass's K/V: the prefix cache works
     "loop": {
         "draft_model": (
@@ -549,7 +558,8 @@ class PagedDecoder:
     platform: str
     # names of what `step` returns after the pools, counted on the
     # device: "moe_experts_hit" (and "moe_rows_held" where the block
-    # holds a share of its experts), "exit_gate_open"; () without
+    # holds a share of its experts, and "moe_tokens_here" where its
+    # router is group-limited too), "exit_gate_open"; () without
     step_counters: Tuple[str, ...]
     # layers with experts: what `moe_experts_hit` and `moe_rows_held`
     # are summed over (0: a block without)
@@ -572,7 +582,8 @@ class PagedDecoder:
     vocab_size: int
     # the pool's storage: "fp32", "bf16" or "int8"
     kv_dtype: str
-    # K+V bytes of one table block over the planes that hold it
+    # K+V bytes of one table block over the planes that hold it (a
+    # latent block's one row a position)
     bytes_per_block: int
     # the ring of a block with sliding layers: blocks a sequence (0:
     # every layer is full), the window, and a ring block's bytes
@@ -588,9 +599,10 @@ class PagedDecoder:
     state_layers: int
     state_bytes_per_lane: int
     # what attends in the resident step (`step`, `step_logits`,
-    # `step_routing`): the streaming Pallas kernel, or the XLA gather
-    # and the reason the kernel was refused; `step_window` (a window of
-    # query rows a slot) runs the gather always
+    # `step_routing`): the streaming Pallas kernel ("pallas";
+    # "pallas:latent" over a latent pool), or the XLA gather and the
+    # reason the kernel was refused; `step_window` (a window of query
+    # rows a slot) runs the gather always
     kernels: Dict[str, str]
     # (pages a chunk, pages of its smallest row window) of the kernel
     # over a slot's table and over its ring: what it copies and
@@ -753,6 +765,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     decides nothing.  `step_routing` returns every pass's x_t and
     gate.  `step_window` and an int8 pool are refused by name.
 
+    A LATENT block (`BlockSpec.kv_lora_rank` > 0, lm_block's seventh
+    description) keeps ONE pool: `pool_k` [layers, blocks, block_size,
+    row] holds a position's compressed latent and its one rotated key
+    part side by side (the row padded with exact zeros to the 128-lane
+    grid), `pool_v` is the empty tuple, and `bytes_per_block` counts
+    the one array.  The step is the ABSORBED form: the query's
+    unrotated part times the key half of `kv_b` a head, attention over
+    the latent rows themselves (scores over the whole row, the context
+    over its latent columns), the context times the value half, `o`.
+    `step_window`, a draft model and an int8 pool are refused by name;
+    the prefix cache works (a block holds every layer's rows).
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -807,6 +831,22 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # own, FULL layers in the table of `nb` blocks.
     n_kv, d_head = spec.heads(d_model, n_heads)
     group, d_kv = n_heads // n_kv, n_kv * d_head
+    # A LATENT block's pool row is the latent and the one rotated key
+    # part, which every head reads whole ("one K/V head" of that
+    # width), stored on the 128-lane grid: the pad columns are exact
+    # zeros in the row and in the query, so they add nothing.
+    latent = spec.latent
+    if latent:
+        d_lat, d_pe = spec.kv_lora_rank, spec.qk_rope_head_dim
+        d_nope, d_v = spec.qk_nope_head_dim, spec.v_head_dim
+        n_kv, group, d_head = 1, n_heads, d_nope + d_pe
+        d_kv = -(-(d_lat + d_pe) // 128) * 128
+        if kv_dtype == "int8":
+            raise NotImplementedError(
+                f"block {spec.name!r}: an int8 pool's scale a (layer, "
+                "block) is not built for a latent row (one scale over a "
+                "normed latent and a rotated key part of other ranges); "
+                "kv_dtype fp32 or bf16")
     kinds = [spec.kind_of(l) for l in range(n_layers)]
     ringed = lm_block.SLIDING in kinds
     stateful = lm_block.MAMBA in kinds
@@ -840,7 +880,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     _attend, _refused = _paged_attention.select_paged_attention(
         d_model=d_model, n_heads=n_heads, d_head=d_head, kv_width=d_kv,
-        block_size=bs, kv_dtype=kv_dtype, platform=platform)
+        block_size=bs, kv_dtype=kv_dtype, platform=platform,
+        value_width=d_lat if latent else None)
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -967,7 +1008,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("rope"):
             # a kind RoPE does not turn (`BlockSpec.rope_layers`)
             # carries no position signal at all
-            return {kind: (lm_block.rope_tables(spec, pos, d_head, kind)
+            return {kind: (lm_block.rope_tables(
+                spec, pos, d_pe if latent else d_head, kind)
                            if spec.rotated(kind) else None)
                     for kind in dict.fromkeys(kinds)}
 
@@ -994,6 +1036,52 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 q = lm_block.rope(q, *rot, n_heads)
                 kk = lm_block.rope(kk, *rot, n_kv)
         return q, kk, vv
+
+    def _kv_b(g, lay):
+        """`kv_b` by head: [latent, H, key columns | value columns]."""
+        return g[lay["kv_b"][0]].reshape(d_lat, n_heads, d_nope + d_v)
+
+    def _latent_qkv(g, lay, x, rot):
+        """The latent block's `_qkv`: -> (the ABSORBED query [S, W, H *
+        row]: a head's unrotated part times the key half of `kv_b`, so
+        that it meets the latent itself, then its rotated part, then
+        the row's zero pad; this position's row [S, W, row]: the normed
+        latent, the one rotated key part, the pad; None: there is no V).
+        The norms are float32; the rotation is of `qk_rope_head_dim`
+        columns, a head's of the query and the one of the key."""
+        lead = x.shape[:-1]
+        with scope("latent_q"):
+            h = _norm(g, x, lay["norm1"])
+            q = _fc(g, _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"]),
+                    lay["q_b"]).reshape(lead + (n_heads, d_head))
+            q_nope, q_pe = q[..., :d_nope], q[..., d_nope:]
+            q_pe = lm_block.rope(q_pe.reshape(lead + (-1,)), *rot,
+                                 n_heads).reshape(q_pe.shape)
+        with scope("latent_kv"):
+            ckv = _fc(g, h, lay["kv_a"])
+            pad = jnp.zeros(lead + (d_kv - d_lat - d_pe,), ckv.dtype)
+            row = jnp.concatenate(
+                [_norm(g, ckv[..., :d_lat], lay["kv_a_norm"]),
+                 lm_block.rope(ckv[..., d_lat:], *rot, 1), pad], axis=-1)
+        with scope("latent_absorb"):
+            q_lat = jnp.einsum("...hn,chn->...hc", q_nope,
+                               _kv_b(g, lay)[..., :d_nope])
+            q_abs = jnp.concatenate(
+                [q_lat, q_pe, jnp.broadcast_to(
+                    pad[..., None, :], lead + (n_heads, pad.shape[-1]))],
+                axis=-1)
+        return q_abs.reshape(lead + (n_heads * d_kv,)), row, None
+
+    def _latent_values(g, lay, ctx):
+        """The attention's context over the LATENT, [.., H * latent],
+        times the value half of `kv_b` a head -> [.., H * v_head_dim]:
+        what the expanded form's `p . V` gives."""
+        with scope("latent_absorb"):
+            out = jnp.einsum(
+                "...hc,chv->...hv",
+                ctx.reshape(ctx.shape[:-1] + (n_heads, d_lat)),
+                _kv_b(g, lay)[..., d_nope:])
+            return out.reshape(ctx.shape[:-1] + (n_heads * d_v,))
 
     def _post_join(g, x, y, pair):
         """x + norm(y): a sub-block's output joins the residual stream
@@ -1083,17 +1171,27 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         """`out` and what the step counted (`decoder.step_counters`):
         the distinct experts each layer with experts routed to and,
         where the block HOLDS a share of them, the assignments of the
-        `live` rows [T] that fell on held experts, a layer."""
+        `live` rows [T] that fell on held experts, a layer; under a
+        group limit (which is what bounds the chips a token reaches)
+        also the live rows with at least one such assignment."""
         if not hits:
             return out
         out = out + (jnp.stack([h[0] for h in hits]),)
         if not shares:
             return out
         first, e_n = spec.held
+
+        def here(h):
+            return (h[3] >= first) & (h[3] < first + e_n) & live[:, None]
+
         with scope("moe_dispatch"):
-            return out + (jnp.stack([jnp.sum(
-                (h[3] >= first) & (h[3] < first + e_n) & live[:, None],
-                dtype=jnp.int32) for h in hits]),)
+            out = out + (jnp.stack([jnp.sum(here(h), dtype=jnp.int32)
+                                    for h in hits]),)
+            if spec.n_group > 1:
+                out = out + (jnp.stack([jnp.sum(here(h).any(axis=1),
+                                                dtype=jnp.int32)
+                                        for h in hits]),)
+            return out
 
     def _kind_scope(name, kind):
         """`paged_decoder/<name>`, and under it the layer's kind where
@@ -1182,8 +1280,23 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         float32."""
         w_n = q.shape[1]
         k, k_scale = _gather(pool_k, l, tables, kind)
-        v, v_scale = _gather(pool_v, l, tables, kind)
         batched = ((0,), (0,))
+        if latent:
+            # one row for every head: the query is dense over it, the
+            # value its latent columns, the context [S, W, H * latent]
+            with _kind_scope("attention", kind):
+                sc = jax.lax.dot_general(
+                    q.reshape(q.shape[0], w_n * n_heads, d_kv), k,
+                    (((2,), (2,)), batched),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(jnp.repeat(pos_mask, n_heads, axis=1), sc,
+                               -jnp.inf)
+                ctx = jax.lax.dot_general(
+                    jax.nn.softmax(sc, axis=-1), k[..., :d_lat],
+                    (((2,), (1,)), batched),
+                    preferred_element_type=jnp.float32)
+                return ctx.reshape(q.shape[0], w_n, n_heads * d_lat)
+        v, v_scale = _gather(pool_v, l, tables, kind)
         with _kind_scope("attention", kind):
             sc = jax.lax.dot_general(
                 _block_diagonal(q), k, (((2,), (2,)), batched),
@@ -1216,8 +1329,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         table."""
         row, lengths = cursor
         with _kind_scope("attention", kind):
-            return _attend(q, pool_k, pool_v, tables, lengths, l, scale,
-                           write=(kk, vv, row))
+            out = _attend(q, pool_k, pool_v, tables, lengths, l, scale,
+                          write=(kk, vv, row))
+            # a latent pool is one array: the V pool is the empty
+            # tuple it came as
+            return out + (pool_v,) if latent else out
 
     def _by_kind(x):
         """A pool or the tables as the step is given them, by layer
@@ -1315,7 +1431,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     scans.append(given)
                     x = _ffn(g, lay, x, hits)
                     continue
-                q, kk, vv = _qkv(g, lay, x, rot[kind])
+                q, kk, vv = (_latent_qkv if latent else _qkv)(
+                    g, lay, x, rot[kind])
                 wb, seen = cursor[kind]
                 plane = li if plane0 is None else plane0 + li
                 if _attend is not None:
@@ -1326,11 +1443,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     with scope("kv_write"):
                         pools_k[kind] = _write(pools_k[kind], plane, wb,
                                                wi, kk)
-                        pools_v[kind] = _write(pools_v[kind], plane, wb,
-                                               wi, vv)
+                        if not latent:
+                            pools_v[kind] = _write(pools_v[kind], plane,
+                                                   wb, wi, vv)
                     ctx_av = _attention(
                         q[:, None, :], pools_k[kind], pools_v[kind],
                         plane, tabs[kind], seen[:, None, :], kind)[:, 0]
+                if latent:
+                    ctx_av = _latent_values(g, lay, ctx_av)
                 with scope("attn_out"):
                     y = _fc(g, ctx_av, lay["o"])
                     if not spec.post_norm:
@@ -1443,6 +1563,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 "`step` computes (a chunked scan is not built); a "
                 "block with Mamba layers runs `step` alone (no draft "
                 "model, no chunked prefill)")
+        if latent:
+            raise NotImplementedError(
+                f"block {spec.name!r}: step_window is not built for a "
+                "latent cache (a window of positions would want the "
+                "EXPANDED form, keys and values widened a chunk at a "
+                "time, and only the absorbed one-position `step` is "
+                "built); such a block runs `step` alone (no draft "
+                "model, no chunked prefill)")
         if looped:
             raise NotImplementedError(
                 f"block {spec.name!r}: step_window is not built for a "
@@ -1521,7 +1649,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # over the full layers (of every pass of a looped stack), a ring
     # block over the sliding ones
     planes = passes * n_full
-    bytes_per_block = int(2 * planes * bs * d_kv * elem_bytes)
+    # (a latent block's one array holds keys and values at once)
+    bytes_per_block = int((1 if latent else 2) * planes * bs * d_kv
+                          * elem_bytes)
     window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
     # a lane's recurrent state over the Mamba layers: the SSM state
     # and the convolution tail, both float32
@@ -1542,7 +1672,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         is the pair (the attention layers' pool, one float32 array a
         Mamba layer: `lanes` SSM states beside K, `lanes` convolution
         tails beside V); `lanes` is the step's lane count and read by
-        no other block."""
+        no other block.  A latent block's is (the one pool, ())."""
         def zeros(layers, blocks):
             shape = (layers, int(blocks), bs, d_kv)
             if kv_dtype == "int8":
@@ -1566,6 +1696,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if stateful:
             return ((zeros(n_full, num_blocks), lane_state(state_shape)),
                     (zeros(n_full, num_blocks), lane_state(tail_shape)))
+
+        if latent:
+            return zeros(planes, num_blocks), ()
 
         def z():
             if not ringed:
@@ -1598,7 +1731,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # op_name as compiled text spells it: the part's scope}, for
     # `profiler.register_jitted`, beside the calls the compiler renames.
     parts = {"q": "qkv", "k": "qkv", "v": "qkv", "o": "attn_out",
-             "w1": "mlp", "w2": "mlp"}
+             "w1": "mlp", "w2": "mlp", "q_a": "latent_q",
+             "q_b": "latent_q", "kv_a": "latent_kv",
+             "kv_b": "latent_absorb"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
@@ -1642,7 +1777,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         zero).  With experts `moe_kernel` (1: the traced step's expert
         layer is the Pallas grouped matmul, 0: `ragged_dot`) and
         `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
-        the planes the pages are counted over."""
+        the planes the pages are counted over.  With a latent cache
+        `latent_rows`: the rows the lanes with a sequence attend over
+        (cursor + 1), summed over them and the layers."""
         n = len(cursors)
         counts = {}
         if passes > 1:
@@ -1672,6 +1809,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["past_window"] = int((rows > window).sum())
             counts["kv_rows_full"] = int(rows.sum())
             counts["kv_rows_win"] = int(np.minimum(rows, window).sum())
+        if latent:
+            counts["latent_rows"] = planes * int(rows.sum())
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))
@@ -1684,8 +1823,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     # where a block has two kinds of state, the ring's word stands
     refuses = {}
-    for kind, has in (("loop", looped), ("state", stateful),
-                      ("ring", ringed)):
+    for kind, has in (("latent", latent), ("loop", looped),
+                      ("state", stateful), ("ring", ringed)):
         if has:
             refuses.update(_REFUSALS[kind])
 
@@ -1696,6 +1835,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         init_pool=init_pool, slot_rings=slot_rings, platform=platform,
         step_counters=(("moe_experts_hit",)
                        + (("moe_rows_held",) if shares else ())
+                       + (("moe_tokens_here",)
+                          if shares and spec.n_group > 1 else ())
                        if spec.ffn == "moe_swiglu"
                        else ("exit_gate_open",) if spec.exit_gate
                        else ()),
@@ -1710,7 +1851,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         table_layers=planes, ring_layers=n_win,
         state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
         kernels={"paged_attention_decode":
-                 "pallas" if _attend is not None else f"xla:{_refused}",
+                 f"xla:{_refused}" if _attend is None
+                 else "pallas:latent" if latent else "pallas",
                  "paged_attention_window":
                  f"xla:{_refused or 'window_rows'}"},
         attention_tiling=tiling, tick_counts=tick_counts, refuses=refuses)

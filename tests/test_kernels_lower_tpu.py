@@ -47,8 +47,12 @@ POOLS = {
     "ouro-chat12": (12, 16, 2048, 16, 64, 192, "bf16"),
     "k-exaone-chat64-table": (64, 64, 1024, 16, 64, 2, "bf16"),
     "k-exaone-chat64-ring": (64, 64, 1024, 16, 8, 6, "bf16"),
+    # the latent form: ONE pool, 128 heads on a row of 512 + 64 stored
+    # 640 wide, of which 512 are the value
+    "deepseek-v2-agent64-latent": (64, 128, 640, 16, 256, 5, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
+D_VALUE = {"deepseek-v2-agent64-latent": 512}
 
 
 def lower_tpu(f, *args):
@@ -61,9 +65,11 @@ def _paged(name, sharding=None):
     through the selected kernel, its arguments as shapes)."""
     s_n, h, d_kv, bs, nb, layers, kv_dtype = POOLS[name]
     d_head = D_HEAD.get(name, 128)
+    d_value = D_VALUE.get(name)
     kern, reason = select_paged_attention(
         d_model=h * d_head, n_heads=h, d_head=d_head, kv_width=d_kv,
-        block_size=bs, kv_dtype=kv_dtype, platform="tpu")
+        block_size=bs, kv_dtype=kv_dtype, platform="tpu",
+        value_width=d_value)
     assert reason is None
     dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
 
@@ -74,6 +80,23 @@ def _paged(name, sharding=None):
     # 288 blocks hold
     blocks = min(s_n * nb, 288) + 1
     pool = shape((layers, blocks, bs, d_kv), dtype)
+
+    def attend_latent(q, pool, _, tables, lengths):
+        # the one pool from layer to layer, a head's query a whole row
+        # and its result the value's columns
+        row = jnp.zeros((s_n, d_kv), dtype)
+        for l in range(layers):
+            out, pool = kern(q, pool, None, tables, lengths, l, 0.115,
+                             write=(row, None, lengths - 1))
+            q = jnp.pad(out.reshape(s_n, h, d_value), (
+                (0, 0), (0, 0), (0, d_kv - d_value))).reshape(
+                    s_n, h * d_kv).astype(dtype)
+        return out, pool
+
+    if d_value:
+        return attend_latent, (
+            shape((s_n, h * d_kv), dtype), pool, None,
+            shape((s_n, nb), jnp.int32), shape((s_n,), jnp.int32))
 
     def attend(q, pool_k, pool_v, tables, lengths):
         # layer upon layer, as a step's are (a looped stack's planes

@@ -341,6 +341,62 @@ def test_kernel_writes_the_row_a_scatter_would(dtype, h, dh, n_kv, bs, nb):
             np.asarray(want[:, 1:], np.float32))
 
 
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-6), ("bfloat16", 2e-2)])
+def test_latent_kernel_equals_plain_attention_over_the_row(dtype, tol):
+    """The latent form (`pool_v` None, `d_value` columns) in the
+    interpreter against plain `jax.numpy`: ONE pool whose row (40
+    columns used of the 128 stored: the pad is zeros in row and query)
+    every one of 8 heads reads whole, the value its first 32 columns;
+    lengths that end inside a page, on a page's and a chunk's edge and
+    past several chunks; this position's row written by the kernel, a
+    negative row writing nothing; layer after layer over the pool it
+    returned."""
+    import jax
+    import jax.numpy as jnp
+
+    r = np.random.RandomState(1)
+    dtype = jnp.dtype(dtype)
+    h, used, width, d_v, bs, nb, layers = 8, 40, 128, 32, 4, 12, 2
+    pos = np.array([0, 2, bs - 1, bs, 4 * bs - 1, 4 * bs, 9 * bs + 1,
+                    nb * bs - 1, 5])
+    active = np.array([1, 1, 1, 1, 1, 1, 1, 1, 0], bool)
+    s_n = len(pos)
+
+    def padded(rows):
+        return jnp.asarray(np.concatenate(
+            [rows, np.zeros(rows.shape[:-1] + (width - used,))], -1))
+
+    pool = padded(r.randn(layers, 1 + s_n * nb, bs, used)).astype(dtype)
+    tables = 1 + np.arange(s_n * nb, dtype=np.int32).reshape(s_n, nb)
+    lengths = np.where(active, pos + 1, 1)
+    q = padded(r.randn(s_n, h, used)).astype(jnp.float32)
+    new = padded(r.randn(s_n, used)).astype(jnp.float32)
+    attend = functools.partial(
+        paged_attention.paged_attention, scale=0.3, pages=4, tile=2,
+        n_heads=h, d_head=0, d_value=d_v, interpret=True)
+    lane = np.arange(s_n)
+    wb = np.where(active, tables[lane, pos // bs], 0)
+    wi = np.where(active, pos % bs, 0)
+    want_pool = pool
+    for layer in range(layers):
+        want_pool = want_pool.at[layer, wb, wi].set(new.astype(dtype))
+        got, pool = attend(
+            q.reshape(s_n, h * width), pool, None, tables,
+            jnp.asarray(lengths, jnp.int32), layer,
+            write=(new, None, np.where(active, pos, -1)))
+        assert got.shape == (s_n, h * d_v) and got.dtype == jnp.float32
+        for s in np.flatnonzero(active):
+            rows = want_pool[layer, tables[s]].reshape(nb * bs, width)[
+                :lengths[s]].astype(jnp.float32)
+            sc = q[s].astype(dtype).astype(jnp.float32) @ rows.T * 0.3
+            want = jax.nn.softmax(sc, -1) @ rows[:, :d_v]
+            np.testing.assert_allclose(
+                np.asarray(got[s]).reshape(h, d_v), np.asarray(want),
+                atol=tol * float(np.abs(want).max()))
+    np.testing.assert_array_equal(np.asarray(pool[:, 1:], np.float32),
+                                  np.asarray(want_pool[:, 1:], np.float32))
+
+
 @pytest.mark.parametrize("kv_dtype,kernel", [
     (None, "pallas"), ("bf16", "pallas"), ("int8", "xla:kv_dtype")])
 def test_greedy_decode_agrees_pallas_vs_xla(kv_dtype, kernel):
@@ -442,6 +498,13 @@ def _cell_geometry(workload):
                        // m["num_attention_heads"])
         geometry.update(
             d_head=d_head, kv_width=m["num_key_value_heads"] * d_head)
+    if "kv_lora_rank" in m:
+        # a latent cache: ONE row a position for all heads, the latent
+        # and the rotated key part, stored on the 128-lane grid
+        row = m["kv_lora_rank"] + m["qk_rope_head_dim"]
+        geometry.pop("d_head")
+        geometry.update(kv_width=-(-row // 128) * 128,
+                        value_width=m["kv_lora_rank"])
     return geometry
 
 
@@ -494,6 +557,12 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16)
     # a ring: the selection takes no word on rings or on a table's
     # length (a ring is a table, the scratch is two chunks)
     ("k-exaone-236b-a23b-serve-chat64", "tpu", False, None),
+    # d5120, 128 heads on ONE latent row of 512 + 64 columns stored 640
+    # wide: the latent form (one pool, a dense query); as it lies (576)
+    # the row is off the lane grid
+    ("deepseek-v2-serve-agent64", "tpu", False, None),
+    (dict(d_model=5120, n_heads=128, block_size=16, kv_dtype="bf16",
+          kv_width=576, value_width=512), "tpu", False, "lane_misaligned"),
     # what Mosaic's tiling refuses: a pool row off the 128-lane grid, a
     # page that is not whole sublane tiles of its dtype (16 rows of bf16)
     (dict(CHIP_SMOKE, kv_dtype="bf16", kv_width=192), "tpu", False,
@@ -512,7 +581,8 @@ CHIP_SMOKE = dict(d_model=1024, n_heads=8, block_size=16)
 ], ids=["opt-1.3b-tpu", "olmoe-1b-7b-1chip-tpu", "mellum2-1chip-tpu",
         "granite-4.0-h-small-1chip-tpu", "ouro-2.6b-tpu", "grouped-kv-tpu",
         "wide-heads-tpu",
-        "ring-tpu", "row-off-the-lanes-tpu", "half-tile-pages-tpu",
+        "ring-tpu", "latent-tpu", "latent-unpadded-tpu",
+        "row-off-the-lanes-tpu", "half-tile-pages-tpu",
         "chip_smoke-fp32-tpu", "chip_smoke-int8-tpu",
         "fp32-pages-of-8-tpu", "chip_smoke-cpu",
         "chip_smoke-cpu-interpret"])
@@ -531,6 +601,37 @@ def test_selection_follows_geometry_and_platform(geometry, platform,
             if k not in ("n_heads", "d_head")}
     assert paged_attention.paged_attention_supports(
         platform=platform, interpret=interpret, **pool) == want
+
+
+@pytest.mark.parametrize("workload,pages,ring_pages,tiling", [
+    ("opt-1.3b-serve-closed32", 32, None, ((16, 8), None)),
+    ("olmoe-1b-7b-serve-chat32", 64, None, ((16, 8), None)),
+    ("mellum2-12b-a2.5b-serve-agent96", 256, 64, ((64, 8), (64, 8))),
+    ("granite-4.0-h-small-serve-chat64", 64, None, ((32, 8), None)),
+    ("ouro-2.6b-serve-chat12", 64, None, ((16, 8), None)),
+    ("k-exaone-236b-a23b-serve-chat64", 64, 8, ((32, 8), (8, 8))),
+    ("deepseek-v2-serve-agent64", 256, None, ((51, 8), None))])
+def test_the_cells_select_the_tiling_they_selected_at_pr_44(
+        workload, pages, ring_pages, tiling, monkeypatch):
+    """The latent form joined the module without moving the others: for
+    the six serving cells that were there, from their own files, the
+    selection returns the kernel with the chunk and row tile PR 44's
+    tree returned (`decoder.attention_tiling`) and calls it with two
+    pools and no `d_value`; the seventh takes the latent form."""
+    geometry = _cell_geometry(workload)
+    kern, reason = paged_attention.select_paged_attention(
+        platform="tpu", **geometry)
+    assert reason is None
+    assert (kern.tiling(pages), ring_pages and kern.tiling(ring_pages)) \
+        == tiling
+    called = {}
+    monkeypatch.setattr(paged_attention, "paged_attention",
+                        lambda *a, **kw: called.update(kw, pool_v=a[2]))
+    kern(None, "k", "v", np.zeros((2, pages), np.int32), None, 0, 0.5)
+    latent = "value_width" in geometry
+    assert called["d_value"] == (512 if latent else 0)
+    assert called["pool_v"] == "v" and called["pages"] == tiling[0][0]
+    assert called["n_heads"] == geometry["n_heads"]
 
 
 @pytest.mark.parametrize("workload,held,rows", [
