@@ -30,6 +30,21 @@ is computed the NEXT slot's first chunk is already in flight (the
 scratch and the buffer cursor outlive a grid step), so DMA latency is
 paid once a call, not once a slot.
 
+A chunk's DMA bookkeeping is per CHUNK, not per page.  Its copies are
+a page each (a table names any block), issued `_ISSUE_UNROLL` to a loop
+iteration, but they are WAITED FOR on their summed bytes: a DMA
+semaphore counts bytes, so a wait need not name the copy it waits for,
+only as many bytes, and a chunk of `copied` pages is one wait for each
+set bit of `copied`, on a descriptor of 2^b pages (`dma_ops` counts
+them).  The invariant that makes it safe: a semaphore `sems[pool, buf]`
+never has more than ONE chunk's copies outstanding.  Buffers alternate;
+chunk c + 1 (or the next slot's first) is started into `1 - buf` while
+chunk c, in `buf`, is still to be waited for, and `1 - buf`'s last
+chunk was waited for whole before its products ran; the written row's
+way back to the pool has semaphores of its own (`wsems`).  So the
+bytes a wait takes off a semaphore are that chunk's and no other's
+(PERF.md section 6, PR 46).
+
 The arithmetic is `_attention`'s.  The query arrives as the projection
 made it (`q` [S, H*dh], cast to the pool's dtype: what the MXU rounds
 it to on the XLA path too) and the kernel lays it out block-diagonal by
@@ -85,7 +100,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attention", "paged_attention_supports",
+__all__ = ["dma_ops", "paged_attention", "paged_attention_supports",
            "rows_multiplied", "select_paged_attention"]
 
 # K (or V) bytes a chunk: the copies of one chunk are in flight while
@@ -106,6 +121,12 @@ _CHUNK_BYTES = 1024 * 1024
 # takes 2.5; PERF.md section 6, PR 41).  128 rows fill the MXU's
 # columns once; fewer would save no pass of it.
 _TILE_ROWS = 128
+# Page copies issued a loop iteration: a page's table read, descriptor
+# and start are scalar work in the one instruction stream the products
+# are in, and a loop's counter, test and branch a page were a part of
+# it (PERF.md section 6, PR 46); the pages a chunk has beyond a
+# multiple of it go one at a time.
+_ISSUE_UNROLL = 8
 _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 
 
@@ -172,6 +193,16 @@ def rows_multiplied(n_pages, pages: int, tile: int, block_size: int):
     return (whole * pages + last) * block_size
 
 
+def dma_ops(n_pages, pages: int):
+    """DMA starts and waits a POOL the kernel performs for a slot of
+    `n_pages` pages (an integer or an integer array) in chunks of
+    `pages`: a start a page, and for each chunk a wait for each set
+    bit of the pages copied into it."""
+    whole, rest = n_pages // pages, n_pages % pages
+    return n_pages + whole * pages.bit_count() + sum(
+        (rest >> bit) & 1 for bit in range(pages.bit_length()))
+
+
 def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
             windows, scale, h, dh, n_kv, writes, d_value=0):
     """Grid step s: slot s's attention over its first
@@ -212,26 +243,49 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     def n_pages(slot):
         return (lengths_ref[slot] + bs - 1) // bs
 
-    def each_page(slot, chunk, buf, do):
-        """`do` each page copy (K, then V; a latent row once) of
-        `slot`'s chunk `chunk` into buffer `buf`: the pages the slot's
-        length reaches, so a table entry past it is never read."""
-        first = chunk * pages
-
-        def page(i, _):
-            blk = tables_ref[slot * nb + first + i]
-            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            for i_pool, (hbm, into) in enumerate(zip(hbms, bufs)):
-                do(pltpu.make_async_copy(hbm.at[layer, blk],
-                                         into.at[buf, dst],
-                                         sems.at[i_pool, buf]))
-            return 0
-
-        jax.lax.fori_loop(0, jnp.minimum(pages, n_pages(slot) - first),
-                          page, 0)
+    def copied_into(slot, chunk):
+        """Pages of `slot`'s chunk `chunk` that its length reaches."""
+        return jnp.minimum(pages, n_pages(slot) - chunk * pages)
 
     def start(slot, chunk, buf):
-        each_page(slot, chunk, buf, lambda copy: copy.start())
+        """Start each page copy (K, then V; a latent row once) of
+        `slot`'s chunk `chunk` into buffer `buf`: the pages the slot's
+        length reaches, so a table entry past it is never read;
+        `_ISSUE_UNROLL` pages a loop iteration, the rest one by one."""
+        base = slot * nb + chunk * pages
+        planes = [hbm.at[layer] for hbm in hbms]
+        unroll = min(_ISSUE_UNROLL, pages)
+
+        def page(i, carry=0):
+            blk = tables_ref[base + i]
+            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            for i_pool, (plane, into) in enumerate(zip(planes, bufs)):
+                pltpu.make_async_copy(plane.at[blk], into.at[buf, dst],
+                                      sems.at[i_pool, buf]).start()
+            return carry
+
+        def group(g, carry):
+            for j in range(unroll):
+                page(g * unroll + j)
+            return carry
+
+        n = copied_into(slot, chunk)
+        jax.lax.fori_loop(0, n // unroll, group, 0)
+        jax.lax.fori_loop(n // unroll * unroll, n, page, 0)
+
+    def wait(copied, buf):
+        """Wait for the `copied` pages a `start` sent to buffer `buf`,
+        on their summed bytes: for each set bit b of `copied` one wait
+        a pool on a descriptor of 2^b pages, of which only the size
+        and the semaphore matter (the module's docstring: the
+        semaphore holds this chunk's copies and no other's)."""
+        for bit in range(pages.bit_length()):
+            @pl.when(((copied >> bit) & 1) == 1)
+            def _wait(size=pl.ds(0, (1 << bit) * bs)):
+                for i_pool, into in enumerate(bufs):
+                    pltpu.make_async_copy(into.at[buf, size],
+                                          into.at[buf, size],
+                                          sems.at[i_pool, buf]).wait()
 
     @pl.when(s == 0)
     def _first_slot():
@@ -313,15 +367,17 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     def chunk(c, carry):
         buf = (first_buf + c) % 2
 
-        @pl.when(c + 1 < n_chunks)
-        def _next_chunk():
-            start(s, c + 1, 1 - buf)
+        more = c + 1 < n_chunks
 
-        @pl.when((c + 1 == n_chunks) & (s + 1 < n_slots))
-        def _next_slot():
-            start(s + 1, 0, 1 - buf)
+        @pl.when(more | (s + 1 < n_slots))
+        def _next():
+            # this slot's next chunk, else the next slot's first
+            start(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
+                  1 - buf)
 
-        each_page(s, c, buf, lambda copy: copy.wait())
+        # the pages copied into this chunk
+        copied = copied_into(s, c)
+        wait(copied, buf)
         if writes:
             written = put_row(c, buf)
 
@@ -347,8 +403,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
                                                   keepdims=True)
             return multiply
 
-        # the smallest window the pages copied into this chunk fill
-        copied = jnp.minimum(pages, n_pages(s) - c * pages)
+        # the smallest window they fill
         carry = jax.lax.switch(
             sum((copied > w).astype(jnp.int32) for w in windows[:-1]),
             [over(w * bs) for w in windows], carry)

@@ -1768,7 +1768,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         step's two products run over, summed the same way: through the
         kernel, for each chunk of a lane's pages, the smallest row
         window that holds them (`attention_tiling`), on the gather path
-        every row.  With sliding layers `past_window` (cursors at or
+        every row.  `kv_dma_ops`, through the kernel alone: the DMA
+        starts and waits it performs for those pages, summed the same
+        way and over the pools (`kernels.paged_attention.dma_ops`: a
+        start a page, a wait for each set bit of a chunk's pages).
+        With sliding layers `past_window` (cursors at or
         past the window: their rings have wrapped) and the rows a layer
         of each kind attends over, `kv_rows_full` (cursor + 1) and
         `kv_rows_win` (the window at most).  With Mamba layers
@@ -1790,7 +1794,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         read, multiplied = table, table * bs
         if tiling is not None and not windowed:
             idle = slots - n                 # a page each, a layer
-            read = multiplied = 0
+            read = multiplied = dma = 0
             reached = [(planes, -(-rows // bs), tiling[0])]
             if n_win:
                 reached.append(
@@ -1802,6 +1806,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         1, chunk, tile, bs)
                     + _paged_attention.rows_multiplied(
                         pages, chunk, tile, bs).sum())
+                dma += layers_n * (1 if latent else 2) * int(
+                    idle * _paged_attention.dma_ops(1, chunk)
+                    + _paged_attention.dma_ops(pages, chunk).sum())
+            counts["kv_dma_ops"] = dma
         counts["kv_pages_read"] = read
         counts["kv_pages_table"] = table
         counts["kv_rows_multiplied"] = multiplied

@@ -1171,10 +1171,14 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     loop = {"loop_passes": 3, "kv_planes": 6} if kind == "loop" else {}
     # and PR 40: the layers with experts, on a block that has any
     # and PR 41: the rows the attention's products run over
-    extra = ({"ahead", "kv_wait", "moe_layers", "kv_rows_multiplied"}
-             | set(loop) | set(dec.step_counters))
+    # and PR 46: the kernel's DMA starts and waits, where it runs
+    extra = ({"ahead", "kv_wait", "moe_layers", "kv_rows_multiplied",
+              "kv_dma_ops"} | set(loop) | set(dec.step_counters))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old
+        # a start a page, and a wait a chunk at the least
+        assert ("kv_dma_ops" in got) == streamed
+        assert got.get("kv_dma_ops", 0) <= 2 * 2 * got["kv_pages_read"]
         assert got["kv_rows_multiplied"] == (
             got["kv_pages_read"] * srv._cache.block_size)
         assert all(type(v) is int for v in got.values())
@@ -1198,13 +1202,18 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
 # a table in chunks of 4 (toy pages of 512 bytes) takes 2, 4 and 4 + 2
 # for those three lanes and 2 for the idle one, 14; a table in one chunk
 # of 6 (pages of 256 bytes) windows of 2, 4, 6 and 2: 14 as well; a
-# ring of 2 pages is one tile: 2 a lane, 8.
+# ring of 2 pages is one tile: 2 a lane, 8.  DMA starts and waits a
+# pool (a start a page, a wait for each set bit of a chunk's pages): in
+# chunks of 4 the lanes take 1 + 1, 3 + 2, 6 + 1 + 1 and the idle one
+# 1 + 1, 17; in one chunk of 6 they take 1 + 1, 3 + 2, 6 + 2 and 1 + 1,
+# 17 as well; a ring of 2 pages 1 + 1, 1 + 1, 2 + 1 and 2 + 1, 10.
 _TICK_COUNTS = {
     # 2 layers on a table of 6 pages
     "table": dict(
         tiling=((4, 2), None),
         streamed={"kv_pages_read": 2 * 11, "kv_pages_table": 48,
-                  "kv_rows_multiplied": 2 * 14 * 4},
+                  "kv_rows_multiplied": 2 * 14 * 4,
+                  "kv_dma_ops": 2 * 2 * 17},
         gathered={"kv_pages_read": 48, "kv_pages_table": 48,
                   "kv_rows_multiplied": 192}),
     # a full layer, and 3 sliding ones on a ring of 2 pages (window 8:
@@ -1214,7 +1223,7 @@ _TICK_COUNTS = {
         streamed={"kv_pages_read": 11 + 3 * (1 + 1 + 2 + 2),
                   "kv_pages_table": 4 * (6 + 3 * 2),
                   "kv_rows_multiplied": (14 + 3 * 8) * 4,
-                  "past_window": 2, "kv_rows_full": 1 + 10 + 22,
+                  "kv_dma_ops": 2 * (17 + 3 * 10), "past_window": 2, "kv_rows_full": 1 + 10 + 22,
                   "kv_rows_win": 1 + 8 + 8, "moe_layers": 4},
         gathered={"kv_pages_read": 48, "kv_pages_table": 48,
                   "kv_rows_multiplied": 192, "past_window": 2,
@@ -1225,8 +1234,8 @@ _TICK_COUNTS = {
     "state": dict(
         tiling=((6, 2), None),
         streamed={"kv_pages_read": 11, "kv_pages_table": 24,
-                  "kv_rows_multiplied": 14 * 4, "state_lanes": 3,
-                  "state_resets": 1, "moe_layers": 4},
+                  "kv_rows_multiplied": 14 * 4, "kv_dma_ops": 2 * 17,
+                  "state_lanes": 3, "state_resets": 1, "moe_layers": 4},
         gathered={"kv_pages_read": 24, "kv_pages_table": 24,
                   "kv_rows_multiplied": 96, "state_lanes": 3,
                   "state_resets": 1, "moe_layers": 4}),
@@ -1235,7 +1244,8 @@ _TICK_COUNTS = {
         tiling=((4, 2), None),
         streamed={"loop_passes": 3, "kv_planes": 6,
                   "kv_pages_read": 6 * 11, "kv_pages_table": 144,
-                  "kv_rows_multiplied": 6 * 14 * 4},
+                  "kv_rows_multiplied": 6 * 14 * 4,
+                  "kv_dma_ops": 6 * 2 * 17},
         gathered={"loop_passes": 3, "kv_planes": 6,
                   "kv_pages_read": 144, "kv_pages_table": 144,
                   "kv_rows_multiplied": 576}),

@@ -50,9 +50,23 @@ POOLS = {
     # the latent form: ONE pool, 128 heads on a row of 512 + 64 stored
     # 640 wide, of which 512 are the value
     "deepseek-v2-agent64-latent": (64, 128, 640, 16, 256, 5, "bf16"),
+    # a K and a V pool in chunks of 51 pages too: the one chunk length
+    # of the cells that is no power of two, so its waits on summed
+    # bytes take four sizes and its issue loop a remainder (a row of 5
+    # heads of 128: DeepSeek's page bytes under grouped heads)
+    "kv-chunks-of-51": (16, 20, 640, 16, 128, 2, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512}
+# pages a chunk over each pool: what the waits' static list and the
+# issue loop's groups follow
+CHUNK_PAGES = {
+    "chip_smoke-fp32": 16, "chip_smoke-bf16": 32, "opt-1.3b-closed32": 16,
+    "olmoe-chat32": 16, "mellum2-agent96-table": 64,
+    "mellum2-agent96-ring": 64, "granite-chat64": 32, "ouro-chat12": 16,
+    "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
+    "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
+}
 
 
 def lower_tpu(f, *args):
@@ -71,6 +85,7 @@ def _paged(name, sharding=None):
         block_size=bs, kv_dtype=kv_dtype, platform="tpu",
         value_width=d_value)
     assert reason is None
+    assert kern.tiling(nb)[0] == CHUNK_PAGES[name]
     dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
 
     def shape(dims, dt):

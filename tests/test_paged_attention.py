@@ -397,6 +397,188 @@ def test_latent_kernel_equals_plain_attention_over_the_row(dtype, tol):
                                   np.asarray(want_pool[:, 1:], np.float32))
 
 
+# A chunk's DMA bookkeeping (PR 46).  name: query heads, head width, K/V
+# heads, value columns (0: a K and a V pool), pages a chunk, pool dtype.
+# Chunks of 5 and of 3 pages are not powers of two (DeepSeek's are 51);
+# the grouped one is a ring's power of two.
+_DMA_GEOMETRIES = {
+    "kv-plain": (4, 8, 4, 0, 5, "float32"),
+    "kv-grouped": (4, 16, 2, 0, 4, "bfloat16"),
+    "latent": (8, 128, 1, 32, 3, "float32"),
+}
+# pages a slot holds, by name; None: a lane with no sequence
+_DMA_LENGTHS = {
+    "1": lambda pages: 1, "2": lambda pages: 2, "3": lambda pages: 3,
+    "chunk-1": lambda pages: pages - 1, "chunk": lambda pages: pages,
+    "chunk+1": lambda pages: pages + 1,
+    "2chunks+3": lambda pages: 2 * pages + 3, "idle": lambda pages: None,
+}
+
+
+def _dma_case(geometry, lanes_pages, write, interpret=True):
+    """The kernel over lanes of `lanes_pages` pages (None: a lane with
+    no sequence) against plain `jax.numpy` after a scatter of the
+    written row -> (got, want, pools got, pools wanted, active)."""
+    import jax
+    import jax.numpy as jnp
+
+    h, dh, n_kv, d_value, pages, dtype = _DMA_GEOMETRIES[geometry]
+    bs, nb = 4, 2 * pages + 3
+    row, dtype = n_kv * dh, jnp.dtype(dtype)
+    r = np.random.RandomState(7)
+    s_n = len(lanes_pages)
+    active = np.array([n is not None for n in lanes_pages])
+    # the cursor ends inside its last page, but for a lane that fills
+    # its pages to the last row
+    pos = np.array([(n or 1) * bs - 1 - (i % bs if (n or 1) > 1 else 0)
+                    for i, n in enumerate(lanes_pages)])
+    lengths = np.where(active, pos + 1, 1)
+    pools = [jnp.asarray(r.randn(2, 1 + s_n * nb, bs, row), dtype)
+             for _ in range(1 if d_value else 2)]
+    # a table names any block: the lanes' blocks shuffled
+    tables = 1 + r.permutation(s_n * nb).astype(np.int32).reshape(s_n, nb)
+    q = jnp.asarray(r.randn(s_n, h * (row if d_value else dh)), jnp.float32)
+    news = [jnp.asarray(r.randn(s_n, row), jnp.float32) for _ in pools]
+    wanted = list(pools)
+    if write:
+        lane = np.arange(s_n)
+        wb = np.where(active, tables[lane, pos // bs], 0)
+        wanted = [pool.at[1, wb, pos % bs].set(new.astype(dtype))
+                  for pool, new in zip(pools, news)]
+    out = paged_attention.paged_attention(
+        q, pools[0], None if d_value else pools[1], tables,
+        jnp.asarray(lengths, jnp.int32), 1, scale=0.3, pages=pages,
+        tile=2, n_heads=h, d_head=dh, d_value=d_value,
+        interpret=interpret,
+        write=((news[0], None if d_value else news[1],
+                np.where(active, pos, -1)) if write else None))
+    # (the interpreter's callbacks read arrays on a thread of their
+    # own: an op dispatched beside a running kernel can deadlock it)
+    out = jax.block_until_ready(out)
+    got, got_pools = (out[0], out[1:]) if write else (out, pools)
+    want = []
+    for lane in range(s_n):
+        rows = [pool[1, tables[lane]].reshape(nb * bs, row)[
+            :lengths[lane]].astype(jnp.float32) for pool in wanted]
+        keys, values = rows[0], rows[-1][:, :d_value or row]
+        ql = q[lane].astype(dtype).astype(jnp.float32)
+        if d_value:
+            sc = ql.reshape(h, row) @ keys.T
+            want.append((jax.nn.softmax(sc * 0.3, -1) @ values).reshape(-1))
+            continue
+        ql = ql.reshape(n_kv, h // n_kv, dh)
+        sc = jnp.einsum("gid,tgd->git", ql, keys.reshape(-1, n_kv, dh))
+        want.append(jnp.einsum(
+            "git,tgd->gid", jax.nn.softmax(sc * 0.3, -1),
+            values.reshape(-1, n_kv, dh)).reshape(-1))
+    return (np.asarray(got), np.asarray(jnp.stack(want)),
+            got_pools, wanted, active)
+
+
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+@pytest.mark.parametrize("length", sorted(_DMA_LENGTHS))
+@pytest.mark.parametrize("geometry", sorted(_DMA_GEOMETRIES))
+def test_a_chunk_is_waited_for_on_its_summed_bytes(geometry, length, write):
+    """Every bit pattern of the pages copied into a chunk (one wait a
+    set bit, on 2^b pages' bytes) between two lanes of other lengths,
+    whose first chunks the lane before starts: results and pools equal
+    plain attention after a scatter, at a K and a V pool under plain
+    and grouped heads and at a latent pool."""
+    pages = _DMA_GEOMETRIES[geometry][4]
+    got, want, pools, wanted, active = _dma_case(
+        geometry, [pages + 2, _DMA_LENGTHS[length](pages), 1], write)
+    tol = 2e-2 if _DMA_GEOMETRIES[geometry][5] == "bfloat16" else 2e-6
+    np.testing.assert_allclose(got[active], want[active],
+                               atol=tol * float(np.abs(want).max()))
+    # (the scatter puts a lane that writes nothing into the null block)
+    for pool, same in zip(pools, wanted):
+        np.testing.assert_array_equal(np.asarray(pool[:, 1:], np.float32),
+                                      np.asarray(same[:, 1:], np.float32))
+
+
+def _loops_around(jaxpr, name, depth=0):
+    """The loop depths at which primitive `name` stands in `jaxpr`."""
+    import jax
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(depth)
+        inside = depth + (eqn.primitive.name in ("while", "scan"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loops_around(sub, name, inside)
+    return found
+
+
+@pytest.mark.parametrize("geometry", sorted(_DMA_GEOMETRIES))
+def test_dma_ops_count_the_starts_and_waits(geometry):
+    """`dma_ops` is a start a page and, chunk by chunk, a wait for each
+    set bit of the pages copied; and the kernel's waits stand in no
+    loop over pages: under the chunk loop alone, a static list as long
+    as the chunk's bit length a pool (and one for the written row),
+    where its starts stand in a loop of their own."""
+    import jax
+    import jax.numpy as jnp
+
+    h, dh, n_kv, d_value, pages, dtype = _DMA_GEOMETRIES[geometry]
+    n_pages = [1, 2, 3, pages - 1, pages, pages + 1, 2 * pages + 3,
+               7 * pages + pages // 2]
+    brute = []
+    for n in n_pages:
+        ops, left = 0, n
+        while left:
+            copied = min(pages, left)
+            ops += copied + bin(copied).count("1")
+            left -= copied
+        brute.append(ops)
+    assert [paged_attention.dma_ops(n, pages) for n in n_pages] == brute
+    assert list(paged_attention.dma_ops(np.asarray(n_pages), pages)) == brute
+
+    n_pools, row = (1 if d_value else 2), n_kv * dh
+    pool = jnp.zeros((2, 9, 4, row), dtype)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_attention.paged_attention, scale=0.3, pages=pages, tile=2,
+        n_heads=h, d_head=dh, d_value=d_value))(
+            jnp.zeros((2, h * (row if d_value else dh))), pool,
+            None if d_value else pool, jnp.zeros((2, 4), jnp.int32),
+            jnp.ones((2,), jnp.int32), 0,
+            write=(jnp.zeros((2, row)),
+                   None if d_value else jnp.zeros((2, row)),
+                   jnp.zeros((2,), jnp.int32))).jaxpr
+    waits = _loops_around(jaxpr, "dma_wait")
+    assert waits == [1] * (n_pools * (pages.bit_length() + 1))
+    assert max(_loops_around(jaxpr, "dma_start")) == 2
+
+
+def test_a_semaphore_holds_one_chunk_at_a_time(monkeypatch, capfd):
+    """The invariant the waits on summed bytes stand on: `sems[pool,
+    buf]` never has more than one chunk's copies outstanding, so the
+    bytes a wait takes are its chunk's own.  Lanes whose first chunks
+    differ in size, each started by the lane before it, under the
+    interpreter's race detector (a wait that took another chunk's bytes
+    would leave pages of its own chunk not arrived: the interpreter
+    runs a copy when it is waited for, so they read NaN) and its check
+    that every semaphore is back at zero when the kernel ends."""
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as mosaic_interpret)
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(pltpu, "InterpretParams", functools.partial(
+        pltpu.InterpretParams, detect_races=True))
+    paged_attention.paged_attention.clear_cache()
+    try:
+        got, want, pools, wanted, _ = _dma_case(
+            "kv-plain", [7, 2, 13, 5, 1, 6], write=True)
+    finally:
+        paged_attention.paged_attention.clear_cache()
+    assert not mosaic_interpret.races.races_found
+    assert "non-zero count" not in capfd.readouterr().out
+    np.testing.assert_allclose(got, want,
+                               atol=2e-6 * float(np.abs(want).max()))
+    for pool, same in zip(pools, wanted):
+        np.testing.assert_array_equal(np.asarray(pool), np.asarray(same))
+
+
 @pytest.mark.parametrize("kv_dtype,kernel", [
     (None, "pallas"), ("bf16", "pallas"), ("int8", "xla:kv_dtype")])
 def test_greedy_decode_agrees_pallas_vs_xla(kv_dtype, kernel):
@@ -834,3 +1016,37 @@ def test_analyze_rows_follow_the_platform(monkeypatch):
     for refused in (dict(spec, d_model=32), dict(spec, kv_dtype="int8")):
         rep = analysis.analyze_generation_spec(refused, slots=4)
         assert rep["kernels"][0]["backend"] == "xla"
+
+
+# ---------------------------------------------------------------------------
+# the kernel-alone harness (tools/kernel_pace.py)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape", [
+    "deepseek-v2-serve-agent64", "mellum2-12b-a2.5b-serve-agent96",
+    "opt-1.3b-serve-closed32"])
+def test_kernel_pace_rehearses_a_cells_shape(shape, tmp_path):
+    """`tools/kernel_pace.py --rehearse --check`: a cell's shape cut to
+    a toy walks the whole kernel in the interpreter, over every table
+    and ring of the shape, and its result is plain attention's (a
+    bfloat16 pool); off a TPU the tool gives a time for nothing else."""
+    import importlib.util
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "tools",
+                        "kernel_pace.py")
+    spec = importlib.util.spec_from_file_location("kernel_pace", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "pace.json"
+    res = tool.main(["--shape", shape, "--rehearse", "--check",
+                     "--out", str(out)])
+    assert res == json.loads(out.read_text())
+    assert res["rehearsal"] and res["bs16.whole"] > 0
+    assert res["bs16.check"] < 1e-2
+    assert res["bs16.pages"] * 16 >= res["rows"] > 0
+    assert set(tool.VARIANTS) == {"whole", "no_copies", "no_products"}
+    with pytest.raises(SystemExit, match="no TPU"):
+        tool.run(shape)
