@@ -26,6 +26,10 @@ a custom_vjp over the two halves; a caller that keeps the residuals
 itself (the Program op, ops/attention.py) calls the halves:
 `flash_attention_forward`, `flash_attention_backward`.
 
+The backward's kernels follow the diagonal inside a causal tile by
+sub-tiles of `block_q` keys and leave out those above it
+(`_causal_keys`).
+
 Falls back to a plain XLA composition when shapes don't tile (seq not a
 multiple of the block) or no TPU is present and interpret mode is off.
 """
@@ -40,7 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_forward",
-           "flash_attention_backward", "flash_attention_reference"]
+           "flash_attention_backward", "flash_attention_reference",
+           "flash_attention_subtiles", "causal_subtiles"]
 
 NEG_INF = -1e30  # finite mask value: keeps exp()/max() NaN-free in-kernel
 # measured on v5e at seq 4096, d 128, bf16 (async-chain, distinct inputs):
@@ -65,6 +70,67 @@ def _select_blocks(sq: int, sk: int, d: int) -> tuple[int, int]:
 
 
 # ---------------------------------------------------------------------------
+# a causal tile against the diagonal
+# ---------------------------------------------------------------------------
+#
+# The mask is the kernels' own `query >= key`, top-left aligned.  A tile
+# of block_q queries x block_k keys is placed against it by ONE integer,
+# d = its first query - its first key, and divided along its keys into
+# sub-tiles of block_q keys where block_k is 2 to 4 times block_q (both
+# rows of `_select_blocks`); else the tile is its own one sub-tile (a
+# larger ratio too: every case is a body the compiler must hold, its
+# score tiles stacked in VMEM beside the others').  A sub-tile holds a
+# kept element or none, and a tile's dead sub-tiles are its last.  The
+# backward's kernels compute a tile's live sub-tiles only: their time is
+# their tiles' area (0.43 ms of a 2.92 ms fused call at 64 x 2048 x 2 x
+# 64).  The forward takes a live tile whole: its time is what it does a
+# ROW, and the cut gained it nothing (PERF.md section 6, PR 47).  A
+# second diagonal (a window) would be more rows of this table.
+
+def _causal_keys(block_q, block_k):
+    """[(lo, hi, keys)]: a tile with lo <= d < hi (hi None: no upper
+    end) computes its first `keys` keys; one with d under every `lo`
+    holds no kept element and computes nothing."""
+    ratio = block_k // block_q
+    sub = block_q if block_k % block_q == 0 and 1 < ratio <= 4 else block_k
+    # sub-tile n holds a kept element from this d on: the tile's last
+    # query at the sub-tile's first key
+    live_from = [n * sub - (block_q - 1) for n in range(block_k // sub)]
+    return [(lo, hi, (n + 1) * sub) for n, (lo, hi) in enumerate(
+        zip(live_from, live_from[1:] + [None]))]
+
+
+def causal_subtiles(sq, sk, block_q, block_k):
+    """(forward, backward, live) over one head's grid of a causal call,
+    in sub-tiles of block_q queries x block_q keys (x block_k where
+    block_q does not divide it): those the forward kernel computes (its
+    live tiles whole), those a backward kernel computes (`_causal_keys`)
+    and those that hold a kept element, whatever a kernel does.  12, 10,
+    10 at 2048 in blocks of 512 x 1024."""
+    unit = block_q if block_k % block_q == 0 else block_k
+    cases = _causal_keys(block_q, block_k)
+    forward = backward = live = 0
+    for qi in range(sq // block_q):
+        for ki in range(sk // block_k):
+            d = qi * block_q - ki * block_k
+            live += sum(d + block_q - 1 >= n * unit
+                        for n in range(block_k // unit))
+            forward += (d >= cases[0][0]) * block_k // unit
+            backward += sum(keys // unit for lo, hi, keys in cases
+                            if lo <= d and (hi is None or d < hi))
+    return forward, backward, live
+
+
+def _when_causal_keys(qi, ki, block_q, block_k, compute):
+    """Run `compute(keys)` for the tile of q block `qi` and k block `ki`
+    under `pl.when`, for the case of `_causal_keys` the tile is in."""
+    d = qi * block_q - ki * block_k
+    for lo, hi, keys in _causal_keys(block_q, block_k):
+        pl.when(d >= lo if hi is None else (d >= lo) & (d < hi))(
+            functools.partial(compute, keys))
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -74,7 +140,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     (q/k/v tiles [block, pack*d_head]): loads/stores fill the 128-lane
     dim even at d_head 64, and the online softmax runs per packed head
     on its own [block_q, block_k] score tile (block-diagonal — heads
-    never mix).  m/l scratch columns are banded per head."""
+    never mix).  m/l scratch: a head's statistics are column hs * cw,
+    the first of its band."""
     i, j = pl.program_id(1), pl.program_id(2)
     cw = 128 // pack  # scratch column band per packed head
 
@@ -115,10 +182,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
                 p.astype(v.dtype), v[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[:, hs * cw:(hs + 1) * cw] = jnp.broadcast_to(
-                m_new, (block_q, cw))
-            l_ref[:, hs * cw:(hs + 1) * cw] = jnp.broadcast_to(
-                l_new, (block_q, cw))
+            # the band's first column alone is ever read (here and in
+            # `_finish`).  Heads that share the lanes store that column:
+            # a store across half the lanes was 0.38 ms of a 2.19 ms
+            # call at 64 x 2048 x 2 x 64.  A head alone fills them with
+            # one unmasked store, which read 2% faster than its column
+            # at 16 x 8192 x 128 (PERF.md section 6, PR 47)
+            width = cw if pack == 1 else 1
+            band = slice(hs * cw, hs * cw + width)
+            m_ref[:, band] = jnp.broadcast_to(m_new, (block_q, width))
+            l_ref[:, band] = jnp.broadcast_to(l_new, (block_q, width))
 
     if causal:
         # skip K/V blocks strictly above the diagonal of this query tile
@@ -200,6 +273,7 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(q, k, v)
 
@@ -233,18 +307,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def _compute():
+    def _compute(keys):
         # native-dtype MXU operands, f32 accumulate (see _fwd_kernel)
         q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        k = k_ref[0, :keys]
+        v = v_ref[0, :keys]
         do = do_ref[0]
         stats = _stat_columns(lse_ref, delta_ref, pack, block_q)
         if causal:
             rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
+                jnp.int32, (block_q, keys), 0)
             cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+                jnp.int32, (block_q, keys), 1)
             keep = rows >= cols
         for hs in range(pack):
             sl = slice(hs * d_head, (hs + 1) * d_head)
@@ -263,11 +337,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 preferred_element_type=jnp.float32)
 
     if causal:
-        @pl.when(j * block_k <= i * block_q + (block_q - 1))
-        def _():
-            _compute()
+        _when_causal_keys(i, j, block_q, block_k, _compute)
     else:
-        _compute()
+        _compute(block_k)
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -304,19 +376,19 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dq_acc[q_rows, :] = jnp.zeros((block_q, pack * d_head),
                                           jnp.float32)
 
-    def _compute():
+    def _compute(keys):
         # native-dtype MXU operands, f32 accumulate (see _fwd_kernel)
         q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        k = k_ref[0, :keys]
+        v = v_ref[0, :keys]
         do = do_ref[0]
         lse = lse_ref[0]        # (pack, block_q): a row a packed head
         delta = delta_ref[0]    # (pack, block_q)
         if causal:
             krows = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 0)
+                jnp.int32, (keys, block_q), 0)
             qcols = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, block_q), 1)
+                jnp.int32, (keys, block_q), 1)
             keep = qcols >= krows
         for hs in range(pack):
             sl = slice(hs * d_head, (hs + 1) * d_head)
@@ -327,14 +399,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             if causal:
                 st = jnp.where(keep, st, NEG_INF)
             pt = jnp.exp(st - lse[hs:hs + 1, :])
-            dv_acc[:, sl] += jax.lax.dot_general(
+            dv_acc[:keys, sl] += jax.lax.dot_general(
                 pt.astype(do.dtype), do[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dpt = jax.lax.dot_general(
                 v[:, sl], do[:, sl], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dst = (pt * (dpt - delta[hs:hs + 1, :]) * scale).astype(q.dtype)
-            dk_acc[:, sl] += jax.lax.dot_general(
+            dk_acc[:keys, sl] += jax.lax.dot_general(
                 dst, q[:, sl], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if fused:
@@ -344,12 +416,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     preferred_element_type=jnp.float32)
 
     if causal:
-        # a k block gets gradient only from q blocks at/below its diagonal
-        @pl.when(j * block_q + (block_q - 1) >= i * block_k)
-        def _():
-            _compute()
+        # a k block gets gradient only from q blocks at/below its diagonal,
+        # and from a q block on it only its keys up to that block's last
+        # query
+        _when_causal_keys(j, i, block_q, block_k, _compute)
     else:
-        _compute()
+        _compute(block_k)
 
     @pl.when(j == nq - 1)
     def _finish():
@@ -368,11 +440,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # 8192 (bf16, packed width 128).  Past this budget (seq 16384 and up) the
 # dq kernel and the dk/dv kernel stay two.
 FUSED_BWD_DQ_VMEM_BUDGET = 8 * 1024 * 1024
-# what the k-major backward call may take in all: at blocks of 1024 x
-# 2048 (seq 8192 and up) the compiler asks for 23.5 MiB fused and 17.4
-# MiB for dk and dv alone, over the 16 MiB a call gets unasked, of the
-# v5e's 128
-BWD_VMEM_LIMIT = 32 * 1024 * 1024
+# what a call may take in all, of the v5e's 128 MiB: at blocks of 1024 x
+# 2048 (seq 8192 and up) the compiler asks for more than the 16 MiB a
+# call gets unasked (the needs: tests/test_kernels_lower_tpu.py)
+VMEM_LIMIT = 32 * 1024 * 1024
 
 
 def _fused_bwd_fits(sq: int, d: int, itemsize: int) -> bool:
@@ -426,7 +497,7 @@ def _bwd_pallas(q, k, v, lse, delta, do, scale, causal, block_q, block_k,
                         pltpu.VMEM((block_k, d), jnp.float32)] + (
             [pltpu.VMEM((sq, d), jnp.float32)] if fused else []),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=BWD_VMEM_LIMIT),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     if fused:
@@ -638,6 +709,20 @@ def flash_attention_forward(q, k, v, causal=False, scale=None,
         return None
     o, lse = _flash(*(_to_kernel(x, plan) for x in (q, k, v)), *plan)
     return _from_kernel(o, q.shape, plan), lse
+
+
+def flash_attention_subtiles(q, k, v, causal=False, scale=None,
+                             block_q=None, block_k=None, interpret=None,
+                             min_seq_k=MIN_PALLAS_SEQ_K, platform=None):
+    """`causal_subtiles` of a head's grid in the kernel calls
+    `flash_attention` makes of the same arguments (arrays or their
+    shapes); None where they are not causal or are not made."""
+    plan = _plan(q, k, v, causal, scale, block_q, block_k, interpret,
+                 min_seq_k, platform)
+    if plan is None or not plan.causal:
+        return None
+    return causal_subtiles(q.shape[1], k.shape[1], plan.block_q,
+                           plan.block_k)
 
 
 def flash_attention_backward(q, k, v, out, lse, d_out, causal=False,

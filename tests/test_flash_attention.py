@@ -384,3 +384,246 @@ def test_forward_and_backward_halves_agree_with_the_function():
     # not selected (no TPU, no interpreter): both halves say so
     assert fa.flash_attention_forward(q, k, v) is None
     assert fa.flash_attention_backward(q, k, v, out, lse, out) is None
+
+
+# ---------------------------------------------------------------------------
+# a causal tile against the diagonal (PR 47: `_causal_keys`): the
+# backward's kernels leave out a tile's sub-tiles above it
+# ---------------------------------------------------------------------------
+
+# id: sq, sk, heads, d_head, block_q, block_k, causal, fused backward
+_DIAGONAL = {
+    # block_k = 2 x block_q, the cell's 4 x 2 tiles a head: a query block
+    # in each half of its diagonal key block, and one wholly below
+    "ratio2-pairs-of-64": (512, 512, 2, 64, 128, 256, True, True),
+    "ratio2-head-of-128": (512, 512, 1, 128, 128, 256, True, True),
+    "ratio2-two-kernels": (512, 512, 2, 64, 128, 256, True, False),
+    # block_k = 4 x block_q: a query block in each quarter
+    "ratio4": (1024, 1024, 2, 64, 128, 512, True, True),
+    "ratio4-two-kernels": (1024, 1024, 1, 128, 128, 512, True, False),
+    # a tile that is its own one sub-tile
+    "ratio1": (384, 384, 2, 64, 128, 128, True, True),
+    "block_k-under-block_q": (512, 512, 2, 64, 256, 128, True, True),
+    "block_k-under-block_q-two-kernels":
+        (512, 512, 1, 128, 256, 128, True, False),
+    "ratio8": (1024, 1024, 2, 64, 128, 1024, True, True),
+    "not-a-multiple": (768, 768, 2, 64, 256, 384, True, True),
+    # the diagonal is top-left aligned whatever the lengths
+    "sk-over-sq": (256, 512, 2, 64, 128, 256, True, True),
+    "sk-over-sq-two-kernels": (256, 512, 2, 64, 128, 256, True, False),
+    "sk-under-sq": (512, 256, 2, 64, 128, 256, True, True),
+    "not-causal": (512, 512, 2, 64, 128, 256, False, True),
+    "not-causal-two-kernels": (256, 512, 1, 128, 128, 256, False, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DIAGONAL))
+def test_causal_tiles_match_reference(monkeypatch, case):
+    """Forward and all three gradients through the Pallas interpreter
+    against the XLA composition, over every way a tile can lie against
+    the diagonal."""
+    sq, sk, h, d, block_q, block_k, causal, fused = _DIAGONAL[case]
+    fa = _kernel_module()
+    if not fused:
+        monkeypatch.setattr(fa, "FUSED_BWD_DQ_VMEM_BUDGET", 0)
+    assert fa._fused_bwd_fits(sq, h * d, 4) is fused
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(1, sq, h, d).astype(np.float32))
+    k = jnp.asarray(rng.randn(1, sk, h, d).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, sk, h, d).astype(np.float32))
+    w = jnp.asarray(rng.randn(1, sq, h, d).astype(np.float32))
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out,) + vjp(w)
+
+    got = run(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, interpret=True, block_q=block_q,
+        block_k=block_k))
+    want = run(lambda q, k, v: flash_attention_reference(
+        q, k, v, causal=causal))
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def _subtiles_by_the_mask(sq, sk, block_q, block_k):
+    """`causal_subtiles` from the mask `rows >= cols` itself: a forward
+    that takes a tile with a kept element whole, a backward that takes
+    the tile's sub-tiles with one."""
+    keep = np.arange(sq)[:, None] >= np.arange(sk)[None, :]
+    divides = block_k % block_q == 0
+    unit = block_q if divides else block_k
+    sub = block_q if divides and 1 < block_k // block_q <= 4 else block_k
+    forward = backward = live = 0
+    for r0 in range(0, sq, block_q):
+        for c0 in range(0, sk, block_k):
+            tile = keep[r0:r0 + block_q, c0:c0 + block_k]
+            live += sum(tile[:, c:c + unit].any()
+                        for c in range(0, block_k, unit))
+            forward += tile.any() * block_k // unit
+            alive = [tile[:, c:c + sub].any()
+                     for c in range(0, block_k, sub)]
+            assert alive == sorted(alive, reverse=True)   # a prefix
+            backward += sum(alive) * sub // unit
+    return forward, backward, live
+
+
+@pytest.mark.parametrize("case", sorted(
+    c for c in _DIAGONAL if _DIAGONAL[c][6] and _DIAGONAL[c][7]) + [
+        "opt-1.3b-train-seq2048", "seq8192"])
+def test_causal_subtiles_counts_what_the_mask_says(case):
+    fa = _kernel_module()
+    sq, sk, _, _, block_q, block_k = {
+        "opt-1.3b-train-seq2048": (2048, 2048, 32, 64, 512, 1024),
+        "seq8192": (8192, 8192, 32, 64, 1024, 2048),
+        **_DIAGONAL}[case][:6]
+    got = fa.causal_subtiles(sq, sk, block_q, block_k)
+    assert got == _subtiles_by_the_mask(sq, sk, block_q, block_k)
+    forward, backward, live = got
+    if block_k // block_q <= 4 or block_k % block_q:
+        assert backward == live     # no sub-tile of zeros is computed
+    if case == "opt-1.3b-train-seq2048":
+        assert fa._select_blocks(sq, sk, 64) == (block_q, block_k)
+        assert got == (12, 10, 10)
+        # of the calls the kernel makes at the cell's shape
+        q = jax.ShapeDtypeStruct((4, sq, 32, 64), jnp.bfloat16)
+        assert fa.flash_attention_subtiles(
+            q, q, q, causal=True, platform="tpu") == got
+        assert fa.flash_attention_subtiles(q, q, q, platform="tpu") is None
+        assert fa.flash_attention_subtiles(q, q, q, causal=True) is None
+
+
+def _sub_jaxprs(jaxpr):
+    for eqn in jaxpr.eqns:
+        for val in eqn.params.values():
+            for x in val if isinstance(val, (tuple, list)) else (val,):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+
+def _case_bodies(fn, *args):
+    """The first product's shape in each `pl.when` body of `fn`'s
+    kernels that multiplies."""
+    bodies = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "cond":
+                for br in eqn.params["branches"]:
+                    dots = [e for e in br.jaxpr.eqns
+                            if e.primitive.name == "dot_general"]
+                    if dots:
+                        bodies.append(tuple(dots[0].outvars[0].aval.shape))
+        for inner in _sub_jaxprs(jaxpr):
+            walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return bodies
+
+
+@pytest.mark.parametrize("kernel", ["forward", "fused", "dq", "dkv"])
+def test_a_cut_case_multiplies_its_live_keys_only(kernel, monkeypatch):
+    """The traced kernel's cases at blocks of 128 x 256: in the
+    backward's kernels the tile on the diagonal with one live half
+    multiplies 128 keys and every other one all 256; the forward has one
+    body, over all 256."""
+    fa = _kernel_module()
+    x = jnp.zeros((1, 512, 128), jnp.float32)       # a packed head pair
+    stat = jnp.zeros((1, 2, 512), jnp.float32)
+    plan = (0.125, True, 128, 256, False, 2, 1)
+    if kernel == "forward":
+        assert _case_bodies(lambda q, k, v: fa._fwd_pallas(
+            q, k, v, *plan), x, x, x) == [(128, 256)]
+        return
+    if kernel != "fused":
+        monkeypatch.setattr(fa, "FUSED_BWD_DQ_VMEM_BUDGET", 0)
+
+    def backward(causal):
+        return _case_bodies(
+            lambda q, k, v, lse, delta, do: fa._bwd_pallas(
+                q, k, v, lse, delta, do, plan[0], causal, *plan[2:]),
+            x, x, x, stat, stat, x)
+
+    bodies = backward(True)
+    if kernel == "dq":
+        bodies = bodies[2:]         # the dk/dv kernel's come first
+    elif kernel == "dkv":
+        bodies = bodies[:2]
+    # the score tile: [queries, keys] in the q-major kernel, [keys,
+    # queries] in the k-major one
+    assert bodies == ([(128, 128), (128, 256)] if kernel == "dq"
+                      else [(128, 128), (256, 128)])
+    # a call that is not causal has no cases
+    assert backward(False) == []
+
+
+def test_subtiles_share_reads_the_cells_shape(monkeypatch):
+    """`train_attention_subtiles_share`: the kernel module's count of
+    the calls it makes at the cell's shape, forward and backward over
+    the live ones twice; nothing where the kernel is not what runs."""
+    import os
+    import sys
+    import types
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf")
+    monkeypatch.syspath_prepend(perf)
+    monkeypatch.delitem(sys.modules, "common", raising=False)
+    import common
+
+    reader = common.load_module(os.path.join(
+        perf, "metrics", "train_attention_subtiles_share.py"))
+    cell = types.SimpleNamespace(
+        config=common.load_json(os.path.join(
+            perf, "configs", "opt-1.3b-depth8.json")),
+        traffic=common.load_json(os.path.join(
+            perf, "traffic", "pretrain-seq2048.json")))
+    run = types.SimpleNamespace(cell=cell)
+    assert reader.compute(run) is None      # no TPU here: the composition
+    fa = _kernel_module()
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    assert reader.compute(run) == pytest.approx(100.0 * (12 + 10) / 20)
+    # a kernel that cuts nothing; a module without the count
+    monkeypatch.setattr(fa, "_causal_keys",
+                        lambda bq, bk: [(1 - bq, None, bk)])
+    assert reader.compute(run) == pytest.approx(120.0)
+    monkeypatch.delattr(fa, "flash_attention_subtiles")
+    assert reader.compute(run) is None
+
+
+def test_kernel_pace_rehearses_the_training_cells_flash_kernels(tmp_path):
+    """`tools/kernel_pace.py --shape opt-1.3b-train-seq2048 --rehearse
+    --check`: the cell's 4 x 2 tiles a head at toy blocks through the
+    interpreter, forward and backward each alone; `uncut` (the table of
+    a kernel that takes a live tile whole) computes the same, `no_mask`
+    does not; off a TPU the tool gives a time for nothing else."""
+    import importlib.util
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "tools",
+                        "kernel_pace.py")
+    spec = importlib.util.spec_from_file_location("kernel_pace", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "pace.json"
+    res = tool.main(["--shape", "opt-1.3b-train-seq2048", "--rehearse",
+                     "--check", "--out", str(out)])
+    assert res == json.loads(out.read_text())
+    assert res["rehearsal"] and res["fwd.whole"] > 0 and res["bwd.whole"] > 0
+    assert res["blocks"] == [128, 256] and res["subtiles"] == [12, 10, 10]
+    assert res["check"] < 1e-2
+    fa = _kernel_module()
+    toy = dict(tool.SHAPES["opt-1.3b-train-seq2048"], batch=1, heads=2,
+               seq=512, block_q=128, block_k=256)
+    with tool.flash_removed("uncut", fa):
+        assert fa.causal_subtiles(512, 512, 128, 256) == (12, 12, 10)
+        assert tool.check_flash(toy, fa, True) < 1e-2
+    with tool.flash_removed("no_mask", fa):
+        assert tool.check_flash(toy, fa, True) > 1e-1
+    assert fa.causal_subtiles(512, 512, 128, 256) == (12, 10, 10)
+    assert tool.check_flash(toy, fa, True) < 1e-2
+    with pytest.raises(SystemExit, match="no TPU"):
+        tool.run("opt-1.3b-train-seq2048")

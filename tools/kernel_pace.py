@@ -25,12 +25,25 @@ over random pools, before any timing.  `--rehearse`: the name's shape
 cut to a toy and run in the Pallas interpreter on the CPU, to see that
 the script runs: never a number.
 
+The flash-attention kernels of a training step the same way
+(`--shape opt-1.3b-train-seq2048`, and `flash-seq8192`,
+`flash-seq8192-d128`, `flash-seq16384` for the shapes no cell runs: one
+layer's forward call and its backward call, each alone, ms a call):
+whole, `no_mask` (no tile applies the causal mask: WRONG results, true
+time) and `uncut` (the backward's kernels take every live tile over all
+its keys, as the forward does: right results, what
+`flash_attention._causal_keys` saves).  `--check`: the kernel under
+`jax.grad` against `flash_attention_reference`.  Copied into the
+checkout of a commit before PR 47 the same command times that commit's
+kernels (`--variants whole,no_mask`: they have no cut to undo).
+
 It imports the kernel and is imported by nothing a cell runs.
 """
 from __future__ import annotations
 
 import argparse
 import contextlib
+import importlib
 import json
 import os
 import sys
@@ -57,8 +70,24 @@ SHAPES = {
     "opt-1.3b-serve-closed32": dict(
         slots=32, heads=32, d_head=64, row=2048, d_value=0, bs=16,
         ctx=512, layers=((512, 24),), scale=0.125, mean_rows=140),
+    # the flash kernels of one layer's training step: 4 sequences of
+    # 2048, 32 heads of 64 packed in pairs, bf16, causal, the blocks
+    # `_select_blocks` gives (512 x 1024)
+    "opt-1.3b-train-seq2048": dict(
+        kernel="flash", batch=4, heads=32, d_head=64, seq=2048),
+    # what no cell runs and `parallel/` and `chip_smoke.py` do: the same
+    # tokens as ONE sequence (blocks of 1024 x 2048, the fused backward
+    # at its VMEM request's edge), heads of 128 that fill the lanes
+    # alone, and the length from which dq has a kernel of its own
+    "flash-seq8192": dict(
+        kernel="flash", batch=1, heads=32, d_head=64, seq=8192),
+    "flash-seq8192-d128": dict(
+        kernel="flash", batch=1, heads=16, d_head=128, seq=8192),
+    "flash-seq16384": dict(
+        kernel="flash", batch=1, heads=32, d_head=64, seq=16384),
 }
 VARIANTS = ("whole", "no_copies", "no_products")
+FLASH_VARIANTS = ("whole", "no_mask", "uncut")
 
 
 def lengths_of(shape):
@@ -228,6 +257,144 @@ def pace(shape, bs, pa, variant="whole", calls=30, interpret=False):
     return took / calls * 1e3
 
 
+# ---------------------------------------------------------------------------
+# the flash-attention kernels of a training step
+# ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def flash_removed(variant, fa):
+    """`fa`'s kernels traced anew inside without `variant`'s part: the
+    select that puts NEG_INF above the diagonal handing its scores
+    through (`no_mask`), or the table of a tile's cases with the one
+    case of a kernel that takes a live tile whole (`uncut`)."""
+    import jax
+    import jax.numpy as jnp
+
+    real_where, real_keys = jnp.where, getattr(fa, "_causal_keys", None)
+    jax.clear_caches()
+    if variant == "no_mask":
+        jnp.where = lambda keep, x, y: (
+            x if isinstance(y, float) and y == fa.NEG_INF
+            else real_where(keep, x, y))
+    elif variant == "uncut":
+        if real_keys is None:
+            raise SystemExit("kernel_pace: these kernels cut nothing")
+        fa._causal_keys = lambda bq, bk: [(1 - bq, None, bk)]
+    elif variant != "whole":
+        raise ValueError(
+            f"no variant {variant!r}: one of {FLASH_VARIANTS}")
+    try:
+        yield
+    finally:
+        jnp.where = real_where
+        if real_keys is not None:
+            fa._causal_keys = real_keys
+        jax.clear_caches()
+
+
+def build_flash(shape, fa, interpret=False):
+    """-> (the jitted forward call, the jitted backward call, their
+    arguments in the kernels' layout, the same as [b, s, h, d])."""
+    import jax
+    import jax.numpy as jnp
+
+    dims = tuple(shape[k] for k in ("batch", "seq", "heads", "d_head"))
+    keys = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v, do = ((jax.random.normal(key, dims, jnp.float32) * 0.5
+                    ).astype(jnp.bfloat16) for key in keys)
+    plan = fa._plan(q, k, v, True, None, shape.get("block_q"),
+                    shape.get("block_k"), interpret, 0, "tpu")
+    assert plan is not None, "the kernel is not selected at this shape"
+    kq, kk, kv, kdo = (fa._to_kernel(x, plan) for x in (q, k, v, do))
+    fwd = jax.jit(lambda q, k, v: fa._fwd_pallas(q, k, v, *plan))
+    bwd = jax.jit(lambda q, k, v, lse, delta, do: fa._bwd_pallas(
+        q, k, v, lse, delta, do, *plan))
+    return fwd, bwd, (kq, kk, kv, kdo), (q, k, v, do), plan
+
+
+def pace_flash(shape, fa, variant="whole", calls=30, interpret=False):
+    """(ms a forward call, ms a backward call), each kernel alone."""
+    import jax
+    import jax.numpy as jnp
+
+    def timed(f, *args):
+        jax.block_until_ready(f(*args))
+        t = time.perf_counter()
+        for _ in range(calls):
+            out = f(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t) / calls * 1e3
+
+    with flash_removed(variant, fa):
+        fwd, bwd, (q, k, v, do), _, _ = build_flash(shape, fa, interpret)
+        _, lse = fwd(q, k, v)
+        # the kernel's time does not follow its values: zeros for
+        # `delta`, the rowsum(do * o) `_backward` hands it beside lse
+        return (timed(fwd, q, k, v),
+                timed(bwd, q, k, v, lse, jnp.zeros_like(lse), do))
+
+
+def check_flash(shape, fa, interpret=False):
+    """Largest difference of the kernel's result and of its three
+    gradients from `flash_attention_reference`'s, each as a share of the
+    reference's largest value (bfloat16 operands: some 1e-2)."""
+    import jax
+    import jax.numpy as jnp
+
+    _, _, _, (q, k, v, do), plan = build_flash(shape, fa, interpret)
+
+    def run(f):
+        out, vjp = jax.vjp(f, q, k, v)
+        return (out,) + vjp(do)
+
+    got = run(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, block_q=plan.block_q, block_k=plan.block_k,
+        interpret=interpret, min_seq_k=0, platform="tpu"))
+    want = run(lambda q, k, v: fa.flash_attention_reference(
+        q, k, v, causal=True))
+    return max(float(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32)
+                             ).max() / jnp.abs(w.astype(jnp.float32)).max())
+               for g, w in zip(got, want))
+
+
+def run_flash(name, variants=FLASH_VARIANTS, calls=30, with_check=False,
+              rehearse=False):
+    """-> {"shape", "blocks", "fwd.<variant>": ms, "bwd.<variant>": ms,
+    "subtiles", "check"}."""
+    import jax
+
+    # the package's attribute of that name is the function
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    shape = SHAPES[name]
+    if rehearse:
+        # blocks of 128 x 256 over 512: the cell's 4 x 2 tiles a head
+        shape, calls = dict(shape, batch=1, heads=2, seq=512, block_q=128,
+                            block_k=256), 1
+    res = {"shape": name, "device": jax.devices()[0].device_kind,
+           "rehearsal": bool(rehearse)}
+    plan = build_flash(shape, fa, rehearse)[-1]
+    res["blocks"] = [plan.block_q, plan.block_k]
+    if hasattr(fa, "causal_subtiles"):
+        # a head's grid: the forward's, the backward's, the live ones
+        res["subtiles"] = list(fa.causal_subtiles(
+            shape["seq"], shape["seq"], plan.block_q, plan.block_k))
+    for variant in variants:
+        if rehearse and variant != "whole":
+            continue    # the interpreter walks the whole kernel only
+        f, b = pace_flash(shape, fa, variant, calls, rehearse)
+        res[f"fwd.{variant}"], res[f"bwd.{variant}"] = (round(f, 4),
+                                                        round(b, 4))
+        print(f"{name} {variant} fwd {f:.4f} bwd {b:.4f}", flush=True)
+    if with_check:
+        # the reference holds a head's whole score matrix in float32,
+        # and its gradients': a head pair of a long sequence is what
+        # fits beside them
+        few = dict(shape, heads=min(shape["heads"], 2))
+        res["check"] = check_flash(
+            few if shape["seq"] > 4096 else shape, fa, rehearse)
+    return res
+
+
 def toy(shape):
     """`shape` cut to what the interpreter walks in seconds."""
     return dict(shape, slots=3, heads=min(shape["heads"], 8),
@@ -238,20 +405,23 @@ def toy(shape):
                 mean_rows=90)
 
 
-def run(name, block_sizes=None, variants=VARIANTS, pa=None, calls=30,
+def run(name, block_sizes=None, variants=None, pa=None, calls=30,
         with_check=False, rehearse=False):
     """-> {"shape", "rows", "bs<n>.<variant>": ms, "bs<n>.pages",
     "bs<n>.tiling", "bs<n>.check"}."""
     import jax
 
-    if pa is None:
-        from paddle_tpu.kernels import paged_attention as pa
     shape = SHAPES[name]
-    if rehearse:
-        shape, calls = toy(shape), 1
-    elif jax.devices()[0].platform != "tpu":
+    if not rehearse and jax.devices()[0].platform != "tpu":
         raise SystemExit("kernel_pace: no TPU here; a time comes from a "
                          "chip run (--rehearse walks the script)")
+    if shape.get("kernel") == "flash":
+        return run_flash(name, variants or FLASH_VARIANTS, calls=calls,
+                         with_check=with_check, rehearse=rehearse)
+    if pa is None:
+        from paddle_tpu.kernels import paged_attention as pa
+    if rehearse:
+        shape, calls = toy(shape), 1
     lengths = lengths_of(shape)
     res = {"shape": name, "device": jax.devices()[0].device_kind,
            "rehearsal": bool(rehearse),
@@ -261,7 +431,7 @@ def run(name, block_sizes=None, variants=VARIANTS, pa=None, calls=30,
         res[f"bs{bs}.pages"] = int(sum(
             n * (-(-np.minimum(lengths, rows) // bs)).sum()
             for rows, n in shape["layers"]))
-        for variant in variants:
+        for variant in variants or VARIANTS:
             if rehearse and variant != "whole":
                 continue    # the interpreter walks the whole kernel only
             res[f"bs{bs}.{variant}"] = round(
@@ -278,7 +448,9 @@ def main(argv=None):
     ap.add_argument("--shape", default="deepseek-v2-serve-agent64",
                     choices=sorted(SHAPES))
     ap.add_argument("--block-sizes", default="")
-    ap.add_argument("--variants", default=",".join(VARIANTS))
+    ap.add_argument("--variants", default="",
+                    help="of the shape's kernel's (all): "
+                    + ",".join(VARIANTS + FLASH_VARIANTS[1:]))
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
