@@ -104,6 +104,28 @@ architecture is a second description and not a second decoder.
                `routed_scaling_factor`, not renormalised.  Dense layer,
                held experts and the shared expert are the sixth's.
 
+  double-      the eighth (Meituan LongCat-Flash, `model_type:
+  layer-like   longcat_flash`), fields again: a LAYER THAT IS TWO
+               SUB-BLOCKS (`sub_blocks` 2), each a latent attention
+               with a cache plane of its own and a dense SwiGLU of
+               `dense_d_inner`, on four norms, and ONE expert layer
+               across them (the SHORTCUT): it reads what the FIRST
+               dense FFN reads (the first sub-block's normed state
+               after attention) and its result joins the stream after
+               the SECOND dense FFN, so that an expert-parallel
+               deployment exchanges rows while the second sub-block
+               computes.  A router WIDER than its experts
+               (`zero_experts`: columns `n_experts` onward are IDENTITY
+               experts, which have no matrices; an assignment to one
+               adds its weight times the expert layer's input and sends
+               no row to the grouped matmul), a softmax over all
+               columns with a CHOICE BIAS beside it (`router_bias`: the
+               sixth's, under the other router), the weights p x
+               `routed_scaling_factor`, not renormalised.  And a
+               constant on each normed latent (`scale_q_lora`,
+               `scale_kv_lora`: sqrt(d_model / rank)).  The latent
+               cache and the held experts are the seventh's.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -150,8 +172,9 @@ class BlockSpec:
     stack's fields, and the ring-and-table block with experts, an FFN
     kind a layer, the per-head QK-norm, RoPE on some layer kinds only
     and the sigmoid router, and the table-only block with experts on a
-    LATENT cache under the group-limited softmax router (module
-    docstring).  `layer_types`,
+    LATENT cache under the group-limited softmax router, and the DOUBLE
+    layer on a latent cache with identity experts under a softmax
+    router with a choice bias (module docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -222,6 +245,19 @@ class BlockSpec:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # the normed latents times sqrt(d_model / their rank)
+    scale_q_lora: bool = False
+    scale_kv_lora: bool = False
+    # -- a DOUBLE layer (`sub_blocks` 2): two sub-blocks a layer, each a
+    #    latent attention and a dense SwiGLU of `dense_d_inner` on norms
+    #    of their own, and ONE expert layer whose input is the first
+    #    sub-block's FFN input and whose result joins after the second
+    #    dense FFN (the shortcut)
+    sub_blocks: int = 1
+    # -- IDENTITY experts: the router's columns [n_experts, n_experts +
+    #    zero_experts) have no matrices; an assignment to one adds its
+    #    weight times the expert layer's input
+    zero_experts: int = 0
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
@@ -264,11 +300,47 @@ class BlockSpec:
                 f"experts [{self.experts_first}, {self.experts_first} + "
                 f"{self.experts_held}) are not among the "
                 f"{self.n_experts} routed over")
+        if self.sub_blocks not in (1, 2):
+            raise ValueError(f"sub_blocks {self.sub_blocks}: 1, or 2 (a "
+                             "double layer)")
+        if self.sub_blocks == 2 and (
+                self.ffn != "moe_swiglu" or self.kv_lora_rank < 1
+                or self.dense_d_inner < 1 or self.mlp_layer_types
+                or self.layer_types or self.shared_d_inner
+                or self.post_norm or self.passes > 1 or self.exit_gate):
+            raise NotImplementedError(
+                f"block {self.name!r}: a double layer (sub_blocks 2) is "
+                "two latent attentions (kv_lora_rank), two dense FFNs "
+                "(dense_d_inner) and one expert layer (ffn 'moe_swiglu') "
+                "across them; no mlp_layer_types, layer_types, shared "
+                "expert, post_norm, passes or exit_gate beside it")
+        if self.zero_experts < 0 or (self.zero_experts and (
+                self.ffn != "moe_swiglu" or self.router != "softmax"
+                or self.norm_topk_prob or self.n_group > 1)):
+            raise NotImplementedError(
+                f"block {self.name!r}: identity experts (zero_experts) "
+                "are columns of a softmax router over experts (ffn "
+                "'moe_swiglu') whose weights are not renormalised "
+                "(norm_topk_prob: over which of them?) and whose choice "
+                "is not group-limited (n_group: they lie in no group)")
+        if (self.scale_q_lora or self.scale_kv_lora) and (
+                self.kv_lora_rank < 1):
+            raise ValueError(
+                f"block {self.name!r}: scale_q_lora and scale_kv_lora "
+                "are constants on the normed latents of a latent cache "
+                "(kv_lora_rank)")
 
     @property
     def held(self):
         """(first, count) of the experts whose matrices are here."""
         return self.experts_first, self.experts_held or self.n_experts
+
+    @property
+    def has_unheld(self) -> bool:
+        """Whether a chosen column can be one with no matrices here: an
+        absent expert's (a share is held) or an identity expert's.  Such
+        an assignment is in no group of the grouped matmul."""
+        return self.held[1] < self.n_experts or self.zero_experts > 0
 
     @property
     def latent(self) -> bool:
@@ -365,7 +437,14 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     are [experts HELD, ...]; the router keeps its published width.  A
     DENSE layer among sparse ones (`mlp_layer_types`) has the dense
     block's three matrices at `dense_d_inner` and no "router" key:
-    that is how the step tells the two apart."""
+    that is how the step tells the two apart.  A DOUBLE layer
+    (`sub_blocks` 2) is TWO entries of `.layers`, one a sub-block:
+    each the latent layer's arrays and norms under `layer_<l>.sub_<i>.`
+    and a dense SwiGLU at `dense_d_inner` under "dense_gate",
+    "dense_up" and "dense_down"; the first also holds the layer's ONE
+    expert layer (`layer_<l>.router.w_0`, ...; "router", "gate", "up",
+    "down"), the second no "router".  The router and its choice bias
+    are `n_experts + zero_experts` wide."""
     dense = spec.ffn == "swiglu"
     if (spec.norm, spec.bias) != ("rms_norm", False) or spec.ffn not in (
             "moe_swiglu", "swiglu"):
@@ -422,13 +501,13 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             "(mlp_layer_types) are built for a block of attention "
             "layers with experts (ffn 'moe_swiglu') and need "
             "dense_d_inner, the dense layers' width")
-    if spec.router != "sigmoid" and (spec.router_bias or (
-            spec.routed_scaling_factor != 1.0 and spec.norm_topk_prob)):
+    if spec.router != "sigmoid" and (
+            spec.routed_scaling_factor != 1.0 and spec.norm_topk_prob):
         raise NotImplementedError(
-            f"block {spec.name!r}: a choice bias, and a scaling factor "
-            "on renormalised weights, are built and tested on the "
-            "sigmoid router alone (the softmax router's probabilities "
-            "take a factor as they are)")
+            f"block {spec.name!r}: a scaling factor on renormalised "
+            "weights is built and tested on the sigmoid router alone "
+            "(the softmax router's probabilities take a factor as they "
+            "are, and a choice bias)")
     if spec.n_group > 1 and (spec.router != "softmax"
                              or spec.router_bias):
         raise NotImplementedError(
@@ -466,8 +545,53 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         shapes[name] = tuple(int(s) for s in shape)
         return name, None
 
+    def latent_arrays(p):
+        dqk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
+        return {"norm1": add(p + "attn_norm.scale_0", d),
+                "q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
+                "q_a_norm": add(p + "q_a_norm.scale_0", spec.q_lora_rank),
+                "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
+                           n_heads * dqk),
+                "kv_a": add(p + "kv_a_proj.w_0", d,
+                            spec.kv_lora_rank + spec.qk_rope_head_dim),
+                "kv_a_norm": add(p + "kv_a_norm.scale_0",
+                                 spec.kv_lora_rank),
+                "kv_b": add(p + "kv_b_proj.w_0", spec.kv_lora_rank,
+                            n_heads * (spec.qk_nope_head_dim
+                                       + spec.v_head_dim)),
+                "o": add(p + "o_proj.w_0", n_heads * spec.v_head_dim, d)}
+
+    def expert_arrays(p):
+        wide = e + spec.zero_experts
+        lay = {"router": add(p + "router.w_0", d, wide),
+               "gate": add(p + "experts_gate.w_0", held, d, f),
+               "up": add(p + "experts_up.w_0", held, d, f),
+               "down": add(p + "experts_down.w_0", held, f, d)}
+        if spec.router_bias:
+            lay["router_bias"] = add(p + "router_bias.b_0", wide)
+        return lay
+
+    def double_layer(l):
+        """Layer l's two sub-blocks, the expert layer with the first
+        (where it is computed)."""
+        subs = []
+        for i in range(2):
+            p, fd = f"layer_{l}.sub_{i}.", spec.dense_d_inner
+            lay = latent_arrays(p)
+            lay.update({"norm2": add(p + "ffn_norm.scale_0", d),
+                        "dense_gate": add(p + "ffn_gate.w_0", d, fd),
+                        "dense_up": add(p + "ffn_up.w_0", d, fd),
+                        "dense_down": add(p + "ffn_down.w_0", fd, d)})
+            if i == 0:
+                lay.update(expert_arrays(f"layer_{l}."))
+            subs.append(lay)
+        return subs
+
     layers = []
     for l, kind in enumerate(kinds):
+        if spec.sub_blocks == 2:
+            layers += double_layer(l)
+            continue
         p = f"layer_{l}."
         if kind == MAMBA:
             lay = {"norm1": add(p + "mixer_norm.scale_0", d),
@@ -482,22 +606,7 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
         elif spec.latent:
-            dqk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
-            lay = {"norm1": add(p + "attn_norm.scale_0", d),
-                   "q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
-                   "q_a_norm": add(p + "q_a_norm.scale_0",
-                                   spec.q_lora_rank),
-                   "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
-                              n_heads * dqk),
-                   "kv_a": add(p + "kv_a_proj.w_0", d,
-                               spec.kv_lora_rank + spec.qk_rope_head_dim),
-                   "kv_a_norm": add(p + "kv_a_norm.scale_0",
-                                    spec.kv_lora_rank),
-                   "kv_b": add(p + "kv_b_proj.w_0", spec.kv_lora_rank,
-                               n_heads * (spec.qk_nope_head_dim
-                                          + spec.v_head_dim)),
-                   "o": add(p + "o_proj.w_0", n_heads * spec.v_head_dim,
-                            d)}
+            lay = latent_arrays(p)
         else:
             lay = {"norm1": add(p + "attn_norm.scale_0", d),
                    "q": add(p + "q_proj.w_0", d, dq),
@@ -513,17 +622,12 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                         "up": add(p + "ffn_up.w_0", d, fd),
                         "down": add(p + "ffn_down.w_0", fd, d)})
         else:
-            lay.update({"router": add(p + "router.w_0", d, e),
-                        "gate": add(p + "experts_gate.w_0", held, d, f),
-                        "up": add(p + "experts_up.w_0", held, d, f),
-                        "down": add(p + "experts_down.w_0", held, f, d)})
+            lay.update(expert_arrays(p))
         if spec.post_norm:
             # g2 and g4: on what attention and the FFN give, before the
             # residual stream takes it
             lay["post1"] = add(p + "attn_post_norm.scale_0", d)
             lay["post2"] = add(p + "ffn_post_norm.scale_0", d)
-        if spec.router_bias and "router" in lay:
-            lay["router_bias"] = add(p + "router_bias.b_0", e)
         if fs and "router" in lay:
             lay.update({"shared_gate": add(p + "shared_gate.w_0", d, fs),
                         "shared_up": add(p + "shared_up.w_0", d, fs),
@@ -633,11 +737,16 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     """The router: tokens m [T, D] (float32) -> (weights [T, k]
     float32, experts [T, k] int32), the k largest of the softmax over
     ALL experts, largest first.  Under `router: "sigmoid"` the scores
-    are sigmoid(logits), the k chosen are the k largest of scores +
-    `b_router` [E] (the bias decides the CHOICE alone: the weights are
-    the scores as they are), renormalised under `norm_topk_prob` and
-    then times `routed_scaling_factor`; a tie goes to the lower expert
-    index either way.  Under `n_group` > 1 the choice is GROUP-LIMITED:
+    are sigmoid(logits).  A CHOICE BIAS `b_router` [E] serves EITHER
+    router: the k chosen are the k largest of scores + `b_router` (the
+    bias decides the CHOICE alone: the weights are the scores as they
+    are, probabilities or sigmoids), renormalised under `norm_topk_prob`
+    and then times `routed_scaling_factor`; a tie goes to the lower
+    expert index either way.  "All experts" are all the router's
+    columns: with IDENTITY experts (`zero_experts`) the matrix and the
+    bias are `n_experts + zero_experts` wide, the softmax is over all
+    of them and an index at or past `n_experts` is an identity expert
+    (`moe_ffn`).  Under `n_group` > 1 the choice is GROUP-LIMITED:
     the experts lie in `n_group` consecutive groups, a group's score is
     its largest probability, the `topk_group` groups of highest score
     are kept (a tie to the lower group), every other expert's score is
@@ -702,6 +811,13 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     weight is NOT shared out among the others (the chip that holds the
     expert adds that part).  `hit` counts held experts.
 
+    An IDENTITY assignment (`spec.zero_experts`: a chosen index at or
+    past `n_experts`) is an expert with no matrices that returns its
+    input: it adds its weight times the token's own row of `m`, under
+    the scope `moe_zero`, and sends NO row to the grouped matmul (like
+    an absent expert's it sorts past the last group).  Every chip of an
+    expert-parallel layer can add that part for its own tokens.
+
     `experts` is what `kernels.grouped_matmul.select_grouped_matmul`
     returned for these shapes: the Pallas kernel (gate, up and the
     gated product in one call, down in a second, over work items it
@@ -716,7 +832,7 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     scope = scope or (lambda name: contextlib.nullcontext())
     t_n, k_n = m.shape[0], spec.experts_per_token
     first, e_n = spec.held
-    share = e_n < spec.n_experts
+    share = spec.has_unheld
     with scope("moe_router"):
         top_w, top_e = route(spec, m, w_router, b_router)   # [T, k]
     with scope("moe_dispatch"):
@@ -755,6 +871,10 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
             # rows past the last group are whatever the kernel left
             per_tok = jnp.where(here[..., None], per_tok, 0.0)
         y = (per_tok * top_w[..., None]).sum(axis=1)
+    if spec.zero_experts:
+        with scope("moe_zero"):
+            zero_w = jnp.where(top_e >= spec.n_experts, top_w, 0.0)
+            y = y + zero_w.sum(axis=1, keepdims=True) * m
     return y, hit, (top_w, top_e)
 
 

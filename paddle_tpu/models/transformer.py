@@ -559,7 +559,9 @@ class PagedDecoder:
     # names of what `step` returns after the pools, counted on the
     # device: "moe_experts_hit" (and "moe_rows_held" where the block
     # holds a share of its experts, and "moe_tokens_here" where its
-    # router is group-limited too), "exit_gate_open"; () without
+    # router is group-limited or has identity experts, and with those
+    # "moe_zero_assignments" and "moe_assignments"), "exit_gate_open";
+    # () without
     step_counters: Tuple[str, ...]
     # layers with experts: what `moe_experts_hit` and `moe_rows_held`
     # are summed over (0: a block without)
@@ -777,6 +779,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     `step_window`, a draft model and an int8 pool are refused by name;
     the prefix cache works (a block holds every layer's rows).
 
+    A DOUBLE layer (`BlockSpec.sub_blocks` 2, lm_block's eighth
+    description) is TWO latent sub-blocks, so the latent pool has
+    `2 * n_layers` planes (sub-block i of layer l writes and reads plane
+    2 * l + i: `decoder.kv_planes`, `table_layers`, `bytes_per_block`
+    and `tick_counts`' `latent_rows` count them all), and ONE expert
+    layer (`decoder.moe_layers` counts one a double layer): computed in
+    the first sub-block on the state its dense FFN reads, held across
+    the second, joined after the second dense FFN.  With IDENTITY
+    experts (`BlockSpec.zero_experts`) `step` counts two more values
+    (`step_counters`): `moe_zero_assignments`, the live lanes'
+    assignments to identity experts a layer, and `moe_assignments`,
+    all of their assignments.
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -847,7 +862,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 "block) is not built for a latent row (one scale over a "
                 "normed latent and a rotated key part of other ranges); "
                 "kv_dtype fp32 or bf16")
-    kinds = [spec.kind_of(l) for l in range(n_layers)]
+    # a kind for every entry of `layout.layers`: a layer, or each of a
+    # double layer's two sub-blocks (a cache plane each)
+    subs = spec.sub_blocks
+    kinds = [spec.kind_of(l) for l in range(n_layers) for _ in range(subs)]
     ringed = lm_block.SLIDING in kinds
     stateful = lm_block.MAMBA in kinds
     n_full = kinds.count(lm_block.FULL)
@@ -908,7 +926,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     moe_layers = (sum(spec.ffn_of(l) == lm_block.SPARSE
                       for l in range(n_layers))
                   if spec.ffn == "moe_swiglu" else 0)
-    shares = spec.ffn == "moe_swiglu" and spec.held[1] < spec.n_experts
+    shares = spec.ffn == "moe_swiglu" and spec.has_unheld
 
     scale = spec.attention_multiplier or 1.0 / math.sqrt(d_head)
     # the residual stream takes each sub-block's output times this
@@ -958,7 +976,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # (values and int8 scales); `attention` is the two contractions,
     # the mask and the softmax (where selection takes the Pallas
     # `_attend`, its call, which reads the pool itself: no gather).
-    scope = jax.named_scope
+    # Inside a sub-block of a DOUBLE layer the parts every sub-block
+    # has carry its index under their own name
+    # (`paged_decoder/dense_ffn/sub1`): the two read apart, and still
+    # sum under the part.
+    sub_parts = ("latent_q", "latent_kv", "latent_absorb", "attention",
+                 "attn_out", "dense_ffn")
+    sub_traced = None                   # the sub-block being traced
+
+    def scope(name):
+        if sub_traced is None or name not in sub_parts:
+            return jax.named_scope(name)
+        both = contextlib.ExitStack()
+        both.enter_context(jax.named_scope(name))
+        both.enter_context(jax.named_scope(f"sub{sub_traced}"))
+        return both
 
     def _sample(logits, seeds, positions, temps):
         """Greedy/sampled next token per row; stateless per-sequence
@@ -1047,22 +1079,30 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         that it meets the latent itself, then its rotated part, then
         the row's zero pad; this position's row [S, W, row]: the normed
         latent, the one rotated key part, the pad; None: there is no V).
+        Each normed latent takes its constant where the description
+        has one (`scale_q_lora`, `scale_kv_lora`).
         The norms are float32; the rotation is of `qk_rope_head_dim`
         columns, a head's of the query and the one of the key."""
         lead = x.shape[:-1]
         with scope("latent_q"):
             h = _norm(g, x, lay["norm1"])
-            q = _fc(g, _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"]),
-                    lay["q_b"]).reshape(lead + (n_heads, d_head))
+            c_q = _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"])
+            if spec.scale_q_lora:
+                c_q = c_q * math.sqrt(d_model / spec.q_lora_rank)
+            q = _fc(g, c_q, lay["q_b"]).reshape(lead + (n_heads, d_head))
             q_nope, q_pe = q[..., :d_nope], q[..., d_nope:]
             q_pe = lm_block.rope(q_pe.reshape(lead + (-1,)), *rot,
                                  n_heads).reshape(q_pe.shape)
         with scope("latent_kv"):
             ckv = _fc(g, h, lay["kv_a"])
             pad = jnp.zeros(lead + (d_kv - d_lat - d_pe,), ckv.dtype)
+            c_kv = _norm(g, ckv[..., :d_lat], lay["kv_a_norm"])
+            if spec.scale_kv_lora:
+                # on the row as stored: `kv_b` meets it either side
+                c_kv = c_kv * math.sqrt(d_model / d_lat)
             row = jnp.concatenate(
-                [_norm(g, ckv[..., :d_lat], lay["kv_a_norm"]),
-                 lm_block.rope(ckv[..., d_lat:], *rot, 1), pad], axis=-1)
+                [c_kv, lm_block.rope(ckv[..., d_lat:], *rot, 1), pad],
+                axis=-1)
         with scope("latent_absorb"):
             q_lat = jnp.einsum("...hn,chn->...hc", q_nope,
                                _kv_b(g, lay)[..., :d_nope])
@@ -1114,7 +1154,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     return _residual(x, y.reshape(x.shape))
         if spec.ffn == "swiglu":
             return _post_join(g, x, y.reshape(x.shape), lay["post2"])
-        h2 = h2.reshape(-1, d_model)
+        y = _experts(g, lay, h2.reshape(-1, d_model), hits)
+        with scope("moe_combine"):
+            return _residual(x, y.reshape(x.shape))
+
+    def _experts(g, lay, h2, hits):
+        """The expert layer of rows h2 [T, D] (and the shared expert's
+        part, where the block has one) -> [T, D]; appends (its count of
+        distinct experts hit, h2, the weights and the experts the
+        router chose) to `hits`."""
         w_gate = g[lay["gate"][0]]
         # the kernel's own module decides from the rows of this trace,
         # the widths, the weights' dtype and the platform whether the
@@ -1137,8 +1185,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 y = y + lm_block.swiglu(h2, *(
                     g[lay[n][0]] for n in ("shared_gate", "shared_up",
                                            "shared_down")))
-        with scope("moe_combine"):
-            return _residual(x, y.reshape(x.shape))
+        return y
+
+    def _shortcut_ffn(g, lay, x, hits, held):
+        """A sub-block of a DOUBLE layer after its attention: x + the
+        dense SwiGLU of u = norm(x).  The FIRST sub-block (a "router"
+        among its names) also computes the layer's ONE expert layer on
+        the SAME u and holds its result; the SECOND is given it as
+        `held` and adds it after its own dense FFN (the shortcut:
+        across chips the experts' exchange has the second sub-block
+        to hide under).  -> (x, what is held)."""
+        first = "router" in lay
+        with scope("dense_ffn"):
+            u = _norm(g, x, lay["norm2"]).reshape(-1, d_model)
+        if first:
+            held = _experts(g, lay, u, hits).reshape(x.shape)
+        with scope("dense_ffn"):
+            x = _residual(x, lm_block.swiglu(u, *(
+                g[lay[n][0]] for n in ("dense_gate", "dense_up",
+                                       "dense_down"))).reshape(x.shape))
+        if first:
+            return x, held
+        with scope("moe_shortcut_join"):
+            return _residual(x, held), None
 
     def _head(g, x, normed=False):
         """The logits of x; `normed`: x is already through the final
@@ -1187,10 +1256,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("moe_dispatch"):
             out = out + (jnp.stack([jnp.sum(here(h), dtype=jnp.int32)
                                     for h in hits]),)
-            if spec.n_group > 1:
+            if spec.n_group > 1 or spec.zero_experts:
                 out = out + (jnp.stack([jnp.sum(here(h).any(axis=1),
                                                 dtype=jnp.int32)
                                         for h in hits]),)
+            if spec.zero_experts:
+                # every layer routes the same live rows, k each
+                sent = jnp.sum(live, dtype=jnp.int32) * spec.experts_per_token
+                out = out + (
+                    jnp.stack([jnp.sum(
+                        live[:, None] & (h[3] >= spec.n_experts),
+                        dtype=jnp.int32) for h in hits]),
+                    jnp.broadcast_to(sent, (len(hits),)))
             return out
 
     def _kind_scope(name, kind):
@@ -1420,8 +1497,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             """The layers once over x, each writing this position's K/V
             and attending; `plane0`: the first plane of this pass of a
             looped stack (traced), else a layer's plane is its index in
-            the pool of its kind (a Python int, as ever)."""
+            the pool of its kind (a Python int, as ever).  A double
+            layer's two sub-blocks are two turns of the loop, the
+            expert layer's result `held` from the first to the second."""
+            nonlocal sub_traced
+            held = None
             for lay, kind, li in zip(layout.layers, kinds, pool_index):
+                sub_traced = li % subs if subs > 1 else None
                 if kind == lm_block.MAMBA:
                     # the lane's state rides where a pool's K does, its
                     # convolution tail where the V does
@@ -1457,7 +1539,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         x = _residual(x, y)
                 if spec.post_norm:
                     x = _post_join(g, x, y, lay["post1"])
-                x = _ffn(g, lay, x, hits)
+                if subs > 1:
+                    x, held = _shortcut_ffn(g, lay, x, hits, held)
+                else:
+                    x = _ffn(g, lay, x, hits)
+            sub_traced = None
             return x
 
         if not looped:
@@ -1733,7 +1819,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     parts = {"q": "qkv", "k": "qkv", "v": "qkv", "o": "attn_out",
              "w1": "mlp", "w2": "mlp", "q_a": "latent_q",
              "q_b": "latent_q", "kv_a": "latent_kv",
-             "kv_b": "latent_absorb"}
+             "kv_b": "latent_absorb", "dense_gate": "dense_ffn",
+             "dense_up": "dense_ffn", "dense_down": "dense_ffn"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
@@ -1742,7 +1829,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # a dense layer among sparse ones: its three matrices
         weights_of += [(lay[key], "dense_ffn") for lay in layout.layers
                        if "router" not in lay
-                       for key in ("gate", "up", "down")]
+                       for key in ("gate", "up", "down") if key in lay]
     compiler_scopes = {
         f"g[\\'{name}\\']": f"paged_decoder/{part}"
         for pair, part in weights_of + [(layout.head, "head")]
@@ -1781,13 +1868,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         zero).  With experts `moe_kernel` (1: the traced step's expert
         layer is the Pallas grouped matmul, 0: `ragged_dot`) and
         `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
-        the planes the pages are counted over.  With a latent cache
-        `latent_rows`: the rows the lanes with a sequence attend over
-        (cursor + 1), summed over them and the layers."""
+        the planes the pages are counted over (`kv_planes` with double
+        layers too: two a layer).  With a latent cache `latent_rows`:
+        the rows the lanes with a sequence attend over (cursor + 1),
+        summed over them and the planes."""
         n = len(cursors)
         counts = {}
         if passes > 1:
             counts["loop_passes"] = passes
+        if passes > 1 or subs > 1:
             counts["kv_planes"] = planes
         rows = cursors.astype(np.int64) + 1  # K/V rows a lane attends
         table = slots * (planes * nb + n_win * nw)
@@ -1844,7 +1933,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         step_counters=(("moe_experts_hit",)
                        + (("moe_rows_held",) if shares else ())
                        + (("moe_tokens_here",)
-                          if shares and spec.n_group > 1 else ())
+                          if shares and (spec.n_group > 1
+                                         or spec.zero_experts) else ())
+                       + (("moe_zero_assignments", "moe_assignments")
+                          if spec.zero_experts else ())
                        if spec.ffn == "moe_swiglu"
                        else ("exit_gate_open",) if spec.exit_gate
                        else ()),

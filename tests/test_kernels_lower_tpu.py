@@ -50,6 +50,8 @@ POOLS = {
     # the latent form: ONE pool, 128 heads on a row of 512 + 64 stored
     # 640 wide, of which 512 are the value
     "deepseek-v2-agent64-latent": (64, 128, 640, 16, 256, 5, "bf16"),
+    # the same row under 64 heads, on the 8 planes of 4 double layers
+    "longcat-flash-agent64-latent": (64, 64, 640, 16, 256, 8, "bf16"),
     # a K and a V pool in chunks of 51 pages too: the one chunk length
     # of the cells that is no power of two, so its waits on summed
     # bytes take four sizes and its issue loop a remainder (a row of 5
@@ -57,7 +59,8 @@ POOLS = {
     "kv-chunks-of-51": (16, 20, 640, 16, 128, 2, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
-D_VALUE = {"deepseek-v2-agent64-latent": 512}
+D_VALUE = {"deepseek-v2-agent64-latent": 512,
+           "longcat-flash-agent64-latent": 512}
 # pages a chunk over each pool: what the waits' static list and the
 # issue loop's groups follow
 CHUNK_PAGES = {
@@ -66,6 +69,7 @@ CHUNK_PAGES = {
     "mellum2-agent96-ring": 64, "granite-chat64": 32, "ouro-chat12": 16,
     "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
     "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
+    "longcat-flash-agent64-latent": 51,
 }
 
 
