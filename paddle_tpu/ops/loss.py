@@ -7,6 +7,8 @@ smooth_l1_loss,squared_l2_distance}_op.cc and math/cross_entropy.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -15,11 +17,17 @@ from ..core.execution import data_of, one, with_lod_of
 from ..core.registry import register_op
 
 
-def _take_label(x, label):
-    """x: [N, D] probabilities/logits; label: [N] or [N,1] int -> x[i, label[i]]."""
+def _label_index(label):
+    """label: [N] or [N,1] int -> [N]."""
     label = data_of(label)
     if label.ndim == 2 and label.shape[-1] == 1:
         label = label.squeeze(-1)
+    return label
+
+
+def _take_label(x, label):
+    """x: [N, D] probabilities/logits; label: [N] or [N,1] int -> x[i, label[i]]."""
+    label = _label_index(label)
     return jnp.take_along_axis(x, label[:, None].astype(jnp.int32),
                                axis=1), label
 
@@ -42,20 +50,61 @@ def cross_entropy(ctx, ins, attrs):
     return {"Y": with_lod_of(xv, y)}
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lse_minus_picked(logits, label, wide):
+    """-log_softmax(logits)[i, label[i]] as logsumexp - the picked logit,
+    [N, 1] in `wide`.  The reductions run in `wide` over the logits as
+    they arrive (the upcast fuses into them) and the pick reads them as
+    they are, so nothing of [rows, classes] wider than the logits is
+    written here or kept for the gradient; log_softmax then
+    take_along_axis wrote the upcast logits and log_p whole, at a
+    50k-class LM head 3.3 GB of float32 to read 8192 of its elements."""
+    return _lse_minus_picked_fwd(logits, label, wide)[0]
+
+
+def _lse_minus_picked_fwd(logits, label, wide):
+    lse = jax.nn.logsumexp(logits.astype(wide), axis=-1, keepdims=True)
+    picked = jnp.take_along_axis(logits, label[:, None], axis=1)
+    return lse - picked.astype(wide), (logits, label, lse)
+
+
+def _lse_minus_picked_bwd(wide, res, g):
+    """(softmax - onehot) * g formed in `wide` and rounded ONCE to the
+    logits' dtype, which is what autodiff through log_softmax gave.
+    Autodiff of the forward above rounds softmax * g and -g apart and
+    adds them in bf16: where the true class is the likely one their sum
+    cancels and keeps a few bits.  The consumers (the head's two
+    gradient products) form this in their prologues from the logits
+    and the row's lse."""
+    logits, label, lse = res
+    # a negative label counts from the end, as take_along_axis reads it
+    label = jnp.where(label < 0, label + logits.shape[-1], label)
+    hot = jax.lax.broadcasted_iota(label.dtype, logits.shape, 1) \
+        == label[:, None]
+    d = (jnp.exp(logits.astype(wide) - lse) - hot.astype(wide)) * g
+    return d.astype(logits.dtype), None
+
+
+_lse_minus_picked.defvjp(_lse_minus_picked_fwd, _lse_minus_picked_bwd)
+
+
 @register_op("softmax_with_cross_entropy", inputs=("Logits", "Label"),
              outputs=("Softmax", "Loss"),
              attrs={"soft_label": False},
              diff_inputs=("Logits",), diff_outputs=("Loss",))
 def softmax_with_cross_entropy(ctx, ins, attrs):
-    logits = amp_upcast(data_of(one(ins, "Logits")))
-    log_p = jax.nn.log_softmax(logits, axis=-1)
+    raw = data_of(one(ins, "Logits"))
+    logits = amp_upcast(raw)
     if attrs.get("soft_label"):
+        # a distribution a row needs every class's log-probability
         lbl = data_of(one(ins, "Label"))
+        log_p = jax.nn.log_softmax(logits, axis=-1)
         loss = -jnp.sum(lbl * log_p, axis=-1, keepdims=True)
-    else:
-        picked, _ = _take_label(log_p, one(ins, "Label"))
-        loss = -picked
-    return {"Softmax": jnp.exp(log_p), "Loss": loss}
+        return {"Softmax": jnp.exp(log_p), "Loss": loss}
+    label = _label_index(one(ins, "Label")).astype(jnp.int32)
+    # `Softmax` is dead code unless a Program fetches it
+    return {"Softmax": jax.nn.softmax(logits, axis=-1),
+            "Loss": _lse_minus_picked(raw, label, logits.dtype)}
 
 
 @register_op("sigmoid_cross_entropy_with_logits", inputs=("X", "Label"),

@@ -4,6 +4,7 @@ Reference analogue: doc/design/float16.md (design only — the reference
 never shipped AMP training; this is the TPU rebuild's MXU-native mode).
 """
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import amp
@@ -140,3 +141,43 @@ def test_explicit_bf16_adam_actually_trains():
     assert tr[-1] < tr[0], tr
     # beta2_pow actually decays
     assert np.asarray(scope.find_var(b2p[0])).reshape(-1)[0] < 0.999
+
+
+@pytest.mark.parametrize("label_shape", [[], [1]], ids=["N", "Nx1"])
+def test_softmax_ce_hard_label_reads_bf16_logits(label_shape):
+    """bf16 logits under amp: the loss is float32 and exact to float32's
+    rounding over the logits AS ROUNDED (the reductions and the one
+    subtraction are float32; the pick reads bf16, which float32 holds),
+    and the gradient comes back in bf16 rounded ONCE (8 bits: 2**-9
+    relative), also where the true class is the likely one and softmax *
+    g and -g all but cancel."""
+    import ml_dtypes
+
+    from test_basic_ops import check_softmax_ce_hard_label
+
+    with amp.bf16_guard():
+        grad = check_softmax_ce_hard_label(ml_dtypes.bfloat16, label_shape,
+                                           2.0 ** -8)
+    assert str(grad.dtype) == "bfloat16"
+
+
+def test_softmax_ce_soft_label_upcasts_bf16_logits_as_before():
+    """`soft_label` keeps log_softmax over the upcast logits, to the
+    bit."""
+    import jax
+    import jax.numpy as jnp
+    import ml_dtypes
+
+    from test_basic_ops import run_softmax_ce, softmax_ce_case
+
+    logits, _ = softmax_ce_case(ml_dtypes.bfloat16, [1])
+    soft = np.random.RandomState(3).rand(6, 9).astype(np.float32)
+    soft /= soft.sum(-1, keepdims=True)
+    with amp.bf16_guard():
+        loss, softmax, _ = run_softmax_ce(logits, soft, soft_label=True,
+                                          compiled=False)
+    log_p = jax.nn.log_softmax(jnp.asarray(logits).astype(jnp.float32),
+                               axis=-1)
+    np.testing.assert_array_equal(
+        loss, np.asarray(-jnp.sum(soft * log_p, axis=-1, keepdims=True)))
+    np.testing.assert_array_equal(softmax, np.asarray(jnp.exp(log_p)))

@@ -225,6 +225,105 @@ class TestSoftmaxWithCE(OpTest):
         self.check_grad(["Logits"], max_relative_error=1e-2)
 
 
+def log_softmax_np(x):
+    """float32 log_softmax written out: the reference of the hard-label
+    cases below and of test_amp.py's bf16 ones."""
+    x = np.asarray(x, np.float32)
+    z = x - x.max(-1, keepdims=True)
+    return z - np.log(np.exp(z).sum(-1, keepdims=True))
+
+
+def softmax_ce_case(dtype, label_shape):
+    """Six rows of 9 classes, the labels and the logits: row 0's true
+    class has the largest logit, row 1's lies 60 below the largest (the
+    saturated case: its probability is e^-60)."""
+    case_rng = np.random.RandomState(7)
+    logits = case_rng.randn(6, 9).astype(np.float32)
+    label = case_rng.randint(0, 9, 6)
+    logits[0, label[0]] = logits[0].max() + 3.0
+    logits[1, label[1]] = logits[1].max() - 60.0
+    return (logits.astype(dtype),
+            label.astype(np.int64).reshape([6] + label_shape))
+
+
+def run_softmax_ce(logits, label, soft_label=False, compiled=True):
+    """`Loss`, `Softmax` and the gradient of mean(Loss) to `Logits`
+    through a Program and its backward, as the Executor gives them."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=list(logits.shape[1:]),
+                              dtype=str(logits.dtype))
+        x.stop_gradient = False
+        y = fluid.layers.data(name="y", shape=list(label.shape[1:]),
+                              dtype=str(label.dtype))
+        loss = fluid.layers.softmax_with_cross_entropy(
+            x, y, soft_label=soft_label)
+        fluid.backward.append_backward(fluid.layers.mean(loss))
+    softmax = main.global_block().ops[0].outputs["Softmax"][0]
+    got = fluid.Executor(fluid.CPUPlace()).run(
+        main, feed={"x": logits, "y": label},
+        fetch_list=[loss, softmax, "x@GRAD"], return_numpy=False,
+        compiled=compiled)
+    return [np.asarray(v) for v in got]
+
+
+def check_softmax_ce_hard_label(dtype, label_shape, grad_rtol):
+    """The op on `softmax_ce_case` against float32 log_softmax over the
+    logits as given: `Loss` and `Softmax` to float32's rounding, the
+    gradient of mean(Loss) to `grad_rtol`; the saturated row keeps a
+    finite loss and -1/N on its true class.  Returns the gradient."""
+    logits, label = softmax_ce_case(dtype, label_shape)
+    loss, softmax, grad = run_softmax_ce(logits, label)
+    log_p = log_softmax_np(logits)
+    rows, flat = np.arange(6), label.ravel()
+    assert loss.dtype == np.float32 and loss.shape == (6, 1)
+    np.testing.assert_allclose(loss.ravel(), -log_p[rows, flat],
+                               rtol=1e-6, atol=1e-6)
+    assert 59.5 < loss[1, 0] < 63.0 and loss[0, 0] < 0.5
+    assert softmax.dtype == np.float32
+    np.testing.assert_allclose(softmax, np.exp(log_p), rtol=1e-5,
+                               atol=1e-7)
+    want = np.exp(log_p)
+    want[rows, flat] -= 1.0
+    np.testing.assert_allclose(grad.astype(np.float32), want / 6.0,
+                               rtol=grad_rtol, atol=1e-6 * grad_rtol)
+    assert float(grad[1, flat[1]]) == pytest.approx(-1.0 / 6.0,
+                                                    rel=grad_rtol)
+    return grad
+
+
+@pytest.mark.parametrize("label_shape", [[], [1]], ids=["N", "Nx1"])
+def test_softmax_ce_hard_label_against_float32_log_softmax(label_shape):
+    grad = check_softmax_ce_hard_label(np.float32, label_shape, 1e-5)
+    assert grad.dtype == np.float32
+
+
+def test_softmax_ce_soft_label_is_log_softmax_to_the_bit():
+    """A distribution a row keeps the form that needs log_p whole: the
+    interpreter's results equal the same jax calls made here."""
+    import jax
+    import jax.numpy as jnp
+
+    logits, _ = softmax_ce_case(np.float32, [1])
+    soft = np.random.RandomState(3).rand(6, 9).astype(np.float32)
+    soft /= soft.sum(-1, keepdims=True)
+    loss, softmax, grad = run_softmax_ce(logits, soft, soft_label=True,
+                                         compiled=False)
+
+    def written_out(x):
+        log_p = jax.nn.log_softmax(x, axis=-1)
+        return -jnp.sum(soft * log_p, axis=-1, keepdims=True), log_p
+
+    (want, log_p), vjp = jax.vjp(written_out, jnp.asarray(logits))
+    np.testing.assert_array_equal(loss, np.asarray(want))
+    np.testing.assert_array_equal(softmax, np.asarray(jnp.exp(log_p)))
+    want_grad, = vjp((jnp.full((6, 1), 1.0 / 6.0, jnp.float32),
+                      jnp.zeros_like(log_p)))
+    np.testing.assert_array_equal(grad, np.asarray(want_grad))
+
+
 class TestReduceSum(OpTest):
     op_type = "reduce_sum"
     attrs = {"dim": [1], "keep_dim": False, "reduce_all": False}

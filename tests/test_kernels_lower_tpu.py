@@ -19,8 +19,13 @@ the kernel it returns lowers to ONE Mosaic custom call however many
 layers call it, and compiles for a v5e at every serving cell's
 geometry; the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
-kernel once a layer.
+kernel once a layer; a language model's AMP training step holds no
+float32 array of [tokens, vocab].
 """
+import contextlib
+import re
+import signal
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -234,6 +239,25 @@ def test_flash_attention_compiles_for_a_v5e(shape, calls, one_v5e):
     assert compiled.as_text().count(MOSAIC_CALL) == calls
 
 
+def _compiled_step_text(main, feeds, loss, chip):
+    """The compiled text of a training Program's step (`program_to_fn`,
+    float32 states) for the described chip; feeds: name -> (shape,
+    dtype)."""
+    from paddle_tpu.core.executor import program_to_fn
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(int(i) for i in shape), dtype,
+                                    sharding=chip)
+
+    fn = program_to_fn(main, list(feeds), [loss.name])
+    blk = main.global_block()
+    states = {n: spec(blk.vars[n].shape, jnp.float32)
+              for n in fn.state_in_names}
+    return jax.jit(fn).lower(
+        {n: spec(*f) for n, f in feeds.items()}, states,
+        spec((), jax.random.key(0).dtype)).compile().as_text()
+
+
 def test_training_step_runs_the_forward_kernel_once_a_layer(
         one_v5e, monkeypatch):
     """The guard for the op's own gradient: a one-layer training Program
@@ -242,7 +266,6 @@ def test_training_step_runs_the_forward_kernel_once_a_layer(
     forward again under the grad op (XLA does not merge two Mosaic calls
     as it merges its own ops), a dq and a dk/dv kernel."""
     import paddle_tpu as fluid
-    from paddle_tpu.core.executor import program_to_fn
 
     # what the lowerings ask where no executor drives them
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -257,14 +280,59 @@ def test_training_step_runs_the_forward_kernel_once_a_layer(
                                            min_seq_k=0)
         loss = fluid.layers.mean(fluid.layers.square(att))
         fluid.SGD(learning_rate=0.1).minimize(loss)
-    fn = program_to_fn(main, ["x"], [loss.name])
-    blk = main.global_block()
-    states = {n: jax.ShapeDtypeStruct(
-        tuple(int(i) for i in blk.vars[n].shape), jnp.float32,
-        sharding=one_v5e) for n in fn.state_in_names}
-    feeds = {"x": jax.ShapeDtypeStruct((b, s, h * d), jnp.float32,
-                                       sharding=one_v5e)}
-    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
-                               sharding=one_v5e)
-    text = jax.jit(fn).lower(feeds, states, key).compile().as_text()
+    text = _compiled_step_text(
+        main, {"x": ((b, s, h * d), jnp.float32)}, loss, one_v5e)
     assert text.count(MOSAIC_CALL) == 2
+
+
+@contextlib.contextmanager
+def _time_limit(seconds):
+    """Fail, not hang: SIGALRM raises in the test where Python runs."""
+    def expired(signum, frame):
+        raise TimeoutError(f"over its time limit of {seconds} s")
+
+    old = signal.signal(signal.SIGALRM, expired)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+def test_lm_training_step_writes_no_float32_logits(one_v5e, monkeypatch):
+    """The guard for the loss on hard labels: a small language model's
+    AMP training step compiled for the described chip holds no float32
+    array of [tokens, vocab], in either order, flattened or not.  The
+    loss is logsumexp minus the picked logit over the bf16 logits; as
+    log_softmax then take_along_axis it wrote the upcast logits and
+    log_p whole and kept the first for the backward."""
+    import paddle_tpu as fluid
+    from paddle_tpu.models.transformer import transformer_lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, vocab = 2, 256, 4096
+    main, startup = fluid.Program(), fluid.Program()
+    with _time_limit(120), fluid.amp.bf16_guard():
+        with fluid.program_guard(main, startup):
+            ids = fluid.layers.data(name="ids", shape=[s], dtype="int64")
+            lbl = fluid.layers.data(name="lbl", shape=[s, 1], dtype="int64")
+            logits = transformer_lm(ids, vocab, d_model=128, n_heads=2,
+                                    n_layers=1, d_inner=256, max_len=s,
+                                    return_logits=True)
+            loss = fluid.layers.mean(
+                fluid.layers.softmax_with_cross_entropy(
+                    fluid.layers.reshape(logits, shape=[-1, vocab]),
+                    fluid.layers.reshape(lbl, shape=[-1, 1])))
+            fluid.Adam(learning_rate=1e-3).minimize(loss)
+        text = _compiled_step_text(
+            main, {"ids": ((b, s), jnp.int32),
+                   "lbl": ((b, s, 1), jnp.int32)}, loss, one_v5e)
+    # what the entry computation's instructions write is what lives in
+    # HBM; inside a fusion's body a float32 value is registers
+    entry = text[text.index("\nENTRY "):]
+    assert f"bf16[{b},{s},{vocab}]" in entry    # the logits themselves
+    wide = [line.split(" = ")[0].strip() for line in entry.splitlines()
+            if re.search(rf" = \(?[^=]*f32\[(?:{b},{s},{vocab}|{vocab},{b},{s}"
+                         rf"|{b * s},{vocab}|{vocab},{b * s})\]", line)]
+    assert not wide, wide
