@@ -32,8 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import registry
+from ..observability import flightrecorder
 from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
+from ..observability.attribution import IterationClock
 from .compile_cache import compile_cache_dir
 from .execution import DictEnv, ExecContext, ScopeEnv, run_op
 from .flags import get_flag, trace_flags
@@ -459,6 +461,31 @@ _M_RUN_SECONDS = obs_metrics.histogram(
     "Executor.run wall latency by execution mode", ("exe", "mode"))
 
 
+def run_clock() -> IterationClock:
+    """The clock an executor keeps over its `run`s.  An iteration is
+    the end of one `run` to the end of the next; its parts are
+    `outside` (the caller's time before the call: with the results
+    left on the device the caller's own wait for an older one lies
+    here), `feed`, `dispatch` and the wait `fetch`.  A steady training
+    step spreads by parts in 1e5, so a step of twice the reference has
+    lost a whole step somewhere: it is slow."""
+    return IterationClock(("outside", "feed", "dispatch"), wait="fetch",
+                          slow_factor=2.0)
+
+
+def end_run(clock: IterationClock, span) -> None:
+    """Where `executor.run` ends: close the clock's iteration, tell a
+    live span the seconds since the executor's previous `run` ended
+    (`period_s`; absent on the first), and note a slow step to the
+    flight recorder (`executor.slow_step`)."""
+    since = clock.start
+    rec = clock.end()
+    if span is not None and since is not None:
+        span.set_attr("period_s", clock.start - since)
+    if rec is not None:
+        flightrecorder.note("executor.slow_step", **rec)
+
+
 class Executor:
     def __init__(self, place=None, seed: int = 0):
         self.place = place or CPUPlace()
@@ -490,7 +517,16 @@ class Executor:
         self._m_state_commits = _M_STATE_COMMITS.labels(exe=self._exe_id)
         self._m_entries = _M_ENTRIES.labels(exe=self._exe_id)
         self._warm_fps: set = set()
+        self._clock = run_clock()
         compile_cache_dir()
+
+    def slow_steps(self) -> List[dict]:
+        """The newest 8 steps that took over twice the reference
+        period, oldest first, kept with tracing on or off: where each
+        went (`phase` of `outside`, `feed`, `dispatch`, `fetch`) and
+        whether this thread worked, waited or was taken off the CPU
+        (docs/observability.md "How a slow record reads")."""
+        return [dict(r) for r in self._clock.slow]
 
     def cache_stats(self) -> Dict:
         """Dispatch/compile telemetry for this Executor's executable cache:
@@ -551,6 +587,8 @@ class Executor:
         """Execute block 0 of `program`.  Mirrors reference
         python/paddle/v2/fluid/executor.py:221 (feed/fetch are handled by the
         executor directly instead of injected feed/fetch ops)."""
+        clock = self._clock
+        clock.mark("outside")
         program = program or default_main_program()
         scope = scope or global_scope()
         feed = feed or {}
@@ -630,7 +668,7 @@ class Executor:
         t0 = time.perf_counter()
         # children in compiled mode: executor.feed, executor.dispatch
         # (_run_compiled) and, below, executor.fetch
-        with obs_tracing.span("executor.run", mode=mode):
+        with obs_tracing.span("executor.run", mode=mode) as run_span:
             if mode == "segmented":
                 outs = self._run_segmented(
                     program, block, scope, feed, fetch_names, step_key
@@ -650,6 +688,7 @@ class Executor:
                 outs = self._run_interpreted(
                     program, block, scope, feed, fetch_names, step_key
                 )
+            clock.mark("dispatch")
             if obs_metrics.enabled():
                 _M_RUN_SECONDS.labels(
                     exe=self._exe_id, mode=mode).observe(
@@ -658,6 +697,7 @@ class Executor:
                 # the wait for the device: the step's results are read
                 with obs_tracing.span("executor.fetch"):
                     outs = [_to_numpy(v) for v in outs]
+            end_run(clock, run_span)
         return outs
 
     def close(self):
@@ -995,6 +1035,7 @@ class Executor:
             if feed_span is not None:
                 feed_span.set_attr("states", len(rec.state_in_names))
                 feed_span.set_attr("recommitted", n_ro + n_rw)
+        self._clock.mark("feed")
         # executor.dispatch: cache lookup and the jitted call
         with obs_tracing.span("executor.dispatch"):
             feed_keys = tuple(sorted(

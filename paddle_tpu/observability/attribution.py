@@ -32,14 +32,24 @@ this layer says WHERE the time went and WHO is slow:
     ``paddle_tpu_calibration_ratio{kind,member,phase}`` for burn-rate
     alerting (tools/slo.json pins the static_vs_measured band).
 
+  * **Iteration clock** — :class:`IterationClock` times every
+    iteration of a loop (the serving scheduler's tick, an executor's
+    step) with tracing on or off, and keeps the slow ones as records
+    that say whether the thread worked, waited or was taken off the
+    CPU.
+
 The collector calls :func:`run_detectors` after every scrape pass.
 See docs/observability.md "Time attribution".
 """
 from __future__ import annotations
 
+import gc
 import math
+import os
+import resource
+import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import metrics as metrics_mod
 from . import tracing
@@ -50,6 +60,7 @@ __all__ = [
     "PHASE_BUCKETS",
     "phase",
     "phased_iter",
+    "IterationClock",
     "observe_phase",
     "phase_family",
     "publish_static_floor",
@@ -184,15 +195,229 @@ def phased_iter(kind: str, name: str, iterable):
             item = next(it, _END)
         else:
             ts, t0 = time.time(), time.perf_counter()
+            c0 = time.thread_time()
             item = next(it, _END)
             if item is not _END:
+                cpu = time.thread_time() - c0
                 dt = time.perf_counter() - t0
                 tracing.record_span(f"{kind}.phase.{name}", ts, dt,
-                                    parent=tracing.current_context())
+                                    parent=tracing.current_context(),
+                                    cpu=cpu)
                 observe_phase(kind, name, dt)
         if item is _END:
             return
         yield item
+
+
+# ---------------------------------------------------------------------------
+# the iteration clock: where a loop's iteration went, tracing on or off
+# ---------------------------------------------------------------------------
+
+
+def _cgroup_cpu_stat() -> Optional[str]:
+    """`cpu.stat` of the CPU group this process is in, as
+    `/proc/self/cgroup` names it: under a `cpu` controller of the first
+    version where one is mounted, else in the unified hierarchy.  None
+    where no such file is."""
+    try:
+        with open("/proc/self/cgroup") as f:
+            lines = [ln.strip().split(":", 2) for ln in f]
+    except OSError:
+        return None
+    found = []
+    for _, controllers, path in (ln for ln in lines if len(ln) == 3):
+        if "cpu" in controllers.split(","):
+            found.insert(0, os.path.join(
+                "/sys/fs/cgroup", controllers, path.lstrip("/")))
+        elif not controllers:
+            found += [os.path.join(root, path.lstrip("/"))
+                      for root in ("/sys/fs/cgroup",
+                                   "/sys/fs/cgroup/unified")]
+    for d in found:
+        if os.path.isfile(os.path.join(d, "cpu.stat")):
+            return os.path.join(d, "cpu.stat")
+    return None
+
+
+def _read_words(path: Optional[str]) -> List[List[str]]:
+    if not path:
+        return []
+    try:
+        with open(path) as f:
+            return [ln.split() for ln in f]
+    except OSError:
+        return []
+
+
+class IterationClock:
+    """Where an iteration of a loop went, kept whether tracing is on
+    or off.  An iteration runs from one `end()` to the next.  Its host
+    `parts` are named by the caller, in the order they run; the loop
+    calls `mark(part)` as each ends, and what lies between the last
+    part's mark and `end()` is the `wait` (the blocking read of the
+    device).  A part not marked in an iteration took no time.  `start`
+    is None while the loop is not iterating; `begin()` then opens an
+    iteration that no `end()` precedes.
+
+    The reference period is the median of the last 64 periods,
+    refreshed every 64 iterations (one sort of 64 floats); an
+    iteration longer than `slow_factor` times it comes back from `end`
+    as a record, and `slow` keeps the newest `KEEP` of them.  Until 64
+    iterations have run there is no reference and nothing is slow.
+
+    Every `end()` also reads, for the calling thread, its CPU seconds,
+    context switches and page faults (one `getrusage(RUSAGE_THREAD)`),
+    and the last part's `mark` reads the thread's CPU seconds once
+    more.  The process's CPU seconds and the count of full collections
+    are read at an `end()` `PROCESS_EVERY_S` or more after their last
+    reading and at every slow one (where a CPU clock is a system call
+    of 6 us, as on the v5e's host, three an iteration are too many for
+    a tick of 4 ms).  A slow record carries the differences over the
+    iteration, the process's over `counted_ms` (what each says:
+    docs/observability.md "How a slow record reads"), and the
+    cumulative throttled time of the process's CPU group and the
+    machine's CPU pressure, read only then.  The thread's counters
+    belong to it: an iteration that ends on another thread than the
+    one it began on restarts the clock and gives no record."""
+
+    __slots__ = ("parts", "wait", "slow_factor", "start", "reference",
+                 "slow", "_marks", "_cpu_mark", "_prev", "_process",
+                 "_periods", "_n", "_cpu_stat", "_pressure")
+
+    KEEP = 8
+    PROCESS_EVERY_S = 0.032
+    _WINDOW = 64
+
+    def __init__(self, parts: Sequence[str], wait: str = "wait",
+                 slow_factor: float = 4.0,
+                 cpu_stat_path: Optional[str] = None,
+                 pressure_path: str = "/proc/pressure/cpu"):
+        self.parts = tuple(parts)
+        self.wait = wait
+        self.slow_factor = float(slow_factor)
+        self.start: Optional[float] = None
+        self.reference: Optional[float] = None
+        self.slow: List[dict] = []
+        self._marks: Dict[str, float] = {}
+        self._cpu_mark = 0.0
+        self._prev: Optional[tuple] = None
+        self._process: Optional[tuple] = None
+        self._periods = [0.0] * self._WINDOW
+        self._n = 0
+        self._cpu_stat = cpu_stat_path or _cgroup_cpu_stat()
+        self._pressure = pressure_path
+
+    @staticmethod
+    def _read_thread() -> tuple:
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return (threading.get_ident(), ru.ru_utime + ru.ru_stime,
+                ru.ru_nvcsw, ru.ru_nivcsw, ru.ru_minflt, ru.ru_majflt)
+
+    @staticmethod
+    def _read_process(now: float) -> tuple:
+        return (now, time.process_time(),
+                gc.get_stats()[2]["collections"])
+
+    def begin(self) -> None:
+        """Open an iteration with no `end()` before it."""
+        self.start = time.perf_counter()
+        self._prev = self._read_thread()
+        self._process = self._read_process(self.start)
+
+    def mark(self, part: str) -> None:
+        """`part` has ended."""
+        self._marks[part] = time.perf_counter()
+        if part == self.parts[-1]:
+            self._cpu_mark = time.thread_time()
+
+    def end(self, **extra) -> Optional[dict]:
+        """Close the iteration; its record, with the caller's `extra`
+        keys, if it was slow."""
+        now = time.perf_counter()
+        cur = self._read_thread()
+        start, self.start = self.start, now
+        prev, self._prev = self._prev, cur
+        process = self._process
+        slow = False
+        if start is not None and prev is not None and prev[0] == cur[0]:
+            period = now - start
+            self._periods[self._n % self._WINDOW] = period
+            self._n += 1
+            if self._n % self._WINDOW == 0:
+                self.reference = sorted(self._periods)[self._WINDOW // 2]
+            ref = self.reference
+            slow = ref is not None and period > self.slow_factor * ref
+        if (slow or process is None
+                or now - process[0] >= self.PROCESS_EVERY_S):
+            self._process = self._read_process(now)
+        if not slow:
+            return None
+        # (an iteration that began had the process read, by `begin()`
+        # or by the `end()` before it)
+        rec = self._record(now, start, period, ref, prev, cur, process,
+                           self._process)
+        rec.update(extra)
+        self.slow = (self.slow + [rec])[-self.KEEP:]
+        return rec
+
+    def _record(self, now, start, period, ref, prev, cur, process0,
+                process1) -> dict:
+        spent, at = [], start
+        for part in self.parts:
+            # a mark from before this iteration: the part did not run
+            mark = max(self._marks.get(part, at), at)
+            spent.append((part, mark - at))
+            at = mark
+        wait = now - at
+        phase, longest = max(spent + [(self.wait, wait)],
+                             key=lambda p: p[1])
+        _, cpu0, vol0, invol0, minor0, major0 = prev
+        _, cpu1, vol1, invol1, minor1, major1 = cur
+        # the CPU clock at the last part's mark, between the two
+        # readings of `getrusage` (which drops each's last microsecond);
+        # a mark this iteration did not make counts as no host work
+        cpu_mark = (min(max(self._cpu_mark, cpu0), cpu1)
+                    if self.parts
+                    and self._marks.get(self.parts[-1], 0.0) >= start
+                    else cpu0)
+        rec = {"at": time.time(), "ms": 1e3 * period,
+               "wait_ms": 1e3 * wait, "phase": phase,
+               "phase_ms": 1e3 * longest, "reference_ms": 1e3 * ref,
+               "cpu_ms": 1e3 * (cpu_mark - cpu0),
+               "wait_cpu_ms": 1e3 * (cpu1 - cpu_mark),
+               "offcpu_ms": 1e3 * max(
+                   period - wait - (cpu_mark - cpu0), 0.0),
+               "counted_ms": 1e3 * (now - process0[0]),
+               "process_cpu_ms": 1e3 * (process1[1] - process0[1]),
+               "gen2_collections": process1[2] - process0[2],
+               "vol_switches": vol1 - vol0,
+               "invol_switches": invol1 - invol0,
+               "minor_faults": minor1 - minor0,
+               "major_faults": major1 - major0}
+        rec.update(self._machine())
+        return rec
+
+    def _machine(self) -> dict:
+        """What the machine has done to the process so far: the
+        cumulative throttled time and count of its CPU group and the
+        time some task of the machine has waited for a CPU.  Two slow
+        records in a row give what came between them.  A key is left
+        out where its file is not there."""
+        out = {}
+        stat = {w[0]: int(w[1]) for w in _read_words(self._cpu_stat)
+                if len(w) == 2}
+        if "throttled_usec" in stat:
+            out["throttled_ms"] = stat["throttled_usec"] / 1e3
+        elif "throttled_time" in stat:        # nanoseconds
+            out["throttled_ms"] = stat["throttled_time"] / 1e6
+        if "nr_throttled" in stat:
+            out["throttled_count"] = stat["nr_throttled"]
+        for words in _read_words(self._pressure):
+            if words[:1] == ["some"]:
+                total = [w[6:] for w in words if w.startswith("total=")]
+                if total:
+                    out["cpu_pressure_ms"] = int(total[0]) / 1e3
+        return out
 
 
 def publish_static_floor(kind: str,

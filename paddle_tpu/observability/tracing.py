@@ -21,6 +21,12 @@ OpenTelemetry-shaped substrate for that:
     under the remote caller: one training step yields a single coherent
     trace across trainer, pserver and master.
 
+A live span reads two clocks at entry and at exit: the wall's
+(``dur``) and its own thread's CPU time (``cpu``, `time.thread_time`),
+so ``dur - cpu`` is the time its thread was NOT running: a blocking
+read of the device, a lock, the interpreter lock, or the kernel running
+something else.
+
 Finished spans collect in ONE bounded in-process store, a ring of the
 last ``_MAX_SPANS`` full records (oldest dropped and counted), which
 ``finished_spans()`` reads and the Chrome-trace export
@@ -201,7 +207,7 @@ class Span:
     recorded at exit."""
 
     __slots__ = ("name", "context", "parent_id", "attrs",
-                 "_t0", "_wall")
+                 "_t0", "_cpu0", "_wall")
 
     def __init__(self, name: str, context: SpanContext,
                  parent_id: Optional[str], attrs: dict):
@@ -211,6 +217,9 @@ class Span:
         self.attrs = attrs
         self._wall = time.time()
         self._t0 = time.perf_counter()
+        # the CPU clock is read inside the wall clock's readings, at
+        # entry and at exit, so that `cpu` never exceeds `dur`
+        self._cpu0 = time.thread_time()
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -242,20 +251,22 @@ def _store(rec: dict) -> None:
         fn(rec)
 
 
-def _record(s: Span, duration: float) -> None:
-    rec = {
-        "name": s.name,
-        "trace_id": s.context.trace_id,
-        "span_id": s.context.span_id,
-        "parent_id": s.parent_id,
-        "ts": s._wall,
-        "dur": duration,
+def _record(name: str, ctx: SpanContext, parent_id: Optional[str],
+            ts: float, dur: float, cpu: Optional[float],
+            attrs: dict) -> None:
+    _store({
+        "name": name,
+        "trace_id": ctx.trace_id,
+        "span_id": ctx.span_id,
+        "parent_id": parent_id,
+        "ts": ts,
+        "dur": dur,
+        "cpu": cpu,
         "pid": os.getpid(),
         "tid": threading.get_ident(),
         "thread": threading.current_thread().name,
-        "attrs": dict(s.attrs),
-    }
-    _store(rec)
+        "attrs": dict(attrs),
+    })
 
 
 class _NoopCtx:
@@ -297,8 +308,11 @@ class _SpanCtx:
         return s
 
     def __exit__(self, *exc):
+        s = self._span
+        cpu = time.thread_time() - s._cpu0
         _stack().pop()
-        _record(self._span, time.perf_counter() - self._span._t0)
+        _record(s.name, s.context, s.parent_id, s._wall,
+                time.perf_counter() - s._t0, cpu, s.attrs)
         return False
 
 
@@ -337,41 +351,35 @@ def activate(ctx: Optional[SpanContext]):
 
 def record_span(name: str, ts: float, dur: float,
                 parent: Optional[SpanContext] = None,
+                cpu: Optional[float] = None,
                 **attrs) -> Optional[SpanContext]:
     """Record an already-timed span WITHOUT touching the thread's
     context stack — for ranges that outlive a `with` frame (e.g. a
     generator-held work window, where an abandoned consumer would leave
     a context-managed span permanently pushed).  `ts` is wall-clock
-    seconds (time.time()), `dur` seconds; `parent` parents it into an
-    existing trace, else it starts its own.  Returns the recorded
-    context (None when tracing is off)."""
+    seconds (time.time()), `dur` seconds; `cpu` the seconds the
+    recording thread spent on a CPU inside the range, where the caller
+    read them (None: a range that lay on no one thread); `parent`
+    parents it into an existing trace, else it starts its own.  Returns
+    the recorded context (None when tracing is off)."""
     if not (_ENABLED or _listeners):
         return None
     ctx = SpanContext(
         parent.trace_id if parent is not None else _new_trace_id(),
         _new_span_id())
-    rec = {
-        "name": name,
-        "trace_id": ctx.trace_id,
-        "span_id": ctx.span_id,
-        "parent_id": parent.span_id if parent is not None else None,
-        "ts": ts,
-        "dur": dur,
-        "pid": os.getpid(),
-        "tid": threading.get_ident(),
-        "thread": threading.current_thread().name,
-        "attrs": dict(attrs),
-    }
-    _store(rec)
+    _record(name, ctx, parent.span_id if parent is not None else None,
+            ts, dur, cpu, attrs)
     return ctx
 
 
 def finished_spans(last: Optional[int] = None) -> List[dict]:
     """The span store, oldest first: every span that finished while
     spans were live (tracing enabled, or a listener registered), as
-    full records: name, ts (wall seconds), dur, trace_id, span_id,
-    parent_id, pid, tid, thread, attrs.  At most the last `_MAX_SPANS`;
-    `dropped_spans()` says how many older ones the ring let go.  `last`
+    full records: name, ts (wall seconds), dur, cpu (the span's own
+    thread on a CPU inside it, seconds; None where `record_span` was
+    given none), trace_id, span_id, parent_id, pid, tid, thread, attrs.
+    At most the last `_MAX_SPANS`; `dropped_spans()` says how many
+    older ones the ring let go.  `last`
     keeps the newest that many (the flight recorder's dump)."""
     with _lock:
         if last is None:
@@ -629,7 +637,7 @@ def maybe_arm_tail_from_env() -> Optional[TailSampler]:
 def chrome_trace_events(include_profiler: bool = True) -> List[dict]:
     """Finished spans (and, optionally, the profiler's aggregated range
     events) as Chrome-trace event dicts (`ph: "X"`, microsecond ts/dur,
-    trace/span ids in args)."""
+    trace/span ids and the span's `cpu` seconds in args)."""
     events = []
     for s in finished_spans():
         events.append({
@@ -644,6 +652,7 @@ def chrome_trace_events(include_profiler: bool = True) -> List[dict]:
                 "trace_id": s["trace_id"],
                 "span_id": s["span_id"],
                 "parent_id": s["parent_id"],
+                "cpu": s["cpu"],
                 **s["attrs"],
             },
         })

@@ -32,7 +32,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import profiler
-from ..core.executor import CPUPlace, Executor, program_to_fn
+from ..core.executor import (CPUPlace, Executor, end_run, program_to_fn,
+                             run_clock)
 from ..core.flags import get_flag, trace_flags
 from ..core.framework import Variable, default_startup_program
 from ..core.scope import Scope
@@ -129,6 +130,7 @@ class ParallelExecutor(ShardedCheckpointMixin):
                           state_rw_names=rw, requested=hinted).check()
         self._seed = seed
         self._step = 0
+        self._clock = run_clock()
         param_shardings = dict(param_shardings or {})
         # kept for the overlap eligibility check: explicitly passed
         # placements must stand the overlap down exactly like derived
@@ -541,7 +543,14 @@ class ParallelExecutor(ShardedCheckpointMixin):
                 if n in self._state_shardings} or None
 
     # -- execution -----------------------------------------------------------
+    def slow_steps(self):
+        """As `Executor.slow_steps`: the newest 8 steps that took over
+        twice the reference period, kept with tracing on or off."""
+        return [dict(r) for r in self._clock.slow]
+
     def run(self, feed: Dict, fetch_list=None, return_numpy=True):
+        clock = self._clock
+        clock.mark("outside")
         t0 = time.perf_counter()
         self._refresh_trace_flags()
         fetch_names = ([v.name if isinstance(v, Variable) else str(v)
@@ -550,7 +559,7 @@ class ParallelExecutor(ShardedCheckpointMixin):
         assert fetch_names == self.fetch_names, \
             "fetch_list must match construction-time fetch_list"
         # the same three children as core.executor's `executor.run`
-        with obs_tracing.span("executor.run", mode="parallel"):
+        with obs_tracing.span("executor.run", mode="parallel") as run_span:
             with obs_tracing.span("executor.feed"):
                 feeds = {
                     n: jax.device_put(
@@ -558,6 +567,7 @@ class ParallelExecutor(ShardedCheckpointMixin):
                         self._feed_shardings.get(n, self._data_sharding))
                     for n, v in feed.items()
                 }
+            clock.mark("feed")
             with obs_tracing.span("executor.dispatch"):
                 key = jax.random.fold_in(jax.random.key(self._seed),
                                          self._step)
@@ -572,10 +582,12 @@ class ParallelExecutor(ShardedCheckpointMixin):
                 fetches, self._states = self._jit_step(
                     feeds, self._states, key)
                 out = [fetches[n] for n in fetch_names]
+            clock.mark("dispatch")
             if return_numpy:
                 # the wait for the device
                 with obs_tracing.span("executor.fetch"):
                     out = [np.asarray(v) for v in out]
+            end_run(clock, run_span)
         if obs_metrics.enabled():
             if not hasattr(self, "_m_run"):
                 self._m_run_id = f"pe{next(_PE_IDS)}"

@@ -323,67 +323,6 @@ class _Tick:
         self.tokens = None
 
 
-class _IterationClock:
-    """Where a scheduler iteration's time went, kept whether tracing
-    is on or off.  An iteration runs from the end of one blocking read
-    to the end of the next; the loop writes a `time.perf_counter`
-    reading into `deliver`, `admit`, `build` and `dispatch` as each
-    part ends (and `lock_wait`, the seconds `admit` spent taking the
-    server's lock), and `end` closes the iteration at the end of the
-    read.  `start` is None while the loop is not ticking (idle, a
-    flush, a hot swap, a failed tick): the next tick then begins an
-    iteration of its own.
-
-    The reference period is the median of the last 64 periods,
-    refreshed every 64 iterations (one sort of 64 floats); an
-    iteration longer than `SLOW_FACTOR` times it comes back from `end`
-    as a record naming the part that was longest.  Until 64 iterations
-    have run there is no reference and nothing is slow."""
-
-    __slots__ = ("start", "deliver", "admit", "build", "dispatch",
-                 "lock_wait", "reference", "_periods", "_n")
-
-    SLOW_FACTOR = 4.0
-    _WINDOW = 64
-
-    def __init__(self):
-        self.start: Optional[float] = None
-        self.deliver = self.admit = self.build = self.dispatch = 0.0
-        self.lock_wait = 0.0
-        self.reference: Optional[float] = None
-        self._periods = [0.0] * self._WINDOW
-        self._n = 0
-
-    def begin(self, now: float) -> None:
-        """An iteration with no read before it: nothing to deliver."""
-        self.start = self.deliver = now
-
-    def end(self, now: float, active: int) -> Optional[dict]:
-        start, self.start = self.start, now
-        if start is None:
-            return None
-        period = now - start
-        self._periods[self._n % self._WINDOW] = period
-        self._n += 1
-        if self._n % self._WINDOW == 0:
-            self.reference = sorted(self._periods)[self._WINDOW // 2]
-        ref = self.reference
-        if ref is None or period <= self.SLOW_FACTOR * ref:
-            return None
-        wait = now - self.dispatch
-        phase, longest = max(
-            (("deliver", self.deliver - start),
-             ("admit", self.admit - self.deliver),
-             ("build", self.build - self.admit),
-             ("dispatch", self.dispatch - self.build),
-             ("wait", wait)), key=lambda part: part[1])
-        return {"at": time.time(), "ms": 1e3 * period,
-                "wait_ms": 1e3 * wait, "phase": phase,
-                "phase_ms": 1e3 * longest, "active": active,
-                "lock_wait_ms": 1e3 * self.lock_wait,
-                "reference_ms": 1e3 * ref}
-
-
 @functools.lru_cache(maxsize=None)
 def _feed_tokens():
     """The jitted select that keeps sampled tokens on the device: a
@@ -539,10 +478,13 @@ class GenerationServer:
         # flight), and what the token select is given in its place
         self._inflight: Optional[_Tick] = None
         # where each iteration's time goes, and the newest iterations
-        # that took over four reference periods (`stats()["slow_ticks"]`;
-        # rebound whole, never mutated: `stats` reads it unlocked)
-        self._clock = _IterationClock()
-        self._slow_ticks: List[dict] = []
+        # that took over four reference periods (`stats()["slow_ticks"]`:
+        # the clock's `slow`, which it rebinds whole and never mutates,
+        # so `stats` reads it unlocked)
+        self._clock = obs_attr.IterationClock(
+            ("deliver", "admit", "build", "dispatch"))
+        # seconds the newest `admit` spent taking the server's lock
+        self._lock_wait = 0.0
         self._no_tokens = jax.device_put(
             np.zeros(self._slots, np.int32), self._device)
         self._active: List[Optional[_Seq]] = [None] * self._slots
@@ -869,7 +811,7 @@ class GenerationServer:
                # four reference periods, oldest first: `wait_ms` near
                # `ms` puts one in the device or the runtime, anything
                # else names host code by `phase` (docs/serving.md)
-               "slow_ticks": [dict(r) for r in self._slow_ticks]}
+               "slow_ticks": [dict(r) for r in self._clock.slow]}
         out.update(self.warmup_stats)
         out.update(self._cache.prefix_stats())
         return out
@@ -996,11 +938,11 @@ class GenerationServer:
         clock = self._clock
         while True:
             if clock.start is None:
-                clock.begin(time.perf_counter())
+                clock.begin()
             with obs_attr.phase("generation", "admit") as asp:
                 t_lock = time.perf_counter()
                 with self._lock:
-                    clock.lock_wait = time.perf_counter() - t_lock
+                    self._lock_wait = time.perf_counter() - t_lock
                     if self._stop:
                         break
                     shed = self._shed_expired_locked(time.monotonic())
@@ -1011,7 +953,7 @@ class GenerationServer:
                             and not seqs else None)
                     qdepth = len(self._queue)
                 if asp is not None:
-                    asp.set_attr("lock_wait_s", clock.lock_wait)
+                    asp.set_attr("lock_wait_s", self._lock_wait)
                 metrics_on = obs_metrics.enabled()
                 for seq in shed:
                     self._m_deadline.inc()
@@ -1031,7 +973,7 @@ class GenerationServer:
                     # tick is delivered
                     seqs = [s for s in seqs
                             if s.cur < s.positions_needed]
-            clock.admit = time.perf_counter()
+            clock.mark("admit")
             if swap is not None:
                 # no sequence holds a slot, but the extra position of
                 # one that ended by eos may still be out
@@ -1065,7 +1007,7 @@ class GenerationServer:
                 self._deliver_spec(plans, preds, metrics_on)
             elif done is not None:
                 self._deliver(done, metrics_on)
-            clock.deliver = time.perf_counter()
+            clock.mark("deliver")
         self._flush()
 
     def _tick(self, seqs: List[_Seq]) -> Optional[_Tick]:
@@ -1122,7 +1064,7 @@ class GenerationServer:
             attrs = (self._tick_attrs(len(rows), prefill,
                                       positions[active])
                      if live else {})
-        clock.build = time.perf_counter()
+        clock.mark("build")
         with obs_tracing.span("serving.decode_tick", active=len(rows),
                               **attrs) as sp:
             if sp is not None:
@@ -1140,7 +1082,7 @@ class GenerationServer:
                 for seq in seqs:
                     seq.cur += 1
                 self._m_ticks.inc()
-            clock.dispatch = time.perf_counter()
+            clock.mark("dispatch")
             if prev is not None:
                 with obs_attr.phase("generation", "sample"):
                     prev.tokens = np.asarray(prev.nxt)
@@ -1152,15 +1094,17 @@ class GenerationServer:
         """Close the iteration at the end of its read.  One that took
         over four reference periods is kept among the newest 8 of
         `stats()["slow_ticks"]`, goes to the flight recorder where one
-        is armed, and marks the live tick span `slow=1`."""
-        rec = self._clock.end(time.perf_counter(), active)
+        is armed, and marks the live tick span `slow=1` with the host
+        time in which this thread did not run."""
+        rec = self._clock.end(active=active,
+                              lock_wait_ms=1e3 * self._lock_wait)
         if rec is None:
             return
-        self._slow_ticks = (self._slow_ticks + [rec])[-8:]
         flightrecorder.note("serving.slow_tick", server=self._sid,
                             **rec)
         if sp is not None:
             sp.set_attr("slow", 1)
+            sp.set_attr("offcpu_ms", rec["offcpu_ms"])
 
     def _step_tables(self):
         """The tables `step` takes: each slot's block table, with the
@@ -1349,7 +1293,7 @@ class GenerationServer:
                 n_prop = (n_max - teacher
                           if greedy and teacher == m else 0)
                 plans.append((seq, c, m, teacher, n_prop))
-        clock.build = time.perf_counter()
+        clock.mark("build")
 
         # draft catch-up: teacher-force the draft over the window's
         # committed head so its KV tracks the target's (positions a
@@ -1448,7 +1392,7 @@ class GenerationServer:
                         self._states, self._pool_k, self._pool_v,
                         self._tables, pos, toks, seeds, temps, nv))
                 self._m_ticks.inc()
-            clock.dispatch = time.perf_counter()
+            clock.mark("dispatch")
             with obs_attr.phase("generation", "sample"):
                 preds = np.asarray(nxt)
                 self._step_counts(sp, counts)

@@ -510,3 +510,128 @@ def test_cli_trace_of_resolves_exemplar_to_trace(tmp_path, capsys):
          "--prom", str(p)])
     assert rc == 1
     assert "no exemplars" in capsys.readouterr().out
+
+
+# ---------------------------------------------------------------------------
+# IterationClock: a loop's iteration split into parts, work and waiting
+# ---------------------------------------------------------------------------
+
+
+def _spin(seconds):
+    """Compute until this thread has had `seconds` of a CPU."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def _clock_with_reference(**kw):
+    """A clock whose 64 iterations of 1 ms have given it a reference."""
+    clock = attribution.IterationClock(("first", "second"), **kw)
+    clock.begin()
+    for _ in range(64):
+        time.sleep(0.001)
+        clock.mark("first")
+        clock.mark("second")
+        clock.end()
+    assert 0.001 <= clock.reference < 0.01
+    clock.slow = []     # (the 64th is judged already: a loaded host)
+    return clock
+
+
+@pytest.mark.parametrize("body, part, working", [
+    (_spin, "first", True), (time.sleep, "second", False),
+    (time.sleep, "idle", False)], ids=["computes", "sleeps", "waits"])
+def test_iteration_clock_names_the_part_and_splits_work_from_waiting(
+        body, part, working):
+    """An iteration over `slow_factor` reference periods comes back as
+    a record naming its longest part; `cpu_ms` is the thread at work
+    in the host parts, `offcpu_ms` the host time it did not run, and a
+    part not marked took no time."""
+    clock = _clock_with_reference(wait="idle", slow_factor=3.0)
+    if part == "first":
+        body(0.04)
+    clock.mark("first")
+    if part == "second":
+        body(0.04)
+    clock.mark("second")
+    if part == "idle":
+        body(0.04)
+    rec = clock.end(active=3)
+    assert rec is not None and clock.slow == [rec]
+    assert rec["phase"] == part and rec["phase_ms"] >= 40.0
+    assert rec["ms"] > 3 * rec["reference_ms"] and rec["active"] == 3
+    # the process's counters are read every 32 ms or more and at a slow
+    # iteration's end: they cover it and at most that much before it
+    # (and one of the quick iterations, which a loaded host stretches)
+    assert rec["ms"] <= rec["counted_ms"] <= rec["ms"] + 1e3 * (
+        clock.PROCESS_EVERY_S + 0.1)
+    assert (rec["wait_ms"] >= 40.0) == (part == "idle")
+    if working:
+        # (on a loaded host the thread may also wait for a CPU)
+        assert rec["cpu_ms"] >= 35.0 and rec["process_cpu_ms"] >= 35.0
+        assert rec["offcpu_ms"] <= rec["phase_ms"] - 35.0
+    elif part == "idle":
+        # inside the wait: neither host work nor host time off the CPU
+        assert rec["cpu_ms"] < 20.0 and rec["offcpu_ms"] < 20.0
+        assert rec["wait_cpu_ms"] < 20.0
+    else:
+        assert rec["cpu_ms"] < 20.0 <= rec["offcpu_ms"]
+    # the next iteration marks nothing: its parts took no time and the
+    # stale marks do not count
+    time.sleep(0.04)
+    rec = clock.end()
+    assert rec["phase"] == "idle" and rec["wait_ms"] >= 40.0
+    assert rec["cpu_ms"] == 0.0 and len(clock.slow) == 2
+    for _ in range(10):
+        time.sleep(0.01)
+        clock.end()
+    assert len(clock.slow) == clock.KEEP == 8
+
+
+def test_iteration_clock_without_the_machines_files(tmp_path):
+    """Where the CPU group's `cpu.stat` or `/proc/pressure/cpu` is not
+    there the record leaves their keys out; where they are, it reads
+    both versions' names."""
+    missing = str(tmp_path / "none")
+    clock = _clock_with_reference(cpu_stat_path=missing,
+                                  pressure_path=missing)
+    time.sleep(0.03)
+    rec = clock.end()
+    assert rec["phase"] == "wait" and rec["offcpu_ms"] == 0.0
+    assert not {"throttled_ms", "throttled_count",
+                "cpu_pressure_ms"} & set(rec)
+    v1, v2, psi = (tmp_path / n for n in ("v1", "v2", "psi"))
+    v1.write_text("nr_periods 9\nnr_throttled 4\nthrottled_time 2500000\n")
+    v2.write_text("usage_usec 77\nnr_throttled 5\nthrottled_usec 1500\n")
+    psi.write_text("some avg10=0.00 avg60=0.00 avg300=0.00 total=8250\n"
+                   "full avg10=0.00 avg60=0.00 avg300=0.00 total=0\n")
+    for stat, ms, count in ((v1, 2.5, 4), (v2, 1.5, 5)):
+        clock = attribution.IterationClock((), cpu_stat_path=str(stat),
+                                           pressure_path=str(psi))
+        assert clock._machine() == {"throttled_ms": ms,
+                                    "throttled_count": count,
+                                    "cpu_pressure_ms": 8.25}
+
+
+def test_iteration_clock_restarts_on_another_thread():
+    """The counters belong to a thread: an iteration that ends on
+    another thread than the one it began on gives no record, however
+    long it was, and the next one on that thread is timed again."""
+    import threading
+
+    clock = _clock_with_reference()
+    got = []
+
+    def other():
+        time.sleep(0.03)
+        got.append(clock.end())
+        time.sleep(0.03)
+        got.append(clock.end())
+
+    t = threading.Thread(target=other)
+    t.start()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert got[0] is None and got[1]["ms"] >= 30.0
+    time.sleep(0.03)
+    assert clock.end() is None

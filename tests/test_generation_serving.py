@@ -931,16 +931,42 @@ def test_slow_iteration_is_kept_and_named_by_its_phase(traced,
         inj.clear()
         srv.close()
     assert before <= len(slow) <= 8
-    (rec,) = [r for r in slow if r["ms"] >= 50.0]
+    # every slow record is noted, the newest 8 are kept (a loaded host
+    # makes more of them slow than the one the delay made)
+    noted = [{k: v for k, v in data.items() if k != "server"}
+             for event, data in notes if event == "serving.slow_tick"]
+    assert all(data["server"] == srv._sid for event, data in notes
+               if event == "serving.slow_tick")
+    assert slow == noted[-len(slow):] if slow else not noted
+    (rec,) = [r for r in noted if r["ms"] >= 50.0]
     assert rec["phase"] == "dispatch" and rec["phase_ms"] >= 50.0
     assert rec["wait_ms"] < 25.0 and rec["active"] == 1
     assert rec["ms"] > 4 * rec["reference_ms"] > 0
     assert abs(rec["at"] - time.time()) < 120
-    assert ("serving.slow_tick", dict(rec, server=srv._sid)) in notes
+    # the injected delay sleeps: the scheduler's thread was not on a
+    # CPU for it, and the record says so with tracing on or off
+    assert rec["cpu_ms"] < 25.0 <= rec["offcpu_ms"] <= rec["ms"]
+    counted = {"cpu_ms", "wait_cpu_ms", "offcpu_ms", "counted_ms",
+               "process_cpu_ms", "vol_switches", "invol_switches",
+               "minor_faults", "major_faults", "gen2_collections"}
+    assert counted <= set(rec)
+    machine = set(rec) - counted - {
+        "at", "ms", "wait_ms", "phase", "phase_ms", "active",
+        "lock_wait_ms", "reference_ms"}
+    assert machine <= {"throttled_ms", "throttled_count",
+                       "cpu_pressure_ms"}
+    assert all(type(rec[k]) in (int, float) and rec[k] >= 0
+               for k in counted | machine)
+    assert rec["vol_switches"] >= 1      # the sleep gave the CPU up
+    # the process's readings: this iteration and a few ticks before it
+    assert rec["ms"] <= rec["counted_ms"] < rec["ms"] + 1000.0
     marked = [s for s in spans if s["name"] == "serving.decode_tick"
               and s["attrs"].get("slow")]
     if traced:
-        assert any(s["dur"] >= 0.05 for s in marked)
+        (tick,) = [s for s in marked
+                   if s["attrs"]["offcpu_ms"] == rec["offcpu_ms"]]
+        assert tick["dur"] >= 0.05
+        assert tick["dur"] - tick["cpu"] >= 0.025
     else:
         assert spans == []
 

@@ -71,13 +71,89 @@ def test_store_keeps_full_records_under_a_listener_alone():
     assert tracing.finished_spans() == got     # the same records
     rec = tracing.finished_spans()[0]
     assert set(rec) == {"name", "trace_id", "span_id", "parent_id", "ts",
-                        "dur", "pid", "tid", "thread", "attrs"}
+                        "dur", "cpu", "pid", "tid", "thread", "attrs"}
     assert rec["attrs"] == {"n": 3}
     assert rec["parent_id"] == outer.context.span_id
     assert rec["trace_id"] == outer.context.trace_id
     late = tracing.finished_spans()[2]
     assert (late["ts"], late["dur"], late["attrs"]) == (12.5, 0.25,
                                                          {"x": "y"})
+
+
+def _spin(seconds):
+    """Compute until this thread has had `seconds` of a CPU."""
+    import time
+
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def _nap(seconds):
+    import time
+
+    time.sleep(seconds)
+
+
+@pytest.mark.parametrize("body, working", [(_spin, True), (_nap, False)],
+                         ids=["busy_loop", "sleep"])
+def test_span_records_its_threads_cpu_seconds(body, working):
+    """`cpu` is the span's own thread on a CPU between entry and exit:
+    all of what a loop that computes was given (on an idle host most of
+    `dur`; the test's host is shared, so the seconds are what is
+    asserted), next to none of a sleep (`dur - cpu` is the time the
+    thread did not run)."""
+    with listening():
+        with tracing.span("outer"):
+            with tracing.span("inner"):
+                body(0.03)
+    inner, outer = tracing.finished_spans()
+    for rec in (inner, outer):
+        assert rec["dur"] >= 0.03 and 0.0 <= rec["cpu"] <= rec["dur"]
+        if working:
+            assert rec["cpu"] >= 0.03
+        else:
+            assert rec["cpu"] < rec["dur"] / 3
+    assert outer["cpu"] >= inner["cpu"]
+
+
+def test_record_span_stores_the_cpu_it_is_given_or_none():
+    """A range recorded after the fact carries the `cpu` its caller
+    read, None where it read none (a request's lifetime lies on no
+    thread); `phased_iter` reads its pull's."""
+    with listening():
+        tracing.record_span("late", 12.5, 0.25)
+        tracing.record_span("timed", 12.5, 0.25, cpu=0.125, x="y")
+        assert list(attribution.phased_iter(
+            "trainer", "reader", (_nap(0.01) for _ in range(2)))) == [
+                None, None]
+    late, timed, *pulls = tracing.finished_spans()
+    assert late["cpu"] is None and late["attrs"] == {}
+    assert timed["cpu"] == 0.125 and timed["attrs"] == {"x": "y"}
+    assert len(pulls) == 2 and all(
+        0.0 <= s["cpu"] < s["dur"] / 3 and s["dur"] >= 0.01 for s in pulls)
+    events = {e["name"]: e for e in tracing.chrome_trace_events(
+        include_profiler=False)}
+    assert events["timed"]["args"]["cpu"] == 0.125
+    assert events["late"]["args"]["cpu"] is None
+
+
+def test_a_noop_span_reads_no_clock(monkeypatch):
+    """With tracing off and no listener `span()` is the shared no-op
+    after one boolean test: no clock is read, nothing is stored."""
+    class NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read by a span that is off")
+
+    monkeypatch.setattr(tracing, "time", NoClock())
+    monkeypatch.setattr(attribution, "time", NoClock())
+    with tracing.span("x", k=1) as s:
+        assert s is None
+    with attribution.phase("generation", "build") as s:
+        assert s is None
+    assert tracing.record_span("y", 0.0, 1.0, cpu=0.5) is None
+    assert list(attribution.phased_iter("trainer", "reader", [1])) == [1]
+    assert tracing.finished_spans() == []
 
 
 def test_store_holds_nothing_while_spans_are_off():
@@ -285,14 +361,115 @@ def test_scheduler_iteration_readers_say_nothing_when_they_cannot(
 
 
 # ---------------------------------------------------------------------------
+# the six readers of a span's `cpu` (PR 51: perf/metrics/span_cpu.py)
+# ---------------------------------------------------------------------------
+
+_CPU_READERS = ("sched_host_offcpu_share", "sched_dispatch_offcpu_ms",
+                "sched_iteration_max_offcpu_ms", "reader_pack_offcpu_share",
+                "reader_run_offcpu_share", "train_step_max_ms")
+
+
+def _read_cpu(monkeypatch, body):
+    return _read_executor(monkeypatch, body, names=_CPU_READERS)
+
+
+def _spans_by_hand(with_cpu):
+    """Three scheduler iterations after the read that opens the first
+    period (phases of 1, 2, 1, 4 and 2 ms; the second's dispatch takes
+    104 ms), four packs of 20 ms on the worker and four steps of an
+    executor.  `off` is the part of a span its thread did not run."""
+    def record(name, t, dur, off, **attrs):
+        if with_cpu:
+            attrs["cpu"] = dur - off
+        tracing.record_span(name, t, dur, **attrs)
+        return t + dur
+
+    def body():
+        t = record("generation.phase.sample", 1000.0, 0.002, 0.002)
+        for dispatch, off in ((0.004, 0.001), (0.104, 0.100),
+                              (0.004, 0.001)):
+            t = record("generation.phase.deliver", t, 0.001, 0.0)
+            t = record("generation.phase.admit", t, 0.002, 0.0005)
+            t = record("generation.phase.build", t, 0.001, 0.0)
+            t = record("generation.phase.decode", t, dispatch, off)
+            t = record("generation.phase.sample", t, 0.002, 0.002)
+        for i in range(4):
+            record("trainer.phase.feed_pack", t, 0.020, 0.005)
+            record("executor.feed", t, 0.002, 0.0)
+            record("executor.dispatch", t + 0.002, 0.006, 0.002)
+            record("executor.fetch", t + 0.008, 0.090, 0.090)
+            period = {} if i == 0 or not with_cpu else {
+                "period_s": 0.3 if i == 2 else 0.1}
+            t = record("executor.run", t, 0.1, 0.09, mode="compiled",
+                       **period)
+    return body
+
+
+def test_cpu_readers_on_known_spans(monkeypatch):
+    got = _read_cpu(monkeypatch, _spans_by_hand(with_cpu=True))
+    # host phases: 3 x (1 + 2 + 1) + 4 + 104 + 4 = 124 ms, of which
+    # 3 x 0.5 + 1 + 100 + 1 = 103.5 ms off the CPU
+    assert got["sched_host_offcpu_share"] == pytest.approx(
+        100 * 0.1035 / 0.124)
+    assert got["sched_dispatch_offcpu_ms"] == pytest.approx(102.0 / 3)
+    # the longest iteration is the second: its admit's 0.5 ms and its
+    # dispatch's 100
+    assert got["sched_iteration_max_offcpu_ms"] == pytest.approx(100.5)
+    assert got["reader_pack_offcpu_share"] == pytest.approx(25.0)
+    assert got["reader_run_offcpu_share"] == pytest.approx(25.0)
+    assert got["train_step_max_ms"] == pytest.approx(300.0)
+
+
+def test_cpu_readers_say_nothing_when_they_cannot(monkeypatch):
+    """A program whose records carry no `cpu` and whose `executor.run`
+    has no `period_s` (the parent of PR 51) gives none of the six, and
+    neither does a span store that has dropped records."""
+    got = _read_cpu(monkeypatch, _spans_by_hand(with_cpu=False))
+    assert set(got.values()) == {None}
+    tracing.clear()
+    monkeypatch.setattr(tracing, "_dropped", 1)
+    got = _read_cpu(monkeypatch, _spans_by_hand(with_cpu=True))
+    assert set(got.values()) == {None}
+    tracing.clear()
+    monkeypatch.setattr(tracing, "_dropped", 0)
+    assert set(_read_cpu(monkeypatch, lambda: None).values()) == {None}
+
+
+def test_cpu_readers_on_the_programs_own_spans(monkeypatch):
+    """Steps of a Program recorded by `Executor.run` itself: every step
+    but the executor's first carries `period_s`, and the run's host
+    work reads as a share between 0 and 100."""
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    feed = _batch()
+
+    def body():
+        exe.run(startup, scope=scope)
+        for _ in range(5):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+
+    got = _read_cpu(monkeypatch, body)
+    runs = _named("executor.run")
+    assert ["period_s" in s["attrs"] for s in runs] == [False] + [True] * 5
+    ends = [s["ts"] + s["dur"] for s in runs]
+    for s, a, b in zip(runs[1:], ends, ends[1:]):
+        assert s["attrs"]["period_s"] == pytest.approx(b - a, abs=2e-3)
+    assert got["train_step_max_ms"] == pytest.approx(
+        1e3 * max(s["attrs"]["period_s"] for s in runs[1:]))
+    assert 0.0 <= got["reader_run_offcpu_share"] <= 100.0
+    exe.close()
+
+
+# ---------------------------------------------------------------------------
 # the two readers of `Executor.run`'s record of its states (PR 39:
 # perf/metrics/reader_state_reuse_share.py, reader_dispatch_share.py)
 # ---------------------------------------------------------------------------
 
 
-def _read_executor(monkeypatch, body):
+def _read_executor(monkeypatch, body, names=("reader_state_reuse_share",
+                                             "reader_dispatch_share")):
     """Run `body()` under a tap like the benchmark's and give what the
-    two readers make of the spans it left."""
+    readers called `names` make of the spans it left."""
     import os
     import sys
 
@@ -312,7 +489,7 @@ def _read_executor(monkeypatch, body):
     run.spans = tap.records
     return {name: common.load_module(os.path.join(
         perf, "metrics", name + ".py")).compute(run)
-        for name in ("reader_state_reuse_share", "reader_dispatch_share")}
+        for name in names}
 
 
 def _steps_by_hand(attrs):
@@ -436,6 +613,93 @@ def test_executor_run_has_feed_dispatch_fetch_children(run):
         assert len(_named("executor.run")) == 1
         assert _named("executor.fetch") == []
         assert len(_named("executor.feed")) == 1
+
+
+class _SleepyFeed(dict):
+    """A feed whose arrays take `nap` seconds to hand over, once."""
+    nap = 0.0
+
+    def items(self):
+        import time
+
+        nap, self.nap = self.nap, 0.0
+        time.sleep(nap)
+        return super().items()
+
+
+def _serial_stepper():
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    return exe, lambda feed: exe.run(main, feed=feed, fetch_list=[loss],
+                                     scope=scope)
+
+
+def _parallel_stepper():
+    main, startup, loss = _classifier()
+    pe = parallel.ParallelExecutor(main, ["x", "y"], [loss],
+                                   mesh={"dp": 8},
+                                   startup_program=startup)
+    return pe, pe.run
+
+
+@pytest.mark.parametrize("where", ["outside", "feed"])
+@pytest.mark.parametrize("stepper", [_serial_stepper, _parallel_stepper],
+                         ids=["serial", "parallel"])
+def test_slow_executor_step_is_kept_noted_and_on_the_span(stepper, where,
+                                                          monkeypatch):
+    """The executors time every `run` with tracing on or off: one that
+    a 50 ms sleep makes slow, between two calls or inside the feed, is
+    kept in `slow_steps()` under that part with the thread's CPU
+    seconds beside the wall's, noted to the flight recorder as
+    `executor.slow_step`, and a live `executor.run` span carries the
+    seconds since the previous `run` ended."""
+    import time
+
+    notes = []
+    monkeypatch.setattr(flightrecorder, "note",
+                        lambda event, **data: notes.append((event, data)))
+    exe, step = stepper()
+    feed = _SleepyFeed(_batch())
+
+    def slow_step():
+        if where == "outside":
+            time.sleep(0.05)
+        else:
+            feed.nap = 0.05
+        step(feed)
+
+    # 64 iterations give the clock its reference period
+    for _ in range(70):
+        step(feed)
+    assert exe._clock.reference is not None
+    assert tracing.finished_spans() == []
+    def kept():
+        return [r for r in exe.slow_steps()
+                if r["phase"] == where and r["phase_ms"] >= 50.0]
+
+    # (a loaded host may make another part of that step longer still:
+    # then once more)
+    for _ in range(3):
+        slow_step()
+        if kept():
+            break
+    rec = kept()[-1]
+    assert rec["ms"] > 2 * rec["reference_ms"] > 0
+    assert rec["cpu_ms"] < 25.0 <= rec["offcpu_ms"]
+    assert rec["vol_switches"] >= 1
+    assert {"process_cpu_ms", "invol_switches", "minor_faults",
+            "major_faults", "gen2_collections"} <= set(rec)
+    assert ("executor.slow_step", rec) in notes
+    assert len(exe.slow_steps()) <= 8
+    with listening():
+        step(feed)
+        slow_step()
+    quick, slow = _named("executor.run")[-2:]
+    assert quick["attrs"]["period_s"] > 0.0
+    assert slow["attrs"]["period_s"] >= 0.05
+    assert exe.slow_steps()[-1]["ms"] >= 50.0
+    exe.close()
 
 
 @pytest.mark.parametrize("sync_every_n", [1, 2])
