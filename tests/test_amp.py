@@ -161,6 +161,31 @@ def test_softmax_ce_hard_label_reads_bf16_logits(label_shape):
     assert str(grad.dtype) == "bfloat16"
 
 
+@pytest.mark.parametrize("from_the_end", [False, True],
+                         ids=["label", "label-from-the-end"])
+@pytest.mark.parametrize("label_shape", [[], [1]], ids=["N", "Nx1"])
+def test_softmax_ce_hard_label_bf16_gradient_is_rounded_once_to_the_bit(
+        label_shape, from_the_end):
+    """bf16 logits under amp: the written gradient equals, TO THE BIT,
+    numpy's `(softmax - onehot) / rows` formed in float32 over the
+    logits as rounded and rounded once to bf16, compiled and
+    interpreted, on the likely row (where softmax * g and -g rounded
+    apart keep a few bits) and on the saturated one."""
+    import ml_dtypes
+
+    from test_basic_ops import (softmax_ce_case_gradients,
+                                softmax_ce_grad_rounded_once)
+
+    with amp.bf16_guard():
+        grads, logits, label = softmax_ce_case_gradients(
+            ml_dtypes.bfloat16, label_shape, from_the_end)
+    want = softmax_ce_grad_rounded_once(logits, label)
+    for grad in grads:
+        assert str(grad.dtype) == "bfloat16"
+        np.testing.assert_array_equal(grad.view(np.uint16),
+                                      want.view(np.uint16))
+
+
 def test_softmax_ce_soft_label_upcasts_bf16_logits_as_before():
     """`soft_label` keeps log_softmax over the upcast logits, to the
     bit."""
@@ -181,3 +206,120 @@ def test_softmax_ce_soft_label_upcasts_bf16_logits_as_before():
     np.testing.assert_array_equal(
         loss, np.asarray(-jnp.sum(soft * log_p, axis=-1, keepdims=True)))
     np.testing.assert_array_equal(softmax, np.asarray(jnp.exp(log_p)))
+
+
+# -- the loss's written gradient under every executor (PR 52) ----------------
+
+LM = dict(vocab=48, seq=8, batch=4, d_model=16, n_heads=2, n_layers=2)
+
+
+def _tiny_lm(pipeline_stages=None, recompute_head=False):
+    """A two-layer language model with its loss on hard labels and Adam,
+    as the training cell writes it (Momentum under `pipeline_stages`:
+    `PipelineExecutor` refuses Adam's shared beta-pow accumulators);
+    `recompute_head` builds the head and the loss inside a
+    `layers.recompute` segment."""
+    from paddle_tpu.core.framework import reset_unique_names
+    from paddle_tpu.models.transformer import transformer_decoder
+
+    reset_unique_names()
+    main, startup = fluid.Program(), fluid.Program()
+    main.seed = startup.seed = 11
+    with fluid.program_guard(main, startup):
+        ids = fluid.layers.data(name="ids", shape=[LM["seq"]],
+                                dtype="int64")
+        lbl = fluid.layers.data(name="lbl", shape=[LM["seq"], 1],
+                                dtype="int64")
+        h = transformer_decoder(
+            ids, None, LM["vocab"], d_model=LM["d_model"],
+            n_heads=LM["n_heads"], n_layers=LM["n_layers"],
+            d_inner=2 * LM["d_model"], max_len=LM["seq"],
+            pipeline_stages=pipeline_stages)
+
+        def head():
+            logits = fluid.layers.fc(input=h, size=LM["vocab"],
+                                     num_flatten_dims=2)
+            return fluid.layers.softmax_with_cross_entropy(
+                fluid.layers.reshape(logits, shape=[-1, LM["vocab"]]),
+                fluid.layers.reshape(lbl, shape=[-1, 1]))
+
+        cost = fluid.layers.recompute(head) if recompute_head else head()
+        loss = fluid.layers.mean(cost)
+        (fluid.Momentum(learning_rate=0.1, momentum=0.9) if pipeline_stages
+         else fluid.Adam(learning_rate=1e-2)).minimize(loss)
+    params = sorted(p.name for p in main.global_block().all_parameters())
+    return main, startup, loss, params
+
+
+def _lm_batches(steps):
+    r = np.random.RandomState(5)
+    toks = r.randint(0, LM["vocab"], (steps, LM["batch"], LM["seq"] + 1))
+    return [{"ids": t[:, :-1].astype(np.int64),
+             "lbl": t[:, 1:, None].astype(np.int64)} for t in toks]
+
+
+def _train_lm(how, steps):
+    """(losses, {parameter: value}) of the tiny model's `steps` steps
+    through one executor, under bf16 amp but for `pipeline` (a stage's
+    bf16 output does not match the float32 carry of the schedule's
+    scan: `PipelineExecutor` runs float32 Programs)."""
+    import contextlib
+
+    from paddle_tpu import parallel
+    from paddle_tpu.core.flags import get_flag, set_flags
+
+    batches = _lm_batches(steps)
+    with (contextlib.nullcontext() if how == "pipeline"
+          else amp.bf16_guard()):
+        if how in ("pipeline", "parallel"):
+            main, startup, loss, params = _tiny_lm(
+                pipeline_stages=2 if how == "pipeline" else None)
+            pe = (parallel.PipelineExecutor(
+                main, ["ids", "lbl"], [loss], mesh={"dp": 1, "pp": 2},
+                startup_program=startup, n_micro=2)
+                if how == "pipeline" else parallel.ParallelExecutor(
+                    main, ["ids", "lbl"], [loss], mesh={"dp": 2},
+                    startup_program=startup))
+            losses = [float(np.asarray(pe.run(b)[0]).ravel()[0])
+                      for b in batches]
+            return losses, {n: np.asarray(pe.state(n)) for n in params}
+        main, startup, loss, params = _tiny_lm(
+            recompute_head=how == "recompute")
+        was = get_flag("memory_optimize")
+        set_flags({"memory_optimize": how == "memory_optimize"})
+        try:
+            scope = fluid.Scope()
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup, scope=scope)
+            losses = [float(np.asarray(exe.run(
+                main, feed=b, fetch_list=[loss],
+                scope=scope)[0]).ravel()[0]) for b in batches]
+        finally:
+            set_flags({"memory_optimize": was})
+        return losses, {n: np.asarray(scope.find_var(n)) for n in params}
+
+
+@pytest.mark.parametrize("how", ["executor", "pipeline", "parallel",
+                                 "memory_optimize", "recompute"])
+def test_lm_steps_equal_the_unwritten_gradients_to_the_bit(how,
+                                                           monkeypatch):
+    """The barrier behind which `softmax_with_cross_entropy`'s gradient
+    is written changes WHERE the value is formed and not the value: a
+    tiny language model's steps (12 through `Executor.run`; 4 through
+    `PipelineExecutor`, which differentiates the composed forward with
+    `jax.value_and_grad`, through `ParallelExecutor`'s mapping, under
+    the `memory_optimize` flag and with the head inside a `recompute`
+    segment; bf16 amp wherever the executor takes it) give the losses and
+    every parameter that the same steps give with the barrier taken out
+    (the parent's form: the consumers form the gradient themselves), bit
+    for bit, and the model learns."""
+    import jax
+
+    steps = 12 if how == "executor" else 4
+    losses, params = _train_lm(how, steps)
+    assert np.isfinite(losses).all() and losses[-1] < losses[0]
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    want_losses, want = _train_lm(how, steps)
+    assert losses == want_losses
+    for name, value in want.items():
+        np.testing.assert_array_equal(params[name], value, err_msg=name)

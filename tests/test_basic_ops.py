@@ -300,6 +300,61 @@ def test_softmax_ce_hard_label_against_float32_log_softmax(label_shape):
     assert grad.dtype == np.float32
 
 
+def softmax_ce_grad_rounded_once(logits, label):
+    """numpy: the gradient of mean(Loss) to the logits, `(softmax -
+    onehot) / rows` formed in float32 over the logits as given and
+    rounded ONCE to their dtype.  A negative label counts from the
+    end."""
+    x = np.asarray(logits, np.float32)
+    flat = label.ravel() % x.shape[-1]
+    top = x.max(-1, keepdims=True)
+    lse = np.log(np.exp(x - top).sum(-1, keepdims=True,
+                                     dtype=np.float32)) + top
+    hot = (np.arange(x.shape[-1])[None, :] == flat[:, None])
+    d = (np.exp(x - lse) - hot.astype(np.float32)) \
+        * np.float32(1.0 / len(x))
+    return d.astype(logits.dtype)
+
+
+def softmax_ce_case_gradients(dtype, label_shape, from_the_end):
+    """`softmax_ce_case`'s gradient through the compiled step and
+    through the interpreter, and the case itself; `from_the_end` names
+    every class by its negative index."""
+    logits, label = softmax_ce_case(dtype, label_shape)
+    if from_the_end:
+        label = label - logits.shape[-1]
+    grads = [run_softmax_ce(logits, label, compiled=compiled)[2]
+             for compiled in (True, False)]
+    return grads, logits, label
+
+
+@pytest.mark.parametrize("from_the_end", [False, True],
+                         ids=["label", "label-from-the-end"])
+@pytest.mark.parametrize("label_shape", [[], [1]], ids=["N", "Nx1"])
+def test_softmax_ce_hard_label_gradient_is_formed_once_in_float32(
+        label_shape, from_the_end):
+    """The written gradient (`ops/loss.py`: one producer behind a
+    barrier, read by every consumer) is the formula's value: to the bit
+    the same jax calls made here in float32, and numpy's to float32's
+    last places (its exp and log round apart from XLA's), on the row
+    whose true class is the likely one and on the saturated row."""
+    import jax
+    import jax.numpy as jnp
+
+    grads, logits, label = softmax_ce_case_gradients(
+        np.float32, label_shape, from_the_end)
+    x = jnp.asarray(logits)
+    flat = jnp.asarray(label.ravel() % logits.shape[-1])
+    hot = jnp.arange(logits.shape[-1])[None, :] == flat[:, None]
+    want = (jnp.exp(x - jax.nn.logsumexp(x, axis=-1, keepdims=True))
+            - hot.astype(jnp.float32)) * jnp.float32(1.0 / len(logits))
+    for grad in grads:
+        assert grad.dtype == np.float32
+        np.testing.assert_array_equal(grad, np.asarray(want))
+        np.testing.assert_array_max_ulp(
+            grad, softmax_ce_grad_rounded_once(logits, label), maxulp=8)
+
+
 def test_softmax_ce_soft_label_is_log_softmax_to_the_bit():
     """A distribution a row keeps the form that needs log_p whole: the
     interpreter's results equal the same jax calls made here."""

@@ -300,20 +300,24 @@ def _time_limit(seconds):
         signal.signal(signal.SIGALRM, old)
 
 
-def test_lm_training_step_writes_no_float32_logits(one_v5e, monkeypatch):
-    """The guard for the loss on hard labels: a small language model's
-    AMP training step compiled for the described chip holds no float32
-    array of [tokens, vocab], in either order, flattened or not.  The
-    loss is logsumexp minus the picked logit over the bf16 logits; as
-    log_softmax then take_along_axis it wrote the upcast logits and
-    log_p whole and kept the first for the backward."""
+LM_STEP = (2, 256, 4096)        # sequences, their length, the vocabulary
+
+
+@pytest.fixture(scope="module")
+def lm_step_text(one_v5e):
+    """The compiled text of a small language model's AMP training step
+    (one layer, Adam, `softmax_with_cross_entropy` on hard labels over
+    2 x 256 tokens and 4096 words) for the described chip: compiled
+    once for the guards below."""
     import paddle_tpu as fluid
     from paddle_tpu.models.transformer import transformer_lm
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    b, s, vocab = 2, 256, 4096
+    b, s, vocab = LM_STEP
     main, startup = fluid.Program(), fluid.Program()
-    with _time_limit(120), fluid.amp.bf16_guard():
+    with pytest.MonkeyPatch.context() as patch, _time_limit(120), \
+            fluid.amp.bf16_guard():
+        # what the lowerings ask where no executor drives them
+        patch.setattr(jax, "default_backend", lambda: "tpu")
         with fluid.program_guard(main, startup):
             ids = fluid.layers.data(name="ids", shape=[s], dtype="int64")
             lbl = fluid.layers.data(name="lbl", shape=[s, 1], dtype="int64")
@@ -325,14 +329,54 @@ def test_lm_training_step_writes_no_float32_logits(one_v5e, monkeypatch):
                     fluid.layers.reshape(logits, shape=[-1, vocab]),
                     fluid.layers.reshape(lbl, shape=[-1, 1])))
             fluid.Adam(learning_rate=1e-3).minimize(loss)
-        text = _compiled_step_text(
+        return _compiled_step_text(
             main, {"ids": ((b, s), jnp.int32),
                    "lbl": ((b, s, 1), jnp.int32)}, loss, one_v5e)
+
+
+def test_lm_training_step_writes_no_float32_logits(lm_step_text):
+    """The guard for the loss on hard labels: a small language model's
+    AMP training step compiled for the described chip holds no float32
+    array of [tokens, vocab], in either order, flattened or not.  The
+    loss is logsumexp minus the picked logit over the bf16 logits; as
+    log_softmax then take_along_axis it wrote the upcast logits and
+    log_p whole and kept the first for the backward."""
+    b, s, vocab = LM_STEP
     # what the entry computation's instructions write is what lives in
     # HBM; inside a fusion's body a float32 value is registers
-    entry = text[text.index("\nENTRY "):]
+    entry = lm_step_text[lm_step_text.index("\nENTRY "):]
     assert f"bf16[{b},{s},{vocab}]" in entry    # the logits themselves
     wide = [line.split(" = ")[0].strip() for line in entry.splitlines()
             if re.search(rf" = \(?[^=]*f32\[(?:{b},{s},{vocab}|{vocab},{b},{s}"
                          rf"|{b * s},{vocab}|{vocab},{b * s})\]", line)]
     assert not wide, wide
+
+
+def test_lm_training_step_forms_the_loss_gradient_once(lm_step_text):
+    """The guard for the written gradient: in the same compiled step
+    exactly TWO instructions evaluate an exponential over [tokens,
+    vocab], the forward's sum of exponentials and the ONE pass that
+    writes `softmax - onehot`, and neither of the head's gradient
+    products is one of them.  Left to itself the compiler clones that
+    producer into the prologue of each consumer (both products and the
+    bias's reduction: four), where a tile is formed anew for every
+    output tile that reads it.  Counted by the benchmark's own reader
+    (`perf/metrics/train_head_exp_passes.py`)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf", "metrics",
+        "train_head_exp_passes.py")
+    spec = importlib.util.spec_from_file_location("_exp_passes", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    b, s, vocab = LM_STEP
+    passes = reader.exponential_passes(lm_step_text, b * s * vocab)
+    assert len(passes) == 2, passes
+    under_products = [
+        name for name in passes
+        if re.search(rf'%?{re.escape(name)} = [^\n]*op_name="[^"]*mul_grad:',
+                     lm_step_text)]
+    assert not under_products, under_products

@@ -950,3 +950,98 @@ def test_every_new_site_is_the_shared_noop_when_all_is_off(monkeypatch):
     assert list(attribution.phased_iter("trainer", "reader", [1, 2])) == [
         1, 2]
     assert made == [] and tracing.finished_spans() == []
+
+
+# ---------------------------------------------------------------------------
+# the exponentials over [tokens, vocab] a traced training step makes (PR 52:
+# perf/metrics/train_head_exp_passes.py)
+# ---------------------------------------------------------------------------
+
+_HEAD_HLO = """\
+HloModule jit_fn
+
+%fused_computation.sum (p: bf16[8,32]) -> f32[8] {
+  %p = bf16[8,32]{1,0} parameter(0)
+  %e.1 = f32[8,32]{1,0} exponential(%p)
+  ROOT %r = f32[8]{0} reduce(%e.1), dimensions={1}
+}
+
+%fused_computation.d (p: bf16[8,32]) -> bf16[8,32] {
+  %p = bf16[8,32]{1,0} parameter(0)
+  ROOT %e.2 = bf16[8,32]{1,0:T(8,128)(2,1)} exponential(%p)
+}
+
+%fused_computation.product (p: bf16[8,32], w: bf16[32,4]) -> bf16[8,4] {
+  %p = bf16[8,32]{1,0} parameter(0)
+  %w = bf16[32,4]{1,0} parameter(1)
+  %clone.1 = bf16[8,32]{1,0} fusion(%p), kind=kLoop, calls=%fused_computation.d
+  ROOT %dot = bf16[8,4]{1,0} convolution(%clone.1, %w)
+}
+
+%fused_computation.plain (p: bf16[8,32], w: bf16[32,4]) -> bf16[8,4] {
+  %p = bf16[8,32]{1,0} parameter(0)
+  %w = bf16[32,4]{1,0} parameter(1)
+  ROOT %dot.2 = bf16[8,4]{1,0} convolution(%p, %w)
+}
+
+%fused_computation.rows (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %e.3 = f32[8]{0} exponential(%p)
+}
+
+ENTRY %main (a: bf16[8,32], w: bf16[32,4]) -> bf16[8,4] {
+  %a = bf16[8,32]{1,0} parameter(0)
+  %w = bf16[32,4]{1,0} parameter(1)
+  %fusion.5 = f32[8]{0} fusion(%a), kind=kInput, calls=%fused_computation.sum
+  %rows = f32[8]{0} fusion(%fusion.5), kind=kLoop, calls=%fused_computation.rows
+  %written = bf16[8,32]{1,0} fusion(%a), kind=kLoop, calls=%fused_computation.d
+  %product = bf16[8,4]{1,0} fusion(%a, %w), kind=kOutput, calls=%fused_computation.product
+  %plain = bf16[8,4]{1,0} fusion(%written, %w), kind=kOutput, calls=%fused_computation.plain
+  %alone = f32[2,4,32]{2,1,0} exponential(%a)
+  ROOT %untimed = bf16[8,4]{1,0} fusion(%a, %w), kind=kOutput, calls=%fused_computation.product
+}
+"""
+
+
+def test_head_exp_passes_reader_on_hand_made_text(monkeypatch):
+    """`train_head_exp_passes` names the timed instructions that
+    evaluate an exponential over tokens x vocab elements: a fusion
+    whose body holds one, a fusion whose body holds a NESTED fusion
+    that does (a producer cloned into its consumer), an exponential
+    that stands alone; not a product that reads a written operand, not
+    an exponential over the rows alone, not the inner clone itself, and
+    not an instruction the slice did not time.  `None` without a device
+    plane or a registered text."""
+    import os
+    import sys
+    import types
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf")
+    monkeypatch.syspath_prepend(perf)
+    monkeypatch.delitem(sys.modules, "common", raising=False)
+    import common
+
+    reader = common.load_module(os.path.join(
+        perf, "metrics", "train_head_exp_passes.py"))
+    assert reader.exponential_passes(_HEAD_HLO, 8 * 32) == [
+        "fusion.5", "written", "product", "alone", "untimed"]
+    assert reader.exponential_passes(_HEAD_HLO, 8 * 32 + 1) == []
+
+    cell = types.SimpleNamespace(
+        traffic={"sequence_length": 4, "sequences_per_step": 2},
+        config={"vocab_size": 32})
+    timed = {name: 0.01 for name in
+             ("fusion.5", "rows", "written", "product", "plain", "alone")}
+    run = types.SimpleNamespace(cell=cell, trace={"op_seconds": timed})
+    assert reader.compute(run) is None          # no text registered
+    profiler._register_hlo_text("executor.block", lambda: "ENTRY %m {\n}")
+    profiler._register_hlo_text("executor.block", lambda: _HEAD_HLO)
+    profiler._register_hlo_text("paged_decoder.step", lambda: _HEAD_HLO * 2)
+    assert reader.compute(run) == 4             # `untimed` is left out
+    del timed["product"], timed["alone"]
+    assert reader.compute(run) == 2             # the change's reading
+    run.trace = {"op_seconds": {}}
+    assert reader.compute(run) is None          # no device plane
+    run.trace = None
+    assert reader.compute(run) is None
