@@ -80,6 +80,19 @@ position's one row is written inside as K and V are.  At 128 heads a
 row's two products are 240 operations a byte: the one kernel here that
 the MXU can pace (PERF.md section 6, PR 45).
 
+A SELECTION (`select=`, a latent pool's: the rows a lightning indexer
+chose, `lm_block.select_rows`) rides the same kernel as a row mask a
+slot, [S, rows of the table], brought into VMEM a slot a grid step and
+read a chunk at a time beside the cursor's own mask: the pages are
+those the cursor has reached, the softmax is over the selected rows
+alone.  A list of rows cannot be copied row by row: a DMA moves whole
+sublane tiles (16 rows of bfloat16: a page of the cells' tables), so
+the page is the smallest unit a selection can leave unread, and while
+a cursor is a few times the rows selected nearly every page holds one.
+A chunk in which nothing is selected leaves the running maximum at
+minus infinity, so under a selection the exponentials are taken against
+a finite stand-in there.
+
 The call sits behind one module-level `jax.jit` (`paged_attention`),
 the layer a TRACED scalar: the body is traced once a process for a set
 of shapes and lowered once a program however many layers call it (an
@@ -204,7 +217,7 @@ def dma_ops(n_pages, pages: int):
 
 
 def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
-            windows, scale, h, dh, n_kv, writes, d_value=0):
+            windows, scale, h, dh, n_kv, writes, d_value=0, selects=False):
     """Grid step s: slot s's attention over its first
     `ceil(lengths[s] / bs)` pages of layer `layer[0]`, copied a chunk
     of `pages` pages at a time and multiplied over the smallest of
@@ -212,7 +225,10 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     `cursor_ref[0]` is the buffer (0 or 1) that holds this slot's
     first chunk, started by the step before.  `d_value`: a latent
     pool, ONE array whose first `d_value` columns are the value (0: a
-    K pool and a V pool)."""
+    K pool and a V pool).  `selects`: a row mask a slot follows the
+    query ([chunks, 1, rows a chunk] float32, 1 where the row is
+    selected: a chunk's mask a tile of its own, so that the chunk
+    indexes an untiled axis)."""
     n_pools = 1 if d_value else 2
     refs = iter(refs)
 
@@ -223,6 +239,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     # new rows (a pool each) only where the kernel writes
     wrow_ref = next(refs) if writes else None
     q_ref = next(refs)
+    sel_ref = next(refs) if selects else None
     new_refs = take(n_pools if writes else 0)
     hbms, (o_ref,) = take(n_pools), take(1)
     outs = take(n_pools if writes else 0)
@@ -390,10 +407,16 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
                     q, k_buf[buf, :n_rows], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
-                sc = jnp.where(row < length, sc, -jnp.inf)
+                seen = row < length
+                if selects:
+                    seen &= sel_ref[0, c, :, :n_rows] > 0.0
+                sc = jnp.where(seen, sc, -jnp.inf)
                 m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(sc - m_new)
+                # nothing selected so far: exp(-inf - -inf) is no number
+                m_ref = (jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                         if selects else m_new)
+                alpha = jnp.exp(m - m_ref)
+                p = jnp.exp(sc - m_ref)
                 v = (v_buf[buf, :n_rows, :d_value] if d_value
                      else v_buf[buf, :n_rows])
                 acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
@@ -438,7 +461,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
 def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
                     scale: float, pages: int, tile: int, n_heads: int,
                     d_head: int, d_value: int = 0,
-                    interpret: bool = False, write=None):
+                    interpret: bool = False, write=None, select=None):
     """Attention of one query position a slot over a paged pool.
 
     q [S, H*dh] (the projection's rows; cast to the pools' dtype: what
@@ -463,7 +486,10 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
     read).  `pool_k`'s row is then what every head attends over, q
     [S, H*Dkv] a whole row a head, the result [S, H*d_value] float32:
     head i's `softmax(scale * q_i . rows^T) . rows[:, :d_value]`;
-    `write` = (row [S, Dkv], None, rows) and (out, pool) comes back."""
+    `write` = (row [S, Dkv], None, rows) and (out, pool) comes back.
+    `select` [S, NB * block_size] bool: the rows of its table, in table
+    order, that slot s attends over of those under its length (at least
+    one of them: a slot with none divides by zero)."""
     s_n, h = q.shape[0], n_heads
     bs, nb, d_kv = pool_k.shape[2], tables.shape[1], pool_k.shape[3]
     pools = (pool_k,) if d_value else (pool_k, pool_v)
@@ -494,6 +520,15 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
                            jnp.float32),
                 pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32)]
+    if select is not None:
+        # a slot's mask by chunk: [chunks, 1, rows a chunk], the last
+        # chunk's rows past the table unselected
+        n_chunks = -(-nb // pages)
+        mask = jnp.pad(select.astype(jnp.float32),
+                       ((0, 0), (0, (n_chunks * pages - nb) * bs)))
+        inputs.append(mask.reshape(s_n, n_chunks, 1, pages * bs))
+        in_specs.append(pl.BlockSpec((1, n_chunks, 1, pages * bs),
+                                     lambda s, *_: (s, 0, 0, 0)))
     aliases = {}
     if write is not None:
         *news, rows = write
@@ -511,7 +546,8 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
         functools.partial(_kernel, bs=bs, nb=nb, pages=pages,
                           windows=_windows(pages, tile), scale=scale,
                           h=h, dh=dh, n_kv=n_kv,
-                          writes=write is not None, d_value=d_value),
+                          writes=write is not None, d_value=d_value,
+                          selects=select is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(s_n,),
             in_specs=in_specs + [hbm() for _ in pools],
@@ -544,7 +580,7 @@ def select_paged_attention(
     pages: `attend.tiling(table_pages)` says which.  With
     `value_width` the pool is a LATENT one (`paged_attention`): one
     array of rows `kv_width` wide, `pool_v` None and `write`'s V
-    None."""
+    None; `select` is `paged_attention`'s."""
     reason = paged_attention_supports(
         d_model=d_model, block_size=block_size, kv_dtype=kv_dtype,
         platform=platform, interpret=interpret, kv_width=kv_width,
@@ -564,7 +600,7 @@ def select_paged_attention(
         return pages, min(row_tile, pages)
 
     def attend(q, pool_k, pool_v, tables, lengths, layer, scale,
-               write=None):
+               write=None, select=None):
         pages, tile = tiling(tables.shape[1])
         return paged_attention(
             q, pool_k, pool_v, tables, lengths, layer,
@@ -572,7 +608,7 @@ def select_paged_attention(
             n_heads=int(n_heads),
             d_head=int(d_head or d_model // n_heads),
             d_value=int(value_width or 0), interpret=interpret,
-            write=write)
+            write=write, select=select)
 
     attend.tiling = tiling
     return attend, None

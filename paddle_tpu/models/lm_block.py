@@ -126,6 +126,29 @@ architecture is a second description and not a second decoder.
                `scale_kv_lora`: sqrt(d_model / rank)).  The latent
                cache and the held experts are the seventh's.
 
+  selected-    the ninth (Zhipu GLM-5.2, `model_type: glm_moe_dsa`, whose
+  latent-like  sparse attention is DeepSeek-V3.2's), fields again: a
+               LIGHTNING INDEXER beside the latent attention
+               (`index_topk` > 0).  On a layer whose `indexer_types`
+               entry is "full" the normed query latent goes up to
+               `index_n_heads` index queries of `index_head_dim`
+               columns, the block's normed input down to ONE index key
+               a position (a LayerNorm with a shift over it; RoPE on
+               the first `qk_rope_head_dim` columns of both) and to a
+               weight a head; the index score of a query position
+               against an earlier one is the head-weighted sum of
+               relu(query . key), and attention on that layer is the
+               softmax over the `index_topk` positions of largest
+               score alone (all of them while there are fewer; a tie
+               to the lower position).  The index key is a SECOND kind
+               of cache row, a plane a "full" layer on the latent
+               rows' own block table.  A "shared" layer has no indexer
+               and attends over the rows the nearest earlier "full"
+               layer selected in the same step.  A value head
+               (`v_head_dim`) may be wider than a key's unrotated
+               part.  The router is the sixth's, dense layers and the
+               held experts the seventh's.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -143,13 +166,17 @@ from typing import Dict, Tuple
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
            "swiglu", "mamba2_step", "MOE_COMPILER_SCOPES", "SLIDING",
-           "FULL", "MAMBA", "ATTENTION", "DENSE", "SPARSE"]
+           "FULL", "MAMBA", "ATTENTION", "DENSE", "SPARSE", "INDEX_FULL",
+           "INDEX_SHARED", "select_rows"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # an FFN kind a layer (`mlp_layer_types`)
 DENSE, SPARSE = "dense", "sparse"
 # Granite's names: a layer with no attention, and full attention
 MAMBA, ATTENTION = "mamba", "attention"
+# an indexer kind a layer (`indexer_types`): a layer that computes a
+# selection, and one that reuses the nearest earlier one's
+INDEX_FULL, INDEX_SHARED = "full", "shared"
 
 
 def _frozen(value):
@@ -258,10 +285,19 @@ class BlockSpec:
     #    zero_experts) have no matrices; an assignment to one adds its
     #    weight times the expert layer's input
     zero_experts: int = 0
+    # -- a LIGHTNING INDEXER (`index_topk` > 0): on an INDEX_FULL layer
+    #    `index_n_heads` index queries of `index_head_dim` columns score
+    #    ONE cached index key a position, and attention is over the
+    #    `index_topk` positions of largest score; an INDEX_SHARED layer
+    #    attends over the nearest earlier INDEX_FULL layer's selection
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    indexer_types: tuple = ()       # a kind a layer; (): all INDEX_FULL
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
-                     "rope_layers"):
+                     "rope_layers", "indexer_types"):
             object.__setattr__(self, name, _frozen(getattr(self, name)))
         bad = set(self.mlp_layer_types) - {DENSE, SPARSE}
         if bad:
@@ -323,6 +359,27 @@ class BlockSpec:
                 "'moe_swiglu') whose weights are not renormalised "
                 "(norm_topk_prob: over which of them?) and whose choice "
                 "is not group-limited (n_group: they lie in no group)")
+        if set(self.indexer_types) - {INDEX_FULL, INDEX_SHARED}:
+            raise ValueError(f"indexer_types {self.indexer_types}: of "
+                             f"{INDEX_FULL!r} and {INDEX_SHARED!r}")
+        if self.index_topk < 0 or (self.index_topk and (
+                self.kv_lora_rank < 1 or self.sub_blocks != 1
+                or self.index_n_heads < 1
+                or self.index_head_dim < self.qk_rope_head_dim
+                or self.indexer_types[:1] == (INDEX_SHARED,))):
+            raise NotImplementedError(
+                f"block {self.name!r}: a lightning indexer (index_topk) "
+                "selects rows of a LATENT cache (kv_lora_rank) of single "
+                "layers (sub_blocks 1) and needs index_n_heads and an "
+                "index_head_dim of at least qk_rope_head_dim (RoPE turns "
+                "that many of its columns); the first layer computes a "
+                "selection (indexer_types starts 'full')")
+        if (self.index_n_heads or self.index_head_dim
+                or self.indexer_types) and not self.index_topk:
+            raise ValueError(
+                f"block {self.name!r}: index_n_heads, index_head_dim and "
+                "indexer_types describe a lightning indexer, and "
+                "index_topk is 0")
         if (self.scale_q_lora or self.scale_kv_lora) and (
                 self.kv_lora_rank < 1):
             raise ValueError(
@@ -347,6 +404,23 @@ class BlockSpec:
         """Whether attention keeps a latent row a position (module
         docstring, the seventh description)."""
         return self.kv_lora_rank > 0
+
+    @property
+    def sparse(self) -> bool:
+        """Whether attention is over rows a lightning indexer selects
+        (module docstring, the ninth description)."""
+        return self.index_topk > 0
+
+    def indexer_of(self, layer: int) -> str:
+        """Layer `layer`'s indexer kind, INDEX_FULL or INDEX_SHARED; a
+        description without `indexer_types` is all INDEX_FULL."""
+        if not self.indexer_types:
+            return INDEX_FULL
+        if layer >= len(self.indexer_types):
+            raise ValueError(
+                f"block {self.name!r}: {len(self.indexer_types)} "
+                f"indexer_types, and a layer {layer}")
+        return self.indexer_types[layer]
 
     def heads(self, d_model: int, n_heads: int):
         """(K/V heads, head size) at a model width and query heads."""
@@ -433,7 +507,11 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     kv_lora_rank + rope] (the latent, then the one key part),
     `kv_a_norm` [kv_lora_rank], `kv_b` [kv_lora_rank, H * (nope +
     v_head_dim)] (a head's key columns, then its value columns) and
-    `o` [H * v_head_dim, d].  The expert matrices
+    `o` [H * v_head_dim, d].  With a lightning indexer an INDEX_FULL
+    layer has four more: `idx_q` [q_lora_rank, index_n_heads *
+    index_head_dim], `idx_k` [d, index_head_dim], its LayerNorm
+    `idx_k_norm` (a scale and a shift of index_head_dim) and `idx_w`
+    [d, index_n_heads]; an INDEX_SHARED layer none.  The expert matrices
     are [experts HELD, ...]; the router keeps its published width.  A
     DENSE layer among sparse ones (`mlp_layer_types`) has the dense
     block's three matrices at `dense_d_inner` and no "router" key:
@@ -607,6 +685,16 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
         elif spec.latent:
             lay = latent_arrays(p)
+            if spec.sparse and spec.indexer_of(l) == INDEX_FULL:
+                hi, di = spec.index_n_heads, spec.index_head_dim
+                lay.update({
+                    "idx_q": add(p + "indexer_q.w_0", spec.q_lora_rank,
+                                 hi * di),
+                    "idx_k": add(p + "indexer_k.w_0", d, di),
+                    "idx_k_norm": (
+                        add(p + "indexer_k_norm.scale_0", di)[0],
+                        add(p + "indexer_k_norm.shift_0", di)[0]),
+                    "idx_w": add(p + "indexer_w.w_0", d, hi)})
         else:
             lay = {"norm1": add(p + "attn_norm.scale_0", d),
                    "q": add(p + "q_proj.w_0", d, dq),
@@ -786,6 +874,43 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     if spec.routed_scaling_factor != 1.0:
         top_w = top_w * spec.routed_scaling_factor
     return top_w, top_e
+
+
+def select_rows(scores, valid, k: int):
+    """The lightning indexer's choice: scores [..., R] float32 and
+    valid [..., R] bool -> bool [..., R], the `k` valid rows of largest
+    score (every valid row where there are `k` or fewer), a tie at the
+    k-th score to the LOWER row.  Exact and without a sort: a float32's
+    bits, turned so that unsigned order is the floats' order, are
+    searched a bit at a time for the largest key that `k` rows reach
+    (32 counts over the rows), which is the k-th largest score; the
+    rows above it are taken, and of the rows AT it the lowest until
+    there are `k`.  An invalid row's key is 0, under every score's
+    (a NaN score is refused nowhere: the step's are finite)."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(
+        scores.astype(jnp.float32) + 0.0, jnp.uint32)    # -0.0 -> +0.0
+    top = jnp.uint32(1 << 31)
+    keys = jnp.where(bits >= top, ~bits, bits | top)
+    keys = jnp.where(valid, jnp.maximum(keys, jnp.uint32(1)),
+                     jnp.uint32(0))
+
+    def bit(i, kth):
+        cand = kth | (top >> i.astype(jnp.uint32))
+        reach = jnp.sum(keys >= cand[..., None], axis=-1,
+                        dtype=jnp.int32) >= k
+        return jnp.where(reach, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(keys.shape[:-1], jnp.uint32))
+    kth = kth[..., None]
+    above = keys > kth
+    tied = (keys == kth) & valid
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                            <= room))
 
 
 def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,

@@ -526,6 +526,16 @@ _REFUSALS = {
             "whose XLA gather path knows a K pool and a V pool and is not "
             "built for one latent row a position "
             "(build_lm_paged_decoder's step_window refuses it too)")},
+    # a cached block holds every layer's latent rows AND every index-key
+    # plane's keys, on the one table: the prefix cache works
+    "sparse": {
+        "draft_model": (
+            "a decoder with a lightning indexer takes no draft model: "
+            "speculative verification runs a window of positions through "
+            "step_window, and a selection is computed for ONE query position "
+            "a lane a step (a chunk of positions would want a selection a "
+            "position and the expanded form over each; "
+            "build_lm_paged_decoder's step_window refuses it too)")},
     # a cached block holds every pass's K/V: the prefix cache works
     "loop": {
         "draft_model": (
@@ -596,6 +606,10 @@ class PagedDecoder:
     # for every pass of a looped stack), on a slot's ring
     table_layers: int
     ring_layers: int
+    # a lightning indexer's key planes: the layers that compute a
+    # selection, each with an index key a position on the table's
+    # blocks (0: no indexer)
+    index_planes: int
     # the Mamba layers' recurrent state: how many layers keep one (0:
     # none) and the float32 bytes a lane holds over them
     state_layers: int
@@ -792,6 +806,26 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     assignments to identity experts a layer, and `moe_assignments`,
     all of their assignments.
 
+    A SELECTED latent (`BlockSpec.index_topk` > 0, lm_block's ninth
+    description; docs/serving.md "A selected latent") keeps a SECOND
+    kind of cache row on the latent rows' own table: `pool_v` is then
+    the INDEX-KEY pool [layers that compute a selection, blocks,
+    block_size, index_head_dim] (`decoder.index_planes`; a block id
+    names a block of both pools, so a prefix-cache block holds a
+    position's latent rows and its index keys, and `bytes_per_block`
+    counts both).  A layer whose `indexer_types` entry is "full" writes
+    this position's index key, scores every row under the cursor
+    against the position's index queries and selects `index_topk` of
+    them (`lm_block.select_rows`); the selection, a row mask a lane
+    [S, rows of the table], is carried inside the step to the "shared"
+    layers after it; every layer attends over the selected rows alone.
+    `step_routing` also returns what each selecting layer was given
+    and chose: "index_inputs" [F, S, D] (the block's normed input),
+    "index_latents" [F, S, q_lora_rank] (the normed query latent),
+    "index_scores" [F, S, rows] float32 and "selected" [F, S, rows]
+    bool.  `step_window`, a draft model and an int8 pool are refused
+    by name.
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -862,6 +896,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 "block) is not built for a latent row (one scale over a "
                 "normed latent and a rotated key part of other ranges); "
                 "kv_dtype fp32 or bf16")
+    # A lightning indexer: the layers that compute a selection keep an
+    # index key a position in a pool of their own, a plane each
+    sparse = spec.sparse
+    index_of = ([spec.indexer_of(l) for l in range(n_layers)]
+                if sparse else [])
+    index_plane = [index_of[:l].count(lm_block.INDEX_FULL)
+                   for l in range(len(index_of))]
+    n_index = index_of.count(lm_block.INDEX_FULL)
+    d_idx, h_idx = spec.index_head_dim, spec.index_n_heads
     # a kind for every entry of `layout.layers`: a layer, or each of a
     # double layer's two sub-blocks (a cache plane each)
     subs = spec.sub_blocks
@@ -1073,22 +1116,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         """`kv_b` by head: [latent, H, key columns | value columns]."""
         return g[lay["kv_b"][0]].reshape(d_lat, n_heads, d_nope + d_v)
 
-    def _latent_qkv(g, lay, x, rot):
-        """The latent block's `_qkv`: -> (the ABSORBED query [S, W, H *
-        row]: a head's unrotated part times the key half of `kv_b`, so
-        that it meets the latent itself, then its rotated part, then
-        the row's zero pad; this position's row [S, W, row]: the normed
-        latent, the one rotated key part, the pad; None: there is no V).
-        Each normed latent takes its constant where the description
-        has one (`scale_q_lora`, `scale_kv_lora`).
-        The norms are float32; the rotation is of `qk_rope_head_dim`
-        columns, a head's of the query and the one of the key."""
-        lead = x.shape[:-1]
+    def _latent_down(g, lay, x):
+        """The block's normed input and the normed query latent (times
+        its constant where the description has one, `scale_q_lora`):
+        what `_latent_qkv` and a lightning indexer both read."""
         with scope("latent_q"):
             h = _norm(g, x, lay["norm1"])
             c_q = _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"])
             if spec.scale_q_lora:
                 c_q = c_q * math.sqrt(d_model / spec.q_lora_rank)
+            return h, c_q
+
+    def _latent_qkv(g, lay, h, c_q, rot):
+        """The latent block's `_qkv`, from `_latent_down`'s two: -> (the
+        ABSORBED query [S, W, H * row]: a head's unrotated part times
+        the key half of `kv_b`, so that it meets the latent itself, then
+        its rotated part, then the row's zero pad; this position's row
+        [S, W, row]: the normed latent (times its constant,
+        `scale_kv_lora`), the one rotated key part, the pad; None: there
+        is no V).  The norms are float32; the rotation is of
+        `qk_rope_head_dim` columns, a head's of the query and the one of
+        the key."""
+        lead = h.shape[:-1]
+        with scope("latent_q"):
             q = _fc(g, c_q, lay["q_b"]).reshape(lead + (n_heads, d_head))
             q_nope, q_pe = q[..., :d_nope], q[..., d_nope:]
             q_pe = lm_block.rope(q_pe.reshape(lead + (-1,)), *rot,
@@ -1122,6 +1172,58 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 ctx.reshape(ctx.shape[:-1] + (n_heads, d_lat)),
                 _kv_b(g, lay)[..., d_nope:])
             return out.reshape(ctx.shape[:-1] + (n_heads * d_v,))
+
+    def _indexer(g, lay, h, c_q, rot, pool_i, plane, tables, wb, wi,
+                 positions, active):
+        """The lightning indexer of a selecting layer, one query
+        position a lane: from the block's normed input h [S, D] and the
+        normed query latent c_q [S, q_lora_rank] -> (the selection
+        [S, rows] bool over the rows of the lane's table, the index
+        scores [S, rows] float32, the index-key pool with this
+        position's key written at (block wb, offset wi) of plane
+        `plane`).  The key is ONE row for all index heads (a LayerNorm
+        with a shift over it), RoPE turns the first `qk_rope_head_dim`
+        columns of the key and of every index query, and the score of
+        row r is sum_j w_j relu(q_j . k_r) with w = h W_w / sqrt(heads
+        x head size): products of the pool's dtype accumulated in
+        float32, the rest float32.  The rows are read through the
+        table in logical order (the XLA gather: an index-key row is a
+        fifth of a latent one), those past the cursor score nothing."""
+        s_n = h.shape[0]
+
+        def turned(t, n):
+            """RoPE on the first `d_pe` columns of each of t's n heads."""
+            th = t.reshape(s_n, n, d_idx)
+            first = lm_block.rope(th[..., :d_pe].reshape(s_n, n * d_pe),
+                                  *rot, n).reshape(s_n, n, d_pe)
+            return jnp.concatenate([first, th[..., d_pe:]], axis=-1)
+
+        with scope("indexer_q"):
+            q_i = turned(_fc(g, c_q, lay["idx_q"]), h_idx)  # [S, Hi, di]
+            w_i = _fc(g, h, lay["idx_w"]).astype(jnp.float32) * (
+                1.0 / math.sqrt(h_idx * d_idx))
+        with scope("indexer_k"):
+            k_i = _fc(g, h, lay["idx_k"]).astype(jnp.float32)
+            mu = k_i.mean(-1, keepdims=True)
+            var = ((k_i - mu) ** 2).mean(-1, keepdims=True)
+            scale_, shift_ = (g[n].astype(jnp.float32)
+                              for n in lay["idx_k_norm"])
+            k_i = (k_i - mu) / jnp.sqrt(var + 1e-6) * scale_ + shift_
+            k_i = turned(k_i, 1)[:, 0]
+            pool_i = _write(pool_i, plane, wb, wi, k_i)
+        with scope("indexer_scores"):
+            keys = pool_i[plane, tables].reshape(s_n, nb * bs, d_idx)
+            dots = jax.lax.dot_general(
+                q_i.astype(keys.dtype), keys, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)       # [S, Hi, rows]
+            scores = (jax.nn.relu(dots) * w_i[:, :, None]).sum(axis=1)
+        with scope("indexer_topk"):
+            # a lane with no sequence sees row 0 of the null block
+            cur = jnp.where(active, positions, 0)
+            valid = jnp.arange(nb * bs)[None, :] <= cur[:, None]
+            scores = jnp.where(valid, scores, -jnp.inf)
+            chosen = lm_block.select_rows(scores, valid, spec.index_topk)
+        return chosen, scores, pool_i
 
     def _post_join(g, x, y, pair):
         """x + norm(y): a sub-block's output joins the residual stream
@@ -1335,7 +1437,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         return kept.reshape(s_n, w_n, n_heads, n_kv, d_head).sum(
             axis=3).reshape(s_n, w_n, n_heads * d_head)
 
-    def _attention(q, pool_k, pool_v, l, tables, pos_mask, kind):
+    def _attention(q, pool_k, pool_v, l, tables, pos_mask, kind,
+                   selected=False):
         """Attention of q [S, W, H*dh] over layer `l` of the paged
         pools -> [S, W, H*dh]; pos_mask [S, W, rows] says which rows
         of the table (logical positions; ring slots on a sliding
@@ -1354,14 +1457,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         or V to [.., heads, d_head] (on a TPU a relayout of the whole
         gathered view into half-empty lane tiles) nor widens them to
         float32.  Scores, mask, softmax and both accumulations are
-        float32."""
+        float32.  `selected`: the mask is a lightning indexer's
+        selection under the cursor, and the products lie one scope down
+        (`attention/selected`)."""
         w_n = q.shape[1]
         k, k_scale = _gather(pool_k, l, tables, kind)
         batched = ((0,), (0,))
         if latent:
             # one row for every head: the query is dense over it, the
             # value its latent columns, the context [S, W, H * latent]
-            with _kind_scope("attention", kind):
+            with _kind_scope("attention", kind), (
+                    scope("selected") if selected
+                    else contextlib.nullcontext()):
                 sc = jax.lax.dot_general(
                     q.reshape(q.shape[0], w_n * n_heads, d_kv), k,
                     (((2,), (2,)), batched),
@@ -1390,7 +1497,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 preferred_element_type=jnp.float32)
             return _own_columns(ctx, w_n)
 
-    def _streamed(q, kk, vv, pool_k, pool_v, l, tables, cursor, kind):
+    def _streamed(q, kk, vv, pool_k, pool_v, l, tables, cursor, kind,
+                  select=None):
         """`_write` and `_attention` of one position a slot, q
         [S, H*dh], through the Pallas kernel -> (context, pools):
         `cursor` is (the row of its table, its ring on a sliding
@@ -1403,9 +1511,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         `_attention`'s (the kernel builds `_block_diagonal`'s operand
         and keeps `_own_columns`' columns itself, in VMEM), an online
         softmax over chunks of pages in place of one softmax over the
-        table."""
+        table.  `select`: a lightning indexer's selection [S, rows]:
+        the softmax is over those of the cursor's rows alone, under a
+        scope of its own."""
         row, lengths = cursor
         with _kind_scope("attention", kind):
+            if select is not None:
+                with scope("selected"):
+                    out = _attend(q, pool_k, None, tables, lengths, l,
+                                  scale, write=(kk, vv, row),
+                                  select=select)
+                return out + (pool_v,)
             out = _attend(q, pool_k, pool_v, tables, lengths, l, scale,
                           write=(kk, vv, row))
             # a latent pool is one array: the V pool is the empty
@@ -1472,7 +1588,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                      active):
         s_n = tokens.shape[0]
         lane = jnp.arange(s_n)
-        hits, scans = [], []
+        hits, scans, picks = [], [], []
         pools_k, pools_v = _by_kind(pool_k), _by_kind(pool_v)
         # only a ring brings a second table
         tabs = _by_kind(tables) if ringed else {lm_block.FULL: tables}
@@ -1501,7 +1617,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             layer's two sub-blocks are two turns of the loop, the
             expert layer's result `held` from the first to the second."""
             nonlocal sub_traced
-            held = None
+            held = chosen = None
             for lay, kind, li in zip(layout.layers, kinds, pool_index):
                 sub_traced = li % subs if subs > 1 else None
                 if kind == lm_block.MAMBA:
@@ -1513,14 +1629,26 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     scans.append(given)
                     x = _ffn(g, lay, x, hits)
                     continue
-                q, kk, vv = (_latent_qkv if latent else _qkv)(
-                    g, lay, x, rot[kind])
+                if latent:
+                    h, c_q = _latent_down(g, lay, x)
+                    q, kk, vv = _latent_qkv(g, lay, h, c_q, rot[kind])
+                else:
+                    q, kk, vv = _qkv(g, lay, x, rot[kind])
                 wb, seen = cursor[kind]
                 plane = li if plane0 is None else plane0 + li
+                if "idx_q" in lay:
+                    # a selecting layer: this position's index key into
+                    # its plane (the V pool's place), the selection the
+                    # layers up to the next selecting one attend over
+                    chosen, scores, pools_v[kind] = _indexer(
+                        g, lay, h, c_q, rot[kind], pools_v[kind],
+                        index_plane[li], tabs[kind], wb, wi, positions,
+                        active)
+                    picks.append((h, c_q, scores, chosen))
                 if _attend is not None:
                     ctx_av, pools_k[kind], pools_v[kind] = _streamed(
                         q, kk, vv, pools_k[kind], pools_v[kind], plane,
-                        tabs[kind], seen, kind)
+                        tabs[kind], seen, kind, select=chosen)
                 else:
                     with scope("kv_write"):
                         pools_k[kind] = _write(pools_k[kind], plane, wb,
@@ -1528,9 +1656,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         if not latent:
                             pools_v[kind] = _write(pools_v[kind], plane,
                                                    wb, wi, vv)
+                    if chosen is not None:
+                        seen = seen & chosen
                     ctx_av = _attention(
                         q[:, None, :], pools_k[kind], pools_v[kind],
-                        plane, tabs[kind], seen[:, None, :], kind)[:, 0]
+                        plane, tabs[kind], seen[:, None, :], kind,
+                        selected=chosen is not None)[:, 0]
                 if latent:
                     ctx_av = _latent_values(g, lay, ctx_av)
                 with scope("attn_out"):
@@ -1549,7 +1680,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if not looped:
             x = stack(x, pools_k, pools_v)
             return (_head(g, x), _joined(pools_k), _joined(pools_v),
-                    hits, scans)                              # [S, V]
+                    hits, picks if sparse else scans)         # [S, V]
 
         def one_pass(carry, t):
             """Pass t of the stack: ONE body in the program however
@@ -1628,7 +1759,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
             out = {"inputs": inputs, "weights": weights,
                    "experts": experts}
-            if scans:
+            if sparse:
+                # what each selecting layer was given and chose
+                for i, name in enumerate(("index_inputs", "index_latents",
+                                          "index_scores", "selected")):
+                    out[name] = jnp.stack([p[i] for p in scans])
+            elif scans:
                 out["ssm_inputs"] = jnp.stack(scans)
             return logits, out
 
@@ -1738,6 +1874,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # (a latent block's one array holds keys and values at once)
     bytes_per_block = int((1 if latent else 2) * planes * bs * d_kv
                           * elem_bytes)
+    # and an index key a position on each selecting layer's plane
+    bytes_per_block += int(n_index * bs * d_idx * elem_bytes)
     window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
     # a lane's recurrent state over the Mamba layers: the SSM state
     # and the convolution tail, both float32
@@ -1758,9 +1896,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         is the pair (the attention layers' pool, one float32 array a
         Mamba layer: `lanes` SSM states beside K, `lanes` convolution
         tails beside V); `lanes` is the step's lane count and read by
-        no other block.  A latent block's is (the one pool, ())."""
-        def zeros(layers, blocks):
-            shape = (layers, int(blocks), bs, d_kv)
+        no other block.  A latent block's is (the one pool, ()), with a
+        lightning indexer (the latent pool, the index-key pool)."""
+        def zeros(layers, blocks, width=d_kv):
+            shape = (layers, int(blocks), bs, width)
             if kv_dtype == "int8":
                 z = (jnp.zeros(shape, jnp.int8),
                      jnp.full(shape[:2], 1e-8, jnp.float32))
@@ -1783,6 +1922,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             return ((zeros(n_full, num_blocks), lane_state(state_shape)),
                     (zeros(n_full, num_blocks), lane_state(tail_shape)))
 
+        if sparse:
+            return (zeros(planes, num_blocks),
+                    zeros(n_index, num_blocks, d_idx))
         if latent:
             return zeros(planes, num_blocks), ()
 
@@ -1819,7 +1961,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     parts = {"q": "qkv", "k": "qkv", "v": "qkv", "o": "attn_out",
              "w1": "mlp", "w2": "mlp", "q_a": "latent_q",
              "q_b": "latent_q", "kv_a": "latent_kv",
-             "kv_b": "latent_absorb", "dense_gate": "dense_ffn",
+             "kv_b": "latent_absorb", "idx_q": "indexer_q",
+             "idx_w": "indexer_q", "idx_k": "indexer_k",
+             "dense_gate": "dense_ffn",
              "dense_up": "dense_ffn", "dense_down": "dense_ffn"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
@@ -1870,8 +2014,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
         the planes the pages are counted over (`kv_planes` with double
         layers too: two a layer).  With a latent cache `latent_rows`:
-        the rows the lanes with a sequence attend over (cursor + 1),
-        summed over them and the planes."""
+        the rows the lanes with a sequence hold under their cursors
+        (cursor + 1), summed over them and the planes.  With a lightning
+        indexer `index_planes`, `kv_rows_indexed` (the rows its selecting
+        layers score: cursor + 1 a lane a plane) and `kv_rows_selected`
+        (the rows attention is over: `index_topk` at most of cursor + 1,
+        a lane a latent plane)."""
         n = len(cursors)
         counts = {}
         if passes > 1:
@@ -1908,6 +2056,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["kv_rows_win"] = int(np.minimum(rows, window).sum())
         if latent:
             counts["latent_rows"] = planes * int(rows.sum())
+        if sparse:
+            counts["index_planes"] = n_index
+            counts["kv_rows_indexed"] = n_index * int(rows.sum())
+            counts["kv_rows_selected"] = planes * int(
+                np.minimum(rows, spec.index_topk).sum())
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))
@@ -1920,10 +2073,26 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
     # where a block has two kinds of state, the ring's word stands
     refuses = {}
-    for kind, has in (("latent", latent), ("loop", looped),
-                      ("state", stateful), ("ring", ringed)):
+    for kind, has in (("latent", latent), ("sparse", sparse),
+                      ("loop", looped), ("state", stateful),
+                      ("ring", ringed)):
         if has:
             refuses.update(_REFUSALS[kind])
+
+    kernels = {"paged_attention_decode":
+               f"xla:{_refused}" if _attend is None
+               else "pallas:latent" if latent else "pallas",
+               "paged_attention_window": f"xla:{_refused or 'window_rows'}"}
+    if sparse:
+        # how the selected rows are read: by page under a row mask (a
+        # DMA moves whole sublane tiles, so a row list is read as the
+        # pages that hold it), or the gather under the mask; the index
+        # keys through the table in logical order, always
+        kernels.update(
+            paged_attention_selected=(
+                f"xla:{_refused}:masked_gather" if _attend is None
+                else "pallas:latent:masked_pages"),
+            lightning_indexer="xla:table_gather")
 
     decoder = PagedDecoder(
         step=step, step_window=step_window, step_logits=step_logits,
@@ -1948,13 +2117,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         bytes_per_block=bytes_per_block,
         window_blocks_per_seq=nw, window=window,
         window_bytes_per_block=window_bytes_per_block,
-        table_layers=planes, ring_layers=n_win,
+        table_layers=planes, ring_layers=n_win, index_planes=n_index,
         state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
-        kernels={"paged_attention_decode":
-                 f"xla:{_refused}" if _attend is None
-                 else "pallas:latent" if latent else "pallas",
-                 "paged_attention_window":
-                 f"xla:{_refused or 'window_rows'}"},
+        kernels=kernels,
         attention_tiling=tiling, tick_counts=tick_counts, refuses=refuses)
     return startup, decoder
 

@@ -1265,7 +1265,8 @@ class GenerationServer:
             "serving.request", seq.t_submit_wall, now - seq.t_submit,
             parent=seq.trace_ctx, server=self._sid,
             tokens=seq.emitted, prompt_tokens=seq.prompt_len,
-            cached_tokens=seq.cached, queue_s=t_admit - seq.t_submit,
+            cached_tokens=seq.cached, prefix_hit_tokens=seq.cached,
+            queue_s=t_admit - seq.t_submit,
             prefill_s=t_first - t_admit, decode_s=now - t_first,
             **attrs)
 

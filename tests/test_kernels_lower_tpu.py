@@ -57,6 +57,10 @@ POOLS = {
     "deepseek-v2-agent64-latent": (64, 128, 640, 16, 256, 5, "bf16"),
     # the same row under 64 heads, on the 8 planes of 4 double layers
     "longcat-flash-agent64-latent": (64, 64, 640, 16, 256, 8, "bf16"),
+    # the same row again under a lightning indexer's SELECTION (a row
+    # mask a slot over the table's 432 pages: 2048 rows selected), 64
+    # heads whose value is the latent's 512 columns, 5 planes
+    "glm-5.2-docqa64-selected": (64, 64, 640, 16, 432, 5, "bf16"),
     # a K and a V pool in chunks of 51 pages too: the one chunk length
     # of the cells that is no power of two, so its waits on summed
     # bytes take four sizes and its issue loop a remainder (a row of 5
@@ -65,7 +69,10 @@ POOLS = {
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512,
-           "longcat-flash-agent64-latent": 512}
+           "longcat-flash-agent64-latent": 512,
+           "glm-5.2-docqa64-selected": 512}
+# rows a slot's mask selects (the indexer's `index_topk`)
+SELECTED = {"glm-5.2-docqa64-selected": 2048}
 # pages a chunk over each pool: what the waits' static list and the
 # issue loop's groups follow
 CHUNK_PAGES = {
@@ -74,7 +81,7 @@ CHUNK_PAGES = {
     "mellum2-agent96-ring": 64, "granite-chat64": 32, "ouro-chat12": 16,
     "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
     "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
-    "longcat-flash-agent64-latent": 51,
+    "longcat-flash-agent64-latent": 51, "glm-5.2-docqa64-selected": 51,
 }
 
 
@@ -109,9 +116,13 @@ def _paged(name, sharding=None):
         # the one pool from layer to layer, a head's query a whole row
         # and its result the value's columns
         row = jnp.zeros((s_n, d_kv), dtype)
+        # a selection: the first `SELECTED` rows of every slot's table
+        select = ({"select": jnp.arange(nb * bs)[None, :]
+                   < jnp.minimum(lengths, SELECTED[name])[:, None]}
+                  if name in SELECTED else {})
         for l in range(layers):
             out, pool = kern(q, pool, None, tables, lengths, l, 0.115,
-                             write=(row, None, lengths - 1))
+                             write=(row, None, lengths - 1), **select)
             q = jnp.pad(out.reshape(s_n, h, d_value), (
                 (0, 0), (0, 0), (0, d_kv - d_value))).reshape(
                     s_n, h * d_kv).astype(dtype)
