@@ -865,6 +865,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # selection")
     from ..kernels import grouped_matmul as _grouped_matmul
     from ..kernels import paged_attention as _paged_attention
+    from ..kernels import paged_index_scores as _paged_index_scores
 
     platform = platform or jax.default_backend()
     spec = lm_block.OPT if block is None else block
@@ -943,6 +944,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         d_model=d_model, n_heads=n_heads, d_head=d_head, kv_width=d_kv,
         block_size=bs, kv_dtype=kv_dtype, platform=platform,
         value_width=d_lat if latent else None)
+    # and the same of a lightning indexer's scores over its index-key
+    # plane, from the plane's geometry
+    _index_scores, _index_refused = (
+        _paged_index_scores.select_index_scores(
+            index_head_dim=d_idx, block_size=bs, kv_dtype=kv_dtype,
+            platform=platform) if sparse else (None, None))
     if spec is lm_block.OPT:
         startup, shapes, tok_emb, pos_tab, lns, weights, biases = (
             _lm_param_structure(vocab_size, max_len, d_model, n_heads,
@@ -1186,9 +1193,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         columns of the key and of every index query, and the score of
         row r is sum_j w_j relu(q_j . k_r) with w = h W_w / sqrt(heads
         x head size): products of the pool's dtype accumulated in
-        float32, the rest float32.  The rows are read through the
-        table in logical order (the XLA gather: an index-key row is a
-        fifth of a latent one), those past the cursor score nothing."""
+        float32, the rest float32.  The rows are read by the streaming
+        kernel where `select_index_scores` returned it (the pages of
+        the plane a cursor has reached, straight from the pool), else
+        through the table in logical order (the XLA gather of the whole
+        table); those past the cursor score nothing."""
         s_n = h.shape[0]
 
         def turned(t, n):
@@ -1212,11 +1221,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             k_i = turned(k_i, 1)[:, 0]
             pool_i = _write(pool_i, plane, wb, wi, k_i)
         with scope("indexer_scores"):
-            keys = pool_i[plane, tables].reshape(s_n, nb * bs, d_idx)
-            dots = jax.lax.dot_general(
-                q_i.astype(keys.dtype), keys, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)       # [S, Hi, rows]
-            scores = (jax.nn.relu(dots) * w_i[:, :, None]).sum(axis=1)
+            if _index_scores is not None:
+                # rows past the cursor are not the kernel's to define:
+                # the mask below makes them minus infinity
+                scores = _index_scores(
+                    q_i, w_i, pool_i, tables,
+                    jnp.where(active, positions + 1, 1), plane)
+            else:
+                keys = pool_i[plane, tables].reshape(s_n, nb * bs, d_idx)
+                dots = jax.lax.dot_general(
+                    q_i.astype(keys.dtype), keys,
+                    (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)   # [S, Hi, rows]
+                scores = (jax.nn.relu(dots) * w_i[:, :, None]).sum(axis=1)
         with scope("indexer_topk"):
             # a lane with no sequence sees row 0 of the null block
             cur = jnp.where(active, positions, 0)
@@ -1984,6 +2001,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     window = spec.window if ringed else 0
     tiling = ((_attend.tiling(nb), _attend.tiling(nw) if nw else None)
               if _attend is not None else None)
+    index_chunk = (_index_scores.tiling(nb)[0]
+                   if _index_scores is not None else None)
 
     def tick_counts(cursors, slots, windowed=False):
         """What the step dispatched for a tick reads and does, for its
@@ -2019,7 +2038,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         indexer `index_planes`, `kv_rows_indexed` (the rows its selecting
         layers score: cursor + 1 a lane a plane) and `kv_rows_selected`
         (the rows attention is over: `index_topk` at most of cursor + 1,
-        a lane a latent plane)."""
+        a lane a latent plane), and `index_pages_read` of
+        `index_pages_table`: the pages of the index planes its scores
+        read, of those the lanes' tables hold: through the kernel
+        (`kernels["lightning_indexer"]`) the pages each cursor has
+        reached and one for a lane with no sequence, with
+        `index_dma_ops`, the DMA starts and waits it performs for them
+        (`dma_ops`); on the gather path every page."""
         n = len(cursors)
         counts = {}
         if passes > 1:
@@ -2061,6 +2086,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["kv_rows_indexed"] = n_index * int(rows.sum())
             counts["kv_rows_selected"] = planes * int(
                 np.minimum(rows, spec.index_topk).sum())
+            counts["index_pages_table"] = n_index * slots * nb
+            if index_chunk is None:
+                counts["index_pages_read"] = counts["index_pages_table"]
+            else:
+                idle, pages = slots - n, -(-rows // bs)
+                counts["index_pages_read"] = n_index * (
+                    idle + int(pages.sum()))
+                counts["index_dma_ops"] = n_index * int(
+                    idle * _paged_attention.dma_ops(1, index_chunk)
+                    + _paged_attention.dma_ops(pages, index_chunk).sum())
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))
@@ -2087,12 +2122,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # how the selected rows are read: by page under a row mask (a
         # DMA moves whole sublane tiles, so a row list is read as the
         # pages that hold it), or the gather under the mask; the index
-        # keys through the table in logical order, always
+        # keys by the pages under the cursor, or through the table in
+        # logical order
         kernels.update(
             paged_attention_selected=(
                 f"xla:{_refused}:masked_gather" if _attend is None
                 else "pallas:latent:masked_pages"),
-            lightning_indexer="xla:table_gather")
+            lightning_indexer=(
+                f"xla:{_index_refused}:table_gather"
+                if _index_scores is None else "pallas:paged_scores"))
 
     decoder = PagedDecoder(
         step=step, step_window=step_window, step_logits=step_logits,

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu.kernels import paged_attention
+from paddle_tpu.kernels import paged_attention, paged_index_scores
 from paddle_tpu.models import lm_block
 from paddle_tpu.models.transformer import build_lm_paged_decoder
 from paddle_tpu.observability import tracing
@@ -110,6 +110,17 @@ def _interpreted(monkeypatch, chunk_bytes=2 * BS * 128 * 4, tile_rows=4):
     monkeypatch.setattr(
         paged_attention, "select_paged_attention", functools.partial(
             paged_attention.select_paged_attention, interpret=True))
+
+
+def _indexer_interpreted(monkeypatch, pages=4, tile_rows=BS):
+    """The index-score kernel under the interpreter through a whole
+    decoder: the table's 16 pages of 4 float32 keys in four chunks."""
+    monkeypatch.setattr(paged_index_scores, "_CHUNK_BYTES",
+                        pages * BS * DI * 4)
+    monkeypatch.setattr(paged_index_scores, "_TILE_ROWS", tile_rows)
+    monkeypatch.setattr(
+        paged_index_scores, "select_index_scores", functools.partial(
+            paged_index_scores.select_index_scores, interpret=True))
 
 
 def _weights(dec, seed=0):
@@ -236,6 +247,89 @@ def test_the_kernel_under_a_selection_equals_the_gather_path(monkeypatch):
     for a, b in zip(want, got):
         assert np.isfinite(b).all()
         assert np.abs(a - b).max() <= 2e-5 * np.abs(a).max()
+
+
+@pytest.mark.parametrize("attention_too", [False, True],
+                         ids=["indexer", "indexer_and_attention"])
+def test_the_index_score_kernel_holds_the_references_limits(
+        attention_too, monkeypatch):
+    """`step_routing`'s `index_scores` and the selection taken from
+    them, through the streaming kernel in the interpreter (chunks of
+    four pages, beside a lane with no sequence and a lane out of
+    step), against the reference under the toy's limits, and against
+    the gather path's own: the same products, the heads' float32 sum in
+    another order."""
+    dec_x = _decoder()
+    _indexer_interpreted(monkeypatch)
+    if attention_too:
+        _interpreted(monkeypatch)
+    dec_k = _decoder()
+    assert dec_k.kernels["lightning_indexer"] == "pallas:paged_scores"
+    assert dec_x.kernels["lightning_indexer"] == \
+        "xla:not_tpu:table_gather"
+    g = _weights(dec_x, seed=1)
+    seqs = [SEQ, SEQ[5:23]]
+    drive = dict(slots=3, lanes=[0, 2], starts=[0, 4], routing=True)
+    (want, _), routed_x, _ = _drive(dec_x, g, seqs, **drive)
+    (got, _), routed_k, _ = _drive(dec_k, g, seqs, **drive)
+    ok = REF.compare(g, CONFIG, IDS, got, routed_k)
+    assert ok["index_rel_err"] <= LIMITS["index_rel_err"], ok
+    assert ok["selection_gap"] <= LIMITS["selection_gap"], ok
+    assert all(ok[k] <= v for k, v in LIMITS.items()), ok
+    a, b = routed_x["index_scores"], routed_k["index_scores"]
+    assert np.array_equal(np.isneginf(a), np.isneginf(b))
+    seen = np.isfinite(a)
+    assert np.abs(a[seen] - b[seen]).max() <= 1e-5 * np.abs(a[seen]).max()
+    assert np.array_equal(routed_x["selected"], routed_k["selected"])
+    assert np.abs(want - got).max() <= 2e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("platform,key,kv_dtype,page,reads", [
+    ("cpu", DI, "fp32", BS, "xla:not_tpu:table_gather"),
+    # the toy's key of 16 columns is not on the lane grid
+    ("tpu", DI, "fp32", BS, "xla:lane_misaligned:table_gather"),
+    ("tpu", 128, "bf16", BS, "xla:sublane_misaligned:table_gather"),
+    ("tpu", 128, "bf16", 16, "pallas:paged_scores"),
+    ("tpu", 128, "fp32", 8, "pallas:paged_scores"),
+])
+def test_the_indexers_kernel_follows_platform_and_geometry(
+        platform, key, kv_dtype, page, reads):
+    """Nothing but what the code can see chooses: the platform the
+    decoder is built for, the key's width, the pool's dtype and the
+    page's rows."""
+    _, dec = build_lm_paged_decoder(
+        V, page, NB, d_model=D, n_heads=H, n_layers=L, d_inner=F,
+        kv_dtype=kv_dtype, platform=platform,
+        block=_block(index_head_dim=key))
+    assert dec.kernels["lightning_indexer"] == reads
+    counts = dec.tick_counts(np.array([3, 40]), 4)
+    assert ("index_dma_ops" in counts) == reads.startswith("pallas")
+
+
+def test_the_ticks_index_pages_are_what_the_cursors_say(monkeypatch):
+    """Cursors 3 and 40 in pages of 4 on four lanes, two selecting
+    layers: the gather reads the lanes' whole tables; the kernel a page
+    and 11 pages and one for each idle lane, a start a page and a wait
+    for each set bit of a chunk's pages (chunks of 4: 4, 4, 3)."""
+    cursors = np.array([3, 40])
+    counts = _decoder().tick_counts(cursors, 4)
+    assert (counts["index_pages_read"], counts["index_pages_table"]) == (
+        2 * 4 * NB, 2 * 4 * NB)
+    assert "index_dma_ops" not in counts
+    _indexer_interpreted(monkeypatch)
+    counts = _decoder().tick_counts(cursors, 4)
+    assert (counts["index_pages_read"], counts["index_pages_table"],
+            counts["index_dma_ops"]) == (
+                2 * (1 + 11 + 2), 2 * 4 * NB,
+                2 * ((1 + 1) + (11 + 1 + 1 + 2) + 2 * (1 + 1)))
+    # one chunk for the table: 11 pages are waited for in three sizes
+    _indexer_interpreted(monkeypatch, pages=NB)
+    assert _decoder().tick_counts(cursors, 4)["index_dma_ops"] == \
+        2 * ((1 + 1) + (11 + 3) + 2 * (1 + 1))
+    # a windowed tick is refused for this block, and a lane at the
+    # table's end reads all of it
+    full = _decoder().tick_counts(np.array([NB * BS - 1] * 4), 4)
+    assert full["index_pages_read"] == full["index_pages_table"]
 
 
 def test_fewer_rows_than_index_topk_is_dense_attention_exactly():
@@ -614,7 +708,11 @@ def test_scopes_name_the_indexer_and_the_selected_rows_attention():
     plain = _decoder(index_n_heads=0, index_head_dim=0, index_topk=0,
                      indexer_types=[])
     assert "paged_attention_selected" not in plain.kernels
-    assert "kv_rows_selected" not in plain.tick_counts(np.array([3]), 2)
+    assert "lightning_indexer" not in plain.kernels
+    counts = plain.tick_counts(np.array([3]), 2)
+    assert "kv_rows_selected" not in counts
+    assert not {"index_pages_read", "index_pages_table",
+                "index_dma_ops"} & set(counts)
     assert plain.init_pool(3)[1] == () and plain.index_planes == 0
 
 
@@ -803,3 +901,45 @@ def test_the_cost_functions_and_the_readers_on_a_synthetic_run(monkeypatch):
             assert (mod.LAYER, mod.UNIT, mod.MOVES, mod.SOURCE) == (
                 spec["layer"], spec["unit"], spec["moves"], spec["source"])
             assert spec["workloads"] == ["glm-5.2-serve-docqa64"]
+
+
+def test_the_index_pages_reader_on_a_synthetic_run(monkeypatch):
+    """`sched_index_pages_read_share` on a `Run` made by hand: the
+    window's ticks with the indexer's page counts, those outside it and
+    those of a program without the counts left out."""
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "perf"))
+    import common
+
+    reader = common.load_module(os.path.join(
+        ROOT, "perf", "metrics", "sched_index_pages_read_share.py"))
+
+    def tick(ts, **attrs):
+        return {"name": "serving.decode_tick", "ts": ts, "dur": 0.5,
+                "attrs": attrs}
+
+    ticks = [tick(5.0, index_pages_read=1, index_pages_table=1000),
+             tick(10.0, index_pages_read=600, index_pages_table=1000),
+             tick(11.0, index_pages_read=800, index_pages_table=1000),
+             tick(12.0, kv_pages_read=5, kv_pages_table=10),
+             {"name": "serving.request", "ts": 10.0, "dur": 1.0,
+              "attrs": {"index_pages_read": 7, "index_pages_table": 7}},
+             tick(20.0, index_pages_read=1, index_pages_table=1000)]
+    monkeypatch.setattr(tracing, "finished_spans", lambda: ticks)
+    run = common.Run()
+    run.spans = [{"name": "x", "ts": 9.0, "dur": 0.1},
+                 {"name": "x", "ts": 14.0, "dur": 0.1}]
+    assert reader.compute(run) == pytest.approx(70.0)
+    # a program that sets no such attribute (the parent's), and a run
+    # with no span store: nothing, and no error
+    monkeypatch.setattr(tracing, "finished_spans", lambda: [
+        tick(10.0, kv_pages_read=5, kv_pages_table=10,
+             kv_rows_indexed=400)])
+    assert reader.compute(run) is None
+    run.spans = []
+    assert reader.compute(run) is None
+    spec, = (m for m in _json("BENCHMARK.json")["per_layer"]
+             if m["name"] == "sched_index_pages_read_share")
+    assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
+        spec["layer"], spec["unit"], spec["moves"], spec["source"])
+    assert spec["better"] == "lower"
+    assert spec["workloads"] == ["glm-5.2-serve-docqa64"]

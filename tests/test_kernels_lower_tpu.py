@@ -1,5 +1,5 @@
 """Every Pallas kernel cross-lowered for TPU from the CPU, and the
-paged-attention kernel compiled for a described v5e.
+paged-attention and index-score kernels compiled for a described v5e.
 
 The interpret-mode parity suites (test_paged_attention.py,
 test_flash_attention.py) prove the kernels' MATH; they say nothing
@@ -17,7 +17,8 @@ compiles for a chip that is described and not attached
 Contract: at a pool `select_paged_attention(platform="tpu")` accepts,
 the kernel it returns lowers to ONE Mosaic custom call however many
 layers call it, and compiles for a v5e at every serving cell's
-geometry; the flash kernels lower forward and backward, compile for a
+geometry; a lightning indexer's score kernel the same at its plane's;
+the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
 kernel once a layer; a language model's AMP training step holds no
 float32 array of [tokens, vocab].
@@ -32,6 +33,7 @@ import pytest
 
 from paddle_tpu.kernels.flash_attention import flash_attention
 from paddle_tpu.kernels.paged_attention import select_paged_attention
+from paddle_tpu.kernels.paged_index_scores import select_index_scores
 
 MOSAIC_CALL = "tpu_custom_call"
 
@@ -203,6 +205,64 @@ def test_paged_attention_compiles_for_a_v5e(name, one_v5e):
     s_n, width = args[0].shape
     assert compiled.memory_analysis().temp_size_in_bytes <= \
         4 * s_n * width * 4
+
+
+# name: lanes, index heads, an index key's width, page rows, table
+# pages, selecting layers (a plane each), blocks a plane, pool dtype:
+# the GLM cell's index-key pool, and a float32 one in pages of 8
+INDEX_PLANES = {
+    "glm-5.2-docqa64": (64, 32, 128, 16, 432, 2, 9216, "bf16"),
+    "fp32-pages-of-8": (8, 4, 128, 8, 40, 3, 321, "fp32"),
+}
+
+
+def _index_scores(name, sharding=None):
+    """(a function that scores every plane of `name` through the
+    selected kernel, as the selecting layers of a step do one after
+    another, its arguments as shapes)."""
+    s_n, h, d, bs, nb, planes, blocks, kv_dtype = INDEX_PLANES[name]
+    kern, reason = select_index_scores(
+        index_head_dim=d, block_size=bs, kv_dtype=kv_dtype,
+        platform="tpu")
+    assert reason is None
+    dtype = jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32
+
+    def shape(dims, dt):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=sharding)
+
+    def scores(q, w, pool, tables, lengths):
+        out = [kern(q, w, pool, tables, lengths, plane)
+               for plane in range(planes)]
+        valid = jnp.arange(nb * bs)[None, :] < lengths[:, None]
+        return [jnp.where(valid, o, -jnp.inf) for o in out]
+
+    return scores, (shape((s_n, h, d), jnp.float32),
+                    shape((s_n, h), jnp.float32),
+                    shape((planes, blocks, bs, d), dtype),
+                    shape((s_n, nb), jnp.int32), shape((s_n,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", sorted(INDEX_PLANES))
+def test_index_scores_lower_for_tpu(name):
+    """One Mosaic module however many selecting layers call it: the
+    call sits behind a module-level `jax.jit`, the plane traced."""
+    scores, args = _index_scores(name)
+    assert lower_tpu(scores, *args).count(MOSAIC_CALL) == 1
+
+
+@pytest.mark.parametrize("name", sorted(INDEX_PLANES))
+def test_index_scores_compile_for_a_v5e(name, one_v5e):
+    """Mosaic's own compile at the cell's plane (the table's 432 pages
+    one chunk, the products over windows of 128 to 6912 rows), the pool
+    read where it lies: nothing but the scores themselves lives
+    outside the kernel (no logical-order copy of the keys, no
+    [lanes, heads, rows] products)."""
+    scores, args = _index_scores(name, one_v5e)
+    compiled = jax.jit(scores).lower(*args).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+    s_n, _, _, bs, nb, planes = INDEX_PLANES[name][:6]
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        2 * planes * s_n * nb * bs * 4
 
 
 @pytest.mark.parametrize("shape,dtype,calls", [

@@ -37,6 +37,12 @@ its keys, as the forward does: right results, what
 checkout of a commit before PR 47 the same command times that commit's
 kernels (`--variants whole,no_mask`: they have no cut to undo).
 
+A lightning indexer's score kernel the same way (`--shape
+glm-5.2-serve-docqa64-indexer`: `kernels/paged_index_scores.py` over
+the cell's two index planes at its lanes' cursors, ms for both calls):
+whole, `no_copies`, `no_products`; `--check`: against the gather of the
+whole table in plain `jax.numpy`, over the rows under the cursors.
+
 It imports the kernel and is imported by nothing a cell runs.
 """
 from __future__ import annotations
@@ -85,6 +91,13 @@ SHAPES = {
         kernel="flash", batch=1, heads=16, d_head=128, seq=8192),
     "flash-seq16384": dict(
         kernel="flash", batch=1, heads=32, d_head=64, seq=16384),
+    # a lightning indexer's scores: 32 index heads of 128 over ONE key
+    # a row, two selecting layers' planes of 9216 blocks, a document of
+    # 3072 to 6144 rows and 32 to 640 of question and answer under each
+    # cursor (mean 4.9 k: docqa64's)
+    "glm-5.2-serve-docqa64-indexer": dict(
+        kernel="index", slots=64, heads=32, row=128, bs=16, ctx=6912,
+        planes=2, blocks=9216),
 }
 VARIANTS = ("whole", "no_copies", "no_products")
 FLASH_VARIANTS = ("whole", "no_mask", "uncut")
@@ -312,26 +325,30 @@ def build_flash(shape, fa, interpret=False):
     return fwd, bwd, (kq, kk, kv, kdo), (q, k, v, do), plan
 
 
+def timed(f, args, calls):
+    """Milliseconds a call of `f(*args)`, after one call that compiles
+    it."""
+    import jax
+
+    jax.block_until_ready(f(*args))
+    t = time.perf_counter()
+    for _ in range(calls):
+        out = f(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t) / calls * 1e3
+
+
 def pace_flash(shape, fa, variant="whole", calls=30, interpret=False):
     """(ms a forward call, ms a backward call), each kernel alone."""
-    import jax
     import jax.numpy as jnp
-
-    def timed(f, *args):
-        jax.block_until_ready(f(*args))
-        t = time.perf_counter()
-        for _ in range(calls):
-            out = f(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t) / calls * 1e3
 
     with flash_removed(variant, fa):
         fwd, bwd, (q, k, v, do), _, _ = build_flash(shape, fa, interpret)
         _, lse = fwd(q, k, v)
         # the kernel's time does not follow its values: zeros for
         # `delta`, the rowsum(do * o) `_backward` hands it beside lse
-        return (timed(fwd, q, k, v),
-                timed(bwd, q, k, v, lse, jnp.zeros_like(lse), do))
+        return (timed(fwd, (q, k, v), calls),
+                timed(bwd, (q, k, v, lse, jnp.zeros_like(lse), do), calls))
 
 
 def check_flash(shape, fa, interpret=False):
@@ -395,6 +412,104 @@ def run_flash(name, variants=FLASH_VARIANTS, calls=30, with_check=False,
     return res
 
 
+# ---------------------------------------------------------------------------
+# a lightning indexer's scores over its index-key planes
+# ---------------------------------------------------------------------------
+
+def index_lengths(shape):
+    """docqa64's cursors: a document and what a request has added to
+    it so far, uniform both, cut to the context."""
+    r = np.random.RandomState(0)
+    ctx, s_n = shape["ctx"], shape["slots"]
+    docs = r.randint(ctx * 4 // 9, ctx * 8 // 9 + 1, s_n)
+    return np.minimum(docs + r.randint(ctx // 216, ctx * 5 // 54, s_n),
+                      ctx).astype(np.int32)
+
+
+def build_index(shape, pis, interpret=False):
+    """-> (a jitted function that scores every plane of `shape`
+    through the selected kernel, its arguments, the lanes' lengths)."""
+    import jax
+    import jax.numpy as jnp
+
+    s_n, h, d, bs = (shape[k] for k in ("slots", "heads", "row", "bs"))
+    kern, why = pis.select_index_scores(
+        index_head_dim=d, block_size=bs, kv_dtype="bf16", platform="tpu",
+        interpret=interpret)
+    assert kern is not None, why
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    nb = shape["ctx"] // bs
+    pool = (jax.random.normal(keys[0], (shape["planes"], shape["blocks"],
+                                        bs, d), jnp.float32)
+            ).astype(jnp.bfloat16)
+    q = jax.random.normal(keys[1], (s_n, h, d), jnp.float32) * 0.3
+    w = jax.random.normal(keys[2], (s_n, h), jnp.float32)
+    # every lane's pages anywhere in the pool, as a served table's are
+    tables = jnp.asarray(np.random.RandomState(2).randint(
+        1, shape["blocks"], (s_n, nb)), jnp.int32)
+    lengths = jnp.asarray(index_lengths(shape))
+
+    def f(q, w, pool):
+        return [kern(q, w, pool, tables, lengths, plane)
+                for plane in range(shape["planes"])]
+
+    return jax.jit(f), (q, w, pool), dict(
+        tables=tables, lengths=lengths, tiling=kern.tiling(nb))
+
+
+def check_index(shape, pis, interpret=False):
+    """Largest difference, over the rows under the cursors, of the
+    kernel's scores from the gather of the whole table's, as a share of
+    the largest score (float32 sums in another order: some 1e-6)."""
+    import jax
+    import jax.numpy as jnp
+
+    f, (q, w, pool), aux = build_index(shape, pis, interpret)
+    s_n, rows = q.shape[0], shape["ctx"]
+    valid = np.arange(rows)[None, :] < np.asarray(aux["lengths"])[:, None]
+    worst = 0.0
+    for plane, got in enumerate(f(q, w, pool)):
+        keys = pool[plane, aux["tables"]].reshape(s_n, rows, -1)
+        dots = jax.lax.dot_general(
+            q.astype(keys.dtype), keys, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        want = np.asarray((jax.nn.relu(dots) * w[:, :, None]).sum(axis=1))
+        worst = max(worst, float(
+            np.abs(np.where(valid, np.asarray(got) - want, 0.0)).max()
+            / np.abs(want).max()))
+    return worst
+
+
+def run_index(name, variants=VARIANTS, calls=30, with_check=False,
+              rehearse=False):
+    """-> {"shape", "rows", "pages", "tiling", "<variant>": ms for the
+    planes' calls together, "check"}."""
+    import jax
+
+    from paddle_tpu.kernels import paged_index_scores as pis
+
+    shape = SHAPES[name]
+    if rehearse:
+        shape, calls = dict(shape, slots=3, heads=4, ctx=512, blocks=97), 1
+    lengths = index_lengths(shape)
+    res = {"shape": name, "device": jax.devices()[0].device_kind,
+           "rehearsal": bool(rehearse),
+           "rows": int(shape["planes"] * lengths.sum()),
+           "pages": int(shape["planes"]
+                        * (-(-lengths // shape["bs"])).sum())}
+    for variant in variants:
+        if rehearse and variant != "whole":
+            continue    # the interpreter walks the whole kernel only
+        with removed(variant):
+            f, args, aux = build_index(shape, pis, rehearse)
+            res["tiling"] = list(aux["tiling"])
+            res[variant] = round(timed(f, args, calls), 4)
+        print(f"{name} {variant}", res[variant], flush=True)
+    if with_check:
+        res["check"] = check_index(shape, pis, rehearse)
+    return res
+
+
 def toy(shape):
     """`shape` cut to what the interpreter walks in seconds."""
     return dict(shape, slots=3, heads=min(shape["heads"], 8),
@@ -417,6 +532,9 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
                          "chip run (--rehearse walks the script)")
     if shape.get("kernel") == "flash":
         return run_flash(name, variants or FLASH_VARIANTS, calls=calls,
+                         with_check=with_check, rehearse=rehearse)
+    if shape.get("kernel") == "index":
+        return run_index(name, variants or VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse)
     if pa is None:
         from paddle_tpu.kernels import paged_attention as pa
