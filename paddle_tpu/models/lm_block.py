@@ -149,6 +149,23 @@ architecture is a second description and not a second decoder.
                part.  The router is the sixth's, dense layers and the
                held experts the seventh's.
 
+  short-conv-   the tenth (LiquidAI LFM2, `model_type: lfm2_moe`), fields
+  hybrid-like  again: a layer kind whose whole memory is A FEW ROWS
+               ("conv": a gated short convolution, `short_conv_step`:
+               the normed input goes to three gates B, C, u of the
+               model's width, the product B * u through a depthwise
+               causal convolution of `conv_width` taps with NO
+               activation, times C, and back through one matrix; what a
+               lane keeps is the last `conv_width - 1` rows of B * u,
+               the fourth description's convolution tail at another
+               width and with no state beside it) among full-attention
+               layers under RoPE, so that the table is written by a
+               MINORITY of the layers; three kinds of layer by two
+               kinds of FFN in one stack (`mlp_layer_types` beside
+               `layer_types` with "conv" in it); and the sixth's router
+               with 1e-6 added to the sum the chosen scores are
+               divided by (`norm_topk_eps`).
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -165,15 +182,18 @@ from typing import Dict, Tuple
 
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
-           "swiglu", "mamba2_step", "MOE_COMPILER_SCOPES", "SLIDING",
-           "FULL", "MAMBA", "ATTENTION", "DENSE", "SPARSE", "INDEX_FULL",
-           "INDEX_SHARED", "select_rows"]
+           "swiglu", "mamba2_step", "short_conv_step",
+           "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA", "ATTENTION",
+           "CONV", "DENSE", "SPARSE", "INDEX_FULL", "INDEX_SHARED",
+           "select_rows"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # an FFN kind a layer (`mlp_layer_types`)
 DENSE, SPARSE = "dense", "sparse"
 # Granite's names: a layer with no attention, and full attention
 MAMBA, ATTENTION = "mamba", "attention"
+# LFM2's name: a gated short convolution, whose memory is a tail a lane
+CONV = "conv"
 # an indexer kind a layer (`indexer_types`): a layer that computes a
 # selection, and one that reuses the nearest earlier one's
 INDEX_FULL, INDEX_SHARED = "full", "shared"
@@ -201,7 +221,9 @@ class BlockSpec:
     and the sigmoid router, and the table-only block with experts on a
     LATENT cache under the group-limited softmax router, and the DOUBLE
     layer on a latent cache with identity experts under a softmax
-    router with a choice bias (module docstring).  `layer_types`,
+    router with a choice bias, and the table-only block with experts,
+    an FFN kind a layer and the per-head QK-norm whose other layers are
+    gated short convolutions (module docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -258,6 +280,8 @@ class BlockSpec:
     router: str = "softmax"
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    # added to the sum that `norm_topk_prob` divides the chosen by
+    norm_topk_eps: float = 0.0
     # -- GROUP-LIMITED choice: the experts routed over are `n_group`
     #    consecutive groups, a group's score its largest score, and the
     #    k are chosen among the `topk_group` best groups (1 group: all)
@@ -294,6 +318,9 @@ class BlockSpec:
     index_head_dim: int = 0
     index_topk: int = 0
     indexer_types: tuple = ()       # a kind a layer; (): all INDEX_FULL
+    # -- a CONV layer's taps (a gated short convolution; 0: none): a
+    #    lane keeps the last `conv_width - 1` rows of its gated product
+    conv_width: int = 0
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
@@ -309,9 +336,20 @@ class BlockSpec:
         if set(self.rope_layers) - {SLIDING, FULL}:
             raise ValueError(f"rope_layers {self.rope_layers}: of "
                              f"{SLIDING!r} and {FULL!r}")
-        bad = set(self.layer_types) - {SLIDING, FULL, MAMBA, ATTENTION}
+        bad = set(self.layer_types) - {SLIDING, FULL, MAMBA, ATTENTION,
+                                       CONV}
         if bad:
             raise ValueError(f"layer_types: unknown kind(s) {sorted(bad)}")
+        if (CONV in self.layer_types) != (self.conv_width > 0):
+            raise ValueError(
+                f"block {self.name!r}: conv_width {self.conv_width} "
+                f"{'without' if self.conv_width else 'with'} {CONV!r} "
+                "layers: the width is the taps of those layers' "
+                "convolution")
+        if self.norm_topk_eps and not self.norm_topk_prob:
+            raise ValueError(
+                f"block {self.name!r}: norm_topk_eps is added to the sum "
+                "norm_topk_prob divides by, and norm_topk_prob is off")
         if SLIDING in self.layer_types and self.window < 1:
             raise ValueError(f"{SLIDING} layers need window >= 1")
         if self.passes < 1:
@@ -455,8 +493,9 @@ class BlockSpec:
         return self.mlp_layer_types[layer]
 
     def rotated(self, kind: str) -> bool:
-        """Whether RoPE turns Q and K on a layer of this kind."""
-        return self.positions == "rope" and (
+        """Whether RoPE turns Q and K on a layer of this kind (a layer
+        without attention has neither)."""
+        return self.positions == "rope" and kind not in (MAMBA, CONV) and (
             not self.rope_layers or kind in self.rope_layers)
 
     def rope_of(self, kind: str) -> dict:
@@ -500,7 +539,13 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     C, then dt, side by side), the depthwise convolution over x B C
     (`ssm_conv`: [width, H*P + 2*N] and its bias), `ssm_dt` (dt's
     bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
-    `ssm_out` [H*P, d]; no bias on a projection.  A LATENT layer has
+    `ssm_out` [H*P, d]; no bias on a projection.  A CONV layer has three:
+    `conv_in` [d, 3 * d] (the gates B, C and u, side by side in that
+    order), the depthwise convolution's taps `conv_w` [conv_width, d]
+    (row j multiplies the row `conv_width - 1 - j` positions back: the
+    last row this position's own) and `conv_out` [d, d]; no bias, and
+    no QK-norm scales (those are the attention layers').  A LATENT
+    layer has
     seven arrays in place of the four: `q_a` [d, q_lora_rank], its
     norm's scale `q_a_norm`, `q_b` [q_lora_rank, H * (nope + rope)] (a
     head's unrotated columns, then its rotated ones), `kv_a` [d,
@@ -545,7 +590,14 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         raise NotImplementedError(
             f"block {spec.name!r}: passes, post_norm and exit_gate are "
             "built for the dense SwiGLU block alone (ffn 'swiglu')")
-    mamba = MAMBA in kinds
+    mamba, conv = MAMBA in kinds, CONV in kinds
+    if conv and (dense or mamba or SLIDING in kinds or spec.latent
+                 or FULL not in kinds or spec.conv_width < 2):
+        raise NotImplementedError(
+            f"block {spec.name!r}: gated short convolutions (conv_width "
+            ">= 2) are built among full-attention layers on the table, "
+            "in a block with experts: no Mamba layers, ring, latent "
+            "cache or dense SwiGLU block beside them")
     if spec.positions != ("none" if mamba else "rope"):
         raise NotImplementedError(
             f"block {spec.name!r}: positions {spec.positions!r} "
@@ -576,9 +628,9 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     if DENSE in ffns and (dense or mamba or spec.dense_d_inner < 1):
         raise NotImplementedError(
             f"block {spec.name!r}: dense layers among sparse ones "
-            "(mlp_layer_types) are built for a block of attention "
-            "layers with experts (ffn 'moe_swiglu') and need "
-            "dense_d_inner, the dense layers' width")
+            "(mlp_layer_types) are built for a block of attention (and "
+            "short-convolution) layers with experts (ffn 'moe_swiglu') and "
+            "need dense_d_inner, the dense layers' width")
     if spec.router != "sigmoid" and (
             spec.routed_scaling_factor != 1.0 and spec.norm_topk_prob):
         raise NotImplementedError(
@@ -683,6 +735,11 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_d": add(p + "ssm_d.w_0", spec.ssm_heads),
                    "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
+        elif kind == CONV:
+            lay = {"norm1": add(p + "operator_norm.scale_0", d),
+                   "conv_in": add(p + "conv_in_proj.w_0", d, 3 * d),
+                   "conv_w": add(p + "conv.w_0", spec.conv_width, d),
+                   "conv_out": add(p + "conv_out_proj.w_0", d, d)}
         elif spec.latent:
             lay = latent_arrays(p)
             if spec.sparse and spec.indexer_of(l) == INDEX_FULL:
@@ -720,7 +777,7 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             lay.update({"shared_gate": add(p + "shared_gate.w_0", d, fs),
                         "shared_up": add(p + "shared_up.w_0", d, fs),
                         "shared_down": add(p + "shared_down.w_0", fs, d)})
-        if spec.qk_norm:
+        if spec.qk_norm and kind != CONV:
             # one scale for all of Q (of K), or one of d_head for every
             # head of Q (of K)
             dn = d_head if spec.qk_norm_per_head else d
@@ -829,7 +886,8 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     router: the k chosen are the k largest of scores + `b_router` (the
     bias decides the CHOICE alone: the weights are the scores as they
     are, probabilities or sigmoids), renormalised under `norm_topk_prob`
-    and then times `routed_scaling_factor`; a tie goes to the lower
+    (divided by their sum plus `norm_topk_eps`) and then times
+    `routed_scaling_factor`; a tie goes to the lower
     expert index either way.  "All experts" are all the router's
     columns: with IDENTITY experts (`zero_experts`) the matrix and the
     bias are `n_experts + zero_experts` wide, the softmax is over all
@@ -870,7 +928,10 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
                                  spec.experts_per_token)
         top_w = jnp.take_along_axis(probs, top_e, axis=-1)
     if spec.norm_topk_prob:
-        top_w = top_w / top_w.sum(-1, keepdims=True)
+        total = top_w.sum(-1, keepdims=True)
+        # Python's 0.0: no operation, the other blocks' steps as they were
+        top_w = top_w / (total + spec.norm_topk_eps
+                         if spec.norm_topk_eps else total)
     if spec.routed_scaling_factor != 1.0:
         top_w = top_w * spec.routed_scaling_factor
     return top_w, top_e
@@ -1019,6 +1080,24 @@ def swiglu(x, w_gate, w_up, w_down):
     return jnp.dot(act, w_down, preferred_element_type=f32)
 
 
+def _tail_rows(tail, row, fresh, live):
+    """A lane's convolution tail one position on, for every lane: tail
+    [S, width - 1, C] float32 (the rows before this position's) and
+    row [S, C] (this position's) -> (rows [S, width, C]: what the
+    convolution's `width` taps multiply, oldest first; the tail after
+    the position).  `fresh` [S] bool: the lane starts a sequence here
+    and the rows before are zeros whatever it held; `live` [S] bool: a
+    lane that is not keeps its tail as it was.  The one code path of a
+    tail's reset, shift and hold: a Mamba-2 layer's x B C rows and a
+    gated short convolution's products are the same object at another
+    width."""
+    import jax.numpy as jnp
+
+    before = jnp.where(fresh[:, None, None], 0.0, tail)
+    rows = jnp.concatenate([before, row[:, None, :]], axis=1)
+    return rows, jnp.where(live[:, None, None], rows[:, 1:], tail)
+
+
 def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
                 scope=None):
     """ONE position of a Mamba-2 mixer (Dao & Gu, arXiv:2405.21060;
@@ -1061,9 +1140,7 @@ def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
                       preferred_element_type=f32)
         z, xbc, dt = (zxd[:, :di], zxd[:, di:-h_n], zxd[:, -h_n:])
     with scope("ssm_conv"):
-        before = jnp.where(fresh[:, None, None], 0.0, tail)
-        rows = jnp.concatenate([before, xbc[:, None, :]], axis=1)
-        tail = jnp.where(live[:, None, None], rows[:, 1:], tail)
+        rows, tail = _tail_rows(tail, xbc, fresh, live)
         xbc = jax.nn.silu((rows * conv_w.astype(f32)[None]).sum(axis=1)
                           + conv_b.astype(f32))
     with scope("ssm_scan"):
@@ -1085,3 +1162,43 @@ def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
         out = jnp.dot(y.astype(p["ssm_out"].dtype), p["ssm_out"],
                       preferred_element_type=f32)
     return out, state, tail, given
+
+
+def short_conv_step(spec: BlockSpec, u, tail, fresh, live, p, scope=None):
+    """ONE position of a gated short convolution (LFM2's "conv" layer)
+    for every lane, `mamba2_step`'s sibling: u [S, D] float32 (the
+    normed residual) -> (out [S, D] float32, the lane's tail
+    [S, conv_width - 1, D] float32: the last rows of the product B * u
+    before the convolution).
+
+      B, C, x = u @ W_in               (three gates of D columns each)
+      g = B * x
+      c = sum_j w[j] * (tail, g)[j]    (depthwise, causal, NO activation)
+      out = (C * c) @ W_out
+
+    The step IS the prefill, as a Mamba layer's: a prompt goes through
+    one position a tick.  `fresh` and `live` are `mamba2_step`'s, under
+    the same contract and through the same code (`_tail_rows`): a lane
+    whose cursor is 0 starts from a zero tail whatever it holds, a lane
+    that is not live keeps its tail.  `p`: the layer's arrays by
+    `param_layout`'s keys ("conv_in", "conv_w", "conv_out").  The
+    product, the convolution and the second gate are float32; the two
+    projections take the weights' dtype with float32 accumulation."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    scope = scope or (lambda name: contextlib.nullcontext())
+    f32 = jnp.float32
+    d = u.shape[-1]
+    with scope("conv_in_proj"):
+        bcx = jnp.dot(u.astype(p["conv_in"].dtype), p["conv_in"],
+                      preferred_element_type=f32)
+        b, c, x = bcx[:, :d], bcx[:, d:2 * d], bcx[:, 2 * d:]
+    with scope("conv_gate"):
+        rows, tail = _tail_rows(tail, b * x, fresh, live)
+        y = c * (rows * p["conv_w"].astype(f32)[None]).sum(axis=1)
+    with scope("conv_out_proj"):
+        out = jnp.dot(y.astype(p["conv_out"].dtype), p["conv_out"],
+                      preferred_element_type=f32)
+    return out, tail

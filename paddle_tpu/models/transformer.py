@@ -508,15 +508,17 @@ _REFUSALS = {
             "prefix_cache=False")},
     "state": {
         "draft_model": (
-            "a decoder with Mamba layers takes no draft model: speculative "
-            "verification runs a window of positions through step_window, "
-            "and a recurrent state is computed one position a step (and "
-            "cannot be rolled back over rejected tokens)"),
+            "a decoder whose layers keep a recurrent state or a convolution "
+            "tail a lane takes no draft model: speculative verification runs "
+            "a window of positions through step_window, and a lane's state "
+            "is computed one position a step (and cannot be rolled back over "
+            "rejected tokens)"),
         "prefix_cache": (
-            "prefix_cache=True with Mamba layers: a hit starts a sequence "
-            "past position 0, where the attention layers find the prompt's "
-            "K/V in the shared blocks but a lane has no recurrent state for "
-            "it (no snapshot is kept); pass prefix_cache=False")},
+            "prefix_cache=True with layers that keep a recurrent state or a "
+            "convolution tail a lane: a hit starts a sequence past position "
+            "0, where the attention layers find the prompt's K/V in the "
+            "shared blocks but a lane has no state or tail for it (no "
+            "snapshot is kept); pass prefix_cache=False")},
     # a cached block holds every layer's latent rows: the prefix cache
     # works on the latent table as on any other
     "latent": {
@@ -610,8 +612,9 @@ class PagedDecoder:
     # selection, each with an index key a position on the table's
     # blocks (0: no indexer)
     index_planes: int
-    # the Mamba layers' recurrent state: how many layers keep one (0:
-    # none) and the float32 bytes a lane holds over them
+    # what belongs to a LANE: how many layers keep a recurrent state or
+    # a convolution tail (Mamba layers: both; gated short convolutions:
+    # the tail alone; 0: none) and the float32 bytes a lane holds over them
     state_layers: int
     state_bytes_per_lane: int
     # what attends in the resident step (`step`, `step_logits`,
@@ -764,7 +767,23 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     mask); an inactive lane's state does not move.  The table serves
     the attention layers alone.  `step_window` is refused by name;
     `decoder.state_layers` and `decoder.state_bytes_per_lane` say what
-    a lane holds (0 without Mamba layers).
+    a lane holds (0 for a block without such layers).
+
+    A block with CONV layers (`layer_types` "conv": a gated short
+    convolution and no attention, `lm_block.short_conv_step`; lm_block's
+    tenth description, docs/serving.md "A convolution tail a lane")
+    keeps the same kind of state at its smallest: a TAIL ONLY, per conv
+    layer the float32 last `conv_width - 1` rows of the gated product
+    [S, conv_width - 1, d_model].  The pair's layout is the Mamba
+    block's with nothing where the states were: `pool_k` is (the
+    attention layers' K pool, ()) and `pool_v` (their V pool, a tuple of
+    the tails), donated and updated in place with the pools; `init_pool`
+    takes `lanes`; the reset from the cursor, the hold of an idle lane
+    and what is refused (a prefix cache, a draft model, `step_window`)
+    are the Mamba block's, through the same code.  The table serves the
+    attention layers alone (`table_layers`, `bytes_per_block` and the
+    kernel's plane index count them), RoPE turns them alone, and an int8
+    pool is refused by name.
 
     A LOOPED stack (`BlockSpec.passes` > 1: the layers run `passes`
     times a token over the same weights, the final norm after every
@@ -911,7 +930,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     subs = spec.sub_blocks
     kinds = [spec.kind_of(l) for l in range(n_layers) for _ in range(subs)]
     ringed = lm_block.SLIDING in kinds
-    stateful = lm_block.MAMBA in kinds
+    # the kind of layer that keeps something a LANE (`param_layout`
+    # builds one such kind a block): Mamba layers a recurrent state and
+    # a convolution tail, gated short convolutions the tail alone
+    lane_kind = next((k for k in (lm_block.MAMBA, lm_block.CONV)
+                      if k in kinds), None)
+    stateful = lane_kind is not None
+    if lane_kind == lm_block.CONV and kv_dtype == "int8":
+        raise NotImplementedError(
+            f"block {spec.name!r}: an int8 pool beside convolution tails "
+            "is not built (its per-(layer, block) scales are untested on "
+            "a table that a minority of the layers write); kv_dtype fp32 "
+            "or bf16")
     n_full = kinds.count(lm_block.FULL)
     nw = 0
     if ringed:
@@ -1355,6 +1385,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("ssm_out_proj"):
             return _residual(x, out), state, tail, given
 
+    def _conv_mixer(g, lay, x, tail, fresh, live):
+        """x + the gated short convolution of norm(x), one position a
+        lane, and the layer's tail after it (`short_conv_step`)."""
+        with scope("conv_in_proj"):
+            u = _norm(g, x, lay["norm1"])
+        out, tail = lm_block.short_conv_step(
+            spec, u, tail, fresh, live,
+            {n: g[lay[n][0]] for n in ("conv_in", "conv_w", "conv_out")},
+            scope=scope)
+        with scope("conv_out_proj"):
+            return _residual(x, out), tail
+
     def _with_counts(out, hits, live):
         """`out` and what the step counted (`decoder.step_counters`):
         the distinct experts each layer with experts routed to and,
@@ -1549,17 +1591,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         """A pool or the tables as the step is given them, by layer
         kind: one array (or int8 pair) where every layer is full, else
         the pair (full layers', sliding layers') or (attention
-        layers', Mamba layers': a list, one array a layer)."""
+        layers', the layers' that keep something a lane: a list, one
+        array a layer; empty where conv layers have no state)."""
         if stateful:
-            return {lm_block.FULL: x[0], lm_block.MAMBA: list(x[1])}
+            return {lm_block.FULL: x[0], lane_kind: list(x[1])}
         if not ringed:
             return {lm_block.FULL: x}
         return {lm_block.FULL: x[0], lm_block.SLIDING: x[1]}
 
     def _joined(by_kind):
         if stateful:
-            return (by_kind[lm_block.FULL],
-                    tuple(by_kind[lm_block.MAMBA]))
+            return (by_kind[lm_block.FULL], tuple(by_kind[lane_kind]))
         return (tuple(by_kind[k] for k in (lm_block.FULL,
                                            lm_block.SLIDING))
                 if ringed else by_kind[lm_block.FULL])
@@ -1644,6 +1686,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         _mixer(g, lay, x, pools_k[kind][li],
                                pools_v[kind][li], positions == 0, active))
                     scans.append(given)
+                    x = _ffn(g, lay, x, hits)
+                    continue
+                if kind == lm_block.CONV:
+                    # the lane's tail rides where a pool's V does; there
+                    # is no state beside it
+                    x, pools_v[kind][li] = _conv_mixer(
+                        g, lay, x, pools_v[kind][li], positions == 0,
+                        active)
                     x = _ffn(g, lay, x, hits)
                     continue
                 if latent:
@@ -1797,10 +1847,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if stateful:
             raise NotImplementedError(
                 f"block {spec.name!r}: step_window runs a window of "
-                "positions in one dispatch, and a Mamba layer's state "
-                "is a recurrence over them that only the one-position "
-                "`step` computes (a chunked scan is not built); a "
-                "block with Mamba layers runs `step` alone (no draft "
+                "positions in one dispatch, and a lane's recurrent "
+                "state or convolution tail is carried over them by the "
+                "one-position `step` alone (a chunked scan, or a "
+                "convolution over the tail and the chunk, is not "
+                "built); such a block runs `step` alone (no draft "
                 "model, no chunked prefill)")
         if latent:
             raise NotImplementedError(
@@ -1894,14 +1945,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # and an index key a position on each selecting layer's plane
     bytes_per_block += int(n_index * bs * d_idx * elem_bytes)
     window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
-    # a lane's recurrent state over the Mamba layers: the SSM state
-    # and the convolution tail, both float32
+    # what a lane holds over the layers that keep something a lane,
+    # float32: a Mamba layer its SSM state and the convolution tail of
+    # x B C, a gated short convolution the tail of its product alone
     n_mamba = kinds.count(lm_block.MAMBA)
+    n_conv = kinds.count(lm_block.CONV)
+    n_lane = n_mamba + n_conv
     state_shape = (spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state)
-    tail_shape = (spec.ssm_conv - 1,
-                  spec.ssm_heads * spec.ssm_d_head + 2 * spec.ssm_d_state)
-    state_bytes_per_lane = 4 * n_mamba * (
-        math.prod(state_shape) + math.prod(tail_shape))
+    tail_shape = ((spec.conv_width - 1, d_model) if n_conv else
+                  (spec.ssm_conv - 1, spec.ssm_heads * spec.ssm_d_head
+                   + 2 * spec.ssm_d_state))
+    state_bytes_per_lane = 4 * (n_mamba * math.prod(state_shape)
+                                + n_lane * math.prod(tail_shape))
 
     def init_pool(num_blocks, device=None, window_blocks=None,
                   lanes=None):
@@ -1912,8 +1967,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         (full, ring) `step` takes.  For a block with Mamba layers each
         is the pair (the attention layers' pool, one float32 array a
         Mamba layer: `lanes` SSM states beside K, `lanes` convolution
-        tails beside V); `lanes` is the step's lane count and read by
-        no other block.  A latent block's is (the one pool, ()), with a
+        tails beside V), for one with conv layers the same pair with no
+        states (the empty tuple beside K, a tail a conv layer beside
+        V); `lanes` is the step's lane count and read by no other
+        block.  A latent block's is (the one pool, ()), with a
         lightning indexer (the latent pool, the index-key pool)."""
         def zeros(layers, blocks, width=d_kv):
             shape = (layers, int(blocks), bs, width)
@@ -1925,19 +1982,22 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                               else jnp.float32)
             return z if device is None else jax.device_put(z, device)
 
-        def lane_state(shape):
+        def lane_state(shape, layers):
             if lanes is None:
                 raise ValueError(
-                    f"block {spec.name!r} has Mamba layers: init_pool "
-                    "needs lanes, the lane count of the step")
+                    f"block {spec.name!r} has layers with a state or a "
+                    "tail a lane: init_pool needs lanes, the lane count "
+                    "of the step")
             z = [jnp.zeros((int(lanes),) + shape, jnp.float32)
-                 for _ in range(n_mamba)]
+                 for _ in range(layers)]
             return tuple(z if device is None
                          else jax.device_put(z, device))
 
         if stateful:
-            return ((zeros(n_full, num_blocks), lane_state(state_shape)),
-                    (zeros(n_full, num_blocks), lane_state(tail_shape)))
+            return ((zeros(n_full, num_blocks),
+                     lane_state(state_shape, n_mamba)),
+                    (zeros(n_full, num_blocks),
+                     lane_state(tail_shape, n_lane)))
 
         if sparse:
             return (zeros(planes, num_blocks),
@@ -1981,7 +2041,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
              "kv_b": "latent_absorb", "idx_q": "indexer_q",
              "idx_w": "indexer_q", "idx_k": "indexer_k",
              "dense_gate": "dense_ffn",
-             "dense_up": "dense_ffn", "dense_down": "dense_ffn"}
+             "dense_up": "dense_ffn", "dense_down": "dense_ffn",
+             "conv_in": "conv_in_proj", "conv_out": "conv_out_proj"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
@@ -2025,10 +2086,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         With sliding layers `past_window` (cursors at or
         past the window: their rings have wrapped) and the rows a layer
         of each kind attends over, `kv_rows_full` (cursor + 1) and
-        `kv_rows_win` (the window at most).  With Mamba layers
-        `state_lanes` (lanes with a recurrent state: all the tick's) and
-        `state_resets` (those at position 0, which the step starts from
-        zero).  With experts `moe_kernel` (1: the traced step's expert
+        `kv_rows_win` (the window at most).  With Mamba or conv layers
+        `state_lanes` (lanes with a recurrent state or a convolution
+        tail: all the tick's) and `state_resets` (those at position 0,
+        which the step starts from zero); with conv layers also
+        `conv_layers` and `conv_tail_bytes` (the float32 tails those
+        lanes' conv layers read and write back).  With experts
+        `moe_kernel` (1: the traced step's expert
         layer is the Pallas grouped matmul, 0: `ragged_dot`) and
         `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
         the planes the pages are counted over (`kv_planes` with double
@@ -2099,6 +2163,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))
+        if n_conv:
+            counts["conv_layers"] = n_conv
+            # read and written back: a conv block's lanes hold tails alone
+            counts["conv_tail_bytes"] = 2 * n * state_bytes_per_lane
         if decoder.expert_kernel is not None:
             counts["moe_kernel"] = int(
                 not decoder.expert_kernel.startswith("xla:"))
@@ -2156,7 +2224,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         window_blocks_per_seq=nw, window=window,
         window_bytes_per_block=window_bytes_per_block,
         table_layers=planes, ring_layers=n_win, index_planes=n_index,
-        state_layers=n_mamba, state_bytes_per_lane=state_bytes_per_lane,
+        state_layers=n_lane, state_bytes_per_lane=state_bytes_per_lane,
         kernels=kernels,
         attention_tiling=tiling, tick_counts=tick_counts, refuses=refuses)
     return startup, decoder

@@ -352,10 +352,11 @@ def test_generation_server_serves_a_state_a_lane():
     place = fluid.CPUPlace()
     # the block's own word, which the server raises as it stands
     assert set(dec.refuses) == {"draft_model", "prefix_cache"}
-    assert all("Mamba layers" in why for why in dec.refuses.values())
-    with pytest.raises(ValueError, match="prefix_cache=True with Mamba"):
+    assert all("a recurrent state or a convolution tail" in why
+               for why in dec.refuses.values())
+    with pytest.raises(ValueError, match="prefix_cache=True with layers"):
         GenerationServer(dec, g, slots=2, kv_blocks=16, place=place)
-    with pytest.raises(ValueError, match="Mamba layers takes no draft"):
+    with pytest.raises(ValueError, match="a lane takes no draft model"):
         GenerationServer(dec, g, slots=2, kv_blocks=16, place=place,
                          prefix_cache=False, draft_decoder=dec,
                          draft_states=g)
