@@ -30,13 +30,36 @@ is computed the NEXT slot's first chunk is already in flight (the
 scratch and the buffer cursor outlive a grid step), so DMA latency is
 paid once a call, not once a slot.
 
-A chunk's DMA bookkeeping is per CHUNK, not per page.  Its copies are
-a page each (a table names any block), issued `_ISSUE_UNROLL` to a loop
-iteration, but they are WAITED FOR on their summed bytes: a DMA
-semaphore counts bytes, so a wait need not name the copy it waits for,
-only as many bytes, and a chunk of `copied` pages is one wait for each
-set bit of `copied`, on a descriptor of 2^b pages (`dma_ops` counts
-them).  The invariant that makes it safe: a semaphore `sems[pool, buf]`
+A chunk's DMA bookkeeping is per CHUNK, not per page.  A table names
+any block, so a copy is a page; but every copy is a descriptor the
+scalar core makes and the DMA engine takes (some 17.5 ns a start
+whatever its size; a 20 KB latent page is 25 ns of bytes, a 4 KiB
+index key page 5), in the one instruction stream the products are in,
+and tables mostly name RUNS: a request's blocks are taken at one
+admission, side by side and ascending where the free supply allows
+(`serving/kv_cache.py`), and a ring is consecutive blocks by
+construction.  So the issue loop (`start_pages`, which the index-score
+kernel calls too) takes a chunk's table entries in groups of
+`_ISSUE_UNROLL` and starts ONE copy of that many pages for a group
+whose entries are consecutive ascending block ids (a slice of so many
+blocks of the pool into as many pages of the scratch, which is
+declared by pages for it), a copy a page for the others as before.
+Which groups are runs is the table's own word, computed from the
+tables by the same jitted call that hands them to the kernel
+(`issue_order`, on the scalar-prefetch lane): never the layer's kind
+or a promise of the allocator's.  It arrives SORTED, a chunk's run
+groups first, so that the loop over runs and the loop over the others
+are each branch-free: a test inside one loop (a flag a group, or eight
+loads and compares) cost a table with no run 7 to 17% of a call, the
+sorted lists 1 to 2% (PERF.md section 6, PR 56).  A table in any
+order, an idle lane's ring of block 0 and a shared prefix followed by
+fresh blocks give the same bytes in the same places, and only the
+count of descriptors differs (`starts_saved` and `dma_ops` count
+them).  The copies are WAITED FOR on their summed bytes: a
+DMA semaphore counts bytes, so a wait need not name the copy it waits
+for, only as many bytes, and a chunk of `copied` pages is one wait for
+each set bit of `copied`, on a descriptor of 2^b pages.  The invariant
+that makes it safe: a semaphore `sems[pool, buf]`
 never has more than ONE chunk's copies outstanding.  Buffers alternate;
 chunk c + 1 (or the next slot's first) is started into `1 - buf` while
 chunk c, in `buf`, is still to be waited for, and `1 - buf`'s last
@@ -110,11 +133,13 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dma_ops", "paged_attention", "paged_attention_supports",
-           "rows_multiplied", "select_paged_attention"]
+           "group_runs", "issue_order", "rows_multiplied",
+           "select_paged_attention", "start_pages", "starts_saved"]
 
 # K (or V) bytes a chunk: the copies of one chunk are in flight while
 # the one before it is computed, so a chunk is long enough to hide a
@@ -134,11 +159,12 @@ _CHUNK_BYTES = 1024 * 1024
 # takes 2.5; PERF.md section 6, PR 41).  128 rows fill the MXU's
 # columns once; fewer would save no pass of it.
 _TILE_ROWS = 128
-# Page copies issued a loop iteration: a page's table read, descriptor
-# and start are scalar work in the one instruction stream the products
-# are in, and a loop's counter, test and branch a page were a part of
-# it (PERF.md section 6, PR 46); the pages a chunk has beyond a
-# multiple of it go one at a time.
+# Table entries the issue loop takes a loop iteration: a page's table
+# read, descriptor and start are scalar work in the one instruction
+# stream the products are in, and a loop's counter, test and branch a
+# page were a part of it (PERF.md section 6, PR 46).  It is also the
+# GROUP that goes as one copy where its entries are a run (PR 56); the
+# pages a chunk has beyond a multiple of it go one at a time.
 _ISSUE_UNROLL = 8
 _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 
@@ -206,18 +232,135 @@ def rows_multiplied(n_pages, pages: int, tile: int, block_size: int):
     return (whole * pages + last) * block_size
 
 
-def dma_ops(n_pages, pages: int):
+def group_runs(tables, pages: int, unroll: int = _ISSUE_UNROLL):
+    """Which of the issue loop's groups are RUNS: `tables` [lanes,
+    table pages] (a jax array, traced or not, or host integers) in
+    chunks of `pages`, a chunk's first `pages // unroll * unroll`
+    entries in groups of `unroll` -> bool [lanes, chunks, groups a
+    chunk], True where a group's entries are consecutive ascending
+    block ids (`start_pages` then starts ONE copy for it).  The table's
+    own word, whatever made it: what `issue_order` sorts for the
+    kernels and `starts_saved` counts."""
+    xp = jnp if isinstance(tables, jax.Array) else np
+    lanes, nb = tables.shape
+    unroll = min(unroll, pages)
+    chunks = -(-nb // pages)
+    padded = xp.pad(tables, ((0, 0), (0, chunks * pages - nb)))
+    groups = padded.reshape(lanes, chunks, pages)[
+        :, :, :pages // unroll * unroll].reshape(lanes, chunks, -1, unroll)
+    return (groups == groups[..., :1] + xp.arange(unroll)).all(axis=3)
+
+
+def issue_order(tables, pages: int, unroll: int = _ISSUE_UNROLL):
+    """What `start_pages` reads of `tables` [lanes, table pages] (a jax
+    array) beside the tables themselves: int32 [lanes * chunks, 2 * per
+    + 1] with per = `pages // unroll` groups a chunk.  A row's first
+    per + 1 words: the run groups among a chunk's first k, k = 0 to per
+    (`group_runs`); its last per: the chunk's groups in the order they
+    are issued, the runs first, each kind ascending.  A lane's length
+    cuts a chunk after its first k groups, and those are then the first
+    so many of either list: two loops, no test inside."""
+    runs = group_runs(tables, pages, unroll).astype(jnp.int32)
+    per = runs.shape[2]
+    ahead = jnp.cumsum(runs, axis=2)
+    before = ahead - runs
+    group = jnp.arange(per)
+    # a group's place: among the runs, or after all of them among the rest
+    place = jnp.where(runs > 0, before, ahead[..., -1:] + group - before)
+    order = jnp.sum(group[:, None] * (place[..., None] == group), axis=2)
+    return jnp.concatenate(
+        [jnp.zeros_like(runs[..., :1]), ahead, order], axis=2).reshape(
+            -1, 2 * per + 1)
+
+
+def starts_saved(tables, pages: int, unroll: int = _ISSUE_UNROLL):
+    """What `start_pages` saves of a start a page over `tables` [lanes,
+    table pages] (host integers) in chunks of `pages`, as prefix sums a
+    lane over its groups, chunk upon chunk (`group_runs`): [lanes,
+    groups + 1], entry k the starts saved by the first k groups,
+    `unroll - 1` for each that is a run.  A table does not change while
+    a request holds it: computed once, `dma_ops` looks a lane's count
+    up whatever its cursor."""
+    runs = group_runs(np.asarray(tables), pages, unroll)
+    runs = runs.reshape(runs.shape[0], -1)
+    saved = np.zeros((runs.shape[0], runs.shape[1] + 1), np.int64)
+    np.cumsum(runs * (min(unroll, pages) - 1), axis=1, out=saved[:, 1:])
+    return saved
+
+
+def dma_ops(n_pages, pages: int, saved=None, unroll: int = _ISSUE_UNROLL):
     """DMA starts and waits a POOL the kernel performs for a slot of
     `n_pages` pages (an integer or an integer array) in chunks of
-    `pages`: a start a page, and for each chunk a wait for each set
-    bit of the pages copied into it."""
+    `pages`: a start a page, less what the groups that are runs save
+    (`saved`: `starts_saved` of the lanes' tables at this `pages` and
+    `unroll`, a row for each of `n_pages`; None: no group is a run),
+    and for each chunk a wait for each set bit of the pages copied
+    into it."""
     whole, rest = n_pages // pages, n_pages % pages
-    return n_pages + whole * pages.bit_count() + sum(
+    ops = n_pages + whole * pages.bit_count() + sum(
         (rest >> bit) & 1 for bit in range(pages.bit_length()))
+    if saved is None:
+        return ops
+    unroll = min(unroll, pages)
+    groups = whole * (pages // unroll) + rest // unroll
+    return ops - saved[range(len(saved)), groups]
 
 
-def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
-            windows, scale, h, dh, n_kv, writes, d_value=0, selects=False):
+def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
+                sems, *, unroll: int):
+    """Start the copies of the `n` pages that table entries `base` to
+    `base + n - 1` name (scalars in SMEM; no other entry is read) into
+    pages 0 to `n - 1` of buffer `buf`: for each pool i, from
+    `planes[i]` ([blocks, block_size, width], HBM) into `bufs[i]` ([2,
+    pages, block_size, width], VMEM), counted on `sems[i, buf]`.  The
+    first `n // unroll` groups of `unroll` entries go by `order_ref`
+    (`issue_order` of the same tables; `chunk`: this chunk's row of
+    it): ONE copy of `unroll` pages for each that is a run, then a copy
+    a page for the others; the `n % unroll` last pages a copy each.
+    The one issue loop of the paged kernels (this one and
+    `paged_index_scores.py`)."""
+    def copy(i, n_pages=1):
+        """`n_pages` pages from entry i's block on, to page i on."""
+        blk = tables_ref[base + i]
+        for i_pool, (plane, into) in enumerate(zip(planes, bufs)):
+            if n_pages == 1:
+                src, dst = plane.at[blk], into.at[buf, i]
+            else:
+                src = plane.at[pl.ds(blk, n_pages)]
+                dst = into.at[buf, pl.ds(i, n_pages)]
+            pltpu.make_async_copy(src, dst, sems.at[i_pool, buf]).start()
+
+    def page(i, carry=0):
+        copy(i)
+        return carry
+
+    grouped = 0
+    if unroll > 1:
+        per = bufs[0].shape[1] // unroll
+        groups = n // unroll
+        grouped = groups * unroll
+        row = chunk * (2 * per + 1)
+        runs, all_runs = order_ref[row + groups], order_ref[row + per]
+        order = row + per + 1
+
+        def run(i, carry):
+            copy(order_ref[order + i] * unroll, unroll)
+            return carry
+
+        def pages(i, carry):
+            first = order_ref[order + all_runs + i] * unroll
+            for j in range(unroll):
+                copy(first + j)
+            return carry
+
+        jax.lax.fori_loop(0, runs, run, 0)
+        jax.lax.fori_loop(0, groups - runs, pages, 0)
+    jax.lax.fori_loop(grouped, n, page, 0)
+
+
+def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
+            pages, windows, scale, h, dh, n_kv, writes, d_value=0,
+            selects=False):
     """Grid step s: slot s's attention over its first
     `ceil(lengths[s] / bs)` pages of layer `layer[0]`, copied a chunk
     of `pages` pages at a time and multiplied over the smallest of
@@ -228,7 +371,9 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
     K pool and a V pool).  `selects`: a row mask a slot follows the
     query ([chunks, 1, rows a chunk] float32, 1 where the row is
     selected: a chunk's mask a tile of its own, so that the chunk
-    indexes an untiled axis)."""
+    indexes an untiled axis).  `order_ref`: `issue_order` of the
+    tables.  A buffer is [2, pages, bs, width]: a chunk's pages, which
+    the products read as its rows."""
     n_pools = 1 if d_value else 2
     refs = iter(refs)
 
@@ -265,30 +410,14 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         return jnp.minimum(pages, n_pages(slot) - chunk * pages)
 
     def start(slot, chunk, buf):
-        """Start each page copy (K, then V; a latent row once) of
+        """Start the page copies (K and V; a latent row once) of
         `slot`'s chunk `chunk` into buffer `buf`: the pages the slot's
-        length reaches, so a table entry past it is never read;
-        `_ISSUE_UNROLL` pages a loop iteration, the rest one by one."""
-        base = slot * nb + chunk * pages
-        planes = [hbm.at[layer] for hbm in hbms]
-        unroll = min(_ISSUE_UNROLL, pages)
-
-        def page(i, carry=0):
-            blk = tables_ref[base + i]
-            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            for i_pool, (plane, into) in enumerate(zip(planes, bufs)):
-                pltpu.make_async_copy(plane.at[blk], into.at[buf, dst],
-                                      sems.at[i_pool, buf]).start()
-            return carry
-
-        def group(g, carry):
-            for j in range(unroll):
-                page(g * unroll + j)
-            return carry
-
-        n = copied_into(slot, chunk)
-        jax.lax.fori_loop(0, n // unroll, group, 0)
-        jax.lax.fori_loop(n // unroll * unroll, n, page, 0)
+        length reaches, so a table entry past it is never read."""
+        start_pages(tables_ref, order_ref, slot * nb + chunk * pages,
+                    slot * -(-nb // pages) + chunk,
+                    copied_into(slot, chunk),
+                    [hbm.at[layer] for hbm in hbms], bufs, buf, sems,
+                    unroll=min(_ISSUE_UNROLL, pages))
 
     def wait(copied, buf):
         """Wait for the `copied` pages a `start` sent to buffer `buf`,
@@ -298,7 +427,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         semaphore holds this chunk's copies and no other's)."""
         for bit in range(pages.bit_length()):
             @pl.when(((copied >> bit) & 1) == 1)
-            def _wait(size=pl.ds(0, (1 << bit) * bs)):
+            def _wait(size=pl.ds(0, 1 << bit)):
                 for i_pool, into in enumerate(bufs):
                     pltpu.make_async_copy(into.at[buf, size],
                                           into.at[buf, size],
@@ -352,10 +481,9 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         at = wrow_ref[s] - c * rows
         first = pl.multiple_of(at // group_rows * group_rows, group_rows)
         blk = tables_ref[s * nb + c * pages + at // bs]
-        src = pl.ds(first, group_rows)
         dst = pl.ds(pl.multiple_of(first % bs, group_rows), group_rows)
         return tuple(
-            pltpu.make_async_copy(back.at[buf, src],
+            pltpu.make_async_copy(back.at[buf, at // bs, dst],
                                   out.at[layer, blk, dst], wsems.at[i])
             for i, (back, out) in enumerate(zip(bufs, outs)))
 
@@ -371,7 +499,7 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
         @pl.when(here)
         def _put():
             at = wrow - c * rows
-            page = pl.ds(pl.multiple_of(at // bs * bs, bs), bs)
+            page = at // bs
             mine = iota((bs, 1), 0) == at % bs
             for into, new_ref in zip(bufs, new_refs):
                 into[buf, page] = jnp.where(mine, new_ref[0],
@@ -403,8 +531,9 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
             rows (static)."""
             def multiply(carry):
                 m, l = carry
+                k = k_buf[buf, :n_rows // bs].reshape(n_rows, -1)
                 sc = jax.lax.dot_general(
-                    q, k_buf[buf, :n_rows], (((1,), (1,)), ((), ())),
+                    q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
                 row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
                 seen = row < length
@@ -417,8 +546,8 @@ def _kernel(tables_ref, lengths_ref, layer_ref, *refs, bs, nb, pages,
                          if selects else m_new)
                 alpha = jnp.exp(m - m_ref)
                 p = jnp.exp(sc - m_ref)
-                v = (v_buf[buf, :n_rows, :d_value] if d_value
-                     else v_buf[buf, :n_rows])
+                v = (v_buf[buf, :n_rows // bs, :, :d_value] if d_value
+                     else v_buf[buf, :n_rows // bs]).reshape(n_rows, -1)
                 acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -507,14 +636,16 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
     def hbm():
         return pl.BlockSpec(memory_space=pl.ANY)
 
-    scalars = [tables.reshape(-1).astype(jnp.int32),
+    tables = jnp.asarray(tables, jnp.int32)
+    scalars = [tables.reshape(-1),
+               issue_order(tables, pages).reshape(-1),
                jnp.maximum(lengths.astype(jnp.int32), 1),
                jnp.asarray(layer, jnp.int32).reshape(1)]
     inputs = [q.astype(pool_k.dtype).reshape((s_n,) + block[1:])]
     in_specs = [pl.BlockSpec(block, slot)]
     out_specs = [pl.BlockSpec(out_block, slot)]
     out_shape = [jax.ShapeDtypeStruct((s_n,) + out_block[1:], jnp.float32)]
-    scratch = [pltpu.VMEM((2, pages * bs, d_kv), pool.dtype)
+    scratch = [pltpu.VMEM((2, pages, bs, d_kv), pool.dtype)
                for pool in pools]
     scratch += [pltpu.VMEM(out_block[1:] if d_value else (h, d_kv),
                            jnp.float32),

@@ -15,12 +15,15 @@ attention kernel (`paged_attention.py`, whose scheme it takes, two
 constants apart): tables and a LENGTH a lane on the scalar-prefetch lane,
 the pool left in HBM, the `ceil(length / block_size)` pages of a lane
 and no other copied a chunk at a time into two VMEM buffers by manual
-async copies, issued `_ISSUE_UNROLL` to a loop iteration and waited
-for on their summed bytes, the next lane's first chunk in flight under
-this lane's last.  Nothing is written to the pool (it is read only and
-not aliased: the key's write is the caller's scatter, before the call,
-by data dependence) and no softmax joins the chunks: a chunk's scores
-go to the chunk's columns of the lane's output and that is all.
+async copies, started by the attention kernel's own issue loop
+(`paged_attention.start_pages`: table entries in groups of
+`_ISSUE_UNROLL`, ONE copy for a group that is a run of consecutive
+blocks) and waited for on their summed bytes, the next lane's first
+chunk in flight under this lane's last.  Nothing is written to the
+pool (it is read only and not aliased: the key's write is the caller's
+scatter, before the call, by data dependence) and no softmax joins the
+chunks: a chunk's scores go to the chunk's columns of the lane's
+output and that is all.
 
 A chunk's work: the index queries `q` [heads, width] (cast to the
 pool's dtype: what the MXU rounds them to on the XLA path too) times
@@ -51,8 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (_KV_DTYPES, _TILE_ROWS, _windows,
-                              paged_attention_supports)
+from .paged_attention import (_KV_DTYPES, _TILE_ROWS, _windows, issue_order,
+                              paged_attention_supports, start_pages)
 
 __all__ = ["paged_index_scores", "select_index_scores"]
 
@@ -65,26 +68,28 @@ __all__ = ["paged_index_scores", "select_index_scores"]
 # 0.79 ms at chunks of 128 KiB to 2 MiB.  2 MiB is the cell's whole
 # table (432 pages) in one chunk; two chunks are the whole scratch.
 _CHUNK_BYTES = 2 * 1024 * 1024
-# Page copies issued a loop iteration (`paged_attention.py`'s scheme:
-# the rest one at a time).  With nothing but descriptors in its way
-# the loop's own counter, test and branch show: the cell's planes take
-# 0.787 ms at 8 to an iteration and 0.752 at 16, and 32 gave nothing
-# over 16 (PERF.md section 6, PR 54).
+# Table entries the issue loop takes a loop iteration, and the group
+# that goes as ONE copy where they are a run (`start_pages`).  With
+# nothing but descriptors in its way the loop's own counter, test and
+# branch show: a page a copy, the cell's planes take 0.787 ms at 8 to
+# an iteration and 0.752 at 16, and 32 gave nothing over 16 (PERF.md
+# section 6, PR 54).
 _ISSUE_UNROLL = 16
 
 
-def _kernel(tables_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm, o_ref,
-            buf_ref, sems, cursor_ref, *, bs, nb, pages, windows):
+def _kernel(tables_ref, order_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm,
+            o_ref, buf_ref, sems, cursor_ref, *, bs, nb, pages, windows):
     """Grid step s: lane s's scores over its first
     `ceil(lengths[s] / bs)` pages of plane `plane[0]`, copied a chunk
     of `pages` pages at a time and multiplied over the smallest of
     `windows` (pages, static) that the copied pages fill.
     `cursor_ref[0]` is the buffer (0 or 1) that holds this lane's first
-    chunk, started by the step before.  `o_ref` [1, chunks, 1, rows a
+    chunk, started by the step before; `order_ref`: `issue_order` of
+    the tables; `buf_ref` [2, pages, bs, width]: a chunk's pages, which
+    the product reads as its rows.  `o_ref` [1, chunks, 1, rows a
     chunk]: a chunk's scores a tile of their own, so that the chunk
     indexes an untiled axis."""
     s, n_slots = pl.program_id(0), pl.num_programs(0)
-    plane = hbm.at[plane_ref[0]]
 
     def n_pages(slot):
         return (lengths_ref[slot] + bs - 1) // bs
@@ -94,41 +99,26 @@ def _kernel(tables_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm, o_ref,
         return jnp.minimum(pages, n_pages(slot) - chunk * pages)
 
     def start(slot, chunk, buf):
-        """Start each page copy of `slot`'s chunk `chunk` into buffer
+        """Start the page copies of `slot`'s chunk `chunk` into buffer
         `buf`: the pages the lane's length reaches, so a table entry
-        past it is never read; `_ISSUE_UNROLL` pages a loop iteration,
-        the rest one by one."""
-        base = slot * nb + chunk * pages
-        unroll = min(_ISSUE_UNROLL, pages)
-
-        def page(i, carry=0):
-            blk = tables_ref[base + i]
-            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            pltpu.make_async_copy(plane.at[blk], buf_ref.at[buf, dst],
-                                  sems.at[buf]).start()
-            return carry
-
-        def group(g, carry):
-            for j in range(unroll):
-                page(g * unroll + j)
-            return carry
-
-        n = copied_into(slot, chunk)
-        jax.lax.fori_loop(0, n // unroll, group, 0)
-        jax.lax.fori_loop(n // unroll * unroll, n, page, 0)
+        past it is never read."""
+        start_pages(tables_ref, order_ref, slot * nb + chunk * pages,
+                    slot * -(-nb // pages) + chunk,
+                    copied_into(slot, chunk), [hbm.at[plane_ref[0]]],
+                    [buf_ref], buf, sems, unroll=min(_ISSUE_UNROLL, pages))
 
     def wait(copied, buf):
         """Wait for the `copied` pages a `start` sent to buffer `buf`,
         on their summed bytes: for each set bit b of `copied` one wait
         on a descriptor of 2^b pages, of which only the size and the
-        semaphore matter (`sems[buf]` never holds more than ONE chunk's
-        copies: `paged_attention.py`'s invariant)."""
+        semaphore matter (`sems[0, buf]` never holds more than ONE
+        chunk's copies: `paged_attention.py`'s invariant)."""
         for bit in range(pages.bit_length()):
             @pl.when(((copied >> bit) & 1) == 1)
-            def _wait(size=pl.ds(0, (1 << bit) * bs)):
+            def _wait(size=pl.ds(0, 1 << bit)):
                 pltpu.make_async_copy(buf_ref.at[buf, size],
                                       buf_ref.at[buf, size],
-                                      sems.at[buf]).wait()
+                                      sems.at[0, buf]).wait()
 
     @pl.when(s == 0)
     def _first_slot():
@@ -156,8 +146,9 @@ def _kernel(tables_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm, o_ref,
             """The scores of the chunk's first `n_rows` rows
             (static)."""
             def multiply(c):
+                keys = buf_ref[buf, :n_rows // bs].reshape(n_rows, -1)
                 dots = jax.lax.dot_general(
-                    q, buf_ref[buf, :n_rows], (((1,), (1,)), ((), ())),
+                    q, keys, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)   # [H, n_rows]
                 o_ref[0, c, :, :n_rows] = jnp.sum(
                     jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
@@ -195,7 +186,9 @@ def paged_index_scores(q, w, pool, tables, lengths, plane, *, pages: int,
     s_n, h, d = q.shape
     bs, nb = pool.shape[2], tables.shape[1]
     n_chunks = -(-nb // pages)
-    scalars = [tables.reshape(-1).astype(jnp.int32),
+    tables = jnp.asarray(tables, jnp.int32)
+    scalars = [tables.reshape(-1),
+               issue_order(tables, pages, _ISSUE_UNROLL).reshape(-1),
                jnp.maximum(lengths.astype(jnp.int32), 1),
                jnp.asarray(plane, jnp.int32).reshape(1)]
     out = pl.pallas_call(
@@ -208,8 +201,8 @@ def paged_index_scores(q, w, pool, tables, lengths, plane, *, pages: int,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, n_chunks, 1, pages * bs),
                                    lambda s, *_: (s, 0, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, pages * bs, d), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,)),
+            scratch_shapes=[pltpu.VMEM((2, pages, bs, d), pool.dtype),
+                            pltpu.SemaphoreType.DMA((1, 2)),
                             pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((s_n, n_chunks, 1, pages * bs),
                                        jnp.float32),
@@ -240,7 +233,8 @@ def select_index_scores(
 
     scores(q, w, pool, tables, lengths, plane): `paged_index_scores`
     with the chunk and the row tile chosen from a page's bytes and the
-    table's pages: `scores.tiling(table_pages)` says which."""
+    table's pages: `scores.tiling(table_pages)` says which, and
+    `scores.unroll` the table entries its issue loop takes at once."""
     reason = paged_attention_supports(
         d_model=index_head_dim, block_size=block_size, kv_dtype=kv_dtype,
         platform=platform, interpret=interpret)
@@ -268,4 +262,7 @@ def select_index_scores(
                                   interpret=interpret)
 
     scores.tiling = tiling
+    # the issue loop's group: what `paged_attention.starts_saved` and
+    # `dma_ops` count this kernel's starts by
+    scores.unroll = _ISSUE_UNROLL
     return scores, None

@@ -628,9 +628,15 @@ class PagedDecoder:
     # multiplies in (`kernels.paged_attention.rows_multiplied`); None
     # on the gather path, and for a ring where there is none
     attention_tiling: Optional[Tuple[Any, Any]]
-    # tick_counts(cursors, slots, windowed=False) -> dict: what one
-    # dispatched step reads and does (the builder's `tick_counts`)
+    # tick_counts(cursors, slots, windowed=False, saved=None) -> dict:
+    # what one dispatched step reads and does (the builder's
+    # `tick_counts`); starts_saved(tables=None, rings=None) -> {name:
+    # int [lanes, groups + 1]}: the DMA starts the kernels' issue loop
+    # saves over those lanes' tables and rings, made once a table, of
+    # which `tick_counts` takes the rows of the lanes `cursors` names
+    # (`saved`) and looks a tick's count up ({} on the gather path)
     tick_counts: Callable
+    starts_saved: Callable
     # {"draft_model": reason, "prefix_cache": reason}: a key for each
     # the server must refuse this block
     refuses: Dict[str, str]
@@ -2065,7 +2071,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     index_chunk = (_index_scores.tiling(nb)[0]
                    if _index_scores is not None else None)
 
-    def tick_counts(cursors, slots, windowed=False):
+    def starts_saved(tables=None, rings=None):
+        """{"table", "index": from `tables` [lanes, table pages];
+        "ring": from `rings` [lanes, ring pages]}, each int [lanes,
+        groups + 1]: the DMA starts the attention kernel's issue loop
+        (the index-score kernel's) saves a pool over each lane's table
+        (ring), as `kernels.paged_attention.starts_saved`'s prefix sums
+        over its groups at that kernel's chunk and unroll.  A request's
+        table does not change after admission and a ring never: made
+        once for each, so that `tick_counts` looks a tick's starts up.
+        Only the keys of what was given and runs through a kernel."""
+        saved = {}
+        if tiling is not None and tables is not None:
+            saved["table"] = _paged_attention.starts_saved(
+                tables, tiling[0][0])
+        if tiling is not None and n_win and rings is not None:
+            saved["ring"] = _paged_attention.starts_saved(
+                rings, tiling[1][0])
+        if index_chunk is not None and tables is not None:
+            saved["index"] = _paged_attention.starts_saved(
+                tables, index_chunk, _index_scores.unroll)
+        return saved
+
+    def tick_counts(cursors, slots, windowed=False, saved=None):
         """What the step dispatched for a tick reads and does, for its
         `serving.decode_tick` span, from `cursors` (int array: the
         step's `positions` at the lanes that hold a sequence) and
@@ -2082,7 +2110,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         every row.  `kv_dma_ops`, through the kernel alone: the DMA
         starts and waits it performs for those pages, summed the same
         way and over the pools (`kernels.paged_attention.dma_ops`: a
-        start a page, a wait for each set bit of a chunk's pages).
+        start a group of table entries that are a run of consecutive
+        blocks and a start a page elsewhere, a wait for each set bit of
+        a chunk's pages; `saved`: `starts_saved`'s rows for the lanes of
+        `cursors`, in their order; without it every page counts a
+        start).
         With sliding layers `past_window` (cursors at or
         past the window: their rings have wrapped) and the rows a layer
         of each kind attends over, `kv_rows_full` (cursor + 1) and
@@ -2108,9 +2140,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         (`kernels["lightning_indexer"]`) the pages each cursor has
         reached and one for a lane with no sequence, with
         `index_dma_ops`, the DMA starts and waits it performs for them
-        (`dma_ops`); on the gather path every page."""
+        (`dma_ops`, a start a group of 16 entries that are a run); on
+        the gather path every page."""
         n = len(cursors)
-        counts = {}
+        counts, saved = {}, saved or {}
         if passes > 1:
             counts["loop_passes"] = passes
         if passes > 1 or subs > 1:
@@ -2121,11 +2154,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if tiling is not None and not windowed:
             idle = slots - n                 # a page each, a layer
             read = multiplied = dma = 0
-            reached = [(planes, -(-rows // bs), tiling[0])]
+            reached = [(planes, -(-rows // bs), tiling[0], "table")]
             if n_win:
                 reached.append(
-                    (n_win, -(-np.minimum(rows, nw * bs) // bs), tiling[1]))
-            for layers_n, pages, (chunk, tile) in reached:
+                    (n_win, -(-np.minimum(rows, nw * bs) // bs), tiling[1],
+                     "ring"))
+            for layers_n, pages, (chunk, tile), held in reached:
                 read += layers_n * (idle + int(pages.sum()))
                 multiplied += layers_n * int(
                     idle * _paged_attention.rows_multiplied(
@@ -2134,7 +2168,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         pages, chunk, tile, bs).sum())
                 dma += layers_n * (1 if latent else 2) * int(
                     idle * _paged_attention.dma_ops(1, chunk)
-                    + _paged_attention.dma_ops(pages, chunk).sum())
+                    + _paged_attention.dma_ops(
+                        pages, chunk, saved.get(held)).sum())
             counts["kv_dma_ops"] = dma
         counts["kv_pages_read"] = read
         counts["kv_pages_table"] = table
@@ -2159,7 +2194,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     idle + int(pages.sum()))
                 counts["index_dma_ops"] = n_index * int(
                     idle * _paged_attention.dma_ops(1, index_chunk)
-                    + _paged_attention.dma_ops(pages, index_chunk).sum())
+                    + _paged_attention.dma_ops(
+                        pages, index_chunk, saved.get("index"),
+                        _index_scores.unroll).sum())
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))
@@ -2226,7 +2263,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         table_layers=planes, ring_layers=n_win, index_planes=n_index,
         state_layers=n_lane, state_bytes_per_lane=state_bytes_per_lane,
         kernels=kernels,
-        attention_tiling=tiling, tick_counts=tick_counts, refuses=refuses)
+        attention_tiling=tiling, tick_counts=tick_counts,
+        starts_saved=starts_saved, refuses=refuses)
     return startup, decoder
 
 

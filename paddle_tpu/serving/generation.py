@@ -495,9 +495,18 @@ class GenerationServer:
         # admitted to a used slot writes over its predecessor's keys,
         # and the step's mask shows a sequence only positions it has
         # written itself
-        self._rings = jax.device_put(
-            decoder.slot_rings(self._slots),
-            self._device) if ring else None
+        rings = decoder.slot_rings(self._slots) if ring else None
+        self._rings = (jax.device_put(rings, self._device) if ring
+                       else None)
+        # the DMA starts the kernels' issue loop saves over each slot's
+        # table and ring (`decoder.starts_saved`), made once a table: a
+        # tick's span looks its slots' counts up.  `_saved_stale`: the
+        # slots whose table was set since; their rows are made by the
+        # next tick that has a span to put them on (`_tick_attrs`), so
+        # a server nobody traces pays nothing at admission (closed32
+        # admits a request every other tick, and its host is 85% busy)
+        self._saved = decoder.starts_saved(self._tables, rings)
+        self._saved_stale = np.zeros(self._slots, bool)
         # the last admission left the queue's head waiting for BLOCKS
         # with a slot free (on the next tick's span as `kv_wait`)
         self._kv_wait = False
@@ -909,12 +918,14 @@ class GenerationServer:
             seq.slot = slot
             self._active[slot] = seq
             self._tables[slot] = table
+            self._saved_stale[slot] = True
             admitted.append(seq)
         return admitted
 
     def _evict_locked(self, seq: _Seq):
         self._active[seq.slot] = None
         self._tables[seq.slot] = 0
+        self._saved_stale[seq.slot] = True
         seq.slot = -1
         with obs_attr.phase("generation", "kv_release"):
             self._cache.release(seq)
@@ -1062,7 +1073,7 @@ class GenerationServer:
             phase_name = "prefill" if prefilling else "decode"
             tables = self._step_tables()
             attrs = (self._tick_attrs(len(rows), prefill,
-                                      positions[active])
+                                      positions[active], active)
                      if live else {})
         clock.mark("build")
         with obs_tracing.span("serving.decode_tick", active=len(rows),
@@ -1117,25 +1128,38 @@ class GenerationServer:
         return self._tables.copy(), self._rings
 
     def _tick_attrs(self, n: int, prefill: int, cur: np.ndarray,
-                    window: bool = False) -> dict:
+                    slots=None, window: bool = False) -> dict:
         """The counts of the tick being dispatched, for its
         `serving.decode_tick` span, from what `build` has already made:
         `n` slots (`len(cur)`), `prefill` of them teacher-forcing a
         prompt position (cursor below prompt_len - 1: they deliver
-        nothing), and `cur`, the step's `positions` at those slots.
-        Only called while a span is live.  The scheduler's own:
+        nothing), and `cur`, the step's `positions` at those slots
+        (`slots`: which they are, a mask over the lanes; a `window` tick
+        needs none).  Only called while a span is live.  The scheduler's own:
         `prefill`, `kv_used` of `kv_total` pool blocks owned, and
         `kv_wait`: 1 where the admission before this tick left the
         queue's head waiting with a slot free because `can_admit`
         refused it for blocks.  What the step reads and does at those
         cursors is the decoder's to count (`decoder.tick_counts`; never
-        through the kernel on a `step_window` tick: `window`)."""
+        through the kernel on a `step_window` tick: `window`; its DMA
+        starts looked up in `_saved`, whose rows for the tables set
+        since the last span are made here, once a table)."""
+        saved = None
+        if slots is not None:
+            stale = self._saved_stale
+            if stale.any():
+                for name, rows in self._decoder.starts_saved(
+                        self._tables[stale]).items():
+                    self._saved[name][stale] = rows
+                stale[:] = False
+            saved = {name: rows[slots]
+                     for name, rows in self._saved.items()}
         return {"prefill": prefill,
                 "kv_used": self._cache.used_blocks,
                 "kv_total": self._cache.num_blocks,
                 "kv_wait": int(self._kv_wait),
                 **self._decoder.tick_counts(cur, self._slots,
-                                            windowed=window)}
+                                            windowed=window, saved=saved)}
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live

@@ -18,6 +18,12 @@ The vLLM PagedAttention design decouples the two:
   finishes, so long and short sequences share the pool without
   fragmentation (any free block serves any sequence; "fragmentation"
   can only exist inside a sequence's LAST partially-filled block).
+  A request's FRESH blocks are handed out side by side and in
+  ascending order of id wherever the free supply allows (`_FreeRuns`:
+  the lowest run of free ids that holds them all, else the longest
+  runs): any blocks in any order are correct, but the decode kernels
+  copy a run of consecutive table entries with one DMA descriptor
+  (kernels/paged_attention.py).
 
 Block 0 is reserved as the null/scratch block: unallocated table
 entries point at it (gathers stay in-bounds; the position mask hides
@@ -44,6 +50,7 @@ models/transformer.build_lm_paged_decoder.
 """
 from __future__ import annotations
 
+import bisect
 import hashlib
 import itertools
 import threading
@@ -90,6 +97,60 @@ class KVPoolExhausted(RuntimeError):
     to free), never as a crash."""
 
 
+class _FreeRuns:
+    """The free blocks, held as RUNS of consecutive ids (`starts[i]` to
+    `ends[i]`, exclusive, ascending and never touching): what an
+    admission wants is not any n blocks but n blocks side by side, and
+    a list popped from its end hands out, after some churn, runs of ten
+    where requests hold a hundred (PERF.md section 6, PR 56).  A few
+    hundred runs at most: every operation is a bisection or a walk over
+    them, under the cache's lock."""
+
+    def __init__(self, first: int, last: int):
+        self.starts, self.ends = [first], [last + 1]
+        self.count = last - first + 1
+
+    def __len__(self) -> int:
+        return self.count
+
+    def add(self, blk: int) -> None:
+        """`blk` comes back: it joins the runs it touches."""
+        i = bisect.bisect_left(self.starts, blk)
+        left = i > 0 and self.ends[i - 1] == blk
+        right = i < len(self.starts) and self.starts[i] == blk + 1
+        if left and right:
+            self.ends[i - 1] = self.ends.pop(i)
+            self.starts.pop(i)
+        elif left:
+            self.ends[i - 1] = blk + 1
+        elif right:
+            self.starts[i] = blk
+        else:
+            self.starts.insert(i, blk)
+            self.ends.insert(i, blk + 1)
+        self.count += 1
+
+    def take(self, n: int) -> List[int]:
+        """`n` blocks (at most `len(self)`) for ONE admission, in
+        ascending order: the head of the lowest run that holds them
+        all, else the longest runs whole until the rest fits one."""
+        taken: List[int] = []
+        self.count -= n
+        while n:
+            lengths = [e - s for s, e in zip(self.starts, self.ends)]
+            i = next((i for i, length in enumerate(lengths) if length >= n),
+                     None)
+            if i is None:
+                i = lengths.index(max(lengths))
+            got = min(n, lengths[i])
+            taken.extend(range(self.starts[i], self.starts[i] + got))
+            self.starts[i] += got
+            if self.starts[i] == self.ends[i]:
+                del self.starts[i], self.ends[i]
+            n -= got
+        return sorted(taken)
+
+
 def _chain_block_hashes(tokens: Sequence[int],
                         block_size: int) -> List[bytes]:
     """Chained content digests for each FULL block of `tokens`: key i
@@ -133,7 +194,7 @@ class PagedKVCache:
         self.prefix_cache = bool(prefix_cache)
         self.bytes_per_block = int(bytes_per_block)
         # device block ids 1..num_blocks (0 is the reserved null block)
-        self._free: List[int] = list(range(1, self.num_blocks + 1))
+        self._free = _FreeRuns(1, self.num_blocks)
         self._owned: Dict[object, List[int]] = {}
         self._ref: Dict[int, int] = {}            # block -> live refs
         self._by_hash: Dict[bytes, int] = {}      # content key -> block
@@ -250,7 +311,7 @@ class PagedKVCache:
         least-recently-released unreferenced cached block (its hash is
         unregistered — the content is about to be overwritten)."""
         if self._free:
-            return self._free.pop()
+            return self._free.take(1)[0]
         if self._lru:
             blk, _ = self._lru.popitem(last=False)
             key = self._hash_of.pop(blk)
@@ -294,6 +355,11 @@ class PagedKVCache:
                 self._lru.pop(blk, None)   # resurrect from eviction
                 hits += 1
             fresh_start = len(blocks)
+            # the fresh blocks side by side where the free runs allow
+            # (`_FreeRuns.take`); what they lack is evicted below
+            blocks += self._free.take(min(n - fresh_start, len(self._free)))
+            for blk in blocks[fresh_start:]:
+                self._ref[blk] = 1
             while len(blocks) < n:
                 blk = self._take_block_locked()
                 if blk is None:
@@ -303,13 +369,20 @@ class PagedKVCache:
                         self._release_block_locked(b)
                     for b in blocks[fresh_start:]:
                         self._ref.pop(b, None)
-                        self._free.append(b)
+                        self._free.add(b)
                     raise KVPoolExhausted(
                         f"need {n} KV blocks, "
                         f"{len(self._free) + len(self._lru)} free "
                         f"(pool {self.num_blocks})")
                 self._ref[blk] = 1
                 blocks.append(blk)
+            # the fresh blocks in ascending order of id (evicted ones
+            # among them), so that what lies side by side in the pool
+            # lies side by side in the table: the attention kernel
+            # copies a run of consecutive table entries with one DMA
+            # descriptor (kernels/paged_attention.start_pages).  Prefix
+            # hits keep the order the prompt gives them.
+            blocks[fresh_start:] = sorted(blocks[fresh_start:])
             self._owned[owner] = blocks
             if keys:
                 self._hits += hits
@@ -360,7 +433,7 @@ class PagedKVCache:
         if blk in self._hash_of:
             self._lru[blk] = None      # park: evictable, still cached
         else:
-            self._free.append(blk)
+            self._free.add(blk)
 
     def release(self, owner) -> None:
         """Drop `owner`'s references (idempotent — a sequence evicted
@@ -385,7 +458,7 @@ class PagedKVCache:
         and free normally on release."""
         with self._lock:
             for blk in list(self._lru):
-                self._free.append(blk)
+                self._free.add(blk)
             self._lru.clear()
             self._by_hash.clear()
             self._hash_of.clear()

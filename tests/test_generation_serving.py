@@ -1662,6 +1662,142 @@ def test_prefix_exhaustion_rolls_back_shared_refs():
     cache.close()
 
 
+def _ascending(ids):
+    return all(b > a for a, b in zip(ids, ids[1:]))
+
+
+def _consecutive(ids):
+    return all(b == a + 1 for a, b in zip(ids, ids[1:]))
+
+
+def _fresh_pool(cache):
+    """A fresh pool hands out its lowest ids, side by side."""
+    a, b = cache.allocate("a", 20), cache.allocate("b", 9)
+    assert list(a[:5]) == [1, 2, 3, 4, 5] and list(b[:3]) == [6, 7, 8]
+    return [(a, 5, True), (b, 3, True)]
+
+
+def _released_then_taken(cache):
+    """A released table's blocks come back as the run they were, and
+    the lowest run that holds an admission whole serves it; one that no
+    run holds takes the longest runs, ascending over all of them."""
+    a = cache.allocate("a", 20)             # 1..5
+    cache.allocate("between", 4)            # 6
+    cache.allocate("b", 12)                 # 7..9
+    cache.allocate("rest", 28)              # 10..16
+    cache.release("a")
+    again = cache.allocate("d", 20)
+    assert list(again[:5]) == list(a[:5])
+    cache.release("d")
+    cache.release("b")                      # free: 1..5 and 7..9
+    two = cache.allocate("e", 12)           # fits the lower run's head
+    assert list(two[:3]) == [1, 2, 3]
+    cache.release("e")
+    spans = cache.allocate("f", 28)         # 7 blocks: no run holds them
+    assert list(spans[:7]) == [1, 2, 3, 4, 5, 7, 8]
+    return [(again, 5, True), (spans, 7, False)]
+
+
+def _lru_evicted(cache):
+    """Cached blocks parked in the LRU are evicted oldest first when
+    the free runs are dry: fresh ids all the same, ascending with the
+    free ones."""
+    prompt = list(range(24))
+    cache.allocate_prefix("x", 24, prompt_tokens=prompt)
+    cache.commit_prefix("x", 24)
+    cache.release("x")                      # 6 blocks park, cached
+    assert cache.cached_blocks == 6
+    got = cache.allocate("y", 16 * 4)       # the 10 free and the 6 parked
+    assert cache.cached_blocks == 0 and cache.free_blocks == 0
+    return [(got, 16, True)]
+
+
+def _hits_then_fresh(cache):
+    """Prefix hits keep the order the prompt gives them, and the fresh
+    blocks after them are a run of their own."""
+    cache.allocate("hole", 8)
+    prompt = list(range(16))
+    first, _ = cache.allocate_prefix("x", 16, prompt_tokens=prompt)
+    cache.commit_prefix("x", 16)
+    cache.allocate("other", 12)
+    got, cached = cache.allocate_prefix("y", 40, prompt_tokens=prompt)
+    assert cached == 16 and list(got[:4]) == list(first[:4])
+    fresh = [int(b) for b in got[4:10]]
+    assert _consecutive(fresh) and fresh[0] > first[3] + 1
+    assert cache.refcount(int(first[0])) == 2
+    return [(first, 4, True), (got, 10, False)]
+
+
+@pytest.mark.parametrize("case", [_fresh_pool, _released_then_taken,
+                                  _lru_evicted, _hits_then_fresh],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_fresh_blocks_are_handed_out_ascending(case):
+    """`allocate_prefix` hands a request its FRESH blocks in ascending
+    order of id, consecutive where the supply is: a table then names
+    runs, which the attention kernel copies with one DMA descriptor a
+    group (kernels/paged_attention.start_pages)."""
+    cache = PagedKVCache(16, 4, 16, prefix_cache=True)
+    for table, n, one_run in case(cache):
+        ids = [int(b) for b in table[:n]]
+        assert all(ids) and not table[n:].any()
+        assert _ascending(ids)
+        assert _consecutive(ids) == one_run
+    cache.close()
+
+
+@pytest.mark.parametrize("back,runs", [
+    ([5], [(5, 6)]), ([5, 6], [(5, 7)]), ([6, 5], [(5, 7)]),
+    ([4, 6, 5], [(4, 7)]), ([9, 3, 7, 8], [(3, 4), (7, 10)]),
+    ([3, 4, 5, 6, 7, 8, 9, 10], [(3, 11)])],
+    ids=["one", "up", "down", "joins-two", "apart", "all"])
+def test_free_runs_join_what_touches_and_serve_the_lowest_fit(back, runs):
+    """`_FreeRuns`: a block that comes back joins the runs it touches;
+    an admission takes the head of the lowest run that holds it whole,
+    else the longest runs first."""
+    from paddle_tpu.serving.kv_cache import _FreeRuns
+
+    free = _FreeRuns(1, 10)
+    assert free.take(10) == list(range(1, 11)) and len(free) == 0
+    free.add(1)
+    for blk in back:
+        free.add(blk)
+    assert list(zip(free.starts, free.ends)) == [(1, 2)] + runs
+    assert len(free) == 1 + len(back)
+    longest = max(runs, key=lambda r: r[1] - r[0])
+    n = longest[1] - longest[0]
+    # block 1's run holds one block; n > 1 needs the lowest run of n
+    first = next(r for r in [(1, 2)] + runs if r[1] - r[0] >= n)
+    assert free.take(n) == list(range(first[0], first[0] + n))
+    rest = len(free)
+    assert sorted(free.take(rest)) == sorted(
+        set([1] + back) - set(range(first[0], first[0] + n)))
+    assert not free and not free.starts
+
+
+def test_exhaustion_rolls_back_under_ascending_order():
+    """The roll-back on `KVPoolExhausted` is what it was: the free
+    count, the reference counts and the pending list of the owner that
+    holds blocks are untouched, and the one refused holds nothing."""
+    cache = PagedKVCache(6, 4, 8, prefix_cache=True)
+    prompt = list(range(12))
+    t, _ = cache.allocate_prefix("x", 14, prompt_tokens=prompt)
+    cache.commit_prefix("x", 8)             # two of three full blocks
+    pending = list(cache._pending["x"])
+    assert [blk for _, _, blk in pending] == [int(t[2])]
+    free, refs = cache.free_blocks, dict(cache._ref)
+    assert not cache.can_admit(28, prompt_tokens=prompt)
+    with pytest.raises(KVPoolExhausted):    # 2 hits + 5 fresh of 2 free
+        cache.allocate_prefix("y", 28, prompt_tokens=prompt)
+    assert cache.free_blocks == free and cache._ref == refs
+    assert cache._pending == {"x": pending} and "y" not in cache._owned
+    # the pending list pairs keys with blocks by POSITION: the block
+    # committed under the third key is the table's third
+    cache.commit_prefix("x", 12)
+    got, cached = cache.allocate_prefix("z", 13, prompt_tokens=prompt)
+    assert cached == 12 and list(got[:3]) == list(t[:3])
+    cache.close()
+
+
 def test_prefix_caching_skips_prefill_bit_identical():
     """Shared-prefix admissions skip prefill ticks (cursor starts past
     the hit blocks) and stay bit-identical to a cold server — incl.

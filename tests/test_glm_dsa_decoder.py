@@ -332,6 +332,66 @@ def test_the_ticks_index_pages_are_what_the_cursors_say(monkeypatch):
     assert full["index_pages_read"] == full["index_pages_table"]
 
 
+def _walk(table, n_pages, pages, unroll):
+    """DMA starts and waits a pool of a kernel that copies a lane's
+    first `n_pages` pages in chunks of `pages`, walking its table page
+    by page: `unroll` entries at a time, ONE start where they are
+    consecutive ascending ids and `unroll` where not, a start for each
+    page of a chunk past its last whole group, a wait for each set bit
+    of a chunk's pages."""
+    unroll, ops = min(unroll, pages), 0
+    for first in range(0, n_pages, pages):
+        copied = min(pages, n_pages - first)
+        for g in range(copied // unroll):
+            ids = table[first + g * unroll:first + (g + 1) * unroll]
+            ops += 1 if all(b == ids[0] + j for j, b in enumerate(ids)) \
+                else unroll
+        ops += copied % unroll + bin(copied).count("1")
+    return ops
+
+
+@pytest.mark.parametrize("given", [True, False], ids=["tables", "none"])
+def test_the_ticks_dma_ops_are_a_walk_of_the_tables(monkeypatch, given):
+    """`kv_dma_ops` and `index_dma_ops` count what the two kernels'
+    issue loop does over the lanes' tables (`decoder.starts_saved`, made
+    once a table, looked up at the tick's cursors): a run, a shuffled
+    table, a shared prefix then fresh blocks and an idle lane, in
+    chunks of 5 latent pages (groups of 5) and of 6 index pages; with
+    no table given every page counts a start, as before PR 56."""
+    _interpreted(monkeypatch, chunk_bytes=5 * BS * 128 * 4)
+    _indexer_interpreted(monkeypatch, pages=6)
+    dec = _decoder()
+    (pages, _), _ = dec.attention_tiling
+    assert pages == 5 and paged_index_scores._ISSUE_UNROLL == 16
+    r = np.random.RandomState(0)
+    tables = np.zeros((4, NB), np.int32)
+    tables[0] = 1 + np.arange(NB)
+    tables[1] = 1 + NB + r.permutation(NB)
+    tables[2, :7], tables[2, 7:] = tables[0, :7], 40 + np.arange(NB - 7)
+    cursors = np.array([NB * BS - 1, 45, 58])
+    saved = dec.starts_saved(tables)
+    assert sorted(saved) == ["index", "table"]
+    assert saved["table"][:, -1].tolist() == [4 * (NB // 5), 0, 4 * 2, 0]
+    counts = dec.tick_counts(
+        cursors, 4, saved={k: v[:3] for k, v in saved.items()}
+        if given else None)
+    if not given:
+        tables = tables[:, ::-1]        # no run anywhere
+    n_pages = cursors // BS + 1
+    planes, index_planes = dec.kv_planes, dec.index_planes
+    assert (planes, index_planes) == (L, 2)
+    # the idle lane's page: a start and a wait
+    assert counts["kv_dma_ops"] == planes * (2 + sum(
+        _walk(tables[lane].tolist(), n, 5, 8)
+        for lane, n in enumerate(n_pages)))
+    assert counts["index_dma_ops"] == index_planes * (2 + sum(
+        _walk(tables[lane].tolist(), n, 6, 16)
+        for lane, n in enumerate(n_pages)))
+    plain = dec.tick_counts(cursors, 4)
+    assert (counts == plain) == (not given)
+    assert all(counts[k] <= v for k, v in plain.items())
+
+
 def test_fewer_rows_than_index_topk_is_dense_attention_exactly():
     """While a lane holds no more rows than `index_topk` the selection
     is every row under the cursor: the logits are those of the SAME
@@ -901,6 +961,44 @@ def test_the_cost_functions_and_the_readers_on_a_synthetic_run(monkeypatch):
             assert (mod.LAYER, mod.UNIT, mod.MOVES, mod.SOURCE) == (
                 spec["layer"], spec["unit"], spec["moves"], spec["source"])
             assert spec["workloads"] == ["glm-5.2-serve-docqa64"]
+
+
+def test_the_index_dma_ops_reader_on_a_synthetic_run(monkeypatch):
+    """`sched_index_dma_ops_per_page` on a `Run` made by hand: the
+    window's ticks that carry the index kernel's DMA count, over the
+    pages they read; ticks outside it, and those of a program without
+    the count (the gather path, a parent before PR 54), left out."""
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "perf"))
+    import common
+
+    reader = common.load_module(os.path.join(
+        ROOT, "perf", "metrics", "sched_index_dma_ops_per_page.py"))
+
+    def tick(ts, **attrs):
+        return {"name": "serving.decode_tick", "ts": ts, "dur": 0.5,
+                "attrs": attrs}
+
+    ticks = [tick(5.0, index_pages_read=100, index_dma_ops=900),
+             tick(10.0, index_pages_read=600, index_dma_ops=90),
+             tick(11.0, index_pages_read=400, index_dma_ops=60),
+             tick(12.0, index_pages_read=5000, index_pages_table=5000),
+             tick(13.0, kv_pages_read=5, kv_dma_ops=10),
+             tick(20.0, index_pages_read=100, index_dma_ops=900)]
+    monkeypatch.setattr(tracing, "finished_spans", lambda: ticks)
+    run = common.Run()
+    run.spans = [{"name": "x", "ts": 9.0, "dur": 0.1},
+                 {"name": "x", "ts": 14.0, "dur": 0.1}]
+    assert reader.compute(run) == pytest.approx(0.15)
+    monkeypatch.setattr(tracing, "finished_spans", lambda: ticks[3:5])
+    assert reader.compute(run) is None
+    run.spans = []
+    assert reader.compute(run) is None
+    spec, = (m for m in _json("BENCHMARK.json")["per_layer"]
+             if m["name"] == "sched_index_dma_ops_per_page")
+    assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
+        spec["layer"], spec["unit"], spec["moves"], spec["source"])
+    assert spec["better"] == "lower"
+    assert spec["workloads"] == ["glm-5.2-serve-docqa64"]
 
 
 def test_the_index_pages_reader_on_a_synthetic_run(monkeypatch):

@@ -672,13 +672,14 @@ def test_the_bytes_and_the_two_readers_on_a_synthetic_run(monkeypatch):
     assert {n: r.compute(run) for n, r in readers.items()} == dict.fromkeys(
         readers)
     bench = _json("BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-2:]] == list(readers)
-    for spec in bench["per_layer"][-2:]:
+    specs = [m for m in bench["per_layer"] if m["name"] in readers]
+    assert [m["name"] for m in specs] == list(readers)
+    for spec in specs:
         mod = readers[spec["name"]]
         assert (mod.LAYER, mod.UNIT, mod.MOVES, mod.SOURCE) == (
             spec["layer"], spec["unit"], spec["moves"], spec["source"])
         assert spec["workloads"] == [CELL]
-    assert bench["per_layer"][-1]["better"] == "higher"
+    assert specs[-1]["better"] == "higher"
 
 
 def test_the_job_makes_the_two_assumed_arrays_and_reads_the_tails():

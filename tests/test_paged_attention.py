@@ -406,6 +406,13 @@ _DMA_GEOMETRIES = {
     "kv-grouped": (4, 16, 2, 0, 4, "bfloat16"),
     "latent": (8, 128, 1, 32, 3, "float32"),
 }
+# The issue loop's groups (PR 56): chunks of 11 pages, so a chunk is a
+# group of `_ISSUE_UNROLL` (8) table entries and three pages that go
+# one by one, over a K and a V pool and over a latent one.
+_DMA_GEOMETRIES.update({
+    "kv-runs": (4, 8, 4, 0, 11, "float32"),
+    "latent-runs": (8, 128, 1, 32, 11, "float32"),
+})
 # pages a slot holds, by name; None: a lane with no sequence
 _DMA_LENGTHS = {
     "1": lambda pages: 1, "2": lambda pages: 2, "3": lambda pages: 3,
@@ -415,10 +422,18 @@ _DMA_LENGTHS = {
 }
 
 
-def _dma_case(geometry, lanes_pages, write, interpret=True):
+def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
+              select=False, behind=None):
     """The kernel over lanes of `lanes_pages` pages (None: a lane with
     no sequence) against plain `jax.numpy` after a scatter of the
-    written row -> (got, want, pools got, pools wanted, active)."""
+    written row -> (got, want, pools got, pools wanted, active).
+    `tables` [lanes, 2 * pages + 3]: the lanes' block ids (the lanes'
+    own blocks shuffled without).  `select`: a latent pool's row mask,
+    a third of the rows and a lane's first.  `behind`: a permutation of
+    the pool's block ids; the kernel is then given the SAME pages
+    behind another table (block b's page in block `behind[b]`, the
+    table renamed), and the pools it returns are read back through
+    it."""
     import jax
     import jax.numpy as jnp
 
@@ -436,26 +451,39 @@ def _dma_case(geometry, lanes_pages, write, interpret=True):
     pools = [jnp.asarray(r.randn(2, 1 + s_n * nb, bs, row), dtype)
              for _ in range(1 if d_value else 2)]
     # a table names any block: the lanes' blocks shuffled
-    tables = 1 + r.permutation(s_n * nb).astype(np.int32).reshape(s_n, nb)
+    shuffled = 1 + r.permutation(s_n * nb).astype(np.int32).reshape(s_n, nb)
+    tables = shuffled if tables is None else np.asarray(tables, np.int32)
     q = jnp.asarray(r.randn(s_n, h * (row if d_value else dh)), jnp.float32)
     news = [jnp.asarray(r.randn(s_n, row), jnp.float32) for _ in pools]
+    mask = None
+    if select:
+        mask = r.rand(s_n, nb * bs) < 1 / 3
+        mask[:, 0] = True
     wanted = list(pools)
     if write:
         lane = np.arange(s_n)
         wb = np.where(active, tables[lane, pos // bs], 0)
         wanted = [pool.at[1, wb, pos % bs].set(new.astype(dtype))
                   for pool, new in zip(pools, news)]
+    given, names = pools, tables
+    if behind is not None:
+        # block b's page now lies in block behind[b]
+        given = [pool[:, np.argsort(behind)] for pool in pools]
+        names = np.asarray(behind, np.int32)[tables]
     out = paged_attention.paged_attention(
-        q, pools[0], None if d_value else pools[1], tables,
+        q, given[0], None if d_value else given[1], names,
         jnp.asarray(lengths, jnp.int32), 1, scale=0.3, pages=pages,
         tile=2, n_heads=h, d_head=dh, d_value=d_value,
         interpret=interpret,
         write=((news[0], None if d_value else news[1],
-                np.where(active, pos, -1)) if write else None))
+                np.where(active, pos, -1)) if write else None),
+        select=None if mask is None else jnp.asarray(mask))
     # (the interpreter's callbacks read arrays on a thread of their
     # own: an op dispatched beside a running kernel can deadlock it)
     out = jax.block_until_ready(out)
-    got, got_pools = (out[0], out[1:]) if write else (out, pools)
+    got, got_pools = (out[0], out[1:]) if write else (out, given)
+    if behind is not None:
+        got_pools = [pool[:, behind] for pool in got_pools]
     want = []
     for lane in range(s_n):
         rows = [pool[1, tables[lane]].reshape(nb * bs, row)[
@@ -463,8 +491,10 @@ def _dma_case(geometry, lanes_pages, write, interpret=True):
         keys, values = rows[0], rows[-1][:, :d_value or row]
         ql = q[lane].astype(dtype).astype(jnp.float32)
         if d_value:
-            sc = ql.reshape(h, row) @ keys.T
-            want.append((jax.nn.softmax(sc * 0.3, -1) @ values).reshape(-1))
+            sc = ql.reshape(h, row) @ keys.T * 0.3
+            if select:
+                sc = jnp.where(mask[lane, :lengths[lane]], sc, -jnp.inf)
+            want.append((jax.nn.softmax(sc, -1) @ values).reshape(-1))
             continue
         ql = ql.reshape(n_kv, h // n_kv, dh)
         sc = jnp.einsum("gid,tgd->git", ql, keys.reshape(-1, n_kv, dh))
@@ -494,6 +524,122 @@ def test_a_chunk_is_waited_for_on_its_summed_bytes(geometry, length, write):
     for pool, same in zip(pools, wanted):
         np.testing.assert_array_equal(np.asarray(pool[:, 1:], np.float32),
                                       np.asarray(same[:, 1:], np.float32))
+
+
+# What a table names, [lanes, table pages] int32 over the lanes' own
+# blocks (lane s's: 1 + s * nb on), by name.  `group`: the issue loop's.
+def _ascending_tables(s_n, nb, pages, group):
+    return 1 + np.arange(s_n * nb, dtype=np.int32).reshape(s_n, nb)
+
+
+def _descending_tables(s_n, nb, pages, group):
+    return _ascending_tables(s_n, nb, pages, group)[:, ::-1].copy()
+
+
+def _shuffled_tables(s_n, nb, pages, group):
+    return 1 + np.random.RandomState(11).permutation(
+        s_n * nb).astype(np.int32).reshape(s_n, nb)
+
+
+def _broken_inside_a_group(s_n, nb, pages, group):
+    """Runs but for two neighbours swapped inside the first chunk's
+    first group, at another place a lane."""
+    tables = _ascending_tables(s_n, nb, pages, group)
+    for lane in range(s_n):
+        at = lane % (group - 1)
+        tables[lane, [at, at + 1]] = tables[lane, [at + 1, at]]
+    return tables
+
+
+def _run_across_a_chunks_edge(s_n, nb, pages, group):
+    """No run up to three pages before the first chunk's end, one run
+    from there on: it starts inside a group, covers the chunk's pages
+    that go one by one and goes on through the next chunk's groups."""
+    tables = _ascending_tables(s_n, nb, pages, group)
+    r = np.random.RandomState(12)
+    for lane in range(s_n):
+        head = pages - 3 - lane % 2
+        tables[lane, :head] = r.permutation(tables[lane, :head])
+    return tables
+
+
+def _prefix_then_fresh(s_n, nb, pages, group):
+    """Every lane's first `group - 3` pages are lane 0's (a prefix hit:
+    one run), its own fresh run after them: the first group straddles
+    the two.  (No lane's cursor is in a shared page: a lane writes
+    where it alone reads.)"""
+    tables = _ascending_tables(s_n, nb, pages, group)
+    tables[:, :group - 3] = tables[0, :group - 3]
+    return tables
+
+
+def _idle_ring_of_block_0(s_n, nb, pages, group):
+    """Runs, and a lane with no sequence between them: every entry of
+    its table the null block."""
+    tables = _ascending_tables(s_n, nb, pages, group)
+    tables[1] = 0
+    return tables
+
+
+_RUN_TABLES = {f.__name__.strip("_"): f for f in (
+    _ascending_tables, _descending_tables, _shuffled_tables,
+    _broken_inside_a_group, _run_across_a_chunks_edge, _prefix_then_fresh,
+    _idle_ring_of_block_0)}
+# pages a lane holds: the whole table (two chunks and three pages), a
+# chunk to its last page, lengths that end inside a chunk's group and
+# among the pages after it
+_RUN_LANES = (2 * 11 + 3, 11, 11 + 5, 11 + 9)
+
+
+@pytest.mark.parametrize("pool", ["kv-write", "latent-write",
+                                  "latent-select"])
+@pytest.mark.parametrize("kind", sorted(_RUN_TABLES) + ["ends_inside_a_group"])
+def test_a_run_of_pages_is_one_copy_and_the_same_pages(kind, pool):
+    """Where the table entries an issue-loop iteration takes are
+    consecutive ascending block ids the kernel starts ONE copy of the
+    group, else a copy a page, and nothing else may follow from it:
+    whatever a table names (runs up or down, no run, a run broken
+    inside a group, one that crosses a chunk's edge, a shared prefix
+    then fresh blocks, an idle lane's block 0 throughout, lengths that
+    end inside a group) the result and the pools equal plain attention
+    after a scatter, AND equal, bit for bit, the kernel's own result on
+    the same pages behind a shuffled table (a copy a page)."""
+    geometry = "kv-runs" if pool.startswith("kv") else "latent-runs"
+    pages = _DMA_GEOMETRIES[geometry][4]
+    group = min(paged_attention._ISSUE_UNROLL, pages)
+    assert (pages, group) == (11, 8)
+    lanes = list(_RUN_LANES)
+    if kind == "ends_inside_a_group":
+        lanes, kind = [3, group - 1, pages + 2, pages + group - 1], \
+            "ascending_tables"
+    if kind == "idle_ring_of_block_0":
+        lanes[1] = None
+    nb = 2 * pages + 3
+    tables = _RUN_TABLES[kind](len(lanes), nb, pages, group)
+    # the groups of these tables that are runs, by `starts_saved`: all
+    # of an ascending table's, none of a descending or shuffled one's
+    saved = paged_attention.starts_saved(tables, pages)[:, -1] // (group - 1)
+    whole = nb // pages * (pages // group)
+    assert {"ascending_tables": (saved == whole).all(),
+            "descending_tables": not saved.any(),
+            "shuffled_tables": not saved.any()}.get(kind, saved.any())
+    # a selection as the cell has it, beside the written row
+    kw = dict(write=True, tables=tables, select=pool == "latent-select")
+    got, want, pools, wanted, active = _dma_case(geometry, lanes, **kw)
+    np.testing.assert_allclose(got[active], want[active],
+                               atol=2e-6 * float(np.abs(want).max()))
+    behind = np.concatenate([[0], 1 + np.random.RandomState(13).permutation(
+        len(lanes) * nb)])
+    twin, _, twin_pools, _, _ = _dma_case(geometry, lanes, behind=behind,
+                                          **kw)
+    assert not paged_attention.starts_saved(
+        behind[tables], pages)[:, -1].any()
+    np.testing.assert_array_equal(got[active], twin[active])
+    for pool_got, pool_twin, same in zip(pools, twin_pools, wanted):
+        np.testing.assert_array_equal(np.asarray(pool_got[:, 1:]),
+                                      np.asarray(same[:, 1:]))
+        np.testing.assert_array_equal(np.asarray(pool_twin[:, 1:]),
+                                      np.asarray(same[:, 1:]))
 
 
 def _loops_around(jaxpr, name, depth=0):
@@ -886,9 +1032,10 @@ def test_rows_multiplied_follow_the_windows(pages, tile, n_pages, want):
             for n in n_pages] == want
 
 
-def _tick_attrs(dec, states):
-    """The attributes of the `serving.decode_tick` spans of one request
-    of 5 prompt tokens and 6 new ones on a server of 3 slots."""
+def _tick_attrs(dec, states, new=6, requests=1):
+    """The attributes of the `serving.decode_tick` spans of `requests`
+    requests, one after another, of 5 prompt tokens and `new` new ones
+    on a server of 3 slots."""
     from paddle_tpu.observability import tracing
 
     tracing.set_enabled(True)
@@ -896,7 +1043,9 @@ def _tick_attrs(dec, states):
     srv = GenerationServer(dec, states, slots=3, kv_blocks=12,
                            place=fluid.CPUPlace())
     try:
-        srv.submit([3, 1, 4, 1, 5], 6).result(timeout=120)
+        for i in range(requests):       # no prompt a prefix of another
+            srv.submit([3 + i, 1, 4, 1, 5], new).result(timeout=120)
+        assert not srv._tables.any()
     finally:
         srv.close()
         tracing.set_enabled(False)
@@ -921,6 +1070,33 @@ def test_tick_spans_count_the_pages_read():
     assert [a["kv_pages_read"] for a in got] == [
         2 * (-(-(c + 1) // 4) + 2) for c in range(len(got))]
     assert len(got) == 10
+
+
+def test_tick_spans_count_a_start_for_a_group_that_is_a_run():
+    """`kv_dma_ops` on `serving.decode_tick` counts what the issue loop
+    does with the slot's table as the cache manager made it: a fresh
+    pool hands a request's 4 blocks out ascending, so once the cursor
+    reaches the fourth page the chunk's one group of 4 is ONE start
+    (and one wait) a pool a layer where three pages were three starts
+    and two waits; the idle slots' page a start and a wait each.  The
+    server looks the count up in what it made of the table, once,
+    when the table's first tick had a span (`decoder.starts_saved`):
+    the second request's table, set in a used slot, counts the same."""
+    from paddle_tpu.models.transformer import build_lm_paged_decoder
+
+    _, states = _decoder()
+    with _interpreted():        # the table's 4 pages one chunk
+        fw.reset_unique_names()
+        _, dec_p = build_lm_paged_decoder(
+            V, 4, 4, d_model=32, n_heads=2, n_layers=2, platform="cpu")
+    assert dec_p.attention_tiling[0][0] == 4
+    got = _tick_attrs(dec_p, states, new=11, requests=2)
+    assert len(got) == 2 * 15
+    for tick, a in enumerate(got):
+        n = tick % 15 // 4 + 1
+        ops = 2 if n == 4 else n + bin(n).count("1")
+        # 2 layers, a K and a V pool; two idle slots
+        assert a["kv_dma_ops"] == 2 * 2 * (ops + 2 * (1 + 1))
 
 
 def test_tick_spans_count_the_rows_multiplied():

@@ -153,6 +153,97 @@ def test_rows_past_the_length_are_the_callers_to_mask():
                                    atol=1e-5)
 
 
+# The issue loop's groups (`paged_attention.start_pages`, PR 56): a
+# table of 43 pages in chunks of 20, so a chunk is a group of
+# `_ISSUE_UNROLL` (16) table entries and four pages that go one by one.
+RUN_NB, RUN_PAGES = 43, 20
+
+
+def _run_tables(kind, s_n):
+    """[s_n, RUN_NB] int32 over the lanes' own blocks (lane s's: 1 + s
+    * RUN_NB on), by name."""
+    group = min(pis._ISSUE_UNROLL, RUN_PAGES)
+    up = 1 + np.arange(s_n * RUN_NB, dtype=np.int32).reshape(s_n, RUN_NB)
+    r = np.random.RandomState(11)
+    if kind == "descending":
+        return up[:, ::-1].copy()
+    if kind == "shuffled":
+        return 1 + r.permutation(s_n * RUN_NB).astype(np.int32).reshape(
+            s_n, RUN_NB)
+    if kind == "broken_inside_a_group":
+        # two neighbours swapped in the first chunk's group
+        for lane in range(s_n):
+            at = (5 * lane) % (group - 1)
+            up[lane, [at, at + 1]] = up[lane, [at + 1, at]]
+    if kind == "run_across_a_chunks_edge":
+        # no run up to three pages before the first chunk's end
+        for lane in range(s_n):
+            up[lane, :RUN_PAGES - 3] = r.permutation(up[lane, :RUN_PAGES - 3])
+    if kind == "prefix_then_fresh":
+        # lane 0's first pages under every lane, then its own run
+        up[:, :group - 5] = up[0, :group - 5]
+    if kind == "idle_ring_of_block_0":
+        up[1] = 0
+    return up
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("kind", [
+    "ascending", "descending", "shuffled", "broken_inside_a_group",
+    "run_across_a_chunks_edge", "prefix_then_fresh", "idle_ring_of_block_0",
+    "ends_inside_a_group"])
+def test_a_run_of_pages_is_one_copy_and_the_same_scores(kind, dtype):
+    """Whatever a table names (runs up or down, no run, a run broken
+    inside a group, one across a chunk's edge, a shared prefix then
+    fresh blocks, an idle lane's block 0 throughout, lengths that end
+    inside a group) the scores under the cursors equal the gather's,
+    AND equal, bit for bit, the kernel's own on the same pages behind a
+    shuffled table (a copy a page)."""
+    from paddle_tpu.kernels import paged_attention
+
+    group = min(pis._ISSUE_UNROLL, RUN_PAGES)
+    # pages a lane holds: the whole table, a chunk to its last page,
+    # ends inside the second chunk's group and among the pages after it
+    n_pages = [RUN_NB, RUN_PAGES, RUN_PAGES + 7, 2 * RUN_PAGES - 2]
+    if kind == "ends_inside_a_group":
+        n_pages = [3, group - 1, RUN_PAGES + 2, RUN_PAGES + group - 1]
+    s_n = len(n_pages)
+    tables = _run_tables(kind, s_n)
+    active = tables.any(axis=1)
+    cur = np.where(active, np.asarray(n_pages) * BS - 1 - np.arange(s_n), 0)
+    saved = paged_attention.starts_saved(
+        tables, RUN_PAGES, pis._ISSUE_UNROLL)[:, -1] // (group - 1)
+    assert {"ascending": (saved == 2).all(), "descending": not saved.any(),
+            "shuffled": not saved.any()}.get(kind, saved.any())
+    r = np.random.RandomState(3)
+    pool = jnp.asarray(r.randn(2, s_n * RUN_NB + 1, BS, D) * 0.5, dtype)
+    q = jnp.asarray(r.randn(s_n, H, D) * 0.3, jnp.float32)
+    w = jnp.asarray(r.randn(s_n, H), jnp.float32)
+    valid = np.arange(RUN_NB * BS)[None, :] <= cur[:, None]
+
+    def scores(pool, tables):
+        return np.where(valid, np.asarray(pis.paged_index_scores(
+            q, w, pool, jnp.asarray(tables), jnp.asarray(cur + 1, jnp.int32),
+            1, pages=RUN_PAGES, tile=TILE, interpret=True)), -np.inf)
+
+    got = scores(pool, tables)
+    keys = pool[1, tables].reshape(s_n, RUN_NB * BS, D)
+    dots = jax.lax.dot_general(
+        q.astype(keys.dtype), keys, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    want = np.where(valid, np.asarray(
+        (jax.nn.relu(dots) * w[:, :, None]).sum(axis=1)), -np.inf)
+    _agree(got, want, jnp.asarray(valid))
+    # block b's page in block behind[b], the table renamed
+    behind = np.concatenate(
+        [[0], 1 + r.permutation(s_n * RUN_NB)]).astype(np.int32)
+    assert not paged_attention.starts_saved(
+        behind[tables], RUN_PAGES, pis._ISSUE_UNROLL)[:, -1].any()
+    np.testing.assert_array_equal(
+        got, scores(pool[:, np.argsort(behind)], behind[tables]))
+
+
 @pytest.mark.parametrize("kw,reason", [
     (dict(platform="cpu"), "not_tpu"),
     (dict(platform="gpu"), "not_tpu"),

@@ -20,7 +20,11 @@ out together.
 `--shape`: a name of `SHAPES` (a cell's slots, heads, pool row, tables
 and rings with their layers, and a seeded mix of cursor lengths).
 `--block-sizes`: the same rows in pages of so many rows (the cell's
-first).  `--check`: the whole kernel's result against plain `jax.numpy`
+first).  `--tables`: what a lane's table names, `consecutive` blocks
+(a fresh pool's, a ring's: the runs the kernels' issue loop takes one
+descriptor a group for) or the same blocks `shuffled` (no two in a
+row: a descriptor a page); the paged shapes' alone.  `--check`: the
+whole kernel's result against plain `jax.numpy`
 over random pools, before any timing.  `--rehearse`: the name's shape
 cut to a toy and run in the Pallas interpreter on the CPU, to see that
 the script runs: never a number.
@@ -76,6 +80,14 @@ SHAPES = {
     "opt-1.3b-serve-closed32": dict(
         slots=32, heads=32, d_head=64, row=2048, d_value=0, bs=16,
         ctx=512, layers=((512, 24),), scale=0.125, mean_rows=140),
+    # DeepSeek's latent row under 64 heads and a lightning indexer's
+    # SELECTION: a row mask of 2048 rows a lane over docqa64's cursors
+    # (a document and what a request has added: mean 4.9 k rows), a
+    # table of 432 pages over 5 planes
+    "glm-5.2-serve-docqa64": dict(
+        slots=64, heads=64, d_head=0, row=640, d_value=512, bs=16,
+        ctx=6912, layers=((6912, 5),), scale=0.0625, cursors="docqa",
+        select=2048),
     # the flash kernels of one layer's training step: 4 sequences of
     # 2048, 32 heads of 64 packed in pairs, bf16, causal, the blocks
     # `_select_blocks` gives (512 x 1024)
@@ -101,11 +113,15 @@ SHAPES = {
 }
 VARIANTS = ("whole", "no_copies", "no_products")
 FLASH_VARIANTS = ("whole", "no_mask", "uncut")
+TABLES = ("consecutive", "shuffled")
 
 
 def lengths_of(shape):
     """The cursors' mix: lognormal about `mean_rows` (900: the long
-    cells' mean by `sched_kv_pages_read_share`), cut to the context."""
+    cells' mean by `sched_kv_pages_read_share`), cut to the context;
+    docqa64's where the shape asks for them."""
+    if shape.get("cursors") == "docqa":
+        return index_lengths(shape)
     r = np.random.RandomState(0)
     mean = shape.get("mean_rows", 900)
     return np.clip(r.lognormal(np.log(mean), 0.6, shape["slots"]), 30,
@@ -143,7 +159,23 @@ def removed(variant):
         jax.clear_caches()
 
 
-def build(shape, bs, pa, interpret=False):
+def lane_tables(s_n, nb, blocks, order):
+    """[s_n, nb] int32 block ids of a pool of `blocks` blocks (block 0
+    the null block): `consecutive`, a run of `nb` ascending ids a lane
+    (lanes share blocks where the pool holds fewer than they name, as
+    lanes that ask one document do); `shuffled`, the same ids in an
+    order in which no run survives."""
+    first = 1 + (np.arange(s_n) * nb) % max(1, blocks - nb)
+    tables = first[:, None] + np.arange(nb)[None, :]
+    if order == "shuffled":
+        r = np.random.RandomState(2)
+        tables = np.stack([r.permutation(row) for row in tables])
+    elif order != "consecutive":
+        raise ValueError(f"no tables {order!r}: one of {TABLES}")
+    return tables.astype(np.int32)
+
+
+def build(shape, bs, pa, interpret=False, order="consecutive"):
     """-> (a jitted function over every layer of every table and ring
     of `shape` at pages of `bs` rows, its arguments, what a reference
     needs beside them)."""
@@ -172,10 +204,20 @@ def build(shape, bs, pa, interpret=False):
         pools.append(tuple(normal(dims, 0.5)
                            for _ in range(1 if d_value else 2)))
         tables.append(jnp.asarray(
-            1 + np.arange(s_n * nb).reshape(s_n, nb), jnp.int32))
+            lane_tables(s_n, nb, s_n * nb + 1, order)))
         lens.append(jnp.asarray(np.minimum(lengths, rows), jnp.int32))
     q = normal((s_n, h * (row if d_value else shape["d_head"])), 0.1)
     new = normal((s_n, row), 0.5)
+    select = None
+    if shape.get("select"):
+        # the rows an indexer chose: so many of those under the cursor,
+        # the newest among them (the row the call writes)
+        r = np.random.RandomState(3)
+        select = np.zeros((s_n, shape["layers"][0][0]), bool)
+        for lane, n in enumerate(np.asarray(lens[0])):
+            select[lane, r.permutation(n)[:shape["select"]]] = True
+            select[lane, n - 1] = True
+        select = jnp.asarray(select)
 
     def f(q, pools):
         outs, back = [], []
@@ -185,7 +227,8 @@ def build(shape, bs, pa, interpret=False):
                 if d_value:
                     out, *pool = kern(q, pool[0], None, table, ln, layer,
                                       shape["scale"],
-                                      write=(new, None, ln - 1))
+                                      write=(new, None, ln - 1),
+                                      select=select)
                 else:
                     out, *pool = kern(q, pool[0], pool[1], table, ln,
                                       layer, shape["scale"],
@@ -195,7 +238,7 @@ def build(shape, bs, pa, interpret=False):
         return outs, back
 
     return (jax.jit(f, donate_argnums=(1,)), (q, pools),
-            dict(tables=tables, lengths=lens, new=new))
+            dict(tables=tables, lengths=lens, new=new, select=select))
 
 
 def reference(shape, bs, q, pools, aux):
@@ -225,8 +268,10 @@ def reference(shape, bs, q, pools, aux):
             sc = jnp.einsum("sgid,stgd->sgit", qh,
                             keys.reshape(s_n, rows, n_kv, dh)
                             ).reshape(s_n, h, rows)
-        sc = jnp.where(jnp.arange(rows)[None, None] < ln[:, None, None],
-                       sc * shape["scale"], -jnp.inf)
+        seen = jnp.arange(rows)[None] < ln[:, None]
+        if aux["select"] is not None:
+            seen &= aux["select"]
+        sc = jnp.where(seen[:, None], sc * shape["scale"], -jnp.inf)
         p = jax.nn.softmax(sc, -1)
         if d_value:
             want = jnp.einsum("sht,stv->shv", p, values)
@@ -238,13 +283,13 @@ def reference(shape, bs, q, pools, aux):
     return wants
 
 
-def check(shape, bs, pa, interpret=False):
+def check(shape, bs, pa, interpret=False, order="consecutive"):
     """Largest difference of the whole kernel from `reference`, as a
     share of the reference's largest value (a bfloat16 pool: some
     1e-2)."""
     import jax
 
-    f, (q, pools), aux = build(shape, bs, pa, interpret)
+    f, (q, pools), aux = build(shape, bs, pa, interpret, order)
     wants = jax.jit(lambda q, pools: reference(shape, bs, q, pools, aux))(
         q, pools)
     wants = [np.asarray(w, np.float32) for w in wants]
@@ -254,12 +299,13 @@ def check(shape, bs, pa, interpret=False):
                for out, want in zip(outs, wants))
 
 
-def pace(shape, bs, pa, variant="whole", calls=30, interpret=False):
+def pace(shape, bs, pa, variant="whole", calls=30, interpret=False,
+         order="consecutive"):
     """Milliseconds a call of every layer of every table and ring."""
     import jax
 
     with removed(variant):
-        f, (q, pools), _ = build(shape, bs, pa, interpret)
+        f, (q, pools), _ = build(shape, bs, pa, interpret, order)
         outs, pools = f(q, pools)
         jax.block_until_ready(outs)
         t = time.perf_counter()
@@ -426,7 +472,7 @@ def index_lengths(shape):
                       ctx).astype(np.int32)
 
 
-def build_index(shape, pis, interpret=False):
+def build_index(shape, pis, interpret=False, order="consecutive"):
     """-> (a jitted function that scores every plane of `shape`
     through the selected kernel, its arguments, the lanes' lengths)."""
     import jax
@@ -444,9 +490,7 @@ def build_index(shape, pis, interpret=False):
             ).astype(jnp.bfloat16)
     q = jax.random.normal(keys[1], (s_n, h, d), jnp.float32) * 0.3
     w = jax.random.normal(keys[2], (s_n, h), jnp.float32)
-    # every lane's pages anywhere in the pool, as a served table's are
-    tables = jnp.asarray(np.random.RandomState(2).randint(
-        1, shape["blocks"], (s_n, nb)), jnp.int32)
+    tables = jnp.asarray(lane_tables(s_n, nb, shape["blocks"], order))
     lengths = jnp.asarray(index_lengths(shape))
 
     def f(q, w, pool):
@@ -457,14 +501,14 @@ def build_index(shape, pis, interpret=False):
         tables=tables, lengths=lengths, tiling=kern.tiling(nb))
 
 
-def check_index(shape, pis, interpret=False):
+def check_index(shape, pis, interpret=False, order="consecutive"):
     """Largest difference, over the rows under the cursors, of the
     kernel's scores from the gather of the whole table's, as a share of
     the largest score (float32 sums in another order: some 1e-6)."""
     import jax
     import jax.numpy as jnp
 
-    f, (q, w, pool), aux = build_index(shape, pis, interpret)
+    f, (q, w, pool), aux = build_index(shape, pis, interpret, order)
     s_n, rows = q.shape[0], shape["ctx"]
     valid = np.arange(rows)[None, :] < np.asarray(aux["lengths"])[:, None]
     worst = 0.0
@@ -481,7 +525,7 @@ def check_index(shape, pis, interpret=False):
 
 
 def run_index(name, variants=VARIANTS, calls=30, with_check=False,
-              rehearse=False):
+              rehearse=False, order="consecutive"):
     """-> {"shape", "rows", "pages", "tiling", "<variant>": ms for the
     planes' calls together, "check"}."""
     import jax
@@ -493,7 +537,7 @@ def run_index(name, variants=VARIANTS, calls=30, with_check=False,
         shape, calls = dict(shape, slots=3, heads=4, ctx=512, blocks=97), 1
     lengths = index_lengths(shape)
     res = {"shape": name, "device": jax.devices()[0].device_kind,
-           "rehearsal": bool(rehearse),
+           "rehearsal": bool(rehearse), "tables": order,
            "rows": int(shape["planes"] * lengths.sum()),
            "pages": int(shape["planes"]
                         * (-(-lengths // shape["bs"])).sum())}
@@ -501,12 +545,12 @@ def run_index(name, variants=VARIANTS, calls=30, with_check=False,
         if rehearse and variant != "whole":
             continue    # the interpreter walks the whole kernel only
         with removed(variant):
-            f, args, aux = build_index(shape, pis, rehearse)
+            f, args, aux = build_index(shape, pis, rehearse, order)
             res["tiling"] = list(aux["tiling"])
             res[variant] = round(timed(f, args, calls), 4)
         print(f"{name} {variant}", res[variant], flush=True)
     if with_check:
-        res["check"] = check_index(shape, pis, rehearse)
+        res["check"] = check_index(shape, pis, rehearse, order)
     return res
 
 
@@ -517,11 +561,11 @@ def toy(shape):
                 d_value=shape["d_value"] and 128, ctx=256,
                 layers=tuple((min(rows, 256), 1)
                              for rows, _ in shape["layers"]),
-                mean_rows=90)
+                mean_rows=90, select=shape.get("select") and 40)
 
 
 def run(name, block_sizes=None, variants=None, pa=None, calls=30,
-        with_check=False, rehearse=False):
+        with_check=False, rehearse=False, order="consecutive"):
     """-> {"shape", "rows", "bs<n>.<variant>": ms, "bs<n>.pages",
     "bs<n>.tiling", "bs<n>.check"}."""
     import jax
@@ -535,14 +579,15 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
                          with_check=with_check, rehearse=rehearse)
     if shape.get("kernel") == "index":
         return run_index(name, variants or VARIANTS, calls=calls,
-                         with_check=with_check, rehearse=rehearse)
+                         with_check=with_check, rehearse=rehearse,
+                         order=order)
     if pa is None:
         from paddle_tpu.kernels import paged_attention as pa
     if rehearse:
         shape, calls = toy(shape), 1
     lengths = lengths_of(shape)
     res = {"shape": name, "device": jax.devices()[0].device_kind,
-           "rehearsal": bool(rehearse),
+           "rehearsal": bool(rehearse), "tables": order,
            "rows": int(sum(n * np.minimum(lengths, rows).sum()
                            for rows, n in shape["layers"]))}
     for bs in block_sizes or (shape["bs"],):
@@ -553,11 +598,11 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
             if rehearse and variant != "whole":
                 continue    # the interpreter walks the whole kernel only
             res[f"bs{bs}.{variant}"] = round(
-                pace(shape, bs, pa, variant, calls, rehearse), 4)
+                pace(shape, bs, pa, variant, calls, rehearse, order), 4)
             print(f"{name} bs{bs}.{variant}", res[f"bs{bs}.{variant}"],
                   flush=True)
         if with_check:
-            res[f"bs{bs}.check"] = check(shape, bs, pa, rehearse)
+            res[f"bs{bs}.check"] = check(shape, bs, pa, rehearse, order)
     return res
 
 
@@ -569,6 +614,7 @@ def main(argv=None):
     ap.add_argument("--variants", default="",
                     help="of the shape's kernel's (all): "
                     + ",".join(VARIANTS + FLASH_VARIANTS[1:]))
+    ap.add_argument("--tables", default="consecutive", choices=TABLES)
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
@@ -578,7 +624,7 @@ def main(argv=None):
               [int(b) for b in args.block_sizes.split(",") if b],
               tuple(v for v in args.variants.split(",") if v),
               calls=args.calls, with_check=args.check,
-              rehearse=args.rehearse)
+              rehearse=args.rehearse, order=args.tables)
     print(json.dumps(res))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
