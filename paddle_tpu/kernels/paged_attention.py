@@ -28,7 +28,9 @@ products run over the smallest row window (128 rows, doubled up to the
 chunk) that holds the pages copied into it.  While a slot's last chunk
 is computed the NEXT slot's first chunk is already in flight (the
 scratch and the buffer cursor outlive a grid step), so DMA latency is
-paid once a call, not once a slot.
+paid once a call, not once a slot.  That stream is ONE function,
+`stream_chunks`, which the index-score kernel (`paged_index_scores.py`)
+runs too: a kernel keeps its operand and what it does with a chunk.
 
 A chunk's DMA bookkeeping is per CHUNK, not per page.  A table names
 any block, so a copy is a page; but every copy is a descriptor the
@@ -38,9 +40,8 @@ index key page 5), in the one instruction stream the products are in,
 and tables mostly name RUNS: a request's blocks are taken at one
 admission, side by side and ascending where the free supply allows
 (`serving/kv_cache.py`), and a ring is consecutive blocks by
-construction.  So the issue loop (`start_pages`, which the index-score
-kernel calls too) takes a chunk's table entries in groups of
-`_ISSUE_UNROLL` and starts ONE copy of that many pages for a group
+construction.  So the issue loop (`start_pages`) takes a chunk's table
+entries in groups of `_ISSUE_UNROLL` and starts ONE copy for a group
 whose entries are consecutive ascending block ids (a slice of so many
 blocks of the pool into as many pages of the scratch, which is
 declared by pages for it), a copy a page for the others as before.
@@ -55,18 +56,10 @@ sorted lists 1 to 2% (PERF.md section 6, PR 56).  A table in any
 order, an idle lane's ring of block 0 and a shared prefix followed by
 fresh blocks give the same bytes in the same places, and only the
 count of descriptors differs (`starts_saved` and `dma_ops` count
-them).  The copies are WAITED FOR on their summed bytes: a
-DMA semaphore counts bytes, so a wait need not name the copy it waits
-for, only as many bytes, and a chunk of `copied` pages is one wait for
-each set bit of `copied`, on a descriptor of 2^b pages.  The invariant
-that makes it safe: a semaphore `sems[pool, buf]`
-never has more than ONE chunk's copies outstanding.  Buffers alternate;
-chunk c + 1 (or the next slot's first) is started into `1 - buf` while
-chunk c, in `buf`, is still to be waited for, and `1 - buf`'s last
-chunk was waited for whole before its products ran; the written row's
-way back to the pool has semaphores of its own (`wsems`).  So the
-bytes a wait takes off a semaphore are that chunk's and no other's
-(PERF.md section 6, PR 46).
+them).  The copies are WAITED FOR on their summed bytes, one wait for
+each set bit of the pages copied: `stream_chunks` has the invariant on
+the semaphores that makes it safe; the written row's way back to the
+pool has semaphores of its own (`wsems`).
 
 The arithmetic is `_attention`'s.  The query arrives as the projection
 made it (`q` [S, H*dh], cast to the pool's dtype: what the MXU rounds
@@ -139,7 +132,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dma_ops", "paged_attention", "paged_attention_supports",
            "group_runs", "issue_order", "rows_multiplied",
-           "select_paged_attention", "start_pages", "starts_saved"]
+           "select_paged_attention", "start_pages", "starts_saved",
+           "stream_chunks", "stream_counts"]
 
 # K (or V) bytes a chunk: the copies of one chunk are in flight while
 # the one before it is computed, so a chunk is long enough to hide a
@@ -306,6 +300,24 @@ def dma_ops(n_pages, pages: int, saved=None, unroll: int = _ISSUE_UNROLL):
     return ops - saved[range(len(saved)), groups]
 
 
+def stream_counts(rows, idle: int, pages: int, tile: int, block_size: int,
+                  saved=None, unroll: int = _ISSUE_UNROLL):
+    """What one call of a paged kernel's stream (`stream_chunks`) reads
+    and does, a plane and a pool, over lanes with `rows` rows under
+    their cursors (an integer array: the lanes that hold a sequence)
+    beside `idle` lanes that hold none, which read ONE page each (the
+    kernels raise a length to 1) -> (pages read, rows multiplied, DMA
+    operations): `rows_multiplied` and `dma_ops` (`saved`, `unroll`:
+    its) over `ceil(rows / block_size)` pages a lane, in chunks of
+    `pages` and row windows from `tile`."""
+    n_pages = -(-rows // block_size)
+    return (idle + int(n_pages.sum()),
+            int(idle * rows_multiplied(1, pages, tile, block_size)
+                + rows_multiplied(n_pages, pages, tile, block_size).sum()),
+            int(idle * dma_ops(1, pages)
+                + dma_ops(n_pages, pages, saved, unroll).sum()))
+
+
 def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
                 sems, *, unroll: int):
     """Start the copies of the `n` pages that table entries `base` to
@@ -317,8 +329,7 @@ def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
     (`issue_order` of the same tables; `chunk`: this chunk's row of
     it): ONE copy of `unroll` pages for each that is a run, then a copy
     a page for the others; the `n % unroll` last pages a copy each.
-    The one issue loop of the paged kernels (this one and
-    `paged_index_scores.py`)."""
+    The one issue loop of the paged kernels (`stream_chunks`' own)."""
     def copy(i, n_pages=1):
         """`n_pages` pages from entry i's block on, to page i on."""
         blk = tables_ref[base + i]
@@ -358,22 +369,130 @@ def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
     jax.lax.fori_loop(grouped, n, page, 0)
 
 
+def stream_chunks(tables_ref, order_ref, lengths_ref, planes, bufs, sems,
+                  cursor_ref, *, bs, nb, pages, windows, unroll, before_chunks,
+                  over, before_first_start=lambda: None,
+                  before_count=lambda: None,
+                  around_products=lambda lane, c, buf, products: products(),
+                  after_chunks=lambda lane, carry: None):
+    """Grid step s of a paged kernel: lane s's first
+    `ceil(lengths[s] / bs)` pages through two buffers, a chunk of
+    `pages` pages at a time.  The one chunk loop of the paged kernels:
+    a kernel hands in its operand and what it does with a chunk's rows.
+
+    `tables_ref` [lanes * nb], `order_ref` (`issue_order` of them) and
+    `lengths_ref` [lanes]: scalars in SMEM.  `planes()` -> a plane
+    [blocks, bs, width] in HBM a pool (asked at every start: a kernel
+    may read its plane's scalar there); `bufs`: [2, pages, bs, width]
+    in VMEM a pool; `sems` [pools, 2]; `cursor_ref[0]`: the buffer that
+    holds this lane's first chunk, started by the step before.
+    `windows`: `_windows`; `unroll`: `start_pages`' group.
+
+    The copies are WAITED FOR on their summed bytes: a DMA semaphore
+    counts bytes, so a wait need not name the copy it waits for, only
+    as many bytes, and a chunk of `copied` pages is one wait a pool for
+    each set bit of `copied`, on a descriptor of 2^b pages.  The
+    invariant that makes it safe: `sems[pool, buf]` never has more
+    than ONE chunk's copies outstanding.  Buffers alternate; chunk
+    c + 1 (or the next lane's first) is started into `1 - buf` while
+    chunk c, in `buf`, is still to be waited for, and `1 - buf`'s last
+    chunk was waited for whole before its products ran; whatever else
+    a kernel copies has semaphores of its own.  So the bytes a wait
+    takes off a semaphore are that chunk's and no other's (PERF.md
+    section 6, PR 46).
+
+    The kernel's side, in the order it is called (the order the
+    compiled kernels were measured in: tests/test_kernels_lower_tpu.py
+    pins their text): `before_first_start()`, in the first grid step
+    alone, the cursor set; `before_count()` -> anything, the cursor read
+    and the lane's chunks not yet counted; `before_chunks(that)` ->
+    (lane, carry), the kernel's operand, handed back in every later
+    call, and the chunk loop's carry; `over(lane, c, buf, n_rows)` ->
+    carry -> carry, a branch of the switch: chunk c's first `n_rows`
+    rows (static) in buffer `buf`; `around_products(lane, c, buf,
+    products)` -> carry, `products()` the switch: what else a kernel
+    does between a chunk's wait and the next chunk; `after_chunks(lane,
+    carry)`, before the cursor is written."""
+    s, n_lanes = pl.program_id(0), pl.num_programs(0)
+
+    def n_pages(lane):
+        return (lengths_ref[lane] + bs - 1) // bs
+
+    def copied_into(lane, chunk):
+        """Pages of `lane`'s chunk `chunk` that its length reaches."""
+        return jnp.minimum(pages, n_pages(lane) - chunk * pages)
+
+    def start(lane, chunk, buf):
+        """Start the page copies (a pool each) of `lane`'s chunk
+        `chunk` into buffer `buf`: the pages the lane's length reaches,
+        so a table entry past it is never read."""
+        start_pages(tables_ref, order_ref, lane * nb + chunk * pages,
+                    lane * -(-nb // pages) + chunk,
+                    copied_into(lane, chunk), planes(), bufs, buf, sems,
+                    unroll=min(unroll, pages))
+
+    def wait(copied, buf):
+        """Wait for the `copied` pages a `start` sent to buffer `buf`:
+        of a wait's descriptor only the size and the semaphore
+        matter."""
+        for bit in range(pages.bit_length()):
+            @pl.when(((copied >> bit) & 1) == 1)
+            def _wait(size=pl.ds(0, 1 << bit)):
+                for i_pool, into in enumerate(bufs):
+                    pltpu.make_async_copy(into.at[buf, size],
+                                          into.at[buf, size],
+                                          sems.at[i_pool, buf]).wait()
+
+    @pl.when(s == 0)
+    def _first_lane():
+        cursor_ref[0] = 0
+        before_first_start()
+        start(0, 0, 0)
+
+    first_buf = cursor_ref[0]
+    read = before_count()
+    n_chunks = (n_pages(s) + pages - 1) // pages
+    lane, carry = before_chunks(read)
+
+    def chunk(c, carry):
+        buf = (first_buf + c) % 2
+        more = c + 1 < n_chunks
+
+        @pl.when(more | (s + 1 < n_lanes))
+        def _next():
+            # this lane's next chunk, else the next lane's first
+            start(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
+                  1 - buf)
+
+        # the pages copied into this chunk
+        copied = copied_into(s, c)
+        wait(copied, buf)
+
+        def products():
+            # over the smallest window they fill
+            return jax.lax.switch(
+                sum((copied > w).astype(jnp.int32) for w in windows[:-1]),
+                [over(lane, c, buf, w * bs) for w in windows], carry)
+
+        return around_products(lane, c, buf, products)
+
+    after_chunks(lane, jax.lax.fori_loop(0, n_chunks, chunk, carry))
+    cursor_ref[0] = (first_buf + n_chunks) % 2
+
+
 def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
             pages, windows, scale, h, dh, n_kv, writes, d_value=0,
             selects=False):
     """Grid step s: slot s's attention over its first
-    `ceil(lengths[s] / bs)` pages of layer `layer[0]`, copied a chunk
-    of `pages` pages at a time and multiplied over the smallest of
-    `windows` (pages, static) that the copied pages fill.
-    `cursor_ref[0]` is the buffer (0 or 1) that holds this slot's
-    first chunk, started by the step before.  `d_value`: a latent
-    pool, ONE array whose first `d_value` columns are the value (0: a
-    K pool and a V pool).  `selects`: a row mask a slot follows the
-    query ([chunks, 1, rows a chunk] float32, 1 where the row is
-    selected: a chunk's mask a tile of its own, so that the chunk
-    indexes an untiled axis).  `order_ref`: `issue_order` of the
-    tables.  A buffer is [2, pages, bs, width]: a chunk's pages, which
-    the products read as its rows."""
+    `ceil(lengths[s] / bs)` pages of layer `layer[0]`, which
+    `stream_chunks` brings a chunk of `pages` pages at a time, and
+    multiplied over the smallest of `windows` (pages, static) that the
+    copied pages fill.  `d_value`: a latent pool, ONE array whose first
+    `d_value` columns are the value (0: a K pool and a V pool).
+    `selects`: a row mask a slot follows the query ([chunks, 1, rows a
+    chunk] float32, 1 where the row is selected: a chunk's mask a tile
+    of its own, so that the chunk indexes an untiled axis).  The
+    products read a buffer's pages as a chunk's rows."""
     n_pools = 1 if d_value else 2
     refs = iter(refs)
 
@@ -393,7 +512,7 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
     wsems = next(refs) if writes else None
     # the keys' buffer, and the values': the same one on a latent pool
     k_buf, v_buf = bufs[0], bufs[-1]
-    s, n_slots = pl.program_id(0), pl.num_programs(0)
+    s = pl.program_id(0)
     layer = layer_ref[0]
     rows = pages * bs
     d_kv = n_kv * dh
@@ -402,76 +521,45 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
     # tile where a page is whole tiles, else the page
     group_rows = 8 if bs % 8 == 0 else bs
 
-    def n_pages(slot):
-        return (lengths_ref[slot] + bs - 1) // bs
-
-    def copied_into(slot, chunk):
-        """Pages of `slot`'s chunk `chunk` that its length reaches."""
-        return jnp.minimum(pages, n_pages(slot) - chunk * pages)
-
-    def start(slot, chunk, buf):
-        """Start the page copies (K and V; a latent row once) of
-        `slot`'s chunk `chunk` into buffer `buf`: the pages the slot's
-        length reaches, so a table entry past it is never read."""
-        start_pages(tables_ref, order_ref, slot * nb + chunk * pages,
-                    slot * -(-nb // pages) + chunk,
-                    copied_into(slot, chunk),
-                    [hbm.at[layer] for hbm in hbms], bufs, buf, sems,
-                    unroll=min(_ISSUE_UNROLL, pages))
-
-    def wait(copied, buf):
-        """Wait for the `copied` pages a `start` sent to buffer `buf`,
-        on their summed bytes: for each set bit b of `copied` one wait
-        a pool on a descriptor of 2^b pages, of which only the size
-        and the semaphore matter (the module's docstring: the
-        semaphore holds this chunk's copies and no other's)."""
-        for bit in range(pages.bit_length()):
-            @pl.when(((copied >> bit) & 1) == 1)
-            def _wait(size=pl.ds(0, 1 << bit)):
-                for i_pool, into in enumerate(bufs):
-                    pltpu.make_async_copy(into.at[buf, size],
-                                          into.at[buf, size],
-                                          sems.at[i_pool, buf]).wait()
-
-    @pl.when(s == 0)
-    def _first_slot():
-        cursor_ref[0] = 0
+    def zero_values():
         # rows of a window no page was copied into weigh 0 in `p . V`:
         # they must be finite, which VMEM as it comes is not
         v_buf[...] = jnp.zeros_like(v_buf)
-        start(0, 0, 0)
 
-    first_buf = cursor_ref[0]
-    length = lengths_ref[s]
-    n_chunks = (n_pages(s) + pages - 1) // pages
-
-    # The block-diagonal operand [H, Dkv]: query head i's d_head
-    # columns in ITS K/V head's columns of a pool row, exact zeros
-    # outside them (`_block_diagonal` of the decoder, built here).
     def iota(shape, axis):
         return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
-    if d_value:
-        # every head reads the whole latent row: q_ref[0] [H, row] IS
-        # the operand
-        q = q_ref[0]
-    else:
-        kv_head = iota((h, 1), 0)
-        if group > 1:
-            kv_head = jax.lax.div(kv_head, group)
-        col = iota((h, d_kv), 1)
-        own = (col >= kv_head * dh) & (col < kv_head * dh + dh)
-        if group == 1:
-            # q_ref[0] is the projection's row [1, H*dh]: a head's
-            # columns of it ARE its columns of a pool row
-            q_wide = jnp.broadcast_to(q_ref[0].astype(jnp.float32),
-                                      (h, d_kv))
+    def operand(length):
+        """The block-diagonal operand [H, Dkv]: query head i's d_head
+        columns in ITS K/V head's columns of a pool row, exact zeros
+        outside them (`_block_diagonal` of the decoder, built here).
+        -> ((the slot's length, the operand, each head's own columns,
+        its K/V head), the softmax's carry: the running max and sum)."""
+        own = kv_head = None
+        if d_value:
+            # every head reads the whole latent row: q_ref[0] [H, row]
+            # IS the operand
+            q = q_ref[0]
         else:
-            # q_ref[0] is [H, dh]: a head's columns under every K/V head
-            q_wide = jnp.concatenate(
-                [q_ref[0].astype(jnp.float32)] * n_kv, axis=1)
-        q = jnp.where(own, q_wide, 0.0).astype(k_buf.dtype)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+            kv_head = iota((h, 1), 0)
+            if group > 1:
+                kv_head = jax.lax.div(kv_head, group)
+            col = iota((h, d_kv), 1)
+            own = (col >= kv_head * dh) & (col < kv_head * dh + dh)
+            if group == 1:
+                # q_ref[0] is the projection's row [1, H*dh]: a head's
+                # columns of it ARE its columns of a pool row
+                q_wide = jnp.broadcast_to(q_ref[0].astype(jnp.float32),
+                                          (h, d_kv))
+            else:
+                # q_ref[0] is [H, dh]: a head's columns under every K/V head
+                q_wide = jnp.concatenate(
+                    [q_ref[0].astype(jnp.float32)] * n_kv, axis=1)
+            q = jnp.where(own, q_wide, 0.0).astype(k_buf.dtype)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        return (length, q, own, kv_head), (
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32))
 
     def row_copies(c, buf):
         """This position's K and V on their way back to the pool: the
@@ -509,80 +597,71 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
 
         return here
 
-    def chunk(c, carry):
-        buf = (first_buf + c) % 2
+    def around_written_row(_, c, buf, products):
+        if not writes:
+            return products()
+        written = put_row(c, buf)
+        carry = products()
 
-        more = c + 1 < n_chunks
-
-        @pl.when(more | (s + 1 < n_slots))
-        def _next():
-            # this slot's next chunk, else the next slot's first
-            start(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
-                  1 - buf)
-
-        # the pages copied into this chunk
-        copied = copied_into(s, c)
-        wait(copied, buf)
-        if writes:
-            written = put_row(c, buf)
-
-        def over(n_rows):
-            """The online softmax over the chunk's first `n_rows`
-            rows (static)."""
-            def multiply(carry):
-                m, l = carry
-                k = k_buf[buf, :n_rows // bs].reshape(n_rows, -1)
-                sc = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
-                seen = row < length
-                if selects:
-                    seen &= sel_ref[0, c, :, :n_rows] > 0.0
-                sc = jnp.where(seen, sc, -jnp.inf)
-                m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-                # nothing selected so far: exp(-inf - -inf) is no number
-                m_ref = (jnp.where(m_new == -jnp.inf, 0.0, m_new)
-                         if selects else m_new)
-                alpha = jnp.exp(m - m_ref)
-                p = jnp.exp(sc - m_ref)
-                v = (v_buf[buf, :n_rows // bs, :, :d_value] if d_value
-                     else v_buf[buf, :n_rows // bs]).reshape(n_rows, -1)
-                acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                return m_new, alpha * l + jnp.sum(p, axis=1,
-                                                  keepdims=True)
-            return multiply
-
-        # the smallest window they fill
-        carry = jax.lax.switch(
-            sum((copied > w).astype(jnp.int32) for w in windows[:-1]),
-            [over(w * bs) for w in windows], carry)
-        if writes:
-            # the row's way back to the pool lay under the products
-            @pl.when(written)
-            def _row_is_back():
-                for copy in row_copies(c, buf):
-                    copy.wait()
+        # the row's way back to the pool lay under the products
+        @pl.when(written)
+        def _row_is_back():
+            for copy in row_copies(c, buf):
+                copy.wait()
         return carry
 
-    _, l = jax.lax.fori_loop(
-        0, n_chunks, chunk, (jnp.full((h, 1), -jnp.inf, jnp.float32),
-                             jnp.zeros((h, 1), jnp.float32)))
-    # of all Dkv columns a head keeps its K/V head's
-    # (`_own_columns` of the decoder)
-    ctx = acc_ref[...] / l
-    if d_value:
-        o_ref[0] = ctx
-    elif group == 1:
-        o_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0,
-                           keepdims=True)
-    else:
-        o_ref[0] = sum(
-            jnp.where(kv_head == g, ctx[:, g * dh:(g + 1) * dh], 0.0)
-            for g in range(n_kv))
-    cursor_ref[0] = (first_buf + n_chunks) % 2
+    def over(lane, c, buf, n_rows):
+        """The online softmax over the first `n_rows` rows (static) of
+        chunk c."""
+        length, q = lane[:2]
+
+        def multiply(carry):
+            m, l = carry
+            k = k_buf[buf, :n_rows // bs].reshape(n_rows, -1)
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
+            seen = row < length
+            if selects:
+                seen &= sel_ref[0, c, :, :n_rows] > 0.0
+            sc = jnp.where(seen, sc, -jnp.inf)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            # nothing selected so far: exp(-inf - -inf) is no number
+            m_ref = (jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                     if selects else m_new)
+            alpha = jnp.exp(m - m_ref)
+            p = jnp.exp(sc - m_ref)
+            v = (v_buf[buf, :n_rows // bs, :, :d_value] if d_value
+                 else v_buf[buf, :n_rows // bs]).reshape(n_rows, -1)
+            acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        return multiply
+
+    def result(lane, carry):
+        # of all Dkv columns a head keeps its K/V head's
+        # (`_own_columns` of the decoder)
+        (_, _, own, kv_head), (_, l) = lane, carry
+        ctx = acc_ref[...] / l
+        if d_value:
+            o_ref[0] = ctx
+        elif group == 1:
+            o_ref[0] = jnp.sum(jnp.where(own, ctx, 0.0), axis=0,
+                               keepdims=True)
+        else:
+            o_ref[0] = sum(
+                jnp.where(kv_head == g, ctx[:, g * dh:(g + 1) * dh], 0.0)
+                for g in range(n_kv))
+
+    stream_chunks(
+        tables_ref, order_ref, lengths_ref,
+        lambda: tuple(hbm.at[layer] for hbm in hbms), bufs, sems,
+        cursor_ref, bs=bs, nb=nb, pages=pages, windows=windows,
+        unroll=_ISSUE_UNROLL, before_first_start=zero_values,
+        before_count=lambda: lengths_ref[s], before_chunks=operand,
+        over=over, around_products=around_written_row, after_chunks=result)
 
 
 @functools.partial(jax.jit, static_argnames=(

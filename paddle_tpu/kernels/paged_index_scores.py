@@ -11,15 +11,12 @@ blocks of every lane whatever the cursor, writes a logical-order copy,
 reads it back into a product that writes [S, heads, rows] float32, and
 reads that again: seven times the bytes the cursors need (PERF.md
 section 6, PR 54).  This kernel is the plain sibling of the paged
-attention kernel (`paged_attention.py`, whose scheme it takes, two
-constants apart): tables and a LENGTH a lane on the scalar-prefetch lane,
-the pool left in HBM, the `ceil(length / block_size)` pages of a lane
-and no other copied a chunk at a time into two VMEM buffers by manual
-async copies, started by the attention kernel's own issue loop
-(`paged_attention.start_pages`: table entries in groups of
-`_ISSUE_UNROLL`, ONE copy for a group that is a run of consecutive
-blocks) and waited for on their summed bytes, the next lane's first
-chunk in flight under this lane's last.  Nothing is written to the
+attention kernel and runs that kernel's own page stream
+(`paged_attention.stream_chunks`, two measured constants apart): tables
+and a LENGTH a lane on the scalar-prefetch lane, the pool left in HBM,
+the `ceil(length / block_size)` pages of a lane and no other through
+two VMEM buffers a chunk at a time, the next lane's first chunk in
+flight under this lane's last.  Nothing is written to the
 pool (it is read only and not aliased: the key's write is the caller's
 scatter, before the call, by data dependence) and no softmax joins the
 chunks: a chunk's scores go to the chunk's columns of the lane's
@@ -55,7 +52,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .paged_attention import (_KV_DTYPES, _TILE_ROWS, _windows, issue_order,
-                              paged_attention_supports, start_pages)
+                              paged_attention_supports, stream_chunks)
 
 __all__ = ["paged_index_scores", "select_index_scores"]
 
@@ -80,91 +77,33 @@ _ISSUE_UNROLL = 16
 def _kernel(tables_ref, order_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm,
             o_ref, buf_ref, sems, cursor_ref, *, bs, nb, pages, windows):
     """Grid step s: lane s's scores over its first
-    `ceil(lengths[s] / bs)` pages of plane `plane[0]`, copied a chunk
-    of `pages` pages at a time and multiplied over the smallest of
-    `windows` (pages, static) that the copied pages fill.
-    `cursor_ref[0]` is the buffer (0 or 1) that holds this lane's first
-    chunk, started by the step before; `order_ref`: `issue_order` of
-    the tables; `buf_ref` [2, pages, bs, width]: a chunk's pages, which
-    the product reads as its rows.  `o_ref` [1, chunks, 1, rows a
-    chunk]: a chunk's scores a tile of their own, so that the chunk
-    indexes an untiled axis."""
-    s, n_slots = pl.program_id(0), pl.num_programs(0)
+    `ceil(lengths[s] / bs)` pages of plane `plane[0]`, which
+    `paged_attention.stream_chunks` brings a chunk of `pages` pages at
+    a time, and multiplied over the smallest of `windows` (pages,
+    static) that the copied pages fill: the product reads `buf_ref`'s
+    pages as a chunk's rows.  `o_ref` [1, chunks, 1, rows a chunk]: a
+    chunk's scores a tile of their own, so that the chunk indexes an
+    untiled axis."""
+    def over(lane, c, buf, n_rows):
+        """The scores of the first `n_rows` rows (static) of chunk c."""
+        q, w = lane                                 # [H, D], [H, 1]
 
-    def n_pages(slot):
-        return (lengths_ref[slot] + bs - 1) // bs
+        def multiply(carry):
+            keys = buf_ref[buf, :n_rows // bs].reshape(n_rows, -1)
+            dots = jax.lax.dot_general(
+                q, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # [H, n_rows]
+            o_ref[0, c, :, :n_rows] = jnp.sum(
+                jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
+            # the scores went to `o_ref`: the loop carries nothing
+            return carry
+        return multiply
 
-    def copied_into(slot, chunk):
-        """Pages of `slot`'s chunk `chunk` that its length reaches."""
-        return jnp.minimum(pages, n_pages(slot) - chunk * pages)
-
-    def start(slot, chunk, buf):
-        """Start the page copies of `slot`'s chunk `chunk` into buffer
-        `buf`: the pages the lane's length reaches, so a table entry
-        past it is never read."""
-        start_pages(tables_ref, order_ref, slot * nb + chunk * pages,
-                    slot * -(-nb // pages) + chunk,
-                    copied_into(slot, chunk), [hbm.at[plane_ref[0]]],
-                    [buf_ref], buf, sems, unroll=min(_ISSUE_UNROLL, pages))
-
-    def wait(copied, buf):
-        """Wait for the `copied` pages a `start` sent to buffer `buf`,
-        on their summed bytes: for each set bit b of `copied` one wait
-        on a descriptor of 2^b pages, of which only the size and the
-        semaphore matter (`sems[0, buf]` never holds more than ONE
-        chunk's copies: `paged_attention.py`'s invariant)."""
-        for bit in range(pages.bit_length()):
-            @pl.when(((copied >> bit) & 1) == 1)
-            def _wait(size=pl.ds(0, 1 << bit)):
-                pltpu.make_async_copy(buf_ref.at[buf, size],
-                                      buf_ref.at[buf, size],
-                                      sems.at[0, buf]).wait()
-
-    @pl.when(s == 0)
-    def _first_slot():
-        cursor_ref[0] = 0
-        start(0, 0, 0)
-
-    first_buf = cursor_ref[0]
-    n_chunks = (n_pages(s) + pages - 1) // pages
-    q, w = q_ref[0], w_ref[0]                       # [H, D], [H, 1]
-
-    def chunk(c, carry):
-        buf = (first_buf + c) % 2
-        more = c + 1 < n_chunks
-
-        @pl.when(more | (s + 1 < n_slots))
-        def _next():
-            # this lane's next chunk, else the next lane's first
-            start(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
-                  1 - buf)
-
-        copied = copied_into(s, c)
-        wait(copied, buf)
-
-        def over(n_rows):
-            """The scores of the chunk's first `n_rows` rows
-            (static)."""
-            def multiply(c):
-                keys = buf_ref[buf, :n_rows // bs].reshape(n_rows, -1)
-                dots = jax.lax.dot_general(
-                    q, keys, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [H, n_rows]
-                o_ref[0, c, :, :n_rows] = jnp.sum(
-                    jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
-                return c
-            return multiply
-
-        # the smallest window the copied pages fill (a branch hands its
-        # operand through: `lax.switch` wants one, the scores go to
-        # `o_ref`)
-        jax.lax.switch(
-            sum((copied > win).astype(jnp.int32) for win in windows[:-1]),
-            [over(win * bs) for win in windows], c)
-        return carry
-
-    jax.lax.fori_loop(0, n_chunks, chunk, 0)
-    cursor_ref[0] = (first_buf + n_chunks) % 2
+    stream_chunks(
+        tables_ref, order_ref, lengths_ref,
+        lambda: (hbm.at[plane_ref[0]],), (buf_ref,), sems, cursor_ref,
+        bs=bs, nb=nb, pages=pages, windows=windows, unroll=_ISSUE_UNROLL,
+        before_chunks=lambda _: ((q_ref[0], w_ref[0]), 0), over=over)
 
 
 @functools.partial(jax.jit, static_argnames=("pages", "tile", "interpret"))

@@ -2068,8 +2068,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     window = spec.window if ringed else 0
     tiling = ((_attend.tiling(nb), _attend.tiling(nw) if nw else None)
               if _attend is not None else None)
-    index_chunk = (_index_scores.tiling(nb)[0]
-                   if _index_scores is not None else None)
+    index_tiling = (_index_scores.tiling(nb)
+                    if _index_scores is not None else None)
 
     def starts_saved(tables=None, rings=None):
         """{"table", "index": from `tables` [lanes, table pages];
@@ -2088,9 +2088,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if tiling is not None and n_win and rings is not None:
             saved["ring"] = _paged_attention.starts_saved(
                 rings, tiling[1][0])
-        if index_chunk is not None and tables is not None:
+        if index_tiling is not None and tables is not None:
             saved["index"] = _paged_attention.starts_saved(
-                tables, index_chunk, _index_scores.unroll)
+                tables, index_tiling[0], _index_scores.unroll)
         return saved
 
     def tick_counts(cursors, slots, windowed=False, saved=None):
@@ -2098,23 +2098,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         `serving.decode_tick` span, from `cursors` (int array: the
         step's `positions` at the lanes that hold a sequence) and
         `slots`, its lanes; `windowed`: a `step_window` tick, which
-        gathers always.  `kv_pages_read` of `kv_pages_table`: the K/V
-        pages the step's attention reads, summed over lanes and
-        attention layers, of the pages the lanes' tables and rings hold:
-        through the Pallas kernel (`kernels`) the pages each cursor has
-        reached, and one a layer for a lane with no sequence; on the
-        gather path every page.  `kv_rows_multiplied`: the K/V rows the
-        step's two products run over, summed the same way: through the
-        kernel, for each chunk of a lane's pages, the smallest row
-        window that holds them (`attention_tiling`), on the gather path
-        every row.  `kv_dma_ops`, through the kernel alone: the DMA
-        starts and waits it performs for those pages, summed the same
-        way and over the pools (`kernels.paged_attention.dma_ops`: a
-        start a group of table entries that are a run of consecutive
-        blocks and a start a page elsewhere, a wait for each set bit of
-        a chunk's pages; `saved`: `starts_saved`'s rows for the lanes of
-        `cursors`, in their order; without it every page counts a
-        start).
+        gathers always.  `kv_pages_read` of `kv_pages_table`,
+        `kv_rows_multiplied` and, through the kernel alone,
+        `kv_dma_ops`: the K/V pages the step's attention reads of those
+        the lanes' tables and rings hold, the rows its two products run
+        over and the DMA starts and waits it performs, summed over
+        lanes, attention layers and (the last) pools.  Through the
+        Pallas kernel (`kernels`) they are what
+        `kernels.paged_attention.stream_counts` says of the table's
+        stream and the ring's at `attention_tiling` (`saved`:
+        `starts_saved`'s rows for the lanes of `cursors`, in their
+        order; without it every page counts a start); on the gather
+        path every page and every row.
         With sliding layers `past_window` (cursors at or
         past the window: their rings have wrapped) and the rows a layer
         of each kind attends over, `kv_rows_full` (cursor + 1) and
@@ -2137,11 +2132,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         a lane a latent plane), and `index_pages_read` of
         `index_pages_table`: the pages of the index planes its scores
         read, of those the lanes' tables hold: through the kernel
-        (`kernels["lightning_indexer"]`) the pages each cursor has
-        reached and one for a lane with no sequence, with
-        `index_dma_ops`, the DMA starts and waits it performs for them
-        (`dma_ops`, a start a group of 16 entries that are a run); on
-        the gather path every page."""
+        (`kernels["lightning_indexer"]`) `stream_counts` of its stream,
+        with `index_dma_ops` (a start a group of 16 entries that are a
+        run); on the gather path every page."""
         n = len(cursors)
         counts, saved = {}, saved or {}
         if passes > 1:
@@ -2151,26 +2144,16 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         rows = cursors.astype(np.int64) + 1  # K/V rows a lane attends
         table = slots * (planes * nb + n_win * nw)
         read, multiplied = table, table * bs
+        idle = slots - n                     # a page each, a plane
         if tiling is not None and not windowed:
-            idle = slots - n                 # a page each, a layer
-            read = multiplied = dma = 0
-            reached = [(planes, -(-rows // bs), tiling[0], "table")]
+            streamed = planes * np.array(_paged_attention.stream_counts(
+                rows, idle, *tiling[0], bs, saved.get("table")))
             if n_win:
-                reached.append(
-                    (n_win, -(-np.minimum(rows, nw * bs) // bs), tiling[1],
-                     "ring"))
-            for layers_n, pages, (chunk, tile), held in reached:
-                read += layers_n * (idle + int(pages.sum()))
-                multiplied += layers_n * int(
-                    idle * _paged_attention.rows_multiplied(
-                        1, chunk, tile, bs)
-                    + _paged_attention.rows_multiplied(
-                        pages, chunk, tile, bs).sum())
-                dma += layers_n * (1 if latent else 2) * int(
-                    idle * _paged_attention.dma_ops(1, chunk)
-                    + _paged_attention.dma_ops(
-                        pages, chunk, saved.get(held)).sum())
-            counts["kv_dma_ops"] = dma
+                streamed += n_win * np.array(_paged_attention.stream_counts(
+                    np.minimum(rows, nw * bs), idle, *tiling[1], bs,
+                    saved.get("ring")))
+            read, multiplied, dma = map(int, streamed)
+            counts["kv_dma_ops"] = (1 if latent else 2) * dma
         counts["kv_pages_read"] = read
         counts["kv_pages_table"] = table
         counts["kv_rows_multiplied"] = multiplied
@@ -2186,17 +2169,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["kv_rows_selected"] = planes * int(
                 np.minimum(rows, spec.index_topk).sum())
             counts["index_pages_table"] = n_index * slots * nb
-            if index_chunk is None:
+            if index_tiling is None:
                 counts["index_pages_read"] = counts["index_pages_table"]
             else:
-                idle, pages = slots - n, -(-rows // bs)
-                counts["index_pages_read"] = n_index * (
-                    idle + int(pages.sum()))
-                counts["index_dma_ops"] = n_index * int(
-                    idle * _paged_attention.dma_ops(1, index_chunk)
-                    + _paged_attention.dma_ops(
-                        pages, index_chunk, saved.get("index"),
-                        _index_scores.unroll).sum())
+                read, _, dma = _paged_attention.stream_counts(
+                    rows, idle, *index_tiling, bs, saved.get("index"),
+                    _index_scores.unroll)
+                counts["index_pages_read"] = n_index * read
+                counts["index_dma_ops"] = n_index * dma
         if stateful:
             counts["state_lanes"] = n
             counts["state_resets"] = n - int(np.count_nonzero(cursors))

@@ -2057,17 +2057,17 @@ def test_quantized_pool_admits_2x_resident_sequences():
 def _run_load(dec, states, reqs, *, static_batch, slots, kv_blocks,
               place):
     """Submit every request at once to one server configuration, wait
-    for all; returns completions, tokens/s and the p99 latency."""
+    for all; returns completions, the decode ticks it took, each
+    request's tokens, tokens/s and the p99 latency."""
     server = GenerationServer(dec, states, slots=slots,
                               kv_blocks=kv_blocks,
                               static_batch=static_batch, place=place)
     lat = [None] * len(reqs)
-    toks = [0] * len(reqs)
+    toks = [None] * len(reqs)
 
     def wait_for(i, t0, stream):
-        out = stream.result(timeout=300)
+        toks[i] = list(stream.result(timeout=300))
         lat[i] = time.perf_counter() - t0
-        toks[i] = len(out)
 
     t_start = time.perf_counter()
     waiters = []
@@ -2081,19 +2081,23 @@ def _run_load(dec, states, reqs, *, static_batch, slots, kv_blocks,
     for w in waiters:
         w.join(timeout=300)
     wall = time.perf_counter() - t_start
+    ticks = server.stats()["ticks"]
     server.close()
     done = [l for l in lat if l is not None]
-    return {"completed": len(done),
-            "tokens_per_sec": sum(toks) / wall,
+    return {"completed": len(done), "ticks": ticks, "tokens": toks,
+            "tokens_per_sec": sum(len(t or ()) for t in toks) / wall,
             "latency_p99_s": float(np.percentile(done, 99))}
 
 
 @pytest.mark.perf
 def test_continuous_batching_2x_static_at_equal_p99():
-    """Under the mixed-length open-loop load continuous batching sustains >= 2x the static drain-then-refill
-    tokens/s at no worse p99.  Best-of-trials; the ratio is structural
-    (identical executables, ~2.4x fewer decode ticks), so it holds on
-    loaded CI hosts."""
+    """Under the mixed-length open-loop load continuous batching needs
+    at most HALF the decode ticks of static drain-then-refill for the
+    same 24 requests, all completed with the same tokens: the claim is
+    structural (identical executables, ~2.4x fewer ticks), so it is
+    asserted on `stats()["ticks"]`, which no other process on the host
+    can move.  The tokens/s ratio and the p99s that follow from it are
+    printed, not asserted: on a loaded host they raced (ROADMAP D14)."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
         from mini_fleet import make_requests   # the drills' request mix
@@ -2105,20 +2109,19 @@ def test_continuous_batching_2x_static_at_equal_p99():
     rng = np.random.RandomState(0)
     reqs = [(list(np.asarray(p) % V), m)
             for p, m in make_requests(24, 96, rng)]
-    best = {}
-    for static in (True, False):
-        rows = [_run_load(dec, states, reqs, static_batch=static,
-                         slots=4, kv_blocks=56,
-                         place=fluid.CPUPlace())
-                for _ in range(2)]
-        key = "static" if static else "continuous"
-        best[key] = max(rows, key=lambda r: r["tokens_per_sec"])
-    cont, stat = best["continuous"], best["static"]
+    stat, cont = (_run_load(dec, states, reqs, static_batch=static,
+                            slots=4, kv_blocks=56, place=fluid.CPUPlace())
+                  for static in (True, False))
     assert cont["completed"] == stat["completed"] == 24
-    ratio = cont["tokens_per_sec"] / stat["tokens_per_sec"]
-    assert ratio >= 2.0, (ratio, cont, stat)
-    assert cont["latency_p99_s"] <= stat["latency_p99_s"] * 1.25, (
-        cont["latency_p99_s"], stat["latency_p99_s"])
+    assert cont["tokens"] == stat["tokens"]
+    assert [len(t) for t in cont["tokens"]] == [m for _, m in reqs]
+    assert 0 < 2 * cont["ticks"] <= stat["ticks"], (cont["ticks"],
+                                                    stat["ticks"])
+    print("continuous over static: ticks %d | %d, tokens/s x%.2f, "
+          "p99 %.3f | %.3f s" % (
+              cont["ticks"], stat["ticks"],
+              cont["tokens_per_sec"] / stat["tokens_per_sec"],
+              cont["latency_p99_s"], stat["latency_p99_s"]))
 
 
 # ---------------------------------------------------------------------------

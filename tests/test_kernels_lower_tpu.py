@@ -17,13 +17,19 @@ compiles for a chip that is described and not attached
 Contract: at a pool `select_paged_attention(platform="tpu")` accepts,
 the kernel it returns lowers to ONE Mosaic custom call however many
 layers call it, and compiles for a v5e at every serving cell's
-geometry; a lightning indexer's score kernel the same at its plane's;
+geometry; a lightning indexer's score kernel the same at its plane's,
+and both kernels' Mosaic modules, source locations apart, are the ones
+pinned here (what the chip's readings were taken of);
 the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
 kernel once a layer; a language model's AMP training step holds no
 float32 array of [tokens, vocab].
 """
+import base64
 import contextlib
+import functools
+import hashlib
+import json
 import re
 import signal
 
@@ -90,6 +96,31 @@ CHUNK_PAGES = {
 def lower_tpu(f, *args):
     return jax.jit(f).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text()
+
+
+def mosaic_modules(text):
+    """The Mosaic module of every `tpu_custom_call` in `text` (what
+    `lower_tpu` returns), printed WITHOUT what names Python source: the
+    call carries its module as serialised bytecode with a location on
+    every operation, so the raw text moves with any line shift in a
+    kernel's file.  Printed with locations off it holds no file, no
+    line and no Python function's name (its functions are `main` and
+    the block specs' `transform_<i>`): it is the kernel the compiler is
+    given, operation for operation."""
+    from jax._src.lib.mlir import ir
+
+    modules = []
+    for config in re.findall(
+            r'backend_config = "((?:[^"\\]|\\.)*)"', text):
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        with context:
+            modules.append(ir.Module.parse(
+                base64.b64decode(body)).operation.get_asm(
+                    enable_debug_info=False))
+    return modules
 
 
 def _paged(name, sharding=None):
@@ -162,13 +193,19 @@ def _paged(name, sharding=None):
                     shape((s_n, nb), jnp.int32), shape((s_n,), jnp.int32))
 
 
+@functools.lru_cache(maxsize=None)
+def _lowered(name):
+    """`lower_tpu` of pool or plane `name`, once for the tests that
+    read it."""
+    f, args = _paged(name) if name in POOLS else _index_scores(name)
+    return lower_tpu(f, *args)
+
+
 @pytest.mark.parametrize("name", sorted(POOLS))
 def test_paged_attention_lowers_for_tpu(name):
     """One Mosaic module for all the layers of a program: the call sits
     behind a module-level `jax.jit` with the layer a traced scalar."""
-    attend, args = _paged(name)
-    text = lower_tpu(attend, *args)
-    assert text.count(MOSAIC_CALL) == 1
+    assert _lowered(name).count(MOSAIC_CALL) == 1
 
 
 @pytest.fixture(scope="module")
@@ -246,8 +283,64 @@ def _index_scores(name, sharding=None):
 def test_index_scores_lower_for_tpu(name):
     """One Mosaic module however many selecting layers call it: the
     call sits behind a module-level `jax.jit`, the plane traced."""
-    scores, args = _index_scores(name)
-    assert lower_tpu(scores, *args).count(MOSAIC_CALL) == 1
+    assert _lowered(name).count(MOSAIC_CALL) == 1
+
+
+# sha256 of each kernel's Mosaic module (`mosaic_modules`), taken at
+# the parent commit of the PR that put both kernels' page stream into
+# one function (`paged_attention.stream_chunks`, PR 57): a refactoring
+# at trace time emits the parent's operations in the parent's order.
+# The v5e readings in PERF.md section 6 are of THESE kernels: a PR that
+# means to move one re-pins it from its own tree and times parent and
+# change with `tools/kernel_pace.py`.
+MOSAIC_SHA256 = {
+    "chip_smoke-bf16":
+        "8b3fb392118116ceb9ad07141978a3ba36a2a3654c50b1837b12ba4576985dc9",
+    "chip_smoke-fp32":
+        "2c3f48cc795205cc521afa624a94697d991e92d9af930dc8237aed154e1664cf",
+    "deepseek-v2-agent64-latent":
+        "be3a9f5b2df357b759570073c26cf27ca84c467424dfbee4621614fa6cc0ead2",
+    "glm-5.2-docqa64-selected":
+        "0d41b4167bd12f8cffbed550f5175826db5921d5491cfc527307a4ed222c64dc",
+    "granite-chat64":
+        "7d620c4e4dd1270ff25fc2e00d7e8735dadc8174f91058db0b5dec1b472d685f",
+    "k-exaone-chat64-ring":
+        "54320e32fb33a366d0a6e951def3b99b0c5be0536ce084be7b77d996af371943",
+    "k-exaone-chat64-table":
+        "5ac1fee06575b16bb5a6303bbf77ff61223aad4f8372f9e8b9c6fa26f1b35d85",
+    "kv-chunks-of-51":
+        "1a5c31b009f39440d5471a184091ea20d39fd12a114fc34a09a66fa0154e41bb",
+    "longcat-flash-agent64-latent":
+        "24bac2245d0e8047d0c58ac2e2da805f371feae6495f00725fd417fb5b7650e7",
+    "mellum2-agent96-ring":
+        "50c4e356060f8b904ea3882adfab17059f57ef7d96d0a5fbe8db72fcbea29783",
+    "mellum2-agent96-table":
+        "e9b0b3b465fbb1525069606131a0471967786cdb9699c60aa22ec93cc3d4600c",
+    "olmoe-chat32":
+        "e596acc2d03e869b36b218239f8cb6713e5ea90e157bff9f61980df3d1622dd6",
+    "opt-1.3b-closed32":
+        "c460dd3873dcac6b759f99b007a3c077a8c7b02a317a785bb88f80b60014308e",
+    "ouro-chat12":
+        "c03f950b688b3a4f7291b26fc039b46c27e759484082c301b5f2c59738dc7b63",
+    # the two index planes
+    "fp32-pages-of-8":
+        "09a2e5ceac6de785a7e32190c385bca34717421dc4a54bb0e5b73af172ba105a",
+    "glm-5.2-docqa64":
+        "61b66068504f80125bfdc1d85c52247af1a77ded5b61a09b875fb3b8742b5677",
+}
+
+
+@pytest.mark.parametrize("name", sorted(POOLS) + sorted(INDEX_PLANES))
+def test_the_kernel_the_compiler_is_given_is_the_pinned_one(name):
+    """Source locations apart, the Mosaic module of each paged kernel
+    at each cell's pool is, letter for letter, the one its readings on
+    the chip were taken of."""
+    (module,) = mosaic_modules(_lowered(name))
+    assert "loc(" not in module and ".py" not in module
+    assert set(re.findall(r'sym_name = "([^"]*)"', module)) <= (
+        {"main"} | {f"transform_{i}" for i in range(16)})
+    assert hashlib.sha256(module.encode()).hexdigest() == \
+        MOSAIC_SHA256[name]
 
 
 @pytest.mark.parametrize("name", sorted(INDEX_PLANES))

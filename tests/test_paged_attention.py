@@ -1195,6 +1195,199 @@ def test_analyze_rows_follow_the_platform(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# one page stream under both paged kernels, and one account of it
+# ---------------------------------------------------------------------------
+
+
+def test_both_paged_kernels_trace_through_the_one_stream(monkeypatch):
+    """The chunk loop, its starts and waits, the prefetch across lanes
+    and the buffer cursor are `stream_chunks` and nothing else: tracing
+    the attention kernel (K and V, and a latent pool) and the
+    index-score kernel calls it once each, with that kernel's pools and
+    its own issue group, and neither `_kernel` copies or counts pages
+    itself."""
+    import inspect
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import paged_index_scores
+
+    real = paged_attention.stream_chunks
+    assert paged_index_scores.stream_chunks is real
+    calls = []
+
+    def counted(*args, **kw):
+        calls.append((len(args[4]), kw["unroll"], kw["pages"]))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(paged_attention, "stream_chunks", counted)
+    monkeypatch.setattr(paged_index_scores, "stream_chunks", counted)
+    tables = jnp.zeros((3, 7), jnp.int32)
+    lengths = jnp.ones((3,), jnp.int32)
+    pool = jnp.zeros((2, 9, 4, 24), jnp.float32)
+    latent = jnp.zeros((2, 9, 4, 40), jnp.float32)
+    # shapes no other test of this process has traced: the calls sit
+    # behind module-level `jax.jit`s, which keep their traces
+    jax.make_jaxpr(functools.partial(
+        paged_attention.paged_attention, scale=0.3, pages=3, tile=1,
+        n_heads=3, d_head=8))(
+            jnp.zeros((3, 24)), pool, pool, tables, lengths, 1)
+    jax.make_jaxpr(functools.partial(
+        paged_attention.paged_attention, scale=0.3, pages=5, tile=1,
+        n_heads=3, d_head=0, d_value=32))(
+            jnp.zeros((3, 120)), latent, None, tables, lengths, 1)
+    jax.make_jaxpr(functools.partial(
+        paged_index_scores.paged_index_scores, pages=4, tile=2))(
+            jnp.zeros((3, 2, 24)), jnp.zeros((3, 2)), pool, tables,
+            lengths, 1)
+    assert calls == [(2, paged_attention._ISSUE_UNROLL, 3),
+                     (1, paged_attention._ISSUE_UNROLL, 5),
+                     (1, paged_index_scores._ISSUE_UNROLL, 4)]
+    for module in (paged_attention, paged_index_scores):
+        own = inspect.getsource(module._kernel)
+        assert "stream_chunks(" in own
+        assert "start_pages" not in own and "cursor_ref[" not in own
+        assert ".wait()" not in own.replace("copy.wait()", "")
+
+
+# the ten served blocks' toys: each configuration's file under its own
+# `rehearse` overlay, as the benchmark's jobs build them
+_TOYS = ["opt-1.3b", "olmoe-1b-7b-1chip", "mellum2-12b-a2.5b-1chip",
+         "granite-4.0-h-small-1chip", "ouro-2.6b",
+         "k-exaone-236b-a23b-1chip", "deepseek-v2-1chip",
+         "longcat-flash-1chip", "glm-5.2-1chip", "lfm2-24b-a2b-1chip"]
+# what of a tick's counts the page streams decide
+_STREAMED = ("kv_pages_read", "kv_rows_multiplied", "kv_dma_ops",
+             "index_pages_read", "index_dma_ops")
+
+
+def _toy_decoder(name, block_size, max_blocks):
+    """-> (the toy of configuration `name` with every paged kernel it
+    can run selected (the interpreter's selection: nothing is run), in
+    chunks of 2048 bytes and row tiles of 8 rows; the index-score
+    kernel's tiling over its table, or None)."""
+    import json
+    import os
+
+    from paddle_tpu.kernels import paged_index_scores
+    from paddle_tpu.models import lm_block
+    from paddle_tpu.models.transformer import build_lm_paged_decoder
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "perf",
+                           "configs", name + ".json")) as f:
+        m = json.load(f)
+    m.update(m["rehearse"])
+    spec, d_inner = None, m.get("ffn_dim")
+    if "block" in m:
+        b = m["block"]
+        spec = lm_block.BlockSpec(**dict(
+            b["spec"], **{f: m[k] for f, k in b["from_keys"].items()}))
+        d_inner = m[b["d_inner"]]
+    index = functools.partial(paged_index_scores.select_index_scores,
+                              interpret=True)
+    with _interpreted(chunk_bytes=2048, tile_rows=8), \
+            pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_index_scores, "select_index_scores", index)
+        mp.setattr(paged_index_scores, "_CHUNK_BYTES", 2048)
+        fw.reset_unique_names()
+        _, dec = build_lm_paged_decoder(
+            m["vocab_size"], block_size, max_blocks,
+            d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
+            n_layers=m["num_hidden_layers"], d_inner=d_inner,
+            platform="cpu", block=spec)
+        tiling = None
+        if dec.index_planes:
+            tiling = index(
+                index_head_dim=spec.index_head_dim, block_size=block_size,
+                kv_dtype="fp32", platform="cpu")[0].tiling(max_blocks)
+    return dec, tiling
+
+
+def _parents_streamed_counts(dec, index_tiling, rows, slots, saved):
+    """`tick_counts`' arithmetic over the page streams as it stood at
+    the parent of PR 57, a copy for the table, for the ring and for the
+    index planes: the oracle of `stream_counts`."""
+    bs, idle = dec.block_size, slots - len(rows)
+    pools = 1 if dec.kernels["paged_attention_decode"].endswith(
+        "latent") else 2
+    read = multiplied = dma = 0
+    reached = [(dec.table_layers, -(-rows // bs),
+                dec.attention_tiling[0], "table")]
+    if dec.ring_layers:
+        reached.append(
+            (dec.ring_layers,
+             -(-np.minimum(rows, dec.window_blocks_per_seq * bs) // bs),
+             dec.attention_tiling[1], "ring"))
+    for layers_n, pages, (chunk, tile), held in reached:
+        read += layers_n * (idle + int(pages.sum()))
+        multiplied += layers_n * int(
+            idle * paged_attention.rows_multiplied(1, chunk, tile, bs)
+            + paged_attention.rows_multiplied(
+                pages, chunk, tile, bs).sum())
+        dma += layers_n * pools * int(
+            idle * paged_attention.dma_ops(1, chunk)
+            + paged_attention.dma_ops(
+                pages, chunk, saved.get(held)).sum())
+    want = {"kv_pages_read": read, "kv_rows_multiplied": multiplied,
+            "kv_dma_ops": dma}
+    if index_tiling is not None:
+        from paddle_tpu.kernels.paged_index_scores import _ISSUE_UNROLL
+        pages = -(-rows // bs)
+        want["index_pages_read"] = dec.index_planes * (
+            idle + int(pages.sum()))
+        want["index_dma_ops"] = dec.index_planes * int(
+            idle * paged_attention.dma_ops(1, index_tiling[0])
+            + paged_attention.dma_ops(
+                pages, index_tiling[0], saved.get("index"),
+                _ISSUE_UNROLL).sum())
+    return want
+
+
+@pytest.mark.parametrize("name", _TOYS)
+def test_the_one_account_is_the_parents_arithmetic(name):
+    """`decoder.tick_counts` over random cursors, tables with runs and
+    without, rings and idle lanes: what the streams decide
+    (`stream_counts`, once for the table, for the ring and for the
+    index planes) is what the parent's three copies computed, with and
+    without the saved starts, and every other attribute is the one the
+    gather path reports for the same cursors."""
+    slots, bs, nb = 6, 4, 11
+    dec, index_tiling = _toy_decoder(name, bs, nb)
+    assert dec.attention_tiling is not None
+    assert (index_tiling is not None) == bool(dec.index_planes)
+    r = np.random.RandomState(len(name))
+    rings = (np.asarray(dec.slot_rings(slots)) if dec.ring_layers
+             else None)
+    seen = set()
+    for trial in range(40):
+        # a lane's table: ascending blocks from somewhere, of which a
+        # few trials shuffle a few lanes (no run survives there)
+        tables = (1 + r.randint(0, 50, (slots, 1))
+                  + np.arange(nb)[None, :])
+        for lane in r.permutation(slots)[:trial % 4]:
+            tables[lane] = r.permutation(tables[lane])
+        held = dec.starts_saved(tables, rings)
+        lanes = np.sort(r.permutation(slots)[:r.randint(0, slots + 1)])
+        cursors = r.randint(0, bs * nb, len(lanes)).astype(np.int32)
+        for saved in ({}, {k: v[lanes] for k, v in held.items()}):
+            got = dec.tick_counts(cursors, slots, saved=saved)
+            want = _parents_streamed_counts(
+                dec, index_tiling, cursors.astype(np.int64) + 1, slots,
+                saved)
+            assert {k: got[k] for k in _STREAMED if k in got} == want
+            assert all(type(v) is int for v in got.values())
+            gathered = dec.tick_counts(cursors, slots, windowed=True)
+            assert {k: v for k, v in got.items()
+                    if k not in _STREAMED} == {
+                        k: v for k, v in gathered.items()
+                        if k not in _STREAMED}
+            seen |= set(got)
+    assert set(want) <= seen
+    assert ("index_dma_ops" in seen) == (name == "glm-5.2-1chip")
+
+
+# ---------------------------------------------------------------------------
 # the kernel-alone harness (tools/kernel_pace.py)
 # ---------------------------------------------------------------------------
 
