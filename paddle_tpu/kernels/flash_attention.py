@@ -17,6 +17,18 @@ goes through a transposed copy, [batch * heads, seq, head_dim].  grid =
 (bh, q_blocks, k_blocks) with the k dimension innermost so the VMEM
 accumulator scratch persists across K/V blocks of one query tile.
 
+The forward's online softmax keeps three things a query row: the running
+maximum m (a scratch column), the unnormalised output (the accumulator)
+and the running denominator l.  Where a head's values leave lanes of the
+128 free (head size 64, paired or alone: `_rides`) l has NO scratch and
+NO reduction of its own: the `p . V` product's right operand is the
+head's V tile with one column of ones in a free lane, so `sum_j p_ij` is
+one more column of the product the MXU makes anyway, and l lives in that
+column of the head's accumulator, [block_q, 128] float32 a head, rescaled
+with the rest.  It sums the p the values are multiplied by (rounded to
+their dtype).  A head of 128 fills the lanes: its l is a scratch column
+fed by a reduction over the score tile's lanes.
+
 Backward is the standard flash recomputation: forward saves only the
 per-row logsumexp ([bh, pack, seq], one lane a query); dq, dk and dv come
 from ONE more streaming kernel that computes each score tile once, or
@@ -45,7 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_forward",
            "flash_attention_backward", "flash_attention_reference",
-           "flash_attention_subtiles", "causal_subtiles"]
+           "flash_attention_subtiles", "flash_attention_row_reductions",
+           "causal_subtiles"]
 
 NEG_INF = -1e30  # finite mask value: keeps exp()/max() NaN-free in-kernel
 # measured on v5e at seq 4096, d 128, bf16 (async-chain, distinct inputs):
@@ -134,22 +147,70 @@ def _when_causal_keys(qi, ki, block_q, block_k, compute):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, block_k, nk, pack, d_head):
+def _rides(d_head):
+    """Whether the softmax denominator rides the `p . V` product: a
+    head's value columns leave lanes of the 128 free (head size 64), so
+    a column of ones beside them gives `sum_j p_ij` as one more column
+    of the product the kernel makes anyway (the MXU pass costs the same
+    at 64 columns and at 128).  A head of 128 fills the lanes: a ones
+    column would be a whole pass more, and the sum is a reduction over
+    the score tile's lanes."""
+    return d_head < 128
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                l_ref=None, *, scale, causal, block_q, block_k, nk, pack,
+                d_head):
     """pack >= 2 folds `pack` heads side-by-side in the trailing dim
     (q/k/v tiles [block, pack*d_head]): loads/stores fill the 128-lane
     dim even at d_head 64, and the online softmax runs per packed head
     on its own [block_q, block_k] score tile (block-diagonal — heads
-    never mix).  m/l scratch: a head's statistics are column hs * cw,
-    the first of its band."""
+    never mix).
+
+    Where the denominator rides (`_rides`) a head's accumulator is its
+    product's full 128 columns, acc_ref [block_q, pack * 128]: the
+    head's values in the lanes they have in the tile and the running
+    denominator l in the lane of the ones, `stat` below, which
+    `acc * alpha` rescales with the rest; there is no l scratch.  Else
+    acc_ref is [block_q, pack * d_head] and l_ref holds l.  m (and such
+    an l) sit in column `stat` of [block_q, 128]."""
     i, j = pl.program_id(1), pl.program_id(2)
-    cw = 128 // pack  # scratch column band per packed head
+    rides = l_ref is None
+    aw = 128 if rides else d_head     # a head's accumulator columns
+
+    def stat(hs):
+        # the lane after the head's values: 64 | 0 for a pair of 64
+        return (hs + 1) * d_head % 128
+
+    def denominator(hs):
+        """Head hs's l, [block_q, 1]: the ONE place it is read."""
+        if rides:
+            return acc_ref[:, hs * aw + stat(hs):hs * aw + stat(hs) + 1]
+        return l_ref[:, stat(hs):stat(hs) + 1]
 
     @pl.when(j == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        if not rides:
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+    def values(v, hs):
+        """The product's right operand: head hs's [block_k, d_head]; or,
+        riding, [block_k, 128] with the head's values where they lie in
+        the tile and ones in lane `stat(hs)`."""
+        sl = slice(hs * d_head, (hs + 1) * d_head)
+        if not rides:
+            return v[:, sl]
+        if pack * d_head == 128:
+            # the tile fills the lanes: two selects, no lane moves
+            lanes = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+            mine = (lanes >= sl.start) & (lanes < sl.stop)
+            ones = jnp.where(lanes == stat(hs), 1.0, 0.0).astype(v.dtype)
+            return jnp.where(mine, v, ones)
+        # a head alone: every free lane holds the ones (and so l)
+        return jnp.concatenate(
+            [v, jnp.ones((v.shape[0], 128 - d_head), v.dtype)], axis=1)
 
     def _compute():
         # operands stay in their storage dtype: bf16 x bf16 -> f32 rides
@@ -172,26 +233,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 preferred_element_type=jnp.float32) * scale
             if causal:
                 s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_ref[:, hs * cw:hs * cw + 1]
-            l_prev = l_ref[:, hs * cw:hs * cw + 1]
+            m_prev = m_ref[:, stat(hs):stat(hs) + 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[:, sl] = acc_ref[:, sl] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v[:, sl], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            # the band's first column alone is ever read (here and in
-            # `_finish`).  Heads that share the lanes store that column:
-            # a store across half the lanes was 0.38 ms of a 2.19 ms
-            # call at 64 x 2048 x 2 x 64.  A head alone fills them with
-            # one unmasked store, which read 2% faster than its column
-            # at 16 x 8192 x 128 (PERF.md section 6, PR 47)
-            width = cw if pack == 1 else 1
-            band = slice(hs * cw, hs * cw + width)
+            # riding, the denominator sums the p that the values are
+            # multiplied by, rounded to their dtype: a row's weights sum
+            # to 1 over what is multiplied
+            cols_h = slice(hs * aw, (hs + 1) * aw)
+            acc_ref[:, cols_h] = acc_ref[:, cols_h] * alpha + (
+                jax.lax.dot_general(
+                    p.astype(v.dtype), values(v, hs),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            # the statistics' one column alone is ever read (here and
+            # in `_finish`).  Heads that share the lanes store that
+            # column: a store across half the lanes was 0.38 ms of a
+            # 2.19 ms call at 64 x 2048 x 2 x 64.  A head alone fills
+            # them with one unmasked store, which read 2% faster than
+            # its column at 16 x 8192 x 128 (PERF.md section 6, PR 47)
+            band = (slice(0, 128) if pack == 1
+                    else slice(stat(hs), stat(hs) + 1))
+            width = band.stop - band.start
             m_ref[:, band] = jnp.broadcast_to(m_new, (block_q, width))
-            l_ref[:, band] = jnp.broadcast_to(l_new, (block_q, width))
+            if not rides:
+                l_new = alpha * denominator(hs) + jnp.sum(
+                    p, axis=1, keepdims=True)
+                l_ref[:, band] = jnp.broadcast_to(l_new, (block_q, width))
 
     if causal:
         # skip K/V blocks strictly above the diagonal of this query tile
@@ -203,17 +272,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(j == nk - 1)
     def _finish():
-        l_safe = jnp.where(l_ref[:] == 0.0, 1.0, l_ref[:])
+        # column `stat` of a head is its l; the other lanes read 1
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (block_q, 128), 1)
+        l_all = jnp.ones((block_q, 128), jnp.float32)
+        for hs in range(pack):
+            l = denominator(hs)
+            l = jnp.where(l == 0.0, 1.0, l)
+            sl = slice(hs * d_head, (hs + 1) * d_head)
+            # riding, the head's values have the tile's lanes
+            at = hs * aw + (sl.start if rides else 0)
+            o_ref[0, :, sl] = (acc_ref[:, at:at + d_head] / l
+                               ).astype(o_ref.dtype)
+            l_all = jnp.where(lanes == stat(hs), l, l_all)
         # the statistics sit one row a query (block_q sublanes); they are
         # SAVED one lane a query, [pack, block_q]: a [.., seq, pack] array
         # pads its 2 lanes to 128 in HBM (67 MB a layer at 64 x 2048 x 2)
-        lse_t = (m_ref[:] + jnp.log(l_safe)).T      # (128, block_q)
+        lse_t = (m_ref[:] + jnp.log(l_all)).T      # (128, block_q)
         for hs in range(pack):
-            sl = slice(hs * d_head, (hs + 1) * d_head)
-            o_ref[0, :, sl] = (acc_ref[:, sl]
-                               / l_safe[:, hs * cw:hs * cw + 1]
-                               ).astype(o_ref.dtype)
-            lse_ref[0, hs:hs + 1, :] = lse_t[hs * cw:hs * cw + 1, :]
+            lse_ref[0, hs:hs + 1, :] = lse_t[stat(hs):stat(hs) + 1, :]
 
 
 def _tile(block, d, groups, seq_block):
@@ -251,9 +327,11 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
     sk = k.shape[1]
     bh, d = nb * groups, gd // groups    # d = pack * d_head
     nq, nk = sq // block_q, sk // block_k
+    d_head = d // pack
+    rides = _rides(d_head)
     kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                              block_q=block_q, block_k=block_k, nk=nk,
-                             pack=pack, d_head=d // pack)
+                             pack=pack, d_head=d_head)
     q_spec = _tile(block_q, d, groups, lambda i, j: i)
     kv_spec = _tile(block_k, d, groups, _kv_block(causal, block_q, block_k))
     return pl.pallas_call(
@@ -268,11 +346,11 @@ def _fwd_pallas(q, k, v, scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, pack, sq), jnp.float32),
         ],
+        # the accumulator, m and, where it does not ride in the
+        # accumulator (`_rides`), l
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-        ],
+            pltpu.VMEM((block_q, pack * 128 if rides else d), jnp.float32),
+        ] + [pltpu.VMEM((block_q, 128), jnp.float32)] * (1 if rides else 2),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(q, k, v)
@@ -723,6 +801,23 @@ def flash_attention_subtiles(q, k, v, causal=False, scale=None,
         return None
     return causal_subtiles(q.shape[1], k.shape[1], plan.block_q,
                            plan.block_k)
+
+
+def flash_attention_row_reductions(q, k, v, causal=False, scale=None,
+                                   block_q=None, block_k=None,
+                                   interpret=None,
+                                   min_seq_k=MIN_PALLAS_SEQ_K,
+                                   platform=None):
+    """Reductions over a score tile's lanes that the forward kernel
+    makes a query row a key block, in the call `flash_attention` makes
+    of the same arguments (arrays or their shapes): 2, the row maximum
+    and the row sum; 1 where the sum rides the `p . V` product
+    (`_rides`); None where the kernel is not what runs."""
+    plan = _plan(q, k, v, causal, scale, block_q, block_k, interpret,
+                 min_seq_k, platform)
+    if plan is None:
+        return None
+    return 1 if _rides(q.shape[-1]) else 2
 
 
 def flash_attention_backward(q, k, v, out, lse, d_out, causal=False,

@@ -20,13 +20,50 @@ def _rand_qkv(b=2, s=256, h=2, d=64, seed=0, dtype=np.float32):
     return mk(), mk(), mk()
 
 
+def _reference_lse(q, k, causal):
+    """logsumexp of the scaled, masked scores, [b, h, sq] float32."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        s = jnp.where(jnp.arange(q.shape[1])[:, None]
+                      >= jnp.arange(k.shape[1])[None, :], s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+@pytest.mark.parametrize("heads,d_head,dtype", [
+    (2, 64, "float32"),     # a pair of 64: the denominator rides p . V
+    (2, 64, "bfloat16"),
+    (1, 64, "bfloat16"),    # a head of 64 alone: it rides too
+    (2, 32, "float32"),     # so does any head under the lanes' 128
+    (1, 96, "bfloat16"),
+    (1, 128, "bfloat16"),   # a head of 128 keeps the summed denominator
+])
 @pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_reference(causal):
-    q, k, v = _rand_qkv()
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
-    ref = flash_attention_reference(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+def test_forward_matches_reference(causal, heads, d_head, dtype):
+    """`out` and `lse` against the composition on float32 copies of the
+    inputs as they are stored.  float32 inputs: the order of float32
+    sums alone differs.  bfloat16 inputs: the scores are exact products
+    summed in float32 on both sides; the kernel rounds p to bfloat16 for
+    the `p . V` product (8 bits of mantissa: at most 2**-9 of each
+    weight, to nearest), and where the denominator rides that product it
+    sums the ROUNDED p, so `lse` = m + log(l) moves by at most
+    log(1 + 2**-9) < 2**-9; `out` carries p's rounding and its own to
+    bfloat16, 2**-9 each of values that reach 3.3 here."""
+    fa = _kernel_module()
+    q, k, v = (x.astype(dtype) for x in _rand_qkv(h=heads, d=d_head))
+    out, lse = fa.flash_attention_forward(q, k, v, causal=causal,
+                                          interpret=True)
+    assert out.dtype == q.dtype and lse.dtype == jnp.float32
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    ref = flash_attention_reference(q32, k32, v32, causal=causal)
+    tol, lse_tol = ((2e-5, 2e-5) if dtype == "float32" else
+                    (2 * 2.0 ** -9 * 3.3, 2.0 ** -9 if d_head < 128 else 2e-5))
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
+                               np.asarray(ref), rtol=tol, atol=tol)
+    # [b * h / pack, pack, sq] -> [b, h, sq]: folded heads are adjacent
+    np.testing.assert_allclose(
+        np.asarray(lse).reshape(q.shape[0], heads, q.shape[1]),
+        np.asarray(_reference_lse(q32, k32, causal)),
+        rtol=0, atol=lse_tol)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -523,19 +560,63 @@ def _case_bodies(fn, *args):
     return bodies
 
 
-@pytest.mark.parametrize("kernel", ["forward", "fused", "dq", "dkv"])
+def _eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs inside it, in order."""
+    yield from jaxpr.eqns
+    for inner in _sub_jaxprs(jaxpr):
+        yield from _eqns(inner)
+
+
+def _lane_reductions(fn, *args):
+    """[(primitive, operand shape)] of the reductions over the lanes of
+    a 2-D tile of more than 128 columns (a score tile) anywhere in
+    `fn`'s traced kernels."""
+    found = []
+    for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        shape = tuple(getattr(eqn.invars[0].aval, "shape", ())) \
+            if eqn.invars else ()
+        if (eqn.primitive.name.startswith("reduce_")
+                and len(shape) == 2 and shape[1] > 128
+                and tuple(eqn.params.get("axes", ())) == (1,)):
+            found.append((eqn.primitive.name, shape))
+    return found
+
+
+@pytest.mark.parametrize("kernel", [
+    "forward", "forward-head-64-alone", "forward-head-128", "fused", "dq",
+    "dkv"])
 def test_a_cut_case_multiplies_its_live_keys_only(kernel, monkeypatch):
     """The traced kernel's cases at blocks of 128 x 256: in the
     backward's kernels the tile on the diagonal with one live half
     multiplies 128 keys and every other one all 256; the forward has one
-    body, over all 256."""
+    body, over all 256, and in it a head's reductions over the score
+    tile's lanes: the maximum alone where the head's values leave lanes
+    of the product free for the denominator's column of ones (a head of
+    64, paired or alone), the maximum and the sum at a head of 128."""
     fa = _kernel_module()
     x = jnp.zeros((1, 512, 128), jnp.float32)       # a packed head pair
     stat = jnp.zeros((1, 2, 512), jnp.float32)
     plan = (0.125, True, 128, 256, False, 2, 1)
-    if kernel == "forward":
-        assert _case_bodies(lambda q, k, v: fa._fwd_pallas(
-            q, k, v, *plan), x, x, x) == [(128, 256)]
+    if kernel.startswith("forward"):
+        pack, width = {"forward": (2, 128), "forward-head-64-alone": (1, 64),
+                       "forward-head-128": (1, 128)}[kernel]
+        x = jnp.zeros((1, 512, width), jnp.float32)
+
+        def forward(q, k, v):
+            return fa._fwd_pallas(q, k, v, *plan[:5], pack, 1)
+
+        assert _case_bodies(forward, x, x, x) == [(128, 256)]
+        tile = (128, 256)
+        assert _lane_reductions(forward, x, x, x) == (
+            [("reduce_max", tile), ("reduce_sum", tile)]
+            if kernel == "forward-head-128"
+            else [("reduce_max", tile)] * pack)
+        # the product's result a head: its values' columns, or all 128
+        # with the denominator among them
+        assert [tuple(e.outvars[0].aval.shape) for e in _eqns(
+            jax.make_jaxpr(forward)(x, x, x).jaxpr)
+            if e.primitive.name == "dot_general"] == [
+                tile, (128, 128)] * pack
         return
     if kernel != "fused":
         monkeypatch.setattr(fa, "FUSED_BWD_DQ_VMEM_BUDGET", 0)
@@ -559,10 +640,9 @@ def test_a_cut_case_multiplies_its_live_keys_only(kernel, monkeypatch):
     assert backward(False) == []
 
 
-def test_subtiles_share_reads_the_cells_shape(monkeypatch):
-    """`train_attention_subtiles_share`: the kernel module's count of
-    the calls it makes at the cell's shape, forward and backward over
-    the live ones twice; nothing where the kernel is not what runs."""
+def _cell_run(monkeypatch, reader):
+    """(the reader `perf/metrics/<reader>.py`, a run of the training
+    cell for it to read: configuration and traffic, nothing else)."""
     import os
     import sys
     import types
@@ -573,14 +653,21 @@ def test_subtiles_share_reads_the_cells_shape(monkeypatch):
     monkeypatch.delitem(sys.modules, "common", raising=False)
     import common
 
-    reader = common.load_module(os.path.join(
-        perf, "metrics", "train_attention_subtiles_share.py"))
     cell = types.SimpleNamespace(
         config=common.load_json(os.path.join(
             perf, "configs", "opt-1.3b-depth8.json")),
         traffic=common.load_json(os.path.join(
             perf, "traffic", "pretrain-seq2048.json")))
-    run = types.SimpleNamespace(cell=cell)
+    return (common.load_module(os.path.join(perf, "metrics",
+                                            reader + ".py")),
+            types.SimpleNamespace(cell=cell))
+
+
+def test_subtiles_share_reads_the_cells_shape(monkeypatch):
+    """`train_attention_subtiles_share`: the kernel module's count of
+    the calls it makes at the cell's shape, forward and backward over
+    the live ones twice; nothing where the kernel is not what runs."""
+    reader, run = _cell_run(monkeypatch, "train_attention_subtiles_share")
     assert reader.compute(run) is None      # no TPU here: the composition
     fa = _kernel_module()
     monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
@@ -593,12 +680,100 @@ def test_subtiles_share_reads_the_cells_shape(monkeypatch):
     assert reader.compute(run) is None
 
 
-def test_kernel_pace_rehearses_the_training_cells_flash_kernels(tmp_path):
+@pytest.mark.parametrize("shape,want", [
+    ((4, 2048, 32, 64), 1),     # opt-1.3b-train-seq2048: the sum rides
+    ((1, 8192, 32, 64), 1),     # flash-seq8192
+    ((1, 8192, 31, 64), 1),     # heads of 64 that do not pair
+    ((1, 8192, 16, 128), 2),    # flash-seq8192-d128: maximum and sum
+    ((4, 1024, 32, 64), None),  # under the crossover: the composition
+])
+def test_row_reductions_counts_what_the_forward_would_run(
+        shape, want, monkeypatch):
+    """`flash_attention_row_reductions`: the reductions over a score
+    tile's lanes in the forward call `flash_attention` makes of the same
+    arguments; the reader `train_attention_row_reductions` gives the
+    cell's, and nothing without the function or the kernel."""
+    fa = _kernel_module()
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert fa.flash_attention_row_reductions(
+        x, x, x, causal=True, platform="tpu") == want
+    assert fa.flash_attention_row_reductions(x, x, x, causal=True) is None
+    if shape != (4, 2048, 32, 64):
+        return
+    reader, run = _cell_run(monkeypatch, "train_attention_row_reductions")
+    assert reader.compute(run) is None      # no TPU here: the composition
+    monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
+    assert reader.compute(run) == want
+    monkeypatch.delattr(fa, "flash_attention_row_reductions")
+    assert reader.compute(run) is None
+
+
+_STEP_HLO = """\
+HloModule jit_fn
+
+ENTRY %main (a: bf16[4,2048,2048]) -> bf16[4,2048,2048] {
+  %a = bf16[4,2048,2048]{2,1,0} parameter(0)
+  %flash_attention_0.tmp_0.1 = (bf16[4,2048,2048]{2,1,0}, f32[64,2,2048]{2,1,0}) custom-call(%a, %a, %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/flash_attention:flash_attention_0.tmp_0/pallas_call"}
+  %flash_attention_1.tmp_0.1 = (bf16[4,2048,2048]{2,1,0}, f32[64,2,2048]{2,1,0}) custom-call(%a, %a, %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/flash_attention:flash_attention_1.tmp_0/pallas_call"}
+  %flash_attention_2.tmp_0.1 = (bf16[4,2048,2048]{2,1,0}, f32[64,2,2048]{2,1,0}) custom-call(%a, %a, %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/flash_attention:flash_attention_2.tmp_0/pallas_call"}
+  %flash_attention_grad_reshape_0.tmp_0.1 = bf16[4,2048,2048]{2,1,0} custom-call(%a, %a, %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/flash_attention_grad:reshape_0.tmp_0@GRAD/pallas_call"}
+  %grouped.1 = bf16[4,2048,2048]{2,1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(fn)/mul:fc_0.tmp_0/pallas_call"}
+  ROOT %fusion.1 = bf16[4,2048,2048]{2,1,0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(fn)/flash_attention:flash_attention_0.tmp_0/mul"}
+}
+"""
+
+
+def test_forward_ms_reader_on_hand_made_text(monkeypatch):
+    """`train_attention_forward_ms`: the slice's seconds of the Mosaic
+    calls under the Program op type `flash_attention` that it timed (not
+    the grad op's, another op's kernel or a fusion under the same op),
+    over their count and over the steps its BUSY seconds hold, so a
+    slice with a stall in it reads the kernel's own time.  `None`
+    without a device plane, a registered text, a step or such a call."""
+    from paddle_tpu import profiler
+
+    reader, run = _cell_run(monkeypatch, "train_attention_forward_ms")
+    timed = {"flash_attention_0.tmp_0.1": 0.0170,
+             "flash_attention_1.tmp_0.1": 0.0160,
+             "flash_attention_grad_reshape_0.tmp_0.1": 0.0250,
+             "grouped.1": 0.5, "fusion.1": 0.5}
+    run.trace = {"op_seconds": timed, "window_s": 3.0, "busy_s": 2.0}
+    run.samples = {"step_done": [0.2 * n for n in range(16)]}
+    profiler.reset_profiler()
+    try:
+        assert reader.compute(run) is None      # no text registered
+        profiler._register_hlo_text("executor.block",
+                                    lambda: "ENTRY %m {\n}")
+        assert reader.compute(run) is None      # no such call in it
+        profiler._register_hlo_text("executor.block", lambda: _STEP_HLO)
+        profiler._register_hlo_text("paged_decoder.step",
+                                    lambda: _STEP_HLO)
+        # two timed calls, ten steps of 200 ms in two busy seconds
+        assert reader.compute(run) == pytest.approx(
+            1e3 * (0.0170 + 0.0160) / 2 / 10)
+        run.samples = {"step_done": [0.0]}
+        assert reader.compute(run) is None      # no step was measured
+        run.samples = {"step_done": [0.0, 0.2]}
+        run.trace = {"op_seconds": {}, "window_s": 3.0, "busy_s": 0.0}
+        assert reader.compute(run) is None      # no device plane
+        run.trace = None
+        assert reader.compute(run) is None
+    finally:
+        profiler.reset_profiler()
+
+
+@pytest.mark.parametrize("variant", [
+    "whole", "uncut", "no_mask", "no_row_sum", "no_row_max"])
+def test_kernel_pace_rehearses_the_training_cells_flash_kernels(
+        tmp_path, variant):
     """`tools/kernel_pace.py --shape opt-1.3b-train-seq2048 --rehearse
     --check`: the cell's 4 x 2 tiles a head at toy blocks through the
     interpreter, forward and backward each alone; `uncut` (the table of
     a kernel that takes a live tile whole) computes the same, `no_mask`
-    does not; off a TPU the tool gives a time for nothing else."""
+    does not; `no_row_sum` and `no_row_max` take the forward's
+    reductions over a score tile's lanes out of the trace (the sum is
+    there at a head of 128 alone: at 64 it rides the product); off a TPU
+    the tool gives a time for nothing else."""
     import importlib.util
     import json
     import os
@@ -608,22 +783,49 @@ def test_kernel_pace_rehearses_the_training_cells_flash_kernels(tmp_path):
     spec = importlib.util.spec_from_file_location("kernel_pace", path)
     tool = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(tool)
-    out = tmp_path / "pace.json"
-    res = tool.main(["--shape", "opt-1.3b-train-seq2048", "--rehearse",
-                     "--check", "--out", str(out)])
-    assert res == json.loads(out.read_text())
-    assert res["rehearsal"] and res["fwd.whole"] > 0 and res["bwd.whole"] > 0
-    assert res["blocks"] == [128, 256] and res["subtiles"] == [12, 10, 10]
-    assert res["check"] < 1e-2
+    assert set(tool.FLASH_VARIANTS) == {
+        "whole", "uncut", "no_mask", "no_row_sum", "no_row_max"}
     fa = _kernel_module()
     toy = dict(tool.SHAPES["opt-1.3b-train-seq2048"], batch=1, heads=2,
                seq=512, block_q=128, block_k=256)
-    with tool.flash_removed("uncut", fa):
-        assert fa.causal_subtiles(512, 512, 128, 256) == (12, 12, 10)
-        assert tool.check_flash(toy, fa, True) < 1e-2
-    with tool.flash_removed("no_mask", fa):
-        assert tool.check_flash(toy, fa, True) > 1e-1
+    toy128 = dict(toy, heads=1, d_head=128)
+
+    def reductions(shape):
+        fwd, _, (q, k, v, _), _, _ = tool.build_flash(shape, fa, True)
+        return sorted(name for name, _ in _lane_reductions(fwd, q, k, v))
+
+    if variant == "whole":
+        out = tmp_path / "pace.json"
+        res = tool.main(["--shape", "opt-1.3b-train-seq2048", "--rehearse",
+                         "--check", "--out", str(out)])
+        assert res == json.loads(out.read_text())
+        assert (res["rehearsal"] and res["fwd.whole"] > 0
+                and res["bwd.whole"] > 0)
+        assert res["blocks"] == [128, 256] and res["subtiles"] == [12, 10, 10]
+        assert res["check"] < 1e-2
+        with pytest.raises(SystemExit, match="no TPU"):
+            tool.run("opt-1.3b-train-seq2048")
+        return
+    with tool.flash_removed(variant, fa):
+        if variant == "uncut":
+            assert fa.causal_subtiles(512, 512, 128, 256) == (12, 12, 10)
+            assert tool.check_flash(toy, fa, True) < 1e-2
+        elif variant == "no_mask":
+            assert tool.check_flash(toy, fa, True) > 1e-1
+        elif variant == "no_row_sum":
+            # nothing to remove where the sum rides; a head of 128
+            # without its sum is WRONG
+            assert reductions(toy) == ["reduce_max"] * 2
+            assert reductions(toy128) == ["reduce_max"]
+            assert tool.check_flash(toy, fa, True) < 1e-2
+            assert tool.check_flash(toy128, fa, True) > 1e-1
+        else:
+            # softmax does not move with what is subtracted, short of an
+            # overflow: these scores are small
+            assert reductions(toy) == [] and reductions(toy128) == [
+                "reduce_sum"]
+            assert tool.check_flash(toy, fa, True) < 1e-2
     assert fa.causal_subtiles(512, 512, 128, 256) == (12, 10, 10)
+    assert reductions(toy) == ["reduce_max"] * 2
+    assert reductions(toy128) == ["reduce_max", "reduce_sum"]
     assert tool.check_flash(toy, fa, True) < 1e-2
-    with pytest.raises(SystemExit, match="no TPU"):
-        tool.run("opt-1.3b-train-seq2048")

@@ -36,7 +36,11 @@ layer's forward call and its backward call, each alone, ms a call):
 whole, `no_mask` (no tile applies the causal mask: WRONG results, true
 time) and `uncut` (the backward's kernels take every live tile over all
 its keys, as the forward does: right results, what
-`flash_attention._causal_keys` saves).  `--check`: the kernel under
+`flash_attention._causal_keys` saves), and the forward's two reductions
+over a score tile's lanes each handing back the tile's first column:
+`no_row_sum` (WRONG results; nothing to remove where the denominator
+rides the `p . V` product: head size 64 since PR 58) and `no_row_max`
+(right results short of an overflow).  `--check`: the kernel under
 `jax.grad` against `flash_attention_reference`.  Copied into the
 checkout of a commit before PR 47 the same command times that commit's
 kernels (`--variants whole,no_mask`: they have no cut to undo).
@@ -112,7 +116,7 @@ SHAPES = {
         planes=2, blocks=9216),
 }
 VARIANTS = ("whole", "no_copies", "no_products")
-FLASH_VARIANTS = ("whole", "no_mask", "uncut")
+FLASH_VARIANTS = ("whole", "no_mask", "uncut", "no_row_sum", "no_row_max")
 TABLES = ("consecutive", "shuffled")
 
 
@@ -324,12 +328,22 @@ def pace(shape, bs, pa, variant="whole", calls=30, interpret=False,
 def flash_removed(variant, fa):
     """`fa`'s kernels traced anew inside without `variant`'s part: the
     select that puts NEG_INF above the diagonal handing its scores
-    through (`no_mask`), or the table of a tile's cases with the one
-    case of a kernel that takes a live tile whole (`uncut`)."""
+    through (`no_mask`), the table of a tile's cases with the one case
+    of a kernel that takes a live tile whole (`uncut`), or a reduction
+    over a [rows, keys] tile's lanes handing back the tile's first
+    column (`no_row_sum`, `no_row_max`: the forward's alone reduce so)."""
     import jax
     import jax.numpy as jnp
 
     real_where, real_keys = jnp.where, getattr(fa, "_causal_keys", None)
+    reductions = {"no_row_sum": "sum", "no_row_max": "max"}
+    real_reduce = getattr(jnp, reductions.get(variant, "sum"))
+
+    def first_column(x, axis=None, keepdims=False, **kw):
+        if axis == 1 and keepdims and x.ndim == 2:
+            return x[:, :1]
+        return real_reduce(x, axis=axis, keepdims=keepdims, **kw)
+
     jax.clear_caches()
     if variant == "no_mask":
         jnp.where = lambda keep, x, y: (
@@ -339,6 +353,8 @@ def flash_removed(variant, fa):
         if real_keys is None:
             raise SystemExit("kernel_pace: these kernels cut nothing")
         fa._causal_keys = lambda bq, bk: [(1 - bq, None, bk)]
+    elif variant in reductions:
+        setattr(jnp, reductions[variant], first_column)
     elif variant != "whole":
         raise ValueError(
             f"no variant {variant!r}: one of {FLASH_VARIANTS}")
@@ -346,6 +362,8 @@ def flash_removed(variant, fa):
         yield
     finally:
         jnp.where = real_where
+        if variant in reductions:
+            setattr(jnp, reductions[variant], real_reduce)
         if real_keys is not None:
             fa._causal_keys = real_keys
         jax.clear_caches()
