@@ -166,6 +166,28 @@ architecture is a second description and not a second decoder.
                with 1e-6 added to the sum the chosen scores are
                divided by (`norm_topk_eps`).
 
+  delta-rule-  the eleventh (Upstage Solar-Open2, `model_type:
+  hybrid-like  solar_open2`, whose linear mixer is Kimi Delta Attention's,
+               arXiv:2510.26692), fields again: a layer kind whose memory
+               is a MATRIX a head that a rank-one correction rewrites
+               ("delta_rule": a gated delta rule, `delta_rule_step`: the
+               normed input goes to q, k and v of `delta_heads` heads of
+               `delta_d_head` columns, each through a depthwise causal
+               convolution of `delta_conv` taps and a SiLU, q and k to
+               unit length a head; a log decay a key CHANNEL and a write
+               strength a head come from the input too; the state S
+               [keys, values] a head decays by channel, is corrected by
+               beta k (v - k^T S)^T and read by q; the result goes
+               through a norm a head and a sigmoid gate and back through
+               one matrix.  What a lane keeps is S in float32 and the last
+               `delta_conv - 1` rows of q | k | v before the convolution:
+               the fourth description's state and tail at other shapes)
+               among full-attention layers with NO position signal, whose
+               output passes a sigmoid gate of the layer's input before
+               `o` (`attention_gate`), a minority of the layers.  The
+               router is the second's renormalised, the held experts and
+               the shared expert the fourth's.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -182,10 +204,10 @@ from typing import Dict, Tuple
 
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
-           "swiglu", "mamba2_step", "short_conv_step",
+           "swiglu", "mamba2_step", "short_conv_step", "delta_rule_step",
            "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA", "ATTENTION",
-           "CONV", "DENSE", "SPARSE", "INDEX_FULL", "INDEX_SHARED",
-           "select_rows"]
+           "CONV", "DELTA", "DENSE", "SPARSE", "INDEX_FULL",
+           "INDEX_SHARED", "select_rows"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # an FFN kind a layer (`mlp_layer_types`)
@@ -194,6 +216,9 @@ DENSE, SPARSE = "dense", "sparse"
 MAMBA, ATTENTION = "mamba", "attention"
 # LFM2's name: a gated short convolution, whose memory is a tail a lane
 CONV = "conv"
+# Solar-Open2's linear mixer: a gated delta rule, whose memory is a
+# matrix state a head and a tail a lane
+DELTA = "delta_rule"
 # an indexer kind a layer (`indexer_types`): a layer that computes a
 # selection, and one that reuses the nearest earlier one's
 INDEX_FULL, INDEX_SHARED = "full", "shared"
@@ -321,6 +346,20 @@ class BlockSpec:
     # -- a CONV layer's taps (a gated short convolution; 0: none): a
     #    lane keeps the last `conv_width - 1` rows of its gated product
     conv_width: int = 0
+    # -- a DELTA layer's geometry (a gated delta rule): `delta_heads`
+    #    heads whose keys AND values are `delta_d_head` columns, the
+    #    depthwise convolution's taps over q | k | v, the rank of the
+    #    two low-rank gates (the decay's and the output's), and whether
+    #    the write strength is 2 sigmoid (eigenvalues down to -1) or
+    #    sigmoid
+    delta_heads: int = 0
+    delta_d_head: int = 0
+    delta_conv: int = 0
+    delta_gate_rank: int = 0
+    delta_neg_eigval: bool = False
+    # -- attention's output times sigmoid(the layer's normed input @ a
+    #    matrix [d, H * d_head]) before `o`
+    attention_gate: bool = False
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
@@ -337,9 +376,14 @@ class BlockSpec:
             raise ValueError(f"rope_layers {self.rope_layers}: of "
                              f"{SLIDING!r} and {FULL!r}")
         bad = set(self.layer_types) - {SLIDING, FULL, MAMBA, ATTENTION,
-                                       CONV}
+                                       CONV, DELTA}
         if bad:
             raise ValueError(f"layer_types: unknown kind(s) {sorted(bad)}")
+        if (DELTA in self.layer_types) != (self.delta_heads > 0):
+            raise ValueError(
+                f"block {self.name!r}: delta_heads {self.delta_heads} "
+                f"{'without' if self.delta_heads else 'with'} {DELTA!r} "
+                "layers: the heads are those layers' mixer's")
         if (CONV in self.layer_types) != (self.conv_width > 0):
             raise ValueError(
                 f"block {self.name!r}: conv_width {self.conv_width} "
@@ -495,7 +539,8 @@ class BlockSpec:
     def rotated(self, kind: str) -> bool:
         """Whether RoPE turns Q and K on a layer of this kind (a layer
         without attention has neither)."""
-        return self.positions == "rope" and kind not in (MAMBA, CONV) and (
+        return self.positions == "rope" and kind not in (
+            MAMBA, CONV, DELTA) and (
             not self.rope_layers or kind in self.rope_layers)
 
     def rope_of(self, kind: str) -> dict:
@@ -544,7 +589,18 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     order), the depthwise convolution's taps `conv_w` [conv_width, d]
     (row j multiplies the row `conv_width - 1 - j` positions back: the
     last row this position's own) and `conv_out` [d, d]; no bias, and
-    no QK-norm scales (those are the attention layers').  A LATENT
+    no QK-norm scales (those are the attention layers').  A DELTA
+    layer (H = `delta_heads`, K = `delta_d_head`, r = `delta_gate_rank`)
+    has, in place of the four: `delta_in` [d, 3*H*K] (q, k and v side
+    by side), the depthwise convolution over them `delta_conv`
+    [delta_conv, 3*H*K] (rows as a CONV layer's; no bias), the decay's
+    low-rank pair `delta_fa` [d, r] and `delta_fb` [r, H*K] with its
+    bias `delta_dt` [H*K] and `delta_a_log` [H], the write strength's
+    `delta_b` [d, H], the output gate's pair `delta_ga` [d, r] and
+    `delta_gb` [r, H*K] with its bias `delta_g` [H*K], the head norm's
+    ONE scale `delta_o_norm` [K] and `delta_out` [H*K, d].  With
+    `attention_gate` an attention layer has a fifth matrix, `attn_gate`
+    [d, H*dh].  A LATENT
     layer has
     seven arrays in place of the four: `q_a` [d, q_lora_rank], its
     norm's scale `q_a_norm`, `q_b` [q_lora_rank, H * (nope + rope)] (a
@@ -590,7 +646,24 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         raise NotImplementedError(
             f"block {spec.name!r}: passes, post_norm and exit_gate are "
             "built for the dense SwiGLU block alone (ffn 'swiglu')")
-    mamba, conv = MAMBA in kinds, CONV in kinds
+    mamba, conv, delta = MAMBA in kinds, CONV in kinds, DELTA in kinds
+    if delta and (dense or mamba or conv or SLIDING in kinds or spec.latent
+                  or FULL not in kinds or spec.qk_norm
+                  or spec.mlp_layer_types
+                  or min(spec.delta_heads, spec.delta_d_head,
+                         spec.delta_conv - 1, spec.delta_gate_rank) < 1):
+        raise NotImplementedError(
+            f"block {spec.name!r}: gated delta-rule layers (delta_heads, "
+            "delta_d_head, delta_conv >= 2, delta_gate_rank) are built "
+            "among full-attention layers on the table, in a block with "
+            "experts in every layer: no Mamba or conv layers, ring, "
+            "latent cache, QK-norm, dense layers or dense SwiGLU block "
+            "beside them")
+    if spec.attention_gate and not delta:
+        raise NotImplementedError(
+            f"block {spec.name!r}: attention_gate (the attention's output "
+            "times a sigmoid of the layer's input) is built and tested "
+            "on the attention layers of a block with delta-rule layers")
     if conv and (dense or mamba or SLIDING in kinds or spec.latent
                  or FULL not in kinds or spec.conv_width < 2):
         raise NotImplementedError(
@@ -598,12 +671,13 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             ">= 2) are built among full-attention layers on the table, "
             "in a block with experts: no Mamba layers, ring, latent "
             "cache or dense SwiGLU block beside them")
-    if spec.positions != ("none" if mamba else "rope"):
+    if spec.positions != ("none" if mamba or delta else "rope"):
         raise NotImplementedError(
             f"block {spec.name!r}: positions {spec.positions!r} "
-            f"{'with' if mamba else 'without'} Mamba layers; built are "
-            "RoPE on a block of attention layers, and no position "
-            "signal where Mamba layers carry the order")
+            f"{'with' if mamba or delta else 'without'} Mamba or "
+            "delta-rule layers; built are RoPE on a block of attention "
+            "layers, and no position signal where Mamba or delta-rule "
+            "layers carry the order")
     if mamba and SLIDING in kinds:
         raise NotImplementedError(
             f"block {spec.name!r}: Mamba layers beside sliding-window "
@@ -735,6 +809,26 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_d": add(p + "ssm_d.w_0", spec.ssm_heads),
                    "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
+        elif kind == DELTA:
+            hk = spec.delta_heads * spec.delta_d_head
+            r = spec.delta_gate_rank
+            lay = {"norm1": add(p + "mixer_norm.scale_0", d),
+                   "delta_in": add(p + "delta_in_proj.w_0", d, 3 * hk),
+                   "delta_conv": add(p + "delta_conv.w_0", spec.delta_conv,
+                                     3 * hk),
+                   "delta_fa": add(p + "delta_decay_a.w_0", d, r),
+                   "delta_fb": add(p + "delta_decay_b.w_0", r, hk),
+                   "delta_dt": add(p + "delta_dt.b_0", hk),
+                   "delta_a_log": add(p + "delta_a_log.w_0",
+                                      spec.delta_heads),
+                   "delta_b": add(p + "delta_beta.w_0", d,
+                                  spec.delta_heads),
+                   "delta_ga": add(p + "delta_gate_a.w_0", d, r),
+                   "delta_gb": add(p + "delta_gate_b.w_0", r, hk),
+                   "delta_g": add(p + "delta_gate.b_0", hk),
+                   "delta_o_norm": add(p + "delta_o_norm.scale_0",
+                                       spec.delta_d_head),
+                   "delta_out": add(p + "delta_out_proj.w_0", hk, d)}
         elif kind == CONV:
             lay = {"norm1": add(p + "operator_norm.scale_0", d),
                    "conv_in": add(p + "conv_in_proj.w_0", d, 3 * d),
@@ -758,6 +852,8 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "k": add(p + "k_proj.w_0", d, dkv),
                    "v": add(p + "v_proj.w_0", d, dkv),
                    "o": add(p + "o_proj.w_0", dq, d)}
+            if spec.attention_gate:
+                lay["attn_gate"] = add(p + "attn_gate.w_0", d, dq)
         lay["norm2"] = add(p + "ffn_norm.scale_0", d)
         if dense or ffns[l] == DENSE:
             # one SwiGLU every token takes: the block's FFN, or a dense
@@ -1202,3 +1298,84 @@ def short_conv_step(spec: BlockSpec, u, tail, fresh, live, p, scope=None):
         out = jnp.dot(y.astype(p["conv_out"].dtype), p["conv_out"],
                       preferred_element_type=f32)
     return out, tail
+
+
+def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
+                    scope=None):
+    """ONE position of a gated delta-rule mixer (Solar-Open2's linear
+    layer, after Kimi Delta Attention, arXiv:2510.26692) for every
+    lane, `mamba2_step`'s sibling: u [S, D] float32 (the normed
+    residual) -> (out [S, D] float32, the lane's state [S, H, K, K]
+    float32: a matrix [keys, values] a head, its tail [S, width - 1,
+    3*H*K] float32: the last rows of q | k | v before the convolution).
+
+      q, k, v = silu(sum_j w_conv[j] * (tail, u @ W_in)[j])   [H, K] each
+      q = q / |q| / sqrt(K);  k = k / |k|          (L2 a head, eps 1e-6)
+      g = -exp(A_log) * softplus((u @ W_fa) @ W_fb + dt_bias)    [H, K]
+      beta = sigmoid(u @ W_b) [H]          (times 2 under `delta_neg_eigval`)
+      S' = exp(g)[:, :, None] * S          a decay a key CHANNEL
+      S = S' + beta * k (v - k^T S')^T     the rank-one correction
+      o = S^T q;  gate = sigmoid((u @ W_ga) @ W_gb + b_g)
+      out = (rmsnorm_head(o) * w * gate) @ W_out
+
+    The step IS the prefill, as a Mamba layer's.  `fresh` and `live` are
+    `mamba2_step`'s, under the same contract and (for the tail) through
+    the same code (`_tail_rows`): a lane whose cursor is 0 starts from a
+    zero state and tail whatever it holds, a lane that is not live
+    keeps both.  `p`: the layer's arrays by `param_layout`'s keys.  The
+    recurrence, the convolution, the L2 norms, the gates and the head
+    norm are float32; the projections take the weights' dtype with
+    float32 accumulation."""
+    import contextlib
+
+    import jax
+    import jax.numpy as jnp
+
+    scope = scope or (lambda name: contextlib.nullcontext())
+    f32 = jnp.float32
+    s_n = u.shape[0]
+    h_n, k_n = spec.delta_heads, spec.delta_d_head
+    hk = h_n * k_n
+
+    def proj(x, *names):
+        for name in names:
+            x = jnp.dot(x.astype(p[name].dtype), p[name],
+                        preferred_element_type=f32)
+        return x
+
+    def unit(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    with scope("delta_in_proj"):
+        qkv = proj(u, "delta_in")
+    with scope("delta_conv"):
+        rows, tail = _tail_rows(tail, qkv, fresh, live)
+        qkv = jax.nn.silu(
+            (rows * p["delta_conv"].astype(f32)[None]).sum(axis=1))
+        q, k, v = (qkv[:, i * hk:(i + 1) * hk].reshape(s_n, h_n, k_n)
+                   for i in range(3))
+        q, k = unit(q) * (k_n ** -0.5), unit(k)
+    with scope("delta_gates"):
+        g = -jnp.exp(p["delta_a_log"].astype(f32))[None, :, None] * (
+            jax.nn.softplus(proj(u, "delta_fa", "delta_fb")
+                            + p["delta_dt"].astype(f32))
+        ).reshape(s_n, h_n, k_n)
+        beta = jax.nn.sigmoid(proj(u, "delta_b"))               # [S, H]
+        if spec.delta_neg_eigval:
+            beta = 2.0 * beta
+    with scope("delta_rule"):
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state)
+        decayed = jnp.exp(g)[..., None] * s0
+        seen = (k[..., None] * decayed).sum(axis=2)             # k^T S'
+        new = decayed + (beta[..., None] * k)[..., None] * (
+            v - seen)[:, :, None, :]
+        o = (new * q[..., None]).sum(axis=2)                    # S^T q
+        state = jnp.where(live[:, None, None, None], new, state)
+    with scope("delta_gate_norm"):
+        gate = jax.nn.sigmoid(proj(u, "delta_ga", "delta_gb")
+                              + p["delta_g"].astype(f32))
+        o = norm(spec, o, p["delta_o_norm"].astype(f32))
+        y = o.reshape(s_n, hk) * gate
+    with scope("delta_out_proj"):
+        out = proj(y, "delta_out")
+    return out, state, tail

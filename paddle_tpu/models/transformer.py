@@ -506,19 +506,17 @@ _REFUSALS = {
             "wrote it and is overwritten as that slot goes on, so a later "
             "hit would attend over another request's keys; pass "
             "prefix_cache=False")},
+    # the prefix cache works: the decoder offers `snapshot_save` and
+    # `snapshot_restore`, and a hit ends at a block whose snapshot of the
+    # lane's state and tails the server restores (docs/serving.md "A
+    # snapshot of a lane's state")
     "state": {
         "draft_model": (
             "a decoder whose layers keep a recurrent state or a convolution "
             "tail a lane takes no draft model: speculative verification runs "
             "a window of positions through step_window, and a lane's state "
             "is computed one position a step (and cannot be rolled back over "
-            "rejected tokens)"),
-        "prefix_cache": (
-            "prefix_cache=True with layers that keep a recurrent state or a "
-            "convolution tail a lane: a hit starts a sequence past position "
-            "0, where the attention layers find the prompt's K/V in the "
-            "shared blocks but a lane has no state or tail for it (no "
-            "snapshot is kept); pass prefix_cache=False")},
+            "rejected tokens: a snapshot is kept a prompt, not a position)")},
     # a cached block holds every layer's latent rows: the prefix cache
     # works on the latent table as on any other
     "latent": {
@@ -613,10 +611,23 @@ class PagedDecoder:
     # blocks (0: no indexer)
     index_planes: int
     # what belongs to a LANE: how many layers keep a recurrent state or
-    # a convolution tail (Mamba layers: both; gated short convolutions:
-    # the tail alone; 0: none) and the float32 bytes a lane holds over them
+    # a convolution tail (Mamba and delta-rule layers: both; gated short
+    # convolutions: the tail alone; 0: none) and the float32 bytes a lane
+    # holds over them
     state_layers: int
     state_bytes_per_lane: int
+    # SNAPSHOTS of what belongs to a lane, for a prefix cache over such a
+    # block (None, all three, for a block whose lanes keep nothing):
+    # init_snapshots(rows, device=None) -> an opaque pool of `rows`
+    # snapshots; snapshot_save(snapshots, pool_k, pool_v, lane, row) ->
+    # snapshots, with lane `lane`'s state and tails of every such layer
+    # copied into row `row`; snapshot_restore(pool_k, pool_v, snapshots,
+    # lane, row) -> (pool_k, pool_v), with row `row` copied back into
+    # lane `lane`.  Each donates what it writes; lane and row are int32
+    # scalars
+    init_snapshots: Optional[Callable]
+    snapshot_save: Optional[Callable]
+    snapshot_restore: Optional[Callable]
     # what attends in the resident step (`step`, `step_logits`,
     # `step_routing`): the streaming Pallas kernel ("pallas";
     # "pallas:latent" over a latent pool), or the XLA gather and the
@@ -791,6 +802,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     kernel's plane index count them), RoPE turns them alone, and an int8
     pool is refused by name.
 
+    A block with DELTA layers (`layer_types` "delta_rule": a gated delta
+    rule and no attention, `lm_block.delta_rule_step`; lm_block's
+    eleventh description) keeps the Mamba block's kind of state at other
+    shapes: per delta layer a float32 MATRIX state [S, H, K, K] (keys by
+    values a head) beside K and a float32 tail [S, delta_conv - 1,
+    3*H*K] (the rows of q | k | v before their convolution) beside V,
+    donated and updated in place with the pools; the reset from the
+    cursor and the hold of an idle lane are the Mamba block's.  Its
+    attention layers carry no position signal and, under
+    `BlockSpec.attention_gate`, multiply their context by a sigmoid of
+    the layer's normed input before `o` (scope `attention_gate`).  A
+    draft model, `step_window` and an int8 pool are refused by name.
+
+    Every block whose lanes keep something (Mamba, conv or delta layers)
+    is served under a PREFIX CACHE through SNAPSHOTS: the decoder owns
+    `init_snapshots`, `snapshot_save` and `snapshot_restore` (see
+    `PagedDecoder`), two small jitted programs under the scopes
+    `state_snapshot_save` and `state_snapshot_restore` that copy one
+    lane's state and tails of every such layer into a row of a snapshot
+    pool and back, so that serving/ moves a snapshot by its row and
+    never learns what a state is (docs/serving.md "A snapshot of a
+    lane's state").
+
     A LOOPED stack (`BlockSpec.passes` > 1: the layers run `passes`
     times a token over the same weights, the final norm after every
     pass, docs/serving.md "A looped stack") keeps the ONE table pool
@@ -939,15 +973,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # the kind of layer that keeps something a LANE (`param_layout`
     # builds one such kind a block): Mamba layers a recurrent state and
     # a convolution tail, gated short convolutions the tail alone
-    lane_kind = next((k for k in (lm_block.MAMBA, lm_block.CONV)
-                      if k in kinds), None)
+    lane_kind = next((k for k in (lm_block.MAMBA, lm_block.CONV,
+                                  lm_block.DELTA) if k in kinds), None)
     stateful = lane_kind is not None
-    if lane_kind == lm_block.CONV and kv_dtype == "int8":
+    if lane_kind in (lm_block.CONV, lm_block.DELTA) and kv_dtype == "int8":
         raise NotImplementedError(
             f"block {spec.name!r}: an int8 pool beside convolution tails "
-            "is not built (its per-(layer, block) scales are untested on "
-            "a table that a minority of the layers write); kv_dtype fp32 "
-            "or bf16")
+            "or delta-rule states is not built (its per-(layer, block) "
+            "scales are untested on a table that a minority of the "
+            "layers write); kv_dtype fp32 or bf16")
     n_full = kinds.count(lm_block.FULL)
     nw = 0
     if ringed:
@@ -1403,6 +1437,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("conv_out_proj"):
             return _residual(x, out), tail
 
+    def _delta_mixer(g, lay, x, state, tail, fresh, live):
+        """x + the gated delta rule of norm(x), one position a lane, and
+        the layer's state and tail after it (`delta_rule_step`)."""
+        with scope("delta_in_proj"):
+            u = _norm(g, x, lay["norm1"])
+        out, state, tail = lm_block.delta_rule_step(
+            spec, u, state, tail, fresh, live,
+            {n: g[pair[0]] for n, pair in lay.items()
+             if n.startswith("delta_")}, scope=scope)
+        with scope("delta_out_proj"):
+            return _residual(x, out), state, tail
+
     def _with_counts(out, hits, live):
         """`out` and what the step counted (`decoder.step_counters`):
         the distinct experts each layer with experts routed to and,
@@ -1694,6 +1740,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     scans.append(given)
                     x = _ffn(g, lay, x, hits)
                     continue
+                if kind == lm_block.DELTA:
+                    # state and tail ride as a Mamba layer's
+                    x, pools_k[kind][li], pools_v[kind][li] = _delta_mixer(
+                        g, lay, x, pools_k[kind][li], pools_v[kind][li],
+                        positions == 0, active)
+                    x = _ffn(g, lay, x, hits)
+                    continue
                 if kind == lm_block.CONV:
                     # the lane's tail rides where a pool's V does; there
                     # is no state beside it
@@ -1737,6 +1790,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         selected=chosen is not None)[:, 0]
                 if latent:
                     ctx_av = _latent_values(g, lay, ctx_av)
+                if "attn_gate" in lay:
+                    with scope("attention_gate"):
+                        ctx_av = ctx_av * jax.nn.sigmoid(_fc(
+                            g, _norm(g, x, lay["norm1"]), lay["attn_gate"]))
                 with scope("attn_out"):
                     y = _fc(g, ctx_av, lay["o"])
                     if not spec.post_norm:
@@ -1954,14 +2011,23 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # what a lane holds over the layers that keep something a lane,
     # float32: a Mamba layer its SSM state and the convolution tail of
     # x B C, a gated short convolution the tail of its product alone
+    # a delta-rule layer its matrix state and the tail of q | k | v
     n_mamba = kinds.count(lm_block.MAMBA)
     n_conv = kinds.count(lm_block.CONV)
-    n_lane = n_mamba + n_conv
-    state_shape = (spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state)
-    tail_shape = ((spec.conv_width - 1, d_model) if n_conv else
-                  (spec.ssm_conv - 1, spec.ssm_heads * spec.ssm_d_head
-                   + 2 * spec.ssm_d_state))
-    state_bytes_per_lane = 4 * (n_mamba * math.prod(state_shape)
+    n_delta = kinds.count(lm_block.DELTA)
+    n_state = n_mamba + n_delta
+    n_lane = n_state + n_conv
+    if n_delta:
+        state_shape = (spec.delta_heads, spec.delta_d_head,
+                       spec.delta_d_head)
+        tail_shape = (spec.delta_conv - 1,
+                      3 * spec.delta_heads * spec.delta_d_head)
+    else:
+        state_shape = (spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state)
+        tail_shape = ((spec.conv_width - 1, d_model) if n_conv else
+                      (spec.ssm_conv - 1, spec.ssm_heads * spec.ssm_d_head
+                       + 2 * spec.ssm_d_state))
+    state_bytes_per_lane = 4 * (n_state * math.prod(state_shape)
                                 + n_lane * math.prod(tail_shape))
 
     def init_pool(num_blocks, device=None, window_blocks=None,
@@ -1970,9 +2036,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         for the full layers and, for a block with sliding layers,
         `window_blocks` for their rings (read by no other block):
         (pool_k, pool_v), each one array (an int8 pair) or the pair
-        (full, ring) `step` takes.  For a block with Mamba layers each
-        is the pair (the attention layers' pool, one float32 array a
-        Mamba layer: `lanes` SSM states beside K, `lanes` convolution
+        (full, ring) `step` takes.  For a block with Mamba or delta-rule
+        layers each is the pair (the attention layers' pool, one float32
+        array such a layer: `lanes` states beside K, `lanes` convolution
         tails beside V), for one with conv layers the same pair with no
         states (the empty tuple beside K, a tail a conv layer beside
         V); `lanes` is the step's lane count and read by no other
@@ -2001,7 +2067,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
 
         if stateful:
             return ((zeros(n_full, num_blocks),
-                     lane_state(state_shape, n_mamba)),
+                     lane_state(state_shape, n_state)),
                     (zeros(n_full, num_blocks),
                      lane_state(tail_shape, n_lane)))
 
@@ -2034,6 +2100,36 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         return 1 + np.arange(slots * nw, dtype=np.int32).reshape(
             slots, nw)
 
+    # -- snapshots of what belongs to a lane (a prefix cache's side of
+    #    such a block): one row of every state and tail array a snapshot
+    def init_snapshots(rows, device=None):
+        """A pool of `rows` snapshots, zeros: (a float32 array [rows,
+        ...] a layer with a state, one a layer with a tail), opaque to
+        the caller."""
+        z = tuple(tuple(jnp.zeros((int(rows),) + shape, jnp.float32)
+                        for _ in range(layers))
+                  for shape, layers in ((state_shape, n_state),
+                                        (tail_shape, n_lane)))
+        return z if device is None else jax.device_put(z, device)
+
+    @functools.partial(jax.jit,
+                       donate_argnums=(0,) if platform != "cpu" else ())
+    def snapshot_save(snapshots, pool_k, pool_v, lane, row):
+        with jax.named_scope("state_snapshot_save"):
+            return tuple(
+                tuple(snap.at[row].set(held[lane])
+                      for snap, held in zip(snaps, pool[1]))
+                for snaps, pool in zip(snapshots, (pool_k, pool_v)))
+
+    @functools.partial(jax.jit,
+                       donate_argnums=(0, 1) if platform != "cpu" else ())
+    def snapshot_restore(pool_k, pool_v, snapshots, lane, row):
+        with jax.named_scope("state_snapshot_restore"):
+            return tuple(
+                (pool[0], tuple(held.at[lane].set(snap[row])
+                                for snap, held in zip(snaps, pool[1])))
+                for snaps, pool in zip(snapshots, (pool_k, pool_v)))
+
     # The TPU compiler brings a matmul's weight in ahead of it in
     # slices of its own making (`slice-start` / `slice-done`, no
     # metadata), and the wait for them counts under their producer:
@@ -2048,7 +2144,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
              "idx_w": "indexer_q", "idx_k": "indexer_k",
              "dense_gate": "dense_ffn",
              "dense_up": "dense_ffn", "dense_down": "dense_ffn",
-             "conv_in": "conv_in_proj", "conv_out": "conv_out_proj"}
+             "conv_in": "conv_in_proj", "conv_out": "conv_out_proj",
+             "delta_in": "delta_in_proj", "delta_out": "delta_out_proj",
+             "delta_fa": "delta_gates", "delta_fb": "delta_gates",
+             "delta_b": "delta_gates", "delta_ga": "delta_gate_norm",
+             "delta_gb": "delta_gate_norm",
+             "attn_gate": "attention_gate"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
@@ -2118,7 +2219,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         tail: all the tick's) and `state_resets` (those at position 0,
         which the step starts from zero); with conv layers also
         `conv_layers` and `conv_tail_bytes` (the float32 tails those
-        lanes' conv layers read and write back).  With experts
+        lanes' conv layers read and write back); with delta-rule layers
+        `delta_layers` and `state_bytes` (the float32 states and tails
+        those lanes' delta layers read and write back).  With experts
         `moe_kernel` (1: the traced step's expert
         layer is the Pallas grouped matmul, 0: `ragged_dot`) and
         `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
@@ -2184,6 +2287,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["conv_layers"] = n_conv
             # read and written back: a conv block's lanes hold tails alone
             counts["conv_tail_bytes"] = 2 * n * state_bytes_per_lane
+        if n_delta:
+            counts["delta_layers"] = n_delta
+            # the live lanes' states and tails, read and written back
+            counts["state_bytes"] = 2 * n * state_bytes_per_lane
         if decoder.expert_kernel is not None:
             counts["moe_kernel"] = int(
                 not decoder.expert_kernel.startswith("xla:"))
@@ -2242,6 +2349,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         window_bytes_per_block=window_bytes_per_block,
         table_layers=planes, ring_layers=n_win, index_planes=n_index,
         state_layers=n_lane, state_bytes_per_lane=state_bytes_per_lane,
+        init_snapshots=init_snapshots if stateful else None,
+        snapshot_save=snapshot_save if stateful else None,
+        snapshot_restore=snapshot_restore if stateful else None,
         kernels=kernels,
         attention_tiling=tiling, tick_counts=tick_counts,
         starts_saved=starts_saved, refuses=refuses)

@@ -265,7 +265,7 @@ class _Seq:
                  "temperature", "seed", "cur", "slot", "emitted",
                  "t_submit", "t_submit_wall", "t_admit", "t_first",
                  "cached", "expires", "trace_ctx", "draft_next",
-                 "prompt_keys")
+                 "prompt_keys", "snap_at", "snap_row", "snap_saved", "cut")
 
     def __init__(self, stream, max_new, eos_id, temperature, seed,
                  expires, trace_ctx):
@@ -299,6 +299,15 @@ class _Seq:
         # chained prefix-cache block keys, computed ONCE at submit
         # (the scheduler re-checks a blocked queue head every tick)
         self.prompt_keys = None
+        # a decoder with a lane state under the prefix cache: the cursor
+        # after which this prompt's ONE snapshot is saved (its last full
+        # block boundary; -1: none), the row its admission restores
+        # (None: none), whether its own was saved and the blocks that
+        # hit by hash past the last snapshot
+        self.snap_at = -1
+        self.snap_row = None
+        self.snap_saved = 0
+        self.cut = 0
 
     @property
     def positions_needed(self) -> int:
@@ -360,7 +369,8 @@ class GenerationServer:
                  idle_poll_s: float = 0.005,
                  prefix_cache: bool = True,
                  draft_decoder=None, draft_states=None,
-                 spec_k: Optional[int] = None):
+                 spec_k: Optional[int] = None,
+                 state_snapshots: Optional[int] = None):
         import jax
 
         from ..core.executor import TPUPlace
@@ -442,10 +452,21 @@ class GenerationServer:
         bpb = decoder.bytes_per_block
         if draft_decoder is not None:
             bpb += draft_decoder.bytes_per_block
+        # a decoder whose lanes keep a state is served under the prefix
+        # cache through SNAPSHOTS of it (docs/serving.md "A snapshot of
+        # a lane's state"): `state_snapshots` rows (None: one a slot) of
+        # a pool that the decoder makes and its two programs copy into
+        # and out of; here a snapshot is a row number
+        self._snap_rows = (
+            (self._slots if state_snapshots is None
+             else int(state_snapshots))
+            if prefix_cache and decoder.init_snapshots is not None
+            else None)
         self._cache = PagedKVCache(
             kv_blocks, decoder.block_size, decoder.max_blocks_per_seq,
             server_label=f"gen{sid}", prefix_cache=prefix_cache,
-            bytes_per_block=bpb)
+            bytes_per_block=bpb, state_snapshots=self._snap_rows,
+            snapshot_bytes=decoder.state_bytes_per_lane)
         # int8 pools cannot share a prompt's FINAL block: the
         # block-aligned full-prompt hit re-runs the last prompt
         # position, and an int8 write RE-QUANTIZES the whole shared
@@ -466,6 +487,8 @@ class GenerationServer:
         self._pool_k, self._pool_v = decoder.init_pool(
             kv_blocks + 1, self._device,
             window_blocks=ring * self._slots + 1, lanes=self._slots)
+        self._snaps = (decoder.init_snapshots(self._snap_rows, self._device)
+                       if self._snap_rows else None)
         if draft_decoder is not None:
             self._draft_states = {
                 n: jax.device_put(np.asarray(draft_states[n]),
@@ -583,6 +606,14 @@ class GenerationServer:
             nxt, self._pool_k, self._pool_v, *_ = self._decoder.step(
                 *args)
             np.asarray(nxt)  # block: compile is done when this returns
+            if self._snaps is not None:
+                # the two copies, as the scheduler calls them (zeros
+                # into row 0, row 0 into lane 0: nothing moves)
+                self._save_snapshot(0, 0, register=True)
+                self._restore_snapshot(0, 0, register=True)
+                import jax
+
+                jax.block_until_ready(self._pool_k)
             return
         w = self._spec_k + 1
         zw = np.zeros((self._slots, w), np.int32)
@@ -599,6 +630,27 @@ class GenerationServer:
             self._tables, z, z, zs, zt,
             np.zeros(self._slots, bool))
         np.asarray(nxt)
+
+    def _save_snapshot(self, lane: int, row: int, register=False) -> None:
+        """Dispatch the decoder's copy of lane `lane`'s state into
+        snapshot `row`, behind every step dispatched so far."""
+        args = (self._snaps, self._pool_k, self._pool_v, np.int32(lane),
+                np.int32(row))
+        if register:
+            profiler.register_jitted("paged_decoder.snapshot_save",
+                                     self._decoder.snapshot_save, *args)
+        self._snaps = self._decoder.snapshot_save(*args)
+
+    def _restore_snapshot(self, lane: int, row: int,
+                          register=False) -> None:
+        """Dispatch the decoder's copy of snapshot `row` into lane
+        `lane`, ahead of every step dispatched from now on."""
+        args = (self._pool_k, self._pool_v, self._snaps, np.int32(lane),
+                np.int32(row))
+        if register:
+            profiler.register_jitted("paged_decoder.snapshot_restore",
+                                     self._decoder.snapshot_restore, *args)
+        self._pool_k, self._pool_v = self._decoder.snapshot_restore(*args)
 
     # -- client side --------------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens: int, *,
@@ -801,6 +853,10 @@ class GenerationServer:
                # without): float32, resident whatever the lanes hold
                "state_bytes": (self._slots
                                * self._decoder.state_bytes_per_lane),
+               # the snapshot pool beside it (0 without): resident too
+               "state_snapshot_pool_bytes": (
+                   (self._snap_rows or 0)
+                   * self._decoder.state_bytes_per_lane),
                "kv_dtype": self._decoder.kv_dtype,
                "decode_kernel":
                self._decoder.kernels["paged_attention_decode"],
@@ -887,8 +943,13 @@ class GenerationServer:
             if slot < 0:
                 break
             seq = self._queue[0]
+            # with snapshots a hit must leave the last prompt position
+            # to be run: a recurrence cannot run it twice
+            upto = (seq.prompt_len - 1 if self._snap_rows is not None
+                    else None)
             if not self._cache.can_admit(seq.positions_needed,
-                                         prompt_keys=seq.prompt_keys):
+                                         prompt_keys=seq.prompt_keys,
+                                         cached_upto=upto):
                 # a slot is free and the pool's blocks refuse the head
                 self._kv_wait = True
                 break
@@ -897,7 +958,7 @@ class GenerationServer:
                 with obs_attr.phase("generation", "kv_alloc"):
                     table, cached = self._cache.allocate_prefix(
                         seq, seq.positions_needed,
-                        prompt_keys=seq.prompt_keys)
+                        prompt_keys=seq.prompt_keys, cached_upto=upto)
             except KVPoolExhausted:
                 # can_admit/allocate_prefix disagreeing is a bug, but
                 # an unserved admission must back off (head of queue,
@@ -914,6 +975,15 @@ class GenerationServer:
             seq.cur = min(cached, seq.prompt_len - 1)
             seq.draft_next = seq.cur
             seq.cached = seq.cur
+            if upto is not None:
+                # the lane starts from the hit's snapshot (dispatched by
+                # `_loop`, outside the lock), and saves its own when the
+                # cursor passes the prompt's last full block boundary,
+                # if that lies past the hit
+                bs = self._cache.block_size
+                seq.snap_row, seq.cut = self._cache.hit_snapshot(seq)
+                boundary = len(seq.prompt_keys) * bs
+                seq.snap_at = boundary if boundary > cached else -1
             seq.t_admit = time.perf_counter()
             seq.slot = slot
             self._active[slot] = seq
@@ -921,6 +991,17 @@ class GenerationServer:
             self._saved_stale[slot] = True
             admitted.append(seq)
         return admitted
+
+    def _restore_admitted(self, admitted: List[_Seq]) -> None:
+        """Dispatch the restore of every admission that hit a snapshot,
+        before the lane's first tick is dispatched: the device runs its
+        stream in order, so the tick in flight (in which the lane's last
+        occupant may still run a position that is dropped) runs before
+        it and the lane's first tick after."""
+        for seq in admitted:
+            if seq.snap_row is not None:
+                with obs_attr.phase("generation", "snapshot_restore"):
+                    self._restore_snapshot(seq.slot, seq.snap_row)
 
     def _evict_locked(self, seq: _Seq):
         self._active[seq.slot] = None
@@ -975,6 +1056,8 @@ class GenerationServer:
                         "admission"))
                 if admitted:
                     self._m_requests.inc(len(admitted))
+                    if self._snaps is not None:
+                        self._restore_admitted(admitted)
                 if metrics_on:
                     self._m_qdepth.set(qdepth)
                     self._m_active.set(len(seqs))
@@ -1093,6 +1176,8 @@ class GenerationServer:
                 for seq in seqs:
                     seq.cur += 1
                 self._m_ticks.inc()
+                if self._snaps is not None:
+                    self._save_passed(seqs)
             clock.mark("dispatch")
             if prev is not None:
                 with obs_attr.phase("generation", "sample"):
@@ -1100,6 +1185,20 @@ class GenerationServer:
                     self._step_counts(sp, prev.counts)
             self._end_iteration(sp, len(rows))
         return prev
+
+    def _save_passed(self, seqs: List[_Seq]) -> None:
+        """Dispatch the save of every sequence whose cursor has just
+        passed the last full block boundary of its prompt: behind the
+        tick that ran the boundary's last position, ahead of the next.
+        One snapshot a prompt; the cache hangs it on the block when the
+        tick has been read (`commit_prefix`)."""
+        for seq in seqs:
+            if seq.cur == seq.snap_at:
+                row = self._cache.reserve_snapshot(seq, seq.snap_at)
+                if row is not None:
+                    with obs_attr.phase("generation", "snapshot_save"):
+                        self._save_snapshot(seq.slot, row)
+                    seq.snap_saved = 1
 
     def _end_iteration(self, sp, active: int) -> None:
         """Close the iteration at the end of its read.  One that took
@@ -1240,6 +1339,13 @@ class GenerationServer:
                     finished.append(seq)
             if delivered:
                 self._m_tokens.inc(delivered)
+            if self._snaps is not None:
+                # a sequence that ends with this tick would lose, with
+                # its release, the snapshot saved behind its last
+                # position: its blocks are committed before it goes
+                for seq, _, cur in tick.rows:
+                    if seq in finished:
+                        self._cache.commit_prefix(seq, cur + 1)
             self._finish_seqs(finished, now, metrics_on)
             # freshly-filled full prompt blocks become shareable once
             # the tick that passed their end has been READ, so a block
@@ -1285,6 +1391,14 @@ class GenerationServer:
             return None
         t_admit = seq.t_admit if seq.t_admit is not None else now
         t_first = seq.t_first if seq.t_first is not None else now
+        if self._snap_rows is not None:
+            saved, restored = seq.snap_saved, int(seq.snap_row is not None)
+            attrs.update(
+                state_snapshots_saved=saved,
+                state_snapshots_restored=restored,
+                state_snapshot_bytes=(
+                    (saved + restored) * self._decoder.state_bytes_per_lane),
+                prefix_blocks_cut=seq.cut)
         return obs_tracing.record_span(
             "serving.request", seq.t_submit_wall, now - seq.t_submit,
             parent=seq.trace_ctx, server=self._sid,

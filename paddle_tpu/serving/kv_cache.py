@@ -44,6 +44,25 @@ refcount drops to zero is NOT freed — it parks in an LRU of
 unreferenced cached blocks and is evicted (hash unregistered, block
 reused) only when an allocation finds the free list empty.
 
+SNAPSHOTS (`state_snapshots=n`): a decoder whose layers keep something
+a LANE (a recurrent state, a convolution tail) cannot start a sequence
+past position 0 from shared K/V alone, so a cached block MAY CARRY a
+snapshot id: a row of the server's snapshot pool that holds "the lanes'
+state after this block's last position".  A hit run is then cut back to
+the longest prefix that ENDS in a block with a snapshot (and that
+leaves the position `cached_upto` to be run); the blocks that hit by
+hash and lay past it are counted (`prefix_blocks_cut`) and given to the
+request fresh.  Snapshots have a count and an LRU of their own, in two
+ages: taking a row for a new one drops the least recently used of those
+that NO admission has hit, and only when every one has been hit the
+least recently used of all (most prompts' snapshots are never hit
+again; at a save a request they would otherwise push a shared
+document's out between two of its hits, and a document that has lost
+its snapshot is run whole by every later request).  The block of a
+dropped snapshot stays cached (later hits are shorter, never wrong).
+What a snapshot holds is the server's and the decoder's business: here
+it is an integer.
+
 This module is the HOST-side manager (free list, refcounts, hash
 table, LRU, accounting); the device-side gather/scatter math lives in
 models/transformer.build_lm_paged_decoder.
@@ -179,13 +198,18 @@ class PagedKVCache:
     full prompt blocks, refcounted sharing, LRU eviction of
     unreferenced cached blocks).  `bytes_per_block` (device bytes of
     K+V across all layers for one block) feeds the
-    `paddle_tpu_serving_kv_bytes_resident` gauge."""
+    `paddle_tpu_serving_kv_bytes_resident` gauge.  `state_snapshots`
+    (None: hits need none) is the number of snapshot rows cached blocks
+    may carry between them (module docstring); `snapshot_bytes` what one
+    holds, for `state_snapshot_bytes`."""
 
     def __init__(self, num_blocks: int, block_size: int,
                  max_blocks_per_seq: int,
                  server_label: Optional[str] = None,
                  prefix_cache: bool = False,
-                 bytes_per_block: int = 0):
+                 bytes_per_block: int = 0,
+                 state_snapshots: Optional[int] = None,
+                 snapshot_bytes: int = 0):
         if num_blocks < 1:
             raise ValueError("num_blocks must be >= 1")
         self.num_blocks = int(num_blocks)
@@ -210,6 +234,20 @@ class PagedKVCache:
         self._pending: Dict[object, List[Tuple[int, bytes, int]]] = {}
         self._hits = 0
         self._misses = 0
+        # snapshots of the lanes' state: None where hits need none, else
+        # the rows not in use, {cached block: its row} in LRU order
+        # (oldest first), {owner: (boundary, row)} of a snapshot whose
+        # copy is dispatched and whose block is not yet shareable, and
+        # {owner: (row or None, blocks cut)} of what an admission hit
+        self.snapshot_bytes = int(snapshot_bytes)
+        self._snap_free: Optional[List[int]] = (
+            None if state_snapshots is None or not self.prefix_cache
+            else list(range(int(state_snapshots) - 1, -1, -1)))
+        self._snap_of: "OrderedDict[int, int]" = OrderedDict()
+        self._snap_hit: set = set()     # blocks whose snapshot was hit
+        self._snap_pending: Dict[object, Tuple[int, int]] = {}
+        self._snap_restore: Dict[object, Tuple[Optional[int], int]] = {}
+        self._snaps = {"saved": 0, "restored": 0, "evicted": 0, "cut": 0}
         self._lock = threading.Lock()
         self._sid = server_label or f"kv{next(_CACHE_IDS)}"
         self._m_used = _M_BLOCKS_USED.labels(server=self._sid)
@@ -246,9 +284,20 @@ class PagedKVCache:
 
     def prefix_stats(self) -> Dict[str, int]:
         with self._lock:
-            return {"prefix_hits": self._hits,
-                    "prefix_misses": self._misses,
-                    "kv_blocks_cached": len(self._by_hash)}
+            out = {"prefix_hits": self._hits,
+                   "prefix_misses": self._misses,
+                   "kv_blocks_cached": len(self._by_hash)}
+            if self._snap_free is not None:
+                n = self._snaps
+                out.update(
+                    state_snapshots=len(self._snap_of),
+                    state_snapshots_saved=n["saved"],
+                    state_snapshots_restored=n["restored"],
+                    state_snapshots_evicted=n["evicted"],
+                    state_snapshot_bytes=(
+                        (n["saved"] + n["restored"]) * self.snapshot_bytes),
+                    prefix_blocks_cut=n["cut"])
+            return out
 
     def blocks_for(self, num_positions: int) -> int:
         """Blocks needed to hold `num_positions` KV entries."""
@@ -272,13 +321,15 @@ class PagedKVCache:
 
     def can_admit(self, num_positions: int,
                   prompt_tokens: Optional[Sequence[int]] = None,
-                  prompt_keys: Optional[List[bytes]] = None) -> bool:
+                  prompt_keys: Optional[List[bytes]] = None,
+                  cached_upto: Optional[int] = None) -> bool:
         n = self.blocks_for(num_positions)
         if n > self.max_blocks_per_seq:
             return False
         keys = self._keys(prompt_tokens, prompt_keys)
         with self._lock:
-            hits, lru_hits = self._count_hits_locked(keys)
+            shared, lru_hits, _ = self._count_hits_locked(keys, cached_upto)
+            hits = len(shared)
             # hit blocks parked in the LRU are RESURRECTED by the
             # allocation, not consumed as fresh supply — counting them
             # on both sides would admit a request allocate_prefix
@@ -286,17 +337,29 @@ class PagedKVCache:
             avail = len(self._free) + len(self._lru) - lru_hits
             return n - hits <= avail
 
-    def _count_hits_locked(self, keys) -> Tuple[int, int]:
-        """(leading hit blocks, how many of those sit in the LRU)."""
-        hits = lru_hits = 0
+    def _count_hits_locked(self, keys, cached_upto=None
+                           ) -> Tuple[int, int, int]:
+        """(the leading hit blocks, how many of those sit in the LRU,
+        how many hit by hash and are not among them).  Where
+        blocks carry snapshots the run is cut back to its last block
+        with one whose end is at most `cached_upto` (None: any); the
+        rest are the third number."""
+        blocks = []
         for key in keys:
             blk = self._by_hash.get(key)
             if blk is None:
                 break           # a hit run must be prefix-contiguous
-            hits += 1
-            if blk in self._lru:
-                lru_hits += 1
-        return hits, lru_hits
+            blocks.append(blk)
+        hits = len(blocks)
+        if self._snap_free is not None:
+            while hits and (
+                    blocks[hits - 1] not in self._snap_of or (
+                        cached_upto is not None
+                        and hits * self.block_size > cached_upto)):
+                hits -= 1
+        shared = blocks[:hits]
+        return (shared, sum(1 for b in shared if b in self._lru),
+                len(blocks) - hits)
 
     def _publish(self):
         used = self.num_blocks - len(self._free) - len(self._lru)
@@ -316,8 +379,18 @@ class PagedKVCache:
             blk, _ = self._lru.popitem(last=False)
             key = self._hash_of.pop(blk)
             self._by_hash.pop(key, None)
+            self._drop_snapshot_locked(blk)
             return blk
         return None
+
+    def _drop_snapshot_locked(self, blk: int) -> None:
+        """`blk` loses its snapshot, if it carries one: the row is free
+        again and the block stays what it was."""
+        row = self._snap_of.pop(blk, None)
+        self._snap_hit.discard(blk)
+        if row is not None:
+            self._snap_free.append(row)
+            self._snaps["evicted"] += 1
 
     def allocate(self, owner, num_positions: int) -> np.ndarray:
         """Allocate blocks for `num_positions` under `owner` (one admit
@@ -328,13 +401,17 @@ class PagedKVCache:
 
     def allocate_prefix(self, owner, num_positions: int,
                         prompt_tokens: Optional[Sequence[int]] = None,
-                        prompt_keys: Optional[List[bytes]] = None
+                        prompt_keys: Optional[List[bytes]] = None,
+                        cached_upto: Optional[int] = None
                         ) -> Tuple[np.ndarray, int]:
         """Allocate like `allocate`, sharing leading fully-filled
         prompt blocks already in the prefix cache.  Returns (table,
         cached_positions): the first `cached_positions` logical
         positions already hold this prompt's K/V — the scheduler starts
-        the cursor there and skips their prefill."""
+        the cursor there and skips their prefill.  Where blocks carry
+        snapshots the shared run ends in a block with one, at or before
+        position `cached_upto`, and `hit_snapshot(owner)` then names the
+        snapshot the owner's lane starts from."""
         n = self.blocks_for(num_positions)
         if n > self.max_blocks_per_seq:
             raise ValueError(
@@ -344,16 +421,11 @@ class PagedKVCache:
         with self._lock:
             if owner in self._owned:
                 raise ValueError("owner already holds blocks")
-            blocks: List[int] = []
-            hits = 0
-            for key in keys:
-                blk = self._by_hash.get(key)
-                if blk is None:
-                    break
-                blocks.append(blk)
+            blocks, _, cut = self._count_hits_locked(keys, cached_upto)
+            hits = len(blocks)
+            for blk in blocks:
                 self._ref[blk] = self._ref.get(blk, 0) + 1
                 self._lru.pop(blk, None)   # resurrect from eviction
-                hits += 1
             fresh_start = len(blocks)
             # the fresh blocks side by side where the free runs allow
             # (`_FreeRuns.take`); what they lack is evicted below
@@ -392,6 +464,17 @@ class PagedKVCache:
                 self._pending[owner] = [
                     ((i + 1) * self.block_size, keys[i], blocks[i])
                     for i in range(hits, len(keys))]
+            if self._snap_free is not None:
+                self._snaps["cut"] += cut
+                row = None
+                if hits:
+                    # the lane starts from this block's snapshot, which
+                    # is now the most recently used
+                    self._snap_of.move_to_end(blocks[hits - 1])
+                    self._snap_hit.add(blocks[hits - 1])
+                    row = self._snap_of[blocks[hits - 1]]
+                    self._snaps["restored"] += 1
+                self._snap_restore[owner] = (row, cut)
             self._publish()
         if hits:
             self._m_hits.inc(hits)
@@ -400,6 +483,37 @@ class PagedKVCache:
         table = np.zeros(self.max_blocks_per_seq, np.int32)
         table[:n] = blocks
         return table, hits * self.block_size
+
+    def hit_snapshot(self, owner) -> Tuple[Optional[int], int]:
+        """(the snapshot row `owner`'s admission hit, None where it hit
+        none; the blocks that hit by hash and were cut off behind it).
+        Asked once: the caller dispatches the restore."""
+        with self._lock:
+            return self._snap_restore.pop(owner, (None, 0))
+
+    def reserve_snapshot(self, owner, boundary: int) -> Optional[int]:
+        """A row for a snapshot of `owner`'s lane after position
+        `boundary` - 1 (the end of a full prompt block of its own);
+        where none is free, that of the least recently used snapshot
+        that no admission has hit, else of the least recently used: the
+        caller
+        dispatches the copy into it, and `commit_prefix` hangs it on the
+        block once that is shareable.  None where the cache keeps no
+        snapshots, the owner holds no blocks, or it reserved one
+        already."""
+        with self._lock:
+            if (self._snap_free is None or owner not in self._owned
+                    or owner in self._snap_pending):
+                return None
+            if not self._snap_free and self._snap_of:
+                self._drop_snapshot_locked(next(
+                    (b for b in self._snap_of if b not in self._snap_hit),
+                    next(iter(self._snap_of))))
+            if not self._snap_free:
+                return None
+            row = self._snap_free.pop()
+            self._snap_pending[owner] = (int(boundary), row)
+            return row
 
     def commit_prefix(self, owner, filled_upto: int) -> None:
         """Register `owner`'s pending prompt blocks whose last position
@@ -423,6 +537,20 @@ class PagedKVCache:
                 self._pending[owner] = remaining
             else:
                 self._pending.pop(owner, None)
+            snap = self._snap_pending.get(owner)
+            if snap is not None and snap[0] <= filled_upto:
+                # the snapshot goes on the block that is cached under the
+                # boundary's key (this owner's, or the first committed:
+                # the state after equal tokens is equal), unless that
+                # carries one already
+                del self._snap_pending[owner]
+                blk = next((self._by_hash.get(key) for end, key, _ in pend
+                            if end == snap[0]), None)
+                if blk is None or blk in self._snap_of:
+                    self._snap_free.append(snap[1])
+                else:
+                    self._snap_of[blk] = snap[1]
+                    self._snaps["saved"] += 1
 
     def _release_block_locked(self, blk: int) -> None:
         r = self._ref.get(blk, 0) - 1
@@ -443,6 +571,10 @@ class PagedKVCache:
         with self._lock:
             blocks = self._owned.pop(owner, None)
             self._pending.pop(owner, None)
+            self._snap_restore.pop(owner, None)
+            snap = self._snap_pending.pop(owner, None)
+            if snap is not None:
+                self._snap_free.append(snap[1])
             if blocks:
                 for blk in blocks:
                     self._release_block_locked(blk)
@@ -463,6 +595,14 @@ class PagedKVCache:
             self._by_hash.clear()
             self._hash_of.clear()
             self._pending.clear()
+            # every snapshot goes with the blocks: a state is a function
+            # of the parameters as the K/V is
+            for blk in list(self._snap_of):
+                self._drop_snapshot_locked(blk)
+            for _, row in self._snap_pending.values():
+                self._snap_free.append(row)
+            self._snap_pending.clear()
+            self._snap_restore.clear()
             self._publish()
 
     def refcount(self, block: int) -> int:

@@ -74,6 +74,9 @@ POOLS = {
     # bytes take four sizes and its issue loop a remainder (a row of 5
     # heads of 128: DeepSeek's page bytes under grouped heads)
     "kv-chunks-of-51": (16, 20, 640, 16, 128, 2, "bf16"),
+    # K-EXAONE's row (8 K/V heads of 128) on the ONE attention layer of
+    # four, over docqa64's table of 432 pages a lane (PR 59)
+    "solar-open2-docqa64": (64, 64, 1024, 16, 432, 1, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512,
@@ -90,6 +93,7 @@ CHUNK_PAGES = {
     "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
     "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
     "longcat-flash-agent64-latent": 51, "glm-5.2-docqa64-selected": 51,
+    "solar-open2-docqa64": 32,
 }
 
 
@@ -320,6 +324,8 @@ MOSAIC_SHA256 = {
         "e596acc2d03e869b36b218239f8cb6713e5ea90e157bff9f61980df3d1622dd6",
     "opt-1.3b-closed32":
         "c460dd3873dcac6b759f99b007a3c077a8c7b02a317a785bb88f80b60014308e",
+    "solar-open2-docqa64":
+        "137cf5fe5ce4434cd02d17e904f7f444d308d7d1506608366dc5cd3572e2f409",
     "ouro-chat12":
         "c03f950b688b3a4f7291b26fc039b46c27e759484082c301b5f2c59738dc7b63",
     # the two index planes

@@ -439,11 +439,13 @@ def test_generation_server_serves_a_tail_a_lane_and_refuses_by_name():
     dec = _decoder()
     g = {n: np.asarray(v) for n, v in _weights(dec).items()}
     place = fluid.CPUPlace()
-    assert set(dec.refuses) == {"draft_model", "prefix_cache"}
-    assert all("a recurrent state or a convolution tail" in why
-               and "Mamba" not in why for why in dec.refuses.values())
-    with pytest.raises(ValueError, match="prefix_cache=True with layers"):
-        GenerationServer(dec, g, slots=2, kv_blocks=16, place=place)
+    # the prefix cache is served through snapshots of the lane's state
+    # since PR 59 (tests/test_solar_open2_decoder.py holds a hit to the
+    # miss for this block too): the draft model alone is refused
+    assert set(dec.refuses) == {"draft_model"}
+    assert "a recurrent state or a convolution tail" in dec.refuses[
+        "draft_model"]
+    assert dec.snapshot_save is not None is not dec.snapshot_restore
     with pytest.raises(ValueError, match="a lane takes no draft model"):
         GenerationServer(dec, g, slots=2, kv_blocks=16, place=place,
                          prefix_cache=False, draft_decoder=dec,
