@@ -205,7 +205,8 @@ from typing import Dict, Tuple
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
            "swiglu", "mamba2_step", "short_conv_step", "delta_rule_step",
-           "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA", "ATTENTION",
+           "delta_rule", "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA",
+           "ATTENTION",
            "CONV", "DELTA", "DENSE", "SPARSE", "INDEX_FULL",
            "INDEX_SHARED", "select_rows"]
 
@@ -1300,8 +1301,26 @@ def short_conv_step(spec: BlockSpec, u, tail, fresh, live, p, scope=None):
     return out, tail
 
 
+def delta_rule(state, q, k, v, g, beta, fresh, live):
+    """The recurrence of `delta_rule_step` in plain `jax.numpy`: state
+    [S, H, K keys, K values], q, k, v and the log decay g [S, H, K],
+    beta [S, H] (all float32), fresh and live bool [S] -> (the state
+    after this position, o [S, H, K]).  What runs where
+    `kernels.delta_rule.select_delta_rule` refuses, and what that
+    kernel is held to."""
+    import jax.numpy as jnp
+
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, state)
+    decayed = jnp.exp(g)[..., None] * s0
+    seen = (k[..., None] * decayed).sum(axis=2)                 # k^T S'
+    new = decayed + (beta[..., None] * k)[..., None] * (
+        v - seen)[:, :, None, :]
+    o = (new * q[..., None]).sum(axis=2)                        # S^T q
+    return jnp.where(live[:, None, None, None], new, state), o
+
+
 def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
-                    scope=None):
+                    scope=None, kernel=None):
     """ONE position of a gated delta-rule mixer (Solar-Open2's linear
     layer, after Kimi Delta Attention, arXiv:2510.26692) for every
     lane, `mamba2_step`'s sibling: u [S, D] float32 (the normed
@@ -1325,7 +1344,10 @@ def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
     keeps both.  `p`: the layer's arrays by `param_layout`'s keys.  The
     recurrence, the convolution, the L2 norms, the gates and the head
     norm are float32; the projections take the weights' dtype with
-    float32 accumulation."""
+    float32 accumulation.  `kernel`: what
+    `kernels.delta_rule.select_delta_rule` returned for these shapes;
+    its `rule` then stands where `delta_rule`'s lines do, a head's
+    matrix crossing HBM once in and once out."""
     import contextlib
 
     import jax
@@ -1364,13 +1386,8 @@ def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
         if spec.delta_neg_eigval:
             beta = 2.0 * beta
     with scope("delta_rule"):
-        s0 = jnp.where(fresh[:, None, None, None], 0.0, state)
-        decayed = jnp.exp(g)[..., None] * s0
-        seen = (k[..., None] * decayed).sum(axis=2)             # k^T S'
-        new = decayed + (beta[..., None] * k)[..., None] * (
-            v - seen)[:, :, None, :]
-        o = (new * q[..., None]).sum(axis=2)                    # S^T q
-        state = jnp.where(live[:, None, None, None], new, state)
+        state, o = (delta_rule if kernel is None else kernel.rule)(
+            state, q, k, v, g, beta, fresh, live)
     with scope("delta_gate_norm"):
         gate = jax.nn.sigmoid(proj(u, "delta_ga", "delta_gb")
                               + p["delta_g"].astype(f32))

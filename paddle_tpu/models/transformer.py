@@ -552,7 +552,8 @@ class PagedDecoder:
     jitted steps of one block description (its docstring has their
     arguments), what they keep on the device, and what the builder
     alone knows of them (docs/serving.md "What a decoder tells the
-    server").  Not frozen: a step's first trace sets `expert_kernel`."""
+    server").  Not frozen: a step's first trace sets `expert_kernel`
+    and `delta_kernel`."""
 
     step: Callable
     step_window: Callable
@@ -656,6 +657,11 @@ class PagedDecoder:
     # None until a step is traced (the weights' dtype and the rows are
     # the step's arguments) and for a block without experts
     expert_kernel: Optional[str] = None
+    # the same of the delta-rule layers' recurrence: the Pallas kernel's
+    # name (`kernels/delta_rule.py`), or "xla:<reason>" where
+    # `lm_block.delta_rule`'s lines run; None until a step is traced
+    # (the lanes are the step's arguments) and for a block without
+    delta_kernel: Optional[str] = None
 
 
 def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
@@ -814,6 +820,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     `BlockSpec.attention_gate`, multiply their context by a sigmoid of
     the layer's normed input before `o` (scope `attention_gate`).  A
     draft model, `step_window` and an int8 pool are refused by name.
+    The recurrence itself (scope `delta_rule`) is the Pallas kernel of
+    `kernels/delta_rule.py` where `select_delta_rule` returns it (a
+    TPU, heads of a multiple of 128 columns: a head's matrix crosses HBM
+    once in and once out, in place on the donated state) and
+    `lm_block.delta_rule`'s `jax.numpy` lines elsewhere; chosen when a
+    step is traced and reported as `decoder.delta_kernel`.
 
     Every block whose lanes keep something (Mamba, conv or delta layers)
     is served under a PREFIX CACHE through SNAPSHOTS: the decoder owns
@@ -922,6 +934,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # pool inside the Pallas kernel (no logical-order gather copy) or
     # the XLA gather below runs (docs/performance.md "Kernel
     # selection")
+    from ..kernels import delta_rule as _delta_rule
     from ..kernels import grouped_matmul as _grouped_matmul
     from ..kernels import paged_attention as _paged_attention
     from ..kernels import paged_index_scores as _paged_index_scores
@@ -1442,10 +1455,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         the layer's state and tail after it (`delta_rule_step`)."""
         with scope("delta_in_proj"):
             u = _norm(g, x, lay["norm1"])
+        # the kernel's own module decides from the lanes of this trace,
+        # the heads and the platform whether the recurrence is its
+        # Pallas kernel, in place on the pool, or `lm_block.delta_rule`
+        kernel, refused = _delta_rule.select_delta_rule(
+            lanes=state.shape[0], heads=spec.delta_heads,
+            d_head=spec.delta_d_head, platform=platform)
+        decoder.delta_kernel = (kernel.name if kernel is not None
+                                else f"xla:{refused}")
         out, state, tail = lm_block.delta_rule_step(
             spec, u, state, tail, fresh, live,
             {n: g[pair[0]] for n, pair in lay.items()
-             if n.startswith("delta_")}, scope=scope)
+             if n.startswith("delta_")}, scope=scope, kernel=kernel)
         with scope("delta_out_proj"):
             return _residual(x, out), state, tail
 
@@ -2224,7 +2245,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         those lanes' delta layers read and write back).  With experts
         `moe_kernel` (1: the traced step's expert
         layer is the Pallas grouped matmul, 0: `ragged_dot`) and
-        `moe_layers`.  With a looped stack `loop_passes` and `kv_planes`,
+        `moe_layers`; with delta-rule layers `delta_kernel` the same way
+        (1: their recurrence is `kernels/delta_rule.py`, 0:
+        `lm_block.delta_rule`'s lines).  With a looped stack
+        `loop_passes` and `kv_planes`,
         the planes the pages are counted over (`kv_planes` with double
         layers too: two a layer).  With a latent cache `latent_rows`:
         the rows the lanes with a sequence hold under their cursors
@@ -2294,6 +2318,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if decoder.expert_kernel is not None:
             counts["moe_kernel"] = int(
                 not decoder.expert_kernel.startswith("xla:"))
+        if decoder.delta_kernel is not None:
+            counts["delta_kernel"] = int(
+                not decoder.delta_kernel.startswith("xla:"))
         if moe_layers:
             counts["moe_layers"] = moe_layers
         return counts

@@ -864,6 +864,8 @@ class GenerationServer:
                # grouped matmul's name or "xla:<reason>"; None for a
                # block without experts
                "expert_kernel": self._decoder.expert_kernel,
+               # the same of a delta-rule layer's recurrence
+               "delta_kernel": self._decoder.delta_kernel,
                "kv_bytes_resident": (self._cache.used_blocks
                                      * self._cache.bytes_per_block),
                "draft_proposed": int(self._m_proposed.value),

@@ -20,6 +20,8 @@ layers call it, and compiles for a v5e at every serving cell's
 geometry; a lightning indexer's score kernel the same at its plane's,
 and both kernels' Mosaic modules, source locations apart, are the ones
 pinned here (what the chip's readings were taken of);
+a gated delta rule's kernel lowers and compiles for a v5e at the
+Solar cell's states with its output state ALIASING its input;
 the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
 kernel once a layer; a language model's AMP training step holds no
@@ -37,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from paddle_tpu.kernels import delta_rule
 from paddle_tpu.kernels.flash_attention import flash_attention
 from paddle_tpu.kernels.paged_attention import select_paged_attention
 from paddle_tpu.kernels.paged_index_scores import select_index_scores
@@ -362,6 +365,66 @@ def test_index_scores_compile_for_a_v5e(name, one_v5e):
     s_n, _, _, bs, nb, planes = INDEX_PLANES[name][:6]
     assert compiled.memory_analysis().temp_size_in_bytes <= \
         2 * planes * s_n * nb * bs * 4
+
+
+# name: lanes, heads, a head's keys (and values): one delta-rule
+# layer's states on `solar-open2-250b-serve-docqa64`, and a block of
+# heads that is no power of two
+DELTA_STATES = {
+    "solar-open2-docqa64": (64, 64, 128),
+    "heads-of-24": (3, 24, 128),
+}
+
+
+def _delta_rule(name, sharding=None):
+    """-> (the selected kernel, its `rule`'s arguments as shapes)."""
+    s_n, h_n, k_n = DELTA_STATES[name]
+    kern, why = delta_rule.select_delta_rule(
+        lanes=s_n, heads=h_n, d_head=k_n, platform="tpu")
+    assert kern is not None, why
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    row = sds((s_n, h_n, k_n))
+    return kern, (sds((s_n, h_n, k_n, k_n)), row, row, row, row,
+                  sds((s_n, h_n)), sds((s_n,), jnp.bool_),
+                  sds((s_n,), jnp.bool_))
+
+
+@pytest.mark.parametrize("name", sorted(DELTA_STATES))
+def test_delta_rule_lowers_for_tpu(name):
+    """One Mosaic call a layer's recurrence, float32 end to end: no
+    operand or result of the kernel in fewer bits."""
+    kern, args = _delta_rule(name)
+    text = lower_tpu(kern.rule, *args)
+    assert text.count(MOSAIC_CALL) == 1
+    (module,) = mosaic_modules(text)
+    assert "bf16" not in module and "f16" not in module
+
+
+@pytest.mark.parametrize("name", sorted(DELTA_STATES))
+def test_delta_rule_compiles_for_a_v5e_in_place(name, one_v5e):
+    """Mosaic's own compile at the cell's states (a step's blocks fit
+    the VMEM the call asks for), and the state donated, as the step
+    donates its pools, comes back as the SAME buffer: the output
+    aliases the input and nothing the size of a state lives beside it
+    (PR 59's `snapshot_restore` held 302 MB of temporaries that no CPU
+    test saw)."""
+    kern, args = _delta_rule(name, one_v5e)
+    compiled = jax.jit(kern.rule, donate_argnums=(0,)).lower(
+        *args).compile()
+    assert MOSAIC_CALL in compiled.as_text()
+    s_n, h_n, k_n = DELTA_STATES[name]
+    state, row = 4 * s_n * h_n * k_n * k_n, 4 * s_n * h_n * k_n
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == state
+    # the three transposed columns and the repeated beta, no more
+    assert memory.temp_size_in_bytes <= 6 * row
+    assert delta_rule._vmem_bytes(kern.heads_block, k_n) <= (
+        delta_rule._VMEM_BLOCK_BUDGET) < delta_rule._VMEM_LIMIT_BYTES
+    assert h_n % kern.heads_block == 0
+    assert kern.grid == (s_n, h_n // kern.heads_block)
 
 
 @pytest.mark.parametrize("shape,dtype,calls", [

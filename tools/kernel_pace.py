@@ -51,6 +51,19 @@ the cell's two index planes at its lanes' cursors, ms for both calls):
 whole, `no_copies`, `no_products`; `--check`: against the gather of the
 whole table in plain `jax.numpy`, over the rows under the cursors.
 
+A gated delta rule's kernel the same way (`--shape
+solar-open2-250b-serve-docqa64-delta`: `kernels/delta_rule.py` over one
+layer's states of the cell's 64 lanes, ms a call): whole, `no_copies`
+(every grid step names the FIRST state block, which the pipeline then
+fetches once and writes back once: the arithmetic alone, WRONG
+results), `no_arithmetic` (a head's tile handed through: the copies
+alone, WRONG results) and `xla`, `lm_block.delta_rule`'s `jax.numpy`
+lines on the same arguments under the same donation (what the kernel
+replaced).  `--heads-blocks`: the same call at so many heads a grid
+step (the kernel's own choice first).  `--check`: against the
+`jax.numpy` lines, state and o, as a share of their largest value
+(float32 sums in another order: some 1e-6).
+
 It imports the kernel and is imported by nothing a cell runs.
 """
 from __future__ import annotations
@@ -114,8 +127,13 @@ SHAPES = {
     "glm-5.2-serve-docqa64-indexer": dict(
         kernel="index", slots=64, heads=32, row=128, bs=16, ctx=6912,
         planes=2, blocks=9216),
+    # one delta-rule layer's recurrence over the cell's lanes: 64 heads
+    # of a [128 keys, 128 values] float32 matrix a lane, 268 MB
+    "solar-open2-250b-serve-docqa64-delta": dict(
+        kernel="delta", slots=64, heads=64, d_head=128),
 }
 VARIANTS = ("whole", "no_copies", "no_products")
+DELTA_VARIANTS = ("whole", "no_copies", "no_arithmetic", "xla")
 FLASH_VARIANTS = ("whole", "no_mask", "uncut", "no_row_sum", "no_row_max")
 TABLES = ("consecutive", "shuffled")
 
@@ -572,6 +590,133 @@ def run_index(name, variants=VARIANTS, calls=30, with_check=False,
     return res
 
 
+# ---------------------------------------------------------------------------
+# a gated delta rule's recurrence over the lanes' matrix states
+# ---------------------------------------------------------------------------
+
+@contextlib.contextmanager
+def delta_removed(variant, dr):
+    """`dr`'s kernel traced anew inside without `variant`'s part: every
+    grid step on the first state block (`no_copies`), or a head's rule
+    handing its tile through (`no_arithmetic`)."""
+    import jax
+    import jax.numpy as jnp
+
+    real_block, real_head = dr._state_block, dr._head
+    jax.clear_caches()
+    if variant == "no_copies":
+        dr._state_block = lambda lane, blk, fresh, live: (0, 0, 0, 0)
+    elif variant == "no_arithmetic":
+        dr._head = lambda s, e, k, q, v, beta: (
+            jnp.zeros((k.shape[0], v.shape[1]), v.dtype) if s is None
+            else s, v)
+    elif variant not in ("whole", "xla"):
+        raise ValueError(
+            f"no variant {variant!r}: one of {DELTA_VARIANTS}")
+    try:
+        yield
+    finally:
+        dr._state_block, dr._head = real_block, real_head
+        jax.clear_caches()
+
+
+def build_delta(shape, dr, variant="whole", heads_block=None,
+                interpret=False):
+    """-> (the jitted recurrence, the state donated: the kernel's, or
+    `lm_block.delta_rule`'s lines under `xla`; its arguments, the state
+    first; the heads a grid step takes)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import lm_block
+
+    s_n, h_n, k_n = (shape[k] for k in ("slots", "heads", "d_head"))
+    keys = jax.random.split(jax.random.PRNGKey(1), 6)
+
+    def unit(x):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    state = jax.random.normal(keys[0], (s_n, h_n, k_n, k_n), jnp.float32)
+    q, k, v = (jax.random.normal(key, (s_n, h_n, k_n), jnp.float32)
+               for key in keys[1:4])
+    q, k = unit(q) * k_n ** -0.5, unit(k)
+    g = -jax.nn.softplus(jax.random.normal(keys[4], (s_n, h_n, k_n)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(keys[5], (s_n, h_n)))
+    fresh = jnp.zeros(s_n, bool).at[0].set(True)
+    live = jnp.ones(s_n, bool).at[-1].set(False)
+    rule, block = lm_block.delta_rule, None
+    if variant != "xla":
+        chooses = dr._heads_block
+        if heads_block:
+            dr._heads_block = lambda heads, d_head: heads_block
+        try:
+            kern, why = dr.select_delta_rule(
+                lanes=s_n, heads=h_n, d_head=k_n, platform="tpu",
+                interpret=interpret)
+        finally:
+            dr._heads_block = chooses
+        assert kern is not None, why
+        rule, block = kern.rule, kern.heads_block
+    return (jax.jit(rule, donate_argnums=(0,)),
+            (state, q, k, v, g, beta, fresh, live), block)
+
+
+def check_delta(shape, dr, heads_block=None, interpret=False):
+    """-> (the heads a grid step takes, the largest difference of the
+    kernel's state and o from the `jax.numpy` lines', each as a share
+    of the lines' largest value)."""
+    import jax
+
+    ref, args, _ = build_delta(shape, dr, "xla")
+    want = [np.asarray(x) for x in ref(*args)]      # donates its state
+    f, args, block = build_delta(shape, dr, "whole", heads_block,
+                                 interpret)
+    got = jax.block_until_ready(f(*args))
+    return block, max(
+        float(np.abs(np.asarray(g) - w).max() / np.abs(w).max())
+        for g, w in zip(got, want))
+
+
+def run_delta(name, variants=DELTA_VARIANTS, heads_blocks=(), calls=30,
+              with_check=False, rehearse=False):
+    """-> {"shape", "state_bytes", "hb<n>.<variant>": ms a call,
+    "xla": ms a call, "hb<n>.check"}."""
+    import jax
+
+    from paddle_tpu.kernels import delta_rule as dr
+
+    shape = SHAPES[name]
+    if rehearse:
+        shape, calls = dict(shape, slots=3, heads=4), 1
+    res = {"shape": name, "device": jax.devices()[0].device_kind,
+           "rehearsal": bool(rehearse),
+           "state_bytes": 4 * shape["slots"] * shape["heads"]
+           * shape["d_head"] ** 2}
+    for hb in heads_blocks or (None,):
+        for variant in variants:
+            if rehearse and variant not in ("whole", "xla"):
+                continue    # the interpreter walks the whole kernel only
+            if variant == "xla" and "xla" in res:
+                continue
+            with delta_removed(variant, dr):
+                f, (state, *rest), block = build_delta(
+                    shape, dr, variant, hb, rehearse)
+                state, o = f(state, *rest)
+                jax.block_until_ready(o)
+                t = time.perf_counter()
+                for _ in range(calls):
+                    state, o = f(state, *rest)
+                jax.block_until_ready(o)
+                ms = (time.perf_counter() - t) / calls * 1e3
+            key = "xla" if variant == "xla" else f"hb{block}.{variant}"
+            res[key] = round(ms, 4)
+            print(f"{name} {key}", res[key], flush=True)
+        if with_check:
+            block, res_check = check_delta(shape, dr, hb, rehearse)
+            res[f"hb{block}.check"] = res_check
+    return res
+
+
 def toy(shape):
     """`shape` cut to what the interpreter walks in seconds."""
     return dict(shape, slots=3, heads=min(shape["heads"], 8),
@@ -583,7 +728,8 @@ def toy(shape):
 
 
 def run(name, block_sizes=None, variants=None, pa=None, calls=30,
-        with_check=False, rehearse=False, order="consecutive"):
+        with_check=False, rehearse=False, order="consecutive",
+        heads_blocks=()):
     """-> {"shape", "rows", "bs<n>.<variant>": ms, "bs<n>.pages",
     "bs<n>.tiling", "bs<n>.check"}."""
     import jax
@@ -595,6 +741,10 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
     if shape.get("kernel") == "flash":
         return run_flash(name, variants or FLASH_VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse)
+    if shape.get("kernel") == "delta":
+        return run_delta(name, variants or DELTA_VARIANTS, heads_blocks,
+                         calls=calls, with_check=with_check,
+                         rehearse=rehearse)
     if shape.get("kernel") == "index":
         return run_index(name, variants or VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse,
@@ -629,9 +779,13 @@ def main(argv=None):
     ap.add_argument("--shape", default="deepseek-v2-serve-agent64",
                     choices=sorted(SHAPES))
     ap.add_argument("--block-sizes", default="")
+    ap.add_argument("--heads-blocks", default="",
+                    help="the delta-rule kernel at so many heads a grid "
+                    "step (its own choice)")
     ap.add_argument("--variants", default="",
                     help="of the shape's kernel's (all): "
-                    + ",".join(VARIANTS + FLASH_VARIANTS[1:]))
+                    + ",".join(VARIANTS + FLASH_VARIANTS[1:]
+                               + DELTA_VARIANTS[2:]))
     ap.add_argument("--tables", default="consecutive", choices=TABLES)
     ap.add_argument("--calls", type=int, default=30)
     ap.add_argument("--check", action="store_true")
@@ -642,7 +796,9 @@ def main(argv=None):
               [int(b) for b in args.block_sizes.split(",") if b],
               tuple(v for v in args.variants.split(",") if v),
               calls=args.calls, with_check=args.check,
-              rehearse=args.rehearse, order=args.tables)
+              rehearse=args.rehearse, order=args.tables,
+              heads_blocks=[int(b) for b in args.heads_blocks.split(",")
+                            if b])
     print(json.dumps(res))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
