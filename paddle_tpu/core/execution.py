@@ -52,6 +52,14 @@ jax.tree_util.register_pytree_node(
 # ---------------------------------------------------------------------------
 
 
+def step_key(seed, step):
+    """The key of one step of a Program: its seed's key with the count
+    of the executor's runs folded in.  Called with two integers it is
+    two executables sent to the device; a compiled step calls it with
+    its two traced scalars and makes the same key inside itself."""
+    return jax.random.fold_in(jax.random.key(seed), step)
+
+
 class ExecContext:
     """Passed to every lowering.  Provides deterministic per-op PRNG keys and
     access to host-side facilities for interpreter-only ops.

@@ -22,6 +22,7 @@ via Param/ParamOut aliasing in optimizer ops, e.g. sgd_op.cc).
 from __future__ import annotations
 
 import itertools
+import operator
 import time
 import warnings
 import weakref
@@ -37,7 +38,7 @@ from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
 from ..observability.attribution import IterationClock
 from .compile_cache import compile_cache_dir
-from .execution import DictEnv, ExecContext, ScopeEnv, run_op
+from .execution import DictEnv, ExecContext, ScopeEnv, run_op, step_key
 from .flags import get_flag, trace_flags
 from .framework import Program, Variable, default_main_program
 from .lod import LoDTensor
@@ -179,8 +180,11 @@ def _place_feed(v, device):
                          v.lod), True
     if isinstance(v, jnp.ndarray):
         # already a jax array: placing it directly avoids a device->host
-        # round-trip and keeps a weak dtype weak (np.asarray would do both)
-        return jax.device_put(v, device), False
+        # round-trip and keeps a weak dtype weak (np.asarray would do
+        # both); one that rests there (a batch the trainer's pipeline
+        # staged) is what `device_put` would hand back
+        return (v if _rests_on(v, device)
+                else jax.device_put(v, device)), False
     if isinstance(v, (int, float, bool)) and not isinstance(v, np.generic):
         # same weak-typing rule as _commit below: a Python scalar fed to
         # a bf16 program must not arrive as a strong f32/i64 array
@@ -219,8 +223,21 @@ def _to_numpy(v):
     return v
 
 
+def _seed_word(seed):
+    """The 32 bits `jax.random.key` keeps of a Python integer seed while
+    64-bit types are off (it wraps the seed into an int32 before it
+    seeds), as a NumPy scalar a jitted step can take traced: the key it
+    makes of this is the key made of the integer, bit for bit."""
+    return np.int64(seed).astype(np.int32)
+
+
+_LEAF = str(jax.tree_util.tree_structure(0))
+
+
 def _aval_key(v):
     """Hashable (structure, shapes, dtypes) key for one value."""
+    if isinstance(v, jax.Array):  # a leaf: what the lines below give
+        return _LEAF, ((tuple(v.shape), str(v.dtype)),)
     leaves, treedef = jax.tree_util.tree_flatten(v)
     return (
         str(treedef),
@@ -277,6 +294,11 @@ def _rests_on(v, target) -> bool:
     return a.devices() == {target}
 
 
+def _NO_FN():
+    """A dead weak reference's answer: the record knows no executable."""
+    return None
+
+
 class _StepRecord:
     """What `_run_compiled` learnt about one compiled step the last time
     it ran it: the analysis of the Program (which never changes under
@@ -289,12 +311,15 @@ class _StepRecord:
 
     __slots__ = ("device", "flags", "state_in_names", "state_out_names",
                  "ro_names", "rw_names", "donatable", "repl", "target",
-                 "seen", "twin", "keys", "feed_keys", "don_names",
-                 "cache_key", "outs")
+                 "seen", "twin", "keys", "feed_names", "feed_keys",
+                 "don_names", "keep_names", "cache_key", "fn", "outs")
 
-    def __init__(self, device, flags, state_in_names, state_out_names,
-                 repl):
+    def __init__(self, device, flags, feed_names, state_in_names,
+                 state_out_names, repl):
         self.device, self.flags = device, flags
+        # the feeds in the order every executable of this record takes
+        # them: by name, whatever order the caller's dictionary has
+        self.feed_names = tuple(sorted(feed_names))
         self.state_in_names = state_in_names
         self.state_out_names = state_out_names
         written = set(state_out_names)
@@ -308,32 +333,40 @@ class _StepRecord:
         self.seen: Dict = {}
         self.twin: Dict = {}
         self.keys: Dict = {}
-        self.feed_keys = self.don_names = None
+        self.feed_keys = self.don_names = self.keep_names = None
         # the executable-cache key of the last call while every part of
-        # it still holds; None once a state's key moved
-        self.cache_key = None
+        # it still holds, None once a state's key moved; and a weak
+        # reference to the executable under it (the executor's cache
+        # owns it: a record names no program, and its executable's
+        # closure does)
+        self.cache_key, self.fn = None, _NO_FN
         # cache key -> {written state: its `_aval_key`}: an executable's
         # output shapes are fixed at compile time, so they are read from
         # its first outputs only
         self.outs: Dict = {}
 
     def gather(self, names, scope):
-        """({name: committed value} for `names` from the scope, how many
-        of them went through `_commit`)."""
+        """(the committed values of `names` from the scope, a tuple in
+        their order, how many of them went through `_commit`)."""
         seen, twin, keys = self.seen, self.twin, self.keys
-        find, vals, recommitted = scope.find_var, {}, 0
-        for n in names:
+        found = scope.find_vars(names)
+        if all(map(operator.is_, found, map(seen.get, names))):
+            # the scope holds every one as the last step left it (the
+            # common step): three passes at C speed, no line a state
             try:
-                v = find(n)
+                return tuple(map(twin.__getitem__, names)), 0
             except KeyError:
-                v = None
+                pass  # a name neither in the scope nor ever seen
+        vals, recommitted = [], 0
+        for n, v in zip(names, found):
             if v is None:
                 raise _MissingState(n)
             if v is seen.get(n):
-                vals[n] = twin[n]
+                vals.append(twin[n])
                 continue
             recommitted += 1
-            c = vals[n] = _commit(v, self.target)
+            c = _commit(v, self.target)
+            vals.append(c)
             k = _aval_key(c)
             if keys.get(n) != k:
                 keys[n], self.cache_key = k, None
@@ -342,7 +375,7 @@ class _StepRecord:
             else:
                 seen.pop(n, None)
                 twin.pop(n, None)
-        return vals, recommitted
+        return tuple(vals), recommitted
 
     def written(self, cache_key, state_out):
         """After the call: what the step wrote is what the scope now
@@ -453,6 +486,11 @@ _M_STATE_COMMITS = obs_metrics.counter(
     "persistable states a compiled step put through _commit (a step "
     "whose states are all as the last one left them adds none)",
     ("exe",), always=True)
+_M_AUX_DISPATCHES = obs_metrics.counter(
+    "paddle_tpu_executor_aux_dispatches_total",
+    "executables run() sent to the device besides the step's own (the "
+    "two that make a step key outside a compiled step)",
+    ("exe",), always=True)
 _M_ENTRIES = obs_metrics.gauge(
     "paddle_tpu_executor_cache_entries",
     "live executables in the cache", ("exe",), always=True)
@@ -515,6 +553,7 @@ class Executor:
         self._m_compile_s = _M_COMPILE_S.labels(exe=self._exe_id)
         self._m_recompiles = _M_RECOMPILES.labels(exe=self._exe_id)
         self._m_state_commits = _M_STATE_COMMITS.labels(exe=self._exe_id)
+        self._m_aux_dispatches = _M_AUX_DISPATCHES.labels(exe=self._exe_id)
         self._m_entries = _M_ENTRIES.labels(exe=self._exe_id)
         self._warm_fps: set = set()
         self._clock = run_clock()
@@ -540,7 +579,10 @@ class Executor:
         through `_commit`: a step that finds every state as the last one
         left it adds none, so a count that grows with the steps names a
         loop that replaces states or keeps NumPy values in the scope
-        (docs/performance.md).
+        (docs/performance.md); `aux_dispatches`, the executables the
+        runs sent to the device besides the steps' own: two a run that
+        made its step key outside the step (the interpreted and
+        segmented modes), none for a compiled one.
 
         A view over this instance's series in the process metrics
         registry (exported with everything else by
@@ -550,6 +592,7 @@ class Executor:
                 "compile_s": self._m_compile_s.value,
                 "recompiles_after_warmup": int(self._m_recompiles.value),
                 "state_commits": int(self._m_state_commits.value),
+                "aux_dispatches": int(self._m_aux_dispatches.value),
                 "entries": len(self._cache)}
 
     def _note_lookup(self, hit: bool, fp, cache_key, once=None) -> None:
@@ -629,9 +672,11 @@ class Executor:
                 compiled = False
             elif not host_ops:
                 compiled = True
-        step_key = jax.random.fold_in(
-            jax.random.key(program.seed or self._seed), self._step
-        )
+        # the step's key is fold_in(key(seed), step).  A compiled step
+        # takes the two numbers and makes the key itself (a Program that
+        # draws nothing compiles it away); the other modes make it
+        # here, as two dispatches to the device (`_outside_step_key`)
+        seed, step = program.seed or self._seed, self._step
         self._step += 1
 
         if compiled:
@@ -669,14 +714,16 @@ class Executor:
         # children in compiled mode: executor.feed, executor.dispatch
         # (_run_compiled) and, below, executor.fetch
         with obs_tracing.span("executor.run", mode=mode) as run_span:
+            aux = self._m_aux_dispatches.value if run_span is not None else 0
             if mode == "segmented":
                 outs = self._run_segmented(
-                    program, block, scope, feed, fetch_names, step_key
+                    program, block, scope, feed, fetch_names,
+                    self._outside_step_key(seed, step)
                 )
             elif mode == "compiled":
                 try:
                     outs = self._run_compiled(
-                        program, block, scope, feed, fetch_names, step_key,
+                        program, block, scope, feed, fetch_names, seed, step,
                         record_key, record
                     )
                 except _MissingState as e:
@@ -686,8 +733,13 @@ class Executor:
                     ) from None
             else:
                 outs = self._run_interpreted(
-                    program, block, scope, feed, fetch_names, step_key
+                    program, block, scope, feed, fetch_names,
+                    self._outside_step_key(seed, step)
                 )
+            if run_span is not None:
+                run_span.set_attr(
+                    "aux_dispatches",
+                    int(self._m_aux_dispatches.value - aux))
             clock.mark("dispatch")
             if obs_metrics.enabled():
                 _M_RUN_SECONDS.labels(
@@ -695,10 +747,21 @@ class Executor:
                         time.perf_counter() - t0)
             if return_numpy:
                 # the wait for the device: the step's results are read
+                # (`np.asarray` of a device array queues its copy to the
+                # host behind the step that makes it at once and then
+                # waits: starting the copy earlier gains nothing)
                 with obs_tracing.span("executor.fetch"):
                     outs = [_to_numpy(v) for v in outs]
             end_run(clock, run_span)
         return outs
+
+    def _outside_step_key(self, seed, step):
+        """The step's key made on the host's side of the step, for the
+        modes that run ops eagerly: `key(seed)` and `fold_in` are each
+        an executable of their own sent to the device, which
+        `aux_dispatches` counts."""
+        self._m_aux_dispatches.inc(2)
+        return step_key(seed, step)
 
     def close(self):
         self._cache.clear()
@@ -710,7 +773,7 @@ class Executor:
         _M_LOOKUPS.remove(exe=self._exe_id, result="hit")
         _M_LOOKUPS.remove(exe=self._exe_id, result="miss")
         for fam in (_M_COMPILE_S, _M_RECOMPILES, _M_STATE_COMMITS,
-                    _M_ENTRIES):
+                    _M_AUX_DISPATCHES, _M_ENTRIES):
             fam.remove(exe=self._exe_id)
         for mode in ("interpreted", "segmented", "compiled"):
             _M_RUN_SECONDS.remove(exe=self._exe_id, mode=mode)
@@ -993,7 +1056,7 @@ class Executor:
 
     def _new_step_record(self, program, block, feed_names, fetch_names,
                          device):
-        rec = _StepRecord(device, trace_flags(),
+        rec = _StepRecord(device, trace_flags(), feed_names,
                           *self._analyze_states(program, block, feed_names),
                           _dp_replicated_sharding(block.ops))
         # liveness donation plan (memory_optimization_transpiler): which
@@ -1010,11 +1073,12 @@ class Executor:
             if n in block.vars and getattr(block.vars[n], "donate", False)}
         return rec
 
-    def _run_compiled(self, program, block, scope, feed, fetch_names, key,
-                      record_key, rec):
+    def _run_compiled(self, program, block, scope, feed, fetch_names, seed,
+                      step, record_key, rec):
         """`rec`: the `_StepRecord` `run` took out of the table for
         this call, or None; it goes back under `record_key` only when
-        the step has run to its end."""
+        the step has run to its end.  `seed` and `step` are what the
+        step makes its key from."""
         device = self.place.jax_device()
         # executor.feed: feeds placed on the device, states committed
         with obs_tracing.span("executor.feed") as feed_span:
@@ -1026,8 +1090,8 @@ class Executor:
             if rec is None or rec.device != device:
                 rec = self._new_step_record(program, block, feed_vals.keys(),
                                             fetch_names, device)
-            don_names = tuple(sorted(
-                n for n in rec.donatable if n in fresh))
+            don_names = tuple(n for n in rec.feed_names
+                              if n in rec.donatable and n in fresh)
             ro, n_ro = rec.gather(rec.ro_names, scope)
             rw, n_rw = rec.gather(rec.rw_names, scope)
             if n_ro + n_rw:
@@ -1038,12 +1102,14 @@ class Executor:
         self._clock.mark("feed")
         # executor.dispatch: cache lookup and the jitted call
         with obs_tracing.span("executor.dispatch"):
-            feed_keys = tuple(sorted(
-                (n, _aval_key(v)) for n, v in feed_vals.items()))
+            feed_keys = tuple((n, _aval_key(feed_vals[n]))
+                              for n in rec.feed_names)
             if (rec.cache_key is None or feed_keys != rec.feed_keys
                     or don_names != rec.don_names):
                 keys = rec.keys
                 rec.feed_keys, rec.don_names = feed_keys, don_names
+                rec.keep_names = tuple(n for n in rec.feed_names
+                                       if n not in don_names)
                 rec.cache_key = (
                     self._fingerprint(program),
                     block.idx,
@@ -1055,33 +1121,42 @@ class Executor:
                     don_names,  # donation is baked into the executable
                     rec.flags,
                 )
+                rec.fn = _NO_FN
             # no state's key and no feed's moved: the last call's tuple
-            cache_key = rec.cache_key
-            fn = self._cache.get(cache_key)
+            # and the executable found under it (a tuple of several
+            # hundred tuples is hashed anew at every lookup)
+            cache_key, fn = rec.cache_key, rec.fn()
+            if fn is None:
+                fn = self._cache.get(cache_key)
             miss = fn is None
             self._note_lookup(not miss, cache_key[0], cache_key)
             if miss:
                 fn = self._build_compiled_fn(
-                    block, fetch_names, rec.state_out_names, rec.repl
+                    block, fetch_names, rec.state_out_names,
+                    (don_names, rec.keep_names, rec.ro_names, rec.rw_names),
+                    rec.repl
                 )
                 self._cache[cache_key] = fn
-            don_feeds = {n: feed_vals[n] for n in don_names}
-            keep_feeds = {n: v for n, v in feed_vals.items()
-                          if n not in don_feeds}
+            rec.fn = weakref.ref(fn)
+            # the step's arguments in the record's order, as tuples: a
+            # dictionary of several hundred states is sorted by name at
+            # every call of a jitted function, a tuple is walked
+            args = (tuple(feed_vals[n] for n in don_names),
+                    tuple(feed_vals[n] for n in rec.keep_names),
+                    ro, rw, _seed_word(seed), np.uint32(step))
             from paddle_tpu import profiler
 
             if miss:
                 # device time by scope: hlo_scopes() can read this
                 # executable's compiled text later (shapes, no buffers)
-                profiler.register_jitted("executor.block", fn, don_feeds,
-                                         keep_feeds, ro, rw, key)
+                profiler.register_jitted("executor.block", fn, *args)
             t0 = time.perf_counter() if miss else None
             if profiler.is_enabled():
                 with profiler.record_event("xla_block"):
-                    fetches, state_out = fn(don_feeds, keep_feeds, ro, rw, key)
+                    fetches, state_out = fn(*args)
                     jax.block_until_ready((fetches, state_out))
             else:
-                fetches, state_out = fn(don_feeds, keep_feeds, ro, rw, key)
+                fetches, state_out = fn(*args)
             if miss:
                 self._m_compile_s.inc(time.perf_counter() - t0)
                 self._m_entries.set(len(self._cache))
@@ -1089,16 +1164,24 @@ class Executor:
                 scope.set_var(n, v)
             rec.written(cache_key, state_out)
             self._keep_step_record(scope, program, record_key, rec)
-        return [fetches[n] for n in fetch_names]
+        return fetches
 
-    def _build_compiled_fn(self, block, fetch_names, state_out_names,
+    def _build_compiled_fn(self, block, fetch_names, state_out_names, names,
                            repl=None):
-        def fn(don_feeds, keep_feeds, ro, rw, rng_key):
-            env = DictEnv({**ro, **rw, **keep_feeds, **don_feeds})
-            ctx = ExecContext(rng_key, executor=self, compiled=True)
+        """The jitted step.  `names`: the names of its first four
+        arguments' values (donated feeds, kept feeds, read-only states,
+        read-write states), each a tuple in that order; then the seed
+        and the step count, two traced scalars the key is made from."""
+        don_names, keep_names, ro_names, rw_names = map(tuple, names)
+
+        def fn(don_feeds, keep_feeds, ro, rw, seed, step):
+            env = DictEnv(zip(ro_names + rw_names + keep_names + don_names,
+                              ro + rw + keep_feeds + don_feeds))
+            ctx = ExecContext(step_key(seed, step), executor=self,
+                              compiled=True)
             for op in block.ops:
                 run_op(ctx, op, env)
-            fetches = {n: env.get(n) for n in fetch_names}
+            fetches = [env.get(n) for n in fetch_names]
             state_out = {
                 n: env.d[n]
                 for n in state_out_names
@@ -1116,7 +1199,7 @@ class Executor:
             # partitioner may shard the annotated subgraph (single-device
             # committed args would conflict with the mesh)
             return jax.jit(fn, donate_argnums=(0, 3),
-                           in_shardings=(repl, repl, repl, repl, repl))
+                           in_shardings=(repl,) * 6)
         return jax.jit(fn, donate_argnums=(0, 3))
 
 

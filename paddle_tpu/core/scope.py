@@ -46,6 +46,18 @@ class Scope:
             raise KeyError(f"variable '{name}' not found in scope")
         return s._vars[name]
 
+    def find_vars(self, names):
+        """`find_var` for each of `names` in one pass, None where a name
+        is not found: what a compiled step asks for its few hundred
+        states at every call."""
+        if self.parent is None:
+            return list(map(self._vars.get, names))
+        found = []
+        for name in names:
+            s = self._find_scope(name)
+            found.append(None if s is None else s._vars[name])
+        return found
+
     def has_var(self, name: str) -> bool:
         return self._find_scope(name) is not None
 

@@ -125,7 +125,8 @@ def test_cache_stats_counters_and_steady_state():
     scope = fluid.Scope()
     assert exe.cache_stats() == {"hits": 0, "misses": 0, "compile_s": 0.0,
                                  "recompiles_after_warmup": 0,
-                                 "state_commits": 0, "entries": 0}
+                                 "state_commits": 0, "aux_dispatches": 0,
+                                 "entries": 0}
     exe.run(startup, scope=scope)
     _run_steps(exe, main, loss, scope, [_feed()] * 5)
     s = exe.cache_stats()
@@ -597,3 +598,181 @@ def test_executor_keeps_neither_scope_nor_program_alive(what):
     assert ref() is None
     assert arr is None or arr() is None
     assert _records(exe) == []
+
+
+# ---------------------------------------------------------------------------
+# the serial section of a step (PR 61): a compiled step makes its key from
+# two traced scalars, the fetched arrays' readback starts at dispatch, and
+# the step's arguments go to the jitted call as tuples
+# ---------------------------------------------------------------------------
+
+
+def _reference_steps(main, loss, states, seeds, feeds):
+    """The steps with the key made OUTSIDE the step, as `run` made it
+    before PR 61: `fold_in(key(seed), step)` on the host's side, handed
+    to a jitted `program_to_fn` as an argument.  Step i (from 1: the
+    startup program's run was step 0) takes `seeds[i - 1]`."""
+    from paddle_tpu.core.executor import program_to_fn
+
+    fn = program_to_fn(main, ["x", "y"], [loss.name])
+    step = jax.jit(fn)
+    losses = []
+    for i, (seed, feed) in enumerate(zip(seeds, feeds), start=1):
+        key = jax.random.fold_in(jax.random.key(seed), i)
+        fetches, states = step(feed, states, key)
+        losses.append(np.asarray(fetches[loss.name]))
+    return losses, {n: np.asarray(v) for n, v in states.items()}
+
+
+@pytest.mark.parametrize("seeding", ["program_seed", "executor_seed",
+                                     "seed_changed_between_runs",
+                                     "seed_past_32_bits"])
+def test_compiled_step_makes_the_key_the_host_made(seeding):
+    """Ten compiled steps of a dropout + momentum Program, their key made
+    inside the step from the seed and the count, are bit for bit the
+    steps whose key is made outside: same masks, same losses, same
+    parameters.  Seed and count are traced: ten steps, and a seed that
+    changes on the way, are ONE executable."""
+    from paddle_tpu.core.framework import reset_unique_names
+
+    reset_unique_names()
+    main, startup, loss = _build_momentum_dropout()
+    exe_seed = 0
+    if seeding == "executor_seed":
+        main.seed, exe_seed = 0, 1234
+    elif seeding == "seed_past_32_bits":
+        main.seed = 2 ** 32 + 2 ** 31 + 5
+    exe = fluid.Executor(fluid.CPUPlace(), seed=exe_seed)
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    states = {n: np.asarray(scope.find_var(n))
+              for n in scope.local_names()}
+    feeds = [_feed(i) for i in range(10)]
+    seeds, losses = [], []
+    for i, feed in enumerate(feeds):
+        if seeding == "seed_changed_between_runs" and i == 5:
+            main.seed = 99          # no part of the executable's key
+        seeds.append(main.seed or exe_seed)
+        losses.append(exe.run(main, feed=feed, fetch_list=[loss],
+                              scope=scope)[0])
+    want_losses, want_states = _reference_steps(main, loss, states, seeds,
+                                                feeds)
+    assert [v.tobytes() for v in losses] == \
+        [v.tobytes() for v in want_losses]
+    assert len(want_states) >= 9
+    for n, v in want_states.items():
+        assert np.asarray(scope.find_var(n)).tobytes() == v.tobytes(), n
+    # a seed and a count are arguments, not constants of the trace
+    s = exe.cache_stats()
+    assert (s["misses"], s["hits"]) == (2, 9), s     # startup + main
+    assert s["recompiles_after_warmup"] == 0, s
+    assert all(size == 1 for size in _jit_cache_sizes(exe))
+    assert s["aux_dispatches"] == 0, s
+    # and the masks differ from step to step (the count reaches the key)
+    assert len({v.tobytes() for v in losses}) == 10
+
+
+def _dropout_of_ones():
+    """A Program whose fetch IS its dropout mask."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.seed = startup.seed = 11
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[64], dtype="float32")
+        out = fluid.layers.dropout(x, dropout_prob=0.5)
+    return main, out
+
+
+@pytest.mark.parametrize("mode", ["interpreted", "segmented"])
+def test_eager_modes_draw_the_stream_they_drew(mode):
+    """The interpreted and the segmented paths still make the step key
+    on the host's side (two dispatches a run, counted), and it is the
+    key a compiled step makes inside itself: the same mask at the same
+    step, a different one at the next."""
+    from paddle_tpu.core.flags import set_flags
+
+    main, out = _dropout_of_ones()
+    feed = {"x": np.ones((4, 64), np.float32)}
+
+    def masks(compiled, granularity="block"):
+        exe = fluid.Executor(fluid.CPUPlace())
+        set_flags({"jit_granularity": granularity})
+        try:
+            got = [exe.run(main, feed=feed, fetch_list=[out],
+                           scope=fluid.Scope(), compiled=compiled)[0]
+                   for _ in range(3)]
+        finally:
+            set_flags({"jit_granularity": "block"})
+        return got, exe.cache_stats()["aux_dispatches"]
+
+    want, aux = masks(True)
+    assert aux == 0
+    got, aux = (masks(False) if mode == "interpreted"
+                else masks(True, "segment"))
+    assert aux == 2 * 3
+    assert [v.tobytes() for v in got] == [v.tobytes() for v in want]
+    assert len({v.tobytes() for v in want}) == 3
+    assert set(np.unique(want[0])) == {0.0, 1.0}     # it IS the mask
+
+
+def test_warm_compiled_step_sends_nothing_but_the_step(monkeypatch):
+    """`aux_dispatches`: 0 in `cache_stats()` and on the `executor.run`
+    span of a compiled step (warm or first), 2 on an interpreted one;
+    and a warm step calls neither `jax.random.key` nor `fold_in`."""
+    from paddle_tpu.observability import tracing
+
+    main, loss, exe, scope, feed = _warm()
+    assert exe.cache_stats()["aux_dispatches"] == 0
+    calls = []
+    for name in ("key", "fold_in"):
+        real = getattr(jax.random, name)
+        monkeypatch.setattr(
+            jax.random, name,
+            lambda *a, _real=real, _n=name, **k: calls.append(_n)
+            or _real(*a, **k))
+    spans = []
+    tracing.add_span_listener(spans.append)
+    try:
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        assert calls == []
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                compiled=False)
+        assert sorted(set(calls)) == ["fold_in", "key"]
+    finally:
+        tracing.remove_span_listener(spans.append)
+        tracing.clear()
+    runs = [(s["attrs"]["mode"], s["attrs"]["aux_dispatches"])
+            for s in spans if s["name"] == "executor.run"]
+    assert runs == [("compiled", 0), ("interpreted", 2)]
+    assert exe.cache_stats()["aux_dispatches"] == 2
+
+
+@pytest.mark.parametrize("return_numpy", [True, False])
+def test_a_fetch_is_the_devices_bytes_either_way(return_numpy):
+    """`return_numpy=True` returns NumPy arrays of the device values'
+    dtype and bytes, `False` the device arrays themselves, a large
+    fetch (4 MiB) like a scalar: the step's arguments and its key
+    changed their form in PR 61, what it returns did not."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[512], dtype="float32")
+        h = fluid.layers.fc(input=x, size=2048, act="relu")   # 4 MiB out
+        small = fluid.layers.mean(h)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = {"x": np.random.RandomState(3).rand(512, 512).astype(np.float32)}
+    w, b = (np.asarray(scope.find_var(n)) for n in ("fc_0.w_0", "fc_0.b_0"))
+    got = exe.run(main, feed=feed, fetch_list=[h, small], scope=scope,
+                  return_numpy=return_numpy)
+    if return_numpy:
+        assert all(type(v) is np.ndarray for v in got)
+    else:
+        assert all(isinstance(v, jax.Array) for v in got)
+    assert got[0].shape == (512, 2048) and got[0].nbytes == 4 << 20
+    assert all(v.dtype == np.float32 for v in got)
+    want = np.maximum(feed["x"] @ w + b, 0)
+    np.testing.assert_allclose(np.asarray(got[0]), want, rtol=1e-4,
+                               atol=1e-5)
+    other = exe.run(main, feed=feed, fetch_list=[h, small], scope=scope,
+                    return_numpy=not return_numpy)
+    for g, o in zip(got, other):
+        assert np.asarray(g).tobytes() == np.asarray(o).tobytes()

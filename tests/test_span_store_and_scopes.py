@@ -552,6 +552,109 @@ def test_state_reuse_and_dispatch_readers_on_the_programs_own_spans(
 
 
 # ---------------------------------------------------------------------------
+# the two readers of a synchronous step's serial section (PR 61:
+# perf/metrics/reader_serial_host_ms.py, reader_aux_dispatches.py)
+# ---------------------------------------------------------------------------
+
+_SERIAL = ("reader_serial_host_ms", "reader_aux_dispatches")
+
+
+def _sync_steps_by_hand(run_attrs, fetch=True, tail_ms=(4.0,) * 4):
+    """Steps as a loop that reads its loss records them: the read ends
+    with the step, and `tail_ms[i]` later the next step's dispatch
+    ends (1 ms of it the dispatch's own span)."""
+    def body():
+        t = 1000.0
+        for attrs, tail in zip(run_attrs, tail_ms):
+            tracing.record_span("executor.dispatch", t + tail / 1e3 - 0.001,
+                                0.001)
+            if fetch:
+                tracing.record_span("executor.fetch", t + tail / 1e3, 0.006)
+            tracing.record_span("executor.run", t, tail / 1e3 + 0.006,
+                                mode="compiled", **attrs)
+            t += tail / 1e3 + 0.006
+    return body
+
+
+@pytest.mark.parametrize("run_attrs, fetch, tail_ms, serial, aux", [
+    ([{"aux_dispatches": 0}] * 4, True, (4.0,) * 4, 4.0, 0.0),
+    # the first dispatch follows no read; the median of 5, 3, 2
+    ([{"aux_dispatches": 2}] * 4, True, (9.0, 5.0, 3.0, 2.0), 3.0, 2.0),
+    ([{"aux_dispatches": 2}] + [{"aux_dispatches": 0}] * 3, True,
+     (4.0,) * 4, 4.0, 0.5),
+    # a parent's spans carry no such attribute: the gap reads, the count
+    # says nothing
+    ([{}] * 4, True, (4.0,) * 4, 4.0, None),
+    # a loop that leaves its results on the device records no read
+    ([{"aux_dispatches": 0}] * 4, False, (4.0,) * 4, None, 0.0),
+], ids=["steady", "known_gaps", "one_eager_run", "parent_without_attribute",
+        "store_without_fetch"])
+def test_serial_section_readers_on_known_spans(monkeypatch, run_attrs, fetch,
+                                               tail_ms, serial, aux):
+    got = _read_executor(
+        monkeypatch, _sync_steps_by_hand(run_attrs, fetch, tail_ms),
+        names=_SERIAL)
+    assert got["reader_serial_host_ms"] == (
+        None if serial is None else pytest.approx(serial, abs=1e-3))
+    assert got["reader_aux_dispatches"] == (
+        None if aux is None else pytest.approx(aux))
+
+
+def test_serial_section_pairs_a_dispatch_with_its_own_threads_read(
+        monkeypatch):
+    """A read that ended on another thread (an evaluation beside the
+    loop) opens no gap on this one."""
+    import threading
+
+    def body():
+        tracing.record_span("executor.fetch", 1000.000, 0.002)
+
+        def other():
+            tracing.record_span("executor.fetch", 1000.004, 0.001)
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=10)
+        assert not th.is_alive()
+        tracing.record_span("executor.dispatch", 1000.006, 0.001)
+    got = _read_executor(monkeypatch, body, names=_SERIAL)
+    assert got["reader_serial_host_ms"] == pytest.approx(5.0, abs=1e-3)
+
+
+def test_serial_section_readers_on_the_programs_own_spans(monkeypatch):
+    """Five compiled steps and one interpreted, recorded by
+    `Executor.run` itself: a gap a step after the first, all positive
+    and under the period; no key is made outside a compiled step, two
+    dispatches make one for the interpreter."""
+    main, startup, loss = _classifier()
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    exe.run(startup, scope=scope)
+    feed = _batch()
+
+    def body():
+        for _ in range(5):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+
+    got = _read_executor(monkeypatch, body, names=_SERIAL)
+    assert got["reader_aux_dispatches"] == 0.0
+    runs = _named("executor.run")
+    periods = [b["ts"] + b["dur"] - a["ts"] - a["dur"]
+               for a, b in zip(runs, runs[1:])]
+    assert 0 < got["reader_serial_host_ms"] < 1e3 * max(periods)
+    assert [s["attrs"]["aux_dispatches"] for s in runs] == [0] * 5
+    tracing.clear()
+    got = _read_executor(
+        monkeypatch,
+        lambda: exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                        compiled=False), names=_SERIAL)
+    assert got["reader_aux_dispatches"] == 2.0
+    assert exe.cache_stats()["aux_dispatches"] == 2
+    exe.close()
+    tracing.clear()
+    assert set(_read_executor(monkeypatch, lambda: None,
+                              names=_SERIAL).values()) == {None}
+
+
+# ---------------------------------------------------------------------------
 # B. the executors' three children, the trainer's reader phase
 # ---------------------------------------------------------------------------
 
