@@ -188,6 +188,32 @@ architecture is a second description and not a second decoder.
                router is the second's renormalised, the held experts and
                the shared expert the fourth's.
 
+  delta-rule-  the twelfth (inclusionAI Ling-3.0-flash, `model_type:
+  latent-like  bailing_hybrid`), fields again, and the first COMPOSITION:
+               the eleventh's delta-rule lanes BESIDE the seventh's
+               latent table in one block (a period of layers of which
+               the last is latent attention and the others delta rules),
+               so that a lane keeps a matrix state and a tail a delta
+               layer and the table ONE latent row a position for the
+               minority of layers that attend.  Both gates of the delta
+               rule FULL matrices (`delta_gate_rank` 0: one [d, H*K]
+               matrix each, no low-rank pair, no bias on the output
+               gate) and the decay BOUNDED (`delta_gate_floor` f < 0:
+               g = f * sigmoid(exp(A_log) * (z W_f + dt_bias)), so that
+               a channel's decay a step lies in (e^f, 1)); RoPE on the
+               latent layers and NO position signal on the lanes'
+               layers; a latent query WITHOUT a low-rank step
+               (`q_lora_rank` 0: one matrix, no `q_a`, no norm); the
+               latent layer's heads each times a sigmoid SCALAR of the
+               layer's input before `o` (`attention_gate_per_head`: a
+               matrix [d, H]); dense layers before the sparse ones
+               beside the lanes (`mlp_layer_types`); the sixth's
+               sigmoid router with its choice bias under the seventh's
+               GROUP LIMIT, a group's score the SUM OF ITS TWO LARGEST
+               biased scores (`group_score: "top2_sum"`); and a CLAMP a
+               layer on the experts' and the shared expert's SwiGLU
+               inputs (`expert_swiglu_limits`, `shared_swiglu_limits`).
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -204,7 +230,7 @@ from typing import Dict, Tuple
 
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "rope_tables", "yarn_inv_freq", "rope", "route", "moe_ffn",
-           "swiglu", "mamba2_step", "short_conv_step", "delta_rule_step",
+           "swiglu", "clamped", "mamba2_step", "short_conv_step", "delta_rule_step",
            "delta_rule", "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA",
            "ATTENTION",
            "CONV", "DELTA", "DENSE", "SPARSE", "INDEX_FULL",
@@ -249,7 +275,12 @@ class BlockSpec:
     layer on a latent cache with identity experts under a softmax
     router with a choice bias, and the table-only block with experts,
     an FFN kind a layer and the per-head QK-norm whose other layers are
-    gated short convolutions (module docstring).  `layer_types`,
+    gated short convolutions, and the table-only block with experts
+    whose other layers are gated delta rules (attention on a K/V table
+    without positions under an elementwise gate, or LATENT attention
+    under RoPE with a gate a head, full-rank or low-rank delta gates,
+    dense layers among the sparse ones, the group-limited sigmoid router
+    with a choice bias) (module docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -309,10 +340,14 @@ class BlockSpec:
     # added to the sum that `norm_topk_prob` divides the chosen by
     norm_topk_eps: float = 0.0
     # -- GROUP-LIMITED choice: the experts routed over are `n_group`
-    #    consecutive groups, a group's score its largest score, and the
-    #    k are chosen among the `topk_group` best groups (1 group: all)
+    #    consecutive groups, a group's score its largest score
+    #    (`group_score` "max": the softmax router's) or the sum of its
+    #    two largest scores + bias ("top2_sum": the sigmoid router's with
+    #    a choice bias), and the k are chosen among the `topk_group` best
+    #    groups (1 group: all)
     n_group: int = 1
     topk_group: int = 1
+    group_score: str = "max"
     # -- LATENT attention (`kv_lora_rank` > 0): the cache holds one row
     #    [kv_lora_rank latent | qk_rope_head_dim rotated key] a position
     #    a layer for all heads; a query head is qk_nope_head_dim +
@@ -350,22 +385,54 @@ class BlockSpec:
     # -- a DELTA layer's geometry (a gated delta rule): `delta_heads`
     #    heads whose keys AND values are `delta_d_head` columns, the
     #    depthwise convolution's taps over q | k | v, the rank of the
-    #    two low-rank gates (the decay's and the output's), and whether
+    #    two low-rank gates (the decay's and the output's; 0: each ONE
+    #    full matrix [d, H*K], the output gate's without a bias), whether
     #    the write strength is 2 sigmoid (eigenvalues down to -1) or
-    #    sigmoid
+    #    sigmoid, and the log decay's lower bound (f < 0: g = f *
+    #    sigmoid(exp(A_log) * (. + dt_bias)); 0: g = -exp(A_log) *
+    #    softplus(. + dt_bias), unbounded)
     delta_heads: int = 0
     delta_d_head: int = 0
     delta_conv: int = 0
     delta_gate_rank: int = 0
     delta_neg_eigval: bool = False
+    delta_gate_floor: float = 0.0
     # -- attention's output times sigmoid(the layer's normed input @ a
-    #    matrix [d, H * d_head]) before `o`
+    #    matrix [d, H * d_head]) before `o`; `attention_gate_per_head`:
+    #    the matrix is [d, H], one scalar a head (a latent layer's)
     attention_gate: bool = False
+    attention_gate_per_head: bool = False
+    # -- a CLAMP a layer on the SwiGLU's inputs: with a limit L > 0 the
+    #    gate input is min(. , L) and the up input clip(., -L, L), on the
+    #    routed experts (`expert_swiglu_limits`) and on the shared expert
+    #    (`shared_swiglu_limits`); (), or 0 at a layer: no clamp
+    expert_swiglu_limits: tuple = ()
+    shared_swiglu_limits: tuple = ()
 
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
-                     "rope_layers", "indexer_types"):
+                     "rope_layers", "indexer_types", "expert_swiglu_limits",
+                     "shared_swiglu_limits"):
             object.__setattr__(self, name, _frozen(getattr(self, name)))
+        if self.group_score not in ("max", "top2_sum") or (
+                self.group_score != "max" and self.n_group < 2):
+            raise ValueError(
+                f"group_score {self.group_score!r}: 'max' or 'top2_sum', "
+                "the score of a group of a group-limited router (n_group "
+                "> 1)")
+        if min(self.expert_swiglu_limits + self.shared_swiglu_limits
+               + (0,)) < 0:
+            raise ValueError(
+                f"block {self.name!r}: a SwiGLU limit is 0 (no clamp) or "
+                "positive")
+        if self.delta_gate_floor > 0:
+            raise ValueError(
+                f"delta_gate_floor {self.delta_gate_floor}: the log "
+                "decay's lower bound is negative (0: unbounded)")
+        if self.attention_gate_per_head and not self.attention_gate:
+            raise ValueError(
+                f"block {self.name!r}: attention_gate_per_head says how "
+                "attention_gate is applied, and attention_gate is off")
         bad = set(self.mlp_layer_types) - {DENSE, SPARSE}
         if bad:
             raise ValueError(
@@ -537,6 +604,14 @@ class BlockSpec:
                 f"mlp_layer_types, and a layer {layer}")
         return self.mlp_layer_types[layer]
 
+    def swiglu_limits_of(self, layer: int):
+        """(the routed experts', the shared expert's) SwiGLU clamp at
+        layer `layer`; 0.0: none (a description without the lists, or a
+        depth past their end, has none)."""
+        return tuple(float(ls[layer]) if layer < len(ls) else 0.0
+                     for ls in (self.expert_swiglu_limits,
+                                self.shared_swiglu_limits))
+
     def rotated(self, kind: str) -> bool:
         """Whether RoPE turns Q and K on a layer of this kind (a layer
         without attention has neither)."""
@@ -599,9 +674,12 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     bias `delta_dt` [H*K] and `delta_a_log` [H], the write strength's
     `delta_b` [d, H], the output gate's pair `delta_ga` [d, r] and
     `delta_gb` [r, H*K] with its bias `delta_g` [H*K], the head norm's
-    ONE scale `delta_o_norm` [K] and `delta_out` [H*K, d].  With
+    ONE scale `delta_o_norm` [K] and `delta_out` [H*K, d]; at
+    `delta_gate_rank` 0 the two pairs and the gate's bias give way to
+    ONE matrix each, `delta_f` and `delta_gw` [d, H*K].  With
     `attention_gate` an attention layer has a fifth matrix, `attn_gate`
-    [d, H*dh].  A LATENT
+    [d, H*dh] (a latent layer under `attention_gate_per_head`:
+    `attn_head_gate` [d, H]).  A LATENT
     layer has
     seven arrays in place of the four: `q_a` [d, q_lora_rank], its
     norm's scale `q_a_norm`, `q_b` [q_lora_rank, H * (nope + rope)] (a
@@ -609,7 +687,9 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     kv_lora_rank + rope] (the latent, then the one key part),
     `kv_a_norm` [kv_lora_rank], `kv_b` [kv_lora_rank, H * (nope +
     v_head_dim)] (a head's key columns, then its value columns) and
-    `o` [H * v_head_dim, d].  With a lightning indexer an INDEX_FULL
+    `o` [H * v_head_dim, d]; at `q_lora_rank` 0 there is no `q_a` and no
+    `q_a_norm`, and `q_b` is the one query matrix [d, H * (nope +
+    rope)].  With a lightning indexer an INDEX_FULL
     layer has four more: `idx_q` [q_lora_rank, index_n_heads *
     index_head_dim], `idx_k` [d, index_head_dim], its LayerNorm
     `idx_k_norm` (a scale and a shift of index_head_dim) and `idx_w`
@@ -648,23 +728,31 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             f"block {spec.name!r}: passes, post_norm and exit_gate are "
             "built for the dense SwiGLU block alone (ffn 'swiglu')")
     mamba, conv, delta = MAMBA in kinds, CONV in kinds, DELTA in kinds
-    if delta and (dense or mamba or conv or SLIDING in kinds or spec.latent
+    if delta and (dense or mamba or conv or SLIDING in kinds or spec.sparse
                   or FULL not in kinds or spec.qk_norm
-                  or spec.mlp_layer_types
                   or min(spec.delta_heads, spec.delta_d_head,
-                         spec.delta_conv - 1, spec.delta_gate_rank) < 1):
+                         spec.delta_conv - 1) < 1
+                  or spec.delta_gate_rank < 0):
         raise NotImplementedError(
             f"block {spec.name!r}: gated delta-rule layers (delta_heads, "
-            "delta_d_head, delta_conv >= 2, delta_gate_rank) are built "
-            "among full-attention layers on the table, in a block with "
-            "experts in every layer: no Mamba or conv layers, ring, "
-            "latent cache, QK-norm, dense layers or dense SwiGLU block "
-            "beside them")
-    if spec.attention_gate and not delta:
+            "delta_d_head, delta_conv >= 2, delta_gate_rank >= 0) are "
+            "built among full-attention layers on the table (K and V, or "
+            "a latent row) in a block with experts: no Mamba or conv "
+            "layers, ring, lightning indexer, QK-norm or dense SwiGLU "
+            "block beside them")
+    if spec.delta_gate_floor and not delta:
+        raise ValueError(
+            f"block {spec.name!r}: delta_gate_floor bounds the log decay "
+            "of delta-rule layers, and the block has none")
+    if spec.attention_gate and (
+            not delta or spec.attention_gate_per_head != spec.latent):
         raise NotImplementedError(
             f"block {spec.name!r}: attention_gate (the attention's output "
             "times a sigmoid of the layer's input) is built and tested "
-            "on the attention layers of a block with delta-rule layers")
+            "on the attention layers of a block with delta-rule layers: "
+            "elementwise ([d, H * d_head]) on a K/V table, a scalar a "
+            "head (attention_gate_per_head) on a latent table, and "
+            "neither the other way round")
     if conv and (dense or mamba or SLIDING in kinds or spec.latent
                  or FULL not in kinds or spec.conv_width < 2):
         raise NotImplementedError(
@@ -672,13 +760,17 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             ">= 2) are built among full-attention layers on the table, "
             "in a block with experts: no Mamba layers, ring, latent "
             "cache or dense SwiGLU block beside them")
-    if spec.positions != ("none" if mamba or delta else "rope"):
+    # a latent layer's one shared key part IS a rotated one
+    unsigned = mamba or (delta and not spec.latent)
+    if spec.positions != ("none" if unsigned else "rope"):
         raise NotImplementedError(
             f"block {spec.name!r}: positions {spec.positions!r} "
-            f"{'with' if mamba or delta else 'without'} Mamba or "
-            "delta-rule layers; built are RoPE on a block of attention "
-            "layers, and no position signal where Mamba or delta-rule "
-            "layers carry the order")
+            f"{'with' if unsigned else 'without'} Mamba layers, or "
+            "delta-rule layers beside a K/V table; built are RoPE on a "
+            "block of attention layers and on the LATENT layers beside "
+            "delta-rule layers (which carry none themselves), and no "
+            "position signal where Mamba layers, or delta-rule layers "
+            "beside attention on a K/V table, carry the order")
     if mamba and SLIDING in kinds:
         raise NotImplementedError(
             f"block {spec.name!r}: Mamba layers beside sliding-window "
@@ -713,24 +805,33 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             "weights is built and tested on the sigmoid router alone "
             "(the softmax router's probabilities take a factor as they "
             "are, and a choice bias)")
-    if spec.n_group > 1 and (spec.router != "softmax"
-                             or spec.router_bias):
+    if spec.n_group > 1 and (spec.router, spec.router_bias,
+                             spec.group_score) not in (
+            ("softmax", False, "max"), ("sigmoid", True, "top2_sum")):
         raise NotImplementedError(
             f"block {spec.name!r}: group-limited choice is built on the "
-            "softmax router without a choice bias (a group's score is "
-            "its largest probability)")
+            "softmax router without a choice bias (a group's score its "
+            "largest probability: group_score 'max') and on the sigmoid "
+            "router with one (the sum of its two largest scores + bias: "
+            "'top2_sum'); not a softmax router with a bias, a sigmoid "
+            "router without, or the other score under either")
     if spec.latent and (
-            dense or mamba or SLIDING in kinds or spec.qk_norm
+            dense or mamba or conv or SLIDING in kinds or spec.qk_norm
             or spec.n_kv_heads not in (0, n_heads) or spec.d_head
-            or min(spec.q_lora_rank, spec.qk_nope_head_dim,
-                   spec.qk_rope_head_dim, spec.v_head_dim) < 1
-            or spec.qk_rope_head_dim % 2):
+            or min(spec.qk_nope_head_dim, spec.qk_rope_head_dim,
+                   spec.v_head_dim) < 1 or spec.q_lora_rank < 0
+            or spec.qk_rope_head_dim % 2
+            or (spec.q_lora_rank < 1 and (spec.sparse or spec.scale_q_lora
+                                          or spec.sub_blocks > 1))):
         raise NotImplementedError(
             f"block {spec.name!r}: a latent cache (kv_lora_rank) is "
             "built for a block of full-attention layers with experts "
-            "under RoPE, and needs q_lora_rank, qk_nope_head_dim, an "
-            "even qk_rope_head_dim and v_head_dim; no ring, Mamba "
-            "layers, QK-norm, grouped K/V heads or d_head beside it "
+            "under RoPE (delta-rule layers may stand beside them), and "
+            "needs qk_nope_head_dim, an even qk_rope_head_dim and "
+            "v_head_dim; q_lora_rank 0 is a query of ONE matrix, which a "
+            "lightning indexer, scale_q_lora and a double layer are not "
+            "built on (they read the query's latent); no ring, Mamba or "
+            "conv layers, QK-norm, grouped K/V heads or d_head beside it "
             "(every head reads the one latent row)")
     for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
         if not spec.rotated(kind):
@@ -752,11 +853,17 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
 
     def latent_arrays(p):
         dqk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
-        return {"norm1": add(p + "attn_norm.scale_0", d),
-                "q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
-                "q_a_norm": add(p + "q_a_norm.scale_0", spec.q_lora_rank),
-                "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
-                           n_heads * dqk),
+        # without a low-rank step the one matrix stands where `q_b` does
+        # and reads the block's normed input itself
+        query = ({"q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
+                  "q_a_norm": add(p + "q_a_norm.scale_0",
+                                  spec.q_lora_rank),
+                  "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
+                             n_heads * dqk)} if spec.q_lora_rank else
+                 {"q_b": add(p + "q_proj.w_0", d, n_heads * dqk)})
+        gate = ({"attn_head_gate": add(p + "attn_gate.w_0", d, n_heads)}
+                if spec.attention_gate else {})
+        return {"norm1": add(p + "attn_norm.scale_0", d), **query, **gate,
                 "kv_a": add(p + "kv_a_proj.w_0", d,
                             spec.kv_lora_rank + spec.qk_rope_head_dim),
                 "kv_a_norm": add(p + "kv_a_norm.scale_0",
@@ -813,20 +920,25 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         elif kind == DELTA:
             hk = spec.delta_heads * spec.delta_d_head
             r = spec.delta_gate_rank
+            # the decay's and the output gate's maps: a low-rank pair
+            # each (and the gate's bias), or ONE full matrix each
+            gates = ({"delta_fa": add(p + "delta_decay_a.w_0", d, r),
+                      "delta_fb": add(p + "delta_decay_b.w_0", r, hk),
+                      "delta_ga": add(p + "delta_gate_a.w_0", d, r),
+                      "delta_gb": add(p + "delta_gate_b.w_0", r, hk),
+                      "delta_g": add(p + "delta_gate.b_0", hk)} if r else
+                     {"delta_f": add(p + "delta_decay.w_0", d, hk),
+                      "delta_gw": add(p + "delta_gate.w_0", d, hk)})
             lay = {"norm1": add(p + "mixer_norm.scale_0", d),
                    "delta_in": add(p + "delta_in_proj.w_0", d, 3 * hk),
                    "delta_conv": add(p + "delta_conv.w_0", spec.delta_conv,
                                      3 * hk),
-                   "delta_fa": add(p + "delta_decay_a.w_0", d, r),
-                   "delta_fb": add(p + "delta_decay_b.w_0", r, hk),
+                   **gates,
                    "delta_dt": add(p + "delta_dt.b_0", hk),
                    "delta_a_log": add(p + "delta_a_log.w_0",
                                       spec.delta_heads),
                    "delta_b": add(p + "delta_beta.w_0", d,
                                   spec.delta_heads),
-                   "delta_ga": add(p + "delta_gate_a.w_0", d, r),
-                   "delta_gb": add(p + "delta_gate_b.w_0", r, hk),
-                   "delta_g": add(p + "delta_gate.b_0", hk),
                    "delta_o_norm": add(p + "delta_o_norm.scale_0",
                                        spec.delta_d_head),
                    "delta_out": add(p + "delta_out_proj.w_0", hk, d)}
@@ -993,8 +1105,17 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     the experts lie in `n_group` consecutive groups, a group's score is
     its largest probability, the `topk_group` groups of highest score
     are kept (a tie to the lower group), every other expert's score is
-    set to 0 and the k are the largest of what is left.  Float32 at
-    `highest` precision (one
+    set to 0 and the k are the largest of what is left.  Under
+    `group_score: "top2_sum"` (the sigmoid router with its choice bias)
+    everything the CHOICE reads is the biased score c = s + b: a group's
+    score is the sum of its two largest c, the groups kept are the
+    `topk_group` of highest score, and the k are the largest c among
+    the kept groups' experts (an expert of another group is never
+    chosen, whatever its c); the weights are the scores s of the chosen,
+    as without groups.  The groups' scores and the mask lie under the
+    named scope `moe_group_choice`, inside whatever scope the caller is
+    in (`moe_ffn`'s `moe_router`).
+    Float32 at `highest` precision (one
     bf16 pass moves a probability by 1e-3 of itself and swaps the k-th
     and k+1-th expert wherever they lie that close); the weights are
     the probabilities as they are, renormalised only under
@@ -1012,12 +1133,21 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     else:
         probs = jax.nn.softmax(logits, axis=-1)             # [T, E]
     if spec.n_group > 1:
-        grouped = probs.reshape(probs.shape[:-1] + (spec.n_group, -1))
-        _, kept = jax.lax.top_k(grouped.max(-1), spec.topk_group)
-        keep = (kept[..., None] == jnp.arange(spec.n_group)).any(-2)
-        top_w, top_e = jax.lax.top_k(
-            jnp.where(keep[..., None], grouped, 0.0).reshape(probs.shape),
-            spec.experts_per_token)
+        # by the biased scores and the sum of a group's two best, or by
+        # the scores and a group's best; what is left out can never win
+        top2 = spec.group_score == "top2_sum"
+        with jax.named_scope("moe_group_choice"):
+            by = probs + b_router.astype(jnp.float32) if top2 else probs
+            grouped = by.reshape(probs.shape[:-1] + (spec.n_group, -1))
+            _, kept = jax.lax.top_k(
+                jax.lax.top_k(grouped, 2)[0].sum(-1) if top2
+                else grouped.max(-1), spec.topk_group)
+            keep = (kept[..., None] == jnp.arange(spec.n_group)).any(-2)
+            left = jnp.where(keep[..., None], grouped,
+                             -jnp.inf if top2 else 0.0).reshape(probs.shape)
+        top_w, top_e = jax.lax.top_k(left, spec.experts_per_token)
+        if top2:
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
     elif b_router is None:
         top_w, top_e = jax.lax.top_k(probs, spec.experts_per_token)
     else:
@@ -1072,7 +1202,7 @@ def select_rows(scores, valid, k: int):
 
 
 def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
-            scope=None, experts=None, b_router=None):
+            scope=None, experts=None, b_router=None, limit=0.0):
     """Dropless top-k-of-E SwiGLU expert layer over tokens m [T, D]
     (float32) -> ([T, D] float32, experts hit: int32 scalar, routing:
     `route`'s (weights, experts)).
@@ -1106,7 +1236,11 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     gated product in one call, down in a second, over work items it
     plans from the group sizes under `moe_dispatch`), or None: three
     `jax.lax.ragged_dot`s, the one fallback.  `b_router`: the
-    router's choice bias, where the description has one (`route`)."""
+    router's choice bias, where the description has one (`route`).
+    `limit` L > 0 (the layer's entry of `expert_swiglu_limits`): every
+    expert's gate input is min(., L) and its up input clip(., -L, L)
+    (`clamped`); on the `ragged_dot`s alone: the kernel's gated product
+    is inside its call, so the caller hands no kernel with a limit."""
     import contextlib
 
     import jax
@@ -1136,6 +1270,7 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     with scope("moe_experts"):
         f32 = jnp.float32
         if experts is not None:
+            assert not limit, "the grouped matmul kernel takes no clamp"
             act = experts.gate_up(rows, w_gate, w_up, plan)
             out = experts.down(act, w_down, plan)
         else:
@@ -1143,6 +1278,8 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
                                       preferred_element_type=f32)
             up = jax.lax.ragged_dot(rows, w_up, sizes,
                                     preferred_element_type=f32)
+            if limit:
+                gate, up = clamped(gate, up, limit)
             act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
             out = jax.lax.ragged_dot(act, w_down, sizes,
                                      preferred_element_type=f32)
@@ -1161,11 +1298,21 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     return y, hit, (top_w, top_e)
 
 
-def swiglu(x, w_gate, w_up, w_down):
+def clamped(gate, up, limit: float):
+    """A SwiGLU's two inputs under a limit L > 0: the gate input
+    min(., L) (SiLU is bounded below by itself), the up input
+    clip(., -L, L)."""
+    import jax.numpy as jnp
+
+    return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
+def swiglu(x, w_gate, w_up, w_down, limit=0.0):
     """One SwiGLU FFN every row takes (a shared expert): x [T, D]
     float32 -> [T, D] float32, the matmuls in the weights' dtype with
     float32 accumulation and the gated product rounded to it, as an
-    expert of `moe_ffn` rounds."""
+    expert of `moe_ffn` rounds.  `limit` L > 0: the two inputs
+    `clamped`."""
     import jax
     import jax.numpy as jnp
 
@@ -1173,6 +1320,8 @@ def swiglu(x, w_gate, w_up, w_down):
     rows = x.astype(w_gate.dtype)
     gate = jnp.dot(rows, w_gate, preferred_element_type=f32)
     up = jnp.dot(rows, w_up, preferred_element_type=f32)
+    if limit:
+        gate, up = clamped(gate, up, limit)
     act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
     return jnp.dot(act, w_down, preferred_element_type=f32)
 
@@ -1331,10 +1480,13 @@ def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
       q, k, v = silu(sum_j w_conv[j] * (tail, u @ W_in)[j])   [H, K] each
       q = q / |q| / sqrt(K);  k = k / |k|          (L2 a head, eps 1e-6)
       g = -exp(A_log) * softplus((u @ W_fa) @ W_fb + dt_bias)    [H, K]
+          (under `delta_gate_floor` f: f * sigmoid(exp(A_log) * (. +
+          dt_bias)); at `delta_gate_rank` 0 the pair is ONE matrix W_f)
       beta = sigmoid(u @ W_b) [H]          (times 2 under `delta_neg_eigval`)
       S' = exp(g)[:, :, None] * S          a decay a key CHANNEL
       S = S' + beta * k (v - k^T S')^T     the rank-one correction
       o = S^T q;  gate = sigmoid((u @ W_ga) @ W_gb + b_g)
+          (at `delta_gate_rank` 0: sigmoid(u @ W_g), no bias)
       out = (rmsnorm_head(o) * w * gate) @ W_out
 
     The step IS the prefill, as a Mamba layer's.  `fresh` and `live` are
@@ -1377,11 +1529,21 @@ def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
         q, k, v = (qkv[:, i * hk:(i + 1) * hk].reshape(s_n, h_n, k_n)
                    for i in range(3))
         q, k = unit(q) * (k_n ** -0.5), unit(k)
+    # the gates' maps: a low-rank pair each, or one full matrix each
+    decay_w, gate_w = ((("delta_fa", "delta_fb"), ("delta_ga", "delta_gb"))
+                       if "delta_fa" in p else (("delta_f",), ("delta_gw",)))
     with scope("delta_gates"):
-        g = -jnp.exp(p["delta_a_log"].astype(f32))[None, :, None] * (
-            jax.nn.softplus(proj(u, "delta_fa", "delta_fb")
-                            + p["delta_dt"].astype(f32))
-        ).reshape(s_n, h_n, k_n)
+        if spec.delta_gate_floor:
+            # bounded: a channel's decay a step lies in (e^floor, 1)
+            g = spec.delta_gate_floor * jax.nn.sigmoid(
+                jnp.exp(p["delta_a_log"].astype(f32))[None, :, None] * (
+                    proj(u, *decay_w) + p["delta_dt"].astype(f32)
+                ).reshape(s_n, h_n, k_n))
+        else:
+            g = -jnp.exp(p["delta_a_log"].astype(f32))[None, :, None] * (
+                jax.nn.softplus(proj(u, *decay_w)
+                                + p["delta_dt"].astype(f32))
+            ).reshape(s_n, h_n, k_n)
         beta = jax.nn.sigmoid(proj(u, "delta_b"))               # [S, H]
         if spec.delta_neg_eigval:
             beta = 2.0 * beta
@@ -1389,8 +1551,10 @@ def delta_rule_step(spec: BlockSpec, u, state, tail, fresh, live, p,
         state, o = (delta_rule if kernel is None else kernel.rule)(
             state, q, k, v, g, beta, fresh, live)
     with scope("delta_gate_norm"):
-        gate = jax.nn.sigmoid(proj(u, "delta_ga", "delta_gb")
-                              + p["delta_g"].astype(f32))
+        gate = proj(u, *gate_w)
+        if "delta_g" in p:
+            gate = gate + p["delta_g"].astype(f32)
+        gate = jax.nn.sigmoid(gate)
         o = norm(spec, o, p["delta_o_norm"].astype(f32))
         y = o.reshape(s_n, hk) * gate
     with scope("delta_out_proj"):

@@ -827,6 +827,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     `lm_block.delta_rule`'s `jax.numpy` lines elsewhere; chosen when a
     step is traced and reported as `decoder.delta_kernel`.
 
+    DELTA layers BESIDE A LATENT TABLE (lm_block's twelfth description,
+    docs/serving.md "A lane's state beside a latent table"): the
+    attention layers of such a block are latent (`kv_lora_rank` > 0), so
+    `pool_k` is (the latent pool [attention layers, blocks, block_size,
+    row], the delta layers' states) and `pool_v` ((), their tails): the
+    latent block's empty tuple rides where the V pool did.  RoPE turns
+    the latent layers' rotated columns alone; a latent layer's heads
+    each take a sigmoid scalar of the layer's input before `o`
+    (`BlockSpec.attention_gate_per_head`, scope `attention_head_gate`);
+    `bytes_per_block` counts the one plane an attention layer,
+    `tick_counts` gives `latent_rows` AND `delta_layers` / `state_bytes`
+    on one tick, a snapshot knows nothing of the table, and
+    `decoder.refuses["draft_model"]` holds the lane's reason and the
+    latent table's.
+
     Every block whose lanes keep something (Mamba, conv or delta layers)
     is served under a PREFIX CACHE through SNAPSHOTS: the decoder owns
     `init_snapshots`, `snapshot_save` and `snapshot_restore` (see
@@ -1209,9 +1224,13 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def _latent_down(g, lay, x):
         """The block's normed input and the normed query latent (times
         its constant where the description has one, `scale_q_lora`):
-        what `_latent_qkv` and a lightning indexer both read."""
+        what `_latent_qkv` and a lightning indexer both read (the input
+        twice where the query has no low-rank step)."""
         with scope("latent_q"):
             h = _norm(g, x, lay["norm1"])
+            if "q_a" not in lay:
+                # a query of ONE matrix (`q_lora_rank` 0) reads h itself
+                return h, h
             c_q = _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"])
             if spec.scale_q_lora:
                 c_q = c_q * math.sqrt(d_model / spec.q_lora_rank)
@@ -1332,6 +1351,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         with scope("loop_norm"):
             return _residual(x, _norm(g, y, pair))
 
+    # a clamp a layer on the SwiGLU inputs of the routed experts and of
+    # the shared expert, by the identity of the layer's entry of
+    # `layout.layers` (a description without the lists: no entry, 0.0)
+    swiglu_limits = {id(lay): spec.swiglu_limits_of(i // spec.sub_blocks)
+                     for i, lay in enumerate(layout.layers)
+                     if spec.expert_swiglu_limits
+                     or spec.shared_swiglu_limits}
+
     def _ffn(g, lay, x, hits):
         """x + FFN(norm(x)); a block with experts appends (its count
         of distinct experts hit, the router's input, the weights and
@@ -1366,6 +1393,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         distinct experts hit, h2, the weights and the experts the
         router chose) to `hits`."""
         w_gate = g[lay["gate"][0]]
+        limit, shared_limit = swiglu_limits.get(id(lay), (0.0, 0.0))
         # the kernel's own module decides from the rows of this trace,
         # the widths, the weights' dtype and the platform whether the
         # grouped matmuls are its Pallas kernel or `ragged_dot`
@@ -1373,20 +1401,25 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             rows=h2.shape[0] * spec.experts_per_token, d_model=d_model,
             d_ff=w_gate.shape[-1], n_experts=spec.held[1],
             dtype=w_gate.dtype, platform=platform)
+        if limit:
+            # the kernel's gated product is inside its call: a clamped
+            # layer runs the `ragged_dot`s
+            experts, refused = None, "swiglu_limit"
         decoder.expert_kernel = (experts.name if experts is not None
                                  else f"xla:{refused}")
         y, hit, routed = lm_block.moe_ffn(
             spec, h2, g[lay["router"][0]], w_gate, g[lay["up"][0]],
             g[lay["down"][0]], scope=scope, experts=experts,
             b_router=(g[lay["router_bias"][0]] if "router_bias" in lay
-                      else None))
+                      else None), limit=limit)
         hits.append((hit, h2) + routed)
         if spec.shared_d_inner:
             # the shared expert: every token, whole, weight 1
             with scope("shared_expert"):
                 y = y + lm_block.swiglu(h2, *(
                     g[lay[n][0]] for n in ("shared_gate", "shared_up",
-                                           "shared_down")))
+                                           "shared_down")),
+                    limit=shared_limit)
         return y
 
     def _shortcut_ffn(g, lay, x, hits, held):
@@ -1811,6 +1844,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         selected=chosen is not None)[:, 0]
                 if latent:
                     ctx_av = _latent_values(g, lay, ctx_av)
+                if "attn_head_gate" in lay:
+                    # a sigmoid SCALAR a head, on the heads' values
+                    with scope("attention_head_gate"):
+                        gate = jax.nn.sigmoid(_fc(g, h, lay["attn_head_gate"]))
+                        ctx_av = (ctx_av.reshape(s_n, n_heads, d_v)
+                                  * gate[..., None]).reshape(ctx_av.shape)
                 if "attn_gate" in lay:
                     with scope("attention_gate"):
                         ctx_av = ctx_av * jax.nn.sigmoid(_fc(
@@ -2064,7 +2103,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         states (the empty tuple beside K, a tail a conv layer beside
         V); `lanes` is the step's lane count and read by no other
         block.  A latent block's is (the one pool, ()), with a
-        lightning indexer (the latent pool, the index-key pool)."""
+        lightning indexer (the latent pool, the index-key pool), with
+        delta-rule layers ((the latent pool, the states), ((), the
+        tails))."""
         def zeros(layers, blocks, width=d_kv):
             shape = (layers, int(blocks), bs, width)
             if kv_dtype == "int8":
@@ -2087,9 +2128,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                          else jax.device_put(z, device))
 
         if stateful:
+            # (a latent table beside the lanes is ONE plane an attention
+            # layer: nothing stands beside V but the tails)
             return ((zeros(n_full, num_blocks),
                      lane_state(state_shape, n_state)),
-                    (zeros(n_full, num_blocks),
+                    (() if latent else zeros(n_full, num_blocks),
                      lane_state(tail_shape, n_lane)))
 
         if sparse:
@@ -2168,9 +2211,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
              "conv_in": "conv_in_proj", "conv_out": "conv_out_proj",
              "delta_in": "delta_in_proj", "delta_out": "delta_out_proj",
              "delta_fa": "delta_gates", "delta_fb": "delta_gates",
+             "delta_f": "delta_gates",
              "delta_b": "delta_gates", "delta_ga": "delta_gate_norm",
-             "delta_gb": "delta_gate_norm",
-             "attn_gate": "attention_gate"}
+             "delta_gb": "delta_gate_norm", "delta_gw": "delta_gate_norm",
+             "attn_gate": "attention_gate",
+             "attn_head_gate": "attention_head_gate"}
     if spec.ffn == "swiglu":
         parts.update(gate="mlp", up="mlp", down="mlp")
     weights_of = [(lay[key], part) for lay in layout.layers
@@ -2325,13 +2370,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["moe_layers"] = moe_layers
         return counts
 
-    # where a block has two kinds of state, the ring's word stands
+    # where a block has two kinds of state, the ring's word stands; a
+    # lane's state beside a latent table is refused for both reasons
     refuses = {}
     for kind, has in (("latent", latent), ("sparse", sparse),
                       ("loop", looped), ("state", stateful),
                       ("ring", ringed)):
         if has:
-            refuses.update(_REFUSALS[kind])
+            refuses.update({
+                what: (f"{refuses[what]}; and {why}"
+                       if kind == "state" and latent and what in refuses
+                       else why)
+                for what, why in _REFUSALS[kind].items()})
 
     kernels = {"paged_attention_decode":
                f"xla:{_refused}" if _attend is None

@@ -21,7 +21,8 @@ geometry; a lightning indexer's score kernel the same at its plane's,
 and both kernels' Mosaic modules, source locations apart, are the ones
 pinned here (what the chip's readings were taken of);
 a gated delta rule's kernel lowers and compiles for a v5e at the
-Solar cell's states with its output state ALIASING its input;
+Solar cell's and the Ling cell's states with its output state ALIASING
+its input;
 the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
 kernel once a layer; a language model's AMP training step holds no
@@ -80,11 +81,15 @@ POOLS = {
     # K-EXAONE's row (8 K/V heads of 128) on the ONE attention layer of
     # four, over docqa64's table of 432 pages a lane (PR 59)
     "solar-open2-docqa64": (64, 64, 1024, 16, 432, 1, "bf16"),
+    # the latent row under 32 heads at 128 lanes, ONE plane: the latent
+    # layer of six beside five delta-rule layers (PR 62)
+    "ling-3.0-flash-agent128-latent": (128, 32, 640, 16, 256, 1, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512,
            "longcat-flash-agent64-latent": 512,
-           "glm-5.2-docqa64-selected": 512}
+           "glm-5.2-docqa64-selected": 512,
+           "ling-3.0-flash-agent128-latent": 512}
 # rows a slot's mask selects (the indexer's `index_topk`)
 SELECTED = {"glm-5.2-docqa64-selected": 2048}
 # pages a chunk over each pool: what the waits' static list and the
@@ -96,7 +101,7 @@ CHUNK_PAGES = {
     "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
     "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
     "longcat-flash-agent64-latent": 51, "glm-5.2-docqa64-selected": 51,
-    "solar-open2-docqa64": 32,
+    "solar-open2-docqa64": 32, "ling-3.0-flash-agent128-latent": 51,
 }
 
 
@@ -317,6 +322,10 @@ MOSAIC_SHA256 = {
         "5ac1fee06575b16bb5a6303bbf77ff61223aad4f8372f9e8b9c6fa26f1b35d85",
     "kv-chunks-of-51":
         "1a5c31b009f39440d5471a184091ea20d39fd12a114fc34a09a66fa0154e41bb",
+    # taken at the tree that added the geometry (PR 62: no kernel file
+    # moved; the cell's readings are of this module)
+    "ling-3.0-flash-agent128-latent":
+        "bb9fc52c41318c7ff2a448c5c15f71e9c125ce8e6a95f809ef0a5545158f2aed",
     "longcat-flash-agent64-latent":
         "24bac2245d0e8047d0c58ac2e2da805f371feae6495f00725fd417fb5b7650e7",
     "mellum2-agent96-ring":
@@ -372,6 +381,8 @@ def test_index_scores_compile_for_a_v5e(name, one_v5e):
 # heads that is no power of two
 DELTA_STATES = {
     "solar-open2-docqa64": (64, 64, 128),
+    # `ling-3.0-flash-serve-agent128`: twice the lanes, half the heads
+    "ling-3.0-flash-agent128": (128, 32, 128),
     "heads-of-24": (3, 24, 128),
 }
 
