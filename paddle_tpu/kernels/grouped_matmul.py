@@ -1,7 +1,7 @@
 """The served expert layer's grouped matmuls as a Pallas TPU kernel.
 
-`lm_block.moe_ffn` sorts a tick's assignments (rows x experts per
-token) by expert and needs, for every expert that got rows, its rows
+`lm_block.moe_ffn` puts a tick's assignments (rows x experts per
+token) in expert order and needs, for every expert that got rows, its rows
 times its gate, up and down matrices.  `jax.lax.ragged_dot` says that,
 but the TPU compiler runs it as a DENSE product of all rows with all
 experts and masks seven eighths of it away (PERF.md section 5): the

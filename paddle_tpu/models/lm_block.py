@@ -1087,7 +1087,74 @@ MOE_COMPILER_SCOPES = {"ragged-dot-none": "paged_decoder/moe_experts",
                        "ragged-dot-metadata": "paged_decoder/moe_dispatch"}
 
 
-def route(spec: BlockSpec, m, w_router, b_router=None):
+def _largest(x, k: int):
+    """`jax.lax.top_k(x, k)` without a sort: x [..., n] -> (values
+    [..., k], indices [..., k] int32), largest first, a tie to the
+    LOWER index, by k passes over the row (the TPU compiler makes a
+    FULL sort of the row of a `top_k`, whatever k is).  A pass: of the
+    positions no earlier pass took the largest value, and of those that
+    hold it the lowest index.  Taken is a MASK, not a value written
+    over the score: a row may hold `-inf` itself (`route`'s `left`
+    outside the kept groups), and no position is chosen twice."""
+    import jax
+    import jax.numpy as jnp
+
+    n = x.shape[-1]
+    at = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    taken = jnp.zeros(x.shape, bool)
+    values, indices = [], []
+    for _ in range(k):
+        best = jnp.max(jnp.where(taken, -jnp.inf, x), -1, keepdims=True)
+        first = jnp.min(jnp.where((x == best) & ~taken, at, n), -1,
+                        keepdims=True)
+        taken = taken | (at == first)
+        values.append(best)
+        indices.append(first)
+    return jnp.concatenate(values, -1), jnp.concatenate(indices, -1)
+
+
+def _chosen(spec: BlockSpec, probs, b_router=None, choice=None):
+    """`route`'s choice from the scores on: probs [T, E] float32 ->
+    (the chosen experts' scores [T, k], the experts [T, k] int32), the
+    group limit and the choice bias as `route` tells them.  Nothing
+    here rounds: the same scores give the same experts in the same
+    order with the same weights whichever of `_largest`'s passes and
+    `choice`'s one call makes the choice."""
+    import jax
+    import jax.numpy as jnp
+
+    top2 = spec.group_score == "top2_sum"
+    if choice is not None:
+        # what the choice reads, and the weights where they are not it
+        biased = b_router is not None and (spec.n_group == 1 or top2)
+        return choice.choose(
+            probs + b_router.astype(jnp.float32) if biased else probs,
+            probs if biased else None)
+    if spec.n_group > 1:
+        # by the biased scores and the sum of a group's two best, or by
+        # the scores and a group's best; what is left out can never win
+        with jax.named_scope("moe_group_choice"):
+            by = probs + b_router.astype(jnp.float32) if top2 else probs
+            grouped = by.reshape(probs.shape[:-1] + (spec.n_group, -1))
+            _, kept = _largest(
+                _largest(grouped, 2)[0].sum(-1) if top2
+                else grouped.max(-1), spec.topk_group)
+            keep = (kept[..., None] == jnp.arange(spec.n_group)).any(-2)
+            left = jnp.where(keep[..., None], grouped,
+                             -jnp.inf if top2 else 0.0).reshape(probs.shape)
+        top_w, top_e = _largest(left, spec.experts_per_token)
+        if top2:
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+    elif b_router is None:
+        top_w, top_e = _largest(probs, spec.experts_per_token)
+    else:
+        _, top_e = _largest(probs + b_router.astype(jnp.float32),
+                            spec.experts_per_token)
+        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+    return top_w, top_e
+
+
+def route(spec: BlockSpec, m, w_router, b_router=None, choice=None):
     """The router: tokens m [T, D] (float32) -> (weights [T, k]
     float32, experts [T, k] int32), the k largest of the softmax over
     ALL experts, largest first.  Under `router: "sigmoid"` the scores
@@ -1114,7 +1181,12 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
     chosen, whatever its c); the weights are the scores s of the chosen,
     as without groups.  The groups' scores and the mask lie under the
     named scope `moe_group_choice`, inside whatever scope the caller is
-    in (`moe_ffn`'s `moe_router`).
+    in (`moe_ffn`'s `moe_router`).  Every choice is `_largest`'s (k
+    passes of a maximum, no sort), or where `choice` is given
+    (`kernels.router_choice.select_router_choice`'s kernel for these
+    shapes) ONE Pallas call from the scores on: the group limit and the
+    k passes over scores it holds in VMEM, the same experts in the same
+    order with the same weights.
     Float32 at `highest` precision (one
     bf16 pass moves a probability by 1e-3 of itself and swaps the k-th
     and k+1-th expert wherever they lie that close); the weights are
@@ -1132,28 +1204,7 @@ def route(spec: BlockSpec, m, w_router, b_router=None):
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jax.nn.softmax(logits, axis=-1)             # [T, E]
-    if spec.n_group > 1:
-        # by the biased scores and the sum of a group's two best, or by
-        # the scores and a group's best; what is left out can never win
-        top2 = spec.group_score == "top2_sum"
-        with jax.named_scope("moe_group_choice"):
-            by = probs + b_router.astype(jnp.float32) if top2 else probs
-            grouped = by.reshape(probs.shape[:-1] + (spec.n_group, -1))
-            _, kept = jax.lax.top_k(
-                jax.lax.top_k(grouped, 2)[0].sum(-1) if top2
-                else grouped.max(-1), spec.topk_group)
-            keep = (kept[..., None] == jnp.arange(spec.n_group)).any(-2)
-            left = jnp.where(keep[..., None], grouped,
-                             -jnp.inf if top2 else 0.0).reshape(probs.shape)
-        top_w, top_e = jax.lax.top_k(left, spec.experts_per_token)
-        if top2:
-            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
-    elif b_router is None:
-        top_w, top_e = jax.lax.top_k(probs, spec.experts_per_token)
-    else:
-        _, top_e = jax.lax.top_k(probs + b_router.astype(jnp.float32),
-                                 spec.experts_per_token)
-        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+    top_w, top_e = _chosen(spec, probs, b_router, choice)
     if spec.norm_topk_prob:
         total = top_w.sum(-1, keepdims=True)
         # Python's 0.0: no operation, the other blocks' steps as they were
@@ -1201,14 +1252,42 @@ def select_rows(scores, valid, k: int):
                             <= room))
 
 
+def _by_expert(flat_e, e_n: int):
+    """A tick's assignments in expert order, by counting: flat_e [n]
+    int32, each assignment's expert in [0, e_n] (`e_n`: an absent one,
+    in no group) -> (order [n], place [n], sizes [e_n], all int32).
+    `place[i]` is where assignment i stands once the assignments are in
+    expert order, an expert's in the order they came: the number of
+    assignments whose (expert, index) lies below its own, which is the
+    inverse permutation of `jnp.argsort(flat_e, stable=True)`.  `order`
+    is that `argsort` itself: the assignment whose place is p, found by
+    comparing again (n x n comparisons twice, n a tick's rows x k: on a
+    TPU a sort of n keys and a scatter each take longer).  `sizes[e]`
+    counts expert e's assignments (an absent expert's stand past the
+    last group and are counted nowhere)."""
+    import jax.numpy as jnp
+
+    n = flat_e.shape[0]
+    index = jnp.arange(n, dtype=jnp.int32)
+    key = flat_e.astype(jnp.int32) * n + index           # all distinct
+    place = jnp.sum(key[None, :] < key[:, None], axis=-1, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(place[None, :] == index[:, None],
+                              index[None, :], 0), axis=-1, dtype=jnp.int32)
+    sizes = jnp.sum(flat_e[:, None] == jnp.arange(e_n), axis=0,
+                    dtype=jnp.int32)
+    return order, place, sizes
+
+
 def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
-            scope=None, experts=None, b_router=None, limit=0.0):
+            scope=None, experts=None, b_router=None, limit=0.0,
+            choice=None):
     """Dropless top-k-of-E SwiGLU expert layer over tokens m [T, D]
     (float32) -> ([T, D] float32, experts hit: int32 scalar, routing:
     `route`'s (weights, experts)).
 
-    Every one of the T*k assignments is computed: they are sorted by
-    expert and run as grouped matmuls (one group an expert), so an
+    Every one of the T*k assignments is computed: they are put in
+    expert order (by counting, `_by_expert`: no sort) and run as
+    grouped matmuls (one group an expert), so an
     expert's matrices are read once however many rows it has and NO
     capacity bounds a group: what one token gets never depends on
     where the others went, which is what keeps a continuously batched
@@ -1219,7 +1298,7 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     Where the description HOLDS a share of the experts (`spec.held`:
     `w_gate`, `w_up`, `w_down` are then those experts' matrices alone)
     the router still routes over all of them and the result is the
-    part the held ones give: an assignment to an absent expert sorts
+    part the held ones give: an assignment to an absent expert stands
     past the last group, is in no group and adds nothing, and its
     weight is NOT shared out among the others (the chip that holds the
     expert adds that part).  `hit` counts held experts.
@@ -1228,7 +1307,7 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     past `n_experts`) is an expert with no matrices that returns its
     input: it adds its weight times the token's own row of `m`, under
     the scope `moe_zero`, and sends NO row to the grouped matmul (like
-    an absent expert's it sorts past the last group).  Every chip of an
+    an absent expert's it stands past the last group).  Every chip of an
     expert-parallel layer can add that part for its own tokens.
 
     `experts` is what `kernels.grouped_matmul.select_grouped_matmul`
@@ -1240,7 +1319,8 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     `limit` L > 0 (the layer's entry of `expert_swiglu_limits`): every
     expert's gate input is min(., L) and its up input clip(., -L, L)
     (`clamped`); on the `ragged_dot`s alone: the kernel's gated product
-    is inside its call, so the caller hands no kernel with a limit."""
+    is inside its call, so the caller hands no kernel with a limit.
+    `choice`: `route`'s (the router's choice as one Pallas call)."""
     import contextlib
 
     import jax
@@ -1251,16 +1331,15 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
     first, e_n = spec.held
     share = spec.has_unheld
     with scope("moe_router"):
-        top_w, top_e = route(spec, m, w_router, b_router)   # [T, k]
+        top_w, top_e = route(spec, m, w_router, b_router, choice)
     with scope("moe_dispatch"):
         flat_e = top_e.reshape(t_n * k_n)
         if share:
             # the held experts count from 0; an absent one is e_n,
-            # which sorts last and falls off the end of `sizes`
+            # which stands last and falls off the end of `sizes`
             here = (top_e >= first) & (top_e < first + e_n)
             flat_e = jnp.where(here.reshape(-1), flat_e - first, e_n)
-        order = jnp.argsort(flat_e, stable=True)            # by expert
-        sizes = jnp.zeros(e_n, jnp.int32).at[flat_e].add(1, mode="drop")
+        order, back, sizes = _by_expert(flat_e, e_n)
         rows = m[order // k_n].astype(w_gate.dtype)         # [T*k, D]
         hit = jnp.sum(sizes > 0).astype(jnp.int32)
         plan = None if experts is None else experts.plan(sizes)
@@ -1284,8 +1363,6 @@ def moe_ffn(spec: BlockSpec, m, w_router, w_gate, w_up, w_down,
             out = jax.lax.ragged_dot(act, w_down, sizes,
                                      preferred_element_type=f32)
     with scope("moe_combine"):
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t_n * k_n, dtype=order.dtype))
         per_tok = out[back].reshape(t_n, k_n, -1)
         if share:
             # rows past the last group are whatever the kernel left

@@ -552,8 +552,8 @@ class PagedDecoder:
     jitted steps of one block description (its docstring has their
     arguments), what they keep on the device, and what the builder
     alone knows of them (docs/serving.md "What a decoder tells the
-    server").  Not frozen: a step's first trace sets `expert_kernel`
-    and `delta_kernel`."""
+    server").  Not frozen: a step's first trace sets `expert_kernel`,
+    `router_choice` and `delta_kernel`."""
 
     step: Callable
     step_window: Callable
@@ -657,6 +657,10 @@ class PagedDecoder:
     # None until a step is traced (the weights' dtype and the rows are
     # the step's arguments) and for a block without experts
     expert_kernel: Optional[str] = None
+    # what the router of that layer makes its choice with: the Pallas
+    # call's name (`kernels/router_choice.py`), or "passes:<reason>"
+    # where `lm_block._largest`'s fusions do; never a sort
+    router_choice: Optional[str] = None
     # the same of the delta-rule layers' recurrence: the Pallas kernel's
     # name (`kernels/delta_rule.py`), or "xla:<reason>" where
     # `lm_block.delta_rule`'s lines run; None until a step is traced
@@ -951,6 +955,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     # selection")
     from ..kernels import delta_rule as _delta_rule
     from ..kernels import grouped_matmul as _grouped_matmul
+    from ..kernels import router_choice as _router_choice
     from ..kernels import paged_attention as _paged_attention
     from ..kernels import paged_index_scores as _paged_index_scores
 
@@ -1407,11 +1412,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             experts, refused = None, "swiglu_limit"
         decoder.expert_kernel = (experts.name if experts is not None
                                  else f"xla:{refused}")
+        # and the router's choice, from the rows and the router's shape
+        choice, refused = _router_choice.select_router_choice(
+            rows=h2.shape[0], width=spec.n_experts + spec.zero_experts,
+            k=spec.experts_per_token, n_group=spec.n_group,
+            topk_group=spec.topk_group, group_score=spec.group_score,
+            platform=platform)
+        decoder.router_choice = (choice.name if choice is not None
+                                 else f"passes:{refused}")
         y, hit, routed = lm_block.moe_ffn(
             spec, h2, g[lay["router"][0]], w_gate, g[lay["up"][0]],
             g[lay["down"][0]], scope=scope, experts=experts,
             b_router=(g[lay["router_bias"][0]] if "router_bias" in lay
-                      else None), limit=limit)
+                      else None), limit=limit, choice=choice)
         hits.append((hit, h2) + routed)
         if spec.shared_d_inner:
             # the shared expert: every token, whole, weight 1

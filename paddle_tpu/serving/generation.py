@@ -864,6 +864,9 @@ class GenerationServer:
                # grouped matmul's name or "xla:<reason>"; None for a
                # block without experts
                "expert_kernel": self._decoder.expert_kernel,
+               # and of its router's choice: the Pallas call's name or
+               # "passes:<reason>"
+               "router_choice": self._decoder.router_choice,
                # the same of a delta-rule layer's recurrence
                "delta_kernel": self._decoder.delta_kernel,
                "kv_bytes_resident": (self._cache.used_blocks

@@ -380,6 +380,24 @@ def test_kernel_is_traced_once_a_process_and_lowered_once_a_program(
     assert _mosaic_modules(routing) == _mosaic_modules(text)
 
 
+@pytest.mark.parametrize("n_experts,choice,calls", [
+    (8, "pallas_router_choice", 3),
+    # six experts are no multiple of a sublane tile
+    (6, "passes:sublane_misaligned", 2)])
+def test_a_step_for_a_tpu_routes_without_a_sort(n_experts, choice, calls):
+    """What says the mechanism engages: `decoder.router_choice` names
+    the Pallas call (one function more in the lowered step, however
+    many layers call it) or the passes and why, and the step's text
+    holds neither a `top_k` nor a sort either way."""
+    dec = _routing_decoder(3, d_model=256, d_inner=128, n_experts=n_experts)
+    assert dec.router_choice is None        # chosen when a step is traced
+    text = dec.step.trace(*_step_args(dec, slots=16)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert dec.router_choice == choice
+    assert text.count("tpu_custom_call") == calls
+    assert "top_k" not in text and "stablehlo.sort" not in text
+
+
 @pytest.mark.parametrize("n_layers", [2, 8])
 def test_building_a_decoder_compiles_and_runs_nothing(n_layers):
     """What the kernel needs (group offsets, work items) is computed

@@ -23,6 +23,10 @@ pinned here (what the chip's readings were taken of);
 a gated delta rule's kernel lowers and compiles for a v5e at the
 Solar cell's and the Ling cell's states with its output state ALIASING
 its input;
+a router's choice kernel compiles for a v5e at the ten expert cells'
+rows and routers, and an expert layer from the router's matmul through
+`moe_combine`, compiled there at Ling's and LongCat's shapes with the
+kernel and with `_largest`'s passes, holds no `sort`;
 the flash kernels lower forward and backward, compile for a
 v5e at the training cell's shape, and a training step holds the forward
 kernel once a layer; a language model's AMP training step holds no
@@ -436,6 +440,80 @@ def test_delta_rule_compiles_for_a_v5e_in_place(name, one_v5e):
         delta_rule._VMEM_BLOCK_BUDGET) < delta_rule._VMEM_LIMIT_BYTES
     assert h_n % kern.heads_block == 0
     assert kern.grid == (s_n, h_n // kern.heads_block)
+
+
+def _routed_layer(cell, kernel, sharding):
+    """(an expert layer of the cell as its step traces it on a TPU:
+    `lm_block.moe_ffn` with the grouped matmul and, under `kernel`, the
+    choice kernel; its arguments as shapes)."""
+    from test_moe_routing import cell_router, choice_kernel
+
+    from paddle_tpu.kernels import grouped_matmul
+    from paddle_tpu.models import lm_block
+
+    spec, t_n, d = cell_router(cell)
+    width, k_n = spec.n_experts + spec.zero_experts, spec.experts_per_token
+    (_, e_n), d_ff = spec.held, {"ling": 768, "longcat": 2048}[
+        cell.split("-")[0]]
+    experts, refused = grouped_matmul.select_grouped_matmul(
+        rows=t_n * k_n, d_model=d, d_ff=d_ff, n_experts=e_n,
+        dtype=jnp.bfloat16, platform="tpu")
+    assert refused is None
+    choice, refused = choice_kernel(spec, t_n, platform="tpu")
+    assert refused is None
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def layer(m, w_router, b_router, w_gate, w_up, w_down):
+        return lm_block.moe_ffn(
+            spec, m, w_router, w_gate, w_up, w_down, experts=experts,
+            b_router=b_router, choice=choice if kernel else None)[:2]
+
+    bf16 = jnp.bfloat16
+    return layer, (sds((t_n, d)), sds((d, width)), sds((width,)),
+                   sds((e_n, d, d_ff), bf16), sds((e_n, d, d_ff), bf16),
+                   sds((e_n, d_ff, d), bf16))
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "passes"])
+@pytest.mark.parametrize("cell", ["ling-3.0-flash-serve-agent128",
+                                  "longcat-flash-serve-agent64"])
+def test_routed_layer_compiles_for_a_v5e_without_a_sort(cell, kernel,
+                                                        one_v5e):
+    """From the router's matmul through `moe_combine` the compiled
+    layer orders nothing: no `sort` (the TPU compiler's `top_k` and
+    `argsort` are FULL sorts of the row: four a layer on Ling's cell
+    until PR 63), with the choice kernel (one Mosaic call more than the
+    experts' two) and with `_largest`'s passes, the fallback."""
+    layer, args = _routed_layer(cell, kernel, one_v5e)
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    assert " sort(" not in text and "sort." not in text
+    assert text.count(MOSAIC_CALL) == 2 + kernel
+
+
+@pytest.mark.parametrize("cell", [
+    "olmoe-1b-7b-serve-chat32", "mellum2-12b-a2.5b-serve-agent96",
+    "granite-4.0-h-small-serve-chat64", "k-exaone-236b-a23b-serve-chat64",
+    "deepseek-v2-serve-agent64", "longcat-flash-serve-agent64",
+    "glm-5.2-serve-docqa64", "lfm2-24b-a2b-serve-agent128",
+    "solar-open2-250b-serve-docqa64", "ling-3.0-flash-serve-agent128"])
+def test_router_choice_compiles_for_a_v5e(cell, one_v5e):
+    """Mosaic's own compile at every expert cell's rows and router (a
+    group of 20 experts is no multiple of a sublane tile: DeepSeek's),
+    one call for the group limit and the k passes."""
+    from test_moe_routing import cell_router, choice_kernel
+
+    from paddle_tpu.kernels import router_choice
+
+    spec, t_n, _ = cell_router(cell)
+    width = spec.n_experts + spec.zero_experts
+    kern, refused = choice_kernel(spec, t_n, platform="tpu")
+    assert refused is None and kern.name == router_choice.NAME
+    scores = jax.ShapeDtypeStruct((t_n, width), jnp.float32,
+                                  sharding=one_v5e)
+    text = jax.jit(kern.choose).lower(scores, scores).compile().as_text()
+    assert text.count(MOSAIC_CALL) == 1 and " sort(" not in text
 
 
 @pytest.mark.parametrize("shape,dtype,calls", [

@@ -457,6 +457,7 @@ def test_a_clamp_on_one_layer_equals_the_references(which):
     free = REF.compare(g, CONFIG, ids, got, routing)
     assert free["logits_rms_err"] > 10 * TOL_FP32, free
     assert dec.expert_kernel == "xla:not_tpu"
+    assert dec.router_choice == "passes:not_tpu"
 
 
 def test_a_clamped_layer_takes_the_ragged_dots_on_a_tpu():
@@ -1009,27 +1010,29 @@ def test_rehearsal_of_the_cell_prints_the_readers():
 # the ten other configurations' toys, taken at the parent commit of
 # the PR that put delta-rule lanes beside a latent table: a description
 # without the new fields computes what it computed
+# (a block with experts: taken again at PR 63, whose routing orders
+# nothing: `tests/test_moe_routing.py` holds it to the results it had)
 PARENTS_STEPS = {
     "deepseek-v2-1chip":
-        "4cdf28a6d829d45f44c6482585db890b0642f269fa92f2e545db85075cf16529",
+        "9c3830f8c2a1b9138e20f455378fb171060f6d4315ba634aa4ee74bd4b592eae",
     "glm-5.2-1chip":
-        "387b4207736565792a663e6a4a5dc17a76cac7c0d9b6c1c00b59792b011595db",
+        "c2087f45f65f60cf050d6351ca811905c6c4db817d265cde6d714819933e5ca5",
     "granite-4.0-h-small-1chip":
-        "2bd1e8ff9be64a7aba888fd307efca0a26d78b7af8078d47b46232db3e5d5d71",
+        "253a1dfac12af82aae0c4a96fde88a25ce72655e5821ba4f294308b4bb447b37",
     "k-exaone-236b-a23b-1chip":
-        "57b92381c2341438ff7e6c4b66c0663cb2ac9c51a8f664828f6c3b26dcb3f3a7",
+        "4ffbd3e82acdc257b7942dc29e8e4eb0490383b28ace53b1c7d480a6fa040522",
     "lfm2-24b-a2b-1chip":
-        "fe439de8450cb15246bd5919494b77f2934534c03bce981b4a0c9eca3c41c963",
+        "1eb9e2f2533b44ba25115b9015bd3bca06580000c971b2cdee7f87dec1a4031f",
     "longcat-flash-1chip":
-        "d72784e53848f00ebbe940abc7eb7e5427b3107bca5d76264d6440e5ee9433c8",
+        "9701d7e40fd04fc70b38db89e5fa74c0ff1fd7824acb46fb0678af10329f0ecc",
     "mellum2-12b-a2.5b-1chip":
-        "1bc041d2e948dc6a749388a6ac4aae8b7e6a77d151ca2ea8f65d74340f36243a",
+        "212478f1abda7105caa4cdbcca0cbf132f2b82a36c59fa52d9f7f3ec136f15c1",
     "olmoe-1b-7b-1chip":
-        "0f38d8d68383bed3850933df794600416bf8c933eaeacd5120de00ea4ac29331",
+        "581e73cc9cf988400e7f3617e6d7daed41b3b7f4f9640d37a2a2c5183dad795b",
     "ouro-2.6b":
         "f36a5c45128a6cc5bdc417a7c0750a5bf83734afcdd4d1b341bd85d1b96a531e",
     "solar-open2-250b-1chip":
-        "e8e9ce26378307049e2531b020ee62af7e8e3730bd91a627896fc97476ff98ae",
+        "5f635bdc363ce11324dd4a658685153cfd808418ff428b25bede464595b9a1ea",
 }
 
 

@@ -366,7 +366,7 @@ def test_comparison_refuses_lower_precision_routing_and_renormalising(
     if fault == "renormalised":
         dec = _decoder(norm_topk_prob=True)
     else:
-        def bf16_route(spec, m, w_router, b_router=None):
+        def bf16_route(spec, m, w_router, b_router=None, choice=None):
             bf = jnp.bfloat16
             probs = jax.nn.softmax(jnp.dot(m.astype(bf),
                                            w_router.astype(bf)), -1)

@@ -64,6 +64,25 @@ step (the kernel's own choice first).  `--check`: against the
 `jax.numpy` lines, state and o, as a share of their largest value
 (float32 sums in another order: some 1e-6).
 
+The routing of an expert layer the same way (`--shape <cell>-route`,
+the ten cells with experts; rows, widths, k and groups from the cell's
+own files): `lm_block.route` with `moe_ffn`'s dispatch and combine and
+NO expert matmul between them (a stand-in hands the ordered rows
+through), 32 layers chained in one call (the chip's host hands a call
+over in some 0.2 ms: eight layers a call read 25 us a layer whatever
+ran), ms a LAYER: whole (the choice `kernels/router_choice.py`'s call
+where it is selected), `passes` (the choice `lm_block._largest`'s
+fusions, what runs where the kernel is refused; right results),
+`no_choice` (every choice of `route` a FIXED one of the same shapes,
+spread as the router's own, and a maximum over the row in its place:
+WRONG results) and `no_order` (`route`, then the identity order: the
+rows repeated, the result reshaped; WRONG results).  `--check`: on the
+same scores, the choice's experts, order and weights against the same
+lines with `jax.lax.top_k`, and the order against the stable `argsort`,
+bit for bit.  100 calls a reading; several shapes with commas between
+run in one process.  Copied into the checkout of a commit before PR 63 the
+same command times that commit's sorts.
+
 It imports the kernel and is imported by nothing a cell runs.
 """
 from __future__ import annotations
@@ -132,7 +151,18 @@ SHAPES = {
     "solar-open2-250b-serve-docqa64-delta": dict(
         kernel="delta", slots=64, heads=64, d_head=128),
 }
+# an expert layer's routing at a cell's rows and router (`run_route`)
+ROUTE_CELLS = (
+    "olmoe-1b-7b-serve-chat32", "mellum2-12b-a2.5b-serve-agent96",
+    "granite-4.0-h-small-serve-chat64", "k-exaone-236b-a23b-serve-chat64",
+    "deepseek-v2-serve-agent64", "longcat-flash-serve-agent64",
+    "glm-5.2-serve-docqa64", "lfm2-24b-a2b-serve-agent128",
+    "solar-open2-250b-serve-docqa64", "ling-3.0-flash-serve-agent128")
+SHAPES.update({cell + "-route": dict(kernel="route", cell=cell)
+               for cell in ROUTE_CELLS})
 VARIANTS = ("whole", "no_copies", "no_products")
+ROUTE_VARIANTS = ("whole", "passes", "no_choice", "no_order")
+ROUTE_LAYERS = 32
 DELTA_VARIANTS = ("whole", "no_copies", "no_arithmetic", "xla")
 FLASH_VARIANTS = ("whole", "no_mask", "uncut", "no_row_sum", "no_row_max")
 TABLES = ("consecutive", "shuffled")
@@ -717,6 +747,256 @@ def run_delta(name, variants=DELTA_VARIANTS, heads_blocks=(), calls=30,
     return res
 
 
+def route_cell(cell):
+    """(block description, rows of a tick, the model's width) of a
+    serving cell, from its own files as its job reads them."""
+    from paddle_tpu.models import lm_block
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*parts):
+        with open(os.path.join(root, *parts)) as f:
+            return json.load(f)
+
+    w = next(w for w in load("BENCHMARK.json")["workloads"]
+             if w["name"] == cell)
+    m = load("perf", "configs", w["config"] + ".json")
+    b = m["block"]
+    spec = lm_block.BlockSpec(**dict(
+        b["spec"], **{f: m[k] for f, k in b["from_keys"].items()}))
+    slots = load("perf", "traffic", w["traffic"] + ".json")["slots"]
+    return spec, int(slots), int(m["hidden_size"])
+
+
+def fixed_choice(spec, rows):
+    """A choice [rows, k] int32 of distinct experts that no score
+    decides, spread as a router with random weights spreads them:
+    `topk_group` of `n_group` groups a row, then k of their experts."""
+    rng = np.random.RandomState(193)
+    width = spec.n_experts + spec.zero_experts
+    out = np.empty((rows, spec.experts_per_token), np.int32)
+    for r in range(rows):
+        pool = np.arange(width)
+        if spec.n_group > 1:
+            kept = rng.choice(spec.n_group, spec.topk_group, replace=False)
+            pool = pool.reshape(spec.n_group, -1)[kept].reshape(-1)
+        out[r] = rng.choice(pool, spec.experts_per_token, replace=False)
+    return out
+
+
+@contextlib.contextmanager
+def route_removed(variant, spec, lm_block):
+    """While it lasts every choice of `lm_block.route` (its `_largest`,
+    and `jax.lax.top_k` for a tree that still sorts) is `fixed_choice`
+    or the first k, with a maximum over the row so that what feeds the
+    choice stays live; `whole` and `no_order` patch nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    if variant != "no_choice":
+        yield
+        return
+    width = spec.n_experts + spec.zero_experts
+
+    def fixed(x, k):
+        top = x.max(-1, keepdims=True)
+        if x.shape[-1] == width and k == spec.experts_per_token:
+            at = jnp.asarray(fixed_choice(spec, x.shape[0]))
+        else:
+            at = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32),
+                                  x.shape[:-1] + (k,))
+        # no score is this: a zero the compiler cannot fold
+        return (jnp.broadcast_to(top, x.shape[:-1] + (k,)),
+                at + (top == 12345.678).astype(jnp.int32))
+
+    saved = jax.lax.top_k, getattr(lm_block, "_largest", None)
+    jax.lax.top_k = fixed
+    if saved[1] is not None:
+        lm_block._largest = fixed
+    try:
+        yield
+    finally:
+        jax.lax.top_k = saved[0]
+        if saved[1] is not None:
+            lm_block._largest = saved[1]
+
+
+class _HandThrough:
+    """The experts' stand-in: the ordered rows come back as the experts'
+    result, the plan kept live."""
+    name = "hand_through"
+
+    @staticmethod
+    def plan(sizes):
+        return sizes, sizes
+
+    @staticmethod
+    def gate_up(rows, w_gate, w_up, plan):
+        return rows + (plan[0][0] + plan[1][-1]).astype(rows.dtype)
+
+    @staticmethod
+    def down(act, w_down, plan):
+        import jax.numpy as jnp
+
+        return act.astype(jnp.float32)
+
+
+def choice_kernel(spec, rows, interpret=False):
+    """{"choice": the tree's choice kernel for a TPU at these shapes, or
+    None where it is refused}; {} in a tree before PR 63, whose `route`
+    takes none."""
+    from paddle_tpu.models import lm_block
+
+    if not hasattr(lm_block, "_largest"):
+        return {}
+    from paddle_tpu.kernels import router_choice
+
+    return {"choice": router_choice.select_router_choice(
+        rows=rows, width=spec.n_experts + spec.zero_experts,
+        k=spec.experts_per_token, n_group=spec.n_group,
+        topk_group=spec.topk_group, group_score=spec.group_score,
+        platform="tpu", interpret=interpret)[0]}
+
+
+def route_inputs(shape, rehearse=False):
+    """-> (block description, tokens m [rows, d] float32, the router's
+    matrix [d, width] float32, its choice bias [width] or None) at the
+    cell's shape, seeded."""
+    import jax
+    import jax.numpy as jnp
+
+    spec, t_n, d = route_cell(shape["cell"])
+    if rehearse:
+        t_n, d = 4, 32
+    width = spec.n_experts + spec.zero_experts
+    keys = jax.random.split(jax.random.key(63), 3)
+    m = jax.random.normal(keys[0], (t_n, d), jnp.float32)
+    w_router = jax.random.normal(keys[1], (d, width), jnp.float32) * d ** -0.5
+    b_router = (0.01 * jax.random.normal(keys[2], (width,), jnp.float32)
+                if spec.router_bias else None)
+    return spec, m, w_router, b_router
+
+
+def build_route(shape, variant="whole", rehearse=False):
+    """-> (the compiled chain of `ROUTE_LAYERS` routed layers, its
+    arguments)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import lm_block
+
+    spec, m, w_router, b_router = route_inputs(shape, rehearse)
+    t_n, k_n = m.shape[0], spec.experts_per_token
+    first, e_n = spec.held
+    stub = jnp.zeros((1,), jnp.bfloat16)
+    # whole and no_order: with the tree's choice kernel at these shapes
+    chosen = ({} if variant in ("passes", "no_choice")
+              else choice_kernel(spec, t_n, rehearse))
+
+    def layer(m):
+        if variant != "no_order":
+            return lm_block.moe_ffn(
+                spec, m, w_router, stub, stub, stub, experts=_HandThrough,
+                b_router=b_router, **chosen)[0]
+        top_w, top_e = lm_block.route(spec, m, w_router, b_router, **chosen)
+        out = jnp.repeat(m.astype(jnp.bfloat16), k_n, axis=0).astype(
+            jnp.float32).reshape(t_n, k_n, -1)
+        if spec.has_unheld:
+            here = (top_e >= first) & (top_e < first + e_n)
+            out = jnp.where(here[..., None], out, 0.0)
+        return (out * top_w[..., None]).sum(axis=1)
+
+    def chain(m):
+        for _ in range(ROUTE_LAYERS):
+            m = m + layer(m)
+        return m
+
+    with route_removed(variant, spec, lm_block):
+        return jax.jit(chain).lower(m).compile(), (m,)
+
+
+def check_route(shape, rehearse=False):
+    """-> whether, on the SAME scores (the router's, made once and
+    kept), `lm_block._chosen`'s weights and experts as the tree makes
+    them (its kernel where one is selected) are bit for bit those of
+    the same lines with `jax.lax.top_k`, and `_by_expert`'s order,
+    places and sizes the stable `argsort`'s, at the cell's shape.  (A
+    whole `route` against a whole `route` differs in the scores' last
+    bits on the chip whatever chooses: beside another consumer the
+    compiler turns the router's product over, `fb_oi->bf` for
+    `bf_io->bf`, and its six bfloat16 passes add up in another order.)
+    A tree that still sorts is its own reference."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import lm_block
+
+    if not hasattr(lm_block, "_chosen"):
+        return True
+    spec, m, w_router, b_router = route_inputs(shape, rehearse)
+    logits = jnp.dot(m, w_router, precision=jax.lax.Precision.HIGHEST)
+    probs = (jax.nn.sigmoid(logits) if spec.router == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+
+    def chosen(**choice):
+        return jax.jit(functools.partial(lm_block._chosen, spec, **choice))(
+            probs, b_router)
+
+    got = chosen(**choice_kernel(spec, m.shape[0], rehearse))
+    passes, lm_block._largest = lm_block._largest, jax.lax.top_k
+    try:
+        want = chosen()
+    finally:
+        lm_block._largest = passes
+    first, e_n = spec.held
+    flat_e = got[1].reshape(-1) - first
+    flat_e = jnp.where((flat_e >= 0) & (flat_e < e_n), flat_e, e_n)
+    by_sort = jnp.argsort(flat_e, stable=True)
+    return all(
+        np.array_equal(np.asarray(g), np.asarray(w)) for g, w in zip(
+            got + tuple(jax.jit(functools.partial(
+                lm_block._by_expert, e_n=e_n))(flat_e)),
+            want + (by_sort, jnp.zeros_like(by_sort).at[by_sort].set(
+                jnp.arange(len(by_sort))),
+                jnp.zeros(e_n, jnp.int32).at[flat_e].add(1, mode="drop"))))
+
+
+def run_route(name, variants=ROUTE_VARIANTS, calls=100, with_check=False,
+              rehearse=False):
+    """-> {"shape", "rows", "width", "k", "groups", "<variant>": ms a
+    layer, "sorts": `sort` ops a layer in the whole chain's compiled
+    text, "check"}."""
+    import jax
+
+    shape = SHAPES[name]
+    spec, t_n, d = route_cell(shape["cell"])
+    chosen = choice_kernel(spec, t_n, rehearse)
+    kernel = chosen.get("choice")
+    res = {"shape": name, "device": jax.devices()[0].device_kind,
+           "rehearsal": bool(rehearse), "rows": t_n, "d_model": d,
+           "choice": (kernel.name if kernel is not None
+                      else "passes" if chosen else "top_k"),
+           "width": spec.n_experts + spec.zero_experts,
+           "k": spec.experts_per_token,
+           "groups": [spec.n_group, spec.topk_group, spec.group_score],
+           "held": list(spec.held)}
+    for variant in variants:
+        if variant == "passes" and kernel is None and "whole" in res:
+            res[variant] = res["whole"]     # the same program
+            continue
+        f, args = build_route(shape, variant, rehearse)
+        if variant == "whole":
+            res["sorts"] = f.as_text().count(" sort(") / ROUTE_LAYERS
+        res[variant] = round(
+            timed(f, args, 1 if rehearse else calls) / ROUTE_LAYERS, 5)
+        print(f"{name} {variant}", res[variant], flush=True)
+    if with_check:
+        res["check"] = check_route(shape, rehearse)
+    return res
+
+
 def toy(shape):
     """`shape` cut to what the interpreter walks in seconds."""
     return dict(shape, slots=3, heads=min(shape["heads"], 8),
@@ -745,6 +1025,9 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
         return run_delta(name, variants or DELTA_VARIANTS, heads_blocks,
                          calls=calls, with_check=with_check,
                          rehearse=rehearse)
+    if shape.get("kernel") == "route":
+        return run_route(name, variants or ROUTE_VARIANTS, calls=calls,
+                         with_check=with_check, rehearse=rehearse)
     if shape.get("kernel") == "index":
         return run_index(name, variants or VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse,
@@ -777,7 +1060,9 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--shape", default="deepseek-v2-serve-agent64",
-                    choices=sorted(SHAPES))
+                    help="a name of SHAPES, or several with commas "
+                    "between (one process, a result each): "
+                    + ", ".join(sorted(SHAPES)))
     ap.add_argument("--block-sizes", default="")
     ap.add_argument("--heads-blocks", default="",
                     help="the delta-rule kernel at so many heads a grid "
@@ -785,20 +1070,29 @@ def main(argv=None):
     ap.add_argument("--variants", default="",
                     help="of the shape's kernel's (all): "
                     + ",".join(VARIANTS + FLASH_VARIANTS[1:]
-                               + DELTA_VARIANTS[2:]))
+                               + DELTA_VARIANTS[2:] + ROUTE_VARIANTS[1:]))
     ap.add_argument("--tables", default="consecutive", choices=TABLES)
-    ap.add_argument("--calls", type=int, default=30)
+    ap.add_argument("--calls", type=int, default=0,
+                    help="calls a reading (30; a route shape's 100)")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
-    res = run(args.shape,
-              [int(b) for b in args.block_sizes.split(",") if b],
-              tuple(v for v in args.variants.split(",") if v),
-              calls=args.calls, with_check=args.check,
-              rehearse=args.rehearse, order=args.tables,
-              heads_blocks=[int(b) for b in args.heads_blocks.split(",")
-                            if b])
+    names = args.shape.split(",")
+    for name in names:
+        if name not in SHAPES:
+            ap.error(f"--shape {name!r}: not one of SHAPES")
+    res = [run(name,
+               [int(b) for b in args.block_sizes.split(",") if b],
+               tuple(v for v in args.variants.split(",") if v),
+               calls=args.calls or (
+                   100 if SHAPES[name].get("kernel") == "route" else 30),
+               with_check=args.check,
+               rehearse=args.rehearse, order=args.tables,
+               heads_blocks=[int(b) for b in args.heads_blocks.split(",")
+                             if b])
+           for name in names]
+    res = res[0] if len(res) == 1 else res
     print(json.dumps(res))
     if args.out:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
