@@ -214,6 +214,34 @@ architecture is a second description and not a second decoder.
                layer on the experts' and the shared expert's SwiGLU
                inputs (`expert_swiglu_limits`, `shared_swiglu_limits`).
 
+  latent-ring- the thirteenth (dots-studio dots3-note-prev, `model_type:
+  selected-    dots3_note`), fields again, and the second COMPOSITION: the
+  latent-like  third's ring and table with BOTH on latent rows, the
+               table's under the ninth's lightning indexer.  A SLIDING
+               layer is latent attention with a geometry OF ITS OWN
+               (`sliding_n_heads`, `sliding_q_lora_rank`,
+               `sliding_kv_lora_rank`, `sliding_qk_nope_head_dim`,
+               `sliding_qk_rope_head_dim`, `sliding_v_head_dim`: other
+               heads, another latent rank and another unrotated key part
+               than the full layers'; 0: the block's one set), RoPE
+               parameters of its own (`rope_parameters` by kind) and a
+               scale of its own (1 / sqrt of ITS head); its cache is a
+               ring of latent rows a LANE, one row [its latent | its
+               rotated key part] a position, at ITS row width, and the
+               window need not be a whole number of blocks (the ring is
+               the blocks that hold a window, under a mask of the last
+               `window` rows).  A FULL layer is the ninth's selected
+               latent on the table; a sliding layer computes no selection
+               and reuses none (`INDEX_NONE`: it attends over its
+               window), so a selection is never shared across one.  Each
+               normed latent times sqrt(d_model / ITS OWN rank)
+               (`scale_q_lora`, `scale_kv_lora`, by kind), and on both
+               kinds a sigmoid scalar a head of the layer's input before
+               `o` (`attention_gate_per_head`, the twelfth's, here with
+               no delta-rule layer in the block).  The router is the
+               sixth's, the dense layer and the held experts the
+               seventh's.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -226,6 +254,7 @@ from __future__ import annotations
 
 import dataclasses
 import types
+import typing
 from typing import Dict, Tuple
 
 __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
@@ -234,7 +263,7 @@ __all__ = ["BlockSpec", "OPT", "olmoe", "param_layout", "norm",
            "delta_rule", "MOE_COMPILER_SCOPES", "SLIDING", "FULL", "MAMBA",
            "ATTENTION",
            "CONV", "DELTA", "DENSE", "SPARSE", "INDEX_FULL",
-           "INDEX_SHARED", "select_rows"]
+           "INDEX_SHARED", "INDEX_NONE", "LatentGeometry", "select_rows"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # an FFN kind a layer (`mlp_layer_types`)
@@ -249,6 +278,19 @@ DELTA = "delta_rule"
 # an indexer kind a layer (`indexer_types`): a layer that computes a
 # selection, and one that reuses the nearest earlier one's
 INDEX_FULL, INDEX_SHARED = "full", "shared"
+# and one that does neither: a SLIDING layer, which attends over its window
+INDEX_NONE = "none"
+
+
+class LatentGeometry(typing.NamedTuple):
+    """A latent attention's sizes on one kind of layer
+    (`BlockSpec.latent_of`)."""
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
 
 
 def _frozen(value):
@@ -280,7 +322,11 @@ class BlockSpec:
     without positions under an elementwise gate, or LATENT attention
     under RoPE with a gate a head, full-rank or low-rank delta gates,
     dense layers among the sparse ones, the group-limited sigmoid router
-    with a choice bias) (module docstring).  `layer_types`,
+    with a choice bias), and the ring-and-table block with experts whose
+    ring AND table hold latent rows (sliding layers with a latent
+    geometry of their own and a window of any length, full layers under
+    a lightning indexer, a gate a head on both, an FFN kind a layer, the
+    sigmoid router with a choice bias) (module docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -360,6 +406,16 @@ class BlockSpec:
     # the normed latents times sqrt(d_model / their rank)
     scale_q_lora: bool = False
     scale_kv_lora: bool = False
+    # -- the SLIDING layers' own latent geometry, where they have one (0:
+    #    the block's set above): query heads, the two ranks, a head's
+    #    unrotated and rotated key columns and its value columns; their
+    #    ring's row is [sliding_kv_lora_rank | sliding_qk_rope_head_dim]
+    sliding_n_heads: int = 0
+    sliding_q_lora_rank: int = 0
+    sliding_kv_lora_rank: int = 0
+    sliding_qk_nope_head_dim: int = 0
+    sliding_qk_rope_head_dim: int = 0
+    sliding_v_head_dim: int = 0
     # -- a DOUBLE layer (`sub_blocks` 2): two sub-blocks a layer, each a
     #    latent attention and a dense SwiGLU of `dense_d_inner` on norms
     #    of their own, and ONE expert layer whose input is the first
@@ -374,11 +430,13 @@ class BlockSpec:
     #    `index_n_heads` index queries of `index_head_dim` columns score
     #    ONE cached index key a position, and attention is over the
     #    `index_topk` positions of largest score; an INDEX_SHARED layer
-    #    attends over the nearest earlier INDEX_FULL layer's selection
+    #    attends over the nearest earlier INDEX_FULL layer's selection; a
+    #    SLIDING layer is INDEX_NONE (its window is what it attends over)
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
-    indexer_types: tuple = ()       # a kind a layer; (): all INDEX_FULL
+    # a kind a layer; (): INDEX_FULL, and INDEX_NONE on a SLIDING layer
+    indexer_types: tuple = ()
     # -- a CONV layer's taps (a gated short convolution; 0: none): a
     #    lane keeps the last `conv_width - 1` rows of its gated product
     conv_width: int = 0
@@ -399,7 +457,8 @@ class BlockSpec:
     delta_gate_floor: float = 0.0
     # -- attention's output times sigmoid(the layer's normed input @ a
     #    matrix [d, H * d_head]) before `o`; `attention_gate_per_head`:
-    #    the matrix is [d, H], one scalar a head (a latent layer's)
+    #    the matrix is [d, H], one scalar a head (a latent layer's, at the
+    #    heads of ITS kind)
     attention_gate: bool = False
     attention_gate_per_head: bool = False
     # -- a CLAMP a layer on the SwiGLU's inputs: with a limit L > 0 the
@@ -509,9 +568,21 @@ class BlockSpec:
                 "'moe_swiglu') whose weights are not renormalised "
                 "(norm_topk_prob: over which of them?) and whose choice "
                 "is not group-limited (n_group: they lie in no group)")
-        if set(self.indexer_types) - {INDEX_FULL, INDEX_SHARED}:
+        if set(self.indexer_types) - {INDEX_FULL, INDEX_SHARED, INDEX_NONE}:
             raise ValueError(f"indexer_types {self.indexer_types}: of "
-                             f"{INDEX_FULL!r} and {INDEX_SHARED!r}")
+                             f"{INDEX_FULL!r}, {INDEX_SHARED!r} and (a "
+                             f"sliding layer's) {INDEX_NONE!r}")
+        own = [n for n in ("sliding_n_heads", "sliding_q_lora_rank",
+                           "sliding_kv_lora_rank",
+                           "sliding_qk_nope_head_dim",
+                           "sliding_qk_rope_head_dim", "sliding_v_head_dim")
+               if getattr(self, n)]
+        if min([getattr(self, n) for n in own] + [0]) < 0 or (own and (
+                self.kv_lora_rank < 1 or SLIDING not in self.layer_types)):
+            raise ValueError(
+                f"block {self.name!r}: {', '.join(own)}: the sliding "
+                "layers' own latent geometry, of a block with a latent "
+                "cache (kv_lora_rank) and sliding layers")
         if self.index_topk < 0 or (self.index_topk and (
                 self.kv_lora_rank < 1 or self.sub_blocks != 1
                 or self.index_n_heads < 1
@@ -562,15 +633,31 @@ class BlockSpec:
         return self.index_topk > 0
 
     def indexer_of(self, layer: int) -> str:
-        """Layer `layer`'s indexer kind, INDEX_FULL or INDEX_SHARED; a
-        description without `indexer_types` is all INDEX_FULL."""
+        """Layer `layer`'s indexer kind, INDEX_FULL, INDEX_SHARED or
+        INDEX_NONE; a description without `indexer_types` is INDEX_FULL on
+        every layer but the SLIDING ones, which are INDEX_NONE."""
         if not self.indexer_types:
-            return INDEX_FULL
+            return (INDEX_NONE if self.kind_of(layer) == SLIDING
+                    else INDEX_FULL)
         if layer >= len(self.indexer_types):
             raise ValueError(
                 f"block {self.name!r}: {len(self.indexer_types)} "
                 f"indexer_types, and a layer {layer}")
         return self.indexer_types[layer]
+
+    def latent_of(self, kind: str, n_heads: int) -> LatentGeometry:
+        """The latent attention's sizes on a layer of kind `kind` at the
+        builder's `n_heads`: the block's one set, or on a SLIDING layer
+        each size the sliding layers' own where they have one."""
+        whole = LatentGeometry(
+            n_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+        if kind != SLIDING:
+            return whole
+        own = (self.sliding_n_heads, self.sliding_q_lora_rank,
+               self.sliding_kv_lora_rank, self.sliding_qk_nope_head_dim,
+               self.sliding_qk_rope_head_dim, self.sliding_v_head_dim)
+        return LatentGeometry(*(o or w for o, w in zip(own, whole)))
 
     def heads(self, d_model: int, n_heads: int):
         """(K/V heads, head size) at a model width and query heads."""
@@ -681,7 +768,9 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     [d, H*dh] (a latent layer under `attention_gate_per_head`:
     `attn_head_gate` [d, H]).  A LATENT
     layer has
-    seven arrays in place of the four: `q_a` [d, q_lora_rank], its
+    seven arrays (at the sizes of ITS kind, `BlockSpec.latent_of`: a
+    SLIDING layer of a latent block is a latent layer at the sliding
+    layers' own heads, ranks and head sizes, with no indexer) in place of the four: `q_a` [d, q_lora_rank], its
     norm's scale `q_a_norm`, `q_b` [q_lora_rank, H * (nope + rope)] (a
     head's unrotated columns, then its rotated ones), `kv_a` [d,
     kv_lora_rank + rope] (the latent, then the one key part),
@@ -693,7 +782,8 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     layer has four more: `idx_q` [q_lora_rank, index_n_heads *
     index_head_dim], `idx_k` [d, index_head_dim], its LayerNorm
     `idx_k_norm` (a scale and a shift of index_head_dim) and `idx_w`
-    [d, index_n_heads]; an INDEX_SHARED layer none.  The expert matrices
+    [d, index_n_heads]; an INDEX_SHARED or INDEX_NONE layer none.  The
+    expert matrices
     are [experts HELD, ...]; the router keeps its published width.  A
     DENSE layer among sparse ones (`mlp_layer_types`) has the dense
     block's three matrices at `dense_d_inner` and no "router" key:
@@ -745,14 +835,18 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             f"block {spec.name!r}: delta_gate_floor bounds the log decay "
             "of delta-rule layers, and the block has none")
     if spec.attention_gate and (
-            not delta or spec.attention_gate_per_head != spec.latent):
+            spec.attention_gate_per_head != spec.latent
+            or not (delta or spec.latent)):
         raise NotImplementedError(
             f"block {spec.name!r}: attention_gate (the attention's output "
             "times a sigmoid of the layer's input) is built and tested "
-            "on the attention layers of a block with delta-rule layers: "
-            "elementwise ([d, H * d_head]) on a K/V table, a scalar a "
-            "head (attention_gate_per_head) on a latent table, and "
-            "neither the other way round")
+            "elementwise ([d, H * d_head]) on the K/V table of a block "
+            "with delta-rule layers, and as a scalar a head "
+            "(attention_gate_per_head) on LATENT layers, with delta-rule "
+            "layers beside them or without; STILL refused: an "
+            "elementwise gate on a latent layer, a gate a head on K/V "
+            "heads, and any gate on a K/V block without delta-rule "
+            "layers")
     if conv and (dense or mamba or SLIDING in kinds or spec.latent
                  or FULL not in kinds or spec.conv_width < 2):
         raise NotImplementedError(
@@ -815,24 +909,50 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             "router with one (the sum of its two largest scores + bias: "
             "'top2_sum'); not a softmax router with a bias, a sigmoid "
             "router without, or the other score under either")
+    swa = (spec.latent_of(SLIDING, n_heads) if spec.latent
+           and SLIDING in kinds else None)
     if spec.latent and (
-            dense or mamba or conv or SLIDING in kinds or spec.qk_norm
+            dense or mamba or conv or spec.qk_norm
             or spec.n_kv_heads not in (0, n_heads) or spec.d_head
             or min(spec.qk_nope_head_dim, spec.qk_rope_head_dim,
                    spec.v_head_dim) < 1 or spec.q_lora_rank < 0
             or spec.qk_rope_head_dim % 2
             or (spec.q_lora_rank < 1 and (spec.sparse or spec.scale_q_lora
-                                          or spec.sub_blocks > 1))):
+                                          or spec.sub_blocks > 1))
+            or (swa is not None and (
+                FULL not in kinds or min(swa) < 1
+                or swa.qk_rope_head_dim % 2))):
         raise NotImplementedError(
             f"block {spec.name!r}: a latent cache (kv_lora_rank) is "
             "built for a block of full-attention layers with experts "
-            "under RoPE (delta-rule layers may stand beside them), and "
+            "under RoPE (delta-rule layers may stand beside them, or "
+            "SLIDING layers that are latent attention themselves: a ring "
+            "of latent rows beside the latent table), and "
             "needs qk_nope_head_dim, an even qk_rope_head_dim and "
-            "v_head_dim; q_lora_rank 0 is a query of ONE matrix, which a "
+            "v_head_dim (of the sliding layers' own geometry too, and a "
+            "query through a rank there); q_lora_rank 0 is a query of "
+            "ONE matrix, which a "
             "lightning indexer, scale_q_lora and a double layer are not "
-            "built on (they read the query's latent); no ring, Mamba or "
-            "conv layers, QK-norm, grouped K/V heads or d_head beside it "
-            "(every head reads the one latent row)")
+            "built on (they read the query's latent); STILL refused: a "
+            "ring of K and V heads beside a latent table (n_kv_heads, "
+            "d_head: every head reads the one latent row, of the table "
+            "and of the ring alike), Mamba or "
+            "conv layers, QK-norm, grouped K/V heads or d_head beside it")
+    if SLIDING in kinds and spec.sparse and (
+            INDEX_SHARED in [spec.indexer_of(l) for l in range(n_layers)]
+            or any((spec.indexer_of(l) == INDEX_NONE) != (k == SLIDING)
+                   for l, k in enumerate(kinds))):
+        raise NotImplementedError(
+            f"block {spec.name!r}: beside sliding layers every full layer "
+            "computes its own selection (indexer_types 'full') and a "
+            "sliding layer none ('none': it attends over its window); "
+            "STILL refused: a selection SHARED across a sliding layer "
+            "('shared'), a selection on a sliding layer, and a full "
+            "layer without one")
+    if INDEX_NONE in spec.indexer_types and SLIDING not in kinds:
+        raise ValueError(
+            f"block {spec.name!r}: indexer_types 'none' is a sliding "
+            "layer's, and the block has none")
     for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
         if not spec.rotated(kind):
             continue
@@ -851,27 +971,24 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         shapes[name] = tuple(int(s) for s in shape)
         return name, None
 
-    def latent_arrays(p):
-        dqk = spec.qk_nope_head_dim + spec.qk_rope_head_dim
+    def latent_arrays(p, kind=FULL):
+        # the sizes of the layer's KIND: a sliding layer's own, where
+        # the description gives them
+        h, r_q, r_kv, d_nope, d_pe, d_v = spec.latent_of(kind, n_heads)
+        dqk = d_nope + d_pe
         # without a low-rank step the one matrix stands where `q_b` does
         # and reads the block's normed input itself
-        query = ({"q_a": add(p + "q_a_proj.w_0", d, spec.q_lora_rank),
-                  "q_a_norm": add(p + "q_a_norm.scale_0",
-                                  spec.q_lora_rank),
-                  "q_b": add(p + "q_b_proj.w_0", spec.q_lora_rank,
-                             n_heads * dqk)} if spec.q_lora_rank else
-                 {"q_b": add(p + "q_proj.w_0", d, n_heads * dqk)})
-        gate = ({"attn_head_gate": add(p + "attn_gate.w_0", d, n_heads)}
+        query = ({"q_a": add(p + "q_a_proj.w_0", d, r_q),
+                  "q_a_norm": add(p + "q_a_norm.scale_0", r_q),
+                  "q_b": add(p + "q_b_proj.w_0", r_q, h * dqk)} if r_q else
+                 {"q_b": add(p + "q_proj.w_0", d, h * dqk)})
+        gate = ({"attn_head_gate": add(p + "attn_gate.w_0", d, h)}
                 if spec.attention_gate else {})
         return {"norm1": add(p + "attn_norm.scale_0", d), **query, **gate,
-                "kv_a": add(p + "kv_a_proj.w_0", d,
-                            spec.kv_lora_rank + spec.qk_rope_head_dim),
-                "kv_a_norm": add(p + "kv_a_norm.scale_0",
-                                 spec.kv_lora_rank),
-                "kv_b": add(p + "kv_b_proj.w_0", spec.kv_lora_rank,
-                            n_heads * (spec.qk_nope_head_dim
-                                       + spec.v_head_dim)),
-                "o": add(p + "o_proj.w_0", n_heads * spec.v_head_dim, d)}
+                "kv_a": add(p + "kv_a_proj.w_0", d, r_kv + d_pe),
+                "kv_a_norm": add(p + "kv_a_norm.scale_0", r_kv),
+                "kv_b": add(p + "kv_b_proj.w_0", r_kv, h * (d_nope + d_v)),
+                "o": add(p + "o_proj.w_0", h * d_v, d)}
 
     def expert_arrays(p):
         wide = e + spec.zero_experts
@@ -948,7 +1065,7 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "conv_w": add(p + "conv.w_0", spec.conv_width, d),
                    "conv_out": add(p + "conv_out_proj.w_0", d, d)}
         elif spec.latent:
-            lay = latent_arrays(p)
+            lay = latent_arrays(p, kind)
             if spec.sparse and spec.indexer_of(l) == INDEX_FULL:
                 hi, di = spec.index_n_heads, spec.index_head_dim
                 lay.update({

@@ -499,13 +499,13 @@ _REFUSALS = {
             "a decoder with sliding-window layers takes no draft model: "
             "speculative verification writes a window of positions before it "
             "attends, which a ring one window long cannot hold "
-            "(build_lm_paged_decoder's step_window refuses it too)"),
-        "prefix_cache": (
-            "prefix_cache=True with sliding-window layers: a cached prompt "
-            "block's sliding-layer K/V lives in the ring of the slot that "
-            "wrote it and is overwritten as that slot goes on, so a later "
-            "hit would attend over another request's keys; pass "
-            "prefix_cache=False")},
+            "(build_lm_paged_decoder's step_window refuses it too)")},
+    # (the prefix cache works over a ring too: a cached prompt block's
+    # sliding-layer rows live in the ring of the lane that wrote them, so
+    # the decoder offers `snapshot_save` and `snapshot_restore` of a
+    # lane's ring blocks and a hit ends at a block whose snapshot the
+    # server restores, like a lane's state below; docs/serving.md "A
+    # latent ring beside a selected table")
     # the prefix cache works: the decoder offers `snapshot_save` and
     # `snapshot_restore`, and a hit ends at a block whose snapshot of the
     # lane's state and tails the server restores (docs/serving.md "A
@@ -614,11 +614,14 @@ class PagedDecoder:
     # what belongs to a LANE: how many layers keep a recurrent state or
     # a convolution tail (Mamba and delta-rule layers: both; gated short
     # convolutions: the tail alone; 0: none) and the float32 bytes a lane
-    # holds over them
+    # holds over them; for a block with sliding layers the bytes of a
+    # lane's RING over them, in the pool's dtype (what a snapshot of it
+    # holds)
     state_layers: int
     state_bytes_per_lane: int
     # SNAPSHOTS of what belongs to a lane, for a prefix cache over such a
-    # block (None, all three, for a block whose lanes keep nothing):
+    # block (None, all three, for a block whose lanes keep nothing; a
+    # lane's ring blocks of every sliding layer for a block with a ring):
     # init_snapshots(rows, device=None) -> an opaque pool of `rows`
     # snapshots; snapshot_save(snapshots, pool_k, pool_v, lane, row) ->
     # snapshots, with lane `lane`'s state and tails of every such layer
@@ -633,7 +636,10 @@ class PagedDecoder:
     # `step_routing`): the streaming Pallas kernel ("pallas";
     # "pallas:latent" over a latent pool), or the XLA gather and the
     # reason the kernel was refused; `step_window` (a window of query
-    # rows a slot) runs the gather always
+    # rows a slot) runs the gather always; a LATENT ring's kernel, which
+    # is selected at the ring's own geometry, under
+    # "paged_attention_ring" ("...:masked_pages" where the ring is
+    # longer than its window)
     kernels: Dict[str, str]
     # (pages a chunk, pages of its smallest row window) of the kernel
     # over a slot's table and over its ring: what it copies and
@@ -916,6 +922,31 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     bool.  `step_window`, a draft model and an int8 pool are refused
     by name.
 
+    A LATENT RING beside a selected latent table (lm_block's thirteenth
+    description; docs/serving.md "A latent ring beside a selected
+    table"): the SLIDING layers of a latent block are latent attention
+    at sizes of their own (`BlockSpec.latent_of`), so `pool_k` is (the
+    latent table [full layers, blocks, block_size, table row], the
+    latent RING pool [sliding layers, ring blocks, block_size, ring
+    row]: ONE array a kind, each row padded to the 128-lane grid at its
+    own width) and `pool_v` (the index-key pool of the full layers, or
+    () without an indexer; ()), `tables` the pair (tables, rings).  The
+    ring is `ceil(window / block_size)` blocks a lane; where that is
+    longer than the window the sliding layers attend under a mask of the
+    last `window` rows made from the cursor (the kernel's `select`; a
+    ring exactly one window long lowers as it always did).  The query
+    projections, the absorbed products, the head gate and the kernel are
+    selected at the layer's KIND's sizes, a full layer alone reaches the
+    indexer, and `tick_counts` gives `latent_rows`, `kv_rows_win`,
+    `past_window`, `kv_rows_indexed`, `kv_rows_selected` and
+    `ring_bytes` on one tick.  EVERY block with a ring (of K and V heads
+    too) is served under a PREFIX CACHE through snapshots of a lane's
+    ring blocks: `init_snapshots`, `snapshot_save` and
+    `snapshot_restore` copy the `window_blocks_per_seq` blocks of every
+    sliding layer (a ring holds position p at block (p // block_size) %
+    window_blocks_per_seq whatever the lane, so a snapshot taken in one
+    lane is right in another).
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -989,6 +1020,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 "block) is not built for a latent row (one scale over a "
                 "normed latent and a rotated key part of other ranges); "
                 "kv_dtype fp32 or bf16")
+    # the latent sizes by layer KIND (a sliding layer's may be its own:
+    # `BlockSpec.latent_of`), each with its padded row and its scale
+    def _geometry(kind):
+        k = spec.latent_of(kind, n_heads)
+        dh = k.qk_nope_head_dim + k.qk_rope_head_dim
+        return types.SimpleNamespace(
+            h=k.n_heads, r_q=k.q_lora_rank, d_lat=k.kv_lora_rank,
+            d_nope=k.qk_nope_head_dim, d_pe=k.qk_rope_head_dim,
+            d_v=k.v_head_dim, d_head=dh,
+            d_kv=-(-(k.kv_lora_rank + k.qk_rope_head_dim) // 128) * 128,
+            scale=spec.attention_multiplier or 1.0 / math.sqrt(dh))
+
+    geo = ({kind: _geometry(kind)
+            for kind in (lm_block.FULL, lm_block.SLIDING)}
+           if latent else None)
     # A lightning indexer: the layers that compute a selection keep an
     # index key a position in a pool of their own, a plane each
     sparse = spec.sparse
@@ -1018,17 +1064,24 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     n_full = kinds.count(lm_block.FULL)
     nw = 0
     if ringed:
-        if spec.window % bs:
+        if spec.window % bs and not latent:
             raise ValueError(
                 f"block {spec.name!r}: window {spec.window} is not a "
-                f"whole number of {bs}-position blocks")
+                f"whole number of {bs}-position blocks (a ring longer "
+                "than its window, under a mask of the last `window` "
+                "rows, is built for a ring of LATENT rows; a ring of K "
+                "and V heads is still exactly one window long)")
         if kv_dtype == "int8":
             raise NotImplementedError(
                 f"block {spec.name!r}: an int8 pool re-quantizes a "
                 "block under the offsets written so far, which a ring "
                 "that overwrites its oldest block in place breaks; "
                 "sliding layers take kv_dtype fp32 or bf16")
-        nw = min(spec.window // bs, nb)
+        nw = min(-(-spec.window // bs), nb)
+    # a ring LONGER than its window (the window is no whole number of
+    # blocks): its oldest rows are past the window, and the sliding
+    # layers attend under a mask of the last `window` rows
+    ring_masked = ringed and nw * bs > spec.window
     # a layer's index inside the pool of its kind
     pool_index = [kinds[:l].count(k) for l, k in enumerate(kinds)]
     # A LOOPED stack (`BlockSpec.passes`, or its exit gate): the layers
@@ -1047,6 +1100,22 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         d_model=d_model, n_heads=n_heads, d_head=d_head, kv_width=d_kv,
         block_size=bs, kv_dtype=kv_dtype, platform=platform,
         value_width=d_lat if latent else None)
+    # a latent RING is read at the sliding layers' own geometry: a
+    # selection of its own (a ring of K and V heads is read by the
+    # table's kernel: their rows are one width).  One refused, both are:
+    # the step then gathers on every layer
+    _attend_ring, _ring_refused = _attend, _refused
+    if ringed and latent:
+        _ring = geo[lm_block.SLIDING]
+        _attend_ring, _ring_refused = (
+            _paged_attention.select_paged_attention(
+                d_model=d_model, n_heads=_ring.h, d_head=_ring.d_head,
+                kv_width=_ring.d_kv, block_size=bs, kv_dtype=kv_dtype,
+                platform=platform, value_width=_ring.d_lat))
+        if _attend is None or _attend_ring is None:
+            _refused = _ring_refused = _refused or _ring_refused
+            _attend = _attend_ring = None
+    attend_of = {lm_block.FULL: _attend, lm_block.SLIDING: _attend_ring}
     # and the same of a lightning indexer's scores over its index-key
     # plane, from the plane's geometry
     _index_scores, _index_refused = (
@@ -1136,13 +1205,25 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     sub_parts = ("latent_q", "latent_kv", "latent_absorb", "attention",
                  "attn_out", "dense_ffn")
     sub_traced = None                   # the sub-block being traced
+    # Beside a latent RING the parts of a latent layer carry the layer's
+    # kind the same way (`paged_decoder/latent_q/sliding`): the two
+    # geometries read apart, and still sum under the part (`attention`
+    # and `kv_gather` carry it through `_kind_scope`, as on every ring).
+    kind_parts = ("latent_q", "latent_kv", "latent_absorb", "attn_out",
+                  "attention_head_gate", "indexer_q", "indexer_k",
+                  "indexer_scores", "indexer_topk")
+    kind_traced = None                  # the kind of the layer being traced
 
     def scope(name):
-        if sub_traced is None or name not in sub_parts:
+        below = (f"sub{sub_traced}" if sub_traced is not None
+                 and name in sub_parts else
+                 kind_traced.split("_")[0] if kind_traced is not None
+                 and name in kind_parts else None)
+        if below is None:
             return jax.named_scope(name)
         both = contextlib.ExitStack()
         both.enter_context(jax.named_scope(name))
-        both.enter_context(jax.named_scope(f"sub{sub_traced}"))
+        both.enter_context(jax.named_scope(below))
         return both
 
     def _sample(logits, seeds, positions, temps):
@@ -1194,7 +1275,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             # a kind RoPE does not turn (`BlockSpec.rope_layers`)
             # carries no position signal at all
             return {kind: (lm_block.rope_tables(
-                spec, pos, d_pe if latent else d_head, kind)
+                spec, pos, geo[kind].d_pe if latent else d_head, kind)
                            if spec.rotated(kind) else None)
                     for kind in dict.fromkeys(kinds)}
 
@@ -1222,11 +1303,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 kk = lm_block.rope(kk, *rot, n_kv)
         return q, kk, vv
 
-    def _kv_b(g, lay):
-        """`kv_b` by head: [latent, H, key columns | value columns]."""
-        return g[lay["kv_b"][0]].reshape(d_lat, n_heads, d_nope + d_v)
+    def _kv_b(g, lay, k):
+        """`kv_b` by head: [latent, H, key columns | value columns], at
+        the sizes `k` of the layer's kind (`geo`)."""
+        return g[lay["kv_b"][0]].reshape(k.d_lat, k.h, k.d_nope + k.d_v)
 
-    def _latent_down(g, lay, x):
+    def _latent_down(g, lay, x, k):
         """The block's normed input and the normed query latent (times
         its constant where the description has one, `scale_q_lora`):
         what `_latent_qkv` and a lightning indexer both read (the input
@@ -1238,10 +1320,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 return h, h
             c_q = _norm(g, _fc(g, h, lay["q_a"]), lay["q_a_norm"])
             if spec.scale_q_lora:
-                c_q = c_q * math.sqrt(d_model / spec.q_lora_rank)
+                c_q = c_q * math.sqrt(d_model / k.r_q)
             return h, c_q
 
-    def _latent_qkv(g, lay, h, c_q, rot):
+    def _latent_qkv(g, lay, h, c_q, rot, k):
         """The latent block's `_qkv`, from `_latent_down`'s two: -> (the
         ABSORBED query [S, W, H * row]: a head's unrotated part times
         the key half of `kv_b`, so that it meets the latent itself, then
@@ -1250,16 +1332,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         `scale_kv_lora`), the one rotated key part, the pad; None: there
         is no V).  The norms are float32; the rotation is of
         `qk_rope_head_dim` columns, a head's of the query and the one of
-        the key."""
+        the key.  `k`: the sizes of the layer's kind (`geo`)."""
         lead = h.shape[:-1]
+        n_heads, d_lat, d_nope, d_pe = k.h, k.d_lat, k.d_nope, k.d_pe
         with scope("latent_q"):
-            q = _fc(g, c_q, lay["q_b"]).reshape(lead + (n_heads, d_head))
+            q = _fc(g, c_q, lay["q_b"]).reshape(lead + (n_heads, k.d_head))
             q_nope, q_pe = q[..., :d_nope], q[..., d_nope:]
             q_pe = lm_block.rope(q_pe.reshape(lead + (-1,)), *rot,
                                  n_heads).reshape(q_pe.shape)
         with scope("latent_kv"):
             ckv = _fc(g, h, lay["kv_a"])
-            pad = jnp.zeros(lead + (d_kv - d_lat - d_pe,), ckv.dtype)
+            pad = jnp.zeros(lead + (k.d_kv - d_lat - d_pe,), ckv.dtype)
             c_kv = _norm(g, ckv[..., :d_lat], lay["kv_a_norm"])
             if spec.scale_kv_lora:
                 # on the row as stored: `kv_b` meets it either side
@@ -1269,23 +1352,23 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 axis=-1)
         with scope("latent_absorb"):
             q_lat = jnp.einsum("...hn,chn->...hc", q_nope,
-                               _kv_b(g, lay)[..., :d_nope])
+                               _kv_b(g, lay, k)[..., :d_nope])
             q_abs = jnp.concatenate(
                 [q_lat, q_pe, jnp.broadcast_to(
                     pad[..., None, :], lead + (n_heads, pad.shape[-1]))],
                 axis=-1)
-        return q_abs.reshape(lead + (n_heads * d_kv,)), row, None
+        return q_abs.reshape(lead + (n_heads * k.d_kv,)), row, None
 
-    def _latent_values(g, lay, ctx):
+    def _latent_values(g, lay, ctx, k):
         """The attention's context over the LATENT, [.., H * latent],
         times the value half of `kv_b` a head -> [.., H * v_head_dim]:
         what the expanded form's `p . V` gives."""
         with scope("latent_absorb"):
             out = jnp.einsum(
                 "...hc,chv->...hv",
-                ctx.reshape(ctx.shape[:-1] + (n_heads, d_lat)),
-                _kv_b(g, lay)[..., d_nope:])
-            return out.reshape(ctx.shape[:-1] + (n_heads * d_v,))
+                ctx.reshape(ctx.shape[:-1] + (k.h, k.d_lat)),
+                _kv_b(g, lay, k)[..., k.d_nope:])
+            return out.reshape(ctx.shape[:-1] + (k.h * k.d_v,))
 
     def _indexer(g, lay, h, c_q, rot, pool_i, plane, tables, wb, wi,
                  positions, active):
@@ -1550,15 +1633,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     jnp.broadcast_to(sent, (len(hits),)))
             return out
 
-    def _kind_scope(name, kind):
+    def _kind_scope(name, kind, under=None):
         """`paged_decoder/<name>`, and under it the layer's kind where
         the block has sliding layers: the gather and the attention of
-        ring and table then read apart, and still sum under `name`."""
-        if not ringed:
+        ring and table then read apart, and still sum under `name`.
+        `under`: a scope between the two (`attention/selected/full`: the
+        readers of a selection look for `attention/selected`)."""
+        if not ringed and under is None:
             return scope(name)
         both = contextlib.ExitStack()
         both.enter_context(scope(name))
-        both.enter_context(scope(kind.split("_")[0]))
+        if under is not None:
+            both.enter_context(scope(under))
+        if ringed:
+            both.enter_context(scope(kind.split("_")[0]))
         return both
 
     def _gather(pool, l, tables, kind):
@@ -1577,7 +1665,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 q, sc_ = pool
                 return (q[l, tables].reshape(s_n, rows, d_kv),
                         jnp.repeat(sc_[l, tables], bs, axis=1))
-            return pool[l, tables].reshape(s_n, rows, d_kv), None
+            # (a latent ring's row is the sliding layers' own width)
+            return pool[l, tables].reshape(
+                s_n, rows, geo[kind].d_kv if latent else d_kv), None
 
     def _block_diagonal(q):
         """q [S, W, H*dh] laid out block-diagonally by K/V head:
@@ -1644,20 +1734,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if latent:
             # one row for every head: the query is dense over it, the
             # value its latent columns, the context [S, W, H * latent]
-            with _kind_scope("attention", kind), (
-                    scope("selected") if selected
-                    else contextlib.nullcontext()):
+            kg = geo[kind]
+            with _kind_scope("attention", kind,
+                             under="selected" if selected else None):
                 sc = jax.lax.dot_general(
-                    q.reshape(q.shape[0], w_n * n_heads, d_kv), k,
+                    q.reshape(q.shape[0], w_n * kg.h, kg.d_kv), k,
                     (((2,), (2,)), batched),
-                    preferred_element_type=jnp.float32) * scale
-                sc = jnp.where(jnp.repeat(pos_mask, n_heads, axis=1), sc,
+                    preferred_element_type=jnp.float32) * kg.scale
+                sc = jnp.where(jnp.repeat(pos_mask, kg.h, axis=1), sc,
                                -jnp.inf)
                 ctx = jax.lax.dot_general(
-                    jax.nn.softmax(sc, axis=-1), k[..., :d_lat],
+                    jax.nn.softmax(sc, axis=-1), k[..., :kg.d_lat],
                     (((2,), (1,)), batched),
                     preferred_element_type=jnp.float32)
-                return ctx.reshape(q.shape[0], w_n, n_heads * d_lat)
+                return ctx.reshape(q.shape[0], w_n, kg.h * kg.d_lat)
         v, v_scale = _gather(pool_v, l, tables, kind)
         with _kind_scope("attention", kind):
             sc = jax.lax.dot_general(
@@ -1691,17 +1781,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         softmax over chunks of pages in place of one softmax over the
         table.  `select`: a lightning indexer's selection [S, rows]:
         the softmax is over those of the cursor's rows alone, under a
-        scope of its own."""
+        scope of its own; on a SLIDING layer the ring's rows inside the
+        window (`_ring_window`), under the ring's own scope."""
         row, lengths = cursor
+        attend = attend_of[kind]
+        kind_scale = geo[kind].scale if latent else scale
+        if select is not None:
+            with _kind_scope("attention", kind, under=(
+                    None if kind == lm_block.SLIDING else "selected")):
+                out = attend(q, pool_k, None, tables, lengths, l,
+                             kind_scale, write=(kk, vv, row), select=select)
+            return out + (pool_v,)
         with _kind_scope("attention", kind):
-            if select is not None:
-                with scope("selected"):
-                    out = _attend(q, pool_k, None, tables, lengths, l,
-                                  scale, write=(kk, vv, row),
-                                  select=select)
-                return out + (pool_v,)
-            out = _attend(q, pool_k, pool_v, tables, lengths, l, scale,
-                          write=(kk, vv, row))
+            out = attend(q, pool_k, pool_v, tables, lengths, l, kind_scale,
+                         write=(kk, vv, row))
             # a latent pool is one array: the V pool is the empty
             # tuple it came as
             return out + (pool_v,) if latent else out
@@ -1734,6 +1827,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             return jnp.where(
                 active, win_tables[lane, (positions // bs) % nw], 0)
 
+    def _ring_window(positions, active):
+        """[S, ring rows] bool: the rows of a ring LONGER than its window
+        that lie inside it.  Ring row r holds position c - (c - r) mod
+        rows (the newest one congruent to r at or under the cursor c),
+        which is inside the window iff that distance is under `window`;
+        a row never written is hidden by `_sees`, as ever."""
+        rows = nw * bs
+        with _kind_scope("attention", lm_block.SLIDING):
+            c = jnp.where(active, positions, 0)[:, None]
+            return (c - jnp.arange(rows)[None, :]) % rows < spec.window
+
     def _sees(kind, positions, active):
         """What each slot sees, after this position's write, of the
         rows of its table (full layer) or ring (sliding layer), from
@@ -1749,7 +1853,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         gather path the mask [S, rows] that says the same."""
         rows = (nw if kind == lm_block.SLIDING else nb) * bs
         with _kind_scope("attention", kind):
-            if _attend is not None:
+            if attend_of[kind] is not None:
                 # and the row the kernel writes this position's K and
                 # V to: on a table the position, on a ring where
                 # `_ring_block` puts it; nothing for a slot with no
@@ -1786,6 +1890,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 tabs[lm_block.SLIDING], positions, active)
         cursor = {kind: (wb, _sees(kind, positions, active))
                   for kind, wb in written.items()}
+        in_window = (_ring_window(positions, active) if ring_masked
+                     else None)
 
         def stack(x, pools_k, pools_v, plane0=None):
             """The layers once over x, each writing this position's K/V
@@ -1794,10 +1900,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             the pool of its kind (a Python int, as ever).  A double
             layer's two sub-blocks are two turns of the loop, the
             expert layer's result `held` from the first to the second."""
-            nonlocal sub_traced
+            nonlocal sub_traced, kind_traced
             held = chosen = None
-            for lay, kind, li in zip(layout.layers, kinds, pool_index):
+            for l, (lay, kind, li) in enumerate(zip(layout.layers, kinds,
+                                                    pool_index)):
                 sub_traced = li % subs if subs > 1 else None
+                kind_traced = kind if ringed and latent else None
                 if kind == lm_block.MAMBA:
                     # the lane's state rides where a pool's K does, its
                     # convolution tail where the V does
@@ -1823,8 +1931,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     x = _ffn(g, lay, x, hits)
                     continue
                 if latent:
-                    h, c_q = _latent_down(g, lay, x)
-                    q, kk, vv = _latent_qkv(g, lay, h, c_q, rot[kind])
+                    h, c_q = _latent_down(g, lay, x, geo[kind])
+                    q, kk, vv = _latent_qkv(g, lay, h, c_q, rot[kind],
+                                            geo[kind])
                 else:
                     q, kk, vv = _qkv(g, lay, x, rot[kind])
                 wb, seen = cursor[kind]
@@ -1835,13 +1944,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     # layers up to the next selecting one attend over
                     chosen, scores, pools_v[kind] = _indexer(
                         g, lay, h, c_q, rot[kind], pools_v[kind],
-                        index_plane[li], tabs[kind], wb, wi, positions,
+                        index_plane[l], tabs[kind], wb, wi, positions,
                         active)
                     picks.append((h, c_q, scores, chosen))
-                if _attend is not None:
+                # the rows attention is over, of those the cursor shows:
+                # a selection on the table, the window on a ring longer
+                # than it (a sliding layer reads no selection)
+                only = in_window if kind == lm_block.SLIDING else chosen
+                if attend_of[kind] is not None:
                     ctx_av, pools_k[kind], pools_v[kind] = _streamed(
                         q, kk, vv, pools_k[kind], pools_v[kind], plane,
-                        tabs[kind], seen, kind, select=chosen)
+                        tabs[kind], seen, kind, select=only)
                 else:
                     with scope("kv_write"):
                         pools_k[kind] = _write(pools_k[kind], plane, wb,
@@ -1849,19 +1962,21 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                         if not latent:
                             pools_v[kind] = _write(pools_v[kind], plane,
                                                    wb, wi, vv)
-                    if chosen is not None:
-                        seen = seen & chosen
+                    if only is not None:
+                        seen = seen & only
                     ctx_av = _attention(
                         q[:, None, :], pools_k[kind], pools_v[kind],
                         plane, tabs[kind], seen[:, None, :], kind,
-                        selected=chosen is not None)[:, 0]
+                        selected=only is not None
+                        and kind != lm_block.SLIDING)[:, 0]
                 if latent:
-                    ctx_av = _latent_values(g, lay, ctx_av)
+                    ctx_av = _latent_values(g, lay, ctx_av, geo[kind])
                 if "attn_head_gate" in lay:
                     # a sigmoid SCALAR a head, on the heads' values
                     with scope("attention_head_gate"):
                         gate = jax.nn.sigmoid(_fc(g, h, lay["attn_head_gate"]))
-                        ctx_av = (ctx_av.reshape(s_n, n_heads, d_v)
+                        ctx_av = (ctx_av.reshape(s_n, geo[kind].h,
+                                                 geo[kind].d_v)
                                   * gate[..., None]).reshape(ctx_av.shape)
                 if "attn_gate" in lay:
                     with scope("attention_gate"):
@@ -1871,6 +1986,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     y = _fc(g, ctx_av, lay["o"])
                     if not spec.post_norm:
                         x = _residual(x, y)
+                kind_traced = None
                 if spec.post_norm:
                     x = _post_join(g, x, y, lay["post1"])
                 if subs > 1:
@@ -2080,7 +2196,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                           * elem_bytes)
     # and an index key a position on each selecting layer's plane
     bytes_per_block += int(n_index * bs * d_idx * elem_bytes)
-    window_bytes_per_block = int(2 * n_win * bs * d_kv * elem_bytes)
+    # (a latent ring's ONE array, at the sliding layers' own row width)
+    window_bytes_per_block = int(
+        n_win * bs * geo[lm_block.SLIDING].d_kv * elem_bytes if latent
+        else 2 * n_win * bs * d_kv * elem_bytes)
     # what a lane holds over the layers that keep something a lane,
     # float32: a Mamba layer its SSM state and the convolution tail of
     # x B C, a gated short convolution the tail of its product alone
@@ -2102,6 +2221,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                        + 2 * spec.ssm_d_state))
     state_bytes_per_lane = 4 * (n_state * math.prod(state_shape)
                                 + n_lane * math.prod(tail_shape))
+    if ringed:
+        # what belongs to a lane of a block with a ring: its ring blocks
+        # of every sliding layer, which a snapshot holds
+        state_bytes_per_lane = nw * window_bytes_per_block
 
     def init_pool(num_blocks, device=None, window_blocks=None,
                   lanes=None):
@@ -2118,7 +2241,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         block.  A latent block's is (the one pool, ()), with a
         lightning indexer (the latent pool, the index-key pool), with
         delta-rule layers ((the latent pool, the states), ((), the
-        tails))."""
+        tails)), with sliding layers ((the latent table, the latent ring
+        pool of `window_blocks` blocks at the sliding layers' row), (the
+        index-key pool or (), ()))."""
         def zeros(layers, blocks, width=d_kv):
             shape = (layers, int(blocks), bs, width)
             if kv_dtype == "int8":
@@ -2148,20 +2273,25 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     (() if latent else zeros(n_full, num_blocks),
                      lane_state(tail_shape, n_lane)))
 
-        if sparse:
-            return (zeros(planes, num_blocks),
-                    zeros(n_index, num_blocks, d_idx))
+        if ringed and window_blocks is None:
+            raise ValueError(
+                f"block {spec.name!r} has sliding layers: "
+                "init_pool needs window_blocks, the ring pool's "
+                "size (null block included)")
+        index_keys = (zeros(n_index, num_blocks, d_idx) if sparse else ())
+        if latent and ringed:
+            # one array a kind beside K, each at its kind's row; beside
+            # V the full layers' index keys and nothing of the ring's
+            return ((zeros(planes, num_blocks),
+                     zeros(n_win, window_blocks,
+                           geo[lm_block.SLIDING].d_kv)),
+                    (index_keys, ()))
         if latent:
-            return zeros(planes, num_blocks), ()
+            return zeros(planes, num_blocks), index_keys
 
         def z():
             if not ringed:
                 return zeros(planes, num_blocks)
-            if window_blocks is None:
-                raise ValueError(
-                    f"block {spec.name!r} has sliding layers: "
-                    "init_pool needs window_blocks, the ring pool's "
-                    "size (null block included)")
             return (zeros(n_layers - n_win, num_blocks),
                     zeros(n_win, window_blocks))
 
@@ -2182,17 +2312,35 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     def init_snapshots(rows, device=None):
         """A pool of `rows` snapshots, zeros: (a float32 array [rows,
         ...] a layer with a state, one a layer with a tail), opaque to
-        the caller."""
-        z = tuple(tuple(jnp.zeros((int(rows),) + shape, jnp.float32)
-                        for _ in range(layers))
-                  for shape, layers in ((state_shape, n_state),
-                                        (tail_shape, n_lane)))
+        the caller.  For a block with a ring: (a lane's ring blocks
+        beside K [rows, sliding layers, ring blocks, block_size, row] in
+        the pool's dtype, the same beside V or () for a latent ring)."""
+        if ringed:
+            width = geo[lm_block.SLIDING].d_kv if latent else d_kv
+            z = tuple(jnp.zeros(
+                (int(rows), n_win, nw, bs, width),
+                jnp.bfloat16 if kv_dtype == "bf16" else jnp.float32)
+                for _ in range(1 if latent else 2))
+            z = z + ((),) * (2 - len(z))
+        else:
+            z = tuple(tuple(jnp.zeros((int(rows),) + shape, jnp.float32)
+                            for _ in range(layers))
+                      for shape, layers in ((state_shape, n_state),
+                                            (tail_shape, n_lane)))
         return z if device is None else jax.device_put(z, device)
 
     @functools.partial(jax.jit,
                        donate_argnums=(0,) if platform != "cpu" else ())
     def snapshot_save(snapshots, pool_k, pool_v, lane, row):
         with jax.named_scope("state_snapshot_save"):
+            if ringed:
+                # the lane's `nw` ring blocks of every sliding layer,
+                # from ring-pool block 1 + lane * nw (`slot_rings`)
+                return tuple(
+                    snap if isinstance(snap, tuple) else snap.at[row].set(
+                        jax.lax.dynamic_slice_in_dim(
+                            pool[1], 1 + lane * nw, nw, axis=1))
+                    for snap, pool in zip(snapshots, (pool_k, pool_v)))
             return tuple(
                 tuple(snap.at[row].set(held[lane])
                       for snap, held in zip(snaps, pool[1]))
@@ -2202,6 +2350,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                        donate_argnums=(0, 1) if platform != "cpu" else ())
     def snapshot_restore(pool_k, pool_v, snapshots, lane, row):
         with jax.named_scope("state_snapshot_restore"):
+            if ringed:
+                return tuple(
+                    pool if isinstance(snap, tuple) else (
+                        pool[0], jax.lax.dynamic_update_slice_in_dim(
+                            pool[1], snap[row], 1 + lane * nw, axis=1))
+                    for snap, pool in zip(snapshots, (pool_k, pool_v)))
             return tuple(
                 (pool[0], tuple(held.at[lane].set(snap[row])
                                 for snap, held in zip(snaps, pool[1])))
@@ -2246,7 +2400,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         compiler_scopes.update(lm_block.MOE_COMPILER_SCOPES)
 
     window = spec.window if ringed else 0
-    tiling = ((_attend.tiling(nb), _attend.tiling(nw) if nw else None)
+    tiling = ((_attend.tiling(nb), _attend_ring.tiling(nw) if nw else None)
               if _attend is not None else None)
     index_tiling = (_index_scores.tiling(nb)
                     if _index_scores is not None else None)
@@ -2319,7 +2473,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         read, of those the lanes' tables hold: through the kernel
         (`kernels["lightning_indexer"]`) `stream_counts` of its stream,
         with `index_dma_ops` (a start a group of 16 entries that are a
-        run); on the gather path every page."""
+        run); on the gather path every page.  With a LATENT ring
+        `ring_bytes`: the ring rows the lanes with a sequence read
+        (cursor + 1, the ring's rows at most), times the ring's stored
+        row's bytes, summed over them and the sliding layers."""
         n = len(cursors)
         counts, saved = {}, saved or {}
         if passes > 1:
@@ -2346,6 +2503,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["past_window"] = int((rows > window).sum())
             counts["kv_rows_full"] = int(rows.sum())
             counts["kv_rows_win"] = int(np.minimum(rows, window).sum())
+        if window and latent:
+            # the ring rows the lanes' sliding layers read (a ring holds
+            # nw * bs rows at most), at the ring's stored row
+            counts["ring_bytes"] = int(
+                np.minimum(rows, nw * bs).sum()
+                * window_bytes_per_block // bs)
         if latent:
             counts["latent_rows"] = planes * int(rows.sum())
         if sparse:
@@ -2413,6 +2576,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             lightning_indexer=(
                 f"xla:{_index_refused}:table_gather"
                 if _index_scores is None else "pallas:paged_scores"))
+    if ringed and latent:
+        # the ring's kernel is a selection of its own, at the sliding
+        # layers' geometry; a ring longer than its window is read by
+        # page under the window's row mask
+        kernels["paged_attention_ring"] = (
+            f"xla:{_ring_refused}" + ":masked_gather" * ring_masked
+            if _attend_ring is None
+            else "pallas:latent" + ":masked_pages" * ring_masked)
 
     decoder = PagedDecoder(
         step=step, step_window=step_window, step_logits=step_logits,
@@ -2439,9 +2610,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         window_bytes_per_block=window_bytes_per_block,
         table_layers=planes, ring_layers=n_win, index_planes=n_index,
         state_layers=n_lane, state_bytes_per_lane=state_bytes_per_lane,
-        init_snapshots=init_snapshots if stateful else None,
-        snapshot_save=snapshot_save if stateful else None,
-        snapshot_restore=snapshot_restore if stateful else None,
+        init_snapshots=init_snapshots if stateful or ringed else None,
+        snapshot_save=snapshot_save if stateful or ringed else None,
+        snapshot_restore=snapshot_restore if stateful or ringed else None,
         kernels=kernels,
         attention_tiling=tiling, tick_counts=tick_counts,
         starts_saved=starts_saved, refuses=refuses)

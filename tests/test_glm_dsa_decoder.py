@@ -574,7 +574,11 @@ def test_description_is_checked_and_laid_out():
     with pytest.raises(NotImplementedError, match="starts 'full'"):
         _block(indexer_types=["shared", "full"])
     with pytest.raises(ValueError, match="indexer_types"):
-        _block(indexer_types=["full", "none"])
+        _block(indexer_types=["full", "windowed"])
+    # "none" is a sliding layer's kind (lm_block's thirteenth
+    # description), and this block has no sliding layer
+    with pytest.raises(ValueError, match="a sliding layer's"):
+        _decoder(indexer_types=["full", "none", "none", "none", "full"])
     with pytest.raises(ValueError, match="index_topk is 0"):
         _block(index_topk=0)
     with pytest.raises(ValueError, match="indexer_types, and a layer"):
@@ -978,9 +982,12 @@ def test_the_cost_functions_and_the_readers_on_a_synthetic_run(monkeypatch):
                 spec["layer"], spec["unit"], spec["moves"], spec["source"])
             # the prefix cache's reader is also the cell's that PR 59
             # added (a lane state under snapshots)
+            # (and the cell PR 65 added: latent rings beside a selected
+            # table, whose full layers have the indexer)
             assert spec["workloads"] == ["glm-5.2-serve-docqa64"] + (
                 ["solar-open2-250b-serve-docqa64"]
-                if spec["name"] == "sched_prefix_hit_share" else [])
+                if spec["name"] == "sched_prefix_hit_share" else []) + [
+                    "dots3-note-prev-serve-docqa64"]
 
 
 def test_the_index_dma_ops_reader_on_a_synthetic_run(monkeypatch):
@@ -1018,7 +1025,8 @@ def test_the_index_dma_ops_reader_on_a_synthetic_run(monkeypatch):
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
         spec["layer"], spec["unit"], spec["moves"], spec["source"])
     assert spec["better"] == "lower"
-    assert spec["workloads"] == ["glm-5.2-serve-docqa64"]
+    assert spec["workloads"] == ["glm-5.2-serve-docqa64",
+                                 "dots3-note-prev-serve-docqa64"]
 
 
 def test_the_index_pages_reader_on_a_synthetic_run(monkeypatch):
@@ -1060,4 +1068,5 @@ def test_the_index_pages_reader_on_a_synthetic_run(monkeypatch):
     assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
         spec["layer"], spec["unit"], spec["moves"], spec["source"])
     assert spec["better"] == "lower"
-    assert spec["workloads"] == ["glm-5.2-serve-docqa64"]
+    assert spec["workloads"] == ["glm-5.2-serve-docqa64",
+                                 "dots3-note-prev-serve-docqa64"]

@@ -88,14 +88,23 @@ POOLS = {
     # the latent row under 32 heads at 128 lanes, ONE plane: the latent
     # layer of six beside five delta-rule layers (PR 62)
     "ling-3.0-flash-agent128-latent": (128, 32, 640, 16, 256, 1, "bf16"),
+    # latent RINGS beside a selected latent table (PR 65): the table's
+    # row under 128 heads and the indexer's selection on the 2 full
+    # layers' planes, and the sliding layers' own row (1024 + 64 stored
+    # 1152 wide, 1024 of them the value) under 64 heads over a ring of
+    # 33 pages a lane, the last 513 rows of it under the window's mask
+    "dots3-docqa64-table": (64, 128, 640, 16, 432, 2, "bf16"),
+    "dots3-docqa64-ring": (64, 64, 1152, 16, 33, 3, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512,
            "longcat-flash-agent64-latent": 512,
            "glm-5.2-docqa64-selected": 512,
-           "ling-3.0-flash-agent128-latent": 512}
+           "ling-3.0-flash-agent128-latent": 512,
+           "dots3-docqa64-table": 512, "dots3-docqa64-ring": 1024}
 # rows a slot's mask selects (the indexer's `index_topk`)
-SELECTED = {"glm-5.2-docqa64-selected": 2048}
+SELECTED = {"glm-5.2-docqa64-selected": 2048,
+            "dots3-docqa64-table": 2048, "dots3-docqa64-ring": 513}
 # pages a chunk over each pool: what the waits' static list and the
 # issue loop's groups follow
 CHUNK_PAGES = {
@@ -106,6 +115,7 @@ CHUNK_PAGES = {
     "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
     "longcat-flash-agent64-latent": 51, "glm-5.2-docqa64-selected": 51,
     "solar-open2-docqa64": 32, "ling-3.0-flash-agent128-latent": 51,
+    "dots3-docqa64-table": 51, "dots3-docqa64-ring": 28,
 }
 
 
@@ -265,6 +275,8 @@ def test_paged_attention_compiles_for_a_v5e(name, one_v5e):
 # the GLM cell's index-key pool, and a float32 one in pages of 8
 INDEX_PLANES = {
     "glm-5.2-docqa64": (64, 32, 128, 16, 432, 2, 9216, "bf16"),
+    # the same planes under 64 index heads (PR 65)
+    "dots3-docqa64": (64, 64, 128, 16, 432, 2, 9216, "bf16"),
     "fp32-pages-of-8": (8, 4, 128, 8, 40, 3, 321, "fp32"),
 }
 
@@ -310,6 +322,14 @@ def test_index_scores_lower_for_tpu(name):
 # means to move one re-pins it from its own tree and times parent and
 # change with `tools/kernel_pace.py`.
 MOSAIC_SHA256 = {
+    # (PR 65's three, taken from its own tree: the kernels' files are
+    # the parent's, these are their modules at geometries new with it)
+    "dots3-docqa64-table":
+        "4186e38494e1177303a4ad1cd3fbf1c5fb70d61fe5067b55f2ed66b25300e6d8",
+    "dots3-docqa64-ring":
+        "b609ef9b4fb6bb20a3723648caabe560c225e0ed7270f8427ee76014f0d2447c",
+    "dots3-docqa64":
+        "e8de98f66eeb9c1ed71554ad5dc12bdeceb987c93bffeb60bb4a82c2c9e9ce0a",
     "chip_smoke-bf16":
         "8b3fb392118116ceb9ad07141978a3ba36a2a3654c50b1837b12ba4576985dc9",
     "chip_smoke-fp32":
