@@ -20,17 +20,32 @@ softmax does not care in what order its rows come).
 
 The grid is over SLOTS, not pages (a page a grid step is some 0.35 us
 of step overhead for 0.08 us of copying).  Inside a step the slot's
-pages arrive in chunks of `pages` pages through manual async copies
-into a double-buffered VMEM scratch of two chunks, K and V, whatever
-the context; an online softmax (running max and sum, float32) joins
-them a chunk at a time: the chunk is the unit of copying, and its two
-products run over the smallest row window (128 rows, doubled up to the
-chunk) that holds the pages copied into it.  While a slot's last chunk
-is computed the NEXT slot's first chunk is already in flight (the
-scratch and the buffer cursor outlive a grid step), so DMA latency is
-paid once a call, not once a slot.  That stream is ONE function,
+pages arrive in chunks through manual async copies into a
+double-buffered VMEM scratch of two buffers of `pages` pages, K and V,
+whatever the context; an online softmax (running max and sum, float32)
+joins them a chunk at a time: the chunk is the unit of copying, and its
+two products run over the smallest row window (128 rows doubled up to
+the buffer, or a stride) that holds the pages copied into it.  While a
+slot's last chunk is computed the NEXT slot's first chunk is already in
+flight (the scratch and the buffer cursor outlive a grid step), so DMA
+latency is paid once a call, not once a slot.  That stream is ONE function,
 `stream_chunks`, which the index-score kernel (`paged_index_scores.py`)
 runs too: a kernel keeps its operand and what it does with a chunk.
+
+HOW a slot's pages are cut into chunks decides what the copies cost,
+because a copy can hide only under the products of the chunk BEFORE it
+in the stream (chunk c + 1, or the next slot's chunk 0, is started at
+the head of chunk c's iteration).  A slot cut `[full, short]` hides its
+short copy under its full products and the next slot's FULL copy under
+its own SHORT products: it costs `P_full + max(P_short, C_full)` where
+`max(P, C)` would do.  So (`chunk_cut`) a slot whose pages the buffer
+holds is ONE chunk, and a longer slot is cut in EQUAL chunks of whole
+issue groups, the stride its own scalar, made once a call beside the
+issue order (`stream_scalars`) and read on the scalar-prefetch lane: no
+division enters a grid step.  The buffer is `_CHUNK_BYTES` of pages at
+most (`chunk_cap`), or the table where that is shorter: dots3's ring of
+33 pages is one chunk where 28 + 5 left a 28-page copy under five
+pages' products (PERF.md section 6, PR 66).
 
 A chunk's DMA bookkeeping is per CHUNK, not per page.  A table names
 any block, so a copy is a page; but every copy is a descriptor the
@@ -48,8 +63,11 @@ declared by pages for it), a copy a page for the others as before.
 Which groups are runs is the table's own word, computed from the
 tables by the same jitted call that hands them to the kernel
 (`issue_order`, on the scalar-prefetch lane): never the layer's kind
-or a promise of the allocator's.  It arrives SORTED, a chunk's run
-groups first, so that the loop over runs and the loop over the others
+or a promise of the allocator's.  A chunk starts on a group of the
+slot's table, so the groups are the table's own whatever the cut.  It
+arrives SORTED, a slot's run groups first (a chunk's are a slice of
+either list), by counting and never by a sort, so that the loop over
+runs and the loop over the others
 are each branch-free: a test inside one loop (a flag a group, or eight
 loads and compares) cost a table with no run 7 to 17% of a call, the
 sorted lists 1 to 2% (PERF.md section 6, PR 56).  A table in any
@@ -99,7 +117,8 @@ the MXU can pace (PERF.md section 6, PR 45).
 A SELECTION (`select=`, a latent pool's: the rows a lightning indexer
 chose, `lm_block.select_rows`) rides the same kernel as a row mask a
 slot, [S, rows of the table], brought into VMEM a slot a grid step and
-read a chunk at a time beside the cursor's own mask: the pages are
+read a chunk at a time, from the chunk's first row on, beside the
+cursor's own mask: the pages are
 those the cursor has reached, the softmax is over the selected rows
 alone.  A list of rows cannot be copied row by row: a DMA moves whole
 sublane tiles (16 rows of bfloat16: a page of the cells' tables), so
@@ -122,6 +141,7 @@ and the reason the XLA gather path runs instead.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -130,24 +150,33 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["dma_ops", "paged_attention", "paged_attention_supports",
-           "group_runs", "issue_order", "rows_multiplied",
-           "select_paged_attention", "start_pages", "starts_saved",
-           "stream_chunks", "stream_counts"]
+__all__ = ["chunk_cap", "chunk_cut", "cut_group", "dma_ops", "group_runs",
+           "issue_order", "paged_attention", "paged_attention_supports",
+           "rows_multiplied", "select_paged_attention", "start_pages",
+           "starts_saved", "stream_chunks", "stream_counts",
+           "stream_scalars"]
 
-# K (or V) bytes a chunk: the copies of one chunk are in flight while
-# the one before it is computed, so a chunk is long enough to hide a
-# DMA's latency; only the pages a slot has are copied.  On the v5e
-# 1 MiB reads 3 to 6% faster than 512 KiB and 10 to 15% faster than
-# 256 KiB, at pages of 64 KB and of 16 KB alike (PERF.md section 6,
-# PR 35).  Two chunks of K and two of V are the whole scratch: 4 MiB,
+# K (or V) bytes a chunk AT MOST: the cap under which a slot's pages are
+# cut (`chunk_cut`), and a buffer of the scratch.  The copies of one
+# chunk are in flight while the chunk BEFORE it in the stream is
+# multiplied, and under nothing else: so a slot the cap holds is ONE
+# chunk (the next slot's whole copy under this slot's whole products),
+# a longer one equal chunks, and only the pages a slot has are copied.
+# On the v5e 1 MiB read 3 to 6% faster than 512 KiB and 10 to 15%
+# faster than 256 KiB, at pages of 64 KB and of 16 KB alike (PERF.md
+# section 6, PR 35); 1.25 MiB is the least that holds dots3's ring (33
+# pages of 36 KB: 28 + 5 under 1 MiB, the 28-page copy waiting under
+# five pages' products) and 64 pages of a latent row stored 640 wide,
+# which is eight whole issue groups (PERF.md section 6, PR 66).  Two
+# such buffers of K and two of V are the whole scratch: 5 MiB at most,
 # whatever the context.
-_CHUNK_BYTES = 1024 * 1024
+_CHUNK_BYTES = 1280 * 1024
 # The fewest rows of a chunk the two products run over: the chunk is
 # the unit of COPYING, and the products take the smallest row window
-# (this many rows, doubled up to the chunk) that holds the pages copied
-# into it, so a slot with a page or two pays for 128 rows and not for
-# the chunk's.  One product a window, chosen by a switch: a LOOP over
+# (this many rows doubled up to the cap, or a stride: `_windows`) that
+# holds the pages copied into it, so a slot with a page or two pays
+# for 128 rows and not for the chunk's.  One product a window, chosen
+# by a switch: a LOOP over
 # row tiles costs some 0.2 us an iteration in latency (Mellum 2's
 # 1024-row chunks in tiles of 128: 3.6 ms a tick where the switch
 # takes 2.5; PERF.md section 6, PR 41).  128 rows fill the MXU's
@@ -157,8 +186,10 @@ _TILE_ROWS = 128
 # read, descriptor and start are scalar work in the one instruction
 # stream the products are in, and a loop's counter, test and branch a
 # page were a part of it (PERF.md section 6, PR 46).  It is also the
-# GROUP that goes as one copy where its entries are a run (PR 56); the
-# pages a chunk has beyond a multiple of it go one at a time.
+# GROUP that goes as one copy where its entries are a run (PR 56), and
+# what a slot's chunks are whole numbers of (`cut_group`): every chunk
+# but a slot's LAST starts and ends on a group of the slot's table, so
+# only the `n % 8` pages at a slot's end go one at a time.
 _ISSUE_UNROLL = 8
 _KV_DTYPES = {"fp32": jnp.float32, "bf16": jnp.bfloat16}
 
@@ -202,137 +233,255 @@ def paged_attention_supports(*, d_model: int, block_size: int,
     return None
 
 
-def _windows(pages: int, tile: int) -> Tuple[int, ...]:
-    """The row windows (in pages) a chunk of `pages` pages is
-    multiplied over: the tile doubled up to the chunk, the chunk
-    last."""
-    sizes = []
+def cut_group(pages: int, tile: int, unroll: int = _ISSUE_UNROLL) -> int:
+    """What `chunk_cut` rounds a stride up to at a cap of `pages`
+    pages: whole issue groups (`unroll` table entries, the cap where it
+    is shorter) that are whole row tiles too, so that a chunk starts on
+    a group of the LANE's table and on a row window's edge (whole
+    issue groups alone where a tile is longer than the cap); and so
+    many of those that a cap holds at most eight, since every stride
+    wants a row window of its own (`_windows`)."""
+    unroll = min(unroll, pages)
+    group = math.lcm(unroll, tile)
+    if group > pages:
+        return unroll
+    return group * -(-pages // (8 * group))
+
+
+@functools.lru_cache(maxsize=None)
+def _windows(pages: int, tile: int,
+             unroll: int = _ISSUE_UNROLL) -> Tuple[int, ...]:
+    """The row windows (in pages) a chunk of up to `pages` pages is
+    multiplied over: the tile doubled up to the cap, the strides a
+    lane longer than the cap is cut at (`chunk_cut`: the multiples of
+    `cut_group` over half the cap), the cap last.  So a chunk that is
+    not a lane's last is multiplied over its own pages and no more.  A
+    window is a branch of the products' switch, a whole products body:
+    the three that a cap of 64 pages adds to the doubled four cost a
+    kernel 0.4 to 0.9 s of compile, 12 KB of lowered text and, all
+    lanes at 64 pages, 5% of a call (PERF.md section 6, PR 66)."""
+    sizes, group = set(), cut_group(pages, tile, unroll)
     while tile < pages:
-        sizes.append(tile)
+        sizes.add(tile)
         tile *= 2
-    return tuple(sizes) + (pages,)
+    sizes.update(range(pages // 2 // group * group + group, pages, group))
+    return tuple(sorted(sizes)) + (pages,)
 
 
-def rows_multiplied(n_pages, pages: int, tile: int, block_size: int):
+def chunk_cap(table_pages: int, pages: int, tile: int,
+              unroll: int = _ISSUE_UNROLL) -> int:
+    """The pages a chunk has at most over a table of `table_pages`
+    pages where a chunk's bytes hold `pages`: the table itself where
+    they hold it (every lane is then ONE chunk), else the whole groups
+    (`cut_group`) among them, so that equal chunks of whole groups
+    reach the cap (one page where a page is more bytes than a
+    chunk)."""
+    pages = max(1, pages)
+    if table_pages <= pages:
+        return table_pages
+    group = cut_group(pages, tile, unroll)
+    return pages // group * group
+
+
+def chunk_cut(n_pages, cap: int, group: int):
+    """How a lane of `n_pages` pages (an integer, or an integer array of
+    numpy's or jax's) is cut into chunks of at most `cap` pages ->
+    (stride, count): chunk c covers table entries `[c * stride,
+    min((c + 1) * stride, n_pages))`.  A lane the cap holds is ONE
+    chunk; a longer one is cut in EQUAL chunks, as few as the cap's
+    whole groups allow, the stride rounded up to `group`
+    (`cut_group`): only a lane's last chunk is short, and never by more
+    than the rounding.  A copy hides under the products of the chunk
+    BEFORE it in the stream (`stream_chunks`), so chunks of one length
+    hide each other's copies where `[full, short]` left a full copy
+    under a short chunk's products (PERF.md section 6, PR 66).  The ONE
+    cut: the kernels' prologue (`stream_scalars`) and the host's
+    account (`rows_multiplied`, `dma_ops`, `stream_counts`) call it."""
+    xp = jnp if isinstance(n_pages, jax.Array) else np
+    n_pages = xp.maximum(n_pages, 1)
+    count = xp.where(n_pages <= cap, 1,
+                     -(-n_pages // (cap // group * group)))
+    stride = xp.minimum(-(-(-(-n_pages // count)) // group) * group, cap)
+    return stride, count
+
+
+def _window_of(copied, pages: int, tile: int, unroll: int):
+    """The smallest of `_windows(pages, tile, unroll)` that holds
+    `copied` pages (an integer or an integer array under the cap)."""
+    windows = np.asarray(_windows(pages, tile, unroll))
+    return windows[np.searchsorted(windows, copied)]
+
+
+def _cut_counts(n_pages, pages: int, tile: int, unroll: int):
+    """Of lanes of `n_pages` pages (an integer or an integer array)
+    under a cap of `pages`, by `chunk_cut` -> (the pages of a lane's
+    first chunk, the pages its products run over, the waits its chunks
+    take: one for each set bit of the pages copied into a chunk, the
+    pages of its last chunk's row window)."""
+    stride, count = chunk_cut(n_pages, pages, cut_group(pages, tile, unroll))
+    last = n_pages - (count - 1) * stride
+    last_window = _window_of(last, pages, tile, unroll)
+    return (np.minimum(n_pages, stride),
+            (count - 1) * _window_of(stride, pages, tile, unroll)
+            + last_window,
+            (count - 1) * np.bitwise_count(stride) + np.bitwise_count(last),
+            last_window)
+
+
+def rows_multiplied(n_pages, pages: int, tile: int, block_size: int,
+                    unroll: int = _ISSUE_UNROLL):
     """Rows of K (and of V) the kernel's two products run over for a
-    slot of `n_pages` pages (an integer or an integer array): for each
-    chunk of `pages` pages the smallest window of `_windows(pages,
-    tile)` that holds the pages copied into it."""
-    whole, rest = n_pages // pages, n_pages % pages
-    last, smaller = 0, 0
-    for window in _windows(pages, tile):
-        last = last + (window - smaller) * (rest > smaller)
-        smaller = window
-    return (whole * pages + last) * block_size
+    slot of `n_pages` pages (an integer or an integer array) under a
+    cap of `pages` pages a chunk: for each chunk of its cut
+    (`chunk_cut`) the smallest window of `_windows(pages, tile,
+    unroll)` that holds the pages copied into it."""
+    return _cut_counts(n_pages, pages, tile, unroll)[1] * block_size
 
 
-def group_runs(tables, pages: int, unroll: int = _ISSUE_UNROLL):
+def group_runs(tables, unroll: int = _ISSUE_UNROLL):
     """Which of the issue loop's groups are RUNS: `tables` [lanes,
-    table pages] (a jax array, traced or not, or host integers) in
-    chunks of `pages`, a chunk's first `pages // unroll * unroll`
-    entries in groups of `unroll` -> bool [lanes, chunks, groups a
-    chunk], True where a group's entries are consecutive ascending
-    block ids (`start_pages` then starts ONE copy for it).  The table's
-    own word, whatever made it: what `issue_order` sorts for the
-    kernels and `starts_saved` counts."""
+    table pages] (a jax array, traced or not, or host integers), a
+    lane's first `table pages // unroll * unroll` entries in groups of
+    `unroll` -> bool [lanes, groups], True where a group's entries are
+    consecutive ascending block ids (`start_pages` then starts ONE copy
+    for it).  The table's own word, whatever made it: what
+    `issue_order` sorts for the kernels and `starts_saved` counts.  A
+    chunk starts on a group (`cut_group`), so a chunk's groups are
+    these, however a lane's length cuts it."""
     xp = jnp if isinstance(tables, jax.Array) else np
     lanes, nb = tables.shape
-    unroll = min(unroll, pages)
-    chunks = -(-nb // pages)
-    padded = xp.pad(tables, ((0, 0), (0, chunks * pages - nb)))
-    groups = padded.reshape(lanes, chunks, pages)[
-        :, :, :pages // unroll * unroll].reshape(lanes, chunks, -1, unroll)
-    return (groups == groups[..., :1] + xp.arange(unroll)).all(axis=3)
+    groups = tables[:, :nb // unroll * unroll].reshape(lanes, -1, unroll)
+    return (groups == groups[..., :1] + xp.arange(unroll)).all(axis=2)
 
 
-def issue_order(tables, pages: int, unroll: int = _ISSUE_UNROLL):
+def issue_order(tables, unroll: int = _ISSUE_UNROLL):
     """What `start_pages` reads of `tables` [lanes, table pages] (a jax
-    array) beside the tables themselves: int32 [lanes * chunks, 2 * per
-    + 1] with per = `pages // unroll` groups a chunk.  A row's first
-    per + 1 words: the run groups among a chunk's first k, k = 0 to per
-    (`group_runs`); its last per: the chunk's groups in the order they
-    are issued, the runs first, each kind ascending.  A lane's length
-    cuts a chunk after its first k groups, and those are then the first
-    so many of either list: two loops, no test inside."""
-    runs = group_runs(tables, pages, unroll).astype(jnp.int32)
-    per = runs.shape[2]
-    ahead = jnp.cumsum(runs, axis=2)
+    array) beside the tables themselves: int32 [lanes, 2 * G + 1] over
+    a lane's G = `table pages // unroll` groups.  A row's first G + 1
+    words: the run groups among the lane's first k, k = 0 to G
+    (`group_runs`); its last G: the lane's groups in the order they
+    are issued, the runs first, each kind ascending.  A chunk takes
+    groups g0 to g0 + k - 1 of its lane, wherever the lane's length
+    cuts it, and those are a SLICE of either list (both ascend): the
+    runs from the count ahead of g0 on, the others from g0 less that
+    count on.  Two loops, no test inside, and nothing here follows the
+    cut.  Sorted by counting, never a `sort`."""
+    runs = group_runs(tables, unroll).astype(jnp.int32)
+    group = jnp.arange(runs.shape[1])
+    ahead = jnp.cumsum(runs, axis=1)
     before = ahead - runs
-    group = jnp.arange(per)
     # a group's place: among the runs, or after all of them among the rest
-    place = jnp.where(runs > 0, before, ahead[..., -1:] + group - before)
-    order = jnp.sum(group[:, None] * (place[..., None] == group), axis=2)
+    place = jnp.where(runs > 0, before, ahead[:, -1:] + group - before)
+    order = jnp.sum(group[:, None] * (place[..., None] == group), axis=1)
     return jnp.concatenate(
-        [jnp.zeros_like(runs[..., :1]), ahead, order], axis=2).reshape(
-            -1, 2 * per + 1)
+        [jnp.zeros_like(runs[:, :1]), ahead, order], axis=1)
+
+
+def stream_scalars(tables, lengths, *, bs: int, pages: int, tile: int,
+                   unroll: int = _ISSUE_UNROLL):
+    """The scalar-prefetch operands of a paged kernel's stream
+    (`stream_chunks`), made ONCE a call from `tables` [lanes, table
+    pages] and `lengths` [lanes] -> ([tables, `issue_order` of them,
+    lengths (at least 1), the lanes' cut: their strides, their counts
+    of chunks (`chunk_cut`)], each flat int32; the chunks a lane has at
+    most).  Every division of the cut is here: a
+    grid step multiplies."""
+    tables = jnp.asarray(tables, jnp.int32)
+    nb = tables.shape[1]
+    lengths = jnp.maximum(lengths.astype(jnp.int32), 1)
+    group = cut_group(pages, tile, unroll)
+    strides, counts = chunk_cut(-(-lengths // bs), pages, group)
+    return [tables.reshape(-1),
+            issue_order(tables, min(unroll, pages)).reshape(-1),
+            lengths, strides.astype(jnp.int32), counts.astype(jnp.int32)
+            ], int(chunk_cut(nb, pages, group)[1])
 
 
 def starts_saved(tables, pages: int, unroll: int = _ISSUE_UNROLL):
     """What `start_pages` saves of a start a page over `tables` [lanes,
-    table pages] (host integers) in chunks of `pages`, as prefix sums a
-    lane over its groups, chunk upon chunk (`group_runs`): [lanes,
-    groups + 1], entry k the starts saved by the first k groups,
-    `unroll - 1` for each that is a run.  A table does not change while
-    a request holds it: computed once, `dma_ops` looks a lane's count
-    up whatever its cursor."""
-    runs = group_runs(np.asarray(tables), pages, unroll)
-    runs = runs.reshape(runs.shape[0], -1)
+    table pages] (host integers) under a cap of `pages` pages a chunk,
+    as prefix sums a lane over its groups (`group_runs`): [lanes,
+    groups + 1], entry k the starts saved by the lane's first k groups,
+    `unroll - 1` for each that is a run.  A chunk starts on a group of
+    the lane's table wherever its length cuts it (`cut_group`), so the
+    groups a lane of n pages issues whole are its first `n // unroll`.
+    A table does not change while a request holds it: computed once,
+    `dma_ops` looks a lane's count up whatever its cursor."""
+    unroll = min(unroll, pages)
+    runs = group_runs(np.asarray(tables), unroll)
     saved = np.zeros((runs.shape[0], runs.shape[1] + 1), np.int64)
-    np.cumsum(runs * (min(unroll, pages) - 1), axis=1, out=saved[:, 1:])
+    np.cumsum(runs * (unroll - 1), axis=1, out=saved[:, 1:])
     return saved
 
 
-def dma_ops(n_pages, pages: int, saved=None, unroll: int = _ISSUE_UNROLL):
+def dma_ops(n_pages, pages: int, saved=None, unroll: int = _ISSUE_UNROLL,
+            tile: int = 1):
     """DMA starts and waits a POOL the kernel performs for a slot of
-    `n_pages` pages (an integer or an integer array) in chunks of
-    `pages`: a start a page, less what the groups that are runs save
-    (`saved`: `starts_saved` of the lanes' tables at this `pages` and
-    `unroll`, a row for each of `n_pages`; None: no group is a run),
-    and for each chunk a wait for each set bit of the pages copied
-    into it."""
-    whole, rest = n_pages // pages, n_pages % pages
-    ops = n_pages + whole * pages.bit_count() + sum(
-        (rest >> bit) & 1 for bit in range(pages.bit_length()))
+    `n_pages` pages (an integer or an integer array) under a cap of
+    `pages` pages a chunk and row tiles of `tile`: a start a page, less
+    what the groups that are runs save (`saved`: `starts_saved` of the
+    lanes' tables at this `pages` and `unroll`, a row for each of
+    `n_pages`; None: no group is a run), and for each chunk of the
+    lane's cut (`chunk_cut`) a wait for each set bit of the pages
+    copied into it."""
+    ops = n_pages + _cut_counts(n_pages, pages, tile, unroll)[2]
     if saved is None:
         return ops
-    unroll = min(unroll, pages)
-    groups = whole * (pages // unroll) + rest // unroll
-    return ops - saved[range(len(saved)), groups]
+    return ops - saved[range(len(saved)), n_pages // min(unroll, pages)]
 
 
 def stream_counts(rows, idle: int, pages: int, tile: int, block_size: int,
                   saved=None, unroll: int = _ISSUE_UNROLL):
     """What one call of a paged kernel's stream (`stream_chunks`) reads
     and does, a plane and a pool, over lanes with `rows` rows under
-    their cursors (an integer array: the lanes that hold a sequence)
-    beside `idle` lanes that hold none, which read ONE page each (the
-    kernels raise a length to 1) -> (pages read, rows multiplied, DMA
-    operations): `rows_multiplied` and `dma_ops` (`saved`, `unroll`:
-    its) over `ceil(rows / block_size)` pages a lane, in chunks of
-    `pages` and row windows from `tile`."""
-    n_pages = -(-rows // block_size)
-    return (idle + int(n_pages.sum()),
-            int(idle * rows_multiplied(1, pages, tile, block_size)
-                + rows_multiplied(n_pages, pages, tile, block_size).sum()),
-            int(idle * dma_ops(1, pages)
-                + dma_ops(n_pages, pages, saved, unroll).sum()))
+    their cursors (an integer array: the lanes that hold a sequence, in
+    the stream's order) beside `idle` lanes that hold none, which read
+    ONE page each (the kernels raise a length to 1) and are counted
+    after them -> (pages read, rows multiplied, DMA operations, pages
+    whose copy the products cover): `rows_multiplied` and `dma_ops`
+    (`saved`, `unroll`: its) over `ceil(rows / block_size)` pages a
+    lane, cut by `chunk_cut` under a cap of `pages` and row windows
+    from `tile`.  COVERED: every chunk's copy but the call's first is
+    in flight while the chunk before it in the stream is multiplied,
+    and counts `min(its pages, the pages of that chunk's row window)`:
+    a lane's later chunks whole (a stride is its own window), its
+    first chunk against the window of the lane before's last."""
+    n_pages = np.concatenate([-(-np.asarray(rows, np.int64) // block_size),
+                              np.ones(idle, np.int64)])
+    first, windows, waits, last_window = _cut_counts(n_pages, pages, tile,
+                                                     unroll)
+    read = int(n_pages.sum())
+    starts = read
+    if saved is not None:
+        held = len(n_pages) - idle
+        starts -= saved[range(held),
+                        n_pages[:held] // min(unroll, pages)].sum()
+    return (read, int(windows.sum()) * block_size,
+            int(starts + waits.sum()),
+            int(read - first.sum()
+                + np.minimum(first[1:], last_window[:-1]).sum()))
 
 
-def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
-                sems, *, unroll: int):
-    """Start the copies of the `n` pages that table entries `base` to
-    `base + n - 1` name (scalars in SMEM; no other entry is read) into
-    pages 0 to `n - 1` of buffer `buf`: for each pool i, from
-    `planes[i]` ([blocks, block_size, width], HBM) into `bufs[i]` ([2,
-    pages, block_size, width], VMEM), counted on `sems[i, buf]`.  The
-    first `n // unroll` groups of `unroll` entries go by `order_ref`
-    (`issue_order` of the same tables; `chunk`: this chunk's row of
-    it): ONE copy of `unroll` pages for each that is a run, then a copy
-    a page for the others; the `n % unroll` last pages a copy each.
-    The one issue loop of the paged kernels (`stream_chunks`' own)."""
-    def copy(i, n_pages=1):
-        """`n_pages` pages from entry i's block on, to page i on."""
-        blk = tables_ref[base + i]
+def start_pages(tables_ref, order_ref, lane, first, n, planes, bufs, buf,
+                sems, *, nb: int, unroll: int):
+    """Start the copies of the `n` pages that entries `first` to
+    `first + n - 1` of lane `lane`'s table name (`tables_ref` [lanes *
+    nb], scalars in SMEM; no other entry is read; `first` on a group of
+    `unroll` entries) into pages 0 to `n - 1` of buffer `buf`: for each
+    pool i, from `planes[i]` ([blocks, block_size, width], HBM) into
+    `bufs[i]` ([2, pages, block_size, width], VMEM), counted on
+    `sems[i, buf]`.  The first `n // unroll` groups go by `order_ref`
+    (`issue_order` of the same tables: the lane's row of it, of which
+    these groups are a slice of either list): ONE copy of `unroll`
+    pages for each that is a run, then a copy a page for the others;
+    the `n % unroll` last pages a copy each.  The one issue loop of the
+    paged kernels (`stream_chunks`' own)."""
+    table = lane * nb
+
+    def copy(entry, n_pages=1):
+        """`n_pages` pages from the block the lane's entry `entry`
+        names on, to the buffer's page `entry - first` on."""
+        blk, i = tables_ref[table + entry], entry - first
         for i_pool, (plane, into) in enumerate(zip(planes, bufs)):
             if n_pages == 1:
                 src, dst = plane.at[blk], into.at[buf, i]
@@ -341,52 +490,70 @@ def start_pages(tables_ref, order_ref, base, chunk, n, planes, bufs, buf,
                 dst = into.at[buf, pl.ds(i, n_pages)]
             pltpu.make_async_copy(src, dst, sems.at[i_pool, buf]).start()
 
-    def page(i, carry=0):
-        copy(i)
+    def page(entry, carry=0):
+        copy(entry)
         return carry
 
     grouped = 0
     if unroll > 1:
-        per = bufs[0].shape[1] // unroll
+        lane_groups = nb // unroll
         groups = n // unroll
         grouped = groups * unroll
-        row = chunk * (2 * per + 1)
-        runs, all_runs = order_ref[row + groups], order_ref[row + per]
-        order = row + per + 1
+        row = lane * (2 * lane_groups + 1)
+        ahead = row + first // unroll
+        runs_ahead = order_ref[ahead]
+        runs = order_ref[ahead + groups] - runs_ahead
+        # the lane's run groups from this chunk's first on, and its
+        # others: those past all the lane's runs in the list
+        order = row + lane_groups + 1
+        of_runs = order + runs_ahead
+        of_others = order + order_ref[row + lane_groups] + (
+            ahead - row - runs_ahead)
 
         def run(i, carry):
-            copy(order_ref[order + i] * unroll, unroll)
+            copy(order_ref[of_runs + i] * unroll, unroll)
             return carry
 
         def pages(i, carry):
-            first = order_ref[order + all_runs + i] * unroll
+            entry = order_ref[of_others + i] * unroll
             for j in range(unroll):
-                copy(first + j)
+                copy(entry + j)
             return carry
 
         jax.lax.fori_loop(0, runs, run, 0)
         jax.lax.fori_loop(0, groups - runs, pages, 0)
-    jax.lax.fori_loop(grouped, n, page, 0)
+    jax.lax.fori_loop(first + grouped, first + n, page, 0)
 
 
-def stream_chunks(tables_ref, order_ref, lengths_ref, planes, bufs, sems,
-                  cursor_ref, *, bs, nb, pages, windows, unroll, before_chunks,
-                  over, before_first_start=lambda: None,
+def stream_chunks(tables_ref, order_ref, lengths_ref, stride_ref, count_ref,
+                  planes, bufs, sems, cursor_ref, *, bs, nb, pages, windows, unroll,
+                  before_chunks, over, before_first_start=lambda: None,
                   before_count=lambda: None,
-                  around_products=lambda lane, c, buf, products: products(),
+                  around_products=lambda lane, chunk, buf, products:
+                  products(),
                   after_chunks=lambda lane, carry: None):
     """Grid step s of a paged kernel: lane s's first
-    `ceil(lengths[s] / bs)` pages through two buffers, a chunk of
-    `pages` pages at a time.  The one chunk loop of the paged kernels:
-    a kernel hands in its operand and what it does with a chunk's rows.
+    `ceil(lengths[s] / bs)` pages through two buffers of `pages` pages,
+    a chunk of the lane's own stride at a time (`chunk_cut`: the lane
+    whole where the buffer holds it, else equal chunks).  The one chunk
+    loop of the paged kernels: a kernel hands in its operand and what
+    it does with a chunk's rows.
 
-    `tables_ref` [lanes * nb], `order_ref` (`issue_order` of them) and
-    `lengths_ref` [lanes]: scalars in SMEM.  `planes()` -> a plane
-    [blocks, bs, width] in HBM a pool (asked at every start: a kernel
-    may read its plane's scalar there); `bufs`: [2, pages, bs, width]
-    in VMEM a pool; `sems` [pools, 2]; `cursor_ref[0]`: the buffer that
-    holds this lane's first chunk, started by the step before.
-    `windows`: `_windows`; `unroll`: `start_pages`' group.
+    `tables_ref` [lanes * nb], `order_ref`, `lengths_ref` [lanes],
+    `stride_ref` and `count_ref` [lanes] (a lane's stride, its count of
+    chunks): scalars in SMEM, `stream_scalars` of the call.  `planes()` -> a
+    plane [blocks, bs, width] in HBM a
+    pool (asked at every start: a kernel may read its plane's scalar
+    there); `bufs`: [2, pages, bs, width] in VMEM a pool; `sems`
+    [pools, 2]; `cursor_ref[0]`: the buffer that holds this lane's
+    first chunk, started by the step before.  `windows`: `_windows`;
+    `unroll`: `start_pages`' group.
+
+    Chunk c + 1 (or the NEXT lane's first) is started at the head of
+    chunk c's iteration and chunk c is waited for before its products:
+    a copy hides under the products of the chunk before it in the
+    stream and under nothing else, which is why a lane's chunks are of
+    one length and a lane the buffer holds is one chunk.
 
     The copies are WAITED FOR on their summed bytes: a DMA semaphore
     counts bytes, so a wait need not name the copy it waits for, only
@@ -407,28 +574,28 @@ def stream_chunks(tables_ref, order_ref, lengths_ref, planes, bufs, sems,
     alone, the cursor set; `before_count()` -> anything, the cursor read
     and the lane's chunks not yet counted; `before_chunks(that)` ->
     (lane, carry), the kernel's operand, handed back in every later
-    call, and the chunk loop's carry; `over(lane, c, buf, n_rows)` ->
-    carry -> carry, a branch of the switch: chunk c's first `n_rows`
-    rows (static) in buffer `buf`; `around_products(lane, c, buf,
-    products)` -> carry, `products()` the switch: what else a kernel
-    does between a chunk's wait and the next chunk; `after_chunks(lane,
-    carry)`, before the cursor is written."""
+    call, and the chunk loop's carry; `over(lane, chunk, buf, n_rows)`
+    -> carry -> carry, a branch of the switch: the first `n_rows` rows
+    (static) of buffer `buf`, `chunk` = (c, first, copied): the lane's
+    chunk c, `copied` pages from page `first` of its table on;
+    `around_products(lane, chunk, buf, products)` -> carry,
+    `products()` the switch: what else a kernel does between a chunk's
+    wait and the next chunk; `after_chunks(lane, carry)`, before the
+    cursor is written."""
     s, n_lanes = pl.program_id(0), pl.num_programs(0)
 
     def n_pages(lane):
         return (lengths_ref[lane] + bs - 1) // bs
 
-    def copied_into(lane, chunk):
-        """Pages of `lane`'s chunk `chunk` that its length reaches."""
-        return jnp.minimum(pages, n_pages(lane) - chunk * pages)
-
     def start(lane, chunk, buf):
         """Start the page copies (a pool each) of `lane`'s chunk
         `chunk` into buffer `buf`: the pages the lane's length reaches,
         so a table entry past it is never read."""
-        start_pages(tables_ref, order_ref, lane * nb + chunk * pages,
-                    lane * -(-nb // pages) + chunk,
-                    copied_into(lane, chunk), planes(), bufs, buf, sems,
+        stride = stride_ref[lane]
+        first = chunk * stride
+        start_pages(tables_ref, order_ref, lane, first,
+                    jnp.minimum(stride, n_pages(lane) - first),
+                    planes(), bufs, buf, sems, nb=nb,
                     unroll=min(unroll, pages))
 
     def wait(copied, buf):
@@ -451,7 +618,8 @@ def stream_chunks(tables_ref, order_ref, lengths_ref, planes, bufs, sems,
 
     first_buf = cursor_ref[0]
     read = before_count()
-    n_chunks = (n_pages(s) + pages - 1) // pages
+    stride, n_chunks = stride_ref[s], count_ref[s]
+    reached = n_pages(s)
     lane, carry = before_chunks(read)
 
     def chunk(c, carry):
@@ -464,35 +632,45 @@ def stream_chunks(tables_ref, order_ref, lengths_ref, planes, bufs, sems,
             start(jnp.where(more, s, s + 1), jnp.where(more, c + 1, 0),
                   1 - buf)
 
-        # the pages copied into this chunk
-        copied = copied_into(s, c)
+        # the pages copied into this chunk, from page `first` on
+        first = c * stride
+        copied = jnp.minimum(stride, reached - first)
         wait(copied, buf)
 
         def products():
-            # over the smallest window they fill
+            # over the smallest window they fill.  Mosaic lowers a
+            # switch to a cascade of tests, branch 0 first: the longest
+            # window, which a lane's equal chunks take, is branch 0
+            # (seven windows in ascending order cost a chunk of 64
+            # pages 0.15 us over the same in descending: PERF.md
+            # section 6, PR 66)
+            longest_first = windows[::-1]
             return jax.lax.switch(
-                sum((copied > w).astype(jnp.int32) for w in windows[:-1]),
-                [over(lane, c, buf, w * bs) for w in windows], carry)
+                sum((copied <= w).astype(jnp.int32)
+                    for w in longest_first[1:]),
+                [over(lane, (c, first, copied), buf, w * bs)
+                 for w in longest_first], carry)
 
-        return around_products(lane, c, buf, products)
+        return around_products(lane, (c, first, copied), buf, products)
 
     after_chunks(lane, jax.lax.fori_loop(0, n_chunks, chunk, carry))
     cursor_ref[0] = (first_buf + n_chunks) % 2
 
 
-def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
-            pages, windows, scale, h, dh, n_kv, writes, d_value=0,
-            selects=False):
+def _kernel(tables_ref, order_ref, lengths_ref, stride_ref, count_ref,
+            layer_ref, *refs, bs, nb, pages, windows, scale, h, dh, n_kv,
+            writes, d_value=0, selects=False):
     """Grid step s: slot s's attention over its first
     `ceil(lengths[s] / bs)` pages of layer `layer[0]`, which
-    `stream_chunks` brings a chunk of `pages` pages at a time, and
-    multiplied over the smallest of `windows` (pages, static) that the
-    copied pages fill.  `d_value`: a latent pool, ONE array whose first
-    `d_value` columns are the value (0: a K pool and a V pool).
-    `selects`: a row mask a slot follows the query ([chunks, 1, rows a
-    chunk] float32, 1 where the row is selected: a chunk's mask a tile
-    of its own, so that the chunk indexes an untiled axis).  The
-    products read a buffer's pages as a chunk's rows."""
+    `stream_chunks` brings a chunk of the slot's stride at a time
+    (`pages` at most), and multiplied over the smallest of `windows`
+    (pages, static) that the copied pages fill.  `d_value`: a latent
+    pool, ONE array whose first `d_value` columns are the value (0: a K
+    pool and a V pool).  `selects`: a row mask a slot follows the query
+    ([1, rows of the table and a chunk's more] float32, 1 where the row
+    is selected; a chunk reads it from its first row on, which is a
+    whole number of lane tiles wherever a slot has a second chunk).
+    The products read a buffer's pages as a chunk's rows."""
     n_pools = 1 if d_value else 2
     refs = iter(refs)
 
@@ -514,7 +692,6 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
     k_buf, v_buf = bufs[0], bufs[-1]
     s = pl.program_id(0)
     layer = layer_ref[0]
-    rows = pages * bs
     d_kv = n_kv * dh
     group = h // n_kv
     # the rows a written row goes back to the pool with: a sublane
@@ -561,59 +738,71 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
             jnp.full((h, 1), -jnp.inf, jnp.float32),
             jnp.zeros((h, 1), jnp.float32))
 
-    def row_copies(c, buf):
+    def row_copies(first, buf):
         """This position's K and V on their way back to the pool: the
-        `group_rows` rows of the chunk in buffer `buf` that hold row
-        `wrow_ref[s]` of the slot's table, to their place in its
-        page."""
-        at = wrow_ref[s] - c * rows
-        first = pl.multiple_of(at // group_rows * group_rows, group_rows)
-        blk = tables_ref[s * nb + c * pages + at // bs]
-        dst = pl.ds(pl.multiple_of(first % bs, group_rows), group_rows)
+        `group_rows` rows of the chunk in buffer `buf` (the table's
+        pages from `first` on) that hold row `wrow_ref[s]` of the
+        slot's table, to their place in its page."""
+        at = wrow_ref[s] - first * bs
+        tile = pl.multiple_of(at // group_rows * group_rows, group_rows)
+        blk = tables_ref[s * nb + first + at // bs]
+        dst = pl.ds(pl.multiple_of(tile % bs, group_rows), group_rows)
         return tuple(
             pltpu.make_async_copy(back.at[buf, at // bs, dst],
                                   out.at[layer, blk, dst], wsems.at[i])
             for i, (back, out) in enumerate(zip(bufs, outs)))
 
-    def put_row(c, buf):
-        """Where chunk c holds the row this tick writes (`wrow_ref[s]`
-        of the slot's table; negative: a slot that writes nothing),
-        put this position's K and V into its page as it lies in VMEM,
-        before the products read it, and start the page's rows back to
-        the pool.  -> whether it did."""
+    def put_row(first, copied, buf):
+        """Where the chunk of `copied` pages from page `first` on holds
+        the row this tick writes (`wrow_ref[s]` of the slot's table;
+        negative: a slot that writes nothing), put this position's K
+        and V into its page as it lies in VMEM, before the products
+        read it, and start the page's rows back to the pool.  ->
+        whether it did."""
         wrow = wrow_ref[s]
-        here = (wrow >= c * rows) & (wrow < (c + 1) * rows)
+        here = (wrow >= first * bs) & (wrow < (first + copied) * bs)
 
         @pl.when(here)
         def _put():
-            at = wrow - c * rows
+            at = wrow - first * bs
             page = at // bs
             mine = iota((bs, 1), 0) == at % bs
             for into, new_ref in zip(bufs, new_refs):
                 into[buf, page] = jnp.where(mine, new_ref[0],
                                             into[buf, page])
-            for copy in row_copies(c, buf):
+            for copy in row_copies(first, buf):
                 copy.start()
 
         return here
 
-    def around_written_row(_, c, buf, products):
+    def around_written_row(_, chunk, buf, products):
         if not writes:
             return products()
-        written = put_row(c, buf)
+        _, first, copied = chunk
+        written = put_row(first, copied, buf)
         carry = products()
 
         # the row's way back to the pool lay under the products
         @pl.when(written)
         def _row_is_back():
-            for copy in row_copies(c, buf):
+            for copy in row_copies(first, buf):
                 copy.wait()
         return carry
 
-    def over(lane, c, buf, n_rows):
+    # a chunk's first row is a whole number of lane tiles wherever a
+    # slot has a second chunk: it starts on an issue group
+    lane_tiled = (min(_ISSUE_UNROLL, pages) * bs) % 128 == 0
+
+    def over(lane, chunk, buf, n_rows):
         """The online softmax over the first `n_rows` rows (static) of
-        chunk c."""
+        the chunk of `copied` pages from page `first` of the table
+        on."""
         length, q = lane[:2]
+        _, first, copied = chunk
+        # a window's rows past the copied pages are nobody's (under a
+        # stride of whole row tiles only a slot's last chunk has any)
+        base = first * bs
+        seen_to = jnp.minimum(length - base, copied * bs)
 
         def multiply(carry):
             m, l = carry
@@ -621,10 +810,10 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
             sc = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            row = c * rows + iota(sc.shape, 1)        # [H, n_rows]
-            seen = row < length
+            seen = iota(sc.shape, 1) < seen_to            # [H, n_rows]
             if selects:
-                seen &= sel_ref[0, c, :, :n_rows] > 0.0
+                at = pl.multiple_of(base, 128) if lane_tiled else base
+                seen &= sel_ref[0, :, pl.ds(at, n_rows)] > 0.0
             sc = jnp.where(seen, sc, -jnp.inf)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
             # nothing selected so far: exp(-inf - -inf) is no number
@@ -656,7 +845,7 @@ def _kernel(tables_ref, order_ref, lengths_ref, layer_ref, *refs, bs, nb,
                 for g in range(n_kv))
 
     stream_chunks(
-        tables_ref, order_ref, lengths_ref,
+        tables_ref, order_ref, lengths_ref, stride_ref, count_ref,
         lambda: tuple(hbm.at[layer] for hbm in hbms), bufs, sems,
         cursor_ref, bs=bs, nb=nb, pages=pages, windows=windows,
         unroll=_ISSUE_UNROLL, before_first_start=zero_values,
@@ -678,8 +867,9 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
     tables [S, NB] int32 block ids, lengths [S] int32 (rows of its
     table, in table order, that slot s attends over: at least 1, and
     no page past `ceil(length / block_size)` is read), layer an int32
-    scalar, traced.  A chunk is `pages` pages, its smallest row window
-    `tile` of them.  Returns [S, H*dh] float32: head i's
+    scalar, traced.  A chunk is `pages` pages AT MOST (the scratch is
+    two of them a pool; `chunk_cut` cuts a slot's pages under it), its
+    smallest row window `tile` of them.  Returns [S, H*dh] float32: head i's
     `softmax(scale * q_i . K_g^T) . V_g` over its K/V head g.
 
     `write` = (k [S, Dkv], v [S, Dkv], rows [S] int32): this
@@ -715,11 +905,9 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
     def hbm():
         return pl.BlockSpec(memory_space=pl.ANY)
 
-    tables = jnp.asarray(tables, jnp.int32)
-    scalars = [tables.reshape(-1),
-               issue_order(tables, pages).reshape(-1),
-               jnp.maximum(lengths.astype(jnp.int32), 1),
-               jnp.asarray(layer, jnp.int32).reshape(1)]
+    scalars, n_chunks = stream_scalars(tables, lengths, bs=bs, pages=pages,
+                                       tile=tile)
+    scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
     inputs = [q.astype(pool_k.dtype).reshape((s_n,) + block[1:])]
     in_specs = [pl.BlockSpec(block, slot)]
     out_specs = [pl.BlockSpec(out_block, slot)]
@@ -731,14 +919,13 @@ def paged_attention(q, pool_k, pool_v, tables, lengths, layer, *,
                 pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32)]
     if select is not None:
-        # a slot's mask by chunk: [chunks, 1, rows a chunk], the last
-        # chunk's rows past the table unselected
-        n_chunks = -(-nb // pages)
+        # a slot's mask in table order, and unselected rows after it
+        # for the window of a last chunk that starts past row 0
+        rows = (nb + (n_chunks > 1) * pages) * bs
         mask = jnp.pad(select.astype(jnp.float32),
-                       ((0, 0), (0, (n_chunks * pages - nb) * bs)))
-        inputs.append(mask.reshape(s_n, n_chunks, 1, pages * bs))
-        in_specs.append(pl.BlockSpec((1, n_chunks, 1, pages * bs),
-                                     lambda s, *_: (s, 0, 0, 0)))
+                       ((0, 0), (0, rows - nb * bs)))
+        inputs.append(mask.reshape(s_n, 1, rows))
+        in_specs.append(pl.BlockSpec((1, 1, rows), slot))
     aliases = {}
     if write is not None:
         *news, rows = write
@@ -799,14 +986,16 @@ def select_paged_attention(
         return None, reason
     page_bytes = (int(block_size) * int(kv_width or d_model)
                   * jnp.dtype(_KV_DTYPES[kv_dtype]).itemsize)
-    chunk = max(1, _CHUNK_BYTES // page_bytes)
+    chunk = _CHUNK_BYTES // page_bytes
     row_tile = max(1, _TILE_ROWS // int(block_size))
 
     def tiling(table_pages):
-        """(pages a chunk, pages a row tile) over slots that hold
-        `table_pages` pages (a table's, a ring's): a chunk no longer
-        than the table, a tile no longer than the chunk."""
-        pages = min(chunk, int(table_pages))
+        """(pages a chunk at most, pages a row tile) over slots that
+        hold `table_pages` pages (a table's, a ring's): the table
+        itself where `_CHUNK_BYTES` hold it (every slot is then ONE
+        chunk), else the whole groups they hold (`chunk_cap`); a tile
+        no longer than the chunk."""
+        pages = chunk_cap(int(table_pages), chunk, row_tile)
         return pages, min(row_tile, pages)
 
     def attend(q, pool_k, pool_v, tables, lengths, layer, scale,

@@ -12,11 +12,12 @@ reads it back into a product that writes [S, heads, rows] float32, and
 reads that again: seven times the bytes the cursors need (PERF.md
 section 6, PR 54).  This kernel is the plain sibling of the paged
 attention kernel and runs that kernel's own page stream
-(`paged_attention.stream_chunks`, two measured constants apart): tables
-and a LENGTH a lane on the scalar-prefetch lane, the pool left in HBM,
-the `ceil(length / block_size)` pages of a lane and no other through
-two VMEM buffers a chunk at a time, the next lane's first chunk in
-flight under this lane's last.  Nothing is written to the
+(`paged_attention.stream_chunks` under `stream_scalars`' cut, two
+measured constants apart): tables and a LENGTH a lane on the
+scalar-prefetch lane, the pool left in HBM, the
+`ceil(length / block_size)` pages of a lane and no other through two
+VMEM buffers a chunk at a time, the next lane's first chunk in flight
+under this lane's last.  Nothing is written to the
 pool (it is read only and not aliased: the key's write is the caller's
 scatter, before the call, by data dependence) and no softmax joins the
 chunks: a chunk's scores go to the chunk's columns of the lane's
@@ -26,8 +27,8 @@ A chunk's work: the index queries `q` [heads, width] (cast to the
 pool's dtype: what the MXU rounds them to on the XLA path too) times
 the chunk's keys transposed, float32 accumulation; relu; times `w`
 [heads, 1] float32; summed over the heads in float32, over the
-smallest row window (128 rows, doubled up to the chunk) that holds the
-pages copied into it.  [S, rows of the table] float32 leaves the
+smallest row window (128 rows doubled up to the buffer, or a stride)
+that holds the pages copied into it.  [S, rows of the table] float32 leaves the
 kernel, and of it ONLY the rows under a lane's length are defined: a
 window's rows past the copied pages hold what the buffer held, a chunk
 the length never reached what VMEM held.  The caller's mask makes them
@@ -51,8 +52,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import (_KV_DTYPES, _TILE_ROWS, _windows, issue_order,
-                              paged_attention_supports, stream_chunks)
+from .paged_attention import (_KV_DTYPES, _TILE_ROWS, _windows, chunk_cap,
+                              paged_attention_supports, stream_chunks,
+                              stream_scalars)
 
 __all__ = ["paged_index_scores", "select_index_scores"]
 
@@ -63,7 +65,9 @@ __all__ = ["paged_index_scores", "select_index_scores"]
 # and every chunk's edge is a place where the engine can run dry: on
 # the v5e the GLM cell's two planes take 1.16, 0.99, 0.90, 0.84 and
 # 0.79 ms at chunks of 128 KiB to 2 MiB.  2 MiB is the cell's whole
-# table (432 pages) in one chunk; two chunks are the whole scratch.
+# table (432 pages) in one chunk; two such buffers are the whole
+# scratch.  A longer table's lanes would be cut in equal chunks under
+# it (`paged_attention.chunk_cut`).
 _CHUNK_BYTES = 2 * 1024 * 1024
 # Table entries the issue loop takes a loop iteration, and the group
 # that goes as ONE copy where they are a run (`start_pages`).  With
@@ -74,19 +78,24 @@ _CHUNK_BYTES = 2 * 1024 * 1024
 _ISSUE_UNROLL = 16
 
 
-def _kernel(tables_ref, order_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm,
-            o_ref, buf_ref, sems, cursor_ref, *, bs, nb, pages, windows):
+def _kernel(tables_ref, order_ref, lengths_ref, stride_ref, count_ref,
+            plane_ref, q_ref, w_ref, hbm, o_ref, buf_ref, sems, cursor_ref, *,
+            bs, nb, pages, windows):
     """Grid step s: lane s's scores over its first
     `ceil(lengths[s] / bs)` pages of plane `plane[0]`, which
-    `paged_attention.stream_chunks` brings a chunk of `pages` pages at
-    a time, and multiplied over the smallest of `windows` (pages,
-    static) that the copied pages fill: the product reads `buf_ref`'s
-    pages as a chunk's rows.  `o_ref` [1, chunks, 1, rows a chunk]: a
-    chunk's scores a tile of their own, so that the chunk indexes an
-    untiled axis."""
-    def over(lane, c, buf, n_rows):
-        """The scores of the first `n_rows` rows (static) of chunk c."""
+    `paged_attention.stream_chunks` brings a chunk of the lane's stride
+    at a time (`pages` at most), and multiplied over the smallest of
+    `windows` (pages, static) that the copied pages fill: the product
+    reads `buf_ref`'s pages as a chunk's rows.  `o_ref` [1, chunks, 1,
+    rows a chunk at most]: a chunk's scores a tile of their own, so
+    that the chunk indexes an untiled axis (a store that starts at a
+    lane the kernel computes is what Mosaic's compiler does not
+    survive)."""
+    def over(lane, chunk, buf, n_rows):
+        """The scores of the first `n_rows` rows (static) of the
+        lane's chunk c."""
         q, w = lane                                 # [H, D], [H, 1]
+        c = chunk[0]
 
         def multiply(carry):
             keys = buf_ref[buf, :n_rows // bs].reshape(n_rows, -1)
@@ -100,7 +109,7 @@ def _kernel(tables_ref, order_ref, lengths_ref, plane_ref, q_ref, w_ref, hbm,
         return multiply
 
     stream_chunks(
-        tables_ref, order_ref, lengths_ref,
+        tables_ref, order_ref, lengths_ref, stride_ref, count_ref,
         lambda: (hbm.at[plane_ref[0]],), (buf_ref,), sems, cursor_ref,
         bs=bs, nb=nb, pages=pages, windows=windows, unroll=_ISSUE_UNROLL,
         before_chunks=lambda _: ((q_ref[0], w_ref[0]), 0), over=over)
@@ -117,22 +126,20 @@ def paged_index_scores(q, w, pool, tables, lengths, plane, *, pages: int,
     tables [S, NB] int32 block ids, lengths [S] int32 (rows of its
     table, in table order, that lane s scores: at least 1, and no page
     past `ceil(length / block_size)` is read), plane an int32 scalar,
-    traced.  A chunk is `pages` pages, its smallest row window `tile`
-    of them.  Returns [S, NB * block_size] float32: row r of lane s
+    traced.  A chunk is `pages` pages at most (`paged_attention.
+    chunk_cut` cuts a lane's pages under it), its smallest row window
+    `tile` of them.  Returns [S, NB * block_size] float32: row r of lane s
     reads sum_j w[s, j] relu(q[s, j] . key r of its table) where
     r < lengths[s], and is NOT DEFINED past it (whatever the buffers
     held, no number among them)."""
     s_n, h, d = q.shape
     bs, nb = pool.shape[2], tables.shape[1]
-    n_chunks = -(-nb // pages)
-    tables = jnp.asarray(tables, jnp.int32)
-    scalars = [tables.reshape(-1),
-               issue_order(tables, pages, _ISSUE_UNROLL).reshape(-1),
-               jnp.maximum(lengths.astype(jnp.int32), 1),
-               jnp.asarray(plane, jnp.int32).reshape(1)]
+    scalars, n_chunks = stream_scalars(tables, lengths, bs=bs, pages=pages,
+                                       tile=tile, unroll=_ISSUE_UNROLL)
+    scalars.append(jnp.asarray(plane, jnp.int32).reshape(1))
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, nb=nb, pages=pages,
-                          windows=_windows(pages, tile)),
+                          windows=_windows(pages, tile, _ISSUE_UNROLL)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars), grid=(s_n,),
             in_specs=[pl.BlockSpec((1, h, d), lambda s, *_: (s, 0, 0)),
@@ -152,8 +159,17 @@ def paged_index_scores(q, w, pool, tables, lengths, plane, *, pages: int,
         name="paged_index_scores",
     )(*scalars, q.astype(pool.dtype),
       w.astype(jnp.float32).reshape(s_n, h, 1), pool)
+    out = out.reshape(s_n, n_chunks * pages * bs)
+    if n_chunks > 1:
+        # a lane's chunks lie a stride apart in its table and a whole
+        # buffer apart here: each row from its chunk's tile (a table
+        # one buffer does not hold: no cell has one)
+        stride = scalars[3][:, None] * bs
+        row = jnp.arange(nb * bs, dtype=jnp.int32)[None, :]
+        out = jnp.take_along_axis(
+            out, row // stride * (pages * bs) + row % stride, axis=1)
     # the last chunk's rows past the table are nobody's
-    return out.reshape(s_n, n_chunks * pages * bs)[:, :nb * bs]
+    return out[:, :nb * bs]
 
 
 def select_index_scores(
@@ -181,17 +197,17 @@ def select_index_scores(
         return None, reason
     page_bytes = (int(block_size) * int(index_head_dim)
                   * jnp.dtype(_KV_DTYPES[kv_dtype]).itemsize)
-    chunk = max(1, _CHUNK_BYTES // page_bytes)
+    chunk = _CHUNK_BYTES // page_bytes
     row_tile = max(1, _TILE_ROWS // int(block_size))
 
     def tiling(table_pages):
-        """(pages a chunk, pages a row tile) over lanes that hold
-        `table_pages` pages: the table in as few chunks as
-        `_CHUNK_BYTES` allows, all of one length (no rows past the
-        table where the pages divide), a tile no longer than the
+        """(pages a chunk at most, pages a row tile) over lanes that
+        hold `table_pages` pages: the table itself where `_CHUNK_BYTES`
+        hold it (every lane is then ONE chunk: every cell's), else the
+        whole groups they hold, under which `paged_attention.chunk_cut`
+        cuts a lane in equal chunks; a tile no longer than the
         chunk."""
-        table_pages = int(table_pages)
-        pages = -(-table_pages // -(-table_pages // chunk))
+        pages = chunk_cap(int(table_pages), chunk, row_tile, _ISSUE_UNROLL)
         return pages, min(row_tile, pages)
 
     def scores(q, w, pool, tables, lengths, plane):

@@ -641,9 +641,10 @@ class PagedDecoder:
     # "paged_attention_ring" ("...:masked_pages" where the ring is
     # longer than its window)
     kernels: Dict[str, str]
-    # (pages a chunk, pages of its smallest row window) of the kernel
-    # over a slot's table and over its ring: what it copies and
-    # multiplies in (`kernels.paged_attention.rows_multiplied`); None
+    # (pages a chunk at most, pages of its smallest row window) of the
+    # kernel over a slot's table and over its ring: what it copies and
+    # multiplies in (`kernels.paged_attention.chunk_cut`,
+    # `rows_multiplied`); None
     # on the gather path, and for a ring where there is none
     attention_tiling: Optional[Tuple[Any, Any]]
     # tick_counts(cursors, slots, windowed=False, saved=None) -> dict:
@@ -2434,10 +2435,12 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         `slots`, its lanes; `windowed`: a `step_window` tick, which
         gathers always.  `kv_pages_read` of `kv_pages_table`,
         `kv_rows_multiplied` and, through the kernel alone,
-        `kv_dma_ops`: the K/V pages the step's attention reads of those
-        the lanes' tables and rings hold, the rows its two products run
-        over and the DMA starts and waits it performs, summed over
-        lanes, attention layers and (the last) pools.  Through the
+        `kv_dma_ops` and `kv_pages_covered`: the K/V pages the step's
+        attention reads of those the lanes' tables and rings hold, the
+        rows its two products run over, the DMA starts and waits it
+        performs and the pages whose copy is in flight under as many
+        pages' products (the lanes in `cursors`' order), summed over
+        lanes, attention layers and (the DMA operations) pools.  Through the
         Pallas kernel (`kernels`) they are what
         `kernels.paged_attention.stream_counts` says of the table's
         stream and the ring's at `attention_tiling` (`saved`:
@@ -2494,8 +2497,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 streamed += n_win * np.array(_paged_attention.stream_counts(
                     np.minimum(rows, nw * bs), idle, *tiling[1], bs,
                     saved.get("ring")))
-            read, multiplied, dma = map(int, streamed)
+            read, multiplied, dma, covered = map(int, streamed)
             counts["kv_dma_ops"] = (1 if latent else 2) * dma
+            counts["kv_pages_covered"] = covered
         counts["kv_pages_read"] = read
         counts["kv_pages_table"] = table
         counts["kv_rows_multiplied"] = multiplied
@@ -2520,7 +2524,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             if index_tiling is None:
                 counts["index_pages_read"] = counts["index_pages_table"]
             else:
-                read, _, dma = _paged_attention.stream_counts(
+                read, _, dma, _ = _paged_attention.stream_counts(
                     rows, idle, *index_tiling, bs, saved.get("index"),
                     _index_scores.unroll)
                 counts["index_pages_read"] = n_index * read

@@ -655,7 +655,7 @@ def test_configuration_file_describes_the_block_and_its_arithmetic():
     pool_gb = dec.bytes_per_block * 64 * 256 / 1e9
     assert round(pool_gb, 2) == 1.68
     assert weights_gb + pool_gb >= 11.8                  # held, of 16
-    assert dec.attention_tiling == ((51, 8), None)
+    assert dec.attention_tiling == ((64, 8), None)
     ids = np.zeros(9, np.int32)
     g = _weights(_decoder())
     assert set(m["compare"]["limits"]) <= set(

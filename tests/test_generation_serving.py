@@ -1198,8 +1198,10 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
     # and PR 40: the layers with experts, on a block that has any
     # and PR 41: the rows the attention's products run over
     # and PR 46: the kernel's DMA starts and waits, where it runs
+    # and PR 66: the pages whose copy its products cover
     extra = ({"ahead", "kv_wait", "moe_layers", "kv_rows_multiplied",
-              "kv_dma_ops"} | set(loop) | set(dec.step_counters))
+              "kv_dma_ops", "kv_pages_covered"} | set(loop)
+             | set(dec.step_counters))
     for got, old in zip(ticks, want):
         assert {k: v for k, v in got.items() if k not in extra} == old
         # a start a page, and a wait a chunk at the least
@@ -1233,13 +1235,20 @@ def test_tick_span_attributes_equal_the_old_walks(kind, streamed):
 # chunks of 4 the lanes take 1 + 1, 3 + 2, 6 + 1 + 1 and the idle one
 # 1 + 1, 17; in one chunk of 6 they take 1 + 1, 3 + 2, 6 + 2 and 1 + 1,
 # 17 as well; a ring of 2 pages 1 + 1, 1 + 1, 2 + 1 and 2 + 1, 10.
+# Pages whose copy lies under as many pages' products (every chunk copy
+# but the call's first against the row window of the chunk before it in
+# the stream, the idle lane last: PR 66): in chunks of 4 the lanes'
+# windows are 2, 4, 4 then 2, and 2, so 3 pages under 2 count 2, 4 under
+# 4, 2 under 4 and the idle page under 2: 9; in one chunk of 6 (windows
+# 2, 4, 6) 3 under 2, 6 under 4 and 1 under 6: 7; a ring of 2 pages
+# (lanes of 1, 2, 2 and the idle 1, every window 2) 2 + 2 + 1, 5.
 _TICK_COUNTS = {
     # 2 layers on a table of 6 pages
     "table": dict(
         tiling=((4, 2), None),
         streamed={"kv_pages_read": 2 * 11, "kv_pages_table": 48,
                   "kv_rows_multiplied": 2 * 14 * 4,
-                  "kv_dma_ops": 2 * 2 * 17},
+                  "kv_dma_ops": 2 * 2 * 17, "kv_pages_covered": 2 * 9},
         gathered={"kv_pages_read": 48, "kv_pages_table": 48,
                   "kv_rows_multiplied": 192}),
     # a full layer, and 3 sliding ones on a ring of 2 pages (window 8:
@@ -1249,7 +1258,9 @@ _TICK_COUNTS = {
         streamed={"kv_pages_read": 11 + 3 * (1 + 1 + 2 + 2),
                   "kv_pages_table": 4 * (6 + 3 * 2),
                   "kv_rows_multiplied": (14 + 3 * 8) * 4,
-                  "kv_dma_ops": 2 * (17 + 3 * 10), "past_window": 2, "kv_rows_full": 1 + 10 + 22,
+                  "kv_dma_ops": 2 * (17 + 3 * 10),
+                  "kv_pages_covered": 7 + 3 * 5, "past_window": 2,
+                  "kv_rows_full": 1 + 10 + 22,
                   "kv_rows_win": 1 + 8 + 8, "moe_layers": 4},
         gathered={"kv_pages_read": 48, "kv_pages_table": 48,
                   "kv_rows_multiplied": 192, "past_window": 2,
@@ -1261,7 +1272,8 @@ _TICK_COUNTS = {
         tiling=((6, 2), None),
         streamed={"kv_pages_read": 11, "kv_pages_table": 24,
                   "kv_rows_multiplied": 14 * 4, "kv_dma_ops": 2 * 17,
-                  "state_lanes": 3, "state_resets": 1, "moe_layers": 4},
+                  "kv_pages_covered": 7, "state_lanes": 3,
+                  "state_resets": 1, "moe_layers": 4},
         gathered={"kv_pages_read": 24, "kv_pages_table": 24,
                   "kv_rows_multiplied": 96, "state_lanes": 3,
                   "state_resets": 1, "moe_layers": 4}),
@@ -1271,7 +1283,7 @@ _TICK_COUNTS = {
         streamed={"loop_passes": 3, "kv_planes": 6,
                   "kv_pages_read": 6 * 11, "kv_pages_table": 144,
                   "kv_rows_multiplied": 6 * 14 * 4,
-                  "kv_dma_ops": 6 * 2 * 17},
+                  "kv_dma_ops": 6 * 2 * 17, "kv_pages_covered": 6 * 9},
         gathered={"loop_passes": 3, "kv_planes": 6,
                   "kv_pages_read": 144, "kv_pages_table": 144,
                   "kv_rows_multiplied": 576}),

@@ -77,11 +77,13 @@ POOLS = {
     # mask a slot over the table's 432 pages: 2048 rows selected), 64
     # heads whose value is the latent's 512 columns, 5 planes
     "glm-5.2-docqa64-selected": (64, 64, 640, 16, 432, 5, "bf16"),
-    # a K and a V pool in chunks of 51 pages too: the one chunk length
-    # of the cells that is no power of two, so its waits on summed
-    # bytes take four sizes and its issue loop a remainder (a row of 5
-    # heads of 128: DeepSeek's page bytes under grouped heads)
-    "kv-chunks-of-51": (16, 20, 640, 16, 128, 2, "bf16"),
+    # a K and a V pool under a cap of 51 pages: a table of 51 is one
+    # chunk of no whole number of issue groups and no power of two, so
+    # its waits on summed bytes take four sizes and its issue loop a
+    # remainder (a row of 5 heads of 128: DeepSeek's page bytes under
+    # grouped heads; the cells' caps since PR 66 are whole groups but
+    # for dots3's ring of 33)
+    "kv-chunks-of-51": (16, 20, 640, 16, 51, 2, "bf16"),
     # K-EXAONE's row (8 K/V heads of 128) on the ONE attention layer of
     # four, over docqa64's table of 432 pages a lane (PR 59)
     "solar-open2-docqa64": (64, 64, 1024, 16, 432, 1, "bf16"),
@@ -109,13 +111,13 @@ SELECTED = {"glm-5.2-docqa64-selected": 2048,
 # issue loop's groups follow
 CHUNK_PAGES = {
     "chip_smoke-fp32": 16, "chip_smoke-bf16": 32, "opt-1.3b-closed32": 16,
-    "olmoe-chat32": 16, "mellum2-agent96-table": 64,
-    "mellum2-agent96-ring": 64, "granite-chat64": 32, "ouro-chat12": 16,
-    "k-exaone-chat64-table": 32, "k-exaone-chat64-ring": 8,
-    "deepseek-v2-agent64-latent": 51, "kv-chunks-of-51": 51,
-    "longcat-flash-agent64-latent": 51, "glm-5.2-docqa64-selected": 51,
-    "solar-open2-docqa64": 32, "ling-3.0-flash-agent128-latent": 51,
-    "dots3-docqa64-table": 51, "dots3-docqa64-ring": 28,
+    "olmoe-chat32": 16, "mellum2-agent96-table": 80,
+    "mellum2-agent96-ring": 64, "granite-chat64": 40, "ouro-chat12": 16,
+    "k-exaone-chat64-table": 40, "k-exaone-chat64-ring": 8,
+    "deepseek-v2-agent64-latent": 64, "kv-chunks-of-51": 51,
+    "longcat-flash-agent64-latent": 64, "glm-5.2-docqa64-selected": 64,
+    "solar-open2-docqa64": 40, "ling-3.0-flash-agent128-latent": 64,
+    "dots3-docqa64-table": 64, "dots3-docqa64-ring": 33,
 }
 
 
@@ -314,61 +316,61 @@ def test_index_scores_lower_for_tpu(name):
     assert _lowered(name).count(MOSAIC_CALL) == 1
 
 
-# sha256 of each kernel's Mosaic module (`mosaic_modules`), taken at
-# the parent commit of the PR that put both kernels' page stream into
-# one function (`paged_attention.stream_chunks`, PR 57): a refactoring
-# at trace time emits the parent's operations in the parent's order.
-# The v5e readings in PERF.md section 6 are of THESE kernels: a PR that
-# means to move one re-pins it from its own tree and times parent and
-# change with `tools/kernel_pace.py`.
+# sha256 of each kernel's Mosaic module (`mosaic_modules`), taken from
+# PR 66's own tree, which moved every one of them (a lane's stride and
+# count of chunks on the scalar-prefetch lane, the row windows of the
+# strides, the switch's longest window first, a selection read from a
+# chunk's first row on) and timed parent and change with
+# `tools/kernel_pace.py`: the v5e readings in PERF.md section 6, PR 66,
+# are of THESE kernels.  A refactoring at trace time emits these
+# operations in this order; a PR that means to move one re-pins it from
+# its own tree and times both.
 MOSAIC_SHA256 = {
-    # (PR 65's three, taken from its own tree: the kernels' files are
-    # the parent's, these are their modules at geometries new with it)
+    # (the geometries PR 65 brought)
     "dots3-docqa64-table":
-        "4186e38494e1177303a4ad1cd3fbf1c5fb70d61fe5067b55f2ed66b25300e6d8",
+        "a08fadbffc51b87afc0c1f5eee0ba2457904286e82a29a6dd578ac4ca97318aa",
     "dots3-docqa64-ring":
-        "b609ef9b4fb6bb20a3723648caabe560c225e0ed7270f8427ee76014f0d2447c",
+        "6e4fd2eaf46391df2dd75760a100ec6c35ad596d5e196c1dab3d235702279baf",
     "dots3-docqa64":
-        "e8de98f66eeb9c1ed71554ad5dc12bdeceb987c93bffeb60bb4a82c2c9e9ce0a",
+        "273606f80ecb7604a0a9ea55bb2ba5a4171bd90ff040aba6295e572cdbd2982f",
     "chip_smoke-bf16":
-        "8b3fb392118116ceb9ad07141978a3ba36a2a3654c50b1837b12ba4576985dc9",
+        "885657113170485435814d3d481a1431cd26e4a844dcc3d82a03431564535480",
     "chip_smoke-fp32":
-        "2c3f48cc795205cc521afa624a94697d991e92d9af930dc8237aed154e1664cf",
+        "170669b9eb1d68ee7e67e4baad2e637ba0f276018bf934d5729e28b12668a587",
     "deepseek-v2-agent64-latent":
-        "be3a9f5b2df357b759570073c26cf27ca84c467424dfbee4621614fa6cc0ead2",
+        "4b82bbd556315f9ca91b582a9d84a32ba5fa328d76f981426cabc58bc7ebfacb",
     "glm-5.2-docqa64-selected":
-        "0d41b4167bd12f8cffbed550f5175826db5921d5491cfc527307a4ed222c64dc",
+        "8a6bc2d01f90f52abcd5b53a13aa61651f68c95f70c85210e798d7923de806e3",
     "granite-chat64":
-        "7d620c4e4dd1270ff25fc2e00d7e8735dadc8174f91058db0b5dec1b472d685f",
+        "e4fdf13f18c1921a8ce82da49d78728f7a7ffdad5a13e1773a4a434f0813356e",
     "k-exaone-chat64-ring":
-        "54320e32fb33a366d0a6e951def3b99b0c5be0536ce084be7b77d996af371943",
+        "5696d992651821808b836303a96ba45947baad5890d1f9c583390c6750c75618",
     "k-exaone-chat64-table":
-        "5ac1fee06575b16bb5a6303bbf77ff61223aad4f8372f9e8b9c6fa26f1b35d85",
+        "bca36a91f7912acaba2ee64e8a82c6fb79cee4a6bdf0fcc0a2a8ef795ecb63c6",
     "kv-chunks-of-51":
-        "1a5c31b009f39440d5471a184091ea20d39fd12a114fc34a09a66fa0154e41bb",
-    # taken at the tree that added the geometry (PR 62: no kernel file
-    # moved; the cell's readings are of this module)
+        "f8667e276ce7113243ef0cd3fed63be5af708564c066086b3489224ceb25c296",
+    # (the geometry PR 62 brought)
     "ling-3.0-flash-agent128-latent":
-        "bb9fc52c41318c7ff2a448c5c15f71e9c125ce8e6a95f809ef0a5545158f2aed",
+        "a7832d6541bd23902835ae77fea0ad08ebc16cdf230a24cddb937ebcb3627467",
     "longcat-flash-agent64-latent":
-        "24bac2245d0e8047d0c58ac2e2da805f371feae6495f00725fd417fb5b7650e7",
+        "2d7d22afc4b2392d18000518a0ca373f0d4c6b4576a6a05ad18298ca81a882fd",
     "mellum2-agent96-ring":
-        "50c4e356060f8b904ea3882adfab17059f57ef7d96d0a5fbe8db72fcbea29783",
+        "d0a49d1184cde5a2070932facd6d2383f3286df79d8d57e25aefcdfd91ddd928",
     "mellum2-agent96-table":
-        "e9b0b3b465fbb1525069606131a0471967786cdb9699c60aa22ec93cc3d4600c",
+        "ac22a655211a6251a28125e0338656398625ff86042c60a33fec87e6f1d69d0d",
     "olmoe-chat32":
-        "e596acc2d03e869b36b218239f8cb6713e5ea90e157bff9f61980df3d1622dd6",
+        "e14e18ea005c7c27f5d9978a8ac0440acbd8f830af1067f358975d7c5072b3e7",
     "opt-1.3b-closed32":
-        "c460dd3873dcac6b759f99b007a3c077a8c7b02a317a785bb88f80b60014308e",
+        "c48778ca859e0e024d157e0fb3c8fc00a07213a6cf73e861f8560831e1f3804a",
     "solar-open2-docqa64":
-        "137cf5fe5ce4434cd02d17e904f7f444d308d7d1506608366dc5cd3572e2f409",
+        "4a3f7b7d6cbd664dbd53b736c3537f4df1a75117abdf70abc1726127aa46ae05",
     "ouro-chat12":
-        "c03f950b688b3a4f7291b26fc039b46c27e759484082c301b5f2c59738dc7b63",
+        "c25aba33b0fe3c02ff8a5f593e62ea3889338507fa3028574f0d510981b574c5",
     # the two index planes
     "fp32-pages-of-8":
-        "09a2e5ceac6de785a7e32190c385bca34717421dc4a54bb0e5b73af172ba105a",
+        "fba18d6b462124fc1389c7dbf2ca706448ebd1a65f2ec8dab558c442aca5b02d",
     "glm-5.2-docqa64":
-        "61b66068504f80125bfdc1d85c52247af1a77ded5b61a09b875fb3b8742b5677",
+        "0065c788ff7ab505e75d31c19f2d15d1f29589ddd95a733f233b9e71af9a6015",
 }
 
 
@@ -383,6 +385,48 @@ def test_the_kernel_the_compiler_is_given_is_the_pinned_one(name):
         {"main"} | {f"transform_{i}" for i in range(16)})
     assert hashlib.sha256(module.encode()).hexdigest() == \
         MOSAIC_SHA256[name]
+
+
+def dma_sequence(module):
+    """The copies a kernel issues and waits for, in the order its
+    Mosaic module (`mosaic_modules`) has them, each with the memories
+    and shapes it moves between, and the loops they stand in."""
+    sequence = []
+    for line in module.splitlines():
+        op = re.search(r'"stable_mosaic\.(tpu\.enqueue_dma|tpu\.wait_dma2|'
+                       r'scf\.for|scf\.while)"', line)
+        if op:
+            sequence.append(op.group(1) + "".join(
+                re.findall(r"memref<[^>]*>", line.split(" : ")[-1])
+                if op.group(1).startswith("tpu") else []))
+    return sequence
+
+
+# sha256 of `dma_sequence` of a ring that is ONE chunk a lane, taken at
+# PR 66's PARENT: the cut moved no copy and no wait of a lane the
+# buffer already held (the issue loops over runs, over the other
+# groups and over the last pages, the first lane's start, the next
+# lane's, the waits on summed bytes, the written row's way back)
+DMA_SEQUENCE_SHA256 = {
+    "k-exaone-chat64-ring":
+        "a68bb60d73316257fcb68898bcce142c7cab8886f37f1b857ea63214e0ef34a4",
+    "mellum2-agent96-ring":
+        "5c36beeca9fdd9200b641cbc39e95cc57c4d216efb584964f4906815b496b47a",
+}
+
+
+@pytest.mark.parametrize("name", sorted(DMA_SEQUENCE_SHA256))
+def test_a_one_chunk_lanes_copies_and_waits_are_the_parents(name):
+    """agent96's ring (64 pages of 16 KB) and K-EXAONE's (8 pages) were
+    one chunk a lane before the cut and are one after it: the kernel
+    starts and waits for the same copies in the same order."""
+    nb = POOLS[name][4]
+    assert CHUNK_PAGES[name] == nb
+    (module,) = mosaic_modules(_lowered(name))
+    sequence = dma_sequence(module)
+    assert sum(op.startswith("tpu.enqueue_dma") for op in sequence) > 8
+    assert hashlib.sha256("\n".join(sequence).encode()).hexdigest() == \
+        DMA_SEQUENCE_SHA256[name]
 
 
 @pytest.mark.parametrize("name", sorted(INDEX_PLANES))

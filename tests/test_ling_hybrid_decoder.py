@@ -958,8 +958,11 @@ def test_the_new_reader_and_the_cells_lists_agree_with_the_benchmark():
     bench = _json("BENCHMARK.json")
     (last,) = [m for m in bench["per_layer"]
                if m["name"] == "serve_delta_gates_share"]
-    # (the list's last entry until PR 65 appended its reader)
-    assert bench["per_layer"].index(last) == len(bench["per_layer"]) - 2
+    # (the list's last entry until PR 65 and PR 66 appended theirs)
+    assert [m["name"] for m in bench["per_layer"]].index(
+        "serve_delta_gates_share") == bench["per_layer"].index(last)
+    assert [m["name"] for m in bench["per_layer"][-2:]] == [
+        "serve_latent_ring_roofline", "sched_kv_copy_covered_share"]
     assert last["workloads"] == [CELL, "solar-open2-250b-serve-docqa64"]
     reader = _load("reader_delta_gates", "perf", "metrics",
                    "serve_delta_gates_share.py")

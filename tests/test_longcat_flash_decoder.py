@@ -743,7 +743,7 @@ def test_configuration_file_describes_the_block_and_its_arithmetic():
     pool_gb = dec.bytes_per_block * 64 * 256 / 1e9
     assert round(pool_gb, 2) == 2.68
     assert 12.9 <= weights_gb + pool_gb <= 13.1          # held, of 16
-    assert dec.attention_tiling == ((51, 8), None)
+    assert dec.attention_tiling == ((64, 8), None)
     assert dec.tick_counts(np.asarray([999] * 64), 64)["latent_rows"] == (
         8 * 64 * 1000)
     g = _weights(_decoder())

@@ -423,7 +423,7 @@ _DMA_LENGTHS = {
 
 
 def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
-              select=False, behind=None):
+              select=False, behind=None, nb=None, write_at=None):
     """The kernel over lanes of `lanes_pages` pages (None: a lane with
     no sequence) against plain `jax.numpy` after a scatter of the
     written row -> (got, want, pools got, pools wanted, active).
@@ -433,12 +433,14 @@ def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
     the pool's block ids; the kernel is then given the SAME pages
     behind another table (block b's page in block `behind[b]`, the
     table renamed), and the pools it returns are read back through
-    it."""
+    it.  `nb`: the table's pages (two chunks and three).  `write_at`:
+    the cursor's row -> the row the lane writes, under its length (the
+    cursor's own: a table's; a ring writes anywhere under it)."""
     import jax
     import jax.numpy as jnp
 
     h, dh, n_kv, d_value, pages, dtype = _DMA_GEOMETRIES[geometry]
-    bs, nb = 4, 2 * pages + 3
+    bs, nb = 4, nb or 2 * pages + 3
     row, dtype = n_kv * dh, jnp.dtype(dtype)
     r = np.random.RandomState(7)
     s_n = len(lanes_pages)
@@ -448,6 +450,7 @@ def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
     pos = np.array([(n or 1) * bs - 1 - (i % bs if (n or 1) > 1 else 0)
                     for i, n in enumerate(lanes_pages)])
     lengths = np.where(active, pos + 1, 1)
+    wrow = pos if write_at is None else np.array([write_at(p) for p in pos])
     pools = [jnp.asarray(r.randn(2, 1 + s_n * nb, bs, row), dtype)
              for _ in range(1 if d_value else 2)]
     # a table names any block: the lanes' blocks shuffled
@@ -462,8 +465,8 @@ def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
     wanted = list(pools)
     if write:
         lane = np.arange(s_n)
-        wb = np.where(active, tables[lane, pos // bs], 0)
-        wanted = [pool.at[1, wb, pos % bs].set(new.astype(dtype))
+        wb = np.where(active, tables[lane, wrow // bs], 0)
+        wanted = [pool.at[1, wb, wrow % bs].set(new.astype(dtype))
                   for pool, new in zip(pools, news)]
     given, names = pools, tables
     if behind is not None:
@@ -476,7 +479,7 @@ def _dma_case(geometry, lanes_pages, write, interpret=True, tables=None,
         tile=2, n_heads=h, d_head=dh, d_value=d_value,
         interpret=interpret,
         write=((news[0], None if d_value else news[1],
-                np.where(active, pos, -1)) if write else None),
+                np.where(active, wrow, -1)) if write else None),
         select=None if mask is None else jnp.asarray(mask))
     # (the interpreter's callbacks read arrays on a thread of their
     # own: an op dispatched beside a running kernel can deadlock it)
@@ -524,6 +527,110 @@ def test_a_chunk_is_waited_for_on_its_summed_bytes(geometry, length, write):
     for pool, same in zip(pools, wanted):
         np.testing.assert_array_equal(np.asarray(pool[:, 1:], np.float32),
                                       np.asarray(same[:, 1:], np.float32))
+
+
+# The cut (PR 66).  cap, row tile, issue group: the cells' caps (64
+# pages of a latent row, 80 of agent96's 16 KB, 40 of 32 KB, 16 of 64
+# KB, dots3's ring of 33, the indexer's 512 under groups of 16) and the
+# tests' own, whole groups or not
+_CUTS = [(64, 8, 8), (80, 8, 8), (40, 8, 8), (16, 8, 8), (33, 8, 8),
+         (512, 8, 16), (51, 8, 8), (24, 2, 8), (11, 2, 8), (5, 2, 8),
+         (3, 1, 8)]
+
+
+@pytest.mark.parametrize("cap,tile,unroll", _CUTS)
+def test_a_lane_is_cut_once_in_chunks_of_one_stride(cap, tile, unroll):
+    """`chunk_cut` over lanes of 1 to 4 caps + 1 pages, integers, numpy
+    and jax arrays alike: the chunks cover `[0, n_pages)` once; a lane
+    the cap holds is ONE chunk; a longer one is `ceil(n / the cap's
+    whole groups)` chunks (`ceil(n / cap)` at a cap of whole groups:
+    every cell's), every one but its last a whole number of groups or
+    the cap itself, none over the cap, the last no longer than the
+    others and short of them by less than a group a chunk; the rule
+    walked by hand (`_brute_cut`) says the same, and every stride is
+    one of the row windows."""
+    import jax.numpy as jnp
+
+    group = paged_attention.cut_group(cap, tile, unroll)
+    assert group == _brute_group(cap, tile, unroll)
+    assert group % min(unroll, cap) == 0 and group <= cap
+    windows = paged_attention._windows(cap, tile, unroll)
+    assert list(windows) == _brute_windows(cap, tile, unroll)
+    n_pages = np.arange(1, 4 * cap + 2)
+    strides, counts = paged_attention.chunk_cut(n_pages, cap, group)
+    for xp_cut in (paged_attention.chunk_cut(jnp.asarray(n_pages), cap,
+                                             group),
+                   zip(*(paged_attention.chunk_cut(int(n), cap, group)
+                         for n in n_pages))):
+        for got, want in zip(xp_cut, (strides, counts)):
+            np.testing.assert_array_equal(np.asarray(got), want)
+    whole = cap // group * group
+    for n, stride, count in zip(n_pages, strides, counts):
+        chunks = [min(stride, n - c * stride) for c in range(count)]
+        assert chunks == _brute_cut(int(n), cap, tile, unroll)
+        assert sum(chunks) == n and min(chunks) > 0 and max(chunks) <= cap
+        assert count == (1 if n <= cap else -(-n // whole))
+        if cap % group == 0:
+            assert count == -(-n // cap)
+        assert all(c % group == 0 or c == cap for c in chunks[:-1])
+        assert len(set(chunks[:-1])) <= 1 and chunks[-1] <= chunks[0]
+        assert count == 1 or chunks[0] - chunks[-1] < group * count
+        assert count == 1 or stride in windows
+
+
+# lanes of a page, one short of a stride, a stride, one over, two
+# strides and one, a ring's 33, under a cap of 24 pages whose strides
+# are 16 and 24: 25 are 16 + 9, 33 are 24 + 9, 49 are 24 + 24 + 1;
+# and two whole strides, 16 + 16 and 24 + 24
+_CUT_LANES = [1, 23, 24, 25, 49, 33, 32, 48]
+_DMA_GEOMETRIES["latent-strides"] = (8, 128, 1, 32, 24, "float32")
+_DMA_GEOMETRIES["kv-strides"] = (4, 8, 4, 0, 24, "float32")
+_WRITTEN = {"first": lambda pos: min(pos, 5),
+            "middle": lambda pos: pos // 2, "last": lambda pos: pos}
+
+
+@pytest.mark.parametrize("written", sorted(_WRITTEN))
+@pytest.mark.parametrize("pool", ["kv", "latent", "latent-select"])
+def test_equal_chunks_equal_plain_attention(pool, written):
+    """Lanes cut 1, 23, 24, 16 + 9, 24 + 24 + 1, 24 + 9, 16 + 16 and
+    24 + 24 in ONE call, each started by the lane before it: results
+    and pools equal plain attention after a scatter, over a K and a V
+    pool and over a latent one, with a selection and without, the row
+    written in a lane's first chunk, in a middle one (the 49-page
+    lane's second) and in its last."""
+    geometry = "kv-strides" if pool == "kv" else "latent-strides"
+    assert [_brute_cut(n, 24, 2) for n in (25, 49, 33, 32)] == [
+        [16, 9], [24, 24, 1], [24, 9], [16, 16]]
+    got, want, pools, wanted, active = _dma_case(
+        geometry, _CUT_LANES, True, select=pool.endswith("select"),
+        write_at=_WRITTEN[written])
+    np.testing.assert_allclose(got, want,
+                               atol=2e-6 * float(np.abs(want).max()))
+    for pool_got, same in zip(pools, wanted):
+        np.testing.assert_array_equal(np.asarray(pool_got[:, 1:]),
+                                      np.asarray(same[:, 1:]))
+
+
+@pytest.mark.parametrize("select", [False, True], ids=["plain", "select"])
+def test_a_ring_of_33_pages_is_one_chunk(select):
+    """dots3's ring: 33 pages under a buffer of 33 (one chunk a lane:
+    four groups of 8 and a page), full lanes between shorter ones, the
+    row written anywhere in the ring: results and pools equal plain
+    attention after a scatter, under the window's row mask and
+    without."""
+    _DMA_GEOMETRIES["ring-33"] = (8, 128, 1, 32, 33, "float32")
+    try:
+        assert paged_attention.chunk_cut(33, 33, 8) == (33, 1)
+        got, want, pools, wanted, active = _dma_case(
+            "ring-33", [33, 33, 7, 33, 1, 32], True, nb=33, select=select,
+            write_at=lambda pos: (pos * 7) % (pos + 1))
+    finally:
+        del _DMA_GEOMETRIES["ring-33"]
+    np.testing.assert_allclose(got, want,
+                               atol=2e-6 * float(np.abs(want).max()))
+    for pool_got, same in zip(pools, wanted):
+        np.testing.assert_array_equal(np.asarray(pool_got[:, 1:]),
+                                      np.asarray(same[:, 1:]))
 
 
 # What a table names, [lanes, table pages] int32 over the lanes' own
@@ -619,7 +726,7 @@ def test_a_run_of_pages_is_one_copy_and_the_same_pages(kind, pool):
     # the groups of these tables that are runs, by `starts_saved`: all
     # of an ascending table's, none of a descending or shuffled one's
     saved = paged_attention.starts_saved(tables, pages)[:, -1] // (group - 1)
-    whole = nb // pages * (pages // group)
+    whole = nb // group
     assert {"ascending_tables": (saved == whole).all(),
             "descending_tables": not saved.any(),
             "shuffled_tables": not saved.any()}.get(kind, saved.any())
@@ -642,6 +749,49 @@ def test_a_run_of_pages_is_one_copy_and_the_same_pages(kind, pool):
                                       np.asarray(same[:, 1:]))
 
 
+def _brute_group(cap, tile, unroll=None):
+    """What a stride is a whole number of: the issue loop's groups in
+    whole row tiles (the groups alone where the cap holds no such),
+    and the fewest of those at a time of which the cap holds no more
+    than eight."""
+    import math
+
+    unroll = min(unroll or paged_attention._ISSUE_UNROLL, cap)
+    group = math.lcm(unroll, tile)
+    if group > cap:
+        return unroll
+    return next(k * group for k in range(1, cap + 1)
+                if cap <= 8 * k * group)
+
+
+def _brute_windows(cap, tile, unroll=None):
+    """A chunk's row windows, in pages: the tile doubled under the
+    cap, the strides over half the cap, the cap."""
+    group = _brute_group(cap, tile, unroll)
+    doubled = [tile << i for i in range(cap.bit_length())
+               if tile << i < cap]
+    strides = [g for g in range(group, cap, group) if 2 * g > cap]
+    return sorted(set(doubled + strides)) + [cap]
+
+
+def _brute_cut(n_pages, cap, tile, unroll=None):
+    """The pages of each chunk of a lane of `n_pages` pages under a cap
+    of `cap`, by the rule and not by `chunk_cut`'s arithmetic: one
+    chunk where the cap holds the lane, else the fewest chunks of the
+    cap's whole groups, all of the smallest stride of whole groups
+    that reaches the lane's end (`_brute_group`)."""
+    group = _brute_group(cap, tile, unroll)
+    if n_pages <= cap:
+        return [n_pages]
+    whole = max(g for g in range(group, cap + 1, group))
+    count = next(c for c in range(2, n_pages + 1) if c * whole >= n_pages)
+    stride = next(g for g in range(group, whole + 1, group)
+                  if count * g >= n_pages)
+    chunks = [stride] * (count - 1) + [n_pages - (count - 1) * stride]
+    assert 0 < chunks[-1] <= stride
+    return chunks
+
+
 def _loops_around(jaxpr, name, depth=0):
     """The loop depths at which primitive `name` stands in `jaxpr`."""
     import jax
@@ -658,8 +808,9 @@ def _loops_around(jaxpr, name, depth=0):
 
 @pytest.mark.parametrize("geometry", sorted(_DMA_GEOMETRIES))
 def test_dma_ops_count_the_starts_and_waits(geometry):
-    """`dma_ops` is a start a page and, chunk by chunk, a wait for each
-    set bit of the pages copied; and the kernel's waits stand in no
+    """`dma_ops` is a start a page and, chunk by chunk of the lane's
+    cut, a wait for each set bit of the pages copied; and the kernel's
+    waits stand in no
     loop over pages: under the chunk loop alone, a static list as long
     as the chunk's bit length a pool (and one for the written row),
     where its starts stand in a loop of their own."""
@@ -669,14 +820,8 @@ def test_dma_ops_count_the_starts_and_waits(geometry):
     h, dh, n_kv, d_value, pages, dtype = _DMA_GEOMETRIES[geometry]
     n_pages = [1, 2, 3, pages - 1, pages, pages + 1, 2 * pages + 3,
                7 * pages + pages // 2]
-    brute = []
-    for n in n_pages:
-        ops, left = 0, n
-        while left:
-            copied = min(pages, left)
-            ops += copied + bin(copied).count("1")
-            left -= copied
-        brute.append(ops)
+    brute = [sum(copied + bin(copied).count("1")
+                 for copied in _brute_cut(n, pages, 2)) for n in n_pages]
     assert [paged_attention.dma_ops(n, pages) for n in n_pages] == brute
     assert list(paged_attention.dma_ops(np.asarray(n_pages), pages)) == brute
 
@@ -934,18 +1079,20 @@ def test_selection_follows_geometry_and_platform(geometry, platform,
 @pytest.mark.parametrize("workload,pages,ring_pages,tiling", [
     ("opt-1.3b-serve-closed32", 32, None, ((16, 8), None)),
     ("olmoe-1b-7b-serve-chat32", 64, None, ((16, 8), None)),
-    ("mellum2-12b-a2.5b-serve-agent96", 256, 64, ((64, 8), (64, 8))),
-    ("granite-4.0-h-small-serve-chat64", 64, None, ((32, 8), None)),
+    ("mellum2-12b-a2.5b-serve-agent96", 256, 64, ((80, 8), (64, 8))),
+    ("granite-4.0-h-small-serve-chat64", 64, None, ((40, 8), None)),
     ("ouro-2.6b-serve-chat12", 64, None, ((16, 8), None)),
-    ("k-exaone-236b-a23b-serve-chat64", 64, 8, ((32, 8), (8, 8))),
-    ("deepseek-v2-serve-agent64", 256, None, ((51, 8), None))])
-def test_the_cells_select_the_tiling_they_selected_at_pr_44(
+    ("k-exaone-236b-a23b-serve-chat64", 64, 8, ((40, 8), (8, 8))),
+    ("deepseek-v2-serve-agent64", 256, None, ((64, 8), None))])
+def test_the_cells_select_the_tiling_of_a_cap_of_whole_groups(
         workload, pages, ring_pages, tiling, monkeypatch):
-    """The latent form joined the module without moving the others: for
-    the six serving cells that were there, from their own files, the
-    selection returns the kernel with the chunk and row tile PR 44's
-    tree returned (`decoder.attention_tiling`) and calls it with two
-    pools and no `d_value`; the seventh takes the latent form."""
+    """For seven serving cells, from their own files, the selection
+    returns the kernel with the cap and the row tile
+    `decoder.attention_tiling` reports: 1.25 MiB of pages in whole
+    issue groups where the table is longer (the three of 64 KB a page
+    keep PR 44's 16), the ring itself where that is shorter; six are
+    called with two pools and no `d_value`, the seventh takes the
+    latent form."""
     geometry = _cell_geometry(workload)
     kern, reason = paged_attention.select_paged_attention(
         platform="tpu", **geometry)
@@ -1013,18 +1160,20 @@ def test_unsupported_shape_is_refused_with_its_reason():
     # closed32's table: chunks of 16 pages, windows of 8 and 16
     (16, 8, [1, 8, 9, 16, 17, 25, 32],
      [128, 128, 256, 256, 384, 512, 512]),
-    # Mellum 2's: chunks of 64, windows of 8, 16, 32 and 64
+    # DeepSeek's: a cap of 64, windows of 8, 16, 32 and the strides 40,
+    # 48, 56, 64; 65 pages are cut 40 + 25 and 100 are 56 + 44
     (64, 8, [1, 9, 17, 33, 64, 65, 100],
-     [128, 256, 512, 1024, 1024, 1152, 2048]),
+     [128, 256, 512, 640, 1024, 1152, 1664]),
     # a table of 12 pages: the chunk is the last window
     (12, 8, [8, 9, 12], [128, 192, 192]),
     # K-EXAONE's ring: one window, the chunk
     (8, 8, [1, 8], [128, 128]),
-], ids=["two-windows", "four-windows", "odd-chunk", "one-window"])
+], ids=["two-windows", "seven-windows", "odd-chunk", "one-window"])
 def test_rows_multiplied_follow_the_windows(pages, tile, n_pages, want):
-    """What `kv_rows_multiplied` sums: whole chunks whole, the last
-    chunk's smallest window (the tile doubled up to the chunk) that
-    holds its pages, for integers and for arrays alike."""
+    """What `kv_rows_multiplied` sums: a lane's equal chunks whole
+    (a stride is a window), the last chunk's smallest window (the
+    tile doubled up to the cap, or a stride) that holds its pages, for
+    integers and for arrays alike."""
     got = paged_attention.rows_multiplied(np.asarray(n_pages), pages,
                                           tile, 16)
     assert list(got) == want
@@ -1218,7 +1367,7 @@ def test_both_paged_kernels_trace_through_the_one_stream(monkeypatch):
     calls = []
 
     def counted(*args, **kw):
-        calls.append((len(args[4]), kw["unroll"], kw["pages"]))
+        calls.append((len(args[6]), kw["unroll"], kw["pages"]))
         return real(*args, **kw)
 
     monkeypatch.setattr(paged_attention, "stream_chunks", counted)
@@ -1259,7 +1408,7 @@ _TOYS = ["opt-1.3b", "olmoe-1b-7b-1chip", "mellum2-12b-a2.5b-1chip",
          "longcat-flash-1chip", "glm-5.2-1chip", "lfm2-24b-a2b-1chip"]
 # what of a tick's counts the page streams decide
 _STREAMED = ("kv_pages_read", "kv_rows_multiplied", "kv_dma_ops",
-             "index_pages_read", "index_dma_ops")
+             "kv_pages_covered", "index_pages_read", "index_dma_ops")
 
 
 def _toy_decoder(name, block_size, max_blocks):
@@ -1304,54 +1453,143 @@ def _toy_decoder(name, block_size, max_blocks):
     return dec, tiling
 
 
-def _parents_streamed_counts(dec, index_tiling, rows, slots, saved):
-    """`tick_counts`' arithmetic over the page streams as it stood at
-    the parent of PR 57, a copy for the table, for the ring and for the
-    index planes: the oracle of `stream_counts`."""
+def _brute_stream(n_pages, tables, cap, tile, bs, unroll, cut=None):
+    """One call of the page stream walked lane by lane, chunk by chunk
+    (`cut`: `_brute_cut`), copy by copy: `n_pages` a lane in the
+    stream's order, `tables` [lanes, table pages] or None (no group is
+    a run) -> (pages read, rows multiplied, DMA starts and waits a
+    pool, pages whose copy is in flight under as many pages'
+    products)."""
+    cut = cut or _brute_cut
+    windows = _brute_windows(cap, tile, unroll)
+    unroll = min(unroll, cap)
+    read = multiplied = dma = covered = 0
+    window_before = None        # of the chunk before in the stream
+    for lane, n in enumerate(n_pages):
+        first = 0
+        for copied in cut(int(n), cap, tile, unroll):
+            assert copied <= cap
+            entries = (None if tables is None
+                       else tables[lane, first:first + copied])
+            for g in range(copied // unroll):
+                group = (None if entries is None
+                         else entries[g * unroll:(g + 1) * unroll])
+                run = group is not None and (
+                    np.diff(group) == 1).all() and unroll > 1
+                dma += 1 if run else unroll
+            dma += copied % unroll + bin(copied).count("1")
+            window = next(w for w in windows if w >= copied)
+            if window_before is not None:
+                covered += min(copied, window_before)
+            read, multiplied = read + copied, multiplied + window * bs
+            window_before, first = window, first + copied
+    return read, multiplied, dma, covered
+
+
+def _brute_streamed_counts(dec, index_tiling, rows, slots, tables, rings):
+    """`tick_counts`' account of the page streams by a walk of them
+    (`_brute_stream`), once for the table, for the ring and for the
+    index planes, the idle lanes (a page each, no run) after the
+    others: the oracle of `stream_counts`.  `tables`, `rings`: the
+    lanes' own, or None for no saved starts."""
     bs, idle = dec.block_size, slots - len(rows)
     pools = 1 if dec.kernels["paged_attention_decode"].endswith(
         "latent") else 2
-    read = multiplied = dma = 0
-    reached = [(dec.table_layers, -(-rows // bs),
-                dec.attention_tiling[0], "table")]
+
+    def walk(pages, names, cap, tile, unroll):
+        if names is not None:
+            # an idle lane's entries are block 0 throughout: no run
+            names = np.concatenate(
+                [names, np.zeros((idle, names.shape[1]), names.dtype)])
+        return np.array(_brute_stream(
+            np.concatenate([pages, np.ones(idle, np.int64)]), names, cap,
+            tile, bs, unroll))
+
+    unroll = paged_attention._ISSUE_UNROLL
+    total = dec.table_layers * walk(
+        -(-rows // bs), tables, *dec.attention_tiling[0], unroll)
     if dec.ring_layers:
-        reached.append(
-            (dec.ring_layers,
-             -(-np.minimum(rows, dec.window_blocks_per_seq * bs) // bs),
-             dec.attention_tiling[1], "ring"))
-    for layers_n, pages, (chunk, tile), held in reached:
-        read += layers_n * (idle + int(pages.sum()))
-        multiplied += layers_n * int(
-            idle * paged_attention.rows_multiplied(1, chunk, tile, bs)
-            + paged_attention.rows_multiplied(
-                pages, chunk, tile, bs).sum())
-        dma += layers_n * pools * int(
-            idle * paged_attention.dma_ops(1, chunk)
-            + paged_attention.dma_ops(
-                pages, chunk, saved.get(held)).sum())
+        total += dec.ring_layers * walk(
+            -(-np.minimum(rows, dec.window_blocks_per_seq * bs) // bs),
+            rings, *dec.attention_tiling[1], unroll)
+    read, multiplied, dma, covered = map(int, total)
     want = {"kv_pages_read": read, "kv_rows_multiplied": multiplied,
-            "kv_dma_ops": dma}
+            "kv_dma_ops": pools * dma, "kv_pages_covered": covered}
     if index_tiling is not None:
         from paddle_tpu.kernels.paged_index_scores import _ISSUE_UNROLL
-        pages = -(-rows // bs)
-        want["index_pages_read"] = dec.index_planes * (
-            idle + int(pages.sum()))
-        want["index_dma_ops"] = dec.index_planes * int(
-            idle * paged_attention.dma_ops(1, index_tiling[0])
-            + paged_attention.dma_ops(
-                pages, index_tiling[0], saved.get("index"),
-                _ISSUE_UNROLL).sum())
+        read, _, dma, _ = walk(-(-rows // bs), tables, *index_tiling,
+                               _ISSUE_UNROLL)
+        want["index_pages_read"] = dec.index_planes * int(read)
+        want["index_dma_ops"] = dec.index_planes * int(dma)
     return want
 
 
+@pytest.mark.parametrize("cut,share", [("28+5", 39.0), ("33", 100.0)])
+def test_copy_covered_share_on_the_rings_two_cuts(cut, share, monkeypatch):
+    """`sched_kv_copy_covered_share` on dots3's rings, 64 lanes of 33
+    pages, three sliding layers: cut 28 + 5 (the parent's, walked by
+    hand) a lane's 28-page copy is in flight under the 8-page window of
+    the five pages before it and its 5 under the 28: (8 + 5) / 33 = 39;
+    as ONE chunk (`stream_counts` at the ring's tiling) every copy but
+    the call's first lies under 33 pages' products: 100 less a lane in
+    64.  The benchmark's reader sums both attributes over the window's
+    tick spans."""
+    import importlib.util
+    import os
+    import types
+
+    from paddle_tpu.observability import tracing
+
+    rows = np.full(64, 33 * 16)
+    if cut == "28+5":
+        read, _, _, covered = _brute_stream(
+            rows // 16, None, 28, 8, 16, 8,
+            cut=lambda n, cap, tile, unroll: [28, 5])
+        assert covered == 63 * 8 + 64 * 5
+    else:
+        kern, _ = paged_attention.select_paged_attention(
+            d_model=64 * 256, n_heads=64, block_size=16, kv_dtype="bf16",
+            platform="tpu", kv_width=1152, value_width=1024)
+        assert kern.tiling(33) == (33, 8)
+        read, _, _, covered = paged_attention.stream_counts(
+            rows, 0, 33, 8, 16)
+        assert (covered, read) == _brute_stream(
+            rows // 16, None, 33, 8, 16, 8)[3::-3]
+        assert covered == 63 * 33
+    assert read == 64 * 33
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perf", "metrics",
+        "sched_kv_copy_covered_share.py")
+    spec = importlib.util.spec_from_file_location("_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    ticks = [{"name": "serving.decode_tick", "ts": 10.0 * i, "dur": 5.0,
+              "attrs": {"kv_pages_read": 3 * read,
+                        "kv_pages_covered": 3 * covered}}
+             for i in range(4)]
+    monkeypatch.setattr(tracing, "finished_spans", lambda: ticks)
+    run = types.SimpleNamespace(spans=ticks)
+    got = reader.compute(run)
+    assert got == pytest.approx(100.0 * covered / read)
+    assert abs(got - share) < (2.0 if cut == "33" else 0.1)
+    assert (reader.LAYER, reader.UNIT, reader.MOVES, reader.SOURCE) == (
+        "kernels", "%", "itl_p95_ms", "program_span")
+    # a program without the attribute (the parent): nothing to read
+    for tick in ticks:
+        del tick["attrs"]["kv_pages_covered"]
+    assert reader.compute(run) is None
+    assert reader.compute(types.SimpleNamespace(spans=[])) is None
+
+
 @pytest.mark.parametrize("name", _TOYS)
-def test_the_one_account_is_the_parents_arithmetic(name):
+def test_the_one_account_is_a_walk_of_the_streams(name):
     """`decoder.tick_counts` over random cursors, tables with runs and
     without, rings and idle lanes: what the streams decide
     (`stream_counts`, once for the table, for the ring and for the
-    index planes) is what the parent's three copies computed, with and
-    without the saved starts, and every other attribute is the one the
-    gather path reports for the same cursors."""
+    index planes) is what a walk of the same cut counts, lane by lane,
+    chunk by chunk, copy by copy (`_brute_stream`), with and without
+    the saved starts, and every other attribute is the one the gather
+    path reports for the same cursors."""
     slots, bs, nb = 6, 4, 11
     dec, index_tiling = _toy_decoder(name, bs, nb)
     assert dec.attention_tiling is not None
@@ -1372,9 +1610,10 @@ def test_the_one_account_is_the_parents_arithmetic(name):
         cursors = r.randint(0, bs * nb, len(lanes)).astype(np.int32)
         for saved in ({}, {k: v[lanes] for k, v in held.items()}):
             got = dec.tick_counts(cursors, slots, saved=saved)
-            want = _parents_streamed_counts(
+            want = _brute_streamed_counts(
                 dec, index_tiling, cursors.astype(np.int64) + 1, slots,
-                saved)
+                tables[lanes] if saved else None,
+                rings[lanes] if saved and rings is not None else None)
             assert {k: got[k] for k in _STREAMED if k in got} == want
             assert all(type(v) is int for v in got.values())
             gathered = dec.tick_counts(cursors, slots, windowed=True)

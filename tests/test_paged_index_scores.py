@@ -244,6 +244,59 @@ def test_a_run_of_pages_is_one_copy_and_the_same_scores(kind, dtype):
         got, scores(pool[:, np.argsort(behind)], behind[tables]))
 
 
+# The cut (`paged_attention.chunk_cut`, PR 66): a table of 75 pages
+# under a cap of 48, three groups of 16 entries (a tile of 8 rows a
+# page is 16 pages too), so a lane past the cap is cut at a stride of
+# 32 or 48: 49 are 32 + 17, 65 are 48 + 17, 75 are 48 + 27
+CUT_NB, CUT_PAGES, CUT_TILE = 75, 48, 16
+CUT_LANES = {"a_page": [1, 75, 1], "a_stride": [47, 48, 49],
+             "past_a_stride": [49, 33, 65], "the_table": [75, 64, 75],
+             "mixed": [65, 1, 48, 75, 49, 96 // 2]}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("lanes", sorted(CUT_LANES))
+def test_a_lane_past_the_cap_is_scored_in_equal_chunks(lanes, dtype):
+    """Lanes of a page, one short of the cap, the cap, one over (32 +
+    17), 48 + 17 and the whole table (48 + 27) in one call, each
+    started by the lane before it: a lane's chunks lie a stride apart
+    in its table and a buffer apart in the kernel's output, and the
+    scores under the cursors equal the gather's, row for row."""
+    from paddle_tpu.kernels import paged_attention
+
+    group = paged_attention.cut_group(CUT_PAGES, CUT_TILE,
+                                      pis._ISSUE_UNROLL)
+    assert group == pis._ISSUE_UNROLL == 16
+    assert [tuple(map(int, paged_attention.chunk_cut(n, CUT_PAGES, group)))
+            for n in (48, 49, 65, 75)] == [(48, 1), (32, 2), (48, 2),
+                                           (48, 2)]
+    n_pages = CUT_LANES[lanes]
+    s_n = len(n_pages)
+    r = np.random.RandomState(5)
+    pool = jnp.asarray(r.randn(2, s_n * CUT_NB + 1, BS, D) * 0.5, dtype)
+    # ascending blocks, every other lane's shuffled: runs and no run
+    tables = 1 + np.arange(s_n * CUT_NB, dtype=np.int32).reshape(
+        s_n, CUT_NB)
+    for lane in range(1, s_n, 2):
+        tables[lane] = r.permutation(tables[lane])
+    q = jnp.asarray(r.randn(s_n, H, D) * 0.3, jnp.float32)
+    w = jnp.asarray(r.randn(s_n, H), jnp.float32)
+    # the cursor ends inside its last page
+    cur = np.asarray(n_pages) * BS - 1 - np.arange(s_n) % BS
+    valid = np.arange(CUT_NB * BS)[None, :] <= cur[:, None]
+    got = np.where(valid, np.asarray(pis.paged_index_scores(
+        q, w, pool, jnp.asarray(tables), jnp.asarray(cur + 1, jnp.int32),
+        1, pages=CUT_PAGES, tile=CUT_TILE, interpret=True)), -np.inf)
+    keys = pool[1, tables].reshape(s_n, CUT_NB * BS, D)
+    dots = jax.lax.dot_general(
+        q.astype(keys.dtype), keys, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    want = np.where(valid, np.asarray(
+        (jax.nn.relu(dots) * w[:, :, None]).sum(axis=1)), -np.inf)
+    _agree(got, want, jnp.asarray(valid))
+
+
 @pytest.mark.parametrize("kw,reason", [
     (dict(platform="cpu"), "not_tpu"),
     (dict(platform="gpu"), "not_tpu"),
@@ -263,11 +316,11 @@ def test_the_selector_refuses_by_geometry_dtype_and_platform(kw, reason):
 @pytest.mark.parametrize("kw,table_pages,tiling", [
     # the cell's plane: 432 pages of 4 KiB in one chunk
     (dict(platform="tpu"), 432, (432, 8)),
-    # a short table is one chunk, a long one equal chunks of at most
-    # 2 MiB
+    # a short table is one chunk, a long one a cap of 2 MiB, under
+    # which a lane is cut in equal chunks by its own length
     (dict(platform="tpu"), 64, (64, 8)),
     (dict(platform="tpu"), 1024, (512, 8)),
-    (dict(platform="tpu"), 1200, (400, 8)),
+    (dict(platform="tpu"), 1200, (512, 8)),
     (dict(platform="tpu", kv_dtype="fp32", block_size=8), 432, (432, 16)),
     # off the TPU only the interpreter, at any geometry
     (dict(platform="cpu", interpret=True, index_head_dim=16,

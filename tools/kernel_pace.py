@@ -25,9 +25,16 @@ first).  `--tables`: what a lane's table names, `consecutive` blocks
 descriptor a group for) or the same blocks `shuffled` (no two in a
 row: a descriptor a page); the paged shapes' alone.  `--check`: the
 whole kernel's result against plain `jax.numpy`
-over random pools, before any timing.  `--rehearse`: the name's shape
-cut to a toy and run in the Pallas interpreter on the CPU, to see that
-the script runs: never a number.
+over random pools, before any timing.  `--pages`: the paged shapes at
+each of these static `pages` of `paged_attention` in one process, in
+place of `tiling`'s (a chunk's pages in a tree before PR 66: how PR 66's
+step 0 read 28 + 5 against 17 + 16 and 33 with no kernel line changed;
+the chunks' CAP since, under which `chunk_cut` cuts a lane).  `--rows`:
+every lane's cursor at so many rows in place of the shape's mix.
+`--rehearse`: the name's shape cut to a toy and run in the Pallas
+interpreter on the CPU, to see that the script runs: never a number;
+beside it the VMEM the Mosaic call asks for at the REAL geometry
+(`vmem_request`: a lowering, nothing runs).
 
 The flash-attention kernels of a training step the same way
 (`--shape opt-1.3b-train-seq2048`, and `flash-seq8192`,
@@ -124,6 +131,27 @@ SHAPES = {
         slots=64, heads=64, d_head=0, row=640, d_value=512, bs=16,
         ctx=6912, layers=((6912, 5),), scale=0.0625, cursors="docqa",
         select=2048),
+    # the same stored row under 64 heads on the 8 planes of 4 double
+    # layers, and under 32 heads at 128 lanes on ONE plane (the latent
+    # layer of six beside five delta-rule layers)
+    "longcat-flash-serve-agent64": dict(
+        slots=64, heads=64, d_head=0, row=640, d_value=512, bs=16,
+        ctx=4096, layers=((4096, 8),), scale=0.0722),
+    "ling-3.0-flash-serve-agent128": dict(
+        slots=128, heads=32, d_head=0, row=640, d_value=512, bs=16,
+        ctx=4096, layers=((4096, 1),), scale=0.0722),
+    # dots3's two geometries: the sliding layers' latent RING (64 heads
+    # on a row of 1024 + 64 stored 1152 wide, 33 pages a lane and every
+    # lane's full, the window's 513 rows under a row mask) and the two
+    # full layers' SELECTED table (128 heads on DeepSeek's row, 2048
+    # rows selected under docqa64's cursors)
+    "dots3-note-prev-serve-docqa64-ring": dict(
+        slots=64, heads=64, d_head=0, row=1152, d_value=1024, bs=16,
+        ctx=528, layers=((528, 3),), scale=0.0625, rows=528, select=513),
+    "dots3-note-prev-serve-docqa64-table": dict(
+        slots=64, heads=128, d_head=0, row=640, d_value=512, bs=16,
+        ctx=6912, layers=((6912, 2),), scale=0.0722, cursors="docqa",
+        select=2048),
     # the flash kernels of one layer's training step: 4 sequences of
     # 2048, 32 heads of 64 packed in pairs, bf16, causal, the blocks
     # `_select_blocks` gives (512 x 1024)
@@ -171,7 +199,10 @@ TABLES = ("consecutive", "shuffled")
 def lengths_of(shape):
     """The cursors' mix: lognormal about `mean_rows` (900: the long
     cells' mean by `sched_kv_pages_read_share`), cut to the context;
-    docqa64's where the shape asks for them."""
+    docqa64's where the shape asks for them; `rows` (a ring every lane
+    has filled, `--rows`): every lane at so many."""
+    if shape.get("rows"):
+        return np.full(shape["slots"], shape["rows"], np.int32)
     if shape.get("cursors") == "docqa":
         return index_lengths(shape)
     r = np.random.RandomState(0)
@@ -227,21 +258,44 @@ def lane_tables(s_n, nb, blocks, order):
     return tables.astype(np.int32)
 
 
-def build(shape, bs, pa, interpret=False, order="consecutive"):
-    """-> (a jitted function over every layer of every table and ring
-    of `shape` at pages of `bs` rows, its arguments, what a reference
-    needs beside them)."""
-    import jax
-    import jax.numpy as jnp
-
-    s_n, h, row, d_value = (shape[k] for k in
-                            ("slots", "heads", "row", "d_value"))
+def selected(shape, bs, pa, interpret=False, pages=0):
+    """The kernel `select_paged_attention` gives at `shape`'s geometry
+    in pages of `bs` rows; `pages`: the same call at that static
+    `pages` of `paged_attention` (a chunk's pages in a tree before
+    PR 66, the chunks' cap since) in place of `tiling`'s."""
+    h, row, d_value = (shape[k] for k in ("heads", "row", "d_value"))
     kern, why = pa.select_paged_attention(
         d_model=h * (shape["d_head"] or 1), n_heads=h,
         d_head=shape["d_head"] or None, block_size=bs, kv_dtype="bf16",
         platform="tpu", interpret=interpret, kv_width=row,
         value_width=d_value or None)
     assert kern is not None, why
+    if not pages:
+        return kern
+
+    def attend(q, pool_k, pool_v, tables, lengths, layer, scale,
+               write=None, select=None):
+        return pa.paged_attention(
+            q, pool_k, pool_v, tables, lengths, layer, scale=float(scale),
+            pages=pages, tile=min(kern.tiling(tables.shape[1])[1], pages),
+            n_heads=h, d_head=shape["d_head"] or 1, d_value=d_value,
+            interpret=interpret, write=write, select=select)
+
+    attend.tiling = lambda table_pages: (
+        pages, min(kern.tiling(table_pages)[1], pages))
+    return attend
+
+
+def build(shape, bs, pa, interpret=False, order="consecutive", pages=0):
+    """-> (a jitted function over every layer of every table and ring
+    of `shape` at pages of `bs` rows (`pages`: `selected`'s), its
+    arguments, what a reference needs beside them)."""
+    import jax
+    import jax.numpy as jnp
+
+    s_n, h, row, d_value = (shape[k] for k in
+                            ("slots", "heads", "row", "d_value"))
+    kern = selected(shape, bs, pa, interpret, pages)
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 16))
 
     def normal(dims, sigma):
@@ -335,13 +389,13 @@ def reference(shape, bs, q, pools, aux):
     return wants
 
 
-def check(shape, bs, pa, interpret=False, order="consecutive"):
+def check(shape, bs, pa, interpret=False, order="consecutive", pages=0):
     """Largest difference of the whole kernel from `reference`, as a
     share of the reference's largest value (a bfloat16 pool: some
     1e-2)."""
     import jax
 
-    f, (q, pools), aux = build(shape, bs, pa, interpret, order)
+    f, (q, pools), aux = build(shape, bs, pa, interpret, order, pages)
     wants = jax.jit(lambda q, pools: reference(shape, bs, q, pools, aux))(
         q, pools)
     wants = [np.asarray(w, np.float32) for w in wants]
@@ -352,12 +406,12 @@ def check(shape, bs, pa, interpret=False, order="consecutive"):
 
 
 def pace(shape, bs, pa, variant="whole", calls=30, interpret=False,
-         order="consecutive"):
+         order="consecutive", pages=0):
     """Milliseconds a call of every layer of every table and ring."""
     import jax
 
     with removed(variant):
-        f, (q, pools), _ = build(shape, bs, pa, interpret, order)
+        f, (q, pools), _ = build(shape, bs, pa, interpret, order, pages)
         outs, pools = f(q, pools)
         jax.block_until_ready(outs)
         t = time.perf_counter()
@@ -366,6 +420,71 @@ def pace(shape, bs, pa, variant="whole", calls=30, interpret=False,
         jax.block_until_ready(outs)
         took = time.perf_counter() - t
     return took / calls * 1e3
+
+
+def vmem_request(shape, bs, pa, pages=0):
+    """Bytes of VMEM the Mosaic call of each table and ring of `shape`
+    asks for at its REAL geometry, lowered for a TPU from shapes alone
+    (nothing runs, no chip is needed): the scratch operands once, a
+    grid step's blocks twice (the pipeline's two buffers).  The
+    compiler's own verdict on a v5e's 16 MiB is
+    tests/test_kernels_lower_tpu.py's."""
+    import base64
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.lib.mlir import ir
+
+    s_n, h, row, d_value = (shape[k] for k in
+                            ("slots", "heads", "row", "d_value"))
+    kern = selected(shape, bs, pa, pages=pages)
+    sizes = {"bf16": 2, "f32": 4, "i32": 4}
+    requests = []
+    for rows, n_layers in shape["layers"]:
+        nb = rows // bs
+        pool = jax.ShapeDtypeStruct((n_layers, s_n * nb + 1, bs, row),
+                                    jnp.bfloat16)
+        new = jax.ShapeDtypeStruct((s_n, row), jnp.bfloat16)
+        lengths = jax.ShapeDtypeStruct((s_n,), jnp.int32)
+
+        def f(q, pool, new, tables, lengths, select):
+            return kern(q, pool, None if d_value else pool, tables, lengths,
+                        0, shape["scale"],
+                        write=(new, None if d_value else new, lengths - 1),
+                        **({"select": select} if d_value
+                           and shape.get("select") else {}))
+
+        text = jax.jit(f).trace(
+            jax.ShapeDtypeStruct(
+                (s_n, h * (row if d_value else shape["d_head"])),
+                jnp.bfloat16), pool, new,
+            jax.ShapeDtypeStruct((s_n, nb), jnp.int32), lengths,
+            jax.ShapeDtypeStruct((s_n, rows), jnp.bool_)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        (config,) = re.findall(r'backend_config = "((?:[^"\\]|\\.)*)"', text)
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(
+                base64.b64decode(body)).operation.get_asm(
+                    enable_debug_info=False)
+        (main,) = [line for line in module.splitlines()
+                   if 'sym_name = "main"' in line]
+        types = re.findall(r"memref<[^>]*>>", main[
+            main.index("function_type = ("):main.index(") -> ()")])
+        n_scratch = int(re.search(r"scratch_operands = (\d+)", main).group(1))
+        operands = [
+            int(np.prod([int(d) for d in dims.split("x") if d])) * sizes[dt]
+            if space == "vmem" else 0
+            for dims, dt, space in (re.match(
+                r"memref<((?:\d+x)*)([^,]+), #tpu.memory_space<(\w+)>>",
+                t).groups() for t in types)]
+        scratch = sum(operands[len(operands) - n_scratch:])
+        requests.append(2 * sum(operands) - scratch)
+    return requests
 
 
 # ---------------------------------------------------------------------------
@@ -1004,17 +1123,22 @@ def toy(shape):
                 d_value=shape["d_value"] and 128, ctx=256,
                 layers=tuple((min(rows, 256), 1)
                              for rows, _ in shape["layers"]),
-                mean_rows=90, select=shape.get("select") and 40)
+                mean_rows=90, select=shape.get("select") and 40,
+                rows=min(shape.get("rows", 0), 256))
 
 
 def run(name, block_sizes=None, variants=None, pa=None, calls=30,
         with_check=False, rehearse=False, order="consecutive",
-        heads_blocks=()):
+        heads_blocks=(), pages=(), rows=0):
     """-> {"shape", "rows", "bs<n>.<variant>": ms, "bs<n>.pages",
-    "bs<n>.tiling", "bs<n>.check"}."""
+    "bs<n>.tiling", "bs<n>.vmem_request", "bs<n>.check"}; with
+    `pages` a reading a static cut, "bs<n>.p<pages>.<variant>".
+    `rows`: every lane's cursor at so many (the shape's mix)."""
     import jax
 
     shape = SHAPES[name]
+    if rows and "layers" in shape:
+        shape = dict(shape, rows=rows)
     if not rehearse and jax.devices()[0].platform != "tpu":
         raise SystemExit("kernel_pace: no TPU here; a time comes from a "
                          "chip run (--rehearse walks the script)")
@@ -1034,6 +1158,7 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
                          order=order)
     if pa is None:
         from paddle_tpu.kernels import paged_attention as pa
+    real = shape
     if rehearse:
         shape, calls = toy(shape), 1
     lengths = lengths_of(shape)
@@ -1045,15 +1170,28 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
         res[f"bs{bs}.pages"] = int(sum(
             n * (-(-np.minimum(lengths, rows) // bs)).sum()
             for rows, n in shape["layers"]))
-        for variant in variants or VARIANTS:
-            if rehearse and variant != "whole":
-                continue    # the interpreter walks the whole kernel only
-            res[f"bs{bs}.{variant}"] = round(
-                pace(shape, bs, pa, variant, calls, rehearse, order), 4)
-            print(f"{name} bs{bs}.{variant}", res[f"bs{bs}.{variant}"],
-                  flush=True)
-        if with_check:
-            res[f"bs{bs}.check"] = check(shape, bs, pa, rehearse, order)
+        for cut in pages or (0,):
+            at = f"bs{bs}.p{cut}" if cut else f"bs{bs}"
+            res[f"{at}.tiling"] = [
+                list(selected(real, bs, pa, pages=cut).tiling(rows // bs))
+                for rows, _ in real["layers"]]
+            if rehearse:
+                # of the REAL geometry: a lowering, nothing runs
+                res[f"{at}.vmem_request"] = vmem_request(real, bs, pa, cut)
+                print(f"{name} {at}.vmem_request",
+                      res[f"{at}.vmem_request"], flush=True)
+                # the toy's tables are shorter than the cut may be
+                cut = min(cut, min(r for r, _ in shape["layers"]) // bs)
+            for variant in variants or VARIANTS:
+                if rehearse and variant != "whole":
+                    continue    # the interpreter walks the whole kernel only
+                res[f"{at}.{variant}"] = round(pace(
+                    shape, bs, pa, variant, calls, rehearse, order, cut), 4)
+                print(f"{name} {at}.{variant}", res[f"{at}.{variant}"],
+                      flush=True)
+            if with_check:
+                res[f"{at}.check"] = check(shape, bs, pa, rehearse, order,
+                                           cut)
     return res
 
 
@@ -1072,6 +1210,12 @@ def main(argv=None):
                     + ",".join(VARIANTS + FLASH_VARIANTS[1:]
                                + DELTA_VARIANTS[2:] + ROUTE_VARIANTS[1:]))
     ap.add_argument("--tables", default="consecutive", choices=TABLES)
+    ap.add_argument("--pages", default="",
+                    help="a paged-attention shape at each of these static "
+                    "`pages` of `paged_attention`, one process (`tiling`'s)")
+    ap.add_argument("--rows", type=int, default=0,
+                    help="every lane's cursor at so many rows (the "
+                    "shape's mix of cursors)")
     ap.add_argument("--calls", type=int, default=0,
                     help="calls a reading (30; a route shape's 100)")
     ap.add_argument("--check", action="store_true")
@@ -1090,7 +1234,9 @@ def main(argv=None):
                with_check=args.check,
                rehearse=args.rehearse, order=args.tables,
                heads_blocks=[int(b) for b in args.heads_blocks.split(",")
-                             if b])
+                             if b],
+               pages=[int(p) for p in args.pages.split(",") if p],
+               rows=args.rows)
            for name in names]
     res = res[0] if len(res) == 1 else res
     print(json.dumps(res))
