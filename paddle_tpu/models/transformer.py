@@ -673,6 +673,10 @@ class PagedDecoder:
     # `lm_block.delta_rule`'s lines run; None until a step is traced
     # (the lanes are the step's arguments) and for a block without
     delta_kernel: Optional[str] = None
+    # bytes an element of the weights of the step traced last (what
+    # `tick_counts` turns its weights' elements into bytes with); None
+    # until a step is traced
+    weight_itemsize: Optional[int] = None
 
 
 def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
@@ -1258,6 +1262,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         one.  Under RoPE nothing is added and the residual stream is
         float32 from the start (with a table, OPT's stays in the
         weights' dtype until the first attention output widens it)."""
+        # (every step embeds: the weights' dtype is the step's argument)
+        decoder.weight_itemsize = g[layout.tok].dtype.itemsize
         with scope("embed"):
             if layout.pos is not None:
                 return g[layout.tok][tokens] + g[layout.pos][pos]
@@ -2226,6 +2232,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         # what belongs to a lane of a block with a ring: its ring blocks
         # of every sliding layer, which a snapshot holds
         state_bytes_per_lane = nw * window_bytes_per_block
+    # what a step reads WHOLE whatever the traffic, in elements (their
+    # bytes are the weights' dtype's, which a step's trace notes): every
+    # array of the stack outside the routed experts, once a pass, and
+    # what stands outside the stack (the final norm, the head, an exit
+    # gate); not the embedding, of which a step reads a row a lane,
+    # unless the head is the table, nor a position table.  And ONE
+    # routed expert's three matrices (0: no experts)
+    routed = [lay[key][0] for lay in layout.layers if "router" in lay
+              for key in ("gate", "up", "down")]
+    stack = {name for lay in layout.layers for pair in lay.values()
+             for name in pair if name is not None} - set(routed)
+    rows_of = {layout.pos} | ({layout.tok} - set(layout.head))
+    weight_elems = sum(
+        (passes if name in stack else 1) * math.prod(shapes[name])
+        for name in set(shapes) - set(routed) - rows_of)
+    expert_elems = sum(math.prod(shapes[name][1:]) for name in routed[:3])
+    # a page of one plane of each pool, and the rows a lane writes a
+    # position over all of them
+    page_bytes = {"table": int((1 if latent else 2) * bs * d_kv
+                               * elem_bytes),
+                  "ring": window_bytes_per_block // max(n_win, 1),
+                  "index": int(bs * d_idx * elem_bytes)}
+    row_bytes = (bytes_per_block + window_bytes_per_block) // bs
 
     def init_pool(num_blocks, device=None, window_blocks=None,
                   lanes=None):
@@ -2468,7 +2497,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         layers too: two a layer).  With a latent cache `latent_rows`:
         the rows the lanes with a sequence hold under their cursors
         (cursor + 1), summed over them and the planes.  With a lightning
-        indexer `index_planes`, `kv_rows_indexed` (the rows its selecting
+        indexer `kv_rows_indexed` (the rows its selecting
         layers score: cursor + 1 a lane a plane) and `kv_rows_selected`
         (the rows attention is over: `index_topk` at most of cursor + 1,
         a lane a latent plane), and `index_pages_read` of
@@ -2479,7 +2508,24 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         run); on the gather path every page.  With a LATENT ring
         `ring_bytes`: the ring rows the lanes with a sequence read
         (cursor + 1, the ring's rows at most), times the ring's stored
-        row's bytes, summed over them and the sliding layers."""
+        row's bytes, summed over them and the sliding layers.
+        And the BYTES the tick must move, under `perf/*_bytes.py`'s rule
+        (what the algorithm needs, not what a compiler emitted), once a
+        step has been traced (the weights' dtype is its argument) and
+        never for a `step_window` tick: `step_bytes_weights`, every
+        array the step reads whole whatever the traffic (each layer's
+        matrices and scales outside the routed experts, once a pass of a
+        looped stack; the final norm, the head, an exit gate; of the
+        embedding a step reads a row a lane, which is left out unless
+        the head is the table); `step_bytes_cache`, what follows the
+        cursors: the table pages read times a table page's bytes a
+        plane, the rings' pages times a ring page's (`ring_bytes` where
+        the ring is latent), `index_pages_read` times an index page's,
+        the ticking lanes' states and tails read and written back
+        (`state_bytes`, `conv_tail_bytes`, a Mamba lane's state the same
+        way) and the row a ticking lane writes a plane; and
+        `expert_bytes`, ONE routed expert's three matrices (0 without
+        experts), for the span's `moe_experts_hit` to multiply."""
         n = len(cursors)
         counts, saved = {}, saved or {}
         if passes > 1:
@@ -2489,17 +2535,22 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         rows = cursors.astype(np.int64) + 1  # K/V rows a lane attends
         table = slots * (planes * nb + n_win * nw)
         read, multiplied = table, table * bs
+        read_ring = slots * n_win * nw       # of them, the rings' pages
         idle = slots - n                     # a page each, a plane
         if tiling is not None and not windowed:
             streamed = planes * np.array(_paged_attention.stream_counts(
                 rows, idle, *tiling[0], bs, saved.get("table")))
+            read_ring = 0
             if n_win:
-                streamed += n_win * np.array(_paged_attention.stream_counts(
+                ring = n_win * np.array(_paged_attention.stream_counts(
                     np.minimum(rows, nw * bs), idle, *tiling[1], bs,
                     saved.get("ring")))
+                read_ring = int(ring[0])
+                streamed += ring
             read, multiplied, dma, covered = map(int, streamed)
             counts["kv_dma_ops"] = (1 if latent else 2) * dma
             counts["kv_pages_covered"] = covered
+        read_table = read - read_ring
         counts["kv_pages_read"] = read
         counts["kv_pages_table"] = table
         counts["kv_rows_multiplied"] = multiplied
@@ -2516,7 +2567,6 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if latent:
             counts["latent_rows"] = planes * int(rows.sum())
         if sparse:
-            counts["index_planes"] = n_index
             counts["kv_rows_indexed"] = n_index * int(rows.sum())
             counts["kv_rows_selected"] = planes * int(
                 np.minimum(rows, spec.index_topk).sum())
@@ -2548,6 +2598,18 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 not decoder.delta_kernel.startswith("xla:"))
         if moe_layers:
             counts["moe_layers"] = moe_layers
+        if decoder.weight_itemsize is not None and not windowed:
+            # what this tick must move (a `step_window` tick's written
+            # rows are its caller's to know: no account of it)
+            counts["step_bytes_weights"] = (
+                weight_elems * decoder.weight_itemsize)
+            counts["step_bytes_cache"] = int(
+                read_table * page_bytes["table"]
+                + counts.get("ring_bytes", read_ring * page_bytes["ring"])
+                + counts.get("index_pages_read", 0) * page_bytes["index"]
+                + (2 * n * state_bytes_per_lane if stateful else 0)
+                + n * row_bytes)
+            counts["expert_bytes"] = expert_elems * decoder.weight_itemsize
         return counts
 
     # where a block has two kinds of state, the ring's word stands; a

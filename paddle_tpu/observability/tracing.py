@@ -38,6 +38,15 @@ at process exit, so a multi-process run drops one merge-able trace file
 per process) or while any span listener is registered (the flight
 recorder, the tail sampler, a benchmark's tap).  While neither holds, a
 span costs one boolean test and nothing is created or stored.
+
+A span may carry a DEFERRED ACCOUNT (``Span.defer(fn)``): attributes that
+cost more to make than the span's thread should pay between two pieces
+of its work, and that nobody needs before the record is read.  The
+record is stored and handed to the listeners without them; the function
+runs once, on the thread of the first reader (``finished_spans()`` and
+every export, which all read through it), and its dict is merged into
+``attrs``.  A listener therefore reads name, ``ts``, ``dur``, ``cpu`` and
+the eager attributes only.
 """
 from __future__ import annotations
 
@@ -65,6 +74,8 @@ __all__ = [
     "trace_dir",
     "finished_spans",
     "dropped_spans",
+    "dropped_deferred",
+    "failed_deferred",
     "clear",
     "chrome_trace_events",
     "write_chrome_trace",
@@ -92,6 +103,22 @@ _MAX_SPANS = 262_144
 _spans: deque = deque(maxlen=_MAX_SPANS)
 _dropped = 0
 _lock = threading.Lock()
+# deferred accounts (`Span.defer`): the records stored with one since
+# the store was last read whole, oldest first, _MAX_DEFERRED at most.  A
+# record keeps its function under "deferred" until somebody reads it
+# (`_resolve`); one that leaves this ring unread loses the function, and
+# `dropped_deferred()` counts it.
+# It has to hold a benchmark window's ticks whole, like the ring above:
+# closed32's ramp and window are 13 000 ticks, agent96's 10 000.  What a
+# function holds is its maker's to keep small (a tick's: its cursors, a
+# mask and its lanes' table rows by reference, about a kilobyte).
+_MAX_DEFERRED = 65_536
+_deferred: deque = deque()
+_dropped_deferred = 0
+_failed_deferred = 0
+# taken by a reader while it resolves, never by a span: two readers at
+# once (the flight recorder's flush beside a dump) run a function once
+_resolve_lock = threading.Lock()
 _tls = threading.local()
 _rng = random.Random()
 # span listeners (the flight recorder's tap, the tail sampler, a
@@ -106,10 +133,13 @@ def _after_fork_in_child():
     would re-dump the parent's spans under its own pid), and the buffer
     lock may have been held by a parent thread at fork time."""
     global _spans, _dropped, _lock, _TAIL
+    global _deferred, _dropped_deferred, _failed_deferred, _resolve_lock
     _rng.seed()  # fresh OS entropy
     _lock = threading.Lock()
+    _resolve_lock = threading.Lock()
     _spans = deque(maxlen=_MAX_SPANS)
-    _dropped = 0
+    _deferred = deque()
+    _dropped = _dropped_deferred = _failed_deferred = 0
     # a forked child shares the parent's tail buffer: re-arm with a
     # fresh one so the child's dump carries only its own spans
     t = _TAIL
@@ -207,7 +237,7 @@ class Span:
     recorded at exit."""
 
     __slots__ = ("name", "context", "parent_id", "attrs",
-                 "_t0", "_cpu0", "_wall")
+                 "_t0", "_cpu0", "_wall", "_deferred")
 
     def __init__(self, name: str, context: SpanContext,
                  parent_id: Optional[str], attrs: dict):
@@ -215,6 +245,7 @@ class Span:
         self.context = context
         self.parent_id = parent_id
         self.attrs = attrs
+        self._deferred = None
         self._wall = time.time()
         self._t0 = time.perf_counter()
         # the CPU clock is read inside the wall clock's readings, at
@@ -223,6 +254,23 @@ class Span:
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
+
+    def defer(self, fn) -> None:
+        """Give the span a deferred account: `fn()` returns a dict of
+        attributes that are costly to make and that nobody needs until
+        the record is read.  The span's thread never calls it.  The
+        record is stored and handed to the listeners without them; `fn`
+        runs ONCE, on the thread that first reads the record
+        (`finished_spans()`, and through it every export), its dict is
+        merged into `attrs` (over an eager attribute of the same name)
+        and the reference dropped.  So `fn` must hold everything it
+        needs by value or by a reference nobody mutates, and hold
+        little: the store keeps the newest `_MAX_DEFERRED` unread
+        accounts (`dropped_deferred()` counts the rest).  A `fn` that
+        raises leaves the attributes absent and is counted
+        (`failed_deferred()`), never raised into the reader.  One
+        account a span: a second `defer` replaces the first."""
+        self._deferred = fn
 
 
 def add_span_listener(fn) -> None:
@@ -242,19 +290,48 @@ def remove_span_listener(fn) -> None:
 def _store(rec: dict) -> None:
     """Append one finished record to the ring and hand it to the
     listeners.  Only reached while spans are live."""
-    global _dropped
+    global _dropped, _dropped_deferred
     with _lock:
         if len(_spans) == _spans.maxlen:
             _dropped += 1
         _spans.append(rec)
+        if "deferred" in rec:
+            _deferred.append(rec)
+            if len(_deferred) > _MAX_DEFERRED:
+                # (a reader may be resolving it this moment: then the
+                # pop finds nothing, or the reader's own finds nothing)
+                if _deferred.popleft().pop("deferred", None) is not None:
+                    _dropped_deferred += 1
     for fn in _listeners:
         fn(rec)
 
 
+def _resolve(recs=None) -> None:
+    """Make the deferred accounts of `recs` (None: of every record that
+    still has one) on this thread and merge each into its record's
+    `attrs`: what every reader of full records calls before it looks at
+    them.  A reader that finds another at it waits, so that no record is
+    seen between the function's removal and the merge."""
+    global _failed_deferred
+    with _resolve_lock:
+        if recs is None:
+            with _lock:
+                recs = list(_deferred)
+                _deferred.clear()
+        for rec in recs:
+            fn = rec.pop("deferred", None)
+            if fn is None:
+                continue
+            try:
+                rec["attrs"].update(fn())
+            except Exception:
+                _failed_deferred += 1
+
+
 def _record(name: str, ctx: SpanContext, parent_id: Optional[str],
             ts: float, dur: float, cpu: Optional[float],
-            attrs: dict) -> None:
-    _store({
+            attrs: dict, deferred=None) -> None:
+    rec = {
         "name": name,
         "trace_id": ctx.trace_id,
         "span_id": ctx.span_id,
@@ -266,7 +343,10 @@ def _record(name: str, ctx: SpanContext, parent_id: Optional[str],
         "tid": threading.get_ident(),
         "thread": threading.current_thread().name,
         "attrs": dict(attrs),
-    })
+    }
+    if deferred is not None:
+        rec["deferred"] = deferred
+    _store(rec)
 
 
 class _NoopCtx:
@@ -312,7 +392,7 @@ class _SpanCtx:
         cpu = time.thread_time() - s._cpu0
         _stack().pop()
         _record(s.name, s.context, s.parent_id, s._wall,
-                time.perf_counter() - s._t0, cpu, s.attrs)
+                time.perf_counter() - s._t0, cpu, s.attrs, s._deferred)
         return False
 
 
@@ -380,12 +460,18 @@ def finished_spans(last: Optional[int] = None) -> List[dict]:
     given none), trace_id, span_id, parent_id, pid, tid, thread, attrs.
     At most the last `_MAX_SPANS`; `dropped_spans()` says how many
     older ones the ring let go.  `last`
-    keeps the newest that many (the flight recorder's dump)."""
-    with _lock:
-        if last is None:
+    keeps the newest that many (the flight recorder's dump).  The
+    records' deferred accounts (`Span.defer`) are made here, on the
+    caller's thread, before it sees them: of the whole store, or of the
+    newest `last`."""
+    if last is None:
+        _resolve()
+        with _lock:
             return list(_spans)
+    with _lock:
         tail = list(islice(reversed(_spans), last))
     tail.reverse()
+    _resolve(tail)
     return tail
 
 
@@ -395,11 +481,26 @@ def dropped_spans() -> int:
         return _dropped
 
 
-def clear() -> None:
-    global _dropped
+def dropped_deferred() -> int:
+    """Deferred accounts lost unread since `clear()`: their records
+    stand in the store without the attributes the account would have
+    made (more than `_MAX_DEFERRED` waited for a reader)."""
     with _lock:
+        return _dropped_deferred
+
+
+def failed_deferred() -> int:
+    """Deferred accounts whose function raised when a reader made them,
+    since `clear()`: their records lack those attributes too."""
+    return _failed_deferred
+
+
+def clear() -> None:
+    global _dropped, _dropped_deferred, _failed_deferred
+    with _resolve_lock, _lock:
         _spans.clear()
-        _dropped = 0
+        _deferred.clear()
+        _dropped = _dropped_deferred = _failed_deferred = 0
 
 
 # ---------------------------------------------------------------------------
@@ -558,6 +659,7 @@ class TailSampler:
             self._dirty = False
             self._last_flush = time.monotonic()
             spans = self._sampled_spans_locked()
+        _resolve(spans)     # the kept records leave the process here
         out = path or os.path.join(self._dir,
                                    f"trace_tail_{os.getpid()}.json")
         events = [{

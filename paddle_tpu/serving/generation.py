@@ -265,7 +265,8 @@ class _Seq:
                  "temperature", "seed", "cur", "slot", "emitted",
                  "t_submit", "t_submit_wall", "t_admit", "t_first",
                  "cached", "expires", "trace_ctx", "draft_next",
-                 "prompt_keys", "snap_at", "snap_row", "snap_saved", "cut")
+                 "prompt_keys", "snap_at", "snap_row", "snap_saved", "cut",
+                 "table")
 
     def __init__(self, stream, max_new, eos_id, temperature, seed,
                  expires, trace_ctx):
@@ -308,12 +309,76 @@ class _Seq:
         self.snap_row = None
         self.snap_saved = 0
         self.cut = 0
+        # the request's block table from its admission on (`_Table`)
+        self.table = None
 
     @property
     def positions_needed(self) -> int:
         # the cursor writes K/V at positions 0 .. prompt+max_new-2 (the
         # final emitted token is delivered, never re-attended)
         return self.prompt_len + self.max_new - 1
+
+
+class _Table:
+    """A request's block table as the cache made it at admission (`row`,
+    int32 [max_blocks_per_seq]; nobody writes it again: the server copies
+    it into `_tables`, and a request's blocks do not change while it
+    holds them), and the DMA starts the kernels' issue loop saves over
+    it (`saved`: `decoder.starts_saved`'s row a key, a memo the first
+    reader of a traced tick's account makes).  A traced tick's account
+    holds its lanes' by reference: not the `_Seq`, which has a stream
+    and its tokens behind it."""
+
+    __slots__ = ("row", "saved")
+
+    def __init__(self, row):
+        self.row = row
+        self.saved = None
+
+
+class _TickAccount:
+    """What a traced tick's span is given in place of its counts, one a
+    server: `of(...)` returns the function `Span.defer` takes, which
+    calls `decoder.tick_counts` on the thread of whoever first reads the
+    record (docs/observability.md "Span vocabulary").  The scheduler
+    hands it what `build` has made anyway and never calls it.  It holds
+    the decoder and the slots' rings, not the server: a record outlives
+    `close()`."""
+
+    __slots__ = ("_decoder", "_slots", "_rings", "_ring_saved")
+
+    def __init__(self, decoder, slots, rings):
+        self._decoder = decoder
+        self._slots = slots
+        self._rings = rings
+        self._ring_saved = None     # made by the first reader
+
+    def of(self, cur, lanes=None, held=None):
+        """`cur`: the step's `positions` at the lanes that tick, in the
+        lanes' order (an array of the tick's own).  `lanes`, `held`: the
+        mask of those lanes and a list over ALL lanes with their
+        `_Table`s; without them the tick is a `step_window` tick, which
+        gathers always."""
+        return functools.partial(self._counts, cur, lanes, held)
+
+    def _counts(self, cur, lanes, held) -> dict:
+        dec = self._decoder
+        if lanes is None:
+            return dec.tick_counts(cur, self._slots, windowed=True)
+        held = [held[i] for i in np.flatnonzero(lanes)]
+        new = [t for t in held if t.saved is None]
+        if new:
+            made = dec.starts_saved(np.stack([t.row for t in new]))
+            for i, t in enumerate(new):
+                t.saved = {name: rows[i] for name, rows in made.items()}
+        saved = ({name: np.stack([t.saved[name] for t in held])
+                  for name in held[0].saved} if held else {})
+        if self._rings is not None:
+            if self._ring_saved is None:
+                self._ring_saved = dec.starts_saved(rings=self._rings)
+            saved.update((name, rows[lanes])
+                         for name, rows in self._ring_saved.items())
+        return dec.tick_counts(cur, self._slots, saved=saved)
 
 
 class _Tick:
@@ -521,15 +586,9 @@ class GenerationServer:
         rings = decoder.slot_rings(self._slots) if ring else None
         self._rings = (jax.device_put(rings, self._device) if ring
                        else None)
-        # the DMA starts the kernels' issue loop saves over each slot's
-        # table and ring (`decoder.starts_saved`), made once a table: a
-        # tick's span looks its slots' counts up.  `_saved_stale`: the
-        # slots whose table was set since; their rows are made by the
-        # next tick that has a span to put them on (`_tick_attrs`), so
-        # a server nobody traces pays nothing at admission (closed32
-        # admits a request every other tick, and its host is 85% busy)
-        self._saved = decoder.starts_saved(self._tables, rings)
-        self._saved_stale = np.zeros(self._slots, bool)
+        # what a traced tick's span gets in place of the decoder's
+        # counts (`_TickAccount`): made by the span's reader
+        self._account = _TickAccount(decoder, self._slots, rings)
         # the last admission left the queue's head waiting for BLOCKS
         # with a slot free (on the next tick's span as `kv_wait`)
         self._kv_wait = False
@@ -993,7 +1052,7 @@ class GenerationServer:
             seq.slot = slot
             self._active[slot] = seq
             self._tables[slot] = table
-            self._saved_stale[slot] = True
+            seq.table = _Table(table)
             admitted.append(seq)
         return admitted
 
@@ -1011,7 +1070,6 @@ class GenerationServer:
     def _evict_locked(self, seq: _Seq):
         self._active[seq.slot] = None
         self._tables[seq.slot] = 0
-        self._saved_stale[seq.slot] = True
         seq.slot = -1
         with obs_attr.phase("generation", "kv_release"):
             self._cache.release(seq)
@@ -1121,11 +1179,15 @@ class GenerationServer:
         prev = self._inflight
         clock = self._clock
         with obs_attr.phase("generation", "build") as bsp:
-            # a span is live: the tick span's counts come out of this
-            # one walk and the arrays it fills; else none is computed
-            # (a tick span that goes live between this block and the
-            # next, once an arming, carries `active` and `ahead` alone)
+            # a span is live: the scheduler's own counts come out of
+            # this one walk, and what the decoder's are made FROM (the
+            # lanes' cursors, mask and tables, all made or held anyway)
+            # goes to the tick span as a deferred account; else nothing
+            # is computed or kept (a tick span that goes live between
+            # this block and the next, once an arming, carries `active`
+            # and `ahead` alone)
             live = bsp is not None
+            held = [None] * self._slots if live else None
             tokens = np.zeros(self._slots, np.int32)
             positions = np.zeros(self._slots, np.int32)
             temps = np.zeros(self._slots, np.float32)
@@ -1146,8 +1208,10 @@ class GenerationServer:
                 temps[slot] = seq.temperature
                 seeds[slot] = seq.seed
                 active[slot] = True
-                if live and cur < seq.prompt_len - 1:
-                    prefill += 1
+                if live:
+                    held[slot] = seq.table
+                    if cur < seq.prompt_len - 1:
+                        prefill += 1
             # attribution: dispatch is "prefill" while EVERY ticking
             # sequence is still teacher-forcing its prompt, else
             # "decode" (mixed ticks are decode work for at least one
@@ -1160,14 +1224,16 @@ class GenerationServer:
                 prefilling = all(c < s.prompt_len - 1 for s, _, c in rows)
             phase_name = "prefill" if prefilling else "decode"
             tables = self._step_tables()
-            attrs = (self._tick_attrs(len(rows), prefill,
-                                      positions[active], active)
-                     if live else {})
+            attrs = self._tick_attrs(prefill) if live else {}
+            account = (self._account.of(positions[active], active, held)
+                       if live else None)
         clock.mark("build")
         with obs_tracing.span("serving.decode_tick", active=len(rows),
                               **attrs) as sp:
             if sp is not None:
                 sp.set_attr("ahead", int(prev is not None))
+                if account is not None:
+                    sp.defer(account)
             with obs_attr.phase("generation", phase_name):
                 fault_injector().fire("serving.decode")
                 fed = _feed_tokens()(
@@ -1231,39 +1297,21 @@ class GenerationServer:
             return self._tables.copy()
         return self._tables.copy(), self._rings
 
-    def _tick_attrs(self, n: int, prefill: int, cur: np.ndarray,
-                    slots=None, window: bool = False) -> dict:
-        """The counts of the tick being dispatched, for its
-        `serving.decode_tick` span, from what `build` has already made:
-        `n` slots (`len(cur)`), `prefill` of them teacher-forcing a
-        prompt position (cursor below prompt_len - 1: they deliver
-        nothing), and `cur`, the step's `positions` at those slots
-        (`slots`: which they are, a mask over the lanes; a `window` tick
-        needs none).  Only called while a span is live.  The scheduler's own:
-        `prefill`, `kv_used` of `kv_total` pool blocks owned, and
-        `kv_wait`: 1 where the admission before this tick left the
-        queue's head waiting with a slot free because `can_admit`
-        refused it for blocks.  What the step reads and does at those
-        cursors is the decoder's to count (`decoder.tick_counts`; never
-        through the kernel on a `step_window` tick: `window`; its DMA
-        starts looked up in `_saved`, whose rows for the tables set
-        since the last span are made here, once a table)."""
-        saved = None
-        if slots is not None:
-            stale = self._saved_stale
-            if stale.any():
-                for name, rows in self._decoder.starts_saved(
-                        self._tables[stale]).items():
-                    self._saved[name][stale] = rows
-                stale[:] = False
-            saved = {name: rows[slots]
-                     for name, rows in self._saved.items()}
+    def _tick_attrs(self, prefill: int) -> dict:
+        """The scheduler's own counts of the tick being dispatched, for
+        its `serving.decode_tick` span: `prefill` of its slots
+        teacher-forcing a prompt position (cursor below prompt_len - 1:
+        they deliver nothing), `kv_used` of `kv_total` pool blocks
+        owned, and `kv_wait`: 1 where the admission before this tick
+        left the queue's head waiting with a slot free because
+        `can_admit` refused it for blocks.  Only called while a span is
+        live.  What the step reads and does at the lanes' cursors is the
+        decoder's to count, and the span's reader's to ask for
+        (`_TickAccount`)."""
         return {"prefill": prefill,
                 "kv_used": self._cache.used_blocks,
                 "kv_total": self._cache.num_blocks,
-                "kv_wait": int(self._kv_wait),
-                **self._decoder.tick_counts(cur, self._slots,
-                                            windowed=window, saved=saved)}
+                "kv_wait": int(self._kv_wait)}
 
     def _step_counts(self, sp, counts) -> None:
         """What a step counted on the device, summed onto the live
@@ -1524,11 +1572,13 @@ class GenerationServer:
                     prefill += 1
             full_plans = [(seq, c, m, teacher, n_prop, proposals[seq])
                           for seq, c, m, teacher, n_prop in plans]
-            attrs = (self._tick_attrs(len(plans), prefill, pos[nv > 0],
-                                      window=True)
-                     if bsp is not None else {})
+            attrs = self._tick_attrs(prefill) if bsp is not None else {}
+            account = (self._account.of(pos[nv > 0])
+                       if bsp is not None else None)
         with obs_tracing.span("serving.decode_tick", active=len(plans),
                               speculative=True, **attrs) as sp:
+            if sp is not None and account is not None:
+                sp.defer(account)
             with obs_attr.phase("generation", "draft_verify"):
                 fault_injector().fire("serving.decode")
                 nxt, self._pool_k, self._pool_v, *counts = (

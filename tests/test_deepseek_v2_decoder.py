@@ -504,6 +504,8 @@ def test_generation_server_serves_the_block_and_refuses_by_name():
         assert srv.stats()["decode_kernel"] == "xla:not_tpu"
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
         srv.close()
     ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
     assert ticks and all(a["moe_layers"] == 2 and a["latent_rows"] > 0

@@ -275,6 +275,8 @@ def test_served_with_the_kernel_in_a_hit_equals_the_miss_and_the_lines(
         hit, stats = solar._serve(dec, g, True, asks)
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
     miss, _ = solar._serve(dec, g, False, asks)
     assert dec.delta_kernel == delta_rule.NAME
     assert stats["delta_kernel"] == delta_rule.NAME

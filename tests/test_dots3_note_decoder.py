@@ -606,7 +606,8 @@ def test_counts_of_a_tick_name_table_rings_and_selection_together():
     assert counts["past_window"] == 2
     assert counts["kv_rows_indexed"] == 2 * rows.sum()
     assert counts["kv_rows_selected"] == 2 * np.minimum(rows, TOPK).sum()
-    assert counts["index_planes"] == 2 and counts["moe_layers"] == 4
+    assert dec.index_planes == 2 and counts["moe_layers"] == 4
+    assert "index_planes" not in counts     # the field has no reader
     # three rings of rows 128 wide in bfloat16, 12 rows at most
     assert counts["ring_bytes"] == 3 * 128 * 2 * np.minimum(
         rows, NW * BS).sum()

@@ -653,10 +653,12 @@ def test_generation_server_serves_the_block_and_refuses_by_name():
                              kv_blocks=2 * NB, prefix_cache=True)
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
     assert got == want and stats["decode_kernel"] == "xla:not_tpu"
     ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
     assert ticks and all(
-        a["index_planes"] == 2 and a["moe_layers"] == 4
+        "index_planes" not in a and a["moe_layers"] == 4
         and a["kv_rows_indexed"] * 5 == a["latent_rows"] * 2
         and 0 < a["kv_rows_selected"] <= a["latent_rows"] for a in ticks)
     assert any(a["kv_rows_selected"] < a["latent_rows"] for a in ticks)
@@ -694,6 +696,8 @@ def test_a_prefix_hit_reads_latent_rows_and_index_keys_from_shared_blocks():
                 srv.close()
         finally:
             tracing.remove_span_listener(spans.append)
+            # (a listener sees a tick before its account is made: a reader's)
+            tracing.finished_spans()
     assert out[True] == out[False] and hits >= 2 * 7
     done = [s["attrs"] for s in spans if s["name"] == "serving.request"]
     assert sorted(a["prefix_hit_tokens"] for a in done) == [0, 28, 28]
@@ -781,7 +785,7 @@ def test_scopes_name_the_indexer_and_the_selected_rows_attention():
     assert scopes["g[\\'layer_2.kv_b_proj.w_0\\']"] == \
         "paged_decoder/latent_absorb"
     counts = dec.tick_counts(np.array([3, 40]), 4)
-    assert (counts["index_planes"], counts["kv_rows_indexed"],
+    assert (dec.index_planes, counts["kv_rows_indexed"],
             counts["kv_rows_selected"], counts["latent_rows"]) == (
                 2, 2 * 45, 5 * (4 + TOPK), 5 * 45)
     # a latent block without an indexer names none of it

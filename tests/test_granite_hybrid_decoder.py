@@ -419,6 +419,8 @@ def test_generation_server_serves_a_state_a_lane():
         assert stats["kv_blocks_free"] == stats["kv_blocks_total"]
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
         srv.close()
     ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
     assert ticks and all({"state_lanes", "state_resets"} <= set(a)

@@ -550,6 +550,8 @@ def test_generation_server_serves_the_block_and_refuses_by_name():
         assert srv.stats()["decode_kernel"] == "xla:not_tpu"
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
         srv.close()
     ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
     assert ticks and all(a["moe_layers"] == L and a["kv_planes"] == 2 * L
@@ -851,6 +853,8 @@ def test_the_readers_read_the_identity_share_from_the_tick_spans():
                     sp.set_attr("moe_assignments", sent)
     finally:
         tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
     run = types.SimpleNamespace(spans=spans)
     assert reader.compute(run) == pytest.approx(100.0 * 50 / 150)
     assert reader.compute(types.SimpleNamespace(spans=[])) is None

@@ -1228,9 +1228,11 @@ def test_tick_spans_count_a_start_for_a_group_that_is_a_run():
     reaches the fourth page the chunk's one group of 4 is ONE start
     (and one wait) a pool a layer where three pages were three starts
     and two waits; the idle slots' page a start and a wait each.  The
-    server looks the count up in what it made of the table, once,
-    when the table's first tick had a span (`decoder.starts_saved`):
-    the second request's table, set in a used slot, counts the same."""
+    span's READER looks the count up in what it makes of the request's
+    table, once a table, long after the request has gone
+    (`decoder.starts_saved`, a memo on `_Seq.table`: the deferred
+    account of PR 67): the second request's table, set in a used slot,
+    counts the same, and the first's ticks still count by THEIR table."""
     from paddle_tpu.models.transformer import build_lm_paged_decoder
 
     _, states = _decoder()

@@ -184,6 +184,149 @@ def test_store_is_a_ring_that_drops_the_oldest_and_counts(monkeypatch):
     assert tracing._MAX_SPANS >= 2 * 48 * (201 * 6 + 62 * 3)
 
 
+def _deferred_span(name, fn, **attrs):
+    """One span with a deferred account, made on a thread of its own:
+    -> that thread's ident."""
+    import threading
+
+    def body():
+        with tracing.span(name, **attrs) as sp:
+            sp.defer(fn)
+
+    t = threading.Thread(target=body)
+    t.start()
+    t.join()
+    return t.ident
+
+
+@pytest.mark.parametrize("read", ["store", "newest", "chrome", "flight",
+                                  "tail"])
+def test_a_deferred_account_is_made_once_by_the_first_reader(read, tmp_path):
+    """`Span.defer(fn)`: the span's thread never calls `fn`; the record
+    stands in the store without its attributes until somebody READS it
+    by any of the ways a record leaves the process (the store whole, its
+    newest, the Chrome export, the flight recorder's dump, the tail
+    sampler's flush), and that reader's thread calls `fn` once; the
+    dict is merged into `attrs` and the reference dropped."""
+    import json
+    import threading
+
+    calls = []
+
+    def account():
+        calls.append(threading.get_ident())
+        return {"pages": 7, "k": 2}
+
+    sampler = (tracing.arm_tail_sampler(threshold_s=0.0,
+                                        out_dir=str(tmp_path),
+                                        flush_s=1e9)
+               if read == "tail" else None)
+    tracing.set_enabled(True)
+    made_on = _deferred_span("tick", account, k=1)
+    assert calls == []
+    (raw,) = tracing._spans                 # the record as it was stored
+    assert raw["attrs"] == {"k": 1} and raw["deferred"] is account
+    if read == "store":
+        (rec,) = tracing.finished_spans()
+    elif read == "newest":
+        (rec,) = tracing.finished_spans(last=5)
+    elif read == "chrome":
+        (event,) = tracing.chrome_trace_events(include_profiler=False)
+        assert event["args"]["pages"] == 7
+        rec = raw
+    elif read == "flight":
+        (rec,) = flightrecorder.FlightRecorder().dump_dict()["spans"]
+    else:
+        path = sampler.flush(force=True)
+        with open(path) as f:
+            (event,) = json.load(f)["traceEvents"]
+        assert event["args"]["pages"] == 7 and event["args"]["k"] == 2
+        rec = raw
+    assert rec is raw and "deferred" not in rec
+    assert rec["attrs"] == {"k": 2, "pages": 7}      # over the eager k
+    assert calls == [threading.get_ident()] and calls[0] != made_on
+    assert rec["tid"] == made_on
+    # once: every later read finds the attributes and calls nothing
+    assert tracing.finished_spans() == [rec]
+    assert tracing.finished_spans(last=1) == [rec]
+    assert len(calls) == 1
+    assert (tracing.dropped_deferred(), tracing.failed_deferred()) == (0, 0)
+
+
+def test_a_listener_sees_the_record_unresolved_and_unbroken():
+    """The three listeners in the tree read name, `ts`, `dur`, `cpu` and
+    eager attributes only: a listener is handed the store's own record
+    before any reader made its account, whole in everything else, and
+    sees the attributes there once a reader has."""
+    calls, seen = [], []
+
+    def listener(rec):
+        seen.append((dict(rec["attrs"]), "deferred" in rec,
+                     {k for k in rec if k != "deferred"}))
+
+    tracing.add_span_listener(listener)
+    with listening() as got:
+        _deferred_span("tick", lambda: calls.append(1) or {"pages": 7},
+                       active=3)
+        with tracing.span("plain"):
+            pass
+    assert calls == []
+    attrs, deferred, keys = seen[0]
+    assert attrs == {"active": 3} and deferred
+    assert keys == {"name", "trace_id", "span_id", "parent_id", "ts",
+                    "dur", "cpu", "pid", "tid", "thread", "attrs"}
+    assert seen[1][:2] == ({}, False)       # no account: nothing kept
+    assert got[0]["dur"] >= 0 and got[0]["cpu"] is not None
+    assert tracing.finished_spans() == got  # the same records, resolved
+    assert got[0]["attrs"] == {"active": 3, "pages": 7} and calls == [1]
+
+
+def test_a_deferred_account_that_raises_is_counted_not_raised():
+    tracing.set_enabled(True)
+    _deferred_span("bad", lambda: 1 / 0, k=1)
+    _deferred_span("worse", lambda: 5)              # no dict
+    _deferred_span("good", lambda: {"n": 2})
+    bad, worse, good = tracing.finished_spans()
+    assert bad["attrs"] == {"k": 1} and worse["attrs"] == {}
+    assert good["attrs"] == {"n": 2}
+    assert not any("deferred" in r for r in (bad, worse, good))
+    assert tracing.failed_deferred() == 2
+    assert tracing.dropped_deferred() == 0
+    tracing.finished_spans()
+    assert tracing.failed_deferred() == 2           # not tried again
+    tracing.clear()
+    assert tracing.failed_deferred() == 0
+
+
+def test_the_oldest_unread_account_is_dropped_and_counted(monkeypatch):
+    """At most `_MAX_DEFERRED` accounts wait for a reader: one more and
+    the oldest record loses its account (the record stays), counted as
+    `dropped_spans()` counts a record; accounts already made take no
+    room."""
+    # a window's ticks whole: closed32's ramp and window are 13 000
+    # ticks, agent96's 10 000 (docs/observability.md)
+    assert tracing._MAX_DEFERRED >= 4 * 13_000
+    monkeypatch.setattr(tracing, "_MAX_DEFERRED", 3)
+    tracing.set_enabled(True)
+    calls = []
+    for i in range(5):
+        _deferred_span(f"s{i}", lambda i=i: calls.append(i) or {"i": i})
+    assert tracing.dropped_deferred() == 2 and calls == []
+    spans = tracing.finished_spans()
+    assert [s["name"] for s in spans] == [f"s{i}" for i in range(5)]
+    assert [s["attrs"] for s in spans] == [{}, {}, {"i": 2}, {"i": 3},
+                                           {"i": 4}]
+    assert calls == [2, 3, 4] and tracing.dropped_spans() == 0
+    # what has been read waits no longer: three more fit
+    for i in range(5, 8):
+        _deferred_span(f"s{i}", lambda i=i: {"i": i})
+    assert tracing.dropped_deferred() == 2
+    assert [s["attrs"] for s in tracing.finished_spans()[5:]] == [
+        {"i": 5}, {"i": 6}, {"i": 7}]
+    tracing.clear()
+    assert tracing.dropped_deferred() == 0 and not tracing._deferred
+
+
 # ---------------------------------------------------------------------------
 # B. counts on the scheduler's spans
 # ---------------------------------------------------------------------------
@@ -220,7 +363,9 @@ def test_decode_tick_and_request_spans_account_for_the_run():
     # (a block without experts sets no `moe_experts_hit`)
     assert all(set(a) == {"active", "prefill", "kv_used", "kv_total",
                           "kv_pages_read", "kv_pages_table",
-                          "kv_rows_multiplied", "kv_wait", "ahead"}
+                          "kv_rows_multiplied", "kv_wait", "ahead",
+                          "step_bytes_weights", "step_bytes_cache",
+                          "expert_bytes"}
                for a in ticks)
     assert len(reqs) == 6
     for r in reqs:
