@@ -639,7 +639,10 @@ class PagedDecoder:
     # rows a slot) runs the gather always; a LATENT ring's kernel, which
     # is selected at the ring's own geometry, under
     # "paged_attention_ring" ("...:masked_pages" where the ring is
-    # longer than its window)
+    # longer than its window); with a lightning indexer, once a step has
+    # been traced (the lanes are its arguments), "index_selection":
+    # "pallas:select_rows" (`kernels/select_rows.py`) or
+    # "passes:<reason>" where `lm_block.select_rows`' passes select
     kernels: Dict[str, str]
     # (pages a chunk at most, pages of its smallest row window) of the
     # kernel over a slot's table and over its ring: what it copies and
@@ -992,6 +995,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     from ..kernels import delta_rule as _delta_rule
     from ..kernels import grouped_matmul as _grouped_matmul
     from ..kernels import router_choice as _router_choice
+    from ..kernels import select_rows as _select_rows
     from ..kernels import paged_attention as _paged_attention
     from ..kernels import paged_index_scores as _paged_index_scores
 
@@ -1431,13 +1435,26 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     (((2,), (2,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32)   # [S, Hi, rows]
                 scores = (jax.nn.relu(dots) * w_i[:, :, None]).sum(axis=1)
+        # the kernel's own module decides from the lanes of this trace,
+        # the table's rows and the platform whether the selection is its
+        # Pallas call (the keys in VMEM for all 32 counts, whatever the
+        # compiler does with the rest of the step) or `select_rows`'
+        # passes
+        selection, refused = _select_rows.select_index_selection(
+            rows=nb * bs, lanes=s_n, k=spec.index_topk, platform=platform)
+        decoder.kernels["index_selection"] = (
+            selection.name if selection is not None
+            else f"passes:{refused}")
         with scope("indexer_topk"):
             # a lane with no sequence sees row 0 of the null block
             cur = jnp.where(active, positions, 0)
             valid = jnp.arange(nb * bs)[None, :] <= cur[:, None]
-            scores = jnp.where(valid, scores, -jnp.inf)
-            chosen = lm_block.select_rows(scores, valid, spec.index_topk)
-        return chosen, scores, pool_i
+            masked = jnp.where(valid, scores, -jnp.inf)
+            chosen = (selection.select(scores, cur)
+                      if selection is not None
+                      else lm_block.select_rows(masked, valid,
+                                                spec.index_topk))
+        return chosen, masked, pool_i
 
     def _post_join(g, x, y, pair):
         """x + norm(y): a sub-block's output joins the residual stream
@@ -2505,7 +2522,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         read, of those the lanes' tables hold: through the kernel
         (`kernels["lightning_indexer"]`) `stream_counts` of its stream,
         with `index_dma_ops` (a start a group of 16 entries that are a
-        run); on the gather path every page.  With a LATENT ring
+        run); on the gather path every page.  Once a step has been
+        traced also `select_kernel` (1: the selection of the traced
+        step is `kernels/select_rows.py`'s call, 0: `lm_block
+        .select_rows`' passes; `kernels["index_selection"]`).  With a LATENT ring
         `ring_bytes`: the ring rows the lanes with a sequence read
         (cursor + 1, the ring's rows at most), times the ring's stored
         row's bytes, summed over them and the sliding layers.
@@ -2596,6 +2616,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         if decoder.delta_kernel is not None:
             counts["delta_kernel"] = int(
                 not decoder.delta_kernel.startswith("xla:"))
+        if "index_selection" in decoder.kernels:
+            counts["select_kernel"] = int(
+                decoder.kernels["index_selection"].startswith("pallas:"))
         if moe_layers:
             counts["moe_layers"] = moe_layers
         if decoder.weight_itemsize is not None and not windowed:

@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu.kernels import paged_attention, paged_index_scores
+from paddle_tpu.kernels import (paged_attention, paged_index_scores,
+                                select_rows)
 from paddle_tpu.models import lm_block
 from paddle_tpu.models.transformer import build_lm_paged_decoder
 from paddle_tpu.observability import tracing
@@ -147,7 +148,8 @@ def _weights(dec, seed=0):
     return g
 
 
-def _drive(dec, g, seqs, slots=None, lanes=None, starts=None, pools=None):
+def _drive(dec, g, seqs, slots=None, lanes=None, starts=None, pools=None,
+           nb=NB):
     """Teacher-force each of `seqs` through `step` in its own lane, the
     tables from a `PagedKVCache`, the rings `slot_rings`', lane i
     starting at tick `starts[i]`; -> (each sequence's [len, V] logits,
@@ -157,10 +159,10 @@ def _drive(dec, g, seqs, slots=None, lanes=None, starts=None, pools=None):
     slots = slots or len(seqs)
     lanes = lanes if lanes is not None else list(range(len(seqs)))
     starts = starts or [0] * len(seqs)
-    cache = PagedKVCache(slots * NB, BS, NB)
+    cache = PagedKVCache(slots * nb, BS, nb)
     pool_k, pool_v = pools or dec.init_pool(
-        1 + slots * NB, window_blocks=1 + slots * NW)
-    tables = np.zeros((slots, NB), np.int32)
+        1 + slots * nb, window_blocks=1 + slots * NW)
+    tables = np.zeros((slots, nb), np.int32)
     rings = dec.slot_rings(slots)
     for s, lane in zip(seqs, lanes):
         tables[lane] = cache.allocate(lane, len(s))
@@ -190,7 +192,7 @@ def _drive(dec, g, seqs, slots=None, lanes=None, starts=None, pools=None):
     lane = lanes[0]
     routing["latent_rows"] = np.asarray(
         pool_k[0][:, tables[lane]], np.float32).reshape(
-            pool_k[0].shape[0], NB * BS, -1)
+            pool_k[0].shape[0], nb * BS, -1)
     routing["ring_rows"] = np.asarray(
         pool_k[1][:, rings[lane]], np.float32).reshape(
             pool_k[1].shape[0], NW * BS, -1)
@@ -413,6 +415,43 @@ def test_both_kernels_in_the_interpreter_equal_the_gather_path(monkeypatch):
     assert np.abs(got - want).max() <= 2e-5 * np.abs(want).max()
 
 
+def test_the_selection_kernel_in_the_interpreter_selects_what_the_passes_do(
+        monkeypatch):
+    """`kernels/select_rows.py` under the interpreter through a whole
+    decoder whose table holds 128 rows (whole 128-lane tiles; the toy's
+    64 are refused by name): both full layers' selections and the
+    logits are the passes' bit for bit, with the rings two wraps past
+    their first, and the path is named once a step is traced, on and
+    off the interpreter."""
+    def decoder(nb):
+        return build_lm_paged_decoder(
+            V, BS, nb, d_model=D, n_heads=H, n_layers=L, d_inner=F,
+            kv_dtype="fp32", platform="cpu", block=_block())[1]
+
+    plain = decoder(32)
+    assert "index_selection" not in plain.kernels   # a step names it
+    g = _weights(plain, seed=6)
+    seqs, drive = [SEQ[:30], SEQ[3:12]], dict(slots=3, lanes=[0, 2],
+                                              starts=[0, 2], nb=32)
+    (want, _), routed_x, _ = _drive(plain, g, seqs, **drive)
+    assert plain.kernels["index_selection"] == "passes:not_tpu"
+    assert plain.tick_counts(np.array([3]), 2)["select_kernel"] == 0
+    monkeypatch.setattr(
+        select_rows, "select_index_selection", functools.partial(
+            select_rows.select_index_selection, interpret=True))
+    small = _decoder()
+    _drive(small, g, [SEQ[:3]])
+    assert small.kernels["index_selection"] == "passes:lane_misaligned"
+    dec = decoder(32)
+    (got, _), routed_k, _ = _drive(dec, g, seqs, **drive)
+    assert dec.kernels["index_selection"] == "pallas:select_rows"
+    assert dec.tick_counts(np.array([3]), 2)["select_kernel"] == 1
+    assert routed_k["selected"].shape[0] == 2       # both full layers
+    assert np.array_equal(routed_x["selected"], routed_k["selected"])
+    assert routed_k["selected"].sum(-1).max() == TOPK
+    assert np.array_equal(want, got)
+
+
 # -- the expert layer's share ------------------------------------------------
 def test_the_eight_shares_and_the_shared_expert_are_the_uncut_layer():
     """The guide's test of a share: the parts that the eight chips of a
@@ -530,6 +569,7 @@ def test_the_server_delivers_the_references_tokens(prefix):
     finally:
         srv.close()
     assert (stats["prefix_hits"] > 0) == prefix
+    assert dec.kernels["index_selection"] == "passes:not_tpu"
     requests = [(np.asarray(p + list(o), np.int32), len(p))
                 for p, o in zip(prompts, out)]
     got = REF.served({n: jnp.asarray(w) for n, w in g.items()}, CONFIG,

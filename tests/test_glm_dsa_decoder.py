@@ -28,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as fluid
-from paddle_tpu.kernels import paged_attention, paged_index_scores
+from paddle_tpu.kernels import (paged_attention, paged_index_scores,
+                                select_rows)
 from paddle_tpu.models import lm_block
 from paddle_tpu.models.transformer import build_lm_paged_decoder
 from paddle_tpu.observability import tracing
@@ -137,7 +138,7 @@ def _weights(dec, seed=0):
 
 
 def _drive(dec, g, seqs, slots=None, lanes=None, starts=None,
-           routing=False):
+           routing=False, nb=NB):
     """Teacher-force each of `seqs` through `step` in its own lane, the
     tables taken from a `PagedKVCache` as the server takes them, lane i
     starting at tick `starts[i]` (lanes out of step); returns each
@@ -146,13 +147,13 @@ def _drive(dec, g, seqs, slots=None, lanes=None, starts=None,
     slots = slots or len(seqs)
     lanes = lanes if lanes is not None else list(range(len(seqs)))
     starts = starts or [0] * len(seqs)
-    cache = PagedKVCache(slots * NB, BS, NB)
-    pool_k, pool_v = dec.init_pool(1 + slots * NB)
+    cache = PagedKVCache(slots * nb, BS, nb)
+    pool_k, pool_v = dec.init_pool(1 + slots * nb)
     # the latent rows a plane a layer, the index keys a plane a
     # selecting layer, on the same blocks
     assert pool_k.shape[0] == L and pool_k.shape[-1] == 128
     assert pool_v.shape == (2,) + pool_k.shape[1:3] + (DI,)
-    tables = np.zeros((slots, NB), np.int32)
+    tables = np.zeros((slots, nb), np.int32)
     for s, lane in zip(seqs, lanes):
         tables[lane] = cache.allocate(lane, len(s))
     zs, zt = np.zeros(slots, np.uint32), np.zeros(slots, np.float32)
@@ -656,9 +657,11 @@ def test_generation_server_serves_the_block_and_refuses_by_name():
         # (a listener sees a tick before its account is made: a reader's)
         tracing.finished_spans()
     assert got == want and stats["decode_kernel"] == "xla:not_tpu"
+    assert dec.kernels["index_selection"] == "passes:not_tpu"
     ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
     assert ticks and all(
         "index_planes" not in a and a["moe_layers"] == 4
+        and a["select_kernel"] == 0
         and a["kv_rows_indexed"] * 5 == a["latent_rows"] * 2
         and 0 < a["kv_rows_selected"] <= a["latent_rows"] for a in ticks)
     assert any(a["kv_rows_selected"] < a["latent_rows"] for a in ticks)
@@ -668,6 +671,55 @@ def test_generation_server_serves_the_block_and_refuses_by_name():
     assert len(done) == 3 and all(
         a["prefix_hit_tokens"] == a["cached_tokens"] <= a["prompt_tokens"]
         for a in done)
+
+
+def test_the_selection_kernel_in_the_interpreter_selects_what_the_passes_do(
+        monkeypatch):
+    """`kernels/select_rows.py` under the interpreter through a whole
+    decoder, on a table of 128 rows (the kernel takes whole 128-lane
+    tiles: the toy's 64 rows are refused by name even there): the path
+    is named once a step is traced, the selection and the logits are
+    the passes' bit for bit over lanes out of step (an idle lane sees
+    row 0), and a server's tick spans carry `select_kernel` 1."""
+    def decoder(nb):
+        return build_lm_paged_decoder(
+            V, BS, nb, d_model=D, n_heads=H, n_layers=L, d_inner=F,
+            kv_dtype="fp32", platform="cpu", block=_block())[1]
+
+    plain = decoder(32)
+    assert "index_selection" not in plain.kernels   # a step names it
+    assert "select_kernel" not in plain.tick_counts(np.array([3]), 2)
+    g = _weights(plain, seed=2)
+    seqs = [SEQ[:22], SEQ[5:16]]
+    drive = dict(slots=3, lanes=[0, 2], starts=[0, 4], routing=True, nb=32)
+    (want, _), routed_x, _ = _drive(plain, g, seqs, **drive)
+    assert plain.kernels["index_selection"] == "passes:not_tpu"
+    assert plain.tick_counts(np.array([3]), 2)["select_kernel"] == 0
+    monkeypatch.setattr(
+        select_rows, "select_index_selection", functools.partial(
+            select_rows.select_index_selection, interpret=True))
+    small = _decoder()
+    _drive(small, g, [SEQ[:3]])
+    assert small.kernels["index_selection"] == "passes:lane_misaligned"
+    dec = decoder(32)
+    (got, _), routed_k, _ = _drive(dec, g, seqs, **drive)
+    assert dec.kernels["index_selection"] == "pallas:select_rows"
+    assert dec.tick_counts(np.array([3]), 2)["select_kernel"] == 1
+    assert np.array_equal(routed_x["selected"], routed_k["selected"])
+    assert routed_k["selected"].sum(-1).max() == TOPK
+    assert np.array_equal(want, got)
+    spans = []
+    tracing.add_span_listener(spans.append)
+    try:
+        served, _ = _served(dec, {n: np.asarray(v) for n, v in g.items()},
+                            [SEQ[:11]], 4, slots=2, kv_blocks=64)
+    finally:
+        tracing.remove_span_listener(spans.append)
+        # (a listener sees a tick before its account is made: a reader's)
+        tracing.finished_spans()
+    ticks = [s["attrs"] for s in spans if s["name"] == "serving.decode_tick"]
+    assert ticks and all(a["select_kernel"] == 1 for a in ticks)
+    assert len(served[0]) == 4
 
 
 def test_a_prefix_hit_reads_latent_rows_and_index_keys_from_shared_blocks():

@@ -580,6 +580,77 @@ def test_router_choice_compiles_for_a_v5e(cell, one_v5e):
     assert text.count(MOSAIC_CALL) == 1 and " sort(" not in text
 
 
+def test_index_selection_compiles_for_a_v5e(one_v5e):
+    """Mosaic's own compile of the lightning indexer's selection at the
+    two selecting cells' shape ([64 lanes, 6912 rows], k 2048): one
+    call, the 64 lanes one block, and no loop of XLA's beside it."""
+    from paddle_tpu.kernels import select_rows
+
+    kern, refused = select_rows.select_index_selection(
+        rows=6912, lanes=64, k=2048, platform="tpu")
+    assert refused is None and kern.name == select_rows.NAME
+    assert kern.lanes_block == 64
+    scores = jax.ShapeDtypeStruct((64, 6912), jnp.float32, sharding=one_v5e)
+    cursors = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_v5e)
+    text = jax.jit(kern.select).lower(scores, cursors).compile().as_text()
+    assert text.count(MOSAIC_CALL) == 1 and " while(" not in text
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "passes"])
+def test_a_selecting_step_compiles_for_a_v5e_without_a_loop(kernel, one_v5e,
+                                                            monkeypatch):
+    """The served step of GLM's toy (the configuration file's rehearsal
+    sizes on a table of 128 rows, 8 lanes) built for "tpu" and compiled
+    for the described v5e: under `paged_decoder/indexer_topk` stand the
+    selection's Mosaic calls, one a selecting layer, and NO `while`
+    (`lm_block.select_rows`' 32 counts, whose keys the compiler's
+    memory-space assignment may leave in HBM: PERF.md section 7, "From
+    PR 68"); with the kernel refused the same scope holds the two loops
+    again, which is what this test would miss were they renamed."""
+    import os
+
+    from paddle_tpu.kernels import select_rows
+    from paddle_tpu.models import lm_block
+    from paddle_tpu.models.transformer import build_lm_paged_decoder
+
+    if not kernel:
+        monkeypatch.setattr(select_rows, "select_index_selection",
+                            lambda **kw: (None, "refused_here"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs",
+                           "glm-5.2-1chip.json")) as f:
+        m = json.load(f)
+    m.update(m["rehearse"])
+    b = m["block"]
+    spec = lm_block.BlockSpec(**dict(
+        b["spec"], **{f: m[k] for f, k in b["from_keys"].items()}))
+    slots, bs, nb = 8, 8, 16
+    _, dec = build_lm_paged_decoder(
+        m["vocab_size"], bs, nb, d_model=m["hidden_size"],
+        n_heads=m["num_attention_heads"], n_layers=m["num_hidden_layers"],
+        d_inner=m[b["d_inner"]], kv_dtype="bf16", platform="tpu", block=spec)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    g = {n: sds(s, jnp.bfloat16) for n, s in dec.state_shapes.items()}
+    pools = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: dec.init_pool(slots * nb + 1)))
+    text = dec.step.lower(
+        g, *pools, sds((slots, nb)), sds((slots,)), sds((slots,)),
+        sds((slots,), jnp.uint32), sds((slots,), jnp.float32),
+        sds((slots,), jnp.bool_)).compile().as_text()
+    assert dec.kernels["index_selection"] == (
+        select_rows.NAME if kernel else "passes:refused_here")
+    under = [line for line in text.splitlines()
+             if "paged_decoder/indexer_topk" in line]
+    loops = [line for line in under if " while(" in line]
+    calls = [line for line in under if MOSAIC_CALL in line]
+    assert (len(loops), len(calls)) == ((0, dec.index_planes) if kernel
+                                        else (dec.index_planes, 0))
+
+
 @pytest.mark.parametrize("shape,dtype,calls", [
     ((1, 8192, 6, 128), jnp.bfloat16, 2),   # the 1024x2048 block table
     ((1, 4096, 8, 64), jnp.bfloat16, 2),    # d64 head-pair packing

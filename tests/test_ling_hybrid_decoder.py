@@ -960,13 +960,14 @@ def test_the_new_reader_and_the_cells_lists_agree_with_the_benchmark():
     bench = _json("BENCHMARK.json")
     (last,) = [m for m in bench["per_layer"]
                if m["name"] == "serve_delta_gates_share"]
-    # (the list's last entry until PR 65, PR 66 and PR 67 appended
-    # theirs)
+    # (the list's last entry until PR 65, PR 66, PR 67 and PR 68
+    # appended theirs)
     assert [m["name"] for m in bench["per_layer"]].index(
         "serve_delta_gates_share") == bench["per_layer"].index(last)
-    assert [m["name"] for m in bench["per_layer"][-4:]] == [
+    assert [m["name"] for m in bench["per_layer"][-5:]] == [
         "serve_latent_ring_roofline", "sched_kv_copy_covered_share",
-        "serve_step_bytes_roofline", "sched_step_cache_bytes_share"]
+        "serve_step_bytes_roofline", "sched_step_cache_bytes_share",
+        "sched_select_kernel_share"]
     assert last["workloads"] == [CELL, "solar-open2-250b-serve-docqa64"]
     reader = _load("reader_delta_gates", "perf", "metrics",
                    "serve_delta_gates_share.py")

@@ -90,6 +90,22 @@ bit for bit.  100 calls a reading; several shapes with commas between
 run in one process.  Copied into the checkout of a commit before PR 63 the
 same command times that commit's sorts.
 
+A lightning indexer's SELECTION the same way (`--shape glm-5.2-select`,
+`dots3-note-prev-select`: lanes, rows and k from the cell's own files,
+cursors a document of the traffic file's and a seeded point of a
+request's question and answer a lane): `kernels/select_rows.py` on 16
+layers' scores chained in one call, ms a SELECTION: whole, `xla`
+(`lm_block.select_rows` jitted alone on the same arguments: with
+nothing else in the program the compiler keeps its loop's keys in VMEM,
+the floor XLA can reach) and `no_counts` (the 32 passes removed: the
+copies, the keys and the mask alone, WRONG results).  `--check`: the
+kernel's mask, then the chip's jitted `select_rows`', against
+`select_rows` called eagerly on the CPU backend, on the shape's scores
+and on the same rounded to quarters (ties at the k-th score in every
+lane, signed zeros among them): rows that differ, the kernel's 0 (the
+jitted lines' not: under `jax.jit` XLA takes their `x + 0.0` for x and
+-0.0 ranks under +0.0).  100 calls a reading.
+
 It imports the kernel and is imported by nothing a cell runs.
 """
 from __future__ import annotations
@@ -188,7 +204,15 @@ ROUTE_CELLS = (
     "solar-open2-250b-serve-docqa64", "ling-3.0-flash-serve-agent128")
 SHAPES.update({cell + "-route": dict(kernel="route", cell=cell)
                for cell in ROUTE_CELLS})
+# a lightning indexer's selection at a cell's lanes, table and k
+# (`run_select`)
+SHAPES.update({
+    "glm-5.2-select": dict(kernel="select", cell="glm-5.2-serve-docqa64"),
+    "dots3-note-prev-select": dict(
+        kernel="select", cell="dots3-note-prev-serve-docqa64")})
 VARIANTS = ("whole", "no_copies", "no_products")
+SELECT_VARIANTS = ("whole", "xla", "no_counts")
+SELECT_LAYERS = 16
 ROUTE_VARIANTS = ("whole", "passes", "no_choice", "no_order")
 ROUTE_LAYERS = 32
 DELTA_VARIANTS = ("whole", "no_copies", "no_arithmetic", "xla")
@@ -866,11 +890,8 @@ def run_delta(name, variants=DELTA_VARIANTS, heads_blocks=(), calls=30,
     return res
 
 
-def route_cell(cell):
-    """(block description, rows of a tick, the model's width) of a
-    serving cell, from its own files as its job reads them."""
-    from paddle_tpu.models import lm_block
-
+def cell_files(cell):
+    """(configuration, traffic mix) of a cell, as its job reads them."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def load(*parts):
@@ -879,12 +900,20 @@ def route_cell(cell):
 
     w = next(w for w in load("BENCHMARK.json")["workloads"]
              if w["name"] == cell)
-    m = load("perf", "configs", w["config"] + ".json")
+    return (load("perf", "configs", w["config"] + ".json"),
+            load("perf", "traffic", w["traffic"] + ".json"))
+
+
+def route_cell(cell):
+    """(block description, rows of a tick, the model's width) of a
+    serving cell, from its own files as its job reads them."""
+    from paddle_tpu.models import lm_block
+
+    m, t = cell_files(cell)
     b = m["block"]
     spec = lm_block.BlockSpec(**dict(
         b["spec"], **{f: m[k] for f, k in b["from_keys"].items()}))
-    slots = load("perf", "traffic", w["traffic"] + ".json")["slots"]
-    return spec, int(slots), int(m["hidden_size"])
+    return spec, int(t["slots"]), int(m["hidden_size"])
 
 
 def fixed_choice(spec, rows):
@@ -1116,6 +1145,128 @@ def run_route(name, variants=ROUTE_VARIANTS, calls=100, with_check=False,
     return res
 
 
+# ---------------------------------------------------------------------------
+# a lightning indexer's selection over a tick's index scores
+# ---------------------------------------------------------------------------
+
+def select_cell(cell):
+    """(lanes, rows of a lane's table, k, the lanes' cursors) of a
+    selecting cell, from its own files: lane c at document c mod 16 of
+    the traffic file's and a seeded point of pair c's question and
+    answer."""
+    spec, lanes, _ = route_cell(cell)
+    _, t = cell_files(cell)
+    docs = np.asarray(t["documents"]["lengths"])
+    pairs = np.asarray(t["lengths"]["table"]).sum(axis=1)
+    at = np.arange(lanes)
+    cursors = docs[at % len(docs)] + (
+        np.random.RandomState(0).rand(lanes)
+        * pairs[at % len(pairs)]).astype(np.int64)
+    rows = int(t["context"])
+    return lanes, rows, int(spec.index_topk), np.minimum(
+        cursors, rows - 1).astype(np.int32)
+
+
+def select_geometry(shape, rehearse=False):
+    if rehearse:
+        return 3, 256, 40, np.array([255, 17, 0], np.int32)
+    return select_cell(shape["cell"])
+
+
+def build_select(shape, sr, variant="whole", rehearse=False):
+    """-> (a jitted function that selects on `SELECT_LAYERS` layers'
+    scores in one call: the kernel's, or under `xla`
+    `lm_block.select_rows`'s lines; its arguments)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import lm_block
+
+    lanes, rows, k, cursors = select_geometry(shape, rehearse)
+    layers = 2 if rehearse else SELECT_LAYERS
+    scores = jax.random.normal(jax.random.PRNGKey(1), (layers, lanes, rows),
+                               jnp.float32)
+    if variant == "xla":
+        def select(s, cur):
+            return lm_block.select_rows(
+                s, jnp.arange(rows)[None, :] <= cur[:, None], k)
+    else:
+        kern, why = sr.select_index_selection(
+            rows=rows, lanes=lanes, k=k, platform="tpu", interpret=rehearse)
+        assert kern is not None, why
+        select = kern.select
+
+    def f(scores, cursors):
+        return jnp.stack([select(scores[i], cursors)
+                          for i in range(layers)])
+
+    return jax.jit(f), (scores, jnp.asarray(cursors))
+
+
+def check_select(shape, sr, rehearse=False):
+    """Rows of the kernel's masks that differ from `select_rows`' called
+    EAGERLY on the CPU backend (the definition: under `jax.jit` XLA
+    takes `x + 0.0` for x on every backend, and the jitted lines then
+    rank -0.0 UNDER +0.0 where the words, the eager call, the plain
+    references' `scores == kth` and the kernel tie them), then rows of
+    the chip's jitted `select_rows` that differ from it: each on the
+    shape's scores, and on the same rounded to quarters with signed
+    zeros and infinities among them (ties at the k-th score)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import lm_block
+
+    f, (scores, cursors) = build_select(shape, sr, "whole", rehearse)
+    lines, _ = build_select(shape, sr, "xla", rehearse)
+    _, rows, k, cur = select_geometry(shape, rehearse)
+    valid = np.arange(rows)[None, :] <= cur[:, None]
+    tied = np.round(np.asarray(scores) * 4) / 4
+    tied[:, :, ::97], tied[:, :, 5::89] = np.inf, -np.inf
+    out = []
+    for s in (np.asarray(scores), tied):
+        with jax.default_device(jax.devices("cpu")[0]):
+            want = np.stack([np.asarray(lm_block.select_rows(layer, valid, k))
+                             for layer in s])
+        out.append([int((np.asarray(g(jnp.asarray(s), cursors))
+                         != want).sum()) for g in (f, lines)])
+    return out
+
+
+def run_select(name, variants=SELECT_VARIANTS, calls=100, with_check=False,
+               rehearse=False):
+    """-> {"shape", "lanes", "rows", "k", "lanes_block", "<variant>": ms
+    a selection, "check"}."""
+    import jax
+
+    from paddle_tpu.kernels import select_rows as sr
+
+    shape = SHAPES[name]
+    lanes, rows, k, cursors = select_geometry(shape, rehearse)
+    layers = 2 if rehearse else SELECT_LAYERS
+    res = {"shape": name, "device": jax.devices()[0].device_kind,
+           "rehearsal": bool(rehearse), "lanes": lanes, "rows": rows,
+           "k": k, "valid_rows": int(cursors.sum() + lanes),
+           "lanes_block": sr._lanes_block(lanes, rows)}
+    passes = sr._PASSES
+    for variant in variants:
+        if rehearse and variant == "no_counts":
+            continue    # the interpreter walks the whole kernel only
+        jax.clear_caches()      # `_call` keeps the trace it made
+        sr._PASSES = 0 if variant == "no_counts" else passes
+        try:
+            f, args = build_select(shape, sr, variant, rehearse)
+            res[variant] = round(
+                timed(f, args, 1 if rehearse else calls) / layers, 5)
+        finally:
+            sr._PASSES = passes
+            jax.clear_caches()
+        print(f"{name} {variant}", res[variant], flush=True)
+    if with_check:
+        res["check"] = check_select(shape, sr, rehearse)
+    return res
+
+
 def toy(shape):
     """`shape` cut to what the interpreter walks in seconds."""
     return dict(shape, slots=3, heads=min(shape["heads"], 8),
@@ -1152,6 +1303,9 @@ def run(name, block_sizes=None, variants=None, pa=None, calls=30,
     if shape.get("kernel") == "route":
         return run_route(name, variants or ROUTE_VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse)
+    if shape.get("kernel") == "select":
+        return run_select(name, variants or SELECT_VARIANTS, calls=calls,
+                          with_check=with_check, rehearse=rehearse)
     if shape.get("kernel") == "index":
         return run_index(name, variants or VARIANTS, calls=calls,
                          with_check=with_check, rehearse=rehearse,
@@ -1208,7 +1362,8 @@ def main(argv=None):
     ap.add_argument("--variants", default="",
                     help="of the shape's kernel's (all): "
                     + ",".join(VARIANTS + FLASH_VARIANTS[1:]
-                               + DELTA_VARIANTS[2:] + ROUTE_VARIANTS[1:]))
+                               + DELTA_VARIANTS[2:] + ROUTE_VARIANTS[1:]
+                               + SELECT_VARIANTS[2:]))
     ap.add_argument("--tables", default="consecutive", choices=TABLES)
     ap.add_argument("--pages", default="",
                     help="a paged-attention shape at each of these static "
@@ -1217,7 +1372,8 @@ def main(argv=None):
                     help="every lane's cursor at so many rows (the "
                     "shape's mix of cursors)")
     ap.add_argument("--calls", type=int, default=0,
-                    help="calls a reading (30; a route shape's 100)")
+                    help="calls a reading (30; a route or select shape's "
+                    "100)")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--out", default="")
@@ -1230,7 +1386,8 @@ def main(argv=None):
                [int(b) for b in args.block_sizes.split(",") if b],
                tuple(v for v in args.variants.split(",") if v),
                calls=args.calls or (
-                   100 if SHAPES[name].get("kernel") == "route" else 30),
+                   100 if SHAPES[name].get("kernel") in ("route", "select")
+                   else 30),
                with_check=args.check,
                rehearse=args.rehearse, order=args.tables,
                heads_blocks=[int(b) for b in args.heads_blocks.split(",")
