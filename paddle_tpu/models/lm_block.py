@@ -242,6 +242,32 @@ architecture is a second description and not a second decoder.
                sixth's, the dense layer and the held experts the
                seventh's.
 
+  parallel-    the fourteenth (TII Falcon-H1, `model_type: falcon_h1`), fields
+  hybrid-like  again, and the first layer with TWO MIXERS: every layer is
+               the fourth description's Mamba-2 mixer AND a grouped-query
+               attention under RoPE, both reading the layer's ONE normed
+               input, their outputs summed into the stream
+               (`parallel_attention` on a block whose `layer_types` are
+               all "mamba"), so that every layer owns a lane's state and
+               tail AND a plane of the K/V table.  The mixer has GROUPS
+               of B and C (`ssm_groups`: head h reads group h // (heads /
+               groups); the gated RMSNorm runs over each group's columns
+               apart); its inner width is heads x head size (the
+               family's key of its own for it is held to that where the
+               configuration is loaded).  The family's
+               muP multipliers are fields and folded into no weight: a
+               VECTOR over the columns of the input projection's result
+               (`ssm_multipliers`: five factors, on z, x, B, C and dt,
+               so before the convolution), a factor on each mixer's
+               input and output (`ssm_in_multiplier`,
+               `ssm_out_multiplier`, `attention_in_multiplier`,
+               `attention_out_multiplier`), on the keys before RoPE
+               (`key_multiplier`), on the dense SwiGLU's gate input and
+               its output (`mlp_multipliers`), on the embedding (the
+               fourth's `embedding_multiplier`) and on the logits of an
+               untied head (`lm_head_multiplier`).  The FFN is the
+               fifth's dense SwiGLU.
+
 The fields are NOT free axes yet: those points of the space are the
 ones that are built and tested, and `param_layout` refuses any other
 combination by name rather than build something untried.
@@ -326,7 +352,10 @@ class BlockSpec:
     ring AND table hold latent rows (sliding layers with a latent
     geometry of their own and a window of any length, full layers under
     a lightning indexer, a gate a head on both, an FFN kind a layer, the
-    sigmoid router with a choice bias) (module docstring).  `layer_types`,
+    sigmoid router with a choice bias), and the dense SwiGLU block whose
+    every layer is a Mamba-2 mixer (groups of B and C) AND grouped-query
+    attention under RoPE on one normed input, under the muP multipliers
+    (module docstring).  `layer_types`,
     `mlp_layer_types`, `rope_layers` and `rope_parameters` may be given
     as the JSON list and dict a config.json holds: they are kept as
     (nested) tuples, so the description stays hashable."""
@@ -359,11 +388,32 @@ class BlockSpec:
     residual_multiplier: float = 1.0    # on each sub-block's output
     attention_multiplier: float = 0.0   # on q.k (0: 1 / sqrt(d_head))
     logits_scaling: float = 1.0         # the logits are DIVIDED by it
-    # -- a MAMBA layer's geometry (Mamba-2, one group of B and C)
+    # -- a MAMBA layer's geometry (Mamba-2)
     ssm_heads: int = 0              # heads H
     ssm_d_head: int = 0             # a head's size P (H * P columns)
     ssm_d_state: int = 0            # the state's size N
     ssm_conv: int = 0               # the causal convolution's width
+    # groups of B and C: head h reads group h // (H / groups), and the
+    # gated norm runs over each group's H * P / groups columns apart
+    ssm_groups: int = 1
+    # -- a PARALLEL layer: every MAMBA layer ALSO attends (grouped-query
+    #    heads under RoPE on the K/V table), mixer and attention reading
+    #    the layer's ONE normed input, their outputs summed; and the muP
+    #    multipliers of such a block, none folded into a weight: a
+    #    factor a SEGMENT of the input projection's columns (z, x, B, C,
+    #    dt; (): none), on each mixer's input and output, on the keys
+    #    before RoPE, on the dense SwiGLU's gate input and its output
+    #    ((): none) and on the logits (multiplied; `logits_scaling`
+    #    divides)
+    parallel_attention: bool = False
+    ssm_multipliers: tuple = ()
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: tuple = ()
+    lm_head_multiplier: float = 1.0
     # -- a LOOPED stack: the layers run `passes` times a token over the
     #    same weights, the final norm after every pass
     passes: int = 1
@@ -471,8 +521,16 @@ class BlockSpec:
     def __post_init__(self):
         for name in ("layer_types", "rope_parameters", "mlp_layer_types",
                      "rope_layers", "indexer_types", "expert_swiglu_limits",
-                     "shared_swiglu_limits"):
+                     "shared_swiglu_limits", "ssm_multipliers",
+                     "mlp_multipliers"):
             object.__setattr__(self, name, _frozen(getattr(self, name)))
+        if len(self.ssm_multipliers) not in (0, 5) or len(
+                self.mlp_multipliers) not in (0, 2):
+            raise ValueError(
+                f"block {self.name!r}: ssm_multipliers has a factor for "
+                "each of z, x, B, C and dt (five, or none) and "
+                "mlp_multipliers one for the gate input and one for the "
+                "output (two, or none)")
         if self.group_score not in ("max", "top2_sum") or (
                 self.group_score != "max" and self.n_group < 2):
             raise ValueError(
@@ -701,9 +759,10 @@ class BlockSpec:
 
     def rotated(self, kind: str) -> bool:
         """Whether RoPE turns Q and K on a layer of this kind (a layer
-        without attention has neither)."""
-        return self.positions == "rope" and kind not in (
-            MAMBA, CONV, DELTA) and (
+        without attention has neither; a MAMBA layer attends where the
+        block's layers are parallel)."""
+        return self.positions == "rope" and kind not in (CONV, DELTA) and (
+            kind != MAMBA or self.parallel_attention) and (
             not self.rope_layers or kind in self.rope_layers)
 
     def rope_of(self, kind: str) -> dict:
@@ -713,7 +772,10 @@ class BlockSpec:
         params = dict(self.rope_parameters)
         params = params if "rope_type" in params else params.get(kind)
         if params is None:
-            return {"rope_type": "default", "rope_theta": self.rope_theta}
+            # (a float: a config.json's integer theta past 32 bits, 1e11,
+            # is no jax scalar)
+            return {"rope_type": "default",
+                    "rope_theta": float(self.rope_theta)}
         return dict(params)
 
 
@@ -743,11 +805,14 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     `tied_head`: the step multiplies by its transpose).  Q and O are
     [d, H*dh] and [H*dh, d], K and V [d, Hkv*dh]: all [d, d] where the
     heads split the model's width.  A MAMBA layer has, in place of
-    those four, the mixer's: `ssm_in` [d, 2*H*P + 2*N + H] (z, then x B
-    C, then dt, side by side), the depthwise convolution over x B C
-    (`ssm_conv`: [width, H*P + 2*N] and its bias), `ssm_dt` (dt's
+    those four, the mixer's: `ssm_in` [d, 2*H*P + 2*G*N + H] (z, then x B
+    C, then dt, side by side; G = `ssm_groups` groups of B, then of C),
+    the depthwise convolution over x B C
+    (`ssm_conv`: [width, H*P + 2*G*N] and its bias), `ssm_dt` (dt's
     bias), `ssm_a_log`, `ssm_d` [H], the gated norm's scale [H*P] and
-    `ssm_out` [H*P, d]; no bias on a projection.  A CONV layer has three:
+    `ssm_out` [H*P, d]; no bias on a projection.  Under
+    `parallel_attention` it has the four BESIDE those (`q`, `k`, `v`,
+    `o` at the grouped geometry), on the mixer's one norm.  A CONV layer has three:
     `conv_in` [d, 3 * d] (the gates B, C and u, side by side in that
     order), the depthwise convolution's taps `conv_w` [conv_width, d]
     (row j multiplies the row `conv_width - 1 - j` positions back: the
@@ -804,12 +869,52 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             "its description; OPT's names come from the training "
             "Program")
     kinds = [spec.kind_of(l) for l in range(n_layers)]
-    if dense and (set(kinds) != {FULL} or spec.n_experts or spec.qk_norm
-                  or spec.shared_d_inner or spec.tied_head
-                  or spec.n_kv_heads not in (0, n_heads)):
+    parallel = spec.parallel_attention
+    if parallel and (
+            not dense or set(kinds) != {MAMBA} or spec.latent
+            or spec.n_experts or spec.shared_d_inner or spec.qk_norm
+            or spec.tied_head
+            or spec.mlp_layer_types or spec.attention_gate
+            or spec.positions != "rope" or spec.rope_layers
+            or spec.passes > 1 or spec.post_norm or spec.exit_gate
+            or spec.residual_multiplier != 1.0
+            or spec.attention_multiplier or spec.logits_scaling != 1.0
+            or spec.ssm_groups < 1 or spec.ssm_heads % spec.ssm_groups):
+        raise NotImplementedError(
+            f"block {spec.name!r}: a parallel layer (parallel_attention) "
+            "is built with EVERY layer a Mamba-2 mixer (layer_types all "
+            "'mamba'; ssm_groups dividing ssm_heads) beside grouped-query "
+            "attention under RoPE on the K/V table, a dense SwiGLU FFN "
+            "(ffn 'swiglu') and an untied head, under its own "
+            "multipliers; STILL refused: Mamba mixers beside a ring, a "
+            "latent table or experts (a shared expert, a QK-norm, a tied "
+            "head) in a parallel layer, positions "
+            "other than RoPE there (rope_layers too), an attention gate, "
+            "a looped stack, layers of another kind among them, and "
+            "Granite's residual_multiplier, attention_multiplier and "
+            "logits_scaling beside the muP set")
+    if not parallel and (
+            spec.ssm_groups != 1 or spec.ssm_multipliers
+            or spec.mlp_multipliers or (
+                spec.ssm_in_multiplier, spec.ssm_out_multiplier,
+                spec.attention_in_multiplier, spec.attention_out_multiplier,
+                spec.key_multiplier, spec.lm_head_multiplier) != (1.0,) * 6):
+        raise NotImplementedError(
+            f"block {spec.name!r}: groups of B and C (ssm_groups) "
+            "and the muP multipliers (ssm_multipliers, "
+            "ssm_in_multiplier, ssm_out_multiplier, "
+            "attention_in_multiplier, attention_out_multiplier, "
+            "key_multiplier, mlp_multipliers, lm_head_multiplier) are "
+            "built and tested on a parallel layer (parallel_attention) "
+            "alone")
+    if dense and not parallel and (
+            set(kinds) != {FULL} or spec.n_experts or spec.qk_norm
+            or spec.shared_d_inner or spec.tied_head
+            or spec.n_kv_heads not in (0, n_heads)):
         raise NotImplementedError(
             f"block {spec.name!r}: a dense SwiGLU FFN is built on plain "
-            "multi-head full attention under RoPE with an untied head: "
+            "multi-head full attention under RoPE with an untied head "
+            "(or on a parallel layer: parallel_attention): "
             "no experts, shared expert, QK-norm, grouped K/V heads or "
             "other layer kinds beside it")
     if not dense and (spec.passes > 1 or spec.post_norm
@@ -854,17 +959,21 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
             ">= 2) are built among full-attention layers on the table, "
             "in a block with experts: no Mamba layers, ring, latent "
             "cache or dense SwiGLU block beside them")
-    # a latent layer's one shared key part IS a rotated one
-    unsigned = mamba or (delta and not spec.latent)
+    # a latent layer's one shared key part IS a rotated one; a parallel
+    # layer's attention is turned beside the Mamba mixer of the same layer
+    unsigned = (mamba and not parallel) or (delta and not spec.latent)
     if spec.positions != ("none" if unsigned else "rope"):
         raise NotImplementedError(
             f"block {spec.name!r}: positions {spec.positions!r} "
             f"{'with' if unsigned else 'without'} Mamba layers, or "
             "delta-rule layers beside a K/V table; built are RoPE on a "
-            "block of attention layers and on the LATENT layers beside "
-            "delta-rule layers (which carry none themselves), and no "
-            "position signal where Mamba layers, or delta-rule layers "
-            "beside attention on a K/V table, carry the order")
+            "block of attention layers, on the LATENT layers beside "
+            "delta-rule layers (which carry none themselves) and on the "
+            "attention of a PARALLEL layer (parallel_attention: beside "
+            "the Mamba mixer of the same layer), and no "
+            "position signal where Mamba layers of their own, or "
+            "delta-rule layers beside attention on a K/V table, carry "
+            "the order")
     if mamba and SLIDING in kinds:
         raise NotImplementedError(
             f"block {spec.name!r}: Mamba layers beside sliding-window "
@@ -953,7 +1062,7 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
         raise ValueError(
             f"block {spec.name!r}: indexer_types 'none' is a sliding "
             "layer's, and the block has none")
-    for kind in (set(kinds) - {MAMBA}) if spec.positions == "rope" else ():
+    for kind in set(kinds) if spec.positions == "rope" else ():
         if not spec.rotated(kind):
             continue
         if spec.rope_of(kind)["rope_type"] not in ("default", "yarn"):
@@ -964,7 +1073,7 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
     held, fs = spec.held[1], spec.shared_d_inner
     dq, dkv = n_heads * d_head, n_kv * d_head
     di = spec.ssm_heads * spec.ssm_d_head
-    conv = di + 2 * spec.ssm_d_state
+    conv = di + 2 * spec.ssm_groups * spec.ssm_d_state
     shapes: Dict[str, Tuple[int, ...]] = {}
 
     def add(name, *shape):
@@ -1034,6 +1143,12 @@ def param_layout(spec: BlockSpec, vocab_size: int, d_model: int,
                    "ssm_d": add(p + "ssm_d.w_0", spec.ssm_heads),
                    "ssm_gate_norm": add(p + "ssm_gate_norm.scale_0", di),
                    "ssm_out": add(p + "ssm_out_proj.w_0", di, d)}
+            if parallel:
+                # the attention of the same layer, on the mixer's norm
+                lay.update({"q": add(p + "q_proj.w_0", d, dq),
+                            "k": add(p + "k_proj.w_0", d, dkv),
+                            "v": add(p + "v_proj.w_0", d, dkv),
+                            "o": add(p + "o_proj.w_0", dq, d)})
         elif kind == DELTA:
             hk = spec.delta_heads * spec.delta_d_head
             r = spec.delta_gate_rank
@@ -1501,12 +1616,14 @@ def clamped(gate, up, limit: float):
     return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
 
 
-def swiglu(x, w_gate, w_up, w_down, limit=0.0):
+def swiglu(x, w_gate, w_up, w_down, limit=0.0, multipliers=()):
     """One SwiGLU FFN every row takes (a shared expert): x [T, D]
     float32 -> [T, D] float32, the matmuls in the weights' dtype with
     float32 accumulation and the gated product rounded to it, as an
     expert of `moe_ffn` rounds.  `limit` L > 0: the two inputs
-    `clamped`."""
+    `clamped`.  `multipliers` (m_gate, m_down): the gate input times
+    m_gate before the SiLU and the result times m_down, in float32
+    (`BlockSpec.mlp_multipliers`; (): neither)."""
     import jax
     import jax.numpy as jnp
 
@@ -1516,8 +1633,11 @@ def swiglu(x, w_gate, w_up, w_down, limit=0.0):
     up = jnp.dot(rows, w_up, preferred_element_type=f32)
     if limit:
         gate, up = clamped(gate, up, limit)
+    if multipliers:
+        gate = gate * float(multipliers[0])
     act = (jax.nn.silu(gate) * up).astype(w_down.dtype)
-    return jnp.dot(act, w_down, preferred_element_type=f32)
+    out = jnp.dot(act, w_down, preferred_element_type=f32)
+    return out * float(multipliers[1]) if multipliers else out
 
 
 def _tail_rows(tail, row, fresh, live):
@@ -1541,19 +1661,30 @@ def _tail_rows(tail, row, fresh, live):
 def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
                 scope=None):
     """ONE position of a Mamba-2 mixer (Dao & Gu, arXiv:2405.21060;
-    one group of B and C) for every lane: u [S, D] float32 (the normed
+    G = `ssm_groups` groups of B and C: one, Granite's, or more) for
+    every lane: u [S, D] float32 (the normed
     residual) -> (out [S, D] float32, the lane's SSM state [S, H, P, N]
-    float32, its convolution tail [S, width - 1, H*P + 2N] float32:
+    float32, its convolution tail [S, width - 1, H*P + 2GN] float32:
     the last rows of x B C before the convolution, and what the
     recurrence was given: x B C after the convolution and dt after
-    the softplus side by side, [S, H*P + 2N + H] float32, for a
+    the softplus side by side, [S, H*P + 2GN + H] float32, for a
     comparison that judges the recurrence on its own inputs).
 
-      z, xBC, dt = u @ W_in            (H*P, H*P + 2N and H columns)
+      z, xBC, dt = (u @ W_in) * m      (H*P, H*P + 2GN and H columns; m
+                                        `ssm_multipliers` a segment)
       xBC = silu(sum_j w_conv[j] * (tail, xBC)[j] + b_conv)
-      x [H, P], B [N], C [N] = xBC;  dt = softplus(dt + dt_bias) [H]
-      h = exp(dt * -exp(A_log)) * h + dt * (x outer B)     [H, P, N]
-      y = h . C + D * x;  out = (rmsnorm(y * silu(z)) * w) @ W_out
+      x [H, P], B [G, N], C [G, N] = xBC;  dt = softplus(dt + dt_bias)
+      h = exp(dt * -exp(A_log)) * h + dt * (x outer B_g)   [H, P, N]
+      y = h . C_g + D * x        (head h reads group g = h // (H / G))
+      out = (rmsnorm(y * silu(z)) * w) @ W_out
+
+    With one group the norm runs over all H*P columns and the step
+    lowers to what it lowered to before groups were built; with more it
+    runs over each group's H*P / G columns apart (the gate first, then
+    the norm: `mamba_norm_before_gate` false), times the one scale
+    [H*P].  `ssm_multipliers` (z, x, B, C, dt; (): none) multiply the
+    projection's RESULT in float32, so x, B and C carry theirs into the
+    convolution.
 
     The recurrence IS the prefill: the scheduler feeds a prompt one
     position a tick like any other, so there is no scan over a chunk.
@@ -1573,11 +1704,18 @@ def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
     f32 = jnp.float32
     s_n = u.shape[0]
     h_n, p_n, n_n = spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state
+    g_n = spec.ssm_groups
     di = h_n * p_n
     conv_w, conv_b = p["ssm_conv"]
     with scope("ssm_in_proj"):
         zxd = jnp.dot(u.astype(p["ssm_in"].dtype), p["ssm_in"],
                       preferred_element_type=f32)
+        if spec.ssm_multipliers:
+            import numpy as np
+
+            zxd = zxd * np.repeat(
+                np.asarray(spec.ssm_multipliers, np.float32),
+                (di, di, g_n * n_n, g_n * n_n, h_n))
         z, xbc, dt = (zxd[:, :di], zxd[:, di:-h_n], zxd[:, -h_n:])
     with scope("ssm_conv"):
         rows, tail = _tail_rows(tail, xbc, fresh, live)
@@ -1585,19 +1723,34 @@ def mamba2_step(spec: BlockSpec, u, state, tail, fresh, live, p,
                           + conv_b.astype(f32))
     with scope("ssm_scan"):
         x = xbc[:, :di].reshape(s_n, h_n, p_n)
-        b, c = xbc[:, di:di + n_n], xbc[:, di + n_n:]
+        if g_n == 1:
+            # one B and one C for every head
+            b, c = xbc[:, di:di + n_n], xbc[:, di + n_n:]
+        else:
+            # a group's B and C under each of its H / G heads
+            b, c = (jnp.repeat(t.reshape(s_n, g_n, n_n), h_n // g_n, axis=1)
+                    for t in (xbc[:, di:di + g_n * n_n],
+                              xbc[:, di + g_n * n_n:]))
         dt = jax.nn.softplus(dt + p["ssm_dt"].astype(f32))      # [S, H]
         given = jnp.concatenate([xbc, dt], axis=-1)
         decay = jnp.exp(-dt * jnp.exp(p["ssm_a_log"].astype(f32)))
         h0 = jnp.where(fresh[:, None, None, None], 0.0, state)
+        # [S, 1, 1, N] for all heads, or [S, H, 1, N]: a head's group's
+        by_head = ((lambda t: t[:, None, None, :]) if g_n == 1
+                   else (lambda t: t[:, :, None, :]))
         h = (decay[:, :, None, None] * h0
-             + (dt[:, :, None] * x)[..., None] * b[:, None, None, :])
-        y = ((h * c[:, None, None, :]).sum(axis=-1)
+             + (dt[:, :, None] * x)[..., None] * by_head(b))
+        y = ((h * by_head(c)).sum(axis=-1)
              + p["ssm_d"].astype(f32)[None, :, None] * x)
         state = jnp.where(live[:, None, None, None], h, state)
     with scope("ssm_gate_norm"):
         y = y.reshape(s_n, di) * jax.nn.silu(z)
-        y = norm(spec, y, p["ssm_gate_norm"].astype(f32))
+        if g_n == 1:
+            y = norm(spec, y, p["ssm_gate_norm"].astype(f32))
+        else:
+            # each group's columns apart, then the one scale
+            y = norm(spec, y.reshape(s_n, g_n, di // g_n), 1.0).reshape(
+                s_n, di) * p["ssm_gate_norm"].astype(f32)
     with scope("ssm_out_proj"):
         out = jnp.dot(y.astype(p["ssm_out"].dtype), p["ssm_out"],
                       preferred_element_type=f32)

@@ -559,7 +559,9 @@ class PagedDecoder:
     step_window: Callable
     step_logits: Callable
     # `step_logits` and what every layer's router (every pass of a
-    # looped stack) computed; None for a block with neither
+    # looped stack) computed, or, for a block of parallel layers, what
+    # each layer's recurrence was given and left; None for a block with
+    # none of them
     step_routing: Optional[Callable]
     # init_pool(num_blocks, device, window_blocks=, lanes=) -> the pools;
     # slot_rings(slots) -> int32 [slots, window_blocks_per_seq]
@@ -613,10 +615,11 @@ class PagedDecoder:
     index_planes: int
     # what belongs to a LANE: how many layers keep a recurrent state or
     # a convolution tail (Mamba and delta-rule layers: both; gated short
-    # convolutions: the tail alone; 0: none) and the float32 bytes a lane
-    # holds over them; for a block with sliding layers the bytes of a
-    # lane's RING over them, in the pool's dtype (what a snapshot of it
-    # holds)
+    # convolutions: the tail alone; 0: none; EVERY layer of a block of
+    # parallel layers, which `table_layers` counts too) and the float32
+    # bytes a lane holds over them; for a block with sliding layers the
+    # bytes of a lane's RING over them, in the pool's dtype (what a
+    # snapshot of it holds)
     state_layers: int
     state_bytes_per_lane: int
     # SNAPSHOTS of what belongs to a lane, for a prefix cache over such a
@@ -955,6 +958,28 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     window_blocks_per_seq whatever the lane, so a snapshot taken in one
     lane is right in another).
 
+    A PARALLEL layer (`BlockSpec.parallel_attention`, lm_block's
+    fourteenth description; docs/serving.md "A layer with two caches"):
+    every layer is a Mamba-2 mixer AND grouped-query attention under
+    RoPE on ONE normed input, so layer l owns plane l of the lanes'
+    states, plane l of their tails AND plane l of the K and V table.
+    The pools are the Mamba block's pair with a table of `n_layers`
+    planes: `pool_k` (the K pool [layers, blocks, block_size, Hkv * dh],
+    a float32 state [S, H, P, N] a layer), `pool_v` (the V pool, a
+    float32 tail [S, width - 1, H*P + 2GN] a layer); `bytes_per_block`
+    and `state_bytes_per_lane` both count EVERY layer, a snapshot holds
+    every layer's state and tail while a prefix-cache block holds every
+    layer's K and V rows, and `tick_counts` gives `state_lanes`,
+    `kv_rows_full` (the table's rows under the cursors) and the table's
+    pages on one tick, `step_bytes_cache` counting both caches.  The
+    layer's scopes are the Mamba mixer's five (`ssm_*`) and the
+    attention's (`qkv`, `rope`, `kv_write`, `attention`, `attn_out`)
+    under one layer; its dense SwiGLU lies under `dense_ffn`.  A draft
+    model, `step_window` and an int8 pool are refused by name.
+    `step_routing` returns the logits, "ssm_inputs" and "ssm_states"
+    (nothing is routed; what each layer's recurrence was given and what
+    it left, from one program).
+
     `decoder.step_logits(...)` takes `step`'s arguments and returns the
     [S, vocab] float32 logits `step` samples from, without donating or
     updating the pools — the numerics gate between the Pallas and XLA
@@ -1070,7 +1095,17 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             "or delta-rule states is not built (its per-(layer, block) "
             "scales are untested on a table that a minority of the "
             "layers write); kv_dtype fp32 or bf16")
-    n_full = kinds.count(lm_block.FULL)
+    # a PARALLEL layer: every Mamba layer also attends, so the table has
+    # a plane for each of them (a layer's index among the lanes' states
+    # is its plane of the table: every layer is of the one kind)
+    parallel = spec.parallel_attention
+    if parallel and kv_dtype == "int8":
+        raise NotImplementedError(
+            f"block {spec.name!r}: an int8 pool beside a lane's Mamba "
+            "state in a parallel layer is not built (its per-(layer, "
+            "block) scales are untested beside a float32 state that a "
+            "snapshot restores); kv_dtype fp32 or bf16")
+    n_full = kinds.count(lm_block.FULL) + (n_layers if parallel else 0)
     nw = 0
     if ringed:
         if spec.window % bs and not latent:
@@ -1290,10 +1325,14 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                            if spec.rotated(kind) else None)
                     for kind in dict.fromkeys(kinds)}
 
-    def _qkv(g, lay, x, rot):
+    def _qkv(g, lay, x, rot, normed=None):
+        """`normed`: the layer's normed input where its caller has it (a
+        parallel layer's, which the Mamba mixer reads too)."""
         with scope("qkv"):
-            h = _norm(g, x, lay["norm1"])
+            h = _norm(g, x, lay["norm1"]) if normed is None else normed
             q, kk, vv = (_fc(g, h, lay[n]) for n in ("q", "k", "v"))
+            if spec.key_multiplier != 1.0:
+                kk = kk * spec.key_multiplier
         if spec.qk_norm:
             with scope("qk_norm"):
                 if spec.qk_norm_per_head:
@@ -1471,6 +1510,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                      if spec.expert_swiglu_limits
                      or spec.shared_swiglu_limits}
 
+    # two names for a dense FFN, and neither can move: `mlp` is what
+    # OPT's and the looped stack's breakdowns and readers know theirs
+    # by; `dense_ffn`, a dense SwiGLU in a layer that has other parts a
+    # tick's share goes to (experts in the other layers, or here two
+    # mixers), is what `serve_dense_ffn_share` reads (PERF.md section 3)
+    ffn_scope = "dense_ffn" if parallel else "mlp"
+    mlp_multipliers = ({"multipliers": spec.mlp_multipliers}
+                       if spec.mlp_multipliers else {})
+
     def _ffn(g, lay, x, hits):
         """x + FFN(norm(x)); a block with experts appends (its count
         of distinct experts hit, the router's input, the weights and
@@ -1483,14 +1531,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                 y = lm_block.swiglu(h2.reshape(-1, d_model), *(
                     g[lay[n][0]] for n in ("gate", "up", "down")))
                 return _residual(x, y.reshape(x.shape))
-        with scope("mlp"):
+        with scope(ffn_scope):
             h2 = _norm(g, x, lay["norm2"])
             if spec.ffn == "relu":
                 return x + _fc(g, jax.nn.relu(_fc(g, h2, lay["w1"])),
                                lay["w2"])
             if spec.ffn == "swiglu":
                 y = lm_block.swiglu(h2.reshape(-1, d_model), *(
-                    g[lay[n][0]] for n in ("gate", "up", "down")))
+                    g[lay[n][0]] for n in ("gate", "up", "down")),
+                    **mlp_multipliers)
                 if not spec.post_norm:
                     return _residual(x, y.reshape(x.shape))
         if spec.ffn == "swiglu":
@@ -1573,7 +1622,19 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                       else _fc(g, h, layout.head))
             if spec.logits_scaling != 1.0:
                 logits = logits / spec.logits_scaling
+            if spec.lm_head_multiplier != 1.0:
+                logits = logits * spec.lm_head_multiplier
             return logits
+
+    def _ssm(g, lay, u, state, tail, fresh, live):
+        """`mamba2_step` of the mixer's input u with the layer's arrays."""
+        return lm_block.mamba2_step(
+            spec, u, state, tail, fresh, live,
+            {n: (tuple(g[w] for w in lay[n]) if n == "ssm_conv"
+                 else g[lay[n][0]])
+             for n in ("ssm_in", "ssm_conv", "ssm_dt", "ssm_a_log",
+                       "ssm_d", "ssm_gate_norm", "ssm_out")},
+            scope=scope)
 
     def _mixer(g, lay, x, state, tail, fresh, live):
         """x + the Mamba-2 mixer of norm(x), one position a lane, the
@@ -1581,15 +1642,20 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         given (`mamba2_step`)."""
         with scope("ssm_in_proj"):
             u = _norm(g, x, lay["norm1"])
-        out, state, tail, given = lm_block.mamba2_step(
-            spec, u, state, tail, fresh, live,
-            {n: (tuple(g[w] for w in lay[n]) if n == "ssm_conv"
-                 else g[lay[n][0]])
-             for n in ("ssm_in", "ssm_conv", "ssm_dt", "ssm_a_log",
-                       "ssm_d", "ssm_gate_norm", "ssm_out")},
-            scope=scope)
+        out, state, tail, given = _ssm(g, lay, u, state, tail, fresh, live)
         with scope("ssm_out_proj"):
             return _residual(x, out), state, tail, given
+
+    def _parallel_mixer(g, lay, x, state, tail, fresh, live):
+        """The Mamba half of a PARALLEL layer: -> (u = norm(x), the ONE
+        normed input both mixers read; the mixer's output of
+        `ssm_in_multiplier` * u, NOT yet in the stream; the layer's
+        state and tail after it; what its recurrence was given)."""
+        with scope("ssm_in_proj"):
+            u = _norm(g, x, lay["norm1"])
+            v = (u if spec.ssm_in_multiplier == 1.0
+                 else u * spec.ssm_in_multiplier)
+        return (u,) + _ssm(g, lay, v, state, tail, fresh, live)
 
     def _conv_mixer(g, lay, x, tail, fresh, live):
         """x + the gated short convolution of norm(x), one position a
@@ -1917,6 +1983,53 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         in_window = (_ring_window(positions, active) if ring_masked
                      else None)
 
+        def write_and_attend(kind, plane, q, kk, vv, only, pools_k,
+                             pools_v):
+            """This position's K and V rows into plane `plane` of the
+            pool of `kind` (in `pools_k`, `pools_v`, updated), and the
+            attention of q over what the cursor shows there, of which
+            `only` (a selection or a window; None: all).  -> [S, H*Dv]"""
+            wb, seen = cursor[kind]
+            if attend_of[kind] is not None:
+                ctx_av, pools_k[kind], pools_v[kind] = _streamed(
+                    q, kk, vv, pools_k[kind], pools_v[kind], plane,
+                    tabs[kind], seen, kind, select=only)
+                return ctx_av
+            with scope("kv_write"):
+                pools_k[kind] = _write(pools_k[kind], plane, wb, wi, kk)
+                if not latent:
+                    pools_v[kind] = _write(pools_v[kind], plane, wb, wi,
+                                           vv)
+            if only is not None:
+                seen = seen & only
+            return _attention(
+                q[:, None, :], pools_k[kind], pools_v[kind], plane,
+                tabs[kind], seen[:, None, :], kind,
+                selected=only is not None
+                and kind != lm_block.SLIDING)[:, 0]
+
+        def parallel_layer(lay, li, x, pools_k, pools_v):
+            """A PARALLEL layer's two mixers on its ONE normed input:
+            the Mamba half on plane `li` of the lanes' states and tails
+            (they ride as a Mamba layer's), the attention half on plane
+            `li` of the table, and the two outputs into the stream, each
+            under its factor.  -> x before the layer's FFN."""
+            lanes, table = lm_block.MAMBA, lm_block.FULL
+            u, mixed, pools_k[lanes][li], pools_v[lanes][li], given = (
+                _parallel_mixer(g, lay, x, pools_k[lanes][li],
+                                pools_v[lanes][li], positions == 0, active))
+            scans.append(given)
+            q, kk, vv = _qkv(
+                g, lay, x, rot[lanes],
+                normed=(u if spec.attention_in_multiplier == 1.0
+                        else u * spec.attention_in_multiplier))
+            ctx_av = write_and_attend(table, li, q, kk, vv, None, pools_k,
+                                      pools_v)
+            with scope("attn_out"):
+                return (x + spec.ssm_out_multiplier * mixed
+                        + spec.attention_out_multiplier
+                        * _fc(g, ctx_av, lay["o"]))
+
         def stack(x, pools_k, pools_v, plane0=None):
             """The layers once over x, each writing this position's K/V
             and attending; `plane0`: the first plane of this pass of a
@@ -1930,6 +2043,10 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                                     pool_index)):
                 sub_traced = li % subs if subs > 1 else None
                 kind_traced = kind if ringed and latent else None
+                if kind == lm_block.MAMBA and parallel:
+                    x = _ffn(g, lay, parallel_layer(
+                        lay, li, x, pools_k, pools_v), hits)
+                    continue
                 if kind == lm_block.MAMBA:
                     # the lane's state rides where a pool's K does, its
                     # convolution tail where the V does
@@ -1960,7 +2077,6 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                                             geo[kind])
                 else:
                     q, kk, vv = _qkv(g, lay, x, rot[kind])
-                wb, seen = cursor[kind]
                 plane = li if plane0 is None else plane0 + li
                 if "idx_q" in lay:
                     # a selecting layer: this position's index key into
@@ -1968,31 +2084,15 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     # layers up to the next selecting one attend over
                     chosen, scores, pools_v[kind] = _indexer(
                         g, lay, h, c_q, rot[kind], pools_v[kind],
-                        index_plane[l], tabs[kind], wb, wi, positions,
-                        active)
+                        index_plane[l], tabs[kind], cursor[kind][0], wi,
+                        positions, active)
                     picks.append((h, c_q, scores, chosen))
                 # the rows attention is over, of those the cursor shows:
                 # a selection on the table, the window on a ring longer
                 # than it (a sliding layer reads no selection)
                 only = in_window if kind == lm_block.SLIDING else chosen
-                if attend_of[kind] is not None:
-                    ctx_av, pools_k[kind], pools_v[kind] = _streamed(
-                        q, kk, vv, pools_k[kind], pools_v[kind], plane,
-                        tabs[kind], seen, kind, select=only)
-                else:
-                    with scope("kv_write"):
-                        pools_k[kind] = _write(pools_k[kind], plane, wb,
-                                               wi, kk)
-                        if not latent:
-                            pools_v[kind] = _write(pools_v[kind], plane,
-                                                   wb, wi, vv)
-                    if only is not None:
-                        seen = seen & only
-                    ctx_av = _attention(
-                        q[:, None, :], pools_k[kind], pools_v[kind],
-                        plane, tabs[kind], seen[:, None, :], kind,
-                        selected=only is not None
-                        and kind != lm_block.SLIDING)[:, 0]
+                ctx_av = write_and_attend(kind, plane, q, kk, vv, only,
+                                          pools_k, pools_v)
                 if latent:
                     ctx_av = _latent_values(g, lay, ctx_av, geo[kind])
                 if "attn_head_gate" in lay:
@@ -2088,20 +2188,29 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         experts (`decoder.moe_layers`), and for a block with
         Mamba layers "ssm_inputs": float32 [Mamba layers, S, H*P + 2N +
         H], what each layer's recurrence was given at this position
-        (`lm_block.mamba2_step`).  Its twin for a LOOPED stack, which
-        routes nothing: {"passes": float32 [passes, S, D], x_t of every
-        pass (through the final norm), "gates": float32 [passes, S],
-        the exit gate lambda_t}, so that a pass that read another
+        (`lm_block.mamba2_step`); a block of PARALLEL layers gives
+        beside them "ssm_states": float32 [layers, S, H, P, N], what
+        each layer's recurrence LEFT (the planes `step` writes into the
+        lanes' pool), so that ONE program holds a recurrence's inputs
+        and its result: `step`, compiled apart, rounds the matmuls
+        before a deeper layer its own way, and a state it advanced is
+        not to the bit the recurrence of these inputs.  Its twin for a
+        LOOPED stack, which routes nothing: {"passes": float32 [passes,
+        S, D], x_t of every pass (through the final norm), "gates":
+        float32 [passes, S], the exit gate lambda_t}, so that a pass
+        that read another
         pass's plane shows at the pass where it happened."""
         with scope("paged_decoder"):
-            logits, _, _, hits, scans = _step_logits(
+            logits, new_k, _, hits, scans = _step_logits(
                 g, pool_k, pool_v, tables, positions, tokens, active)
             if looped:
                 return logits, scans
-            inputs, weights, experts = (
-                jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
-            out = {"inputs": inputs, "weights": weights,
-                   "experts": experts}
+            out = {}
+            if hits:
+                inputs, weights, experts = (
+                    jnp.stack([h[i] for h in hits]) for i in (1, 2, 3))
+                out = {"inputs": inputs, "weights": weights,
+                       "experts": experts}
             if sparse:
                 # what each selecting layer was given and chose
                 for i, name in enumerate(("index_inputs", "index_latents",
@@ -2109,6 +2218,8 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
                     out[name] = jnp.stack([p[i] for p in scans])
             elif scans:
                 out["ssm_inputs"] = jnp.stack(scans)
+            if parallel:
+                out["ssm_states"] = jnp.stack(new_k[1])
             return logits, out
 
     @functools.partial(jax.jit, donate_argnums=donate)
@@ -2242,7 +2353,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         state_shape = (spec.ssm_heads, spec.ssm_d_head, spec.ssm_d_state)
         tail_shape = ((spec.conv_width - 1, d_model) if n_conv else
                       (spec.ssm_conv - 1, spec.ssm_heads * spec.ssm_d_head
-                       + 2 * spec.ssm_d_state))
+                       + 2 * spec.ssm_groups * spec.ssm_d_state))
     state_bytes_per_lane = 4 * (n_state * math.prod(state_shape)
                                 + n_lane * math.prod(tail_shape))
     if ringed:
@@ -2431,7 +2542,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
              "attn_gate": "attention_gate",
              "attn_head_gate": "attention_head_gate"}
     if spec.ffn == "swiglu":
-        parts.update(gate="mlp", up="mlp", down="mlp")
+        parts.update(gate=ffn_scope, up=ffn_scope, down=ffn_scope)
+    if parallel:
+        parts.update(ssm_in="ssm_in_proj", ssm_out="ssm_out_proj")
     weights_of = [(lay[key], part) for lay in layout.layers
                   for key, part in parts.items() if key in lay]
     if spec.ffn == "moe_swiglu":
@@ -2496,7 +2609,9 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
         With sliding layers `past_window` (cursors at or
         past the window: their rings have wrapped) and the rows a layer
         of each kind attends over, `kv_rows_full` (cursor + 1) and
-        `kv_rows_win` (the window at most).  With Mamba or conv layers
+        `kv_rows_win` (the window at most; a PARALLEL block gives the
+        first for its table, which every layer reads, and 0).  With
+        Mamba or conv layers
         `state_lanes` (lanes with a recurrent state or a convolution
         tail: all the tick's) and `state_resets` (those at position 0,
         which the step starts from zero); with conv layers also
@@ -2578,6 +2693,11 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
             counts["past_window"] = int((rows > window).sum())
             counts["kv_rows_full"] = int(rows.sum())
             counts["kv_rows_win"] = int(np.minimum(rows, window).sum())
+        if parallel:
+            # the table's rows under the cursors, which EVERY layer reads
+            # beside its lanes' states (no layer has a window)
+            counts["kv_rows_full"] = int(rows.sum())
+            counts["kv_rows_win"] = 0
         if window and latent:
             # the ring rows the lanes' sliding layers read (a ring holds
             # nw * bs rows at most), at the ring's stored row
@@ -2677,7 +2797,7 @@ def build_lm_paged_decoder(vocab_size, block_size, max_blocks_per_seq,
     decoder = PagedDecoder(
         step=step, step_window=step_window, step_logits=step_logits,
         step_routing=(step_routing if spec.ffn == "moe_swiglu" or looped
-                      else None),
+                      or parallel else None),
         init_pool=init_pool, slot_rings=slot_rings, platform=platform,
         step_counters=(("moe_experts_hit",)
                        + (("moe_rows_held",) if shares else ())

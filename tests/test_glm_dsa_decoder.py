@@ -1039,11 +1039,14 @@ def test_the_cost_functions_and_the_readers_on_a_synthetic_run(monkeypatch):
             # the prefix cache's reader is also the cell's that PR 59
             # added (a lane state under snapshots)
             # (and the cell PR 65 added: latent rings beside a selected
-            # table, whose full layers have the indexer)
+            # table, whose full layers have the indexer; and, for the
+            # prefix cache's reader, the cell PR 69 added: a state AND a
+            # table plane a layer)
+            hits = spec["name"] == "sched_prefix_hit_share"
             assert spec["workloads"] == ["glm-5.2-serve-docqa64"] + (
-                ["solar-open2-250b-serve-docqa64"]
-                if spec["name"] == "sched_prefix_hit_share" else []) + [
-                    "dots3-note-prev-serve-docqa64"]
+                ["solar-open2-250b-serve-docqa64"] if hits else []) + [
+                    "dots3-note-prev-serve-docqa64"] + (
+                ["falcon-h1-34b-serve-docqa64"] if hits else [])
 
 
 def test_the_index_dma_ops_reader_on_a_synthetic_run(monkeypatch):

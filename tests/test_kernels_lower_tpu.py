@@ -97,6 +97,10 @@ POOLS = {
     # 33 pages a lane, the last 513 rows of it under the window's mask
     "dots3-docqa64-table": (64, 128, 640, 16, 432, 2, "bf16"),
     "dots3-docqa64-ring": (64, 64, 1152, 16, 33, 3, "bf16"),
+    # a K and a V pool of 4 heads of 128 (a row of 512) under 20 query
+    # heads, FIVE a K/V head, on the 5 planes of a block whose every
+    # layer attends beside its Mamba mixer (PR 69)
+    "falcon-h1-docqa64": (64, 20, 512, 16, 432, 5, "bf16"),
 }
 D_HEAD = {"opt-1.3b-closed32": 64}
 D_VALUE = {"deepseek-v2-agent64-latent": 512,
@@ -118,6 +122,7 @@ CHUNK_PAGES = {
     "longcat-flash-agent64-latent": 64, "glm-5.2-docqa64-selected": 64,
     "solar-open2-docqa64": 40, "ling-3.0-flash-agent128-latent": 64,
     "dots3-docqa64-table": 64, "dots3-docqa64-ring": 33,
+    "falcon-h1-docqa64": 80,
 }
 
 
@@ -333,6 +338,9 @@ MOSAIC_SHA256 = {
         "6e4fd2eaf46391df2dd75760a100ec6c35ad596d5e196c1dab3d235702279baf",
     "dots3-docqa64":
         "273606f80ecb7604a0a9ea55bb2ba5a4171bd90ff040aba6295e572cdbd2982f",
+    # (the geometry PR 69 brought)
+    "falcon-h1-docqa64":
+        "d01c881ece4761a081c25dad0686de0d6224535c5dd26dc23fd452e7ba694fc3",
     "chip_smoke-bf16":
         "885657113170485435814d3d481a1431cd26e4a844dcc3d82a03431564535480",
     "chip_smoke-fp32":
